@@ -1,0 +1,348 @@
+"""Does the Train main path still start on the chip?
+
+    python chip_smoke.py
+
+drives, once, what a user of ray_tpu.train drives: ``ray_tpu.init()`` (a
+subprocess cluster that counts this host's chips) → ``JaxTrainer`` with one
+worker leased every chip → in that worker a mesh over ``jax.devices()``,
+``make_gpt2_train_step(gpt2_124m())`` at full width and depth, batches from
+``get_dataset_shard("train").iter_batches(sharding=…)``, ``step_fn`` for a few
+tens of steps and ``train.report`` per step. Weights and tokens are random,
+from a seed. It then checks what came back (see check_training/check_device).
+
+This process never initialises a JAX backend: a chip belongs to one process
+and that process is the train worker, so every device fact below travelled
+through ``train.report``. With no chip it exits non-zero within seconds and
+prints no result; on a TPU the last line of stdout is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
+
+It reports set-up (compile) seconds and no throughput: speed is the
+benchmark's business. Sizes are arguments of ``run``; ``main`` always asks for
+GPT-2-124M on the chip (tests/test_chip_smoke.py passes gpt2_tiny and CPU).
+"""
+
+from __future__ import annotations
+
+import json
+import math
+import re
+import sys
+from typing import Any, Dict, List, Tuple
+
+STEPS = 24
+PER_CHIP_BATCH = 8           # 124M at 8×1,024 tokens fits a 16 GB chip with room
+DATASET_BATCHES = 4          # global batches in the dataset: 24 steps = 6 epochs
+ALPHABET = 64                # tokens come from the first 64 ids: learning that
+                             # much alone is worth ln(vocab/64) nats of loss
+LR, WARMUP = 6e-4, 4
+MIN_LOSS_DROP = 0.25         # of those nats, first step → mean of the last four
+# First-step agreement of the model's attention (compiled Pallas on the chip)
+# with attention_impl="xla" on the same batch, same chip. Both run bf16 matmuls with f32 accumulation
+# and differ in the order of the softmax/accumulate roundings; bf16's unit
+# roundoff is 2^-9. The loss is a mean over thousands of tokens, the gradient
+# norm a root of a sum over every parameter, so a few roundoffs bound each.
+LOSS_RTOL = 2.0 ** -10
+GRAD_NORM_RTOL = 2.0 ** -8
+
+
+def with_targets(block):
+    """Data map task: next-token targets for a block of token rows."""
+    import numpy as np
+
+    tokens = block["tokens"]
+    targets = np.roll(tokens, -1, axis=1)
+    targets[:, -1] = -1
+    return {"tokens": tokens, "targets": targets}
+
+
+_SHAPE4 = re.compile(r"\b(?:bf16|f32)\[(\d+),(\d+),(\d+),(\d+)\]")
+
+
+def attention_call_shapes(hlo_text: str, head_dim: int) -> Tuple[int, List[List[int]]]:
+    """(number of Mosaic custom calls, the distinct [B, H, S, hd] shapes on
+    their lines) in a compiled step's HLO — the operands and results of the
+    flash kernels as each device runs them."""
+    calls, shapes = 0, set()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        calls += 1
+        for m in _SHAPE4.finditer(line):
+            dims = tuple(int(d) for d in m.groups())
+            if dims[3] == head_dim:
+                shapes.add(dims)
+    return calls, [list(s) for s in sorted(shapes)]
+
+
+def train_loop(config: Dict[str, Any]) -> None:
+    """The per-worker loop. Reports one row per step and a final summary row;
+    judges nothing — the driver does, from the rows."""
+    import importlib.metadata
+    import time
+    from collections import Counter
+    from dataclasses import replace
+
+    import jax
+    import numpy as np
+
+    from ray_tpu import train
+    from ray_tpu.ops.attention import resolve_attention
+    from ray_tpu.parallel import mesh as mesh_lib
+    from ray_tpu.train.train_step import default_optimizer, make_gpt2_train_step
+
+    cfg, steps = config["model"], config["steps"]
+    cache_events: Counter = Counter()
+
+    def on_event(event: str, **_):
+        if event.startswith("/jax/compilation_cache/"):
+            cache_events[event.rsplit("/", 1)[1]] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    t_start = time.perf_counter()
+    devices = jax.devices()
+    backend_seconds = time.perf_counter() - t_start
+    mesh = mesh_lib.make_mesh(
+        mesh_lib.MeshSpec.for_devices(len(devices)), devices
+    )
+
+    def build(model_cfg):
+        return make_gpt2_train_step(
+            model_cfg, mesh=mesh,
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP, total_steps=steps),
+            rng=jax.random.PRNGKey(config["seed"]),
+        )
+
+    bundle = build(cfg)
+    state = bundle.state
+    global_batch = config["per_chip_batch"] * len(devices)
+    shard = train.get_dataset_shard("train")
+
+    first_batch, hlo, compile_seconds, setup_seconds = None, "", 0.0, 0.0
+    step, epochs = 0, 0
+    while step < steps:
+        for batch in shard.iter_batches(
+            batch_size=global_batch, drop_last=True,
+            sharding=bundle.data_sharding,
+        ):
+            if first_batch is None:
+                first_batch = batch
+                t0 = time.perf_counter()
+                hlo = bundle.step_fn.lower(state, batch).compile().as_text()
+                compile_seconds = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            state, metrics = bundle.step_fn(state, batch)
+            row = {
+                "step": int(state["step"]),
+                "loss": float(metrics["loss"]),
+                "grad_norm": float(metrics["grad_norm"]),
+                "epoch": epochs,
+                "seconds": time.perf_counter() - t0,
+            }
+            if step == 0:
+                setup_seconds = time.perf_counter() - t_start
+            train.report(row)
+            step += 1
+            if step == steps:
+                break
+        epochs += 1
+
+    # First-step parity with the XLA einsum attention: fresh state from the
+    # same seed, one batch at full width. The batch is one chip's worth of
+    # rows spread over all devices, not the global batch: under fsdp > 1
+    # GSPMD leaves the XLA variant's S×S residuals unsharded over the batch
+    # (PERF.md, open questions) and the global batch does not fit a chip.
+    data_sharding = bundle.data_sharding
+    del state, bundle
+    n_dev = len(devices)
+    parity_rows = n_dev * max(1, config["per_chip_batch"] // n_dev)
+    parity_batch = jax.device_put(
+        {k: np.asarray(v[:parity_rows]) for k, v in first_batch.items()},
+        data_sharding,
+    )
+    parity = {}
+    for impl in (cfg.attention_impl, "xla"):
+        variant = build(replace(cfg, attention_impl=impl))
+        _, m = variant.step_fn(variant.state, parity_batch)
+        parity[impl] = {"loss": float(m["loss"]),
+                        "grad_norm": float(m["grad_norm"])}
+        del variant
+    jax.monitoring.unregister_event_listener(on_event)
+
+    tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
+    train.report({"summary": {
+        "platforms": sorted({d.platform for d in devices}),
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "jax": jax.__version__,
+        "libtpu": importlib.metadata.version("libtpu"),
+        "mesh": {a: n for a, n in mesh.shape.items() if n > 1},
+        "attention": list(resolve_attention(cfg.attention_impl, mesh)),
+        "tpu_custom_calls": tpu_calls,
+        "attention_call_shapes": attn_shapes,
+        "global_batch": global_batch,
+        "epochs": epochs,
+        "backend_seconds": backend_seconds,
+        "step_compile_seconds": compile_seconds,
+        "setup_seconds": setup_seconds,
+        "cache_dir": jax.config.jax_compilation_cache_dir,
+        "cache_events": dict(cache_events),
+        "parity_rows": parity_rows,
+        "parity": parity,
+    }})
+
+
+def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
+        use_tpu: bool, seed: int = 0) -> List[Dict[str, Any]]:
+    """Driver side: a small token dataset through Data, then
+    JaxTrainer(train_loop) with one worker driving `num_devices` devices.
+    Returns the reported rows (steps, then the summary); raises the worker's
+    error. Needs ray_tpu.init() done; touches no JAX backend."""
+    import numpy as np
+
+    from ray_tpu import data, train
+
+    rows = DATASET_BATCHES * per_chip_batch * num_devices
+    tokens = np.random.default_rng(seed).integers(
+        0, ALPHABET, size=(rows, model_cfg.seq_len), dtype=np.int32
+    )
+    ds = data.from_numpy(
+        np.array_split(tokens, DATASET_BATCHES), column="tokens"
+    ).map_batches(with_targets)
+    result = train.JaxTrainer(
+        train_loop,
+        train_loop_config={
+            "model": model_cfg, "steps": steps,
+            "per_chip_batch": per_chip_batch, "seed": seed,
+        },
+        scaling_config=train.ScalingConfig(
+            num_workers=1, use_tpu=use_tpu,
+            tpus_per_worker=num_devices if use_tpu else 0,
+        ),
+        datasets={"train": ds},
+    ).fit()
+    if result.error is not None:
+        raise result.error
+    return result.metrics_dataframe
+
+
+def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[str]:
+    """What must hold on any device. Returns the failures."""
+    step_rows, summary = rows[:-1], rows[-1]["summary"]
+    bad = []
+    if [r["step"] for r in step_rows] != list(range(1, steps + 1)):
+        bad.append(f"step counter {[r['step'] for r in step_rows]} is not "
+                   f"1..{steps}")
+    for r in step_rows:
+        if not (math.isfinite(r["loss"]) and math.isfinite(r["grad_norm"])):
+            bad.append(f"step {r['step']}: loss {r['loss']} grad_norm "
+                       f"{r['grad_norm']} not finite")
+    if summary["epochs"] < 2:
+        bad.append(f"the iterator did not cycle: {summary['epochs']} epoch(s)")
+    first = step_rows[0]["loss"]
+    last = sum(r["loss"] for r in step_rows[-4:]) / 4
+    need = MIN_LOSS_DROP * math.log(model_cfg.padded_vocab / ALPHABET)
+    if not last < first - need:
+        bad.append(f"loss did not fall: {first:.4f} -> {last:.4f} "
+                   f"(needs {need:.2f} nats)")
+    model, xla = (summary["parity"][i] for i in (model_cfg.attention_impl, "xla"))
+    for name, rtol in (("loss", LOSS_RTOL), ("grad_norm", GRAD_NORM_RTOL)):
+        if not abs(model[name] - xla[name]) <= rtol * abs(xla[name]):
+            bad.append(f"first-step {name} {model[name]!r} disagrees with "
+                       f"attention_impl='xla' {xla[name]!r} beyond rtol {rtol:g}")
+    return bad
+
+
+def check_device(summary: Dict[str, Any], model_cfg, per_chip_batch: int,
+                 advertised_tpus: int) -> List[str]:
+    """What must hold on the chip. Returns the failures."""
+    bad = []
+    if summary["platforms"] != ["tpu"]:
+        bad.append(f"worker devices are on {summary['platforms']}, not tpu")
+    if summary["device_count"] != advertised_tpus:
+        bad.append(f"the node advertised TPU={advertised_tpus} and the worker "
+                   f"leased them all sees {summary['device_count']}")
+    if summary["attention"] != ["pallas", False]:
+        bad.append(f"attention resolved to (impl, interpret)="
+                   f"{summary['attention']}, not compiled Pallas")
+    # fwd + bwd kernels, each on ONE device's shard of the batch: GSPMD
+    # cannot partition a Mosaic call, so anything but the per-chip batch here
+    # means every chip is computing the gathered global batch
+    want = [[per_chip_batch, model_cfg.n_head, model_cfg.seq_len,
+             model_cfg.head_dim]]
+    if summary["tpu_custom_calls"] < 2 or summary["attention_call_shapes"] != want:
+        bad.append(f"compiled step has {summary['tpu_custom_calls']} Mosaic "
+                   f"call(s) over {summary['attention_call_shapes']}; wanted "
+                   f">= 2 over the per-device shard {want}")
+    return bad
+
+
+def _driver_backend_initialised() -> bool:
+    jax = sys.modules.get("jax")
+    return jax is not None and jax._src.xla_bridge.backends_are_initialized()
+
+
+def main() -> int:
+    import os
+
+    import ray_tpu
+    from ray_tpu.core.resources import tpu_device_files
+    from ray_tpu.models import gpt2
+
+    model_cfg = gpt2.gpt2_124m()
+    ray_tpu.init()
+    try:
+        chips = int(ray_tpu.cluster_resources().get("TPU", 0))
+        if chips == 0:
+            # what core/resources.detect_tpu_resources had to go on
+            print("chip_smoke: no TPU on this node (JAX_PLATFORMS="
+                  f"{os.environ.get('JAX_PLATFORMS', 'unset')}; chip device "
+                  f"files: {tpu_device_files() or 'none'})", file=sys.stderr)
+            return 2
+        rows = run(model_cfg, steps=STEPS, per_chip_batch=PER_CHIP_BATCH,
+                   num_devices=chips, use_tpu=True)
+    finally:
+        ray_tpu.shutdown()
+
+    summary = rows[-1]["summary"]
+    failures = check_training(rows, model_cfg, STEPS) + check_device(
+        summary, model_cfg, PER_CHIP_BATCH, chips
+    )
+    if _driver_backend_initialised():
+        failures.append("the driver process initialised a JAX backend")
+
+    print(f"device: platform={','.join(summary['platforms'])} "
+          f"device_kind={summary['device_kind']!r} "
+          f"count={summary['device_count']} (node advertised TPU={chips})")
+    print(f"versions: jax={summary['jax']} libtpu={summary['libtpu']}")
+    print(f"mesh: {summary['mesh'] or 'one device'}  global_batch="
+          f"{summary['global_batch']}x{model_cfg.seq_len}")
+    print(f"attention: impl={summary['attention'][0]} interpret="
+          f"{summary['attention'][1]}; {summary['tpu_custom_calls']} Mosaic "
+          f"calls per device over {summary['attention_call_shapes']}")
+    print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
+          f", step compile {summary['step_compile_seconds']:.1f}, start to "
+          f"end of first step {summary['setup_seconds']:.1f}")
+    print(f"compile cache: {summary['cache_dir']} {summary['cache_events']}")
+    print("loss: " + " ".join(f"{r['loss']:.3f}" for r in rows[:-1]))
+    print("step wall seconds (host-synchronised every step; not a rate): "
+          + " ".join(f"{r['seconds']:.2f}" for r in rows[:-1]))
+    print(f"first-step parity on {summary['parity_rows']} rows: "
+          + "; ".join(f"attention_impl={impl!r} loss={m['loss']:.5f} "
+                      f"grad_norm={m['grad_norm']:.5f}"
+                      for impl, m in summary["parity"].items())
+          + f" (rtol {LOSS_RTOL:g} / {GRAD_NORM_RTOL:g})")
+    print(f"epochs over the {DATASET_BATCHES}-batch dataset: {summary['epochs']}")
+    if failures:
+        for f in failures:
+            print(f"chip_smoke: FAILED: {f}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": {
+        "platform": summary["platforms"][0],
+        "kind": summary["device_kind"],
+        "count": summary["device_count"],
+    }}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

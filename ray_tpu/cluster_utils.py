@@ -96,14 +96,11 @@ class Cluster:
         WAL replay restores every acknowledged mutation."""
         import sys
 
-        from ray_tpu.core.cluster_backend import daemon_env
-
         port = self.gcs_address.rsplit(":", 1)[1]
         self._gcs_proc = self.procs.spawn(
             "gcs-restarted",
             [sys.executable, "-m", "ray_tpu.core.gcs.server",
              "--port", port, "--store", self.gcs_store_path],
-            env=daemon_env(),
         )
 
     def wait_for_nodes(self, n: Optional[int] = None, timeout: float = 30.0):

@@ -27,7 +27,6 @@ KNOWN_ENV_VARS = frozenset({
     "RAY_TPU_SESSION",
     "RAY_TPU_NODE_ID",
     "RAY_TPU_STARTUP_TOKEN",
-    "RAY_TPU_PRESERVED_TPU_ENV",
     "RAY_TPU_LOCAL_MODE",
     "RAY_TPU_CHAOS_PLAN",
     "RAY_TPU_CHAOS_LOG",

@@ -1470,7 +1470,11 @@ class GcsServer:
         node = self.nodes.get(info.node_id) if info.node_id else None
         if node and node.alive and info.address:
             try:
-                await node.conn.call("kill_actor_worker", actor_id=actor_id, timeout=5)
+                # the raylet replies once the worker process is gone
+                from ray_tpu.core.raylet.worker_pool import REAP_TIMEOUT_S
+
+                await node.conn.call("kill_actor_worker", actor_id=actor_id,
+                                     timeout=REAP_TIMEOUT_S + 5)
             except (rpc.RpcError, rpc.ConnectionLost):
                 pass
         if no_restart:

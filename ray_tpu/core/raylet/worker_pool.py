@@ -18,6 +18,8 @@ from typing import Dict, List, Optional, Set
 logger = logging.getLogger(__name__)
 
 STARTING, IDLE, LEASED, ACTOR, DEAD = "STARTING", "IDLE", "LEASED", "ACTOR", "DEAD"
+# a SIGKILLed process that held four v5e chips took 14 s to be gone (PR 21)
+REAP_TIMEOUT_S = 30
 
 
 @dataclass
@@ -33,6 +35,17 @@ class WorkerHandle:
     started_at: float = field(default_factory=time.monotonic)
 
 
+def worker_platform(demand: Optional[Dict[str, float]]) -> str:
+    """The JAX platform a worker process is started on, decided from the
+    resources its lease holds and from nothing else (not the driver's or the
+    raylet's own ``JAX_PLATFORMS``). A chip belongs to one process: the worker
+    leased ``TPU`` runs on ``tpu`` — JAX raises when a platform named
+    explicitly cannot initialise, so it gets the chip or dies — and every
+    other worker on the node runs on ``cpu`` and can never take the chip from
+    under it."""
+    return "tpu" if demand and demand.get("TPU", 0) > 0 else "cpu"
+
+
 class WorkerPool:
     def __init__(self, raylet_address: str, gcs_address: str, session: str,
                  node_id: str, env: Optional[dict] = None):
@@ -46,7 +59,10 @@ class WorkerPool:
         self._registered: asyncio.Event = asyncio.Event()
         self.on_worker_death = None  # callback(handle)
 
-    def start_worker(self, actor_id: Optional[bytes] = None) -> WorkerHandle:
+    def start_worker(self, actor_id: Optional[bytes] = None,
+                     platform: str = "cpu") -> WorkerHandle:
+        """`platform` is worker_platform(<the lease's demand>): pooled task
+        workers hold no TPU lease and keep the default."""
         token = self._next_token
         self._next_token += 1
         env = {
@@ -57,14 +73,8 @@ class WorkerPool:
             "RAY_TPU_SESSION": self.session,
             "RAY_TPU_NODE_ID": self.node_id,
             "RAY_TPU_STARTUP_TOKEN": str(token),
+            "JAX_PLATFORMS": platform,
         }
-        # restore TPU plugin env for workers on TPU nodes (stripped from the
-        # raylet's own env — see cluster_backend.start_raylet)
-        preserved = os.environ.get("RAY_TPU_PRESERVED_TPU_ENV")
-        if preserved:
-            import json
-
-            env.update(json.loads(preserved))
         log_dir = os.path.join("/tmp", "ray_tpu", self.session, "logs")
         os.makedirs(log_dir, exist_ok=True)
         log = open(os.path.join(log_dir, f"worker-{self.node_id}-{token}.log"), "ab")
@@ -150,6 +160,17 @@ class WorkerPool:
             return True
         return False
 
+    def reap(self, handle: WorkerHandle) -> None:
+        """Wait until a killed worker's process is gone. A worker that held
+        the chips frees them only then — not when the signal was sent — and
+        the next process to open them (a restarted trainer, whatever runs
+        after the driver exits) fails or hangs if it comes sooner."""
+        try:
+            handle.proc.wait(timeout=REAP_TIMEOUT_S)
+        except subprocess.TimeoutExpired:
+            logger.warning("worker pid=%d not gone %ds after SIGKILL",
+                           handle.proc.pid, REAP_TIMEOUT_S)
+
     def shutdown(self):
         for w in self.workers.values():
             try:
@@ -157,7 +178,4 @@ class WorkerPool:
             except ProcessLookupError:
                 pass
         for w in self.workers.values():
-            try:
-                w.proc.wait(timeout=2)
-            except subprocess.TimeoutExpired:
-                pass
+            self.reap(w)

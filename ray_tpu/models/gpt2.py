@@ -253,38 +253,17 @@ def _layernorm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
-def _resolve_attention_impl(cfg: GPT2Config):
-    """Resolve attention_impl='auto' against the active mesh/backend.
-    Returns (impl, mesh, interpret) — interpret is the Pallas interpret-mode
-    choice (decided off the mesh's devices, not the process default backend;
-    None = let the kernel decide from the default backend)."""
-    from ray_tpu.parallel import mesh as mesh_lib
-
-    mesh = mesh_lib.current_mesh()
-    impl = cfg.attention_impl
-    if impl == "auto":
-        # cp axis on the mesh → ring attention (sequence parallel). Otherwise
-        # TPU gets the Pallas flash kernel (no S×S residuals → no full remat)
-        # and other backends the XLA einsum path (flash-in-interpret is slow).
-        if mesh is not None and mesh.shape.get("cp", 1) > 1:
-            impl = "ring"
-        else:
-            impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    interpret = None
-    if mesh is not None:
-        interpret = mesh.devices.flat[0].platform != "tpu"
-    return impl, mesh, interpret
-
-
 def _attention(q, k, v, cfg: GPT2Config):
     """q,k,v: [B, H, S, hd] → [B, H, S, hd], causal (head-major layout — the
     flash kernels' native one, so the hot path has no boundary transposes)."""
-    impl, mesh, interpret = _resolve_attention_impl(cfg)
-    if impl == "pallas":
-        from ray_tpu.ops.attention import flash_attention
+    from ray_tpu.ops.attention import flash_attention_sharded, resolve_attention
+    from ray_tpu.parallel import mesh as mesh_lib
 
-        return flash_attention(
-            q, k, v, causal=True, interpret=interpret, layout="bhsd",
+    mesh = mesh_lib.current_mesh()
+    impl, interpret = resolve_attention(cfg.attention_impl, mesh)
+    if impl == "pallas":
+        return flash_attention_sharded(
+            q, k, v, mesh, causal=True, interpret=interpret,
             block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
             bwd_block_q=cfg.attn_bwd_block_q or None,
             bwd_block_k=cfg.attn_bwd_block_k or None,

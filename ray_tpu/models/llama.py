@@ -190,14 +190,19 @@ def _attention(q, k, v, cfg: LlamaConfig):
     if groups > 1:
         k = jnp.repeat(k, groups, axis=1)
         v = jnp.repeat(v, groups, axis=1)
-    impl = cfg.attention_impl
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas":
-        from ray_tpu.ops.attention import flash_attention
+    from ray_tpu.ops.attention import flash_attention_sharded, resolve_attention
+    from ray_tpu.parallel import mesh as mesh_lib
 
-        return flash_attention(
-            q, k, v, causal=True, layout="bhsd",
+    mesh = mesh_lib.current_mesh()
+    impl, interpret = resolve_attention(cfg.attention_impl, mesh)
+    if impl == "ring":
+        raise NotImplementedError(
+            "models/llama.py has no ring-attention path; use a mesh without "
+            "a cp axis"
+        )
+    if impl == "pallas":
+        return flash_attention_sharded(
+            q, k, v, mesh, causal=True, interpret=interpret,
             block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
         )
     S = q.shape[2]

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30  # mask value: large-negative, not -inf (keeps exp() exact 0)
 
@@ -53,8 +54,26 @@ def _pick_block(seq_len: int, preferred: int) -> int:
     return max(b, 1)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def resolve_attention(impl: str = "auto", mesh=None) -> Tuple[str, bool]:
+    """THE rule for which attention runs and whether its Pallas kernels are
+    interpreted. Returns ``(impl, interpret)``.
+
+    Decided from the devices the computation is laid out on — ``mesh``'s, or
+    the default backend's when there is no mesh — never from the caller's
+    guess: a CPU mesh on a TPU host must interpret, a TPU mesh must not.
+    ``impl="auto"`` becomes ``"ring"`` on a mesh with a cp axis, else the
+    compiled flash kernel on TPU (no S×S residuals → no full remat) and the
+    XLA einsum elsewhere (flash-in-interpret is slow); an explicit impl is
+    kept. Off TPU every Pallas kernel interprets, so tests run the same code.
+    """
+    devices = mesh.devices.flat if mesh is not None else jax.devices()
+    on_tpu = devices[0].platform == "tpu"
+    if impl == "auto":
+        if mesh is not None and mesh.shape.get("cp", 1) > 1:
+            impl = "ring"
+        else:
+            impl = "pallas" if on_tpu else "xla"
+    return impl, not on_tpu
 
 
 # --------------------------------------------------------------------------- #
@@ -454,7 +473,7 @@ def flash_attention(
     if layout not in ("bshd", "bhsd"):
         raise ValueError(f"unknown layout {layout!r}")
     if interpret is None:
-        interpret = _use_interpret()
+        _, interpret = resolve_attention()
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _flash(
@@ -462,6 +481,50 @@ def flash_attention(
         bwd_block_q or block_q, bwd_block_k or block_k,
         interpret, layout == "bhsd", block_h, bwd_block_h or block_h,
     )
+
+
+def batch_head_axes(mesh, batch: int, heads: int):
+    """Mesh axes an activation's batch and head dims are split over inside a
+    shard_map: batch over whichever of (dp, fsdp) divide it, heads over tp
+    when it divides — parallel/sharding.py's activation layout. Axes that do
+    not divide are dropped (replicated) so small test shapes work on any
+    mesh; model-size shapes shard fully. Returns (batch_axes | None, head_axis
+    | None)."""
+    batch_axes = []
+    rem = batch
+    for ax in ("dp", "fsdp"):
+        sz = mesh.shape.get(ax, 1)
+        if sz > 1 and rem % sz == 0:
+            batch_axes.append(ax)
+            rem //= sz
+    head_ax = "tp" if heads % mesh.shape.get("tp", 1) == 0 else None
+    return tuple(batch_axes) or None, head_ax
+
+
+def flash_attention_sharded(q, k, v, mesh, **kwargs) -> jax.Array:
+    """flash_attention for callers under jit/GSPMD (the model forward).
+    q, k, v: GLOBAL [B, H, S, hd] in and out; kwargs as flash_attention's.
+
+    GSPMD cannot partition a Mosaic custom call: under a jit over more than
+    one device the bare pallas_call does not lower at all ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map"). The shard_map hands each device its own
+    [B/(dp·fsdp), H/tp, S, hd] shard. The sequence stays whole per device
+    (a cp axis belongs to ring_attention_sharded)."""
+    if mesh is None:
+        return flash_attention(q, k, v, layout="bhsd", **kwargs)
+    if kwargs.get("interpret") is None:
+        _, kwargs["interpret"] = resolve_attention(mesh=mesh)
+    batch_axes, head_ax = batch_head_axes(mesh, q.shape[0], q.shape[1])
+    spec = P(batch_axes, head_ax, None, None)
+    fn = jax.shard_map(
+        functools.partial(flash_attention, layout="bhsd", **kwargs),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )
+    return fn(q, k, v)
 
 
 def flash_attention_with_lse(
@@ -477,7 +540,7 @@ def flash_attention_with_lse(
     per-step chunk computation (ops/ring_attention.py merges partials by lse).
     """
     if interpret is None:
-        interpret = _use_interpret()
+        _, interpret = resolve_attention()
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     q_off = jnp.asarray([q_offset], jnp.int32).reshape(1)
@@ -502,7 +565,7 @@ def mha_backward_chunk(
     (dq, dk, dv) contributions (all [B,S,H,hd]). `lse` is the GLOBAL logsumexp
     over all chunks. Used by ring attention's backward ring pass."""
     if interpret is None:
-        interpret = _use_interpret()
+        _, interpret = resolve_attention()
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     q_off = jnp.asarray([q_offset], jnp.int32).reshape(1)

@@ -33,8 +33,10 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import (
+    batch_head_axes,
     flash_attention_with_lse,
     mha_backward_chunk,
+    resolve_attention,
 )
 
 _NEG_INF = -1e30  # matches ops/attention.py's mask value
@@ -154,7 +156,7 @@ def ring_attention(
     (inside shard_map/pmap); q, k, v are the LOCAL sequence shards
     [B, S_local, H, hd]. Differentiable (custom VJP, ring backward)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        _, interpret = resolve_attention()
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _ring(q, k, v, axis_name, causal, scale, block_q, block_k, interpret)
@@ -175,33 +177,18 @@ def ring_attention_sharded(
 ) -> jax.Array:
     """Ring attention for callers under jit/GSPMD (the GPT-2 forward): wraps
     the ring in a shard_map over `mesh` with batch on (dp, fsdp), sequence on
-    `axis_name`, heads on tp — matching parallel/sharding.py's activation
-    layout. GLOBAL-length q/k/v in, global out.
-
-    Mesh axes that don't divide the corresponding dim are dropped from the
-    spec (replicated) so small test shapes work on any mesh; the model-size
-    path shards fully."""
+    `axis_name`, heads on tp (ops/attention.batch_head_axes). GLOBAL-length
+    q/k/v in, global out."""
     if interpret is None:
-        # Decide off the mesh's actual devices, not the process default
-        # backend: a CPU mesh on a TPU-attached host must interpret.
-        interpret = mesh.devices.flat[0].platform != "tpu"
+        _, interpret = resolve_attention(mesh=mesh)
     cp = mesh.shape.get(axis_name, 1)
     if q.shape[1] % cp:
         raise ValueError(
             f"sequence length {q.shape[1]} not divisible by {axis_name} axis "
             f"size {cp}; pad the sequence or change the mesh"
         )
-    # batch over whichever data axes divide it; heads over tp when it divides
-    B, _, H, _ = q.shape
-    batch_axes = []
-    rem = B
-    for ax in ("dp", "fsdp"):
-        sz = mesh.shape.get(ax, 1)
-        if sz > 1 and rem % sz == 0:
-            batch_axes.append(ax)
-            rem //= sz
-    head_ax = "tp" if H % mesh.shape.get("tp", 1) == 0 else None
-    spec = P(tuple(batch_axes) or None, axis_name, head_ax, None)
+    batch_axes, head_ax = batch_head_axes(mesh, q.shape[0], q.shape[2])
+    spec = P(batch_axes, axis_name, head_ax, None)
     fn = jax.shard_map(
         functools.partial(
             ring_attention,

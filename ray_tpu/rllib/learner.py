@@ -49,7 +49,9 @@ class JaxLearner:
         import optax
 
         from ray_tpu.rllib.models import mlp_actor_critic_init
+        from ray_tpu.util.compile_cache import enable_compile_cache
 
+        enable_compile_cache()
         self.obs_dim = obs_dim
         self.num_actions = num_actions
         self.num_epochs = num_epochs

@@ -49,7 +49,8 @@ class TrainWorker:
         free.bind(("", 0))
         port = free.getsockname()[1]
         free.close()
-        return {"ip": ip, "port": port, "pid": os.getpid()}
+        return {"ip": ip, "port": port, "pid": os.getpid(),
+                "node_id": os.environ.get("RAY_TPU_NODE_ID", "")}
 
     def setup_jax_distributed(self, coordinator: str, num_processes: int,
                               process_id: int) -> bool:
@@ -86,6 +87,9 @@ class TrainWorker:
             experiment_name=self.experiment_name,
         )
         self.session = _Session(ctx, latest_checkpoint, dataset_shards)
+        from ray_tpu.util.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
         def run():
             _set_session(self.session)
@@ -132,6 +136,7 @@ class WorkerGroup:
     def __init__(self, num_workers: int, resources_per_worker: Dict[str, float],
                  experiment_name: str = "", placement_strategy: str = "PACK"):
         self.num_workers = num_workers
+        self.tpu_leased = bool(resources_per_worker.get("TPU"))
         self.placement_group = None
         actor_cls = ray_tpu.remote(TrainWorker)
         opts: Dict[str, Any] = {
@@ -200,6 +205,17 @@ class WorkerGroup:
         last_err: Optional[BaseException] = None
         for _ in range(attempts):
             infos = self.for_all("host_info")
+            nodes = [i["node_id"] for i in infos]
+            if self.tpu_leased and len(set(nodes)) < len(nodes):
+                # a chip belongs to one process and nothing yet narrows a
+                # process to its share of a host's chips: the second worker
+                # would fail or hang opening them. Refuse before any rank
+                # touches JAX (host_info does not).
+                raise RuntimeError(
+                    f"{len(nodes)} TPU-leased train workers on "
+                    f"{len(set(nodes))} host(s) {sorted(set(nodes))}: use "
+                    "one worker per host with tpus_per_worker=<all its chips>"
+                )
             coordinator = f"{infos[0]['ip']}:{infos[0]['port']}"
             refs = [
                 w.setup_jax_distributed.remote(

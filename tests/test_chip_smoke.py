@@ -1,0 +1,240 @@
+"""The launch path's rule for who may touch the chip, and chip_smoke.py.
+
+CPU only. The driver (this pytest process) runs with JAX_PLATFORMS=cpu and the
+node below advertises two FAKE chips (num_tpus=2): enough to see which
+platform the raylet starts each worker on, and nothing here initialises JAX in
+a TPU-leased worker. chip_smoke.py's own loop runs at gpt2_tiny size on the
+CPU mesh — the repo's one test of GPT-2 through JaxTrainer and the Data
+iterator; at GPT-2-124M it runs on the chip (python chip_smoke.py).
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ------------------------------------------------------------------- units
+@pytest.mark.parametrize("driver_platforms", ["cpu", "tpu,cpu", None])
+def test_worker_platform_comes_from_the_lease(monkeypatch, driver_platforms):
+    """TPU in the demand → the process starts on `tpu`; otherwise `cpu` —
+    whatever JAX_PLATFORMS the raylet inherited from the driver."""
+    from ray_tpu.core.raylet import worker_pool
+
+    if driver_platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", driver_platforms)
+    assert worker_pool.worker_platform({"CPU": 1, "TPU": 4}) == "tpu"
+    assert worker_pool.worker_platform({"CPU": 1, "TPU": 0}) == "cpu"
+    assert worker_pool.worker_platform({"CPU": 1}) == "cpu"
+    assert worker_pool.worker_platform(None) == "cpu"
+
+    spawned = []
+
+    class FakePopen:
+        pid = 0
+
+        def __init__(self, argv, env, **_):
+            spawned.append(env)
+
+    monkeypatch.setattr(worker_pool.subprocess, "Popen", FakePopen)
+    pool = worker_pool.WorkerPool(
+        "127.0.0.1:1", "127.0.0.1:2", "s-unit-platform", "n0",
+        env={"JAX_PLATFORMS": "tpu,cpu"},
+    )
+    pool.start_worker()
+    pool.start_worker(actor_id=b"a", platform=worker_pool.worker_platform({"TPU": 1}))
+    assert [e["JAX_PLATFORMS"] for e in spawned] == ["cpu", "tpu"]
+
+
+def test_detect_tpu_resources_counts_device_files(monkeypatch):
+    from ray_tpu.core import resources
+
+    chips = ["/dev/vfio/0", "/dev/vfio/1"]
+    monkeypatch.setattr(resources, "tpu_device_files", lambda: chips)
+    monkeypatch.setattr(resources.os, "access", lambda p, mode: True)
+    # the slice's name does not count this host's chips, the files do
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert resources.detect_tpu_resources() == {}
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert resources.detect_tpu_resources() == {"TPU": 2.0, "TPU-v5litepod": 2.0}
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert resources.detect_tpu_resources()["TPU"] == 2.0
+
+    # chips that cannot be opened are a broken host, not a TPU-less one
+    monkeypatch.setattr(resources.os, "access", lambda p, mode: False)
+    with pytest.raises(RuntimeError, match="cannot open"):
+        resources.detect_tpu_resources()
+    monkeypatch.setattr(resources, "tpu_device_files", lambda: [])
+    assert resources.detect_tpu_resources() == {}
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    import jax
+
+    from ray_tpu.util import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(REPO, ".jax_cache")
+        assert compile_cache.enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_attention_call_shapes_reads_compiled_hlo():
+    import chip_smoke
+
+    hlo = "\n".join([
+        "  %fusion.1 = bf16[8,12,1024,64]{3,2,1,0} fusion(%p)",
+        '  %custom-call.3 = (bf16[8,12,1024,64]{3,2,1,0:T(8,128)(2,1)}, '
+        'f32[8,12,1,1024]{3,2,1,0}) custom-call(s32[1]{0} %a, s32[1]{0} %b, '
+        'bf16[8,12,1024,64]{3,2,1,0} %q, bf16[8,12,1024,64]{3,2,1,0} %k), '
+        'custom_call_target="tpu_custom_call", backend_config={}',
+        '  %custom-call.4 = (f32[32,12,1024,64]{3,2,1,0}, bf16[32,12,1024,64]'
+        '{3,2,1,0}) custom-call(%x), custom_call_target="tpu_custom_call"',
+    ])
+    assert chip_smoke.attention_call_shapes(hlo, 64) == (
+        2, [[8, 12, 1024, 64], [32, 12, 1024, 64]]
+    )
+
+
+# ------------------------------------------------------- separate processes
+def _run(code_or_argv, env_update, cwd=REPO, timeout=180):
+    env = {**os.environ, **{k: v for k, v in env_update.items() if v is not None}}
+    for k, v in env_update.items():
+        if v is None:
+            env.pop(k, None)
+    argv = code_or_argv if isinstance(code_or_argv, list) else [
+        sys.executable, "-c", code_or_argv
+    ]
+    return subprocess.run(argv, env=env, cwd=cwd, capture_output=True,
+                          text=True, timeout=timeout)
+
+
+def test_init_with_detection_leaves_driver_off_jax_and_no_process_behind():
+    """ray_tpu.init() counts chips (JAX_PLATFORMS unset, so the device-file
+    path really runs) and the driver ends with no JAX backend initialised;
+    after shutdown() nothing the session started is still running — the
+    raylet stops its workers and transfer daemon when it is terminated."""
+    code = (
+        "import glob, sys, json, ray_tpu\n"
+        "ray_tpu.init(num_cpus=1)\n"
+        "res = ray_tpu.cluster_resources()\n"
+        "session = ray_tpu.api._global_worker().backend.core.session\n"
+        "ray_tpu.get(ray_tpu.remote(lambda: 1).remote(), timeout=60)\n"
+        "ray_tpu.shutdown()\n"
+        "left = []\n"
+        "for f in glob.glob('/proc/[0-9]*/environ') + glob.glob('/proc/[0-9]*/cmdline'):\n"
+        "    try:\n"
+        "        if session.encode() in open(f, 'rb').read(): left.append(f)\n"
+        "    except OSError: pass\n"
+        "jax = sys.modules.get('jax')\n"
+        "up = jax is not None and jax._src.xla_bridge.backends_are_initialized()\n"
+        "print(json.dumps({'backend': up, 'TPU': res.get('TPU', 0), 'left': left}))\n"
+    )
+    r = _run(code, {"JAX_PLATFORMS": None})
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert json.loads(r.stdout.strip().splitlines()[-1]) == {
+        "backend": False, "TPU": 0, "left": [],
+    }
+
+
+def test_chip_smoke_without_a_chip_fails_and_says_why(tmp_path):
+    r = _run([sys.executable, "chip_smoke.py"], {"JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr and "JAX_PLATFORMS=cpu" in r.stderr, r.stderr
+    assert '"ok"' not in r.stdout
+
+    # and alone, without the program
+    alone = tmp_path / "chip_smoke.py"
+    alone.write_text(open(os.path.join(REPO, "chip_smoke.py")).read())
+    r = _run([sys.executable, "chip_smoke.py"], {"PYTHONPATH": None},
+             cwd=str(tmp_path))
+    assert r.returncode != 0 and '"ok"' not in r.stdout
+
+
+# ------------------------------------------------- one node, two fake chips
+@pytest.fixture(scope="module")
+def fake_tpu_node():
+    import ray_tpu
+
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=4, num_tpus=2)
+    yield ray_tpu
+    ray_tpu.shutdown()
+
+
+def _platform():
+    import os
+
+    return os.environ.get("JAX_PLATFORMS")
+
+
+def test_only_the_tpu_leased_process_starts_on_tpu(fake_tpu_node):
+    ray_tpu = fake_tpu_node
+    from ray_tpu import exceptions as exc
+
+    class Holder:
+        def platform(self):
+            return _platform()
+
+    leased = ray_tpu.remote(num_tpus=1)(Holder).remote()
+    plain = ray_tpu.remote(Holder).remote()
+    task = ray_tpu.remote(_platform)
+    assert os.environ["JAX_PLATFORMS"] == "cpu"  # the driver's, not a worker's
+    assert ray_tpu.get(
+        [leased.platform.remote(), plain.platform.remote(), task.remote()],
+        timeout=60,
+    ) == ["tpu", "cpu", "cpu"]
+    # a pooled task worker is on the CPU backend: it is never handed a TPU
+    # lease it would silently run on the CPU
+    with pytest.raises(exc.RayTpuError, match="actors only"):
+        ray_tpu.get(ray_tpu.remote(num_tpus=1)(_platform).remote(), timeout=60)
+    ray_tpu.kill(leased)
+    ray_tpu.kill(plain)
+
+
+def test_two_tpu_workers_on_one_host_are_refused_not_hung(fake_tpu_node):
+    from ray_tpu.train.worker_group import WorkerGroup
+
+    group = WorkerGroup(2, {"CPU": 1, "TPU": 1})
+    try:
+        with pytest.raises(RuntimeError, match="one worker per host"):
+            group.rendezvous()
+    finally:
+        group.shutdown()
+
+
+def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
+    """chip_smoke's loop: JaxTrainer → Dataset.iter_batches(sharding=…) →
+    make_gpt2_train_step → step_fn, with gpt2_tiny on the worker's CPU mesh."""
+    import chip_smoke
+    from ray_tpu.models import gpt2
+
+    cfg, steps = gpt2.gpt2_tiny(), 16
+    rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1,
+                          num_devices=8, use_tpu=False)
+    assert chip_smoke.check_training(rows, cfg, steps) == []
+    summary = rows[-1]["summary"]
+    assert summary["platforms"] == ["cpu"] and summary["device_count"] == 8
+    assert summary["mesh"] == {"fsdp": 8} and summary["global_batch"] == 8
+    assert summary["attention"] == ["xla", True]   # today's CPU-mesh choice
+    assert summary["cache_dir"] == os.environ.get(
+        "JAX_COMPILATION_CACHE_DIR", os.path.join(REPO, ".jax_cache")
+    )
+    # and what the chip check would say about this run
+    assert chip_smoke.check_device(summary, cfg, 1, advertised_tpus=8) != []
