@@ -1,0 +1,13 @@
+"""`bwd_ms_per_step`: Device time a step of the instructions whose `op_name`
+says backward (`transpose(jvp(`), remat's recompute included, first chip."""
+
+LAYER = "Step"
+UNIT = "ms"
+MOVES = "tokens_per_s_per_chip"
+SOURCE = "device_trace"
+
+
+def read(facts):
+    from benchmarks.harness import program_trace
+
+    return program_trace.device_metric(facts, "bwd_ms_per_step")

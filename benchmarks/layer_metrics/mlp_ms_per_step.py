@@ -1,0 +1,13 @@
+"""`mlp_ms_per_step`: Device time a step under the block's `mlp` scope,
+forward, backward and recompute, first chip."""
+
+LAYER = "Model"
+UNIT = "ms"
+MOVES = "tokens_per_s_per_chip"
+SOURCE = "device_trace"
+
+
+def read(facts):
+    from benchmarks.harness import program_trace
+
+    return program_trace.device_metric(facts, "scope_ms_per_step.mlp")

@@ -411,9 +411,10 @@ class CoreWorker:
         while True:
             await asyncio.sleep(period)
             try:
-                # wire counters aggregate cluster-wide as registry Counters
-                rpc.publish_wire_counters()
-                samples = metrics_api.get_registry().collect()
+                with tracing.bg_span("metrics_report"):
+                    # wire counters aggregate cluster-wide as registry Counters
+                    rpc.publish_wire_counters()
+                    samples = metrics_api.get_registry().collect()
                 if samples and self.gcs is not None and not self.gcs.closed:
                     await self.gcs.notify(
                         "report_metrics", source=source, samples=samples
@@ -653,10 +654,9 @@ class CoreWorker:
 
     # ------------------------------------------------------------- put/get
     # tracing: put/get record "core.put"/"core.get" spans, but only for
-    # operations that took >= _PROFILE_MIN_DUR_S — sub-millisecond hot-path
-    # calls (inline-ready gets, tiny puts) stay span-free so tight get/put
-    # loops don't flood the bounded event buffer.
-    _PROFILE_MIN_DUR_S = 0.001
+    # operations that took >= tracing.PROFILE_MIN_DUR_S — sub-millisecond
+    # hot-path calls (inline-ready gets, tiny puts) stay span-free so tight
+    # get/put loops don't flood the bounded event buffer.
 
     def _put_one(self, value: Any) -> Tuple[ObjectRef, int]:
         """Shared body of put/put_batch: allocate, serialize, own, store."""
@@ -674,7 +674,7 @@ class CoreWorker:
         t0 = time.perf_counter()
         ref, nbytes = self._put_one(value)
         dur = time.perf_counter() - t0
-        if dur >= self._PROFILE_MIN_DUR_S and self.events.enabled():
+        if dur >= tracing.PROFILE_MIN_DUR_S and self.events.enabled():
             self.events.record_profile(
                 "core.put", dur=dur, component="core",
                 node_id=self.node_id, worker=self.address,
@@ -695,7 +695,7 @@ class CoreWorker:
             total += nbytes
             refs.append(ref)
         dur = time.perf_counter() - t0
-        if dur >= self._PROFILE_MIN_DUR_S and self.events.enabled():
+        if dur >= tracing.PROFILE_MIN_DUR_S and self.events.enabled():
             self.events.record_profile(
                 "core.put_batch", dur=dur, component="core",
                 node_id=self.node_id, worker=self.address,
@@ -798,12 +798,13 @@ class CoreWorker:
             await asyncio.sleep(period)
             try:
                 by_raylet: Dict[str, List[str]] = {}
-                for oid, loc in list(self.locations.items()):
-                    if oid.binary() not in self._owned:
-                        continue
-                    addr = (loc or {}).get("raylet_addr")
-                    if addr:
-                        by_raylet.setdefault(addr, []).append(oid.hex())
+                with tracing.bg_span("pin_renew"):
+                    for oid, loc in list(self.locations.items()):
+                        if oid.binary() not in self._owned:
+                            continue
+                        addr = (loc or {}).get("raylet_addr")
+                        if addr:
+                            by_raylet.setdefault(addr, []).append(oid.hex())
                 for addr, entries in by_raylet.items():
                     await self._send_pin_renewals(addr, entries)
             except Exception:  # noqa: BLE001 - bookkeeping must never kill io
@@ -839,7 +840,7 @@ class CoreWorker:
             return self._get_untraced(refs, timeout)
         finally:
             dur = time.perf_counter() - t0
-            if dur >= self._PROFILE_MIN_DUR_S:
+            if dur >= tracing.PROFILE_MIN_DUR_S:
                 self.events.record_profile(
                     "core.get", dur=dur, component="core",
                     node_id=self.node_id, worker=self.address,
@@ -1891,17 +1892,18 @@ class CoreWorker:
             await asyncio.sleep(ttl / 2)
             now = time.monotonic()
             expired: Dict[int, tuple] = {}
-            for pool in list(self._lease_pools.values()):
-                for entry in list(pool.idle):
-                    if now - entry.last_used > ttl and entry.inflight == 0:
-                        pool.idle.remove(entry)
-                        entry.pooled = False
-                        entry.dropped = True  # a late release must not re-pool
-                        pool.wake()
-                        _, ids = expired.setdefault(
-                            id(entry.raylet), (entry.raylet, [])
-                        )
-                        ids.append(entry.lease_id)
+            with tracing.bg_span("lease_reaper"):
+                for pool in list(self._lease_pools.values()):
+                    for entry in list(pool.idle):
+                        if now - entry.last_used > ttl and entry.inflight == 0:
+                            pool.idle.remove(entry)
+                            entry.pooled = False
+                            entry.dropped = True  # a late release must not re-pool
+                            pool.wake()
+                            _, ids = expired.setdefault(
+                                id(entry.raylet), (entry.raylet, [])
+                            )
+                            ids.append(entry.lease_id)
             for raylet, lease_ids in expired.values():
                 try:
                     await raylet.call(

@@ -11,11 +11,26 @@ device_put is async, so issuing it early is all the overlap XLA needs).
 from __future__ import annotations
 
 import collections
+import itertools
 from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
 
-from ray_tpu.data.block import Block, block_concat, block_num_rows, block_slice
+from ray_tpu.data.block import (
+    Block, block_concat, block_num_rows, block_size_bytes, block_slice,
+)
+from ray_tpu.tracing import PROFILE_MIN_DUR_S, profile_span
+from ray_tpu.tracing import names as span_names
+
+
+def _span(path: str, batch: int) -> profile_span:
+    """A span of the Data layer (``ray_tpu:data/<name>``), tagged with the
+    iterator's own count of the batch it works towards. Opened and closed
+    where the work happens, never across a ``yield``: a consumer's pause is
+    not the iterator's time."""
+    component, name = path.split("/")
+    return profile_span(name, {"batch": batch}, component=component,
+                        min_dur_s=PROFILE_MIN_DUR_S)
 
 
 def _host_batches(
@@ -26,20 +41,43 @@ def _host_batches(
 
     buf = []
     buffered = 0
+    batch = 0      # batches handed out so far: the id of the one in the making
     for ref in block_refs:
-        block = ray_tpu.get(ref)
+        with _span(span_names.DATA_GET_BLOCK, batch) as span:
+            block = ray_tpu.get(ref)
+            span.args.update(rows=block_num_rows(block),
+                             bytes=block_size_bytes(block))
         if block_num_rows(block) == 0:
             continue
         buf.append(block)
         buffered += block_num_rows(block)
         while buffered >= batch_size:
-            merged = block_concat(buf)
-            yield block_slice(merged, 0, batch_size)
-            rest = block_slice(merged, batch_size, buffered)
+            with _span(span_names.DATA_ASSEMBLE, batch):
+                merged = block_concat(buf)
+                out = block_slice(merged, 0, batch_size)
+            yield out
+            with _span(span_names.DATA_ASSEMBLE, batch):
+                rest = block_slice(merged, batch_size, buffered)
             buf = [rest] if block_num_rows(rest) else []
             buffered -= batch_size
+            batch += 1
     if buffered and not drop_last:
-        yield block_concat(buf)
+        with _span(span_names.DATA_ASSEMBLE, batch):
+            out = block_concat(buf)
+        yield out
+
+
+def _device_put(target: Any):
+    """``jax.device_put`` onto ``target`` under a span, counting batches."""
+    import jax
+
+    count = itertools.count()
+
+    def put(batch: Block):
+        with _span(span_names.DATA_DEVICE_PUT, next(count)):
+            return jax.device_put(batch, target)
+
+    return put
 
 
 def _prefetched(items: Iterator[Any], put, depth: int) -> Iterator[Any]:
@@ -71,12 +109,7 @@ def iter_batches(
         yield from host_iter
         return
 
-    import jax
-
-    def put(batch: Block):
-        target = sharding if sharding is not None else device
-        return jax.device_put(batch, target)
-
+    put = _device_put(sharding if sharding is not None else device)
     yield from _prefetched(host_iter, put, max(1, prefetch_batches + 1))
 
 
@@ -115,10 +148,6 @@ def iter_stacked_batches(
         yield from stacks()
         return
 
-    import jax
-
     yield from _prefetched(
-        stacks(),
-        lambda stacked: jax.device_put(stacked, stacked_sharding),
-        max(1, prefetch_stacks + 1),
+        stacks(), _device_put(stacked_sharding), max(1, prefetch_stacks + 1),
     )

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.tracing import names as scopes
+
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -293,6 +295,7 @@ def _attention(q, k, v, cfg: GPT2Config):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+@jax.named_scope(scopes.BLOCK)
 def _block(x, layer_params, cfg: GPT2Config):
     """One transformer block. x: [B, S, D] (or (x, aux) when MoE is on —
     the load-balance loss accumulates through the layer carry)."""
@@ -301,33 +304,40 @@ def _block(x, layer_params, cfg: GPT2Config):
         x, aux_in = x
     p = layer_params
     dt = cfg.dtype
-    h = _layernorm(x, p["ln1_scale"], p["ln1_bias"])
+    with jax.named_scope(scopes.LN1):
+        h = _layernorm(x, p["ln1_scale"], p["ln1_bias"])
     # head-major projection, one einsum per q/k/v: each matmul writes its
     # output directly in the flash kernels' [B, H, S, hd] layout (XLA emits
     # transposed-output dots with NO separate formatting op — measured 0.04
     # ms/step). A packed single [D, 3·H·hd] dot was tried (round 5): it
     # saved 7 ms of matmul but XLA materialized 12.5 ms/step of layout
     # glue for the rank-5 transposed output — net loss.
-    w, b = p["qkv_w"].astype(dt), p["qkv_b"].astype(dt)
-    q, k, v = (
-        jnp.einsum("bsd,dhk->bhsk", h, w[:, i]) + b[i][None, :, None, :]
-        for i in range(3)
-    )
-    attn = _attention(q, k, v, cfg)
-    x = x + jnp.einsum("bhsk,hkd->bsd", attn, p["proj_w"].astype(dt)) + p["proj_b"].astype(dt)
-    h = _layernorm(x, p["ln2_scale"], p["ln2_bias"])
+    with jax.named_scope(scopes.QKV):
+        w, b = p["qkv_w"].astype(dt), p["qkv_b"].astype(dt)
+        q, k, v = (
+            jnp.einsum("bsd,dhk->bhsk", h, w[:, i]) + b[i][None, :, None, :]
+            for i in range(3)
+        )
+    with jax.named_scope(scopes.ATTN):
+        attn = _attention(q, k, v, cfg)
+    with jax.named_scope(scopes.PROJ):
+        x = x + jnp.einsum("bhsk,hkd->bsd", attn, p["proj_w"].astype(dt)) + p["proj_b"].astype(dt)
+    with jax.named_scope(scopes.LN2):
+        h = _layernorm(x, p["ln2_scale"], p["ln2_bias"])
     if cfg.moe_experts > 0:
         from ray_tpu.ops.moe import moe_mlp
 
-        y, aux = moe_mlp(
-            h, p["moe"], top_k=cfg.moe_top_k,
-            capacity_factor=cfg.moe_capacity_factor, dtype=dt,
-        )
-        x = x + y
+        with jax.named_scope(scopes.MOE):
+            y, aux = moe_mlp(
+                h, p["moe"], top_k=cfg.moe_top_k,
+                capacity_factor=cfg.moe_capacity_factor, dtype=dt,
+            )
+            x = x + y
         return (x, (aux_in if aux_in is not None else 0.0) + aux)
-    h = jnp.einsum("bsd,df->bsf", h, p["fc_w"].astype(dt)) + p["fc_b"].astype(dt)
-    h = jax.nn.gelu(h, approximate=True)
-    x = x + jnp.einsum("bsf,fd->bsd", h, p["out_w"].astype(dt)) + p["out_b"].astype(dt)
+    with jax.named_scope(scopes.MLP):
+        h = jnp.einsum("bsd,df->bsf", h, p["fc_w"].astype(dt)) + p["fc_b"].astype(dt)
+        h = jax.nn.gelu(h, approximate=True)
+        x = x + jnp.einsum("bsf,fd->bsd", h, p["out_w"].astype(dt)) + p["out_b"].astype(dt)
     return x if aux_in is None else (x, aux_in)
 
 
@@ -382,16 +392,15 @@ def _trunk(params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config) -> jax.Ar
 
     B, S = tokens.shape
     dt = cfg.dtype
-    wte = params["wte"].astype(dt)
-    x = wte[tokens] + params["wpe"][:S].astype(dt)
+    with jax.named_scope(scopes.EMBED):
+        wte = params["wte"].astype(dt)
+        x = wte[tokens] + params["wpe"][:S].astype(dt)
 
     mesh = mesh_lib.current_mesh()
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
     if pp > 1:
         x = _blocks_pipelined(params["blocks"], x, cfg, mesh, pp)
-        return _layernorm(x, params["lnf_scale"], params["lnf_bias"]), jnp.zeros(
-            (), jnp.float32
-        )
+        return _ln_f(x, params), jnp.zeros((), jnp.float32)
 
     block_fn = _make_block_fn(cfg)
     if cfg.moe_experts > 0:
@@ -408,7 +417,12 @@ def _trunk(params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config) -> jax.Ar
     aux = jnp.zeros((), jnp.float32)
     if cfg.moe_experts > 0:
         x, aux = x
-    return _layernorm(x, params["lnf_scale"], params["lnf_bias"]), aux
+    return _ln_f(x, params), aux
+
+
+@jax.named_scope(scopes.LN_F)
+def _ln_f(x, params):
+    return _layernorm(x, params["lnf_scale"], params["lnf_bias"])
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config) -> jax.Array:
@@ -442,10 +456,15 @@ def loss_fn(
     materialized. Same math, f32 softmax, identical numerics to the monolithic
     path (tests/test_gpt2_model.py asserts equality).
     """
-    B, S = tokens.shape
     x, moe_aux = _trunk(params, tokens, cfg)
-    aux_term = cfg.moe_aux_coeff * moe_aux
-    wte = params["wte"].astype(cfg.dtype)
+    return _lm_head_loss(x, targets, params["wte"], cfg) + cfg.moe_aux_coeff * moe_aux
+
+
+@jax.named_scope(scopes.LM_HEAD_LOSS)
+def _lm_head_loss(x, targets, wte, cfg: GPT2Config) -> jax.Array:
+    """Tied LM head + mean cross-entropy over final hidden states [B, S, D]."""
+    B, S = targets.shape
+    wte = wte.astype(cfg.dtype)
     chunk = cfg.loss_chunk or 0
     # chunk is validated against cfg.seq_len at config time; S % chunk can
     # only be nonzero for ad-hoc shorter sequences, where logits are small
@@ -459,7 +478,7 @@ def loss_fn(
         logits = jnp.einsum("bsd,vd->bsv", x, wte)
         nll = softmax_xent(logits, targets)
         count = jnp.sum(targets >= 0)
-        return jnp.sum(nll) / jnp.maximum(count, 1) + aux_term
+        return jnp.sum(nll) / jnp.maximum(count, 1)
 
     xc = x.reshape(B, S // chunk, chunk, -1).swapaxes(0, 1)       # [n, B, c, D]
     tc = targets.reshape(B, S // chunk, chunk).swapaxes(0, 1)     # [n, B, c]
@@ -474,7 +493,7 @@ def loss_fn(
         scan_body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
         (xc, tc),
     )
-    return total / jnp.maximum(count, 1) + aux_term
+    return total / jnp.maximum(count, 1)
 
 
 def flops_per_token(cfg: GPT2Config) -> float:

@@ -44,6 +44,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.tracing import names
+
 _NEG_INF = -1e30  # mask value: large-negative, not -inf (keeps exp() exact 0)
 
 
@@ -228,6 +230,7 @@ def _mha_forward_bhsd(
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name=names.FLASH_FWD_KERNEL,
     )(*operands)
     return o, lse[:, :, 0, :]
 
@@ -378,6 +381,7 @@ def _mha_backward_bhsd(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        name=names.FLASH_BWD_KERNEL,
     )(q_offset, kv_offset, q, k, v, do, lse4, delta)
     return dq_f32.astype(q.dtype), dk, dv
 
@@ -441,6 +445,7 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@jax.named_scope(names.FLASH_ATTENTION)
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -501,6 +506,7 @@ def batch_head_axes(mesh, batch: int, heads: int):
     return tuple(batch_axes) or None, head_ax
 
 
+@jax.named_scope(names.FLASH_ATTENTION)
 def flash_attention_sharded(q, k, v, mesh, **kwargs) -> jax.Array:
     """flash_attention for callers under jit/GSPMD (the model forward).
     q, k, v: GLOBAL [B, H, S, hd] in and out; kwargs as flash_attention's.
@@ -527,6 +533,7 @@ def flash_attention_sharded(q, k, v, mesh, **kwargs) -> jax.Array:
     return fn(q, k, v)
 
 
+@jax.named_scope(names.FLASH_ATTENTION)
 def flash_attention_with_lse(
     q, k, v, q_offset, kv_offset, *,
     causal: bool = True,
@@ -553,6 +560,7 @@ def flash_attention_with_lse(
     return _to_bhsd(o), lse
 
 
+@jax.named_scope(names.FLASH_ATTENTION)
 def mha_backward_chunk(
     q, k, v, o, lse, do, q_offset, kv_offset, *,
     causal: bool = True,

@@ -18,7 +18,13 @@ Model
   propagated through ``TaskSpec`` into every nested submission, so a single
   request stitches across processes in the exported timeline.
 - ``profile_span("name")`` records user spans into the same plane, tagged
-  with the current task/trace.
+  with the current task/trace — and, when JAX is loaded, onto the
+  ``jax.profiler`` clock as ``ray_tpu:<component>/<name>``. The Train path
+  (Data iterator, ``train.report``, ``TrainWorker.poll``), the garbage
+  collector of a train worker and the core worker's periodic loops record
+  their own spans through it; ``tracing/names.py`` is the vocabulary of
+  those spans and of the scopes and kernel names the model puts on the
+  device.
 
 Cheap by default: recording is a couple of dict writes behind one lock;
 ``task_events_enabled=False`` reduces it to a single attribute check, and
@@ -28,8 +34,10 @@ Cheap by default: recording is a couple of dict writes behind one lock;
 
 from ray_tpu.tracing.events import (
     LIFECYCLE_STATES,
+    PROFILE_MIN_DUR_S,
     TERMINAL_STATES,
     TaskEventBuffer,
+    bg_span,
     current_deadline,
     current_job_id,
     current_task_id,
@@ -37,10 +45,12 @@ from ray_tpu.tracing.events import (
     deadline_context,
     ensure_trace,
     get_buffer,
+    install_gc_spans,
     new_trace_id,
     profile_span,
     read_wal,
     remaining_time_s,
+    remove_gc_spans,
     task_context,
     trace_context,
 )
@@ -49,9 +59,11 @@ from ray_tpu.tracing.timeline import build_chrome_trace
 
 __all__ = [
     "LIFECYCLE_STATES",
+    "PROFILE_MIN_DUR_S",
     "TERMINAL_STATES",
     "TaskEventBuffer",
     "TaskEventAggregator",
+    "bg_span",
     "build_chrome_trace",
     "current_deadline",
     "current_job_id",
@@ -61,9 +73,11 @@ __all__ = [
     "remaining_time_s",
     "ensure_trace",
     "get_buffer",
+    "install_gc_spans",
     "new_trace_id",
     "profile_span",
     "read_wal",
+    "remove_gc_spans",
     "task_context",
     "trace_context",
 ]

@@ -10,6 +10,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -19,6 +20,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.analysis import sanitizers as _san
 from ray_tpu.core.config import _config
+from ray_tpu.tracing.names import BG, GC, SPAN_PREFIX
 
 # compact WAL line encoder: separators + no circular check shave ~40% off
 # json.dumps on the per-event hot path; default=str keeps arbitrary span
@@ -180,7 +182,9 @@ class TaskEventBuffer:
     """
 
     def __init__(self, capacity: Optional[int] = None):
-        self._lock = _san.make_lock("tracing.buffer")
+        # re-entrant: a garbage collection triggered by an allocation made
+        # under this lock runs the gc span hook, which records here
+        self._lock = _san.make_rlock("tracing.buffer")
         self._capacity = capacity or max(100, _config.task_events_buffer_size)
         self._events: deque = deque()
         self._dropped = 0          # cumulative, this process
@@ -420,7 +424,9 @@ async def flush_task_events_loop(buf: TaskEventBuffer, get_conn,
     last_dropped = 0
     while True:
         await asyncio.sleep(period)
-        events, raw_dropped = buf.drain()
+        with bg_span("task_event_flush") as tick:
+            events, raw_dropped = buf.drain()
+            tick.args = {"events": len(events)}
         dropped = max(0, raw_dropped - baseline)
         if not events and dropped == last_dropped:
             continue
@@ -473,20 +479,121 @@ def read_wal(path: str, max_bytes: Optional[int] = None) -> List[dict]:
     return out
 
 
-@contextlib.contextmanager
-def profile_span(name: str, args: Optional[dict] = None,
-                 component: str = "user"):
-    """User API: time a block and record it as a span event attached to the
-    current task and trace::
+# Spans on hot paths (core put/get, the Data iterator, train.report, the
+# background loops) reach the buffer only when they lasted this long:
+# sub-millisecond calls stay span-free so tight loops don't flood the bounded
+# event buffer, and what does arrive is what could explain a slow step.
+PROFILE_MIN_DUR_S = 0.001
+
+
+class profile_span:
+    """Time a block and record it as a span attached to the current task and
+    trace — the one span primitive, for users and for the runtime's own
+    layers::
 
         with ray_tpu.tracing.profile_span("tokenize"):
             ...
+
+    Two sinks. (a) ``jax.profiler.TraceAnnotation("ray_tpu:<component>/<name>",
+    **args)``, only when ``jax`` is already imported (a span never imports
+    it): with a profiler session running the span lands on the same clock as
+    the device's planes; with none the annotation is an inactive TraceMe.
+    (b) the process's :class:`TaskEventBuffer`, as a PROFILE event with
+    ``dur`` — when it lasted at least ``min_dur_s`` (hot paths pass
+    ``PROFILE_MIN_DUR_S``) and ``task_events_enabled`` is on. A span opened
+    inside another on the same thread carries ``parent`` in its event's
+    args. ``args`` may be added to until the block ends (the buffer sees
+    them all, the annotation those given at entry).
     """
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        get_buffer().record_profile(
-            name, dur=time.perf_counter() - t0, component=component,
-            args=args,
-        )
+
+    __slots__ = ("name", "args", "component", "min_dur_s", "_label", "_ann",
+                 "_parent", "_t0")
+
+    def __init__(self, name: str, args: Optional[dict] = None,
+                 component: str = "user", min_dur_s: float = 0.0):
+        self.name = name
+        self.args = args
+        self.component = component
+        self.min_dur_s = min_dur_s
+        self._label = f"{SPAN_PREFIX}{component}/{name}"
+        self._ann = None
+
+    def __enter__(self) -> "profile_span":
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            args = self.args
+            self._ann = profiler.TraceAnnotation(
+                self._label, **(args if args and "name" not in args else {}))
+            self._ann.__enter__()
+        stack = getattr(_ctx, "spans", None)
+        if stack is None:
+            stack = _ctx.spans = []
+        self._parent = stack[-1] if stack else None
+        stack.append(self._label)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc_info) -> bool:
+        dur = time.perf_counter() - self._t0
+        _ctx.spans.pop()
+        if self._ann is not None:
+            self._ann.__exit__(*exc_info)
+            self._ann = None
+        if dur >= self.min_dur_s and _config.task_events_enabled:
+            args = self.args
+            if self._parent is not None:
+                args = {**(args or {}), "parent": self._parent}
+            get_buffer().record_profile(
+                self.name, dur=dur, component=self.component, args=args,
+            )
+        return False
+
+
+def bg_span(loop: str, args: Optional[dict] = None) -> profile_span:
+    """One tick of a periodic loop of this process (``ray_tpu:bg/<loop>``):
+    wrap the tick's synchronous part — what holds the GIL against the
+    threads doing the work — not its awaited RPC (``rpc.flush`` times
+    that side)."""
+    return profile_span(loop, args, component=BG,
+                        min_dur_s=PROFILE_MIN_DUR_S)
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: one ``ray_tpu:gc/gen<N>`` span a collection, on
+    whichever thread ran it (args generation, and collected once known).
+    Reaches the buffer only above ``PROFILE_MIN_DUR_S``: a young-generation
+    sweep is microseconds, a full one over a JAX process's heap is what can
+    stop a train loop for a tenth of a second."""
+
+    def __init__(self) -> None:
+        self._span: Optional[profile_span] = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            gen = info["generation"]
+            self._span = profile_span(
+                f"gen{gen}", {"generation": gen}, component=GC,
+                min_dur_s=PROFILE_MIN_DUR_S)
+            self._span.__enter__()
+        elif self._span is not None:
+            span, self._span = self._span, None
+            span.args["collected"] = info.get("collected", 0)
+            span.__exit__(None, None, None)
+
+
+_gc_spans = _GcSpans()
+
+
+def install_gc_spans() -> None:
+    """Record garbage collections of this process as spans (idempotent)."""
+    import gc
+
+    if _gc_spans not in gc.callbacks:
+        gc.callbacks.append(_gc_spans)
+
+
+def remove_gc_spans() -> None:
+    import gc
+
+    if _gc_spans in gc.callbacks:
+        gc.callbacks.remove(_gc_spans)

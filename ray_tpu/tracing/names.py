@@ -1,0 +1,48 @@
+"""The names the Train path gives its own work — one vocabulary, written once.
+
+The model (``models/``), the step factories (``train/train_step.py``) and the
+kernels (``ops/attention.py``) put these names on the device through
+``jax.named_scope`` / ``pallas_call(name=...)``; Data and Train put their span
+names on the profiler's clock through ``profile_span``. Readers of a trace
+(``benchmarks/harness/program_trace.py``), ``tests/test_train_tracing.py`` and
+``PERF.md`` use the same tuples. Constants only: importing this costs nothing.
+
+On the device a scope shows in each HLO instruction's ``op_name`` (the
+trace's ``tf_op``): ``jit(step)/jvp()/while/body/closed_call/block/mlp/tanh``
+is forward, ``jit(step)/transpose(jvp())/.../block/mlp/dot_general`` backward,
+``.../checkpoint/rematted_computation/block/...`` remat's recompute,
+``jit(step)/optimizer/mul`` the update.
+"""
+
+# scopes inside one transformer block, all nested under BLOCK
+BLOCK = "block"
+LN1, QKV, ATTN, PROJ, LN2, MLP = "ln1", "qkv", "attn", "proj", "ln2", "mlp"
+MOE = "moe"                      # stands where `mlp` stands on the MoE branch
+BLOCK_SCOPES = (LN1, QKV, ATTN, PROJ, LN2, MLP)
+# the rest of the model, and the step
+EMBED = "embed"
+LN_F = "ln_f"
+LM_HEAD_LOSS = "lm_head_loss"
+OPTIMIZER = "optimizer"
+# the public entry points of ops/attention.py (carried by the shard_map too)
+FLASH_ATTENTION = "flash_attention"
+SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
+                                          FLASH_ATTENTION)
+
+# the two Mosaic kernels (`name=` of their pallas_call)
+FLASH_FWD_KERNEL = "flash_attention_fwd"
+FLASH_BWD_KERNEL = "flash_attention_bwd"
+KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL)
+
+# host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
+# `<component>/<name>` with that component in the task-event buffer
+SPAN_PREFIX = "ray_tpu:"
+DATA_GET_BLOCK = "data/get_block"
+DATA_ASSEMBLE = "data/assemble"
+DATA_DEVICE_PUT = "data/device_put"
+TRAIN_REPORT = "train/report"
+TRAIN_POLL = "train/poll"
+GC = "gc"
+BG = "bg"                        # `bg/<loop>`: one span a tick of a periodic loop
+SPANS = (DATA_GET_BLOCK, DATA_ASSEMBLE, DATA_DEVICE_PUT, TRAIN_REPORT,
+         TRAIN_POLL, GC)

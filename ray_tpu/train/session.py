@@ -12,6 +12,9 @@ import threading
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
+from ray_tpu.tracing import (
+    PROFILE_MIN_DUR_S, install_gc_spans, profile_span, remove_gc_spans,
+)
 from ray_tpu.train.checkpoint import Checkpoint
 
 
@@ -38,15 +41,21 @@ class _Session:
         self.result_queue: "queue.Queue" = queue.Queue()
         self.finished = threading.Event()
         self.error: Optional[BaseException] = None
+        # a collection stops every thread of the worker, the train loop's
+        # among them: with a session, collections become spans (ray_tpu:gc/*)
+        install_gc_spans()
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None):
-        if checkpoint is not None:
-            self.latest_checkpoint = checkpoint
-        self.result_queue.put(("report", metrics, checkpoint))
+        with profile_span("report", component="train",
+                          min_dur_s=PROFILE_MIN_DUR_S):
+            if checkpoint is not None:
+                self.latest_checkpoint = checkpoint
+            self.result_queue.put(("report", metrics, checkpoint))
 
     def finish(self, error: Optional[BaseException] = None):
         self.error = error
+        remove_gc_spans()
         self.result_queue.put(("done", None, None))
         self.finished.set()
 

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.models import gpt2
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel import sharding as sharding_lib
+from ray_tpu.tracing import names as scopes
 
 
 @dataclass
@@ -167,11 +168,7 @@ def make_gpt2_train_step(
             loss, grads = jax.value_and_grad(gpt2.loss_fn)(
                 state["params"], tokens, targets, cfg
             )
-        updates, new_opt = optimizer.update(
-            grads, state["opt_state"], state["params"]
-        )
-        new_params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        new_params, new_opt, gnorm = _apply_optimizer(optimizer, grads, state)
         new_state = {
             "params": new_params,
             "opt_state": new_opt,
@@ -198,6 +195,17 @@ def make_gpt2_train_step(
         state=state, step_fn=step_fn, mesh=mesh, data_sharding=data_sh,
         cfg=cfg, multi_step_fn=multi_step_fn, stacked_data_sharding=stacked_sh,
     )
+
+
+@jax.named_scope(scopes.OPTIMIZER)
+def _apply_optimizer(optimizer, grads, state):
+    """The update both step factories share: (new params, new optimizer
+    state, the gradients' global norm), under one scope on the device."""
+    updates, new_opt = optimizer.update(
+        grads, state["opt_state"], state["params"]
+    )
+    new_params = optax.apply_updates(state["params"], updates)
+    return new_params, new_opt, optax.global_norm(grads)
 
 
 def _step_counter(mesh: Mesh) -> jax.Array:
@@ -267,17 +275,13 @@ def make_llama_train_step(
             loss, grads = jax.value_and_grad(llama.loss_fn)(
                 state["params"], tokens, targets, cfg
             )
-        updates, new_opt = optimizer.update(
-            grads, state["opt_state"], state["params"]
-        )
-        new_params = optax.apply_updates(state["params"], updates)
+        new_params, new_opt, gnorm = _apply_optimizer(optimizer, grads, state)
         new_state = {
             "params": new_params,
             "opt_state": new_opt,
             "step": state["step"] + 1,
         }
-        return new_state, {"loss": loss,
-                           "grad_norm": optax.global_norm(grads)}
+        return new_state, {"loss": loss, "grad_norm": gnorm}
 
     state_shardings = {
         "params": param_shardings,

@@ -18,6 +18,7 @@ import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu import tracing
 from ray_tpu.train import session as session_mod
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.session import TrainContext, _Session, _set_session
@@ -91,10 +92,16 @@ class TrainWorker:
 
         enable_compile_cache()
 
+        # the loop's own thread inherits this actor task's ids, so that the
+        # spans of Data and Train under it attach to the task and its trace
+        task_ids = (tracing.current_task_id(), tracing.current_trace_id(),
+                    tracing.current_job_id())
+
         def run():
             _set_session(self.session)
             try:
-                fn(config) if config is not None else fn()
+                with tracing.task_context(*task_ids):
+                    fn(config) if config is not None else fn()
                 self.session.finish()
             except BaseException as e:  # noqa: BLE001
                 traceback.print_exc()
@@ -112,15 +119,19 @@ class TrainWorker:
         if self.session is None:
             return out
         deadline = time.monotonic() + timeout
-        while True:
-            try:
-                remaining = max(0.0, deadline - time.monotonic())
-                item = self.session.result_queue.get(timeout=remaining)
-                out.append(item)
-                if item[0] == "done":
+        # one span a long-poll, on the actor's thread (it shares the GIL
+        # with the loop's): between two of them the reply is serialized
+        with tracing.profile_span("poll", component="train") as span:
+            while True:
+                try:
+                    remaining = max(0.0, deadline - time.monotonic())
+                    item = self.session.result_queue.get(timeout=remaining)
+                    out.append(item)
+                    if item[0] == "done":
+                        break
+                except Exception:  # noqa: BLE001 - queue.Empty
                     break
-            except Exception:  # noqa: BLE001 - queue.Empty
-                break
+            span.args = {"items": len(out)}
         return out
 
     def get_error(self):

@@ -1,0 +1,255 @@
+"""The Train path names its own work (PR 24): scopes and kernel names on the
+device, Data / Train / gc spans through ``profile_span``'s two sinks, and the
+reader that turns a recorded trace into per-layer numbers.
+
+Everything here runs in-process: no cluster, no chip, seconds a case.
+"""
+
+import gc
+import glob
+import os
+import re
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import ray_tpu
+from ray_tpu import tracing
+from ray_tpu.core.config import _config
+from ray_tpu.tracing import events as tracing_events
+from ray_tpu.tracing import names
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+# what each lowering covers: the dense model with the Pallas kernels (layers
+# scanned and unrolled, remat on), and the MoE branch
+LOWERINGS = {
+    "scan": dict(remat=True, attention_impl="pallas"),
+    "unrolled": dict(scan_layers=False, remat=True, attention_impl="pallas"),
+    "moe": dict(moe_experts=2, attention_impl="xla"),
+}
+DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE)
+_lowered = {}
+
+
+def _lowering(key):
+    """(op_names of the lowered train step, its jaxpr as text), made once."""
+    if key not in _lowered:
+        import jax
+
+        from ray_tpu.models import gpt2
+        from ray_tpu.train.train_step import (
+            make_gpt2_train_step, synthetic_batch)
+
+        cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
+        bundle = make_gpt2_train_step(cfg)
+        batch = synthetic_batch(cfg, 2)
+        text = bundle.step_fn.lower(bundle.state, batch).as_text(debug_info=True)
+        _lowered[key] = (
+            set(re.findall(r'loc\("([^"]+)"', text)),
+            str(jax.make_jaxpr(bundle.step_fn)(bundle.state, batch)))
+    return _lowered[key]
+
+
+def _has_scope(op_names, scope):
+    pattern = re.compile(r"(^|[/(])" + re.escape(scope) + r"($|[/)])")
+    return any(pattern.search(n) for n in op_names)
+
+
+@pytest.mark.parametrize("scope", DENSE_SCOPES)
+@pytest.mark.parametrize("layers", ["scan", "unrolled"])
+def test_scope_in_lowered_step(layers, scope):
+    op_names, _ = _lowering(layers)
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+
+
+def test_moe_scope_stands_where_mlp_stands():
+    op_names, _ = _lowering("moe")
+    assert _has_scope(op_names, f"{names.BLOCK}/{names.MOE}")
+    assert not _has_scope(op_names, names.MLP)
+
+
+@pytest.mark.parametrize("layers", ["scan", "unrolled"])
+def test_remat_recompute_keeps_the_block_scopes(layers):
+    op_names, _ = _lowering(layers)
+    for scope in names.BLOCK_SCOPES:
+        want = f"rematted_computation/{names.BLOCK}/{scope}/"
+        assert any(want in n for n in op_names), want
+
+
+@pytest.mark.parametrize("kernel", names.KERNELS)
+def test_kernel_name_in_jaxpr(kernel):
+    _, jaxpr = _lowering("scan")
+    assert f"name={kernel}" in jaxpr
+
+
+# ------------------------------------------------------------- profile_span
+@pytest.fixture
+def buffer(monkeypatch):
+    monkeypatch.setattr(_config, "task_events_enabled", True)
+    monkeypatch.setattr(_config, "task_events_sample_rate", 1.0)
+    buf = tracing.get_buffer()
+    buf.drain(10 ** 6)
+    yield buf
+    buf.drain(10 ** 6)
+
+
+def _drain(buf, component):
+    return [e for e in buf.drain(10 ** 6)[0] if e["component"] == component]
+
+
+def test_span_without_jax_imports_nothing_and_records(buffer, monkeypatch):
+    for mod in [m for m in sys.modules if m == "jax" or m.startswith("jax.")]:
+        monkeypatch.delitem(sys.modules, mod)
+    with tracing.profile_span("no-jax", {"k": 1}, component="t24"):
+        pass
+    assert "jax" not in sys.modules
+    (event,) = _drain(buffer, "t24")
+    assert event["name"] == "no-jax" and event["args"] == {"k": 1}
+    assert event["dur"] >= 0.0
+
+
+def test_hot_span_below_threshold_leaves_buffer_empty(buffer):
+    with tracing.profile_span("quick", component="t24",
+                              min_dur_s=tracing.PROFILE_MIN_DUR_S):
+        pass
+    assert _drain(buffer, "t24") == []
+
+
+def test_hot_span_above_threshold_records_one_event_with_dur(buffer):
+    with tracing.profile_span("slow", component="t24",
+                              min_dur_s=tracing.PROFILE_MIN_DUR_S):
+        time.sleep(0.003)
+    (event,) = _drain(buffer, "t24")
+    assert event["dur"] >= 0.003 and event["state"] == "PROFILE"
+
+
+def test_disabled_and_no_profiler_session_records_nothing(buffer, monkeypatch):
+    monkeypatch.setattr(_config, "task_events_enabled", False)
+    with tracing.profile_span("off", component="t24"):
+        pass
+    assert len(buffer) == 0
+
+
+def test_nested_span_carries_its_parent_and_task_ids(buffer):
+    with tracing.task_context("task-24", "trace-24"):
+        with tracing.profile_span("outer", component="t24"):
+            with tracing.profile_span("inner", component="t24"):
+                pass
+    inner, outer = _drain(buffer, "t24")
+    assert inner["args"]["parent"] == f"{names.SPAN_PREFIX}t24/outer"
+    assert "parent" not in (outer.get("args") or {})
+    assert inner["task_id"] == "task-24" and inner["trace_id"] == "trace-24"
+
+
+def test_gc_hook_records_a_collection(buffer, monkeypatch):
+    monkeypatch.setattr(tracing_events, "PROFILE_MIN_DUR_S", 0.0)
+    tracing.install_gc_spans()
+    tracing.install_gc_spans()                     # idempotent
+    try:
+        assert gc.callbacks.count(tracing_events._gc_spans) == 1
+        gc.collect(2)
+    finally:
+        tracing.remove_gc_spans()
+    full = [e for e in _drain(buffer, names.GC) if e["name"] == "gen2"]
+    assert full and full[0]["args"]["generation"] == 2
+    assert "collected" in full[0]["args"]
+    assert tracing_events._gc_spans not in gc.callbacks
+
+
+def test_session_report_and_worker_poll_record_train_spans(buffer, monkeypatch):
+    from ray_tpu.train import session as session_mod
+    from ray_tpu.train.worker_group import TrainWorker
+
+    monkeypatch.setattr(session_mod, "PROFILE_MIN_DUR_S", 0.0)
+    worker = TrainWorker(0, 1)
+    worker.session = session_mod._Session(session_mod.TrainContext())
+    worker.session.report({"loss": 1.0})
+    worker.session.finish()
+    items = worker.poll(timeout=0.5)
+    assert [i[0] for i in items] == ["report", "done"]
+    got = {e["name"]: e for e in _drain(buffer, "train")}
+    assert set(got) == {"report", "poll"}
+    assert got["poll"]["args"] == {"items": 2}
+
+
+# ------------------------------------------------- the iterator, on a trace
+def _trace_iterator(tmp_path, monkeypatch, pause_s):
+    """Run iter_batches over in-memory blocks under a CPU profiler trace with
+    a consumer that pauses; return the trace's host events."""
+    import jax
+
+    from benchmarks.harness import program_trace
+    from ray_tpu.data import iterator
+
+    monkeypatch.setattr(ray_tpu, "get", lambda ref: ref)   # blocks, not refs
+    blocks = [{"tokens": np.full((24, 32), i, np.int32),
+               "targets": np.full((24, 32), -i, np.int32)} for i in range(3)]
+    seen = 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for batch in iterator.iter_batches(
+                iter(blocks), batch_size=8, drop_last=True,
+                device=jax.devices()[0]):
+            assert batch["tokens"].shape == (8, 32)
+            seen += 1
+            time.sleep(pause_s)
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+    return seen, program_trace.load_tables(xplane)["host"]
+
+
+def test_iterator_spans_on_the_profiler_clock(tmp_path, monkeypatch, buffer):
+    pause_s = 0.05
+    seen, host = _trace_iterator(tmp_path, monkeypatch, pause_s)
+    assert seen == 9
+    by = {}
+    for label, _, _, dur_ns, batch in host:
+        by.setdefault(label, []).append((dur_ns, batch))
+    prefix = names.SPAN_PREFIX
+    assert len(by[prefix + names.DATA_GET_BLOCK]) == 3
+    assert len(by[prefix + names.DATA_DEVICE_PUT]) == 9
+    assert len(by[prefix + names.DATA_ASSEMBLE]) >= 9
+    # the iterator's own count ties a batch's spans together
+    assert sorted(b for _, b in by[prefix + names.DATA_DEVICE_PUT]) == list(range(9))
+    assert {b for _, b in by[prefix + names.DATA_ASSEMBLE]} == set(range(9))
+    # no span is held open across a yield: none holds a consumer's pause
+    longest = max(d for spans in by.values() for d, _ in spans)
+    assert longest < pause_s * 1e9 / 2, longest
+
+
+# ------------------------------------------------------- the trace's reader
+@pytest.mark.parametrize("tf_op, want", [
+    ("jit(step)/jvp()/while/body/closed_call/block/mlp/tanh:",
+     dict(direction="fwd", scopes=["block", "mlp"], remat=False, stack=False)),
+    ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/block/qkv/bsd,dhk->bhsk/dot_general:",
+     dict(direction="bwd", scopes=["block", "qkv"], remat=True, stack=False)),
+    ("jit(step)/transpose(jvp(lm_head_loss))/bsd,vd->bsv/dot_general:",
+     dict(direction="bwd", scopes=["lm_head_loss"], remat=False, stack=False)),
+    ("jit(step)/optimizer/mul:",
+     dict(direction="optimizer", scopes=["optimizer"], remat=False, stack=False)),
+    ("jit(step)/jvp()/while/body/dynamic_update_slice:",
+     dict(direction="fwd", scopes=[], remat=False, stack=True)),
+    ("jit(step)/jvp(block)/attn/flash_attention/flash_attention_fwd:",
+     dict(direction="fwd", scopes=["block", "attn", "flash_attention"],
+          kernel="flash_attention_fwd", stack=False)),
+    ("", dict(direction="other", scopes=[], remat=False, stack=False)),
+])
+def test_classify_by_the_programs_names(tf_op, want):
+    from benchmarks.harness import program_trace
+
+    got = program_trace.classify(tf_op, "fusion.1", "op")
+    assert {k: got[k] for k in want} == want
+
+
+def test_program_trace_check_passes_on_the_recorded_trace(capsys):
+    from benchmarks.harness import program_trace
+
+    assert program_trace.check() == 0, capsys.readouterr().out
