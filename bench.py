@@ -127,16 +127,7 @@ def main():
     # - remat=False: with the flash kernel there are no S×S residuals.
     # - fused CE (ops/cross_entropy.py): the f32 [B,S,V] log-softmax
     #   residual was 17 ms/step of pure HBM traffic (r4 profile).
-    # - flash blocks (r5 sweep): fwd 256/512 with 6 heads/grid-step, bwd
-    #   512/512 with 3 (block_h amortizes per-step cost and lets the
-    #   causal loop skip the fully-masked kv tail; more heads OOM the
-    #   16 MB scoped VMEM). Fused single-pass backward kernel.
-    cfg = gpt2.gpt2_124m(
-        remat=False, scan_layers=False,
-        attn_block_q=256, attn_block_k=512,
-        attn_bwd_block_q=512, attn_bwd_block_k=512,
-        attn_block_h=6, attn_bwd_block_h=3,
-    )
+    cfg = gpt2.gpt2_124m(remat=False, scan_layers=False)
     # fsdp over all local chips (== single-device mesh on one chip) so the
     # per-chip division below is honest on multi-chip hosts.
     mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec.for_devices(n_chips), devices)

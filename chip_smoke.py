@@ -55,21 +55,22 @@ def with_targets(block):
     return {"tokens": tokens, "targets": targets}
 
 
-_SHAPE4 = re.compile(r"\b(?:bf16|f32)\[(\d+),(\d+),(\d+),(\d+)\]")
+_SHAPE3 = re.compile(r"\b(?:bf16|f32)\[(\d+),(\d+),(\d+)\]")
 
 
 def attention_call_shapes(hlo_text: str, head_dim: int) -> Tuple[int, List[List[int]]]:
-    """(number of Mosaic custom calls, the distinct [B, H, S, hd] shapes on
+    """(number of Mosaic custom calls, the distinct [B·H, S, hd] shapes on
     their lines) in a compiled step's HLO — the operands and results of the
-    flash kernels as each device runs them."""
+    flash kernels as each device runs them, batch and head merged into the
+    one dim of rows the kernels walk."""
     calls, shapes = 0, set()
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
             continue
         calls += 1
-        for m in _SHAPE4.finditer(line):
+        for m in _SHAPE3.finditer(line):
             dims = tuple(int(d) for d in m.groups())
-            if dims[3] == head_dim:
+            if dims[2] == head_dim:
                 shapes.add(dims)
     return calls, [list(s) for s in sorted(shapes)]
 
@@ -86,7 +87,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     import numpy as np
 
     from ray_tpu import train
-    from ray_tpu.ops.attention import resolve_attention
+    from ray_tpu.ops.attention import flash_tiling_decisions, resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
     from ray_tpu.train.train_step import default_optimizer, make_gpt2_train_step
 
@@ -179,6 +180,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         "attention": list(resolve_attention(cfg.attention_impl, mesh)),
         "tpu_custom_calls": tpu_calls,
         "attention_call_shapes": attn_shapes,
+        "flash_tiling": flash_tiling_decisions(),
         "global_batch": global_batch,
         "epochs": epochs,
         "backend_seconds": backend_seconds,
@@ -267,12 +269,17 @@ def check_device(summary: Dict[str, Any], model_cfg, per_chip_batch: int,
     # fwd + bwd kernels, each on ONE device's shard of the batch: GSPMD
     # cannot partition a Mosaic call, so anything but the per-chip batch here
     # means every chip is computing the gathered global batch
-    want = [[per_chip_batch, model_cfg.n_head, model_cfg.seq_len,
+    want = [[per_chip_batch * model_cfg.n_head, model_cfg.seq_len,
              model_cfg.head_dim]]
     if summary["tpu_custom_calls"] < 2 or summary["attention_call_shapes"] != want:
         bad.append(f"compiled step has {summary['tpu_custom_calls']} Mosaic "
                    f"call(s) over {summary['attention_call_shapes']}; wanted "
                    f">= 2 over the per-device shard {want}")
+    # the ops/flash_tiling decisions the worker traced its kernels with
+    kernels = {d["kernel"] for d in summary["flash_tiling"]}
+    if kernels != {"fwd", "bwd"}:
+        bad.append(f"flash tiling decisions recorded for {sorted(kernels)}, "
+                   "not for fwd and bwd")
     return bad
 
 
@@ -319,6 +326,11 @@ def main() -> int:
     print(f"attention: impl={summary['attention'][0]} interpret="
           f"{summary['attention'][1]}; {summary['tpu_custom_calls']} Mosaic "
           f"calls per device over {summary['attention_call_shapes']}")
+    for d in summary["flash_tiling"]:
+        print(f"flash tiling: {d['kernel']} rows={d['rows']} Sq={d['Sq']} "
+              f"Skv={d['Skv']} hd={d['hd']} -> block_q={d['block_q']} "
+              f"block_k={d['block_k']} vmem_estimate="
+              f"{d['vmem_estimate'] / 2 ** 20:.2f} MiB")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

@@ -50,19 +50,6 @@ class GPT2Config:
     #            of the residual memory
     remat: Any = False
     attention_impl: str = "auto"  # auto | xla | pallas | ring
-    # Pallas flash kernel tile sizes (ops/attention.py), forward and
-    # backward separately. 512/512 wins in-model on v5e (1024/1024 is ~15%
-    # faster standalone but loses ~4% inside the full step — VMEM pressure
-    # against neighboring fusions).
-    attn_block_q: int = 512
-    attn_block_k: int = 512
-    attn_bwd_block_q: int = 0   # 0 = same as attn_block_q
-    attn_bwd_block_k: int = 0   # 0 = same as attn_block_k
-    # heads per kernel grid step (fwd/bwd): at hd=64 the kernels are
-    # grid-overhead bound; packing heads amortizes the per-step cost
-    # (must divide n_head; the kernel falls back to 1 otherwise)
-    attn_block_h: int = 1
-    attn_bwd_block_h: int = 0   # 0 = same as attn_block_h
     use_bias: bool = True
     # scan over layers (True: compact HLO, one traced block) vs an unrolled
     # Python loop (False: 12x the HLO, but no lax.scan slice/stack traffic —
@@ -265,12 +252,7 @@ def _attention(q, k, v, cfg: GPT2Config):
     impl, interpret = resolve_attention(cfg.attention_impl, mesh)
     if impl == "pallas":
         return flash_attention_sharded(
-            q, k, v, mesh, causal=True, interpret=interpret,
-            block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
-            bwd_block_q=cfg.attn_bwd_block_q or None,
-            bwd_block_k=cfg.attn_bwd_block_k or None,
-            block_h=cfg.attn_block_h,
-            bwd_block_h=cfg.attn_bwd_block_h or None,
+            q, k, v, mesh, causal=True, interpret=interpret
         )
     if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention_sharded

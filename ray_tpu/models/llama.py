@@ -48,8 +48,6 @@ class LlamaConfig:
     remat: Any = False            # False | True | "dots" (as GPT-2)
     attention_impl: str = "auto"  # auto | xla | pallas
     scan_layers: bool = True
-    attn_block_q: int = 512
-    attn_block_k: int = 512
 
     def __post_init__(self):
         if self.n_head % self.n_kv_head:
@@ -202,8 +200,7 @@ def _attention(q, k, v, cfg: LlamaConfig):
         )
     if impl == "pallas":
         return flash_attention_sharded(
-            q, k, v, mesh, causal=True, interpret=interpret,
-            block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+            q, k, v, mesh, causal=True, interpret=interpret
         )
     S = q.shape[2]
     scale = 1.0 / math.sqrt(q.shape[-1])

@@ -8,24 +8,30 @@ backward pass recomputes logits blockwise from (q, k, lse) the flash-attention
 way.
 
 Design notes (TPU-first):
-- Kernels operate in [B, H, S, hd] layout so every block's minor dims are the
-  (seq, head_dim) tile Mosaic requires ((8,128)-aligned or full-size); the
-  public API takes [B, S, H, hd] and transposes at the boundary (XLA fuses the
-  transpose into the surrounding projection matmuls).
-- K/V live whole per (batch, head) in VMEM (S·hd·2B ≈ 128 KiB at S=1024 —
-  VMEM is ~16 MiB), so the kv loop is VMEM-resident with no DMA choreography.
-- Logits/softmax accumulate in f32 (MXU native via preferred_element_type);
+- The public API takes [B, S, H, hd] and transposes at the boundary (XLA
+  fuses the transpose into the surrounding projection matmuls) or, with
+  layout="bhsd", takes head-major tensors as they are. Inside, batch and head
+  are merged (a free reshape) into one dim of independent rows, [B·H, S, hd]:
+  every block's minor dims are the (seq, head_dim) tile Mosaic requires, and
+  the grid walks the rows one at a time whatever the head count.
+- The q/kv tile is choose_tiling's decision, from the shapes and an estimate
+  of the VMEM the blocks need; callers pass no tile.
+- K/V live whole per row in VMEM (S·hd·2B ≈ 128 KiB at S=1024), so the kv
+  loop is VMEM-resident with no DMA choreography.
+- The logits tile is computed transposed, s^T = k·q^T: softmax statistics are
+  lane-dense [1, block_q] rows and their reductions run down the sublanes.
+  Logits/softmax accumulate in f32 (MXU native via preferred_element_type);
   p·v and the backward matmuls run bf16→f32.
 - The causal mask is computed from GLOBAL positions `q_offset`/`kv_offset`
   (scalar-prefetch args), so the same kernel serves single-device attention
   (offsets 0) and ring attention (per-step rotated offsets, ops/ring_attention).
 - Backward = ONE fused kernel (grid over kv blocks, loop q): dk/dv written
-  per kv block, dq accumulated in a VMEM-resident whole-row f32 block whose
-  index map is constant in the kv grid dim — s/p/dp computed once per block
-  pair instead of twice (the split dq + dkv formulation costs 7 matmuls and
-  double the exp/mask work; fused is 5).
-- lse/delta ride as [B, H, 1, S] so their (1, block) tiles satisfy the minor-
-  dim rules; squeezed to [B, H, S] at the API edge.
+  per kv block, dq accumulated in a VMEM-resident whole-row f32 scratch —
+  s/p/dp computed once per block pair instead of twice (the split dq + dkv
+  formulation costs 7 matmuls and double the exp/mask work; fused is 5).
+- lse/delta ride as [B·H, 1, S] so their (1, block) tiles satisfy the minor-
+  dim rules and the kernels read them as the rows they are; lse is [B, H, S]
+  at the API edge.
 
 No counterpart exists in the reference (it has no flash/SP story at all —
 SURVEY.md §2.10); this is new TPU-native code.
@@ -35,7 +41,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.tracing import names
+from ray_tpu.tracing import get_buffer, names
 
 _NEG_INF = -1e30  # mask value: large-negative, not -inf (keeps exp() exact 0)
 
@@ -79,22 +85,150 @@ def resolve_attention(impl: str = "auto", mesh=None) -> Tuple[str, bool]:
 
 
 # --------------------------------------------------------------------------- #
+# Tiling: the kernels' work partition, chosen here from the shapes
+# --------------------------------------------------------------------------- #
+
+class Tiling(NamedTuple):
+    block_q: int
+    block_k: int
+    vmem_estimate: int        # bytes, vmem_estimate() of this choice
+
+
+# Mosaic's scoped-VMEM limit for one kernel on the chips this runs on; the
+# rule holds vmem_estimate() under it.
+VMEM_BUDGET_BYTES = 16 * 2 ** 20
+# The q and kv tile both kernels want, and the smallest the rule falls back
+# to: choose_tiling's docstring says where they come from.
+_TARGET_TILE = 512
+_MIN_TILE = 128
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _vmem_block_bytes(shape, itemsize: int) -> int:
+    """Bytes one block takes in VMEM: its last two dims are stored in
+    (sublane, lane) tiles of 8 × 128 32-bit words (16 rows of bf16) and padded
+    up to whole tiles — a [1024, 64] bf16 block takes what [1024, 128] does."""
+    rows, lanes = shape
+    sublanes = 8 * 4 // itemsize
+    return _round_up(rows, sublanes) * _round_up(lanes, 128) * itemsize
+
+
+def vmem_estimate(kernel: str, block_q: int, block_k: int,
+                  Sq: int, Skv: int, hd: int, dtype_bytes: int) -> int:
+    """VMEM bytes one grid step needs, as the rule counts them: every in/out
+    block twice (Pallas double-buffers them), the backward's f32 dq
+    accumulator once, and one [block_k, block_q] f32 logits tile plus the
+    loop's f32 accumulators. An upper bound, not Mosaic's own figure."""
+    blk = _vmem_block_bytes
+    tile = blk((block_k, block_q), 4)
+    if kernel == "fwd":
+        io = (2 * blk((block_q, hd), dtype_bytes)         # q, o
+              + 2 * blk((Skv, hd), dtype_bytes)           # k, v: whole rows
+              + blk((1, block_q), 4))                     # lse
+        live = tile + blk((hd, block_q), 4)               # s^T; acc^T
+    else:
+        io = (3 * blk((Sq, hd), dtype_bytes)              # q, do, dq: whole rows
+              + 4 * blk((block_k, hd), dtype_bytes)       # k, v, dk, dv
+              + 2 * blk((1, Sq), 4))                      # lse, delta
+        live = (blk((hd, Sq), 4)                          # dq^T accumulator
+                + tile + 2 * blk((block_k, hd), 4))       # s^T; dk, dv
+    return 2 * io + live
+
+
+_decisions: Dict[tuple, Dict[str, Any]] = {}
+
+
+def flash_tiling_decisions() -> List[Dict[str, Any]]:
+    """Every distinct tiling this process has traced a flash kernel with, as
+    the ``ops/flash_tiling`` events carry them."""
+    return list(_decisions.values())
+
+
+def _record(kernel: str, rows: int, Sq: int, Skv: int, hd: int,
+            tiling: Tiling) -> None:
+    """A static choice has no hit rate; its counter is the choice. Each
+    distinct one goes once, as an instant event, to the task-event buffer
+    (→ ``ray_tpu.timeline()``), with the shapes the kernel was given."""
+    args = dict(zip(names.FLASH_TILING_ARGS,
+                    (kernel, rows, Sq, Skv, hd) + tuple(tiling)))
+    key = tuple(args.values())
+    if key in _decisions:
+        return
+    _decisions[key] = args
+    component, name = names.FLASH_TILING.split("/")
+    get_buffer().record_profile(name, component=component, args=args)
+
+
+def choose_tiling(
+    kernel: str, Sq: int, Skv: int, hd: int, dtype_bytes: int, *,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
+) -> Tiling:
+    """THE rule for how a flash kernel tiles its work. ``kernel`` is ``"fwd"``
+    or ``"bwd"``. The keywords are a caller's explicit choices: each is kept
+    (clamped to a divisor of its sequence) and the rule fills in the other.
+    Raises ``ValueError`` when no tiling of its own fits the budget.
+
+    The constants, fitted on a v5e inside the `gpt2-124m` and `gpt2-xl` train
+    steps and standalone at ``[8,16,2048,128]`` (PERF.md §6, PR 25):
+
+    - Tiles 512/512, both kernels, hd 64 and 128. The loop body's fixed cost
+      (the matmuls' fill and drain, the chain max → exp → sum → matmul) is
+      paid per tile, so smaller tiles lose (256/256: forward +28 %, backward
+      +8 % in the step) although they skip more of the causal triangle; 1,024
+      on either side loses too (+13–18 %: the f32 tile no longer lives near
+      the registers). A sequence the tile does not divide gets the largest
+      power-of-two fraction of it that does.
+    - One (batch, head) row a grid step. Four rows unrolled into the
+      forward's loop body were measured: nothing on `gpt2-124m` (step 73.99
+      ms against 74.00), 0.28 % of the `gpt2-xl` step (914.8 against 917.4),
+      nothing in the backward — not worth a second loop in each kernel.
+    - When a tiling does not fit VMEM_BUDGET_BYTES the rule halves the kv
+      tile, then the q tile, in turn, down to 128. What does not shrink that
+      way are the whole-row blocks (k/v in the forward; q, do, dq and the f32
+      dq accumulator in the backward): they bound the sequence length.
+    """
+    if kernel not in ("fwd", "bwd"):
+        raise ValueError(f"unknown flash kernel {kernel!r}")
+    q_ = _pick_block(Sq, block_q or _TARGET_TILE)
+    k_ = _pick_block(Skv, block_k or _TARGET_TILE)
+    # a caller who fixed both gets them: Mosaic is the judge
+    explicit = block_q is not None and block_k is not None
+    while True:
+        t = Tiling(q_, k_, vmem_estimate(kernel, q_, k_, Sq, Skv, hd,
+                                         dtype_bytes))
+        if explicit or t.vmem_estimate <= VMEM_BUDGET_BYTES:
+            return t
+        can_k = block_k is None and k_ > _MIN_TILE
+        can_q = block_q is None and q_ > _MIN_TILE
+        if can_k and (k_ >= q_ or not can_q):
+            k_ = _pick_block(Skv, k_ // 2)
+        elif can_q:
+            q_ = _pick_block(Sq, q_ // 2)
+        else:
+            break
+    raise ValueError(
+        f"flash attention {kernel}: no tiling fits the VMEM budget of "
+        f"{VMEM_BUDGET_BYTES} bytes for Sq={Sq} Skv={Skv} hd={hd} "
+        f"({dtype_bytes}-byte operands): the smallest tried, block_q="
+        f"{t.block_q} block_k={t.block_k}, is estimated at "
+        f"{t.vmem_estimate} bytes"
+    )
+
+
+# --------------------------------------------------------------------------- #
 # Forward
 # --------------------------------------------------------------------------- #
 
 def _fwd_kernel(
     q_off_ref, kv_off_ref,            # scalar prefetch: global offsets [1]
-    q_ref, k_ref, v_ref,              # [1, bh, bq, hd], [1, bh, Skv, hd] ×2
-    *rest,                            # [mask_ref,] o_ref, lse_ref
-    scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
-    block_h: int = 1, mask_input: bool = False,
+    q_ref, k_ref, v_ref,              # [bq, hd], [Skv, hd], [Skv, hd]
+    o_ref, lse_ref,                   # [bq, hd], [1, bq]
+    *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
 ):
-    if mask_input:
-        mask_ref, o_ref, lse_ref = rest
-    else:
-        mask_ref = None
-        o_ref, lse_ref = rest
-    qi = pl.program_id(2)
+    qi = pl.program_id(1)
     q_global = q_off_ref[0] + qi * block_q
 
     nk = kv_len // block_k
@@ -111,128 +245,101 @@ def _fwd_kernel(
         num_blocks = nk
         num_full = nk
 
-    # heads are independent; processing block_h of them per grid step
-    # amortizes the per-step grid/DMA overhead (the attention matmuls are
-    # tiny at hd=64 — the kernel is overhead-bound, not FLOP-bound)
-    for hh in range(block_h):
-        # fold the softmax scale into q once — a per-block [bq, bk] f32
-        # multiply otherwise rides every inner iteration
-        q = q_ref[0, hh, :, :] * jnp.asarray(scale, q_ref.dtype)
-        hd = q.shape[-1]
+    # The logits tile is held TRANSPOSED, s^T = k·q^T, [block_k, block_q]:
+    # the softmax reductions then run down the sublanes (elementwise VPU
+    # maxima/sums across vregs) and the running max/sum are [1, block_q]
+    # lane-dense rows. With s as [block_q, block_k] every kv block paid two
+    # cross-lane (XLU) reductions and a lane broadcast of a [block_q, 1]
+    # column — a third of the kernel's time on the v5e.
+    #
+    # fold the softmax scale into q once — a per-block [bk, bq] f32
+    # multiply otherwise rides every inner iteration
+    qs = q_ref[...] * jnp.asarray(scale, q_ref.dtype)
+    hd = qs.shape[-1]
 
-        m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((block_q, 1), jnp.float32)
-        acc0 = jnp.zeros((block_q, hd), jnp.float32)
+    def make_body(masked):
+        def body(ki, carry):
+            m, l, acc = carry               # [1, bq], [1, bq], [hd, bq]
+            kv = pl.ds(ki * block_k, block_k)
+            s = lax.dot_general(
+                k_ref[kv, :], qs, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                               # [bk, bq]
+            if masked:
+                keep = (
+                    q_global + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 1)
+                    >= kv_off_ref[0] + ki * block_k + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 0))
+                s = jnp.where(keep, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+            v = v_ref[kv, :]
+            acc = acc * alpha + lax.dot_general(
+                v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                               # v^T·p^T = (p·v)^T, [hd, bq]
+            return m_new, l, acc
+        return body
 
-        def make_body(masked, hh=hh):
-            def body(ki, carry):
-                m, l, acc = carry
-                k = k_ref[0, hh, pl.ds(ki * block_k, block_k), :]
-                s = lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                if masked:
-                    if mask_input:
-                        # additive mask DMA'd per q-block (shared across the
-                        # block_h heads): ONE vector add versus the 4 VPU
-                        # passes of iota×2 + compare + select — the kernel is
-                        # VPU-bound, so mask arithmetic is step time
-                        s = s + mask_ref[0, :, pl.ds(ki * block_k, block_k)]
-                    else:
-                        rows = q_global + lax.broadcasted_iota(
-                            jnp.int32, (block_q, block_k), 0
-                        )
-                        cols = (kv_off_ref[0] + ki * block_k
-                                + lax.broadcasted_iota(
-                                    jnp.int32, (block_q, block_k), 1))
-                        s = jnp.where(rows >= cols, s, _NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)
-                l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-                v = v_ref[0, hh, pl.ds(ki * block_k, block_k), :]
-                acc = acc * alpha + lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                return m_new, l, acc
-            return body
-
-        carry = lax.fori_loop(0, num_full, make_body(False), (m0, l0, acc0))
-        m, l, acc = lax.fori_loop(
-            num_full, num_blocks, make_body(causal), carry
-        )
-        # rows with no valid kv (ring attention future chunks): l == 0 →
-        # output 0, lse = -inf-ish so the ring merge gives them zero weight.
-        l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, hh, :, :] = (acc / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(
-            l[:, 0] > 0, m[:, 0] + jnp.log(l_safe[:, 0]), _NEG_INF
-        )
-        lse_ref[0, hh, 0, :] = lse
+    carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32),
+             jnp.zeros((hd, block_q), jnp.float32))
+    carry = lax.fori_loop(0, num_full, make_body(False), carry)
+    m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal), carry)
+    # rows with no valid kv (ring attention future chunks): l == 0 →
+    # output 0, lse = -inf-ish so the ring merge gives them zero weight.
+    l_safe = jnp.where(l > 0, l, 1.0)
+    o_ref[...] = (acc / l_safe).T.astype(o_ref.dtype)
+    lse_ref[...] = jnp.where(l > 0, m + jnp.log(l_safe), _NEG_INF)
 
 
 def _mha_forward_bhsd(
     q, k, v, q_offset, kv_offset, *,
-    causal: bool, scale: float, block_q: int, block_k: int,
-    interpret: bool, block_h: int = 1, mask_ok: bool = False,
+    causal: bool, scale: float, interpret: bool,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: [B, H, S, hd] → (o [B,H,S,hd], lse [B,H,S])."""
+    """q,k,v: [B, H, S, hd] → (o [B,H,S,hd], lse [B,H,S]). Batch and head are
+    merged (a free reshape) into the one dim of independent rows the grid
+    walks. Tiles the caller leaves None are choose_tiling's."""
     B, H, Sq, hd = q.shape
     Skv = k.shape[2]
-    bq = _pick_block(Sq, block_q)
-    bk = _pick_block(Skv, block_k)
-    bh = block_h if block_h > 0 and H % block_h == 0 else 1
-    grid = (B, H // bh, Sq // bq)
-    # Precomputed additive causal mask, only valid for zero offsets (the
-    # single-device path — ring attention passes live offsets and keeps the
-    # in-kernel iota mask). Head-independent: one [bq, Skv] plane per
-    # q-block index, DMA'd once per grid step and shared by all bh heads.
-    # Only worth it when several heads amortize the DMA and the [Sq, Skv]
-    # f32 plane stays small — at long sequences (e.g. LLaMA S=4096 → 64 MB)
-    # streaming the mask costs more bandwidth than the iota path costs VPU.
-    mask_input = causal and mask_ok and bh > 1 and Sq * Skv <= 2 ** 21
-    operands = [q_offset, kv_offset, q, k, v]
-    in_specs = [
-        pl.BlockSpec((1, bh, bq, hd), lambda b, h, i, *_: (b, h, i, 0)),
-        pl.BlockSpec((1, bh, Skv, hd), lambda b, h, i, *_: (b, h, 0, 0)),
-        pl.BlockSpec((1, bh, Skv, hd), lambda b, h, i, *_: (b, h, 0, 0)),
-    ]
-    if mask_input:
-        rows = jnp.arange(Sq)[:, None]
-        cols = jnp.arange(Skv)[None, :]
-        mask = jnp.where(rows >= cols, 0.0, _NEG_INF).astype(jnp.float32)
-        operands.append(mask.reshape(Sq // bq, bq, Skv))
-        in_specs.append(
-            pl.BlockSpec((1, bq, Skv), lambda b, h, i, *_: (i, 0, 0))
-        )
+    R = B * H
+    t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize,
+                      block_q=block_q, block_k=block_k)
+    _record("fwd", R, Sq, Skv, hd, t)
+    bq, bk = t.block_q, t.block_k
+    kv_row = pl.BlockSpec((None, Skv, hd), lambda g, i, *_: (g, 0, 0))
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, kv_len=Skv, block_h=bh,
-        mask_input=mask_input,
+        block_q=bq, block_k=bk, kv_len=Skv,
     )
-    out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
-        jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32),
-    ]
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
+            grid=(R, Sq // bq),
+            in_specs=[
+                pl.BlockSpec((None, bq, hd), lambda g, i, *_: (g, i, 0)),
+                kv_row, kv_row,
+            ],
             out_specs=[
-                pl.BlockSpec((1, bh, bq, hd), lambda b, h, i, *_: (b, h, i, 0)),
-                pl.BlockSpec((1, bh, 1, bq), lambda b, h, i, *_: (b, h, 0, i)),
+                pl.BlockSpec((None, bq, hd), lambda g, i, *_: (g, i, 0)),
+                pl.BlockSpec((None, 1, bq), lambda g, i, *_: (g, 0, i)),
             ],
         ),
-        out_shape=out_shape,
+        out_shape=[
+            jax.ShapeDtypeStruct((R, Sq, hd), q.dtype),
+            jax.ShapeDtypeStruct((R, 1, Sq), jnp.float32),
+        ],
         interpret=interpret,
         name=names.FLASH_FWD_KERNEL,
-    )(*operands)
-    return o, lse[:, :, 0, :]
+    )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(R, Skv, hd),
+      v.reshape(R, Skv, hd))
+    return o.reshape(B, H, Sq, hd), lse.reshape(B, H, Sq)
 
 
 # --------------------------------------------------------------------------- #
@@ -242,30 +349,28 @@ def _mha_forward_bhsd(
 def _fused_bwd_kernel(
     q_off_ref, kv_off_ref,
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dk_ref, dv_ref,
+    dq_ref, dk_ref, dv_ref, dq_acc,
     *, scale: float, causal: bool, block_q: int, block_k: int, q_len: int,
-    block_h: int = 1,
 ):
     """Single-pass backward: grid over kv blocks; dk/dv written per block,
-    dq accumulated into a whole-row VMEM-resident output (its index map is
-    constant in the kv grid dim, so Pallas keeps the block live across
-    iterations). Versus the split dq/dkv kernels this computes s, p and dp
-    ONCE per (q, kv) block pair — 5 matmuls instead of 7 and half the
+    dq accumulated over the kv grid dim in a whole-row f32 VMEM scratch
+    (bf16 accumulation would drift with the number of kv blocks) and written
+    once, at the last kv block, into an output block whose index map is
+    constant in that dim. Versus the split dq/dkv kernels this computes s, p
+    and dp ONCE per (q, kv) block pair — 5 matmuls instead of 7 and half the
     exp/mask VPU work — worth ~25% of backward time at GPT-2 shapes."""
-    ki = pl.program_id(2)
-    nk_total = pl.num_programs(2)
-    block_k_ = k_ref.shape[2]
-    kv_global = kv_off_ref[0] + ki * block_k_
+    ki = pl.program_id(1)
+    kv_global = kv_off_ref[0] + ki * block_k
 
     @pl.when(ki == 0)
     def _init():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     nq = q_len // block_q
     if causal:
         first = jnp.clip((kv_global - q_off_ref[0]) // block_q, 0, nq)
         first_full = jnp.clip(
-            -((q_off_ref[0] - kv_global - block_k_ + 1) // block_q), 0, nq
+            -((q_off_ref[0] - kv_global - block_k + 1) // block_q), 0, nq
         )
     else:
         first = 0
@@ -273,117 +378,117 @@ def _fused_bwd_kernel(
 
     scale_c = jnp.asarray(scale, q_ref.dtype)
 
-    # heads are independent; block_h of them per grid step amortizes the
-    # per-step grid/DMA overhead (see _fwd_kernel)
-    for hh in range(block_h):
-        k = k_ref[0, hh, :, :]
-        v = v_ref[0, hh, :, :]
-        hd = k.shape[-1]
-        # dq contribution is ds @ (k*scale): folding the softmax scale into
-        # k here is one [bk, hd] multiply per grid step instead of per-pair
-        k_scaled = k * scale_c
+    # the logits tile is held transposed (see _fwd_kernel): lse and delta are
+    # read as the [1, block_q] rows they are stored as, p^T and ds^T feed dv
+    # and dk as plain matmuls with no transpose, and dq accumulates
+    # transposed, [hd, Sq] — lane-dense, half the VMEM of a lane-padded
+    # [Sq, hd] block.
+    k = k_ref[...]
+    v = v_ref[...]
+    hd = k.shape[-1]
+    # dq contribution is ds @ (k*scale): folding the softmax scale into
+    # k here is one [bk, hd] multiply per grid step instead of per-pair
+    k_scaled = k * scale_c
 
-        def make_body(masked, hh=hh, k=k, v=v, k_scaled=k_scaled):
-            def body(qi, carry):
-                dk, dv = carry
-                qs = q_ref[0, hh, pl.ds(qi * block_q, block_q), :] * scale_c
-                do = do_ref[0, hh, pl.ds(qi * block_q, block_q), :]
-                lse = lse_ref[0, hh, 0, pl.ds(qi * block_q, block_q)][:, None]
-                delta = delta_ref[0, hh, 0, pl.ds(qi * block_q, block_q)][:, None]
-                s = lax.dot_general(
-                    qs, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                if masked:
-                    rows = q_off_ref[0] + qi * block_q + lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 0
-                    )
-                    cols = kv_global + lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1
-                    )
-                    s = jnp.where(rows >= cols, s, _NEG_INF)
-                p = jnp.exp(s - lse)                     # [bq, bk]
-                dv = dv + lax.dot_general(
-                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                dp = lax.dot_general(
-                    do, v, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                ds = p * (dp - delta)
-                dk = dk + lax.dot_general(
-                    ds.astype(qs.dtype), qs, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                sl = pl.ds(qi * block_q, block_q)
-                dq_ref[0, hh, sl, :] += lax.dot_general(
-                    ds.astype(k.dtype), k_scaled, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ).astype(dq_ref.dtype)
-                return dk, dv
-            return body
+    def make_body(masked):
+        def body(qi, carry):
+            dk, dv = carry
+            sl = pl.ds(qi * block_q, block_q)
+            qs = q_ref[sl, :] * scale_c
+            do = do_ref[sl, :]
+            lse = lse_ref[:, sl]                         # [1, bq]
+            delta = delta_ref[:, sl]
+            s = lax.dot_general(
+                k, qs, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # [bk, bq]
+            if masked:
+                keep = (
+                    q_off_ref[0] + qi * block_q + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 1)
+                    >= kv_global + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 0))
+                s = jnp.where(keep, s, _NEG_INF)
+            p = jnp.exp(s - lse)
+            dv = dv + lax.dot_general(
+                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dp = lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # [bk, bq]
+            ds = (p * (dp - delta)).astype(qs.dtype)
+            dk = dk + lax.dot_general(
+                ds, qs, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dq_acc[:, sl] += lax.dot_general(
+                k_scaled, ds, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # [hd, bq]
+            return dk, dv
+        return body
 
-        dk0 = jnp.zeros((block_k_, hd), jnp.float32)
-        dv0 = jnp.zeros((block_k_, hd), jnp.float32)
-        carry = lax.fori_loop(first, first_full, make_body(causal), (dk0, dv0))
-        dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
-        dk_ref[0, hh, :, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, hh, :, :] = dv.astype(dv_ref.dtype)
+    carry = (jnp.zeros((block_k, hd), jnp.float32),
+             jnp.zeros((block_k, hd), jnp.float32))
+    carry = lax.fori_loop(first, first_full, make_body(causal), carry)
+    dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _write_dq():
+        dq_ref[...] = dq_acc[...].T.astype(dq_ref.dtype)
 
 
 def _mha_backward_bhsd(
     q, k, v, o, lse, do, q_offset, kv_offset, *,
-    causal: bool, scale: float, block_q: int, block_k: int, interpret: bool,
-    block_h: int = 1,
+    causal: bool, scale: float, interpret: bool,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
 ):
-    """All tensors [B, H, S, hd]; lse [B, H, S]. Returns dq, dk, dv."""
+    """All tensors [B, H, S, hd]; lse [B, H, S]. Returns dq, dk, dv. Rows and
+    tiles as in _mha_forward_bhsd, chosen for this kernel separately."""
     B, H, Sq, hd = q.shape
     Skv = k.shape[2]
-    bq = _pick_block(Sq, block_q)
-    bk = _pick_block(Skv, block_k)
-    bh = block_h if block_h > 0 and H % block_h == 0 else 1
+    R = B * H
+    t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize,
+                      block_q=block_q, block_k=block_k)
+    _record("bwd", R, Sq, Skv, hd, t)
+    bq, bk = t.block_q, t.block_k
 
     # delta_i = rowsum(dO_i * O_i): cheap elementwise+reduce, XLA fuses it.
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    )[:, :, None, :]                       # [B, H, 1, Sq]
-    lse4 = lse[:, :, None, :]              # [B, H, 1, Sq]
+    ).reshape(R, 1, Sq)
+    row = pl.BlockSpec((None, Sq, hd), lambda g, i, *_: (g, 0, 0))
+    kv_block = pl.BlockSpec((None, bk, hd), lambda g, i, *_: (g, i, 0))
+    stat = pl.BlockSpec((None, 1, Sq), lambda g, i, *_: (g, 0, 0))
 
     fused_kernel = functools.partial(
         _fused_bwd_kernel, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, q_len=Sq, block_h=bh,
+        block_q=bq, block_k=bk, q_len=Sq,
     )
-    # dq accumulates across kv grid steps → f32 output (bf16 accumulation
-    # would drift with the number of kv blocks); cast at the end.
-    dq_f32, dk, dv = pl.pallas_call(
+    dq, dk, dv = pl.pallas_call(
         fused_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, H // bh, Skv // bk),
-            in_specs=[
-                pl.BlockSpec((1, bh, Sq, hd), lambda b, h, i, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, bh, bk, hd), lambda b, h, i, *_: (b, h, i, 0)),
-                pl.BlockSpec((1, bh, bk, hd), lambda b, h, i, *_: (b, h, i, 0)),
-                pl.BlockSpec((1, bh, Sq, hd), lambda b, h, i, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, bh, 1, Sq), lambda b, h, i, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, bh, 1, Sq), lambda b, h, i, *_: (b, h, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bh, Sq, hd), lambda b, h, i, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, bh, bk, hd), lambda b, h, i, *_: (b, h, i, 0)),
-                pl.BlockSpec((1, bh, bk, hd), lambda b, h, i, *_: (b, h, i, 0)),
-            ],
+            grid=(R, Skv // bk),
+            in_specs=[row, kv_block, kv_block, row, stat, stat],
+            out_specs=[row, kv_block, kv_block],
+            scratch_shapes=[pltpu.VMEM((hd, Sq), jnp.float32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, jnp.float32),
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((R, Sq, hd), q.dtype),
+            jax.ShapeDtypeStruct((R, Skv, hd), k.dtype),
+            jax.ShapeDtypeStruct((R, Skv, hd), v.dtype),
         ],
         interpret=interpret,
         name=names.FLASH_BWD_KERNEL,
-    )(q_offset, kv_offset, q, k, v, do, lse4, delta)
-    return dq_f32.astype(q.dtype), dk, dv
+    )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(R, Skv, hd),
+      v.reshape(R, Skv, hd), do.reshape(R, Sq, hd), lse.reshape(R, 1, Sq),
+      delta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # --------------------------------------------------------------------------- #
@@ -398,24 +503,16 @@ def _zero_off():
     return jnp.zeros((1,), jnp.int32)
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
-)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret, bhsd, block_h, bwd_block_h):
-    o, _ = _mha_forward_bhsd(
-        q if bhsd else _to_bhsd(q),
-        k if bhsd else _to_bhsd(k),
-        v if bhsd else _to_bhsd(v),
-        _zero_off(), _zero_off(),
-        causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, block_h=block_h, mask_ok=True,
-    )
-    return o if bhsd else _to_bhsd(o)
+           bwd_block_k, interpret, bhsd):
+    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
+                      bwd_block_k, interpret, bhsd)
+    return o
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-               bwd_block_k, interpret, bhsd, block_h, bwd_block_h):
+               bwd_block_k, interpret, bhsd):
     if bhsd:
         qt, kt, vt = q, k, v
     else:
@@ -423,19 +520,19 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
     o, lse = _mha_forward_bhsd(
         qt, kt, vt, _zero_off(), _zero_off(),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, block_h=block_h, mask_ok=True,
+        interpret=interpret,
     )
     return (o if bhsd else _to_bhsd(o)), (qt, kt, vt, o, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-               interpret, bhsd, block_h, bwd_block_h, res, do):
+               interpret, bhsd, res, do):
     qt, kt, vt, o, lse = res
     dq, dk, dv = _mha_backward_bhsd(
         qt, kt, vt, o, lse, do if bhsd else _to_bhsd(do),
         _zero_off(), _zero_off(),
         causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k,
-        interpret=interpret, block_h=bwd_block_h,
+        interpret=interpret,
     )
     if bhsd:
         return dq, dk, dv
@@ -453,14 +550,12 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     bwd_block_q: Optional[int] = None,
     bwd_block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     layout: str = "bshd",
-    block_h: int = 1,
-    bwd_block_h: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head flash attention. q,k,v: [B, S, H, hd] → [B, S, H, hd]
     (layout="bshd", the default) or [B, H, S, hd] in and out
@@ -468,9 +563,9 @@ def flash_attention(
     head-major tensors directly skip the boundary transposes entirely, worth
     ~3% of a GPT-2 train step on v5e).
 
-    block_h processes that many heads per grid step (must divide H; falls
-    back to 1 otherwise). At small head_dim the kernels are grid-overhead
-    bound, not FLOP bound — packing heads amortizes the per-step cost.
+    The q/kv tile, forward and backward separately, is choose_tiling's, from
+    the shapes. The block_* keywords are explicit overrides of it: block_q /
+    block_k the forward's tiles; a bwd_* left None follows its forward twin.
 
     Differentiable (custom VJP, flash backward). On non-TPU backends the
     kernels run in Pallas interpreter mode so tests validate the same code.
@@ -484,7 +579,7 @@ def flash_attention(
     return _flash(
         q, k, v, causal, scale, block_q, block_k,
         bwd_block_q or block_q, bwd_block_k or block_k,
-        interpret, layout == "bhsd", block_h, bwd_block_h or block_h,
+        interpret, layout == "bhsd",
     )
 
 
@@ -538,8 +633,8 @@ def flash_attention_with_lse(
     q, k, v, q_offset, kv_offset, *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forward-only flash attention returning (out [B,S,H,hd], lse [B,H,S])
@@ -565,8 +660,8 @@ def mha_backward_chunk(
     q, k, v, o, lse, do, q_offset, kv_offset, *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
     """Backward for one (q-chunk, kv-chunk) pair with global offsets; returns

@@ -33,6 +33,11 @@ SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
 FLASH_FWD_KERNEL = "flash_attention_fwd"
 FLASH_BWD_KERNEL = "flash_attention_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL)
+# the tiling ops/attention.py chose for a kernel: one instant event per
+# distinct decision, at trace time, in the task-event buffer
+FLASH_TILING = "ops/flash_tiling"
+FLASH_TILING_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
+                     "vmem_estimate")
 
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer

@@ -99,16 +99,16 @@ def test_attention_call_shapes_reads_compiled_hlo():
     import chip_smoke
 
     hlo = "\n".join([
-        "  %fusion.1 = bf16[8,12,1024,64]{3,2,1,0} fusion(%p)",
-        '  %custom-call.3 = (bf16[8,12,1024,64]{3,2,1,0:T(8,128)(2,1)}, '
-        'f32[8,12,1,1024]{3,2,1,0}) custom-call(s32[1]{0} %a, s32[1]{0} %b, '
-        'bf16[8,12,1024,64]{3,2,1,0} %q, bf16[8,12,1024,64]{3,2,1,0} %k), '
+        "  %fusion.1 = bf16[96,1024,64]{2,1,0} fusion(%p)",
+        '  %flash_attention_fwd.3 = (bf16[96,1024,64]{2,1,0:T(8,128)(2,1)}, '
+        'f32[96,1,1024]{2,1,0}) custom-call(s32[1]{0} %a, s32[1]{0} %b, '
+        'bf16[96,1024,64]{2,1,0} %q, bf16[96,1024,64]{2,1,0} %k), '
         'custom_call_target="tpu_custom_call", backend_config={}',
-        '  %custom-call.4 = (f32[32,12,1024,64]{3,2,1,0}, bf16[32,12,1024,64]'
-        '{3,2,1,0}) custom-call(%x), custom_call_target="tpu_custom_call"',
+        '  %flash_attention_bwd.4 = (bf16[384,1024,64]{2,1,0}, bf16[384,1024,64]'
+        '{2,1,0}) custom-call(%x), custom_call_target="tpu_custom_call"',
     ])
     assert chip_smoke.attention_call_shapes(hlo, 64) == (
-        2, [[8, 12, 1024, 64], [32, 12, 1024, 64]]
+        2, [[96, 1024, 64], [384, 1024, 64]]
     )
 
 

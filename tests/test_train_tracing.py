@@ -87,6 +87,22 @@ def test_kernel_name_in_jaxpr(kernel):
     assert f"name={kernel}" in jaxpr
 
 
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_flash_tiling_decision_of_the_lowered_step(kernel):
+    """Tracing the step leaves one `ops/flash_tiling` decision per kernel,
+    with the vocabulary's args, for the shard the kernel was given."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.ops import attention
+
+    _lowering("scan")
+    cfg = gpt2.gpt2_tiny()
+    mine = [d for d in attention.flash_tiling_decisions()
+            if (d["kernel"], d["rows"], d["Sq"], d["hd"])
+            == (kernel, 2 * cfg.n_head, cfg.seq_len, cfg.head_dim)]
+    assert len(mine) == 1
+    assert tuple(mine[0]) == names.FLASH_TILING_ARGS
+
+
 # ------------------------------------------------------------- profile_span
 @pytest.fixture
 def buffer(monkeypatch):
