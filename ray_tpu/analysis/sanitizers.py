@@ -28,6 +28,17 @@ and are visible from the driver via ``summarize_metrics()`` /
 
 With the gate off every entry point is a cheap flag check and
 ``make_lock`` returns a plain ``threading.Lock`` — zero production cost.
+
+Finalisers may re-enter. On Python 3.12 the collector runs at any
+allocation, so an ``ObjectRef.__del__`` that takes a sanitized lock can
+run on a thread that is already inside the lock-order graph.
+``_graph_section`` is therefore the only code that takes the graph's
+lock: an acquisition noted while this thread is inside it is kept as
+held, its edges are put on a thread-local list, and the section records
+them (and reports any cycle they close) once it has released the lock.
+Violations are recorded outside the section, because
+``record_violation`` takes the metrics registry's locks, which are
+sanitized themselves.
 """
 
 from __future__ import annotations
@@ -39,6 +50,7 @@ import threading
 import time
 import traceback
 import weakref
+from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
 logger = logging.getLogger(__name__)
@@ -117,7 +129,7 @@ def reset() -> None:
     with _vio_lock:
         _violations.clear()
         _counts.clear()
-    with _graph_lock:
+    with _graph_section():
         _edges.clear()
         _cycles_seen.clear()
 
@@ -132,13 +144,11 @@ def scoped(drop_prefixes: tuple = ()):
     thread records concurrently (a watchdog trip, a flush-loop lock
     inversion) survives the exit — a blanket :func:`reset` here would
     silently defeat the suite-wide zero-violations gate in conftest."""
-    from contextlib import contextmanager
-
     @contextmanager
     def _scope():
         with _vio_lock:
             vios, counts = list(_violations), dict(_counts)
-        with _graph_lock:
+        with _graph_section():
             edges, cycles = dict(_edges), set(_cycles_seen)
         try:
             yield
@@ -154,7 +164,7 @@ def scoped(drop_prefixes: tuple = ()):
                 _counts.update(counts)
                 for v in kept:
                     _counts[v["kind"]] = _counts.get(v["kind"], 0) + 1
-            with _graph_lock:
+            with _graph_section():
                 # same keep-the-real-deltas rule for the ordering graph:
                 # erasing an edge another thread first-observed during the
                 # scope would let the REVERSE order become canonical later
@@ -196,6 +206,32 @@ def _held() -> List[str]:
     return held
 
 
+def _pending() -> List[tuple]:
+    pending = getattr(_tls, "pending", None)
+    if pending is None:
+        pending = _tls.pending = []
+    return pending
+
+
+@contextmanager
+def _graph_section():
+    """The one way to ``_edges`` and ``_cycles_seen``, and the only code
+    that takes ``_graph_lock``. The lock is not re-entrant and a finaliser
+    can run on this thread anywhere inside, so the thread says it is
+    inside before it takes the lock and until after it has released it;
+    ``_note_acquired`` then queues instead of entering. What was queued is
+    recorded on the way out, through the same ``_add_edges``."""
+    _tls.in_graph = True
+    try:
+        with _graph_lock:
+            yield
+    finally:
+        _tls.in_graph = False
+        pending = _pending()
+        while pending:
+            _add_edges(*pending.pop(0))
+
+
 def _find_path(src: str, dst: str) -> Optional[List[tuple]]:
     """DFS over the edge graph: a path of edges src -> ... -> dst."""
     stack = [(src, [])]
@@ -215,44 +251,55 @@ def _find_path(src: str, dst: str) -> Optional[List[tuple]]:
 
 def _note_acquired(name: str) -> None:
     held = _held()
-    if held:
-        cur_stack = None
-        # violations are recorded OUTSIDE _graph_lock: record_violation
-        # takes _vio_lock and the metrics registry locks — which may
-        # themselves be sanitized locks re-entering this function
-        found: List[tuple] = []
-        with _graph_lock:
-            for h in dict.fromkeys(held):  # unique, order kept
-                if h == name:
-                    continue  # recursion / same-name class: no self-edges
-                edge = (h, name)
-                if edge not in _edges:
-                    if cur_stack is None:
-                        cur_stack = "".join(traceback.format_stack(limit=12))
-                    _edges[edge] = cur_stack
-                    # does acquiring `name` while holding `h` close a cycle
-                    # (a recorded path name -> ... -> h)?
-                    path = _find_path(name, h)
-                    if path is not None:
-                        cycle = tuple(sorted({name, h}.union(
-                            x for e in path for x in e)))
-                        if cycle not in _cycles_seen:
-                            _cycles_seen.add(cycle)
-                            found.append(
-                                (h, path, cur_stack,
-                                 _edges.get(path[0], "")))
-        held.append(name)
-        for h, path, stack, rev_stack in found:
-            record_violation(
-                "lock_order", name,
-                f"lock-order cycle: acquired {name!r} while holding "
-                f"{h!r}, but the reverse order "
-                f"{' -> '.join(a for a, _ in path)} -> {h} was recorded "
-                f"earlier — potential deadlock",
-                stacks=[stack, rev_stack],
-            )
-        return
+    # Edges not seen yet, from each lock held (unique, order kept; no
+    # self-edges: recursion / same-name class). A look without the lock: a
+    # dict lookup is atomic, edges only go away in reset()/scoped(), and
+    # _add_edges looks again inside the section.
+    new = [h for h in dict.fromkeys(held)
+           if h != name and (h, name) not in _edges]
     held.append(name)
+    if not new:
+        return
+    # taken here, not inside the section: it allocates, so the collector
+    # (and a finaliser that takes a sanitized lock) may run under it
+    stack = "".join(traceback.format_stack(limit=12))
+    if getattr(_tls, "in_graph", False):
+        # a finaliser on a thread that is inside the section: the lock is
+        # this thread's own, so the edges wait for the section's exit
+        _pending().append((new, name, stack))
+        return
+    _add_edges(new, name, stack)
+
+
+def _add_edges(new: List[str], name: str, stack: str) -> None:
+    """Record the edges h -> ``name`` for each h of ``new`` and report the
+    cycles they close."""
+    found: List[tuple] = []
+    with _graph_section():
+        for h in new:
+            edge = (h, name)
+            if edge in _edges:
+                continue
+            _edges[edge] = stack
+            # does acquiring `name` while holding `h` close a cycle
+            # (a recorded path name -> ... -> h)?
+            path = _find_path(name, h)
+            if path is None:
+                continue
+            cycle = tuple(sorted({name, h}.union(
+                x for e in path for x in e)))
+            if cycle not in _cycles_seen:
+                _cycles_seen.add(cycle)
+                found.append((h, path, _edges.get(path[0], "")))
+    for h, path, rev_stack in found:
+        record_violation(
+            "lock_order", name,
+            f"lock-order cycle: acquired {name!r} while holding "
+            f"{h!r}, but the reverse order "
+            f"{' -> '.join(a for a, _ in path)} -> {h} was recorded "
+            f"earlier — potential deadlock",
+            stacks=[stack, rev_stack],
+        )
 
 
 def _note_released(name: str) -> None:
@@ -372,7 +419,7 @@ def make_condition(name: str, lock=None):
 
 
 def lock_order_edges() -> Dict[tuple, str]:
-    with _graph_lock:
+    with _graph_section():
         return dict(_edges)
 
 

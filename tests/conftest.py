@@ -27,7 +27,10 @@ import jax
 
 jax.config.update("jax_platforms", "cpu")
 
+import faulthandler
 import signal
+import sys
+from contextlib import contextmanager
 
 import pytest
 
@@ -39,9 +42,9 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "chaos(timeout=120): deterministic fault-injection tests "
-        "(ray_tpu.testing.chaos). Run in tier-1 under a per-test SIGALRM "
-        "guard so a regression that re-introduces a hang fails fast "
-        "instead of stalling the whole suite.",
+        "(ray_tpu.testing.chaos). Its limit (default 120 s, timeout= "
+        "overrides) replaces the one every test runs under "
+        "(TEST_TIME_LIMIT_S).",
     )
     config.addinivalue_line(
         "markers",
@@ -79,31 +82,53 @@ def pytest_sessionfinish(session, exitstatus):
         session.exitstatus = 1
 
 
-@pytest.hookimpl(hookwrapper=True)
-def pytest_runtest_call(item):
-    """Per-test timeout guard for chaos-marked tests: fault-injection bugs
-    typically manifest as hangs (a blocked get on a dead ring), and the
-    suite-level timeout would eat the whole tier-1 budget. SIGALRM fires in
-    the main thread; the framework's blocking waits are sleep-loops, so the
-    alarm interrupts them."""
-    marker = item.get_closest_marker("chaos")
-    if marker is None or not hasattr(signal, "SIGALRM"):
-        yield
-        return
-    limit = int(marker.kwargs.get("timeout", 120))
+# Every test runs under a limit of its own, in each of set-up, call and
+# tear-down: a hang (a blocked get on a dead ring, a cluster fixture that
+# never comes up) then fails one test instead of eating the suite's budget.
+# Seconds: three times the slowest honest test of a whole run under the
+# driver's six workers (test_microbench_smoke, 94 s). chaos(timeout=...)
+# overrides it for one test; a bare chaos mark keeps the 120 s it always had
+# (fault injection that goes wrong hangs, and says so sooner). The limit
+# bounds a hang local to one test, not a deadlock of the whole process:
+# every later test of that worker would then spend its own limit, a phase.
+TEST_TIME_LIMIT_S = 300
+
+
+@contextmanager
+def time_limit(seconds: int, what: str):
+    """Raise TimeoutError in the main thread after ``seconds``, with every
+    thread's stack dumped to stderr first so that the hang names itself.
+    SIGALRM is handled in the main thread, which is where pytest (and an
+    xdist worker) runs its tests; the framework's blocking waits are
+    sleep-loops or lock waits, both of which the signal interrupts."""
 
     def on_alarm(signum, frame):
-        raise TimeoutError(
-            f"chaos test exceeded its {limit}s guard (stuck failure path?)"
-        )
+        # fd 2, not sys.stderr: capsys puts an object with no fileno there,
+        # and pytest's fd capture shows fd 2 in the failed test's report
+        faulthandler.dump_traceback(file=sys.__stderr__, all_threads=True)
+        signal.alarm(seconds)  # the clean-up this raise sets off is cut too
+        raise TimeoutError(f"{what} exceeded its {seconds}s limit")
 
     old = signal.signal(signal.SIGALRM, on_alarm)
-    signal.alarm(limit)
+    outer = signal.alarm(seconds)  # seconds an enclosing limit had left
     try:
         yield
     finally:
-        signal.alarm(0)
+        signal.alarm(outer)
         signal.signal(signal.SIGALRM, old)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def _under_time_limit(item):
+    marker = item.get_closest_marker("chaos")
+    limit = int(marker.kwargs.get("timeout", 120)) \
+        if marker else TEST_TIME_LIMIT_S
+    with time_limit(limit, item.nodeid):
+        yield
+
+
+pytest_runtest_setup = pytest_runtest_call = pytest_runtest_teardown = \
+    _under_time_limit
 
 
 @pytest.fixture

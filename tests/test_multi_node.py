@@ -47,7 +47,11 @@ def test_spillback_schedules_on_remote_node(two_node_cluster):
 
 
 def test_parallelism_across_nodes(two_node_cluster):
-    """Two 1-CPU nodes must run two 1-CPU tasks concurrently."""
+    """Two 1-CPU nodes must run two 1-CPU tasks concurrently: one a node.
+    (The clock said the same, `elapsed < 5.5` around the two 3 s sleeps.)
+    Red until ROADMAP D17 is repaired: both run on one node, one after the
+    other, because `_dispatch` skips a lease that lacks resources before it
+    reaches the busy-node offload whenever the node has no idle worker."""
     ray, cluster = two_node_cluster
 
     @ray.remote(resources={"head": 0.01})
@@ -58,19 +62,19 @@ def test_parallelism_across_nodes(two_node_cluster):
     def warm_side():
         return 1
 
-    # warm both nodes' worker pools so the timing below measures scheduling,
-    # not interpreter cold start on this 1-core host
+    # warm both nodes' worker pools: placement is what is asserted below,
+    # not which node's interpreter starts first
     ray.get([warm_head.remote(), warm_side.remote()], timeout=120)
 
     @ray.remote
     def block(sec):
-        time.sleep(sec)
-        return time.time()
+        import os
 
-    t0 = time.time()
-    ray.get([block.remote(3), block.remote(3)], timeout=120)
-    elapsed = time.time() - t0
-    assert elapsed < 5.5, f"tasks serialized: {elapsed}s"
+        time.sleep(sec)
+        return os.environ.get("RAY_TPU_NODE_ID")
+
+    where = ray.get([block.remote(3), block.remote(3)], timeout=120)
+    assert sorted(where) == sorted(cluster.node_ids), where
 
 
 def test_object_transfer_between_nodes(two_node_cluster):

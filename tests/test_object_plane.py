@@ -460,18 +460,24 @@ def test_streaming_overflow_spills_to_shm():
             num_returns="streaming",
             generator_backpressure_num_objects=n + 8,
         ).remote(n)
-        time.sleep(1.0)  # let the producer run far ahead of the consumer
+        from ray_tpu.util.metrics import get_registry
+
+        def spilled():
+            return sum(
+                sum(series["points"].values())
+                for series in get_registry().collect()
+                if series["name"] == "streaming_spilled_items_total")
+
+        # nothing is consumed until the producer has run past the in-flight
+        # bound: the owner's own counter says when, not a fixed sleep
+        deadline = time.monotonic() + 60
+        while spilled() < 1 and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert spilled() >= 1, "no stream item ever spilled"
         got = [ray_tpu.get(r, timeout=60) for r in gen]
         assert len(got) == n
         for i, item in enumerate(got):
             assert item == bytes([i % 251]) * 2048
-        from ray_tpu.util.metrics import get_registry
-
-        spilled = 0.0
-        for series in get_registry().collect():
-            if series["name"] == "streaming_spilled_items_total":
-                spilled += sum(series["points"].values())
-        assert spilled >= 1, "no stream item ever spilled"
     finally:
         _config.streaming_max_inflight_items = saved
         ray_tpu.shutdown()

@@ -11,6 +11,7 @@ Covers the PR-13 tentpole guarantees:
 """
 
 import asyncio
+import os
 import threading
 import time
 
@@ -161,7 +162,8 @@ def test_fastpath_respects_admission_and_deadline(fast_warmup):
         ray_tpu.shutdown()
 
 
-def test_replica_killed_mid_fastpath_degrades_to_slow_path(fast_warmup):
+def test_replica_killed_mid_fastpath_degrades_to_slow_path(fast_warmup,
+                                                           tmp_path):
     """The satellite chaos scenario: kill the pinned replica with fast-path
     requests in flight; every request resolves (one budgeted retry on a
     healthy replica), the breaker/eviction plane observes the death, and
@@ -170,7 +172,14 @@ def test_replica_killed_mid_fastpath_degrades_to_slow_path(fast_warmup):
     try:
         @serve.deployment(name="fp_kill", num_replicas=2)
         class Echo:
-            def __call__(self, x):
+            def __call__(self, x, gate=None):
+                # the burst waits at the gate: it is in flight when its
+                # replica is killed, however fast the replica and however
+                # slow the kill
+                deadline = time.monotonic() + 60
+                while gate and not os.path.exists(gate) \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.01)
                 return x + 7
 
         handle = serve.run(Echo.bind())
@@ -193,8 +202,10 @@ def test_replica_killed_mid_fastpath_degrades_to_slow_path(fast_warmup):
         req_before = _metric_total("serve_requests_total", "fp_kill")
         e2e_before = _metric_total("serve_request_latency_ms", "fp_kill")
 
-        refs = [handle.remote(i) for i in range(10)]
+        gate = str(tmp_path / "gate")
+        refs = [handle.remote(i, gate) for i in range(10)]
         ray_tpu.kill(victim)
+        open(gate, "w").close()
         # no user-visible error beyond the typed retry semantics: every
         # ref resolves with the correct value
         assert [ray_tpu.get(r, timeout=60) for r in refs] == \

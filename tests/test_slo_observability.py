@@ -320,50 +320,60 @@ def test_serve_slo_pipeline_cluster(cluster):
         assert [ray.get(handle.remote(i), timeout=60) for i in range(n)] \
             == [i * 2 for i in range(n)]
 
-        # replica registry flush (2s) + GCS sample loop (2s)
+        # replica registry flush (2s) + GCS sample loop (2s): each series is
+        # waited for by name and count, however long the box takes
         gcs_addr = _global_worker().backend.core.gcs_address
         dash = start_dashboard(gcs_addr, port=0)
-        deadline = time.monotonic() + 30
-        text = ""
         want = ('serve_request_latency_ms_bucket{deployment="Echo"',
                 'serve_exec_latency_ms_bucket{deployment="Echo"',
                 'serve_queue_wait_ms_bucket{deployment="Echo"',
-                'serve_requests_total{deployment="Echo"}')
-        while time.monotonic() < deadline:
+                # derived core-task series + cluster-wide rpc wire counters
+                "task_e2e_ms_bucket",
+                "# TYPE rpc_frames_sent counter")
+
+        def requests_served(text):
+            m = re.search(
+                r'serve_requests_total\{deployment="Echo"\} (\S+)', text)
+            return float(m.group(1)) if m else 0.0
+
+        deadline = time.monotonic() + 45
+        while True:
             with urllib.request.urlopen(dash.url + "/metrics",
                                         timeout=10) as r:
                 text = r.read().decode()
-            if all(w in text for w in want):
+            if all(w in text for w in want) and requests_served(text) >= n:
+                break
+            if time.monotonic() >= deadline:
                 break
             time.sleep(0.5)
         for w in want:
             assert w in text, f"missing {w!r} in /metrics:\n{text[:3000]}"
-        m = re.search(r'serve_requests_total\{deployment="Echo"\} (\S+)',
-                      text)
-        assert m and float(m.group(1)) >= n
-        # derived core-task series + cluster-wide rpc wire counters landed
-        assert "task_e2e_ms_bucket" in text
-        assert "# TYPE rpc_frames_sent counter" in text
+        assert requests_served(text) >= n
         _lint_prometheus(text)
 
-        # the same series are in the retained TIME SERIES, with history
-        deadline = time.monotonic() + 20
-        samples = []
-        while time.monotonic() < deadline:
+        # the same series are in the retained TIME SERIES, with history: two
+        # samples hold the latency histogram, and Echo's requests are in it
+        tags = {"deployment": "Echo"}
+        deadline = time.monotonic() + 45
+        while True:
             samples = state.get_metrics_timeseries(
                 names=["serve_requests_total", "serve_request_latency_ms",
                        "serve_exec_latency_ms"]
             )
-            with_data = [s for s in samples if s["series"]]
-            if len(with_data) >= 2:
+            with_latency = [
+                s for s in samples
+                if any(x["name"] == "serve_request_latency_ms"
+                       for x in s["series"])
+            ]
+            p50 = state.metric_percentile("serve_request_latency_ms", 0.5,
+                                          tags, samples=samples)
+            p99 = state.metric_percentile("serve_request_latency_ms", 0.99,
+                                          tags, samples=samples)
+            if (len(with_latency) >= 2 and None not in (p50, p99)) \
+                    or time.monotonic() >= deadline:
                 break
             time.sleep(0.5)
-        assert len([s for s in samples if s["series"]]) >= 2
-        tags = {"deployment": "Echo"}
-        p50 = state.metric_percentile("serve_request_latency_ms", 0.5, tags,
-                                      samples=samples)
-        p99 = state.metric_percentile("serve_request_latency_ms", 0.99, tags,
-                                      samples=samples)
+        assert len(with_latency) >= 2
         assert p50 is not None and p99 is not None and p50 <= p99
 
         # dashboard JSON timeseries + the top-like CLI rendering

@@ -7,12 +7,15 @@ CLI, and — marked ``lint`` so the tier-1 gate is a single test node — the
 whole-package run asserting zero unsuppressed findings.
 """
 
+import gc
 import json
 import textwrap
 import threading
 import time
+import traceback
 
 import pytest
+from conftest import time_limit
 
 from ray_tpu.analysis import lint_source
 
@@ -424,6 +427,168 @@ def test_lock_order_no_false_positive_consistent_order():
                 with b:
                     pass
         assert san.violation_counts() == base
+
+
+class _Reentered:
+    """Stands in for the graph's lock and runs ``finaliser`` each time the
+    lock has been taken: what the collector does when it runs a
+    ``__del__`` that takes a sanitized lock on a thread that is inside
+    the section."""
+
+    def __init__(self, finaliser):
+        self._lock = threading.Lock()
+        self._finaliser = finaliser
+        self.entered = 0
+
+    def __enter__(self):
+        self._lock.acquire()
+        self.entered += 1
+        self._finaliser()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
+class _TakesLockWhenCollected:
+    """The real shape: garbage in a reference cycle whose ``__del__`` takes
+    a sanitized lock (``ObjectRef.__del__``, ``CompiledDAGRef.__del__``)."""
+
+    def __init__(self, lock):
+        self._me = self
+        self._lock = lock
+
+    def __del__(self):
+        with self._lock:
+            pass
+
+
+def _collect_one(lock):
+    _TakesLockWhenCollected(lock)
+    gc.collect()
+
+
+def _once_under_format_stack(m, finaliser):
+    """Run ``finaliser`` inside the next ``traceback.format_stack`` call:
+    the allocation under which the parent's hang was seen."""
+    real = traceback.format_stack
+
+    def format_stack(*args, **kwargs):
+        m.setattr(traceback, "format_stack", real)
+        finaliser()
+        return real(*args, **kwargs)
+
+    m.setattr(traceback, "format_stack", format_stack)
+
+
+def test_nested_acquire_under_format_stack_keeps_its_edge(monkeypatch):
+    """A sanitized lock taken from inside ``format_stack`` (where a
+    finaliser can run) while another is held returns, and the edge it
+    makes is recorded."""
+    from ray_tpu.analysis import sanitizers as san
+
+    san.enable(True)
+    with san.scoped(drop_prefixes=("t.",)), monkeypatch.context() as m:
+        a, b, c = (san.SanitizedLock(n) for n in ("t.A", "t.B", "t.C"))
+        _once_under_format_stack(m, lambda: c.acquire() and c.release())
+        with time_limit(5, "nested acquire under format_stack"):
+            with a:
+                with b:
+                    pass
+        edges = san.lock_order_edges()
+        assert ("t.A", "t.B") in edges and ("t.A", "t.C") in edges
+
+
+def test_cycle_closed_by_a_nested_acquisition_is_reported_once(monkeypatch):
+    from ray_tpu.analysis import sanitizers as san
+
+    san.enable(True)
+    with san.scoped(drop_prefixes=("t.",)), monkeypatch.context() as m:
+        base = san.violation_counts().get("lock_order", 0)
+        a, c = san.SanitizedLock("t.A"), san.SanitizedLock("t.C")
+        with c:
+            with a:
+                pass
+
+        armed = []
+
+        def finaliser():
+            # once a round: record_violation takes sanitized locks of its
+            # own, and t.C under those would be other cycles
+            if armed:
+                armed.pop()
+                with c:    # while t.A is held: closes t.C -> t.A -> t.C
+                    pass
+
+        m.setattr(san, "_graph_lock", _Reentered(finaliser))
+        for _ in range(2):
+            armed.append(True)
+            with time_limit(5, "nested acquisition closing a cycle"):
+                with a:
+                    san.lock_order_edges()
+            assert san.violation_counts().get("lock_order", 0) == base + 1
+        v = san.violations("lock_order")[-1]
+        assert v["name"] == "t.C" and "'t.A'" in v["detail"]
+        assert len([s for s in v["stacks"] if s]) == 2
+
+
+@pytest.mark.parametrize("where", ["format_stack", "section"])
+def test_finaliser_run_by_the_collector_does_not_deadlock(where, monkeypatch):
+    """``gc.collect()`` under ``format_stack`` (the parent's hang) and
+    inside the section: the ``__del__`` takes its lock, returns, and its
+    edges are there afterwards."""
+    from ray_tpu.analysis import sanitizers as san
+
+    san.enable(True)
+    with san.scoped(drop_prefixes=("t.",)), monkeypatch.context() as m:
+        a, b, c = (san.SanitizedLock(n) for n in ("t.A", "t.B", "t.C"))
+        if where == "section":
+            m.setattr(san, "_graph_lock",
+                      _Reentered(lambda: _collect_one(c)))
+        else:
+            _once_under_format_stack(m, lambda: _collect_one(c))
+        with time_limit(5, f"collector under {where}"):
+            with a:
+                with b:
+                    pass
+        edges = san.lock_order_edges()
+        assert {("t.A", "t.B"), ("t.A", "t.C"), ("t.B", "t.C")} <= set(edges)
+
+
+@pytest.mark.parametrize(
+    "entry", ["acquire", "reset", "scoped", "lock_order_edges"])
+def test_graph_section_reentered_by_a_finaliser(entry, monkeypatch):
+    """Every way into the graph survives a finaliser that takes a
+    sanitized lock inside it, and the finaliser's edge is recorded once
+    the section is left — after a ``reset()`` too."""
+    from ray_tpu.analysis import sanitizers as san
+
+    san.enable(True)
+    with san.scoped(drop_prefixes=("t.", "u.")), \
+            monkeypatch.context() as m:
+        a, b, c = (san.SanitizedLock(n) for n in ("t.A", "t.B", "t.C"))
+
+        def finaliser():
+            with c:
+                pass
+
+        lock = _Reentered(finaliser)
+        m.setattr(san, "_graph_lock", lock)
+        with time_limit(5, f"{entry} re-entered"):
+            with a:
+                if entry == "acquire":
+                    with b:
+                        pass
+                elif entry == "reset":
+                    san.reset()
+                elif entry == "scoped":
+                    with san.scoped(drop_prefixes=("u.",)):
+                        pass
+                else:
+                    san.lock_order_edges()
+        assert lock.entered
+        m.undo()
+        assert ("t.A", "t.C") in san.lock_order_edges()
+        assert not a.locked() and not c.locked()
 
 
 def test_sanitized_condition_wait_notify():

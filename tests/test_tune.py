@@ -5,6 +5,8 @@ Parity model: tune/tests/ — scheduler simulations with mock trainables
 hyperparams across >= 8 concurrent trials and Tuner(JaxTrainer).fit() runs.
 """
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -135,9 +137,25 @@ class TestASHA:
 
 
 class TestPBT:
-    def test_exploit_mutates_and_clones(self, ray_start_regular):
-        """>= 8 concurrent trials; bottom trials must adopt top checkpoints
-        (score jumps to cloned total) and mutated hyperparams."""
+    def test_exploit_mutates_and_clones(self, ray_start_local, monkeypatch):
+        """8 concurrent trials, served in turn; bottom trials must adopt top
+        checkpoints (score jumps to cloned total) and mutated hyperparams."""
+        import ray_tpu
+
+        # The controller serves whichever trial reports first, and PBT can
+        # only exploit when a whole population is scored at an interval: on
+        # a loaded box one trial ran its ten iterations before a second had
+        # reported, and nothing was exploited. Here the refs are ready in
+        # turn, so the order is the same on every box.
+        turn = itertools.count()
+        first_ready = ray_tpu.wait
+
+        def ready_in_turn(refs, num_returns=1, timeout=None):
+            mine = refs[next(turn) % len(refs)]
+            first_ready([mine], num_returns=1, timeout=timeout)
+            return [mine], [r for r in refs if r is not mine]
+
+        monkeypatch.setattr(ray_tpu, "wait", ready_in_turn)
         scheduler = PopulationBasedTraining(
             perturbation_interval=2,
             hyperparam_mutations={"increment": [0.25, 0.5, 1.0, 2.0, 4.0]},
@@ -155,6 +173,7 @@ class TestPBT:
             run_config=_stop(training_iteration=10),
         )
         grid = tuner.fit()
+        assert grid.num_errors == 0
         assert scheduler.num_perturbations >= 1
         # at least one trial's config was mutated away from its grid value
         mutated = [

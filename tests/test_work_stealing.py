@@ -15,9 +15,9 @@ import pytest
 
 import ray_tpu
 
-# the fallback requeue timer is pinned HIGH so only stealing can rescue the
-# queued specs — the assertion below would fail on the timer alone
-_REQUEUE_MS = "5000"
+# the fallback requeue timer is pinned above every wait below, so only
+# stealing can move a queued spec off a worker whose task does not end
+_REQUEUE_MS = "60000"
 
 
 @pytest.fixture
@@ -38,70 +38,73 @@ def stealing_cluster():
     _config.worker_requeue_after_ms = saved_cfg
 
 
-def test_spec_queued_behind_blocked_worker_migrates(stealing_cluster):
-    """A spec committed to a busy worker completes on an idle one within
-    bounded time: far sooner than the blocker finishes (2s) and far sooner
-    than the requeue fallback (pinned at 5s)."""
+def _blocked_worker_and_burst(gate):
+    """A task that holds its worker's run slot until ``gate`` exists, and 12
+    quick tasks submitted once it runs: breadth-first placement stacks
+    roughly half of them behind it. Returns the blocker's ref (its value:
+    how many queued specs were stolen off its worker) and the burst's."""
 
     @ray_tpu.remote
     def blocker():
-        time.sleep(2.0)  # out-of-band block: holds the run slot throughout
-        return "blocked"
+        open(gate + ".running", "w").close()
+        # out-of-band block: never enters get_blocking, keeps the slot
+        deadline = time.monotonic() + 90
+        while not os.path.exists(gate) and time.monotonic() < deadline:
+            time.sleep(0.02)
+        from ray_tpu.api import _global_worker
+
+        return _global_worker().backend.core._exec_slot.steals
 
     @ray_tpu.remote
     def quick(i):
         return i
 
-    # warm the 2-worker pool so placement (not process spawn) is measured
+    # warm the 2-worker pool so the burst is placed, not spawned
     ray_tpu.get([quick.remote(i) for i in range(8)], timeout=60)
-
     b = blocker.remote()
-    time.sleep(0.1)  # the blocker takes its run slot
-    t0 = time.perf_counter()
-    # breadth-first placement stacks roughly half of these behind the
-    # blocker; stealing migrates them to the idle worker
-    out = ray_tpu.get([quick.remote(i) for i in range(12)], timeout=30)
-    dt = time.perf_counter() - t0
-    assert out == list(range(12))
-    assert dt < 1.5, (
-        f"quick tasks took {dt:.2f}s — queued specs were NOT stolen off "
-        "the blocked worker (blocker=2s, requeue fallback=5s)"
-    )
-    assert ray_tpu.get(b, timeout=30) == "blocked"
+    deadline = time.monotonic() + 60
+    while not os.path.exists(gate + ".running"):
+        assert time.monotonic() < deadline, "the blocker never started"
+        time.sleep(0.02)
+    return b, [quick.remote(i) for i in range(12)]
 
 
-def test_stealing_disabled_falls_back_to_requeue_timer(stealing_cluster):
-    """With stealing off, the same shape stalls until the blocker ends or
-    the requeue timer fires — the contrast that proves the steal (not
-    placement luck) rescued the queued specs above."""
+def test_spec_queued_behind_blocked_worker_migrates(stealing_cluster,
+                                                    tmp_path):
+    """Specs committed to a busy worker complete on the idle one while the
+    busy one's task is still running: with the requeue fallback out of
+    reach only a steal can do that, and the worker counts it."""
+    gate = str(tmp_path / "gate")
+    b, burst = _blocked_worker_and_burst(gate)
+    assert ray_tpu.get(burst, timeout=30) == list(range(12))
+    ready, _ = ray_tpu.wait([b], timeout=0)
+    assert not ready, "the blocker ended before the burst did"
+    open(gate, "w").close()
+    assert ray_tpu.get(b, timeout=30) >= 1, (
+        "the burst finished beside a blocked worker and nothing was stolen "
+        "off it: specs no longer queue behind the blocker, fix the shape")
+
+
+def test_stealing_disabled_falls_back_to_requeue_timer(stealing_cluster,
+                                                       tmp_path):
+    """With stealing off, the same burst cannot finish until the blocker
+    ends (or the requeue timer fires: pinned out of reach) — the contrast
+    that proves the steal, not placement luck, rescued the specs above."""
     from ray_tpu.core.config import _config
 
     os.environ["RAY_TPU_WORKER_STEALING_ENABLED"] = "0"
     saved = _config.worker_stealing_enabled
     _config.worker_stealing_enabled = False
     try:
-        @ray_tpu.remote
-        def blocker():
-            time.sleep(1.2)
-            return "blocked"
-
-        @ray_tpu.remote
-        def quick(i):
-            return i
-
-        ray_tpu.get([quick.remote(i) for i in range(8)], timeout=60)
-        b = blocker.remote()
-        time.sleep(0.1)
-        t0 = time.perf_counter()
-        out = ray_tpu.get([quick.remote(i) for i in range(12)], timeout=30)
-        dt = time.perf_counter() - t0
-        assert out == list(range(12))
-        # the queued half waits out the blocker (requeue pinned at 5s)
-        assert dt > 0.6, (
-            f"drain took only {dt:.2f}s with stealing OFF — the test no "
-            "longer queues specs behind the blocker, fix the shape"
-        )
-        assert ray_tpu.get(b, timeout=30) == "blocked"
+        gate = str(tmp_path / "gate")
+        b, burst = _blocked_worker_and_burst(gate)
+        ready, _ = ray_tpu.wait(burst, num_returns=len(burst), timeout=1.5)
+        assert len(ready) < len(burst), (
+            "the whole burst finished beside a blocked worker with stealing "
+            "OFF: specs no longer queue behind the blocker, fix the shape")
+        open(gate, "w").close()
+        assert ray_tpu.get(burst, timeout=30) == list(range(12))
+        assert ray_tpu.get(b, timeout=30) == 0
     finally:
         os.environ.pop("RAY_TPU_WORKER_STEALING_ENABLED", None)
         _config.worker_stealing_enabled = saved
