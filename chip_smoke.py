@@ -37,7 +37,8 @@ ALPHABET = 64                # tokens come from the first 64 ids: learning that
 LR, WARMUP = 6e-4, 4
 MIN_LOSS_DROP = 0.25         # of those nats, first step → mean of the last four
 # First-step agreement of the model's attention (compiled Pallas on the chip)
-# with attention_impl="xla" on the same batch, same chip. Both run bf16 matmuls with f32 accumulation
+# with attention_impl="xla", and of the model with itself under remat=True,
+# on the same batch, same chip. Both run bf16 matmuls with f32 accumulation
 # and differ in the order of the softmax/accumulate roundings; bf16's unit
 # roundoff is 2^-9. The loss is a mean over thousands of tokens, the gradient
 # norm a root of a sum over every parameter, so a few roundoffs bound each.
@@ -87,6 +88,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     import numpy as np
 
     from ray_tpu import train
+    from ray_tpu.models.gpt2 import remat_policy_decisions
     from ray_tpu.ops.attention import flash_tiling_decisions, resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
     from ray_tpu.train.train_step import default_optimizer, make_gpt2_train_step
@@ -160,12 +162,16 @@ def train_loop(config: Dict[str, Any]) -> None:
         {k: np.asarray(v[:parity_rows]) for k, v in first_batch.items()},
         data_sharding,
     )
+    # ... and with the same model under remat=True, which also leaves the
+    # model/remat_policy decision for this chip in the summary.
     parity = {}
-    for impl in (cfg.attention_impl, "xla"):
-        variant = build(replace(cfg, attention_impl=impl))
+    for key, model_cfg in ((cfg.attention_impl, cfg),
+                           ("xla", replace(cfg, attention_impl="xla")),
+                           ("remat", replace(cfg, remat=True))):
+        variant = build(model_cfg)
         _, m = variant.step_fn(variant.state, parity_batch)
-        parity[impl] = {"loss": float(m["loss"]),
-                        "grad_norm": float(m["grad_norm"])}
+        parity[key] = {"loss": float(m["loss"]),
+                       "grad_norm": float(m["grad_norm"])}
         del variant
     jax.monitoring.unregister_event_listener(on_event)
 
@@ -181,6 +187,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         "tpu_custom_calls": tpu_calls,
         "attention_call_shapes": attn_shapes,
         "flash_tiling": flash_tiling_decisions(),
+        "remat_policy": remat_policy_decisions(),
         "global_batch": global_batch,
         "epochs": epochs,
         "backend_seconds": backend_seconds,
@@ -246,11 +253,16 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
     if not last < first - need:
         bad.append(f"loss did not fall: {first:.4f} -> {last:.4f} "
                    f"(needs {need:.2f} nats)")
-    model, xla = (summary["parity"][i] for i in (model_cfg.attention_impl, "xla"))
-    for name, rtol in (("loss", LOSS_RTOL), ("grad_norm", GRAD_NORM_RTOL)):
-        if not abs(model[name] - xla[name]) <= rtol * abs(xla[name]):
-            bad.append(f"first-step {name} {model[name]!r} disagrees with "
-                       f"attention_impl='xla' {xla[name]!r} beyond rtol {rtol:g}")
+    model = summary["parity"][model_cfg.attention_impl]
+    for other, what in (("xla", "attention_impl='xla'"), ("remat", "remat=True")):
+        for name, rtol in (("loss", LOSS_RTOL), ("grad_norm", GRAD_NORM_RTOL)):
+            got = summary["parity"][other][name]
+            if not abs(model[name] - got) <= rtol * abs(got):
+                bad.append(f"first-step {name} {model[name]!r} disagrees with "
+                           f"{what} {got!r} beyond rtol {rtol:g}")
+    # the model/remat_policy decision the remat=True step was traced with
+    if not summary["remat_policy"]:
+        bad.append("the remat=True step recorded no remat policy decision")
     return bad
 
 
@@ -331,6 +343,13 @@ def main() -> int:
               f"Skv={d['Skv']} hd={d['hd']} -> block_q={d['block_q']} "
               f"block_k={d['block_k']} vmem_estimate="
               f"{d['vmem_estimate'] / 2 ** 20:.2f} MiB")
+    gib = 2.0 ** 30
+    for d in summary["remat_policy"]:
+        print(f"remat policy: n_layer={d['n_layer']} batch={d['batch']} "
+              f"seq={d['seq']} -> saved {d['saved'] or 'block inputs only'}, "
+              f"{d['saved_bytes'] / gib:.2f} GiB of a budget of "
+              f"{d['budget_bytes'] / gib:.2f} (bytes_limit "
+              f"{d['bytes_limit'] / gib:.2f} GiB)")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")
@@ -339,9 +358,10 @@ def main() -> int:
     print("step wall seconds (host-synchronised every step; not a rate): "
           + " ".join(f"{r['seconds']:.2f}" for r in rows[:-1]))
     print(f"first-step parity on {summary['parity_rows']} rows: "
-          + "; ".join(f"attention_impl={impl!r} loss={m['loss']:.5f} "
-                      f"grad_norm={m['grad_norm']:.5f}"
-                      for impl, m in summary["parity"].items())
+          + "; ".join(("remat=True" if key == "remat"
+                       else f"attention_impl={key!r}")
+                      + f" loss={m['loss']:.5f} grad_norm={m['grad_norm']:.5f}"
+                      for key, m in summary["parity"].items())
           + f" (rtol {LOSS_RTOL:g} / {GRAD_NORM_RTOL:g})")
     print(f"epochs over the {DATASET_BATCHES}-batch dataset: {summary['epochs']}")
     if failures:

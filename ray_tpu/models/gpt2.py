@@ -16,16 +16,19 @@ data-parallel pretraining, tokens/sec/chip). TPU-first choices:
 
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.tracing import names as scopes
+from ray_tpu.tracing import get_buffer, names as scopes
 
 
 def _round_up(x: int, m: int) -> int:
@@ -43,12 +46,12 @@ class GPT2Config:
     dtype: Any = jnp.bfloat16     # activation/compute dtype
     param_dtype: Any = jnp.float32
     # Rematerialization of each block (memory/FLOPs trade):
-    #   False  — save all residuals (fastest, most HBM)
-    #   True   — full block remat (one extra forward, least HBM)
-    #   "dots" — policy remat: keep matmul outputs, recompute elementwise ops
-    #            (layernorm f32 stats, gelu) — near-False FLOPs at a fraction
-    #            of the residual memory
-    remat: Any = False
+    #   False — save all residuals (fastest, most HBM)
+    #   True  — recompute what does not fit: each block keeps its input and
+    #           whichever of its named residuals choose_remat_policy finds
+    #           room for on this chip (none where the device states no
+    #           memory limit: one extra forward, least HBM)
+    remat: bool = False
     attention_impl: str = "auto"  # auto | xla | pallas | ring
     use_bias: bool = True
     # scan over layers (True: compact HLO, one traced block) vs an unrolled
@@ -85,10 +88,8 @@ class GPT2Config:
             )
         if self.attention_impl not in ("auto", "xla", "pallas", "ring"):
             raise ValueError(f"unknown attention_impl {self.attention_impl!r}")
-        if not (isinstance(self.remat, bool) or self.remat == "dots"):
-            raise ValueError(
-                f"remat must be True, False, or 'dots'; got {self.remat!r}"
-            )
+        if not isinstance(self.remat, bool):
+            raise ValueError(f"remat must be True or False; got {self.remat!r}")
         if self.moe_experts < 0:
             raise ValueError("moe_experts must be >= 0")
         if self.moe_experts > 0:
@@ -297,13 +298,16 @@ def _block(x, layer_params, cfg: GPT2Config):
     with jax.named_scope(scopes.QKV):
         w, b = p["qkv_w"].astype(dt), p["qkv_b"].astype(dt)
         q, k, v = (
-            jnp.einsum("bsd,dhk->bhsk", h, w[:, i]) + b[i][None, :, None, :]
-            for i in range(3)
+            checkpoint_name(
+                jnp.einsum("bsd,dhk->bhsk", h, w[:, i]) + b[i][None, :, None, :],
+                name)
+            for i, name in enumerate((scopes.RES_Q, scopes.RES_K, scopes.RES_V))
         )
     with jax.named_scope(scopes.ATTN):
         attn = _attention(q, k, v, cfg)
     with jax.named_scope(scopes.PROJ):
         x = x + jnp.einsum("bhsk,hkd->bsd", attn, p["proj_w"].astype(dt)) + p["proj_b"].astype(dt)
+        x = checkpoint_name(x, scopes.RES_MID)
     with jax.named_scope(scopes.LN2):
         h = _layernorm(x, p["ln2_scale"], p["ln2_bias"])
     if cfg.moe_experts > 0:
@@ -318,19 +322,204 @@ def _block(x, layer_params, cfg: GPT2Config):
         return (x, (aux_in if aux_in is not None else 0.0) + aux)
     with jax.named_scope(scopes.MLP):
         h = jnp.einsum("bsd,df->bsf", h, p["fc_w"].astype(dt)) + p["fc_b"].astype(dt)
-        h = jax.nn.gelu(h, approximate=True)
+        h = jax.nn.gelu(checkpoint_name(h, scopes.RES_MLP_HIDDEN), approximate=True)
         x = x + jnp.einsum("bsf,fd->bsd", h, p["out_w"].astype(dt)) + p["out_b"].astype(dt)
     return x if aux_in is None else (x, aux_in)
 
 
-def _make_block_fn(cfg: GPT2Config):
+# --------------------------------------------------------------------------- #
+# Remat: what a block keeps for its backward
+# --------------------------------------------------------------------------- #
+
+class BlockShard(NamedTuple):
+    """One chip's share of a step, in elements: global shapes ÷ the mesh axes
+    that split them. Everything the remat rule computes, it computes from
+    this and n_layer."""
+    batch: int            # rows of the batch on this chip
+    seq: int
+    d_model: int
+    heads: int            # attention heads on this chip
+    head_dim: int
+    d_ff: int             # MLP hidden width on this chip
+    vocab: int            # LM-head columns on this chip
+    dtype_bytes: int      # of an activation
+    flash: bool           # attention is the flash kernel: its o and lse exist
+    dense_mlp: bool       # the MLP is the dense one: its hidden tensor exists
+
+
+class RematPolicy(NamedTuple):
+    saved: Tuple[str, ...]   # names.RESIDUALS a block keeps, in the order taken
+    saved_bytes: int         # what they take on a chip, over n_layer layers
+    budget_bytes: int        # what was free for them (0: no limit is known)
+    bytes_limit: int         # the chip's own figure the budget came from, or 0
+
+
+# what the rule leaves free: the benchmark's fit rule keeps the same
+# (benchmarks/README.md), and the working-set arithmetic below is an estimate
+REMAT_RESERVE_BYTES = 2 ** 30
+_MXU = 128                   # a matmul dim below this still costs a full pass
+
+class _Observed(threading.local):
+    memory: Tuple[Optional[int], int] = (None, 0)   # outside chip_memory
+
+
+_local = _Observed()
+_decisions: Dict[tuple, Dict[str, Any]] = {}
+
+
+@contextlib.contextmanager
+def chip_memory(bytes_limit: Optional[int], resident_bytes: int):
+    """What the step factory observed of the chip its step will run on,
+    active while the step is traced (as parallel.mesh.use_mesh is):
+    ``bytes_limit`` the device's ``memory_stats()["bytes_limit"]`` (None where
+    it states none — the CPU backend), ``resident_bytes`` what a chip holds
+    through the whole step besides activations: the placed state, and the
+    gradients (the parameters' bytes again). Outside it the rule knows no
+    limit and ``remat=True`` keeps only each block's input."""
+    prev = _local.memory
+    _local.memory = (bytes_limit, resident_bytes)
+    try:
+        yield
+    finally:
+        _local.memory = prev
+
+
+def block_shard(cfg: GPT2Config, global_batch: int, seq: int, mesh,
+                flash: bool) -> BlockShard:
+    """cfg's block on one chip of ``mesh``: batch over the data axes that
+    divide it, heads / MLP width / vocab over tp, the sequence over cp."""
+    from ray_tpu.ops.attention import batch_head_axes
+
+    heads, d_ff, vocab = cfg.n_head, cfg.d_ff, cfg.padded_vocab
+    batch = global_batch
+    if mesh is not None:
+        batch_axes, head_ax = batch_head_axes(mesh, global_batch, heads)
+        for ax in batch_axes or ():
+            batch //= mesh.shape[ax]
+        tp, cp = mesh.shape.get("tp", 1), mesh.shape.get("cp", 1)
+        if head_ax:
+            heads //= tp
+        if d_ff % tp == 0:
+            d_ff //= tp
+        if vocab % tp == 0:
+            vocab //= tp
+        if seq % cp == 0:
+            seq //= cp
+    return BlockShard(
+        batch=batch, seq=seq, d_model=cfg.d_model, heads=heads,
+        head_dim=cfg.head_dim, d_ff=d_ff, vocab=vocab,
+        dtype_bytes=jnp.dtype(cfg.dtype).itemsize, flash=flash,
+        dense_mlp=cfg.moe_experts == 0,
+    )
+
+
+def remat_candidates(s: BlockShard) -> List[Tuple[Tuple[str, ...], int, int]]:
+    """The block's named residuals as (names kept together, bytes a layer,
+    FLOPs a layer to recompute them), most FLOPs per byte first; equal ones
+    stay in the block's own order. A matmul output of width N contracted over
+    K costs 2·K·N a row and holds N elements, so the qkv, proj and fc outputs
+    all come to K FLOPs per element; the flash kernel's o comes to about
+    2·S per element (causal: half of two S×S matmuls, whose head_dim side
+    fills the MXU only from 128 up), so it leads at long sequences and
+    trails at short ones. lse goes with o: neither is of use alone."""
+    tokens = s.batch * s.seq
+    a = s.dtype_bytes
+    attn_width = s.heads * s.head_dim
+    qkv_flops = 2 * tokens * s.d_model * attn_width
+    out = [((name,), tokens * attn_width * a, qkv_flops)
+           for name in (scopes.RES_Q, scopes.RES_K, scopes.RES_V)]
+    if s.flash:
+        out.append((
+            (scopes.RES_FLASH_O, scopes.RES_FLASH_LSE),
+            tokens * s.heads * (s.head_dim * a + 4),
+            2 * s.batch * s.heads * s.seq * s.seq * max(s.head_dim, _MXU),
+        ))
+    out.append(((scopes.RES_MID,), tokens * s.d_model * a,
+                2 * tokens * attn_width * s.d_model))
+    if s.dense_mlp:
+        out.append(((scopes.RES_MLP_HIDDEN,), tokens * s.d_ff * a,
+                    2 * tokens * s.d_model * s.d_ff))
+    return sorted(out, key=lambda c: -c[2] / c[1])
+
+
+def rematted_working_set(s: BlockShard, n_layer: int) -> int:
+    """Bytes of activations a chip needs for a step whose blocks keep only
+    their inputs, as the rule counts them: the stack of block inputs; the LM
+    head's logits, their gradient and one float32 copy inside the softmax;
+    one block's whole residual set, live while its backward runs; the
+    largest parameter (the embedding) gathered in the compute dtype beside
+    its unreduced float32 gradient. An estimate from shapes — XLA's schedule
+    decides the real figure (PERF.md §6, PR 28: from 0.08 GiB under at the
+    cells' shapes to 8 over, compiled for a v5e) — which is what the reserve
+    is for."""
+    tokens = s.batch * s.seq
+    a = s.dtype_bytes
+    stack = n_layer * tokens * s.d_model * a
+    head = tokens * s.vocab * (2 * a + 4)
+    block = tokens * a * (4 * s.d_model + 4 * s.heads * s.head_dim + 2 * s.d_ff)
+    gathered = s.vocab * s.d_model * (a + 4)
+    return stack + head + block + gathered
+
+
+def choose_remat_policy(shard: BlockShard, n_layer: int,
+                        bytes_limit: Optional[int],
+                        resident_bytes: int) -> RematPolicy:
+    """THE rule for what ``remat=True`` keeps besides each block's input: walk
+    remat_candidates (most recompute FLOPs per byte first) and take each
+    whose n_layer copies still fit what the chip has free — its bytes_limit
+    less the reserve, what is resident (state and gradients) and the fully
+    rematted step's working set. With no limit stated, nothing."""
+    if bytes_limit is None:
+        return RematPolicy((), 0, 0, 0)
+    budget = max(0, bytes_limit - REMAT_RESERVE_BYTES - resident_bytes
+                 - rematted_working_set(shard, n_layer))
+    saved, used = [], 0
+    for group, nbytes, _ in remat_candidates(shard):
+        if used + n_layer * nbytes <= budget:
+            saved.extend(group)
+            used += n_layer * nbytes
+    return RematPolicy(tuple(saved), used, budget, bytes_limit)
+
+
+def remat_policy_decisions() -> List[Dict[str, Any]]:
+    """Every distinct remat decision this process has traced a model with, as
+    the ``model/remat_policy`` events carry them."""
+    return list(_decisions.values())
+
+
+def _remat_policy(cfg: GPT2Config, global_batch: int, seq: int, mesh,
+                  n_layer: int) -> RematPolicy:
+    """choose_remat_policy for the step being traced, recorded. A static
+    choice has no hit rate; its counter is the choice: each distinct one goes
+    once, as an instant event, to the task-event buffer
+    (→ ``ray_tpu.timeline()``)."""
+    from ray_tpu.ops.attention import resolve_attention
+
+    flash = resolve_attention(cfg.attention_impl, mesh)[0] == "pallas"
+    shard = block_shard(cfg, global_batch, seq, mesh, flash)
+    policy = choose_remat_policy(shard, n_layer, *_local.memory)
+    args = dict(zip(scopes.REMAT_POLICY_ARGS,
+                    (n_layer, shard.batch, shard.seq, list(policy.saved))
+                    + policy[1:]))
+    key = (n_layer, shard) + policy
+    if key not in _decisions:
+        _decisions[key] = args
+        component, name = scopes.REMAT_POLICY.split("/")
+        get_buffer().record_profile(name, component=component, args=args)
+    return policy
+
+
+def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
+                   n_layer: int):
+    """One block as the layer loops call it; n_layer is how many of them one
+    chip runs (a pipeline stage's share under pp)."""
     block_fn = partial(_block, cfg=cfg)
-    if cfg.remat == "dots":
+    if cfg.remat:
+        policy = _remat_policy(cfg, global_batch, seq, mesh, n_layer)
         block_fn = jax.checkpoint(
-            block_fn, policy=jax.checkpoint_policies.checkpoint_dots
+            block_fn,
+            policy=jax.checkpoint_policies.save_only_these_names(*policy.saved),
         )
-    elif cfg.remat:
-        block_fn = jax.checkpoint(block_fn, static_argnums=())
     return block_fn
 
 
@@ -347,8 +536,10 @@ def _blocks_pipelined(blocks, x, cfg: GPT2Config, mesh, pp: int):
     if cfg.n_layer % pp:
         raise ValueError(f"n_layer={cfg.n_layer} not divisible by pp={pp}")
     M = cfg.pipeline_microbatches or pp
-    block_fn = _make_block_fn(cfg)
     lpp = cfg.n_layer // pp
+    # every microbatch of the batch is in flight at once: lpp layers of the
+    # whole batch is what a stage's chips keep
+    block_fn = _make_block_fn(cfg, x.shape[0], x.shape[1], mesh, lpp)
     stage_params = stages_from_layers(blocks, pp)
 
     def stage_fn(layers, h):
@@ -384,7 +575,7 @@ def _trunk(params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config) -> jax.Ar
         x = _blocks_pipelined(params["blocks"], x, cfg, mesh, pp)
         return _ln_f(x, params), jnp.zeros((), jnp.float32)
 
-    block_fn = _make_block_fn(cfg)
+    block_fn = _make_block_fn(cfg, B, S, mesh, cfg.n_layer)
     if cfg.moe_experts > 0:
         x = (x, jnp.zeros((), jnp.float32))  # thread the aux loss
     if cfg.scan_layers:

@@ -46,6 +46,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -522,6 +523,10 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
+    # the kernel's two residuals by name: a checkpoint policy that keeps both
+    # does not run the forward kernel a second time in the backward
+    o = checkpoint_name(o, names.RES_FLASH_O)
+    lse = checkpoint_name(lse, names.RES_FLASH_LSE)
     return (o if bhsd else _to_bhsd(o)), (qt, kt, vt, o, lse)
 
 

@@ -39,6 +39,23 @@ FLASH_TILING = "ops/flash_tiling"
 FLASH_TILING_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
                      "vmem_estimate")
 
+# the block's residuals a `remat=True` checkpoint may keep, one name a tensor
+# (`jax.ad_checkpoint.checkpoint_name`; an identity outside such a checkpoint):
+# the three qkv einsums' outputs, the flash forward kernel's output and
+# logsumexp (tagged in ops/attention._flash_fwd, where lse exists), x after
+# the attention residual add, the MLP's hidden pre-activation
+RES_Q, RES_K, RES_V = "block_q", "block_k", "block_v"
+RES_FLASH_O, RES_FLASH_LSE = "flash_o", "flash_lse"
+RES_MID = "block_mid"
+RES_MLP_HIDDEN = "mlp_hidden"
+RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
+             RES_MLP_HIDDEN)
+# which of them models/gpt2.py chose to save: one instant event per distinct
+# decision, at trace time, in the task-event buffer
+REMAT_POLICY = "model/remat_policy"
+REMAT_POLICY_ARGS = ("n_layer", "batch", "seq", "saved", "saved_bytes",
+                     "budget_bytes", "bytes_limit")
+
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer
 SPAN_PREFIX = "ray_tpu:"

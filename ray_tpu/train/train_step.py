@@ -9,6 +9,7 @@ over a named mesh, with buffers donated so params update in place in HBM.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -159,12 +160,14 @@ def make_gpt2_train_step(
     }
 
     data_sh = mesh_lib.data_sharding(mesh, extra_dims=1)
+    memory = _chip_memory(mesh, state)
 
     def step(state, batch):
         tokens, targets = batch["tokens"], batch["targets"]
         # use_mesh: active during tracing so the model can reach the mesh
-        # (ring attention wraps a shard_map over it).
-        with mesh_lib.use_mesh(mesh):
+        # (ring attention wraps a shard_map over it); chip_memory: so its
+        # remat rule knows what the chip has free.
+        with mesh_lib.use_mesh(mesh), gpt2.chip_memory(*memory):
             loss, grads = jax.value_and_grad(gpt2.loss_fn)(
                 state["params"], tokens, targets, cfg
             )
@@ -206,6 +209,23 @@ def _apply_optimizer(optimizer, grads, state):
     )
     new_params = optax.apply_updates(state["params"], updates)
     return new_params, new_opt, optax.global_norm(grads)
+
+
+def _chip_memory(mesh: Mesh, state) -> Tuple[Optional[int], int]:
+    """(bytes_limit, resident bytes) of one chip for a step over the placed
+    ``state``: the smallest limit this process's devices of the mesh report
+    (None when one reports none: the CPU backend), and what stays on a chip
+    through the whole step — its shard of every state leaf, and of the
+    gradients, which are the parameters' bytes again."""
+    def on_chip(tree):
+        return sum(
+            math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+            for x in jax.tree.leaves(tree))
+
+    stats = [d.memory_stats() for d in mesh.local_devices]
+    limits = [s.get("bytes_limit") if s else None for s in stats]
+    limit = None if None in limits else min(limits)
+    return limit, on_chip(state) + on_chip(state["params"])
 
 
 def _step_counter(mesh: Mesh) -> jax.Array:

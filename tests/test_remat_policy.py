@@ -1,0 +1,372 @@
+"""`remat=True` recomputes what does not fit (PR 28): the rule as a pure
+function of the shard and the chip's free bytes, the named residuals a
+policy-`checkpoint` keeps (scanned, unrolled, pipelined), the tags' cost with
+`remat=False` (none), and the `model/remat_policy` event.
+
+All on the CPU: the Pallas kernels interpret, the chip's memory is stated by
+the test through `gpt2.chip_memory`, as the step factory states it on a TPU.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu import tracing
+from ray_tpu.core.config import _config
+from ray_tpu.models import gpt2
+from ray_tpu.ops import attention
+from ray_tpu.parallel import mesh as mesh_lib
+from ray_tpu.tracing import names
+from ray_tpu.train import train_step
+from ray_tpu.train.train_step import make_gpt2_train_step, synthetic_batch
+
+GIB = 2 ** 30
+V5E_BYTES_LIMIT = 16909336064          # memory_stats()["bytes_limit"] of a v5e chip
+
+# one chip's share of `gpt2-xl.fsdp4-dataset` (8 rows of the 32) and of a
+# `gpt2-124m` step with the same rows
+XL = gpt2.BlockShard(batch=8, seq=1024, d_model=1600, heads=25, head_dim=64,
+                     d_ff=6400, vocab=50304, dtype_bytes=2, flash=True,
+                     dense_mlp=True)
+XL_LAYERS = 48
+# its placed state (f32 parameters, two bf16 moments) and the gradients, a chip
+XL_RESIDENT = 4_677_897_000
+SMALL = XL._replace(d_model=768, heads=12, d_ff=3072)
+SMALL_LAYERS = 12
+SMALL_RESIDENT = 1_493_700_000
+
+FLASH = (names.RES_FLASH_O, names.RES_FLASH_LSE)
+QKV = (names.RES_Q, names.RES_K, names.RES_V)
+EVERYTHING = FLASH + QKV + (names.RES_MID, names.RES_MLP_HIDDEN)
+
+
+def _limit_admitting(shard, n_layer, resident, groups):
+    """The smallest bytes_limit whose budget holds the first `groups`
+    candidates of `shard`."""
+    need = sum(n_layer * nbytes
+               for _, nbytes, _ in gpt2.remat_candidates(shard)[:groups])
+    return (gpt2.REMAT_RESERVE_BYTES + resident
+            + gpt2.rematted_working_set(shard, n_layer) + need)
+
+
+RULE_CASES = {
+    # the cell on the chip it runs on: q, k and v fit beside o and lse, the
+    # block's mid-point does not
+    "xl_at_15.75GiB": (XL, XL_LAYERS, V5E_BYTES_LIMIT, XL_RESIDENT, FLASH + QKV),
+    "xl_room_for_o_lse_only": (
+        XL, XL_LAYERS, _limit_admitting(XL, XL_LAYERS, XL_RESIDENT, 1) + 1000,
+        XL_RESIDENT, FLASH),
+    "xl_nothing_free": (
+        XL, XL_LAYERS, _limit_admitting(XL, XL_LAYERS, XL_RESIDENT, 0),
+        XL_RESIDENT, ()),
+    "xl_over_full": (XL, XL_LAYERS, 4 * GIB, XL_RESIDENT, ()),
+    "xl_no_limit_reported": (XL, XL_LAYERS, None, XL_RESIDENT, ()),
+    "gpt2_124m_shard": (SMALL, SMALL_LAYERS, V5E_BYTES_LIMIT, SMALL_RESIDENT,
+                        EVERYTHING),
+}
+
+
+@pytest.mark.parametrize("case", list(RULE_CASES))
+def test_rule_takes_names_in_order_while_they_fit(case):
+    shard, n_layer, limit, resident, want = RULE_CASES[case]
+    policy = gpt2.choose_remat_policy(shard, n_layer, limit, resident)
+    assert policy.saved == want
+    candidates = gpt2.remat_candidates(shard)
+    sizes = {group: n_layer * nbytes for group, nbytes, _ in candidates}
+    taken = [g for g in sizes if set(g) <= set(policy.saved)]
+    # the order of the list, and the bytes of exactly what was taken
+    assert policy.saved == tuple(n for g in taken for n in g)
+    assert policy.saved_bytes == sum(sizes[g] for g in taken)
+    assert policy.saved_bytes <= policy.budget_bytes
+    if limit is None:
+        assert policy[1:] == (0, 0, 0)
+        return
+    assert policy.bytes_limit == limit
+    # reserve respected: everything counted still leaves it free ...
+    counted = (resident + gpt2.rematted_working_set(shard, n_layer)
+               + policy.saved_bytes)
+    assert counted + gpt2.REMAT_RESERVE_BYTES <= limit or not policy.saved
+    # ... and nothing that was left out would have fitted
+    for g, size in sizes.items():
+        if g not in taken:
+            assert policy.saved_bytes + size > policy.budget_bytes
+
+
+def test_candidates_are_ordered_by_recompute_flops_per_byte():
+    """From the shapes: the flash kernel's o leads at S = 1,024 (about 2·S
+    FLOPs an element against d_model for a matmul's output) and trails once
+    the sequence is short against the width; equal ones keep the block's
+    order; lse rides with o."""
+    for shard in (XL, SMALL):
+        cands = gpt2.remat_candidates(shard)
+        assert [g for g, _, _ in cands] == [
+            FLASH, (names.RES_Q,), (names.RES_K,), (names.RES_V,),
+            (names.RES_MID,), (names.RES_MLP_HIDDEN,)]
+        ratios = [flops / nbytes for _, nbytes, flops in cands]
+        assert ratios == sorted(ratios, reverse=True)
+    short = gpt2.remat_candidates(XL._replace(seq=256))
+    assert short[-1][0] == FLASH
+    # bytes from the shapes: bf16 [8, 25, 1024, 64] and f32 [8, 25, 1024]
+    by_name = {g: nbytes for g, nbytes, _ in gpt2.remat_candidates(XL)}
+    assert by_name[(names.RES_Q,)] == 8 * 25 * 1024 * 64 * 2
+    assert by_name[FLASH] == 8 * 25 * 1024 * (64 * 2 + 4)
+    assert by_name[(names.RES_MLP_HIDDEN,)] == 8 * 1024 * 6400 * 2
+    # a tensor that does not exist is no candidate
+    no_flash = gpt2.remat_candidates(XL._replace(flash=False, dense_mlp=False))
+    assert [g for g, _, _ in no_flash] == [
+        (names.RES_Q,), (names.RES_K,), (names.RES_V,), (names.RES_MID,)]
+
+
+def test_block_shard_divides_by_the_mesh_axes_that_split(cpu_mesh8):
+    cfg = gpt2.gpt2_tiny()
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(fsdp=2, tp=2, dp=2), cpu_mesh8)
+    s = gpt2.block_shard(cfg, 8, cfg.seq_len, mesh, True)
+    assert (s.batch, s.heads, s.d_ff, s.vocab) == (
+        2, cfg.n_head // 2, cfg.d_ff // 2, cfg.padded_vocab // 2)
+    assert (s.seq, s.d_model, s.head_dim, s.dtype_bytes) == (
+        cfg.seq_len, cfg.d_model, cfg.head_dim, 2)
+    whole = gpt2.block_shard(cfg, 8, cfg.seq_len, None, False)
+    assert (whole.batch, whole.heads, whole.flash) == (8, cfg.n_head, False)
+
+
+# ------------------------------------------------- the checkpoint, end to end
+LAYER_LOOPS = {
+    "scan": dict(),
+    "unrolled": dict(scan_layers=False),
+    "pp2": dict(pipeline_microbatches=2),
+}
+BATCH = 4
+
+
+def _kernel_calls(jaxpr, name, times=1):
+    """Calls of the Pallas kernel `name` one evaluation of `jaxpr` makes: a
+    scan's body counts once an iteration, a sub-jaxpr once a reference."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and name in str(eqn.params.get("name", ""))):
+            n += times
+        inner = times * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else times
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _kernel_calls(sub, name, inner)
+    return n
+
+
+def _decision(n_layer, batch, bytes_limit):
+    """The one recorded decision for these facts (a repeated decision is
+    recorded once a process, so the newest need not be this trace's)."""
+    (d,) = [d for d in gpt2.remat_policy_decisions()
+            if (d["n_layer"], d["batch"], d["bytes_limit"])
+            == (n_layer, batch, bytes_limit)]
+    return d
+
+
+_runs = {}
+
+
+def _run(loop, remat, admits, devices):
+    """(loss, grads, forward kernel calls of loss-and-gradient, the decision)
+    of gpt2_tiny with the interpreted flash kernels; `admits` is how many
+    candidate groups the stated chip has room for (None: it states nothing)."""
+    key = (loop, remat, admits)
+    if key in _runs:
+        return _runs[key]
+    cfg = gpt2.gpt2_tiny(remat=remat, attention_impl="pallas",
+                         **LAYER_LOOPS[loop])
+    mesh = (mesh_lib.make_mesh(mesh_lib.MeshSpec(pp=2), devices[:2])
+            if loop == "pp2" else None)
+    n_layer = cfg.n_layer // 2 if loop == "pp2" else cfg.n_layer
+    limit = None
+    if admits is not None:
+        shard = gpt2.block_shard(cfg, BATCH, cfg.seq_len, mesh, True)
+        limit = _limit_admitting(shard, n_layer, 0, admits) + 8
+    params = gpt2.init(cfg, jax.random.PRNGKey(0))
+    batch = synthetic_batch(cfg, BATCH)
+
+    def loss(p):
+        with mesh_lib.use_mesh(mesh), gpt2.chip_memory(limit, 0):
+            return gpt2.loss_fn(p, batch["tokens"], batch["targets"], cfg)
+
+    fn = jax.value_and_grad(loss)
+    calls = _kernel_calls(jax.make_jaxpr(fn)(params).jaxpr,
+                          names.FLASH_FWD_KERNEL)
+    value, grads = jax.jit(fn)(params)
+    decision = _decision(n_layer, BATCH, limit or 0) if remat else None
+    _runs[key] = (float(value), grads, calls, decision)
+    return _runs[key]
+
+
+@pytest.mark.parametrize("loop", list(LAYER_LOOPS))
+def test_saved_o_and_lse_spare_the_second_forward_kernel_call(loop, cpu_mesh8):
+    """With room for o + lse a block's backward has the named tensors and
+    does not run the forward kernel again: one call a layer where whole-block
+    remat makes two."""
+    n_layer = gpt2.gpt2_tiny().n_layer
+    _, _, calls_off, _ = _run(loop, False, None, cpu_mesh8)
+    _, _, calls_whole, whole = _run(loop, True, None, cpu_mesh8)
+    _, _, calls_fit, fit = _run(loop, True, 1, cpu_mesh8)
+    # a chip runs every layer once; a pipeline stage runs its half of them
+    # in each of the schedule's microbatches + stages - 1 ticks
+    once = (2 + 2 - 1) * n_layer // 2 if loop == "pp2" else n_layer
+    assert calls_off == once
+    assert calls_whole == 2 * once
+    assert calls_fit == once
+    assert whole["saved"] == [] and whole["bytes_limit"] == 0
+    assert tuple(fit["saved"]) == FLASH
+    assert 0 < fit["saved_bytes"] <= fit["budget_bytes"]
+
+
+@pytest.mark.parametrize("loop", list(LAYER_LOOPS))
+def test_loss_and_every_gradient_equal_whatever_is_saved(loop, cpu_mesh8):
+    """remat=False, today's whole-block remat, o + lse saved, everything
+    saved: the same loss and gradients (bf16 activations, as the cells run).
+    Scanned and pipelined they are equal bit for bit; unrolled, XLA fuses the
+    twelve-fold inlined program differently with and without the checkpoint's
+    barriers, so there the bound is bf16's roundoff on each leaf's largest
+    entry, four times over."""
+    want_loss, want, _, _ = _run(loop, False, None, cpu_mesh8)
+    for admits in (None, 1, 6):
+        loss, grads, _, decision = _run(loop, True, admits, cpu_mesh8)
+        if admits == 6:
+            assert tuple(decision["saved"]) == EVERYTHING
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(want)):
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            if loop == "unrolled":
+                np.testing.assert_allclose(
+                    g, w, rtol=0, atol=2.0 ** -6 * np.abs(w).max(),
+                    err_msg=jax.tree_util.keystr(path))
+            else:
+                np.testing.assert_array_equal(
+                    g, w, err_msg=jax.tree_util.keystr(path))
+        assert loss == pytest.approx(
+            want_loss, rel=2.0 ** -8 if loop == "unrolled" else 0, abs=0)
+
+
+def test_named_residuals_are_what_the_checkpoint_saves():
+    """`saved_residuals` of one checkpointed block: with room for o + lse
+    they are what it keeps beside its arguments, q, k and v are not; with no
+    room it keeps its arguments alone."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    cfg = gpt2.gpt2_tiny(remat=True, attention_impl="pallas")
+    params = gpt2.init(cfg, jax.random.PRNGKey(0))
+    layer = jax.tree.map(lambda p: p[0], params["blocks"])
+    x = jnp.zeros((BATCH, cfg.seq_len, cfg.d_model), cfg.dtype)
+    shard = gpt2.block_shard(cfg, BATCH, cfg.seq_len, None, True)
+
+    def kept(admits):
+        limit = _limit_admitting(shard, cfg.n_layer, 0, admits) + 8
+        with gpt2.chip_memory(limit, 0):
+            block_fn = gpt2._make_block_fn(cfg, BATCH, cfg.seq_len, None,
+                                           cfg.n_layer)
+            saved = saved_residuals(block_fn, x, layer)
+        return sorted((aval.shape, str(aval.dtype)) for aval, why in saved
+                      if not why.startswith("from the argument"))
+
+    o = ((BATCH, cfg.n_head, cfg.seq_len, cfg.head_dim), "bfloat16")
+    lse = ((BATCH, cfg.n_head, cfg.seq_len), "float32")
+    assert kept(0) == []
+    assert kept(1) == sorted([o, lse])
+    assert kept(4) == sorted([o, o, o, o, lse])      # q, k and v beside them
+
+
+def test_tags_cost_nothing_without_remat(monkeypatch):
+    """remat=False: the step lowers to the same StableHLO with the tags as
+    with `checkpoint_name` patched to the identity (locations are not
+    printed; the counter behind private functions' `@name_<n>` symbols moves
+    with every primitive traced, so the numbers are taken off)."""
+    import re
+
+    def lowered():
+        cfg = gpt2.gpt2_tiny(attention_impl="pallas")
+        bundle = make_gpt2_train_step(cfg)
+        text = bundle.step_fn.lower(
+            bundle.state, synthetic_batch(cfg, 2)).as_text()
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    tagged = lowered()
+    for module in (gpt2, attention):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    assert lowered() == tagged
+
+
+def test_step_factory_states_the_chip_and_cpu_states_no_limit(monkeypatch):
+    """make_gpt2_train_step reports (bytes_limit, state + gradients) of a
+    chip to the model; the CPU backend has no limit, so remat=True keeps
+    block inputs only there. A device that states one gets the rule."""
+    cfg = gpt2.gpt2_tiny(remat=True, attention_impl="pallas")
+    bundle = make_gpt2_train_step(cfg)
+    limit, resident = train_step._chip_memory(bundle.mesh, bundle.state)
+    nbytes = lambda tree: sum(x.nbytes for x in jax.tree.leaves(tree))
+    assert limit is None
+    assert resident == nbytes(bundle.state) + nbytes(bundle.state["params"])
+    batch = synthetic_batch(cfg, 2)
+    bundle.step_fn.lower(bundle.state, batch)
+    assert _decision(cfg.n_layer, 2, 0)["saved"] == []
+
+    monkeypatch.setattr(train_step, "_chip_memory",
+                        lambda mesh, state: (64 * GIB, resident))
+    bundle = make_gpt2_train_step(cfg)
+    bundle.step_fn.lower(bundle.state, batch)
+    assert tuple(_decision(cfg.n_layer, 2, 64 * GIB)["saved"]) == EVERYTHING
+
+
+# ------------------------------------------------------------- the counter
+@pytest.fixture
+def buffer(monkeypatch):
+    monkeypatch.setattr(_config, "task_events_enabled", True)
+    monkeypatch.setattr(_config, "task_events_sample_rate", 1.0)
+    buf = tracing.get_buffer()
+    buf.drain(10 ** 6)
+    yield buf
+    buf.drain(10 ** 6)
+
+
+def test_remat_policy_event_once_a_distinct_decision(buffer):
+    cfg = gpt2.gpt2_tiny(remat=True, attention_impl="pallas")
+    params = gpt2.init(cfg, jax.random.PRNGKey(0))
+    batch = synthetic_batch(cfg, 2)
+
+    def trace(limit):
+        def loss(p):
+            with gpt2.chip_memory(limit, 12345):
+                return gpt2.loss_fn(p, batch["tokens"], batch["targets"], cfg)
+        jax.make_jaxpr(jax.grad(loss))(params)
+
+    # limits no other test states, so the decisions are new to this process
+    first, second = 3 * GIB + 28, 3 * GIB + 29
+    for limit in (first, first, second, first):
+        trace(limit)
+    component, name = names.REMAT_POLICY.split("/")
+    events = [e for e in buffer.drain(10 ** 6)[0]
+              if e["component"] == component and e["name"] == name]
+    assert [e["args"]["bytes_limit"] for e in events] == [first, second]
+    for e in events:
+        assert tuple(e["args"]) == names.REMAT_POLICY_ARGS
+        assert e["args"]["n_layer"] == cfg.n_layer
+        assert (e["args"]["batch"], e["args"]["seq"]) == (2, cfg.seq_len)
+        assert set(e["args"]["saved"]) <= set(names.RESIDUALS)
+    mine = [d for d in gpt2.remat_policy_decisions()
+            if d["bytes_limit"] in (first, second)]
+    assert [d["bytes_limit"] for d in mine] == [first, second]
+
+
+def test_remat_without_a_policy_checkpoint_records_nothing(buffer):
+    cfg = gpt2.gpt2_tiny(attention_impl="pallas")
+    params = gpt2.init(cfg, jax.random.PRNGKey(0))
+    batch = synthetic_batch(cfg, 2)
+    before = len(gpt2.remat_policy_decisions())
+    with gpt2.chip_memory(5 * GIB + 1, 0):
+        jax.make_jaxpr(jax.grad(
+            lambda p: gpt2.loss_fn(p, batch["tokens"], batch["targets"], cfg)
+        ))(params)
+    assert len(gpt2.remat_policy_decisions()) == before
+
+
+@pytest.mark.parametrize("value", ["dots", "full", 1, None])
+def test_remat_is_a_plain_bool(value):
+    with pytest.raises(ValueError, match="remat must be True or False"):
+        gpt2.gpt2_tiny(remat=value)
