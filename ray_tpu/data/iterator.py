@@ -111,43 +111,3 @@ def iter_batches(
 
     put = _device_put(sharding if sharding is not None else device)
     yield from _prefetched(host_iter, put, max(1, prefetch_batches + 1))
-
-
-def iter_stacked_batches(
-    block_refs: Iterator[Any],
-    *,
-    batch_size: int,
-    steps_per_stack: int,
-    stacked_sharding: Any = None,
-    prefetch_stacks: int = 1,
-) -> Iterator[Dict[str, Any]]:
-    """Yield batches STACKED on a leading step axis ``[N, B, ...]`` — the
-    delivery format of ``TrainStepBundle.multi_step_fn`` (a device-side
-    ``lax.scan`` over pre-staged batches: ONE dispatch per N optimizer
-    steps instead of one per step, hiding host dispatch latency the way
-    MaxText-style TPU trainers do).
-
-    Each stack is assembled on host, then transferred in one
-    ``jax.device_put`` with ``stacked_sharding`` (use the bundle's
-    ``stacked_data_sharding``); a prefetch window keeps stack N+1's
-    transfer overlapped with the scan over stack N. A trailing partial
-    stack is dropped — scan needs a static step count."""
-    host_iter = _host_batches(block_refs, batch_size, drop_last=True)
-
-    def stacks():
-        stack = []
-        for batch in host_iter:
-            stack.append(batch)
-            if len(stack) == steps_per_stack:
-                yield {
-                    k: np.stack([b[k] for b in stack]) for k in stack[0]
-                }
-                stack = []
-
-    if stacked_sharding is None:
-        yield from stacks()
-        return
-
-    yield from _prefetched(
-        stacks(), _device_put(stacked_sharding), max(1, prefetch_stacks + 1),
-    )

@@ -16,9 +16,7 @@ data-parallel pretraining, tokens/sec/chip). TPU-first choices:
 
 from __future__ import annotations
 
-import contextlib
 import math
-import threading
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
@@ -171,6 +169,26 @@ def logical_axes(cfg: GPT2Config) -> Dict[str, Any]:
         "lnf_scale": ("embed",),
         "lnf_bias": ("embed",),
     }
+
+
+def mesh_rules(cfg: GPT2Config, mesh) -> Dict[str, str]:
+    """What this config needs of this mesh: the sharding rules to lay over
+    parallel/sharding's defaults, or the refusal of a mesh it cannot run on."""
+    pp = mesh.shape.get("pp", 1)
+    if pp == 1:
+        return {}
+    if cfg.moe_experts > 0:
+        raise NotImplementedError(
+            "pipeline parallelism with MoE blocks is not supported yet "
+            "(the aux-loss carry needs threading through the schedule); "
+            "use a pp=1 mesh for MoE configs"
+        )
+    if cfg.n_layer % pp:
+        raise ValueError(f"n_layer={cfg.n_layer} not divisible by pp={pp}")
+    # pipelined plan: shard the stacked layer dim over pp so each stage
+    # group holds only its own layers (parallel/pipeline.py reshapes
+    # [L, ...] → [pp, L/pp, ...], which preserves this sharding).
+    return {"layers": "pp"}
 
 
 def init(cfg: GPT2Config, rng: jax.Array) -> Dict[str, Any]:
@@ -359,29 +377,7 @@ class RematPolicy(NamedTuple):
 REMAT_RESERVE_BYTES = 2 ** 30
 _MXU = 128                   # a matmul dim below this still costs a full pass
 
-class _Observed(threading.local):
-    memory: Tuple[Optional[int], int] = (None, 0)   # outside chip_memory
-
-
-_local = _Observed()
 _decisions: Dict[tuple, Dict[str, Any]] = {}
-
-
-@contextlib.contextmanager
-def chip_memory(bytes_limit: Optional[int], resident_bytes: int):
-    """What the step factory observed of the chip its step will run on,
-    active while the step is traced (as parallel.mesh.use_mesh is):
-    ``bytes_limit`` the device's ``memory_stats()["bytes_limit"]`` (None where
-    it states none — the CPU backend), ``resident_bytes`` what a chip holds
-    through the whole step besides activations: the placed state, and the
-    gradients (the parameters' bytes again). Outside it the rule knows no
-    limit and ``remat=True`` keeps only each block's input."""
-    prev = _local.memory
-    _local.memory = (bytes_limit, resident_bytes)
-    try:
-        yield
-    finally:
-        _local.memory = prev
 
 
 def block_shard(cfg: GPT2Config, global_batch: int, seq: int, mesh,
@@ -494,10 +490,12 @@ def _remat_policy(cfg: GPT2Config, global_batch: int, seq: int, mesh,
     once, as an instant event, to the task-event buffer
     (→ ``ray_tpu.timeline()``)."""
     from ray_tpu.ops.attention import resolve_attention
+    from ray_tpu.parallel import mesh as mesh_lib
 
     flash = resolve_attention(cfg.attention_impl, mesh)[0] == "pallas"
     shard = block_shard(cfg, global_batch, seq, mesh, flash)
-    policy = choose_remat_policy(shard, n_layer, *_local.memory)
+    policy = choose_remat_policy(shard, n_layer,
+                                 *mesh_lib.current_chip_memory())
     args = dict(zip(scopes.REMAT_POLICY_ARGS,
                     (n_layer, shard.batch, shard.seq, list(policy.saved))
                     + policy[1:]))
@@ -527,14 +525,7 @@ def _blocks_pipelined(blocks, x, cfg: GPT2Config, mesh, pp: int):
     """Run the layer stack as a pp-stage GPipe pipeline (parallel/pipeline)."""
     from ray_tpu.parallel.pipeline import pipeline_apply, stages_from_layers
 
-    if cfg.moe_experts > 0:
-        raise NotImplementedError(
-            "pipeline parallelism with MoE blocks is not supported yet "
-            "(the aux-loss carry needs threading through the schedule); "
-            "use a pp=1 mesh for MoE configs"
-        )
-    if cfg.n_layer % pp:
-        raise ValueError(f"n_layer={cfg.n_layer} not divisible by pp={pp}")
+    mesh_rules(cfg, mesh)     # refuses what cannot be pipelined
     M = cfg.pipeline_microbatches or pp
     lpp = cfg.n_layer // pp
     # every microbatch of the batch is in flight at once: lpp layers of the

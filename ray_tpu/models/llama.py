@@ -45,7 +45,7 @@ class LlamaConfig:
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    remat: Any = False            # False | True | "dots" (as GPT-2)
+    remat: bool = False           # True: recompute each block in the backward
     attention_impl: str = "auto"  # auto | xla | pallas
     scan_layers: bool = True
 
@@ -111,6 +111,18 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
+
+
+def mesh_rules(cfg: LlamaConfig, mesh) -> Dict[str, str]:
+    """What this config needs of this mesh (as gpt2.mesh_rules): no rule
+    beyond the defaults, and no pipeline — the layer loop below has no stage
+    schedule, so a pp axis would only repeat the whole model on every stage."""
+    if mesh.shape.get("pp", 1) > 1:
+        raise NotImplementedError(
+            "pipeline parallelism is not implemented for the LLaMA family; "
+            "use a pp=1 mesh"
+        )
+    return {}
 
 
 def init(cfg: LlamaConfig, rng: jax.Array) -> Dict[str, Any]:
@@ -234,11 +246,7 @@ def _trunk(params, tokens, cfg: LlamaConfig):
     positions = jnp.arange(S)
 
     block_fn = partial(_block, positions=positions, cfg=cfg)
-    if cfg.remat == "dots":
-        block_fn = jax.checkpoint(
-            block_fn, policy=jax.checkpoint_policies.checkpoint_dots
-        )
-    elif cfg.remat:
+    if cfg.remat:
         block_fn = jax.checkpoint(block_fn)
 
     if cfg.scan_layers:

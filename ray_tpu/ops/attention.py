@@ -2,7 +2,7 @@
 
 This is the perf-critical op the XLA fallback can't match: XLA materializes the
 [S, S] probability matrix as a backward residual per layer, forcing full remat
-at GPT-2 batch sizes (see bench.py). The kernels below keep the online-softmax
+at GPT-2 batch sizes. The kernels below keep the online-softmax
 running state (m, l, acc) in VMEM and never write probabilities to HBM; the
 backward pass recomputes logits blockwise from (q, k, lse) the flash-attention
 way.

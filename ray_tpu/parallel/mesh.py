@@ -109,15 +109,22 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-# Trace-time mesh context: model code that needs the mesh (e.g. GPT-2's ring
-# attention wraps a shard_map) reads it here; train_step enters the context
-# inside its jitted body so it is active whenever the step traces.
-_local = threading.local()
+# Trace-time context: what the step factory knows of the chips its step will
+# run on. Model code reads it while the step traces; train_step enters both
+# inside its jitted body so they are active whenever the step traces.
+class _Observed(threading.local):
+    mesh: Optional[Mesh] = None
+    memory: Tuple[Optional[int], int] = (None, 0)   # outside chip_memory
+
+
+_local = _Observed()
 
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
-    prev = getattr(_local, "mesh", None)
+    """The mesh, for model code that needs it (GPT-2's ring attention wraps a
+    shard_map over it)."""
+    prev = _local.mesh
     _local.mesh = mesh
     try:
         yield mesh
@@ -126,4 +133,24 @@ def use_mesh(mesh: Mesh):
 
 
 def current_mesh() -> Optional[Mesh]:
-    return getattr(_local, "mesh", None)
+    return _local.mesh
+
+
+@contextlib.contextmanager
+def chip_memory(bytes_limit: Optional[int], resident_bytes: int):
+    """What the step factory observed of one chip of the mesh:
+    ``bytes_limit`` the device's ``memory_stats()["bytes_limit"]`` (None where
+    it states none — the CPU backend), ``resident_bytes`` what a chip holds
+    through the whole step besides activations: the placed state, and the
+    gradients (the parameters' bytes again). Outside it a remat rule knows no
+    limit (GPT-2's ``remat=True`` then keeps only each block's input)."""
+    prev = _local.memory
+    _local.memory = (bytes_limit, resident_bytes)
+    try:
+        yield
+    finally:
+        _local.memory = prev
+
+
+def current_chip_memory() -> Tuple[Optional[int], int]:
+    return _local.memory

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -34,13 +33,6 @@ class TrainStepBundle:
     mesh: Mesh
     data_sharding: NamedSharding
     cfg: Any
-    # (state, batches) -> (state, stacked metrics): lax.scan over a leading
-    # step axis of pre-staged batches — ONE dispatch for N optimizer steps,
-    # hiding per-step host dispatch latency (the device loop MaxText-style
-    # trainers use). Batches: {"tokens": [N, B, S], "targets": [N, B, S]},
-    # placed with stacked_data_sharding.
-    multi_step_fn: Optional[Callable] = None
-    stacked_data_sharding: Optional[NamedSharding] = None
 
 
 def _scale_by_adam_lowmem(b1: float, b2: float, eps: float,
@@ -86,28 +78,67 @@ def default_optimizer(
     lr: float = 3e-4, weight_decay: float = 0.1, warmup: int = 100,
     total_steps: int = 10_000, b1: float = 0.9, b2: float = 0.95,
     grad_clip: float = 1.0, eps: float = 1e-8,
-    moment_dtype=jnp.bfloat16,
 ) -> optax.GradientTransformation:
-    """AdamW with warmup-cosine LR, global-norm clipping, and (by default)
-    bf16-stored moments (see _scale_by_adam_lowmem; pass
-    moment_dtype=jnp.float32 for classic f32 state).
-
-    NOTE: the bf16-moment default (round 5) changes the opt_state pytree
-    vs the earlier chain(clip, optax.adamw) — restoring a checkpoint taken
-    before then needs moment_dtype=jnp.float32 AND optax.adamw; structure
-    mismatches fail loudly at restore."""
+    """AdamW with warmup-cosine LR, global-norm clipping, and bf16-stored
+    moments (see _scale_by_adam_lowmem)."""
     sched = optax.warmup_cosine_decay_schedule(
         0.0, lr, warmup, max(total_steps, warmup + 1), end_value=lr * 0.1
     )
-    if moment_dtype == jnp.float32:
-        scale = optax.scale_by_adam(b1=b1, b2=b2, eps=eps)
-    else:
-        scale = _scale_by_adam_lowmem(b1, b2, eps, moment_dtype)
     return optax.chain(
         optax.clip_by_global_norm(grad_clip),
-        scale,
+        _scale_by_adam_lowmem(b1, b2, eps, jnp.bfloat16),
         optax.add_decayed_weights(weight_decay),
         optax.scale_by_learning_rate(sched),
+    )
+
+
+def make_train_step(
+    model,
+    cfg,
+    mesh: Optional[Mesh] = None,
+    optimizer: Optional[optax.GradientTransformation] = None,
+    rng: Optional[jax.Array] = None,
+    rules: Optional[Dict] = None,
+) -> TrainStepBundle:
+    """Build sharded state and a jitted train step for ``cfg`` on ``mesh``.
+
+    ``model`` is a model module of ``ray_tpu.models``. All the factory asks
+    of it: ``init(cfg, rng)``, ``logical_axes(cfg)``,
+    ``loss_fn(params, tokens, targets, cfg)`` and ``mesh_rules(cfg, mesh)``
+    — the sharding rules this config adds on this mesh, or the refusal of a
+    mesh it cannot run on."""
+    if mesh is None:
+        mesh = mesh_lib.single_device_mesh()
+    if optimizer is None:
+        optimizer = default_optimizer()
+    if rng is None:
+        rng = jax.random.PRNGKey(0)
+
+    step_given, state_shardings, batch_shardings = _compose_step(
+        model, cfg, mesh, optimizer, rules)
+
+    # Shard-aware init: run init jitted with output shardings so large models
+    # are *born sharded* and never materialize on one device.
+    params = jax.jit(
+        lambda r: model.init(cfg, r), out_shardings=state_shardings["params"]
+    )(rng)
+    opt_state = jax.jit(
+        optimizer.init, out_shardings=state_shardings["opt_state"]
+    )(params)
+    state = {
+        "params": params,
+        "opt_state": opt_state,
+        "step": _step_counter(mesh),
+    }
+    step_fn = jax.jit(
+        step_given(_chip_memory(mesh, state)),
+        in_shardings=(state_shardings, batch_shardings),
+        out_shardings=(state_shardings, None),
+        donate_argnums=(0,),
+    )
+    return TrainStepBundle(
+        state=state, step_fn=step_fn, mesh=mesh,
+        data_sharding=batch_shardings["tokens"], cfg=cfg,
     )
 
 
@@ -118,92 +149,56 @@ def make_gpt2_train_step(
     rng: Optional[jax.Array] = None,
     rules: Optional[Dict] = None,
 ) -> TrainStepBundle:
-    """Build sharded state and a jitted train step for GPT-2 on `mesh`."""
-    if mesh is None:
-        mesh = mesh_lib.single_device_mesh()
-    if optimizer is None:
-        optimizer = default_optimizer()
-    if rng is None:
-        rng = jax.random.PRNGKey(0)
+    """make_train_step for GPT-2 (models/gpt2.py)."""
+    return make_train_step(gpt2, cfg, mesh, optimizer, rng, rules)
 
-    if mesh.shape.get("pp", 1) > 1:
-        if cfg.moe_experts > 0:
-            raise NotImplementedError(
-                "pipeline parallelism with MoE blocks is not supported yet; "
-                "use a pp=1 mesh for MoE configs"
-            )
-        if cfg.n_layer % mesh.shape["pp"]:
-            raise ValueError(
-                f"n_layer={cfg.n_layer} not divisible by pp={mesh.shape['pp']}"
-            )
-        # pipelined plan: shard the stacked layer dim over pp so each stage
-        # group holds only its own layers (parallel/pipeline.py reshapes
-        # [L, ...] → [pp, L/pp, ...], which preserves this sharding).
-        rules = {"layers": "pp", **(rules or {})}
 
-    log_axes = gpt2.logical_axes(cfg)
-    param_shardings = sharding_lib.tree_shardings(mesh, log_axes, rules)
-
-    # Shard-aware init: run init jitted with output shardings so large models
-    # are *born sharded* and never materialize on one device.
-    params_init = jax.jit(
-        lambda r: gpt2.init(cfg, r), out_shardings=param_shardings
-    )
-    params = params_init(rng)
-    opt_shardings = _opt_state_shardings(optimizer, params, param_shardings, mesh)
-    opt_init = jax.jit(optimizer.init, out_shardings=opt_shardings)
-    opt_state = opt_init(params)
-    state = {
-        "params": params,
-        "opt_state": opt_state,
-        "step": _step_counter(mesh),
-    }
-
-    data_sh = mesh_lib.data_sharding(mesh, extra_dims=1)
-    memory = _chip_memory(mesh, state)
-
-    def step(state, batch):
-        tokens, targets = batch["tokens"], batch["targets"]
-        # use_mesh: active during tracing so the model can reach the mesh
-        # (ring attention wraps a shard_map over it); chip_memory: so its
-        # remat rule knows what the chip has free.
-        with mesh_lib.use_mesh(mesh), gpt2.chip_memory(*memory):
-            loss, grads = jax.value_and_grad(gpt2.loss_fn)(
-                state["params"], tokens, targets, cfg
-            )
-        new_params, new_opt, gnorm = _apply_optimizer(optimizer, grads, state)
-        new_state = {
-            "params": new_params,
-            "opt_state": new_opt,
-            "step": state["step"] + 1,
-        }
-        return new_state, {"loss": loss, "grad_norm": gnorm}
-
+def _compose_step(model, cfg, mesh: Mesh, optimizer, rules: Optional[Dict]):
+    """What of a train step needs no array: ``(step_given, state_shardings,
+    batch_shardings)``. ``step_given(memory)`` is the whole step — forward,
+    backward, optimizer — as ``step(state, batch)``, to jit over those
+    shardings with the state donated. ``memory`` is what _chip_memory
+    measures of the placed state, so it can only come after the shardings."""
+    rules = {**model.mesh_rules(cfg, mesh), **(rules or {})}
+    param_shardings = sharding_lib.tree_shardings(
+        mesh, model.logical_axes(cfg), rules)
+    params = jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0)))
     state_shardings = {
         "params": param_shardings,
-        "opt_state": opt_shardings,
-        "step": NamedSharding(mesh, P()),
+        "opt_state": _opt_state_shardings(
+            optimizer, params, param_shardings, mesh),
+        "step": mesh_lib.replicated(mesh),
     }
-    batch_shardings = {"tokens": data_sh, "targets": data_sh}
-    step_fn = jax.jit(
-        step,
-        in_shardings=(state_shardings, batch_shardings),
-        out_shardings=(state_shardings, None),
-        donate_argnums=(0,),
-    )
-    multi_step_fn, stacked_sh = _make_multi_step(
-        step, state_shardings, data_sh, mesh
-    )
-    return TrainStepBundle(
-        state=state, step_fn=step_fn, mesh=mesh, data_sharding=data_sh,
-        cfg=cfg, multi_step_fn=multi_step_fn, stacked_data_sharding=stacked_sh,
-    )
+    data_sh = mesh_lib.data_sharding(mesh, extra_dims=1)
+
+    def step_given(memory: Tuple[Optional[int], int]):
+        def step(state, batch):
+            tokens, targets = batch["tokens"], batch["targets"]
+            # active while the step traces: use_mesh so the model can reach
+            # the mesh (ring attention wraps a shard_map over it), chip_memory
+            # so its remat rule knows what the chip has free.
+            with mesh_lib.use_mesh(mesh), mesh_lib.chip_memory(*memory):
+                loss, grads = jax.value_and_grad(model.loss_fn)(
+                    state["params"], tokens, targets, cfg
+                )
+            new_params, new_opt, gnorm = _apply_optimizer(
+                optimizer, grads, state)
+            new_state = {
+                "params": new_params,
+                "opt_state": new_opt,
+                "step": state["step"] + 1,
+            }
+            return new_state, {"loss": loss, "grad_norm": gnorm}
+
+        return step
+
+    return step_given, state_shardings, {"tokens": data_sh, "targets": data_sh}
 
 
 @jax.named_scope(scopes.OPTIMIZER)
 def _apply_optimizer(optimizer, grads, state):
-    """The update both step factories share: (new params, new optimizer
-    state, the gradients' global norm), under one scope on the device."""
+    """The step's update: (new params, new optimizer state, the gradients'
+    global norm), under one scope on the device."""
     updates, new_opt = optimizer.update(
         grads, state["opt_state"], state["params"]
     )
@@ -236,111 +231,15 @@ def _step_counter(mesh: Mesh) -> jax.Array:
     return jax.device_put(jnp.zeros((), jnp.int32), NamedSharding(mesh, P()))
 
 
-def _make_multi_step(step, state_shardings, data_sh, mesh):
-    """Jit a device-side train loop: lax.scan of `step` over batches stacked
-    on a leading step axis (one dispatch for N optimizer steps)."""
-
-    def multi(state, batches):
-        return jax.lax.scan(step, state, batches)
-
-    stacked_sh = NamedSharding(mesh, P(None, *data_sh.spec))
-    multi_step_fn = jax.jit(
-        multi,
-        in_shardings=(
-            state_shardings,
-            {"tokens": stacked_sh, "targets": stacked_sh},
-        ),
-        out_shardings=(state_shardings, None),
-        donate_argnums=(0,),
-    )
-    return multi_step_fn, stacked_sh
-
-
-def make_llama_train_step(
-    cfg,
-    mesh: Optional[Mesh] = None,
-    optimizer: Optional[optax.GradientTransformation] = None,
-    rng: Optional[jax.Array] = None,
-    rules: Optional[Dict] = None,
-) -> TrainStepBundle:
-    """Sharded train step for the LLaMA family (models/llama.py) — same
-    factory shape as make_gpt2_train_step: born-sharded init, jitted
-    fwd+bwd+AdamW with donated buffers, data split over the batch axes."""
-    from ray_tpu.models import llama
-
-    if mesh is None:
-        mesh = mesh_lib.single_device_mesh()
-    if optimizer is None:
-        optimizer = default_optimizer()
-    if rng is None:
-        rng = jax.random.PRNGKey(0)
-
-    log_axes = llama.logical_axes(cfg)
-    param_shardings = sharding_lib.tree_shardings(mesh, log_axes, rules)
-    params = jax.jit(
-        lambda r: llama.init(cfg, r), out_shardings=param_shardings
-    )(rng)
-    opt_shardings = _opt_state_shardings(optimizer, params, param_shardings, mesh)
-    opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
-    state = {
-        "params": params,
-        "opt_state": opt_state,
-        "step": _step_counter(mesh),
-    }
-    data_sh = mesh_lib.data_sharding(mesh, extra_dims=1)
-
-    def step(state, batch):
-        tokens, targets = batch["tokens"], batch["targets"]
-        with mesh_lib.use_mesh(mesh):
-            loss, grads = jax.value_and_grad(llama.loss_fn)(
-                state["params"], tokens, targets, cfg
-            )
-        new_params, new_opt, gnorm = _apply_optimizer(optimizer, grads, state)
-        new_state = {
-            "params": new_params,
-            "opt_state": new_opt,
-            "step": state["step"] + 1,
-        }
-        return new_state, {"loss": loss, "grad_norm": gnorm}
-
-    state_shardings = {
-        "params": param_shardings,
-        "opt_state": opt_shardings,
-        "step": NamedSharding(mesh, P()),
-    }
-    step_fn = jax.jit(
-        step,
-        in_shardings=(state_shardings,
-                      {"tokens": data_sh, "targets": data_sh}),
-        out_shardings=(state_shardings, None),
-        donate_argnums=(0,),
-    )
-    multi_step_fn, stacked_sh = _make_multi_step(
-        step, state_shardings, data_sh, mesh
-    )
-    return TrainStepBundle(
-        state=state, step_fn=step_fn, mesh=mesh, data_sharding=data_sh,
-        cfg=cfg, multi_step_fn=multi_step_fn, stacked_data_sharding=stacked_sh,
-    )
-
-
 def _opt_state_shardings(optimizer, params, param_shardings, mesh):
-    """Derive shardings for the optimizer state: any leaf whose shape matches a
-    param mirrors that param's sharding; everything else replicates."""
-    shapes = jax.eval_shape(optimizer.init, params)
-    flat_params, _ = jax.tree.flatten(params)
-    flat_shardings, _ = jax.tree.flatten(
-        param_shardings, is_leaf=lambda x: isinstance(x, NamedSharding)
+    """Shardings for the optimizer state: every copy of the parameter tree in
+    it (Adam's mu and nu) takes the parameters' shardings leaf for leaf;
+    everything else (counts) replicates. ``params`` may be abstract."""
+    return optax.tree_map_params(
+        optimizer, lambda _, sharding: sharding,
+        jax.eval_shape(optimizer.init, params), param_shardings,
+        transform_non_params=lambda _: mesh_lib.replicated(mesh),
     )
-    by_shape = {}
-    for p, s in zip(flat_params, flat_shardings):
-        by_shape.setdefault(tuple(p.shape), s)
-    repl = NamedSharding(mesh, P())
-
-    def pick(leaf):
-        return by_shape.get(tuple(leaf.shape), repl)
-
-    return jax.tree.map(pick, shapes)
 
 
 def synthetic_batch(cfg: gpt2.GPT2Config, global_batch: int, seed: int = 0):

@@ -1,7 +1,7 @@
 """Where JAX keeps compiled programs between processes.
 
-One rule, for every process that compiles (TrainWorker, the RLlib learner,
-bench.py): the cache is placed from OUTSIDE through ``JAX_COMPILATION_CACHE_DIR``
+One rule, for every process that compiles (TrainWorker, the RLlib learner):
+the cache is placed from OUTSIDE through ``JAX_COMPILATION_CACHE_DIR``
 when that is set — JAX reads the variable itself, worker environments descend
 from the driver's, and no code here overrides it. Only when it is unset does
 the program choose, and then it chooses one fixed directory inside the

@@ -22,7 +22,8 @@ if "xla_force_host_platform_device_count" not in prev:
 
 # libtpu is installed: an unpinned JAX looks for a chip first (and on a TPU
 # host this process would take it from the workers). Tests run on the virtual
-# CPU mesh; the chip is reached only by `python chip_smoke.py` / bench.py.
+# CPU mesh; the chip is reached only through the chip tool
+# (`python chip_smoke.py`, `benchmarks/run.py`).
 import jax
 
 jax.config.update("jax_platforms", "cpu")

@@ -126,30 +126,6 @@ class TestDatasetLocal:
         assert isinstance(batches[0]["id"], jax.Array)
         assert batches[0]["id"].sum() == sum(range(8))
 
-    def test_iter_stacked_batches(self, ray_start_local):
-        """multi_step_fn delivery: batches stacked on a leading step axis,
-        one device_put per stack; trailing partial stacks drop."""
-        import jax
-
-        from ray_tpu.data.iterator import iter_stacked_batches
-
-        ds = rd.range(70, parallelism=3)
-        stacks = list(iter_stacked_batches(
-            ds.iter_block_refs(), batch_size=16, steps_per_stack=2
-        ))
-        # 70 rows -> 4 full batches of 16 -> 2 stacks of [2, 16]
-        assert [s["id"].shape for s in stacks] == [(2, 16), (2, 16)]
-        assert stacks[0]["id"][0].tolist() == list(range(16))
-
-        sh = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
-        stacks = list(iter_stacked_batches(
-            rd.range(64, parallelism=2).iter_block_refs(),
-            batch_size=8, steps_per_stack=4, stacked_sharding=sh,
-        ))
-        assert len(stacks) == 2
-        assert isinstance(stacks[0]["id"], jax.Array)
-        assert stacks[0]["id"].shape == (4, 8)
-
 
 class TestFileIO:
     def test_parquet_roundtrip(self, ray_start_local, tmp_path):

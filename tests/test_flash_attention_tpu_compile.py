@@ -13,7 +13,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops.attention import flash_attention, flash_tiling_decisions
+from ray_tpu.ops.attention import (
+    flash_attention, flash_attention_with_lse, flash_tiling_decisions,
+    mha_backward_chunk,
+)
 
 
 @pytest.fixture(scope="module")
@@ -63,3 +66,23 @@ def test_chosen_tiling_compiles_for_the_v5e(one_chip, shape):
     # the target tile fits at these shapes: Mosaic took what the rule chose
     assert all((d["block_q"], d["block_k"]) == (512, 512)
                for d in mine.values())
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_ring_chunk_kernels_compile_for_the_v5e(one_chip, kernel):
+    """Ring attention's building blocks (a q chunk against an earlier kv
+    chunk, global offsets): every other test of them interprets."""
+    B, S, H, hd = 2, 512, 4, 64
+    x = jax.ShapeDtypeStruct((B, S, H, hd), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((B, H, S), jnp.float32, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention_with_lse(q, k, v, S, 0, interpret=False)
+
+    def bwd(q, k, v, o, lse, do):
+        return mha_backward_chunk(q, k, v, o, lse, do, S, 0, interpret=False)
+
+    lowered = (jax.jit(fwd).lower(x, x, x) if kernel == "fwd"
+               else jax.jit(bwd).lower(x, x, x, x, lse, x))
+    hlo = lowered.compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1

@@ -5,11 +5,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt2
+from ray_tpu.models import gpt2, llama
 from ray_tpu.parallel import mesh as mesh_lib
+from ray_tpu.parallel import sharding as sharding_lib
 from ray_tpu.train.train_step import (
+    _opt_state_shardings,
     default_optimizer,
     make_gpt2_train_step,
+    make_train_step,
     synthetic_batch,
 )
 
@@ -146,3 +149,32 @@ def test_step_fn_compiles_once():
     state, _ = b.step_fn(b.state, batch)
     state, _ = b.step_fn(state, batch)
     assert b.step_fn._cache_size() == 1
+
+
+def test_opt_state_shardings_tell_same_shaped_parameters_apart(cpu_mesh8):
+    """Adam's moments take their own parameter's sharding, not that of the
+    first parameter with the same shape (abstract parameters, as the
+    benchmark's rehearsal compile passes them)."""
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(fsdp=4, tp=2), cpu_mesh8)
+    axes = {"up": ("embed", "mlp"), "down": ("mlp", "embed")}
+    param_sh = sharding_lib.tree_shardings(mesh, axes)
+    assert param_sh["up"] != param_sh["down"]
+    params = {k: jax.ShapeDtypeStruct((64, 64), jnp.float32) for k in axes}
+    clip, adam, decay, schedule = _opt_state_shardings(
+        default_optimizer(), params, param_sh, mesh)
+    assert adam.mu == param_sh and adam.nu == param_sh
+    assert adam.count == schedule.count == mesh_lib.replicated(mesh)
+    assert not jax.tree.leaves((clip, decay))
+
+
+@pytest.mark.parametrize("model, cfg, spec", [
+    (gpt2, gpt2.gpt2_tiny(), mesh_lib.MeshSpec(dp=2, fsdp=2, tp=2)),
+    (llama, llama.llama_tiny(), mesh_lib.MeshSpec(dp=2, tp=2)),
+], ids=["gpt2", "llama"])
+def test_moments_are_placed_as_their_parameters(model, cfg, spec, cpu_mesh8):
+    mesh = mesh_lib.make_mesh(spec, cpu_mesh8[:spec.num_devices])
+    state = make_train_step(model, cfg, mesh=mesh).state
+    adam = state["opt_state"][1]
+    placed = lambda tree: jax.tree.map(lambda x: x.sharding, tree)
+    assert placed(adam.mu) == placed(adam.nu) == placed(state["params"])
+    assert len({str(s.spec) for s in jax.tree.leaves(placed(adam.mu))}) > 1

@@ -100,14 +100,15 @@ def test_hf_numerics_parity():
 
 
 def test_llama_train_step_on_mesh(cpu_mesh8):
-    """Full sharded train step (train_step.make_llama_train_step) on a
+    """Full sharded train step (train_step.make_train_step) on a
     dp2/tp2 mesh: loss finite, decreases, params stay sharded."""
     from ray_tpu.parallel import mesh as mesh_lib
-    from ray_tpu.train.train_step import make_llama_train_step
+    from ray_tpu.train.train_step import make_train_step
 
     cfg = llama.llama_tiny(dtype=jnp.float32)
     mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(dp=2, tp=2), cpu_mesh8[:4])
-    bundle = make_llama_train_step(cfg, mesh=mesh, rng=jax.random.PRNGKey(0))
+    bundle = make_train_step(llama, cfg, mesh=mesh,
+                             rng=jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     toks = rng.integers(0, cfg.vocab_size, (4, 64)).astype(np.int32)
     tgt = np.roll(toks, -1, 1).copy()

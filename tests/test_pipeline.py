@@ -11,10 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt2
+from ray_tpu.models import gpt2, llama
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel.pipeline import pipeline_apply, stages_from_layers
-from ray_tpu.train.train_step import make_gpt2_train_step, synthetic_batch
+from ray_tpu.train.train_step import (
+    make_gpt2_train_step, make_train_step, synthetic_batch,
+)
 
 
 def test_pipeline_apply_matches_sequential(cpu_mesh8):
@@ -128,8 +130,12 @@ def test_pipeline_microbatch_validation(cpu_mesh8):
         bundle.step_fn(bundle.state, batch)
 
 
-def test_pipeline_moe_unsupported(cpu_mesh8):
-    cfg = gpt2.gpt2_tiny(dtype=jnp.float32, moe_experts=4, moe_top_k=2)
+@pytest.mark.parametrize("model, cfg", [
+    (gpt2, gpt2.gpt2_tiny(dtype=jnp.float32, moe_experts=4, moe_top_k=2)),
+    (llama, llama.llama_tiny(dtype=jnp.float32)),
+], ids=["gpt2-moe", "llama"])
+def test_pipeline_moe_unsupported(cpu_mesh8, model, cfg):
+    """What a model cannot pipeline it refuses when the step is built."""
     mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(pp=2), cpu_mesh8[:2])
     with pytest.raises(NotImplementedError, match="pipeline"):
-        make_gpt2_train_step(cfg, mesh=mesh, rng=jax.random.PRNGKey(0))
+        make_train_step(model, cfg, mesh=mesh, rng=jax.random.PRNGKey(0))

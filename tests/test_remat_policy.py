@@ -4,7 +4,8 @@ policy-`checkpoint` keeps (scanned, unrolled, pipelined), the tags' cost with
 `remat=False` (none), and the `model/remat_policy` event.
 
 All on the CPU: the Pallas kernels interpret, the chip's memory is stated by
-the test through `gpt2.chip_memory`, as the step factory states it on a TPU.
+the test through `parallel.mesh.chip_memory`, as the step factory states it on
+a TPU.
 """
 
 import jax
@@ -186,7 +187,7 @@ def _run(loop, remat, admits, devices):
     batch = synthetic_batch(cfg, BATCH)
 
     def loss(p):
-        with mesh_lib.use_mesh(mesh), gpt2.chip_memory(limit, 0):
+        with mesh_lib.use_mesh(mesh), mesh_lib.chip_memory(limit, 0):
             return gpt2.loss_fn(p, batch["tokens"], batch["targets"], cfg)
 
     fn = jax.value_and_grad(loss)
@@ -259,7 +260,7 @@ def test_named_residuals_are_what_the_checkpoint_saves():
 
     def kept(admits):
         limit = _limit_admitting(shard, cfg.n_layer, 0, admits) + 8
-        with gpt2.chip_memory(limit, 0):
+        with mesh_lib.chip_memory(limit, 0):
             block_fn = gpt2._make_block_fn(cfg, BATCH, cfg.seq_len, None,
                                            cfg.n_layer)
             saved = saved_residuals(block_fn, x, layer)
@@ -332,7 +333,7 @@ def test_remat_policy_event_once_a_distinct_decision(buffer):
 
     def trace(limit):
         def loss(p):
-            with gpt2.chip_memory(limit, 12345):
+            with mesh_lib.chip_memory(limit, 12345):
                 return gpt2.loss_fn(p, batch["tokens"], batch["targets"], cfg)
         jax.make_jaxpr(jax.grad(loss))(params)
 
@@ -359,7 +360,7 @@ def test_remat_without_a_policy_checkpoint_records_nothing(buffer):
     params = gpt2.init(cfg, jax.random.PRNGKey(0))
     batch = synthetic_batch(cfg, 2)
     before = len(gpt2.remat_policy_decisions())
-    with gpt2.chip_memory(5 * GIB + 1, 0):
+    with mesh_lib.chip_memory(5 * GIB + 1, 0):
         jax.make_jaxpr(jax.grad(
             lambda p: gpt2.loss_fn(p, batch["tokens"], batch["targets"], cfg)
         ))(params)
