@@ -3,8 +3,9 @@
 Flagship model for the Train benchmarks (BASELINE.md config 3: GPT-2-124M
 data-parallel pretraining, tokens/sec/chip). TPU-first choices:
 
-- layers are *stacked* and iterated with ``lax.scan`` → compile time independent
-  of depth, XLA pipelines the layer loop;
+- layers are *stacked* and iterated with ``lax.scan`` → compile time and the
+  compiled program's size independent of depth; each block is a
+  policy-``checkpoint`` so the scan stacks only the block's named residuals;
 - weights carry logical axis names so any (dp, fsdp, tp, cp) mesh works via
   parallel/sharding.py rules — no model changes for a new parallelism plan;
 - bf16 activations + matmuls (MXU native), f32 params/optimizer master copy;
@@ -43,20 +44,20 @@ class GPT2Config:
     dropout: float = 0.0          # pretraining default; nonzero not yet implemented
     dtype: Any = jnp.bfloat16     # activation/compute dtype
     param_dtype: Any = jnp.float32
-    # Rematerialization of each block (memory/FLOPs trade):
-    #   False — save all residuals (fastest, most HBM)
-    #   True  — recompute what does not fit: each block keeps its input and
-    #           whichever of its named residuals choose_remat_policy finds
-    #           room for on this chip (none where the device states no
-    #           memory limit: one extra forward, least HBM)
+    # What each block keeps for its backward besides its input:
+    #   False — every named residual (tracing/names.RESIDUALS: the outputs of
+    #           its matmuls and of the flash kernel), whatever the memory;
+    #           only elementwise work (layer norms, gelu, bias and residual
+    #           adds) runs again in the backward (fastest, most HBM). Where
+    #           the names do not cover the block (_make_block_fn), all that
+    #           AD saves
+    #   True  — recompute what does not fit: whichever of those names
+    #           choose_remat_policy finds room for on this chip (none where
+    #           the device states no memory limit: one extra forward, least
+    #           HBM)
     remat: bool = False
     attention_impl: str = "auto"  # auto | xla | pallas | ring
     use_bias: bool = True
-    # scan over layers (True: compact HLO, one traced block) vs an unrolled
-    # Python loop (False: 12x the HLO, but no lax.scan slice/stack traffic —
-    # the profiler showed ~15% of the v5e step in dynamic-update-slice
-    # fusions moving stacked layer params/grads through the scan carry)
-    scan_layers: bool = True
     # mixture-of-experts MLP (ops/moe.py): 0 = dense. When > 0 every block's
     # MLP becomes E experts with top-k routing; expert params shard over the
     # mesh's ep axis. aux (load-balance) loss joins the training loss.
@@ -483,17 +484,22 @@ def remat_policy_decisions() -> List[Dict[str, Any]]:
     return list(_decisions.values())
 
 
+def _flash(cfg: GPT2Config, mesh) -> bool:
+    """Attention on this mesh is the flash kernel: its o and lse exist."""
+    from ray_tpu.ops.attention import resolve_attention
+
+    return resolve_attention(cfg.attention_impl, mesh)[0] == "pallas"
+
+
 def _remat_policy(cfg: GPT2Config, global_batch: int, seq: int, mesh,
                   n_layer: int) -> RematPolicy:
     """choose_remat_policy for the step being traced, recorded. A static
     choice has no hit rate; its counter is the choice: each distinct one goes
     once, as an instant event, to the task-event buffer
     (→ ``ray_tpu.timeline()``)."""
-    from ray_tpu.ops.attention import resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
 
-    flash = resolve_attention(cfg.attention_impl, mesh)[0] == "pallas"
-    shard = block_shard(cfg, global_batch, seq, mesh, flash)
+    shard = block_shard(cfg, global_batch, seq, mesh, _flash(cfg, mesh))
     policy = choose_remat_policy(shard, n_layer,
                                  *mesh_lib.current_chip_memory())
     args = dict(zip(scopes.REMAT_POLICY_ARGS,
@@ -509,16 +515,38 @@ def _remat_policy(cfg: GPT2Config, global_batch: int, seq: int, mesh,
 
 def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
                    n_layer: int):
-    """One block as the layer loops call it; n_layer is how many of them one
-    chip runs (a pipeline stage's share under pp)."""
+    """One block as the layer scan calls it; n_layer is how many of them one
+    chip runs (a pipeline stage's share under pp): a policy-``checkpoint``
+    that keeps the block's input and, of its named residuals, those the chip
+    has room for with ``remat`` and all of them without. Left to its own AD
+    the scan stacks every elementwise intermediate too (the gelu alone: five
+    ``[n_layer, B, S, d_ff]`` tensors beside its input), and copying those in
+    and out of the stacks cost the gpt2-124m step 9.2 of its 74.0 ms and 4.2
+    of its 9.25 GiB; recomputing them costs 0.5 ms (PERF.md §6, PR 30).
+    Without remat that holds only where the names cover every output that is
+    dear to make again — the flash kernel's and the dense MLP's; XLA and ring
+    attention and the experts tag none of theirs, so those blocks stay as AD
+    leaves them."""
     block_fn = partial(_block, cfg=cfg)
     if cfg.remat:
-        policy = _remat_policy(cfg, global_batch, seq, mesh, n_layer)
-        block_fn = jax.checkpoint(
-            block_fn,
-            policy=jax.checkpoint_policies.save_only_these_names(*policy.saved),
-        )
-    return block_fn
+        saved = _remat_policy(cfg, global_batch, seq, mesh, n_layer).saved
+    elif _flash(cfg, mesh) and cfg.moe_experts == 0:
+        saved = scopes.RESIDUALS
+    else:
+        return block_fn
+    return jax.checkpoint(
+        block_fn,
+        policy=jax.checkpoint_policies.save_only_these_names(*saved),
+    )
+
+
+def _run_blocks(block_fn, x, layers):
+    """x through the blocks whose parameters are stacked in ``layers``."""
+    def scan_body(x, layer_params):
+        return block_fn(x, layer_params), None
+
+    x, _ = lax.scan(scan_body, x, layers)
+    return x
 
 
 def _blocks_pipelined(blocks, x, cfg: GPT2Config, mesh, pp: int):
@@ -534,15 +562,7 @@ def _blocks_pipelined(blocks, x, cfg: GPT2Config, mesh, pp: int):
     stage_params = stages_from_layers(blocks, pp)
 
     def stage_fn(layers, h):
-        if cfg.scan_layers:
-            def body(h, lp):
-                return block_fn(h, lp), None
-
-            h, _ = lax.scan(body, h, layers)
-            return h
-        for i in range(lpp):
-            h = block_fn(h, jax.tree_util.tree_map(lambda p: p[i], layers))
-        return h
+        return _run_blocks(block_fn, h, layers)
 
     return pipeline_apply(
         stage_fn, stage_params, x,
@@ -569,15 +589,7 @@ def _trunk(params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config) -> jax.Ar
     block_fn = _make_block_fn(cfg, B, S, mesh, cfg.n_layer)
     if cfg.moe_experts > 0:
         x = (x, jnp.zeros((), jnp.float32))  # thread the aux loss
-    if cfg.scan_layers:
-        def scan_body(x, layer_params):
-            return block_fn(x, layer_params), None
-
-        x, _ = lax.scan(scan_body, x, params["blocks"])
-    else:
-        for i in range(cfg.n_layer):
-            layer = jax.tree_util.tree_map(lambda p: p[i], params["blocks"])
-            x = block_fn(x, layer)
+    x = _run_blocks(block_fn, x, params["blocks"])
     aux = jnp.zeros((), jnp.float32)
     if cfg.moe_experts > 0:
         x, aux = x

@@ -1,8 +1,10 @@
 """The flash kernels, at the tiling the rule chooses, through the TPU's own
 compiler at real widths — for a v5e that is described, not attached. What
 interpret mode cannot show (a slice Mosaic cannot lay out, more scoped VMEM
-than a kernel may use) fails here, at no chip time. Nothing runs: no number
-comes from this file.
+than a kernel may use) fails here, at no chip time — and, since PR 30, the
+`gpt2-124m` cells' whole step, to see what the layer scan stacks in the
+program the cell's config compiles to. Nothing runs: no time comes from this
+file.
 
 Kept in ONE file and behind fixtures: only the xdist worker that is given this
 file loads the TPU's library (on-chip-measurement guide, section 2).
@@ -86,3 +88,56 @@ def test_ring_chunk_kernels_compile_for_the_v5e(one_chip, kernel):
                else jax.jit(bwd).lower(x, x, x, x, lse, x))
     hlo = lowered.compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
+    """The `gpt2-124m` cells' whole train step — the cell's own config through
+    `program_config`, composed as `make_train_step` composes it, the
+    benchmark's optimizer — compiled for one described chip. Its layer scan
+    writes ONE `[12, 8, 1024, 3072]` stack (the MLP's named hidden tensor),
+    where AD left alone made it write six (the gelu's intermediates), and the
+    step needs 5.07 GiB where that one needed 9.25. What a cell's config
+    compiles to is what PR 27 never looked at."""
+    import os
+    import re
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.families import gpt2 as family
+    from benchmarks.harness import spec
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel import mesh as mesh_lib
+    from ray_tpu.train import train_step
+
+    cell, config, _ = spec.load_cell("gpt2-124m.resident")
+    cfg = family.program_config(config, cell)
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(**cell["mesh"]),
+                              list(topo.devices)[:cell["chips"]])
+    optimizer = family._optimizer()
+    step_given, state_sh, batch_sh = train_step._compose_step(
+        gpt2, cfg, mesh, optimizer, None)
+    params = jax.eval_shape(lambda: gpt2.init(cfg, jax.random.PRNGKey(0)))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        {"params": params, "opt_state": jax.eval_shape(optimizer.init, params),
+         "step": jax.ShapeDtypeStruct((), jnp.int32)},
+        state_sh)
+    rows = cell["per_chip_batch"] * cell["chips"]
+    tokens = jax.ShapeDtypeStruct((rows, cfg.seq_len), jnp.int32,
+                                  sharding=batch_sh["tokens"])
+    # what a v5e chip states (memory_stats()["bytes_limit"]); without remat
+    # nothing reads it
+    step = jax.jit(step_given((16909334528, 0)),
+                   in_shardings=(state_sh, batch_sh),
+                   out_shardings=(state_sh, None), donate_argnums=(0,))
+    compiled = step.lower(state, {"tokens": tokens, "targets": tokens}).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    wide = re.escape(f"= bf16[{cfg.n_layer},{rows},{cfg.seq_len},{cfg.d_ff}]")
+    assert len(re.findall(wide + r"\S* dynamic-update-slice\(", hlo)) == 1
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < 5.5 * 2 ** 30, need / 2 ** 30

@@ -1,12 +1,16 @@
 """`remat=True` recomputes what does not fit (PR 28): the rule as a pure
 function of the shard and the chip's free bytes, the named residuals a
-policy-`checkpoint` keeps (scanned, unrolled, pipelined), the tags' cost with
-`remat=False` (none), and the `model/remat_policy` event.
+policy-`checkpoint` keeps (in the trunk's scan and a pipeline stage's), the
+`model/remat_policy` event — and `remat=False`, which keeps every name and
+lets the scan stack nothing else (PR 30).
 
 All on the CPU: the Pallas kernels interpret, the chip's memory is stated by
 the test through `parallel.mesh.chip_memory`, as the step factory states it on
 a TPU.
 """
+
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +25,10 @@ from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tracing import names
 from ray_tpu.train import train_step
 from ray_tpu.train.train_step import make_gpt2_train_step, synthetic_batch
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)               # the benchmark's families
 
 GIB = 2 ** 30
 V5E_BYTES_LIMIT = 16909336064          # memory_stats()["bytes_limit"] of a v5e chip
@@ -134,7 +142,6 @@ def test_block_shard_divides_by_the_mesh_axes_that_split(cpu_mesh8):
 # ------------------------------------------------- the checkpoint, end to end
 LAYER_LOOPS = {
     "scan": dict(),
-    "unrolled": dict(scan_layers=False),
     "pp2": dict(pipeline_microbatches=2),
 }
 BATCH = 4
@@ -221,12 +228,9 @@ def test_saved_o_and_lse_spare_the_second_forward_kernel_call(loop, cpu_mesh8):
 
 @pytest.mark.parametrize("loop", list(LAYER_LOOPS))
 def test_loss_and_every_gradient_equal_whatever_is_saved(loop, cpu_mesh8):
-    """remat=False, today's whole-block remat, o + lse saved, everything
-    saved: the same loss and gradients (bf16 activations, as the cells run).
-    Scanned and pipelined they are equal bit for bit; unrolled, XLA fuses the
-    twelve-fold inlined program differently with and without the checkpoint's
-    barriers, so there the bound is bf16's roundoff on each leaf's largest
-    entry, four times over."""
+    """remat=False (every name kept, no rule asked), whole-block remat, o +
+    lse saved, everything the rule can save: the same loss and gradients, bit
+    for bit (bf16 activations, as the cells run)."""
     want_loss, want, _, _ = _run(loop, False, None, cpu_mesh8)
     for admits in (None, 1, 6):
         loss, grads, _, decision = _run(loop, True, admits, cpu_mesh8)
@@ -234,16 +238,10 @@ def test_loss_and_every_gradient_equal_whatever_is_saved(loop, cpu_mesh8):
             assert tuple(decision["saved"]) == EVERYTHING
         for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
                                 jax.tree.leaves(want)):
-            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-            if loop == "unrolled":
-                np.testing.assert_allclose(
-                    g, w, rtol=0, atol=2.0 ** -6 * np.abs(w).max(),
-                    err_msg=jax.tree_util.keystr(path))
-            else:
-                np.testing.assert_array_equal(
-                    g, w, err_msg=jax.tree_util.keystr(path))
-        assert loss == pytest.approx(
-            want_loss, rel=2.0 ** -8 if loop == "unrolled" else 0, abs=0)
+            np.testing.assert_array_equal(
+                np.asarray(g, np.float32), np.asarray(w, np.float32),
+                err_msg=jax.tree_util.keystr(path))
+        assert loss == want_loss
 
 
 def test_named_residuals_are_what_the_checkpoint_saves():
@@ -272,20 +270,88 @@ def test_named_residuals_are_what_the_checkpoint_saves():
     assert kept(0) == []
     assert kept(1) == sorted([o, lse])
     assert kept(4) == sorted([o, o, o, o, lse])      # q, k and v beside them
+    # remat=False asks no rule and states no chip: every name, whatever fits
+    block_fn = gpt2._make_block_fn(gpt2.gpt2_tiny(attention_impl="pallas"),
+                                   BATCH, cfg.seq_len, None, cfg.n_layer)
+    everything = sorted(
+        (aval.shape, str(aval.dtype))
+        for aval, why in saved_residuals(block_fn, x, layer)
+        if not why.startswith("from the argument"))
+    assert everything == kept(6) and len(everything) == len(EVERYTHING)
 
 
-def test_tags_cost_nothing_without_remat(monkeypatch):
-    """remat=False: the step lowers to the same StableHLO with the tags as
-    with `checkpoint_name` patched to the identity (locations are not
-    printed; the counter behind private functions' `@name_<n>` symbols moves
-    with every primitive traced, so the numbers are taken off)."""
+# ----------------------------------------- remat=False: every name, no more
+# sizes a cell's file would state: 4 layers, so that a pipeline stage's share
+# (2) is no other loop's length (the schedule's 2 + 2 - 1 ticks), and a
+# sequence length that is no other dimension's size
+CELL_SIZES = dict(vocab_size=512, n_positions=32, n_layer=4, n_head=4, n_embd=64)
+
+
+def _stacks(jaxpr, length, seq):
+    """(last three dims, dtype) of every activation a `scan` of that length
+    anywhere in `jaxpr` stacks for later: its per-iteration outputs with a
+    sequence dim."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == length:
+            out += [(v.aval.shape[-3:], str(v.aval.dtype))
+                    for v in eqn.outvars[eqn.params["num_carry"]:]
+                    if seq in v.aval.shape[1:]]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _stacks(sub, length, seq)
+    return sorted(out)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["no_remat", "remat"])
+@pytest.mark.parametrize("where", ["trunk", "pipeline_stage"])
+def test_the_cell_step_stacks_the_named_residuals_and_nothing_else(
+        where, remat, cpu_mesh8, monkeypatch):
+    """The train step of a config built as the benchmark's cells build theirs
+    (`benchmarks/families/gpt2.program_config`: published sizes, `remat`,
+    nothing else), with attention resolved as on a TPU: the layer scan stacks
+    each block's input and, without remat, its seven named residuals — one
+    d_ff-wide tensor a layer, where AD left alone keeps six (the gelu's
+    intermediates), which the scan then copies in and out of its stacks. With
+    remat and no chip stated, the input alone."""
+    from benchmarks.families import gpt2 as family
+
+    # the CPU's choice would be the XLA einsum, whose S×S residuals carry no
+    # name; the cells run the kernel (interpreted here)
+    monkeypatch.setattr(attention, "resolve_attention",
+                        lambda impl, mesh=None: ("pallas", True))
+    cfg = family.program_config(CELL_SIZES, dict(remat=remat))
+    assert cfg.attention_impl == "auto"
+    mesh = (mesh_lib.make_mesh(mesh_lib.MeshSpec(pp=2), cpu_mesh8[:2])
+            if where == "pipeline_stage" else None)
+    n_layer = cfg.n_layer // 2 if mesh is not None else cfg.n_layer
+    bundle = make_gpt2_train_step(cfg, mesh=mesh)
+    jaxpr = jax.make_jaxpr(bundle.step_fn)(
+        bundle.state, synthetic_batch(cfg, BATCH)).jaxpr
+    # a pipeline stage sees one of the schedule's two microbatches at a time
+    B = BATCH // 2 if mesh is not None else BATCH
+    S, H, hd = cfg.seq_len, cfg.n_head, cfg.head_dim
+    x = ((B, S, cfg.d_model), "bfloat16")
+    named = ([((H, S, hd), "bfloat16")] * 4          # q, k, v, the kernel's o
+             + [((B, H, S), "float32"),              # its lse
+                x,                                   # after the attention add
+                ((B, S, cfg.d_ff), "bfloat16")])     # the MLP's hidden
+    assert _stacks(jaxpr, n_layer, S) == sorted([x] + ([] if remat else named))
+
+
+def test_tags_cost_nothing_where_nothing_is_differentiated(monkeypatch):
+    """`forward` (inference: no gradient, so no checkpoint and no scan
+    residual) lowers to the same StableHLO with the tags as with
+    `checkpoint_name` patched to the identity (locations are not printed;
+    the counter behind private functions' `@name_<n>` symbols moves with
+    every primitive traced, so the numbers are taken off)."""
     import re
 
     def lowered():
         cfg = gpt2.gpt2_tiny(attention_impl="pallas")
-        bundle = make_gpt2_train_step(cfg)
-        text = bundle.step_fn.lower(
-            bundle.state, synthetic_batch(cfg, 2)).as_text()
+        params = gpt2.init(cfg, jax.random.PRNGKey(0))
+        tokens = synthetic_batch(cfg, 2)["tokens"]
+        text = jax.jit(lambda p, t: gpt2.forward(p, t, cfg)).lower(
+            params, tokens).as_text()
         return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
 
     tagged = lowered()
@@ -355,7 +421,7 @@ def test_remat_policy_event_once_a_distinct_decision(buffer):
     assert [d["bytes_limit"] for d in mine] == [first, second]
 
 
-def test_remat_without_a_policy_checkpoint_records_nothing(buffer):
+def test_no_remat_asks_no_rule_and_records_nothing(buffer):
     cfg = gpt2.gpt2_tiny(attention_impl="pallas")
     params = gpt2.init(cfg, jax.random.PRNGKey(0))
     batch = synthetic_batch(cfg, 2)

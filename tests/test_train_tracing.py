@@ -25,11 +25,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-# what each lowering covers: the dense model with the Pallas kernels (layers
-# scanned and unrolled, remat on), and the MoE branch
+# what each lowering covers: the dense model with the Pallas kernels (remat
+# on: each block keeps its input alone on the CPU; off: its named residuals),
+# and the MoE branch
 LOWERINGS = {
-    "scan": dict(remat=True, attention_impl="pallas"),
-    "unrolled": dict(scan_layers=False, remat=True, attention_impl="pallas"),
+    "remat": dict(remat=True, attention_impl="pallas"),
+    "no_remat": dict(attention_impl="pallas"),
     "moe": dict(moe_experts=2, attention_impl="xla"),
 }
 DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE)
@@ -61,9 +62,9 @@ def _has_scope(op_names, scope):
 
 
 @pytest.mark.parametrize("scope", DENSE_SCOPES)
-@pytest.mark.parametrize("layers", ["scan", "unrolled"])
-def test_scope_in_lowered_step(layers, scope):
-    op_names, _ = _lowering(layers)
+@pytest.mark.parametrize("blocks", ["remat", "no_remat"])
+def test_scope_in_lowered_step(blocks, scope):
+    op_names, _ = _lowering(blocks)
     assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
 
 
@@ -73,17 +74,26 @@ def test_moe_scope_stands_where_mlp_stands():
     assert not _has_scope(op_names, names.MLP)
 
 
-@pytest.mark.parametrize("layers", ["scan", "unrolled"])
-def test_remat_recompute_keeps_the_block_scopes(layers):
-    op_names, _ = _lowering(layers)
-    for scope in names.BLOCK_SCOPES:
+@pytest.mark.parametrize("blocks", ["remat", "no_remat"])
+def test_remat_recompute_keeps_the_block_scopes(blocks):
+    """What a block runs again in its backward carries the block's scopes:
+    all of it with remat and nothing saved; without remat only the work
+    between the named residuals (the layer norms, the gelu), never the
+    flash kernel."""
+    op_names, _ = _lowering(blocks)
+    again = (names.BLOCK_SCOPES if blocks == "remat"
+             else (names.LN1, names.LN2, names.MLP))
+    for scope in again:
         want = f"rematted_computation/{names.BLOCK}/{scope}/"
         assert any(want in n for n in op_names), want
+    recomputed_kernel = [n for n in op_names if "rematted_computation" in n
+                         and names.FLASH_FWD_KERNEL in n]
+    assert bool(recomputed_kernel) == (blocks == "remat")
 
 
 @pytest.mark.parametrize("kernel", names.KERNELS)
 def test_kernel_name_in_jaxpr(kernel):
-    _, jaxpr = _lowering("scan")
+    _, jaxpr = _lowering("remat")
     assert f"name={kernel}" in jaxpr
 
 
@@ -94,7 +104,7 @@ def test_flash_tiling_decision_of_the_lowered_step(kernel):
     from ray_tpu.models import gpt2
     from ray_tpu.ops import attention
 
-    _lowering("scan")
+    _lowering("remat")
     cfg = gpt2.gpt2_tiny()
     mine = [d for d in attention.flash_tiling_decisions()
             if (d["kernel"], d["rows"], d["Sq"], d["hd"])
