@@ -8,7 +8,10 @@ worker leased every chip → in that worker a mesh over ``jax.devices()``,
 ``make_gpt2_train_step(gpt2_124m())`` at full width and depth, batches from
 ``get_dataset_shard("train").iter_batches(sharding=…)``, ``step_fn`` for a few
 tens of steps and ``train.report`` per step. Weights and tokens are random,
-from a seed. It then checks what came back (see check_training/check_device).
+from a seed. Then one step of a small EvaByte (``models/llama.py`` with the
+EVA mixer, ``remat=True``) through the same factory, for the ``ops/eva_tiling``
+and ``model/remat_policy`` decisions it is traced with. It then checks what
+came back (see check_training/check_device).
 
 This process never initialises a JAX backend: a chip belongs to one process
 and that process is the train worker, so every device fact below travelled
@@ -91,7 +94,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     from ray_tpu.models.gpt2 import remat_policy_decisions
     from ray_tpu.ops.attention import flash_tiling_decisions, resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
-    from ray_tpu.train.train_step import default_optimizer, make_gpt2_train_step
+    from ray_tpu.train.train_step import (
+        default_optimizer, make_gpt2_train_step, make_train_step)
 
     cfg, steps = config["model"], config["steps"]
     cache_events: Counter = Counter()
@@ -173,6 +177,26 @@ def train_loop(config: Dict[str, Any]) -> None:
         parity[key] = {"loss": float(m["loss"]),
                        "grad_norm": float(m["grad_norm"])}
         del variant
+    # One step of an EVA model through the same factory: its kernels' tiling
+    # decisions and the remat rule's on this block's shapes.
+    eva = None
+    if config.get("eva_model") is not None:
+        from ray_tpu.models import llama
+        from ray_tpu.ops.eva_attention import eva_tiling_decisions
+
+        eva_cfg = config["eva_model"]
+        variant = make_train_step(
+            llama, eva_cfg, mesh=mesh, rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP, total_steps=steps))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, eva_cfg.seq_len), dtype=np.int32)
+        _, m = variant.step_fn(variant.state, jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding))
+        eva = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+               "seq_len": eva_cfg.seq_len,
+               "attention": list(resolve_attention(eva_cfg.attention_impl, mesh)),
+               "tiling": eva_tiling_decisions()}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -197,11 +221,12 @@ def train_loop(config: Dict[str, Any]) -> None:
         "cache_events": dict(cache_events),
         "parity_rows": parity_rows,
         "parity": parity,
+        "eva": eva,
     }})
 
 
 def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
-        use_tpu: bool, seed: int = 0) -> List[Dict[str, Any]]:
+        use_tpu: bool, seed: int = 0, eva_model=None) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -222,6 +247,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         train_loop_config={
             "model": model_cfg, "steps": steps,
             "per_chip_batch": per_chip_batch, "seed": seed,
+            "eva_model": eva_model,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -263,6 +289,19 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
     # the model/remat_policy decision the remat=True step was traced with
     if not summary["remat_policy"]:
         bad.append("the remat=True step recorded no remat policy decision")
+    eva = summary["eva"]
+    if eva is not None:
+        if not (math.isfinite(eva["loss"]) and math.isfinite(eva["grad_norm"])):
+            bad.append(f"the EVA step's loss {eva['loss']} or grad_norm "
+                       f"{eva['grad_norm']} is not finite")
+        if not any(d["seq"] == eva["seq_len"] for d in summary["remat_policy"]):
+            bad.append("the EVA step recorded no remat policy decision")
+        # where the kernels run (compiled or interpreted) each is traced with
+        # a tiling decision; the XLA formulation has none to make
+        kernels = {d["kernel"] for d in eva["tiling"]}
+        if eva["attention"][0] == "pallas" and kernels != {"fwd", "bwd"}:
+            bad.append(f"the EVA step recorded tiling decisions for "
+                       f"{sorted(kernels)}, not for fwd and bwd")
     return bad
 
 
@@ -305,9 +344,13 @@ def main() -> int:
 
     import ray_tpu
     from ray_tpu.core.resources import tpu_device_files
-    from ray_tpu.models import gpt2
+    from ray_tpu.models import gpt2, llama
 
     model_cfg = gpt2.gpt2_124m()
+    # EvaByte's block at an eighth of its width: heads of 128, two windows of
+    # 2,048 bytes, so the second window's queries see 128 summaries
+    eva_cfg = llama.evabyte_6p5b(n_layer=2, n_head=8, n_kv_head=8, d_model=1024,
+                                 d_ff=2816, seq_len=4096, remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -318,7 +361,7 @@ def main() -> int:
                   f"files: {tpu_device_files() or 'none'})", file=sys.stderr)
             return 2
         rows = run(model_cfg, steps=STEPS, per_chip_batch=PER_CHIP_BATCH,
-                   num_devices=chips, use_tpu=True)
+                   num_devices=chips, use_tpu=True, eva_model=eva_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -350,6 +393,16 @@ def main() -> int:
               f"{d['saved_bytes'] / gib:.2f} GiB of a budget of "
               f"{d['budget_bytes'] / gib:.2f} (bytes_limit "
               f"{d['bytes_limit'] / gib:.2f} GiB)")
+    eva = summary["eva"]
+    for d in eva["tiling"]:
+        print(f"eva tiling: {d['kernel']} rows={d['rows']} S={d['Sq']} "
+              f"hd={d['hd']} window={d['window']} chunk={d['chunk']} -> "
+              f"block_q={d['block_q']} block_k={d['block_k']} vmem_estimate="
+              f"{d['vmem_estimate'] / 2 ** 20:.2f} MiB")
+    print(f"eva step ({eva_cfg.n_layer} layers of {eva_cfg.d_model}, "
+          f"{summary['device_count']}x{eva['seq_len']} bytes, remat): "
+          f"attention {eva['attention']}, loss {eva['loss']:.4f} "
+          f"grad_norm {eva['grad_norm']:.4f}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

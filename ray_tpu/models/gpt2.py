@@ -353,7 +353,8 @@ def _block(x, layer_params, cfg: GPT2Config):
 class BlockShard(NamedTuple):
     """One chip's share of a step, in elements: global shapes ÷ the mesh axes
     that split them. Everything the remat rule computes, it computes from
-    this and n_layer."""
+    this and n_layer. The model states its block's shapes (block_shard here,
+    llama.block_shard); the defaults are GPT-2's block."""
     batch: int            # rows of the batch on this chip
     seq: int
     d_model: int
@@ -362,8 +363,19 @@ class BlockShard(NamedTuple):
     d_ff: int             # MLP hidden width on this chip
     vocab: int            # LM-head columns on this chip
     dtype_bytes: int      # of an activation
-    flash: bool           # attention is the flash kernel: its o and lse exist
-    dense_mlp: bool       # the MLP is the dense one: its hidden tensor exists
+    flash: bool           # attention is a Pallas kernel: its o and lse exist
+    dense_mlp: bool       # the MLP is the dense one: its hidden tensors exist
+    kv_heads: int = 0     # heads of k and v where q has more (0: as many)
+    # the dense MLP's named hidden tensors, each d_ff wide: one before a
+    # gelu, two (gate, up) in a SwiGLU
+    mlp_hidden: Tuple[str, ...] = (scopes.RES_MLP_HIDDEN,)
+    window: int = 0       # > 0: the EVA mixer (ops/eva_attention.py) — a query
+    chunk: int = 0        # sees its window and one summary a chunk before it
+    # rows of the sequence the LM head and the MLP take at a time (0: all of
+    # them). An MLP that takes fewer makes its hidden tensors again in each
+    # chunk's backward: they are no candidates
+    head_rows: int = 0
+    mlp_rows: int = 0
 
 
 class RematPolicy(NamedTuple):
@@ -381,33 +393,43 @@ _MXU = 128                   # a matmul dim below this still costs a full pass
 _decisions: Dict[tuple, Dict[str, Any]] = {}
 
 
-def block_shard(cfg: GPT2Config, global_batch: int, seq: int, mesh,
-                flash: bool) -> BlockShard:
-    """cfg's block on one chip of ``mesh``: batch over the data axes that
-    divide it, heads / MLP width / vocab over tp, the sequence over cp."""
+def shard_block(whole: BlockShard, mesh) -> BlockShard:
+    """A block stated in global shapes, on one chip of ``mesh``: batch over
+    the data axes that divide it, heads / MLP width / vocab over tp, the
+    sequence over cp."""
     from ray_tpu.ops.attention import batch_head_axes
 
-    heads, d_ff, vocab = cfg.n_head, cfg.d_ff, cfg.padded_vocab
-    batch = global_batch
-    if mesh is not None:
-        batch_axes, head_ax = batch_head_axes(mesh, global_batch, heads)
-        for ax in batch_axes or ():
-            batch //= mesh.shape[ax]
-        tp, cp = mesh.shape.get("tp", 1), mesh.shape.get("cp", 1)
-        if head_ax:
-            heads //= tp
-        if d_ff % tp == 0:
-            d_ff //= tp
-        if vocab % tp == 0:
-            vocab //= tp
-        if seq % cp == 0:
-            seq //= cp
-    return BlockShard(
-        batch=batch, seq=seq, d_model=cfg.d_model, heads=heads,
-        head_dim=cfg.head_dim, d_ff=d_ff, vocab=vocab,
+    if mesh is None:
+        return whole
+    batch, heads, kv_heads = whole.batch, whole.heads, whole.kv_heads
+    d_ff, vocab, seq = whole.d_ff, whole.vocab, whole.seq
+    batch_axes, head_ax = batch_head_axes(mesh, batch, heads)
+    for ax in batch_axes or ():
+        batch //= mesh.shape[ax]
+    tp, cp = mesh.shape.get("tp", 1), mesh.shape.get("cp", 1)
+    if head_ax:
+        heads //= tp
+        if kv_heads % tp == 0:
+            kv_heads //= tp
+    if d_ff % tp == 0:
+        d_ff //= tp
+    if vocab % tp == 0:
+        vocab //= tp
+    if seq % cp == 0:
+        seq //= cp
+    return whole._replace(batch=batch, heads=heads, kv_heads=kv_heads,
+                          d_ff=d_ff, vocab=vocab, seq=seq)
+
+
+def block_shard(cfg: GPT2Config, global_batch: int, seq: int, mesh,
+                flash: bool) -> BlockShard:
+    """cfg's block on one chip of ``mesh``."""
+    return shard_block(BlockShard(
+        batch=global_batch, seq=seq, d_model=cfg.d_model, heads=cfg.n_head,
+        head_dim=cfg.head_dim, d_ff=cfg.d_ff, vocab=cfg.padded_vocab,
         dtype_bytes=jnp.dtype(cfg.dtype).itemsize, flash=flash,
         dense_mlp=cfg.moe_experts == 0,
-    )
+    ), mesh)
 
 
 def remat_candidates(s: BlockShard) -> List[Tuple[Tuple[str, ...], int, int]]:
@@ -418,14 +440,34 @@ def remat_candidates(s: BlockShard) -> List[Tuple[Tuple[str, ...], int, int]]:
     all come to K FLOPs per element; the flash kernel's o comes to about
     2·S per element (causal: half of two S×S matmuls, whose head_dim side
     fills the MXU only from 128 up), so it leads at long sequences and
-    trails at short ones. lse goes with o: neither is of use alone."""
+    trails at short ones. lse goes with o: neither is of use alone.
+
+    A block with the EVA mixer has that kernel's o and lse in their place: a
+    query's keys are half its window and, on average, the summaries of half
+    the sequence — (w + S/c − w/c) per element where causal attention has S.
+    Its summaries (1/chunk the size of k and v) come from a pass over k and v
+    that is a few operations an element: they trail everything."""
     tokens = s.batch * s.seq
     a = s.dtype_bytes
     attn_width = s.heads * s.head_dim
-    qkv_flops = 2 * tokens * s.d_model * attn_width
-    out = [((name,), tokens * attn_width * a, qkv_flops)
-           for name in (scopes.RES_Q, scopes.RES_K, scopes.RES_V)]
-    if s.flash:
+    kv_width = (s.kv_heads or s.heads) * s.head_dim
+    out = [((name,), tokens * width * a, 2 * tokens * s.d_model * width)
+           for name, width in ((scopes.RES_Q, attn_width),
+                               (scopes.RES_K, kv_width),
+                               (scopes.RES_V, kv_width))]
+    if s.flash and s.window:
+        keys = s.window + (s.seq - s.window) // s.chunk      # twice the mean
+        out.append((
+            (scopes.RES_EVA_O, scopes.RES_EVA_LSE),
+            tokens * s.heads * (s.head_dim * a + 4),
+            2 * s.batch * s.heads * s.seq * keys * max(s.head_dim, _MXU),
+        ))
+        out.append((
+            (scopes.RES_EVA_KT, scopes.RES_EVA_VT),
+            2 * tokens // s.chunk * attn_width * a,
+            6 * tokens * attn_width,
+        ))
+    elif s.flash:
         out.append((
             (scopes.RES_FLASH_O, scopes.RES_FLASH_LSE),
             tokens * s.heads * (s.head_dim * a + 4),
@@ -433,9 +475,9 @@ def remat_candidates(s: BlockShard) -> List[Tuple[Tuple[str, ...], int, int]]:
         ))
     out.append(((scopes.RES_MID,), tokens * s.d_model * a,
                 2 * tokens * attn_width * s.d_model))
-    if s.dense_mlp:
-        out.append(((scopes.RES_MLP_HIDDEN,), tokens * s.d_ff * a,
-                    2 * tokens * s.d_model * s.d_ff))
+    if s.dense_mlp and s.mlp_rows in (0, s.seq):
+        out += [((name,), tokens * s.d_ff * a, 2 * tokens * s.d_model * s.d_ff)
+                for name in s.mlp_hidden]
     return sorted(out, key=lambda c: -c[2] / c[1])
 
 
@@ -452,8 +494,11 @@ def rematted_working_set(s: BlockShard, n_layer: int) -> int:
     tokens = s.batch * s.seq
     a = s.dtype_bytes
     stack = n_layer * tokens * s.d_model * a
-    head = tokens * s.vocab * (2 * a + 4)
-    block = tokens * a * (4 * s.d_model + 4 * s.heads * s.head_dim + 2 * s.d_ff)
+    head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
+    # each of the MLP's hidden tensors beside its gradient
+    block = a * (tokens * (4 * s.d_model + 4 * s.heads * s.head_dim)
+                 + s.batch * (s.mlp_rows or s.seq)
+                 * 2 * len(s.mlp_hidden) * s.d_ff)
     gathered = s.vocab * s.d_model * (a + 4)
     return stack + head + block + gathered
 
@@ -491,15 +536,13 @@ def _flash(cfg: GPT2Config, mesh) -> bool:
     return resolve_attention(cfg.attention_impl, mesh)[0] == "pallas"
 
 
-def _remat_policy(cfg: GPT2Config, global_batch: int, seq: int, mesh,
-                  n_layer: int) -> RematPolicy:
+def _remat_policy(shard: BlockShard, n_layer: int) -> RematPolicy:
     """choose_remat_policy for the step being traced, recorded. A static
     choice has no hit rate; its counter is the choice: each distinct one goes
     once, as an instant event, to the task-event buffer
     (→ ``ray_tpu.timeline()``)."""
     from ray_tpu.parallel import mesh as mesh_lib
 
-    shard = block_shard(cfg, global_batch, seq, mesh, _flash(cfg, mesh))
     policy = choose_remat_policy(shard, n_layer,
                                  *mesh_lib.current_chip_memory())
     args = dict(zip(scopes.REMAT_POLICY_ARGS,
@@ -513,24 +556,23 @@ def _remat_policy(cfg: GPT2Config, global_batch: int, seq: int, mesh,
     return policy
 
 
-def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
-                   n_layer: int):
-    """One block as the layer scan calls it; n_layer is how many of them one
-    chip runs (a pipeline stage's share under pp): a policy-``checkpoint``
-    that keeps the block's input and, of its named residuals, those the chip
-    has room for with ``remat`` and all of them without. Left to its own AD
-    the scan stacks every elementwise intermediate too (the gelu alone: five
-    ``[n_layer, B, S, d_ff]`` tensors beside its input), and copying those in
-    and out of the stacks cost the gpt2-124m step 9.2 of its 74.0 ms and 4.2
-    of its 9.25 GiB; recomputing them costs 0.5 ms (PERF.md §6, PR 30).
-    Without remat that holds only where the names cover every output that is
-    dear to make again — the flash kernel's and the dense MLP's; XLA and ring
-    attention and the experts tag none of theirs, so those blocks stay as AD
-    leaves them."""
-    block_fn = partial(_block, cfg=cfg)
-    if cfg.remat:
-        saved = _remat_policy(cfg, global_batch, seq, mesh, n_layer).saved
-    elif _flash(cfg, mesh) and cfg.moe_experts == 0:
+def _checkpointed(block_fn, remat: bool, shard: BlockShard, n_layer: int):
+    """``block_fn(x, layer_params)`` as the layer scan calls it, for any model
+    whose block carries the names of tracing/names.RESIDUALS; n_layer is how
+    many of them one chip runs (a pipeline stage's share under pp): a
+    policy-``checkpoint`` that keeps the block's input and, of its named
+    residuals, those the chip has room for with ``remat`` and all of them
+    without. Left to its own AD the scan stacks every elementwise
+    intermediate too (the gelu alone: five ``[n_layer, B, S, d_ff]`` tensors
+    beside its input), and copying those in and out of the stacks cost the
+    gpt2-124m step 9.2 of its 74.0 ms and 4.2 of its 9.25 GiB; recomputing
+    them costs 0.5 ms (PERF.md §6, PR 30). Without remat that holds only
+    where the names cover every output that is dear to make again — a Pallas
+    attention kernel's and the dense MLP's; XLA and ring attention and the
+    experts tag none of theirs, so those blocks stay as AD leaves them."""
+    if remat:
+        saved = _remat_policy(shard, n_layer).saved
+    elif shard.flash and shard.dense_mlp:
         saved = scopes.RESIDUALS
     else:
         return block_fn
@@ -538,6 +580,14 @@ def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
         block_fn,
         policy=jax.checkpoint_policies.save_only_these_names(*saved),
     )
+
+
+def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
+                   n_layer: int):
+    """GPT-2's block, checkpointed for this step's shard (_checkpointed)."""
+    return _checkpointed(
+        partial(_block, cfg=cfg), cfg.remat,
+        block_shard(cfg, global_batch, seq, mesh, _flash(cfg, mesh)), n_layer)
 
 
 def _run_blocks(block_fn, x, layers):

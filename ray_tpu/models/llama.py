@@ -2,12 +2,19 @@
 
 Second flagship model family beside GPT-2 (models/gpt2.py): the modern
 decoder recipe — RMSNorm (pre-norm, no biases), SwiGLU MLP, rotary position
-embeddings, grouped-query attention, untied LM head. Same TPU-first
-structure as GPT-2: stacked layers under `lax.scan` (or unrolled), logical
-axis names on every parameter so any dp/fsdp/tp/cp mesh works through
-parallel/sharding.py rules, bf16 compute over f32 params, the Pallas flash
-kernel in head-major layout, and the fused softmax cross-entropy
-(ops/cross_entropy.py).
+embeddings, grouped-query attention, untied LM head. It runs on GPT-2's
+machinery, not a copy of it: the same layer scan and policy-``checkpoint``
+(``gpt2._run_blocks`` / ``_checkpointed``), the same remat rule on this
+block's own shapes, the same scopes and residual names (tracing/names.py);
+logical axis names on every parameter so any dp/fsdp/tp mesh works through
+parallel/sharding.py rules, bf16 compute over f32 params, the Pallas kernels
+in head-major layout.
+
+The config selects the mixer — ``causal`` (flash attention) or ``eva``
+(ops/eva_attention.py: exact softmax inside a window, one learned summary a
+chunk of every earlier window) — the number of prediction heads (head p
+predicts token t + 1 + p) and the norm's unit offset: with ``eva``, eight
+heads and the offset this is EvaByte (``evabyte_6p5b``).
 
 Numerics anchor: tests/test_llama_model.py checks logits against
 HuggingFace transformers' LlamaForCausalLM on a tiny config — RoPE layout,
@@ -21,15 +28,25 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.models import gpt2
+from ray_tpu.models.gpt2 import _round_up
+from ray_tpu.tracing import names as scopes
 
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
+# the head's float32 logits of one sequence chunk stay under this
+# (_head_rows): [B, S, V] whole is 4.2 GB at llama_7b's 8 x 4,096 x 32,000
+_HEAD_CHUNK_BYTES = 2 ** 26
+# one hidden tensor of the MLP, for the rows of the sequence it takes at a
+# time, stays under this (_mlp_rows): a SwiGLU's backward holds about five of
+# them — 3.4 GB at 32,768 x 11,008, which one chip does not have beside
+# EvaByte's state
+_MLP_CHUNK_BYTES = 2 ** 28
 
 
 @dataclass(frozen=True)
@@ -45,11 +62,31 @@ class LlamaConfig:
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    remat: bool = False           # True: recompute each block in the backward
+    remat: bool = False           # as GPT2Config.remat: True recomputes what
+                                  # does not fit (gpt2.choose_remat_policy)
     attention_impl: str = "auto"  # auto | xla | pallas
-    scan_layers: bool = True
+    mixer: str = "causal"         # causal | eva (window, chunk)
+    window: int = 0
+    chunk: int = 0
+    n_pred_heads: int = 1         # head p predicts token t + 1 + p
+    norm_unit_offset: bool = False   # norm scales by (1 + g), g born 0
+    init_std: float = 0.02
 
     def __post_init__(self):
+        if self.mixer not in ("causal", "eva"):
+            raise ValueError(f"unknown mixer {self.mixer!r}")
+        if self.mixer == "eva":
+            if self.n_kv_head != self.n_head:
+                raise ValueError("the eva mixer has no grouped heads: "
+                                 "n_kv_head must equal n_head")
+            if (self.chunk <= 0 or self.window % self.chunk
+                    or self.seq_len % self.window):
+                raise ValueError(
+                    f"the eva mixer needs chunk | window | seq_len; got "
+                    f"chunk={self.chunk} window={self.window} "
+                    f"seq_len={self.seq_len}")
+        if not isinstance(self.remat, bool):
+            raise ValueError(f"remat must be True or False; got {self.remat!r}")
         if self.n_head % self.n_kv_head:
             raise ValueError(
                 f"n_head={self.n_head} must be divisible by "
@@ -65,6 +102,12 @@ class LlamaConfig:
     @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab_size, 128)
+
+    @property
+    def head_vocab(self) -> int:
+        """Columns of one prediction head: the vocabulary padded so that the
+        heads' one matmul is a whole number of 128-lane tiles wide."""
+        return _round_up(self.vocab_size, 128 // math.gcd(self.n_pred_heads, 128))
 
 
 def llama_tiny(**overrides) -> LlamaConfig:
@@ -89,6 +132,28 @@ def llama_7b(**overrides) -> LlamaConfig:
     )
 
 
+def evabyte_6p5b(**overrides) -> LlamaConfig:
+    """EvaByte 6.5B as published (huggingface.co/EvaByte/EvaByte): llama_7b's
+    widths over bytes, EVA attention, eight heads."""
+    return replace(
+        llama_7b(vocab_size=320, seq_len=32768, rope_theta=100000.0,
+                 mixer="eva", window=2048, chunk=16, n_pred_heads=8,
+                 norm_unit_offset=True, init_std=0.01275),
+        **overrides,
+    )
+
+
+def evabyte_tiny(**overrides) -> LlamaConfig:
+    """Test-size EvaByte: 4 windows of 64, chunks of 8, 4 heads."""
+    return replace(
+        LlamaConfig(vocab_size=320, seq_len=256, n_layer=2, n_head=4,
+                    n_kv_head=4, d_model=128, d_ff=352, rope_theta=100000.0,
+                    mixer="eva", window=64, chunk=8, n_pred_heads=4,
+                    norm_unit_offset=True, init_std=0.01275),
+        **overrides,
+    )
+
+
 # --------------------------------------------------------------------------- #
 # Parameters
 # --------------------------------------------------------------------------- #
@@ -105,6 +170,8 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
+    if cfg.mixer == "eva":
+        blocks["eva_phi"] = blocks["eva_mu"] = ("layers", "heads", "kv")
     return {
         "wte": ("vocab", "embed"),
         "blocks": blocks,
@@ -129,28 +196,40 @@ def init(cfg: LlamaConfig, rng: jax.Array) -> Dict[str, Any]:
     D, H, KH, hd = cfg.d_model, cfg.n_head, cfg.n_kv_head, cfg.head_dim
     F, L, V = cfg.d_ff, cfg.n_layer, cfg.padded_vocab
     pd = cfg.param_dtype
-    keys = iter(jax.random.split(rng, 9))
-    std = 0.02
+    keys = iter(jax.random.split(rng, 11))
+    std = cfg.init_std
+    # a norm's scale is 1 at birth: g = 1, or g = 0 under (1 + g)
+    norm_init = jnp.zeros if cfg.norm_unit_offset else jnp.ones
 
     def normal(key, shape, s=std):
         return (jax.random.normal(key, shape) * s).astype(pd)
 
+    def eva_vector(key):
+        # clip(N(0, 1), -1, 1) · hd^-1/2
+        return (jnp.clip(jax.random.normal(key, (L, H, hd)), -1.0, 1.0)
+                / math.sqrt(hd)).astype(pd)
+
     blocks = {
-        "attn_norm": jnp.ones((L, D), pd),
+        "attn_norm": norm_init((L, D), pd),
         "wq": normal(next(keys), (L, D, H, hd)),
         "wk": normal(next(keys), (L, D, KH, hd)),
         "wv": normal(next(keys), (L, D, KH, hd)),
         "wo": normal(next(keys), (L, H, hd, D), std / math.sqrt(2 * L)),
-        "mlp_norm": jnp.ones((L, D), pd),
+        "mlp_norm": norm_init((L, D), pd),
         "w_gate": normal(next(keys), (L, D, F)),
         "w_up": normal(next(keys), (L, D, F)),
         "w_down": normal(next(keys), (L, F, D), std / math.sqrt(2 * L)),
     }
+    wte, lm_head = normal(next(keys), (V, D)), normal(
+        next(keys), (D, cfg.n_pred_heads * cfg.head_vocab))
+    if cfg.mixer == "eva":
+        blocks["eva_phi"] = eva_vector(next(keys))
+        blocks["eva_mu"] = eva_vector(next(keys))
     return {
-        "wte": normal(next(keys), (V, D)),
+        "wte": wte,
         "blocks": blocks,
-        "final_norm": jnp.ones((D,), pd),
-        "lm_head": normal(next(keys), (D, V)),
+        "final_norm": norm_init((D,), pd),
+        "lm_head": lm_head,
     }
 
 
@@ -169,9 +248,10 @@ def param_count(cfg: LlamaConfig) -> int:
 # Forward
 # --------------------------------------------------------------------------- #
 
-def _rmsnorm(x, scale, eps):
+def _rmsnorm(x, g, cfg: LlamaConfig):
     xf = x.astype(jnp.float32)
-    rms = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    rms = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + cfg.rms_eps)
+    scale = 1.0 + g.astype(jnp.float32) if cfg.norm_unit_offset else g
     return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
 
 
@@ -184,22 +264,29 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
     )
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
-    cos = jnp.cos(angles)
-    sin = jnp.sin(angles)
-    x1, x2 = x[..., :half], x[..., half:]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    out = jnp.concatenate(
-        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
-    )
-    return out.astype(x.dtype)
+    # x·cos + rotate_half(x)·sin, rotate_half(x) = [-x2, x1] = x @ R with R a
+    # signed permutation (exact in any dtype): a [hd, hd] matmul a head, 0.5 %
+    # of a block's operations, where slicing the head dim in two makes
+    # tensors of half a head — 64 of 128 lanes, each taking what a whole one
+    # does; four stood in HBM in the 32,768-token backward
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)             # [S, hd]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    eye = jnp.eye(half, dtype=x.dtype)
+    zero = jnp.zeros_like(eye)
+    rot = jnp.block([[zero, eye], [-eye, zero]])                      # x @ rot
+    rotated = jnp.einsum("...d,de->...e", x, rot)
+    return (x.astype(jnp.float32) * cos
+            + rotated.astype(jnp.float32) * sin).astype(x.dtype)
 
 
-def _attention(q, k, v, cfg: LlamaConfig):
-    """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd], causal, GQA."""
-    groups = cfg.n_head // cfg.n_kv_head
-    if groups > 1:
-        k = jnp.repeat(k, groups, axis=1)
-        v = jnp.repeat(v, groups, axis=1)
+def _residual_add(x, y):
+    """x + y in float32, the stream stored in x's dtype (the released
+    EvaByte's ``fp32_skip_add``; y is a matmul's float32 accumulator)."""
+    return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype)
+
+
+def _attention(q, k, v, p, cfg: LlamaConfig):
+    """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd]: the config's mixer."""
     from ray_tpu.ops.attention import flash_attention_sharded, resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
 
@@ -210,6 +297,19 @@ def _attention(q, k, v, cfg: LlamaConfig):
             "models/llama.py has no ring-attention path; use a mesh without "
             "a cp axis"
         )
+    if cfg.mixer == "eva":
+        from ray_tpu.ops import eva_attention as eva
+
+        phi, mu = p["eva_phi"], p["eva_mu"]
+        if impl == "pallas":
+            return eva.eva_attention_sharded(
+                q, k, v, phi, mu, mesh, window=cfg.window, chunk=cfg.chunk)
+        return eva.eva_attention_xla(
+            q, k, v, phi, mu, window=cfg.window, chunk=cfg.chunk)
+    groups = cfg.n_head // cfg.n_kv_head
+    if groups > 1:
+        k = jnp.repeat(k, groups, axis=1)
+        v = jnp.repeat(v, groups, axis=1)
     if impl == "pallas":
         return flash_attention_sharded(
             q, k, v, mesh, causal=True, interpret=interpret
@@ -223,58 +323,202 @@ def _attention(q, k, v, cfg: LlamaConfig):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def _block(x, p, positions, cfg: LlamaConfig):
-    dt = cfg.dtype
-    h = _rmsnorm(x, p["attn_norm"], cfg.rms_eps)
-    q = jnp.einsum("bsd,dhk->bhsk", h, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bhsk", h, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bhsk", h, p["wv"].astype(dt))
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    attn = _attention(q, k, v, cfg)
-    x = x + jnp.einsum("bhsk,hkd->bsd", attn, p["wo"].astype(dt))
-    h = _rmsnorm(x, p["mlp_norm"], cfg.rms_eps)
-    gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, p["w_gate"].astype(dt)))
-    up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(dt))
-    return x + jnp.einsum("bsf,fd->bsd", gate * up, p["w_down"].astype(dt))
+_MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def _cast_in_the_loop(p, x, dt):
+    """The layer's matmul weights in the compute dtype, cast inside the layer
+    loop. A plain ``astype`` of a layer sliced out of the stack the TPU
+    compiler turns into one cast of the WHOLE stack before the loop (through
+    an ``optimization_barrier`` too) and keeps the copy for the length of the
+    step: 1.5 GB beside EvaByte's four layers, which the chip does not have.
+    A factor of one that depends on the loop's carry keeps the cast where it
+    is written; it costs a read of the layer's f32 weights a use, 0.6 % of
+    the 32,768-token step."""
+    one = lax.stop_gradient(1.0 + 0.0 * x[0, 0, 0].astype(jnp.float32))
+    return {k: (p[k] * one).astype(dt) for k in _MATMUL_WEIGHTS}
+
+
+@jax.named_scope(scopes.BLOCK)
+def _block(x, p, cfg: LlamaConfig):
+    """One block, x [B, S, D], under GPT-2's scopes and residual names."""
+    positions = jnp.arange(x.shape[1])
+    p = {**p, **_cast_in_the_loop(p, x, cfg.dtype)}
+    with jax.named_scope(scopes.LN1):
+        h = _rmsnorm(x, p["attn_norm"], cfg)
+    with jax.named_scope(scopes.QKV):
+        # named after the rotation: a kept q or k is not rotated again
+        q = checkpoint_name(_rope(
+            jnp.einsum("bsd,dhk->bhsk", h, p["wq"]),
+            positions, cfg.rope_theta), scopes.RES_Q)
+        k = checkpoint_name(_rope(
+            jnp.einsum("bsd,dhk->bhsk", h, p["wk"]),
+            positions, cfg.rope_theta), scopes.RES_K)
+        v = checkpoint_name(
+            jnp.einsum("bsd,dhk->bhsk", h, p["wv"]), scopes.RES_V)
+    with jax.named_scope(scopes.ATTN):
+        attn = _attention(q, k, v, p, cfg)
+    with jax.named_scope(scopes.PROJ):
+        x = checkpoint_name(_residual_add(x, jnp.einsum(
+            "bhsk,hkd->bsd", attn, p["wo"],
+            preferred_element_type=jnp.float32)), scopes.RES_MID)
+    with jax.named_scope(scopes.LN2):
+        h = _rmsnorm(x, p["mlp_norm"], cfg)
+    with jax.named_scope(scopes.MLP):
+        return _mlp(x, h, p, cfg)
+
+
+def _swiglu(x, h, w_gate, w_up, w_down):
+    """x + down(silu(gate(h)) · up(h)) on [B, rows, D]."""
+    gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, w_gate),
+                           scopes.RES_MLP_GATE)
+    up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, w_up),
+                         scopes.RES_MLP_UP)
+    return _residual_add(x, jnp.einsum(
+        "bsf,fd->bsd", jax.nn.silu(gate) * up, w_down,
+        preferred_element_type=jnp.float32))
+
+
+def _mlp(x, h, p, cfg: LlamaConfig):
+    """The block's second half. Where one hidden tensor of the whole sequence
+    would pass _MLP_CHUNK_BYTES the sequence goes through in chunks, each its
+    own ``checkpoint``: a chunk's hidden tensors are made again in its
+    backward and never exist for the whole sequence (nor can a remat policy
+    keep them: llama.block_shard tells the rule so)."""
+    B, S, D = x.shape
+    w = [p[k] for k in ("w_gate", "w_up", "w_down")]      # cast by the block
+    rows = _mlp_rows(B, S, cfg.d_ff, x.dtype.itemsize)
+    if rows == S:
+        return _swiglu(x, h, *w)
+    chunks = lambda a: a.reshape(B, S // rows, rows, D).swapaxes(0, 1)
+    out = lax.map(jax.checkpoint(lambda xh: _swiglu(*xh, *w)),
+                  (chunks(x), chunks(h)))
+    return out.swapaxes(0, 1).reshape(B, S, D)
+
+
+def _rows_under(seq: int, bytes_a_row: int, limit: int) -> int:
+    """The largest power-of-two fraction of ``seq`` whose rows stay under
+    ``limit`` bytes (``seq`` itself where they do)."""
+    rows = seq
+    while rows % 2 == 0 and rows * bytes_a_row > limit:
+        rows //= 2
+    return rows
+
+
+def _mlp_rows(batch: int, seq: int, d_ff: int, itemsize: int) -> int:
+    """Rows of the sequence the MLP takes at a time."""
+    return _rows_under(seq, batch * d_ff * itemsize, _MLP_CHUNK_BYTES)
+
+
+def _head_rows(batch: int, seq: int, columns: int) -> int:
+    """Rows of the sequence the head takes at a time: their float32 logits
+    stay under _HEAD_CHUNK_BYTES."""
+    return _rows_under(seq, batch * columns * 4, _HEAD_CHUNK_BYTES)
+
+
+def block_shard(cfg: LlamaConfig, global_batch: int, seq: int,
+                mesh) -> gpt2.BlockShard:
+    """This config's block on one chip of ``mesh``, for the remat rule: the
+    shapes of ITS residuals (two hidden tensors of d_ff, k and v of n_kv_head
+    heads, with eva the window and the chunk the summaries come from)."""
+    from ray_tpu.ops.attention import resolve_attention
+
+    columns = cfg.n_pred_heads * cfg.head_vocab
+    return gpt2.shard_block(gpt2.BlockShard(
+        batch=global_batch, seq=seq, d_model=cfg.d_model, heads=cfg.n_head,
+        head_dim=cfg.head_dim, d_ff=cfg.d_ff, vocab=columns,
+        dtype_bytes=jnp.dtype(cfg.dtype).itemsize,
+        flash=resolve_attention(cfg.attention_impl, mesh)[0] == "pallas",
+        dense_mlp=True, kv_heads=cfg.n_kv_head,
+        mlp_hidden=(scopes.RES_MLP_GATE, scopes.RES_MLP_UP),
+        window=cfg.window if cfg.mixer == "eva" else 0, chunk=cfg.chunk,
+        head_rows=_head_rows(global_batch, seq, columns),
+        mlp_rows=_mlp_rows(global_batch, seq, cfg.d_ff,
+                           jnp.dtype(cfg.dtype).itemsize),
+    ), mesh)
 
 
 def _trunk(params, tokens, cfg: LlamaConfig):
+    """tokens [B, S] int32 → final hidden states [B, S, D]."""
+    from ray_tpu.parallel import mesh as mesh_lib
+
     B, S = tokens.shape
-    dt = cfg.dtype
-    x = params["wte"].astype(dt)[tokens]
-    positions = jnp.arange(S)
-
-    block_fn = partial(_block, positions=positions, cfg=cfg)
-    if cfg.remat:
-        block_fn = jax.checkpoint(block_fn)
-
-    if cfg.scan_layers:
-        def body(x, layer):
-            return block_fn(x, layer), None
-
-        x, _ = lax.scan(body, x, params["blocks"])
-    else:
-        for i in range(cfg.n_layer):
-            layer = jax.tree_util.tree_map(lambda p: p[i], params["blocks"])
-            x = block_fn(x, layer)
-    return _rmsnorm(x, params["final_norm"], cfg.rms_eps)
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]
+    block_fn = gpt2._checkpointed(
+        partial(_block, cfg=cfg), cfg.remat,
+        block_shard(cfg, B, S, mesh_lib.current_mesh()), cfg.n_layer)
+    x = gpt2._run_blocks(block_fn, x, params["blocks"])
+    with jax.named_scope(scopes.LN_F):
+        return _rmsnorm(x, params["final_norm"], cfg)
 
 
 def forward(params, tokens, cfg: LlamaConfig) -> jax.Array:
-    """tokens [B, S] int32 → logits [B, S, padded_vocab]."""
+    """tokens [B, S] int32 → logits [B, S, n_pred_heads · head_vocab], head p
+    in columns p·head_vocab … (p+1)·head_vocab."""
     x = _trunk(params, tokens, cfg)
     return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype))
 
 
-def loss_fn(params, tokens, targets, cfg: LlamaConfig) -> jax.Array:
-    """Mean next-token CE over targets >= 0 (fused CE, no [B,S,V] residual)."""
-    from ray_tpu.ops.cross_entropy import softmax_xent
+def head_targets(targets: jax.Array, n_heads: int) -> jax.Array:
+    """targets [B, S] (the next token, -1 = ignore) → [B, S, n_heads]: head
+    p's target at t is targets[t + p], -1 past the row's end."""
+    S = targets.shape[1]
+    padded = jnp.pad(targets, ((0, 0), (0, n_heads - 1)), constant_values=-1)
+    return jnp.stack([padded[:, p:p + S] for p in range(n_heads)], axis=-1)
 
-    logits = forward(params, tokens, cfg)
-    nll = softmax_xent(logits, targets)
-    count = jnp.sum(targets >= 0)
-    return jnp.sum(nll) / jnp.maximum(count, 1)
+
+def _chunk_nll(x_c, targets_c, lm_head, n_heads: int):
+    """[B, c, D] hidden + [B, c, P] targets → (sum nll [P], count [P]); the
+    logits are float32 out of the matmul (``fp32_logits``)."""
+    logits = jnp.einsum("bsd,dv->bsv", x_c, lm_head,
+                        preferred_element_type=jnp.float32)
+    logp = jax.nn.log_softmax(
+        logits.reshape(logits.shape[:2] + (n_heads, -1)), axis=-1)
+    mask = targets_c >= 0
+    safe = jnp.where(mask, targets_c, 0)
+    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+    return jnp.sum(nll * mask, axis=(0, 1)), jnp.sum(mask, axis=(0, 1))
+
+
+@jax.named_scope(scopes.LM_HEAD_LOSS)
+def _lm_head_loss(x, targets, lm_head, cfg: LlamaConfig) -> jax.Array:
+    """Untied head(s) + cross-entropy over final hidden states [B, S, D]: the
+    mean over the heads of each head's mean over its valid targets. Chunked
+    over the sequence as gpt2._lm_head_loss is (scan + rematerialised chunk
+    logits) wherever the whole [B, S, V] float32 logits would pass
+    _HEAD_CHUNK_BYTES: they and their gradient are never one tensor."""
+    B, S = targets.shape
+    P = cfg.n_pred_heads
+    lm_head = lm_head.astype(cfg.dtype)
+    rows = _head_rows(B, S, lm_head.shape[1])
+    if P == 1 and rows == S:
+        from ray_tpu.ops.cross_entropy import softmax_xent
+
+        # fused CE (ops/cross_entropy.py): no [B, S, V] float32 residual
+        nll = softmax_xent(jnp.einsum("bsd,dv->bsv", x, lm_head), targets)
+        return jnp.sum(nll) / jnp.maximum(jnp.sum(targets >= 0), 1)
+    tp = head_targets(targets, P)
+    xc = x.reshape(B, S // rows, rows, -1).swapaxes(0, 1)         # [n, B, c, D]
+    tc = tp.reshape(B, S // rows, rows, P).swapaxes(0, 1)         # [n, B, c, P]
+    chunk_fn = jax.checkpoint(partial(_chunk_nll, lm_head=lm_head, n_heads=P))
+
+    def scan_body(carry, xs):
+        total, count = carry
+        s, c = chunk_fn(*xs)
+        return (total + s, count + c), None
+
+    (total, count), _ = lax.scan(
+        scan_body, (jnp.zeros((P,), jnp.float32), jnp.zeros((P,), jnp.int32)),
+        (xc, tc))
+    return jnp.mean(total / jnp.maximum(count, 1))
+
+
+def loss_fn(params, tokens, targets, cfg: LlamaConfig) -> jax.Array:
+    """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token):
+    with n_pred_heads > 1 head p is scored on targets[t + p]."""
+    x = _trunk(params, tokens, cfg)
+    return _lm_head_loss(x, targets, params["lm_head"], cfg)
 
 
 def flops_per_token(cfg: LlamaConfig) -> float:

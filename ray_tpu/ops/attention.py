@@ -148,19 +148,25 @@ def flash_tiling_decisions() -> List[Dict[str, Any]]:
     return list(_decisions.values())
 
 
+def record_decision(decisions: Dict[tuple, Dict[str, Any]], event: str,
+                    args: Dict[str, Any]) -> None:
+    """A static choice has no hit rate; its counter is the choice. Each
+    distinct one goes once, as the instant event ``<component>/<name>``, to
+    the task-event buffer (→ ``ray_tpu.timeline()``), and into ``decisions``."""
+    key = tuple(args.values())
+    if key in decisions:
+        return
+    decisions[key] = args
+    component, name = event.split("/")
+    get_buffer().record_profile(name, component=component, args=args)
+
+
 def _record(kernel: str, rows: int, Sq: int, Skv: int, hd: int,
             tiling: Tiling) -> None:
-    """A static choice has no hit rate; its counter is the choice. Each
-    distinct one goes once, as an instant event, to the task-event buffer
-    (→ ``ray_tpu.timeline()``), with the shapes the kernel was given."""
-    args = dict(zip(names.FLASH_TILING_ARGS,
-                    (kernel, rows, Sq, Skv, hd) + tuple(tiling)))
-    key = tuple(args.values())
-    if key in _decisions:
-        return
-    _decisions[key] = args
-    component, name = names.FLASH_TILING.split("/")
-    get_buffer().record_profile(name, component=component, args=args)
+    """The tiling a flash kernel is traced with, with the shapes it was
+    given (``ops/flash_tiling``)."""
+    record_decision(_decisions, names.FLASH_TILING, dict(zip(
+        names.FLASH_TILING_ARGS, (kernel, rows, Sq, Skv, hd) + tuple(tiling))))
 
 
 def choose_tiling(

@@ -26,30 +26,50 @@ LM_HEAD_LOSS = "lm_head_loss"
 OPTIMIZER = "optimizer"
 # the public entry points of ops/attention.py (carried by the shard_map too)
 FLASH_ATTENTION = "flash_attention"
+# ops/eva_attention.py: the whole op (summaries, both kernels' calls), and
+# inside it the chunk-summary pass alone
+EVA_ATTENTION = "eva_attention"
+EVA_PREP_KV = "eva_prep_kv"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
-                                          FLASH_ATTENTION)
+                                          FLASH_ATTENTION, EVA_ATTENTION,
+                                          EVA_PREP_KV)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
 FLASH_BWD_KERNEL = "flash_attention_bwd"
-KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL)
+# EVA's aggregation (one softmax over a window's own keys and the summaries
+# of every earlier window), forward and backward; the summary pass is XLA
+EVA_AGG_FWD_KERNEL = "eva_agg_fwd"
+EVA_AGG_BWD_KERNEL = "eva_agg_bwd"
+KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
+           EVA_AGG_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
 FLASH_TILING_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
                      "vmem_estimate")
+# the same for an EVA kernel (ops/eva_attention.py): Sq = Skv = the sequence
+EVA_TILING = "ops/eva_tiling"
+EVA_TILING_ARGS = FLASH_TILING_ARGS + ("window", "chunk")
 
 # the block's residuals a `remat=True` checkpoint may keep, one name a tensor
 # (`jax.ad_checkpoint.checkpoint_name`; an identity outside such a checkpoint):
 # the three qkv einsums' outputs, the flash forward kernel's output and
 # logsumexp (tagged in ops/attention._flash_fwd, where lse exists), x after
-# the attention residual add, the MLP's hidden pre-activation
+# the attention residual add, the MLP's hidden pre-activation. A block has
+# the names of the tensors it computes: with the EVA mixer the aggregation
+# kernel's output and log-normaliser and the chunk summaries stand where
+# flash_o / flash_lse stand; a gated (SwiGLU) MLP has two hidden tensors.
 RES_Q, RES_K, RES_V = "block_q", "block_k", "block_v"
 RES_FLASH_O, RES_FLASH_LSE = "flash_o", "flash_lse"
+RES_EVA_O, RES_EVA_LSE = "eva_o", "eva_lse"
+RES_EVA_KT, RES_EVA_VT = "eva_k_summary", "eva_v_summary"
 RES_MID = "block_mid"
 RES_MLP_HIDDEN = "mlp_hidden"
+RES_MLP_GATE, RES_MLP_UP = "mlp_gate", "mlp_up"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
-             RES_MLP_HIDDEN)
+             RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
+             RES_MLP_GATE, RES_MLP_UP)
 # which of them models/gpt2.py chose to save: one instant event per distinct
 # decision, at trace time, in the task-event buffer
 REMAT_POLICY = "model/remat_policy"

@@ -206,21 +206,26 @@ def _apply_optimizer(optimizer, grads, state):
     return new_params, new_opt, optax.global_norm(grads)
 
 
-def _chip_memory(mesh: Mesh, state) -> Tuple[Optional[int], int]:
-    """(bytes_limit, resident bytes) of one chip for a step over the placed
-    ``state``: the smallest limit this process's devices of the mesh report
-    (None when one reports none: the CPU backend), and what stays on a chip
-    through the whole step — its shard of every state leaf, and of the
+def _resident_bytes(state) -> int:
+    """What stays on a chip through a whole step over ``state`` (placed, or
+    abstract with shardings): its shard of every state leaf, and of the
     gradients, which are the parameters' bytes again."""
     def on_chip(tree):
         return sum(
             math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
             for x in jax.tree.leaves(tree))
 
+    return on_chip(state) + on_chip(state["params"])
+
+
+def _chip_memory(mesh: Mesh, state) -> Tuple[Optional[int], int]:
+    """(bytes_limit, resident bytes) of one chip for a step over the placed
+    ``state``: the smallest limit this process's devices of the mesh report
+    (None when one reports none: the CPU backend), and _resident_bytes."""
     stats = [d.memory_stats() for d in mesh.local_devices]
     limits = [s.get("bytes_limit") if s else None for s in stats]
     limit = None if None in limits else min(limits)
-    return limit, on_chip(state) + on_chip(state["params"])
+    return limit, _resident_bytes(state)
 
 
 def _step_counter(mesh: Mesh) -> jax.Array:

@@ -225,11 +225,19 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     import chip_smoke
     from ray_tpu.models import gpt2
 
+    from ray_tpu.models import llama
+
     cfg, steps = gpt2.gpt2_tiny(), 16
+    eva_cfg = llama.evabyte_tiny(remat=True, attention_impl="pallas")
     rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1,
-                          num_devices=8, use_tpu=False)
+                          num_devices=8, use_tpu=False, eva_model=eva_cfg)
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
+    # the EVA step: interpreted kernels under the fsdp=8 shard_map, a row a
+    # device, with their tiling decisions and the rule's
+    assert summary["eva"]["attention"] == ["pallas", True]
+    assert {d["kernel"] for d in summary["eva"]["tiling"]} == {"fwd", "bwd"}
+    assert all(d["rows"] == eva_cfg.n_head for d in summary["eva"]["tiling"])
     assert summary["platforms"] == ["cpu"] and summary["device_count"] == 8
     assert summary["mesh"] == {"fsdp": 8} and summary["global_batch"] == 8
     assert summary["attention"] == ["xla", True]   # today's CPU-mesh choice

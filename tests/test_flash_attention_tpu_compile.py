@@ -90,6 +90,37 @@ def test_ring_chunk_kernels_compile_for_the_v5e(one_chip, kernel):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
 
 
+def test_eva_kernels_compile_for_the_v5e_at_the_cells_shape(one_chip):
+    """The EVA aggregation kernels at the `evabyte-6.5b-l4.dataset` shard —
+    32 heads of 128 over one row of 32,768 bytes, windows of 2,048, chunks of
+    16 — forward and backward: Mosaic takes the 512/512 tile of the flash
+    rule and the VMEM limit the backward asks for (its estimate passes the
+    16 MiB default: a window's q, k, v, do, dq, dk, dv and the summaries'
+    f32 accumulators stand whole)."""
+    from ray_tpu.ops import eva_attention as eva
+    from ray_tpu.ops.attention import VMEM_BUDGET_BYTES
+
+    B, H, S, hd = 1, 32, 32768, 128
+    x = jax.ShapeDtypeStruct((B, H, S, hd), jnp.bfloat16, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((H, hd), jnp.float32, sharding=one_chip)
+
+    def grads(*args):
+        def loss(*a):
+            return jnp.sum(eva._eva(*a, window=2048, chunk=16,
+                                    interpret=False).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+    hlo = jax.jit(grads).lower(x, x, x, vec, vec).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    mine = {d["kernel"]: d for d in eva.eva_tiling_decisions()
+            if (d["rows"], d["Sq"], d["hd"]) == (B * H, S, hd)}
+    assert set(mine) == {"fwd", "bwd"}
+    assert all((d["block_q"], d["block_k"], d["window"], d["chunk"])
+               == (512, 512, 2048, 16) for d in mine.values())
+    assert mine["fwd"]["vmem_estimate"] <= VMEM_BUDGET_BYTES
+    assert VMEM_BUDGET_BYTES < mine["bwd"]["vmem_estimate"] < 2 * VMEM_BUDGET_BYTES
+
+
 def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
     """The `gpt2-124m` cells' whole train step — the cell's own config through
     `program_config`, composed as `make_train_step` composes it, the

@@ -32,8 +32,17 @@ LOWERINGS = {
     "remat": dict(remat=True, attention_impl="pallas"),
     "no_remat": dict(attention_impl="pallas"),
     "moe": dict(moe_experts=2, attention_impl="xla"),
+    # the llama-family block with the EVA mixer (EvaByte, PR 31)
+    "eva": dict(remat=True, attention_impl="pallas"),
 }
-DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE)
+# each model's own mixer: GPT-2 has the flash kernels, EvaByte the EVA ones
+EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
+EVA_KERNELS = (names.EVA_AGG_FWD_KERNEL, names.EVA_AGG_BWD_KERNEL)
+FLASH_KERNELS = (names.FLASH_FWD_KERNEL, names.FLASH_BWD_KERNEL)
+DENSE_SCOPES = tuple(s for s in names.SCOPES
+                     if s != names.MOE and s not in EVA_SCOPES)
+EVABYTE_SCOPES = tuple(s for s in names.SCOPES
+                       if s not in (names.MOE, names.FLASH_ATTENTION))
 _lowered = {}
 
 
@@ -42,12 +51,16 @@ def _lowering(key):
     if key not in _lowered:
         import jax
 
-        from ray_tpu.models import gpt2
+        from ray_tpu.models import gpt2, llama
         from ray_tpu.train.train_step import (
-            make_gpt2_train_step, synthetic_batch)
+            make_gpt2_train_step, make_train_step, synthetic_batch)
 
-        cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
-        bundle = make_gpt2_train_step(cfg)
+        if key == "eva":
+            cfg = llama.evabyte_tiny(**LOWERINGS[key])
+            bundle = make_train_step(llama, cfg)
+        else:
+            cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
+            bundle = make_gpt2_train_step(cfg)
         batch = synthetic_batch(cfg, 2)
         text = bundle.step_fn.lower(bundle.state, batch).as_text(debug_info=True)
         _lowered[key] = (
@@ -66,6 +79,15 @@ def _has_scope(op_names, scope):
 def test_scope_in_lowered_step(blocks, scope):
     op_names, _ = _lowering(blocks)
     assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+
+
+@pytest.mark.parametrize("scope", EVABYTE_SCOPES)
+def test_scope_in_lowered_evabyte_step(scope):
+    """The llama-family block carries GPT-2's scopes, and the EVA op its two."""
+    op_names, _ = _lowering("eva")
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+    if scope == names.EVA_PREP_KV:       # the summary pass, inside the op
+        assert _has_scope(op_names, f"{names.EVA_ATTENTION}/{scope}")
 
 
 def test_moe_scope_stands_where_mlp_stands():
@@ -91,10 +113,37 @@ def test_remat_recompute_keeps_the_block_scopes(blocks):
     assert bool(recomputed_kernel) == (blocks == "remat")
 
 
+def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
+    assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS)
+
+
 @pytest.mark.parametrize("kernel", names.KERNELS)
 def test_kernel_name_in_jaxpr(kernel):
-    _, jaxpr = _lowering("remat")
+    _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else "remat")
     assert f"name={kernel}" in jaxpr
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_eva_tiling_decision_of_the_lowered_step(kernel):
+    """Tracing the EvaByte step leaves an `ops/eva_tiling` decision per
+    kernel, with the vocabulary's args, and a `model/remat_policy` one."""
+    from ray_tpu.models import gpt2, llama
+    from ray_tpu.ops import eva_attention
+
+    _lowering("eva")
+    cfg = llama.evabyte_tiny()
+    mine = [d for d in eva_attention.eva_tiling_decisions()
+            if (d["kernel"], d["rows"], d["Sq"], d["hd"])
+            == (kernel, 2 * cfg.n_head, cfg.seq_len, cfg.head_dim)]
+    # one a distinct decision: another test file's float32 model, traced in
+    # this process, is another (its blocks take twice the VMEM)
+    assert mine and len({d["vmem_estimate"] for d in mine}) == len(mine)
+    assert all(tuple(d) == names.EVA_TILING_ARGS for d in mine)
+    assert all((d["window"], d["chunk"]) == (cfg.window, cfg.chunk)
+               for d in mine)
+    assert any((d["n_layer"], d["batch"], d["seq"])
+               == (cfg.n_layer, 2, cfg.seq_len)
+               for d in gpt2.remat_policy_decisions())
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
