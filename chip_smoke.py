@@ -91,7 +91,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     import numpy as np
 
     from ray_tpu import train
-    from ray_tpu.models.gpt2 import remat_policy_decisions
+    from ray_tpu.models.gpt2 import (
+        compiler_rematerialized, remat_policy_decisions)
     from ray_tpu.ops.attention import flash_tiling_decisions, resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
     from ray_tpu.train.train_step import (
@@ -190,10 +191,16 @@ def train_loop(config: Dict[str, Any]) -> None:
             optimizer=default_optimizer(lr=LR, warmup=WARMUP, total_steps=steps))
         tokens = np.random.default_rng(config["seed"]).integers(
             0, ALPHABET, size=(n_dev, eva_cfg.seq_len), dtype=np.int32)
-        _, m = variant.step_fn(variant.state, jax.device_put(
-            with_targets({"tokens": tokens}), data_sharding))
+        eva_batch = jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding)
+        # one compile, read and run: what the compiler rematerialized by
+        # itself is recompute the remat rule did not choose
+        compiled = variant.step_fn.lower(variant.state, eva_batch).compile()
+        _, m = compiled(variant.state, eva_batch)
         eva = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
                "seq_len": eva_cfg.seq_len,
+               "compiler_rematerialized": len(
+                   compiler_rematerialized(compiled.as_text())),
                "attention": list(resolve_attention(eva_cfg.attention_impl, mesh)),
                "tiling": eva_tiling_decisions()}
         del variant
@@ -392,7 +399,8 @@ def main() -> int:
               f"seq={d['seq']} -> saved {d['saved'] or 'block inputs only'}, "
               f"{d['saved_bytes'] / gib:.2f} GiB of a budget of "
               f"{d['budget_bytes'] / gib:.2f} (bytes_limit "
-              f"{d['bytes_limit'] / gib:.2f} GiB)")
+              f"{d['bytes_limit'] / gib:.2f} GiB); MLP {d['mlp_rows']} rows "
+              f"at a time, head {d['head_rows']}")
     eva = summary["eva"]
     for d in eva["tiling"]:
         print(f"eva tiling: {d['kernel']} rows={d['rows']} S={d['Sq']} "
@@ -402,7 +410,8 @@ def main() -> int:
     print(f"eva step ({eva_cfg.n_layer} layers of {eva_cfg.d_model}, "
           f"{summary['device_count']}x{eva['seq_len']} bytes, remat): "
           f"attention {eva['attention']}, loss {eva['loss']:.4f} "
-          f"grad_norm {eva['grad_norm']:.4f}")
+          f"grad_norm {eva['grad_norm']:.4f}; instructions the compiler "
+          f"rematerialized by itself: {eva['compiler_rematerialized']}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

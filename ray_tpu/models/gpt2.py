@@ -18,6 +18,7 @@ data-parallel pretraining, tokens/sec/chip). TPU-first choices:
 from __future__ import annotations
 
 import math
+import re
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
@@ -376,12 +377,24 @@ class BlockShard(NamedTuple):
     # chunk's backward: they are no candidates
     head_rows: int = 0
     mlp_rows: int = 0
+    # the block casts its layer's matmul weights inside the layer loop
+    # (llama._cast_in_the_loop): one layer's stand in the block's backward
+    cast_in_loop: bool = False
+
+
+class RematCandidate(NamedTuple):
+    names: Tuple[str, ...]   # residuals kept together
+    nbytes: int              # what they take, a layer
+    flops: int               # what making them again costs, a layer
+    frees: int = 0           # bytes of rematted_working_set that are there
+                             # only while these are made again, not kept
 
 
 class RematPolicy(NamedTuple):
     saved: Tuple[str, ...]   # names.RESIDUALS a block keeps, in the order taken
     saved_bytes: int         # what they take on a chip, over n_layer layers
-    budget_bytes: int        # what was free for them (0: no limit is known)
+    budget_bytes: int        # what was free for them, with what keeping them
+                             # freed (0: no limit is known)
     bytes_limit: int         # the chip's own figure the budget came from, or 0
 
 
@@ -432,10 +445,11 @@ def block_shard(cfg: GPT2Config, global_batch: int, seq: int, mesh,
     ), mesh)
 
 
-def remat_candidates(s: BlockShard) -> List[Tuple[Tuple[str, ...], int, int]]:
+def remat_candidates(s: BlockShard) -> List[RematCandidate]:
     """The block's named residuals as (names kept together, bytes a layer,
-    FLOPs a layer to recompute them), most FLOPs per byte first; equal ones
-    stay in the block's own order. A matmul output of width N contracted over
+    FLOPs a layer to recompute them, bytes keeping them frees), most FLOPs
+    per byte first; of equal ones the one that frees more, then the block's
+    own order. A matmul output of width N contracted over
     K costs 2·K·N a row and holds N elements, so the qkv, proj and fc outputs
     all come to K FLOPs per element; the flash kernel's o comes to about
     2·S per element (causal: half of two S×S matmuls, whose head_dim side
@@ -446,39 +460,53 @@ def remat_candidates(s: BlockShard) -> List[Tuple[Tuple[str, ...], int, int]]:
     query's keys are half its window and, on average, the summaries of half
     the sequence — (w + S/c − w/c) per element where causal attention has S.
     Its summaries (1/chunk the size of k and v) come from a pass over k and v
-    that is a few operations an element: they trail everything."""
+    that is a few operations an element: they trail everything. That pass
+    reads k in float32, and a k that is made again stands in both precisions
+    from the block's second forward to the pass's backward — across the whole
+    MLP backward (_eva_k_f32). A kept k is read from its stack when the pass
+    needs it: keeping k frees those bytes, so k leads q."""
     tokens = s.batch * s.seq
     a = s.dtype_bytes
     attn_width = s.heads * s.head_dim
     kv_width = (s.kv_heads or s.heads) * s.head_dim
-    out = [((name,), tokens * width * a, 2 * tokens * s.d_model * width)
-           for name, width in ((scopes.RES_Q, attn_width),
-                               (scopes.RES_K, kv_width),
-                               (scopes.RES_V, kv_width))]
+    out = [RematCandidate((name,), tokens * width * a,
+                          2 * tokens * s.d_model * width, frees)
+           for name, width, frees in ((scopes.RES_Q, attn_width, 0),
+                                      (scopes.RES_K, kv_width, _eva_k_f32(s)),
+                                      (scopes.RES_V, kv_width, 0))]
     if s.flash and s.window:
         keys = s.window + (s.seq - s.window) // s.chunk      # twice the mean
-        out.append((
+        out.append(RematCandidate(
             (scopes.RES_EVA_O, scopes.RES_EVA_LSE),
             tokens * s.heads * (s.head_dim * a + 4),
             2 * s.batch * s.heads * s.seq * keys * max(s.head_dim, _MXU),
         ))
-        out.append((
+        out.append(RematCandidate(
             (scopes.RES_EVA_KT, scopes.RES_EVA_VT),
             2 * tokens // s.chunk * attn_width * a,
             6 * tokens * attn_width,
         ))
     elif s.flash:
-        out.append((
+        out.append(RematCandidate(
             (scopes.RES_FLASH_O, scopes.RES_FLASH_LSE),
             tokens * s.heads * (s.head_dim * a + 4),
             2 * s.batch * s.heads * s.seq * s.seq * max(s.head_dim, _MXU),
         ))
-    out.append(((scopes.RES_MID,), tokens * s.d_model * a,
-                2 * tokens * attn_width * s.d_model))
+    out.append(RematCandidate((scopes.RES_MID,), tokens * s.d_model * a,
+                              2 * tokens * attn_width * s.d_model))
     if s.dense_mlp and s.mlp_rows in (0, s.seq):
-        out += [((name,), tokens * s.d_ff * a, 2 * tokens * s.d_model * s.d_ff)
+        out += [RematCandidate((name,), tokens * s.d_ff * a,
+                               2 * tokens * s.d_model * s.d_ff)
                 for name in s.mlp_hidden]
-    return sorted(out, key=lambda c: -c[2] / c[1])
+    return sorted(out, key=lambda c: (-c.flops / c.nbytes, -c.frees))
+
+
+def _eva_k_f32(s: BlockShard) -> int:
+    """Bytes of the float32 k the EVA summary pass reads (0 with no window):
+    the compiler writes it beside k out of the rotation."""
+    if not s.window:
+        return 0
+    return s.batch * s.seq * (s.kv_heads or s.heads) * s.head_dim * 4
 
 
 def rematted_working_set(s: BlockShard, n_layer: int) -> int:
@@ -487,20 +515,34 @@ def rematted_working_set(s: BlockShard, n_layer: int) -> int:
     head's logits, their gradient and one float32 copy inside the softmax;
     one block's whole residual set, live while its backward runs; the
     largest parameter (the embedding) gathered in the compute dtype beside
-    its unreduced float32 gradient. An estimate from shapes — XLA's schedule
-    decides the real figure (PERF.md §6, PR 28: from 0.08 GiB under at the
-    cells' shapes to 8 over, compiled for a v5e) — which is what the reserve
-    is for."""
+    its unreduced float32 gradient. The block's set peaks in the MLP's
+    backward, where everything the attention's backward will read is already
+    made again and waits: four tensors of the stream's width and q, k, v, o,
+    beside each of the MLP's hidden tensors and its gradient (and a gated
+    MLP's product) for the rows it takes at a time. A block that states more
+    holds more there (PERF.md §6, PR 32: the 32,768-byte EvaByte step
+    compiled for a v5e). With the EVA mixer the summary pass's float32 k
+    waits too (_eva_k_f32), unless k is kept — remat_candidates says what
+    keeping it frees. Where the block casts its layer's weights inside the
+    loop they stand twice in the compute dtype: the cast, and the copy the
+    compiler moves ahead of the MLP's loop. An estimate from shapes — XLA's
+    schedule decides the real figure (PR 28: from 0.08 GiB under at the
+    GPT-2 cells' shapes to 8 over; PR 32: 0.13 GB over at the EvaByte cell's)
+    — which is what the reserve is for."""
     tokens = s.batch * s.seq
     a = s.dtype_bytes
+    attn_width = s.heads * s.head_dim
+    kv_width = (s.kv_heads or s.heads) * s.head_dim
     stack = n_layer * tokens * s.d_model * a
     head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
-    # each of the MLP's hidden tensors beside its gradient
-    block = a * (tokens * (4 * s.d_model + 4 * s.heads * s.head_dim)
-                 + s.batch * (s.mlp_rows or s.seq)
-                 * 2 * len(s.mlp_hidden) * s.d_ff)
+    hidden = 2 * len(s.mlp_hidden) + (len(s.mlp_hidden) - 1)
+    block = a * (tokens * (4 * s.d_model + 4 * attn_width)
+                 + s.batch * (s.mlp_rows or s.seq) * hidden * s.d_ff)
+    weights = 2 * a * s.d_model * (
+        2 * attn_width + 2 * kv_width + (len(s.mlp_hidden) + 1) * s.d_ff
+    ) if s.cast_in_loop else 0
     gathered = s.vocab * s.d_model * (a + 4)
-    return stack + head + block + gathered
+    return stack + head + block + _eva_k_f32(s) + weights + gathered
 
 
 def choose_remat_policy(shard: BlockShard, n_layer: int,
@@ -510,23 +552,33 @@ def choose_remat_policy(shard: BlockShard, n_layer: int,
     remat_candidates (most recompute FLOPs per byte first) and take each
     whose n_layer copies still fit what the chip has free — its bytes_limit
     less the reserve, what is resident (state and gradients) and the fully
-    rematted step's working set. With no limit stated, nothing."""
+    rematted step's working set, plus what keeping it frees of that set. With
+    no limit stated, nothing."""
     if bytes_limit is None:
         return RematPolicy((), 0, 0, 0)
-    budget = max(0, bytes_limit - REMAT_RESERVE_BYTES - resident_bytes
-                 - rematted_working_set(shard, n_layer))
+    budget = (bytes_limit - REMAT_RESERVE_BYTES - resident_bytes
+              - rematted_working_set(shard, n_layer))
     saved, used = [], 0
-    for group, nbytes, _ in remat_candidates(shard):
-        if used + n_layer * nbytes <= budget:
-            saved.extend(group)
-            used += n_layer * nbytes
-    return RematPolicy(tuple(saved), used, budget, bytes_limit)
+    for c in remat_candidates(shard):
+        if used + n_layer * c.nbytes <= budget + c.frees:
+            saved.extend(c.names)
+            used += n_layer * c.nbytes
+            budget += c.frees
+    return RematPolicy(tuple(saved), used, max(0, budget), bytes_limit)
 
 
 def remat_policy_decisions() -> List[Dict[str, Any]]:
     """Every distinct remat decision this process has traced a model with, as
     the ``model/remat_policy`` events carry them."""
     return list(_decisions.values())
+
+
+def compiler_rematerialized(hlo: str) -> List[str]:
+    """The instructions of a compiled step (``compiled.as_text()``) that
+    XLA's own rematerialization pass made: it clones what it frees early and
+    marks the clone's name ``.remat``. Each is recompute the rule did not
+    choose — the budget it spent was not there (PERF.md §6, PR 32)."""
+    return re.findall(r"^\s*(?:ROOT )?%?(\S*\.remat\S*) = ", hlo, re.M)
 
 
 def _flash(cfg: GPT2Config, mesh) -> bool:
@@ -547,7 +599,8 @@ def _remat_policy(shard: BlockShard, n_layer: int) -> RematPolicy:
                                  *mesh_lib.current_chip_memory())
     args = dict(zip(scopes.REMAT_POLICY_ARGS,
                     (n_layer, shard.batch, shard.seq, list(policy.saved))
-                    + policy[1:]))
+                    + policy[1:] + (shard.mlp_rows or shard.seq,
+                                    shard.head_rows or shard.seq)))
     key = (n_layer, shard) + policy
     if key not in _decisions:
         _decisions[key] = args

@@ -42,10 +42,10 @@ from ray_tpu.tracing import names as scopes
 # the head's float32 logits of one sequence chunk stay under this
 # (_head_rows): [B, S, V] whole is 4.2 GB at llama_7b's 8 x 4,096 x 32,000
 _HEAD_CHUNK_BYTES = 2 ** 26
-# one hidden tensor of the MLP, for the rows of the sequence it takes at a
-# time, stays under this (_mlp_rows): a SwiGLU's backward holds about five of
-# them — 3.4 GB at 32,768 x 11,008, which one chip does not have beside
-# EvaByte's state
+# an MLP whose hidden tensor of the whole sequence passes this takes the
+# sequence in chunks (_mlp_rows): a SwiGLU's backward holds five of them —
+# 3.6 GB at 32,768 x 11,008, which one chip does not have beside EvaByte's
+# state
 _MLP_CHUNK_BYTES = 2 ** 28
 
 
@@ -362,37 +362,41 @@ def _block(x, p, cfg: LlamaConfig):
         x = checkpoint_name(_residual_add(x, jnp.einsum(
             "bhsk,hkd->bsd", attn, p["wo"],
             preferred_element_type=jnp.float32)), scopes.RES_MID)
+    return _mlp(x, p, cfg)
+
+
+def _swiglu(x, p, cfg: LlamaConfig):
+    """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]."""
     with jax.named_scope(scopes.LN2):
         h = _rmsnorm(x, p["mlp_norm"], cfg)
     with jax.named_scope(scopes.MLP):
-        return _mlp(x, h, p, cfg)
+        gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
+                               scopes.RES_MLP_GATE)
+        up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
+                             scopes.RES_MLP_UP)
+        return _residual_add(x, jnp.einsum(
+            "bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
+            preferred_element_type=jnp.float32))
 
 
-def _swiglu(x, h, w_gate, w_up, w_down):
-    """x + down(silu(gate(h)) · up(h)) on [B, rows, D]."""
-    gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, w_gate),
-                           scopes.RES_MLP_GATE)
-    up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, w_up),
-                         scopes.RES_MLP_UP)
-    return _residual_add(x, jnp.einsum(
-        "bsf,fd->bsd", jax.nn.silu(gate) * up, w_down,
-        preferred_element_type=jnp.float32))
-
-
-def _mlp(x, h, p, cfg: LlamaConfig):
-    """The block's second half. Where one hidden tensor of the whole sequence
-    would pass _MLP_CHUNK_BYTES the sequence goes through in chunks, each its
-    own ``checkpoint``: a chunk's hidden tensors are made again in its
-    backward and never exist for the whole sequence (nor can a remat policy
-    keep them: llama.block_shard tells the rule so)."""
+def _mlp(x, p, cfg: LlamaConfig):
+    """The block's second half, norm and all. Where one hidden tensor of the
+    whole sequence would pass _MLP_CHUNK_BYTES the sequence goes through in
+    chunks (_mlp_rows), each its own ``checkpoint``: a chunk's hidden tensors
+    are made again in its backward and never exist for the whole sequence
+    (nor can a remat policy keep them: llama.block_shard tells the rule so).
+    The norm is the chunk's too — a row's norm needs the row alone — so the
+    loop's one input is the stream itself: the normed stream and its gradient
+    never stand whole beside it. That is 0.5 GB of the 32,768-byte EvaByte
+    step's peak, which falls in this loop's backward; with it, and k kept
+    where q was, the step fits without the compiler making k and v a second
+    time in every layer (PERF.md §6, PR 32)."""
     B, S, D = x.shape
-    w = [p[k] for k in ("w_gate", "w_up", "w_down")]      # cast by the block
-    rows = _mlp_rows(B, S, cfg.d_ff, x.dtype.itemsize)
+    rows = _mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
     if rows == S:
-        return _swiglu(x, h, *w)
-    chunks = lambda a: a.reshape(B, S // rows, rows, D).swapaxes(0, 1)
-    out = lax.map(jax.checkpoint(lambda xh: _swiglu(*xh, *w)),
-                  (chunks(x), chunks(h)))
+        return _swiglu(x, p, cfg)
+    chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
+    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg)), chunks)
     return out.swapaxes(0, 1).reshape(B, S, D)
 
 
@@ -405,9 +409,21 @@ def _rows_under(seq: int, bytes_a_row: int, limit: int) -> int:
     return rows
 
 
-def _mlp_rows(batch: int, seq: int, d_ff: int, itemsize: int) -> int:
-    """Rows of the sequence the MLP takes at a time."""
-    return _rows_under(seq, batch * d_ff * itemsize, _MLP_CHUNK_BYTES)
+def _mlp_rows(batch: int, seq: int, d_model: int, d_ff: int,
+              itemsize: int) -> int:
+    """Rows of the sequence the MLP takes at a time: all of them where a
+    hidden tensor of the whole sequence stays under _MLP_CHUNK_BYTES. A longer
+    sequence goes in chunks whose five hidden tensors together take what two
+    of the block's [B, S, D] activations do — a fifth more beside the eight
+    of that size that wait in the chunk's backward for the attention's
+    (gpt2.rematted_working_set). On the chip the 32,768-byte EvaByte step's
+    MLP backward takes 403.6 ms at the 4,096 rows this gives, 405.1 at 2,048
+    and 420.7 at 8,192, and its compiled step needs 0.7 GB less than at 8,192
+    (PERF.md §6, PR 32)."""
+    if batch * seq * d_ff * itemsize <= _MLP_CHUNK_BYTES:
+        return seq
+    return _rows_under(seq, 5 * batch * d_ff * itemsize,
+                       2 * batch * seq * d_model * itemsize)
 
 
 def _head_rows(batch: int, seq: int, columns: int) -> int:
@@ -433,8 +449,9 @@ def block_shard(cfg: LlamaConfig, global_batch: int, seq: int,
         mlp_hidden=(scopes.RES_MLP_GATE, scopes.RES_MLP_UP),
         window=cfg.window if cfg.mixer == "eva" else 0, chunk=cfg.chunk,
         head_rows=_head_rows(global_batch, seq, columns),
-        mlp_rows=_mlp_rows(global_batch, seq, cfg.d_ff,
+        mlp_rows=_mlp_rows(global_batch, seq, cfg.d_model, cfg.d_ff,
                            jnp.dtype(cfg.dtype).itemsize),
+        cast_in_loop=True,
     ), mesh)
 
 

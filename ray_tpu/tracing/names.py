@@ -70,11 +70,13 @@ RES_MLP_GATE, RES_MLP_UP = "mlp_gate", "mlp_up"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
              RES_MLP_GATE, RES_MLP_UP)
-# which of them models/gpt2.py chose to save: one instant event per distinct
-# decision, at trace time, in the task-event buffer
+# which of them models/gpt2.py chose to save, and the rows of the sequence
+# the block's MLP and the LM head take at a time (the sequence: all at once):
+# one instant event per distinct decision, at trace time, in the task-event
+# buffer
 REMAT_POLICY = "model/remat_policy"
 REMAT_POLICY_ARGS = ("n_layer", "batch", "seq", "saved", "saved_bytes",
-                     "budget_bytes", "bytes_limit")
+                     "budget_bytes", "bytes_limit", "mlp_rows", "head_rows")
 
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer

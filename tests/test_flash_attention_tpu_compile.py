@@ -121,6 +121,26 @@ def test_eva_kernels_compile_for_the_v5e_at_the_cells_shape(one_chip):
     assert VMEM_BUDGET_BYTES < mine["bwd"]["vmem_estimate"] < 2 * VMEM_BUDGET_BYTES
 
 
+def _cell_on(topo, name):
+    """(cell, config, its family, its mesh over the described chips) of a
+    BENCHMARK.json cell."""
+    import importlib
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.harness import spec
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    cell, config, _ = spec.load_cell(name)
+    family = importlib.import_module(f"benchmarks.families.{config['family']}")
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(**cell["mesh"]),
+                              list(topo.devices)[:cell["chips"]])
+    return cell, config, family, mesh
+
+
 def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
     """The `gpt2-124m` cells' whole train step — the cell's own config through
     `program_config`, composed as `make_train_step` composes it, the
@@ -129,23 +149,13 @@ def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
     where AD left alone made it write six (the gelu's intermediates), and the
     step needs 5.07 GiB where that one needed 9.25. What a cell's config
     compiles to is what PR 27 never looked at."""
-    import os
     import re
-    import sys
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from benchmarks.families import gpt2 as family
-    from benchmarks.harness import spec
     from ray_tpu.models import gpt2
-    from ray_tpu.parallel import mesh as mesh_lib
     from ray_tpu.train import train_step
 
-    cell, config, _ = spec.load_cell("gpt2-124m.resident")
+    cell, config, family, mesh = _cell_on(topo, "gpt2-124m.resident")
     cfg = family.program_config(config, cell)
-    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(**cell["mesh"]),
-                              list(topo.devices)[:cell["chips"]])
     optimizer = family._optimizer()
     step_given, state_sh, batch_sh = train_step._compose_step(
         gpt2, cfg, mesh, optimizer, None)
@@ -172,3 +182,36 @@ def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert need < 5.5 * 2 ** 30, need / 2 ** 30
+
+
+def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(
+        topo, monkeypatch):
+    """`evabyte-6.5b-l4.dataset`'s whole train step — the cell's config, the
+    step `train_step._compose_step` composes over `models/llama.py`, told a
+    v5e's bytes_limit (`families/evabyte.abstract_step`: what
+    `harness/rehearse_compile.py` compiles) — compiled for one described chip.
+    The remat rule keeps k and the summaries, and XLA's own rematerialization
+    pass, which runs when a step does not fit otherwise, cloned nothing: the
+    parent's step held the k and v projections and k's rotation a second time
+    in every layer's backward (`fusion.507.remat` …, 57 of the step's 1,591
+    ms on the chip). One compile, ~20 s alone; under its own limit, not the
+    suite's."""
+    from conftest import time_limit
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.tracing import names
+
+    # recorded once a process by its facts: this test reads its own
+    monkeypatch.setattr(gpt2, "_decisions", {})
+    cell, config, family, mesh = _cell_on(topo, "evabyte-6.5b-l4.dataset")
+    with time_limit(240, "the EvaByte cell's compile for a described v5e"):
+        step, args = family.abstract_step(config, cell, mesh)
+        hlo = step.lower(*args).compile().as_text()
+    assert gpt2.compiler_rematerialized(hlo) == []
+    # forward, the block's second forward and backward, a layer: the scan
+    # holds each once
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    (d,) = gpt2.remat_policy_decisions()
+    assert d["bytes_limit"] == family.V5E_BYTES_LIMIT
+    assert d["saved"] == [names.RES_K, names.RES_EVA_KT, names.RES_EVA_VT]
+    assert (d["mlp_rows"], d["head_rows"]) == (4096, 4096)

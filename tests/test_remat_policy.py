@@ -19,7 +19,7 @@ import pytest
 
 from ray_tpu import tracing
 from ray_tpu.core.config import _config
-from ray_tpu.models import gpt2
+from ray_tpu.models import gpt2, llama
 from ray_tpu.ops import attention
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tracing import names
@@ -45,6 +45,17 @@ SMALL = XL._replace(d_model=768, heads=12, d_ff=3072)
 SMALL_LAYERS = 12
 SMALL_RESIDENT = 1_493_700_000
 
+# the block of `evabyte-6.5b-l4.dataset`: one row of 32,768 bytes, four
+# published-width layers, 12 B a parameter resident
+EVA_CFG = llama.evabyte_6p5b(n_layer=4, remat=True, attention_impl="pallas")
+EVA = llama.block_shard(EVA_CFG, 1, EVA_CFG.seq_len, None)
+EVA_LAYERS = 4
+EVA_RESIDENT = 12 * llama.param_count(EVA_CFG)
+# what that cell's step compiles with for a v5e, nothing rematerialized by
+# the compiler (test_flash_attention_tpu_compile.py): k, whose float32 copy
+# for the summary pass goes with it, and the summaries
+EVA_KEPT = (names.RES_K, names.RES_EVA_KT, names.RES_EVA_VT)
+
 FLASH = (names.RES_FLASH_O, names.RES_FLASH_LSE)
 QKV = (names.RES_Q, names.RES_K, names.RES_V)
 EVERYTHING = FLASH + QKV + (names.RES_MID, names.RES_MLP_HIDDEN)
@@ -53,8 +64,8 @@ EVERYTHING = FLASH + QKV + (names.RES_MID, names.RES_MLP_HIDDEN)
 def _limit_admitting(shard, n_layer, resident, groups):
     """The smallest bytes_limit whose budget holds the first `groups`
     candidates of `shard`."""
-    need = sum(n_layer * nbytes
-               for _, nbytes, _ in gpt2.remat_candidates(shard)[:groups])
+    need = sum(n_layer * c.nbytes
+               for c in gpt2.remat_candidates(shard)[:groups])
     return (gpt2.REMAT_RESERVE_BYTES + resident
             + gpt2.rematted_working_set(shard, n_layer) + need)
 
@@ -73,6 +84,13 @@ RULE_CASES = {
     "xl_no_limit_reported": (XL, XL_LAYERS, None, XL_RESIDENT, ()),
     "gpt2_124m_shard": (SMALL, SMALL_LAYERS, V5E_BYTES_LIMIT, SMALL_RESIDENT,
                         EVERYTHING),
+    # k before q: keeping it frees the float32 copy a recomputed k stands in
+    "evabyte_cell_at_15.75GiB": (EVA, EVA_LAYERS, V5E_BYTES_LIMIT, EVA_RESIDENT,
+                                 EVA_KEPT),
+    "evabyte_room_for_k_only": (
+        EVA, EVA_LAYERS,
+        _limit_admitting(EVA, EVA_LAYERS, EVA_RESIDENT, 1) - 4 * 32768 * 4096,
+        EVA_RESIDENT, (names.RES_K,)),
 }
 
 
@@ -82,7 +100,8 @@ def test_rule_takes_names_in_order_while_they_fit(case):
     policy = gpt2.choose_remat_policy(shard, n_layer, limit, resident)
     assert policy.saved == want
     candidates = gpt2.remat_candidates(shard)
-    sizes = {group: n_layer * nbytes for group, nbytes, _ in candidates}
+    sizes = {c.names: n_layer * c.nbytes for c in candidates}
+    frees = {c.names: c.frees for c in candidates}
     taken = [g for g in sizes if set(g) <= set(policy.saved)]
     # the order of the list, and the bytes of exactly what was taken
     assert policy.saved == tuple(n for g in taken for n in g)
@@ -92,14 +111,59 @@ def test_rule_takes_names_in_order_while_they_fit(case):
         assert policy[1:] == (0, 0, 0)
         return
     assert policy.bytes_limit == limit
-    # reserve respected: everything counted still leaves it free ...
+    # reserve respected: everything counted — the working set less what the
+    # kept names freed of it — still leaves it free ...
     counted = (resident + gpt2.rematted_working_set(shard, n_layer)
-               + policy.saved_bytes)
+               - sum(frees[g] for g in taken) + policy.saved_bytes)
     assert counted + gpt2.REMAT_RESERVE_BYTES <= limit or not policy.saved
     # ... and nothing that was left out would have fitted
     for g, size in sizes.items():
         if g not in taken:
-            assert policy.saved_bytes + size > policy.budget_bytes
+            assert policy.saved_bytes + size > policy.budget_bytes + frees[g]
+
+
+def test_rule_on_the_cells_shards_byte_for_byte():
+    """`gpt2-xl.fsdp4-dataset`'s decision is PR 28's to the byte: nothing a
+    block with no window, no gate and no weights cast in the loop states
+    reaches the working set or the order. The EvaByte cell's is the set its
+    step compiles with, beside the rows its MLP and head take."""
+    limit = 16_909_334_528          # a v5e's, as the chip states it
+    assert gpt2.rematted_working_set(XL, XL_LAYERS) == 5_457_362_944
+    resident = limit - gpt2.REMAT_RESERVE_BYTES - 5_457_362_944 - 5_700_332_148
+    assert gpt2.choose_remat_policy(XL, XL_LAYERS, limit, resident) == (
+        FLASH + QKV, 5_072_486_400, 5_700_332_148, limit)
+    assert all(c.frees == 0 for c in gpt2.remat_candidates(XL))
+
+    assert (EVA.mlp_rows, EVA.head_rows) == (4096, 4096)
+    policy = gpt2.choose_remat_policy(EVA, EVA_LAYERS, limit, EVA_RESIDENT)
+    assert policy.saved == EVA_KEPT
+    # k, and two summaries a sixteenth its size, in four layers
+    assert policy.saved_bytes == 4 * (32768 * 4096 * 2) * 9 // 8
+    k, q = gpt2.remat_candidates(EVA)[:2]
+    assert (k.names, q.names) == ((names.RES_K,), (names.RES_Q,))
+    # float32 k: what the summary pass reads, gone from the set with k kept
+    assert (k.frees, q.frees) == (32768 * 4096 * 4, 0)
+    assert policy.budget_bytes == (
+        limit - gpt2.REMAT_RESERVE_BYTES - EVA_RESIDENT
+        - gpt2.rematted_working_set(EVA, EVA_LAYERS) + k.frees)
+
+
+@pytest.mark.parametrize("block, rows", [
+    # batch, seq, d_model, d_ff, bytes an element
+    ((8, 1024, 768, 3072, 2), 1024),         # gpt2-124m's sizes: 50 MB hidden
+    ((2, 128, 64, 176, 2), 128),             # llama_tiny
+    ((8, 2048, 2048, 5632, 2), 2048),        # llama_1b: 185 MB
+    ((8, 4096, 4096, 11008, 2), 512),        # llama_7b: 4,096 tokens a chunk
+    ((1, 32768, 4096, 11008, 2), 4096),      # the EvaByte cell: the same
+], ids=["gpt2-124m", "llama_tiny", "llama_1b", "llama_7b", "evabyte"])
+def test_mlp_rows_come_from_the_blocks_shapes(block, rows):
+    """The whole sequence while one hidden tensor stays under 256 MiB; past
+    that, chunks whose five hidden tensors take what two [B, S, D] do."""
+    assert llama._mlp_rows(*block) == rows
+    batch, seq, d_model, d_ff, a = block
+    if rows < seq:
+        assert 5 * batch * rows * d_ff * a <= 2 * batch * seq * d_model * a
+        assert 5 * batch * 2 * rows * d_ff * a > 2 * batch * seq * d_model * a
 
 
 def test_candidates_are_ordered_by_recompute_flops_per_byte():
@@ -109,21 +173,21 @@ def test_candidates_are_ordered_by_recompute_flops_per_byte():
     order; lse rides with o."""
     for shard in (XL, SMALL):
         cands = gpt2.remat_candidates(shard)
-        assert [g for g, _, _ in cands] == [
+        assert [c.names for c in cands] == [
             FLASH, (names.RES_Q,), (names.RES_K,), (names.RES_V,),
             (names.RES_MID,), (names.RES_MLP_HIDDEN,)]
-        ratios = [flops / nbytes for _, nbytes, flops in cands]
+        ratios = [c.flops / c.nbytes for c in cands]
         assert ratios == sorted(ratios, reverse=True)
     short = gpt2.remat_candidates(XL._replace(seq=256))
-    assert short[-1][0] == FLASH
+    assert short[-1].names == FLASH
     # bytes from the shapes: bf16 [8, 25, 1024, 64] and f32 [8, 25, 1024]
-    by_name = {g: nbytes for g, nbytes, _ in gpt2.remat_candidates(XL)}
+    by_name = {c.names: c.nbytes for c in gpt2.remat_candidates(XL)}
     assert by_name[(names.RES_Q,)] == 8 * 25 * 1024 * 64 * 2
     assert by_name[FLASH] == 8 * 25 * 1024 * (64 * 2 + 4)
     assert by_name[(names.RES_MLP_HIDDEN,)] == 8 * 1024 * 6400 * 2
     # a tensor that does not exist is no candidate
     no_flash = gpt2.remat_candidates(XL._replace(flash=False, dense_mlp=False))
-    assert [g for g, _, _ in no_flash] == [
+    assert [c.names for c in no_flash] == [
         (names.RES_Q,), (names.RES_K,), (names.RES_V,), (names.RES_MID,)]
 
 
@@ -364,6 +428,10 @@ def test_step_factory_states_the_chip_and_cpu_states_no_limit(monkeypatch):
     """make_gpt2_train_step reports (bytes_limit, state + gradients) of a
     chip to the model; the CPU backend has no limit, so remat=True keeps
     block inputs only there. A device that states one gets the rule."""
+    # decisions are recorded once a process, by their facts: whatever this
+    # worker traced before (another file's tiny model, two rows, no limit)
+    # is not this test's
+    monkeypatch.setattr(gpt2, "_decisions", {})
     cfg = gpt2.gpt2_tiny(remat=True, attention_impl="pallas")
     bundle = make_gpt2_train_step(cfg)
     limit, resident = train_step._chip_memory(bundle.mesh, bundle.state)
@@ -416,6 +484,9 @@ def test_remat_policy_event_once_a_distinct_decision(buffer):
         assert e["args"]["n_layer"] == cfg.n_layer
         assert (e["args"]["batch"], e["args"]["seq"]) == (2, cfg.seq_len)
         assert set(e["args"]["saved"]) <= set(names.RESIDUALS)
+        # GPT-2's block takes the sequence whole
+        assert (e["args"]["mlp_rows"], e["args"]["head_rows"]) == (
+            cfg.seq_len, cfg.seq_len)
     mine = [d for d in gpt2.remat_policy_decisions()
             if d["bytes_limit"] in (first, second)]
     assert [d["bytes_limit"] for d in mine] == [first, second]
