@@ -1,0 +1,14 @@
+"""`moe_dispatch_ms_per_step`: Device time a step under the program's
+`moe_dispatch` scope (inside `moe_routed`: all of it but the two grouped
+products), forward, backward and recompute, first chip."""
+
+LAYER = "Kernels"
+UNIT = "ms"
+MOVES = "tokens_per_s_per_chip"
+SOURCE = "device_trace"
+
+
+def read(facts):
+    from benchmarks.harness import program_trace
+
+    return program_trace.device_metric(facts, "scope_ms_per_step.moe_dispatch")

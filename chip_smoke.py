@@ -10,8 +10,11 @@ worker leased every chip → in that worker a mesh over ``jax.devices()``,
 tens of steps and ``train.report`` per step. Weights and tokens are random,
 from a seed. Then one step of a small EvaByte (``models/llama.py`` with the
 EVA mixer, ``remat=True``) through the same factory, for the ``ops/eva_tiling``
-and ``model/remat_policy`` decisions it is traced with. It then checks what
-came back (see check_training/check_device).
+and ``model/remat_policy`` decisions it is traced with, and one step of a small
+Nemotron-H hybrid (``models/nemotron_h.py``: Mamba-2, LatentMoE and attention
+layers and the MTP module) for its ``model/layer_pattern`` and
+``model/expert_load`` events. It then checks what came back (see
+check_training/check_device).
 
 This process never initialises a JAX backend: a chip belongs to one process
 and that process is the train worker, so every device fact below travelled
@@ -204,6 +207,36 @@ def train_loop(config: Dict[str, Any]) -> None:
                "attention": list(resolve_attention(eva_cfg.attention_impl, mesh)),
                "tiling": eva_tiling_decisions()}
         del variant
+    # One step of a hybrid (Mamba-2 / LatentMoE / attention / MTP) through
+    # the same factory: the pattern it is traced with, and what its first
+    # batch sends the experts held here once their selection bias is
+    # balanced on it.
+    hybrid = None
+    if config.get("hybrid_model") is not None:
+        from ray_tpu.models import nemotron_h
+        from ray_tpu.models.gpt2 import layer_pattern_decisions
+
+        hybrid_cfg = config["hybrid_model"]
+        variant = make_train_step(
+            nemotron_h, hybrid_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP, total_steps=steps,
+                                        decay_mask=nemotron_h.decays))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, hybrid_cfg.seq_len), dtype=np.int32)
+        hybrid_batch = jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding)
+        with mesh_lib.use_mesh(mesh):
+            params, load = nemotron_h.balance_router_bias(
+                variant.state["params"], hybrid_batch["tokens"],
+                hybrid_batch["targets"], hybrid_cfg)
+        _, m = variant.step_fn({**variant.state, "params": params},
+                               hybrid_batch)
+        hybrid = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+                  "seq_len": hybrid_cfg.seq_len,
+                  "layer_pattern": layer_pattern_decisions(),
+                  "expert_load": load}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -229,11 +262,13 @@ def train_loop(config: Dict[str, Any]) -> None:
         "parity_rows": parity_rows,
         "parity": parity,
         "eva": eva,
+        "hybrid": hybrid,
     }})
 
 
 def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
-        use_tpu: bool, seed: int = 0, eva_model=None) -> List[Dict[str, Any]]:
+        use_tpu: bool, seed: int = 0, eva_model=None,
+        hybrid_model=None) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -254,7 +289,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         train_loop_config={
             "model": model_cfg, "steps": steps,
             "per_chip_batch": per_chip_batch, "seed": seed,
-            "eva_model": eva_model,
+            "eva_model": eva_model, "hybrid_model": hybrid_model,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -309,6 +344,22 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if eva["attention"][0] == "pallas" and kernels != {"fwd", "bwd"}:
             bad.append(f"the EVA step recorded tiling decisions for "
                        f"{sorted(kernels)}, not for fwd and bwd")
+    hybrid = summary.get("hybrid")
+    if hybrid is not None:
+        if not (math.isfinite(hybrid["loss"])
+                and math.isfinite(hybrid["grad_norm"])):
+            bad.append(f"the hybrid step's loss {hybrid['loss']} or grad_norm "
+                       f"{hybrid['grad_norm']} is not finite")
+        # both of its events: the pattern it was traced with, and what the
+        # batch sent the held experts of every expert layer
+        if not hybrid["layer_pattern"]:
+            bad.append("the hybrid step recorded no model/layer_pattern event")
+        if not hybrid["expert_load"]:
+            bad.append("the hybrid step recorded no model/expert_load event")
+        dropped = sum(e["pairs_dropped"] for e in hybrid["expert_load"])
+        if dropped:
+            bad.append(f"the hybrid step's expert layers dropped {dropped} "
+                       "(token, choice) pairs")
     return bad
 
 
@@ -351,9 +402,16 @@ def main() -> int:
 
     import ray_tpu
     from ray_tpu.core.resources import tpu_device_files
-    from ray_tpu.models import gpt2, llama
+    from ray_tpu.models import gpt2, llama, nemotron_h
 
     model_cfg = gpt2.gpt2_124m()
+    # Nemotron-H's three kinds of layer at a quarter of the width: one short
+    # period and the MTP module, 8 of 64 experts held, heads of 128 / 64
+    hybrid_cfg = nemotron_h.NemotronHConfig(
+        vocab_size=4096, seq_len=2048, pattern="MEME*E", n_layer_published=6,
+        d_model=1024, n_head=4, n_kv_head=1, mamba_heads=16, mamba_groups=1,
+        n_experts=64, top_k=6, held_first=8, held_count=8, latent=256,
+        d_expert=640, d_shared=1280, remat=True)
     # EvaByte's block at an eighth of its width: heads of 128, two windows of
     # 2,048 bytes, so the second window's queries see 128 summaries
     eva_cfg = llama.evabyte_6p5b(n_layer=2, n_head=8, n_kv_head=8, d_model=1024,
@@ -368,7 +426,8 @@ def main() -> int:
                   f"files: {tpu_device_files() or 'none'})", file=sys.stderr)
             return 2
         rows = run(model_cfg, steps=STEPS, per_chip_batch=PER_CHIP_BATCH,
-                   num_devices=chips, use_tpu=True, eva_model=eva_cfg)
+                   num_devices=chips, use_tpu=True, eva_model=eva_cfg,
+                   hybrid_model=hybrid_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -412,6 +471,21 @@ def main() -> int:
           f"attention {eva['attention']}, loss {eva['loss']:.4f} "
           f"grad_norm {eva['grad_norm']:.4f}; instructions the compiler "
           f"rematerialized by itself: {eva['compiler_rematerialized']}")
+    hybrid = summary["hybrid"]
+    for d in hybrid["layer_pattern"]:
+        print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
+              f"{d['groups']}")
+    for e in hybrid["expert_load"]:
+        print(f"expert load: layer {e['layer']}: {e['pairs']} pairs of "
+              f"{e['tokens']} tokens on the held experts (max "
+              f"{e['max_per_expert']}, mean {e['mean_per_expert']:.1f} an "
+              f"expert; {e['tokens_without_held_expert']} tokens with none), "
+              f"{e['buffer_passes']} pass(es) over a buffer of "
+              f"{e['buffer_rows']} rows, dropped {e['pairs_dropped']}")
+    print(f"hybrid step ({hybrid_cfg.pattern} + MTP {hybrid_cfg.mtp_pattern} "
+          f"of {hybrid_cfg.d_model}, {summary['device_count']}x"
+          f"{hybrid['seq_len']} tokens, remat): loss {hybrid['loss']:.4f} "
+          f"grad_norm {hybrid['grad_norm']:.4f}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

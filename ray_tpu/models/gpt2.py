@@ -21,7 +21,8 @@ import math
 import re
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -404,6 +405,7 @@ REMAT_RESERVE_BYTES = 2 ** 30
 _MXU = 128                   # a matmul dim below this still costs a full pass
 
 _decisions: Dict[tuple, Dict[str, Any]] = {}
+_patterns: Dict[str, Dict[str, Any]] = {}
 
 
 def shard_block(whole: BlockShard, mesh) -> BlockShard:
@@ -529,42 +531,81 @@ def rematted_working_set(s: BlockShard, n_layer: int) -> int:
     schedule decides the real figure (PR 28: from 0.08 GiB under at the
     GPT-2 cells' shapes to 8 over; PR 32: 0.13 GB over at the EvaByte cell's)
     — which is what the reserve is for."""
+    return model_working_set(s, n_layer) + block_working_set(s)
+
+
+def model_working_set(s: BlockShard, n_layer: int) -> int:
+    """rematted_working_set's part that no block decides: the stack of
+    ``n_layer`` block inputs, the LM head's logits, the gathered embedding."""
+    tokens = s.batch * s.seq
+    a = s.dtype_bytes
+    stack = n_layer * tokens * s.d_model * a
+    head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
+    gathered = s.vocab * s.d_model * (a + 4)
+    return stack + head + gathered
+
+
+def block_working_set(s: BlockShard) -> int:
+    """rematted_working_set's part that is one block's: its whole residual
+    set, live while its backward runs. Of a model whose layers are of more
+    than one kind the largest kind's counts (choose_remat_policy_kinds)."""
     tokens = s.batch * s.seq
     a = s.dtype_bytes
     attn_width = s.heads * s.head_dim
     kv_width = (s.kv_heads or s.heads) * s.head_dim
-    stack = n_layer * tokens * s.d_model * a
-    head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
     hidden = 2 * len(s.mlp_hidden) + (len(s.mlp_hidden) - 1)
     block = a * (tokens * (4 * s.d_model + 4 * attn_width)
                  + s.batch * (s.mlp_rows or s.seq) * hidden * s.d_ff)
     weights = 2 * a * s.d_model * (
         2 * attn_width + 2 * kv_width + (len(s.mlp_hidden) + 1) * s.d_ff
     ) if s.cast_in_loop else 0
-    gathered = s.vocab * s.d_model * (a + 4)
-    return stack + head + block + _eva_k_f32(s) + weights + gathered
+    return block + _eva_k_f32(s) + weights
+
+
+class KindShard(NamedTuple):
+    """What the remat rule needs of one kind of layer on one chip: how often
+    the kind is applied, what a block of it may keep (remat_candidates, or
+    the kind's own arithmetic) and what its whole residual set takes while
+    its backward runs (block_working_set)."""
+    applications: int
+    candidates: Tuple[RematCandidate, ...]
+    block_bytes: int
+
+
+def choose_remat_policy_kinds(kinds: Sequence[KindShard], model_bytes: int,
+                              bytes_limit: Optional[int],
+                              resident_bytes: int) -> RematPolicy:
+    """THE rule for what ``remat=True`` keeps besides each block's input, for
+    layers of any number of kinds: walk every kind's candidates (most
+    recompute FLOPs per byte first) and take each whose copies — one an
+    application of its kind — still fit what the chip has free: its
+    bytes_limit less the reserve, what is resident (state and gradients) and
+    the fully rematted step's working set (``model_bytes`` and the largest
+    kind's block), plus what keeping it frees of that set. With no limit
+    stated, nothing."""
+    if bytes_limit is None:
+        return RematPolicy((), 0, 0, 0)
+    budget = (bytes_limit - REMAT_RESERVE_BYTES - resident_bytes
+              - model_bytes - max(k.block_bytes for k in kinds))
+    ranked = sorted(((c, k.applications) for k in kinds for c in k.candidates),
+                    key=lambda cn: (-cn[0].flops / cn[0].nbytes, -cn[0].frees))
+    saved, used = [], 0
+    for c, n in ranked:
+        if used + n * c.nbytes <= budget + c.frees:
+            saved.extend(c.names)
+            used += n * c.nbytes
+            budget += c.frees
+    return RematPolicy(tuple(saved), used, max(0, budget), bytes_limit)
 
 
 def choose_remat_policy(shard: BlockShard, n_layer: int,
                         bytes_limit: Optional[int],
                         resident_bytes: int) -> RematPolicy:
-    """THE rule for what ``remat=True`` keeps besides each block's input: walk
-    remat_candidates (most recompute FLOPs per byte first) and take each
-    whose n_layer copies still fit what the chip has free — its bytes_limit
-    less the reserve, what is resident (state and gradients) and the fully
-    rematted step's working set, plus what keeping it frees of that set. With
-    no limit stated, nothing."""
-    if bytes_limit is None:
-        return RematPolicy((), 0, 0, 0)
-    budget = (bytes_limit - REMAT_RESERVE_BYTES - resident_bytes
-              - rematted_working_set(shard, n_layer))
-    saved, used = [], 0
-    for c in remat_candidates(shard):
-        if used + n_layer * c.nbytes <= budget + c.frees:
-            saved.extend(c.names)
-            used += n_layer * c.nbytes
-            budget += c.frees
-    return RematPolicy(tuple(saved), used, max(0, budget), bytes_limit)
+    """choose_remat_policy_kinds for ``n_layer`` blocks of one kind."""
+    return choose_remat_policy_kinds(
+        [KindShard(n_layer, tuple(remat_candidates(shard)),
+                   block_working_set(shard))],
+        model_working_set(shard, n_layer), bytes_limit, resident_bytes)
 
 
 def remat_policy_decisions() -> List[Dict[str, Any]]:
@@ -588,25 +629,43 @@ def _flash(cfg: GPT2Config, mesh) -> bool:
     return resolve_attention(cfg.attention_impl, mesh)[0] == "pallas"
 
 
-def _remat_policy(shard: BlockShard, n_layer: int) -> RematPolicy:
-    """choose_remat_policy for the step being traced, recorded. A static
+def _remat_policy(shard: BlockShard, kinds: Sequence[KindShard]) -> RematPolicy:
+    """choose_remat_policy_kinds for the step being traced, recorded. A static
     choice has no hit rate; its counter is the choice: each distinct one goes
     once, as an instant event, to the task-event buffer
-    (→ ``ray_tpu.timeline()``)."""
+    (→ ``ray_tpu.timeline()``). ``shard`` is the model's: the stream, the
+    head and the rows the head and the MLP take at a time."""
     from ray_tpu.parallel import mesh as mesh_lib
 
-    policy = choose_remat_policy(shard, n_layer,
-                                 *mesh_lib.current_chip_memory())
+    n_layer = sum(k.applications for k in kinds)
+    policy = choose_remat_policy_kinds(
+        kinds, model_working_set(shard, n_layer),
+        *mesh_lib.current_chip_memory())
     args = dict(zip(scopes.REMAT_POLICY_ARGS,
                     (n_layer, shard.batch, shard.seq, list(policy.saved))
                     + policy[1:] + (shard.mlp_rows or shard.seq,
                                     shard.head_rows or shard.seq)))
-    key = (n_layer, shard) + policy
+    key = (shard, tuple(kinds)) + policy
     if key not in _decisions:
         _decisions[key] = args
         component, name = scopes.REMAT_POLICY.split("/")
         get_buffer().record_profile(name, component=component, args=args)
     return policy
+
+
+def checkpoint_kinds(block_fns: Dict[str, Callable], remat: bool,
+                     shard: BlockShard, kinds: Dict[str, KindShard]
+                     ) -> Dict[str, Callable]:
+    """Each kind's ``block_fn(x, layer_params)`` as run_pattern calls it: a
+    policy-``checkpoint`` that keeps the block's input and, of the named
+    residuals (tracing/names.RESIDUALS), those the ONE rule gave room —
+    over all the kinds' applications together — with ``remat`` and all of
+    them without."""
+    saved = (_remat_policy(shard, tuple(kinds.values())).saved if remat
+             else scopes.RESIDUALS)
+    policy = jax.checkpoint_policies.save_only_these_names(*saved)
+    return {kind: jax.checkpoint(fn, policy=policy)
+            for kind, fn in block_fns.items()}
 
 
 def _checkpointed(block_fn, remat: bool, shard: BlockShard, n_layer: int):
@@ -622,17 +681,14 @@ def _checkpointed(block_fn, remat: bool, shard: BlockShard, n_layer: int):
     them costs 0.5 ms (PERF.md §6, PR 30). Without remat that holds only
     where the names cover every output that is dear to make again — a Pallas
     attention kernel's and the dense MLP's; XLA and ring attention and the
-    experts tag none of theirs, so those blocks stay as AD leaves them."""
-    if remat:
-        saved = _remat_policy(shard, n_layer).saved
-    elif shard.flash and shard.dense_mlp:
-        saved = scopes.RESIDUALS
-    else:
+    experts tag none of theirs, so those blocks stay as AD leaves them. The
+    one-kind case of checkpoint_kinds."""
+    if not remat and not (shard.flash and shard.dense_mlp):
         return block_fn
-    return jax.checkpoint(
-        block_fn,
-        policy=jax.checkpoint_policies.save_only_these_names(*saved),
-    )
+    kind = KindShard(n_layer, tuple(remat_candidates(shard)),
+                     block_working_set(shard))
+    return checkpoint_kinds({"block": block_fn}, remat, shard,
+                            {"block": kind})["block"]
 
 
 def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
@@ -643,13 +699,95 @@ def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
         block_shard(cfg, global_batch, seq, mesh, _flash(cfg, mesh)), n_layer)
 
 
-def _run_blocks(block_fn, x, layers):
-    """x through the blocks whose parameters are stacked in ``layers``."""
-    def scan_body(x, layer_params):
-        return block_fn(x, layer_params), None
+def pattern_groups(pattern: str) -> List[Tuple[str, int]]:
+    """A pattern of layer kinds, one character a layer, as runs of a repeated
+    sub-pattern: ``"MEMEMEMEM*E"`` → ``[("ME", 4), ("M", 1), ("*", 1),
+    ("E", 1)]``, twelve layers of one kind → ``[("B", 12)]``. Greedy from the
+    left: the repeat that covers most layers, of equal ones the shortest
+    sub-pattern."""
+    groups, i = [], 0
+    while i < len(pattern):
+        best = (pattern[i], 1)
+        for width in range(1, (len(pattern) - i) // 2 + 1):
+            sub, reps = pattern[i:i + width], 1
+            while pattern.startswith(sub, i + reps * width):
+                reps += 1
+            if reps > 1 and reps * width > best[1] * len(best[0]):
+                best = (sub, reps)
+        groups.append(best)
+        i += best[1] * len(best[0])
+    return groups
 
-    x, _ = lax.scan(scan_body, x, layers)
-    return x
+
+def run_pattern(block_fns: Dict[str, Callable], pattern: str, x,
+                stacks: Sequence[Dict[str, Any]], with_aux: bool = False):
+    """x through ``pattern``'s layers, one character a layer: kind ``c`` is
+    ``block_fns[c](x, layer_params)``. ``stacks`` holds the parameters, one
+    entry a run of pattern_groups(pattern): ``stacks[g][c]`` stacks the
+    run's layers of kind ``c`` in the order they come. A run of a repeated
+    sub-pattern is ONE ``lax.scan`` over its own stacks, whose body holds the
+    sub-pattern's layers — compile time and program size follow the number of
+    distinct runs, not the depth, and no stack is sliced or copied; a layer
+    outside any repeat is applied where it stands.
+
+    ``with_aux``: every block function returns ``(x, aux)`` and the result is
+    ``(x, auxes)``, ``auxes[g][i]`` the aux of the i-th layer of run g's
+    sub-pattern (stacked over the repeats where the run is a scan)."""
+    auxes = []
+    for (sub, reps), group in zip(pattern_groups(pattern), stacks, strict=True):
+        per_rep = {kind: sub.count(kind) for kind in dict.fromkeys(sub)}
+        xs = {kind: group[kind] if n == 1 else jax.tree.map(
+            lambda a, n=n: a.reshape((reps, n) + a.shape[1:]), group[kind])
+            for kind, n in per_rep.items()}
+
+        def body(x, layer_params, sub=sub, per_rep=per_rep):
+            seen = {kind: 0 for kind in per_rep}
+            aux = []
+            for kind in sub:
+                p = layer_params[kind]
+                if per_rep[kind] > 1:
+                    p = jax.tree.map(lambda a: a[seen[kind]], p)
+                seen[kind] += 1
+                x = block_fns[kind](x, p)
+                if with_aux:
+                    x, a = x
+                    aux.append(a)
+            return x, (aux if with_aux else None)
+
+        if reps > 1:
+            x, aux = lax.scan(body, x, xs)
+        else:
+            x, aux = body(x, jax.tree.map(lambda a: a[0], xs))
+        auxes.append(aux)
+    return (x, auxes) if with_aux else x
+
+
+def record_layer_pattern(pattern: str) -> None:
+    """The ``model/layer_pattern`` event of a model whose layers are of more
+    than one kind: the pattern, how often each kind is applied and which
+    runs are one scan; once per distinct pattern, at trace time."""
+    if pattern in _patterns:
+        return
+    groups = pattern_groups(pattern)
+    _patterns[pattern] = dict(zip(scopes.LAYER_PATTERN_ARGS, (
+        pattern, {kind: pattern.count(kind) for kind in dict.fromkeys(pattern)},
+        [f"{reps} x scan({sub})" if reps > 1 else sub for sub, reps in groups])))
+    component, name = scopes.LAYER_PATTERN.split("/")
+    get_buffer().record_profile(name, component=component,
+                                args=_patterns[pattern])
+
+
+def layer_pattern_decisions() -> List[Dict[str, Any]]:
+    """Every distinct pattern this process has traced a model with, as the
+    ``model/layer_pattern`` events carry them."""
+    return list(_patterns.values())
+
+
+def _run_blocks(block_fn, x, layers):
+    """x through the blocks whose parameters are stacked in ``layers``: the
+    one-kind case of run_pattern."""
+    n_layer = jax.tree.leaves(layers)[0].shape[0]
+    return run_pattern({"B": block_fn}, "B" * n_layer, x, [{"B": layers}])
 
 
 def _blocks_pipelined(blocks, x, cfg: GPT2Config, mesh, pp: int):

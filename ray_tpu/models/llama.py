@@ -326,7 +326,7 @@ def _attention(q, k, v, p, cfg: LlamaConfig):
 _MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
-def _cast_in_the_loop(p, x, dt):
+def _cast_in_the_loop(p, x, dt, keys=_MATMUL_WEIGHTS):
     """The layer's matmul weights in the compute dtype, cast inside the layer
     loop. A plain ``astype`` of a layer sliced out of the stack the TPU
     compiler turns into one cast of the WHOLE stack before the loop (through
@@ -336,7 +336,7 @@ def _cast_in_the_loop(p, x, dt):
     is written; it costs a read of the layer's f32 weights a use, 0.6 % of
     the 32,768-token step."""
     one = lax.stop_gradient(1.0 + 0.0 * x[0, 0, 0].astype(jnp.float32))
-    return {k: (p[k] * one).astype(dt) for k in _MATMUL_WEIGHTS}
+    return {k: (p[k] * one).astype(dt) for k in keys}
 
 
 @jax.named_scope(scopes.BLOCK)

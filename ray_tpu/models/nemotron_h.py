@@ -1,0 +1,574 @@
+"""Nemotron-H-family hybrid decoder in pure JAX: a PATTERN of layer kinds.
+
+Third model family beside GPT-2 and LLaMA, and the first whose layers are not
+alike: every layer is a mixer OR a feed-forward part alone,
+``x ← x + f_kind(RMSNorm(x))``, the kind read off a pattern string, one
+character a layer (the published ``hybrid_override_pattern``):
+
+- ``M`` — a Mamba-2 mixer (ops/mamba2.py: chunked state-space scan);
+- ``E`` — a LatentMoE layer (ops/moe.latent_moe: sigmoid router over all the
+  experts with a selection bias, the held experts' grouped products inside a
+  latent projection, a shared expert beside them);
+- ``*`` — grouped-query causal attention, no positional encoding
+  (llama._attention: the flash kernels).
+
+After the trunk, one multi-token-prediction module (``mtp_pattern``, a second
+small trunk fed by the first and by the next token's embedding) predicts the
+token after next through the SAME final norm, embedding and head;
+``loss = CE_trunk + mtp_loss_weight · CE_mtp``.
+
+It runs on the shared machinery: ``gpt2.run_pattern`` (a run of a repeated
+sub-pattern is one ``lax.scan`` over the kinds' stacked parameters),
+``gpt2.checkpoint_kinds`` (ONE remat rule over all the kinds' applications),
+llama's RMSNorm, residual add, weight cast inside the loop and chunked head +
+loss; scopes and residual names from tracing/names.py.
+
+The config states the chip's SHARE of a deployment beside the published
+sizes: how many of the Mamba heads / groups, attention heads, routed experts
+and vocabulary rows are held here. Routing is over all ``n_experts`` at the
+published top-k; what absent experts and heads would have added is left out
+(no code stands in for absent chips): the out-projections' partial sums go
+on as they are.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass, replace
+from functools import partial
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.models import gpt2, llama
+from ray_tpu.ops import mamba2, moe
+from ray_tpu.tracing import get_buffer, names as scopes
+
+KINDS = "ME*"        # Mamba-2 mixer, LatentMoE layer, attention
+INIT_STD = 0.02      # every matrix; the three out-projections rescaled (init)
+
+
+@dataclass(frozen=True)
+class NemotronHConfig:
+    vocab_size: int = 131072          # rows of the embedding / head held here
+    seq_len: int = 4096
+    pattern: str = "MEMEMEMEM*E"      # one character a layer: M, E or *
+    mtp_pattern: str = "*E"           # the MTP module's layers ("": none)
+    n_layer_published: int = 88       # the out-projections' init scale
+    d_model: int = 4096
+    # attention: heads held here, of head_dim
+    n_head: int = 32
+    n_kv_head: int = 2
+    head_dim: int = 128
+    # Mamba-2: heads and groups held here
+    mamba_heads: int = 128
+    mamba_head_dim: int = 64
+    mamba_groups: int = 8
+    ssm_state: int = 128
+    conv_kernel: int = 4
+    chunk: int = 128
+    # LatentMoE: the router is n_experts wide; ids held_first … + held_count
+    # − 1 are computed here
+    n_experts: int = 512
+    top_k: int = 22
+    held_first: int = 0
+    held_count: int = 512
+    latent: int = 1024
+    d_expert: int = 2688
+    d_shared: int = 5376
+    routed_scaling: float = 5.0
+    rms_eps: float = 1e-5
+    mtp_loss_weight: float = 0.1
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    remat: bool = False
+    attention_impl: str = "auto"
+
+    # what llama's shared functions read of a config
+    mixer = "causal"
+    norm_unit_offset = False
+    n_pred_heads = 1
+
+    def __post_init__(self):
+        for name in ("pattern", "mtp_pattern"):
+            odd = set(getattr(self, name)) - set(KINDS)
+            if odd:
+                raise ValueError(f"{name} {getattr(self, name)!r}: a layer is "
+                                 f"one of {sorted(KINDS)}, not {sorted(odd)}")
+        if not self.pattern:
+            raise ValueError("pattern is empty")
+        if not isinstance(self.remat, bool):
+            raise ValueError(f"remat must be True or False; got {self.remat!r}")
+        if self.n_head % self.n_kv_head:
+            raise ValueError(f"n_head={self.n_head} must be divisible by "
+                             f"n_kv_head={self.n_kv_head}")
+        if self.mamba_heads % self.mamba_groups:
+            raise ValueError("mamba_heads must be divisible by mamba_groups")
+        if not 0 <= self.held_first <= self.n_experts - self.held_count:
+            raise ValueError(
+                f"held experts {self.held_first}…+{self.held_count} are not "
+                f"among {self.n_experts}")
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError("top_k must be in [1, n_experts]")
+        if self.vocab_size % 128:
+            raise ValueError("vocab_size (the rows held here) must be a "
+                             "multiple of 128")
+
+    @property
+    def n_layer(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def held(self) -> moe.Held:
+        return moe.Held(self.held_first, self.held_count)
+
+
+def nemotron_3_super_120b(**overrides) -> NemotronHConfig:
+    """NVIDIA-Nemotron-3-Super-120B-A12B as published, whole (88 layers)."""
+    return replace(NemotronHConfig(
+        pattern="MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"), **overrides)
+
+
+def nemotron_h_tiny(**overrides) -> NemotronHConfig:
+    """Test-size config: one short period, every kind, an MTP module."""
+    return replace(NemotronHConfig(
+        vocab_size=256, seq_len=64, pattern="MEME*E", mtp_pattern="*E",
+        n_layer_published=6, d_model=64, n_head=4, n_kv_head=2, head_dim=16,
+        mamba_heads=4, mamba_head_dim=16, mamba_groups=2, ssm_state=16,
+        chunk=16, n_experts=32, top_k=4, held_first=8, held_count=8,
+        latent=32, d_expert=48, d_shared=96), **overrides)
+
+
+# --------------------------------------------------------------------------- #
+# Parameters
+# --------------------------------------------------------------------------- #
+
+def _group_counts(pattern: str):
+    """[{kind: layers of it}] a run of gpt2.pattern_groups(pattern)."""
+    return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
+            for sub, reps in gpt2.pattern_groups(pattern)]
+
+
+def _attn_init(rng, n: int, cfg: NemotronHConfig, out_std: float):
+    D, H, KH, hd = cfg.d_model, cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    k = jax.random.split(rng, 4)
+
+    def normal(key, shape, s):
+        return (jax.random.normal(key, shape) * s).astype(cfg.param_dtype)
+
+    return {"wq": normal(k[0], (n, D, H, hd), INIT_STD),
+            "wk": normal(k[1], (n, D, KH, hd), INIT_STD),
+            "wv": normal(k[2], (n, D, KH, hd), INIT_STD),
+            "wo": normal(k[3], (n, H, hd, D), out_std)}
+
+
+_ATTN_AXES = {"wq": ("layers", "embed", "heads", "kv"),
+              "wk": ("layers", "embed", "heads", "kv"),
+              "wv": ("layers", "embed", "heads", "kv"),
+              "wo": ("layers", "heads", "kv", "embed")}
+_ATTN_WEIGHTS = ("wq", "wk", "wv", "wo")
+
+
+def _stack_init(rng, pattern: str, cfg: NemotronHConfig):
+    """The layers of ``pattern`` as gpt2.run_pattern takes them: one entry a
+    run of the pattern, a kind's layers of the run stacked in the order they
+    come; every kind's layer has its pre-norm ``norm``."""
+    # rescale_prenorm_residual: the three out-projections by 1/sqrt(2·layers)
+    out_std = INIT_STD / math.sqrt(2 * cfg.n_layer_published)
+    groups = _group_counts(pattern)
+    out = []
+    for counts, group_key in zip(groups, jax.random.split(rng, len(groups))):
+        keys = dict(zip(KINDS, jax.random.split(group_key, len(KINDS))))
+        group = {}
+        for kind, n in counts.items():
+            if kind == "M":
+                p = mamba2.mamba2_init(
+                    keys[kind], n, cfg.d_model, cfg.mamba_heads,
+                    cfg.mamba_head_dim, cfg.mamba_groups, cfg.ssm_state,
+                    cfg.conv_kernel, INIT_STD, out_std, cfg.param_dtype)
+            elif kind == "E":
+                p = moe.latent_moe_init(
+                    keys[kind], n, cfg.d_model, cfg.n_experts, cfg.held_count,
+                    cfg.latent, cfg.d_expert, cfg.d_shared, INIT_STD, out_std,
+                    cfg.param_dtype)
+            else:
+                p = _attn_init(keys[kind], n, cfg, out_std)
+            group[kind] = {**p,
+                           "norm": jnp.ones((n, cfg.d_model), cfg.param_dtype)}
+        out.append(group)
+    return out
+
+
+def _stack_axes(pattern: str):
+    axes = {"M": mamba2.mamba2_logical_axes(),
+            "E": moe.latent_moe_logical_axes(), "*": _ATTN_AXES}
+    return [{kind: {**axes[kind], "norm": ("layers", "embed")}
+             for kind in counts} for counts in _group_counts(pattern)]
+
+
+def logical_axes(cfg: NemotronHConfig) -> Dict[str, Any]:
+    out = {"wte": ("vocab", "embed"), "blocks": _stack_axes(cfg.pattern),
+           "final_norm": ("embed",), "lm_head": ("embed", "vocab")}
+    if cfg.mtp_pattern:
+        out["mtp"] = {"blocks": _stack_axes(cfg.mtp_pattern),
+                      "enorm": ("embed",), "hnorm": ("embed",),
+                      "eh_proj": (None, "embed")}
+    return out
+
+
+def mesh_rules(cfg: NemotronHConfig, mesh) -> Dict[str, str]:
+    """What this config needs of this mesh: no rule beyond the defaults, and
+    the refusal of the axes no code here runs over."""
+    if mesh.shape.get("pp", 1) > 1:
+        raise NotImplementedError(
+            "pipeline parallelism is not implemented for the Nemotron-H "
+            "family (expert layers under a stage schedule); use a pp=1 mesh")
+    if mesh.shape.get("ep", 1) > 1:
+        raise NotImplementedError(
+            "expert parallelism across chips (ep > 1) is not implemented: "
+            "the expert layer computes the experts the config says it holds "
+            "and no all-to-all exchanges tokens; use an ep=1 mesh")
+    if mesh.shape.get("cp", 1) > 1:
+        raise NotImplementedError(
+            "context parallelism is not implemented for the Nemotron-H "
+            "family (the state-space scan carries its state along the whole "
+            "row); use a cp=1 mesh")
+    return {}
+
+
+def init(cfg: NemotronHConfig, rng: jax.Array) -> Dict[str, Any]:
+    D, V, pd = cfg.d_model, cfg.vocab_size, cfg.param_dtype
+    k = jax.random.split(rng, 5)
+
+    def normal(key, shape):
+        return (jax.random.normal(key, shape) * INIT_STD).astype(pd)
+
+    out = {"wte": normal(k[0], (V, D)),
+           "blocks": _stack_init(k[1], cfg.pattern, cfg),
+           "final_norm": jnp.ones((D,), pd),
+           "lm_head": normal(k[2], (D, V))}
+    if cfg.mtp_pattern:
+        out["mtp"] = {"blocks": _stack_init(k[3], cfg.mtp_pattern, cfg),
+                      "enorm": jnp.ones((D,), pd), "hnorm": jnp.ones((D,), pd),
+                      "eh_proj": normal(k[4], (2 * D, D))}
+    return out
+
+
+def param_count(cfg: NemotronHConfig) -> int:
+    return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(
+        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))))
+
+
+def decays(params):
+    """Which leaves an optimizer's weight decay may touch (optax's ``mask``):
+    all but the expert layers' selection biases, which are buffers — no
+    gradient reaches them, and a decay must not."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) != "router_bias",
+        params)
+
+
+# --------------------------------------------------------------------------- #
+# Forward
+# --------------------------------------------------------------------------- #
+
+def _shared_rows(cfg: NemotronHConfig, batch: int, seq: int) -> int:
+    """Rows of the sequence the shared expert takes at a time (llama._mlp_rows
+    for an MLP with one hidden tensor of d_shared)."""
+    a = jnp.dtype(cfg.dtype).itemsize
+    if batch * seq * cfg.d_shared * a <= llama._MLP_CHUNK_BYTES:
+        return seq
+    return llama._rows_under(seq, 3 * batch * cfg.d_shared * a,
+                             2 * batch * seq * cfg.d_model * a)
+
+
+@jax.named_scope(scopes.BLOCK)
+def _layer(x, p, cfg: NemotronHConfig, kind: str, balance: bool = False):
+    """One layer of ``kind``: x + f_kind(RMSNorm(x)), x [B, S, D]. With
+    ``balance`` (set-up's forward, balance_router_bias) an expert layer
+    first balances its selection bias on this input, and the result is (x,
+    aux): the bias and what the input then sends the held experts
+    (moe.held_load) for an expert layer, None for the others."""
+    aux = None
+    weights = {"M": mamba2.MATMUL_WEIGHTS, "E": moe.LATENT_MOE_MATMUL_WEIGHTS,
+               "*": _ATTN_WEIGHTS}[kind]
+    p = {**p, **llama._cast_in_the_loop(p, x, cfg.dtype, weights)}
+    with jax.named_scope(scopes.LN1):
+        u = llama._rmsnorm(x, p["norm"], cfg)
+    if kind == "M":
+        y = mamba2.mamba2_mixer(
+            u, p, heads=cfg.mamba_heads, head_dim=cfg.mamba_head_dim,
+            groups=cfg.mamba_groups, state=cfg.ssm_state, chunk=cfg.chunk,
+            eps=cfg.rms_eps)
+    elif kind == "E":
+        routing = dict(top_k=cfg.top_k, held=cfg.held,
+                       scaling=cfg.routed_scaling)
+        if balance:
+            ut = u.reshape(-1, u.shape[-1])
+            bias = moe.balance_bias(ut, p["router_w"], p["router_bias"],
+                                    cfg.top_k)
+            p = {**p, "router_bias": bias}
+            aux = {"router_bias": bias, **moe.held_load(ut, p, **routing)}
+        with jax.named_scope(scopes.MOE):
+            y = moe.latent_moe(
+                u, p, **routing,
+                shared_rows=_shared_rows(cfg, x.shape[0], x.shape[1]))
+    else:
+        with jax.named_scope(scopes.QKV):
+            q = checkpoint_name(
+                jnp.einsum("bsd,dhk->bhsk", u, p["wq"]), scopes.RES_Q)
+            k = checkpoint_name(
+                jnp.einsum("bsd,dhk->bhsk", u, p["wk"]), scopes.RES_K)
+            v = checkpoint_name(
+                jnp.einsum("bsd,dhk->bhsk", u, p["wv"]), scopes.RES_V)
+        with jax.named_scope(scopes.ATTN):
+            o = llama._attention(q, k, v, p, cfg)
+        with jax.named_scope(scopes.PROJ):
+            y = jnp.einsum("bhsk,hkd->bsd", o, p["wo"],
+                           preferred_element_type=jnp.float32)
+    x = llama._residual_add(x, y)
+    return (x, aux) if balance else x
+
+
+def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
+                ) -> Tuple[gpt2.BlockShard, Dict[str, gpt2.KindShard]]:
+    """This config's layers on one chip of ``mesh``, for the remat rule: the
+    model's shard (stream, head, rows at a time) and, a kind, how often it is
+    applied (trunk and MTP module together), what a layer of it may keep and
+    what its backward holds at once — each from the kind's own shapes."""
+    from ray_tpu.ops.attention import resolve_attention
+
+    a = jnp.dtype(cfg.dtype).itemsize
+    D = cfg.d_model
+    base = gpt2.shard_block(gpt2.BlockShard(
+        batch=global_batch, seq=seq, d_model=D, heads=cfg.n_head,
+        head_dim=cfg.head_dim, d_ff=cfg.d_shared, vocab=cfg.vocab_size,
+        dtype_bytes=a,
+        flash=resolve_attention(cfg.attention_impl, mesh)[0] == "pallas",
+        dense_mlp=False, kv_heads=cfg.n_kv_head,
+        head_rows=llama._head_rows(global_batch, seq, cfg.vocab_size),
+        mlp_rows=_shared_rows(cfg, global_batch, seq), cast_in_loop=True,
+    ), mesh)
+    tokens = base.batch * base.seq
+    C = gpt2.RematCandidate
+    counts = {kind: cfg.pattern.count(kind) + cfg.mtp_pattern.count(kind)
+              for kind in KINDS}
+
+    # M: the three projections, the chunk states, the scan's output
+    H, P, G, N = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups,
+                  cfg.ssm_state)
+    inner, conv_dim = H * P, H * P + 2 * G * N
+    Q = min(cfg.chunk, seq)
+    chunks = base.batch * -(-base.seq // Q)
+    scan_flops = 2 * tokens * (Q * (G * N + H * P) + 2 * H * P * N)
+    mamba = gpt2.KindShard(counts["M"], (
+        C((scopes.RES_MAMBA_Z,), tokens * inner * a, 2 * tokens * D * inner),
+        C((scopes.RES_MAMBA_XBC,), tokens * conv_dim * a,
+          2 * tokens * D * conv_dim),
+        C((scopes.RES_MAMBA_DT,), tokens * H * 4, 2 * tokens * D * gpt2._MXU),
+        C((scopes.RES_SSD_STATES,), chunks * H * P * N * 4,
+          2 * tokens * H * P * N),
+        C((scopes.RES_SSD_Y,), tokens * inner * a, scan_flops),
+    ), a * tokens * (4 * D + 4 * inner + 2 * conv_dim)
+        + chunks * H * (3 * Q * Q * 4 + 3 * P * N * 4)
+        + 2 * a * D * (2 * inner + conv_dim))
+
+    # E: the latent input; the shared expert's hidden where it is not chunked
+    rows = moe.row_buffer(tokens, cfg.n_experts, cfg.top_k, cfg.held_count)
+    latent = [C((scopes.RES_MOE_LATENT,), tokens * cfg.latent * a,
+                2 * tokens * D * cfg.latent)]
+    if base.mlp_rows in (0, base.seq):
+        latent.append(C((scopes.RES_MOE_SHARED_HIDDEN,),
+                        tokens * base.d_ff * a, 2 * tokens * D * base.d_ff))
+    expert_params = (2 * D * cfg.latent + 2 * D * base.d_ff
+                     + 2 * cfg.held_count * cfg.latent * cfg.d_expert)
+    experts = gpt2.KindShard(counts["E"], tuple(latent), (
+        a * tokens * (4 * D + 3 * cfg.latent) + tokens * cfg.n_experts * 12
+        + a * base.batch * (base.mlp_rows or base.seq) * 3 * base.d_ff
+        + a * rows * (2 * cfg.latent + 3 * cfg.d_expert)
+        + 2 * a * expert_params))
+
+    # *: q, k, v and the flash kernel's outputs (no MLP half, no mid-stream)
+    attn = gpt2.KindShard(counts["*"], tuple(
+        c for c in gpt2.remat_candidates(base) if c.names != (scopes.RES_MID,)
+    ), a * tokens * (4 * D + 4 * base.heads * base.head_dim)
+        + 2 * a * D * 2 * (base.heads + base.kv_heads) * base.head_dim)
+    kinds = {"M": mamba, "E": experts, "*": attn}
+    return base, {k: v for k, v in kinds.items() if v.applications}
+
+
+def _block_fns(cfg: NemotronHConfig, batch: int, seq: int):
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    base, kinds = kind_shards(cfg, batch, seq, mesh_lib.current_mesh())
+    for pattern in filter(None, (cfg.pattern, cfg.mtp_pattern)):
+        gpt2.record_layer_pattern(pattern)
+    return gpt2.checkpoint_kinds(
+        {kind: partial(_layer, cfg=cfg, kind=kind) for kind in kinds},
+        cfg.remat, base, kinds)
+
+
+def _hidden(params, tokens, targets, cfg: NemotronHConfig,
+            balance: bool = False):
+    """tokens [B, S] → (the trunk's stream before the final norm, the MTP
+    module's — None without one —, the MTP targets, and with ``balance`` the
+    layers' aux (_layer): the trunk's, then the MTP module's)."""
+    B, S = tokens.shape
+    wte = params["wte"].astype(cfg.dtype)
+    with jax.named_scope(scopes.EMBED):
+        x = wte[tokens]
+    if balance:      # a forward for set-up: no backward, nothing to checkpoint
+        block_fns = {kind: partial(_layer, cfg=cfg, kind=kind, balance=True)
+                     for kind in KINDS}
+    else:
+        block_fns = _block_fns(cfg, B, S)
+
+    def run(pattern, x, stacks):
+        out = gpt2.run_pattern(block_fns, pattern, x, stacks, with_aux=balance)
+        return out if balance else (out, None)
+
+    x, aux = run(cfg.pattern, x, params["blocks"])
+    if not cfg.mtp_pattern:
+        return x, None, None, (aux, None)
+    with jax.named_scope(scopes.MTP):
+        h, mtp_targets = _mtp_input(params, x, targets, wte, cfg)
+        h, mtp_aux = run(cfg.mtp_pattern, h, params["mtp"]["blocks"])
+    return x, h, mtp_targets, (aux, mtp_aux)
+
+
+def _mtp_input(params, x, targets, wte, cfg: NemotronHConfig):
+    """The MTP module's input and targets: position t joins the trunk's x_t
+    (before the final norm) with the embedding of token t+1 (= targets[t])
+    and predicts token t+2 (= targets[t+1]); no target where either is past
+    the row's end."""
+    mtp = params["mtp"]
+    has_next = targets >= 0
+    later = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)), constant_values=-1)
+    with jax.named_scope(scopes.EMBED):
+        e = wte[jnp.where(has_next, targets, 0)]
+    both = jnp.concatenate([llama._rmsnorm(e, mtp["enorm"], cfg),
+                            llama._rmsnorm(x, mtp["hnorm"], cfg)], axis=-1)
+    h = jnp.einsum("bse,ed->bsd", both, mtp["eh_proj"].astype(cfg.dtype))
+    return h, jnp.where(has_next, later, -1)
+
+
+def _final_norm(x, params, cfg):
+    with jax.named_scope(scopes.LN_F):
+        return llama._rmsnorm(x, params["final_norm"], cfg)
+
+
+def forward(params, tokens, cfg: NemotronHConfig) -> jax.Array:
+    """tokens [B, S] int32 → the trunk's logits [B, S, vocab_size]."""
+    x, *_ = _hidden(params, tokens, None, replace(cfg, mtp_pattern=""))
+    return jnp.einsum("bsd,dv->bsv", _final_norm(x, params, cfg),
+                      params["lm_head"].astype(cfg.dtype))
+
+
+def losses(params, tokens, targets, cfg: NemotronHConfig):
+    """(the trunk's mean cross-entropy, the MTP module's or 0.0)."""
+    x, h, mtp_targets, _ = _hidden(params, tokens, targets, cfg)
+    trunk = llama._lm_head_loss(_final_norm(x, params, cfg), targets,
+                                params["lm_head"], cfg)
+    if h is None:
+        return trunk, jnp.zeros((), jnp.float32)
+    with jax.named_scope(scopes.MTP):
+        return trunk, llama._lm_head_loss(
+            _final_norm(h, params, cfg), mtp_targets, params["lm_head"], cfg)
+
+
+def loss_fn(params, tokens, targets, cfg: NemotronHConfig) -> jax.Array:
+    """CE_trunk + mtp_loss_weight · CE_mtp over targets >= 0 ([B, S] int32,
+    the next token)."""
+    trunk, mtp = losses(params, tokens, targets, cfg)
+    return trunk + cfg.mtp_loss_weight * mtp
+
+
+def flops_per_token(cfg: NemotronHConfig) -> float:
+    """Forward + backward operations one trained token REQUIRES here: 6 per
+    matmul parameter the token meets (the routed experts by the pairs a token
+    is expected to land on held ones, top_k · held / n_experts a layer; the
+    embedding is a gather), and by shape three times the forward's attention
+    (two matmuls over the causal half) and state-space scan (the chunk's two
+    quadratic products over its causal half, the state's two products)."""
+    D, S = cfg.d_model, cfg.seq_len
+    H, P, G, N = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups,
+                  cfg.ssm_state)
+    Q = min(cfg.chunk, S)
+    inner = H * P
+    per = {
+        "M": (D * (2 * inner + 2 * G * N + H) + inner * D,
+              Q / 2 * (G * N + inner) + 2 * inner * N),
+        "E": (D * cfg.n_experts + 2 * D * cfg.latent + 2 * D * cfg.d_shared
+              + cfg.top_k * cfg.held_count / cfg.n_experts
+              * 2 * cfg.latent * cfg.d_expert, 0.0),
+        "*": (2 * D * (cfg.n_head + cfg.n_kv_head) * cfg.head_dim,
+              2 * cfg.n_head * cfg.head_dim * (S + 1) / 2),
+    }
+    layers = cfg.pattern + cfg.mtp_pattern
+    matmul = sum(per[k][0] for k in layers) + D * cfg.vocab_size
+    shaped = sum(per[k][1] for k in layers)
+    if cfg.mtp_pattern:
+        matmul += 2 * D * D + D * cfg.vocab_size
+    return 6.0 * (matmul + shaped)
+
+
+# --------------------------------------------------------------------------- #
+# The selection bias, balanced at set-up; what a batch sends the held experts
+# --------------------------------------------------------------------------- #
+
+def _balanced(pattern: str, stacks, auxes):
+    """``stacks`` with the balanced biases of ``auxes`` (run_pattern's, on the
+    host) in place, and the expert layers' loads in the order they come."""
+    out, loads = [], []
+    for (sub, reps), group, aux in zip(gpt2.pattern_groups(pattern), stacks,
+                                       auxes, strict=True):
+        layers = [a for a in aux if a is not None]   # the sub-pattern's E's
+        if layers:
+            # a leaf [reps, …] (a scan's) or […] a layer of the sub-pattern →
+            # [reps · layers, …], the order the run's stack has
+            n = reps * len(layers)
+            flat = {k: np.stack([np.reshape(a[k], (reps, -1)) for a in layers],
+                                axis=1).reshape(n, -1) for k in layers[0]}
+            old = group["E"]["router_bias"]
+            group = {**group, "E": {**group["E"], "router_bias": jax.device_put(
+                flat.pop("router_bias"), old.sharding)}}
+            loads += [{k: v[i, 0] for k, v in flat.items()} for i in range(n)]
+        out.append(group)
+    return out, loads
+
+
+def balance_router_bias(params, tokens, targets, cfg: NemotronHConfig):
+    """(``params`` with every expert layer's selection bias balanced on this
+    batch, what the batch then sends the experts held here). The bias's
+    between-step update is not part of the step, so a run starts from a bias
+    that something balanced: moe.balance_bias, layer by layer in one forward
+    of its own (a layer's input is what the balanced layers before it give),
+    the weights held. The loads are the ``model/expert_load`` events
+    (tracing/names.EXPERT_LOAD_ARGS), recorded here, the trunk's expert
+    layers first, then the MTP module's. For set-up, on the first batch."""
+    trunk, mtp = jax.device_get(jax.jit(
+        lambda p, tok, tgt: _hidden(p, tok, tgt, cfg, balance=True)[3])(
+        params, tokens, targets))
+    blocks, loads = _balanced(cfg.pattern, params["blocks"], trunk)
+    params = {**params, "blocks": blocks}
+    if cfg.mtp_pattern:
+        blocks, more = _balanced(cfg.mtp_pattern, params["mtp"]["blocks"], mtp)
+        params = {**params, "mtp": {**params["mtp"], "blocks": blocks}}
+        loads += more
+    component, name = scopes.EXPERT_LOAD.split("/")
+    events = []
+    for layer, load in enumerate(loads):
+        args = {"layer": layer, **{
+            k: (float if k == "mean_per_expert" else int)(load[k])
+            for k in scopes.EXPERT_LOAD_ARGS[1:]}}
+        get_buffer().record_profile(name, component=component, args=args)
+        events.append(args)
+    return params, events

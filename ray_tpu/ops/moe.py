@@ -18,16 +18,30 @@ Shapes (T = B*S tokens, E experts, C capacity slots per expert):
 Tokens over an expert's capacity are DROPPED (standard GShard semantics:
 the residual connection carries them through unchanged); capacity_factor
 sizes C = ceil(k * T / E) * capacity_factor.
+
+Beside it, ``latent_moe`` (PR 33): an expert layer that is told which experts
+it HOLDS — one chip's share of an expert-parallel deployment. It routes over
+all ``n_experts`` at the published width and top-k (sigmoid scores, a
+selection bias, gates normalised over the chosen and scaled), sorts the
+(token, choice) pairs that land on its own experts by expert, and computes
+their two products as grouped matmuls (``lax.ragged_dot``: the TPU compiler's
+own grouped kernel, whose work follows the real group sizes), around them a
+latent projection and beside them a shared expert. No pair is dropped —
+a batch that lands more pairs here than one row buffer holds takes further
+passes over it — and memory is linear in T.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.tracing import names as scopes
 
 
 def moe_capacity(num_tokens: int, num_experts: int, top_k: int,
@@ -135,3 +149,324 @@ def moe_mlp(x: jax.Array, params: Dict[str, Any], *, top_k: int,
     frac_probs = jnp.mean(probs, axis=0)
     aux = E * jnp.sum(frac_tokens * frac_probs)
     return y.reshape(B, S, D), aux.astype(jnp.float32)
+
+
+# --------------------------------------------------------------------------- #
+# An expert layer that knows its share (PR 33)
+# --------------------------------------------------------------------------- #
+
+# the row buffer ONE PASS of the held experts' pairs takes, as a multiple of
+# the pairs a batch sends them on average (T · top_k · held / n_experts). A
+# batch that lands more than that here — a router that has learnt to prefer
+# the experts whose gradient it sees — takes further passes over the same
+# buffer, as many as its pairs need and never more than the worst case
+# (every token's choices on held experts) would: no pair is ever dropped, the
+# cost follows the pairs that are there, and memory stays one buffer's.
+ROW_BUFFER_MULTIPLE = 4.0
+_ROW_TILE = 512              # the compiler's grouped kernel tiles rows by 512
+# the selection bias at initialisation: noise small beside the scores' spread
+# (a sigmoid of logits of std ~1.3), large enough to decide near-ties
+ROUTER_BIAS_STD = 0.01
+# balance_bias: rounds of the balancing rule on one batch, and its first rate
+# (the rate falls linearly to 0, so the last rounds settle what the first
+# ones found; 64 rounds of at most 0.02 can carry a bias 0.65, the scores
+# span 1)
+BALANCE_ROUNDS, BALANCE_RATE = 64, 0.02
+
+
+class Held(NamedTuple):
+    """The routed experts one chip holds: ids first … first + count − 1."""
+    first: int
+    count: int
+
+
+def latent_moe_init(rng: jax.Array, n_layers: int, d_model: int,
+                    n_experts: int, held: int, latent: int, d_expert: int,
+                    d_shared: int, std: float, out_std: float,
+                    param_dtype=jnp.float32) -> Dict[str, Any]:
+    """``n_layers`` stacked layers: the router over all ``n_experts`` and its
+    selection bias (a buffer: no gradient reaches it, and an optimizer that
+    decays weights must leave it out), the latent projections, ``held``
+    routed experts and the shared expert."""
+    k = iter(jax.random.split(rng, 8))
+    L = n_layers
+
+    def normal(key, shape, s):
+        return (jax.random.normal(key, shape) * s).astype(param_dtype)
+
+    return {
+        "router_w": normal(next(k), (L, d_model, n_experts), std),
+        "router_bias": normal(next(k), (L, n_experts), ROUTER_BIAS_STD),
+        "w_down": normal(next(k), (L, d_model, latent), std),
+        "w_up": normal(next(k), (L, latent, d_model), out_std),
+        "w1": normal(next(k), (L, held, latent, d_expert), std),
+        "w2": normal(next(k), (L, held, d_expert, latent), std),
+        "shared_w1": normal(next(k), (L, d_model, d_shared), std),
+        "shared_w2": normal(next(k), (L, d_shared, d_model), out_std),
+    }
+
+
+def latent_moe_logical_axes() -> Dict[str, Any]:
+    return {
+        "router_w": ("layers", "embed", None),
+        "router_bias": ("layers", None),
+        "w_down": ("layers", "embed", None),
+        "w_up": ("layers", None, "embed"),
+        "w1": ("layers", "expert", None, "mlp"),
+        "w2": ("layers", "expert", "mlp", None),
+        "shared_w1": ("layers", "embed", "mlp"),
+        "shared_w2": ("layers", "mlp", "embed"),
+    }
+
+
+# what latent_moe takes in the compute dtype (the router stays float32)
+LATENT_MOE_MATMUL_WEIGHTS = ("w_down", "w_up", "w1", "w2", "shared_w1",
+                             "shared_w2")
+
+
+def row_buffer(tokens: int, n_experts: int, top_k: int, held: int) -> int:
+    """Rows of the held experts' pair buffer for a batch of ``tokens``:
+    ROW_BUFFER_MULTIPLE times the mean, a whole number of row tiles, and never
+    more than the worst case a batch can produce (every token's choices on
+    held experts) — where that is the smaller, one pass takes any batch."""
+    worst = tokens * min(top_k, held)
+    mean = tokens * top_k * held / n_experts
+    rows = -(-int(math.ceil(ROW_BUFFER_MULTIPLE * mean)) // _ROW_TILE) * _ROW_TILE
+    return min(worst, rows)
+
+
+def buffer_passes(tokens: int, n_experts: int, top_k: int, held: int) -> int:
+    """Passes over the row buffer that the worst case a batch can produce
+    would take: the static length of routed_experts' loop, of which a batch
+    runs those its pairs fill."""
+    return -(-tokens * min(top_k, held)
+             // row_buffer(tokens, n_experts, top_k, held))
+
+
+def _scores(u: jax.Array, router_w: jax.Array) -> jax.Array:
+    """u [T, D] → every expert's sigmoid score [T, n_experts], float32."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", u.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGH))
+
+
+def route(u: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
+          scaling: float) -> Tuple[jax.Array, jax.Array]:
+    """u [T, D] → (chosen expert ids [T, k], their gates [T, k] float32):
+    sigmoid scores in float32, the k largest of score + bias chosen (the bias
+    chooses only), gates = scaling · score / Σ over ALL the chosen."""
+    scores = _scores(u, router_w)
+    _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
+                       top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx, gates
+
+
+def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
+                 top_k: int) -> jax.Array:
+    """The selection bias after BALANCE_ROUNDS of the auxiliary-loss-free
+    balancing rule on ONE batch (u [T, D], the layer's normed input), the
+    weights held: ``b_e ← b_e + γ · sign(mean load − load_e)`` over all the
+    experts, γ falling from BALANCE_RATE to 0. What a run's many steps do to
+    the bias between them, done at once; for set-up — no step calls it."""
+    scores = _scores(u, router_w)
+    mean = scores.shape[0] * top_k / scores.shape[1]
+
+    def body(i, b):
+        biased = scores + b
+        kth = lax.top_k(biased, top_k)[0][:, -1:]
+        load = jnp.sum(biased >= kth, axis=0, dtype=jnp.float32)
+        rate = BALANCE_RATE * (1.0 - i / BALANCE_ROUNDS)
+        return b + rate * jnp.sign(mean - load)
+
+    return lax.fori_loop(0, BALANCE_ROUNDS, body, bias.astype(jnp.float32))
+
+
+class HeldPairs(NamedTuple):
+    """The (token, choice) pairs on held experts, sorted by expert, as
+    ``passes`` buffers of ``rows``: row r of pass i is pair ``token[i, r]``
+    with gate ``gate[i, r]`` while ``valid[i, r]``; ``group_sizes[i, e]`` of
+    the pass's rows belong to held expert e. Every pair on a held expert is
+    in some pass: passes · rows covers the worst case."""
+    token: jax.Array          # [passes, rows] int32
+    gate: jax.Array           # [passes, rows] float32
+    valid: jax.Array          # [passes, rows] bool
+    group_sizes: jax.Array    # [passes, held] int32
+    per_expert: jax.Array     # [held] int32: pairs on each held expert
+
+
+def held_pairs(idx: jax.Array, gates: jax.Array, held: Held, rows: int,
+               passes: int) -> HeldPairs:
+    """Sort the pairs of ``idx`` [T, k] that land on ``held`` by expert and
+    lay them over ``passes`` buffers of ``rows``."""
+    T, k = idx.shape
+    local = idx - held.first
+    here = (local >= 0) & (local < held.count)
+    key = jnp.where(here, local, held.count).reshape(T * k)
+    per_expert = jnp.sum(
+        key[:, None] == jnp.arange(held.count, dtype=key.dtype),
+        axis=0, dtype=jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    # pairs on no held expert sort last; past the T·k pairs there are none
+    total = passes * rows
+    order = jnp.pad(order, (0, max(0, total - T * k)))[:total]
+    valid = jnp.arange(total) < jnp.sum(per_expert)
+    # a pass's share of each expert's run of rows
+    lo = (jnp.arange(passes) * rows)[:, None]
+    ends = jnp.clip(jnp.cumsum(per_expert)[None, :], lo, lo + rows) - lo
+    return HeldPairs(
+        token=(order // k).astype(jnp.int32).reshape(passes, rows),
+        gate=gates.reshape(T * k)[order].reshape(passes, rows),
+        valid=valid.reshape(passes, rows),
+        group_sizes=jnp.diff(ends, axis=1, prepend=0).astype(jnp.int32),
+        per_expert=per_expert)
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def _one_pass(ell, w1, w2, token, gate, valid, group_sizes):
+    """One buffer of pairs through the held experts: gate · relu(ell·W1_e)² ·
+    W2_e a row, added to its token's row of a [T, latent] float32 sum."""
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        x = jnp.where(valid[:, None], ell[token], 0)          # [rows, latent]
+    # rows past the last group are whatever the kernel left there (NaN as
+    # likely as not), in the products and in their cotangents: each is masked
+    # before anything multiplies it
+    h = lax.ragged_dot(x, w1, group_sizes, preferred_element_type=ell.dtype)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        a = _relu2(jnp.where(valid[:, None], h, 0))
+    # out of the kernel in the compute dtype: a float32 output would make
+    # the backward's two grouped products take float32 operands
+    o = lax.ragged_dot(a, w2, group_sizes, preferred_element_type=ell.dtype)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        o = (jnp.where(valid[:, None], o, 0).astype(jnp.float32)
+             * gate[:, None])
+        return jnp.zeros(ell.shape, jnp.float32).at[token].add(o)
+
+
+@jax.custom_vjp
+def _run_passes(ell, w1, w2, gate, token, valid, group_sizes, n):
+    """The first ``n`` passes' sum: Σ_i _one_pass(…, token[i], gate[i], …).
+    ``n`` is a value of the step — the passes this batch's pairs fill — so
+    the loop is a ``while``; its backward is written out below (one pass's
+    vjp at a time into float32 sums), because AD through a loop of
+    conditional passes keeps every pass's operands at once (the 8 x 4,096-
+    token step then needs 18.7 GB of a v5e's 15.75)."""
+    def body(i, r):
+        return r + _one_pass(ell, w1, w2, token[i], gate[i], valid[i],
+                             group_sizes[i])
+
+    return lax.fori_loop(0, n, body, jnp.zeros(ell.shape, jnp.float32))
+
+
+def _run_passes_fwd(ell, w1, w2, gate, token, valid, group_sizes, n):
+    return (_run_passes(ell, w1, w2, gate, token, valid, group_sizes, n),
+            (ell, w1, w2, gate, token, valid, group_sizes, n))
+
+
+def _run_passes_bwd(res, d_r):
+    ell, w1, w2, gate, token, valid, group_sizes, n = res
+
+    def body(i, sums):
+        _, vjp = jax.vjp(
+            lambda e, a, b, g: _one_pass(e, a, b, token[i], g, valid[i],
+                                         group_sizes[i]),
+            ell, w1, w2, gate[i])
+        d_ell, d_w1, d_w2, d_gate = vjp(d_r)
+        return (sums[0] + d_ell.astype(jnp.float32),
+                sums[1] + d_w1.astype(jnp.float32),
+                sums[2] + d_w2.astype(jnp.float32),
+                sums[3].at[i].set(d_gate))
+
+    sums = lax.fori_loop(0, n, body, (
+        jnp.zeros(ell.shape, jnp.float32), jnp.zeros(w1.shape, jnp.float32),
+        jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(gate)))
+    return (sums[0].astype(ell.dtype), sums[1].astype(w1.dtype),
+            sums[2].astype(w2.dtype), sums[3], None, None, None, None)
+
+
+_run_passes.defvjp(_run_passes_fwd, _run_passes_bwd)
+
+
+def _dispatch(u, p, top_k: int, held: Held, scaling: float):
+    """Route u [T, D] and lay the pairs on held experts over the row buffer:
+    (chosen ids [T, k], the HeldPairs, the passes they fill)."""
+    T = u.shape[0]
+    n_experts = p["router_w"].shape[-1]
+    rows = row_buffer(T, n_experts, top_k, held.count)
+    idx, gates = route(u, p["router_w"], p["router_bias"], top_k, scaling)
+    pairs = held_pairs(idx, gates, held, rows,
+                       buffer_passes(T, n_experts, top_k, held.count))
+    return idx, pairs, -(-jnp.sum(pairs.per_expert) // rows)
+
+
+@jax.named_scope(scopes.MOE_ROUTED)
+def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
+                   top_k: int, held: Held, scaling: float) -> jax.Array:
+    """The held experts' part of the routed result, in the latent: u [T, D]
+    (what the router reads), ell [T, latent] (what the experts read) →
+    r [T, latent] float32 = Σ over a token's chosen AND held experts of
+    gate · relu(ell·W1_e)² · W2_e. One pass over the row buffer where the
+    batch's pairs fit it (row_buffer); a batch with more runs the further
+    passes it fills."""
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        _, pairs, filled = _dispatch(u, p, top_k, held, scaling)
+    return _run_passes(ell, p["w1"], p["w2"], pairs.gate, pairs.token,
+                       pairs.valid, pairs.group_sizes, filled)
+
+
+def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
+               scaling: float, shared_rows: int = 0) -> jax.Array:
+    """u [B, S, D] (normed, compute dtype) → the layer's output [B, S, D] in
+    float32: ``r·W_up`` for the held routed experts' r over ``u·W_down``,
+    plus the shared expert ``relu(u·S1)²·S2``. ``p`` holds one layer's
+    tensors, LATENT_MOE_MATMUL_WEIGHTS in the compute dtype. With
+    ``shared_rows`` < S the shared expert takes the sequence in chunks of
+    that many rows, each its own ``checkpoint`` (llama._mlp's reason)."""
+    B, S, D = u.shape
+    ut = u.reshape(B * S, D)
+    with jax.named_scope(scopes.MOE_LATENT):
+        ell = checkpoint_name(jnp.einsum("td,dl->tl", ut, p["w_down"]),
+                              scopes.RES_MOE_LATENT)
+    r = routed_experts(ut, ell, p, top_k=top_k, held=held, scaling=scaling)
+    with jax.named_scope(scopes.MOE_LATENT):
+        out = jnp.einsum("tl,ld->td", r.astype(u.dtype), p["w_up"],
+                         preferred_element_type=jnp.float32)
+
+    def shared(u_rows):
+        # a dense MLP: under the block's `mlp` scope as any other
+        with jax.named_scope(scopes.MLP), jax.named_scope(scopes.MOE_SHARED):
+            h = checkpoint_name(
+                jnp.einsum("bsd,df->bsf", u_rows, p["shared_w1"]),
+                scopes.RES_MOE_SHARED_HIDDEN)
+            return jnp.einsum("bsf,fd->bsd", _relu2(h), p["shared_w2"],
+                              preferred_element_type=jnp.float32)
+
+    if shared_rows in (0, S):
+        return out.reshape(B, S, D) + shared(u)
+    chunks = u.reshape(B, S // shared_rows, shared_rows, D).swapaxes(0, 1)
+    sh = lax.map(jax.checkpoint(shared), chunks)
+    return out.reshape(B, S, D) + sh.swapaxes(0, 1).reshape(B, S, D)
+
+
+def held_load(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
+              scaling: float) -> Dict[str, jax.Array]:
+    """What a batch sends the held experts of one layer (u [T, D], the
+    layer's normed input): the numbers of the ``model/expert_load`` event."""
+    idx, pairs, filled = _dispatch(u, p, top_k, held, scaling)
+    local = idx - held.first
+    here = (local >= 0) & (local < held.count)
+    landed = jnp.sum(pairs.per_expert)
+    return {
+        "tokens": jnp.asarray(u.shape[0], jnp.int32),
+        "pairs": landed,
+        "max_per_expert": jnp.max(pairs.per_expert),
+        "mean_per_expert": jnp.mean(pairs.per_expert.astype(jnp.float32)),
+        "tokens_without_held_expert": jnp.sum(~jnp.any(here, axis=-1)),
+        "buffer_rows": jnp.asarray(pairs.token.shape[1], jnp.int32),
+        "buffer_passes": filled,
+        "pairs_dropped": landed - jnp.sum(pairs.valid),
+    }

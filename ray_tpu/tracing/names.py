@@ -30,9 +30,24 @@ FLASH_ATTENTION = "flash_attention"
 # inside it the chunk-summary pass alone
 EVA_ATTENTION = "eva_attention"
 EVA_PREP_KV = "eva_prep_kv"
+# ops/mamba2.py: the Mamba-2 mixer (projections, conv, scan, gated norm) and,
+# inside it, the chunked state-space scan alone
+MAMBA = "mamba"
+SSD_SCAN = "ssd_scan"
+# ops/moe.latent_moe, under MOE: the routed experts (router, top-k, sort,
+# gather, grouped products, combine) and, inside that, all of it but the
+# grouped products; the latent projections around them; the shared expert
+MOE_ROUTED = "moe_routed"
+MOE_DISPATCH = "moe_dispatch"
+MOE_LATENT = "moe_latent"
+MOE_SHARED = "moe_shared"
+# models/nemotron_h.py: the multi-token-prediction module, its layers and loss
+MTP = "mtp"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
-                                          EVA_PREP_KV)
+                                          EVA_PREP_KV, MAMBA, SSD_SCAN,
+                                          MOE_ROUTED, MOE_DISPATCH, MOE_LATENT,
+                                          MOE_SHARED, MTP)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -41,8 +56,13 @@ FLASH_BWD_KERNEL = "flash_attention_bwd"
 # of every earlier window), forward and backward; the summary pass is XLA
 EVA_AGG_FWD_KERNEL = "eva_agg_fwd"
 EVA_AGG_BWD_KERNEL = "eva_agg_bwd"
+# what `lax.ragged_dot` (ops/moe.latent_moe's grouped expert products) is on
+# the chip: the TPU compiler's own grouped-matmul kernel, whose instructions
+# are named `ragged-dot-…` and carry NO op_name — the scopes they were written
+# under are gone, so a trace's reader knows them by this name alone
+RAGGED_DOT_KERNEL = "ragged-dot"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
-           EVA_AGG_BWD_KERNEL)
+           EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
@@ -67,9 +87,17 @@ RES_EVA_KT, RES_EVA_VT = "eva_k_summary", "eva_v_summary"
 RES_MID = "block_mid"
 RES_MLP_HIDDEN = "mlp_hidden"
 RES_MLP_GATE, RES_MLP_UP = "mlp_gate", "mlp_up"
+# a Mamba-2 layer's: the three projections' outputs (z, xBC before the conv,
+# dt), the state each chunk of the scan starts from, the scan's output. An
+# expert layer's: the latent input of the routed experts, the shared expert's
+# hidden pre-activation.
+RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT = "mamba_z", "mamba_xbc", "mamba_dt"
+RES_SSD_STATES, RES_SSD_Y = "ssd_states", "ssd_y"
+RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN = "moe_latent_in", "moe_shared_hidden"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
-             RES_MLP_GATE, RES_MLP_UP)
+             RES_MLP_GATE, RES_MLP_UP, RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT,
+             RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN)
 # which of them models/gpt2.py chose to save, and the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once):
 # one instant event per distinct decision, at trace time, in the task-event
@@ -77,6 +105,21 @@ RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
 REMAT_POLICY = "model/remat_policy"
 REMAT_POLICY_ARGS = ("n_layer", "batch", "seq", "saved", "saved_bytes",
                      "budget_bytes", "bytes_limit", "mlp_rows", "head_rows")
+# a model whose layers are of more than one kind (gpt2.run_pattern): the
+# pattern, how often each kind is applied and which runs of it are one scan;
+# one instant event per distinct pattern, at trace time
+LAYER_PATTERN = "model/layer_pattern"
+LAYER_PATTERN_ARGS = ("pattern", "applications", "groups")
+# what the held experts of each expert layer are sent by one batch, its
+# selection bias balanced on it (nemotron_h.balance_router_bias, from the
+# first batch at set-up): pairs landed here,
+# the largest and the mean over the held experts, tokens with no held expert,
+# the row buffer, the passes over it the pairs fill, and the pairs in no pass
+# (none: the passes cover the worst case)
+EXPERT_LOAD = "model/expert_load"
+EXPERT_LOAD_ARGS = ("layer", "tokens", "pairs", "max_per_expert",
+                    "mean_per_expert", "tokens_without_held_expert",
+                    "buffer_rows", "buffer_passes", "pairs_dropped")
 
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer

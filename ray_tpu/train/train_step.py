@@ -77,17 +77,20 @@ def _scale_by_adam_lowmem(b1: float, b2: float, eps: float,
 def default_optimizer(
     lr: float = 3e-4, weight_decay: float = 0.1, warmup: int = 100,
     total_steps: int = 10_000, b1: float = 0.9, b2: float = 0.95,
-    grad_clip: float = 1.0, eps: float = 1e-8,
+    grad_clip: float = 1.0, eps: float = 1e-8, decay_mask=None,
 ) -> optax.GradientTransformation:
     """AdamW with warmup-cosine LR, global-norm clipping, and bf16-stored
-    moments (see _scale_by_adam_lowmem)."""
+    moments (see _scale_by_adam_lowmem). ``decay_mask`` (optax's ``mask``: a
+    tree of bools, or a function of the parameters that gives one) says which
+    leaves the weight decay touches — a model with buffers among its
+    parameters gives its own (``nemotron_h.decays``); None: all of them."""
     sched = optax.warmup_cosine_decay_schedule(
         0.0, lr, warmup, max(total_steps, warmup + 1), end_value=lr * 0.1
     )
     return optax.chain(
         optax.clip_by_global_norm(grad_clip),
         _scale_by_adam_lowmem(b1, b2, eps, jnp.bfloat16),
-        optax.add_decayed_weights(weight_decay),
+        optax.add_decayed_weights(weight_decay, mask=decay_mask),
         optax.scale_by_learning_rate(sched),
     )
 

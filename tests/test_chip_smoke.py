@@ -229,10 +229,25 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
 
     cfg, steps = gpt2.gpt2_tiny(), 16
     eva_cfg = llama.evabyte_tiny(remat=True, attention_impl="pallas")
+    from ray_tpu.models import nemotron_h
+
+    hybrid_cfg = nemotron_h.nemotron_h_tiny(remat=True)
     rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1,
-                          num_devices=8, use_tpu=False, eva_model=eva_cfg)
+                          num_devices=8, use_tpu=False, eva_model=eva_cfg,
+                          hybrid_model=hybrid_cfg)
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
+    # the hybrid step: both of its events came back, no pair dropped; and
+    # the check fails without them
+    hybrid = summary["hybrid"]
+    assert {d["pattern"] for d in hybrid["layer_pattern"]} >= {
+        hybrid_cfg.pattern, hybrid_cfg.mtp_pattern}
+    assert [e["layer"] for e in hybrid["expert_load"]] == [0, 1, 2, 3]
+    assert all(e["pairs_dropped"] == 0 and e["tokens"] == 8 * hybrid_cfg.seq_len
+               for e in hybrid["expert_load"])
+    without = [rows[-1] | {"summary": summary | {"hybrid": hybrid | {
+        "layer_pattern": [], "expert_load": []}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + without, cfg, steps)) == 2
     # the EVA step: interpreted kernels under the fsdp=8 shard_map, a row a
     # device, with their tiling decisions and the rule's
     assert summary["eva"]["attention"] == ["pallas", True]

@@ -10,6 +10,8 @@ Kept in ONE file and behind fixtures: only the xdist worker that is given this
 file loads the TPU's library (on-chip-measurement guide, section 2).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -215,3 +217,40 @@ def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(
     assert d["bytes_limit"] == family.V5E_BYTES_LIMIT
     assert d["saved"] == [names.RES_K, names.RES_EVA_KT, names.RES_EVA_VT]
     assert (d["mlp_rows"], d["head_rows"]) == (4096, 4096)
+
+
+def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(one_chip):
+    """`ops/moe.routed_experts` at the Nemotron cell's shapes (32,768 tokens,
+    8 of 512 experts held, top-22, latent 1,024 → 2,688), forward and
+    backward, compiled for one described chip: every one of its six grouped
+    products (two forward, two a backward operand side) is the TPU compiler's
+    own grouped kernel (`lax.ragged_dot` → a `ragged-dot` custom call whose
+    work follows the real group sizes) and none is expanded into one dense
+    product an expert; the row buffer is 4× the mean, not the worst case."""
+    from ray_tpu.ops import moe
+
+    T, D, E, held, latent, F = 32768, 4096, 512, moe.Held(24, 8), 1024, 2688
+    rows = moe.row_buffer(T, E, 22, held.count)
+    assert rows == 45056 < T * held.count
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"router_w": abstract((D, E), jnp.float32),
+         "router_bias": abstract((E,), jnp.float32),
+         "w1": abstract((held.count, latent, F)),
+         "w2": abstract((held.count, F, latent))}
+
+    def loss(u, ell, p):
+        return jnp.sum(moe.routed_experts(u, ell, p, top_k=22, held=held,
+                                          scaling=5.0))
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        abstract((T, D)), abstract((T, latent)), p).compile().as_text()
+    grouped = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%?ragged-dot-none\S* = ", line)]
+    assert len(grouped) == 6, len(grouped)
+    assert all('custom_call_target="tpu_custom_call"' in g for g in grouped)
+    # no product of the whole buffer with one expert's matrix
+    assert f"bf16[{rows},{F}]" in hlo and not re.search(
+        rf"= \S+\[{held.count},{rows},", hlo)

@@ -1,0 +1,495 @@
+"""Nemotron-H (models/nemotron_h.py, ops/mamba2.py, ops/moe.latent_moe) against
+the benchmark's plain float32 reference on seeded weights, at tiny sizes on
+the CPU: the whole model (loss and gradient norm, remat on and off), each kind
+of layer alone, the chunked scan against the token-by-token recurrence, the
+share of a deployment tied to the uncut layer, no pair dropped, the MTP
+targets, the mesh refusals, the pattern machinery and the rule over kinds."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmarks.families import nemotron_h as family
+from benchmarks.families import nemotron_h_reference as reference
+from ray_tpu.models import gpt2, llama, nemotron_h as nh
+from ray_tpu.ops import mamba2, moe
+from ray_tpu.parallel import mesh as mesh_lib
+from ray_tpu.tracing import names
+
+
+def _batch(cfg, rows=2, seed=0):
+    rng = np.random.default_rng(seed)
+    tokens = rng.integers(0, 64, (rows, cfg.seq_len)).astype(np.int32)
+    targets = np.roll(tokens, -1, axis=1)
+    targets[:, -1] = -1
+    return tokens, targets
+
+
+def _layer_of(group, kind, i=0):
+    """Layer i of ``kind`` out of one run's stacks."""
+    return jax.tree.map(lambda t: t[i], group[kind])
+
+
+def _both(cfg, params, tokens, targets, **switches):
+    sizes = family.reference_sizes(cfg, **switches)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.value_and_grad(
+            lambda p: reference.loss(p, tokens, targets, sizes))(params)
+        got = jax.jit(jax.value_and_grad(
+            lambda p: nh.loss_fn(p, tokens, targets, cfg)))(params)
+    return got, ref
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_loss_and_gradient_equal_the_reference_in_float32(remat):
+    cfg = nh.nemotron_h_tiny(dtype=jnp.float32, remat=remat)
+    params = nh.init(cfg, jax.random.PRNGKey(1))
+    (loss, grads), (ref_loss, ref_grads) = _both(cfg, params, *_batch(cfg))
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+    np.testing.assert_allclose(optax.global_norm(grads),
+                               optax.global_norm(ref_grads), rtol=1e-5)
+    worst = jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))),
+        grads, ref_grads)
+    assert max(jax.tree.leaves(worst)) < 1e-4, worst
+
+
+def test_bf16_program_is_near_the_reference_and_the_switches_are_not():
+    """The limits of the chip's check have something to catch: in bf16 the
+    program stays near the reference; the reference with the MTP loss left
+    out does not, and with the routed experts left out an expert layer's
+    output is another (at these widths too little for the loss to show)."""
+    cfg = nh.nemotron_h_tiny()
+    params = nh.init(cfg, jax.random.PRNGKey(2))
+    tokens, targets = _batch(cfg)
+    (loss, _), (ref_loss, _) = _both(cfg, params, tokens, targets)
+    assert abs(loss - ref_loss) < 2e-3 * ref_loss
+    sizes = family.reference_sizes(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 16, cfg.d_model))
+    p = _layer_of(params["blocks"][-1], "E")
+    with jax.default_matmul_precision("highest"):
+        no_mtp = reference.loss(params, tokens, targets,
+                                {**sizes, "mtp_weight": 0.0})
+        routed = reference.latent_moe(x, p, sizes)
+        shared = reference.latent_moe(x, p, {**sizes, "drop_routed": True})
+    assert abs(no_mtp - ref_loss) > 0.05 * ref_loss
+    assert float(jnp.max(jnp.abs(routed - shared))) > 1e-4 * float(
+        jnp.max(jnp.abs(shared)))
+
+
+@pytest.mark.parametrize("kind", ["M", "E", "*"])
+def test_each_kind_of_layer_alone_equals_the_reference(kind):
+    cfg = nh.nemotron_h_tiny(dtype=jnp.float32, pattern=kind, mtp_pattern="")
+    params = nh.init(cfg, jax.random.PRNGKey(3))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, cfg.seq_len, cfg.d_model))
+    p = _layer_of(params["blocks"][0], kind)
+    sizes = family.reference_sizes(cfg)
+    with jax.default_matmul_precision("highest"):
+        got = nh._layer(x, p, cfg, kind)
+        want = reference.layers(x, kind, params["blocks"], sizes)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("seq,chunk", [(64, 16), (50, 16), (10, 16), (48, 48)])
+def test_chunked_scan_equals_the_recurrence(seq, chunk):
+    """At chunk boundaries, at a row that is no whole number of chunks and at
+    one shorter than a chunk."""
+    B, H, P, G, N = 2, 4, 8, 2, 8
+    k = jax.random.split(jax.random.PRNGKey(5), 5)
+    x = jax.random.normal(k[0], (B, seq, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, seq, H)))
+    A = -jnp.exp(jax.random.normal(k[2], (H,)))
+    Bm = jax.random.normal(k[3], (B, seq, G, N))
+    Cm = jax.random.normal(k[4], (B, seq, G, N))
+
+    def recurrence(x, dt, A, Bm, Cm):
+        hg = H // G
+
+        def step(h, t):
+            x_t, dt_t, b_t, c_t = t
+            a = jnp.exp(dt_t * A).reshape(B, G, hg)
+            dx = (x_t * dt_t[..., None]).reshape(B, G, hg, P)
+            h = a[..., None, None] * h + dx[..., None] * b_t[:, :, None, None, :]
+            return h, jnp.einsum("bghpn,bgn->bghp", h, c_t).reshape(B, H, P)
+
+        _, y = jax.lax.scan(step, jnp.zeros((B, G, hg, P, N)), tuple(
+            jnp.moveaxis(t, 1, 0) for t in (x, dt, Bm, Cm)))
+        return jnp.moveaxis(y, 0, 1)
+
+    with jax.default_matmul_precision("highest"):
+        got = mamba2.ssd_scan(x, dt, A, Bm, Cm, chunk)
+        want = recurrence(x, dt, A, Bm, Cm)
+        g_got = jax.grad(lambda *a: jnp.sum(jnp.sin(mamba2.ssd_scan(*a, chunk))),
+                         argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
+        g_want = jax.grad(lambda *a: jnp.sum(jnp.sin(recurrence(*a))),
+                          argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+
+
+# ---- the share ties to the model: the parts the shares give add up to the
+# ---- uncut reference's layer output
+
+def _whole_and_shares(kind):
+    """An uncut one-layer config and the four shares of it."""
+    whole = nh.nemotron_h_tiny(
+        dtype=jnp.float32, pattern=kind, mtp_pattern="", held_first=0,
+        held_count=32, mamba_heads=8, mamba_groups=4, n_head=8, n_kv_head=4)
+    return whole, [nh.replace(whole, held_first=8 * i, held_count=8,
+                              mamba_heads=2, mamba_groups=1, n_head=2,
+                              n_kv_head=1) for i in range(4)]
+
+
+def _cut(p, kind, i, whole):
+    """Share i's slice of the uncut layer's parameters."""
+    if kind == "E":
+        return {**p, "w1": p["w1"][8 * i:8 * i + 8],
+                "w2": p["w2"][8 * i:8 * i + 8]}
+    if kind == "*":
+        return {**p, "wq": p["wq"][:, 2 * i:2 * i + 2], "wk": p["wk"][:, i:i + 1],
+                "wv": p["wv"][:, i:i + 1], "wo": p["wo"][2 * i:2 * i + 2]}
+    P, N = whole.mamba_head_dim, whole.ssm_state
+    inner, gn = whole.mamba_inner, whole.mamba_groups * whole.ssm_state
+    ch = slice(2 * P * i, 2 * P * (i + 1))               # the group's channels
+    xbc = np.r_[ch, inner + N * i:inner + N * (i + 1),
+                inner + gn + N * i:inner + gn + N * (i + 1)]
+    hd = slice(2 * i, 2 * i + 2)
+    return {**p, "w_z": p["w_z"][:, ch], "w_xbc": p["w_xbc"][:, xbc],
+            "w_dt": p["w_dt"][:, hd], "conv_w": p["conv_w"][:, xbc],
+            "conv_b": p["conv_b"][xbc], "dt_bias": p["dt_bias"][hd],
+            "A_log": p["A_log"][hd], "D": p["D"][hd],
+            "gate_norm": p["gate_norm"][ch], "w_out": p["w_out"][ch]}
+
+
+@pytest.mark.parametrize("kind", ["M", "E", "*"])
+def test_the_shares_add_up_to_the_uncut_layer(kind):
+    """32 experts in four shares of 8, Mamba and attention heads in shares of
+    a group: the program's four partial outputs add up to the uncut
+    REFERENCE's layer output — what every chip computes alike (the shared
+    expert; the residual) counted once."""
+    whole, shares = _whole_and_shares(kind)
+    params = nh.init(whole, jax.random.PRNGKey(6))
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, whole.seq_len, whole.d_model))
+    p = _layer_of(params["blocks"][0], kind)
+    with jax.default_matmul_precision("highest"):
+        want = reference.layers(x, kind, params["blocks"],
+                                family.reference_sizes(whole)) - x
+        parts = [nh._layer(x, _cut(p, kind, i, whole), cfg, kind) - x
+                 for i, cfg in enumerate(shares)]
+        total = sum(parts)
+        if kind == "E":      # the shared expert is in every share: count it once
+            alike = reference.latent_moe(
+                reference._norm(x, p["norm"], whole.rms_eps), p,
+                {**family.reference_sizes(whole), "drop_routed": True})
+            total = total - 3 * alike
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
+    # and a part is only a part
+    assert float(jnp.max(jnp.abs(parts[0] - want))) > 1e-3 * float(
+        jnp.max(jnp.abs(want)))
+
+
+def test_no_pair_is_dropped_when_the_router_sends_everything_to_one_expert():
+    """Every token's first choice on ONE held expert: the layer still equals
+    the reference (nothing has a capacity), and the load it reports is all
+    there."""
+    cfg = nh.nemotron_h_tiny(dtype=jnp.float32, pattern="E", mtp_pattern="")
+    params = nh.init(cfg, jax.random.PRNGKey(8))
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, cfg.seq_len, cfg.d_model))
+    bias = np.zeros((1, cfg.n_experts), np.float32)
+    bias[0, cfg.held_first + 3] = 10.0                   # chosen by every token
+    stacks = [{"E": {**params["blocks"][0]["E"],
+                     "router_bias": jnp.asarray(bias)}}]
+    p = _layer_of(stacks[0], "E")
+    with jax.default_matmul_precision("highest"):
+        got = nh._layer(x, p, cfg, "E")
+        want = reference.layers(x, "E", stacks, family.reference_sizes(cfg))
+        u = llama._rmsnorm(x, p["norm"], cfg).reshape(-1, cfg.d_model)
+        load = moe.held_load(u, p, top_k=cfg.top_k, held=cfg.held,
+                             scaling=cfg.routed_scaling)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    tokens = 2 * cfg.seq_len
+    assert int(load["max_per_expert"]) == tokens
+    assert int(load["pairs"]) >= tokens and int(load["pairs_dropped"]) == 0
+    assert int(load["tokens_without_held_expert"]) == 0
+
+
+def test_row_buffer_is_the_worst_case_where_that_is_small_and_passes_take_the_rest():
+    # tiny: 4x the mean passes the worst case, so one pass takes any batch
+    assert moe.row_buffer(128, 32, 4, 8) == 128 * 4
+    assert moe.buffer_passes(128, 32, 4, 8) == 1
+    # the cell: 4x the mean of 11,264 pairs, far under the worst 262,144
+    assert moe.row_buffer(32768, 512, 22, 8) == 45056
+    assert moe.buffer_passes(32768, 512, 22, 8) == 6
+    # a router that sends every token to both held experts: 12,000 pairs over
+    # two passes of 6,144 rows, every one of them in the sum
+    assert (moe.row_buffer(6000, 16, 2, 2), moe.buffer_passes(6000, 16, 2, 2)
+            ) == (6144, 2)
+    k = jax.random.split(jax.random.PRNGKey(13), 3)
+    p = {"router_w": jnp.zeros((8, 16)).at[:, :2].set(1.0),
+         "router_bias": jnp.zeros((16,)),
+         "w1": jax.random.normal(k[0], (2, 4, 6)),
+         "w2": jax.random.normal(k[1], (2, 6, 4))}
+    ell = jax.random.normal(k[2], (6000, 4))
+
+    def routed(ell, p):
+        return moe.routed_experts(jnp.ones((6000, 8)), ell, p, top_k=2,
+                                  held=moe.Held(0, 2), scaling=1.0)
+
+    def dense(ell, p):                         # both gates are 1/2
+        return sum(0.5 * jnp.square(jax.nn.relu(ell @ p["w1"][e])) @ p["w2"][e]
+                   for e in range(2))
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(routed(ell, p), dense(ell, p),
+                                   rtol=1e-4, atol=1e-4)
+        got = jax.grad(lambda *a: jnp.sum(jnp.sin(routed(*a))), (0, 1))(ell, p)
+        want = jax.grad(lambda *a: jnp.sum(jnp.sin(dense(*a))), (0, 1))(ell, p)
+    for name in ("w1", "w2"):            # the gates are constants in `dense`
+        np.testing.assert_allclose(got[1][name], want[1][name], rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(want[1][name]))))
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-3)
+
+
+def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(monkeypatch):
+    """The TPU's grouped kernel leaves the rows past the last group as it
+    found them (the chip run that lacked a mask: NaN gates' gradients, then a
+    NaN router, then every pair on the first experts and a DMA past the
+    buffer). Here `lax.ragged_dot` is made to leave NaN there, in its output
+    and in its operand's cotangent: the layer and its gradients are what they
+    were."""
+    from jax import lax
+
+    real = lax.ragged_dot
+
+    @jax.custom_vjp
+    def dirty(lhs, rhs, sizes):
+        return _nan_past(real(lhs, rhs, sizes), sizes)
+
+    def _nan_past(x, sizes):
+        return jnp.where(jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes),
+                         x, jnp.nan)
+
+    def fwd(lhs, rhs, sizes):
+        return dirty(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def bwd(res, g):
+        lhs, rhs, sizes = res
+        _, vjp = jax.vjp(lambda l, r: real(l, r, sizes), lhs, rhs)
+        d_lhs, d_rhs = vjp(jnp.where(jnp.isnan(g), 0, g))
+        return _nan_past(d_lhs, sizes), d_rhs, None
+
+    dirty.defvjp(fwd, bwd)
+    cfg = nh.nemotron_h_tiny(dtype=jnp.float32, pattern="E", mtp_pattern="")
+    params = nh.init(cfg, jax.random.PRNGKey(11))
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, cfg.seq_len, cfg.d_model))
+    p = _layer_of(params["blocks"][0], "E")
+
+    def f(x, p):
+        return jnp.sum(jnp.sin(nh._layer(x, p, cfg, "E")))
+
+    want = jax.value_and_grad(f, argnums=(0, 1))(x, p)
+    monkeypatch.setattr(moe.lax, "ragged_dot", lambda l, r, s, **kw: dirty(l, r, s))
+    got = jax.value_and_grad(f, argnums=(0, 1))(x, p)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+def test_passes_share_an_experts_run_of_rows():
+    idx = jnp.zeros((64, 2), jnp.int32).at[:, 1].set(1)
+    pairs = moe.held_pairs(idx, jnp.ones((64, 2)), moe.Held(0, 2), rows=48,
+                           passes=3)
+    assert pairs.per_expert.tolist() == [64, 64]
+    assert pairs.group_sizes.tolist() == [[48, 0], [16, 32], [0, 32]]
+    assert pairs.valid.sum(axis=1).tolist() == [48, 48, 32]
+    # expert 0's tokens first, each once; then expert 1's
+    assert sorted(pairs.token.reshape(-1)[:64].tolist()) == list(range(64))
+
+
+def test_mtp_targets_end_with_the_row():
+    """The module at t embeds token t+1 and predicts token t+2: the last two
+    positions of a row have no target, and a row's second loss is the
+    reference's."""
+    cfg = nh.nemotron_h_tiny(dtype=jnp.float32)
+    params = nh.init(cfg, jax.random.PRNGKey(10))
+    tokens, targets = _batch(cfg, rows=1)
+    _, _, mtp_targets, _ = nh._hidden(params, tokens, jnp.asarray(targets), cfg)
+    np.testing.assert_array_equal(mtp_targets[0, :-2], tokens[0, 2:])
+    np.testing.assert_array_equal(mtp_targets[0, -2:], [-1, -1])
+    with jax.default_matmul_precision("highest"):
+        trunk, mtp = nh.losses(params, tokens, targets, cfg)
+        want = reference.losses(params, tokens, targets,
+                                family.reference_sizes(cfg))
+    np.testing.assert_allclose([trunk, mtp], want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("axis", ["pp", "ep", "cp"])
+def test_mesh_rules_refuse_what_cannot_run(axis):
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(**{axis: 2}), jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="not implemented"):
+        nh.mesh_rules(nh.nemotron_h_tiny(), mesh)
+
+
+def test_trains_through_the_one_factory_on_a_data_mesh():
+    from ray_tpu.train.train_step import make_train_step
+
+    cfg = nh.nemotron_h_tiny(remat=True)
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(fsdp=2), jax.devices()[:2])
+    bundle = make_train_step(nh, cfg, mesh=mesh)
+    tokens, targets = _batch(cfg, rows=4)
+    batch = jax.device_put({"tokens": tokens, "targets": targets},
+                           bundle.data_sharding)
+    state, losses = bundle.state, []
+    for _ in range(3):
+        state, metrics = bundle.step_fn(state, batch)
+        losses.append(float(metrics["loss"]))
+    assert losses[-1] < losses[0] and all(np.isfinite(losses))
+
+
+# ---- the shared machinery
+
+@pytest.mark.parametrize("pattern,groups", [
+    ("MEMEMEMEM*E", [("ME", 4), ("M", 1), ("*", 1), ("E", 1)]),
+    ("B" * 12, [("B", 12)]),
+    ("*E", [("*", 1), ("E", 1)]),
+    ("MEME*E", [("ME", 2), ("*", 1), ("E", 1)]),
+    ("MEMEMEM*EMEMEMEM*",
+     [("ME", 3), ("M", 1), ("*", 1), ("EM", 4), ("*", 1)]),
+    (nh.nemotron_3_super_120b().pattern,
+     [("MEMEMEM*E", 3), ("MEMEMEMEM*E", 4), ("ME", 3), ("M", 1), ("*", 1),
+      ("EM", 4), ("E", 1)]),
+])
+def test_pattern_groups(pattern, groups):
+    assert gpt2.pattern_groups(pattern) == groups
+    assert reference._groups(pattern) == groups
+    assert "".join(sub * reps for sub, reps in groups) == pattern
+
+
+def test_run_pattern_scans_repeats_and_applies_the_rest_in_order():
+    """Each kind adds its own digit: the order of application is the
+    pattern's, whichever layers one scan holds."""
+    fns = {"a": lambda x, p: x * 10 + p["v"], "b": lambda x, p: x * 10 - p["v"]}
+    pattern = "abababba"
+    stacks, n = [], 0
+    for sub, reps in gpt2.pattern_groups(pattern):
+        group = {}
+        for kind in dict.fromkeys(sub):
+            count = reps * sub.count(kind)
+            group[kind] = {"v": jnp.arange(n, n + count, dtype=jnp.float32)}
+            n += count
+        stacks.append(group)
+    want, seen = 0.0, {id(g[k]["v"]): 0 for g in stacks for k in g}
+    for (sub, reps), group in zip(gpt2.pattern_groups(pattern), stacks):
+        for _ in range(reps):
+            for kind in sub:
+                i = seen[id(group[kind]["v"])]
+                seen[id(group[kind]["v"])] += 1
+                want = fns[kind](want, {"v": float(group[kind]["v"][i])})
+    got = gpt2.run_pattern(fns, pattern, jnp.zeros(()), stacks)
+    assert float(got) == want
+
+
+def test_the_rule_counts_applications_per_kind():
+    """One rule over all the kinds: a candidate costs its bytes once an
+    application of ITS kind, and the largest kind's block sets the working
+    set."""
+    C, K = gpt2.RematCandidate, gpt2.KindShard
+    a = K(5, (C(("x",), 100, 1000),), 700)
+    b = K(1, (C(("y",), 100, 500),), 300)
+    limit = gpt2.REMAT_RESERVE_BYTES + 1000 + 700
+    keep = lambda extra: gpt2.choose_remat_policy_kinds(
+        [a, b], 1000, limit + extra, 0)
+    assert keep(0).saved == ()
+    assert keep(100).saved == ("y",)             # one application fits
+    assert keep(500).saved == ("x",)             # five of the better one
+    assert keep(600) == gpt2.RematPolicy(("x", "y"), 600, 600, limit + 600)
+    # the one-kind rule is the same rule
+    shard = gpt2.block_shard(gpt2.gpt2_tiny(), 8, 128, None, True)
+    one = gpt2.choose_remat_policy(shard, 2, 2 ** 31, 0)
+    assert one == gpt2.choose_remat_policy_kinds(
+        [K(2, tuple(gpt2.remat_candidates(shard)),
+           gpt2.block_working_set(shard))],
+        gpt2.model_working_set(shard, 2), 2 ** 31, 0)
+    assert (gpt2.model_working_set(shard, 2) + gpt2.block_working_set(shard)
+            == gpt2.rematted_working_set(shard, 2))
+
+
+def test_the_cells_kinds_and_the_familys_arithmetic():
+    from benchmarks.harness import spec
+
+    cell, config, _ = spec.load_cell("nemotron-3-super-120b-l11.dataset")
+    cfg = family.program_config(config, cell)
+    shapes = family.shapes(config, cell)
+    assert shapes["params"] == nh.param_count(cfg) == 838_245_872
+    assert family.train_flops_per_token(shapes) == nh.flops_per_token(cfg)
+    base, kinds = nh.kind_shards(cfg, cell["per_chip_batch"], cfg.seq_len, None)
+    assert {k: v.applications for k, v in kinds.items()} == {
+        "M": 5, "E": 6, "*": 2}
+    named = {n for k in kinds.values() for c in k.candidates for n in c.names}
+    assert named <= set(names.RESIDUALS) and names.RES_MID not in named
+    assert {names.RES_SSD_STATES, names.RES_MOE_LATENT, names.RES_Q} <= named
+    assert base.head_rows == 128 and base.mlp_rows < cfg.seq_len
+
+
+def test_balancing_the_selection_bias_evens_the_load_and_changes_nothing_else():
+    """moe.balance_bias on one batch: every expert's load comes near the
+    mean (before: some experts several times it, some none), with the
+    weights as they were; balance_router_bias does so for every expert layer,
+    the scanned ones too, and reports the held experts' load after it."""
+    cfg = nh.nemotron_h_tiny(dtype=jnp.float32)
+    params = nh.init(cfg, jax.random.PRNGKey(14))
+    tokens, targets = _batch(cfg, rows=8)
+    p = _layer_of(params["blocks"][0], "E")
+    u = jax.random.normal(jax.random.PRNGKey(15), (512, cfg.d_model))
+    mean = 512 * cfg.top_k / cfg.n_experts
+
+    def loads(bias):
+        idx, _ = moe.route(u, p["router_w"], bias, cfg.top_k, cfg.routed_scaling)
+        return np.bincount(np.asarray(idx).ravel(), minlength=cfg.n_experts)
+
+    before = loads(p["router_bias"])
+    after = loads(moe.balance_bias(u, p["router_w"], p["router_bias"], cfg.top_k))
+    assert before.max() > 1.5 * mean
+    assert after.max() <= 1.1 * mean and after.min() >= 0.9 * mean
+
+    balanced, events = nh.balance_router_bias(params, tokens, targets, cfg)
+    assert [e["layer"] for e in events] == [0, 1, 2, 3]
+    held_mean = 8 * cfg.seq_len * cfg.top_k / cfg.n_experts
+    for e in events:
+        assert e["pairs_dropped"] == 0
+        assert e["max_per_expert"] <= 1.2 * held_mean
+        assert abs(e["mean_per_expert"] - held_mean) <= 0.1 * held_mean
+    # the stacks keep their layout: [layers of the run, n_experts]
+    for old, new in zip(params["blocks"] + params["mtp"]["blocks"],
+                        balanced["blocks"] + balanced["mtp"]["blocks"]):
+        if "E" in old:
+            assert new["E"]["router_bias"].shape == old["E"]["router_bias"].shape
+            assert new["E"]["router_w"] is old["E"]["router_w"]
+    # and the program still equals the reference on the balanced parameters
+    (loss, _), (ref_loss, _) = _both(cfg, balanced, tokens[:2], targets[:2])
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+
+
+def test_the_selection_bias_is_a_buffer_no_step_moves():
+    """No gradient reaches it, and the optimizer's decay is told to leave it
+    (nemotron_h.decays): after steps it is bit for bit what it was, while the
+    router's weights beside it have moved."""
+    from ray_tpu.train.train_step import default_optimizer, make_train_step
+
+    cfg = nh.nemotron_h_tiny()
+    bundle = make_train_step(nh, cfg, optimizer=default_optimizer(
+        lr=1e-2, warmup=0, decay_mask=nh.decays), rng=jax.random.PRNGKey(16))
+    before = jax.tree.map(np.asarray, bundle.state["params"])
+    tokens, targets = _batch(cfg)
+    state = bundle.state
+    for _ in range(2):
+        state, _ = bundle.step_fn(state, {"tokens": tokens, "targets": targets})
+    for old, new in zip(before["blocks"], state["params"]["blocks"]):
+        if "E" in old:
+            np.testing.assert_array_equal(old["E"]["router_bias"],
+                                          new["E"]["router_bias"])
+            assert float(jnp.max(jnp.abs(
+                old["E"]["router_w"] - new["E"]["router_w"]))) > 0

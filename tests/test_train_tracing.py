@@ -34,15 +34,29 @@ LOWERINGS = {
     "moe": dict(moe_experts=2, attention_impl="xla"),
     # the llama-family block with the EVA mixer (EvaByte, PR 31)
     "eva": dict(remat=True, attention_impl="pallas"),
+    # a pattern of layer kinds: Mamba-2, LatentMoE, attention, MTP (PR 33)
+    "nemotron": dict(remat=True, attention_impl="pallas"),
 }
 # each model's own mixer: GPT-2 has the flash kernels, EvaByte the EVA ones
 EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
 EVA_KERNELS = (names.EVA_AGG_FWD_KERNEL, names.EVA_AGG_BWD_KERNEL)
 FLASH_KERNELS = (names.FLASH_FWD_KERNEL, names.FLASH_BWD_KERNEL)
-DENSE_SCOPES = tuple(s for s in names.SCOPES
-                     if s != names.MOE and s not in EVA_SCOPES)
-EVABYTE_SCOPES = tuple(s for s in names.SCOPES
-                       if s not in (names.MOE, names.FLASH_ATTENTION))
+NEMOTRON_SCOPES = (names.MAMBA, names.SSD_SCAN, names.MOE_ROUTED,
+                   names.MOE_DISPATCH, names.MOE_LATENT, names.MOE_SHARED,
+                   names.MTP)
+DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE
+                     and s not in EVA_SCOPES + NEMOTRON_SCOPES)
+EVABYTE_SCOPES = tuple(s for s in names.SCOPES if s not in (
+    names.MOE, names.FLASH_ATTENTION) + NEMOTRON_SCOPES)
+# every layer of the hybrid is a mixer OR a feed-forward part: one norm a
+# layer (ln1), the shared expert under `mlp` inside `moe`
+HYBRID_SCOPES = tuple(s for s in names.SCOPES
+                      if s != names.LN2 and s not in EVA_SCOPES)
+NEMOTRON_RESIDUALS = (
+    names.RES_MAMBA_Z, names.RES_MAMBA_XBC, names.RES_MAMBA_DT,
+    names.RES_SSD_STATES, names.RES_SSD_Y, names.RES_MOE_LATENT,
+    names.RES_MOE_SHARED_HIDDEN, names.RES_Q, names.RES_K, names.RES_V,
+    names.RES_FLASH_O, names.RES_FLASH_LSE)
 _lowered = {}
 
 
@@ -51,13 +65,16 @@ def _lowering(key):
     if key not in _lowered:
         import jax
 
-        from ray_tpu.models import gpt2, llama
+        from ray_tpu.models import gpt2, llama, nemotron_h
         from ray_tpu.train.train_step import (
             make_gpt2_train_step, make_train_step, synthetic_batch)
 
         if key == "eva":
             cfg = llama.evabyte_tiny(**LOWERINGS[key])
             bundle = make_train_step(llama, cfg)
+        elif key == "nemotron":
+            cfg = nemotron_h.nemotron_h_tiny(**LOWERINGS[key])
+            bundle = make_train_step(nemotron_h, cfg)
         else:
             cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
             bundle = make_gpt2_train_step(cfg)
@@ -90,6 +107,71 @@ def test_scope_in_lowered_evabyte_step(scope):
         assert _has_scope(op_names, f"{names.EVA_ATTENTION}/{scope}")
 
 
+@pytest.mark.parametrize("scope", HYBRID_SCOPES)
+def test_scope_in_lowered_nemotron_step(scope):
+    """Every kind of layer carries the block's scopes and its own, nested as
+    tracing/names.py says: the scan inside the mixer, the dispatch inside the
+    routed experts, the MTP module's layers and loss inside `mtp`."""
+    op_names, _ = _lowering("nemotron")
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+    inside = {names.SSD_SCAN: names.MAMBA, names.MOE_DISPATCH: names.MOE_ROUTED,
+              names.MOE_ROUTED: names.MOE, names.MOE_LATENT: names.MOE,
+              names.MOE_SHARED: f"{names.MOE}/{names.MLP}",
+              names.MAMBA: names.BLOCK, names.MOE: names.BLOCK}
+    if scope in inside:
+        assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+    if scope == names.MTP:
+        for inner in (names.BLOCK, names.LM_HEAD_LOSS, names.LN_F):
+            assert any(re.search(rf"{names.MTP}\)*/(.*/)?{inner}", n)
+                       for n in op_names), inner
+
+
+@pytest.mark.parametrize("residual", NEMOTRON_RESIDUALS)
+def test_residual_name_in_nemotron_jaxpr(residual):
+    assert residual in names.RESIDUALS
+    _, jaxpr = _lowering("nemotron")
+    assert f"name={residual}" in jaxpr
+
+
+def test_nemotron_step_records_its_pattern_and_its_expert_load(buffer):
+    """Tracing the step leaves a `model/layer_pattern` decision a pattern
+    (trunk, MTP module) and a `model/remat_policy` one over all 8 layers;
+    `balance_router_bias` on a batch leaves one `model/expert_load` event an
+    expert layer, no pair dropped, and gives back the parameters with nothing
+    but the selection biases changed."""
+    import jax
+
+    from ray_tpu.models import gpt2, nemotron_h
+    from ray_tpu.train.train_step import synthetic_batch
+
+    _lowering("nemotron")
+    cfg = nemotron_h.nemotron_h_tiny()
+    by = {d["pattern"]: d for d in gpt2.layer_pattern_decisions()}
+    assert tuple(by[cfg.pattern]) == names.LAYER_PATTERN_ARGS
+    assert by[cfg.pattern]["applications"] == {"M": 2, "E": 3, "*": 1}
+    assert by[cfg.pattern]["groups"] == ["2 x scan(ME)", "*", "E"]
+    assert by[cfg.mtp_pattern]["groups"] == ["*", "E"]
+    assert any((d["n_layer"], d["batch"], d["seq"]) == (8, 2, cfg.seq_len)
+               for d in gpt2.remat_policy_decisions())
+    batch = synthetic_batch(cfg, 2)
+    params = nemotron_h.init(cfg, jax.random.PRNGKey(0))
+    balanced, loads = nemotron_h.balance_router_bias(
+        params, batch["tokens"], batch["targets"], cfg)
+    changed = jax.tree_util.tree_map_with_path(
+        lambda path, a, b: (path[-1].key, bool((a != b).any())),
+        params, balanced)
+    assert {name for name, moved in jax.tree.leaves(
+        changed, is_leaf=lambda x: isinstance(x, tuple)) if moved} == {
+            "router_bias"}
+    events = [e for e in _drain(buffer, "model")
+              if e["name"] == names.EXPERT_LOAD.split("/")[1]]
+    assert [e["args"] for e in events] == loads and len(loads) == 4
+    for load in loads:
+        assert tuple(load) == names.EXPERT_LOAD_ARGS
+        assert load["pairs_dropped"] == 0 and load["tokens"] == 2 * cfg.seq_len
+        assert 0 < load["pairs"] <= load["buffer_rows"]
+
+
 def test_moe_scope_stands_where_mlp_stands():
     op_names, _ = _lowering("moe")
     assert _has_scope(op_names, f"{names.BLOCK}/{names.MOE}")
@@ -114,11 +196,17 @@ def test_remat_recompute_keeps_the_block_scopes(blocks):
 
 
 def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
-    assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS)
+    assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS
+                                     + (names.RAGGED_DOT_KERNEL,))
 
 
 @pytest.mark.parametrize("kernel", names.KERNELS)
 def test_kernel_name_in_jaxpr(kernel):
+    if kernel == names.RAGGED_DOT_KERNEL:
+        # the compiler's kernel: the program writes the primitive it becomes
+        _, jaxpr = _lowering("nemotron")
+        assert "ragged_dot" in jaxpr
+        return
     _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else "remat")
     assert f"name={kernel}" in jaxpr
 
@@ -316,11 +404,15 @@ def test_iterator_spans_on_the_profiler_clock(tmp_path, monkeypatch, buffer):
      dict(direction="fwd", scopes=["block", "attn", "flash_attention"],
           kernel="flash_attention_fwd", stack=False)),
     ("", dict(direction="other", scopes=[], remat=False, stack=False)),
+    # the compiler's grouped kernel: no op_name of the program's, its own name
+    ("ragged-dot-none:", dict(direction="other", scopes=[],
+                              kernel="ragged-dot", stack=False)),
 ])
 def test_classify_by_the_programs_names(tf_op, want):
     from benchmarks.harness import program_trace
 
-    got = program_trace.classify(tf_op, "fusion.1", "op")
+    got = program_trace.classify(
+        tf_op, "ragged-dot-none.3" if "ragged" in tf_op else "fusion.1", "op")
     assert {k: got[k] for k in want} == want
 
 
