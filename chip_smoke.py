@@ -13,7 +13,9 @@ EVA mixer, ``remat=True``) through the same factory, for the ``ops/eva_tiling``
 and ``model/remat_policy`` decisions it is traced with, and one step of a small
 Nemotron-H hybrid (``models/nemotron_h.py``: Mamba-2, LatentMoE and attention
 layers and the MTP module) for its ``model/layer_pattern`` and
-``model/expert_load`` events. It then checks what came back (see
+``model/expert_load`` events, and — what the expert layer's chosen-set mask
+rests on — that this backend's ``lax.top_k`` lists equal elements in index
+order (``chosen_rows_off``). It then checks what came back (see
 check_training/check_device).
 
 This process never initialises a JAX backend: a chip belongs to one process
@@ -50,6 +52,10 @@ MIN_LOSS_DROP = 0.25         # of those nats, first step → mean of the last fo
 # norm a root of a sum over every parameter, so a few roundoffs bound each.
 LOSS_RTOL = 2.0 ** -10
 GRAD_NORM_RTOL = 2.0 ** -8
+# Nemotron-3-Super's routing as the cell runs it: 8 rows of 4,096 tokens over
+# 512 experts, 22 chosen
+ROUTER_SHAPE = (32768, 512)
+ROUTER_TOP_K = 22
 
 
 def with_targets(block):
@@ -63,6 +69,26 @@ def with_targets(block):
 
 
 _SHAPE3 = re.compile(r"\b(?:bf16|f32)\[(\d+),(\d+),(\d+)\]")
+
+
+def chosen_rows_off(seed: int) -> int:
+    """Rows in which ``ops/moe._chosen`` (the chosen set as a mask) differs
+    from the ids ``lax.top_k`` lists, over scores at the published routing
+    shape whose every row has ties across the k-th place. 0 wherever the
+    backend's top_k lists equal elements in index order, which the mask
+    rests on (tier-1 holds it on the CPU; this holds it on the chip)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    rows, top_k = ROUTER_SHAPE[0], ROUTER_TOP_K
+    tied = jnp.round(
+        16 * jax.random.uniform(jax.random.PRNGKey(seed), ROUTER_SHAPE)) / 16
+    _, ids = jax.lax.top_k(tied, top_k)
+    listed = jnp.zeros(ROUTER_SHAPE, bool).at[
+        jnp.arange(rows)[:, None], ids].set(True)
+    return int(jnp.sum(jnp.any(moe._chosen(tied, top_k) != listed, axis=-1)))
 
 
 def attention_call_shapes(hlo_text: str, head_dim: int) -> Tuple[int, List[List[int]]]:
@@ -235,7 +261,8 @@ def train_loop(config: Dict[str, Any]) -> None:
         hybrid = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
                   "seq_len": hybrid_cfg.seq_len,
                   "layer_pattern": layer_pattern_decisions(),
-                  "expert_load": load}
+                  "expert_load": load,
+                  "chosen_rows_off": chosen_rows_off(config["seed"])}
         del variant
     jax.monitoring.unregister_event_listener(on_event)
 
@@ -360,6 +387,11 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if dropped:
             bad.append(f"the hybrid step's expert layers dropped {dropped} "
                        "(token, choice) pairs")
+        if hybrid["chosen_rows_off"]:
+            bad.append(f"ops/moe._chosen differs from lax.top_k's own list in "
+                       f"{hybrid['chosen_rows_off']} of {ROUTER_SHAPE[0]} "
+                       "rows with ties: this backend's top_k does not list "
+                       "equal elements in index order")
     return bad
 
 
@@ -482,6 +514,9 @@ def main() -> int:
               f"expert; {e['tokens_without_held_expert']} tokens with none), "
               f"{e['buffer_passes']} pass(es) over a buffer of "
               f"{e['buffer_rows']} rows, dropped {e['pairs_dropped']}")
+    print(f"chosen set as a mask against lax.top_k's list, "
+          f"{ROUTER_SHAPE[0]}x{ROUTER_SHAPE[1]} scores with ties, top "
+          f"{ROUTER_TOP_K}: {hybrid['chosen_rows_off']} rows differ")
     print(f"hybrid step ({hybrid_cfg.pattern} + MTP {hybrid_cfg.mtp_pattern} "
           f"of {hybrid_cfg.d_model}, {summary['device_count']}x"
           f"{hybrid['seq_len']} tokens, remat): loss {hybrid['loss']:.4f} "

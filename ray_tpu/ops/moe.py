@@ -22,13 +22,17 @@ sizes C = ceil(k * T / E) * capacity_factor.
 Beside it, ``latent_moe`` (PR 33): an expert layer that is told which experts
 it HOLDS — one chip's share of an expert-parallel deployment. It routes over
 all ``n_experts`` at the published width and top-k (sigmoid scores, a
-selection bias, gates normalised over the chosen and scaled), sorts the
-(token, choice) pairs that land on its own experts by expert, and computes
-their two products as grouped matmuls (``lax.ragged_dot``: the TPU compiler's
-own grouped kernel, whose work follows the real group sizes), around them a
-latent projection and beside them a shared expert. No pair is dropped —
-a batch that lands more pairs here than one row buffer holds takes further
-passes over it — and memory is linear in T.
+selection bias, gates normalised over the chosen and scaled) and keeps the
+outcome in the terms of the experts it holds (PR 34): which tokens chose each
+held expert, a [T, held] membership, and their gates, [T, held] — the chosen
+set as a mask over the scores, so the normalising sum is a masked row-sum and
+no score is gathered by chosen id. The membership's true entries, sorted by
+expert (T · held keys that carry their own token, not T · top_k with an index
+operand), are the rows of the two products, computed as grouped matmuls
+(``lax.ragged_dot``: the TPU compiler's own grouped kernel, whose work follows
+the real group sizes), around them a latent projection and beside them a
+shared expert. No pair is dropped — a batch that lands more pairs here than
+one row buffer holds takes further passes over it — and memory is linear in T.
 """
 
 from __future__ import annotations
@@ -250,17 +254,35 @@ def _scores(u: jax.Array, router_w: jax.Array) -> jax.Array:
         precision=lax.Precision.HIGH))
 
 
+def _chosen(biased: jax.Array, top_k: int) -> jax.Array:
+    """biased [T, E] → [T, E] bool, ``top_k`` true a row: the very set
+    ``lax.top_k`` names, as a mask. It puts equal elements in index order, so
+    with ``kth`` its last value and ``last`` that value's index the set is all
+    above ``kth`` and, of those equal to it, the ids up to ``last`` — one
+    elementwise pass, ties included. That order among equals is the backend's
+    lowering, not ``lax.top_k``'s contract: tier-1 holds it on the CPU
+    (tests/test_nemotron_h.py, the ties case), ``chip_smoke.chosen_rows_off``
+    on the chip at the published routing shape."""
+    vals, idx = lax.top_k(biased, top_k)
+    kth, last = vals[:, -1:], idx[:, -1:]
+    ids = lax.broadcasted_iota(idx.dtype, biased.shape, 1)
+    return (biased > kth) | ((biased == kth) & (ids <= last))
+
+
 def route(u: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float) -> Tuple[jax.Array, jax.Array]:
-    """u [T, D] → (chosen expert ids [T, k], their gates [T, k] float32):
-    sigmoid scores in float32, the k largest of score + bias chosen (the bias
-    chooses only), gates = scaling · score / Σ over ALL the chosen."""
+          scaling: float, held: Held) -> Tuple[jax.Array, jax.Array]:
+    """u [T, D] → (``here`` [T, held] bool: the token chose that held expert,
+    its gates [T, held] float32, 0 where not): sigmoid scores in float32, the
+    k largest of score + bias chosen (the bias chooses only), gates = scaling
+    · score / Σ over ALL the chosen. The sum is a masked row-sum and the held
+    experts' scores a static slice: nothing is gathered by chosen id."""
     scores = _scores(u, router_w)
-    _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
-                       top_k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    gates = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-    return idx, gates
+    chosen = _chosen(
+        lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
+    denom = jnp.sum(jnp.where(chosen, scores, 0.0), axis=-1, keepdims=True)
+    span = slice(held.first, held.first + held.count)
+    here = chosen[:, span]
+    return here, jnp.where(here, scaling * scores[:, span] / denom, 0.0)
 
 
 def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
@@ -284,11 +306,14 @@ def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
 
 
 class HeldPairs(NamedTuple):
-    """The (token, choice) pairs on held experts, sorted by expert, as
-    ``passes`` buffers of ``rows``: row r of pass i is pair ``token[i, r]``
-    with gate ``gate[i, r]`` while ``valid[i, r]``; ``group_sizes[i, e]`` of
-    the pass's rows belong to held expert e. Every pair on a held expert is
-    in some pass: passes · rows covers the worst case."""
+    """The (token, held expert) pairs a batch chose, sorted by expert and
+    within an expert by token, as ``passes`` buffers of ``rows``: row r of
+    pass i is pair ``token[i, r]`` with gate ``gate[i, r]`` while
+    ``valid[i, r]``; ``group_sizes[i, e]`` of the pass's rows belong to held
+    expert e. Every pair on a held expert is in some pass: passes · rows
+    covers the worst case. A row that holds no pair names token 0 and carries
+    whatever gate sits at its place, NOT zero: ``valid`` alone says which
+    rows count, and ``_one_pass`` masks by it."""
     token: jax.Array          # [passes, rows] int32
     gate: jax.Array           # [passes, rows] float32
     valid: jax.Array          # [passes, rows] bool
@@ -296,28 +321,31 @@ class HeldPairs(NamedTuple):
     per_expert: jax.Array     # [held] int32: pairs on each held expert
 
 
-def held_pairs(idx: jax.Array, gates: jax.Array, held: Held, rows: int,
+def held_pairs(here: jax.Array, gates: jax.Array, rows: int,
                passes: int) -> HeldPairs:
-    """Sort the pairs of ``idx`` [T, k] that land on ``held`` by expert and
-    lay them over ``passes`` buffers of ``rows``."""
-    T, k = idx.shape
-    local = idx - held.first
-    here = (local >= 0) & (local < held.count)
-    key = jnp.where(here, local, held.count).reshape(T * k)
-    per_expert = jnp.sum(
-        key[:, None] == jnp.arange(held.count, dtype=key.dtype),
-        axis=0, dtype=jnp.int32)
-    order = jnp.argsort(key, stable=True)
-    # pairs on no held expert sort last; past the T·k pairs there are none
+    """Lay the true entries of ``here`` [T, held] (route's membership, with
+    its ``gates``) over ``passes`` buffers of ``rows``, expert by expert and
+    token-ascending within one. A pair's sort key is its place in ``here.T``
+    — expert · T + token, so it carries both — and every other entry's key
+    sorts last: one sort of T · held keys, no index operand."""
+    T, held = here.shape
+    none = held * T                              # fits int32 with room
+    per_expert = jnp.sum(here, axis=0, dtype=jnp.int32)
+    place = jnp.arange(none, dtype=jnp.int32).reshape(held, T)
+    # (the pairs' keys are distinct, so the sort need not be stable: a stable
+    # one takes an index operand on the TPU)
+    key = jnp.sort(jnp.where(here.T, place, none).reshape(none), stable=False)
+    # past the T · held keys there are no pairs
     total = passes * rows
-    order = jnp.pad(order, (0, max(0, total - T * k)))[:total]
+    key = jnp.pad(key, (0, max(0, total - none)))[:total]
     valid = jnp.arange(total) < jnp.sum(per_expert)
+    key = jnp.where(valid, key, 0)
     # a pass's share of each expert's run of rows
     lo = (jnp.arange(passes) * rows)[:, None]
     ends = jnp.clip(jnp.cumsum(per_expert)[None, :], lo, lo + rows) - lo
     return HeldPairs(
-        token=(order // k).astype(jnp.int32).reshape(passes, rows),
-        gate=gates.reshape(T * k)[order].reshape(passes, rows),
+        token=(key % T).reshape(passes, rows),
+        gate=gates.T.reshape(none)[key].reshape(passes, rows),
         valid=valid.reshape(passes, rows),
         group_sizes=jnp.diff(ends, axis=1, prepend=0).astype(jnp.int32),
         per_expert=per_expert)
@@ -393,14 +421,15 @@ _run_passes.defvjp(_run_passes_fwd, _run_passes_bwd)
 
 def _dispatch(u, p, top_k: int, held: Held, scaling: float):
     """Route u [T, D] and lay the pairs on held experts over the row buffer:
-    (chosen ids [T, k], the HeldPairs, the passes they fill)."""
+    (the membership [T, held], the HeldPairs, the passes they fill)."""
     T = u.shape[0]
     n_experts = p["router_w"].shape[-1]
     rows = row_buffer(T, n_experts, top_k, held.count)
-    idx, gates = route(u, p["router_w"], p["router_bias"], top_k, scaling)
-    pairs = held_pairs(idx, gates, held, rows,
+    here, gates = route(u, p["router_w"], p["router_bias"], top_k, scaling,
+                        held)
+    pairs = held_pairs(here, gates, rows,
                        buffer_passes(T, n_experts, top_k, held.count))
-    return idx, pairs, -(-jnp.sum(pairs.per_expert) // rows)
+    return here, pairs, -(-jnp.sum(pairs.per_expert) // rows)
 
 
 @jax.named_scope(scopes.MOE_ROUTED)
@@ -456,9 +485,7 @@ def held_load(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
               scaling: float) -> Dict[str, jax.Array]:
     """What a batch sends the held experts of one layer (u [T, D], the
     layer's normed input): the numbers of the ``model/expert_load`` event."""
-    idx, pairs, filled = _dispatch(u, p, top_k, held, scaling)
-    local = idx - held.first
-    here = (local >= 0) & (local < held.count)
+    here, pairs, filled = _dispatch(u, p, top_k, held, scaling)
     landed = jnp.sum(pairs.per_expert)
     return {
         "tokens": jnp.asarray(u.shape[0], jnp.int32),

@@ -248,6 +248,12 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     without = [rows[-1] | {"summary": summary | {"hybrid": hybrid | {
         "layer_pattern": [], "expert_load": []}}}]
     assert len(chip_smoke.check_training(rows[:-1] + without, cfg, steps)) == 2
+    # the chosen set as a mask is top_k's own list on this backend, ties
+    # included; and the check fails where it is not
+    assert hybrid["chosen_rows_off"] == 0
+    tied = [rows[-1] | {"summary": summary | {"hybrid": hybrid | {
+        "chosen_rows_off": 3}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + tied, cfg, steps)) == 1
     # the EVA step: interpreted kernels under the fsdp=8 shard_map, a row a
     # device, with their tiling decisions and the rule's
     assert summary["eva"]["attention"] == ["pallas", True]

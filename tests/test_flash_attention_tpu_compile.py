@@ -10,6 +10,7 @@ Kept in ONE file and behind fixtures: only the xdist worker that is given this
 file loads the TPU's library (on-chip-measurement guide, section 2).
 """
 
+import math
 import re
 
 import jax
@@ -219,38 +220,86 @@ def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(
     assert (d["mlp_rows"], d["head_rows"]) == (4096, 4096)
 
 
-def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(one_chip):
+_MOE_T, _MOE_E, _MOE_HELD, _MOE_F = 32768, 512, 8, 2688
+
+
+@pytest.fixture(scope="module")
+def routed_experts_hlo(one_chip):
     """`ops/moe.routed_experts` at the Nemotron cell's shapes (32,768 tokens,
-    8 of 512 experts held, top-22, latent 1,024 → 2,688), forward and
-    backward, compiled for one described chip: every one of its six grouped
-    products (two forward, two a backward operand side) is the TPU compiler's
-    own grouped kernel (`lax.ragged_dot` → a `ragged-dot` custom call whose
-    work follows the real group sizes) and none is expanded into one dense
-    product an expert; the row buffer is 4× the mean, not the worst case."""
+    experts 24–31 of 512 held, top-22, latent 1,024 → 2,688), forward and
+    backward, compiled for one described chip: the program text."""
     from ray_tpu.ops import moe
 
-    T, D, E, held, latent, F = 32768, 4096, 512, moe.Held(24, 8), 1024, 2688
-    rows = moe.row_buffer(T, E, 22, held.count)
-    assert rows == 45056 < T * held.count
+    D, latent, held = 4096, 1024, moe.Held(24, _MOE_HELD)
 
     def abstract(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    p = {"router_w": abstract((D, E), jnp.float32),
-         "router_bias": abstract((E,), jnp.float32),
-         "w1": abstract((held.count, latent, F)),
-         "w2": abstract((held.count, F, latent))}
+    p = {"router_w": abstract((D, _MOE_E), jnp.float32),
+         "router_bias": abstract((_MOE_E,), jnp.float32),
+         "w1": abstract((held.count, latent, _MOE_F)),
+         "w2": abstract((held.count, _MOE_F, latent))}
 
     def loss(u, ell, p):
         return jnp.sum(moe.routed_experts(u, ell, p, top_k=22, held=held,
                                           scaling=5.0))
 
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        abstract((T, D)), abstract((T, latent)), p).compile().as_text()
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        abstract((_MOE_T, D)), abstract((_MOE_T, latent)), p
+    ).compile().as_text()
+
+
+def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(
+        routed_experts_hlo):
+    """Every one of the six grouped products (two forward, two a backward
+    operand side) is the TPU compiler's own grouped kernel (`lax.ragged_dot`
+    → a `ragged-dot` custom call whose work follows the real group sizes) and
+    none is expanded into one dense product an expert; the row buffer is 4×
+    the mean, not the worst case."""
+    from ray_tpu.ops import moe
+
+    hlo = routed_experts_hlo
+    rows = moe.row_buffer(_MOE_T, _MOE_E, 22, _MOE_HELD)
+    assert rows == 45056 < _MOE_T * _MOE_HELD
     grouped = [line for line in hlo.splitlines()
                if re.match(r"\s*(ROOT )?%?ragged-dot-none\S* = ", line)]
     assert len(grouped) == 6, len(grouped)
     assert all('custom_call_target="tpu_custom_call"' in g for g in grouped)
     # no product of the whole buffer with one expert's matrix
-    assert f"bf16[{rows},{F}]" in hlo and not re.search(
-        rf"= \S+\[{held.count},{rows},", hlo)
+    assert f"bf16[{rows},{_MOE_F}]" in hlo and not re.search(
+        rf"= \S+\[{_MOE_HELD},{rows},", hlo)
+
+
+def test_routing_gathers_no_score_and_sorts_the_membership_on_the_v5e(
+        routed_experts_hlo):
+    """PR 34: the gates come from a masked row-sum and a static slice of the
+    scores, so no `gather` (and no `scatter`, its transpose) touches a
+    [32,768 x 512] array; the pairs are sorted from the [tokens x 8]
+    membership, so beside `top_k`'s own sort no `sort` has more than 262,144
+    keys, and the one that has them takes one operand (the key carries its
+    token: no index rides along)."""
+    hlo = routed_experts_hlo
+
+    # every instruction's (first) result: name → elements
+    size = {name: math.prod(int(d) for d in dims.split(",") if d)
+            for name, dims in re.findall(
+                r"(%[\w.\-]+) = \(?\w+\[([\d,]*)\]", hlo)}
+    ops = [line.strip().removeprefix("ROOT ") for line in hlo.splitlines()
+           if re.search(r" (gather|scatter|sort)\(", line)]
+    # a gather reads its first operand, a scatter writes its result
+    read = [size[re.search(r" gather\((%[\w.\-]+)", op).group(1)]
+            for op in ops if " gather(" in op]
+    written = [size[op.split(" = ")[0]] for op in ops if " scatter(" in op]
+    assert read and written
+    assert _MOE_T * _MOE_E not in read + written, (read, written)
+    sorts = [op for op in ops if " sort(" in op]
+    top_k = [op for op in sorts if "/top_k" in op]
+    assert top_k and all(
+        size[op.split(" = ")[0]] == _MOE_T * _MOE_E for op in top_k)
+    operands = {}                                   # keys → operands
+    for op in set(sorts) - set(top_k):
+        shapes = re.findall(r"\w+\[[\d,]*\]", op.split(" sort(")[0])
+        keys = size[op.split(" = ")[0]]
+        operands[keys] = max(operands.get(keys, 0), len(shapes))
+    assert max(operands) == _MOE_T * _MOE_HELD, operands
+    assert operands[_MOE_T * _MOE_HELD] == 1, operands

@@ -299,14 +299,127 @@ def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(monkeypatch
 
 
 def test_passes_share_an_experts_run_of_rows():
-    idx = jnp.zeros((64, 2), jnp.int32).at[:, 1].set(1)
-    pairs = moe.held_pairs(idx, jnp.ones((64, 2)), moe.Held(0, 2), rows=48,
-                           passes=3)
+    pairs = moe.held_pairs(jnp.ones((64, 2), bool), jnp.ones((64, 2)),
+                           rows=48, passes=3)
     assert pairs.per_expert.tolist() == [64, 64]
     assert pairs.group_sizes.tolist() == [[48, 0], [16, 32], [0, 32]]
     assert pairs.valid.sum(axis=1).tolist() == [48, 48, 32]
-    # expert 0's tokens first, each once; then expert 1's
-    assert sorted(pairs.token.reshape(-1)[:64].tolist()) == list(range(64))
+    # expert 0's tokens first, each once and in order; then expert 1's
+    assert pairs.token.reshape(-1)[:128].tolist() == 2 * list(range(64))
+
+
+def _chosen_list_pairs(scores, bias, top_k, scaling, held, rows, passes):
+    """What ops/moe computed until PR 34, written out plainly: the chosen ids
+    [T, k], their gates gathered by id, the T · k (token, choice) pairs
+    stably argsorted by held expert."""
+    T = scores.shape[0]
+    _, idx = jax.lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    local = idx - held.first
+    here = (local >= 0) & (local < held.count)
+    key = jnp.where(here, local, held.count).reshape(T * top_k)
+    per_expert = jnp.sum(key[:, None] == jnp.arange(held.count), axis=0,
+                         dtype=jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    total = passes * rows
+    order = jnp.pad(order, (0, max(0, total - T * top_k)))[:total]
+    valid = jnp.arange(total) < jnp.sum(per_expert)
+    lo = (jnp.arange(passes) * rows)[:, None]
+    ends = jnp.clip(jnp.cumsum(per_expert)[None, :], lo, lo + rows) - lo
+    return idx, moe.HeldPairs(
+        token=(order // top_k).astype(jnp.int32).reshape(passes, rows),
+        gate=gates.reshape(T * top_k)[order].reshape(passes, rows),
+        valid=valid.reshape(passes, rows),
+        group_sizes=jnp.diff(ends, axis=1, prepend=0).astype(jnp.int32),
+        per_expert=per_expert)
+
+
+def _membership_logits(case):
+    """(logits [T, E], selection bias [E], top_k, held, rows) of a case."""
+    T, E = 96, 32
+    rng = np.random.default_rng(34)
+    logits = rng.normal(0, 1.3, (T, E)).astype(np.float32)
+    bias = rng.normal(0, 0.01, E).astype(np.float32)
+    top_k, held, rows = 4, moe.Held(8, 8), 40
+    if case == "ties_across_the_kth_place":
+        # a few levels only, no bias: every row has equal biased scores on
+        # both sides of its k-th place, many rows inside the held ids
+        logits, bias = np.round(logits), np.zeros(E, np.float32)
+        # and written out: ids 0 and 1 above a plateau over ids 5..20, of
+        # which the k-th place takes 5 and 6 (not held), 7's equals stay out;
+        # the next row's plateau starts inside the held ids: 10, 11 are in
+        logits[0], logits[1] = -3.0, -3.0
+        logits[0, [0, 1]], logits[0, 5:21] = 2.0, 1.0
+        logits[1, [0, 31]], logits[1, 10:16] = 2.0, 1.0
+    elif case == "no_held_expert_and_all_of_them":
+        top_k = 10
+        logits[0, 8:16] = -9.0                 # chooses none of the held
+        logits[1, 8:16] = 9.0                  # chooses all eight
+    elif case == "top_k_above_held":
+        top_k, held, rows = 12, moe.Held(29, 3), 56
+    elif case == "top_k_below_held":
+        top_k, held, rows = 2, moe.Held(0, 16), 24
+    return jnp.asarray(logits), jnp.asarray(bias), top_k, held, rows
+
+
+@pytest.mark.parametrize("case", [
+    "random", "ties_across_the_kth_place", "no_held_expert_and_all_of_them",
+    "top_k_above_held", "top_k_below_held"])
+def test_the_membership_dispatch_is_the_chosen_lists(case):
+    """moe.route + moe.held_pairs (the chosen set as a mask, gates from a
+    static slice, pairs sorted from the [T, held] membership) against the
+    chosen-list form above: rows, validity, group sizes and loads equal
+    element for element; gates and their derivative by the scores to 1e-6
+    (22 float32 numbers added in another order)."""
+    logits, bias, top_k, held, rows = _membership_logits(case)
+    T, E = logits.shape
+    passes = -(-T * min(top_k, held.count) // rows)
+    assert passes > 1
+    scaling, cot = 2.5, jax.random.normal(jax.random.PRNGKey(0), (passes, rows))
+
+    def new(logits):         # the router's product with an identity is exact
+        here, gates = moe.route(logits, jnp.eye(E), bias, top_k, scaling, held)
+        return here, moe.held_pairs(here, gates, rows, passes)
+
+    def old(logits):
+        return _chosen_list_pairs(jax.nn.sigmoid(logits), bias, top_k, scaling,
+                                  held, rows, passes)
+
+    (here, got), (idx, want) = new(logits), old(logits)
+    # the mask is the list: top_k a row, ties to the lower ids
+    everyone, _ = moe.route(logits, jnp.eye(E), bias, top_k, scaling,
+                            moe.Held(0, E))
+    assert everyone.sum(axis=1).tolist() == [top_k] * T
+    listed = np.zeros((T, E), bool)
+    np.put_along_axis(listed, np.asarray(idx), True, axis=1)
+    np.testing.assert_array_equal(everyone, listed)
+    np.testing.assert_array_equal(here, listed[:, held.first:][:, :held.count])
+    if case == "ties_across_the_kth_place":
+        assert np.flatnonzero(listed[0]).tolist() == [0, 1, 5, 6]
+        assert np.flatnonzero(listed[1]).tolist() == [0, 10, 11, 31]
+    if case == "no_held_expert_and_all_of_them":
+        assert here[:2].sum(axis=1).tolist() == [0, held.count]
+
+    assert int(want.valid.sum()) == int(here.sum()) > rows
+    np.testing.assert_array_equal(got.valid, want.valid)
+    np.testing.assert_array_equal(got.per_expert, want.per_expert)
+    np.testing.assert_array_equal(got.group_sizes, want.group_sizes)
+    np.testing.assert_array_equal(jnp.where(got.valid, got.token, -1),
+                                  jnp.where(want.valid, want.token, -1))
+    np.testing.assert_allclose(jnp.where(got.valid, got.gate, 0),
+                               jnp.where(want.valid, want.gate, 0),
+                               rtol=1e-6, atol=1e-7)
+
+    def pulled(f):           # d (the valid rows' gates · cot) / d logits:
+        def scalar(logits):  # the scores' derivative times s · (1 − s)
+            pairs = f(logits)[1]
+            return jnp.sum(jnp.where(pairs.valid, pairs.gate * cot, 0))
+        return jax.grad(scalar)(logits)
+
+    d_got, d_want = pulled(new), pulled(old)
+    assert float(jnp.max(jnp.abs(d_want))) > 0.01
+    np.testing.assert_allclose(d_got, d_want, rtol=1e-6, atol=1e-6)
 
 
 def test_mtp_targets_end_with_the_row():
@@ -447,8 +560,9 @@ def test_balancing_the_selection_bias_evens_the_load_and_changes_nothing_else():
     mean = 512 * cfg.top_k / cfg.n_experts
 
     def loads(bias):
-        idx, _ = moe.route(u, p["router_w"], bias, cfg.top_k, cfg.routed_scaling)
-        return np.bincount(np.asarray(idx).ravel(), minlength=cfg.n_experts)
+        chosen, _ = moe.route(u, p["router_w"], bias, cfg.top_k,
+                              cfg.routed_scaling, moe.Held(0, cfg.n_experts))
+        return np.asarray(chosen).sum(axis=0)
 
     before = loads(p["router_bias"])
     after = loads(moe.balance_bias(u, p["router_w"], p["router_bias"], cfg.top_k))

@@ -60,25 +60,30 @@ NEMOTRON_RESIDUALS = (
 _lowered = {}
 
 
+def _step(key):
+    """(bundle, batch of 2) of the tiny train step `key` names, built anew."""
+    from ray_tpu.models import gpt2, llama, nemotron_h
+    from ray_tpu.train.train_step import (
+        make_gpt2_train_step, make_train_step, synthetic_batch)
+
+    if key == "eva":
+        cfg = llama.evabyte_tiny(**LOWERINGS[key])
+        bundle = make_train_step(llama, cfg)
+    elif key == "nemotron":
+        cfg = nemotron_h.nemotron_h_tiny(**LOWERINGS[key])
+        bundle = make_train_step(nemotron_h, cfg)
+    else:
+        cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
+        bundle = make_gpt2_train_step(cfg)
+    return bundle, synthetic_batch(cfg, 2)
+
+
 def _lowering(key):
     """(op_names of the lowered train step, its jaxpr as text), made once."""
     if key not in _lowered:
         import jax
 
-        from ray_tpu.models import gpt2, llama, nemotron_h
-        from ray_tpu.train.train_step import (
-            make_gpt2_train_step, make_train_step, synthetic_batch)
-
-        if key == "eva":
-            cfg = llama.evabyte_tiny(**LOWERINGS[key])
-            bundle = make_train_step(llama, cfg)
-        elif key == "nemotron":
-            cfg = nemotron_h.nemotron_h_tiny(**LOWERINGS[key])
-            bundle = make_train_step(nemotron_h, cfg)
-        else:
-            cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
-            bundle = make_gpt2_train_step(cfg)
-        batch = synthetic_batch(cfg, 2)
+        bundle, batch = _step(key)
         text = bundle.step_fn.lower(bundle.state, batch).as_text(debug_info=True)
         _lowered[key] = (
             set(re.findall(r'loc\("([^"]+)"', text)),
@@ -235,13 +240,19 @@ def test_eva_tiling_decision_of_the_lowered_step(kernel):
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
-def test_flash_tiling_decision_of_the_lowered_step(kernel):
+def test_flash_tiling_decision_of_the_lowered_step(kernel, monkeypatch):
     """Tracing the step leaves one `ops/flash_tiling` decision per kernel,
     with the vocabulary's args, for the shard the kernel was given."""
+    import jax
+
     from ray_tpu.models import gpt2
     from ray_tpu.ops import attention
 
-    _lowering("remat")
+    # the record is the process's: other files trace these tiny shapes with
+    # blocks of their own, so this step is traced anew into a record of its own
+    monkeypatch.setattr(attention, "_decisions", {})
+    bundle, batch = _step("remat")
+    jax.make_jaxpr(bundle.step_fn)(bundle.state, batch)
     cfg = gpt2.gpt2_tiny()
     mine = [d for d in attention.flash_tiling_decisions()
             if (d["kernel"], d["rows"], d["Sq"], d["hd"])
