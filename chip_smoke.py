@@ -16,7 +16,11 @@ layers and the MTP module) for its ``model/layer_pattern`` and
 ``model/expert_load`` events, and — what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
-check_training/check_device).
+check_training/check_device) and, after ``shutdown()``, prints from the
+session's record (``ray_tpu.timeline()``, PR 35) the phases of the ``fit()``
+trace, the worker processes started and reaped and the ``train/compile``
+events of the run — failing if ``train/fit`` or ``train/loop_entered`` is not
+in it (``session_story``).
 
 This process never initialises a JAX backend: a chip belongs to one process
 and that process is the train worker, so every device fact below travelled
@@ -424,6 +428,65 @@ def check_device(summary: Dict[str, Any], model_cfg, per_chip_batch: int,
     return bad
 
 
+def session_story(trace: List[Dict[str, Any]]) -> Tuple[List[str], List[str]]:
+    """(lines, failures) from the finished session's record — what
+    ``ray_tpu.timeline()`` returns after ``shutdown()``: the phases of the
+    ``fit()`` attempt's trace in time order, the worker processes the raylet
+    started and reaped, the driver's init and shutdown, and the train
+    worker's compiles of half a second or more (PR 35). Seconds on the
+    host's clock: set-up and teardown, not speed."""
+    from ray_tpu.tracing import names
+
+    spans = [e for e in trace
+             if e.get("cat") in ("train", "data", "raylet", "driver")
+             and e.get("ph") in ("X", "i")]
+    by_name: Dict[str, List[Dict[str, Any]]] = {}
+    for e in spans:
+        by_name.setdefault(f"{e['cat']}/{e['name']}", []).append(e)
+    failures = [f"the session's record holds no {name}"
+                for name in ("train/fit", "train/loop_entered")
+                if name not in by_name]
+    if failures:
+        return [], failures
+    fit = by_name["train/fit"][-1]
+    t0, trace_id = fit["ts"], fit["args"].get("trace_id")
+    lines = [f"fit() trace {trace_id}: attempt {fit['args'].get('attempt')}, "
+             f"{fit.get('dur', 0.0) / 1e6:.2f} s; phases (start after fit() "
+             "was called, seconds):"]
+    compiles = []
+    for e in spans:
+        name = f"{e['cat']}/{e['name']}"
+        if name == "train/compile":
+            compiles.append(e)
+        elif (name in names.SETUP_SPANS and e is not fit
+              and e["args"].get("trace_id") == trace_id):
+            lines.append(f"  {name:28s} +{(e['ts'] - t0) / 1e6:7.2f}  "
+                         f"{e.get('dur', 0.0) / 1e6:7.2f}")
+    for name in ("raylet/worker_start", "raylet/worker_reap"):
+        groups: Dict[str, List[float]] = {}
+        for e in by_name.get(name, ()):
+            what = f"{e['args'].get('kind', '')} {e['args']['platform']}"
+            groups.setdefault(what.strip(), []).append(e.get("dur", 0.0) / 1e6)
+        for what, durs in sorted(groups.items()):
+            lines.append(f"  {name:28s} {len(durs)} x {what}: "
+                         f"{min(durs):.2f} to {max(durs):.2f} s")
+    for e in by_name.get("driver/wait_process", ()):
+        lines.append(f"  {'driver/wait_process':28s} {e['args']['name']} (pid "
+                     f"{e['args']['pid']}): {e.get('dur', 0.0) / 1e6:.2f} s")
+    for name in ("driver/init", "driver/shutdown"):
+        for e in by_name.get(name, ()):
+            lines.append(f"  {name:28s} {e.get('dur', 0.0) / 1e6:.2f} s")
+    total = sum(e["args"]["seconds"] for e in compiles)
+    slow = [e for e in compiles if e["args"]["seconds"] >= 0.5]
+    lines.append(f"train/compile: {len(compiles)} backend compiles or cache "
+                 f"loads, {total:.1f} s; those of 0.5 s or more: "
+                 + ", ".join(f"{e['args']['fun_name']} "
+                             f"{e['args']['seconds']:.1f}s "
+                             f"({e['args'].get('cache') or 'not cached'})"
+                             for e in slow))
+    return lines, failures
+
+
 def _driver_backend_initialised() -> bool:
     jax = sys.modules.get("jax")
     return jax is not None and jax._src.xla_bridge.backends_are_initialized()
@@ -469,6 +532,10 @@ def main() -> int:
     )
     if _driver_backend_initialised():
         failures.append("the driver process initialised a JAX backend")
+    # the record the session left behind: asked after shutdown(), so it
+    # starts nothing and holds the teardown too
+    story, missing = session_story(ray_tpu.timeline())
+    failures += missing
 
     print(f"device: platform={','.join(summary['platforms'])} "
           f"device_kind={summary['device_kind']!r} "
@@ -535,6 +602,7 @@ def main() -> int:
                       for key, m in summary["parity"].items())
           + f" (rtol {LOSS_RTOL:g} / {GRAD_NORM_RTOL:g})")
     print(f"epochs over the {DATASET_BATCHES}-batch dataset: {summary['epochs']}")
+    print("\n".join(story))
     if failures:
         for f in failures:
             print(f"chip_smoke: FAILED: {f}", file=sys.stderr)

@@ -28,6 +28,8 @@ class Worker:
         self.backend: Optional[Backend] = None
         self.mode: Optional[str] = None  # "local" | "cluster" | "worker"
         self.namespace: str = "default"
+        # the last finished session's Chrome trace (Backend.session_timeline)
+        self.last_timeline: Optional[List[dict]] = None
 
     @property
     def connected(self):
@@ -123,9 +125,11 @@ def shutdown():
             pass
     with _init_lock:
         if _worker.backend is not None:
+            backend = _worker.backend
             try:
-                _worker.backend.shutdown()
+                backend.shutdown()
             finally:
+                _worker.last_timeline = backend.session_timeline
                 _worker.backend = None
                 _worker.mode = None
 
@@ -236,14 +240,28 @@ def timeline(filename: Optional[str] = None) -> List[dict]:
     slices, other lifecycle transitions as instants, profile_span() spans
     as slices on the worker that recorded them. Open the file in
     chrome://tracing or Perfetto. Returns the event list; also writes JSON
-    when `filename` is given."""
+    when `filename` is given.
+
+    Connected, it asks the running cluster. After ``shutdown()`` it returns
+    the finished session's record and starts nothing: the aggregator's events
+    fetched as shutdown began, plus what teardown itself recorded
+    (``driver/shutdown`` and the processes it waited on, each worker's
+    ``raylet/worker_reap``) — the same list ``shutdown()`` wrote to
+    ``/tmp/ray_tpu/<session>/timeline.json`` (cluster backend; the local
+    backend has no session directory and only keeps the list). Each
+    ``fit()`` attempt is one trace: filter on ``args.trace_id`` of its
+    ``train/fit`` span. What a long job still holds of its set-up is the
+    aggregator's retention rule (``tracing/aggregator.py``)."""
     import json
 
     from ray_tpu.tracing import build_chrome_trace
     from ray_tpu.util.state import timeline_events
 
-    out = build_chrome_trace(timeline_events())
+    if not _worker.connected and _worker.last_timeline is not None:
+        out = _worker.last_timeline
+    else:
+        out = build_chrome_trace(timeline_events())
     if filename:
         with open(filename, "w") as f:
-            json.dump(out, f)
+            json.dump(out, f, default=str)
     return out

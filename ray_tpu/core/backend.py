@@ -85,6 +85,10 @@ class Backend(abc.ABC):
     def cancel(self, ref: ObjectRef, force: bool, recursive: bool) -> None:
         ...
 
+    # the finished session's Chrome trace, set by shutdown() where the
+    # backend keeps one: what ray_tpu.timeline() returns once disconnected
+    session_timeline: Optional[List[dict]] = None
+
     @abc.abstractmethod
     def shutdown(self) -> None:
         ...

@@ -20,12 +20,14 @@ import uuid
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ray_tpu import exceptions as exc
+from ray_tpu import tracing
 from ray_tpu.core import rpc
 from ray_tpu.core.backend import Backend
 from ray_tpu.core.core_worker import CoreWorker
 from ray_tpu.core.ids import ActorID
 from ray_tpu.core.options import RemoteOptions
 from ray_tpu.core.refs import ObjectRef
+from ray_tpu.tracing import names
 
 
 def _session_tmp_dir(session: str) -> str:
@@ -40,6 +42,7 @@ class ProcessGroup:
     def __init__(self, session_dir: str):
         self.session_dir = session_dir
         self.procs: List[subprocess.Popen] = []
+        self.names: List[str] = []       # spawn()'s name of each of procs
 
     def spawn(self, name: str, argv: List[str], env=None) -> subprocess.Popen:
         log = open(os.path.join(self.session_dir, "logs", f"{name}.log"), "ab")
@@ -51,6 +54,7 @@ class ProcessGroup:
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
         p = subprocess.Popen(argv, stdout=log, stderr=subprocess.STDOUT, env=env)
         self.procs.append(p)
+        self.names.append(name)
         return p
 
     def shutdown(self):
@@ -63,11 +67,17 @@ class ProcessGroup:
         from ray_tpu.core.raylet.worker_pool import REAP_TIMEOUT_S
 
         deadline = time.monotonic() + REAP_TIMEOUT_S + 5
-        for p in self.procs:
-            try:
-                p.wait(timeout=max(0.1, deadline - time.monotonic()))
-            except subprocess.TimeoutExpired:
-                p.kill()
+        for name, p in zip(self.names, self.procs):
+            with tracing.named_span(names.DRIVER_WAIT_PROCESS, {
+                    "name": name, "pid": p.pid}) as span:
+                t0 = time.monotonic()
+                killed = False
+                try:
+                    p.wait(timeout=max(0.1, deadline - time.monotonic()))
+                except subprocess.TimeoutExpired:
+                    p.kill()
+                    killed = True
+                span.args.update(seconds=time.monotonic() - t0, killed=killed)
 
 
 def _token_path(gcs_address: str) -> str:
@@ -150,6 +160,12 @@ def start_raylet(
     pg.spawn(f"raylet-{node_id}", argv)
 
 
+def _event_key(e: dict) -> tuple:
+    """What makes a task event the same event in two copies of a record."""
+    return (e.get("task_id"), e.get("name"), e.get("state"), e.get("ts"),
+            e.get("worker"))
+
+
 def _free_port() -> int:
     import socket
 
@@ -176,7 +192,18 @@ class ClusterBackend(Backend):
         if core_worker is not None:  # worker mode
             self.core = core_worker
             return
+        self._t_init = time.time()
         session = f"s{uuid.uuid4().hex[:10]}"
+        with tracing.named_span(names.DRIVER_INIT, {
+                "session": session, "started_cluster": address is None}):
+            self._connect_driver(
+                session, address, num_cpus, num_tpus, resources,
+                object_store_memory, node_name)
+
+    def _connect_driver(self, session, address, num_cpus, num_tpus, resources,
+                        object_store_memory, node_name) -> None:
+        """Start a single-node cluster (no address) or join one, and connect
+        this driver's core worker to its GCS and local raylet."""
         node_id = node_name or f"node-{uuid.uuid4().hex[:8]}"
         if address is None:
             self._procs = ProcessGroup(_session_tmp_dir(session))
@@ -485,15 +512,67 @@ class ClusterBackend(Backend):
         )
 
     def shutdown(self):
-        try:
+        if self._procs is None:
+            # a worker, or a driver that joined a cluster others run: the
+            # session and its record are not this process's to close
             self.core.shutdown()
-        finally:
-            if self._procs:
-                self._procs.shutdown()
-                # reclaim tmpfs (real RAM): this driver owns the session
-                try:
-                    from ray_tpu.core.object_store.shm_store import ShmClient
+            return
+        # the session's record outlives the session: what the aggregator
+        # holds is fetched before anything stops, what is recorded from here
+        # on (this driver's shutdown spans, the raylet's and the workers'
+        # last events in the WAL directory) is appended once every process
+        # is gone
+        from ray_tpu.core.config import _config
 
-                    ShmClient(self.core.session).destroy()
-                except Exception:  # noqa: BLE001
-                    pass
+        keep = _config.task_events_enabled
+        events = self._fetch_session_events() if keep else []
+        try:
+            with tracing.named_span(names.DRIVER_SHUTDOWN,
+                                    {"session": self.core.session}):
+                try:
+                    self.core.shutdown()
+                finally:
+                    self._procs.shutdown()
+            if keep:
+                self._write_session_record(events)
+        finally:
+            # reclaim tmpfs (real RAM): this driver owns the session
+            try:
+                from ray_tpu.core.object_store.shm_store import ShmClient
+
+                ShmClient(self.core.session).destroy()
+            except Exception:  # noqa: BLE001
+                pass
+
+    def _fetch_session_events(self) -> List[dict]:
+        try:
+            return self.core.io.run(self.core.gcs.call(
+                "timeline_events", limit=10 ** 9, timeout=30))
+        except Exception:  # noqa: BLE001 - a dead GCS: the files still tell
+            return []
+
+    def _write_session_record(self, events: List[dict]) -> None:
+        """``<session_dir>/timeline.json``: the Chrome trace of the whole
+        session — the aggregator's events, this process's still unflushed
+        ones and the files under the session's ``task_wal/`` — kept in
+        ``session_timeline`` for ``ray_tpu.timeline()`` after shutdown."""
+        import glob
+        import json
+
+        from ray_tpu.core.object_store.shm_store import session_dir
+
+        late = [e for e in tracing.get_buffer().drain(10 ** 6)[0]
+                if e["ts"] >= self._t_init]
+        for path in sorted(glob.glob(os.path.join(
+                session_dir(self.core.session), "task_wal", "*.jsonl"))):
+            late.extend(tracing.read_wal(path))
+        # a worker's last flush may have delivered what its file still holds
+        seen = {_event_key(e) for e in events}
+        events = events + [e for e in late if _event_key(e) not in seen]
+        self.session_timeline = tracing.build_chrome_trace(events)
+        try:
+            with open(os.path.join(self._procs.session_dir, "timeline.json"),
+                      "w") as f:
+                json.dump(self.session_timeline, f, default=str)
+        except OSError:
+            pass    # a full disk loses the file, not the shutdown

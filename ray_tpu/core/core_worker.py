@@ -2291,6 +2291,9 @@ class CoreWorker:
             is_actor_creation=True,
             actor_options={"max_concurrency": options.max_concurrency},
             runtime_env=self._pack_runtime_env(options),
+            trace_id=tracing.current_trace_id(),
+            parent_task_id=tracing.current_task_id(),
+            job_id=self.job_id or tracing.current_job_id(),
         )
         reply = self.io.run(
             self._gcs_call_retrying(

@@ -936,7 +936,16 @@ class LocalBackend(Backend):
         raise ValueError(f"unknown state method {method!r}")
 
     def shutdown(self):
+        from ray_tpu.core.config import _config
+
         self._ts_stop.set()
+        if _config.task_events_enabled:
+            # no session directory here: the record is kept for
+            # ray_tpu.timeline() after shutdown, and written nowhere
+            while len(self._events):
+                self._sync_events()
+            self.session_timeline = tracing.build_chrome_trace(
+                self._aggregator.timeline_events(limit=10 ** 9))
         chaos.set_local_actor_killer(None)
         for a in list(self._actors.values()):
             a.stop()

@@ -1694,6 +1694,15 @@ def main():
             if raylet.transfer:
                 raylet.transfer.stop()
             raylet.pool.shutdown()
+            # this raylet's last events (each worker's reap among them) by
+            # the file route of the workers' WALs: the GCS is going down
+            # beside us, and the driver's shutdown() reads the directory
+            from ray_tpu.core.object_store.shm_store import session_dir
+
+            tracing.events.write_wal(
+                os.path.join(session_dir(raylet.session), "task_wal",
+                             f"raylet-{raylet.node_id}.jsonl"),
+                tracing.get_buffer().drain(10 ** 6)[0])
             signal.signal(signal.SIGTERM, signal.SIG_DFL)
             os.kill(os.getpid(), signal.SIGTERM)
 

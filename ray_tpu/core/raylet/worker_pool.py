@@ -17,8 +17,12 @@ from typing import Dict, List, Optional, Set
 
 logger = logging.getLogger(__name__)
 
+from ray_tpu import tracing
+from ray_tpu.tracing import names
+
 STARTING, IDLE, LEASED, ACTOR, DEAD = "STARTING", "IDLE", "LEASED", "ACTOR", "DEAD"
-# a SIGKILLed process that held four v5e chips took 14 s to be gone (PR 21)
+# a SIGKILLed process that held four v5e chips took 14 s to be gone (PR 21);
+# what each process takes is the span `raylet/worker_reap` (PR 35)
 REAP_TIMEOUT_S = 30
 
 
@@ -33,6 +37,8 @@ class WorkerHandle:
     actor_id: Optional[bytes] = None
     lease_id: Optional[str] = None
     started_at: float = field(default_factory=time.monotonic)
+    platform: str = "cpu"            # worker_platform() of its lease
+    killed_at: Optional[float] = None  # time.monotonic() of our signal
 
 
 def worker_platform(demand: Optional[Dict[str, float]]) -> str:
@@ -84,7 +90,8 @@ class WorkerPool:
             stdout=log,
             stderr=subprocess.STDOUT,
         )
-        handle = WorkerHandle(startup_token=token, proc=proc)
+        handle = WorkerHandle(startup_token=token, proc=proc,
+                              platform=platform)
         if actor_id is not None:
             handle.state = STARTING
             handle.actor_id = actor_id
@@ -99,6 +106,13 @@ class WorkerPool:
         handle.worker_id = worker_id
         handle.address = address
         handle.conn = conn
+        # start_worker -> registered: interpreter start, the package's
+        # import, the connections to raylet and GCS
+        tracing.record_named(names.RAYLET_WORKER_START, {
+            "pid": handle.proc.pid, "startup_token": startup_token,
+            "platform": handle.platform,
+            "kind": "pooled" if handle.actor_id is None else "actor",
+        }, dur=time.monotonic() - handle.started_at)
         if handle.state == STARTING and handle.actor_id is None:
             handle.state = IDLE
         return handle
@@ -136,6 +150,7 @@ class WorkerPool:
                         await res
 
     def kill_worker(self, handle: WorkerHandle, force: bool = True):
+        handle.killed_at = time.monotonic()
         try:
             handle.proc.kill() if force else handle.proc.terminate()
         except ProcessLookupError:
@@ -165,17 +180,28 @@ class WorkerPool:
         the chips frees them only then — not when the signal was sent — and
         the next process to open them (a restarted trainer, whatever runs
         after the driver exits) fails or hangs if it comes sooner."""
+        if handle.proc.returncode is not None:
+            return    # collected before (poll_deaths, an earlier reap)
+        t0 = handle.killed_at or time.monotonic()
+        timed_out = False
         try:
             handle.proc.wait(timeout=REAP_TIMEOUT_S)
         except subprocess.TimeoutExpired:
+            timed_out = True
             logger.warning("worker pid=%d not gone %ds after SIGKILL",
                            handle.proc.pid, REAP_TIMEOUT_S)
+        seconds = time.monotonic() - t0
+        tracing.record_named(names.RAYLET_WORKER_REAP, {
+            "pid": handle.proc.pid, "platform": handle.platform,
+            "seconds": seconds, "timed_out": timed_out}, dur=seconds)
 
     def shutdown(self):
-        for w in self.workers.values():
-            try:
-                w.proc.kill()
-            except ProcessLookupError:
-                pass
-        for w in self.workers.values():
+        alive = [w for w in self.workers.values() if w.proc.poll() is None]
+        for w in alive:
+            if w.killed_at is None:
+                self.kill_worker(w)
+        # chip-less processes first: they are gone in milliseconds, and a
+        # reap's seconds run until the process is SEEN gone — behind a
+        # chip-holding one (seconds to die) they would read its time
+        for w in sorted(alive, key=lambda w: w.platform == "tpu"):
             self.reap(w)

@@ -717,7 +717,13 @@ class WorkerAgent(CoreWorker):
             self._exec_pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=n, thread_name_prefix="actor-exec"
             )
-            self.actor_instance = cls(*args, **kwargs)
+            # the constructor as a slice on this worker's row, under the
+            # creating call's trace (the owner sees no end of a creation:
+            # there is no FINISHED)
+            self._record_task_event(spec, "RUNNING")
+            with self._task_ctx(spec):
+                self.actor_instance = cls(*args, **kwargs)
+            self._record_task_event(spec, "EXECUTED")
             self._actor_ready.set()
             self.io.run(
                 self.gcs.call(

@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Unio
 
 import numpy as np
 
+from ray_tpu import tracing
 from ray_tpu.data import datasource as ds_mod
 from ray_tpu.data.block import (
     Block,
@@ -35,6 +36,7 @@ from ray_tpu.data.executor import (
     RechunkOp,
     StreamingExecutor,
 )
+from ray_tpu.tracing import names
 
 
 class Dataset:
@@ -272,34 +274,46 @@ class Dataset:
         the full data in driver RAM on every fit()."""
         import ray_tpu
 
-        refs = list(self.materialize().iter_block_refs())
-        count_rows = ray_tpu.remote(num_cpus=0.25)(block_num_rows)
-        counts = ray_tpu.get([count_rows.remote(r) for r in refs], timeout=300)
-        total = builtins.sum(counts)
-        per = total // n
-        slice_task = ray_tpu.remote(num_cpus=0.25)(block_slice)
-        shards: List[Dataset] = []
-        block_i, offset = 0, 0  # offset: rows of block_i already consumed
-        for i in builtins.range(n):  # `range` is shadowed by the read API
-            want = total - (n - 1) * per if i == n - 1 else per
-            shard_refs: List[Any] = []
-            while want > 0 and block_i < len(refs):
-                avail = counts[block_i] - offset
-                if avail <= want and offset == 0:
-                    shard_refs.append(refs[block_i])  # whole block, zero copy
-                    want -= avail
-                    block_i += 1
-                else:
-                    take = min(avail, want)
-                    shard_refs.append(
-                        slice_task.remote(refs[block_i], offset, offset + take)
-                    )
-                    want -= take
-                    offset += take
-                    if offset >= counts[block_i]:
-                        block_i += 1
-                        offset = 0
-            shards.append(Dataset([], materialized_refs=shard_refs))
+        with tracing.named_span(names.DATA_SPLIT, {"n": n}) as split:
+            with tracing.named_span(names.DATA_MATERIALIZE, {
+                    "stages": len(self._ops),
+                    "blocks_in": None if self._materialized is None
+                    else len(self._materialized)}) as span:
+                refs = list(self.materialize().iter_block_refs())
+                span.args["blocks_out"] = len(refs)
+            with tracing.named_span(names.DATA_COUNT_ROWS,
+                                    {"blocks": len(refs)}):
+                count_rows = ray_tpu.remote(num_cpus=0.25)(block_num_rows)
+                counts = ray_tpu.get(
+                    [count_rows.remote(r) for r in refs], timeout=300)
+            total = builtins.sum(counts)
+            split.args.update(blocks=len(refs), rows=total)
+            per = total // n
+            slice_task = ray_tpu.remote(num_cpus=0.25)(block_slice)
+            shards: List[Dataset] = []
+            block_i, offset = 0, 0  # offset: rows of block_i already consumed
+            with tracing.named_span(names.DATA_SLICE, {"tasks": 0}) as span:
+                for i in builtins.range(n):  # `range`: shadowed by the read API
+                    want = total - (n - 1) * per if i == n - 1 else per
+                    shard_refs: List[Any] = []
+                    while want > 0 and block_i < len(refs):
+                        avail = counts[block_i] - offset
+                        if avail <= want and offset == 0:
+                            # whole block, zero copy
+                            shard_refs.append(refs[block_i])
+                            want -= avail
+                            block_i += 1
+                        else:
+                            take = min(avail, want)
+                            shard_refs.append(slice_task.remote(
+                                refs[block_i], offset, offset + take))
+                            span.args["tasks"] += 1
+                            want -= take
+                            offset += take
+                            if offset >= counts[block_i]:
+                                block_i += 1
+                                offset = 0
+                    shards.append(Dataset([], materialized_refs=shard_refs))
         return shards
 
     def __repr__(self):

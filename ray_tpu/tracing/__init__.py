@@ -20,11 +20,33 @@ Model
 - ``profile_span("name")`` records user spans into the same plane, tagged
   with the current task/trace — and, when JAX is loaded, onto the
   ``jax.profiler`` clock as ``ray_tpu:<component>/<name>``. The Train path
-  (Data iterator, ``train.report``, ``TrainWorker.poll``), the garbage
-  collector of a train worker and the core worker's periodic loops record
-  their own spans through it; ``tracing/names.py`` is the vocabulary of
-  those spans and of the scopes and kernel names the model puts on the
-  device.
+  (Data iterator, ``train.report``), the garbage collector of a train worker
+  and the core worker's periodic loops record their own spans through it;
+  ``tracing/names.py`` is the vocabulary of those spans and of the scopes and
+  kernel names the model puts on the device.
+- Set-up and teardown are spans too, once an attempt, a split, a process or
+  a session and never a step (``names.SETUP_SPANS``). Each attempt of
+  ``DataParallelTrainer.fit()`` opens a trace of its own: ``train/fit`` and
+  its phases on the driver, the tasks submitted under them (the actor's
+  constructor, the Dataset's tasks, ``start_training``, every ``poll``),
+  ``train/loop_entered`` / ``train/loop_done`` on the worker's loop thread
+  and one ``train/compile`` a backend compile of the worker
+  (``tracing/compiles.py``) share its ``trace_id``. ``Dataset.split`` records
+  ``data/split`` and its three phases, the raylet ``raylet/worker_start`` and
+  ``raylet/worker_reap`` a worker process, the driver ``driver/init``,
+  ``driver/shutdown`` and one ``driver/wait_process`` a daemon.
+- The session's record outlives the session. ``shutdown()`` of the driver
+  that started the cluster fetches the aggregator's events before it stops
+  anything, appends what is recorded afterwards — its own shutdown spans,
+  and what the raylet and the workers left under the session's
+  ``task_wal/`` (the workers' WALs; the raylet writes its last events there
+  on SIGTERM) — and writes one Chrome trace,
+  ``/tmp/ray_tpu/<session>/timeline.json``. After ``shutdown()``,
+  ``ray_tpu.timeline()`` returns that record instead of starting a cluster
+  to ask it (the local backend keeps its last record, and writes no file).
+- Retention (``tracing/aggregator.py``) evicts what is most numerous, not
+  what is oldest: a job's set-up spans and the lifecycle of its set-up tasks
+  are still in the record after any number of later ``poll`` tasks.
 
 Cheap by default: recording is a couple of dict writes behind one lock;
 ``task_events_enabled=False`` reduces it to a single attribute check, and
@@ -46,9 +68,11 @@ from ray_tpu.tracing.events import (
     ensure_trace,
     get_buffer,
     install_gc_spans,
+    named_span,
     new_trace_id,
     profile_span,
     read_wal,
+    record_named,
     remaining_time_s,
     remove_gc_spans,
     task_context,
@@ -74,9 +98,11 @@ __all__ = [
     "ensure_trace",
     "get_buffer",
     "install_gc_spans",
+    "named_span",
     "new_trace_id",
     "profile_span",
     "read_wal",
+    "record_named",
     "remove_gc_spans",
     "task_context",
     "trace_context",

@@ -1,9 +1,34 @@
 """GCS-side task-event aggregation with bounded retention.
 
 Parity: src/ray/gcs/gcs_server/gcs_task_manager.h — per-task event storage
-with a global task cap (oldest-finished evicted first), per-task event caps,
-and drop counters surfaced as metrics. The same class backs local mode
-(the LocalBackend owns one and drains the process buffer into it on query).
+with a global task cap, per-task event caps, and drop counters surfaced as
+metrics. The same class backs local mode (the LocalBackend owns one and
+drains the process buffer into it on query).
+
+Retention rule
+--------------
+What is recorded once — a ``fit()``'s set-up spans, the lifecycle of its
+set-up tasks — is still here after any number of step-rate records of the
+same job (6,000 later ``TrainWorker.poll`` tasks evict polls, not the
+set-up). Every bound evicts from whatever is most numerous, never simply
+from the oldest:
+
+- **Task records, a job** (``task_events_max_tasks_per_job``): over the cap,
+  the job's most numerous task *name* loses its oldest record. A job's one
+  ``start_training`` and sixteen ``count_rows`` outlive its polls.
+- **Task records, all jobs** (``task_events_max_tasks``): oldest first — a
+  job's own cap is lower, so one job never gets here alone.
+- **PROFILE events of one task** (``max_events_per_task``): counted a span
+  name; beyond it that name's newest are dropped (``truncated_events``). A
+  loop's ``train/compile`` and ``train/loop_done`` are not crowded out by
+  its ``data/get_block``. Lifecycle events are never truncated.
+- **Spans with no task** (the driver's, the raylet's): those named in
+  ``tracing/names.SETUP_SPANS`` — once an attempt, a split, a process or a
+  session — have a queue of their own (``max_setup_events``); every other
+  span shares ``max_profile_events``, oldest first.
+
+``timeline_events(limit)`` returns the newest ``limit`` events; the session
+record written at ``shutdown()`` asks for all of them.
 """
 
 from __future__ import annotations
@@ -15,6 +40,7 @@ from typing import Any, Dict, List, Optional
 from ray_tpu.analysis import sanitizers as _san
 from ray_tpu.core.config import _config
 from ray_tpu.tracing import events as ev
+from ray_tpu.tracing import names
 
 
 def _terminal_state(states: List[str]) -> Optional[str]:
@@ -83,7 +109,8 @@ class TaskEventAggregator:
     def __init__(self, max_tasks: Optional[int] = None,
                  max_events_per_task: int = 256,
                  max_profile_events: int = 20_000,
-                 max_tasks_per_job: Optional[int] = None):
+                 max_tasks_per_job: Optional[int] = None,
+                 max_setup_events: int = 2_000):
         self._lock = _san.make_lock("tracing.aggregator")
         self._max_tasks = max_tasks or max(100, _config.task_events_max_tasks)
         self._max_tasks_per_job = max_tasks_per_job or max(
@@ -92,11 +119,16 @@ class TaskEventAggregator:
         self._max_events_per_task = max_events_per_task
         # task_id -> {"task_id", "name", "actor_id", "job_id", "events": []}
         self._tasks: "OrderedDict[str, dict]" = OrderedDict()
-        # per-job retention index: job_id -> OrderedDict[task_id, None] — a
-        # chatty job evicts its OWN oldest tasks before it can push another
-        # job's history out of the global window
-        self._job_tasks: Dict[str, "OrderedDict[str, None]"] = {}
-        # spans with no task id (serve request spans, ad-hoc profile spans)
+        # per-job retention index: job_id -> task name -> OrderedDict[task_id,
+        # None] — a chatty job evicts its OWN tasks before it can push
+        # another job's history out of the global window, and of its own the
+        # oldest of its most numerous name (module docstring)
+        self._job_tasks: Dict[str, Dict[str, "OrderedDict[str, None]"]] = {}
+        self._job_counts: Dict[str, int] = {}
+        # spans with no task id: what is recorded once an attempt or a
+        # session (names.SETUP_SPANS), and everything else (serve request
+        # spans, ad-hoc profile spans, a process's bg/gc/core spans)
+        self._setup: deque = deque(maxlen=max_setup_events)
         self._profile: deque = deque(maxlen=max_profile_events)
         # drop accounting, surfaced as metrics
         self._dropped_at_source: Dict[str, int] = {}  # source -> cumulative
@@ -122,7 +154,9 @@ class TaskEventAggregator:
             for e in events:
                 tid = e.get("task_id")
                 if tid is None:
-                    self._profile.append(e)
+                    once = f"{e.get('component')}/{e.get('name')}"
+                    (self._setup if once in names.SETUP_SPANS
+                     else self._profile).append(e)
                     continue
                 rec = self._tasks.get(tid)
                 if dedup and rec is not None:
@@ -136,31 +170,39 @@ class TaskEventAggregator:
                 if rec is None:
                     rec = self._tasks[tid] = {
                         "task_id": tid,
-                        "name": e.get("name") or "",
+                        # a span's name is its own, not the task's
+                        "name": "" if e.get("state") == ev.PROFILE
+                        else e.get("name") or "",
                         "actor_id": e.get("actor_id"),
                         "job_id": e.get("job_id"),
                         "events": [],
-                        "profile_count": 0,
+                        "profile_counts": {},
                     }
                     self._index_job_locked(tid, rec)
                     self._evict_locked()
                 else:
                     self._tasks.move_to_end(tid)
-                if not rec["name"] and e.get("name"):
-                    rec["name"] = e["name"]
                 if rec.get("actor_id") is None and e.get("actor_id"):
                     rec["actor_id"] = e["actor_id"]
-                if rec.get("job_id") is None and e.get("job_id"):
-                    rec["job_id"] = e["job_id"]
+                name = rec["name"] or (
+                    e.get("name") if e.get("state") != ev.PROFILE else "")
+                job = rec.get("job_id") or e.get("job_id")
+                if (name, job) != (rec["name"], rec.get("job_id")):
+                    # the task's name or job arrived after its first event:
+                    # index the record where it belongs
+                    self._unindex_job_locked(tid, rec)
+                    rec["name"], rec["job_id"] = name or "", job
                     self._index_job_locked(tid, rec)
-                # the cap truncates PROFILE spans only: lifecycle events are
-                # intrinsically bounded (a handful per attempt) and dropping
-                # a terminal one would leave a phantom RUNNING state
+                # the cap truncates PROFILE spans only, a span name: lifecycle
+                # events are intrinsically bounded (a handful per attempt)
+                # and dropping a terminal one would leave a phantom RUNNING
                 if e.get("state") == ev.PROFILE:
-                    if rec["profile_count"] >= self._max_events_per_task:
+                    counts = rec["profile_counts"]
+                    span = e.get("name") or ""
+                    if counts.get(span, 0) >= self._max_events_per_task:
                         self.truncated_events += 1
                         continue
-                    rec["profile_count"] += 1
+                    counts[span] = counts.get(span, 0) + 1
                 rec["events"].append(e)
                 # WAL replays never drive the duration histograms: the
                 # record-level dedup above can't see tasks already evicted
@@ -171,32 +213,38 @@ class TaskEventAggregator:
                     _observe_task_duration(rec, e)
 
     def _index_job_locked(self, tid: str, rec: dict) -> None:
-        """Record tid under its job and enforce the per-job cap (evicting
-        the job's own oldest tasks; jobless events ride only the global
-        cap)."""
+        """Record tid under its job and name and enforce the per-job cap:
+        the job's most numerous name loses its oldest task (jobless events
+        ride only the global cap)."""
         job = rec.get("job_id")
         if job is None:
             return
-        per = self._job_tasks.setdefault(job, OrderedDict())
-        per[tid] = None
-        while len(per) > self._max_tasks_per_job:
-            old_tid, _ = per.popitem(last=False)
-            if self._tasks.pop(old_tid, None) is not None:
-                self.evicted_tasks += 1
-                self.evicted_per_job[job] = (
-                    self.evicted_per_job.get(job, 0) + 1
-                )
+        by_name = self._job_tasks.setdefault(job, {})
+        by_name.setdefault(rec["name"], OrderedDict())[tid] = None
+        self._job_counts[job] = self._job_counts.get(job, 0) + 1
+        while self._job_counts[job] > self._max_tasks_per_job:
+            most = max(by_name.values(), key=len)
+            old_tid = next(iter(most))
+            self._unindex_job_locked(old_tid, self._tasks.pop(old_tid))
+            self.evicted_tasks += 1
+            self.evicted_per_job[job] = self.evicted_per_job.get(job, 0) + 1
+
+    def _unindex_job_locked(self, tid: str, rec: dict) -> None:
+        job = rec.get("job_id")
+        by_name = self._job_tasks.get(job)
+        if by_name is None or tid not in by_name.get(rec["name"], ()):
+            return
+        del by_name[rec["name"]][tid]
+        if not by_name[rec["name"]]:
+            del by_name[rec["name"]]
+        self._job_counts[job] -= 1
+        if not by_name:
+            del self._job_tasks[job], self._job_counts[job]
 
     def _evict_locked(self) -> None:
         while len(self._tasks) > self._max_tasks:
             tid, rec = self._tasks.popitem(last=False)
-            job = rec.get("job_id")
-            if job is not None:
-                per = self._job_tasks.get(job)
-                if per is not None:
-                    per.pop(tid, None)
-                    if not per:
-                        del self._job_tasks[job]
+            self._unindex_job_locked(tid, rec)
             self.evicted_tasks += 1
 
     # ------------------------------------------------- snapshot (durability)
@@ -212,6 +260,7 @@ class TaskEventAggregator:
                     for tid, rec in self._tasks.items()
                 ],
                 "profile": list(self._profile),
+                "setup": list(self._setup),
                 "dropped_at_source": dict(self._dropped_at_source),
                 "evicted_tasks": self.evicted_tasks,
                 "evicted_per_job": dict(self.evicted_per_job),
@@ -226,13 +275,15 @@ class TaskEventAggregator:
         with self._lock:
             self._tasks.clear()
             self._job_tasks.clear()
+            self._job_counts.clear()
             for tid, rec in state.get("tasks", []):
+                rec.setdefault("profile_counts", {})
                 self._tasks[tid] = rec
-                job = rec.get("job_id")
-                if job is not None:
-                    self._job_tasks.setdefault(job, OrderedDict())[tid] = None
+                self._index_job_locked(tid, rec)
             self._profile.clear()
             self._profile.extend(state.get("profile", ()))
+            self._setup.clear()
+            self._setup.extend(state.get("setup", ()))
             self._dropped_at_source = dict(
                 state.get("dropped_at_source", {})
             )
@@ -301,6 +352,7 @@ class TaskEventAggregator:
             out: List[dict] = []
             for rec in self._tasks.values():
                 out.extend(rec["events"])
+            out.extend(self._setup)
             out.extend(self._profile)
         out.sort(key=lambda e: e.get("ts", 0))
         return out[-limit:]

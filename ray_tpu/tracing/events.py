@@ -29,6 +29,12 @@ _WAL_ENCODE = json.JSONEncoder(
     separators=(",", ":"), check_circular=False, default=str
 ).encode
 
+def _wal_line(e: dict) -> str:
+    """One event as a WAL line. None fields are dropped: readers use .get(),
+    and smaller lines keep the per-event cost down on the worker hot path."""
+    return _WAL_ENCODE({k: v for k, v in e.items() if v is not None}) + "\n"
+
+
 # Typed lifecycle states, in causal order. Not every task visits every
 # state: LEASED fires only when the grant hits the raylet (cached-lease
 # reuse skips it), EXECUTED is the worker-side end of execution (same clock
@@ -228,11 +234,7 @@ class TaskEventBuffer:
         if self._wal_fd is None:
             return
         try:
-            # None fields are dropped: readers use .get(), and smaller
-            # lines keep the per-event cost down on the worker hot path
-            os.write(self._wal_fd, (_WAL_ENCODE(
-                {k: v for k, v in e.items() if v is not None}
-            ) + "\n").encode())
+            os.write(self._wal_fd, _wal_line(e).encode())
         except OSError:
             # a full/st-gone disk must never break the hot path; drop the
             # WAL, the in-memory plane keeps working
@@ -258,12 +260,7 @@ class TaskEventBuffer:
                     os.ftruncate(self._wal_fd, 0)
                     return
                 tmp = self._wal_path + ".tmp"
-                data = "".join(
-                    _WAL_ENCODE(
-                        {k: v for k, v in e.items() if v is not None}
-                    ) + "\n"
-                    for e in self._events
-                ).encode()
+                data = "".join(map(_wal_line, self._events)).encode()
                 fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
                              0o644)
                 try:
@@ -448,6 +445,21 @@ async def flush_task_events_loop(buf: TaskEventBuffer, get_conn,
                 buf.note_dropped(len(events))
 
 
+def write_wal(path: str, events: List[dict]) -> bool:
+    """Append ``events`` to ``path`` in the WAL's format (JSON lines): the
+    file route for a process whose last events no flush can carry — the
+    raylet after its SIGTERM, when the GCS is going down beside it."""
+    if not events:
+        return True
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "ab") as f:
+            f.write("".join(map(_wal_line, events)).encode())
+    except OSError:
+        return False
+    return True
+
+
 def read_wal(path: str, max_bytes: Optional[int] = None) -> List[dict]:
     """Parse a worker's WAL file (JSON lines). Tolerates the torn final
     line a SIGKILL mid-write leaves behind; returns [] for a missing or
@@ -547,6 +559,24 @@ class profile_span:
                 self.name, dur=dur, component=self.component, args=args,
             )
         return False
+
+
+def named_span(full_name: str, args: Optional[dict] = None) -> profile_span:
+    """A span under one of ``tracing/names.py``'s ``<component>/<name>``
+    constants, always recorded (set-up and teardown: once an attempt, a
+    split or a session, never a step)."""
+    component, _, name = full_name.partition("/")
+    return profile_span(name, args, component=component)
+
+
+def record_named(full_name: str, args: Optional[dict] = None,
+                 dur: Optional[float] = None) -> bool:
+    """An instant (or, with ``dur``, a span that has just ended) under one of
+    ``tracing/names.py``'s ``<component>/<name>`` constants, in the buffer
+    alone, tagged with the current task and trace."""
+    component, _, name = full_name.partition("/")
+    return get_buffer().record_profile(
+        name, dur=dur, component=component, args=args)
 
 
 def bg_span(loop: str, args: Optional[dict] = None) -> profile_span:

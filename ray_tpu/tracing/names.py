@@ -128,8 +128,98 @@ DATA_GET_BLOCK = "data/get_block"
 DATA_ASSEMBLE = "data/assemble"
 DATA_DEVICE_PUT = "data/device_put"
 TRAIN_REPORT = "train/report"
-TRAIN_POLL = "train/poll"
 GC = "gc"
 BG = "bg"                        # `bg/<loop>`: one span a tick of a periodic loop
-SPANS = (DATA_GET_BLOCK, DATA_ASSEMBLE, DATA_DEVICE_PUT, TRAIN_REPORT,
-         TRAIN_POLL, GC)
+SPANS = (DATA_GET_BLOCK, DATA_ASSEMBLE, DATA_DEVICE_PUT, TRAIN_REPORT, GC)
+
+# ---- set-up and teardown (PR 35): spans recorded once an attempt, a split,
+# a process or a session — never a step. All on `time.time()`, in the
+# task-event buffer -> `ray_tpu.timeline()` and `<session_dir>/timeline.json`;
+# each name with the tuple of its args, in order.
+# One trace per attempt of `DataParallelTrainer.fit()`: the driver's spans
+# below, the tasks submitted under them and what the worker records in its
+# loop share the attempt's `trace_id`. TRAIN_FIT holds the others.
+TRAIN_FIT = "train/fit"
+TRAIN_FIT_ARGS = ("name", "attempt", "num_workers", "tpus_per_worker")
+# the `WorkerGroup(...)` constructor, placement-group wait included
+TRAIN_WORKER_GROUP_START = "train/worker_group_start"
+TRAIN_WORKER_GROUP_START_ARGS = ("num_workers",)
+# `WorkerGroup.rendezvous`, only where it does something (> 1 worker); each
+# rank's `jax.distributed.initialize` inside it, on the worker
+TRAIN_RENDEZVOUS = "train/rendezvous"
+TRAIN_RENDEZVOUS_ARGS = ("num_workers",)
+TRAIN_JAX_DISTRIBUTED_INIT = "train/jax_distributed_init"
+TRAIN_JAX_DISTRIBUTED_INIT_ARGS = ("rank", "num_processes")
+TRAIN_SHARD_DATASETS = "train/shard_datasets"
+TRAIN_SHARD_DATASETS_ARGS = ("datasets",)
+# first `start_training` submitted -> every rank's call returned: what is left
+# of the worker's start once nothing else hides it
+TRAIN_START_TRAINING = "train/start_training"
+TRAIN_START_TRAINING_ARGS = ("num_workers",)
+TRAIN_DRIVE = "train/drive"
+TRAIN_DRIVE_ARGS = ("reports", "error")
+TRAIN_GROUP_SHUTDOWN = "train/group_shutdown"
+TRAIN_GROUP_SHUTDOWN_ARGS = ("num_workers",)
+# instants on the loop's own thread: immediately before the user's function
+# is called, and when it has returned or raised
+TRAIN_LOOP_ENTERED = "train/loop_entered"
+TRAIN_LOOP_ENTERED_ARGS = ("rank", "pid")
+TRAIN_LOOP_DONE = "train/loop_done"
+TRAIN_LOOP_DONE_ARGS = ("rank", "error")
+# every backend compile of a train worker's process, a load from the
+# persistent cache included (`jax.monitoring`'s backend_compile_duration):
+# `seconds` is JAX's own number, `cache` "hit" / "miss" where the cache's
+# events said so, else None. Also on the profiler's clock.
+TRAIN_COMPILE = "train/compile"
+TRAIN_COMPILE_ARGS = ("fun_name", "seconds", "cache")
+
+# `Dataset.split`: DATA_SPLIT holds the three others
+DATA_SPLIT = "data/split"
+DATA_SPLIT_ARGS = ("n", "blocks", "rows")
+DATA_MATERIALIZE = "data/materialize"
+DATA_MATERIALIZE_ARGS = ("stages", "blocks_in", "blocks_out")
+DATA_COUNT_ROWS = "data/count_rows"
+DATA_COUNT_ROWS_ARGS = ("blocks",)
+DATA_SLICE = "data/slice"
+DATA_SLICE_ARGS = ("tasks",)
+
+# a worker process, in the raylet: `WorkerPool.start_worker` -> that token's
+# `on_register` (interpreter start, importing the package, connecting), and
+# kill -> the process gone (`WorkerPool.reap`: a process that held chips
+# frees them only then). `kind` is "actor" or "pooled".
+RAYLET_WORKER_START = "raylet/worker_start"
+RAYLET_WORKER_START_ARGS = ("pid", "startup_token", "platform", "kind")
+RAYLET_WORKER_REAP = "raylet/worker_reap"
+RAYLET_WORKER_REAP_ARGS = ("pid", "platform", "seconds", "timed_out")
+
+# the driver's `init()` and `shutdown()` (ClusterBackend), and inside the
+# latter one span a daemon process waited on
+DRIVER_INIT = "driver/init"
+DRIVER_INIT_ARGS = ("session", "started_cluster")
+DRIVER_SHUTDOWN = "driver/shutdown"
+DRIVER_SHUTDOWN_ARGS = ("session",)
+DRIVER_WAIT_PROCESS = "driver/wait_process"
+DRIVER_WAIT_PROCESS_ARGS = ("name", "pid", "seconds", "killed")
+
+SETUP_SPANS = {
+    TRAIN_FIT: TRAIN_FIT_ARGS,
+    TRAIN_WORKER_GROUP_START: TRAIN_WORKER_GROUP_START_ARGS,
+    TRAIN_RENDEZVOUS: TRAIN_RENDEZVOUS_ARGS,
+    TRAIN_JAX_DISTRIBUTED_INIT: TRAIN_JAX_DISTRIBUTED_INIT_ARGS,
+    TRAIN_SHARD_DATASETS: TRAIN_SHARD_DATASETS_ARGS,
+    TRAIN_START_TRAINING: TRAIN_START_TRAINING_ARGS,
+    TRAIN_DRIVE: TRAIN_DRIVE_ARGS,
+    TRAIN_GROUP_SHUTDOWN: TRAIN_GROUP_SHUTDOWN_ARGS,
+    TRAIN_LOOP_ENTERED: TRAIN_LOOP_ENTERED_ARGS,
+    TRAIN_LOOP_DONE: TRAIN_LOOP_DONE_ARGS,
+    TRAIN_COMPILE: TRAIN_COMPILE_ARGS,
+    DATA_SPLIT: DATA_SPLIT_ARGS,
+    DATA_MATERIALIZE: DATA_MATERIALIZE_ARGS,
+    DATA_COUNT_ROWS: DATA_COUNT_ROWS_ARGS,
+    DATA_SLICE: DATA_SLICE_ARGS,
+    RAYLET_WORKER_START: RAYLET_WORKER_START_ARGS,
+    RAYLET_WORKER_REAP: RAYLET_WORKER_REAP_ARGS,
+    DRIVER_INIT: DRIVER_INIT_ARGS,
+    DRIVER_SHUTDOWN: DRIVER_SHUTDOWN_ARGS,
+    DRIVER_WAIT_PROCESS: DRIVER_WAIT_PROCESS_ARGS,
+}

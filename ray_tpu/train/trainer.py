@@ -14,6 +14,8 @@ import uuid
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu import tracing
+from ray_tpu.tracing import names
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.worker_group import WorkerGroup
@@ -98,17 +100,55 @@ class DataParallelTrainer(BaseTrainer):
         latest_ckpt = self.resume_from_checkpoint
         history: List[Dict[str, Any]] = []
 
+        attempt = 0
         while True:
+            # one trace an attempt: the actor creation, the Dataset's tasks,
+            # start_training and every poll carry its id, so a timeline
+            # filters to one attempt
+            with tracing.trace_context(tracing.new_trace_id()), \
+                    tracing.named_span(names.TRAIN_FIT, {
+                        "name": name, "attempt": attempt,
+                        "num_workers": cfg.num_workers,
+                        "tpus_per_worker":
+                            cfg.worker_resources().get("TPU", 0)}):
+                error, ckpt = self._run_attempt(name, latest_ckpt, history)
+            latest_ckpt = ckpt or latest_ckpt
+            if error is None or failures_left == 0:
+                return Result(
+                    metrics=history[-1] if history else None,
+                    checkpoint=latest_ckpt,
+                    error=error,
+                    metrics_dataframe=history,
+                )
+            failures_left -= 1
+            attempt += 1
+
+    def _run_attempt(self, name: str, latest_ckpt: Optional[Checkpoint],
+                     history: List[Dict[str, Any]]):
+        """Start a worker group, run the loop on it to its end and stop the
+        group: ``(error or None, the attempt's latest checkpoint)``. Each
+        phase is a span of ``tracing/names.py`` under the attempt's trace."""
+        import ray_tpu
+
+        cfg = self.scaling_config
+        workers = {"num_workers": cfg.num_workers}
+        with tracing.named_span(names.TRAIN_WORKER_GROUP_START, workers):
             group = WorkerGroup(
                 cfg.num_workers,
                 cfg.worker_resources(),
                 experiment_name=name,
                 placement_strategy=cfg.placement_strategy,
             )
+        try:
             try:
-                try:
-                    group.rendezvous()
+                if cfg.num_workers > 1:
+                    with tracing.named_span(names.TRAIN_RENDEZVOUS, workers):
+                        group.rendezvous()
+                with tracing.named_span(
+                        names.TRAIN_SHARD_DATASETS,
+                        {"datasets": len(self.datasets)}):
                     shards = self._shard_datasets(cfg.num_workers)
+                with tracing.named_span(names.TRAIN_START_TRAINING, workers):
                     refs = [
                         w.start_training.remote(
                             self.train_loop_per_worker,
@@ -119,34 +159,22 @@ class DataParallelTrainer(BaseTrainer):
                         for rank, w in enumerate(group.workers)
                     ]
                     ray_tpu.get(refs, timeout=120)
+                reported = len(history)
+                with tracing.named_span(names.TRAIN_DRIVE) as span:
                     error = self._drive(group, history)
-                except Exception as e:  # noqa: BLE001
-                    # Worker-process death (ActorDiedError, rpc loss) must flow
-                    # into the same FailureConfig retry loop as user-code errors
-                    # — elastic restart-from-checkpoint is the whole point
-                    # (reference: Tune trial FailureConfig handling).
-                    # KeyboardInterrupt/SystemExit are NOT retried: Ctrl-C must
-                    # stop training, not restart it (advisor finding r2).
-                    error = e
-                if error is None:
-                    metrics = history[-1] if history else None
-                    ckpt = self._latest_group_checkpoint(group) or latest_ckpt
-                    return Result(
-                        metrics=metrics,
-                        checkpoint=ckpt,
-                        error=None,
-                        metrics_dataframe=history,
-                    )
-                latest_ckpt = self._latest_group_checkpoint(group) or latest_ckpt
-                if failures_left == 0:
-                    return Result(
-                        metrics=history[-1] if history else None,
-                        checkpoint=latest_ckpt,
-                        error=error,
-                        metrics_dataframe=history,
-                    )
-                failures_left -= 1
-            finally:
+                    span.args = {"reports": len(history) - reported,
+                                 "error": repr(error) if error else None}
+            except Exception as e:  # noqa: BLE001
+                # Worker-process death (ActorDiedError, rpc loss) must flow
+                # into the same FailureConfig retry loop as user-code errors
+                # — elastic restart-from-checkpoint is the whole point
+                # (reference: Tune trial FailureConfig handling).
+                # KeyboardInterrupt/SystemExit are NOT retried: Ctrl-C must
+                # stop training, not restart it (advisor finding r2).
+                error = e
+            return error, self._latest_group_checkpoint(group)
+        finally:
+            with tracing.named_span(names.TRAIN_GROUP_SHUTDOWN, workers):
                 group.shutdown()
 
     def _shard_datasets(self, num_workers: int) -> Dict[str, List[Any]]:

@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
 from ray_tpu import tracing
+from ray_tpu.tracing import names
 from ray_tpu.train import session as session_mod
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.session import TrainContext, _Session, _set_session
@@ -69,11 +70,13 @@ class TrainWorker:
             except Exception:  # noqa: BLE001 - half-initialized state
                 pass
             self._distributed_ready = False
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
+        with tracing.named_span(names.TRAIN_JAX_DISTRIBUTED_INIT, {
+                "rank": process_id, "num_processes": num_processes}):
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=num_processes,
+                process_id=process_id,
+            )
         self._distributed_ready = True
         return True
 
@@ -88,9 +91,11 @@ class TrainWorker:
             experiment_name=self.experiment_name,
         )
         self.session = _Session(ctx, latest_checkpoint, dataset_shards)
+        from ray_tpu.tracing.compiles import record_compiles
         from ray_tpu.util.compile_cache import enable_compile_cache
 
         enable_compile_cache()
+        record_compiles()
 
         # the loop's own thread inherits this actor task's ids, so that the
         # spans of Data and Train under it attach to the task and its trace
@@ -99,13 +104,20 @@ class TrainWorker:
 
         def run():
             _set_session(self.session)
+            error: Optional[BaseException] = None
             try:
                 with tracing.task_context(*task_ids):
-                    fn(config) if config is not None else fn()
-                self.session.finish()
-            except BaseException as e:  # noqa: BLE001
-                traceback.print_exc()
-                self.session.finish(error=e)
+                    tracing.record_named(names.TRAIN_LOOP_ENTERED, {
+                        "rank": self.rank, "pid": os.getpid()})
+                    try:
+                        fn(config) if config is not None else fn()
+                    except BaseException as e:  # noqa: BLE001
+                        traceback.print_exc()
+                        error = e
+                    tracing.record_named(names.TRAIN_LOOP_DONE, {
+                        "rank": self.rank,
+                        "error": repr(error) if error else None})
+                self.session.finish(error=error)
             finally:
                 _set_session(None)
 
@@ -119,19 +131,15 @@ class TrainWorker:
         if self.session is None:
             return out
         deadline = time.monotonic() + timeout
-        # one span a long-poll, on the actor's thread (it shares the GIL
-        # with the loop's): between two of them the reply is serialized
-        with tracing.profile_span("poll", component="train") as span:
-            while True:
-                try:
-                    remaining = max(0.0, deadline - time.monotonic())
-                    item = self.session.result_queue.get(timeout=remaining)
-                    out.append(item)
-                    if item[0] == "done":
-                        break
-                except Exception:  # noqa: BLE001 - queue.Empty
+        while True:
+            try:
+                remaining = max(0.0, deadline - time.monotonic())
+                item = self.session.result_queue.get(timeout=remaining)
+                out.append(item)
+                if item[0] == "done":
                     break
-            span.args = {"items": len(out)}
+            except Exception:  # noqa: BLE001 - queue.Empty
+                break
         return out
 
     def get_error(self):

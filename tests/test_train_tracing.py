@@ -335,7 +335,10 @@ def test_gc_hook_records_a_collection(buffer, monkeypatch):
     assert tracing_events._gc_spans not in gc.callbacks
 
 
-def test_session_report_and_worker_poll_record_train_spans(buffer, monkeypatch):
+def test_session_report_records_a_span_and_worker_poll_none(buffer, monkeypatch):
+    """`train/report` is read by a metric; `train/poll` had no reader and is
+    gone (PR 35): a TrainWorker.poll is an actor task, and the timeline draws
+    it as a slice from its lifecycle events, with the same edges."""
     from ray_tpu.train import session as session_mod
     from ray_tpu.train.worker_group import TrainWorker
 
@@ -347,8 +350,18 @@ def test_session_report_and_worker_poll_record_train_spans(buffer, monkeypatch):
     items = worker.poll(timeout=0.5)
     assert [i[0] for i in items] == ["report", "done"]
     got = {e["name"]: e for e in _drain(buffer, "train")}
-    assert set(got) == {"report", "poll"}
-    assert got["poll"]["args"] == {"items": 2}
+    assert set(got) == {"report"}
+    assert not hasattr(names, "TRAIN_POLL") and "train/poll" not in names.SPANS
+    poll = dict(task_id="t-poll", name="poll", actor_id="a", node_id="n",
+                worker="w", attempt=0)
+    trace = tracing.build_chrome_trace([
+        dict(poll, state="SUBMITTED", ts=1.0),
+        dict(poll, state="RUNNING", ts=1.25),
+        dict(poll, state="EXECUTED", ts=2.0),
+        dict(poll, state="FINISHED", ts=2.01)])
+    (drawn,) = [e for e in trace if e["ph"] == "X"]
+    assert (drawn["name"], drawn["cat"]) == ("poll", "actor_task")
+    assert drawn["ts"] == 1.25e6 and drawn["dur"] == pytest.approx(0.75e6)
 
 
 # ------------------------------------------------- the iterator, on a trace
