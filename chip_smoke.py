@@ -580,7 +580,8 @@ def main() -> int:
               f"{e['max_per_expert']}, mean {e['mean_per_expert']:.1f} an "
               f"expert; {e['tokens_without_held_expert']} tokens with none), "
               f"{e['buffer_passes']} pass(es) over a buffer of "
-              f"{e['buffer_rows']} rows, dropped {e['pairs_dropped']}")
+              f"{e['buffer_rows']} rows ({e['buffer_fill']:.3f} full), "
+              f"dropped {e['pairs_dropped']}")
     print(f"chosen set as a mask against lax.top_k's list, "
           f"{ROUTER_SHAPE[0]}x{ROUTER_SHAPE[1]} scores with ties, top "
           f"{ROUTER_TOP_K}: {hybrid['chosen_rows_off']} rows differ")

@@ -566,9 +566,9 @@ def balance_router_bias(params, tokens, targets, cfg: NemotronHConfig):
     component, name = scopes.EXPERT_LOAD.split("/")
     events = []
     for layer, load in enumerate(loads):
+        # (numpy scalars off the host: a count an int, a mean or share a float)
         args = {"layer": layer, **{
-            k: (float if k == "mean_per_expert" else int)(load[k])
-            for k in scopes.EXPERT_LOAD_ARGS[1:]}}
+            k: load[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
         get_buffer().record_profile(name, component=component, args=args)
         events.append(args)
     return params, events

@@ -32,7 +32,9 @@ operand), are the rows of the two products, computed as grouped matmuls
 (``lax.ragged_dot``: the TPU compiler's own grouped kernel, whose work follows
 the real group sizes), around them a latent projection and beside them a
 shared expert. No pair is dropped — a batch that lands more pairs here than
-one row buffer holds takes further passes over it — and memory is linear in T.
+one row buffer holds takes further passes over it, and everything that costs
+a row (its gate included) is done by the pass that holds it (PR 36) — and
+memory is linear in T.
 """
 
 from __future__ import annotations
@@ -161,12 +163,25 @@ def moe_mlp(x: jax.Array, params: Dict[str, Any], *, top_k: int,
 
 # the row buffer ONE PASS of the held experts' pairs takes, as a multiple of
 # the pairs a batch sends them on average (T · top_k · held / n_experts). A
-# batch that lands more than that here — a router that has learnt to prefer
-# the experts whose gradient it sees — takes further passes over the same
-# buffer, as many as its pairs need and never more than the worst case
-# (every token's choices on held experts) would: no pair is ever dropped, the
-# cost follows the pairs that are there, and memory stays one buffer's.
-ROW_BUFFER_MULTIPLE = 4.0
+# COST QUANTUM, not a capacity: a batch that lands more than that here — a
+# router that has learnt to prefer the experts whose gradient it sees — takes
+# further passes over the same buffer, as many as its pairs need and never
+# more than the worst case (every token's choices on held experts) would. No
+# pair is ever dropped; every cost of a row (gather, masks, gate, scatter-add)
+# is paid for the rows of the passes that run, in steps of one buffer; memory
+# stays one buffer's. The number weighs the rows a pass carries empty against
+# a second pass's fixed cost: with a balanced selection bias a batch lands
+# within 0.3 % of the mean (11,231–11,298 of 11,264 pairs at set-up, every
+# layer and seed of the Nemotron cell), so 1.25 — a pass holds a batch up to 27 % over
+# the mean at that shape, tiles rounded — leaves ninety times that spread
+# and runs three empty rows in fourteen, where the 4.0 chosen before the
+# balance existed ran three in four. On the chip 12,800, 14,336 and 16,896
+# rows cost a layer the same to 0.7 ms, and in the Nemotron cell, whose
+# routers drift off set-up's balance within a run, 1.25 was the best or the
+# equal of 1.125 and 1.5 (PERF.md §6, PR 36). It is not a knob: a router out
+# of balance costs more passes (a second one ~10 ms a layer and step there),
+# never a wrong result.
+ROW_BUFFER_MULTIPLE = 1.25
 _ROW_TILE = 512              # the compiler's grouped kernel tiles rows by 512
 # the selection bias at initialisation: noise small beside the scores' spread
 # (a sigmoid of logits of std ~1.3), large enough to decide near-ties
@@ -272,17 +287,19 @@ def _chosen(biased: jax.Array, top_k: int) -> jax.Array:
 def route(u: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
           scaling: float, held: Held) -> Tuple[jax.Array, jax.Array]:
     """u [T, D] → (``here`` [T, held] bool: the token chose that held expert,
-    its gates [T, held] float32, 0 where not): sigmoid scores in float32, the
-    k largest of score + bias chosen (the bias chooses only), gates = scaling
-    · score / Σ over ALL the chosen. The sum is a masked row-sum and the held
-    experts' scores a static slice: nothing is gathered by chosen id."""
+    the held experts' gates [T, held] float32, which mean something only where
+    ``here``): sigmoid scores in float32, the k largest of score + bias chosen
+    (the bias chooses only), gates = scaling · score / Σ over ALL the chosen.
+    The sum is a masked row-sum and the held experts' scores a static slice:
+    nothing is gathered by chosen id. The gates are not masked by ``here``:
+    only a pair's row reads one, and ``HeldPairs.valid`` says which rows are
+    pairs."""
     scores = _scores(u, router_w)
     chosen = _chosen(
         lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
     denom = jnp.sum(jnp.where(chosen, scores, 0.0), axis=-1, keepdims=True)
     span = slice(held.first, held.first + held.count)
-    here = chosen[:, span]
-    return here, jnp.where(here, scaling * scores[:, span] / denom, 0.0)
+    return chosen[:, span], scaling * scores[:, span] / denom
 
 
 def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
@@ -308,14 +325,16 @@ def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
 class HeldPairs(NamedTuple):
     """The (token, held expert) pairs a batch chose, sorted by expert and
     within an expert by token, as ``passes`` buffers of ``rows``: row r of
-    pass i is pair ``token[i, r]`` with gate ``gate[i, r]`` while
+    pass i is the pair at place ``key[i, r]`` of the membership's transpose —
+    expert · T + token, so token ``key % T`` with gate ``gates[key]`` — while
     ``valid[i, r]``; ``group_sizes[i, e]`` of the pass's rows belong to held
     expert e. Every pair on a held expert is in some pass: passes · rows
-    covers the worst case. A row that holds no pair names token 0 and carries
-    whatever gate sits at its place, NOT zero: ``valid`` alone says which
-    rows count, and ``_one_pass`` masks by it."""
-    token: jax.Array          # [passes, rows] int32
-    gate: jax.Array           # [passes, rows] float32
+    covers the worst case. A row that holds no pair has key 0 — token 0 and
+    whatever gate sits there, NOT zero: ``valid`` alone says which rows
+    count, and ``_pass_rows`` masks by it. What a row costs (its token, its
+    gate, its latent) is looked up by the pass that runs it, not here."""
+    key: jax.Array            # [passes, rows] int32
+    gates: jax.Array          # [held · T] float32: route's gates, expert-major
     valid: jax.Array          # [passes, rows] bool
     group_sizes: jax.Array    # [passes, held] int32
     per_expert: jax.Array     # [held] int32: pairs on each held expert
@@ -339,13 +358,12 @@ def held_pairs(here: jax.Array, gates: jax.Array, rows: int,
     total = passes * rows
     key = jnp.pad(key, (0, max(0, total - none)))[:total]
     valid = jnp.arange(total) < jnp.sum(per_expert)
-    key = jnp.where(valid, key, 0)
     # a pass's share of each expert's run of rows
     lo = (jnp.arange(passes) * rows)[:, None]
     ends = jnp.clip(jnp.cumsum(per_expert)[None, :], lo, lo + rows) - lo
     return HeldPairs(
-        token=(key % T).reshape(passes, rows),
-        gate=gates.T.reshape(none)[key].reshape(passes, rows),
+        key=jnp.where(valid, key, 0).reshape(passes, rows),
+        gates=gates.T.reshape(none),
         valid=valid.reshape(passes, rows),
         group_sizes=jnp.diff(ends, axis=1, prepend=0).astype(jnp.int32),
         per_expert=per_expert)
@@ -355,63 +373,84 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def _one_pass(ell, w1, w2, token, gate, valid, group_sizes):
-    """One buffer of pairs through the held experts: gate · relu(ell·W1_e)² ·
-    W2_e a row, added to its token's row of a [T, latent] float32 sum."""
+def _pass_rows(x, w1, w2, gate, valid, group_sizes):
+    """One buffer of pairs through the held experts, row for row: x [rows,
+    latent] (each pair's token's latent), its gate [rows] → gate ·
+    relu(x·W1_e)² · W2_e [rows, latent] float32, 0 in a row without a pair."""
     with jax.named_scope(scopes.MOE_DISPATCH):
-        x = jnp.where(valid[:, None], ell[token], 0)          # [rows, latent]
+        x = jnp.where(valid[:, None], x, 0)
     # rows past the last group are whatever the kernel left there (NaN as
     # likely as not), in the products and in their cotangents: each is masked
     # before anything multiplies it
-    h = lax.ragged_dot(x, w1, group_sizes, preferred_element_type=ell.dtype)
+    h = lax.ragged_dot(x, w1, group_sizes, preferred_element_type=x.dtype)
     with jax.named_scope(scopes.MOE_DISPATCH):
         a = _relu2(jnp.where(valid[:, None], h, 0))
     # out of the kernel in the compute dtype: a float32 output would make
     # the backward's two grouped products take float32 operands
-    o = lax.ragged_dot(a, w2, group_sizes, preferred_element_type=ell.dtype)
+    o = lax.ragged_dot(a, w2, group_sizes, preferred_element_type=x.dtype)
     with jax.named_scope(scopes.MOE_DISPATCH):
-        o = (jnp.where(valid[:, None], o, 0).astype(jnp.float32)
-             * gate[:, None])
-        return jnp.zeros(ell.shape, jnp.float32).at[token].add(o)
+        return (jnp.where(valid[:, None], o, 0).astype(jnp.float32)
+                * gate[:, None])
+
+
+@jax.named_scope(scopes.MOE_DISPATCH)
+def _looked_up(ell, gates, key):
+    """What one pass looks up for its rows' ``key`` [rows]: each row's token,
+    its latent out of ell [T, latent] and its gate out of the flat ``gates``."""
+    token = key % ell.shape[0]
+    return token, ell[token], gates[key]
 
 
 @jax.custom_vjp
-def _run_passes(ell, w1, w2, gate, token, valid, group_sizes, n):
-    """The first ``n`` passes' sum: Σ_i _one_pass(…, token[i], gate[i], …).
-    ``n`` is a value of the step — the passes this batch's pairs fill — so
-    the loop is a ``while``; its backward is written out below (one pass's
-    vjp at a time into float32 sums), because AD through a loop of
-    conditional passes keeps every pass's operands at once (the 8 x 4,096-
-    token step then needs 18.7 GB of a v5e's 15.75)."""
+def _run_passes(ell, w1, w2, gates, key, valid, group_sizes, n):
+    """The first ``n`` passes' sum over ell [T, latent]: each pass looks up
+    its own rows' tokens, latents and gates (``key[i]`` into ell and the flat
+    ``gates``), runs them through _pass_rows and adds each row to its token's
+    row of ONE [T, latent] float32 sum. ``n`` is a value of the step — the
+    passes this batch's pairs fill — so the loop is a ``while``; its backward
+    is written out below (one pass's vjp at a time into float32 sums),
+    because AD through a loop of conditional passes keeps every pass's
+    operands at once (the 8 x 4,096-token step then needs 18.7 GB of a v5e's
+    15.75)."""
     def body(i, r):
-        return r + _one_pass(ell, w1, w2, token[i], gate[i], valid[i],
-                             group_sizes[i])
+        token, x, gate = _looked_up(ell, gates, key[i])
+        o = _pass_rows(x, w1, w2, gate, valid[i], group_sizes[i])
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            return r.at[token].add(o)
 
     return lax.fori_loop(0, n, body, jnp.zeros(ell.shape, jnp.float32))
 
 
-def _run_passes_fwd(ell, w1, w2, gate, token, valid, group_sizes, n):
-    return (_run_passes(ell, w1, w2, gate, token, valid, group_sizes, n),
-            (ell, w1, w2, gate, token, valid, group_sizes, n))
+def _run_passes_fwd(ell, w1, w2, gates, key, valid, group_sizes, n):
+    return (_run_passes(ell, w1, w2, gates, key, valid, group_sizes, n),
+            (ell, w1, w2, gates, key, valid, group_sizes, n))
 
 
 def _run_passes_bwd(res, d_r):
-    ell, w1, w2, gate, token, valid, group_sizes, n = res
+    """The transposes of a pass's three lookups written out: the sum's
+    cotangent gathered by token, the latents' scatter-added by token and the
+    gates' by key — a pass's ``rows`` scalars into the [held · T] sum, not
+    passes · rows of them kept for one scatter at the end."""
+    ell, w1, w2, gates, key, valid, group_sizes, n = res
 
     def body(i, sums):
+        d_ell, d_w1, d_w2, d_gates = sums
+        token, x, gate = _looked_up(ell, gates, key[i])
         _, vjp = jax.vjp(
-            lambda e, a, b, g: _one_pass(e, a, b, token[i], g, valid[i],
-                                         group_sizes[i]),
-            ell, w1, w2, gate[i])
-        d_ell, d_w1, d_w2, d_gate = vjp(d_r)
-        return (sums[0] + d_ell.astype(jnp.float32),
-                sums[1] + d_w1.astype(jnp.float32),
-                sums[2] + d_w2.astype(jnp.float32),
-                sums[3].at[i].set(d_gate))
+            lambda x, a, b, g: _pass_rows(x, a, b, g, valid[i],
+                                          group_sizes[i]), x, w1, w2, gate)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            d_o = d_r[token]
+        d_x, d_a, d_b, d_gate = vjp(d_o)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            d_ell = d_ell.at[token].add(d_x.astype(jnp.float32))
+            d_gates = d_gates.at[key[i]].add(d_gate)
+        return (d_ell, d_w1 + d_a.astype(jnp.float32),
+                d_w2 + d_b.astype(jnp.float32), d_gates)
 
     sums = lax.fori_loop(0, n, body, (
         jnp.zeros(ell.shape, jnp.float32), jnp.zeros(w1.shape, jnp.float32),
-        jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(gate)))
+        jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(gates)))
     return (sums[0].astype(ell.dtype), sums[1].astype(w1.dtype),
             sums[2].astype(w2.dtype), sums[3], None, None, None, None)
 
@@ -443,7 +482,7 @@ def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
     passes it fills."""
     with jax.named_scope(scopes.MOE_DISPATCH):
         _, pairs, filled = _dispatch(u, p, top_k, held, scaling)
-    return _run_passes(ell, p["w1"], p["w2"], pairs.gate, pairs.token,
+    return _run_passes(ell, p["w1"], p["w2"], pairs.gates, pairs.key,
                        pairs.valid, pairs.group_sizes, filled)
 
 
@@ -487,13 +526,16 @@ def held_load(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
     layer's normed input): the numbers of the ``model/expert_load`` event."""
     here, pairs, filled = _dispatch(u, p, top_k, held, scaling)
     landed = jnp.sum(pairs.per_expert)
+    rows = pairs.key.shape[1]
     return {
         "tokens": jnp.asarray(u.shape[0], jnp.int32),
         "pairs": landed,
         "max_per_expert": jnp.max(pairs.per_expert),
         "mean_per_expert": jnp.mean(pairs.per_expert.astype(jnp.float32)),
         "tokens_without_held_expert": jnp.sum(~jnp.any(here, axis=-1)),
-        "buffer_rows": jnp.asarray(pairs.token.shape[1], jnp.int32),
+        "buffer_rows": jnp.asarray(rows, jnp.int32),
         "buffer_passes": filled,
+        # how full the passes that run are: pairs ÷ (passes · rows)
+        "buffer_fill": landed / jnp.maximum(filled * rows, 1),
         "pairs_dropped": landed - jnp.sum(pairs.valid),
     }

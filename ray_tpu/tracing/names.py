@@ -114,12 +114,14 @@ LAYER_PATTERN_ARGS = ("pattern", "applications", "groups")
 # selection bias balanced on it (nemotron_h.balance_router_bias, from the
 # first batch at set-up): pairs landed here,
 # the largest and the mean over the held experts, tokens with no held expert,
-# the row buffer, the passes over it the pairs fill, and the pairs in no pass
-# (none: the passes cover the worst case)
+# the row buffer, the passes over it the pairs fill, how full those passes are
+# (pairs ÷ (passes · rows), PR 36), and the pairs in no pass (none: the passes
+# cover the worst case)
 EXPERT_LOAD = "model/expert_load"
 EXPERT_LOAD_ARGS = ("layer", "tokens", "pairs", "max_per_expert",
                     "mean_per_expert", "tokens_without_held_expert",
-                    "buffer_rows", "buffer_passes", "pairs_dropped")
+                    "buffer_rows", "buffer_passes", "buffer_fill",
+                    "pairs_dropped")
 
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer

@@ -245,6 +245,10 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     assert [e["layer"] for e in hybrid["expert_load"]] == [0, 1, 2, 3]
     assert all(e["pairs_dropped"] == 0 and e["tokens"] == 8 * hybrid_cfg.seq_len
                for e in hybrid["expert_load"])
+    # how full the passes that ran were rides along (PR 36)
+    assert all(e["buffer_fill"] == pytest.approx(
+        e["pairs"] / (e["buffer_passes"] * e["buffer_rows"]))
+        for e in hybrid["expert_load"])
     without = [rows[-1] | {"summary": summary | {"hybrid": hybrid | {
         "layer_pattern": [], "expert_load": []}}}]
     assert len(chip_smoke.check_training(rows[:-1] + without, cfg, steps)) == 2

@@ -254,13 +254,14 @@ def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(
     """Every one of the six grouped products (two forward, two a backward
     operand side) is the TPU compiler's own grouped kernel (`lax.ragged_dot`
     → a `ragged-dot` custom call whose work follows the real group sizes) and
-    none is expanded into one dense product an expert; the row buffer is 4×
-    the mean, not the worst case."""
+    none is expanded into one dense product an expert; the row buffer is
+    1.25× the mean in whole tiles (28 of 512 rows for 11,264 pairs), not the
+    worst case."""
     from ray_tpu.ops import moe
 
     hlo = routed_experts_hlo
     rows = moe.row_buffer(_MOE_T, _MOE_E, 22, _MOE_HELD)
-    assert rows == 45056 < _MOE_T * _MOE_HELD
+    assert rows == 14336 < _MOE_T * _MOE_HELD
     grouped = [line for line in hlo.splitlines()
                if re.match(r"\s*(ROOT )?%?ragged-dot-none\S* = ", line)]
     assert len(grouped) == 6, len(grouped)
@@ -268,6 +269,37 @@ def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(
     # no product of the whole buffer with one expert's matrix
     assert f"bf16[{rows},{_MOE_F}]" in hlo and not re.search(
         rf"= \S+\[{_MOE_HELD},{rows},", hlo)
+
+
+def _elements(hlo):
+    """Every instruction's (first) result: name → elements."""
+    return {name: math.prod(int(d) for d in dims.split(",") if d)
+            for name, dims in re.findall(
+                r"(%[\w.\-]+) = \(?\w+\[([\d,]*)\]", hlo)}
+
+
+def test_a_pass_looks_up_its_own_rows_and_no_more_on_the_v5e(
+        routed_experts_hlo):
+    """PR 36: what a row costs is looked up by the pass that runs it — its
+    token's latent, its gate, and in the backward their transposes — so every
+    `gather` and `scatter` of the program is indexed by one pass's rows
+    (14,336) and none by passes × rows or more (19 × 14,336 ≥ 262,144: the
+    parent gathered the gates of all the passes a layer, 270,336 scalars, and
+    scattered their cotangents back, for 11,264 pairs)."""
+    from ray_tpu.ops import moe
+
+    hlo = routed_experts_hlo
+    rows = moe.row_buffer(_MOE_T, _MOE_E, 22, _MOE_HELD)
+    passes = moe.buffer_passes(_MOE_T, _MOE_E, 22, _MOE_HELD)
+    assert passes * rows >= _MOE_T * _MOE_HELD
+    size = _elements(hlo)
+    # the indices are both ops' second operand, one index a looked-up slice
+    indexed = [size[m.group(1)] for m in re.finditer(
+        r" (?:gather|scatter)\(%[\w.\-]+, (%[\w.\-]+)", hlo)]
+    # (the gradient's program holds the backward's: three lookups, two
+    # scatter-adds and what the compiler makes of them)
+    assert len(indexed) >= 5, indexed
+    assert max(indexed) == rows, indexed
 
 
 def test_routing_gathers_no_score_and_sorts_the_membership_on_the_v5e(
@@ -280,10 +312,7 @@ def test_routing_gathers_no_score_and_sorts_the_membership_on_the_v5e(
     token: no index rides along)."""
     hlo = routed_experts_hlo
 
-    # every instruction's (first) result: name → elements
-    size = {name: math.prod(int(d) for d in dims.split(",") if d)
-            for name, dims in re.findall(
-                r"(%[\w.\-]+) = \(?\w+\[([\d,]*)\]", hlo)}
+    size = _elements(hlo)
     ops = [line.strip().removeprefix("ROOT ") for line in hlo.splitlines()
            if re.search(r" (gather|scatter|sort)\(", line)]
     # a gather reads its first operand, a scatter writes its result

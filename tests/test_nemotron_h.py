@@ -5,6 +5,8 @@ of layer alone, the chunked scan against the token-by-token recurrence, the
 share of a deployment tied to the uncut layer, no pair dropped, the MTP
 targets, the mesh refusals, the pattern machinery and the rule over kinds."""
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -191,10 +193,65 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
         jnp.max(jnp.abs(want)))
 
 
-def test_no_pair_is_dropped_when_the_router_sends_everything_to_one_expert():
+def _fill_passes(monkeypatch, load, passes):
+    """Make moe's row buffer one that the pairs of ``load`` (moe.held_load)
+    fill ``passes`` times with the last pass partly full, and say its rows."""
+    pairs = int(load["pairs"])
+    rows = -(-2 * pairs // (2 * passes - 1))     # the last pass about half
+    assert -(-pairs // rows) == passes and pairs % rows
+    monkeypatch.setattr(moe, "row_buffer", lambda *shape: rows)
+    return rows
+
+
+def _held_load(cfg, p, x):
+    """moe.held_load of the expert layer ``p`` on the stream x."""
+    u = llama._rmsnorm(x, p["norm"], cfg).reshape(-1, cfg.d_model)
+    return moe.held_load(u, p, top_k=cfg.top_k, held=cfg.held,
+                         scaling=cfg.routed_scaling)
+
+
+def _expert_layer_and_reference(cfg, stacks):
+    """(program, reference): x → Σ sin(the expert layer's output) with every
+    gradient — the input's and each tensor's of the layer, the router's
+    among them — both in float32."""
+    sizes = family.reference_sizes(cfg)
+
+    def program(x, p):
+        return jnp.sum(jnp.sin(nh._layer(x, p, cfg, "E")))
+
+    def plain(x, p):
+        return jnp.sum(jnp.sin(reference.layers(
+            x, "E", [{"E": jax.tree.map(lambda t: t[None], p)}], sizes)))
+
+    p = _layer_of(stacks[0], "E")
+    return (lambda x: jax.value_and_grad(program, (0, 1))(x, p),
+            lambda x: jax.value_and_grad(plain, (0, 1))(x, p))
+
+
+def _assert_gradients_close(got, want, rtol=2e-4):
+    """Value and every gradient leaf, each to ``rtol`` of the leaf's largest
+    entry; the selection bias takes no gradient on either side."""
+    np.testing.assert_allclose(got[0], want[0], rtol=rtol)
+    flat_got = jax.tree_util.tree_leaves_with_path(got[1])
+    flat_want = jax.tree.leaves(want[1])
+    assert len(flat_got) == len(flat_want)
+    for (path, a), b in zip(flat_got, flat_want):
+        assert bool(jnp.all(jnp.isfinite(a))), path
+        np.testing.assert_allclose(
+            a, b, rtol=rtol, atol=rtol * float(jnp.max(jnp.abs(b))),
+            err_msg=jax.tree_util.keystr(path))
+    assert float(jnp.max(jnp.abs(want[1][1]["router_w"]))) > 0
+    assert not np.any(np.asarray(got[1][1]["router_bias"]))
+
+
+@pytest.mark.parametrize("passes", [1, 2, 3])
+def test_no_pair_is_dropped_when_the_router_sends_everything_to_one_expert(
+        passes, monkeypatch):
     """Every token's first choice on ONE held expert: the layer still equals
     the reference (nothing has a capacity), and the load it reports is all
-    there."""
+    there — over one pass of the row buffer or over two or three with the
+    last partly filled, in the value and in every gradient (the router's,
+    which reaches it through the gates a pass looks up, included)."""
     cfg = nh.nemotron_h_tiny(dtype=jnp.float32, pattern="E", mtp_pattern="")
     params = nh.init(cfg, jax.random.PRNGKey(8))
     x = jax.random.normal(jax.random.PRNGKey(9), (2, cfg.seq_len, cfg.d_model))
@@ -203,33 +260,42 @@ def test_no_pair_is_dropped_when_the_router_sends_everything_to_one_expert():
     stacks = [{"E": {**params["blocks"][0]["E"],
                      "router_bias": jnp.asarray(bias)}}]
     p = _layer_of(stacks[0], "E")
+    tokens = 2 * cfg.seq_len
     with jax.default_matmul_precision("highest"):
+        rows = _fill_passes(monkeypatch, _held_load(cfg, p, x), passes)
         got = nh._layer(x, p, cfg, "E")
         want = reference.layers(x, "E", stacks, family.reference_sizes(cfg))
-        u = llama._rmsnorm(x, p["norm"], cfg).reshape(-1, cfg.d_model)
-        load = moe.held_load(u, p, top_k=cfg.top_k, held=cfg.held,
-                             scaling=cfg.routed_scaling)
+        after = _held_load(cfg, p, x)
+        program, plain = _expert_layer_and_reference(cfg, stacks)
+        _assert_gradients_close(program(x), plain(x))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
-    tokens = 2 * cfg.seq_len
-    assert int(load["max_per_expert"]) == tokens
-    assert int(load["pairs"]) >= tokens and int(load["pairs_dropped"]) == 0
-    assert int(load["tokens_without_held_expert"]) == 0
+    assert int(after["max_per_expert"]) == tokens
+    assert int(after["pairs"]) >= tokens and int(after["pairs_dropped"]) == 0
+    assert int(after["tokens_without_held_expert"]) == 0
+    assert (int(after["buffer_rows"]), int(after["buffer_passes"])) == (
+        rows, passes)
+    np.testing.assert_allclose(after["buffer_fill"],
+                               int(after["pairs"]) / (passes * rows))
+    assert 0.5 <= float(after["buffer_fill"]) < 1.0
 
 
 def test_row_buffer_is_the_worst_case_where_that_is_small_and_passes_take_the_rest():
-    # tiny: 4x the mean passes the worst case, so one pass takes any batch
+    # tiny: one tile of rows passes the worst case, so one pass takes any batch
     assert moe.row_buffer(128, 32, 4, 8) == 128 * 4
     assert moe.buffer_passes(128, 32, 4, 8) == 1
-    # the cell: 4x the mean of 11,264 pairs, far under the worst 262,144
-    assert moe.row_buffer(32768, 512, 22, 8) == 45056
-    assert moe.buffer_passes(32768, 512, 22, 8) == 6
+    # the cell: 1.25x the mean of 11,264 pairs in whole tiles of 512 rows (a
+    # pass holds a batch up to 27 % over the mean), far under the worst
+    # 262,144, which would take 19 passes
+    assert moe.row_buffer(32768, 512, 22, 8) == 14336 == 28 * 512
+    assert moe.buffer_passes(32768, 512, 22, 8) == 19
     # a router that sends every token to both held experts: 12,000 pairs over
-    # two passes of 6,144 rows, every one of them in the sum
-    assert (moe.row_buffer(6000, 16, 2, 2), moe.buffer_passes(6000, 16, 2, 2)
-            ) == (6144, 2)
+    # two passes of 7,680 rows (the second partly filled), every one of them
+    # in the sum
+    assert (moe.row_buffer(6000, 4, 2, 2), moe.buffer_passes(6000, 4, 2, 2)
+            ) == (7680, 2)
     k = jax.random.split(jax.random.PRNGKey(13), 3)
-    p = {"router_w": jnp.zeros((8, 16)).at[:, :2].set(1.0),
-         "router_bias": jnp.zeros((16,)),
+    p = {"router_w": jnp.zeros((8, 4)).at[:, :2].set(1.0),
+         "router_bias": jnp.zeros((4,)),
          "w1": jax.random.normal(k[0], (2, 4, 6)),
          "w2": jax.random.normal(k[1], (2, 6, 4))}
     ell = jax.random.normal(k[2], (6000, 4))
@@ -253,13 +319,16 @@ def test_row_buffer_is_the_worst_case_where_that_is_small_and_passes_take_the_re
     np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-3)
 
 
-def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(monkeypatch):
+@pytest.mark.parametrize("passes", [1, 2, 3])
+def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(
+        passes, monkeypatch):
     """The TPU's grouped kernel leaves the rows past the last group as it
     found them (the chip run that lacked a mask: NaN gates' gradients, then a
     NaN router, then every pair on the first experts and a DMA past the
     buffer). Here `lax.ragged_dot` is made to leave NaN there, in its output
     and in its operand's cotangent: the layer and its gradients are what they
-    were."""
+    were and what the float32 reference's are, the router's included — with
+    one pass partly filled, and with two and three of which the last is."""
     from jax import lax
 
     real = lax.ragged_dot
@@ -286,26 +355,82 @@ def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(monkeypatch
     params = nh.init(cfg, jax.random.PRNGKey(11))
     x = jax.random.normal(jax.random.PRNGKey(12), (2, cfg.seq_len, cfg.d_model))
     p = _layer_of(params["blocks"][0], "E")
-
-    def f(x, p):
-        return jnp.sum(jnp.sin(nh._layer(x, p, cfg, "E")))
-
-    want = jax.value_and_grad(f, argnums=(0, 1))(x, p)
+    _fill_passes(monkeypatch, _held_load(cfg, p, x), passes)
+    program, plain = _expert_layer_and_reference(cfg, params["blocks"])
+    want = program(x)
     monkeypatch.setattr(moe.lax, "ragged_dot", lambda l, r, s, **kw: dirty(l, r, s))
-    got = jax.value_and_grad(f, argnums=(0, 1))(x, p)
+    got = program(x)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    with jax.default_matmul_precision("highest"):
+        _assert_gradients_close(program(x), plain(x))
 
 
-def test_passes_share_an_experts_run_of_rows():
-    pairs = moe.held_pairs(jnp.ones((64, 2), bool), jnp.ones((64, 2)),
-                           rows=48, passes=3)
+@pytest.mark.parametrize("rows,sizes", [
+    (160, [[64, 64]]),                           # one pass, 128 of 160 rows
+    (80, [[64, 16], [0, 48]]),                   # two, the second 48 of 80
+    (48, [[48, 0], [16, 32], [0, 32]]),          # three, the third 32 of 48
+])
+def test_passes_share_an_experts_run_of_rows(rows, sizes):
+    """An expert's run of rows goes on in the next pass; each pass looks up
+    its own rows' tokens, latents and gates, and the passes' sum — with its
+    gradient by the latents, both weights AND the gates, whose cotangent
+    every pass adds its own rows' scalars to — is the dense float32 sum."""
+    T, passes = 64, len(sizes)
+    k = jax.random.split(jax.random.PRNGKey(36), 5)
+    gates = jax.random.uniform(k[0], (T, 2), minval=0.2)
+    pairs = moe.held_pairs(jnp.ones((T, 2), bool), gates, rows=rows,
+                           passes=passes + 1)     # and one that never runs
     assert pairs.per_expert.tolist() == [64, 64]
-    assert pairs.group_sizes.tolist() == [[48, 0], [16, 32], [0, 32]]
-    assert pairs.valid.sum(axis=1).tolist() == [48, 48, 32]
+    assert pairs.group_sizes.tolist() == sizes + [[0, 0]]
+    assert pairs.valid.sum(axis=1).tolist() == [sum(g) for g in sizes] + [0]
     # expert 0's tokens first, each once and in order; then expert 1's
-    assert pairs.token.reshape(-1)[:128].tolist() == 2 * list(range(64))
+    assert (pairs.key.reshape(-1)[:128] % T).tolist() == 2 * list(range(64))
+    np.testing.assert_array_equal(pairs.gates[pairs.key.reshape(-1)[:128]],
+                                  gates.T.reshape(-1))
+    ell = jax.random.normal(k[1], (T, 4))
+    w1, w2 = jax.random.normal(k[2], (2, 4, 6)), jax.random.normal(k[3], (2, 6, 4))
+
+    def routed(ell, w1, w2, gates):
+        pairs = moe.held_pairs(jnp.ones((T, 2), bool), gates, rows, passes + 1)
+        return moe._run_passes(ell, w1, w2, pairs.gates, pairs.key,
+                               pairs.valid, pairs.group_sizes, passes)
+
+    def dense(ell, w1, w2, gates):
+        return sum(gates[:, e, None]
+                   * jnp.square(jax.nn.relu(ell @ w1[e])) @ w2[e]
+                   for e in range(2))
+
+    def graded(f):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(jnp.sin(f(*a))), (0, 1, 2, 3))(ell, w1, w2, gates)
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(routed(ell, w1, w2, gates),
+                                   dense(ell, w1, w2, gates),
+                                   rtol=1e-5, atol=1e-5)
+        got, want = graded(routed), graded(dense)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        assert float(jnp.max(jnp.abs(b))) > 0
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(b))))
+
+
+class _Rows(NamedTuple):
+    """The passes' rows with each row's token and gate looked up — what
+    moe.HeldPairs held until PR 36, when the passes took the lookups over."""
+    token: jax.Array
+    gate: jax.Array
+    valid: jax.Array
+    group_sizes: jax.Array
+    per_expert: jax.Array
+
+
+def _looked_up(pairs: moe.HeldPairs, T: int) -> _Rows:
+    return _Rows(pairs.key % T, pairs.gates[pairs.key], pairs.valid,
+                 pairs.group_sizes, pairs.per_expert)
 
 
 def _chosen_list_pairs(scores, bias, top_k, scaling, held, rows, passes):
@@ -327,7 +452,7 @@ def _chosen_list_pairs(scores, bias, top_k, scaling, held, rows, passes):
     valid = jnp.arange(total) < jnp.sum(per_expert)
     lo = (jnp.arange(passes) * rows)[:, None]
     ends = jnp.clip(jnp.cumsum(per_expert)[None, :], lo, lo + rows) - lo
-    return idx, moe.HeldPairs(
+    return idx, _Rows(
         token=(order // top_k).astype(jnp.int32).reshape(passes, rows),
         gate=gates.reshape(T * top_k)[order].reshape(passes, rows),
         valid=valid.reshape(passes, rows),
@@ -380,7 +505,7 @@ def test_the_membership_dispatch_is_the_chosen_lists(case):
 
     def new(logits):         # the router's product with an identity is exact
         here, gates = moe.route(logits, jnp.eye(E), bias, top_k, scaling, held)
-        return here, moe.held_pairs(here, gates, rows, passes)
+        return here, _looked_up(moe.held_pairs(here, gates, rows, passes), T)
 
     def old(logits):
         return _chosen_list_pairs(jax.nn.sigmoid(logits), bias, top_k, scaling,

@@ -174,7 +174,9 @@ def test_nemotron_step_records_its_pattern_and_its_expert_load(buffer):
     for load in loads:
         assert tuple(load) == names.EXPERT_LOAD_ARGS
         assert load["pairs_dropped"] == 0 and load["tokens"] == 2 * cfg.seq_len
-        assert 0 < load["pairs"] <= load["buffer_rows"]
+        assert 0 < load["pairs"] <= load["buffer_rows"] * load["buffer_passes"]
+        assert load["buffer_fill"] == pytest.approx(
+            load["pairs"] / (load["buffer_rows"] * load["buffer_passes"]))
 
 
 def test_moe_scope_stands_where_mlp_stands():
