@@ -10,7 +10,9 @@ worker leased every chip → in that worker a mesh over ``jax.devices()``,
 tens of steps and ``train.report`` per step. Weights and tokens are random,
 from a seed. Then one step of a small EvaByte (``models/llama.py`` with the
 EVA mixer, ``remat=True``) through the same factory, for the ``ops/eva_tiling``
-and ``model/remat_policy`` decisions it is traced with, and one step of a small
+and ``model/remat_policy`` decisions it is traced with and the
+``model/head_loss`` event of its eight heads' chunked loss (each chunk's
+gradient made in the forward, PR 39), and one step of a small
 Nemotron-H hybrid (``models/nemotron_h.py``: Mamba-2, LatentMoE and attention
 layers and the MTP module) for its ``model/layer_pattern`` and
 ``model/expert_load`` events, and — what the expert layer's chosen-set mask
@@ -216,6 +218,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     eva = None
     if config.get("eva_model") is not None:
         from ray_tpu.models import llama
+        from ray_tpu.ops.cross_entropy import head_loss_decisions
         from ray_tpu.ops.eva_attention import eva_tiling_decisions
 
         eva_cfg = config["eva_model"]
@@ -235,7 +238,10 @@ def train_loop(config: Dict[str, Any]) -> None:
                "compiler_rematerialized": len(
                    compiler_rematerialized(compiled.as_text())),
                "attention": list(resolve_attention(eva_cfg.attention_impl, mesh)),
-               "tiling": eva_tiling_decisions()}
+               "tiling": eva_tiling_decisions(),
+               # its heads' loss goes in chunks (more heads than one); no
+               # step before it had a chunked head
+               "head_loss": head_loss_decisions()}
         del variant
     # One step of a hybrid (Mamba-2 / LatentMoE / attention / MTP) through
     # the same factory: the pattern it is traced with, and what its first
@@ -375,6 +381,10 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if eva["attention"][0] == "pallas" and kernels != {"fwd", "bwd"}:
             bad.append(f"the EVA step recorded tiling decisions for "
                        f"{sorted(kernels)}, not for fwd and bwd")
+        # its head goes in chunks: each makes its gradient beside its loss
+        if not any(d["grad_in_forward"] for d in eva["head_loss"]):
+            bad.append("the EVA step's chunked head recorded no "
+                       "model/head_loss event with its gradient in the forward")
     hybrid = summary.get("hybrid")
     if hybrid is not None:
         if not (math.isfinite(hybrid["loss"])
@@ -565,6 +575,12 @@ def main() -> int:
               f"hd={d['hd']} window={d['window']} chunk={d['chunk']} -> "
               f"block_q={d['block_q']} block_k={d['block_k']} vmem_estimate="
               f"{d['vmem_estimate'] / 2 ** 20:.2f} MiB")
+    for d in eva["head_loss"]:
+        print(f"head loss: {d['chunks']} chunk(s) of {d['batch']} x "
+              f"{d['rows']} positions x {d['columns']} columns "
+              f"({d['heads']} heads), gradient in the forward: "
+              f"{d['grad_in_forward']}, kept for the backward "
+              f"{d['residual_bytes'] / 2 ** 20:.1f} MiB")
     print(f"eva step ({eva_cfg.n_layer} layers of {eva_cfg.d_model}, "
           f"{summary['device_count']}x{eva['seq_len']} bytes, remat): "
           f"attention {eva['attention']}, loss {eva['loss']:.4f} "

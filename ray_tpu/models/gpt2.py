@@ -541,6 +541,11 @@ def model_working_set(s: BlockShard, n_layer: int) -> int:
     a = s.dtype_bytes
     stack = n_layer * tokens * s.d_model * a
     head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
+    if s.head_rows:
+        # a head in chunks makes its gradient in the forward and keeps it
+        # (ops/cross_entropy.chunked_head_xent): d x stands where the chunked
+        # x stood, the running float32 d lm_head is new
+        head += s.d_model * s.vocab * 4
     gathered = s.vocab * s.d_model * (a + 4)
     return stack + head + gathered
 

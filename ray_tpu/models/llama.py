@@ -426,10 +426,12 @@ def _mlp_rows(batch: int, seq: int, d_model: int, d_ff: int,
                        2 * batch * seq * d_model * itemsize)
 
 
-def _head_rows(batch: int, seq: int, columns: int) -> int:
-    """Rows of the sequence the head takes at a time: their float32 logits
-    stay under _HEAD_CHUNK_BYTES."""
-    return _rows_under(seq, batch * columns * 4, _HEAD_CHUNK_BYTES)
+def _head_rows(batch: int, seq: int, columns: int, heads: int) -> int:
+    """Rows of the sequence the head takes at a time where it goes in chunks
+    — their float32 logits stay under _HEAD_CHUNK_BYTES; more heads than one
+    always do — and 0 where one head takes the sequence whole (softmax_xent)."""
+    rows = _rows_under(seq, batch * columns * 4, _HEAD_CHUNK_BYTES)
+    return rows if heads > 1 or rows < seq else 0
 
 
 def block_shard(cfg: LlamaConfig, global_batch: int, seq: int,
@@ -448,7 +450,7 @@ def block_shard(cfg: LlamaConfig, global_batch: int, seq: int,
         dense_mlp=True, kv_heads=cfg.n_kv_head,
         mlp_hidden=(scopes.RES_MLP_GATE, scopes.RES_MLP_UP),
         window=cfg.window if cfg.mixer == "eva" else 0, chunk=cfg.chunk,
-        head_rows=_head_rows(global_batch, seq, columns),
+        head_rows=_head_rows(global_batch, seq, columns, cfg.n_pred_heads),
         mlp_rows=_mlp_rows(global_batch, seq, cfg.d_model, cfg.d_ff,
                            jnp.dtype(cfg.dtype).itemsize),
         cast_in_loop=True,
@@ -485,50 +487,27 @@ def head_targets(targets: jax.Array, n_heads: int) -> jax.Array:
     return jnp.stack([padded[:, p:p + S] for p in range(n_heads)], axis=-1)
 
 
-def _chunk_nll(x_c, targets_c, lm_head, n_heads: int):
-    """[B, c, D] hidden + [B, c, P] targets → (sum nll [P], count [P]); the
-    logits are float32 out of the matmul (``fp32_logits``)."""
-    logits = jnp.einsum("bsd,dv->bsv", x_c, lm_head,
-                        preferred_element_type=jnp.float32)
-    logp = jax.nn.log_softmax(
-        logits.reshape(logits.shape[:2] + (n_heads, -1)), axis=-1)
-    mask = targets_c >= 0
-    safe = jnp.where(mask, targets_c, 0)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    return jnp.sum(nll * mask, axis=(0, 1)), jnp.sum(mask, axis=(0, 1))
-
-
 @jax.named_scope(scopes.LM_HEAD_LOSS)
 def _lm_head_loss(x, targets, lm_head, cfg: LlamaConfig) -> jax.Array:
     """Untied head(s) + cross-entropy over final hidden states [B, S, D]: the
-    mean over the heads of each head's mean over its valid targets. Chunked
-    over the sequence as gpt2._lm_head_loss is (scan + rematerialised chunk
-    logits) wherever the whole [B, S, V] float32 logits would pass
-    _HEAD_CHUNK_BYTES: they and their gradient are never one tensor."""
+    mean over the heads of each head's mean over its valid targets. Where the
+    head goes in chunks (_head_rows) the logits and their gradient
+    are never one tensor, and a chunk's logits are multiplied out once a
+    step: the chunk that makes its loss makes its gradient
+    (ops/cross_entropy.chunked_head_xent)."""
+    from ray_tpu.ops import cross_entropy
+
     B, S = targets.shape
     P = cfg.n_pred_heads
     lm_head = lm_head.astype(cfg.dtype)
-    rows = _head_rows(B, S, lm_head.shape[1])
-    if P == 1 and rows == S:
-        from ray_tpu.ops.cross_entropy import softmax_xent
-
+    rows = _head_rows(B, S, lm_head.shape[1], P)
+    if not rows:
         # fused CE (ops/cross_entropy.py): no [B, S, V] float32 residual
-        nll = softmax_xent(jnp.einsum("bsd,dv->bsv", x, lm_head), targets)
+        nll = cross_entropy.softmax_xent(
+            jnp.einsum("bsd,dv->bsv", x, lm_head), targets)
         return jnp.sum(nll) / jnp.maximum(jnp.sum(targets >= 0), 1)
-    tp = head_targets(targets, P)
-    xc = x.reshape(B, S // rows, rows, -1).swapaxes(0, 1)         # [n, B, c, D]
-    tc = tp.reshape(B, S // rows, rows, P).swapaxes(0, 1)         # [n, B, c, P]
-    chunk_fn = jax.checkpoint(partial(_chunk_nll, lm_head=lm_head, n_heads=P))
-
-    def scan_body(carry, xs):
-        total, count = carry
-        s, c = chunk_fn(*xs)
-        return (total + s, count + c), None
-
-    (total, count), _ = lax.scan(
-        scan_body, (jnp.zeros((P,), jnp.float32), jnp.zeros((P,), jnp.int32)),
-        (xc, tc))
-    return jnp.mean(total / jnp.maximum(count, 1))
+    return cross_entropy.chunked_head_xent(x, head_targets(targets, P),
+                                           lm_head, rows)
 
 
 def loss_fn(params, tokens, targets, cfg: LlamaConfig) -> jax.Array:

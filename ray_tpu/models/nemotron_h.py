@@ -354,7 +354,8 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
         dtype_bytes=a,
         flash=resolve_attention(cfg.attention_impl, mesh)[0] == "pallas",
         dense_mlp=False, kv_heads=cfg.n_kv_head,
-        head_rows=llama._head_rows(global_batch, seq, cfg.vocab_size),
+        head_rows=llama._head_rows(global_batch, seq, cfg.vocab_size,
+                                   cfg.n_pred_heads),
         mlp_rows=_shared_rows(cfg, global_batch, seq), cast_in_loop=True,
     ), mesh)
     tokens = base.batch * base.seq

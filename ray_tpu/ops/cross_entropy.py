@@ -1,11 +1,13 @@
-"""Fused masked softmax cross-entropy (custom VJP).
+"""Masked softmax cross-entropy over an LM head's logits: two ops, each with a
+custom VJP, for the two ways a model takes its sequence through the head.
 
-Why this exists: the naive `log_softmax` + `take_along_axis` loss keeps the
-full-vocabulary f32 log-probability tensor as an autodiff residual. At
-GPT-2-124M bench shape ([24, 1024, 50304]) that is a 4.9 GB HBM write plus
-re-reads — the device profile showed ~17 ms/step (8%) in those loop fusions
-alone. This op's VJP saves only the bf16 logits (which the LM-head matmul
-already produced) plus a [B, S] logsumexp:
+**The whole sequence at once — ``softmax_xent``: the bf16 logits are kept.**
+The naive `log_softmax` + `take_along_axis` loss keeps the full-vocabulary
+f32 log-probability tensor as an autodiff residual. At GPT-2-124M bench shape
+([24, 1024, 50304]) that is a 4.9 GB HBM write plus re-reads — the device
+profile showed ~17 ms/step (8%) in those loop fusions alone. This op's VJP
+saves only the bf16 logits (which the LM-head matmul already produced) plus a
+[B, S] logsumexp:
 
 - forward: two streaming passes over the logits (row max, then exp-sum fused
   with the one-hot pick) — no full-size f32 tensor is ever written;
@@ -13,16 +15,34 @@ already produced) plus a [B, S] logsumexp:
   the saved logits, which XLA fuses straight into the two consuming backward
   matmuls (dx and d_wte) instead of materializing it.
 
-Numerics are identical to the reference formulation (f32 max-subtracted
-softmax; tests assert equality vs jax.nn.log_softmax). Ignore index: any
-target < 0 contributes 0 loss and 0 gradient.
+**The sequence in chunks — ``chunked_head_xent``: the gradient is made in the
+forward.** Where the float32 logits of the whole sequence are too large to be
+one tensor (llama._lm_head_loss: EvaByte's eight heads over 32,768 bytes,
+Nemotron's 16,384 columns over 8 x 4,096 tokens) the head and the loss are
+ONE op over chunks of the sequence. A cross-entropy's gradient with respect to
+its logits needs nothing but the logits and a weight known from the targets
+alone, so under differentiation the chunk that has its logits in hand also
+makes ``d x`` and its share of ``d lm_head`` — for a unit cotangent; the
+backward multiplies both by the scalar that arrives. The logits of a chunk
+are multiplied out once a step, and nothing of their size is kept or made
+again (a ``checkpoint`` a chunk made them twice: PERF.md §6, PR 39). Called
+without differentiation the op does no gradient work.
+
+Numerics of both are the reference formulation's (f32 max-subtracted softmax;
+tests assert equality vs jax.nn.log_softmax). Ignore index: any target < 0
+contributes 0 loss and 0 gradient.
 """
 
 from __future__ import annotations
 
+from functools import partial
+from typing import Any, Dict, List
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ray_tpu.tracing import names
 
 
 def _nll_and_lse(logits, targets):
@@ -67,3 +87,117 @@ def _xent_bwd(res, g):
 
 
 softmax_xent.defvjp(_xent_fwd, _xent_bwd)
+
+
+# --------------------------------------------------------------------------- #
+# The head and the loss in chunks of the sequence, gradient in the forward
+# --------------------------------------------------------------------------- #
+
+_decisions: Dict[tuple, Dict[str, Any]] = {}
+
+
+def head_loss_decisions() -> List[Dict[str, Any]]:
+    """Every distinct way this process has traced the chunked head, as the
+    ``model/head_loss`` events carry them."""
+    return list(_decisions.values())
+
+
+def _record(x, targets, lm_head, rows: int, grad_in_forward: bool) -> None:
+    """What a chunked head was traced as (``model/head_loss``): the chunks,
+    whether this trace makes the gradient beside the loss, and what it then
+    keeps for the backward — ``d x`` where the chunked ``x`` stood, and the
+    running ``d lm_head`` in float32."""
+    from ray_tpu.ops.attention import record_decision
+
+    B, S = x.shape[:2]
+    kept = (x.size * x.dtype.itemsize + lm_head.size * 4) if grad_in_forward else 0
+    record_decision(_decisions, names.HEAD_LOSS, dict(zip(
+        names.HEAD_LOSS_ARGS,
+        (B, rows, S // rows, lm_head.shape[1], targets.shape[-1],
+         grad_in_forward, kept))))
+
+
+def _chunks(a, rows: int):
+    """[B, S, ...] -> [S / rows, B, rows, ...]: what the scan walks."""
+    B, S = a.shape[:2]
+    return a.reshape((B, S // rows, rows) + a.shape[2:]).swapaxes(0, 1)
+
+
+def _chunk_logits(x_c, lm_head, heads: int):
+    """[B, c, D] hidden -> [B, c, heads, columns a head] logits, float32 out
+    of the matmul (``fp32_logits``)."""
+    logits = jnp.einsum("bsd,dv->bsv", x_c, lm_head,
+                        preferred_element_type=jnp.float32)
+    return logits.reshape(logits.shape[:2] + (heads, -1))
+
+
+def _mean_over_heads(total, count):
+    return jnp.mean(total / jnp.maximum(count, 1))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def chunked_head_xent(x: jax.Array, targets: jax.Array, lm_head: jax.Array,
+                      rows: int) -> jax.Array:
+    """Head(s) + cross-entropy over hidden states ``x`` [B, S, D], ``rows``
+    positions of the sequence at a time: ``lm_head`` [D, heads · columns] in
+    x's dtype, head p in columns p·columns … (p+1)·columns, ``targets``
+    [B, S, heads] int32 (< 0 = ignore) → the mean over the heads of each
+    head's mean negative log-likelihood over its valid targets, float32.
+    ``rows`` divides S; a chunk's logits [B, rows, heads · columns] are
+    float32 and the largest tensor there is."""
+    _record(x, targets, lm_head, rows, grad_in_forward=False)
+    heads = targets.shape[-1]
+
+    def chunk(total, xs):
+        x_c, t_c = xs
+        nll, _ = _nll_and_lse(_chunk_logits(x_c, lm_head, heads), t_c)
+        return total + jnp.sum(nll, axis=(0, 1)), None
+
+    total, _ = lax.scan(chunk, jnp.zeros((heads,), jnp.float32),
+                        (_chunks(x, rows), _chunks(targets, rows)))
+    return _mean_over_heads(total, jnp.sum(targets >= 0, axis=(0, 1)))
+
+
+def _chunked_fwd(x, targets, lm_head, rows):
+    """The loss, and for a unit cotangent ``d x`` (a chunk: stacked by the
+    scan, in x's dtype) and ``d lm_head`` (the scan's carry, float32): each
+    chunk forms (softmax − onehot) · weight from the logits it has and
+    multiplies it into both. The weight of a valid target of head p is
+    1 / (heads · count_p), from the targets alone. The two products take what
+    AD's transposes of the logits' einsum took: float32 ``d logits``, the
+    other operand in the compute dtype, float32 out."""
+    _record(x, targets, lm_head, rows, grad_in_forward=True)
+    heads = targets.shape[-1]
+    count = jnp.sum(targets >= 0, axis=(0, 1))
+    weight = 1.0 / (heads * jnp.maximum(count, 1).astype(jnp.float32))
+
+    def chunk(carry, xs):
+        total, d_head = carry
+        x_c, t_c = xs
+        logits = _chunk_logits(x_c, lm_head, heads)
+        nll, lse = _nll_and_lse(logits, t_c)
+        d_logits, _ = _xent_bwd((logits, lse, t_c),
+                                jnp.broadcast_to(weight, t_c.shape))
+        d_logits = d_logits.reshape(x_c.shape[:2] + (-1,))
+        d_x = lax.dot_general(d_logits, lm_head, (((2,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        d_head = d_head + lax.dot_general(
+            x_c, d_logits, (((0, 1), (0, 1)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return (total + jnp.sum(nll, axis=(0, 1)), d_head), d_x.astype(x.dtype)
+
+    (total, d_head), d_x = lax.scan(
+        chunk, (jnp.zeros((heads,), jnp.float32),
+                jnp.zeros(lm_head.shape, jnp.float32)),
+        (_chunks(x, rows), _chunks(targets, rows)))
+    return _mean_over_heads(total, count), (d_x, d_head)
+
+
+def _chunked_bwd(rows, res, g):
+    d_x, d_head = res                       # [S / rows, B, rows, D], [D, V]
+    n, B, c, D = d_x.shape
+    d_x = (g * d_x).astype(d_x.dtype).swapaxes(0, 1).reshape(B, n * c, D)
+    return d_x, None, (g * d_head).astype(d_x.dtype)
+
+
+chunked_head_xent.defvjp(_chunked_fwd, _chunked_bwd)

@@ -105,6 +105,15 @@ RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
 REMAT_POLICY = "model/remat_policy"
 REMAT_POLICY_ARGS = ("n_layer", "batch", "seq", "saved", "saved_bytes",
                      "budget_bytes", "bytes_limit", "mlp_rows", "head_rows")
+# a head that takes the sequence in chunks (ops/cross_entropy.
+# chunked_head_xent): the batch rows and the positions a chunk holds, the
+# chunks, the head's columns (all heads') and heads, whether this trace makes
+# each chunk's gradient beside its loss (under differentiation) or only the
+# loss, and the bytes it then keeps for the backward (d x, the float32
+# d lm_head); one instant event per distinct decision, at trace time
+HEAD_LOSS = "model/head_loss"
+HEAD_LOSS_ARGS = ("batch", "rows", "chunks", "columns", "heads",
+                  "grad_in_forward", "residual_bytes")
 # a model whose layers are of more than one kind (gpt2.run_pattern): the
 # pattern, how often each kind is applied and which runs of it are one scan;
 # one instant event per distinct pattern, at trace time

@@ -263,6 +263,14 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     assert summary["eva"]["attention"] == ["pallas", True]
     assert {d["kernel"] for d in summary["eva"]["tiling"]} == {"fwd", "bwd"}
     assert all(d["rows"] == eva_cfg.n_head for d in summary["eva"]["tiling"])
+    # its heads' loss went in chunks that made their gradient (PR 39); and
+    # the check fails without the event
+    (head,) = summary["eva"]["head_loss"]
+    assert head["grad_in_forward"] and head["heads"] == eva_cfg.n_pred_heads
+    assert head["rows"] * head["chunks"] == eva_cfg.seq_len
+    unsaid = [rows[-1] | {"summary": summary | {"eva": summary["eva"] | {
+        "head_loss": []}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + unsaid, cfg, steps)) == 1
     assert summary["platforms"] == ["cpu"] and summary["device_count"] == 8
     assert summary["mesh"] == {"fsdp": 8} and summary["global_batch"] == 8
     assert summary["attention"] == ["xla", True]   # today's CPU-mesh choice

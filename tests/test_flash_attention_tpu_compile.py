@@ -202,10 +202,12 @@ def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(
     from conftest import time_limit
 
     from ray_tpu.models import gpt2
+    from ray_tpu.ops import cross_entropy
     from ray_tpu.tracing import names
 
     # recorded once a process by its facts: this test reads its own
     monkeypatch.setattr(gpt2, "_decisions", {})
+    monkeypatch.setattr(cross_entropy, "_decisions", {})
     cell, config, family, mesh = _cell_on(topo, "evabyte-6.5b-l4.dataset")
     with time_limit(240, "the EvaByte cell's compile for a described v5e"):
         step, args = family.abstract_step(config, cell, mesh)
@@ -218,6 +220,82 @@ def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(
     assert d["bytes_limit"] == family.V5E_BYTES_LIMIT
     assert d["saved"] == [names.RES_K, names.RES_EVA_KT, names.RES_EVA_VT]
     assert (d["mlp_rows"], d["head_rows"]) == (4096, 4096)
+    # PR 39: the eight heads' loss in 8 chunks makes its gradient in the
+    # forward; what that keeps that is new (the float32 d lm_head, 42 MB) is
+    # in the rule's estimate and still leaves the three names room
+    (h,) = cross_entropy.head_loss_decisions()
+    assert h == dict(batch=1, rows=4096, chunks=8, columns=8 * 320, heads=8,
+                     grad_in_forward=True,
+                     residual_bytes=32768 * 4096 * 2 + 4096 * 2560 * 4)
+    assert d["saved_bytes"] <= d["budget_bytes"] == 1_347_631_092 - 4096 * 2560 * 4
+    assert not [line for line in hlo.splitlines()
+                if "lm_head_loss" in line and "rematted_computation" in line]
+
+
+@pytest.fixture(scope="module")
+def nemotron_step(topo):
+    """`nemotron-3-super-120b-l11.dataset`'s whole train step, as
+    `families/nemotron_h.abstract_step` composes it, compiled for one
+    described chip (~40 s alone; under its own limit): the compiled step, and
+    the decisions it was traced with (remat rule, chunked head)."""
+    from conftest import time_limit
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.ops import cross_entropy
+
+    # recorded once a process by their facts: read this trace's own
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(gpt2, "_decisions", {})
+        mp.setattr(cross_entropy, "_decisions", {})
+        cell, config, family, mesh = _cell_on(
+            topo, "nemotron-3-super-120b-l11.dataset")
+        with time_limit(280, "the Nemotron cell's compile for a described v5e"):
+            step, args = family.abstract_step(config, cell, mesh)
+            compiled = step.lower(*args).compile()
+        return (compiled, gpt2.remat_policy_decisions(),
+                cross_entropy.head_loss_decisions())
+
+
+def test_the_nemotron_cell_step_multiplies_a_chunks_logits_once(nemotron_step):
+    """PR 39: the head — called twice a step, trunk and MTP module, 32 chunks
+    of 8 x 128 tokens x 16,384 float32 columns each — makes a chunk's
+    gradient where it makes its loss. Each call is ONE loop, in the forward,
+    whose body holds three matmuls (logits, d x, d lm_head): the parent's
+    held one, and its backward loop three more, the logits' a second time
+    under the chunk's `checkpoint` (`rematted_computation`, 53.4 of the
+    step's 1,315 ms on the chip)."""
+    compiled, _, heads = nemotron_step
+    hlo = compiled.as_text()
+    scoped = [line for line in hlo.splitlines() if "lm_head_loss" in line]
+    assert scoped and not [l for l in scoped if "rematted_computation" in l]
+    dots = [re.search(r'op_name="([^"]*)"', l).group(1) for l in scoped
+            if re.search(r" (convolution|dot)\(", l)]
+    in_loop = [d for d in dots if "lm_head_loss/while/body" in d
+               or "lm_head_loss)/while/body" in d]
+    assert len(in_loop) == 6 == len(dots), dots
+    assert not [d for d in dots if "transpose(jvp" in d], dots
+    assert sum("jvp(mtp)" in d for d in dots) == 3, dots
+    # the d lm_head of a call is the loop's carry, in float32
+    assert len(re.findall(r"= f32\[4096,16384,1\]\S* convolution\(", hlo)) == 2
+    (h,) = heads
+    assert h == dict(batch=8, rows=128, chunks=32, columns=16384, heads=1,
+                     grad_in_forward=True,
+                     residual_bytes=32768 * 4096 * 2 + 4096 * 16384 * 4)
+
+
+def test_the_nemotron_cell_step_fits_with_nothing_cloned(nemotron_step):
+    """The same compiled step: the trunk's d x and float32 d lm_head wait
+    across the whole MTP module (512 MiB where the parent kept the chunked x,
+    256), and the step still leaves the chip room — 13.56 GiB of 15.75 here
+    (13.93 with the chunks under a `checkpoint`) — so XLA's own
+    rematerialization pass cloned nothing; the remat rule keeps nothing, as
+    before."""
+    from ray_tpu.models import gpt2
+
+    compiled, (d,), _ = nemotron_step
+    assert gpt2.compiler_rematerialized(compiled.as_text()) == []
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 14.6 * 2 ** 30
+    assert (d["saved"], d["head_rows"], d["n_layer"]) == ([], 128, 13)
 
 
 _MOE_T, _MOE_E, _MOE_HELD, _MOE_F = 32768, 512, 8, 2688

@@ -122,3 +122,108 @@ def test_llama_train_step_on_mesh(cpu_mesh8):
     assert losses[-1] < losses[0]
     wq = state["params"]["blocks"]["wq"]
     assert "tp" in str(wq.sharding.spec), wq.sharding
+
+
+# --------------------------------------------------------------------------- #
+# The head in chunks: a chunk's gradient is made where its loss is (PR 39)
+# --------------------------------------------------------------------------- #
+
+_B, _S, _D, _V, _ROWS = 2, 64, 32, 40, 16
+
+
+def _whole_sequence_loss(x, targets, lm_head):
+    """The plain formulation: all the logits at once, float32, log_softmax
+    and a pick; the mean over the heads of each head's mean over its valid
+    targets."""
+    P = targets.shape[-1]
+    logits = jnp.einsum("bsd,dv->bsv", x, lm_head, precision="highest")
+    logp = jax.nn.log_softmax(logits.reshape(logits.shape[:2] + (P, -1)), -1)
+    mask = targets >= 0
+    nll = -jnp.take_along_axis(
+        logp, jnp.where(mask, targets, 0)[..., None], axis=-1)[..., 0]
+    return jnp.mean(jnp.sum(nll * mask, (0, 1))
+                    / jnp.maximum(jnp.sum(mask, (0, 1)), 1))
+
+
+def _head_case(heads, ignore):
+    k = jax.random.split(jax.random.PRNGKey(heads), 3)
+    x = jax.random.normal(k[0], (_B, _S, _D), jnp.float32)
+    lm_head = 0.3 * jax.random.normal(k[1], (_D, heads * _V), jnp.float32)
+    targets = np.array(jax.random.randint(k[2], (_B, _S), 0, _V))
+    for rows, cols in ignore:
+        targets[rows, cols] = -1
+    return x, llama.head_targets(jnp.asarray(targets), heads), lm_head
+
+
+@pytest.mark.parametrize("heads,ignore,cotangent", [
+    (1, (), 1.0),
+    (8, (), 1.0),
+    # chunk 1 of 4 (positions 16–31) holds no target in any row
+    (1, ((slice(None), slice(16, 32)),), 1.0),
+    # row 0 ends at 40, inside chunk 2 (32–47); with eight heads the later
+    # heads' targets end earlier still
+    (8, ((0, slice(40, None)),), 1.0),
+    # the MTP module's loss arrives times its weight
+    (8, ((1, slice(50, None)),), 0.1),
+], ids=["one-head", "eight-heads", "a-chunk-all-ignored",
+        "a-row-ends-mid-chunk", "cotangent-0.1"])
+def test_chunked_head_makes_the_whole_sequences_loss_and_gradients(
+        heads, ignore, cotangent):
+    """ops/cross_entropy.chunked_head_xent — each chunk's gradient made beside
+    its loss, for a unit cotangent, the backward scaling both — against
+    jax.value_and_grad of the plain whole-sequence formulation, float32."""
+    from ray_tpu.ops.cross_entropy import chunked_head_xent
+
+    x, targets, lm_head = _head_case(heads, ignore)
+    loss, (d_x, d_head) = jax.value_and_grad(
+        lambda x, w: cotangent * chunked_head_xent(x, targets, w, _ROWS),
+        argnums=(0, 1))(x, lm_head)
+    want, (want_x, want_head) = jax.value_and_grad(
+        lambda x, w: cotangent * _whole_sequence_loss(x, targets, w),
+        argnums=(0, 1))(x, lm_head)
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    np.testing.assert_allclose(d_x, want_x, rtol=1e-4, atol=1e-8)
+    np.testing.assert_allclose(d_head, want_head, rtol=1e-4, atol=1e-8)
+    # the undifferentiated call is the same number
+    np.testing.assert_allclose(
+        cotangent * chunked_head_xent(x, targets, lm_head, _ROWS), loss,
+        rtol=1e-6)
+
+
+def test_chunked_head_multiplies_a_chunks_logits_once(monkeypatch):
+    """Through llama._lm_head_loss: called without differentiation the
+    chunked head's program holds ONE matmul a chunk (the primal does no
+    gradient work); differentiated, three — logits, d x, d lm_head — in the
+    one forward scan, no `checkpoint` and no second scan behind it. The
+    trace says which it was (`model/head_loss`)."""
+    from ray_tpu.ops import cross_entropy
+    from ray_tpu.tracing import names
+
+    monkeypatch.setattr(cross_entropy, "_decisions", {})
+    monkeypatch.setattr(llama, "_HEAD_CHUNK_BYTES", _B * _ROWS * _V * 4)
+    cfg = llama.llama_tiny(dtype=jnp.float32, vocab_size=_V)
+    x, targets, lm_head = _head_case(1, ())
+    assert llama._head_rows(_B, _S, _V, 1) == _ROWS
+
+    def head(x, w):
+        return llama._lm_head_loss(x, targets[..., 0], w, cfg)
+
+    primal = str(jax.make_jaxpr(head)(x, lm_head))
+    grad = str(jax.make_jaxpr(jax.grad(head, argnums=(0, 1)))(x, lm_head))
+    assert (primal.count("dot_general"), primal.count("scan[")) == (1, 1)
+    assert (grad.count("dot_general"), grad.count("scan[")) == (3, 1)
+    assert "checkpoint" not in grad and "remat" not in grad
+    by_grad = {d["grad_in_forward"]: d
+               for d in cross_entropy.head_loss_decisions()}
+    assert set(by_grad) == {False, True}
+    assert tuple(by_grad[True]) == names.HEAD_LOSS_ARGS
+    assert by_grad[False]["residual_bytes"] == 0
+    assert by_grad[True] == dict(
+        batch=_B, rows=_ROWS, chunks=_S // _ROWS, columns=_V, heads=1,
+        grad_in_forward=True,
+        residual_bytes=x.size * 4 + lm_head.size * 4)
+    # one head whose whole-sequence logits fit takes them whole
+    monkeypatch.setattr(llama, "_HEAD_CHUNK_BYTES", _B * _S * _V * 4)
+    assert llama._head_rows(_B, _S, _V, 1) == 0
+    assert "scan[" not in str(jax.make_jaxpr(      # (a new function: no
+        lambda x, w: head(x, w))(x, lm_head))      # trace of `head` is reused)
