@@ -462,7 +462,10 @@ class ClusterBackend(Backend):
             pass
 
     def cancel(self, ref, force, recursive):
-        pass  # cooperative cancellation lands with the task event channel
+        # a task its owner still holds for its arguments is cancelled there;
+        # cooperative cancellation of one a worker already runs lands with
+        # the task event channel
+        self.core.cancel_task(ref)
 
     def get_named_actor(self, name, namespace):
         return self.core.get_named_actor(name, namespace)

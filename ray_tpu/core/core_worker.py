@@ -37,6 +37,8 @@ from ray_tpu.core.refs import ObjectRef
 
 logger = logging.getLogger(__name__)
 
+_NOT_COMPUTED = object()  # TaskSpec._arg_hints before _arg_hints() ran
+
 
 class _MemoryStore:
     """In-process store for small/owned objects (store_provider/memory_store)."""
@@ -213,6 +215,9 @@ class CoreWorker:
         self._reported_borrows: set = set()           # borrower side
         self._reconstructing: Dict[bytes, asyncio.Event] = {}  # by task_id
         self._reconstruct_attempts: Dict[bytes, int] = {}      # by task_id
+        # tasks held until their by-reference arguments exist: task_id → a
+        # future that cancel_task resolves (see _wait_for_args)
+        self._arg_waits: Dict[TaskID, asyncio.Future] = {}
         # results granted to us as borrows, pinned by the outer return oid
         # until released (see _store_task_result / _maybe_free)
         self._granting_outers: Dict[bytes, set] = {}   # inner → outer keys
@@ -1292,6 +1297,10 @@ class CoreWorker:
         fails with the typed error and the consumer's next item raises."""
         attempts = 0
         while True:
+            cancelled = await self._wait_for_args(spec)
+            if cancelled is not None:
+                self._fail_stream(spec, cancelled)
+                return
             if self._shed_expired(spec):
                 self._fail_stream(spec, self._deadline_error(spec))
                 return
@@ -1324,6 +1333,10 @@ class CoreWorker:
     async def _submit_and_track(self, spec: ts.TaskSpec, refs: List[ObjectRef]):
         attempts = 0
         while True:
+            cancelled = await self._wait_for_args(spec)
+            if cancelled is not None:
+                self._store_task_error(refs, cancelled, spec=spec)
+                return
             if self._shed_expired(spec):
                 self._store_task_error(
                     refs, self._deadline_error(spec), spec=spec
@@ -1358,6 +1371,78 @@ class CoreWorker:
                     spec=spec,
                 )
                 return
+
+    # ------------------------------------------- dependency resolution (tasks)
+    # Parity: CoreWorkerDirectTaskSubmitter::SubmitTask runs the
+    # LocalDependencyResolver BEFORE RequestNewWorkerIfNeeded
+    # (transport/dependency_resolver.h) — a task asks for a worker when its
+    # arguments exist. Granted a lease earlier, its worker sat in the
+    # argument get(), reported itself blocked, and the raylet started a
+    # replacement process so that the producer could run at all: a stage of
+    # N consumers cost N workers waiting (Dataset.split, PERF.md §6 PR 40).
+
+    def _arg_unmade(self, ref: ObjectRef) -> bool:
+        """True for a by-reference argument THIS process owns whose
+        producing task has neither a value nor an error yet. Refs borrowed
+        from another owner pass: only their owner knows, and the worker's
+        argument get() waits on it as before."""
+        return (
+            self._is_owner(ref.owner_addr)
+            and ref.id.binary() in self._owned
+            and not self.memory_store.contains(ref.id)
+            and ref.id not in self.locations
+        )
+
+    async def _wait_for_args(self, spec: ts.TaskSpec) -> Optional[BaseException]:
+        """Hold ``spec`` here — no lease request, no worker, no resources —
+        until every argument it takes by reference from this owner has a
+        value or an error (a failed argument still fails the task where it
+        always did, in the worker's argument get()). Returns early when the
+        spec's deadline passes (the caller sheds it), or with the error to
+        fail it with when ``cancel_task`` reached it while it waited."""
+        unmade = [r.id for r in spec.dependencies() if self._arg_unmade(r)]
+        if not unmade:
+            return None
+        self._record_task_event(spec, "PENDING_ARGS_AVAIL")
+        vars(spec).pop("_arg_hints", None)  # a retry's arguments may have moved
+        cancel = asyncio.get_running_loop().create_future()
+        self._arg_waits[spec.task_id] = cancel
+        try:
+            for oid in unmade:
+                timeout = (None if spec.deadline is None
+                           else max(0.0, spec.deadline - time.time()))
+                arrived = asyncio.ensure_future(
+                    self.memory_store.wait_for(oid, None))
+                done, _ = await asyncio.wait(
+                    {arrived, cancel}, timeout=timeout,
+                    return_when=asyncio.FIRST_COMPLETED,
+                )
+                if arrived not in done:
+                    arrived.cancel()
+                    if cancel.done():
+                        return exc.TaskCancelledError(
+                            f"task {spec.name} cancelled while it waited "
+                            "for its arguments"
+                        )
+                    return None  # deadline: the caller's shed check fails it
+            return None
+        finally:
+            self._arg_waits.pop(spec.task_id, None)
+
+    def cancel_task(self, ref: ObjectRef) -> bool:
+        """Cancel the task that makes ``ref`` if its owner still holds it
+        for its arguments: it never asks for a worker and its refs raise
+        ``TaskCancelledError``. A task already handed to a worker runs on."""
+        if ref.task_id is None:
+            return False
+        return self.io.run(self._cancel_arg_wait(ref.task_id))
+
+    async def _cancel_arg_wait(self, task_id: TaskID) -> bool:
+        waiting = self._arg_waits.get(task_id)
+        if waiting is None or waiting.done():
+            return False
+        waiting.set_result(None)
+        return True
 
     async def _ensure_raylet(self):
         """Driver-side: if the adopted raylet died (remote cluster, node
@@ -1402,10 +1487,14 @@ class CoreWorker:
         """Owner-known locations of the spec's by-reference args, largest
         first: ``[(oid_hex, nbytes, node_id)]``. Rides the lease request so
         the raylet can prefer the node already holding the bytes and
-        prefetch the rest. Cached on the spec — retries re-send the same
-        hints, and the scheduling key reads them too."""
-        cached = getattr(spec, "_arg_hints", None)
-        if cached:
+        prefetch the rest. Computed when the first lease is asked for —
+        after ``_wait_for_args``, so every argument this process owns has
+        its location by then — and cached on the spec, ``None`` included:
+        the scheduling key reads them too, and retries re-send the same
+        hints unless an argument had to be remade (``_wait_for_args`` drops
+        the cache when it waits)."""
+        cached = getattr(spec, "_arg_hints", _NOT_COMPUTED)
+        if cached is not _NOT_COMPUTED:
             return cached
         hints = []
         for ref in spec.dependencies():
@@ -1414,14 +1503,8 @@ class CoreWorker:
                 hints.append((ref.id.hex(), int(loc["nbytes"]),
                               loc["node_id"]))
         hints.sort(key=lambda h: -h[1])
-        hints = hints[:8] or None
-        if hints:
-            # cache only NON-empty hints: a pipelined submission computes
-            # this before its producing task finished (no location yet) —
-            # a cached None would blind every retry to the by-then-known
-            # locations of its largest args
-            spec._arg_hints = hints
-        return hints
+        spec._arg_hints = hints[:8] or None
+        return spec._arg_hints
 
     def _sched_key(self, spec: ts.TaskSpec):
         # big-arg tasks get a locality domain in their key: cached-lease

@@ -158,6 +158,9 @@ class Raylet:
         self._disp: Dict[str, int] = {
             "grants": 0, "skipped_no_worker": 0,
             "skipped_no_resources": 0, "done": 0, "seen": 0,
+            # notices from leased workers that went into a get(): each gives
+            # its lease's resources back and can start a replacement process
+            "worker_blocked": 0,
         }
         # actor_id → (release token from _acquire_for-style accounting, demand)
         self._actor_resources: Dict[bytes, Tuple[object, ResourceSet]] = {}
@@ -472,6 +475,7 @@ class Raylet:
         demand, worker, token = entry
         self._release_token(token, demand)
         self._blocked_leases.add(w.lease_id)
+        self._disp["worker_blocked"] += 1
         return True
 
     def handle_worker_unblocked(self, conn, worker_id: str):

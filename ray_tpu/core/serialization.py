@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import io
 import pickle
+import sys
 import threading
 import types
 from typing import Any, Dict, List, Tuple
@@ -141,13 +142,19 @@ def _wire_serialized(payload, buffers) -> "SerializedObject":
     )
 
 
+def _jax_array_type():
+    """``jax.Array`` if this process has imported JAX, else ``None``: a value
+    can only hold a jax array where JAX is loaded, and importing it to ask
+    costs every pooled worker ~3 s before its first result leaves (PR 40:
+    each of ``Dataset.split``'s producers held its block that long)."""
+    jax = sys.modules.get("jax")
+    return getattr(jax, "Array", None)  # None too while jax is mid-import
+
+
 def _device_get_if_jax(value):
     """Move jax.Array leaves to host numpy (TPU HBM → host before shm write)."""
-    try:
-        import jax
-    except ImportError:  # pragma: no cover
-        return value
-    if isinstance(value, jax.Array):
+    array_type = _jax_array_type()
+    if array_type is not None and isinstance(value, array_type):
         import numpy as np
 
         return np.asarray(value)
@@ -211,16 +218,13 @@ class _FrameworkPickler(cloudpickle.CloudPickler):
         if isinstance(obj, ObjectRef):
             self._contained_refs.append(obj)
         # jax arrays nested inside containers
-        try:
-            import jax
+        array_type = _jax_array_type()
+        if array_type is not None and isinstance(obj, array_type):
             import numpy as np
 
-            if isinstance(obj, jax.Array):
-                arr = np.asarray(obj)
-                return (_restore_ndarray,
-                        (pickle.PickleBuffer(arr), arr.dtype.str, arr.shape))
-        except ImportError:  # pragma: no cover
-            pass
+            arr = np.asarray(obj)
+            return (_restore_ndarray,
+                    (pickle.PickleBuffer(arr), arr.dtype.str, arr.shape))
         # Functions/classes from user modules (test files, scripts) must
         # travel by VALUE — the worker can't import their module. Register
         # the module before delegating so cloudpickle's own reduce path

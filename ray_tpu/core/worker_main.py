@@ -30,6 +30,11 @@ from ray_tpu.core.ids import ObjectID
 
 logger = logging.getLogger(__name__)
 
+# how long fetching arguments that EXIST may take before the worker tells the
+# raylet it is blocked after all (WorkerAgent._get_args): a lost argument is
+# being remade from lineage by then, and its producer needs this lease's CPU
+_ARGS_EXIST_GET_S = 1.0
+
 
 class _StealableRunSlot:
     """The plain-task execution slot, with work stealing.
@@ -245,6 +250,22 @@ class WorkerAgent(CoreWorker):
         finally:
             self._notify_blocked(False)
 
+    def _get_args(self, spec: ts.TaskSpec, refs):
+        """The spec's by-reference arguments. Those its owner owns exist:
+        the owner held the task until they did (``_wait_for_args``), so
+        fetching them is a transfer, not a wait on another task, and the
+        lease keeps its resources — a notice to the raylet here made it
+        grant the freed CPU and start a process for every task of a stage.
+        A ref borrowed from another owner may still be being made, and an
+        argument that takes long is being pulled or remade from lineage:
+        both are a blocking get like one in the task's body."""
+        if all(r.owner_addr == spec.owner_addr for r in refs):
+            try:
+                return self.get(refs, _ARGS_EXIST_GET_S)
+            except TimeoutError:  # GetTimeoutError, or io.run's own on a pull
+                pass
+        return self.get_blocking(refs, None)
+
     def _task_ctx(self, spec: ts.TaskSpec):
         """Tracing context for the executing task: nested submissions made
         by the user function inherit this task as parent, ride the
@@ -301,7 +322,7 @@ class WorkerAgent(CoreWorker):
                     fn = self.io.run(self.load_function(spec.fn_id))
                 args, kwargs = ts.decode_args(
                     spec.args, spec.kwargs,
-                    lambda refs: self.get_blocking(refs, None),
+                    lambda refs: self._get_args(spec, refs),
                 )
                 attempts = 0
                 while True:
@@ -470,7 +491,7 @@ class WorkerAgent(CoreWorker):
                     fn = self.io.run(self.load_function(spec.fn_id))
                 args, kwargs = ts.decode_args(
                     spec.args, spec.kwargs,
-                    lambda refs: self.get_blocking(refs, None),
+                    lambda refs: self._get_args(spec, refs),
                 )
                 return self._stream_items(
                     spec, conn,

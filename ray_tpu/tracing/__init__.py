@@ -10,10 +10,13 @@ buffer, periodic GCS flush) + gcs_task_manager.h (bounded aggregation) +
 
 Model
 -----
-- Lifecycle states (``SUBMITTED → LEASED → DISPATCHED → RUNNING → EXECUTED
-  → FINISHED | FAILED``) are recorded at the layer that observes them: the
-  owner records submit/dispatch/terminal states, the raylet records the
-  lease grant, the executing worker records run/executed.
+- Lifecycle states (``SUBMITTED → [PENDING_ARGS_AVAIL →] LEASED → DISPATCHED
+  → RUNNING → EXECUTED → FINISHED | FAILED``) are recorded at the layer that
+  observes them: the owner records submit/dispatch/terminal states — and
+  ``PENDING_ARGS_AVAIL`` for a task it holds until the objects it takes by
+  reference exist, which is waiting for a producer and holds no worker —,
+  the raylet records the lease grant, the executing worker records
+  run/executed.
 - One ``trace_id`` is minted per logical request (e.g. a serve request) and
   propagated through ``TaskSpec`` into every nested submission, so a single
   request stitches across processes in the exported timeline.

@@ -20,7 +20,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.analysis import sanitizers as _san
 from ray_tpu.core.config import _config
-from ray_tpu.tracing.names import BG, GC, SPAN_PREFIX
+from ray_tpu.tracing.names import (BG, GC, SPAN_PREFIX,
+                                   TASK_PENDING_ARGS_AVAIL)
 
 # compact WAL line encoder: separators + no circular check shave ~40% off
 # json.dumps on the per-event hot path; default=str keeps arbitrary span
@@ -36,11 +37,15 @@ def _wal_line(e: dict) -> str:
 
 
 # Typed lifecycle states, in causal order. Not every task visits every
-# state: LEASED fires only when the grant hits the raylet (cached-lease
+# state: PENDING_ARGS_AVAIL (the reference's name) fires only when the owner
+# holds the task because an object it takes by reference is still being made
+# — SUBMITTED → PENDING_ARGS_AVAIL is waiting for a producer, not for a
+# worker —, LEASED fires only when the grant hits the raylet (cached-lease
 # reuse skips it), EXECUTED is the worker-side end of execution (same clock
 # as RUNNING, so spans are accurate), FINISHED/FAILED are the owner-side
 # terminal verdicts.
 SUBMITTED = "SUBMITTED"
+PENDING_ARGS_AVAIL = TASK_PENDING_ARGS_AVAIL
 LEASED = "LEASED"
 DISPATCHED = "DISPATCHED"
 RUNNING = "RUNNING"
@@ -50,7 +55,8 @@ FAILED = "FAILED"
 PROFILE = "PROFILE"  # user/framework span, not a lifecycle transition
 
 LIFECYCLE_STATES = (
-    SUBMITTED, LEASED, DISPATCHED, RUNNING, EXECUTED, FINISHED, FAILED,
+    SUBMITTED, PENDING_ARGS_AVAIL, LEASED, DISPATCHED, RUNNING, EXECUTED,
+    FINISHED, FAILED,
 )
 TERMINAL_STATES = (FINISHED, FAILED)
 

@@ -193,6 +193,12 @@ DATA_COUNT_ROWS = "data/count_rows"
 DATA_COUNT_ROWS_ARGS = ("blocks",)
 DATA_SLICE = "data/slice"
 DATA_SLICE_ARGS = ("tasks",)
+# The tasks these submit show in the record as slices RUNNING -> EXECUTED and
+# as `<task>:<STATE>` instants (`tracing/events.py`'s lifecycle states). A
+# task submitted beside its producer waits AT ITS OWNER, holding no worker
+# (PR 40): its instants read SUBMITTED, this state, then DISPATCHED once the
+# objects it takes by reference exist, and its slice is its own work
+TASK_PENDING_ARGS_AVAIL = "PENDING_ARGS_AVAIL"
 
 # a worker process, in the raylet: `WorkerPool.start_worker` -> that token's
 # `on_register` (interpreter start, importing the package, connecting), and

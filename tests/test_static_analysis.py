@@ -633,7 +633,10 @@ def test_loop_watchdog_catches_blocked_loop():
             elt = EventLoopThread(name="watchdog-test-io")
 
             async def block():
-                time.sleep(1.2)  # raylint: disable=RT001(fixture: deliberately blocks the loop to trip the watchdog)
+                # longer than a ping interval the process's one watchdog may
+                # still be sleeping out (the default, 1 s) + the stall limit:
+                # at 1.2 s a run's load decided whether it saw the block
+                time.sleep(2.0)  # raylint: disable=RT001(fixture: deliberately blocks the loop to trip the watchdog)
 
             elt.spawn(block())
             deadline = time.monotonic() + 5
@@ -642,7 +645,7 @@ def test_loop_watchdog_catches_blocked_loop():
                     break
                 time.sleep(0.05)
             assert san.violation_counts().get("loop_stall", 0) > base, \
-                "watchdog missed a 1.2s loop block"
+                "watchdog missed a 2 s loop block"
             v = san.violations("loop_stall")[-1]
             assert "heartbeat" in v["detail"]
     finally:
