@@ -15,7 +15,8 @@ and ``model/remat_policy`` decisions it is traced with and the
 gradient made in the forward, PR 39), and one step of a small
 Nemotron-H hybrid (``models/nemotron_h.py``: Mamba-2, LatentMoE and attention
 layers and the MTP module) for its ``model/layer_pattern`` and
-``model/expert_load`` events, and — what the expert layer's chosen-set mask
+``model/expert_load`` events and the ``ops/ssd_tiling`` decisions of its
+state-space scan's kernel pair (PR 41), and — what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
 check_training/check_device) and, after ``shutdown()``, prints from the
@@ -251,6 +252,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     if config.get("hybrid_model") is not None:
         from ray_tpu.models import nemotron_h
         from ray_tpu.models.gpt2 import layer_pattern_decisions
+        from ray_tpu.ops.mamba2 import ssd_tiling_decisions
 
         hybrid_cfg = config["hybrid_model"]
         variant = make_train_step(
@@ -271,6 +273,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         hybrid = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
                   "seq_len": hybrid_cfg.seq_len,
                   "layer_pattern": layer_pattern_decisions(),
+                  "ssd_tiling": ssd_tiling_decisions(),
                   "expert_load": load,
                   "chosen_rows_off": chosen_rows_off(config["seed"])}
         del variant
@@ -397,6 +400,10 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
             bad.append("the hybrid step recorded no model/layer_pattern event")
         if not hybrid["expert_load"]:
             bad.append("the hybrid step recorded no model/expert_load event")
+        kernels = {d["kernel"] for d in hybrid["ssd_tiling"]}
+        if kernels != {"fwd", "bwd"}:
+            bad.append("the hybrid step recorded ops/ssd_tiling decisions for "
+                       f"{sorted(kernels)}, not for both scan kernels")
         dropped = sum(e["pairs_dropped"] for e in hybrid["expert_load"])
         if dropped:
             bad.append(f"the hybrid step's expert layers dropped {dropped} "
@@ -590,6 +597,11 @@ def main() -> int:
     for d in hybrid["layer_pattern"]:
         print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
               f"{d['groups']}")
+    for d in hybrid["ssd_tiling"]:
+        print(f"ssd tiling: {d['kernel']} rows={d['rows']} S={d['S']} "
+              f"Q={d['Q']} heads a group={d['group_heads']} P={d['P']} "
+              f"N={d['N']} -> {d['head_tile']} heads a grid step, "
+              f"vmem_estimate={d['vmem_estimate']}")
     for e in hybrid["expert_load"]:
         print(f"expert load: layer {e['layer']}: {e['pairs']} pairs of "
               f"{e['tokens']} tokens on the held experts (max "

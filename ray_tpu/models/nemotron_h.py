@@ -379,7 +379,10 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
           2 * tokens * H * P * N),
         C((scopes.RES_SSD_Y,), tokens * inner * a, scan_flops),
     ), a * tokens * (4 * D + 4 * inner + 2 * conv_dim)
-        + chunks * H * (3 * Q * Q * 4 + 3 * P * N * 4)
+        # the scan's backward kernel reads the chunk states and y's float32
+        # gradient and the recompute wrote y in float32 beside them; a chunk's
+        # [Q, Q] tiles are the kernels', in VMEM (ops/mamba2.py, PR 41)
+        + chunks * H * P * N * 4 + 2 * tokens * inner * 4
         + 2 * a * D * (2 * inner + conv_dim))
 
     # E: the latent input; the shared expert's hidden where it is not chunked

@@ -61,8 +61,14 @@ EVA_AGG_BWD_KERNEL = "eva_agg_bwd"
 # are named `ragged-dot-…` and carry NO op_name — the scopes they were written
 # under are gone, so a trace's reader knows them by this name alone
 RAGGED_DOT_KERNEL = "ragged-dot"
+# the state-space scan (ops/mamba2.ssd_scan): a chunk's quadratic form, its
+# state update and the carried state's part of y, a (row, head tile) at a time
+# along the row's chunks — and the same tiles' gradients, the chunks reversed
+SSD_CHUNK_FWD_KERNEL = "ssd_chunk_fwd"
+SSD_CHUNK_BWD_KERNEL = "ssd_chunk_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
-           EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL)
+           EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
+           SSD_CHUNK_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
@@ -71,6 +77,12 @@ FLASH_TILING_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
 # the same for an EVA kernel (ops/eva_attention.py): Sq = Skv = the sequence
 EVA_TILING = "ops/eva_tiling"
 EVA_TILING_ARGS = FLASH_TILING_ARGS + ("window", "chunk")
+# the same for a scan kernel (ops/mamba2.py): the batch rows, the (padded)
+# sequence, the chunk, the heads that share a group's B and C, head width and
+# state, and the heads one grid step takes
+SSD_TILING = "ops/ssd_tiling"
+SSD_TILING_ARGS = ("kernel", "rows", "S", "Q", "group_heads", "P", "N",
+                   "head_tile", "vmem_estimate")
 
 # the block's residuals a `remat=True` checkpoint may keep, one name a tensor
 # (`jax.ad_checkpoint.checkpoint_name`; an identity outside such a checkpoint):

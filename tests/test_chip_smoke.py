@@ -249,9 +249,15 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     assert all(e["buffer_fill"] == pytest.approx(
         e["pairs"] / (e["buffer_passes"] * e["buffer_rows"]))
         for e in hybrid["expert_load"])
+    # its scan's kernel pair (PR 41), interpreted a row a device under the
+    # fsdp=8 shard_map, left its tiling decisions
+    assert {d["kernel"] for d in hybrid["ssd_tiling"]} == {"fwd", "bwd"}
+    assert all((d["rows"], d["S"], d["Q"]) == (1, hybrid_cfg.seq_len,
+                                               hybrid_cfg.chunk)
+               for d in hybrid["ssd_tiling"])
     without = [rows[-1] | {"summary": summary | {"hybrid": hybrid | {
-        "layer_pattern": [], "expert_load": []}}}]
-    assert len(chip_smoke.check_training(rows[:-1] + without, cfg, steps)) == 2
+        "layer_pattern": [], "expert_load": [], "ssd_tiling": []}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + without, cfg, steps)) == 3
     # the chosen set as a mask is top_k's own list on this backend, ties
     # included; and the check fails where it is not
     assert hybrid["chosen_rows_off"] == 0

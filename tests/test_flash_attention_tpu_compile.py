@@ -124,6 +124,41 @@ def test_eva_kernels_compile_for_the_v5e_at_the_cells_shape(one_chip):
     assert VMEM_BUDGET_BYTES < mine["bwd"]["vmem_estimate"] < 2 * VMEM_BUDGET_BYTES
 
 
+@pytest.mark.parametrize("shape", [
+    (8, 4096, 16, 64, 1, 128, 128),     # the Nemotron cell: 16 heads, 1 group
+    (2, 2048, 128, 64, 2, 128, 256),    # 64 heads a group in four tiles, Q 256
+    (2, 1024, 8, 64, 4, 128, 128),      # groups of two heads: a tile a group
+], ids=["nemotron-cell", "hg64-q256", "four-groups"])
+def test_scan_kernels_compile_for_the_v5e(one_chip, shape):
+    """ops/mamba2's kernel pair (PR 41) at the tile the rule chooses, forward
+    and backward, bf16: what interpret mode cannot show — a lane slice, a
+    broadcast or a block shape Mosaic cannot lay out, more VMEM than the call
+    asked for — and that outside the two calls the compiled scan holds no
+    float32 tensor of a chunk's [Q, Q] a head."""
+    import functools
+
+    from ray_tpu.ops import mamba2
+
+    B, S, H, P, G, N, Q = shape
+    sd = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (sd((B, S, H, P), jnp.bfloat16), sd((B, S, H), jnp.float32),
+            sd((H,), jnp.float32), sd((B, S, G, N), jnp.bfloat16),
+            sd((B, S, G, N), jnp.bfloat16))
+    scan = functools.partial(mamba2._ssd_scan, chunk=Q, interpret=False)
+    hlo = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(scan(*a))),
+                           argnums=(0, 1, 2, 3, 4))).lower(*args).compile(
+                               ).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    mine = {d["kernel"]: d for d in mamba2.ssd_tiling_decisions()
+            if (d["rows"], d["S"], d["Q"], d["group_heads"]) == (
+                B, S, Q, H // G)}
+    assert set(mine) == {"fwd", "bwd"}
+    assert all(d["head_tile"] == min(16, H // G) for d in mine.values())
+    per_head = re.findall(rf"f32\[[0-9,]*{Q},{Q}\]", hlo)
+    sizes = [math.prod(int(n) for n in t[4:-1].split(",")) for t in per_head]
+    assert all(n <= B * (S // Q) * G * Q * Q for n in sizes), set(per_head)
+
+
 def _cell_on(topo, name):
     """(cell, config, its family, its mesh over the described chips) of a
     BENCHMARK.json cell."""
@@ -296,6 +331,28 @@ def test_the_nemotron_cell_step_fits_with_nothing_cloned(nemotron_step):
     assert gpt2.compiler_rematerialized(compiled.as_text()) == []
     assert compiled.memory_analysis().peak_memory_in_bytes <= 14.6 * 2 ** 30
     assert (d["saved"], d["head_rows"], d["n_layer"]) == ([], 128, 13)
+
+
+def test_the_nemotron_cell_step_scans_in_two_kernels_under_the_scope(
+        nemotron_step):
+    """PR 41: the same compiled step holds the scan's kernel pair — forward,
+    the recompute's forward and the backward of its five Mamba layers (one
+    loop of four and one alone: six calls) — each custom call under the
+    `ssd_scan` scope, where `ssd_scan_ms_per_step` and `ssd_scan_roofline`
+    find it; and no float32 [.., 128, 128] of a chunk AND head (the decay
+    matrices were 8 x 32 x 16 of them a layer)."""
+    compiled, _, _ = nemotron_step
+    hlo = compiled.as_text()
+    calls = [re.search(r'op_name="([^"]*)"', l).group(1)
+             for l in hlo.splitlines()
+             if "tpu_custom_call" in l and "ssd_chunk" in l]
+    assert len(calls) == 6 and all("/ssd_scan/" in c for c in calls), calls
+    assert sum("ssd_chunk_bwd" in c for c in calls) == 2
+    assert sum("rematted_computation" in c and "ssd_chunk_fwd" in c
+               for c in calls) == 2
+    square = {t for t in re.findall(r"f32\[[0-9,]*128,128\]", hlo)
+              if math.prod(int(n) for n in t[4:-1].split(",")) > 8 * 32 * 128 * 128}
+    assert not square, square
 
 
 _MOE_T, _MOE_E, _MOE_HELD, _MOE_F = 32768, 512, 8, 2688

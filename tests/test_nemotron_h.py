@@ -94,42 +94,196 @@ def test_each_kind_of_layer_alone_equals_the_reference(kind):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("seq,chunk", [(64, 16), (50, 16), (10, 16), (48, 48)])
-def test_chunked_scan_equals_the_recurrence(seq, chunk):
-    """At chunk boundaries, at a row that is no whole number of chunks and at
-    one shorter than a chunk."""
-    B, H, P, G, N = 2, 4, 8, 2, 8
-    k = jax.random.split(jax.random.PRNGKey(5), 5)
-    x = jax.random.normal(k[0], (B, seq, H, P))
-    dt = jax.nn.softplus(jax.random.normal(k[1], (B, seq, H)))
+def _recurrence(x, dt, A, Bm, Cm):
+    """The state-space recurrence a token at a time, in float32."""
+    (B, _, H, P), (G, N) = x.shape, Bm.shape[2:]
+    hg = H // G
+    x, Bm, Cm = (t.astype(jnp.float32) for t in (x, Bm, Cm))
+
+    def step(h, t):
+        x_t, dt_t, b_t, c_t = t
+        a = jnp.exp(dt_t * A).reshape(B, G, hg)
+        dx = (x_t * dt_t[..., None]).reshape(B, G, hg, P)
+        h = a[..., None, None] * h + dx[..., None] * b_t[:, :, None, None, :]
+        return h, jnp.einsum("bghpn,bgn->bghp", h, c_t).reshape(B, H, P)
+
+    _, y = jax.lax.scan(step, jnp.zeros((B, G, hg, P, N)), tuple(
+        jnp.moveaxis(t, 1, 0) for t in (x, dt, Bm, Cm)))
+    return jnp.moveaxis(y, 0, 1)
+
+
+def _chunked_xla(x, dt, A, Bm, Cm, chunk):
+    """The chunked form in plain XLA with the kernels' dtype rules (what
+    ops/mamba2.ssd_scan was before PR 41; its backward is AD's): the second
+    oracle, so that a difference between the kernels and the recurrence can
+    be told from the chunked form's own rounding of bf16 operands."""
+    Bsz, S, H, P = x.shape
+    G, N = Bm.shape[2], Bm.shape[3]
+    Q = min(chunk, S)
+    pad = -S % Q
+    if pad:
+        x, dt, Bm, Cm = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                         for t in (x, dt, Bm, Cm))
+    nc, hg, dtype = (S + pad) // Q, H // G, x.dtype
+    xc = x.reshape(Bsz, nc, Q, G, hg, P)
+    dtc = dt.reshape(Bsz, nc, Q, G, hg)
+    Bc = Bm.reshape(Bsz, nc, Q, G, N)
+    Cc = Cm.reshape(Bsz, nc, Q, G, N)
+    cum = jnp.cumsum(dtc * A.reshape(G, hg), axis=2)          # [B,nc,Q,G,hg]
+    total = cum[:, :, -1]
+    dx = (xc.astype(jnp.float32) * dtc[..., None]).astype(dtype)
+    cb = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc,
+                    preferred_element_type=jnp.float32)
+    ci = jnp.moveaxis(cum, 2, -1)
+    decay = ci[..., :, None] - ci[..., None, :]
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    L = jnp.exp(jnp.where(causal, decay, -jnp.inf))           # [B,nc,G,hg,Q,Q]
+    m = (cb[:, :, :, None] * L).astype(dtype)
+    y = jnp.einsum("bcghij,bcjghp->bcighp", m, dx,
+                   preferred_element_type=jnp.float32)
+    to_end = jnp.exp(total[:, :, None] - cum)
+    dx_end = (dx.astype(jnp.float32) * to_end[..., None]).astype(dtype)
+    added = jnp.einsum("bcjghp,bcjgn->bcghpn", dx_end, Bc,
+                       preferred_element_type=jnp.float32)
+
+    def step(h, xs):
+        add, tot = xs
+        return h * jnp.exp(tot)[..., None, None] + add, h
+
+    _, starts = jax.lax.scan(step, jnp.zeros((Bsz, G, hg, P, N), jnp.float32),
+                             (jnp.moveaxis(added, 1, 0),
+                              jnp.moveaxis(total, 1, 0)))
+    starts = jnp.moveaxis(starts, 0, 1)
+    y_state = jnp.einsum("bcign,bcghpn->bcighp", Cc, starts.astype(dtype),
+                         preferred_element_type=jnp.float32)
+    y = y + y_state * jnp.exp(cum)[..., None]
+    return y.reshape(Bsz, nc * Q, H, P)[:, :S]
+
+
+_TINY = dict(B=2, H=4, P=8, G=2, N=8)
+_SCAN_CASES = {
+    # at chunk boundaries, at a row that is no whole number of chunks, at one
+    # shorter than a chunk, at one that is one chunk
+    "64-16": dict(_TINY, seq=64, chunk=16),
+    "50-16": dict(_TINY, seq=50, chunk=16),
+    "10-16": dict(_TINY, seq=10, chunk=16),
+    "48-48": dict(_TINY, seq=48, chunk=48),
+    # the cell's proportions and dtype: one group of 16 heads of 64 over a
+    # state of 128, two chunks of 128, one row
+    "cell-bf16": dict(B=1, H=16, P=64, G=1, N=128, seq=256, chunk=128,
+                      dtype=jnp.bfloat16, bc_scale=128 ** -0.5, tol=1e-2,
+                      oracle_tol=1e-2),
+    # two groups whose head tiles are whole lanes: blocks taken in place
+    "two-groups": dict(B=1, H=4, P=64, G=2, N=128, seq=256, chunk=128),
+    # 64 heads a group in four tiles of 16 (their d B, d C add up), Q = 256
+    "hg64-q256": dict(B=1, H=64, P=8, G=1, N=8, seq=512, chunk=256),
+    # Δ·A near 0: nothing decays, the carried state's part of y dominates
+    "state-kept": dict(_TINY, seq=64, chunk=16, dt_scale=1e-3),
+}
+
+
+def _scan_inputs(case):
+    """((x, dt, A, Bm, Cm), a weight for y) of a case, from one key."""
+    c = {"dtype": jnp.float32, "dt_scale": 1.0, "bc_scale": 1.0, **case}
+    B, H, P, G, N, seq = (c[k] for k in "B H P G N seq".split())
+    k = jax.random.split(jax.random.PRNGKey(5), 6)
+    x = jax.random.normal(k[0], (B, seq, H, P)).astype(c["dtype"])
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, seq, H))) * c["dt_scale"]
     A = -jnp.exp(jax.random.normal(k[2], (H,)))
-    Bm = jax.random.normal(k[3], (B, seq, G, N))
-    Cm = jax.random.normal(k[4], (B, seq, G, N))
+    Bm, Cm = ((jax.random.normal(k_, (B, seq, G, N)) * c["bc_scale"]
+               ).astype(c["dtype"]) for k_ in k[3:5])
+    return (x, dt, A, Bm, Cm), jax.random.normal(k[5], (B, seq, H, P))
 
-    def recurrence(x, dt, A, Bm, Cm):
-        hg = H // G
 
-        def step(h, t):
-            x_t, dt_t, b_t, c_t = t
-            a = jnp.exp(dt_t * A).reshape(B, G, hg)
-            dx = (x_t * dt_t[..., None]).reshape(B, G, hg, P)
-            h = a[..., None, None] * h + dx[..., None] * b_t[:, :, None, None, :]
-            return h, jnp.einsum("bghpn,bgn->bghp", h, c_t).reshape(B, H, P)
+@pytest.mark.parametrize("case", list(_SCAN_CASES.values()),
+                         ids=list(_SCAN_CASES))
+def test_chunked_scan_equals_the_recurrence(case):
+    """Value and all five gradients of ops/mamba2.ssd_scan (the Pallas kernel
+    pair, interpreted here) against the token-by-token float32 recurrence,
+    and against the chunked form in plain XLA under the same dtype rules
+    (where operands are bf16 both stand a bf16 rounding from the recurrence:
+    a fault of the kernels' shows against both)."""
+    c = {"tol": 1e-4, "oracle_tol": 1e-4, **case}
+    chunk, args, w = c["chunk"], *_scan_inputs(case)
+    x = args[0]
 
-        _, y = jax.lax.scan(step, jnp.zeros((B, G, hg, P, N)), tuple(
-            jnp.moveaxis(t, 1, 0) for t in (x, dt, Bm, Cm)))
-        return jnp.moveaxis(y, 0, 1)
+    def both(f):
+        return jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(f(*a)) * w),
+                                  argnums=(0, 1, 2, 3, 4))(*args)
 
     with jax.default_matmul_precision("highest"):
-        got = mamba2.ssd_scan(x, dt, A, Bm, Cm, chunk)
-        want = recurrence(x, dt, A, Bm, Cm)
-        g_got = jax.grad(lambda *a: jnp.sum(jnp.sin(mamba2.ssd_scan(*a, chunk))),
-                         argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
-        g_want = jax.grad(lambda *a: jnp.sum(jnp.sin(recurrence(*a))),
-                          argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-    for a, b in zip(g_got, g_want):
-        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+        got = mamba2.ssd_scan(*args, chunk)
+        want = _recurrence(*args)
+        oracle = _chunked_xla(*args, chunk)
+        g_got, g_want, g_oracle = (both(f)[1] for f in (
+            lambda *a: mamba2.ssd_scan(*a, chunk), _recurrence,
+            lambda *a: _chunked_xla(*a, chunk)))
+    assert got.dtype == jnp.float32 and got.shape == x.shape
+    assert all(g.dtype == a.dtype and g.shape == a.shape
+               for g, a in zip(g_got, args))
+
+    def off(a, b):
+        a, b = (np.asarray(t, np.float64) for t in (a, b))
+        return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+    if case.items() >= _TINY.items():
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+        for a, b in zip(g_got, g_want):
+            np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+    offs = [(off(a, b), off(a, o)) for a, b, o in zip(
+        (got,) + g_got, (want,) + g_want, (oracle,) + g_oracle)]
+    assert all(r < c["tol"] and o < c["oracle_tol"] for r, o in offs), (
+        np.round(offs, 6).tolist())
+
+
+def test_state_kept_case_is_the_carried_states():
+    """The last case above tests what it says: with Δ·A near 0 a late chunk's
+    y is mostly the state it started from, not its own tokens'."""
+    c = _SCAN_CASES["state-kept"]
+    (x, dt, A, Bm, Cm), _ = _scan_inputs(c)
+    last = slice(c["seq"] - c["chunk"], None)
+    whole = mamba2.ssd_scan(x, dt, A, Bm, Cm, c["chunk"])[:, last]
+    own = mamba2.ssd_scan(x[:, last], dt[:, last], A, Bm[:, last], Cm[:, last],
+                          c["chunk"])
+    assert (np.linalg.norm(whole - own) > 1.5 * np.linalg.norm(own))
+
+
+def test_the_scan_leaves_no_decay_matrix_outside_its_kernels():
+    """PR 41: at the cell's proportions (two rows of four chunks here) nothing
+    of a chunk's [Q, Q] — the decay matrix, C·Bᵀ ∘ L, their casts, their
+    gradients: 268 MB a layer at the cell's 256 chunks of 16 heads — is an
+    XLA value, forward or backward: outside the two pallas_calls every value
+    of the scan and of its gradient is no larger than y (x-sized, float32) or
+    the chunk states, and none ends in [Q, Q] but the one lower triangle of
+    ones the log-decays are summed with."""
+    B, S, H, P, G, N, Q = 2, 512, 16, 64, 1, 128, 128
+    sd = jax.ShapeDtypeStruct
+    args = (sd((B, S, H, P), jnp.bfloat16), sd((B, S, H), jnp.float32),
+            sd((H,), jnp.float32), sd((B, S, G, N), jnp.bfloat16),
+            sd((B, S, G, N), jnp.bfloat16))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(mamba2.ssd_scan(*a, Q)), argnums=(0, 1, 2, 3, 4)))(
+            *args)
+    kernels, shapes = [], []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                kernels.append(str(eqn.params["name"]))
+                shapes.extend(v.aval.shape for v in eqn.outvars)
+                continue                      # what a kernel holds is VMEM's
+            shapes.extend(v.aval.shape for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert sorted(kernels) == ["ssd_chunk_bwd", "ssd_chunk_fwd"]
+    largest = max(B * S * H * P, B * (S // Q) * H * P * N)
+    assert max(int(np.prod(s)) for s in shapes) <= largest
+    # [Q, Q] a chunk AND head (d B, d C are [Q, N] a chunk, and N = Q here)
+    square = [s for s in shapes if s[-2:] == (Q, Q)
+              and int(np.prod(s)) > B * (S // Q) * Q * Q]
+    assert not square, square
 
 
 # ---- the share ties to the model: the parts the shares give add up to the

@@ -41,6 +41,7 @@ LOWERINGS = {
 EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
 EVA_KERNELS = (names.EVA_AGG_FWD_KERNEL, names.EVA_AGG_BWD_KERNEL)
 FLASH_KERNELS = (names.FLASH_FWD_KERNEL, names.FLASH_BWD_KERNEL)
+SSD_KERNELS = (names.SSD_CHUNK_FWD_KERNEL, names.SSD_CHUNK_BWD_KERNEL)
 NEMOTRON_SCOPES = (names.MAMBA, names.SSD_SCAN, names.MOE_ROUTED,
                    names.MOE_DISPATCH, names.MOE_LATENT, names.MOE_SHARED,
                    names.MTP)
@@ -203,7 +204,7 @@ def test_remat_recompute_keeps_the_block_scopes(blocks):
 
 
 def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
-    assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS
+    assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS + SSD_KERNELS
                                      + (names.RAGGED_DOT_KERNEL,))
 
 
@@ -214,7 +215,8 @@ def test_kernel_name_in_jaxpr(kernel):
         _, jaxpr = _lowering("nemotron")
         assert "ragged_dot" in jaxpr
         return
-    _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else "remat")
+    _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else
+                         "nemotron" if kernel in SSD_KERNELS else "remat")
     assert f"name={kernel}" in jaxpr
 
 
@@ -276,6 +278,48 @@ def buffer(monkeypatch):
 
 def _drain(buf, component):
     return [e for e in buf.drain(10 ** 6)[0] if e["component"] == component]
+
+
+def test_ssd_tiling_decisions_of_the_traced_step(buffer):
+    """Tracing the hybrid's step leaves ONE `ops/ssd_tiling` decision a scan
+    kernel for its Mamba layers' shape (two `M` layers; forward, recompute
+    and backward trace the scan more than once), with the vocabulary's args.
+    Each distinct decision is one instant event of component `ops` in the
+    task-event buffer (-> `ray_tpu.timeline()`): a shape no other test
+    traces records its two, and tracing it again records nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import nemotron_h
+    from ray_tpu.ops import mamba2
+
+    _lowering("nemotron")
+    cfg = nemotron_h.nemotron_h_tiny()
+    step = dict(rows=2, S=cfg.seq_len, Q=cfg.chunk, P=cfg.mamba_head_dim,
+                N=cfg.ssm_state,
+                group_heads=cfg.mamba_heads // cfg.mamba_groups)
+    mine = [d for d in mamba2.ssd_tiling_decisions()
+            if {k: d[k] for k in step} == step]
+    assert sorted(d["kernel"] for d in mine) == ["bwd", "fwd"]
+    assert all(tuple(d) == names.SSD_TILING_ARGS
+               and d["group_heads"] % d["head_tile"] == 0 < d["vmem_estimate"]
+               for d in mine)
+
+    sd = jax.ShapeDtypeStruct
+    odd = (sd((3, 40, 6, 8), jnp.float32), sd((3, 40, 6), jnp.float32),
+           sd((6,), jnp.float32), sd((3, 40, 2, 8), jnp.float32),
+           sd((3, 40, 2, 8), jnp.float32))
+    grad = jax.grad(lambda *a: jnp.sum(mamba2.ssd_scan(*a, 20)),
+                    argnums=(0, 1, 2, 3, 4))
+    buffer.drain(10 ** 6)
+    jax.make_jaxpr(grad)(*odd)
+    events = [e for e in _drain(buffer, "ops") if e["name"] == "ssd_tiling"]
+    assert [(e["args"]["kernel"], e["args"]["rows"], e["args"]["S"],
+             e["args"]["Q"], e["args"]["group_heads"]) for e in events] == [
+                 ("fwd", 3, 40, 20, 3), ("bwd", 3, 40, 20, 3)]
+    assert all(e["args"] in mamba2.ssd_tiling_decisions() for e in events)
+    jax.make_jaxpr(grad)(*odd)
+    assert not [e for e in _drain(buffer, "ops") if e["name"] == "ssd_tiling"]
 
 
 def test_span_without_jax_imports_nothing_and_records(buffer, monkeypatch):
