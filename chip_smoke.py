@@ -574,8 +574,9 @@ def main() -> int:
               f"seq={d['seq']} -> saved {d['saved'] or 'block inputs only'}, "
               f"{d['saved_bytes'] / gib:.2f} GiB of a budget of "
               f"{d['budget_bytes'] / gib:.2f} (bytes_limit "
-              f"{d['bytes_limit'] / gib:.2f} GiB); MLP {d['mlp_rows']} rows "
-              f"at a time, head {d['head_rows']}")
+              f"{d['bytes_limit'] / gib:.2f} GiB) left by the backward's "
+              f"phase {d['phase']!r} ({d['phase_bytes'] / gib:.2f} GiB); MLP "
+              f"{d['mlp_rows']} rows at a time, head {d['head_rows']}")
     eva = summary["eva"]
     for d in eva["tiling"]:
         print(f"eva tiling: {d['kernel']} rows={d['rows']} S={d['Sq']} "

@@ -23,7 +23,10 @@ from ray_tpu.core.ids import ObjectID, TaskID
 # no pending tasks/borrowers remain; borrowers use it to send a release to
 # the owner (core_worker._on_local_refs_zero).
 # ---------------------------------------------------------------------------
-_reg_lock = _san.make_lock("core.refs")
+# Re-entrant: ObjectRef.__del__ takes it, and the collector can run a finaliser
+# on the thread that holds it inside ObjectRef.__init__ (ROADMAP D18) — with a
+# plain lock that thread waits for itself for good.
+_reg_lock = _san.make_rlock("core.refs")
 _local_counts: Dict[bytes, int] = {}
 _owner_addrs: Dict[bytes, Optional[str]] = {}  # last-seen owner per live oid
 _on_zero: Optional[Callable[[ObjectID, Optional[str], Optional[TaskID]], None]] = None

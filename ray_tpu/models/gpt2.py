@@ -537,23 +537,32 @@ def rematted_working_set(s: BlockShard, n_layer: int) -> int:
 def model_working_set(s: BlockShard, n_layer: int) -> int:
     """rematted_working_set's part that no block decides: the stack of
     ``n_layer`` block inputs, the LM head's logits, the gathered embedding."""
-    tokens = s.batch * s.seq
+    return n_layer * _block_input(s) + _head_terms(s) + _gathered(s)
+
+
+def _block_input(s: BlockShard) -> int:
+    return s.batch * s.seq * s.d_model * s.dtype_bytes
+
+
+def _head_terms(s: BlockShard) -> int:
     a = s.dtype_bytes
-    stack = n_layer * tokens * s.d_model * a
     head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
     if s.head_rows:
         # a head in chunks makes its gradient in the forward and keeps it
         # (ops/cross_entropy.chunked_head_xent): d x stands where the chunked
         # x stood, the running float32 d lm_head is new
         head += s.d_model * s.vocab * 4
-    gathered = s.vocab * s.d_model * (a + 4)
-    return stack + head + gathered
+    return head
+
+
+def _gathered(s: BlockShard) -> int:
+    return s.vocab * s.d_model * (s.dtype_bytes + 4)
 
 
 def block_working_set(s: BlockShard) -> int:
     """rematted_working_set's part that is one block's: its whole residual
     set, live while its backward runs. Of a model whose layers are of more
-    than one kind the largest kind's counts (choose_remat_policy_kinds)."""
+    than one kind each run's largest counts in its phase (backward_phases)."""
     tokens = s.batch * s.seq
     a = s.dtype_bytes
     attn_width = s.heads * s.head_dim
@@ -570,14 +579,62 @@ def block_working_set(s: BlockShard) -> int:
 class KindShard(NamedTuple):
     """What the remat rule needs of one kind of layer on one chip: how often
     the kind is applied, what a block of it may keep (remat_candidates, or
-    the kind's own arithmetic) and what its whole residual set takes while
-    its backward runs (block_working_set)."""
+    the kind's own arithmetic), what its whole residual set takes while
+    its backward runs (block_working_set), and the bytes of one application's
+    weight gradients (backward_phases takes those not made yet off a run's
+    phase; a model of one run of layers has none to take off and states 0)."""
     applications: int
     candidates: Tuple[RematCandidate, ...]
     block_bytes: int
+    grad_bytes: int = 0
 
 
-def choose_remat_policy_kinds(kinds: Sequence[KindShard], model_bytes: int,
+# a run of the layers as pattern_groups names it: the kinds of its repeated
+# sub-pattern (keys of the model's KindShards) and the repeats
+Run = Tuple[Sequence[str], int]
+
+
+class Phase(NamedTuple):
+    name: str                # "head", or the run as model/layer_pattern has it
+    nbytes: int              # the fully rematted step's working set, in it
+
+
+def run_name(run: Run) -> str:
+    sub, reps = run
+    return f"{reps} x scan({''.join(sub)})" if reps > 1 else "".join(sub)
+
+
+def backward_phases(s: BlockShard, kinds: Dict[str, KindShard],
+                    runs: Sequence[Run]) -> List[Phase]:
+    """rematted_working_set by the phases of the step's backward, in the
+    order it meets them: the head's, then every run of the layers from the
+    last to the first. The step needs the LARGEST of them, not their sum. In a
+    run's phase stand the block inputs that still wait (its own and those of
+    the runs before it), the largest block of the run's kinds, the gathered
+    embedding, and — in the last run, whose backward starts on them — the
+    head's terms; what does NOT stand there yet are the weight gradients of
+    the runs before it, which the step's resident bytes count from the start.
+    A run's own gradients all count: a scan writes its stacked gradients from
+    the moment its backward starts. A model of one kind in one scan has one
+    such phase, and it is rematted_working_set to the byte (its head phase
+    is that less the block). The MEMEMEMEM*E + *E hybrid's head, MTP module
+    and last five layers are dead by the time the scan of eight writes 1.7 GiB
+    of gradients: summed, the estimate stood 2.5 GiB over the compiled step
+    (PERF.md §6, PR 42)."""
+    layers = [reps * len(sub) for sub, reps in runs]
+    grads = [reps * sum(kinds[k].grad_bytes for k in sub) for sub, reps in runs]
+    phases = [Phase("head", model_working_set(s, sum(layers)) - sum(grads))]
+    for i in reversed(range(len(runs))):
+        live = (sum(layers[:i + 1]) * _block_input(s) + _gathered(s)
+                + max(kinds[k].block_bytes for k in runs[i][0])
+                - sum(grads[:i]))
+        if i == len(runs) - 1:
+            live += _head_terms(s)
+        phases.append(Phase(run_name(runs[i]), live))
+    return phases
+
+
+def choose_remat_policy_kinds(kinds: Sequence[KindShard], working_set: int,
                               bytes_limit: Optional[int],
                               resident_bytes: int) -> RematPolicy:
     """THE rule for what ``remat=True`` keeps besides each block's input, for
@@ -585,13 +642,12 @@ def choose_remat_policy_kinds(kinds: Sequence[KindShard], model_bytes: int,
     recompute FLOPs per byte first) and take each whose copies — one an
     application of its kind — still fit what the chip has free: its
     bytes_limit less the reserve, what is resident (state and gradients) and
-    the fully rematted step's working set (``model_bytes`` and the largest
-    kind's block), plus what keeping it frees of that set. With no limit
+    the fully rematted step's ``working_set`` (the largest of
+    backward_phases), plus what keeping it frees of that set. With no limit
     stated, nothing."""
     if bytes_limit is None:
         return RematPolicy((), 0, 0, 0)
-    budget = (bytes_limit - REMAT_RESERVE_BYTES - resident_bytes
-              - model_bytes - max(k.block_bytes for k in kinds))
+    budget = bytes_limit - REMAT_RESERVE_BYTES - resident_bytes - working_set
     ranked = sorted(((c, k.applications) for k in kinds for c in k.candidates),
                     key=lambda cn: (-cn[0].flops / cn[0].nbytes, -cn[0].frees))
     saved, used = [], 0
@@ -610,7 +666,7 @@ def choose_remat_policy(shard: BlockShard, n_layer: int,
     return choose_remat_policy_kinds(
         [KindShard(n_layer, tuple(remat_candidates(shard)),
                    block_working_set(shard))],
-        model_working_set(shard, n_layer), bytes_limit, resident_bytes)
+        rematted_working_set(shard, n_layer), bytes_limit, resident_bytes)
 
 
 def remat_policy_decisions() -> List[Dict[str, Any]]:
@@ -634,23 +690,25 @@ def _flash(cfg: GPT2Config, mesh) -> bool:
     return resolve_attention(cfg.attention_impl, mesh)[0] == "pallas"
 
 
-def _remat_policy(shard: BlockShard, kinds: Sequence[KindShard]) -> RematPolicy:
+def _remat_policy(shard: BlockShard, kinds: Dict[str, KindShard],
+                  runs: Sequence[Run]) -> RematPolicy:
     """choose_remat_policy_kinds for the step being traced, recorded. A static
     choice has no hit rate; its counter is the choice: each distinct one goes
     once, as an instant event, to the task-event buffer
-    (→ ``ray_tpu.timeline()``). ``shard`` is the model's: the stream, the
+    (→ ``ray_tpu.timeline()``), with the phase of the backward that set the
+    working set and its bytes. ``shard`` is the model's: the stream, the
     head and the rows the head and the MLP take at a time."""
     from ray_tpu.parallel import mesh as mesh_lib
 
-    n_layer = sum(k.applications for k in kinds)
+    n_layer = sum(k.applications for k in kinds.values())
+    phase = max(backward_phases(shard, kinds, runs), key=lambda p: p.nbytes)
     policy = choose_remat_policy_kinds(
-        kinds, model_working_set(shard, n_layer),
-        *mesh_lib.current_chip_memory())
+        tuple(kinds.values()), phase.nbytes, *mesh_lib.current_chip_memory())
     args = dict(zip(scopes.REMAT_POLICY_ARGS,
                     (n_layer, shard.batch, shard.seq, list(policy.saved))
                     + policy[1:] + (shard.mlp_rows or shard.seq,
-                                    shard.head_rows or shard.seq)))
-    key = (shard, tuple(kinds)) + policy
+                                    shard.head_rows or shard.seq) + phase))
+    key = (shard, tuple(kinds.items()), tuple(runs)) + policy
     if key not in _decisions:
         _decisions[key] = args
         component, name = scopes.REMAT_POLICY.split("/")
@@ -659,14 +717,15 @@ def _remat_policy(shard: BlockShard, kinds: Sequence[KindShard]) -> RematPolicy:
 
 
 def checkpoint_kinds(block_fns: Dict[str, Callable], remat: bool,
-                     shard: BlockShard, kinds: Dict[str, KindShard]
-                     ) -> Dict[str, Callable]:
+                     shard: BlockShard, kinds: Dict[str, KindShard],
+                     runs: Sequence[Run]) -> Dict[str, Callable]:
     """Each kind's ``block_fn(x, layer_params)`` as run_pattern calls it: a
     policy-``checkpoint`` that keeps the block's input and, of the named
     residuals (tracing/names.RESIDUALS), those the ONE rule gave room —
-    over all the kinds' applications together — with ``remat`` and all of
-    them without."""
-    saved = (_remat_policy(shard, tuple(kinds.values())).saved if remat
+    over all the kinds' applications together, in the largest phase of the
+    backward over ``runs`` (every run of the layers the step applies, in the
+    forward's order) — with ``remat`` and all of them without."""
+    saved = (_remat_policy(shard, kinds, runs).saved if remat
              else scopes.RESIDUALS)
     policy = jax.checkpoint_policies.save_only_these_names(*saved)
     return {kind: jax.checkpoint(fn, policy=policy)
@@ -693,7 +752,7 @@ def _checkpointed(block_fn, remat: bool, shard: BlockShard, n_layer: int):
     kind = KindShard(n_layer, tuple(remat_candidates(shard)),
                      block_working_set(shard))
     return checkpoint_kinds({"block": block_fn}, remat, shard,
-                            {"block": kind})["block"]
+                            {"block": kind}, [(("block",), n_layer)])["block"]
 
 
 def _make_block_fn(cfg: GPT2Config, global_batch: int, seq: int, mesh,
@@ -776,7 +835,7 @@ def record_layer_pattern(pattern: str) -> None:
     groups = pattern_groups(pattern)
     _patterns[pattern] = dict(zip(scopes.LAYER_PATTERN_ARGS, (
         pattern, {kind: pattern.count(kind) for kind in dict.fromkeys(pattern)},
-        [f"{reps} x scan({sub})" if reps > 1 else sub for sub, reps in groups])))
+        [run_name(run) for run in groups])))
     component, name = scopes.LAYER_PATTERN.split("/")
     get_buffer().record_profile(name, component=component,
                                 args=_patterns[pattern])

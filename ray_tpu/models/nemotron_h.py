@@ -342,8 +342,9 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
                 ) -> Tuple[gpt2.BlockShard, Dict[str, gpt2.KindShard]]:
     """This config's layers on one chip of ``mesh``, for the remat rule: the
     model's shard (stream, head, rows at a time) and, a kind, how often it is
-    applied (trunk and MTP module together), what a layer of it may keep and
-    what its backward holds at once — each from the kind's own shapes."""
+    applied (trunk and MTP module together), what a layer of it may keep,
+    what its backward holds at once and what its weight gradients take —
+    each from the kind's own shapes."""
     from ray_tpu.ops.attention import resolve_attention
 
     a = jnp.dtype(cfg.dtype).itemsize
@@ -385,20 +386,40 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
         + chunks * H * P * N * 4 + 2 * tokens * inner * 4
         + 2 * a * D * (2 * inner + conv_dim))
 
-    # E: the latent input; the shared expert's hidden where it is not chunked
+    # E: the latent input; the shared expert's hidden where it is not chunked;
+    # and what the routing decided (ops/moe.py tags them) — the scores at
+    # three bf16 passes of the router's float32 product; the `top_k`'s last
+    # value and index at a full sort of each row's n_experts with an index
+    # operand (what the TPU lowers it to) and the pairs' sorted keys at theirs
+    # (_sort_ops): a few hundred KB that spare a sort rank first
     rows = moe.row_buffer(tokens, cfg.n_experts, cfg.top_k, cfg.held_count)
-    latent = [C((scopes.RES_MOE_LATENT,), tokens * cfg.latent * a,
-                2 * tokens * D * cfg.latent)]
+    passes = moe.buffer_passes(tokens, cfg.n_experts, cfg.top_k,
+                               cfg.held_count)
+    experts_kept = [
+        C((scopes.RES_MOE_LATENT,), tokens * cfg.latent * a,
+          2 * tokens * D * cfg.latent),
+        C((scopes.RES_MOE_SCORES,), tokens * cfg.n_experts * 4,
+          3 * 2 * tokens * D * cfg.n_experts),
+        C((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
+          tokens * _sort_ops(cfg.n_experts, operands=2)),
+        C((scopes.RES_MOE_PAIR_KEY,), passes * rows * 4,
+          _sort_ops(tokens * cfg.held_count, operands=1))]
     if base.mlp_rows in (0, base.seq):
-        latent.append(C((scopes.RES_MOE_SHARED_HIDDEN,),
-                        tokens * base.d_ff * a, 2 * tokens * D * base.d_ff))
+        experts_kept.append(C((scopes.RES_MOE_SHARED_HIDDEN,),
+                              tokens * base.d_ff * a,
+                              2 * tokens * D * base.d_ff))
     expert_params = (2 * D * cfg.latent + 2 * D * base.d_ff
                      + 2 * cfg.held_count * cfg.latent * cfg.d_expert)
-    experts = gpt2.KindShard(counts["E"], tuple(latent), (
-        a * tokens * (4 * D + 3 * cfg.latent) + tokens * cfg.n_experts * 12
-        + a * base.batch * (base.mlp_rows or base.seq) * 3 * base.d_ff
-        + a * rows * (2 * cfg.latent + 3 * cfg.d_expert)
-        + 2 * a * expert_params))
+    # the routed experts' backward (the latents, three [tokens, n_experts]
+    # tensors of the routing, one pass's rows) and the shared expert's (its
+    # rows' hidden tensors) are never live together: the larger counts. Summed
+    # they stood 0.5 GiB over what the compiled step holds in an expert
+    # layer's backward (PERF.md §6, PR 42)
+    routed = (a * tokens * 3 * cfg.latent + tokens * cfg.n_experts * 12
+              + a * rows * (2 * cfg.latent + 3 * cfg.d_expert))
+    shared = a * base.batch * (base.mlp_rows or base.seq) * 3 * base.d_ff
+    experts = gpt2.KindShard(counts["E"], tuple(experts_kept), (
+        a * tokens * 4 * D + max(routed, shared) + 2 * a * expert_params))
 
     # *: q, k, v and the flash kernel's outputs (no MLP half, no mid-stream)
     attn = gpt2.KindShard(counts["*"], tuple(
@@ -406,7 +427,28 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
     ), a * tokens * (4 * D + 4 * base.heads * base.head_dim)
         + 2 * a * D * 2 * (base.heads + base.kv_heads) * base.head_dim)
     kinds = {"M": mamba, "E": experts, "*": attn}
-    return base, {k: v for k, v in kinds.items() if v.applications}
+    chips = mesh.devices.size if mesh is not None else 1
+    return base, {k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
+                  for k, v in kinds.items() if v.applications}
+
+
+def _sort_ops(n: int, operands: int) -> int:
+    """Operations of a sorting network over ``n`` keys (bitonic: log2(n) ·
+    (log2(n) + 1) / 2 stages of n / 2 compare-exchanges), each a comparison
+    and two selects an operand that moves."""
+    stages = math.log2(n) * (math.log2(n) + 1) / 2
+    return int(n / 2 * stages * (1 + 2 * operands))
+
+
+def _layer_bytes(cfg: NemotronHConfig, kind: str) -> int:
+    """Bytes of one layer of ``kind``'s parameters, which its weight
+    gradients take again. A chip of a mesh holds no less than its even share
+    (kind_shards divides by the chips: the rule takes gradients that do not
+    exist yet OFF what is resident, so the least is the safe figure)."""
+    layer = jax.eval_shape(
+        lambda: _stack_init(jax.random.PRNGKey(0), kind, cfg))
+    return sum(math.prod(p.shape) * p.dtype.itemsize
+               for p in jax.tree.leaves(layer))
 
 
 def _block_fns(cfg: NemotronHConfig, batch: int, seq: int):
@@ -417,7 +459,8 @@ def _block_fns(cfg: NemotronHConfig, batch: int, seq: int):
         gpt2.record_layer_pattern(pattern)
     return gpt2.checkpoint_kinds(
         {kind: partial(_layer, cfg=cfg, kind=kind) for kind in kinds},
-        cfg.remat, base, kinds)
+        cfg.remat, base, kinds,
+        gpt2.pattern_groups(cfg.pattern) + gpt2.pattern_groups(cfg.mtp_pattern))
 
 
 def _hidden(params, tokens, targets, cfg: NemotronHConfig,

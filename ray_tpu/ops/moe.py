@@ -262,9 +262,31 @@ def buffer_passes(tokens: int, n_experts: int, top_k: int, held: int) -> int:
              // row_buffer(tokens, n_experts, top_k, held))
 
 
+@jax.custom_vjp
+def _sigmoid(x: jax.Array) -> jax.Array:
+    """``jax.nn.sigmoid`` whose backward reads the NAMED scores: the
+    primitive's own derivative rule reads its own output, so a block that
+    keeps a ``checkpoint_name`` copy of it keeps the bytes and still makes the
+    router's product again to feed the primitive. Same values forward, and
+    backward ``g · s · (1 − s)`` as ``lax.logistic`` gives."""
+    return jax.nn.sigmoid(x)
+
+
+def _sigmoid_fwd(x):
+    s = checkpoint_name(jax.nn.sigmoid(x), scopes.RES_MOE_SCORES)
+    return s, s
+
+
+def _sigmoid_bwd(s, g):
+    return (g * (s * (1 - s)),)
+
+
+_sigmoid.defvjp(_sigmoid_fwd, _sigmoid_bwd)
+
+
 def _scores(u: jax.Array, router_w: jax.Array) -> jax.Array:
     """u [T, D] → every expert's sigmoid score [T, n_experts], float32."""
-    return jax.nn.sigmoid(jnp.einsum(
+    return _sigmoid(jnp.einsum(
         "td,de->te", u.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGH))
 
@@ -277,9 +299,12 @@ def _chosen(biased: jax.Array, top_k: int) -> jax.Array:
     elementwise pass, ties included. That order among equals is the backend's
     lowering, not ``lax.top_k``'s contract: tier-1 holds it on the CPU
     (tests/test_nemotron_h.py, the ties case), ``chip_smoke.chosen_rows_off``
-    on the chip at the published routing shape."""
+    on the chip at the published routing shape. ``kth`` and ``last`` carry
+    names: a block that keeps them (8 bytes a token) sorts once a step, not
+    again in its backward's second forward."""
     vals, idx = lax.top_k(biased, top_k)
-    kth, last = vals[:, -1:], idx[:, -1:]
+    kth = checkpoint_name(vals[:, -1:], scopes.RES_MOE_KTH)
+    last = checkpoint_name(idx[:, -1:], scopes.RES_MOE_LAST)
     ids = lax.broadcasted_iota(idx.dtype, biased.shape, 1)
     return (biased > kth) | ((biased == kth) & (ids <= last))
 
@@ -356,7 +381,10 @@ def held_pairs(here: jax.Array, gates: jax.Array, rows: int,
     key = jnp.sort(jnp.where(here.T, place, none).reshape(none), stable=False)
     # past the T · held keys there are no pairs
     total = passes * rows
-    key = jnp.pad(key, (0, max(0, total - none)))[:total]
+    # (named: a block that keeps the sorted keys does not sort them again;
+    # valid and group_sizes are a row-sum of `here` away)
+    key = checkpoint_name(jnp.pad(key, (0, max(0, total - none)))[:total],
+                          scopes.RES_MOE_PAIR_KEY)
     valid = jnp.arange(total) < jnp.sum(per_expert)
     # a pass's share of each expert's run of rows
     lo = (jnp.arange(passes) * rows)[:, None]

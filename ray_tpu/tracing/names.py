@@ -102,21 +102,31 @@ RES_MLP_GATE, RES_MLP_UP = "mlp_gate", "mlp_up"
 # a Mamba-2 layer's: the three projections' outputs (z, xBC before the conv,
 # dt), the state each chunk of the scan starts from, the scan's output. An
 # expert layer's: the latent input of the routed experts, the shared expert's
-# hidden pre-activation.
+# hidden pre-activation; and what its routing decided (PR 42) — the router's
+# sigmoid scores, the `top_k`'s last value and index a token (the chosen set
+# is one elementwise pass from them, ops/moe._chosen), the sorted keys of the
+# pairs on held experts (ops/moe.held_pairs).
 RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT = "mamba_z", "mamba_xbc", "mamba_dt"
 RES_SSD_STATES, RES_SSD_Y = "ssd_states", "ssd_y"
 RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN = "moe_latent_in", "moe_shared_hidden"
+RES_MOE_SCORES = "moe_scores"
+RES_MOE_KTH, RES_MOE_LAST = "moe_kth", "moe_kth_index"
+RES_MOE_PAIR_KEY = "moe_pair_key"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
              RES_MLP_GATE, RES_MLP_UP, RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT,
-             RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN)
-# which of them models/gpt2.py chose to save, and the rows of the sequence
-# the block's MLP and the LM head take at a time (the sequence: all at once):
-# one instant event per distinct decision, at trace time, in the task-event
-# buffer
+             RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN,
+             RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY)
+# which of them models/gpt2.py chose to save, the rows of the sequence
+# the block's MLP and the LM head take at a time (the sequence: all at once),
+# and the phase of the backward whose working set the budget was left by
+# (gpt2.backward_phases: "head", or a run of the layers as model/layer_pattern
+# names it) with that set's bytes: one instant event per distinct decision, at
+# trace time, in the task-event buffer
 REMAT_POLICY = "model/remat_policy"
 REMAT_POLICY_ARGS = ("n_layer", "batch", "seq", "saved", "saved_bytes",
-                     "budget_bytes", "bytes_limit", "mlp_rows", "head_rows")
+                     "budget_bytes", "bytes_limit", "mlp_rows", "head_rows",
+                     "phase", "phase_bytes")
 # a head that takes the sequence in chunks (ops/cross_entropy.
 # chunked_head_xent): the batch rows and the positions a chunk holds, the
 # chunks, the head's columns (all heads') and heads, whether this trace makes

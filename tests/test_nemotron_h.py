@@ -5,6 +5,7 @@ of layer alone, the chunked scan against the token-by-token recurrence, the
 share of a deployment tied to the uncut layer, no pair dropped, the MTP
 targets, the mesh refusals, the pattern machinery and the rule over kinds."""
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -787,24 +788,27 @@ def test_run_pattern_scans_repeats_and_applies_the_rest_in_order():
 def test_the_rule_counts_applications_per_kind():
     """One rule over all the kinds: a candidate costs its bytes once an
     application of ITS kind, and the largest kind's block sets the working
-    set."""
+    set of a run that holds both."""
     C, K = gpt2.RematCandidate, gpt2.KindShard
     a = K(5, (C(("x",), 100, 1000),), 700)
     b = K(1, (C(("y",), 100, 500),), 300)
-    limit = gpt2.REMAT_RESERVE_BYTES + 1000 + 700
+    shard = gpt2.block_shard(gpt2.gpt2_tiny(), 8, 128, None, True)
+    (head, run) = gpt2.backward_phases(shard, {"a": a, "b": b}, [("aaaaab", 1)])
+    model = gpt2.model_working_set(shard, 6)
+    assert (head, run) == (("head", model), ("aaaaab", model + 700))
+    limit = gpt2.REMAT_RESERVE_BYTES + run.nbytes
     keep = lambda extra: gpt2.choose_remat_policy_kinds(
-        [a, b], 1000, limit + extra, 0)
+        [a, b], run.nbytes, limit + extra, 0)
     assert keep(0).saved == ()
     assert keep(100).saved == ("y",)             # one application fits
     assert keep(500).saved == ("x",)             # five of the better one
     assert keep(600) == gpt2.RematPolicy(("x", "y"), 600, 600, limit + 600)
     # the one-kind rule is the same rule
-    shard = gpt2.block_shard(gpt2.gpt2_tiny(), 8, 128, None, True)
     one = gpt2.choose_remat_policy(shard, 2, 2 ** 31, 0)
     assert one == gpt2.choose_remat_policy_kinds(
         [K(2, tuple(gpt2.remat_candidates(shard)),
            gpt2.block_working_set(shard))],
-        gpt2.model_working_set(shard, 2), 2 ** 31, 0)
+        gpt2.rematted_working_set(shard, 2), 2 ** 31, 0)
     assert (gpt2.model_working_set(shard, 2) + gpt2.block_working_set(shard)
             == gpt2.rematted_working_set(shard, 2))
 
@@ -824,6 +828,211 @@ def test_the_cells_kinds_and_the_familys_arithmetic():
     assert named <= set(names.RESIDUALS) and names.RES_MID not in named
     assert {names.RES_SSD_STATES, names.RES_MOE_LATENT, names.RES_Q} <= named
     assert base.head_rows == 128 and base.mlp_rows < cfg.seq_len
+
+
+ROUTING = (names.RES_MOE_KTH, names.RES_MOE_LAST, names.RES_MOE_PAIR_KEY)
+
+
+def test_the_cells_decision_from_its_shapes_keeps_the_routing_first():
+    """PR 42: `nemotron-3-super-120b-l11.dataset`'s decision, from its shapes
+    and a v5e's bytes_limit alone. The estimate follows the backward's phases
+    — the scan of eight layers sets it, where its 1.7 GiB of stacked
+    gradients meet eight block inputs and an expert layer's block, and the
+    head, the MTP module and the five later layers are dead — so the budget is
+    about a GiB where the sum of everything left it at -1.1; and the rule
+    spends it on the routing's outcome first (a sort spared for 8 bytes a
+    token, the router's float32 product for its scores), then on what matmul
+    outputs still fit. The latent input does not."""
+    from benchmarks.harness import spec
+    from ray_tpu.train.train_step import _resident_bytes
+
+    cell, config, _ = spec.load_cell("nemotron-3-super-120b-l11.dataset")
+    cfg = family.program_config(config, cell)
+    base, kinds = nh.kind_shards(cfg, cell["per_chip_batch"], cfg.seq_len, None)
+    runs = gpt2.pattern_groups(cfg.pattern) + gpt2.pattern_groups(cfg.mtp_pattern)
+    assert [gpt2.run_name(r) for r in runs] == [
+        "4 x scan(ME)", "M", "*", "E", "*", "E"]
+    # a layer's gradients are its parameters' bytes: the kinds' add up to the
+    # stacks'
+    params = jax.eval_shape(lambda: nh.init(cfg, jax.random.PRNGKey(0)))
+    nbytes = lambda tree: sum(x.size * x.dtype.itemsize
+                              for x in jax.tree.leaves(tree))
+    assert sum(k.applications * k.grad_bytes for k in kinds.values()) == (
+        nbytes(params["blocks"]) + nbytes(params["mtp"]["blocks"]))
+
+    phases = gpt2.backward_phases(base, kinds, runs)
+    assert [p.name for p in phases] == ["head", "E", "*", "E", "*", "M",
+                                        "4 x scan(ME)"]
+    largest = max(phases, key=lambda p: p.nbytes)
+    assert largest.name == "4 x scan(ME)"
+    # the sum the rule took until now: every block input, the head and the
+    # largest block beside every gradient
+    summed = gpt2.model_working_set(base, 13) + max(
+        k.block_bytes for k in kinds.values())
+    assert summed - largest.nbytes > 2 ** 30
+    # (the moments in the benchmark's optimizer are bfloat16: 12 B a parameter
+    # with the gradients)
+    resident = 3 * nbytes(params)
+    policy = gpt2.choose_remat_policy_kinds(
+        tuple(kinds.values()), largest.nbytes, family.V5E_BYTES_LIMIT, resident)
+    assert 2 ** 30 <= policy.budget_bytes <= 1.25 * 2 ** 30
+    assert 0 < policy.saved_bytes <= policy.budget_bytes
+    assert gpt2.choose_remat_policy_kinds(
+        tuple(kinds.values()), summed, family.V5E_BYTES_LIMIT, resident
+    ).saved == ()
+    # the order taken: Δ's projection (one MXU pass for 4 bytes a head), the
+    # sort, the router's product — before any matmul output
+    assert policy.saved[:4] == (names.RES_MAMBA_DT, names.RES_MOE_KTH,
+                                names.RES_MOE_LAST, names.RES_MOE_SCORES)
+    assert set(ROUTING) < set(policy.saved)
+    assert names.RES_MAMBA_Z in policy.saved
+    assert names.RES_MOE_LATENT not in policy.saved
+    by_name = {c.names: c for c in kinds["E"].candidates}
+    T = cell["per_chip_batch"] * cfg.seq_len
+    assert by_name[(names.RES_MOE_KTH, names.RES_MOE_LAST)].nbytes == 8 * T
+    assert by_name[(names.RES_MOE_SCORES,)].nbytes == 4 * T * 512
+    assert by_name[(names.RES_MOE_PAIR_KEY,)].nbytes < 2 ** 21
+
+
+def _count(jaxpr, pred):
+    """Equations of ``jaxpr`` and every jaxpr inside it that ``pred`` takes."""
+    return sum(bool(pred(eqn)) + sum(_count(sub, pred) for sub in
+                                     jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("saved,top_ks,sorts,router_products", [
+    ((), 2, 2, 2),
+    (ROUTING, 1, 1, 2),
+    ((names.RES_MOE_SCORES,), 2, 2, 1),
+    (ROUTING + (names.RES_MOE_SCORES,), 1, 1, 1),
+    (names.RESIDUALS, 1, 1, 1),
+], ids=["nothing", "routing", "scores", "routing_and_scores", "every_name"])
+def test_a_kept_routing_outcome_is_not_made_again(saved, top_ks, sorts,
+                                                  router_products):
+    """The gradient of one checkpointed expert layer: with nothing kept its
+    backward's second forward sorts the scores (`top_k`) and the pairs' keys
+    again and multiplies the router's product a second time; with the
+    `top_k`'s last value and index and the sorted keys kept it sorts once,
+    with the scores kept — the sigmoid's backward reads the NAMED value
+    (moe._sigmoid) — the product is made once. Whatever is kept, the same
+    gradients bit for bit."""
+    cfg = nh.nemotron_h_tiny(latent=16)    # no other [T, n_experts] product
+    params = nh.init(cfg, jax.random.PRNGKey(21))
+    p = _layer_of(params["blocks"][0], "E")
+    x = jax.random.normal(jax.random.PRNGKey(22), (2, cfg.seq_len, cfg.d_model),
+                          cfg.dtype)
+    T = 2 * cfg.seq_len
+
+    def grads(policy):
+        fn = jax.checkpoint(lambda x, p: nh._layer(x, p, cfg, "E"),
+                            policy=policy)
+        return jax.grad(lambda x, p: jnp.sum(jnp.sin(fn(x, p).astype(
+            jnp.float32))), (0, 1))
+
+    kept = grads(jax.checkpoint_policies.save_only_these_names(*saved))
+    jaxpr = jax.make_jaxpr(kept)(x, p).jaxpr
+    router = lambda e: (e.primitive.name == "dot_general"
+                        and e.outvars[0].aval.shape == (T, cfg.n_experts)
+                        and e.outvars[0].aval.dtype == jnp.float32)
+    assert _count(jaxpr, lambda e: e.primitive.name == "top_k") == top_ks
+    assert _count(jaxpr, lambda e: e.primitive.name == "sort") == sorts
+    assert _count(jaxpr, router) == router_products
+    want = jax.jit(grads(jax.checkpoint_policies.nothing_saveable))(x, p)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(
+            jax.jit(kept)(x, p)), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32),
+                                      err_msg=jax.tree_util.keystr(path))
+    assert float(jnp.max(jnp.abs(want[1]["router_w"]))) > 0
+
+
+def test_the_chosen_set_from_a_kept_kth_and_last_is_the_top_ks_on_ties():
+    """The ties case under a checkpoint that keeps `kth` and `last` alone:
+    the residuals are the two [T, 1] columns, the backward holds no `top_k`,
+    and the gates' derivative — which the chosen set decides, through the
+    normalising sum — is the chosen-list form's, where `lax.top_k`'s ids
+    are gathered."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    logits, bias, top_k, held, rows = _membership_logits(
+        "ties_across_the_kth_place")
+    T, E = logits.shape
+    passes = -(-T * min(top_k, held.count) // rows)
+    cot = jax.random.normal(jax.random.PRNGKey(0), (passes, rows))
+
+    def gates_of(pairs):
+        return jnp.sum(jnp.where(pairs.valid, pairs.gate * cot, 0))
+
+    @partial(jax.checkpoint, policy=jax.checkpoint_policies.
+             save_only_these_names(names.RES_MOE_KTH, names.RES_MOE_LAST))
+    def kept(logits):
+        here, gates = moe.route(logits, jnp.eye(E), bias, top_k, 2.5, held)
+        return gates_of(_looked_up(moe.held_pairs(here, gates, rows, passes), T))
+
+    def listed(logits):
+        return gates_of(_chosen_list_pairs(jax.nn.sigmoid(logits), bias, top_k,
+                                           2.5, held, rows, passes)[1])
+
+    residuals = sorted((aval.shape, str(aval.dtype))
+                       for aval, why in saved_residuals(kept, logits)
+                       if not why.startswith(("from the argument",
+                                               "from a constant")))
+    assert residuals == [((T, 1), "float32"), ((T, 1), "int32")]
+    # one `top_k`, in the forward: the backward reads the two columns
+    assert _count(jax.make_jaxpr(jax.grad(kept))(logits).jaxpr,
+                  lambda e: e.primitive.name == "top_k") == 1
+    d_kept, d_listed = jax.grad(kept)(logits), jax.grad(listed)(logits)
+    assert float(jnp.max(jnp.abs(d_listed))) > 0.01
+    np.testing.assert_allclose(d_kept, d_listed, rtol=1e-6, atol=1e-6)
+
+
+_ADMITS = {"nothing": 0, "the_routing": 3, "what_the_cell_keeps": 6,
+           "every_name": None}
+
+
+@pytest.mark.parametrize("admits", list(_ADMITS))
+def test_the_hybrids_loss_and_every_gradient_equal_whatever_is_saved(admits):
+    """The whole hybrid at tiny sizes in bf16, `remat=True` with a chip stated
+    that has room for the first n candidates of the rule's order — none; Δ,
+    the `top_k`'s columns and the scores; six of them — and `remat=False`
+    (every name): the same loss and gradients bit for bit as whole-block
+    remat with no chip stated, and the decision recorded says what was kept
+    and which phase of the backward left the budget."""
+    cfg = nh.nemotron_h_tiny(remat=admits != "every_name")
+    params = nh.init(cfg, jax.random.PRNGKey(23))
+    tokens, targets = _batch(cfg)
+    base, kinds = nh.kind_shards(cfg, 2, cfg.seq_len, None)
+    runs = gpt2.pattern_groups(cfg.pattern) + gpt2.pattern_groups(cfg.mtp_pattern)
+    ranked = sorted(((c, k.applications) for k in kinds.values()
+                     for c in k.candidates),
+                    key=lambda cn: -cn[0].flops / cn[0].nbytes)
+    phase = max(gpt2.backward_phases(base, kinds, runs), key=lambda p: p.nbytes)
+    limit = None
+    if cfg.remat:
+        limit = (gpt2.REMAT_RESERVE_BYTES + phase.nbytes + 12345 + sum(
+            n * c.nbytes for c, n in ranked[:_ADMITS[admits]]))
+
+    def loss(p, cfg=cfg, limit=limit):
+        with mesh_lib.chip_memory(limit, 12345):
+            return nh.loss_fn(p, tokens, targets, cfg)
+
+    got = jax.jit(jax.value_and_grad(loss))(params)
+    want = jax.jit(jax.value_and_grad(
+        lambda p: loss(p, nh.nemotron_h_tiny(remat=True), None)))(params)
+    assert float(got[0]) == float(want[0])
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got[1]),
+                            jax.tree.leaves(want[1])):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32),
+                                      err_msg=jax.tree_util.keystr(path))
+    if cfg.remat:
+        (d,) = [d for d in gpt2.remat_policy_decisions()
+                if d["bytes_limit"] == limit and d["seq"] == cfg.seq_len]
+        assert d["saved"] == [n for c, _ in ranked[:_ADMITS[admits]]
+                              for n in c.names]
+        assert (d["phase"], d["phase_bytes"]) == phase
+        assert d["saved_bytes"] <= d["budget_bytes"]
 
 
 def test_balancing_the_selection_bias_evens_the_load_and_changes_nothing_else():

@@ -33,6 +33,23 @@ def _shm_path(ray, ref):
     return os.path.join(shm_store.session_dir(core.session), ref.id.hex())
 
 
+def test_a_ref_finalised_under_the_registrys_lock_does_not_wait_for_itself():
+    """The collector can run `ObjectRef.__del__` on the thread that stands in
+    `ObjectRef.__init__`'s critical section (ROADMAP D18: a whole tier-1 run
+    hung there, in `Dataset.random_shuffle`'s submit): the registry's lock is
+    re-entrant, the finaliser counts the ref out and returns."""
+    from ray_tpu.core import refs
+    from ray_tpu.core.ids import ObjectID
+
+    ref = refs.ObjectRef(ObjectID.from_random())
+    key = ref.binary()
+    assert refs.local_ref_count(key) == 1
+    with refs._reg_lock:
+        del ref
+    assert refs.local_ref_count(key) == 0
+    assert key not in refs.live_refs()
+
+
 def test_put_object_freed_after_last_ref(ray2):
     ray = ray2
     big = np.ones(1_000_000)  # 8 MB → shm, not inline

@@ -148,6 +148,73 @@ def test_rule_on_the_cells_shards_byte_for_byte():
         - gpt2.rematted_working_set(EVA, EVA_LAYERS) + k.frees)
 
 
+@pytest.mark.parametrize("shard, n_layer", [
+    (XL, XL_LAYERS), (SMALL, SMALL_LAYERS), (EVA, EVA_LAYERS)],
+    ids=["gpt2-xl-shard", "gpt2-124m", "evabyte"])
+def test_one_kind_in_one_scan_has_one_phase_and_it_is_the_sum(shard, n_layer):
+    """PR 42: the working set follows the backward's phases. A model of one
+    kind in one scan has the head's phase and the scan's; the scan's — every
+    block input, the head's terms, the gathered embedding and a block — is
+    `rematted_working_set` to the byte, whatever its layers' gradients take,
+    and the head's is that less the block (and the gradients not yet made)."""
+    kind = gpt2.KindShard(n_layer, tuple(gpt2.remat_candidates(shard)),
+                          gpt2.block_working_set(shard))
+    want = gpt2.rematted_working_set(shard, n_layer)
+    for grad_bytes in (0, 123_456_789):
+        head, scan = gpt2.backward_phases(
+            shard, {"block": kind._replace(grad_bytes=grad_bytes)},
+            [(("block",), n_layer)])
+        assert scan == (f"{n_layer} x scan(block)", want)
+        assert head == ("head", want - kind.block_bytes - n_layer * grad_bytes)
+
+
+def _two_runs():
+    """Two kinds in two runs — a scan of four `a` whose stacked gradients are
+    large, then one `b` whose block is — on a shard whose head is large."""
+    C, K = gpt2.RematCandidate, gpt2.KindShard
+    shard = gpt2.BlockShard(batch=1, seq=64, d_model=32, heads=1, head_dim=32,
+                            d_ff=64, vocab=4096, dtype_bytes=2, flash=False,
+                            dense_mlp=False)
+    kinds = {"a": K(4, (C(("x",), 1_000, 4_000_000),), 50_000, 400_000),
+             "b": K(1, (C(("y",), 1_000, 2_000_000),), 900_000, 10_000)}
+    return shard, kinds, [("a", 4), ("b", 1)]
+
+
+def test_phases_count_what_is_live_together_and_no_more():
+    """Each run's phase from its parts: the block inputs that still wait, the
+    run's block, the gathered embedding, the head's terms in the last run
+    alone, less the gradients of the runs before it — which the resident
+    bytes count from the step's start and which are not made yet."""
+    shard, kinds, runs = _two_runs()
+    x = 64 * 32 * 2
+    model = gpt2.model_working_set(shard, 5)
+    head_terms = model - gpt2.model_working_set(shard._replace(vocab=0), 5) \
+        - 4096 * 32 * 6
+    assert gpt2.backward_phases(shard, kinds, runs) == [
+        ("head", model - 4 * 400_000 - 10_000),
+        ("b", model + 900_000 - 4 * 400_000),
+        ("4 x scan(a)", model - x - head_terms + 50_000)]
+
+
+def test_two_runs_whose_sum_does_not_fit_and_whose_largest_phase_does():
+    """The chip holds the state, every gradient and the LARGER phase with
+    room for both names; with the phases summed — every gradient beside the
+    head's terms and the largest block, as the rule counted until PR 42 —
+    it would hold neither."""
+    shard, kinds, runs = _two_runs()
+    phases = gpt2.backward_phases(shard, kinds, runs)
+    largest = max(p.nbytes for p in phases)
+    summed = gpt2.model_working_set(shard, 5) + 900_000
+    resident = 3 * (4 * 400_000 + 10_000)
+    limit = gpt2.REMAT_RESERVE_BYTES + resident + largest + 5_000
+    assert summed > largest + 5_000
+    kept = gpt2.choose_remat_policy_kinds(
+        tuple(kinds.values()), largest, limit, resident)
+    assert kept == gpt2.RematPolicy(("x", "y"), 5_000, 5_000, limit)
+    assert gpt2.choose_remat_policy_kinds(
+        tuple(kinds.values()), summed, limit, resident).saved == ()
+
+
 @pytest.mark.parametrize("block, rows", [
     # batch, seq, d_model, d_ff, bytes an element
     ((8, 1024, 768, 3072, 2), 1024),         # gpt2-124m's sizes: 50 MB hidden
