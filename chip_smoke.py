@@ -127,7 +127,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     import numpy as np
 
     from ray_tpu import train
-    from ray_tpu.models.gpt2 import (
+    from ray_tpu.models.blocks import (
         compiler_rematerialized, remat_policy_decisions)
     from ray_tpu.ops.attention import flash_tiling_decisions, resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
@@ -251,7 +251,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     hybrid = None
     if config.get("hybrid_model") is not None:
         from ray_tpu.models import nemotron_h
-        from ray_tpu.models.gpt2 import layer_pattern_decisions
+        from ray_tpu.models.blocks import layer_pattern_decisions
         from ray_tpu.ops.mamba2 import ssd_tiling_decisions
 
         hybrid_cfg = config["hybrid_model"]

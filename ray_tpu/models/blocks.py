@@ -1,0 +1,306 @@
+"""What every model of ``ray_tpu.models`` runs its layers on, and nothing of
+any one model: the layer loop over a PATTERN of layer kinds (a run of a repeated
+sub-pattern is one ``lax.scan`` over the kinds' stacked parameters) and the
+half of the remat rule that is about the STEP — the backward's phases, the
+ONE choice of what ``remat=True`` keeps over all the kinds' applications, its
+``model/remat_policy`` event and the policy-``checkpoint`` of each kind.
+
+A model states its layers as KindShards (how often a kind is applied, what a
+layer of it may keep, what its backward holds) and its own share of the step
+as a shard: any tuple with ``batch``, ``seq``, ``d_model``, ``dtype_bytes``,
+``vocab``, ``head_rows`` and ``mlp_rows`` (parts.BlockShard is one). Nothing
+here reads more of it: no mixer, no MLP is named in this file, and it imports
+no other module of ``ray_tpu.models``.
+"""
+
+from __future__ import annotations
+
+import re
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
+
+import jax
+from jax import lax
+
+from ray_tpu.tracing import get_buffer, names as scopes
+
+
+class RematCandidate(NamedTuple):
+    names: Tuple[str, ...]   # residuals kept together
+    nbytes: int              # what they take, a layer
+    flops: int               # what making them again costs, a layer
+    frees: int = 0           # bytes of the step's working set that are there
+                             # only while these are made again, not kept
+
+
+class RematPolicy(NamedTuple):
+    saved: Tuple[str, ...]   # names.RESIDUALS a block keeps, in the order taken
+    saved_bytes: int         # what they take on a chip, over n_layer layers
+    budget_bytes: int        # what was free for them, with what keeping them
+                             # freed (0: no limit is known)
+    bytes_limit: int         # the chip's own figure the budget came from, or 0
+
+
+# what the rule leaves free: the benchmark's fit rule keeps the same
+# (benchmarks/README.md), and the working-set arithmetic below is an estimate
+REMAT_RESERVE_BYTES = 2 ** 30
+
+Shard = Any     # the model's share of a step on one chip (module docstring)
+
+_decisions: Dict[tuple, Dict[str, Any]] = {}
+_patterns: Dict[str, Dict[str, Any]] = {}
+
+
+class KindShard(NamedTuple):
+    """What the remat rule needs of one kind of layer on one chip: how often
+    the kind is applied, what a block of it may keep (parts.remat_candidates,
+    or the kind's own arithmetic), what its whole residual set takes while its
+    backward runs (parts.block_working_set), and the bytes of one application's
+    weight gradients (backward_phases takes those not made yet off a run's
+    phase; a model of one run of layers has none to take off and states 0)."""
+    applications: int
+    candidates: Tuple[RematCandidate, ...]
+    block_bytes: int
+    grad_bytes: int = 0
+
+
+# a run of the layers as pattern_groups names it: the kinds of its repeated
+# sub-pattern (keys of the model's KindShards) and the repeats
+Run = Tuple[Sequence[str], int]
+
+
+class Phase(NamedTuple):
+    name: str                # "head", or the run as model/layer_pattern has it
+    nbytes: int              # the fully rematted step's working set, in it
+
+
+def run_name(run: Run) -> str:
+    sub, reps = run
+    return f"{reps} x scan({''.join(sub)})" if reps > 1 else "".join(sub)
+
+
+def model_working_set(s: Shard, n_layer: int) -> int:
+    """parts.rematted_working_set's part that no block decides: the stack of
+    ``n_layer`` block inputs, the LM head's logits, the gathered embedding."""
+    return n_layer * _block_input(s) + _head_terms(s) + _gathered(s)
+
+
+def _block_input(s: Shard) -> int:
+    return s.batch * s.seq * s.d_model * s.dtype_bytes
+
+
+def _head_terms(s: Shard) -> int:
+    a = s.dtype_bytes
+    head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
+    if s.head_rows:
+        # a head in chunks makes its gradient in the forward and keeps it
+        # (ops/cross_entropy.chunked_head_xent): d x stands where the chunked
+        # x stood, the running float32 d lm_head is new
+        head += s.d_model * s.vocab * 4
+    return head
+
+
+def _gathered(s: Shard) -> int:
+    return s.vocab * s.d_model * (s.dtype_bytes + 4)
+
+
+def backward_phases(s: Shard, kinds: Dict[str, KindShard],
+                    runs: Sequence[Run]) -> List[Phase]:
+    """The fully rematted step's working set by the phases of its backward,
+    in the order it meets them: the head's, then every run of the layers from
+    the last to the first. The step needs the LARGEST of them, not their sum.
+    In a run's phase stand the block inputs that still wait (its own and those of
+    the runs before it), the largest block of the run's kinds, the gathered
+    embedding, and — in the last run, whose backward starts on them — the
+    head's terms; what does NOT stand there yet are the weight gradients of
+    the runs before it, which the step's resident bytes count from the start.
+    A run's own gradients all count: a scan writes its stacked gradients from
+    the moment its backward starts. A model of one kind in one scan has one
+    such phase, parts.rematted_working_set to the byte (its head phase is
+    that less the block). The MEMEMEMEM*E + *E hybrid's head, MTP module
+    and last five layers are dead by the time the scan of eight writes 1.7 GiB
+    of gradients: summed, the estimate stood 2.5 GiB over the compiled step
+    (PERF.md §6, PR 42)."""
+    layers = [reps * len(sub) for sub, reps in runs]
+    grads = [reps * sum(kinds[k].grad_bytes for k in sub) for sub, reps in runs]
+    phases = [Phase("head", model_working_set(s, sum(layers)) - sum(grads))]
+    for i in reversed(range(len(runs))):
+        live = (sum(layers[:i + 1]) * _block_input(s) + _gathered(s)
+                + max(kinds[k].block_bytes for k in runs[i][0])
+                - sum(grads[:i]))
+        if i == len(runs) - 1:
+            live += _head_terms(s)
+        phases.append(Phase(run_name(runs[i]), live))
+    return phases
+
+
+def choose_remat_policy_kinds(kinds: Sequence[KindShard], working_set: int,
+                              bytes_limit: Optional[int],
+                              resident_bytes: int) -> RematPolicy:
+    """THE rule for what ``remat=True`` keeps besides each block's input, for
+    layers of any number of kinds: walk every kind's candidates (most
+    recompute FLOPs per byte first) and take each whose copies — one an
+    application of its kind — still fit what the chip has free: its
+    bytes_limit less the reserve, what is resident (state and gradients) and
+    the fully rematted step's ``working_set`` (the largest of
+    backward_phases), plus what keeping it frees of that set. With no limit
+    stated, nothing."""
+    if bytes_limit is None:
+        return RematPolicy((), 0, 0, 0)
+    budget = bytes_limit - REMAT_RESERVE_BYTES - resident_bytes - working_set
+    ranked = sorted(((c, k.applications) for k in kinds for c in k.candidates),
+                    key=lambda cn: (-cn[0].flops / cn[0].nbytes, -cn[0].frees))
+    saved, used = [], 0
+    for c, n in ranked:
+        if used + n * c.nbytes <= budget + c.frees:
+            saved.extend(c.names)
+            used += n * c.nbytes
+            budget += c.frees
+    return RematPolicy(tuple(saved), used, max(0, budget), bytes_limit)
+
+
+def remat_policy_decisions() -> List[Dict[str, Any]]:
+    """Every distinct remat decision this process has traced a model with, as
+    the ``model/remat_policy`` events carry them."""
+    return list(_decisions.values())
+
+
+def compiler_rematerialized(hlo: str) -> List[str]:
+    """The instructions of a compiled step (``compiled.as_text()``) that
+    XLA's own rematerialization pass made: it clones what it frees early and
+    marks the clone's name ``.remat``. Each is recompute the rule did not
+    choose — the budget it spent was not there (PERF.md §6, PR 32)."""
+    return re.findall(r"^\s*(?:ROOT )?%?(\S*\.remat\S*) = ", hlo, re.M)
+
+
+def _remat_policy(shard: Shard, kinds: Dict[str, KindShard],
+                  runs: Sequence[Run]) -> RematPolicy:
+    """choose_remat_policy_kinds for the step being traced, recorded. A static
+    choice has no hit rate; its counter is the choice: each distinct one goes
+    once, as an instant event, to the task-event buffer
+    (→ ``ray_tpu.timeline()``), with the phase of the backward that set the
+    working set and its bytes. ``shard`` is the model's: the stream, the
+    head and the rows the head and the MLP take at a time."""
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    n_layer = sum(k.applications for k in kinds.values())
+    phase = max(backward_phases(shard, kinds, runs), key=lambda p: p.nbytes)
+    policy = choose_remat_policy_kinds(
+        tuple(kinds.values()), phase.nbytes, *mesh_lib.current_chip_memory())
+    args = dict(zip(scopes.REMAT_POLICY_ARGS,
+                    (n_layer, shard.batch, shard.seq, list(policy.saved))
+                    + policy[1:] + (shard.mlp_rows or shard.seq,
+                                    shard.head_rows or shard.seq) + phase))
+    key = (shard, tuple(kinds.items()), tuple(runs)) + policy
+    if key not in _decisions:
+        _decisions[key] = args
+        component, name = scopes.REMAT_POLICY.split("/")
+        get_buffer().record_profile(name, component=component, args=args)
+    return policy
+
+
+def checkpoint_kinds(block_fns: Dict[str, Callable], remat: bool,
+                     shard: Shard, kinds: Dict[str, KindShard],
+                     runs: Sequence[Run]) -> Dict[str, Callable]:
+    """Each kind's ``block_fn(x, layer_params)`` as run_pattern calls it: a
+    policy-``checkpoint`` that keeps the block's input and, of the named
+    residuals (tracing/names.RESIDUALS), those the ONE rule gave room —
+    over all the kinds' applications together, in the largest phase of the
+    backward over ``runs`` (every run of the layers the step applies, in the
+    forward's order) — with ``remat`` and all of them without."""
+    saved = (_remat_policy(shard, kinds, runs).saved if remat
+             else scopes.RESIDUALS)
+    policy = jax.checkpoint_policies.save_only_these_names(*saved)
+    return {kind: jax.checkpoint(fn, policy=policy)
+            for kind, fn in block_fns.items()}
+
+
+def pattern_groups(pattern: str) -> List[Tuple[str, int]]:
+    """A pattern of layer kinds, one character a layer, as runs of a repeated
+    sub-pattern: ``"MEMEMEMEM*E"`` → ``[("ME", 4), ("M", 1), ("*", 1),
+    ("E", 1)]``, twelve layers of one kind → ``[("B", 12)]``. Greedy from the
+    left: the repeat that covers most layers, of equal ones the shortest
+    sub-pattern."""
+    groups, i = [], 0
+    while i < len(pattern):
+        best = (pattern[i], 1)
+        for width in range(1, (len(pattern) - i) // 2 + 1):
+            sub, reps = pattern[i:i + width], 1
+            while pattern.startswith(sub, i + reps * width):
+                reps += 1
+            if reps > 1 and reps * width > best[1] * len(best[0]):
+                best = (sub, reps)
+        groups.append(best)
+        i += best[1] * len(best[0])
+    return groups
+
+
+def run_pattern(block_fns: Dict[str, Callable], pattern: str, x,
+                stacks: Sequence[Dict[str, Any]], with_aux: bool = False):
+    """x through ``pattern``'s layers, one character a layer: kind ``c`` is
+    ``block_fns[c](x, layer_params)``. ``stacks`` holds the parameters, one
+    entry a run of pattern_groups(pattern): ``stacks[g][c]`` stacks the
+    run's layers of kind ``c`` in the order they come. A run of a repeated
+    sub-pattern is ONE ``lax.scan`` over its own stacks, whose body holds the
+    sub-pattern's layers — compile time and program size follow the number of
+    distinct runs, not the depth, and no stack is sliced or copied; a layer
+    outside any repeat is applied where it stands.
+
+    ``with_aux``: every block function returns ``(x, aux)`` and the result is
+    ``(x, auxes)``, ``auxes[g][i]`` the aux of the i-th layer of run g's
+    sub-pattern (stacked over the repeats where the run is a scan)."""
+    auxes = []
+    for (sub, reps), group in zip(pattern_groups(pattern), stacks, strict=True):
+        per_rep = {kind: sub.count(kind) for kind in dict.fromkeys(sub)}
+        xs = {kind: group[kind] if n == 1 else jax.tree.map(
+            lambda a, n=n: a.reshape((reps, n) + a.shape[1:]), group[kind])
+            for kind, n in per_rep.items()}
+
+        def body(x, layer_params, sub=sub, per_rep=per_rep):
+            seen = {kind: 0 for kind in per_rep}
+            aux = []
+            for kind in sub:
+                p = layer_params[kind]
+                if per_rep[kind] > 1:
+                    p = jax.tree.map(lambda a: a[seen[kind]], p)
+                seen[kind] += 1
+                x = block_fns[kind](x, p)
+                if with_aux:
+                    x, a = x
+                    aux.append(a)
+            return x, (aux if with_aux else None)
+
+        if reps > 1:
+            x, aux = lax.scan(body, x, xs)
+        else:
+            x, aux = body(x, jax.tree.map(lambda a: a[0], xs))
+        auxes.append(aux)
+    return (x, auxes) if with_aux else x
+
+
+def record_layer_pattern(pattern: str) -> None:
+    """The ``model/layer_pattern`` event of a model whose layers are of more
+    than one kind: the pattern, how often each kind is applied and which
+    runs are one scan; once per distinct pattern, at trace time."""
+    if pattern in _patterns:
+        return
+    groups = pattern_groups(pattern)
+    _patterns[pattern] = dict(zip(scopes.LAYER_PATTERN_ARGS, (
+        pattern, {kind: pattern.count(kind) for kind in dict.fromkeys(pattern)},
+        [run_name(run) for run in groups])))
+    component, name = scopes.LAYER_PATTERN.split("/")
+    get_buffer().record_profile(name, component=component,
+                                args=_patterns[pattern])
+
+
+def layer_pattern_decisions() -> List[Dict[str, Any]]:
+    """Every distinct pattern this process has traced a model with, as the
+    ``model/layer_pattern`` events carry them."""
+    return list(_patterns.values())
+
+
+def run_blocks(block_fn, x, layers):
+    """x through the blocks whose parameters are stacked in ``layers``: the
+    one-kind case of run_pattern."""
+    n_layer = jax.tree.leaves(layers)[0].shape[0]
+    return run_pattern({"B": block_fn}, "B" * n_layer, x, [{"B": layers}])

@@ -2,10 +2,10 @@
 
 Second flagship model family beside GPT-2 (models/gpt2.py): the modern
 decoder recipe — RMSNorm (pre-norm, no biases), SwiGLU MLP, rotary position
-embeddings, grouped-query attention, untied LM head. It runs on GPT-2's
-machinery, not a copy of it: the same layer scan and policy-``checkpoint``
-(``gpt2._run_blocks`` / ``_checkpointed``), the same remat rule on this
-block's own shapes, the same scopes and residual names (tracing/names.py);
+embeddings, grouped-query attention, untied LM head. It runs on what every
+model here runs on, not a copy of it: the layer scan (``blocks.run_blocks``),
+the policy-``checkpoint`` and the remat rule on this block's own shapes and
+the shared parts (models/parts.py), tracing/names.py's scopes and residuals;
 logical axis names on every parameter so any dp/fsdp/tp mesh works through
 parallel/sharding.py rules, bf16 compute over f32 params, the Pallas kernels
 in head-major layout.
@@ -35,18 +35,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.models import gpt2
-from ray_tpu.models.gpt2 import _round_up
+from ray_tpu.models import parts
+from ray_tpu.models.blocks import run_blocks
 from ray_tpu.tracing import names as scopes
-
-# the head's float32 logits of one sequence chunk stay under this
-# (_head_rows): [B, S, V] whole is 4.2 GB at llama_7b's 8 x 4,096 x 32,000
-_HEAD_CHUNK_BYTES = 2 ** 26
-# an MLP whose hidden tensor of the whole sequence passes this takes the
-# sequence in chunks (_mlp_rows): a SwiGLU's backward holds five of them —
-# 3.6 GB at 32,768 x 11,008, which one chip does not have beside EvaByte's
-# state
-_MLP_CHUNK_BYTES = 2 ** 28
 
 
 @dataclass(frozen=True)
@@ -63,7 +54,7 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False           # as GPT2Config.remat: True recomputes what
-                                  # does not fit (gpt2.choose_remat_policy)
+                                  # does not fit (parts.choose_remat_policy)
     attention_impl: str = "auto"  # auto | xla | pallas
     mixer: str = "causal"         # causal | eva (window, chunk)
     window: int = 0
@@ -101,13 +92,14 @@ class LlamaConfig:
 
     @property
     def padded_vocab(self) -> int:
-        return _round_up(self.vocab_size, 128)
+        return parts.round_up(self.vocab_size, 128)
 
     @property
     def head_vocab(self) -> int:
         """Columns of one prediction head: the vocabulary padded so that the
         heads' one matmul is a whole number of 128-lane tiles wide."""
-        return _round_up(self.vocab_size, 128 // math.gcd(self.n_pred_heads, 128))
+        return parts.round_up(self.vocab_size,
+                              128 // math.gcd(self.n_pred_heads, 128))
 
 
 def llama_tiny(**overrides) -> LlamaConfig:
@@ -117,11 +109,6 @@ def llama_tiny(**overrides) -> LlamaConfig:
                     n_kv_head=2, d_model=64, d_ff=176),
         **overrides,
     )
-
-
-def llama_1b(**overrides) -> LlamaConfig:
-    """TinyLlama-1.1B shape."""
-    return replace(LlamaConfig(), **overrides)
 
 
 def llama_7b(**overrides) -> LlamaConfig:
@@ -234,124 +221,49 @@ def init(cfg: LlamaConfig, rng: jax.Array) -> Dict[str, Any]:
 
 
 def param_count(cfg: LlamaConfig) -> int:
-    import numpy as np
-
-    return sum(
-        int(np.prod(p.shape))
-        for p in jax.tree.leaves(
-            jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
-        )
-    )
+    return sum(math.prod(p.shape) for p in jax.tree.leaves(
+        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))))
 
 
 # --------------------------------------------------------------------------- #
 # Forward
 # --------------------------------------------------------------------------- #
 
-def _rmsnorm(x, g, cfg: LlamaConfig):
-    xf = x.astype(jnp.float32)
-    rms = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + cfg.rms_eps)
-    scale = 1.0 + g.astype(jnp.float32) if cfg.norm_unit_offset else g
-    return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
-
-
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding, HF-llama convention: x [..., S, hd] with the head
-    dim split as [first half, second half] (rotate_half), NOT interleaved."""
-    hd = x.shape[-1]
-    half = hd // 2
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
-    )
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
-    # x·cos + rotate_half(x)·sin, rotate_half(x) = [-x2, x1] = x @ R with R a
-    # signed permutation (exact in any dtype): a [hd, hd] matmul a head, 0.5 %
-    # of a block's operations, where slicing the head dim in two makes
-    # tensors of half a head — 64 of 128 lanes, each taking what a whole one
-    # does; four stood in HBM in the 32,768-token backward
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)             # [S, hd]
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
-    eye = jnp.eye(half, dtype=x.dtype)
-    zero = jnp.zeros_like(eye)
-    rot = jnp.block([[zero, eye], [-eye, zero]])                      # x @ rot
-    rotated = jnp.einsum("...d,de->...e", x, rot)
-    return (x.astype(jnp.float32) * cos
-            + rotated.astype(jnp.float32) * sin).astype(x.dtype)
-
-
-def _residual_add(x, y):
-    """x + y in float32, the stream stored in x's dtype (the released
-    EvaByte's ``fp32_skip_add``; y is a matmul's float32 accumulator)."""
-    return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype)
+def _norm(x, g, cfg: LlamaConfig):
+    return parts.rmsnorm(x, g, cfg.rms_eps, cfg.norm_unit_offset)
 
 
 def _attention(q, k, v, p, cfg: LlamaConfig):
     """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd]: the config's mixer."""
-    from ray_tpu.ops.attention import flash_attention_sharded, resolve_attention
-    from ray_tpu.parallel import mesh as mesh_lib
+    if cfg.mixer != "eva":
+        return parts.causal_attention(q, k, v, cfg.attention_impl)
+    from ray_tpu.ops import eva_attention as eva
 
-    mesh = mesh_lib.current_mesh()
-    impl, interpret = resolve_attention(cfg.attention_impl, mesh)
-    if impl == "ring":
-        raise NotImplementedError(
-            "models/llama.py has no ring-attention path; use a mesh without "
-            "a cp axis"
-        )
-    if cfg.mixer == "eva":
-        from ray_tpu.ops import eva_attention as eva
-
-        phi, mu = p["eva_phi"], p["eva_mu"]
-        if impl == "pallas":
-            return eva.eva_attention_sharded(
-                q, k, v, phi, mu, mesh, window=cfg.window, chunk=cfg.chunk)
-        return eva.eva_attention_xla(
-            q, k, v, phi, mu, window=cfg.window, chunk=cfg.chunk)
-    groups = cfg.n_head // cfg.n_kv_head
-    if groups > 1:
-        k = jnp.repeat(k, groups, axis=1)
-        v = jnp.repeat(v, groups, axis=1)
+    impl, _, mesh = parts.attention_on_mesh(cfg.attention_impl)
+    phi, mu = p["eva_phi"], p["eva_mu"]
     if impl == "pallas":
-        return flash_attention_sharded(
-            q, k, v, mesh, causal=True, interpret=interpret
-        )
-    S = q.shape[2]
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    mask = jnp.tril(jnp.ones((S, S), dtype=bool))
-    logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        return eva.eva_attention_sharded(
+            q, k, v, phi, mu, mesh, window=cfg.window, chunk=cfg.chunk)
+    return eva.eva_attention_xla(
+        q, k, v, phi, mu, window=cfg.window, chunk=cfg.chunk)
 
 
 _MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-
-
-def _cast_in_the_loop(p, x, dt, keys=_MATMUL_WEIGHTS):
-    """The layer's matmul weights in the compute dtype, cast inside the layer
-    loop. A plain ``astype`` of a layer sliced out of the stack the TPU
-    compiler turns into one cast of the WHOLE stack before the loop (through
-    an ``optimization_barrier`` too) and keeps the copy for the length of the
-    step: 1.5 GB beside EvaByte's four layers, which the chip does not have.
-    A factor of one that depends on the loop's carry keeps the cast where it
-    is written; it costs a read of the layer's f32 weights a use, 0.6 % of
-    the 32,768-token step."""
-    one = lax.stop_gradient(1.0 + 0.0 * x[0, 0, 0].astype(jnp.float32))
-    return {k: (p[k] * one).astype(dt) for k in keys}
 
 
 @jax.named_scope(scopes.BLOCK)
 def _block(x, p, cfg: LlamaConfig):
     """One block, x [B, S, D], under GPT-2's scopes and residual names."""
     positions = jnp.arange(x.shape[1])
-    p = {**p, **_cast_in_the_loop(p, x, cfg.dtype)}
+    p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype, _MATMUL_WEIGHTS)}
     with jax.named_scope(scopes.LN1):
-        h = _rmsnorm(x, p["attn_norm"], cfg)
+        h = _norm(x, p["attn_norm"], cfg)
     with jax.named_scope(scopes.QKV):
         # named after the rotation: a kept q or k is not rotated again
-        q = checkpoint_name(_rope(
+        q = checkpoint_name(parts.rope(
             jnp.einsum("bsd,dhk->bhsk", h, p["wq"]),
             positions, cfg.rope_theta), scopes.RES_Q)
-        k = checkpoint_name(_rope(
+        k = checkpoint_name(parts.rope(
             jnp.einsum("bsd,dhk->bhsk", h, p["wk"]),
             positions, cfg.rope_theta), scopes.RES_K)
         v = checkpoint_name(
@@ -359,7 +271,7 @@ def _block(x, p, cfg: LlamaConfig):
     with jax.named_scope(scopes.ATTN):
         attn = _attention(q, k, v, p, cfg)
     with jax.named_scope(scopes.PROJ):
-        x = checkpoint_name(_residual_add(x, jnp.einsum(
+        x = checkpoint_name(parts.residual_add(x, jnp.einsum(
             "bhsk,hkd->bsd", attn, p["wo"],
             preferred_element_type=jnp.float32)), scopes.RES_MID)
     return _mlp(x, p, cfg)
@@ -368,22 +280,22 @@ def _block(x, p, cfg: LlamaConfig):
 def _swiglu(x, p, cfg: LlamaConfig):
     """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]."""
     with jax.named_scope(scopes.LN2):
-        h = _rmsnorm(x, p["mlp_norm"], cfg)
+        h = _norm(x, p["mlp_norm"], cfg)
     with jax.named_scope(scopes.MLP):
         gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
                                scopes.RES_MLP_GATE)
         up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
                              scopes.RES_MLP_UP)
-        return _residual_add(x, jnp.einsum(
+        return parts.residual_add(x, jnp.einsum(
             "bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
             preferred_element_type=jnp.float32))
 
 
 def _mlp(x, p, cfg: LlamaConfig):
     """The block's second half, norm and all. Where one hidden tensor of the
-    whole sequence would pass _MLP_CHUNK_BYTES the sequence goes through in
-    chunks (_mlp_rows), each its own ``checkpoint``: a chunk's hidden tensors
-    are made again in its backward and never exist for the whole sequence
+    whole sequence would pass parts.MLP_CHUNK_BYTES the sequence goes in
+    chunks (parts.mlp_rows), each its own ``checkpoint``: a chunk's hidden
+    tensors are made again in its backward, never exist for the whole sequence
     (nor can a remat policy keep them: llama.block_shard tells the rule so).
     The norm is the chunk's too — a row's norm needs the row alone — so the
     loop's one input is the stream itself: the normed stream and its gradient
@@ -392,7 +304,7 @@ def _mlp(x, p, cfg: LlamaConfig):
     where q was, the step fits without the compiler making k and v a second
     time in every layer (PERF.md §6, PR 32)."""
     B, S, D = x.shape
-    rows = _mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
+    rows = parts.mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
     if rows == S:
         return _swiglu(x, p, cfg)
     chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
@@ -400,59 +312,24 @@ def _mlp(x, p, cfg: LlamaConfig):
     return out.swapaxes(0, 1).reshape(B, S, D)
 
 
-def _rows_under(seq: int, bytes_a_row: int, limit: int) -> int:
-    """The largest power-of-two fraction of ``seq`` whose rows stay under
-    ``limit`` bytes (``seq`` itself where they do)."""
-    rows = seq
-    while rows % 2 == 0 and rows * bytes_a_row > limit:
-        rows //= 2
-    return rows
-
-
-def _mlp_rows(batch: int, seq: int, d_model: int, d_ff: int,
-              itemsize: int) -> int:
-    """Rows of the sequence the MLP takes at a time: all of them where a
-    hidden tensor of the whole sequence stays under _MLP_CHUNK_BYTES. A longer
-    sequence goes in chunks whose five hidden tensors together take what two
-    of the block's [B, S, D] activations do — a fifth more beside the eight
-    of that size that wait in the chunk's backward for the attention's
-    (gpt2.rematted_working_set). On the chip the 32,768-byte EvaByte step's
-    MLP backward takes 403.6 ms at the 4,096 rows this gives, 405.1 at 2,048
-    and 420.7 at 8,192, and its compiled step needs 0.7 GB less than at 8,192
-    (PERF.md §6, PR 32)."""
-    if batch * seq * d_ff * itemsize <= _MLP_CHUNK_BYTES:
-        return seq
-    return _rows_under(seq, 5 * batch * d_ff * itemsize,
-                       2 * batch * seq * d_model * itemsize)
-
-
-def _head_rows(batch: int, seq: int, columns: int, heads: int) -> int:
-    """Rows of the sequence the head takes at a time where it goes in chunks
-    — their float32 logits stay under _HEAD_CHUNK_BYTES; more heads than one
-    always do — and 0 where one head takes the sequence whole (softmax_xent)."""
-    rows = _rows_under(seq, batch * columns * 4, _HEAD_CHUNK_BYTES)
-    return rows if heads > 1 or rows < seq else 0
-
-
 def block_shard(cfg: LlamaConfig, global_batch: int, seq: int,
-                mesh) -> gpt2.BlockShard:
+                mesh) -> parts.BlockShard:
     """This config's block on one chip of ``mesh``, for the remat rule: the
     shapes of ITS residuals (two hidden tensors of d_ff, k and v of n_kv_head
     heads, with eva the window and the chunk the summaries come from)."""
-    from ray_tpu.ops.attention import resolve_attention
-
     columns = cfg.n_pred_heads * cfg.head_vocab
-    return gpt2.shard_block(gpt2.BlockShard(
+    return parts.shard_block(parts.BlockShard(
         batch=global_batch, seq=seq, d_model=cfg.d_model, heads=cfg.n_head,
         head_dim=cfg.head_dim, d_ff=cfg.d_ff, vocab=columns,
         dtype_bytes=jnp.dtype(cfg.dtype).itemsize,
-        flash=resolve_attention(cfg.attention_impl, mesh)[0] == "pallas",
+        flash=parts.is_flash(cfg.attention_impl, mesh),
         dense_mlp=True, kv_heads=cfg.n_kv_head,
         mlp_hidden=(scopes.RES_MLP_GATE, scopes.RES_MLP_UP),
         window=cfg.window if cfg.mixer == "eva" else 0, chunk=cfg.chunk,
-        head_rows=_head_rows(global_batch, seq, columns, cfg.n_pred_heads),
-        mlp_rows=_mlp_rows(global_batch, seq, cfg.d_model, cfg.d_ff,
-                           jnp.dtype(cfg.dtype).itemsize),
+        head_rows=parts.head_rows(global_batch, seq, columns,
+                                  cfg.n_pred_heads),
+        mlp_rows=parts.mlp_rows(global_batch, seq, cfg.d_model, cfg.d_ff,
+                                jnp.dtype(cfg.dtype).itemsize),
         cast_in_loop=True,
     ), mesh)
 
@@ -464,12 +341,12 @@ def _trunk(params, tokens, cfg: LlamaConfig):
     B, S = tokens.shape
     with jax.named_scope(scopes.EMBED):
         x = params["wte"].astype(cfg.dtype)[tokens]
-    block_fn = gpt2._checkpointed(
+    block_fn = parts.checkpoint_block(
         partial(_block, cfg=cfg), cfg.remat,
         block_shard(cfg, B, S, mesh_lib.current_mesh()), cfg.n_layer)
-    x = gpt2._run_blocks(block_fn, x, params["blocks"])
+    x = run_blocks(block_fn, x, params["blocks"])
     with jax.named_scope(scopes.LN_F):
-        return _rmsnorm(x, params["final_norm"], cfg)
+        return _norm(x, params["final_norm"], cfg)
 
 
 def forward(params, tokens, cfg: LlamaConfig) -> jax.Array:
@@ -479,48 +356,12 @@ def forward(params, tokens, cfg: LlamaConfig) -> jax.Array:
     return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype))
 
 
-def head_targets(targets: jax.Array, n_heads: int) -> jax.Array:
-    """targets [B, S] (the next token, -1 = ignore) → [B, S, n_heads]: head
-    p's target at t is targets[t + p], -1 past the row's end."""
-    S = targets.shape[1]
-    padded = jnp.pad(targets, ((0, 0), (0, n_heads - 1)), constant_values=-1)
-    return jnp.stack([padded[:, p:p + S] for p in range(n_heads)], axis=-1)
-
-
-@jax.named_scope(scopes.LM_HEAD_LOSS)
-def _lm_head_loss(x, targets, lm_head, cfg: LlamaConfig) -> jax.Array:
-    """Untied head(s) + cross-entropy over final hidden states [B, S, D]: the
-    mean over the heads of each head's mean over its valid targets. Where the
-    head goes in chunks (_head_rows) the logits and their gradient
-    are never one tensor, and a chunk's logits are multiplied out once a
-    step: the chunk that makes its loss makes its gradient
-    (ops/cross_entropy.chunked_head_xent)."""
-    from ray_tpu.ops import cross_entropy
-
-    B, S = targets.shape
-    P = cfg.n_pred_heads
-    lm_head = lm_head.astype(cfg.dtype)
-    rows = _head_rows(B, S, lm_head.shape[1], P)
-    if not rows:
-        # fused CE (ops/cross_entropy.py): no [B, S, V] float32 residual
-        nll = cross_entropy.softmax_xent(
-            jnp.einsum("bsd,dv->bsv", x, lm_head), targets)
-        return jnp.sum(nll) / jnp.maximum(jnp.sum(targets >= 0), 1)
-    return cross_entropy.chunked_head_xent(x, head_targets(targets, P),
-                                           lm_head, rows)
-
-
 def loss_fn(params, tokens, targets, cfg: LlamaConfig) -> jax.Array:
     """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token):
     with n_pred_heads > 1 head p is scored on targets[t + p]."""
     x = _trunk(params, tokens, cfg)
-    return _lm_head_loss(x, targets, params["lm_head"], cfg)
-
-
-def flops_per_token(cfg: LlamaConfig) -> float:
-    n = param_count(cfg)
-    attn = 12 * cfg.n_layer * cfg.d_model * cfg.seq_len
-    return 6.0 * n + attn
+    return parts.lm_head_loss(x, targets, params["lm_head"], cfg.dtype,
+                              cfg.n_pred_heads)
 
 
 # --------------------------------------------------------------------------- #

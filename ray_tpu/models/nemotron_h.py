@@ -10,18 +10,18 @@ character a layer (the published ``hybrid_override_pattern``):
   experts with a selection bias, the held experts' grouped products inside a
   latent projection, a shared expert beside them);
 - ``*`` — grouped-query causal attention, no positional encoding
-  (llama._attention: the flash kernels).
+  (parts.causal_attention: the flash kernels).
 
 After the trunk, one multi-token-prediction module (``mtp_pattern``, a second
 small trunk fed by the first and by the next token's embedding) predicts the
 token after next through the SAME final norm, embedding and head;
 ``loss = CE_trunk + mtp_loss_weight · CE_mtp``.
 
-It runs on the shared machinery: ``gpt2.run_pattern`` (a run of a repeated
+It runs on the shared machinery: ``blocks.run_pattern`` (a run of a repeated
 sub-pattern is one ``lax.scan`` over the kinds' stacked parameters),
-``gpt2.checkpoint_kinds`` (ONE remat rule over all the kinds' applications),
-llama's RMSNorm, residual add, weight cast inside the loop and chunked head +
-loss; scopes and residual names from tracing/names.py.
+``blocks.checkpoint_kinds`` (ONE remat rule over all the kinds' applications),
+models/parts.py's RMSNorm, residual add, weight cast inside the loop, causal
+attention and chunked head + loss; tracing/names.py's scopes and residuals.
 
 The config states the chip's SHARE of a deployment beside the published
 sizes: how many of the Mamba heads / groups, attention heads, routed experts
@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.models import gpt2, llama
+from ray_tpu.models import blocks, parts
 from ray_tpu.ops import mamba2, moe
 from ray_tpu.tracing import get_buffer, names as scopes
 
@@ -86,11 +86,6 @@ class NemotronHConfig:
     param_dtype: Any = jnp.float32
     remat: bool = False
     attention_impl: str = "auto"
-
-    # what llama's shared functions read of a config
-    mixer = "causal"
-    norm_unit_offset = False
-    n_pred_heads = 1
 
     def __post_init__(self):
         for name in ("pattern", "mtp_pattern"):
@@ -152,9 +147,9 @@ def nemotron_h_tiny(**overrides) -> NemotronHConfig:
 # --------------------------------------------------------------------------- #
 
 def _group_counts(pattern: str):
-    """[{kind: layers of it}] a run of gpt2.pattern_groups(pattern)."""
+    """[{kind: layers of it}] a run of blocks.pattern_groups(pattern)."""
     return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
-            for sub, reps in gpt2.pattern_groups(pattern)]
+            for sub, reps in blocks.pattern_groups(pattern)]
 
 
 def _attn_init(rng, n: int, cfg: NemotronHConfig, out_std: float):
@@ -178,7 +173,7 @@ _ATTN_WEIGHTS = ("wq", "wk", "wv", "wo")
 
 
 def _stack_init(rng, pattern: str, cfg: NemotronHConfig):
-    """The layers of ``pattern`` as gpt2.run_pattern takes them: one entry a
+    """The layers of ``pattern`` as blocks.run_pattern takes them: one entry a
     run of the pattern, a kind's layers of the run stacked in the order they
     come; every kind's layer has its pre-norm ``norm``."""
     # rescale_prenorm_residual: the three out-projections by 1/sqrt(2·layers)
@@ -281,13 +276,13 @@ def decays(params):
 # --------------------------------------------------------------------------- #
 
 def _shared_rows(cfg: NemotronHConfig, batch: int, seq: int) -> int:
-    """Rows of the sequence the shared expert takes at a time (llama._mlp_rows
+    """Rows of the sequence the shared expert takes at a time (parts.mlp_rows
     for an MLP with one hidden tensor of d_shared)."""
     a = jnp.dtype(cfg.dtype).itemsize
-    if batch * seq * cfg.d_shared * a <= llama._MLP_CHUNK_BYTES:
+    if batch * seq * cfg.d_shared * a <= parts.MLP_CHUNK_BYTES:
         return seq
-    return llama._rows_under(seq, 3 * batch * cfg.d_shared * a,
-                             2 * batch * seq * cfg.d_model * a)
+    return parts.rows_under(seq, 3 * batch * cfg.d_shared * a,
+                            2 * batch * seq * cfg.d_model * a)
 
 
 @jax.named_scope(scopes.BLOCK)
@@ -300,9 +295,9 @@ def _layer(x, p, cfg: NemotronHConfig, kind: str, balance: bool = False):
     aux = None
     weights = {"M": mamba2.MATMUL_WEIGHTS, "E": moe.LATENT_MOE_MATMUL_WEIGHTS,
                "*": _ATTN_WEIGHTS}[kind]
-    p = {**p, **llama._cast_in_the_loop(p, x, cfg.dtype, weights)}
+    p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype, weights)}
     with jax.named_scope(scopes.LN1):
-        u = llama._rmsnorm(x, p["norm"], cfg)
+        u = parts.rmsnorm(x, p["norm"], cfg.rms_eps)
     if kind == "M":
         y = mamba2.mamba2_mixer(
             u, p, heads=cfg.mamba_heads, head_dim=cfg.mamba_head_dim,
@@ -330,37 +325,34 @@ def _layer(x, p, cfg: NemotronHConfig, kind: str, balance: bool = False):
             v = checkpoint_name(
                 jnp.einsum("bsd,dhk->bhsk", u, p["wv"]), scopes.RES_V)
         with jax.named_scope(scopes.ATTN):
-            o = llama._attention(q, k, v, p, cfg)
+            o = parts.causal_attention(q, k, v, cfg.attention_impl)
         with jax.named_scope(scopes.PROJ):
             y = jnp.einsum("bhsk,hkd->bsd", o, p["wo"],
                            preferred_element_type=jnp.float32)
-    x = llama._residual_add(x, y)
+    x = parts.residual_add(x, y)
     return (x, aux) if balance else x
 
 
 def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
-                ) -> Tuple[gpt2.BlockShard, Dict[str, gpt2.KindShard]]:
+                ) -> Tuple[parts.BlockShard, Dict[str, blocks.KindShard]]:
     """This config's layers on one chip of ``mesh``, for the remat rule: the
     model's shard (stream, head, rows at a time) and, a kind, how often it is
     applied (trunk and MTP module together), what a layer of it may keep,
     what its backward holds at once and what its weight gradients take —
     each from the kind's own shapes."""
-    from ray_tpu.ops.attention import resolve_attention
-
     a = jnp.dtype(cfg.dtype).itemsize
     D = cfg.d_model
-    base = gpt2.shard_block(gpt2.BlockShard(
+    base = parts.shard_block(parts.BlockShard(
         batch=global_batch, seq=seq, d_model=D, heads=cfg.n_head,
         head_dim=cfg.head_dim, d_ff=cfg.d_shared, vocab=cfg.vocab_size,
         dtype_bytes=a,
-        flash=resolve_attention(cfg.attention_impl, mesh)[0] == "pallas",
+        flash=parts.is_flash(cfg.attention_impl, mesh),
         dense_mlp=False, kv_heads=cfg.n_kv_head,
-        head_rows=llama._head_rows(global_batch, seq, cfg.vocab_size,
-                                   cfg.n_pred_heads),
+        head_rows=parts.head_rows(global_batch, seq, cfg.vocab_size, 1),
         mlp_rows=_shared_rows(cfg, global_batch, seq), cast_in_loop=True,
     ), mesh)
     tokens = base.batch * base.seq
-    C = gpt2.RematCandidate
+    C = blocks.RematCandidate
     counts = {kind: cfg.pattern.count(kind) + cfg.mtp_pattern.count(kind)
               for kind in KINDS}
 
@@ -371,11 +363,11 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
     Q = min(cfg.chunk, seq)
     chunks = base.batch * -(-base.seq // Q)
     scan_flops = 2 * tokens * (Q * (G * N + H * P) + 2 * H * P * N)
-    mamba = gpt2.KindShard(counts["M"], (
+    mamba = blocks.KindShard(counts["M"], (
         C((scopes.RES_MAMBA_Z,), tokens * inner * a, 2 * tokens * D * inner),
         C((scopes.RES_MAMBA_XBC,), tokens * conv_dim * a,
           2 * tokens * D * conv_dim),
-        C((scopes.RES_MAMBA_DT,), tokens * H * 4, 2 * tokens * D * gpt2._MXU),
+        C((scopes.RES_MAMBA_DT,), tokens * H * 4, 2 * tokens * D * parts.MXU),
         C((scopes.RES_SSD_STATES,), chunks * H * P * N * 4,
           2 * tokens * H * P * N),
         C((scopes.RES_SSD_Y,), tokens * inner * a, scan_flops),
@@ -418,12 +410,12 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
     routed = (a * tokens * 3 * cfg.latent + tokens * cfg.n_experts * 12
               + a * rows * (2 * cfg.latent + 3 * cfg.d_expert))
     shared = a * base.batch * (base.mlp_rows or base.seq) * 3 * base.d_ff
-    experts = gpt2.KindShard(counts["E"], tuple(experts_kept), (
+    experts = blocks.KindShard(counts["E"], tuple(experts_kept), (
         a * tokens * 4 * D + max(routed, shared) + 2 * a * expert_params))
 
     # *: q, k, v and the flash kernel's outputs (no MLP half, no mid-stream)
-    attn = gpt2.KindShard(counts["*"], tuple(
-        c for c in gpt2.remat_candidates(base) if c.names != (scopes.RES_MID,)
+    attn = blocks.KindShard(counts["*"], tuple(
+        c for c in parts.remat_candidates(base) if c.names != (scopes.RES_MID,)
     ), a * tokens * (4 * D + 4 * base.heads * base.head_dim)
         + 2 * a * D * 2 * (base.heads + base.kv_heads) * base.head_dim)
     kinds = {"M": mamba, "E": experts, "*": attn}
@@ -456,11 +448,12 @@ def _block_fns(cfg: NemotronHConfig, batch: int, seq: int):
 
     base, kinds = kind_shards(cfg, batch, seq, mesh_lib.current_mesh())
     for pattern in filter(None, (cfg.pattern, cfg.mtp_pattern)):
-        gpt2.record_layer_pattern(pattern)
-    return gpt2.checkpoint_kinds(
+        blocks.record_layer_pattern(pattern)
+    return blocks.checkpoint_kinds(
         {kind: partial(_layer, cfg=cfg, kind=kind) for kind in kinds},
         cfg.remat, base, kinds,
-        gpt2.pattern_groups(cfg.pattern) + gpt2.pattern_groups(cfg.mtp_pattern))
+        blocks.pattern_groups(cfg.pattern)
+        + blocks.pattern_groups(cfg.mtp_pattern))
 
 
 def _hidden(params, tokens, targets, cfg: NemotronHConfig,
@@ -479,7 +472,8 @@ def _hidden(params, tokens, targets, cfg: NemotronHConfig,
         block_fns = _block_fns(cfg, B, S)
 
     def run(pattern, x, stacks):
-        out = gpt2.run_pattern(block_fns, pattern, x, stacks, with_aux=balance)
+        out = blocks.run_pattern(block_fns, pattern, x, stacks,
+                                 with_aux=balance)
         return out if balance else (out, None)
 
     x, aux = run(cfg.pattern, x, params["blocks"])
@@ -501,15 +495,16 @@ def _mtp_input(params, x, targets, wte, cfg: NemotronHConfig):
     later = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)), constant_values=-1)
     with jax.named_scope(scopes.EMBED):
         e = wte[jnp.where(has_next, targets, 0)]
-    both = jnp.concatenate([llama._rmsnorm(e, mtp["enorm"], cfg),
-                            llama._rmsnorm(x, mtp["hnorm"], cfg)], axis=-1)
+    both = jnp.concatenate([parts.rmsnorm(e, mtp["enorm"], cfg.rms_eps),
+                            parts.rmsnorm(x, mtp["hnorm"], cfg.rms_eps)],
+                           axis=-1)
     h = jnp.einsum("bse,ed->bsd", both, mtp["eh_proj"].astype(cfg.dtype))
     return h, jnp.where(has_next, later, -1)
 
 
 def _final_norm(x, params, cfg):
     with jax.named_scope(scopes.LN_F):
-        return llama._rmsnorm(x, params["final_norm"], cfg)
+        return parts.rmsnorm(x, params["final_norm"], cfg.rms_eps)
 
 
 def forward(params, tokens, cfg: NemotronHConfig) -> jax.Array:
@@ -522,13 +517,14 @@ def forward(params, tokens, cfg: NemotronHConfig) -> jax.Array:
 def losses(params, tokens, targets, cfg: NemotronHConfig):
     """(the trunk's mean cross-entropy, the MTP module's or 0.0)."""
     x, h, mtp_targets, _ = _hidden(params, tokens, targets, cfg)
-    trunk = llama._lm_head_loss(_final_norm(x, params, cfg), targets,
-                                params["lm_head"], cfg)
+    trunk = parts.lm_head_loss(_final_norm(x, params, cfg), targets,
+                               params["lm_head"], cfg.dtype)
     if h is None:
         return trunk, jnp.zeros((), jnp.float32)
     with jax.named_scope(scopes.MTP):
-        return trunk, llama._lm_head_loss(
-            _final_norm(h, params, cfg), mtp_targets, params["lm_head"], cfg)
+        return trunk, parts.lm_head_loss(_final_norm(h, params, cfg),
+                                         mtp_targets, params["lm_head"],
+                                         cfg.dtype)
 
 
 def loss_fn(params, tokens, targets, cfg: NemotronHConfig) -> jax.Array:
@@ -575,7 +571,7 @@ def _balanced(pattern: str, stacks, auxes):
     """``stacks`` with the balanced biases of ``auxes`` (run_pattern's, on the
     host) in place, and the expert layers' loads in the order they come."""
     out, loads = [], []
-    for (sub, reps), group, aux in zip(gpt2.pattern_groups(pattern), stacks,
+    for (sub, reps), group, aux in zip(blocks.pattern_groups(pattern), stacks,
                                        auxes, strict=True):
         layers = [a for a in aux if a is not None]   # the sub-pattern's E's
         if layers:
@@ -604,11 +600,11 @@ def balance_router_bias(params, tokens, targets, cfg: NemotronHConfig):
     trunk, mtp = jax.device_get(jax.jit(
         lambda p, tok, tgt: _hidden(p, tok, tgt, cfg, balance=True)[3])(
         params, tokens, targets))
-    blocks, loads = _balanced(cfg.pattern, params["blocks"], trunk)
-    params = {**params, "blocks": blocks}
+    stacks, loads = _balanced(cfg.pattern, params["blocks"], trunk)
+    params = {**params, "blocks": stacks}
     if cfg.mtp_pattern:
-        blocks, more = _balanced(cfg.mtp_pattern, params["mtp"]["blocks"], mtp)
-        params = {**params, "mtp": {**params["mtp"], "blocks": blocks}}
+        stacks, more = _balanced(cfg.mtp_pattern, params["mtp"]["blocks"], mtp)
+        params = {**params, "mtp": {**params["mtp"], "blocks": stacks}}
         loads += more
     component, name = scopes.EXPERT_LOAD.split("/")
     events = []

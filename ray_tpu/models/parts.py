@@ -1,0 +1,396 @@
+"""The parts more than one model family is built from, taking arguments, not
+a config: RMSNorm, rotary embedding, the float32 residual add, the weight
+cast that stays inside the layer loop, causal attention (grouped heads, the
+flash kernel or XLA's softmax), the rows an MLP and a head take at a time, the
+untied head's loss — and, beside the parts it describes, the half of the
+remat rule that is about an attention + MLP BLOCK: its shapes on one chip
+(BlockShard), what it may keep (remat_candidates) and what its backward
+holds (block_working_set). The half about the step is blocks.py's; a model
+file imports both and no other model.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import List, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models.blocks import (KindShard, RematCandidate, RematPolicy,
+                                   checkpoint_kinds, choose_remat_policy_kinds,
+                                   model_working_set)
+from ray_tpu.tracing import names as scopes
+
+
+# the head's float32 logits of one sequence chunk stay under this
+# (head_rows): [B, S, V] whole is 4.2 GB at llama_7b's 8 x 4,096 x 32,000
+HEAD_CHUNK_BYTES = 2 ** 26
+# an MLP whose hidden tensor of the whole sequence passes this takes the
+# sequence in chunks (mlp_rows): a SwiGLU's backward holds five of them —
+# 3.6 GB at 32,768 x 11,008, which one chip does not have beside EvaByte's
+# state
+MLP_CHUNK_BYTES = 2 ** 28
+MXU = 128       # a matmul dim below this still costs a full pass
+
+
+def round_up(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
+
+
+def rmsnorm(x, g, eps: float, unit_offset: bool = False):
+    """x / rms(x) · g, or · (1 + g) with ``unit_offset``; float32 statistics."""
+    xf = x.astype(jnp.float32)
+    rms = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    scale = 1.0 + g.astype(jnp.float32) if unit_offset else g
+    return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding, HF-llama convention: x [..., S, hd] with the head
+    dim split as [first half, second half] (rotate_half), NOT interleaved."""
+    hd = x.shape[-1]
+    half = hd // 2
+    freqs = 1.0 / theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
+    # x·cos + rotate_half(x)·sin, rotate_half(x) = [-x2, x1] = x @ R with R a
+    # signed permutation (exact in any dtype): a [hd, hd] matmul a head, 0.5 %
+    # of a block's operations, where slicing the head dim in two makes
+    # tensors of half a head — 64 of 128 lanes, each taking what a whole one
+    # does; four stood in HBM in the 32,768-token backward
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)             # [S, hd]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    eye = jnp.eye(half, dtype=x.dtype)
+    zero = jnp.zeros_like(eye)
+    rot = jnp.block([[zero, eye], [-eye, zero]])                      # x @ rot
+    rotated = jnp.einsum("...d,de->...e", x, rot)
+    return (x.astype(jnp.float32) * cos
+            + rotated.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+def residual_add(x, y):
+    """x + y in float32, the stream stored in x's dtype (the released
+    EvaByte's ``fp32_skip_add``; y is a matmul's float32 accumulator)."""
+    return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype)
+
+
+def attention_on_mesh(attention_impl: str):
+    """(impl, interpret, mesh): "pallas" or "xla" on the mesh in use. Ring
+    attention is models/gpt2.py's own path, taken before it comes here."""
+    from ray_tpu.ops.attention import resolve_attention
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.current_mesh()
+    impl, interpret = resolve_attention(attention_impl, mesh)
+    if impl == "ring":
+        raise NotImplementedError("only models/gpt2.py has a ring-attention "
+                                  "path; use a mesh without a cp axis")
+    return impl, interpret, mesh
+
+
+def causal_attention(q, k, v, attention_impl: str):
+    """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd], causal (head-major layout —
+    the flash kernels' native one, so the hot path has no boundary
+    transposes); KH heads of k and v serve H / KH heads of q each."""
+    from ray_tpu.ops.attention import flash_attention_sharded
+
+    impl, interpret, mesh = attention_on_mesh(attention_impl)
+    groups = q.shape[1] // k.shape[1]
+    if groups > 1:
+        k = jnp.repeat(k, groups, axis=1)
+        v = jnp.repeat(v, groups, axis=1)
+    if impl == "pallas":
+        return flash_attention_sharded(
+            q, k, v, mesh, causal=True, interpret=interpret)
+    # XLA path: einsum + mask; XLA fuses the softmax chain.
+    S = q.shape[2]
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    mask = jnp.tril(jnp.ones((S, S), dtype=bool))
+    logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def is_flash(attention_impl: str, mesh) -> bool:
+    """Attention on this mesh is a Pallas kernel: its o and lse exist."""
+    from ray_tpu.ops.attention import resolve_attention
+
+    return resolve_attention(attention_impl, mesh)[0] == "pallas"
+
+
+def cast_in_the_loop(p, x, dt, keys):
+    """The layer's matmul weights ``keys`` in the compute dtype, cast inside
+    the layer loop. A plain ``astype`` of a layer sliced out of the stack the
+    TPU compiler turns into one cast of the WHOLE stack before the loop
+    (through an ``optimization_barrier`` too) and keeps the copy for the
+    length of the step: 1.5 GB beside EvaByte's four layers, which the chip
+    does not have.
+    A factor of one that depends on the loop's carry keeps the cast where it
+    is written; it costs a read of the layer's f32 weights a use, 0.6 % of
+    the 32,768-token step."""
+    one = lax.stop_gradient(1.0 + 0.0 * x[0, 0, 0].astype(jnp.float32))
+    return {k: (p[k] * one).astype(dt) for k in keys}
+
+
+def rows_under(seq: int, bytes_a_row: int, limit: int) -> int:
+    """The largest power-of-two fraction of ``seq`` whose rows stay under
+    ``limit`` bytes (``seq`` itself where they do)."""
+    rows = seq
+    while rows % 2 == 0 and rows * bytes_a_row > limit:
+        rows //= 2
+    return rows
+
+
+def mlp_rows(batch: int, seq: int, d_model: int, d_ff: int,
+             itemsize: int) -> int:
+    """Rows of the sequence the MLP takes at a time: all of them where a
+    hidden tensor of the whole sequence stays under MLP_CHUNK_BYTES. A longer
+    sequence goes in chunks whose five hidden tensors together take what two
+    of the block's [B, S, D] activations do — a fifth more beside the eight
+    of that size that wait in the chunk's backward for the attention's
+    (rematted_working_set). On the chip the 32,768-byte EvaByte step's
+    MLP backward takes 403.6 ms at the 4,096 rows this gives, 405.1 at 2,048
+    and 420.7 at 8,192, and its compiled step needs 0.7 GB less than at 8,192
+    (PERF.md §6, PR 32)."""
+    if batch * seq * d_ff * itemsize <= MLP_CHUNK_BYTES:
+        return seq
+    return rows_under(seq, 5 * batch * d_ff * itemsize,
+                      2 * batch * seq * d_model * itemsize)
+
+
+def head_rows(batch: int, seq: int, columns: int, heads: int) -> int:
+    """Rows of the sequence the head takes at a time where it goes in chunks
+    — their float32 logits stay under HEAD_CHUNK_BYTES; more heads than one
+    always do — and 0 where one head takes the sequence whole (softmax_xent)."""
+    rows = rows_under(seq, batch * columns * 4, HEAD_CHUNK_BYTES)
+    return rows if heads > 1 or rows < seq else 0
+
+
+def head_targets(targets: jax.Array, n_heads: int) -> jax.Array:
+    """targets [B, S] (the next token, -1 = ignore) → [B, S, n_heads]: head
+    p's target at t is targets[t + p], -1 past the row's end."""
+    S = targets.shape[1]
+    padded = jnp.pad(targets, ((0, 0), (0, n_heads - 1)), constant_values=-1)
+    return jnp.stack([padded[:, p:p + S] for p in range(n_heads)], axis=-1)
+
+
+@jax.named_scope(scopes.LM_HEAD_LOSS)
+def lm_head_loss(x, targets, lm_head, dtype, n_pred_heads: int = 1):
+    """Untied head(s) + cross-entropy over final hidden states [B, S, D]: the
+    mean over the heads of each head's mean over its valid targets. Where the
+    head goes in chunks (head_rows) the logits and their gradient
+    are never one tensor, and a chunk's logits are multiplied out once a
+    step: the chunk that makes its loss makes its gradient
+    (ops/cross_entropy.chunked_head_xent)."""
+    from ray_tpu.ops import cross_entropy
+
+    B, S = targets.shape
+    lm_head = lm_head.astype(dtype)
+    rows = head_rows(B, S, lm_head.shape[1], n_pred_heads)
+    if not rows:
+        # fused CE (ops/cross_entropy.py): no [B, S, V] float32 residual
+        nll = cross_entropy.softmax_xent(
+            jnp.einsum("bsd,dv->bsv", x, lm_head), targets)
+        return jnp.sum(nll) / jnp.maximum(jnp.sum(targets >= 0), 1)
+    return cross_entropy.chunked_head_xent(
+        x, head_targets(targets, n_pred_heads), lm_head, rows)
+
+
+class BlockShard(NamedTuple):
+    """One chip's share of a step, in elements: global shapes ÷ the mesh axes
+    that split them. Everything the remat rule computes, it computes from
+    this and n_layer. The model states its block's shapes (gpt2.block_shard,
+    llama.block_shard); the defaults are GPT-2's block."""
+    batch: int            # rows of the batch on this chip
+    seq: int
+    d_model: int
+    heads: int            # attention heads on this chip
+    head_dim: int
+    d_ff: int             # MLP hidden width on this chip
+    vocab: int            # LM-head columns on this chip
+    dtype_bytes: int      # of an activation
+    flash: bool           # attention is a Pallas kernel: its o and lse exist
+    dense_mlp: bool       # the MLP is the dense one: its hidden tensors exist
+    kv_heads: int = 0     # heads of k and v where q has more (0: as many)
+    # the dense MLP's named hidden tensors, each d_ff wide: one before a
+    # gelu, two (gate, up) in a SwiGLU
+    mlp_hidden: Tuple[str, ...] = (scopes.RES_MLP_HIDDEN,)
+    window: int = 0       # > 0: the EVA mixer (ops/eva_attention.py) — a query
+    chunk: int = 0        # sees its window and one summary a chunk before it
+    # rows of the sequence the LM head and the MLP take at a time (0: all of
+    # them). An MLP that takes fewer makes its hidden tensors again in each
+    # chunk's backward: they are no candidates
+    head_rows: int = 0
+    mlp_rows: int = 0
+    # the block casts its layer's matmul weights inside the layer loop
+    # (cast_in_the_loop): one layer's stand in the block's backward
+    cast_in_loop: bool = False
+
+
+def shard_block(whole: BlockShard, mesh) -> BlockShard:
+    """A block stated in global shapes, on one chip of ``mesh``: batch over
+    the data axes that divide it, heads / MLP width / vocab over tp, the
+    sequence over cp."""
+    from ray_tpu.ops.attention import batch_head_axes
+
+    if mesh is None:
+        return whole
+    batch, heads, kv_heads = whole.batch, whole.heads, whole.kv_heads
+    d_ff, vocab, seq = whole.d_ff, whole.vocab, whole.seq
+    batch_axes, head_ax = batch_head_axes(mesh, batch, heads)
+    for ax in batch_axes or ():
+        batch //= mesh.shape[ax]
+    tp, cp = mesh.shape.get("tp", 1), mesh.shape.get("cp", 1)
+    if head_ax:
+        heads //= tp
+        if kv_heads % tp == 0:
+            kv_heads //= tp
+    if d_ff % tp == 0:
+        d_ff //= tp
+    if vocab % tp == 0:
+        vocab //= tp
+    if seq % cp == 0:
+        seq //= cp
+    return whole._replace(batch=batch, heads=heads, kv_heads=kv_heads,
+                          d_ff=d_ff, vocab=vocab, seq=seq)
+
+
+def remat_candidates(s: BlockShard) -> List[RematCandidate]:
+    """The block's named residuals as (names kept together, bytes a layer,
+    FLOPs a layer to recompute them, bytes keeping them frees), most FLOPs
+    per byte first; of equal ones the one that frees more, then the block's
+    own order. A matmul output of width N contracted over
+    K costs 2·K·N a row and holds N elements, so the qkv, proj and fc outputs
+    all come to K FLOPs per element; the flash kernel's o comes to about
+    2·S per element (causal: half of two S×S matmuls, whose head_dim side
+    fills the MXU only from 128 up), so it leads at long sequences and
+    trails at short ones. lse goes with o: neither is of use alone.
+
+    A block with the EVA mixer has that kernel's o and lse in their place: a
+    query's keys are half its window and, on average, the summaries of half
+    the sequence — (w + S/c − w/c) per element where causal attention has S.
+    Its summaries (1/chunk the size of k and v) come from a pass over k and v
+    that is a few operations an element: they trail everything. That pass
+    reads k in float32, and a k that is made again stands in both precisions
+    from the block's second forward to the pass's backward — across the whole
+    MLP backward (_eva_k_f32). A kept k is read from its stack when the pass
+    needs it: keeping k frees those bytes, so k leads q."""
+    tokens = s.batch * s.seq
+    a = s.dtype_bytes
+    attn_width = s.heads * s.head_dim
+    kv_width = (s.kv_heads or s.heads) * s.head_dim
+    out = [RematCandidate((name,), tokens * width * a,
+                          2 * tokens * s.d_model * width, frees)
+           for name, width, frees in ((scopes.RES_Q, attn_width, 0),
+                                      (scopes.RES_K, kv_width, _eva_k_f32(s)),
+                                      (scopes.RES_V, kv_width, 0))]
+    if s.flash and s.window:
+        keys = s.window + (s.seq - s.window) // s.chunk      # twice the mean
+        out.append(RematCandidate(
+            (scopes.RES_EVA_O, scopes.RES_EVA_LSE),
+            tokens * s.heads * (s.head_dim * a + 4),
+            2 * s.batch * s.heads * s.seq * keys * max(s.head_dim, MXU),
+        ))
+        out.append(RematCandidate(
+            (scopes.RES_EVA_KT, scopes.RES_EVA_VT),
+            2 * tokens // s.chunk * attn_width * a,
+            6 * tokens * attn_width,
+        ))
+    elif s.flash:
+        out.append(RematCandidate(
+            (scopes.RES_FLASH_O, scopes.RES_FLASH_LSE),
+            tokens * s.heads * (s.head_dim * a + 4),
+            2 * s.batch * s.heads * s.seq * s.seq * max(s.head_dim, MXU),
+        ))
+    out.append(RematCandidate((scopes.RES_MID,), tokens * s.d_model * a,
+                              2 * tokens * attn_width * s.d_model))
+    if s.dense_mlp and s.mlp_rows in (0, s.seq):
+        out += [RematCandidate((name,), tokens * s.d_ff * a,
+                               2 * tokens * s.d_model * s.d_ff)
+                for name in s.mlp_hidden]
+    return sorted(out, key=lambda c: (-c.flops / c.nbytes, -c.frees))
+
+
+def _eva_k_f32(s: BlockShard) -> int:
+    """Bytes of the float32 k the EVA summary pass reads (0 with no window):
+    the compiler writes it beside k out of the rotation."""
+    if not s.window:
+        return 0
+    return s.batch * s.seq * (s.kv_heads or s.heads) * s.head_dim * 4
+
+
+def rematted_working_set(s: BlockShard, n_layer: int) -> int:
+    """Bytes of activations a chip needs for a step whose blocks keep only
+    their inputs, as the rule counts them: the stack of block inputs; the LM
+    head's logits, their gradient and one float32 copy inside the softmax;
+    one block's whole residual set, live while its backward runs; the
+    largest parameter (the embedding) gathered in the compute dtype beside
+    its unreduced float32 gradient. The block's set peaks in the MLP's
+    backward, where everything the attention's backward will read is already
+    made again and waits: four tensors of the stream's width and q, k, v, o,
+    beside each of the MLP's hidden tensors and its gradient (and a gated
+    MLP's product) for the rows it takes at a time. A block that states more
+    holds more there (PERF.md §6, PR 32: the 32,768-byte EvaByte step
+    compiled for a v5e). With the EVA mixer the summary pass's float32 k
+    waits too (_eva_k_f32), unless k is kept — remat_candidates says what
+    keeping it frees. Where the block casts its layer's weights inside the
+    loop they stand twice in the compute dtype: the cast, and the copy the
+    compiler moves ahead of the MLP's loop. An estimate from shapes — XLA's
+    schedule decides the real figure (PR 28: from 0.08 GiB under at the
+    GPT-2 cells' shapes to 8 over; PR 32: 0.13 GB over at the EvaByte cell's)
+    — which is what the reserve is for."""
+    return model_working_set(s, n_layer) + block_working_set(s)
+
+
+def block_working_set(s: BlockShard) -> int:
+    """rematted_working_set's part that is one block's: its whole residual
+    set, live while its backward runs. Of a model whose layers are of more
+    than one kind each run's largest counts in its phase
+    (blocks.backward_phases)."""
+    tokens = s.batch * s.seq
+    a = s.dtype_bytes
+    attn_width = s.heads * s.head_dim
+    kv_width = (s.kv_heads or s.heads) * s.head_dim
+    hidden = 2 * len(s.mlp_hidden) + (len(s.mlp_hidden) - 1)
+    block = a * (tokens * (4 * s.d_model + 4 * attn_width)
+                 + s.batch * (s.mlp_rows or s.seq) * hidden * s.d_ff)
+    weights = 2 * a * s.d_model * (
+        2 * attn_width + 2 * kv_width + (len(s.mlp_hidden) + 1) * s.d_ff
+    ) if s.cast_in_loop else 0
+    return block + _eva_k_f32(s) + weights
+
+
+def choose_remat_policy(shard: BlockShard, n_layer: int,
+                        bytes_limit: Optional[int],
+                        resident_bytes: int) -> RematPolicy:
+    """choose_remat_policy_kinds for ``n_layer`` blocks of one kind."""
+    return choose_remat_policy_kinds(
+        [KindShard(n_layer, tuple(remat_candidates(shard)),
+                   block_working_set(shard))],
+        rematted_working_set(shard, n_layer), bytes_limit, resident_bytes)
+
+
+def checkpoint_block(block_fn, remat: bool, shard: BlockShard,
+                     n_layer: int):
+    """``block_fn(x, layer_params)`` as the layer scan calls it, for any model
+    whose block carries the names of tracing/names.RESIDUALS; n_layer is how
+    many of them one chip runs (a pipeline stage's share under pp): a
+    policy-``checkpoint`` that keeps the block's input and, of its named
+    residuals, those the chip has room for with ``remat`` and all of them
+    without. Left to its own AD the scan stacks every elementwise
+    intermediate too (the gelu alone: five ``[n_layer, B, S, d_ff]`` tensors
+    beside its input), and copying those in and out of the stacks cost the
+    gpt2-124m step 9.2 of its 74.0 ms and 4.2 of its 9.25 GiB; recomputing
+    them costs 0.5 ms (PERF.md §6, PR 30). Without remat that holds only
+    where the names cover every output that is dear to make again — a Pallas
+    attention kernel's and the dense MLP's; XLA and ring attention and the
+    experts tag none of theirs, so those blocks stay as AD leaves them. The
+    one-kind case of checkpoint_kinds."""
+    if not remat and not (shard.flash and shard.dense_mlp):
+        return block_fn
+    kind = KindShard(n_layer, tuple(remat_candidates(shard)),
+                     block_working_set(shard))
+    return checkpoint_kinds({"block": block_fn}, remat, shard,
+                            {"block": kind}, [(("block",), n_layer)])["block"]

@@ -17,7 +17,7 @@ saves only the bf16 logits (which the LM-head matmul already produced) plus a
 
 **The sequence in chunks — ``chunked_head_xent``: the gradient is made in the
 forward.** Where the float32 logits of the whole sequence are too large to be
-one tensor (llama._lm_head_loss: EvaByte's eight heads over 32,768 bytes,
+one tensor (parts.lm_head_loss: EvaByte's eight heads over 32,768 bytes,
 Nemotron's 16,384 columns over 8 x 4,096 tokens) the head and the loss are
 ONE op over chunks of the sequence. A cross-entropy's gradient with respect to
 its logits needs nothing but the logits and a weight known from the targets

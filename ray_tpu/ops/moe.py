@@ -521,7 +521,8 @@ def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
     plus the shared expert ``relu(u·S1)²·S2``. ``p`` holds one layer's
     tensors, LATENT_MOE_MATMUL_WEIGHTS in the compute dtype. With
     ``shared_rows`` < S the shared expert takes the sequence in chunks of
-    that many rows, each its own ``checkpoint`` (llama._mlp's reason)."""
+    that many rows, each its own ``checkpoint`` (as models/llama.py's MLP,
+    and for its reason)."""
     B, S, D = u.shape
     ut = u.reshape(B * S, D)
     with jax.named_scope(scopes.MOE_LATENT):

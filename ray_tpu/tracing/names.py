@@ -117,10 +117,10 @@ RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_GATE, RES_MLP_UP, RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT,
              RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN,
              RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY)
-# which of them models/gpt2.py chose to save, the rows of the sequence
+# which of them models/blocks.py chose to save, the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once),
 # and the phase of the backward whose working set the budget was left by
-# (gpt2.backward_phases: "head", or a run of the layers as model/layer_pattern
+# (blocks.backward_phases: "head", or a run of the layers as model/layer_pattern
 # names it) with that set's bytes: one instant event per distinct decision, at
 # trace time, in the task-event buffer
 REMAT_POLICY = "model/remat_policy"
@@ -136,7 +136,7 @@ REMAT_POLICY_ARGS = ("n_layer", "batch", "seq", "saved", "saved_bytes",
 HEAD_LOSS = "model/head_loss"
 HEAD_LOSS_ARGS = ("batch", "rows", "chunks", "columns", "heads",
                   "grad_in_forward", "residual_bytes")
-# a model whose layers are of more than one kind (gpt2.run_pattern): the
+# a model whose layers are of more than one kind (blocks.run_pattern): the
 # pattern, how often each kind is applied and which runs of it are one scan;
 # one instant event per distinct pattern, at trace time
 LAYER_PATTERN = "model/layer_pattern"

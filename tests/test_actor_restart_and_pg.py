@@ -15,6 +15,22 @@ def ray_cluster2():
     ray_tpu.shutdown()
 
 
+def _cpus_become(ray, want, within=10.0):
+    """Wait until available_resources() reads ``want`` CPUs — the GCS's view,
+    which follows the raylet's next resource report and not the call that
+    changed it — or fail with everything that was read, and when."""
+    start, read = time.monotonic(), []
+    while True:
+        cpus = ray.available_resources().get("CPU", 0)
+        read.append((round(time.monotonic() - start, 2), cpus))
+        if cpus == want:
+            return
+        assert read[-1][0] < within, (
+            f"available CPUs never read {want} within {within} s: "
+            f"(seconds, CPUs) {read}")
+        time.sleep(0.1)
+
+
 def test_actor_restart_after_crash(ray_cluster2):
     """max_restarts=1: kill the actor's worker process; the GCS must restart
     it (fresh state) and subsequent calls succeed (reference: actor.py:332
@@ -100,12 +116,10 @@ def test_placement_group_reserve_and_run(ray_cluster2):
     assert ray.get(ref, timeout=90) == "ran"
 
     # PG holds both CPUs: a non-PG 1-CPU task must not find node resources
-    avail = ray.available_resources()
-    assert avail.get("CPU", 0) == 0, avail
+    _cpus_become(ray, 0)
 
     remove_placement_group(pg)
-    time.sleep(2)
-    assert ray.available_resources().get("CPU") == 2.0
+    _cpus_become(ray, 2.0)
 
 
 def test_placement_group_infeasible_strict_spread(ray_cluster2):
@@ -141,7 +155,7 @@ def test_pg_actor_draws_from_bundle_not_node(ray_cluster2):
 
     # node had 2 CPUs; PG reserved 1; the actor lives INSIDE that bundle, so
     # 1 CPU must remain for plain tasks
-    assert ray.available_resources().get("CPU", 0) == 1.0
+    _cpus_become(ray, 1.0)
 
     @ray.remote
     def plain():
@@ -151,5 +165,4 @@ def test_pg_actor_draws_from_bundle_not_node(ray_cluster2):
 
     ray.kill(a)
     remove_placement_group(pg)
-    time.sleep(2)
-    assert ray.available_resources().get("CPU") == 2.0
+    _cpus_become(ray, 2.0)

@@ -19,7 +19,7 @@ import numpy as np
 import optax
 import pytest
 
-from ray_tpu.models import gpt2, llama
+from ray_tpu.models import blocks, llama, parts
 from ray_tpu.ops import eva_attention as eva
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tracing import names
@@ -216,7 +216,7 @@ def test_model_through_make_train_step_matches_the_reference(impl):
 
 def test_head_targets_shift_one_array_and_end_with_the_row():
     targets = jnp.asarray([[10, 11, 12, 13, -1], [20, -1, 22, 23, 24]])
-    got = np.asarray(llama.head_targets(targets, 3))
+    got = np.asarray(parts.head_targets(targets, 3))
     assert got.shape == (2, 5, 3)
     np.testing.assert_array_equal(got[0, :, 0], [10, 11, 12, 13, -1])
     np.testing.assert_array_equal(got[0, :, 1], [11, 12, 13, -1, -1])
@@ -225,7 +225,7 @@ def test_head_targets_shift_one_array_and_end_with_the_row():
     np.testing.assert_array_equal(
         got, np.moveaxis(np.asarray(reference.head_targets(targets, 3)), 0, 2))
     # one head: the targets themselves, and the loss is the plain one
-    np.testing.assert_array_equal(llama.head_targets(targets, 1)[..., 0], targets)
+    np.testing.assert_array_equal(parts.head_targets(targets, 1)[..., 0], targets)
 
 
 def test_loss_chunks_the_head_and_the_mlp_to_the_same_numbers(monkeypatch):
@@ -238,9 +238,9 @@ def test_loss_chunks_the_head_and_the_mlp_to_the_same_numbers(monkeypatch):
         lambda p: llama.loss_fn(p, tokens, targets, cfg))(params)
     want, want_g = run()
     rows = cfg.seq_len // 4
-    monkeypatch.setattr(llama, "_HEAD_CHUNK_BYTES",
+    monkeypatch.setattr(parts, "HEAD_CHUNK_BYTES",
                         2 * rows * cfg.n_pred_heads * cfg.head_vocab * 4)
-    monkeypatch.setattr(llama, "_MLP_CHUNK_BYTES", 2 * rows * cfg.d_ff * 4)
+    monkeypatch.setattr(parts, "MLP_CHUNK_BYTES", 2 * rows * cfg.d_ff * 4)
     shard = llama.block_shard(cfg, 2, cfg.seq_len, None)
     # an MLP past its limit goes in chunks whose five hidden tensors take
     # what two [B, S, D] do: 5 x 32 x 352 under 2 x 256 x 128
@@ -261,7 +261,7 @@ def test_rule_on_the_published_block_keeps_what_fits():
     shard = llama.block_shard(PUBLISHED, 1, PUBLISHED.seq_len, None)
     assert (shard.d_ff, shard.head_dim, shard.window, shard.chunk,
             shard.vocab) == (11008, 128, 2048, 16, 8 * 320)
-    by_name = {c.names: c.nbytes for c in gpt2.remat_candidates(shard)}
+    by_name = {c.names: c.nbytes for c in parts.remat_candidates(shard)}
     tokens = 32768
     assert by_name[(names.RES_Q,)] == tokens * 4096 * 2
     assert by_name[(names.RES_EVA_O, names.RES_EVA_LSE)] == tokens * 32 * (128 * 2 + 4)
@@ -271,18 +271,18 @@ def test_rule_on_the_published_block_keeps_what_fits():
     # candidates, and no flash name is one
     assert not {names.RES_MLP_GATE, names.RES_MLP_UP, names.RES_FLASH_O} & {
         n for g in by_name for n in g}
-    policy = gpt2.choose_remat_policy(shard, 4, V5E_BYTES_LIMIT,
-                                      PUBLISHED_RESIDENT)
+    policy = parts.choose_remat_policy(shard, 4, V5E_BYTES_LIMIT,
+                                       PUBLISHED_RESIDENT)
     assert 0 < policy.saved_bytes <= policy.budget_bytes
-    freed = sum(c.frees for c in gpt2.remat_candidates(shard)
+    freed = sum(c.frees for c in parts.remat_candidates(shard)
                 if set(c.names) <= set(policy.saved))
-    assert (PUBLISHED_RESIDENT + gpt2.rematted_working_set(shard, 4) - freed
-            + policy.saved_bytes + gpt2.REMAT_RESERVE_BYTES) <= V5E_BYTES_LIMIT
+    assert (PUBLISHED_RESIDENT + parts.rematted_working_set(shard, 4) - freed
+            + policy.saved_bytes + blocks.REMAT_RESERVE_BYTES) <= V5E_BYTES_LIMIT
     assert set(policy.saved) <= set(names.RESIDUALS)
     # with nothing free, nothing; with no limit stated, nothing
-    assert gpt2.choose_remat_policy(shard, 4, 4 * 2 ** 30,
-                                    PUBLISHED_RESIDENT).saved == ()
-    assert gpt2.choose_remat_policy(shard, 4, None, 0).saved == ()
+    assert parts.choose_remat_policy(shard, 4, 4 * 2 ** 30,
+                                     PUBLISHED_RESIDENT).saved == ()
+    assert parts.choose_remat_policy(shard, 4, None, 0).saved == ()
 
 
 def test_rule_arithmetic_equals_the_traced_shapes():
@@ -297,7 +297,7 @@ def test_rule_arithmetic_equals_the_traced_shapes():
     layer = jax.tree.map(lambda p: p[0], params["blocks"])
     x = jnp.zeros((batch, cfg.seq_len, cfg.d_model), cfg.dtype)
     shard = llama.block_shard(cfg, batch, cfg.seq_len, None)
-    candidates = gpt2.remat_candidates(shard)
+    candidates = parts.remat_candidates(shard)
     assert {n for c in candidates for n in c.names} == {
         names.RES_Q, names.RES_K, names.RES_V, names.RES_EVA_O,
         names.RES_EVA_LSE, names.RES_EVA_KT, names.RES_EVA_VT, names.RES_MID,
@@ -305,7 +305,7 @@ def test_rule_arithmetic_equals_the_traced_shapes():
 
     def kept(limit):
         with mesh_lib.chip_memory(limit, 0):
-            block_fn = gpt2._checkpointed(
+            block_fn = parts.checkpoint_block(
                 lambda x, p: llama._block(x, p, cfg), True, shard, cfg.n_layer)
             saved = saved_residuals(block_fn, x, layer)
         return sum(np.prod(aval.shape) * aval.dtype.itemsize
@@ -313,11 +313,11 @@ def test_rule_arithmetic_equals_the_traced_shapes():
                    if not why.startswith("from the argument"))
 
     everything = sum(c.nbytes for c in candidates)
-    roomy = (gpt2.REMAT_RESERVE_BYTES + cfg.n_layer * everything
-             + gpt2.rematted_working_set(shard, cfg.n_layer) + 8)
+    roomy = (blocks.REMAT_RESERVE_BYTES + cfg.n_layer * everything
+             + parts.rematted_working_set(shard, cfg.n_layer) + 8)
     assert kept(roomy) == everything
-    assert kept(gpt2.REMAT_RESERVE_BYTES) == 0
-    decision = [d for d in gpt2.remat_policy_decisions()
+    assert kept(blocks.REMAT_RESERVE_BYTES) == 0
+    decision = [d for d in blocks.remat_policy_decisions()
                 if d["bytes_limit"] == roomy]
     assert len(decision) == 1 and len(decision[0]["saved"]) == 10
     assert (decision[0]["mlp_rows"], decision[0]["head_rows"]) == (

@@ -236,22 +236,22 @@ def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(
     suite's."""
     from conftest import time_limit
 
-    from ray_tpu.models import gpt2
+    from ray_tpu.models import blocks
     from ray_tpu.ops import cross_entropy
     from ray_tpu.tracing import names
 
     # recorded once a process by its facts: this test reads its own
-    monkeypatch.setattr(gpt2, "_decisions", {})
+    monkeypatch.setattr(blocks, "_decisions", {})
     monkeypatch.setattr(cross_entropy, "_decisions", {})
     cell, config, family, mesh = _cell_on(topo, "evabyte-6.5b-l4.dataset")
     with time_limit(240, "the EvaByte cell's compile for a described v5e"):
         step, args = family.abstract_step(config, cell, mesh)
         hlo = step.lower(*args).compile().as_text()
-    assert gpt2.compiler_rematerialized(hlo) == []
+    assert blocks.compiler_rematerialized(hlo) == []
     # forward, the block's second forward and backward, a layer: the scan
     # holds each once
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
-    (d,) = gpt2.remat_policy_decisions()
+    (d,) = blocks.remat_policy_decisions()
     assert d["bytes_limit"] == family.V5E_BYTES_LIMIT
     assert d["saved"] == [names.RES_K, names.RES_EVA_KT, names.RES_EVA_VT]
     assert (d["mlp_rows"], d["head_rows"]) == (4096, 4096)
@@ -275,19 +275,19 @@ def nemotron_step(topo):
     the decisions it was traced with (remat rule, chunked head)."""
     from conftest import time_limit
 
-    from ray_tpu.models import gpt2
+    from ray_tpu.models import blocks
     from ray_tpu.ops import cross_entropy
 
     # recorded once a process by their facts: read this trace's own
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(gpt2, "_decisions", {})
+        mp.setattr(blocks, "_decisions", {})
         mp.setattr(cross_entropy, "_decisions", {})
         cell, config, family, mesh = _cell_on(
             topo, "nemotron-3-super-120b-l11.dataset")
         with time_limit(280, "the Nemotron cell's compile for a described v5e"):
             step, args = family.abstract_step(config, cell, mesh)
             compiled = step.lower(*args).compile()
-        return (compiled, gpt2.remat_policy_decisions(),
+        return (compiled, blocks.remat_policy_decisions(),
                 cross_entropy.head_loss_decisions())
 
 
@@ -331,12 +331,12 @@ def test_the_nemotron_cell_step_keeps_the_routing_and_clones_nothing(
     recompute too — cloned nothing, and the blocks' second forward holds no
     `top_k` sort of [32,768 x 512], no sort of the 262,144 keys and no router
     product; the scatter-adds' own sorts of a pass's 14,336 indices stay."""
-    from ray_tpu.models import gpt2
+    from ray_tpu.models import blocks
     from ray_tpu.tracing import names
 
     compiled, (d,), _ = nemotron_step
     hlo = compiled.as_text()
-    assert gpt2.compiler_rematerialized(hlo) == []
+    assert blocks.compiler_rematerialized(hlo) == []
     assert compiled.memory_analysis().peak_memory_in_bytes <= 14.6 * 2 ** 30
     assert (d["head_rows"], d["n_layer"]) == (128, 13)
     assert d["saved"] == [

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, parts
 
 
 def test_forward_shapes_and_loss_decreases():
@@ -152,7 +152,7 @@ def _head_case(heads, ignore):
     targets = np.array(jax.random.randint(k[2], (_B, _S), 0, _V))
     for rows, cols in ignore:
         targets[rows, cols] = -1
-    return x, llama.head_targets(jnp.asarray(targets), heads), lm_head
+    return x, parts.head_targets(jnp.asarray(targets), heads), lm_head
 
 
 @pytest.mark.parametrize("heads,ignore,cotangent", [
@@ -191,7 +191,7 @@ def test_chunked_head_makes_the_whole_sequences_loss_and_gradients(
 
 
 def test_chunked_head_multiplies_a_chunks_logits_once(monkeypatch):
-    """Through llama._lm_head_loss: called without differentiation the
+    """Through parts.lm_head_loss: called without differentiation the
     chunked head's program holds ONE matmul a chunk (the primal does no
     gradient work); differentiated, three — logits, d x, d lm_head — in the
     one forward scan, no `checkpoint` and no second scan behind it. The
@@ -200,13 +200,14 @@ def test_chunked_head_multiplies_a_chunks_logits_once(monkeypatch):
     from ray_tpu.tracing import names
 
     monkeypatch.setattr(cross_entropy, "_decisions", {})
-    monkeypatch.setattr(llama, "_HEAD_CHUNK_BYTES", _B * _ROWS * _V * 4)
+    monkeypatch.setattr(parts, "HEAD_CHUNK_BYTES", _B * _ROWS * _V * 4)
     cfg = llama.llama_tiny(dtype=jnp.float32, vocab_size=_V)
     x, targets, lm_head = _head_case(1, ())
-    assert llama._head_rows(_B, _S, _V, 1) == _ROWS
+    assert parts.head_rows(_B, _S, _V, 1) == _ROWS
 
     def head(x, w):
-        return llama._lm_head_loss(x, targets[..., 0], w, cfg)
+        return parts.lm_head_loss(x, targets[..., 0], w, cfg.dtype,
+                                  cfg.n_pred_heads)
 
     primal = str(jax.make_jaxpr(head)(x, lm_head))
     grad = str(jax.make_jaxpr(jax.grad(head, argnums=(0, 1)))(x, lm_head))
@@ -223,7 +224,7 @@ def test_chunked_head_multiplies_a_chunks_logits_once(monkeypatch):
         grad_in_forward=True,
         residual_bytes=x.size * 4 + lm_head.size * 4)
     # one head whose whole-sequence logits fit takes them whole
-    monkeypatch.setattr(llama, "_HEAD_CHUNK_BYTES", _B * _S * _V * 4)
-    assert llama._head_rows(_B, _S, _V, 1) == 0
+    monkeypatch.setattr(parts, "HEAD_CHUNK_BYTES", _B * _S * _V * 4)
+    assert parts.head_rows(_B, _S, _V, 1) == 0
     assert "scan[" not in str(jax.make_jaxpr(      # (a new function: no
         lambda x, w: head(x, w))(x, lm_head))      # trace of `head` is reused)

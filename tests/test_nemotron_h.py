@@ -16,7 +16,7 @@ import pytest
 
 from benchmarks.families import nemotron_h as family
 from benchmarks.families import nemotron_h_reference as reference
-from ray_tpu.models import gpt2, llama, nemotron_h as nh
+from ray_tpu.models import blocks, gpt2, nemotron_h as nh, parts
 from ray_tpu.ops import mamba2, moe
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tracing import names
@@ -334,9 +334,9 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
     with jax.default_matmul_precision("highest"):
         want = reference.layers(x, kind, params["blocks"],
                                 family.reference_sizes(whole)) - x
-        parts = [nh._layer(x, _cut(p, kind, i, whole), cfg, kind) - x
-                 for i, cfg in enumerate(shares)]
-        total = sum(parts)
+        pieces = [nh._layer(x, _cut(p, kind, i, whole), cfg, kind) - x
+                  for i, cfg in enumerate(shares)]
+        total = sum(pieces)
         if kind == "E":      # the shared expert is in every share: count it once
             alike = reference.latent_moe(
                 reference._norm(x, p["norm"], whole.rms_eps), p,
@@ -344,7 +344,7 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
             total = total - 3 * alike
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
     # and a part is only a part
-    assert float(jnp.max(jnp.abs(parts[0] - want))) > 1e-3 * float(
+    assert float(jnp.max(jnp.abs(pieces[0] - want))) > 1e-3 * float(
         jnp.max(jnp.abs(want)))
 
 
@@ -360,7 +360,7 @@ def _fill_passes(monkeypatch, load, passes):
 
 def _held_load(cfg, p, x):
     """moe.held_load of the expert layer ``p`` on the stream x."""
-    u = llama._rmsnorm(x, p["norm"], cfg).reshape(-1, cfg.d_model)
+    u = parts.rmsnorm(x, p["norm"], cfg.rms_eps).reshape(-1, cfg.d_model)
     return moe.held_load(u, p, top_k=cfg.top_k, held=cfg.held,
                          scaling=cfg.routed_scaling)
 
@@ -756,7 +756,7 @@ def test_trains_through_the_one_factory_on_a_data_mesh():
       ("EM", 4), ("E", 1)]),
 ])
 def test_pattern_groups(pattern, groups):
-    assert gpt2.pattern_groups(pattern) == groups
+    assert blocks.pattern_groups(pattern) == groups
     assert reference._groups(pattern) == groups
     assert "".join(sub * reps for sub, reps in groups) == pattern
 
@@ -767,7 +767,7 @@ def test_run_pattern_scans_repeats_and_applies_the_rest_in_order():
     fns = {"a": lambda x, p: x * 10 + p["v"], "b": lambda x, p: x * 10 - p["v"]}
     pattern = "abababba"
     stacks, n = [], 0
-    for sub, reps in gpt2.pattern_groups(pattern):
+    for sub, reps in blocks.pattern_groups(pattern):
         group = {}
         for kind in dict.fromkeys(sub):
             count = reps * sub.count(kind)
@@ -775,13 +775,13 @@ def test_run_pattern_scans_repeats_and_applies_the_rest_in_order():
             n += count
         stacks.append(group)
     want, seen = 0.0, {id(g[k]["v"]): 0 for g in stacks for k in g}
-    for (sub, reps), group in zip(gpt2.pattern_groups(pattern), stacks):
+    for (sub, reps), group in zip(blocks.pattern_groups(pattern), stacks):
         for _ in range(reps):
             for kind in sub:
                 i = seen[id(group[kind]["v"])]
                 seen[id(group[kind]["v"])] += 1
                 want = fns[kind](want, {"v": float(group[kind]["v"][i])})
-    got = gpt2.run_pattern(fns, pattern, jnp.zeros(()), stacks)
+    got = blocks.run_pattern(fns, pattern, jnp.zeros(()), stacks)
     assert float(got) == want
 
 
@@ -789,28 +789,28 @@ def test_the_rule_counts_applications_per_kind():
     """One rule over all the kinds: a candidate costs its bytes once an
     application of ITS kind, and the largest kind's block sets the working
     set of a run that holds both."""
-    C, K = gpt2.RematCandidate, gpt2.KindShard
+    C, K = blocks.RematCandidate, blocks.KindShard
     a = K(5, (C(("x",), 100, 1000),), 700)
     b = K(1, (C(("y",), 100, 500),), 300)
     shard = gpt2.block_shard(gpt2.gpt2_tiny(), 8, 128, None, True)
-    (head, run) = gpt2.backward_phases(shard, {"a": a, "b": b}, [("aaaaab", 1)])
-    model = gpt2.model_working_set(shard, 6)
+    (head, run) = blocks.backward_phases(shard, {"a": a, "b": b}, [("aaaaab", 1)])
+    model = blocks.model_working_set(shard, 6)
     assert (head, run) == (("head", model), ("aaaaab", model + 700))
-    limit = gpt2.REMAT_RESERVE_BYTES + run.nbytes
-    keep = lambda extra: gpt2.choose_remat_policy_kinds(
+    limit = blocks.REMAT_RESERVE_BYTES + run.nbytes
+    keep = lambda extra: blocks.choose_remat_policy_kinds(
         [a, b], run.nbytes, limit + extra, 0)
     assert keep(0).saved == ()
     assert keep(100).saved == ("y",)             # one application fits
     assert keep(500).saved == ("x",)             # five of the better one
-    assert keep(600) == gpt2.RematPolicy(("x", "y"), 600, 600, limit + 600)
+    assert keep(600) == blocks.RematPolicy(("x", "y"), 600, 600, limit + 600)
     # the one-kind rule is the same rule
-    one = gpt2.choose_remat_policy(shard, 2, 2 ** 31, 0)
-    assert one == gpt2.choose_remat_policy_kinds(
-        [K(2, tuple(gpt2.remat_candidates(shard)),
-           gpt2.block_working_set(shard))],
-        gpt2.rematted_working_set(shard, 2), 2 ** 31, 0)
-    assert (gpt2.model_working_set(shard, 2) + gpt2.block_working_set(shard)
-            == gpt2.rematted_working_set(shard, 2))
+    one = parts.choose_remat_policy(shard, 2, 2 ** 31, 0)
+    assert one == blocks.choose_remat_policy_kinds(
+        [K(2, tuple(parts.remat_candidates(shard)),
+           parts.block_working_set(shard))],
+        parts.rematted_working_set(shard, 2), 2 ** 31, 0)
+    assert (blocks.model_working_set(shard, 2) + parts.block_working_set(shard)
+            == parts.rematted_working_set(shard, 2))
 
 
 def test_the_cells_kinds_and_the_familys_arithmetic():
@@ -849,8 +849,8 @@ def test_the_cells_decision_from_its_shapes_keeps_the_routing_first():
     cell, config, _ = spec.load_cell("nemotron-3-super-120b-l11.dataset")
     cfg = family.program_config(config, cell)
     base, kinds = nh.kind_shards(cfg, cell["per_chip_batch"], cfg.seq_len, None)
-    runs = gpt2.pattern_groups(cfg.pattern) + gpt2.pattern_groups(cfg.mtp_pattern)
-    assert [gpt2.run_name(r) for r in runs] == [
+    runs = blocks.pattern_groups(cfg.pattern) + blocks.pattern_groups(cfg.mtp_pattern)
+    assert [blocks.run_name(r) for r in runs] == [
         "4 x scan(ME)", "M", "*", "E", "*", "E"]
     # a layer's gradients are its parameters' bytes: the kinds' add up to the
     # stacks'
@@ -860,24 +860,24 @@ def test_the_cells_decision_from_its_shapes_keeps_the_routing_first():
     assert sum(k.applications * k.grad_bytes for k in kinds.values()) == (
         nbytes(params["blocks"]) + nbytes(params["mtp"]["blocks"]))
 
-    phases = gpt2.backward_phases(base, kinds, runs)
+    phases = blocks.backward_phases(base, kinds, runs)
     assert [p.name for p in phases] == ["head", "E", "*", "E", "*", "M",
                                         "4 x scan(ME)"]
     largest = max(phases, key=lambda p: p.nbytes)
     assert largest.name == "4 x scan(ME)"
     # the sum the rule took until now: every block input, the head and the
     # largest block beside every gradient
-    summed = gpt2.model_working_set(base, 13) + max(
+    summed = blocks.model_working_set(base, 13) + max(
         k.block_bytes for k in kinds.values())
     assert summed - largest.nbytes > 2 ** 30
     # (the moments in the benchmark's optimizer are bfloat16: 12 B a parameter
     # with the gradients)
     resident = 3 * nbytes(params)
-    policy = gpt2.choose_remat_policy_kinds(
+    policy = blocks.choose_remat_policy_kinds(
         tuple(kinds.values()), largest.nbytes, family.V5E_BYTES_LIMIT, resident)
     assert 2 ** 30 <= policy.budget_bytes <= 1.25 * 2 ** 30
     assert 0 < policy.saved_bytes <= policy.budget_bytes
-    assert gpt2.choose_remat_policy_kinds(
+    assert blocks.choose_remat_policy_kinds(
         tuple(kinds.values()), summed, family.V5E_BYTES_LIMIT, resident
     ).saved == ()
     # the order taken: Δ's projection (one MXU pass for 4 bytes a head), the
@@ -1003,14 +1003,14 @@ def test_the_hybrids_loss_and_every_gradient_equal_whatever_is_saved(admits):
     params = nh.init(cfg, jax.random.PRNGKey(23))
     tokens, targets = _batch(cfg)
     base, kinds = nh.kind_shards(cfg, 2, cfg.seq_len, None)
-    runs = gpt2.pattern_groups(cfg.pattern) + gpt2.pattern_groups(cfg.mtp_pattern)
+    runs = blocks.pattern_groups(cfg.pattern) + blocks.pattern_groups(cfg.mtp_pattern)
     ranked = sorted(((c, k.applications) for k in kinds.values()
                      for c in k.candidates),
                     key=lambda cn: -cn[0].flops / cn[0].nbytes)
-    phase = max(gpt2.backward_phases(base, kinds, runs), key=lambda p: p.nbytes)
+    phase = max(blocks.backward_phases(base, kinds, runs), key=lambda p: p.nbytes)
     limit = None
     if cfg.remat:
-        limit = (gpt2.REMAT_RESERVE_BYTES + phase.nbytes + 12345 + sum(
+        limit = (blocks.REMAT_RESERVE_BYTES + phase.nbytes + 12345 + sum(
             n * c.nbytes for c, n in ranked[:_ADMITS[admits]]))
 
     def loss(p, cfg=cfg, limit=limit):
@@ -1027,7 +1027,7 @@ def test_the_hybrids_loss_and_every_gradient_equal_whatever_is_saved(admits):
                                       np.asarray(w, np.float32),
                                       err_msg=jax.tree_util.keystr(path))
     if cfg.remat:
-        (d,) = [d for d in gpt2.remat_policy_decisions()
+        (d,) = [d for d in blocks.remat_policy_decisions()
                 if d["bytes_limit"] == limit and d["seq"] == cfg.seq_len]
         assert d["saved"] == [n for c, _ in ranked[:_ADMITS[admits]]
                               for n in c.names]

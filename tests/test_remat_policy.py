@@ -19,7 +19,7 @@ import pytest
 
 from ray_tpu import tracing
 from ray_tpu.core.config import _config
-from ray_tpu.models import gpt2, llama
+from ray_tpu.models import blocks, gpt2, llama, parts
 from ray_tpu.ops import attention
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tracing import names
@@ -35,9 +35,9 @@ V5E_BYTES_LIMIT = 16909336064          # memory_stats()["bytes_limit"] of a v5e 
 
 # one chip's share of `gpt2-xl.fsdp4-dataset` (8 rows of the 32) and of a
 # `gpt2-124m` step with the same rows
-XL = gpt2.BlockShard(batch=8, seq=1024, d_model=1600, heads=25, head_dim=64,
-                     d_ff=6400, vocab=50304, dtype_bytes=2, flash=True,
-                     dense_mlp=True)
+XL = parts.BlockShard(batch=8, seq=1024, d_model=1600, heads=25, head_dim=64,
+                      d_ff=6400, vocab=50304, dtype_bytes=2, flash=True,
+                      dense_mlp=True)
 XL_LAYERS = 48
 # its placed state (f32 parameters, two bf16 moments) and the gradients, a chip
 XL_RESIDENT = 4_677_897_000
@@ -65,9 +65,9 @@ def _limit_admitting(shard, n_layer, resident, groups):
     """The smallest bytes_limit whose budget holds the first `groups`
     candidates of `shard`."""
     need = sum(n_layer * c.nbytes
-               for c in gpt2.remat_candidates(shard)[:groups])
-    return (gpt2.REMAT_RESERVE_BYTES + resident
-            + gpt2.rematted_working_set(shard, n_layer) + need)
+               for c in parts.remat_candidates(shard)[:groups])
+    return (blocks.REMAT_RESERVE_BYTES + resident
+            + parts.rematted_working_set(shard, n_layer) + need)
 
 
 RULE_CASES = {
@@ -97,9 +97,9 @@ RULE_CASES = {
 @pytest.mark.parametrize("case", list(RULE_CASES))
 def test_rule_takes_names_in_order_while_they_fit(case):
     shard, n_layer, limit, resident, want = RULE_CASES[case]
-    policy = gpt2.choose_remat_policy(shard, n_layer, limit, resident)
+    policy = parts.choose_remat_policy(shard, n_layer, limit, resident)
     assert policy.saved == want
-    candidates = gpt2.remat_candidates(shard)
+    candidates = parts.remat_candidates(shard)
     sizes = {c.names: n_layer * c.nbytes for c in candidates}
     frees = {c.names: c.frees for c in candidates}
     taken = [g for g in sizes if set(g) <= set(policy.saved)]
@@ -113,9 +113,9 @@ def test_rule_takes_names_in_order_while_they_fit(case):
     assert policy.bytes_limit == limit
     # reserve respected: everything counted — the working set less what the
     # kept names freed of it — still leaves it free ...
-    counted = (resident + gpt2.rematted_working_set(shard, n_layer)
+    counted = (resident + parts.rematted_working_set(shard, n_layer)
                - sum(frees[g] for g in taken) + policy.saved_bytes)
-    assert counted + gpt2.REMAT_RESERVE_BYTES <= limit or not policy.saved
+    assert counted + blocks.REMAT_RESERVE_BYTES <= limit or not policy.saved
     # ... and nothing that was left out would have fitted
     for g, size in sizes.items():
         if g not in taken:
@@ -128,24 +128,24 @@ def test_rule_on_the_cells_shards_byte_for_byte():
     reaches the working set or the order. The EvaByte cell's is the set its
     step compiles with, beside the rows its MLP and head take."""
     limit = 16_909_334_528          # a v5e's, as the chip states it
-    assert gpt2.rematted_working_set(XL, XL_LAYERS) == 5_457_362_944
-    resident = limit - gpt2.REMAT_RESERVE_BYTES - 5_457_362_944 - 5_700_332_148
-    assert gpt2.choose_remat_policy(XL, XL_LAYERS, limit, resident) == (
+    assert parts.rematted_working_set(XL, XL_LAYERS) == 5_457_362_944
+    resident = limit - blocks.REMAT_RESERVE_BYTES - 5_457_362_944 - 5_700_332_148
+    assert parts.choose_remat_policy(XL, XL_LAYERS, limit, resident) == (
         FLASH + QKV, 5_072_486_400, 5_700_332_148, limit)
-    assert all(c.frees == 0 for c in gpt2.remat_candidates(XL))
+    assert all(c.frees == 0 for c in parts.remat_candidates(XL))
 
     assert (EVA.mlp_rows, EVA.head_rows) == (4096, 4096)
-    policy = gpt2.choose_remat_policy(EVA, EVA_LAYERS, limit, EVA_RESIDENT)
+    policy = parts.choose_remat_policy(EVA, EVA_LAYERS, limit, EVA_RESIDENT)
     assert policy.saved == EVA_KEPT
     # k, and two summaries a sixteenth its size, in four layers
     assert policy.saved_bytes == 4 * (32768 * 4096 * 2) * 9 // 8
-    k, q = gpt2.remat_candidates(EVA)[:2]
+    k, q = parts.remat_candidates(EVA)[:2]
     assert (k.names, q.names) == ((names.RES_K,), (names.RES_Q,))
     # float32 k: what the summary pass reads, gone from the set with k kept
     assert (k.frees, q.frees) == (32768 * 4096 * 4, 0)
     assert policy.budget_bytes == (
-        limit - gpt2.REMAT_RESERVE_BYTES - EVA_RESIDENT
-        - gpt2.rematted_working_set(EVA, EVA_LAYERS) + k.frees)
+        limit - blocks.REMAT_RESERVE_BYTES - EVA_RESIDENT
+        - parts.rematted_working_set(EVA, EVA_LAYERS) + k.frees)
 
 
 @pytest.mark.parametrize("shard, n_layer", [
@@ -157,11 +157,11 @@ def test_one_kind_in_one_scan_has_one_phase_and_it_is_the_sum(shard, n_layer):
     block input, the head's terms, the gathered embedding and a block — is
     `rematted_working_set` to the byte, whatever its layers' gradients take,
     and the head's is that less the block (and the gradients not yet made)."""
-    kind = gpt2.KindShard(n_layer, tuple(gpt2.remat_candidates(shard)),
-                          gpt2.block_working_set(shard))
-    want = gpt2.rematted_working_set(shard, n_layer)
+    kind = blocks.KindShard(n_layer, tuple(parts.remat_candidates(shard)),
+                            parts.block_working_set(shard))
+    want = parts.rematted_working_set(shard, n_layer)
     for grad_bytes in (0, 123_456_789):
-        head, scan = gpt2.backward_phases(
+        head, scan = blocks.backward_phases(
             shard, {"block": kind._replace(grad_bytes=grad_bytes)},
             [(("block",), n_layer)])
         assert scan == (f"{n_layer} x scan(block)", want)
@@ -171,10 +171,10 @@ def test_one_kind_in_one_scan_has_one_phase_and_it_is_the_sum(shard, n_layer):
 def _two_runs():
     """Two kinds in two runs — a scan of four `a` whose stacked gradients are
     large, then one `b` whose block is — on a shard whose head is large."""
-    C, K = gpt2.RematCandidate, gpt2.KindShard
-    shard = gpt2.BlockShard(batch=1, seq=64, d_model=32, heads=1, head_dim=32,
-                            d_ff=64, vocab=4096, dtype_bytes=2, flash=False,
-                            dense_mlp=False)
+    C, K = blocks.RematCandidate, blocks.KindShard
+    shard = parts.BlockShard(batch=1, seq=64, d_model=32, heads=1, head_dim=32,
+                             d_ff=64, vocab=4096, dtype_bytes=2, flash=False,
+                             dense_mlp=False)
     kinds = {"a": K(4, (C(("x",), 1_000, 4_000_000),), 50_000, 400_000),
              "b": K(1, (C(("y",), 1_000, 2_000_000),), 900_000, 10_000)}
     return shard, kinds, [("a", 4), ("b", 1)]
@@ -187,10 +187,10 @@ def test_phases_count_what_is_live_together_and_no_more():
     bytes count from the step's start and which are not made yet."""
     shard, kinds, runs = _two_runs()
     x = 64 * 32 * 2
-    model = gpt2.model_working_set(shard, 5)
-    head_terms = model - gpt2.model_working_set(shard._replace(vocab=0), 5) \
+    model = blocks.model_working_set(shard, 5)
+    head_terms = model - blocks.model_working_set(shard._replace(vocab=0), 5) \
         - 4096 * 32 * 6
-    assert gpt2.backward_phases(shard, kinds, runs) == [
+    assert blocks.backward_phases(shard, kinds, runs) == [
         ("head", model - 4 * 400_000 - 10_000),
         ("b", model + 900_000 - 4 * 400_000),
         ("4 x scan(a)", model - x - head_terms + 50_000)]
@@ -202,16 +202,16 @@ def test_two_runs_whose_sum_does_not_fit_and_whose_largest_phase_does():
     head's terms and the largest block, as the rule counted until PR 42 —
     it would hold neither."""
     shard, kinds, runs = _two_runs()
-    phases = gpt2.backward_phases(shard, kinds, runs)
+    phases = blocks.backward_phases(shard, kinds, runs)
     largest = max(p.nbytes for p in phases)
-    summed = gpt2.model_working_set(shard, 5) + 900_000
+    summed = blocks.model_working_set(shard, 5) + 900_000
     resident = 3 * (4 * 400_000 + 10_000)
-    limit = gpt2.REMAT_RESERVE_BYTES + resident + largest + 5_000
+    limit = blocks.REMAT_RESERVE_BYTES + resident + largest + 5_000
     assert summed > largest + 5_000
-    kept = gpt2.choose_remat_policy_kinds(
+    kept = blocks.choose_remat_policy_kinds(
         tuple(kinds.values()), largest, limit, resident)
-    assert kept == gpt2.RematPolicy(("x", "y"), 5_000, 5_000, limit)
-    assert gpt2.choose_remat_policy_kinds(
+    assert kept == blocks.RematPolicy(("x", "y"), 5_000, 5_000, limit)
+    assert blocks.choose_remat_policy_kinds(
         tuple(kinds.values()), summed, limit, resident).saved == ()
 
 
@@ -226,7 +226,7 @@ def test_two_runs_whose_sum_does_not_fit_and_whose_largest_phase_does():
 def test_mlp_rows_come_from_the_blocks_shapes(block, rows):
     """The whole sequence while one hidden tensor stays under 256 MiB; past
     that, chunks whose five hidden tensors take what two [B, S, D] do."""
-    assert llama._mlp_rows(*block) == rows
+    assert parts.mlp_rows(*block) == rows
     batch, seq, d_model, d_ff, a = block
     if rows < seq:
         assert 5 * batch * rows * d_ff * a <= 2 * batch * seq * d_model * a
@@ -239,21 +239,21 @@ def test_candidates_are_ordered_by_recompute_flops_per_byte():
     the sequence is short against the width; equal ones keep the block's
     order; lse rides with o."""
     for shard in (XL, SMALL):
-        cands = gpt2.remat_candidates(shard)
+        cands = parts.remat_candidates(shard)
         assert [c.names for c in cands] == [
             FLASH, (names.RES_Q,), (names.RES_K,), (names.RES_V,),
             (names.RES_MID,), (names.RES_MLP_HIDDEN,)]
         ratios = [c.flops / c.nbytes for c in cands]
         assert ratios == sorted(ratios, reverse=True)
-    short = gpt2.remat_candidates(XL._replace(seq=256))
+    short = parts.remat_candidates(XL._replace(seq=256))
     assert short[-1].names == FLASH
     # bytes from the shapes: bf16 [8, 25, 1024, 64] and f32 [8, 25, 1024]
-    by_name = {c.names: c.nbytes for c in gpt2.remat_candidates(XL)}
+    by_name = {c.names: c.nbytes for c in parts.remat_candidates(XL)}
     assert by_name[(names.RES_Q,)] == 8 * 25 * 1024 * 64 * 2
     assert by_name[FLASH] == 8 * 25 * 1024 * (64 * 2 + 4)
     assert by_name[(names.RES_MLP_HIDDEN,)] == 8 * 1024 * 6400 * 2
     # a tensor that does not exist is no candidate
-    no_flash = gpt2.remat_candidates(XL._replace(flash=False, dense_mlp=False))
+    no_flash = parts.remat_candidates(XL._replace(flash=False, dense_mlp=False))
     assert [c.names for c in no_flash] == [
         (names.RES_Q,), (names.RES_K,), (names.RES_V,), (names.RES_MID,)]
 
@@ -296,7 +296,7 @@ def _kernel_calls(jaxpr, name, times=1):
 def _decision(n_layer, batch, bytes_limit):
     """The one recorded decision for these facts (a repeated decision is
     recorded once a process, so the newest need not be this trace's)."""
-    (d,) = [d for d in gpt2.remat_policy_decisions()
+    (d,) = [d for d in blocks.remat_policy_decisions()
             if (d["n_layer"], d["batch"], d["bytes_limit"])
             == (n_layer, batch, bytes_limit)]
     return d
@@ -498,7 +498,7 @@ def test_step_factory_states_the_chip_and_cpu_states_no_limit(monkeypatch):
     # decisions are recorded once a process, by their facts: whatever this
     # worker traced before (another file's tiny model, two rows, no limit)
     # is not this test's
-    monkeypatch.setattr(gpt2, "_decisions", {})
+    monkeypatch.setattr(blocks, "_decisions", {})
     cfg = gpt2.gpt2_tiny(remat=True, attention_impl="pallas")
     bundle = make_gpt2_train_step(cfg)
     limit, resident = train_step._chip_memory(bundle.mesh, bundle.state)
@@ -554,7 +554,7 @@ def test_remat_policy_event_once_a_distinct_decision(buffer):
         # GPT-2's block takes the sequence whole
         assert (e["args"]["mlp_rows"], e["args"]["head_rows"]) == (
             cfg.seq_len, cfg.seq_len)
-    mine = [d for d in gpt2.remat_policy_decisions()
+    mine = [d for d in blocks.remat_policy_decisions()
             if d["bytes_limit"] in (first, second)]
     assert [d["bytes_limit"] for d in mine] == [first, second]
 
@@ -563,12 +563,12 @@ def test_no_remat_asks_no_rule_and_records_nothing(buffer):
     cfg = gpt2.gpt2_tiny(attention_impl="pallas")
     params = gpt2.init(cfg, jax.random.PRNGKey(0))
     batch = synthetic_batch(cfg, 2)
-    before = len(gpt2.remat_policy_decisions())
+    before = len(blocks.remat_policy_decisions())
     with mesh_lib.chip_memory(5 * GIB + 1, 0):
         jax.make_jaxpr(jax.grad(
             lambda p: gpt2.loss_fn(p, batch["tokens"], batch["targets"], cfg)
         ))(params)
-    assert len(gpt2.remat_policy_decisions()) == before
+    assert len(blocks.remat_policy_decisions()) == before
 
 
 @pytest.mark.parametrize("value", ["dots", "full", 1, None])

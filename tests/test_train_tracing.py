@@ -147,18 +147,18 @@ def test_nemotron_step_records_its_pattern_and_its_expert_load(buffer):
     but the selection biases changed."""
     import jax
 
-    from ray_tpu.models import gpt2, nemotron_h
+    from ray_tpu.models import blocks, nemotron_h
     from ray_tpu.train.train_step import synthetic_batch
 
     _lowering("nemotron")
     cfg = nemotron_h.nemotron_h_tiny()
-    by = {d["pattern"]: d for d in gpt2.layer_pattern_decisions()}
+    by = {d["pattern"]: d for d in blocks.layer_pattern_decisions()}
     assert tuple(by[cfg.pattern]) == names.LAYER_PATTERN_ARGS
     assert by[cfg.pattern]["applications"] == {"M": 2, "E": 3, "*": 1}
     assert by[cfg.pattern]["groups"] == ["2 x scan(ME)", "*", "E"]
     assert by[cfg.mtp_pattern]["groups"] == ["*", "E"]
     assert any((d["n_layer"], d["batch"], d["seq"]) == (8, 2, cfg.seq_len)
-               for d in gpt2.remat_policy_decisions())
+               for d in blocks.remat_policy_decisions())
     batch = synthetic_batch(cfg, 2)
     params = nemotron_h.init(cfg, jax.random.PRNGKey(0))
     balanced, loads = nemotron_h.balance_router_bias(
@@ -224,7 +224,7 @@ def test_kernel_name_in_jaxpr(kernel):
 def test_eva_tiling_decision_of_the_lowered_step(kernel):
     """Tracing the EvaByte step leaves an `ops/eva_tiling` decision per
     kernel, with the vocabulary's args, and a `model/remat_policy` one."""
-    from ray_tpu.models import gpt2, llama
+    from ray_tpu.models import blocks, llama
     from ray_tpu.ops import eva_attention
 
     _lowering("eva")
@@ -240,7 +240,7 @@ def test_eva_tiling_decision_of_the_lowered_step(kernel):
                for d in mine)
     assert any((d["n_layer"], d["batch"], d["seq"])
                == (cfg.n_layer, 2, cfg.seq_len)
-               for d in gpt2.remat_policy_decisions())
+               for d in blocks.remat_policy_decisions())
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
