@@ -16,7 +16,11 @@ gradient made in the forward, PR 39), and one step of a small
 Nemotron-H hybrid (``models/nemotron_h.py``: Mamba-2, LatentMoE and attention
 layers and the MTP module) for its ``model/layer_pattern`` and
 ``model/expert_load`` events and the ``ops/ssd_tiling`` decisions of its
-state-space scan's kernel pair (PR 41), and — what the expert layer's chosen-set mask
+state-space scan's kernel pair (PR 41), one step of a small MiniCPM-SALA
+(``models/minicpm_sala.py``: three lightning layers on that scan's kernels at
+one head a group and one block-sparse layer past its ``dense_len``, PR 47) for
+its ``LLLS`` pattern, its ``model/sparse_selection`` event and the
+``ops/sparse_tiling`` decisions of its three attention kernels, and — what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
 check_training/check_device) and, after ``shutdown()``, prints from the
@@ -277,6 +281,34 @@ def train_loop(config: Dict[str, Any]) -> None:
                   "expert_load": load,
                   "chosen_rows_off": chosen_rows_off(config["seed"])}
         del variant
+    # One step of a linear / block-sparse attention hybrid through the same
+    # factory: its pattern, the scan's tiling at one head a group, what its
+    # sparse layer's selection is and how the three attention kernels tile.
+    sala = None
+    if config.get("sala_model") is not None:
+        from ray_tpu.models import minicpm_sala
+        from ray_tpu.models.blocks import layer_pattern_decisions
+        from ray_tpu.ops.mamba2 import ssd_tiling_decisions
+        from ray_tpu.ops.sparse_attention import sparse_tiling_decisions
+
+        sala_cfg = config["sala_model"]
+        variant = make_train_step(
+            minicpm_sala, sala_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP, total_steps=steps))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, sala_cfg.seq_len), dtype=np.int32)
+        _, m = variant.step_fn(variant.state, jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding))
+        sala = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+                "seq_len": sala_cfg.seq_len,
+                "layer_pattern": [d for d in layer_pattern_decisions()
+                                  if d["pattern"] == sala_cfg.pattern],
+                "ssd_tiling": [d for d in ssd_tiling_decisions()
+                               if d["group_heads"] == 1],
+                "sparse_selection": minicpm_sala.sparse_selection_decisions(),
+                "sparse_tiling": sparse_tiling_decisions()}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -303,12 +335,13 @@ def train_loop(config: Dict[str, Any]) -> None:
         "parity": parity,
         "eva": eva,
         "hybrid": hybrid,
+        "sala": sala,
     }})
 
 
 def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         use_tpu: bool, seed: int = 0, eva_model=None,
-        hybrid_model=None) -> List[Dict[str, Any]]:
+        hybrid_model=None, sala_model=None) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -330,6 +363,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "model": model_cfg, "steps": steps,
             "per_chip_batch": per_chip_batch, "seed": seed,
             "eva_model": eva_model, "hybrid_model": hybrid_model,
+            "sala_model": sala_model,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -413,6 +447,25 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
                        f"{hybrid['chosen_rows_off']} of {ROUTER_SHAPE[0]} "
                        "rows with ties: this backend's top_k does not list "
                        "equal elements in index order")
+    sala = summary.get("sala")
+    if sala is not None:
+        if not (math.isfinite(sala["loss"]) and math.isfinite(sala["grad_norm"])):
+            bad.append(f"the MiniCPM-SALA step's loss {sala['loss']} or "
+                       f"grad_norm {sala['grad_norm']} is not finite")
+        if not sala["layer_pattern"]:
+            bad.append("the MiniCPM-SALA step recorded no model/layer_pattern "
+                       "event for its pattern")
+        if {d["kernel"] for d in sala["ssd_tiling"]} != {"fwd", "bwd"}:
+            bad.append("the MiniCPM-SALA step recorded no ops/ssd_tiling "
+                       "decision at one head a group for both scan kernels")
+        if not any(d["mode"] == "sparse" for d in sala["sparse_selection"]):
+            bad.append("the MiniCPM-SALA step recorded no model/"
+                       "sparse_selection event on the sparse branch")
+        kernels = {d["kernel"] for d in sala["sparse_tiling"]}
+        if kernels != {"fwd", "bwd_dq", "bwd_dkv"}:
+            bad.append("the MiniCPM-SALA step recorded ops/sparse_tiling "
+                       f"decisions for {sorted(kernels)}, not for its three "
+                       "kernels")
     return bad
 
 
@@ -504,6 +557,18 @@ def session_story(trace: List[Dict[str, Any]]) -> Tuple[List[str], List[str]]:
     return lines, failures
 
 
+def print_pattern_and_scan(step: Dict[str, Any]) -> None:
+    """A pattern step's `model/layer_pattern` and `ops/ssd_tiling` events."""
+    for d in step["layer_pattern"]:
+        print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
+              f"{d['groups']}")
+    for d in step["ssd_tiling"]:
+        print(f"ssd tiling: {d['kernel']} rows={d['rows']} S={d['S']} "
+              f"Q={d['Q']} heads a group={d['group_heads']} P={d['P']} "
+              f"N={d['N']} -> {d['head_tile']} heads a grid step, "
+              f"vmem_estimate={d['vmem_estimate']}")
+
+
 def _driver_backend_initialised() -> bool:
     jax = sys.modules.get("jax")
     return jax is not None and jax._src.xla_bridge.backends_are_initialized()
@@ -514,7 +579,8 @@ def main() -> int:
 
     import ray_tpu
     from ray_tpu.core.resources import tpu_device_files
-    from ray_tpu.models import gpt2, llama, nemotron_h
+    from ray_tpu.models import gpt2, llama, minicpm_sala, nemotron_h
+    from ray_tpu.ops.sparse_attention import SparseSizes
 
     model_cfg = gpt2.gpt2_124m()
     # Nemotron-H's three kinds of layer at a quarter of the width: one short
@@ -528,6 +594,12 @@ def main() -> int:
     # 2,048 bytes, so the second window's queries see 128 summaries
     eva_cfg = llama.evabyte_6p5b(n_layer=2, n_head=8, n_kv_head=8, d_model=1024,
                                  d_ff=2816, seq_len=4096, remat=True)
+    # MiniCPM-SALA's period at a quarter of the width: heads of 128, rows of
+    # 4,096 tokens past a dense_len of 2,048, 16 of 64 blocks a query
+    sala_cfg = minicpm_sala.MiniCPMSALAConfig(
+        vocab_size=4096, seq_len=4096, pattern="LLLS", d_model=1024, d_ff=4096,
+        lightning_heads=4, lightning_heads_published=8, n_head=4, n_kv_head=1,
+        sparse=SparseSizes(top_k=16, window=512, dense_len=2048), remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -539,7 +611,7 @@ def main() -> int:
             return 2
         rows = run(model_cfg, steps=STEPS, per_chip_batch=PER_CHIP_BATCH,
                    num_devices=chips, use_tpu=True, eva_model=eva_cfg,
-                   hybrid_model=hybrid_cfg)
+                   hybrid_model=hybrid_cfg, sala_model=sala_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -595,14 +667,7 @@ def main() -> int:
           f"grad_norm {eva['grad_norm']:.4f}; instructions the compiler "
           f"rematerialized by itself: {eva['compiler_rematerialized']}")
     hybrid = summary["hybrid"]
-    for d in hybrid["layer_pattern"]:
-        print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
-              f"{d['groups']}")
-    for d in hybrid["ssd_tiling"]:
-        print(f"ssd tiling: {d['kernel']} rows={d['rows']} S={d['S']} "
-              f"Q={d['Q']} heads a group={d['group_heads']} P={d['P']} "
-              f"N={d['N']} -> {d['head_tile']} heads a grid step, "
-              f"vmem_estimate={d['vmem_estimate']}")
+    print_pattern_and_scan(hybrid)
     for e in hybrid["expert_load"]:
         print(f"expert load: layer {e['layer']}: {e['pairs']} pairs of "
               f"{e['tokens']} tokens on the held experts (max "
@@ -618,6 +683,23 @@ def main() -> int:
           f"of {hybrid_cfg.d_model}, {summary['device_count']}x"
           f"{hybrid['seq_len']} tokens, remat): loss {hybrid['loss']:.4f} "
           f"grad_norm {hybrid['grad_norm']:.4f}")
+    sala = summary["sala"]
+    print_pattern_and_scan(sala)
+    for d in sala["sparse_selection"]:
+        print(f"sparse selection: rows={d['rows']} S={d['S']}: {d['mode']}, "
+              f"{d['top_k']} of {d['blocks']} blocks a query "
+              f"({d['window_blocks']} the window's, {d['init_blocks']} "
+              f"initial), dense up to {d['dense_len']}; "
+              f"{100 * d['kept_share']:.1f} % of the visible keys kept")
+    for d in sala["sparse_tiling"]:
+        print(f"sparse tiling: {d['kernel']} rows={d['rows']} S={d['S']} "
+              f"heads a group={d['group_heads']} hd={d['hd']} block="
+              f"{d['block']} x {d['blocks_per_query']} a query -> "
+              f"{d['block_q']} tokens x {d['block_k']} keys a tile, "
+              f"vmem_estimate={d['vmem_estimate']}")
+    print(f"MiniCPM-SALA step ({sala_cfg.pattern} of {sala_cfg.d_model}, "
+          f"{summary['device_count']}x{sala['seq_len']} tokens, remat): loss "
+          f"{sala['loss']:.4f} grad_norm {sala['grad_norm']:.4f}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

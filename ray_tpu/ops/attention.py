@@ -98,6 +98,10 @@ class Tiling(NamedTuple):
 # Mosaic's scoped-VMEM limit for one kernel on the chips this runs on; the
 # rule holds vmem_estimate() under it.
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
+# What a kernel may be given beyond that default (the EVA, scan and
+# sparse-attention kernels ask for their own estimate and a margin, never for
+# more than this): a v5e core has 128 MiB of VMEM.
+VMEM_CEILING_BYTES = 96 * 2 ** 20
 # The q and kv tile both kernels want, and the smallest the rule falls back
 # to: choose_tiling's docstring says where they come from.
 _TARGET_TILE = 512
@@ -108,7 +112,7 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _vmem_block_bytes(shape, itemsize: int) -> int:
+def vmem_block_bytes(shape, itemsize: int) -> int:
     """Bytes one block takes in VMEM: its last two dims are stored in
     (sublane, lane) tiles of 8 × 128 32-bit words (16 rows of bf16) and padded
     up to whole tiles — a [1024, 64] bf16 block takes what [1024, 128] does."""
@@ -123,7 +127,7 @@ def vmem_estimate(kernel: str, block_q: int, block_k: int,
     block twice (Pallas double-buffers them), the backward's f32 dq
     accumulator once, and one [block_k, block_q] f32 logits tile plus the
     loop's f32 accumulators. An upper bound, not Mosaic's own figure."""
-    blk = _vmem_block_bytes
+    blk = vmem_block_bytes
     tile = blk((block_k, block_q), 4)
     if kernel == "fwd":
         io = (2 * blk((block_q, hd), dtype_bytes)         # q, o

@@ -51,14 +51,10 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import (
-    _NEG_INF, VMEM_BUDGET_BYTES, Tiling, _pick_block, _vmem_block_bytes,
-    batch_head_axes, choose_tiling, record_decision, resolve_attention)
+    _NEG_INF, VMEM_BUDGET_BYTES, VMEM_CEILING_BYTES, Tiling, _pick_block,
+    batch_head_axes, choose_tiling, record_decision, resolve_attention,
+    vmem_block_bytes)
 from ray_tpu.tracing import names
-
-# what Mosaic may be given beyond its default scoped limit (VMEM_BUDGET_BYTES):
-# a v5e core has 128 MiB of VMEM; the kernels ask for their own estimate and
-# a margin, never for more than this
-_VMEM_CEILING_BYTES = 96 * 2 ** 20
 
 
 # --------------------------------------------------------------------------- #
@@ -133,7 +129,7 @@ def _tiling(kernel: str, rows: int, S: int, hd: int, dtype_bytes: int,
     Recorded once a distinct decision (``ops/eva_tiling``)."""
     t = choose_tiling(kernel, window, window, hd, dtype_bytes)
     N = S // chunk
-    blk = _vmem_block_bytes
+    blk = vmem_block_bytes
     extra = 2 * blk((N, hd), dtype_bytes)                  # kt, vt
     if kernel == "bwd":
         # dkt, dvt out and their f32 accumulators; the window's k, v, dk, dv
@@ -154,7 +150,7 @@ def _compiler_params(estimate: int):
     if estimate <= VMEM_BUDGET_BYTES:
         return None
     return pltpu.CompilerParams(
-        vmem_limit_bytes=min(_VMEM_CEILING_BYTES, estimate + estimate // 2))
+        vmem_limit_bytes=min(VMEM_CEILING_BYTES, estimate + estimate // 2))
 
 
 # --------------------------------------------------------------------------- #

@@ -48,9 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as PSpec
 
 from ray_tpu.ops.attention import (
-    VMEM_BUDGET_BYTES, _vmem_block_bytes, batch_head_axes, record_decision,
-    resolve_attention)
-from ray_tpu.ops.eva_attention import _VMEM_CEILING_BYTES
+    VMEM_BUDGET_BYTES, VMEM_CEILING_BYTES, batch_head_axes, record_decision,
+    resolve_attention, vmem_block_bytes)
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tracing import names as scopes
 
@@ -151,7 +150,7 @@ def _vmem_estimate(kernel: str, Q: int, ht: int, P: int, N: int,
     once, and the float32 values the body holds at once — [Q, ht·P] spreads
     and products, a head's [Q, Q] tiles. An upper bound, not Mosaic's own
     figure."""
-    blk, a, W = _vmem_block_bytes, dtype_bytes, ht * P
+    blk, a, W = vmem_block_bytes, dtype_bytes, ht * P
     wide, tile, state = blk((Q, W), 4), blk((Q, Q), 4), blk((N, W), 4)
     spreads = (blk((_TERMS * ht, W), 2) + ht * blk((_TERMS * ht, Q), 2)
                + blk((W, ht), 2))                          # the 0/1 matrices
@@ -173,7 +172,7 @@ def choose_ssd_tiling(kernel: str, rows: int, S: int, Q: int, hg: int, P: int,
     (they share the chunk's C·Bᵀ, and the state's products take them all at
     once). The tile is the largest divisor of the group's heads, at most
     _MAX_HEAD_TILE, whose estimate fits half of what a kernel may be given
-    (_VMEM_CEILING_BYTES; past Mosaic's default the call raises its limit, as
+    (VMEM_CEILING_BYTES; past Mosaic's default the call raises its limit, as
     the EVA kernels do); among those, one whose x block is whole 128-lane
     tiles (``head_tile · P``) before one that is not. A shape of which not
     even one head fits is refused. Recorded once a distinct decision
@@ -183,13 +182,13 @@ def choose_ssd_tiling(kernel: str, rows: int, S: int, Q: int, hg: int, P: int,
     tiles = [t for t in range(min(hg, _MAX_HEAD_TILE), 0, -1) if hg % t == 0]
     estimate = functools.partial(_vmem_estimate, kernel, Q, P=P, N=N,
                                  dtype_bytes=dtype_bytes)
-    fit = [t for t in tiles if estimate(ht=t) <= _VMEM_CEILING_BYTES // 2]
+    fit = [t for t in tiles if estimate(ht=t) <= VMEM_CEILING_BYTES // 2]
     if not fit:
         raise ValueError(
             f"ssd_scan {kernel}: one head of a chunk does not fit VMEM for "
             f"chunk Q={Q} head width P={P} state N={N} ({dtype_bytes}-byte "
             f"operands): estimated at {estimate(ht=1)} bytes of "
-            f"{_VMEM_CEILING_BYTES // 2}; use a smaller chunk")
+            f"{VMEM_CEILING_BYTES // 2}; use a smaller chunk")
     ht = next((t for t in fit if (t * P) % 128 == 0), fit[0])
     tiling = SsdTiling(ht, estimate(ht=ht))
     record_decision(_decisions, scopes.SSD_TILING, dict(zip(
@@ -524,7 +523,7 @@ def _chunks_call(kernel: str, x, dt, cum, Bm, Cm, G: int, Q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=None if estimate <= VMEM_BUDGET_BYTES else min(
-                _VMEM_CEILING_BYTES, estimate + estimate // 2)),
+                VMEM_CEILING_BYTES, estimate + estimate // 2)),
         interpret=interpret,
         name=scopes.SSD_CHUNK_FWD_KERNEL if fwd else scopes.SSD_CHUNK_BWD_KERNEL,
     )(*args)

@@ -43,11 +43,21 @@ MOE_LATENT = "moe_latent"
 MOE_SHARED = "moe_shared"
 # models/nemotron_h.py: the multi-token-prediction module, its layers and loss
 MTP = "mtp"
+# models/minicpm_sala.py: the lightning (decayed linear-attention) mixer —
+# projections, QK-norm, RoPE, the output norm and gate — around SSD_SCAN, whose
+# kernels run its recurrence; and the block-sparse mixer (ops/
+# sparse_attention.py) with, inside it, the selection alone: compressed keys,
+# their scores, pooling to blocks and `top_k` (XLA) — the two kernels are
+# under SPARSE_ATTENTION and outside SPARSE_SELECT
+LIGHTNING_ATTN = "lightning_attn"
+SPARSE_ATTENTION = "sparse_attention"
+SPARSE_SELECT = "sparse_select"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
                                           EVA_PREP_KV, MAMBA, SSD_SCAN,
                                           MOE_ROUTED, MOE_DISPATCH, MOE_LATENT,
-                                          MOE_SHARED, MTP)
+                                          MOE_SHARED, MTP, LIGHTNING_ATTN,
+                                          SPARSE_ATTENTION, SPARSE_SELECT)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -66,9 +76,18 @@ RAGGED_DOT_KERNEL = "ragged-dot"
 # along the row's chunks — and the same tiles' gradients, the chunks reversed
 SSD_CHUNK_FWD_KERNEL = "ssd_chunk_fwd"
 SSD_CHUNK_BWD_KERNEL = "ssd_chunk_bwd"
+# attention over the key blocks each query was given (ops/
+# sparse_attention.py): a tile is the 16 query heads of one key-value head for
+# a run of tokens against a run of key blocks, masked by who chose what. The
+# backward is two calls, each with a name of its own (a trace's reader knows
+# a kernel by the name listed here, so a third pass must be listed too)
+SPARSE_ATTN_FWD_KERNEL = "sparse_attn_fwd"
+SPARSE_ATTN_BWD_DQ_KERNEL = "sparse_attn_bwd_dq"
+SPARSE_ATTN_BWD_DKV_KERNEL = "sparse_attn_bwd_dkv"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
-           SSD_CHUNK_BWD_KERNEL)
+           SSD_CHUNK_BWD_KERNEL, SPARSE_ATTN_FWD_KERNEL,
+           SPARSE_ATTN_BWD_DQ_KERNEL, SPARSE_ATTN_BWD_DKV_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
@@ -83,6 +102,24 @@ EVA_TILING_ARGS = FLASH_TILING_ARGS + ("window", "chunk")
 SSD_TILING = "ops/ssd_tiling"
 SSD_TILING_ARGS = ("kernel", "rows", "S", "Q", "group_heads", "P", "N",
                    "head_tile", "vmem_estimate")
+# the same for a block-sparse attention kernel ("fwd", "bwd_dq", "bwd_dkv"):
+# the (batch x key-value head) rows, the sequence, the query heads that share
+# a key-value head (the rows of a tile's products, with `block_q` tokens),
+# the head width, the key block the selection is made in and how many a
+# query is given, and the tile: tokens of queries x keys
+SPARSE_TILING = "ops/sparse_tiling"
+SPARSE_TILING_ARGS = ("kernel", "rows", "S", "group_heads", "hd", "block",
+                      "blocks_per_query", "block_q", "block_k",
+                      "vmem_estimate")
+# what a block-sparse mixer's selection is, once a distinct shape (models/
+# minicpm_sala.py): rows and sequence, the key blocks there are, how many a
+# query is given of them, how many of those are forced (the window's and the
+# initial ones), the row length up to which attention stays dense, which of
+# the two this trace runs, and the share of a row's visible keys that are
+# kept (1.0 dense)
+SPARSE_SELECTION = "model/sparse_selection"
+SPARSE_SELECTION_ARGS = ("rows", "S", "blocks", "top_k", "window_blocks",
+                         "init_blocks", "dense_len", "mode", "kept_share")
 
 # the block's residuals a `remat=True` checkpoint may keep, one name a tensor
 # (`jax.ad_checkpoint.checkpoint_name`; an identity outside such a checkpoint):
@@ -112,11 +149,25 @@ RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN = "moe_latent_in", "moe_shared_hidden"
 RES_MOE_SCORES = "moe_scores"
 RES_MOE_KTH, RES_MOE_LAST = "moe_kth", "moe_kth_index"
 RES_MOE_PAIR_KEY = "moe_pair_key"
+# a MiniCPM-SALA layer's (models/minicpm_sala.py). Both mixers name q, k, v
+# (RES_Q, RES_K, RES_V: after the QK-norm and, in the lightning one, RoPE) and
+# their output gate's pre-activation; the lightning one the scan's states and
+# its output before the output norm (RES_SSD_STATES, and its own name for y:
+# [B, S, H, P] in the compute dtype, not the Mamba mixer's merged one); the
+# sparse one the chosen block ids — small, and a scoring pass and a `top_k`
+# to make again — and the kernel's output and log-sum-exp. The SwiGLU half
+# names RES_MID, RES_MLP_GATE and RES_MLP_UP as the llama block does.
+RES_SALA_GATE = "sala_gate"
+RES_LIGHTNING_Y = "lightning_y"
+RES_SPARSE_IDS = "sparse_block_ids"
+RES_SPARSE_O, RES_SPARSE_LSE = "sparse_o", "sparse_lse"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
              RES_MLP_GATE, RES_MLP_UP, RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT,
              RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN,
-             RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY)
+             RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY,
+             RES_SALA_GATE, RES_LIGHTNING_Y, RES_SPARSE_IDS, RES_SPARSE_O,
+             RES_SPARSE_LSE)
 # which of them models/blocks.py chose to save, the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once),
 # and the phase of the backward whose working set the budget was left by

@@ -231,12 +231,30 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     eva_cfg = llama.evabyte_tiny(remat=True, attention_impl="pallas")
     from ray_tpu.models import nemotron_h
 
+    from ray_tpu.models import minicpm_sala
+
     hybrid_cfg = nemotron_h.nemotron_h_tiny(remat=True)
+    sala_cfg = minicpm_sala.minicpm_sala_tiny(remat=True)
     rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1,
                           num_devices=8, use_tpu=False, eva_model=eva_cfg,
-                          hybrid_model=hybrid_cfg)
+                          hybrid_model=hybrid_cfg, sala_model=sala_cfg)
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
+    # the MiniCPM-SALA step (PR 47): its pattern, the scan at one head a
+    # group, the selection on the sparse branch and the three kernels'
+    # tilings came back; and the check fails without them
+    sala = summary["sala"]
+    assert [d["groups"] for d in sala["layer_pattern"]] == [
+        ["3 x scan(L)", "S"]]
+    assert {d["kernel"] for d in sala["ssd_tiling"]} == {"fwd", "bwd"}
+    assert any(d["mode"] == "sparse" and d["S"] == sala_cfg.seq_len
+               for d in sala["sparse_selection"])
+    assert {d["kernel"] for d in sala["sparse_tiling"]} == {
+        "fwd", "bwd_dq", "bwd_dkv"}
+    bare = [rows[-1] | {"summary": summary | {"sala": sala | {
+        "layer_pattern": [], "ssd_tiling": [], "sparse_selection": [],
+        "sparse_tiling": []}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + bare, cfg, steps)) == 4
     # the hybrid step: both of its events came back, no pair dropped; and
     # the check fails without them
     hybrid = summary["hybrid"]

@@ -128,7 +128,9 @@ def test_eva_kernels_compile_for_the_v5e_at_the_cells_shape(one_chip):
     (8, 4096, 16, 64, 1, 128, 128),     # the Nemotron cell: 16 heads, 1 group
     (2, 2048, 128, 64, 2, 128, 256),    # 64 heads a group in four tiles, Q 256
     (2, 1024, 8, 64, 4, 128, 128),      # groups of two heads: a tile a group
-], ids=["nemotron-cell", "hg64-q256", "four-groups"])
+    (1, 16384, 16, 128, 16, 128, 128),  # the MiniCPM-SALA cell's lightning
+                                        # layers: one head a group, P = N
+], ids=["nemotron-cell", "hg64-q256", "four-groups", "one-head-a-group"])
 def test_scan_kernels_compile_for_the_v5e(one_chip, shape):
     """ops/mamba2's kernel pair (PR 41) at the tile the rule chooses, forward
     and backward, bf16: what interpret mode cannot show — a lane slice, a
@@ -157,6 +159,61 @@ def test_scan_kernels_compile_for_the_v5e(one_chip, shape):
     per_head = re.findall(rf"f32\[[0-9,]*{Q},{Q}\]", hlo)
     sizes = [math.prod(int(n) for n in t[4:-1].split(",")) for t in per_head]
     assert all(n <= B * (S // Q) * G * Q * Q for n in sizes), set(per_head)
+
+
+@pytest.mark.parametrize("S,top_k", [(16384, 64), (2048, 8)],
+                         ids=["minicpm-sala-cell", "short-row"])
+def test_sparse_attention_kernels_compile_for_the_v5e(one_chip, S, top_k):
+    """ops/sparse_attention's three kernels (PR 47) at the tile the rule
+    chooses, with the selection before them, bf16, a group of 16 query heads
+    on one key-value head at hd 128: what interpret mode cannot show — the
+    heads side by side along the lanes, the 0/1 spread of who was given
+    what, the scalar-prefetch table, more VMEM than the call asked for."""
+    from ray_tpu.ops import sparse_attention as sa
+
+    sizes = sa.SparseSizes(top_k=top_k, window=min(2048, S // 4),
+                           dense_len=S // 2)
+    q = jax.ShapeDtypeStruct((1, 16, S, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 1, S, 128), jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            ids = sa.sparse_select(q, k, sizes)
+            return jnp.sum(sa.attend_chosen(q, k, v, ids, sizes, False
+                                            ).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    mine = {d["kernel"]: d for d in sa.sparse_tiling_decisions()
+            if (d["S"], d["group_heads"], d["hd"]) == (S, 16, 128)}
+    assert set(mine) == {"fwd", "bwd_dq", "bwd_dkv"}
+    assert all((d["block_q"], d["block_k"]) == (128, 512)
+               for d in mine.values())
+    # no [heads, S, S] tensor outside the kernels: logits live in VMEM tiles
+    assert not re.findall(rf"\[[0-9,]*{S},{S}\]", hlo)
+
+
+def test_the_minicpm_sala_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
+    """`minicpm-sala-9b-l4.dataset`'s own step compiled for the described
+    chip: it fits (the compiler's peak leaves 1 GB of 15.75 GiB), nothing
+    is rematerialized by the compiler, and its Mosaic calls are the scan's
+    pair in the layer scan's forward, recompute and backward and the sparse
+    layer's three kernels (the forward once more: recompute)."""
+    from ray_tpu.models import blocks
+
+    cell, config, family, mesh = _cell_on(topo, "minicpm-sala-9b-l4.dataset")
+    fn, args = family.abstract_step(config, cell, mesh)
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert blocks.compiler_rematerialized(hlo) == []
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 6
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= family.V5E_BYTES_LIMIT - 10 ** 9, peak / 2 ** 30
+    (policy,) = [d for d in blocks.remat_policy_decisions()
+                 if (d["n_layer"], d["seq"]) == (4, cell["seq_len"])
+                 and d["bytes_limit"] == family.V5E_BYTES_LIMIT]
+    assert policy["saved"][0] == "sparse_block_ids"
 
 
 def _cell_on(topo, name):

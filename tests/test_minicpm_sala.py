@@ -1,0 +1,629 @@
+"""MiniCPM-SALA (models/minicpm_sala.py, ops/sparse_attention.py, the lightning
+mixer on ops/mamba2.ssd_scan) against the benchmark's plain float32 reference
+on seeded weights, at tiny sizes on the CPU: the whole model (loss and every
+gradient, remat on and off), each kind of layer alone (the sparse one on its
+dense and its sparse branch), the scan at one head a group against the
+token-by-token recurrence, the selection rule against the reference's, a
+chip's share tied to the uncut layer, the kernels over more than one tile,
+the family's arithmetic, the events, the float8 control through the comparison that decides
+``correct`` — and what refused PR 46: each reader
+this PR adds names the new cell alone, imports nothing of ``ray_tpu`` at
+module level and reads nothing, without raising, from the recorded GPT-2 and
+Nemotron traces."""
+
+import ast
+import dataclasses
+import importlib
+import json
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks.families import minicpm_sala as family  # noqa: E402
+from benchmarks.families import minicpm_sala_reference as reference  # noqa: E402
+from ray_tpu.models import blocks, minicpm_sala as ms  # noqa: E402
+from ray_tpu.ops import mamba2, sparse_attention as sa  # noqa: E402
+from ray_tpu.tracing import names  # noqa: E402
+
+CELL = "minicpm-sala-9b-l4.dataset"
+NEW_READERS = ("sala_mfu_device", "lightning_attn_ms_per_step",
+               "sparse_attn_ms_per_step", "sparse_select_ms_per_step",
+               "sparse_attn_roofline")
+# the scan under the lightning mixer IS the state-space scan's kernels under
+# its scope: the accepted readers of that scope list this cell too
+SHARED_READERS = ("ssd_scan_ms_per_step", "ssd_scan_roofline")
+
+
+def _batch(cfg, rows=2, seed=0):
+    rng = np.random.default_rng(seed)
+    tokens = rng.integers(0, 64, (rows, cfg.seq_len)).astype(np.int32)
+    targets = np.roll(tokens, -1, axis=1)
+    targets[:, -1] = -1
+    return tokens, targets
+
+
+def _params(cfg, seed=1):
+    """Seeded weights with every gain moved off 1, so that a gain matters."""
+    params = ms.init(cfg, jax.random.PRNGKey(seed))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 64))
+    return jax.tree_util.tree_map_with_path(
+        lambda path, p: p + 0.1 * jax.random.normal(next(keys), p.shape, p.dtype)
+        if "norm" in getattr(path[-1], "key", "") else p, params)
+
+
+def _layer_of(params, cfg, kind, i=0):
+    """Layer i of ``kind`` out of the runs' stacks."""
+    layers = [p for k, p in reference.layer_params(cfg.pattern, params["blocks"])
+              if k == kind]
+    return layers[i]
+
+
+def _worst(got, want):
+    return max(jax.tree.leaves(jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))),
+        got, want)))
+
+
+def _both(cfg, params, tokens, targets, **switches):
+    """((program loss, grads), (reference loss, grads, selection reports)),
+    the reference attending over the program's chosen blocks."""
+    ids = jax.jit(lambda p: ms.chosen_blocks(p, tokens, cfg))(params)
+    sizes = family.reference_sizes(cfg, **switches)
+    with jax.default_matmul_precision("highest"):
+        (ref_loss, reports), ref_grads = jax.jit(jax.value_and_grad(
+            lambda p: reference.loss_and_selection(p, tokens, targets, sizes,
+                                                   ids), has_aux=True))(params)
+        got = jax.jit(jax.value_and_grad(
+            lambda p: ms.loss_fn(p, tokens, targets, cfg)))(params)
+    return got, (ref_loss, ref_grads, reports)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_loss_and_every_gradient_equal_the_reference_in_float32(remat):
+    cfg = ms.minicpm_sala_tiny(dtype=jnp.float32, remat=remat)
+    assert cfg.is_sparse
+    params = _params(cfg)
+    (loss, grads), (ref_loss, ref_grads, reports) = _both(
+        cfg, params, *_batch(cfg))
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+    assert _worst(grads, ref_grads) < 1e-4
+    # in float32 the program's selection IS the reference's
+    assert [float(r["agree_share"]) for r in reports] == [1.0]
+    assert float(reports[0]["worst_margin"]) == 0.0
+
+
+def test_remat_changes_no_number():
+    cfg = ms.minicpm_sala_tiny()
+    params = _params(cfg)
+    tokens, targets = _batch(cfg)
+    a, b = (jax.jit(jax.value_and_grad(lambda p, c=c: ms.loss_fn(
+        p, tokens, targets, c)))(params)
+        for c in (cfg, dataclasses.replace(cfg, remat=True)))
+    assert float(a[0]) == float(b[0])
+    np.testing.assert_allclose(
+        *(np.concatenate([np.ravel(np.asarray(g, np.float32))
+                          for g in jax.tree.leaves(t[1])]) for t in (a, b)),
+        rtol=1e-5, atol=1e-8)
+
+
+def test_bf16_program_is_near_the_reference_and_a_coarser_one_is_not():
+    """The family's comparison at tiny sizes: the bf16 program passes its
+    limits' order of magnitude, the reference with float8 operands does
+    not, and neither does one whose selection rule is wrong."""
+    from benchmarks.families.nemotron_h import grad_error
+
+    cfg = ms.minicpm_sala_tiny(seq_len=256)
+    params = _params(cfg)
+    tokens, targets = _batch(cfg)
+    (loss, grads), (ref_loss, ref_grads, reports) = _both(
+        cfg, params, tokens, targets)
+
+    def norms(tree):
+        return [float(jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32)))))
+                for g in jax.tree.leaves(tree)]
+
+    assert abs(float(loss) - float(ref_loss)) < 1e-3 * float(ref_loss)
+    mine = grad_error(norms(grads), norms(ref_grads))["total"]
+    assert mine < 2e-2
+    assert float(reports[0]["agree_share"]) > 0.97
+    assert float(reports[0]["worst_margin"]) < 4 * family.SELECT_MARGIN
+    _, (_, coarse, _) = _both(cfg, params, tokens, targets,
+                              operand_dtype=jnp.float8_e4m3fn)
+    # (at these sizes float8 reads 1.7x the bf16 program; the readings at the
+    # cell's own sizes, which the limits stand between, are PERF.md's)
+    assert grad_error(norms(coarse), norms(ref_grads))["total"] > 1.5 * mine
+    _, (_, _, wrong) = _both(cfg, params, tokens, targets, drop_pooling=True)
+    assert float(wrong[0]["worst_margin"]) > 4 * family.SELECT_MARGIN
+
+
+@pytest.mark.parametrize("control,refused", [
+    ({}, ()), ({"operand_dtype": jnp.float8_e4m3fn}, ("grad_norm",))],
+    ids=["program", "float8-reference"])
+def test_the_comparison_that_decides_correct_refuses_float8(control, refused):
+    """The family's ``reference_check`` at the CPU rehearsal's sizes, judged
+    by ``harness/checks.failures`` as run.py judges a run: the bf16 program
+    is correct; the reference with float8 operands in the program's place is
+    not — refused, as at the cell's own sizes (PERF.md §6), by the
+    selection's margin, which reaches ``checks`` as a ``grad_norm`` no rtol
+    passes. The margin's limit is stated for these sizes and this seed (the
+    program reads 0.0, float8 1.5e-2; the rehearsal's 3e-2 is for any seed
+    at up to 256 tokens)."""
+    from benchmarks.harness import checks, spec, traffic
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    cell, config, mix = spec.load_cell(CELL)
+    with open(os.path.join(ROOT, "benchmarks", "testdata",
+                           "rehearse-minicpm_sala.json")) as f:
+        tiny = json.load(f)
+    config.update(tiny["config"])
+    cell.update(tiny["cell"], select_margin=4e-3)
+    seed = 3000000019
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(**cell["mesh"]),
+                              jax.devices()[:1])
+    bundle = family.build(config, cell, mesh, seed)
+    rows = traffic.host_batch(cell["reference_rows"], seed, cell["seq_len"],
+                              mix["alphabet"])
+    summary = {
+        "reference": family.reference_check(bundle, rows, config, cell,
+                                            **control),
+        "window": {"nonfinite_losses": 0, "losses_tail": [1.0],
+                   "first_loss": 2.0, "compiles_in_window": 0},
+        "data_ok": True, "step_counter": 3, "steps_run": 3,
+        "device_count": cell["chips"]}
+    bad = checks.failures(summary, cell, rehearse_cpu=True)
+    assert [any(s.startswith(name) for s in bad) for name in refused] == [
+        True] * len(refused), bad
+    assert bool(bad) == bool(refused), bad
+
+
+def test_a_rows_first_position_gives_q_and_k_a_gradient_through_eps_alone():
+    """Why the cell compares gradients without a row's first positions
+    (``reference_grad_skip``): at position 0 a lightning head's output is one
+    term, (q0·k0/√hd) v0, and the per-head output norm keeps its direction
+    alone — q and k get a gradient through the norm's eps only, all of it
+    from the head whose |q0·k0| is smallest (where bf16 cannot resolve it).
+    From the second position on they get one of the size of v's."""
+    cfg = ms.minicpm_sala_tiny(dtype=jnp.float32)
+    p = dict(_layer_of(_params(cfg, seed=3), cfg, "L"))
+    p["wv"] = p["wv"] * 8.0       # v at the cell's scale: 0.02 x sqrt(4,096)
+
+    def grads(seq, eps):
+        sizes = family.reference_sizes(dataclasses.replace(cfg, rms_eps=eps))
+        u = jax.random.normal(jax.random.PRNGKey(4), (1, seq, cfg.d_model))
+        w = jax.random.normal(jax.random.PRNGKey(5), u.shape)
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(lambda p: jnp.sum(
+                reference.mixer_output(u, p, "L", sizes) * w))(p)
+
+    def share(g):                     # ‖d wq‖ / ‖d wv‖
+        return float(jnp.linalg.norm(g["wq"]) / jnp.linalg.norm(g["wv"]))
+
+    assert share(grads(1, 1e-12)) < 1e-3 < 1.0 < share(grads(2, 1e-12))
+    first = grads(1, cfg.rms_eps)
+    assert share(first) > 10 * share(grads(1, 1e-12))
+    by_head = jnp.sum(jnp.square(first["wq"]), axis=(0, 2))     # [D, H, hd]
+    assert float(jnp.max(by_head) / jnp.sum(by_head)) > 0.9
+
+
+@pytest.mark.parametrize("kind,seq", [("L", 128), ("S", 64), ("S", 128)],
+                         ids=["lightning", "sparse-dense-branch",
+                              "sparse-sparse-branch"])
+def test_a_mixer_alone_equals_the_reference(kind, seq):
+    cfg = ms.minicpm_sala_tiny(dtype=jnp.float32, seq_len=seq)
+    assert cfg.is_sparse == (seq > cfg.sparse.dense_len)
+    params = _params(cfg, seed=3)
+    p = _layer_of(params, cfg, kind)
+    u = jax.random.normal(jax.random.PRNGKey(4), (2, seq, cfg.d_model))
+    sizes = family.reference_sizes(cfg)
+
+    program = lambda u, p: ms.mixer(u, p, cfg, kind)
+    w = jax.random.normal(jax.random.PRNGKey(5), u.shape)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(lambda u, p: jnp.sum(program(u, p) * w),
+                                 argnums=(0, 1))(u, p)
+        want = jax.value_and_grad(
+            lambda u, p: jnp.sum(reference.mixer_output(u, p, kind, sizes) * w),
+            argnums=(0, 1))(u, p)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    assert _worst(got[1], want[1]) < 1e-4
+
+
+def test_the_scan_at_one_head_a_group_is_the_token_by_token_recurrence():
+    """ssd_scan(x = v, Δ = 1, A = −slope, B = k, C = q) with G = H and
+    P = N against s_t = λ s_{t−1} + k_tᵀ v_t, o_t = q_t s_t, over several
+    chunks, forward and gradients."""
+    B, S, H, P = 2, 96, 4, 16
+    q, k, v = (jax.random.normal(key, (B, S, H, P))
+               for key in jax.random.split(jax.random.PRNGKey(7), 3))
+    slopes = jnp.exp2(-8.0 * (jnp.arange(H) + 1.0) / H)
+
+    def scanned(q, k, v):
+        return mamba2.ssd_scan(v, jnp.ones((B, S, H), jnp.float32), -slopes,
+                               k, q, chunk=32)
+
+    def stepped(q, k, v):
+        lam = jnp.exp(-slopes)[None, :, None, None]
+
+        def step(s, t):
+            q_t, k_t, v_t = t
+            s = lam * s + k_t[..., :, None] * v_t[..., None, :]
+            return s, jnp.einsum("bhk,bhkv->bhv", q_t, s)
+
+        _, o = jax.lax.scan(step, jnp.zeros((B, H, P, P)),
+                            tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v)))
+        return jnp.moveaxis(o, 0, 1)
+
+    w = jax.random.normal(jax.random.PRNGKey(8), (B, S, H, P))
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(lambda *a: jnp.sum(scanned(*a) * w),
+                                 argnums=(0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(lambda *a: jnp.sum(stepped(*a) * w),
+                                  argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, r in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-4)
+    mine = [d for d in mamba2.ssd_tiling_decisions()
+            if (d["group_heads"], d["P"], d["N"], d["S"]) == (1, P, P, S)]
+    assert {d["kernel"] for d in mine} == {"fwd", "bwd"}
+
+
+def _qk(S, seed, H=4, KH=2, hd=16):
+    kq, kk = jax.random.split(jax.random.PRNGKey(seed))
+    return (jax.random.normal(kq, (1, H, S, hd)),
+            jax.random.normal(kk, (1, KH, S, hd)))
+
+
+def test_the_selection_is_the_references_rule():
+    """Forced blocks are always in (the first, the window's), nothing from
+    the future is among a token's visible choices, and the program's ids are
+    the reference's top_k on its own scores."""
+    z = sa.SparseSizes(block=16, kernel=8, stride=4, top_k=6, init_blocks=1,
+                       window=24, dense_len=32)
+    S = 256
+    q, k = _qk(S, 11)
+    with jax.default_matmul_precision("highest"):
+        ids = np.asarray(sa.sparse_select(q, k, z))              # [1, 2, S, 6]
+        g = q.shape[1] // k.shape[1]
+        score = np.asarray(reference.block_scores(
+            q.reshape(1, 2, g, S, -1), k, 0, {"sparse": z._asdict()}))
+    assert ids.shape == (1, 2, S, z.top_k)
+    for t in (0, 15, 16, 40, 100, 255):
+        own = t // z.block
+        forced = {0} | set(range(max(t - z.window + 1, 0) // z.block, own + 1))
+        for h in range(2):
+            chosen = set(ids[0, h, t].tolist())
+            visible = {b for b in chosen if b <= own}
+            assert forced <= chosen, (t, forced, chosen)
+            assert len(visible) == min(z.top_k, own + 1)
+            want = set(np.argsort(-score[0, h, t], kind="stable")
+                       [:min(z.top_k, own + 1)].tolist())
+            assert visible == want, (t, visible, want)
+    # the margin rule: a choice the reference did not make, far below its
+    # last chosen score, is reported; the program's own choices are not
+    sizes = {"sparse": z._asdict()}
+    v = jax.random.normal(jax.random.PRNGKey(12), k.shape)
+    qg = q.reshape(1, 2, g, S, -1)
+    with jax.default_matmul_precision("highest"):
+        *_, worst = reference._sparse_rows(qg, k, v, jnp.asarray(ids), 0, sizes)
+        assert float(worst) == 0.0
+        off = np.array(ids)
+        off[0, 0, 255, -1] = int(np.argmin(np.where(
+            score[0, 0, 255] >= 0, score[0, 0, 255], np.inf)))
+        *_, worst = reference._sparse_rows(qg, k, v, jnp.asarray(off), 0, sizes)
+    assert float(worst) > 0.05
+
+
+def test_the_sparse_kernels_over_several_tiles_equal_a_masked_softmax():
+    """S = 1,024 in 64-key blocks: 8 query tiles of 128 tokens x 2 key tiles
+    of 512 — the running softmax across key tiles, tiles in the future and a
+    key-value head's two query heads as one tile's rows — against attention
+    by a [S, S] mask over the same chosen blocks, forward and gradients."""
+    z = sa.SparseSizes(block=64, kernel=32, stride=16, top_k=6, init_blocks=1,
+                       window=130, dense_len=128)
+    S, H, KH, hd = 1024, 2, 1, 32
+    q, k = _qk(S, 21, H, KH, hd)
+    v = jax.random.normal(jax.random.PRNGKey(22), k.shape)
+    ids = sa.sparse_select(q, k, z)
+
+    def masked(q, k, v):
+        given = jnp.any(ids[..., None] == jnp.arange(S // z.block), axis=-2)
+        keys = jnp.repeat(given, z.block, axis=-1) & jnp.tril(
+            jnp.ones((S, S), bool))
+        logits = jnp.einsum("bkgqd,bksd->bkgqs", q.reshape(1, KH, H // KH, S, hd),
+                            k) / math.sqrt(hd)
+        p = jax.nn.softmax(jnp.where(keys[:, :, None], logits, -jnp.inf), -1)
+        return jnp.einsum("bkgqs,bksd->bkgqd", p, v).reshape(q.shape)
+
+    w = jax.random.normal(jax.random.PRNGKey(23), q.shape)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(lambda *a: jnp.sum(sa.attend_chosen(
+            *a, ids, z, True) * w), argnums=(0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(lambda *a: jnp.sum(masked(*a) * w),
+                                  argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, r in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+    mine = {d["kernel"]: d for d in sa.sparse_tiling_decisions()
+            if (d["S"], d["group_heads"], d["hd"]) == (S, 2, hd)}
+    assert set(mine) == {"fwd", "bwd_dq", "bwd_dkv"}
+    assert all(tuple(d) == names.SPARSE_TILING_ARGS
+               and (d["block_q"], d["block_k"]) == (128, 512)
+               for d in mine.values())
+
+
+@pytest.mark.parametrize("kind", ["L", "S"])
+def test_the_two_shares_mixer_outputs_add_up_to_the_uncut_layers(kind):
+    """TP 2 over heads: lightning heads 0-1 / 2-3 (the decay by the
+    PUBLISHED index), key-value head 0 / 1 with its group — the program on
+    each share, summed, against the reference on the uncut layer."""
+    whole = ms.minicpm_sala_tiny(dtype=jnp.float32)
+    params = _params(whole, seed=9)
+    p = _layer_of(params, whole, kind)
+    u = jax.random.normal(jax.random.PRNGKey(10), (2, whole.seq_len,
+                                                   whole.d_model))
+    with jax.default_matmul_precision("highest"):
+        want = reference.mixer_output(u, p, kind,
+                                      family.reference_sizes(whole))
+        total = 0.0
+        for share in range(2):
+            if kind == "L":
+                n = whole.lightning_heads // 2
+                cfg = dataclasses.replace(whole, lightning_heads=n,
+                                          lightning_head_first=share * n)
+                heads = kv = slice(share * n, (share + 1) * n)
+            else:
+                cfg = dataclasses.replace(whole, n_head=whole.n_head // 2,
+                                          n_kv_head=1)
+                g = whole.n_head // whole.n_kv_head
+                heads = slice(share * g, (share + 1) * g)
+                kv = slice(share, share + 1)
+            mine = {**p, "wq": p["wq"][:, heads], "wg": p["wg"][:, heads],
+                    "wk": p["wk"][:, kv], "wv": p["wv"][:, kv],
+                    "wo": p["wo"][heads]}
+            if kind == "L":
+                mine["o_norm"] = p["o_norm"][heads]
+            total = total + ms.mixer(u, mine, cfg, kind)
+    np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-6)
+
+
+def _cell():
+    from benchmarks.harness import spec
+
+    cell, config, _ = spec.load_cell(CELL)
+    return cell, config
+
+
+def test_the_configuration_holds_every_published_width_and_states_its_cut():
+    cell, config = _cell()
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(catalog):
+        pytest.skip("the model catalog is not on this machine")
+    with open(catalog) as f:
+        row = next(r for r in map(json.loads, f) if r["name"] == "MiniCPM-SALA")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == cell["config"])
+    assert entry["source"] == row["source_url"] == config["source"]
+    changed = {k for k, v in row["config"].items() if config[k] != v}
+    assert changed == set(entry["reduced"])
+    widths = ("hidden_size", "intermediate_size", "head_dim",
+              "lightning_head_dim", "dim_model_base")
+    assert not changed & set(widths)
+    for key in entry["reduced"]:
+        assert config["published"][key] == row["config"][key]
+    assert config["mixer_types"] == row["config"]["mixer_types"][6:10]
+
+
+def test_the_cells_parameters_and_the_familys_arithmetic():
+    """The built tree's count is the family's, x 12 B is under 12.6 GB; the
+    program's FLOPs a token are the family's; the rooflines' work is what
+    the shapes say."""
+    cell, config = _cell()
+    cfg = family.program_config(config, cell)
+    shapes = family.shapes(config, cell)
+    assert (cfg.pattern, cfg.seq_len, cfg.is_sparse) == ("LLLS", 16384, True)
+    assert ms.param_count(cfg) == shapes["params"] == 1_032_891_392
+    assert shapes["params"] * 12 < 12.6e9
+    assert family.train_flops_per_token(shapes) == pytest.approx(
+        ms.flops_per_token(cfg), rel=1e-12)
+    tiny = ms.minicpm_sala_tiny(seq_len=64)              # the dense branch
+    assert not tiny.is_sparse
+    pairs = family._given_pairs(shapes)
+    assert pairs == 4096 * 4097 / 2 + (16384 - 4096) * 4096
+    assert pairs / (16384 * 16385 / 2) == pytest.approx(
+        sa.kept_share(16384, cfg.sparse))
+    work = family.sparse_attn_call(shapes)
+    assert work["flops"] == 14 * 128 * 16 * pairs
+    scan = family.ssd_scan_call(shapes)
+    assert scan["flops"] == 3 * 6 * 16384 * (64 * 2 * 2048 + 2 * 16 * 128 * 128)
+
+
+def test_the_family_refuses_a_program_without_the_model(monkeypatch):
+    import importlib.util
+
+    real = importlib.util.find_spec
+    monkeypatch.setattr(importlib.util, "find_spec", lambda name, *a: (
+        None if name == "ray_tpu.models.minicpm_sala" else real(name, *a)))
+    cell, config = _cell()
+    with pytest.raises(SystemExit, match="cannot run a cell of family"):
+        family.shapes(config, cell)
+
+
+def test_tracing_a_step_records_the_pattern_the_selection_and_the_tilings():
+    from ray_tpu.train.train_step import make_train_step
+
+    cfg = ms.minicpm_sala_tiny(remat=True)
+    bundle = make_train_step(ms, cfg)
+    tokens, targets = _batch(cfg)
+    batch = {"tokens": tokens, "targets": targets}
+    jaxpr = str(jax.make_jaxpr(bundle.step_fn)(bundle.state, batch))
+    for name in (names.RES_Q, names.RES_K, names.RES_V, names.RES_SALA_GATE,
+                 names.RES_SSD_STATES, names.RES_LIGHTNING_Y,
+                 names.RES_SPARSE_IDS, names.RES_SPARSE_O,
+                 names.RES_SPARSE_LSE, names.RES_MID):
+        assert name in names.RESIDUALS and f"name={name}" in jaxpr, name
+    for kernel in (names.SPARSE_ATTN_FWD_KERNEL,
+                   names.SPARSE_ATTN_BWD_DQ_KERNEL,
+                   names.SPARSE_ATTN_BWD_DKV_KERNEL,
+                   names.SSD_CHUNK_FWD_KERNEL, names.SSD_CHUNK_BWD_KERNEL):
+        assert f"name={kernel}" in jaxpr, kernel
+    by = {d["pattern"]: d for d in blocks.layer_pattern_decisions()}
+    assert by["LLLS"]["applications"] == {"L": 3, "S": 1}
+    assert by["LLLS"]["groups"] == ["3 x scan(L)", "S"]
+    mine = [d for d in ms.sparse_selection_decisions()
+            if (d["rows"], d["S"]) == (2 * cfg.n_kv_head, cfg.seq_len)]
+    assert mine and tuple(mine[0]) == names.SPARSE_SELECTION_ARGS
+    assert (mine[0]["mode"], mine[0]["blocks"], mine[0]["top_k"],
+            mine[0]["window_blocks"]) == ("sparse", 8, 5, 3)
+    assert 0 < mine[0]["kept_share"] < 1
+    assert any((d["n_layer"], d["batch"], d["seq"]) == (4, 2, cfg.seq_len)
+               for d in blocks.remat_policy_decisions())
+    dense = ms.minicpm_sala_tiny(seq_len=64)
+    jax.make_jaxpr(lambda p: ms.loss_fn(
+        p, *_batch(dense), dense))(ms.init(dense, jax.random.PRNGKey(0)))
+    assert any(d["mode"] == "dense" and d["kept_share"] == 1.0
+               for d in ms.sparse_selection_decisions())
+
+
+@pytest.mark.parametrize("axis", ["pp", "cp"])
+def test_a_mesh_the_family_cannot_run_on_is_refused(axis):
+    class Mesh:
+        shape = {axis: 2}
+
+    with pytest.raises(NotImplementedError, match=axis):
+        ms.mesh_rules(ms.minicpm_sala_tiny(), Mesh())
+
+
+def test_the_rule_counts_both_kinds_and_keeps_what_a_chip_has_room_for():
+    """kind_shards at the cell's shapes: three lightning applications and
+    one sparse, each with its own names; with the described chip's limit and
+    the cell's resident bytes the rule keeps the ids first (a scoring pass
+    and a `top_k` for 4 MB)."""
+    cell, config = _cell()
+    cfg = family.program_config(config, cell)
+    base, kinds = ms.kind_shards(cfg, 1, cfg.seq_len, None)
+    assert {k: v.applications for k, v in kinds.items()} == {"L": 3, "S": 1}
+    named = {n for k in kinds.values() for c in k.candidates for n in c.names}
+    assert named <= set(names.RESIDUALS)
+    assert {names.RES_SSD_STATES, names.RES_LIGHTNING_Y, names.RES_SPARSE_IDS,
+            names.RES_SPARSE_O, names.RES_SALA_GATE} <= named
+    assert names.RES_MLP_GATE not in named       # the MLP goes in chunks
+    assert (base.mlp_rows, base.head_rows) == (1024, 1024)
+    runs = blocks.pattern_groups(cfg.pattern)
+    phase = max(blocks.backward_phases(base, kinds, runs),
+                key=lambda p: p.nbytes)
+    policy = blocks.choose_remat_policy_kinds(
+        tuple(kinds.values()), phase.nbytes, family.V5E_BYTES_LIMIT,
+        12 * ms.param_count(cfg))
+    assert policy.saved[0] == names.RES_SPARSE_IDS
+    assert policy.saved_bytes <= policy.budget_bytes
+
+
+# --------------------------------------------------------------------------- #
+# What refused PR 46 (`benchmark_breaks_parent`): a reader this PR adds must
+# leave every other cell's traced run alone
+# --------------------------------------------------------------------------- #
+
+def _benchmark():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_new_reader_names_the_new_cell_alone_and_imports_no_program(name):
+    entry = next(m for m in _benchmark()["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == [CELL]
+    path = os.path.join(ROOT, "benchmarks", "layer_metrics", name + ".py")
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    for node in tree.body:                        # module level only
+        if isinstance(node, (ast.Import, ast.ImportFrom)):
+            mods = ([a.name for a in node.names] if isinstance(node, ast.Import)
+                    else [node.module or ""])
+            assert not any(m.split(".")[0] == "ray_tpu" for m in mods), mods
+    reader = importlib.import_module(f"benchmarks.layer_metrics.{name}")
+    assert (reader.UNIT, reader.MOVES) == (entry["unit"], entry["moves"])
+
+
+def test_the_new_family_files_import_no_program_at_module_level():
+    for name in ("minicpm_sala", "minicpm_sala_reference"):
+        path = os.path.join(ROOT, "benchmarks", "families", name + ".py")
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+        level = tree.body if name == "minicpm_sala" else list(ast.walk(tree))
+        for node in level:
+            if isinstance(node, ast.ImportFrom):
+                assert (node.module or "").split(".")[0] != "ray_tpu"
+            elif isinstance(node, ast.Import):
+                assert all(a.name.split(".")[0] != "ray_tpu" for a in node.names)
+
+
+_RECORDED = {
+    "minicpm_sala": (CELL, CELL + ".1step.scoped.program.json.gz"),
+    "gpt2": ("gpt2-124m.dataset",
+             "gpt2-124m.dataset.10steps.scoped.xplane.pb.gz"),
+    "nemotron_h": ("nemotron-3-super-120b-l11.dataset",
+                   "nemotron-3-super-120b-l11.dataset.1step.scoped.program.json.gz"),
+}
+_facts = {}
+
+
+def _recorded_facts(family_name):
+    """The facts a reader would be handed in that cell's traced run: the
+    cell's own shapes, v5e's peaks and the recorded trace's reduction."""
+    if family_name not in _facts:
+        from benchmarks.harness import peaks, program_trace, spec
+
+        cell_name, trace = _RECORDED[family_name]
+        cell, config, mix = spec.load_cell(cell_name)
+        path = os.path.join(ROOT, "benchmarks", "testdata", trace)
+        tables = (program_trace.read_tables(path) if path.endswith(".json.gz")
+                  else program_trace.load_tables(path))
+        got = program_trace.reduce_tables(tables)
+        assert got["instrumented"]
+        fam = importlib.import_module(f"benchmarks.families.{family_name}")
+        _facts[family_name] = {
+            "cell": cell, "config": config, "traffic": mix, "notes": [],
+            "summary": {"shapes": fam.shapes(config, cell)},
+            "trace": {"steps": got["steps"], "step_device_ms": 100.0},
+            "peaks": peaks.peaks_for("TPU v5 lite"), "driver": {},
+            "program_trace": got}
+    return _facts[family_name]
+
+
+@pytest.mark.parametrize("family_name", ["gpt2", "nemotron_h"])
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_new_reader_reads_nothing_from_another_cells_trace(name, family_name):
+    reader = importlib.import_module(f"benchmarks.layer_metrics.{name}")
+    assert reader.read(_recorded_facts(family_name)) is None
+
+
+@pytest.mark.parametrize("name,value", [
+    ("ssd_scan_ms_per_step", 22.752051), ("ssd_scan_roofline", 16.27),
+    ("lightning_attn_ms_per_step", 143.762913),
+    ("sparse_attn_ms_per_step", 57.435203),
+    ("sparse_select_ms_per_step", 5.009886),
+    # fwd 8.214 + dq 8.363 + dkv 10.894 ms against a least 8.55
+    ("sparse_attn_roofline", 31.13)])
+def test_a_reader_of_this_cell_reads_its_recorded_trace(name, value):
+    """The cell's own traced step (recorded on the chip), through each reader
+    that lists the cell by a scope or a kernel: the accepted scan readers by
+    the family's ``ssd_scan_call``, the sparse roofline over all three
+    kernels by their listed names."""
+    entry = next(m for m in _benchmark()["per_layer"] if m["name"] == name)
+    assert CELL in entry["workloads"]
+    if name in SHARED_READERS:
+        assert entry["workloads"][:-1] == ["nemotron-3-super-120b-l11.dataset"]
+    reader = importlib.import_module(f"benchmarks.layer_metrics.{name}")
+    got = reader.read(_recorded_facts("minicpm_sala"))
+    assert got == pytest.approx(value, rel=1e-3)
+    assert 0 < got <= 100 or entry["unit"] != "%"

@@ -996,9 +996,10 @@ def test_the_hybrids_loss_and_every_gradient_equal_whatever_is_saved(admits):
     """The whole hybrid at tiny sizes in bf16, `remat=True` with a chip stated
     that has room for the first n candidates of the rule's order — none; Δ,
     the `top_k`'s columns and the scores; six of them — and `remat=False`
-    (every name): the same loss and gradients bit for bit as whole-block
-    remat with no chip stated, and the decision recorded says what was kept
-    and which phase of the backward left the budget."""
+    (every name): the same loss and gradients as whole-block remat with no
+    chip stated — bit for bit with `remat=True`, to float32's rounding
+    without —, and the decision recorded says what was kept and which phase
+    of the backward left the budget."""
     cfg = nh.nemotron_h_tiny(remat=admits != "every_name")
     params = nh.init(cfg, jax.random.PRNGKey(23))
     tokens, targets = _batch(cfg)
@@ -1020,12 +1021,20 @@ def test_the_hybrids_loss_and_every_gradient_equal_whatever_is_saved(admits):
     got = jax.jit(jax.value_and_grad(loss))(params)
     want = jax.jit(jax.value_and_grad(
         lambda p: loss(p, nh.nemotron_h_tiny(remat=True), None)))(params)
-    assert float(got[0]) == float(want[0])
+    # `remat=False` is ANOTHER program: XLA fuses its backward differently
+    # and a float32 sum over the tokens comes out in another order — one
+    # tensor (the Mamba layers' gate_norm) read 1.0e-6 off in 16 of 128
+    # elements, five runs of five, on the seed tree and on PR 47's (the
+    # driver's run of the seed tree too). Bits are asked of what shares a
+    # program's shape (the three `remat=True` cases); that case is held to
+    # float32's rounding over a sum, 1e-5.
+    same = (np.testing.assert_array_equal if cfg.remat else
+            partial(np.testing.assert_allclose, rtol=1e-5, atol=1e-8))
+    same(float(got[0]), float(want[0]))
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got[1]),
                             jax.tree.leaves(want[1])):
-        np.testing.assert_array_equal(np.asarray(g, np.float32),
-                                      np.asarray(w, np.float32),
-                                      err_msg=jax.tree_util.keystr(path))
+        same(np.asarray(g, np.float32), np.asarray(w, np.float32),
+             err_msg=jax.tree_util.keystr(path))
     if cfg.remat:
         (d,) = [d for d in blocks.remat_policy_decisions()
                 if d["bytes_limit"] == limit and d["seq"] == cfg.seq_len]

@@ -36,6 +36,9 @@ LOWERINGS = {
     "eva": dict(remat=True, attention_impl="pallas"),
     # a pattern of layer kinds: Mamba-2, LatentMoE, attention, MTP (PR 33)
     "nemotron": dict(remat=True, attention_impl="pallas"),
+    # a pattern of two attention kinds: lightning on the scan's kernels and
+    # block-sparse attention past a tiny dense_len (MiniCPM-SALA, PR 47)
+    "sala": dict(remat=True),
 }
 # each model's own mixer: GPT-2 has the flash kernels, EvaByte the EVA ones
 EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
@@ -45,14 +48,25 @@ SSD_KERNELS = (names.SSD_CHUNK_FWD_KERNEL, names.SSD_CHUNK_BWD_KERNEL)
 NEMOTRON_SCOPES = (names.MAMBA, names.SSD_SCAN, names.MOE_ROUTED,
                    names.MOE_DISPATCH, names.MOE_LATENT, names.MOE_SHARED,
                    names.MTP)
+SPARSE_KERNELS = (names.SPARSE_ATTN_FWD_KERNEL, names.SPARSE_ATTN_BWD_DQ_KERNEL,
+                  names.SPARSE_ATTN_BWD_DKV_KERNEL)
+SALA_SCOPES = (names.LIGHTNING_ATTN, names.SPARSE_ATTENTION,
+               names.SPARSE_SELECT)
 DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE
-                     and s not in EVA_SCOPES + NEMOTRON_SCOPES)
+                     and s not in EVA_SCOPES + NEMOTRON_SCOPES + SALA_SCOPES)
 EVABYTE_SCOPES = tuple(s for s in names.SCOPES if s not in (
-    names.MOE, names.FLASH_ATTENTION) + NEMOTRON_SCOPES)
+    names.MOE, names.FLASH_ATTENTION) + NEMOTRON_SCOPES + SALA_SCOPES)
 # every layer of the hybrid is a mixer OR a feed-forward part: one norm a
 # layer (ln1), the shared expert under `mlp` inside `moe`
-HYBRID_SCOPES = tuple(s for s in names.SCOPES
-                      if s != names.LN2 and s not in EVA_SCOPES)
+HYBRID_SCOPES = tuple(s for s in names.SCOPES if s != names.LN2
+                      and s not in EVA_SCOPES + SALA_SCOPES)
+# every layer of MiniCPM-SALA is a mixer AND a SwiGLU MLP: the block's six
+# scopes, its two mixers' and the scan's; the sparse branch runs no flash
+# kernel
+MINICPM_SCOPES = tuple(
+    s for s in DENSE_SCOPES[:DENSE_SCOPES.index(names.FLASH_ATTENTION)]
+    if s != names.ATTN) + (
+    names.SSD_SCAN,) + SALA_SCOPES
 NEMOTRON_RESIDUALS = (
     names.RES_MAMBA_Z, names.RES_MAMBA_XBC, names.RES_MAMBA_DT,
     names.RES_SSD_STATES, names.RES_SSD_Y, names.RES_MOE_LATENT,
@@ -63,7 +77,7 @@ _lowered = {}
 
 def _step(key):
     """(bundle, batch of 2) of the tiny train step `key` names, built anew."""
-    from ray_tpu.models import gpt2, llama, nemotron_h
+    from ray_tpu.models import gpt2, llama, minicpm_sala, nemotron_h
     from ray_tpu.train.train_step import (
         make_gpt2_train_step, make_train_step, synthetic_batch)
 
@@ -73,6 +87,9 @@ def _step(key):
     elif key == "nemotron":
         cfg = nemotron_h.nemotron_h_tiny(**LOWERINGS[key])
         bundle = make_train_step(nemotron_h, cfg)
+    elif key == "sala":
+        cfg = minicpm_sala.minicpm_sala_tiny(**LOWERINGS[key])
+        bundle = make_train_step(minicpm_sala, cfg)
     else:
         cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
         bundle = make_gpt2_train_step(cfg)
@@ -130,6 +147,21 @@ def test_scope_in_lowered_nemotron_step(scope):
         for inner in (names.BLOCK, names.LM_HEAD_LOSS, names.LN_F):
             assert any(re.search(rf"{names.MTP}\)*/(.*/)?{inner}", n)
                        for n in op_names), inner
+
+
+@pytest.mark.parametrize("scope", MINICPM_SCOPES)
+def test_scope_in_lowered_minicpm_sala_step(scope):
+    """Both kinds of layer carry the block's scopes; the scan stands inside
+    the lightning mixer, the selection inside the sparse one, and both
+    mixers inside the block."""
+    op_names, _ = _lowering("sala")
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+    inside = {names.SSD_SCAN: names.LIGHTNING_ATTN,
+              names.SPARSE_SELECT: names.SPARSE_ATTENTION,
+              names.LIGHTNING_ATTN: names.BLOCK,
+              names.SPARSE_ATTENTION: names.BLOCK}
+    if scope in inside:
+        assert _has_scope(op_names, f"{inside[scope]}/{scope}")
 
 
 @pytest.mark.parametrize("residual", NEMOTRON_RESIDUALS)
@@ -205,6 +237,7 @@ def test_remat_recompute_keeps_the_block_scopes(blocks):
 
 def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
     assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS + SSD_KERNELS
+                                     + SPARSE_KERNELS
                                      + (names.RAGGED_DOT_KERNEL,))
 
 
@@ -216,7 +249,8 @@ def test_kernel_name_in_jaxpr(kernel):
         assert "ragged_dot" in jaxpr
         return
     _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else
-                         "nemotron" if kernel in SSD_KERNELS else "remat")
+                         "nemotron" if kernel in SSD_KERNELS else
+                         "sala" if kernel in SPARSE_KERNELS else "remat")
     assert f"name={kernel}" in jaxpr
 
 
