@@ -103,10 +103,11 @@ def chosen_rows_off(seed: int) -> int:
 
 
 def attention_call_shapes(hlo_text: str, head_dim: int) -> Tuple[int, List[List[int]]]:
-    """(number of Mosaic custom calls, the distinct [B·H, S, hd] shapes on
-    their lines) in a compiled step's HLO — the operands and results of the
-    flash kernels as each device runs them, batch and head merged into the
-    one dim of rows the kernels walk."""
+    """(number of Mosaic custom calls, the distinct [B·H, S, hd] — or, from
+    the S-minor kernel pair, [B·H, hd, S] — shapes on their lines) in a
+    compiled step's HLO: the operands and results of the flash kernels as
+    each device runs them, batch and head merged into the one dim of rows
+    the kernels walk."""
     calls, shapes = 0, set()
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
@@ -114,7 +115,7 @@ def attention_call_shapes(hlo_text: str, head_dim: int) -> Tuple[int, List[List[
         calls += 1
         for m in _SHAPE3.finditer(line):
             dims = tuple(int(d) for d in m.groups())
-            if dims[2] == head_dim:
+            if head_dim in dims[1:]:
                 shapes.add(dims)
     return calls, [list(s) for s in sorted(shapes)]
 
@@ -484,8 +485,12 @@ def check_device(summary: Dict[str, Any], model_cfg, per_chip_batch: int,
     # fwd + bwd kernels, each on ONE device's shard of the batch: GSPMD
     # cannot partition a Mosaic call, so anything but the per-chip batch here
     # means every chip is computing the gathered global batch
-    want = [[per_chip_batch * model_cfg.n_head, model_cfg.seq_len,
-             model_cfg.head_dim]]
+    from ray_tpu.ops.attention import S_MINOR, kernel_layout
+
+    pair = kernel_layout(model_cfg.head_dim)
+    tile = [model_cfg.seq_len, model_cfg.head_dim]
+    want = [[per_chip_batch * model_cfg.n_head]
+            + (tile[::-1] if pair == S_MINOR else tile)]
     if summary["tpu_custom_calls"] < 2 or summary["attention_call_shapes"] != want:
         bad.append(f"compiled step has {summary['tpu_custom_calls']} Mosaic "
                    f"call(s) over {summary['attention_call_shapes']}; wanted "
@@ -495,6 +500,13 @@ def check_device(summary: Dict[str, Any], model_cfg, per_chip_batch: int,
     if kernels != {"fwd", "bwd"}:
         bad.append(f"flash tiling decisions recorded for {sorted(kernels)}, "
                    "not for fwd and bwd")
+    # ... each for the kernel pair its head width takes (the worker's record
+    # holds the other steps' attention layers too)
+    for d in summary["flash_tiling"]:
+        if d["layout"] != kernel_layout(d["hd"]):
+            bad.append(f"flash {d['kernel']} kernel traced for {d['layout']} "
+                       f"operands at hd={d['hd']}, where the rule says "
+                       f"{kernel_layout(d['hd'])}")
     return bad
 
 
@@ -637,8 +649,8 @@ def main() -> int:
           f"calls per device over {summary['attention_call_shapes']}")
     for d in summary["flash_tiling"]:
         print(f"flash tiling: {d['kernel']} rows={d['rows']} Sq={d['Sq']} "
-              f"Skv={d['Skv']} hd={d['hd']} -> block_q={d['block_q']} "
-              f"block_k={d['block_k']} vmem_estimate="
+              f"Skv={d['Skv']} hd={d['hd']} -> {d['layout']} block_q="
+              f"{d['block_q']} block_k={d['block_k']} vmem_estimate="
               f"{d['vmem_estimate'] / 2 ** 20:.2f} MiB")
     gib = 2.0 ** 30
     for d in summary["remat_policy"]:

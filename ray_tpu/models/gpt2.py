@@ -250,11 +250,12 @@ def _layernorm(x, scale, bias, eps=1e-5):
 
 
 def _attention(q, k, v, cfg: GPT2Config):
-    """q,k,v: [B, H, S, hd] → [B, H, S, hd], causal (head-major layout — the
-    flash kernels' native one, so the hot path has no boundary transposes)."""
+    """q,k,v → o, causal, all four in parts.head_layout's order for this head
+    width: the one the flash kernels take with no transpose at their edge."""
     from ray_tpu.ops.attention import resolve_attention
     from ray_tpu.parallel import mesh as mesh_lib
 
+    layout = parts.head_layout(cfg.head_dim)
     mesh = mesh_lib.current_mesh()
     if resolve_attention(cfg.attention_impl, mesh)[0] == "ring":
         from ray_tpu.ops.ring_attention import ring_attention_sharded
@@ -264,12 +265,14 @@ def _attention(q, k, v, cfg: GPT2Config):
                 "attention_impl='ring' needs a mesh with a cp axis; call the "
                 "model inside parallel.mesh.use_mesh(mesh) (train_step does)"
             )
+        # the ring's chunk kernels take [B, S, H, hd]
+        to_ring = tuple(layout.index(c) for c in "bshd")
         o = ring_attention_sharded(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2), mesh, axis_name="cp", causal=True,
+            *(jnp.transpose(x, to_ring) for x in (q, k, v)),
+            mesh, axis_name="cp", causal=True,
         )
-        return jnp.swapaxes(o, 1, 2)
-    return parts.causal_attention(q, k, v, cfg.attention_impl)
+        return jnp.transpose(o, tuple("bshd".index(c) for c in layout))
+    return parts.causal_attention(q, k, v, cfg.attention_impl, layout=layout)
 
 
 @jax.named_scope(scopes.BLOCK)
@@ -283,24 +286,31 @@ def _block(x, layer_params, cfg: GPT2Config):
     dt = cfg.dtype
     with jax.named_scope(scopes.LN1):
         h = _layernorm(x, p["ln1_scale"], p["ln1_bias"])
-    # head-major projection, one einsum per q/k/v: each matmul writes its
-    # output directly in the flash kernels' [B, H, S, hd] layout (XLA emits
-    # transposed-output dots with NO separate formatting op — measured 0.04
-    # ms/step). A packed single [D, 3·H·hd] dot was tried (round 5): it
-    # saved 7 ms of matmul but XLA materialized 12.5 ms/step of layout
-    # glue for the rank-5 transposed output — net loss.
+    # head-major projection, one einsum per q/k/v, each written in the order
+    # the flash kernels read at this head width (parts.head_layout): at
+    # GPT-2's 64 that is [H, B, hd, S] — how XLA stores such a head whatever
+    # the einsum says (an hd-minor [.., S, 64] fills half of every lane
+    # tile), the projections' outputs and the layer scan's saved stacks
+    # alike, so neither pass has a transposing copy between them and a
+    # kernel (PR 48; ten a layer stood there, and
+    # tests/test_flash_attention_tpu_compile.py holds the count at none). A
+    # packed single [D, 3·H·hd] dot was tried (round 5): it saved 7 ms of
+    # matmul but XLA materialized 12.5 ms/step of layout glue for the rank-5
+    # transposed output — net loss.
+    heads = parts.head_layout(cfg.head_dim).replace("d", "k")  # einsum's names
     with jax.named_scope(scopes.QKV):
         w, b = p["qkv_w"].astype(dt), p["qkv_b"].astype(dt)
         q, k, v = (
             checkpoint_name(
-                jnp.einsum("bsd,dhk->bhsk", h, w[:, i]) + b[i][None, :, None, :],
+                jnp.einsum(f"bsd,dhk->{heads}", h, w[:, i])
+                + jnp.expand_dims(b[i], (heads.index("b"), heads.index("s"))),
                 name)
             for i, name in enumerate((scopes.RES_Q, scopes.RES_K, scopes.RES_V))
         )
     with jax.named_scope(scopes.ATTN):
         attn = _attention(q, k, v, cfg)
     with jax.named_scope(scopes.PROJ):
-        x = x + jnp.einsum("bhsk,hkd->bsd", attn, p["proj_w"].astype(dt)) + p["proj_b"].astype(dt)
+        x = x + jnp.einsum(f"{heads},hkd->bsd", attn, p["proj_w"].astype(dt)) + p["proj_b"].astype(dt)
         x = checkpoint_name(x, scopes.RES_MID)
     with jax.named_scope(scopes.LN2):
         h = _layernorm(x, p["ln2_scale"], p["ln2_bias"])

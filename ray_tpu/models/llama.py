@@ -233,10 +233,18 @@ def _norm(x, g, cfg: LlamaConfig):
     return parts.rmsnorm(x, g, cfg.rms_eps, cfg.norm_unit_offset)
 
 
+def _head_layout(cfg: LlamaConfig) -> str:
+    """The order the block projects its heads in: parts.head_layout's for
+    the flash kernels; the EVA kernels take [B, H, S, hd] at any width."""
+    return "bhsd" if cfg.mixer == "eva" else parts.head_layout(cfg.head_dim)
+
+
 def _attention(q, k, v, p, cfg: LlamaConfig):
-    """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd]: the config's mixer."""
+    """q, k/v (H and KH heads) → o, in _head_layout's order ([B,H,S,hd] at
+    hd 128): the config's mixer."""
     if cfg.mixer != "eva":
-        return parts.causal_attention(q, k, v, cfg.attention_impl)
+        return parts.causal_attention(q, k, v, cfg.attention_impl,
+                                      layout=_head_layout(cfg))
     from ray_tpu.ops import eva_attention as eva
 
     impl, _, mesh = parts.attention_on_mesh(cfg.attention_impl)
@@ -256,23 +264,25 @@ def _block(x, p, cfg: LlamaConfig):
     """One block, x [B, S, D], under GPT-2's scopes and residual names."""
     positions = jnp.arange(x.shape[1])
     p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype, _MATMUL_WEIGHTS)}
+    heads = _head_layout(cfg).replace("d", "k")         # the einsums' names
+    s_minor = heads[-1] == "s"
     with jax.named_scope(scopes.LN1):
         h = _norm(x, p["attn_norm"], cfg)
     with jax.named_scope(scopes.QKV):
         # named after the rotation: a kept q or k is not rotated again
         q = checkpoint_name(parts.rope(
-            jnp.einsum("bsd,dhk->bhsk", h, p["wq"]),
-            positions, cfg.rope_theta), scopes.RES_Q)
+            jnp.einsum(f"bsd,dhk->{heads}", h, p["wq"]),
+            positions, cfg.rope_theta, s_minor), scopes.RES_Q)
         k = checkpoint_name(parts.rope(
-            jnp.einsum("bsd,dhk->bhsk", h, p["wk"]),
-            positions, cfg.rope_theta), scopes.RES_K)
+            jnp.einsum(f"bsd,dhk->{heads}", h, p["wk"]),
+            positions, cfg.rope_theta, s_minor), scopes.RES_K)
         v = checkpoint_name(
-            jnp.einsum("bsd,dhk->bhsk", h, p["wv"]), scopes.RES_V)
+            jnp.einsum(f"bsd,dhk->{heads}", h, p["wv"]), scopes.RES_V)
     with jax.named_scope(scopes.ATTN):
         attn = _attention(q, k, v, p, cfg)
     with jax.named_scope(scopes.PROJ):
         x = checkpoint_name(parts.residual_add(x, jnp.einsum(
-            "bhsk,hkd->bsd", attn, p["wo"],
+            f"{heads},hkd->bsd", attn, p["wo"],
             preferred_element_type=jnp.float32)), scopes.RES_MID)
     return _mlp(x, p, cfg)
 

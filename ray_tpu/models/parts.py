@@ -47,10 +47,13 @@ def rmsnorm(x, g, eps: float, unit_offset: bool = False):
     return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         s_minor: bool = False) -> jax.Array:
     """Rotary embedding, HF-llama convention: x [..., S, hd] with the head
-    dim split as [first half, second half] (rotate_half), NOT interleaved."""
-    hd = x.shape[-1]
+    dim split as [first half, second half] (rotate_half), NOT interleaved.
+    With ``s_minor`` x is [..., hd, S] (head_layout's order for a narrow
+    head) and so is the result."""
+    hd = x.shape[-2] if s_minor else x.shape[-1]
     half = hd // 2
     freqs = 1.0 / theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
@@ -64,7 +67,11 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     eye = jnp.eye(half, dtype=x.dtype)
     zero = jnp.zeros_like(eye)
     rot = jnp.block([[zero, eye], [-eye, zero]])                      # x @ rot
-    rotated = jnp.einsum("...d,de->...e", x, rot)
+    if s_minor:
+        cos, sin = cos.T, sin.T                                       # [hd, S]
+        rotated = jnp.einsum("...ds,de->...es", x, rot)
+    else:
+        rotated = jnp.einsum("...d,de->...e", x, rot)
     return (x.astype(jnp.float32) * cos
             + rotated.astype(jnp.float32) * sin).astype(x.dtype)
 
@@ -89,28 +96,48 @@ def attention_on_mesh(attention_impl: str):
     return impl, interpret, mesh
 
 
-def causal_attention(q, k, v, attention_impl: str):
+def head_layout(head_dim: int) -> str:
+    """The axis order a block projects its heads in (a flash_attention
+    layout over b, h, s, d), from the head width alone, whatever attention
+    then runs: the order the flash kernels take at that width with no
+    transpose at their edge. A head narrower than a lane tile (GPT-2's 64)
+    goes S-minor with the heads leading, "hbds" = [H, B, hd, S] — how XLA
+    stores such a projection's output and the layer scan's saved stack of
+    it whatever the einsum says; a wider one "bhsd"."""
+    from ray_tpu.ops.attention import S_MINOR, kernel_layout
+
+    return "hbds" if kernel_layout(head_dim) == S_MINOR else "bhsd"
+
+
+def causal_attention(q, k, v, attention_impl: str, *, layout: str = "bhsd"):
     """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd], causal (head-major layout —
-    the flash kernels' native one, so the hot path has no boundary
-    transposes); KH heads of k and v serve H / KH heads of q each."""
+    the hd-minor flash kernels' own, so the hot path has no boundary
+    transposes); KH heads of k and v serve H / KH heads of q each. Another
+    head-major ``layout`` (head_layout's) says where the four dims of the
+    three and of the result are. Which kernel pair runs is the head width's
+    either way; a block that hands the other pair's order pays the
+    transposes at the kernel's edge."""
     from ray_tpu.ops.attention import flash_attention_sharded
 
     impl, interpret, mesh = attention_on_mesh(attention_impl)
-    groups = q.shape[1] // k.shape[1]
+    heads = layout.index("h")
+    groups = q.shape[heads] // k.shape[heads]
     if groups > 1:
-        k = jnp.repeat(k, groups, axis=1)
-        v = jnp.repeat(v, groups, axis=1)
+        k = jnp.repeat(k, groups, axis=heads)
+        v = jnp.repeat(v, groups, axis=heads)
     if impl == "pallas":
         return flash_attention_sharded(
-            q, k, v, mesh, causal=True, interpret=interpret)
+            q, k, v, mesh, layout=layout, causal=True, interpret=interpret)
     # XLA path: einsum + mask; XLA fuses the softmax chain.
-    S = q.shape[2]
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    S = q.shape[layout.index("s")]
+    scale = 1.0 / math.sqrt(q.shape[layout.index("d")])
+    at_q, at_k = layout.replace("s", "q"), layout.replace("s", "k")
+    rows = layout[:2]                       # the logits keep the rows' order
+    logits = jnp.einsum(f"{at_q},{at_k}->{rows}qk", q, k) * scale
     mask = jnp.tril(jnp.ones((S, S), dtype=bool))
     logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    return jnp.einsum(f"{rows}qk,{at_k}->{at_q}", probs, v)
 
 
 def is_flash(attention_impl: str, mesh) -> bool:

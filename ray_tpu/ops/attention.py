@@ -8,30 +8,43 @@ backward pass recomputes logits blockwise from (q, k, lse) the flash-attention
 way.
 
 Design notes (TPU-first):
+- TWO kernel pairs, chosen by the head width alone (kernel_layout): where hd
+  fills the 128 lanes the operands are hd-minor, [B·H, S, hd]; where it is
+  narrower (GPT-2's 64) they are S-minor, [B·H, hd, S] — dense at any hd and
+  the way XLA stores such a head anyway, so the projections' outputs and the
+  layer scan's saved stacks go in and the gradients come out with no
+  transposing copy. The pairs share the tile rule, the VMEM arithmetic, the
+  transposed logits tile and the f32 statistics; their tiles' axes differ,
+  so each has its own BlockSpecs and loop body.
 - The public API takes [B, S, H, hd] and transposes at the boundary (XLA
-  fuses the transpose into the surrounding projection matmuls) or, with
-  layout="bhsd", takes head-major tensors as they are. Inside, batch and head
-  are merged (a free reshape) into one dim of independent rows, [B·H, S, hd]:
-  every block's minor dims are the (seq, head_dim) tile Mosaic requires, and
-  the grid walks the rows one at a time whatever the head count.
+  fuses the transpose into the surrounding projection matmuls) or, with a
+  head-major ``layout``, takes a pair's own order as it is. Inside, batch
+  and head are merged (a free reshape) into one dim of independent rows:
+  every block's minor dims are the tile Mosaic requires, and the grid walks
+  the rows one at a time whatever the head count.
 - The q/kv tile is choose_tiling's decision, from the shapes and an estimate
   of the VMEM the blocks need; callers pass no tile.
 - K/V live whole per row in VMEM (S·hd·2B ≈ 128 KiB at S=1024), so the kv
-  loop is VMEM-resident with no DMA choreography.
+  loop is VMEM-resident with no DMA choreography. The index maps put the
+  row first and the tile's position last: making kv (forward) or q
+  (backward) a third grid axis with scratch accumulators is a change of
+  those maps, not of the layout (ROADMAP D16).
 - The logits tile is computed transposed, s^T = k·q^T: softmax statistics are
   lane-dense [1, block_q] rows and their reductions run down the sublanes.
   Logits/softmax accumulate in f32 (MXU native via preferred_element_type);
   p·v and the backward matmuls run bf16→f32.
 - The causal mask is computed from GLOBAL positions `q_offset`/`kv_offset`
   (scalar-prefetch args), so the same kernel serves single-device attention
-  (offsets 0) and ring attention (per-step rotated offsets, ops/ring_attention).
+  (offsets 0) and ring attention (per-step rotated offsets, ops/ring_attention;
+  the ring's chunk functions keep the hd-minor pair at every width).
 - Backward = ONE fused kernel (grid over kv blocks, loop q): dk/dv written
   per kv block, dq accumulated in a VMEM-resident whole-row f32 scratch —
   s/p/dp computed once per block pair instead of twice (the split dq + dkv
   formulation costs 7 matmuls and double the exp/mask work; fused is 5).
 - lse/delta ride as [B·H, 1, S] so their (1, block) tiles satisfy the minor-
   dim rules and the kernels read them as the rows they are; lse is [B, H, S]
-  at the API edge.
+  at the API edge (the rows' two dims, in the caller's order, and S). The
+  S-minor backward makes delta itself, from o's row.
 
 No counterpart exists in the reference (it has no flash/SP story at all —
 SURVEY.md §2.10); this is new TPU-native code.
@@ -89,6 +102,24 @@ def resolve_attention(impl: str = "auto", mesh=None) -> Tuple[str, bool]:
 # Tiling: the kernels' work partition, chosen here from the shapes
 # --------------------------------------------------------------------------- #
 
+# Which way a kernel pair's operand tiles lie (kernel_layout's two answers,
+# the `layout` of an ops/flash_tiling event).
+HD_MINOR = "hd_minor"     # [rows, S, hd]: dense where hd fills the 128 lanes
+S_MINOR = "s_minor"       # [rows, hd, S]: dense at any hd
+
+
+def kernel_layout(hd: int) -> str:
+    """THE rule for which kernel pair a head width takes, from the width
+    alone. A head narrower than a lane tile (GPT-2's 64) stored hd-minor
+    fills half of every tile, in HBM and in VMEM, and XLA does not store it
+    so: it writes the projections' outputs and the layer scan's saved stacks
+    S-minor, and a kernel that reads hd-minor costs a transposing copy a
+    tensor each way (PERF.md §6, PR 48). Such a head takes the S-minor pair;
+    a whole tile's worth of head (hd % 128 == 0) is dense hd-minor, which is
+    what XLA picks there, and keeps the hd-minor pair."""
+    return HD_MINOR if hd % 128 == 0 else S_MINOR
+
+
 class Tiling(NamedTuple):
     block_q: int
     block_k: int
@@ -122,24 +153,34 @@ def vmem_block_bytes(shape, itemsize: int) -> int:
 
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int,
-                  Sq: int, Skv: int, hd: int, dtype_bytes: int) -> int:
+                  Sq: int, Skv: int, hd: int, dtype_bytes: int,
+                  layout: str = HD_MINOR) -> int:
     """VMEM bytes one grid step needs, as the rule counts them: every in/out
     block twice (Pallas double-buffers them), the backward's f32 dq
     accumulator once, and one [block_k, block_q] f32 logits tile plus the
-    loop's f32 accumulators. An upper bound, not Mosaic's own figure."""
+    loop's f32 accumulators. An upper bound, not Mosaic's own figure.
+    ``layout`` says which way a block lies: S-minor blocks are [hd, tile]
+    (no lane is padded at any hd), hd-minor blocks [tile, hd]."""
     blk = vmem_block_bytes
+
+    def op(rows, itemsize=dtype_bytes):         # an operand block of `rows`
+        return blk((hd, rows) if layout == S_MINOR else (rows, hd), itemsize)
+
     tile = blk((block_k, block_q), 4)
     if kernel == "fwd":
-        io = (2 * blk((block_q, hd), dtype_bytes)         # q, o
-              + 2 * blk((Skv, hd), dtype_bytes)           # k, v: whole rows
+        io = (2 * op(block_q)                             # q, o
+              + 2 * op(Skv)                               # k, v: whole rows
               + blk((1, block_q), 4))                     # lse
         live = tile + blk((hd, block_q), 4)               # s^T; acc^T
     else:
-        io = (3 * blk((Sq, hd), dtype_bytes)              # q, do, dq: whole rows
-              + 4 * blk((block_k, hd), dtype_bytes)       # k, v, dk, dv
-              + 2 * blk((1, Sq), 4))                      # lse, delta
+        # q, do, dq: whole rows, and lse, delta; the S-minor kernel makes
+        # delta itself, from o's row
+        rows, stats = (4, 1) if layout == S_MINOR else (3, 2)
+        io = (rows * op(Sq)
+              + 4 * op(block_k)                           # k, v, dk, dv
+              + stats * blk((1, Sq), 4))
         live = (blk((hd, Sq), 4)                          # dq^T accumulator
-                + tile + 2 * blk((block_k, hd), 4))       # s^T; dk, dv
+                + tile + 2 * op(block_k, 4))              # s^T; dk, dv
     return 2 * io + live
 
 
@@ -166,21 +207,26 @@ def record_decision(decisions: Dict[tuple, Dict[str, Any]], event: str,
 
 
 def _record(kernel: str, rows: int, Sq: int, Skv: int, hd: int,
-            tiling: Tiling) -> None:
+            tiling: Tiling, layout: str) -> None:
     """The tiling a flash kernel is traced with, with the shapes it was
-    given (``ops/flash_tiling``)."""
+    given and the pair it belongs to (``ops/flash_tiling``)."""
     record_decision(_decisions, names.FLASH_TILING, dict(zip(
-        names.FLASH_TILING_ARGS, (kernel, rows, Sq, Skv, hd) + tuple(tiling))))
+        names.FLASH_TILING_ARGS,
+        (kernel, rows, Sq, Skv, hd) + tuple(tiling) + (layout,))))
 
 
 def choose_tiling(
     kernel: str, Sq: int, Skv: int, hd: int, dtype_bytes: int, *,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
+    layout: str = HD_MINOR,
 ) -> Tiling:
     """THE rule for how a flash kernel tiles its work. ``kernel`` is ``"fwd"``
     or ``"bwd"``. The keywords are a caller's explicit choices: each is kept
     (clamped to a divisor of its sequence) and the rule fills in the other.
     Raises ``ValueError`` when no tiling of its own fits the budget.
+    ``layout`` is the pair's (kernel_layout): the tile is along S either way,
+    the sublanes of an hd-minor block and the lanes of an S-minor one, and
+    only the estimate differs.
 
     The constants, fitted on a v5e inside the `gpt2-124m` and `gpt2-xl` train
     steps and standalone at ``[8,16,2048,128]`` (PERF.md §6, PR 25):
@@ -209,7 +255,7 @@ def choose_tiling(
     explicit = block_q is not None and block_k is not None
     while True:
         t = Tiling(q_, k_, vmem_estimate(kernel, q_, k_, Sq, Skv, hd,
-                                         dtype_bytes))
+                                         dtype_bytes, layout))
         if explicit or t.vmem_estimate <= VMEM_BUDGET_BYTES:
             return t
         can_k = block_k is None and k_ > _MIN_TILE
@@ -223,7 +269,7 @@ def choose_tiling(
     raise ValueError(
         f"flash attention {kernel}: no tiling fits the VMEM budget of "
         f"{VMEM_BUDGET_BYTES} bytes for Sq={Sq} Skv={Skv} hd={hd} "
-        f"({dtype_bytes}-byte operands): the smallest tried, block_q="
+        f"({dtype_bytes}-byte operands, {layout}): the smallest tried, block_q="
         f"{t.block_q} block_k={t.block_k}, is estimated at "
         f"{t.vmem_estimate} bytes"
     )
@@ -232,6 +278,35 @@ def choose_tiling(
 # --------------------------------------------------------------------------- #
 # Forward
 # --------------------------------------------------------------------------- #
+
+def _kv_block_range(q_global, kv_off_ref, block_q: int, block_k: int,
+                    nk: int, causal: bool):
+    """(kv blocks a q tile starting at ``q_global`` attends to, how many of
+    them need no mask) of ``nk``: under the causal mask only blocks whose
+    global start can be <= the last query row count, and those whose last
+    column is <= the FIRST query row need no mask — only the diagonal-
+    straddling tail pays the iota/select work."""
+    if not causal:
+        return nk, nk
+    last_q = q_global + block_q - 1
+    num_blocks = jnp.clip((last_q - kv_off_ref[0]) // block_k + 1, 0, nk)
+    num_full = jnp.clip((q_global - kv_off_ref[0] + 1) // block_k, 0, nk)
+    return num_blocks, num_full
+
+
+def _q_block_range(kv_global, q_off_ref, block_q: int, block_k: int,
+                   nq: int, causal: bool):
+    """(first q tile that sees the kv block starting at ``kv_global``, first
+    that sees all of it) of ``nq``: the backward's walk, masked between the
+    two and unmasked from the second on."""
+    if not causal:
+        return 0, 0
+    first = jnp.clip((kv_global - q_off_ref[0]) // block_q, 0, nq)
+    first_full = jnp.clip(
+        -((q_off_ref[0] - kv_global - block_k + 1) // block_q), 0, nq
+    )
+    return first, first_full
+
 
 def _fwd_kernel(
     q_off_ref, kv_off_ref,            # scalar prefetch: global offsets [1]
@@ -242,19 +317,8 @@ def _fwd_kernel(
     qi = pl.program_id(1)
     q_global = q_off_ref[0] + qi * block_q
 
-    nk = kv_len // block_k
-    if causal:
-        # only kv blocks whose global start can be <= the last query row
-        last_q = q_global + block_q - 1
-        num_blocks = jnp.clip(
-            (last_q - kv_off_ref[0]) // block_k + 1, 0, nk
-        )
-        # blocks whose last column <= the FIRST query row need no mask; only
-        # the diagonal-straddling tail pays the iota/select work
-        num_full = jnp.clip((q_global - kv_off_ref[0] + 1) // block_k, 0, nk)
-    else:
-        num_blocks = nk
-        num_full = nk
+    num_blocks, num_full = _kv_block_range(
+        q_global, kv_off_ref, block_q, block_k, kv_len // block_k, causal)
 
     # The logits tile is held TRANSPOSED, s^T = k·q^T, [block_k, block_q]:
     # the softmax reductions then run down the sublanes (elementwise VPU
@@ -320,7 +384,7 @@ def _mha_forward_bhsd(
     R = B * H
     t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize,
                       block_q=block_q, block_k=block_k)
-    _record("fwd", R, Sq, Skv, hd, t)
+    _record("fwd", R, Sq, Skv, hd, t, HD_MINOR)
     bq, bk = t.block_q, t.block_k
     kv_row = pl.BlockSpec((None, Skv, hd), lambda g, i, *_: (g, 0, 0))
 
@@ -353,6 +417,108 @@ def _mha_forward_bhsd(
     return o.reshape(B, H, Sq, hd), lse.reshape(B, H, Sq)
 
 
+def _fwd_kernel_s_minor(
+    q_off_ref, kv_off_ref,            # scalar prefetch: global offsets [1]
+    q_ref, k_ref, v_ref,              # [hd, bq], [hd, Skv], [hd, Skv]
+    o_ref, lse_ref,                   # [hd, bq], [1, bq]
+    *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
+):
+    """_fwd_kernel on S-minor tiles: every operand tile is [hd, tile], dense
+    at any hd (the sequence fills the lanes). The logits tile and the
+    statistics are the same s^T [block_k, block_q] and [1, block_q] rows; the
+    accumulator is o^T [hd, block_q] and is stored as it is, and
+    v · p^T is a plain product. What the layout costs is k's tile transposed
+    for s^T = k^T · q (a [hd, block_k] tile a pair)."""
+    qi = pl.program_id(1)
+    q_global = q_off_ref[0] + qi * block_q
+
+    num_blocks, num_full = _kv_block_range(
+        q_global, kv_off_ref, block_q, block_k, kv_len // block_k, causal)
+
+    qs = q_ref[...] * jnp.asarray(scale, q_ref.dtype)        # [hd, bq]
+    hd = qs.shape[0]
+
+    def make_body(masked):
+        def body(ki, carry):
+            m, l, acc = carry               # [1, bq], [1, bq], [hd, bq]
+            kv = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+            s = lax.dot_general(
+                k_ref[:, kv], qs, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                               # k^T·q, [bk, bq]
+            if masked:
+                keep = (
+                    q_global + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 1)
+                    >= kv_off_ref[0] + ki * block_k + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 0))
+                s = jnp.where(keep, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+            v = v_ref[:, kv]
+            acc = acc * alpha + jnp.dot(
+                v, p.astype(v.dtype), preferred_element_type=jnp.float32,
+            )                               # v·p^T = (p·v)^T, [hd, bq]
+            return m_new, l, acc
+        return body
+
+    carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32),
+             jnp.zeros((hd, block_q), jnp.float32))
+    carry = lax.fori_loop(0, num_full, make_body(False), carry)
+    m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal), carry)
+    l_safe = jnp.where(l > 0, l, 1.0)
+    o_ref[...] = (acc / l_safe).astype(o_ref.dtype)
+    lse_ref[...] = jnp.where(l > 0, m + jnp.log(l_safe), _NEG_INF)
+
+
+def _mha_forward_s_minor(
+    q, k, v, q_offset, kv_offset, *,
+    causal: bool, scale: float, interpret: bool,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """q,k,v: [B, H, hd, S] → (o [B,H,hd,S], lse [B,H,S]): _mha_forward_bhsd
+    with the sequence minor ([H, B, ..] as well: the two leading dims are the
+    rows). Rows, grid and tile rule are the same; a block is [hd, tile] and
+    its index moves along the last dim."""
+    B, H, hd, Sq = q.shape
+    Skv = k.shape[3]
+    R = B * H
+    t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize,
+                      block_q=block_q, block_k=block_k, layout=S_MINOR)
+    _record("fwd", R, Sq, Skv, hd, t, S_MINOR)
+    bq, bk = t.block_q, t.block_k
+    q_tile = pl.BlockSpec((None, hd, bq), lambda g, i, *_: (g, 0, i))
+    kv_row = pl.BlockSpec((None, hd, Skv), lambda g, i, *_: (g, 0, 0))
+
+    kernel = functools.partial(
+        _fwd_kernel_s_minor, scale=scale, causal=causal,
+        block_q=bq, block_k=bk, kv_len=Skv,
+    )
+    o, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R, Sq // bq),
+            in_specs=[q_tile, kv_row, kv_row],
+            out_specs=[
+                q_tile,
+                pl.BlockSpec((None, 1, bq), lambda g, i, *_: (g, 0, i)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((R, hd, Sq), q.dtype),
+            jax.ShapeDtypeStruct((R, 1, Sq), jnp.float32),
+        ],
+        interpret=interpret,
+        name=names.FLASH_FWD_KERNEL,
+    )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(R, hd, Skv),
+      v.reshape(R, hd, Skv))
+    return o.reshape(B, H, hd, Sq), lse.reshape(B, H, Sq)
+
+
 # --------------------------------------------------------------------------- #
 # Backward
 # --------------------------------------------------------------------------- #
@@ -378,14 +544,8 @@ def _fused_bwd_kernel(
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     nq = q_len // block_q
-    if causal:
-        first = jnp.clip((kv_global - q_off_ref[0]) // block_q, 0, nq)
-        first_full = jnp.clip(
-            -((q_off_ref[0] - kv_global - block_k + 1) // block_q), 0, nq
-        )
-    else:
-        first = 0
-        first_full = 0
+    first, first_full = _q_block_range(
+        kv_global, q_off_ref, block_q, block_k, nq, causal)
 
     scale_c = jnp.asarray(scale, q_ref.dtype)
 
@@ -465,7 +625,7 @@ def _mha_backward_bhsd(
     R = B * H
     t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize,
                       block_q=block_q, block_k=block_k)
-    _record("bwd", R, Sq, Skv, hd, t)
+    _record("bwd", R, Sq, Skv, hd, t, HD_MINOR)
     bq, bk = t.block_q, t.block_k
 
     # delta_i = rowsum(dO_i * O_i): cheap elementwise+reduce, XLA fuses it.
@@ -502,6 +662,134 @@ def _mha_backward_bhsd(
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
+def _fused_bwd_kernel_s_minor(
+    q_off_ref, kv_off_ref,
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,       # [hd, Sq] / [hd, bk]
+    dq_ref, dk_ref, dv_ref, dq_acc,
+    *, scale: float, causal: bool, block_q: int, block_k: int, q_len: int,
+):
+    """_fused_bwd_kernel on S-minor tiles. s^T = k^T·q and dp^T = v^T·do
+    contract hd, dimension 0 of both tiles (Mosaic transposes the [hd, bk]
+    one); dv^T = do·p and dk^T = q·ds contract the q tile of both operands
+    (the form of q·k^T), dq^T = k·ds^T is a plain product into the [hd, Sq]
+    accumulator, which is written as it is.
+    delta = rowsum(do · o) is made here, a q tile at a time, from the o row
+    (a sum down hd's sublanes): made by XLA beside the out-projection's
+    backward, it drew o and do into that product's layout, batch-major, and
+    each cost a transposing copy on its way here."""
+    ki = pl.program_id(1)
+    kv_global = kv_off_ref[0] + ki * block_k
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    nq = q_len // block_q
+    first, first_full = _q_block_range(
+        kv_global, q_off_ref, block_q, block_k, nq, causal)
+
+    scale_c = jnp.asarray(scale, q_ref.dtype)
+    k = k_ref[...]                                       # [hd, bk]
+    v = v_ref[...]
+    hd = k.shape[0]
+    k_scaled = k * scale_c
+
+    def make_body(masked):
+        def body(qi, carry):
+            dk, dv = carry                               # [hd, bk] f32
+            sl = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            qs = q_ref[:, sl] * scale_c                  # [hd, bq]
+            do = do_ref[:, sl]
+            lse = lse_ref[:, sl]                         # [1, bq]
+            delta = jnp.sum(
+                do.astype(jnp.float32) * o_ref[:, sl].astype(jnp.float32),
+                axis=0, keepdims=True)
+            s = lax.dot_general(
+                k, qs, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # k^T·q, [bk, bq]
+            if masked:
+                keep = (
+                    q_off_ref[0] + qi * block_q + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 1)
+                    >= kv_global + lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 0))
+                s = jnp.where(keep, s, _NEG_INF)
+            p = jnp.exp(s - lse)                         # [bk, bq]
+            dv = dv + lax.dot_general(
+                do, p.astype(do.dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # do·p, [hd, bk]
+            dp = lax.dot_general(
+                v, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # v^T·do, [bk, bq]
+            ds = (p * (dp - delta)).astype(qs.dtype)     # [bk, bq]
+            dk = dk + lax.dot_general(
+                qs, ds, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # q·ds, [hd, bk]
+            dq_acc[:, sl] += jnp.dot(
+                k_scaled, ds, preferred_element_type=jnp.float32)
+            return dk, dv
+        return body
+
+    carry = (jnp.zeros((hd, block_k), jnp.float32),
+             jnp.zeros((hd, block_k), jnp.float32))
+    carry = lax.fori_loop(first, first_full, make_body(causal), carry)
+    dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _write_dq():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _mha_backward_s_minor(
+    q, k, v, o, lse, do, q_offset, kv_offset, *,
+    causal: bool, scale: float, interpret: bool,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
+):
+    """All tensors [B, H, hd, S]; lse [B, H, S]. Returns dq, dk, dv:
+    _mha_backward_bhsd with the sequence minor."""
+    B, H, hd, Sq = q.shape
+    Skv = k.shape[3]
+    R = B * H
+    t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize,
+                      block_q=block_q, block_k=block_k, layout=S_MINOR)
+    _record("bwd", R, Sq, Skv, hd, t, S_MINOR)
+    bq, bk = t.block_q, t.block_k
+    row = pl.BlockSpec((None, hd, Sq), lambda g, i, *_: (g, 0, 0))
+    kv_block = pl.BlockSpec((None, hd, bk), lambda g, i, *_: (g, 0, i))
+    stat = pl.BlockSpec((None, 1, Sq), lambda g, i, *_: (g, 0, 0))
+
+    fused_kernel = functools.partial(
+        _fused_bwd_kernel_s_minor, scale=scale, causal=causal,
+        block_q=bq, block_k=bk, q_len=Sq,
+    )
+    dq, dk, dv = pl.pallas_call(
+        fused_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R, Skv // bk),
+            in_specs=[row, kv_block, kv_block, row, row, stat],
+            out_specs=[row, kv_block, kv_block],
+            scratch_shapes=[pltpu.VMEM((hd, Sq), jnp.float32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((R, hd, Sq), q.dtype),
+            jax.ShapeDtypeStruct((R, hd, Skv), k.dtype),
+            jax.ShapeDtypeStruct((R, hd, Skv), v.dtype),
+        ],
+        interpret=interpret,
+        name=names.FLASH_BWD_KERNEL,
+    )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(R, hd, Skv),
+      v.reshape(R, hd, Skv), o.reshape(R, hd, Sq), do.reshape(R, hd, Sq),
+      lse.reshape(R, 1, Sq))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
 # --------------------------------------------------------------------------- #
 # Public API ([B, S, H, hd] boundary layout)
 # --------------------------------------------------------------------------- #
@@ -510,25 +798,47 @@ def _to_bhsd(x):
     return jnp.swapaxes(x, 1, 2)
 
 
+def _relayout(x, src: str, dst: str):
+    """x from axis order ``src`` to ``dst`` (strings over b, h, s, d)."""
+    if src == dst:
+        return x
+    return jnp.transpose(x, tuple(src.index(c) for c in dst))
+
+
+# The axis orders a kernel pair takes as they are. Batch and head are merged
+# into rows, so either may lead: the S-minor pair's callers put the heads
+# first (parts.head_layout).
+_KERNEL_AXES = {HD_MINOR: ("bhsd",), S_MINOR: ("bhds", "hbds")}
+HEAD_MAJOR_LAYOUTS = _KERNEL_AXES[HD_MINOR] + _KERNEL_AXES[S_MINOR]
+LAYOUTS = ("bshd",) + HEAD_MAJOR_LAYOUTS
+
+
+def _kernel_axes(layout: str, hd: int) -> Tuple[str, str]:
+    """(pair, the axis order its kernels are handed) for a caller's layout:
+    the caller's own where the pair takes it, else the pair's first."""
+    pair = kernel_layout(hd)
+    own = _KERNEL_AXES[pair]
+    return pair, layout if layout in own else own[0]
+
+
 def _zero_off():
     return jnp.zeros((1,), jnp.int32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret, bhsd):
+           bwd_block_k, interpret, layout):
     o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-                      bwd_block_k, interpret, bhsd)
+                      bwd_block_k, interpret, layout)
     return o
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-               bwd_block_k, interpret, bhsd):
-    if bhsd:
-        qt, kt, vt = q, k, v
-    else:
-        qt, kt, vt = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
-    o, lse = _mha_forward_bhsd(
+               bwd_block_k, interpret, layout):
+    pair, axes = _kernel_axes(layout, q.shape[layout.index("d")])
+    qt, kt, vt = (_relayout(x, layout, axes) for x in (q, k, v))
+    forward = _mha_forward_s_minor if pair == S_MINOR else _mha_forward_bhsd
+    o, lse = forward(
         qt, kt, vt, _zero_off(), _zero_off(),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
@@ -537,21 +847,21 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
     # does not run the forward kernel a second time in the backward
     o = checkpoint_name(o, names.RES_FLASH_O)
     lse = checkpoint_name(lse, names.RES_FLASH_LSE)
-    return (o if bhsd else _to_bhsd(o)), (qt, kt, vt, o, lse)
+    return _relayout(o, axes, layout), (qt, kt, vt, o, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-               interpret, bhsd, res, do):
+               interpret, layout, res, do):
     qt, kt, vt, o, lse = res
-    dq, dk, dv = _mha_backward_bhsd(
-        qt, kt, vt, o, lse, do if bhsd else _to_bhsd(do),
+    pair, axes = _kernel_axes(layout, do.shape[layout.index("d")])
+    backward = _mha_backward_s_minor if pair == S_MINOR else _mha_backward_bhsd
+    grads = backward(
+        qt, kt, vt, o, lse, _relayout(do, layout, axes),
         _zero_off(), _zero_off(),
         causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k,
         interpret=interpret,
     )
-    if bhsd:
-        return dq, dk, dv
-    return _to_bhsd(dq), _to_bhsd(dk), _to_bhsd(dv)
+    return tuple(_relayout(g, axes, layout) for g in grads)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -572,11 +882,16 @@ def flash_attention(
     interpret: Optional[bool] = None,
     layout: str = "bshd",
 ) -> jax.Array:
-    """Multi-head flash attention. q,k,v: [B, S, H, hd] → [B, S, H, hd]
-    (layout="bshd", the default) or [B, H, S, hd] in and out
-    (layout="bhsd" — the kernels' native layout; callers that can produce
-    head-major tensors directly skip the boundary transposes entirely, worth
-    ~3% of a GPT-2 train step on v5e).
+    """Multi-head flash attention. q,k,v in and o out are in the axis order
+    ``layout`` spells (one of LAYOUTS): [B, S, H, hd] ("bshd", the default),
+    head-major hd-minor ("bhsd") or head-major S-minor ("bhds", or "hbds"
+    with the heads leading: [.., hd, S]).
+
+    Which kernel pair runs is kernel_layout's answer for hd, not the
+    caller's: "bhsd" is the hd-minor pair's own order and "bhds" / "hbds"
+    the S-minor pair's (batch and head are merged into rows, so either may
+    lead), and a caller that hands a pair its own order (models/gpt2.py
+    does) has no transpose at the boundary; any other is transposed here.
 
     The q/kv tile, forward and backward separately, is choose_tiling's, from
     the shapes. The block_* keywords are explicit overrides of it: block_q /
@@ -585,16 +900,16 @@ def flash_attention(
     Differentiable (custom VJP, flash backward). On non-TPU backends the
     kernels run in Pallas interpreter mode so tests validate the same code.
     """
-    if layout not in ("bshd", "bhsd"):
+    if layout not in LAYOUTS:
         raise ValueError(f"unknown layout {layout!r}")
     if interpret is None:
         _, interpret = resolve_attention()
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(q.shape[layout.index("d")])
     return _flash(
         q, k, v, causal, scale, block_q, block_k,
         bwd_block_q or block_q, bwd_block_k or block_k,
-        interpret, layout == "bhsd",
+        interpret, layout,
     )
 
 
@@ -617,24 +932,33 @@ def batch_head_axes(mesh, batch: int, heads: int):
 
 
 @jax.named_scope(names.FLASH_ATTENTION)
-def flash_attention_sharded(q, k, v, mesh, **kwargs) -> jax.Array:
+def flash_attention_sharded(q, k, v, mesh, *, layout: str = "bhsd",
+                            **kwargs) -> jax.Array:
     """flash_attention for callers under jit/GSPMD (the model forward).
-    q, k, v: GLOBAL [B, H, S, hd] in and out; kwargs as flash_attention's.
+    q, k, v: GLOBAL head-major arrays in and out — [B, H, S, hd], or any
+    other of flash_attention's head-major ``layout``s; kwargs as
+    flash_attention's.
 
     GSPMD cannot partition a Mosaic custom call: under a jit over more than
     one device the bare pallas_call does not lower at all ("Mosaic kernels
     cannot be automatically partitioned. Please wrap the call in a
-    shard_map"). The shard_map hands each device its own
-    [B/(dp·fsdp), H/tp, S, hd] shard. The sequence stays whole per device
-    (a cp axis belongs to ring_attention_sharded)."""
+    shard_map"). The shard_map hands each device its own shard, the batch
+    over dp·fsdp and the heads over tp, wherever ``layout`` has them. The
+    sequence stays whole per device (a cp axis belongs to
+    ring_attention_sharded)."""
+    if layout not in HEAD_MAJOR_LAYOUTS:
+        raise ValueError(f"unknown head-major layout {layout!r}")
     if mesh is None:
-        return flash_attention(q, k, v, layout="bhsd", **kwargs)
+        return flash_attention(q, k, v, layout=layout, **kwargs)
     if kwargs.get("interpret") is None:
         _, kwargs["interpret"] = resolve_attention(mesh=mesh)
-    batch_axes, head_ax = batch_head_axes(mesh, q.shape[0], q.shape[1])
-    spec = P(batch_axes, head_ax, None, None)
+    b, h = layout.index("b"), layout.index("h")
+    batch_axes, head_ax = batch_head_axes(mesh, q.shape[b], q.shape[h])
+    spec = [None] * 4
+    spec[b], spec[h] = batch_axes, head_ax
+    spec = P(*spec)
     fn = jax.shard_map(
-        functools.partial(flash_attention, layout="bhsd", **kwargs),
+        functools.partial(flash_attention, layout=layout, **kwargs),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

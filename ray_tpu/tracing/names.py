@@ -91,11 +91,14 @@ KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
-FLASH_TILING_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
-                     "vmem_estimate")
+_TILE_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
+              "vmem_estimate")
+# ... and which kernel pair it was traced for: "s_minor" ([rows, hd, S]
+# operands, where hd is narrower than a lane tile) or "hd_minor"
+FLASH_TILING_ARGS = _TILE_ARGS + ("layout",)
 # the same for an EVA kernel (ops/eva_attention.py): Sq = Skv = the sequence
 EVA_TILING = "ops/eva_tiling"
-EVA_TILING_ARGS = FLASH_TILING_ARGS + ("window", "chunk")
+EVA_TILING_ARGS = _TILE_ARGS + ("window", "chunk")
 # the same for a scan kernel (ops/mamba2.py): the batch rows, the (padded)
 # sequence, the chunk, the heads that share a group's B and C, head width and
 # state, and the heads one grid step takes

@@ -110,6 +110,11 @@ def test_attention_call_shapes_reads_compiled_hlo():
     assert chip_smoke.attention_call_shapes(hlo, 64) == (
         2, [[96, 1024, 64], [384, 1024, 64]]
     )
+    # the S-minor pair's operands are [rows, hd, S]
+    s_minor = hlo.replace("1024,64]", "64,1024]")
+    assert chip_smoke.attention_call_shapes(s_minor, 64) == (
+        2, [[96, 64, 1024], [384, 64, 1024]]
+    )
 
 
 # ------------------------------------------------------- separate processes

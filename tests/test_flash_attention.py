@@ -37,9 +37,32 @@ def make_qkv(key, B=2, S=256, H=4, hd=64, dtype=jnp.float32):
     return q, k, v
 
 
+# The two kernel pairs (ops/attention.kernel_layout): which one a head width
+# takes is the rule's, so a test that wants the OTHER pair at that width —
+# the hd-minor pair at hd 64 and 32 (the ring's chunk kernels run it there),
+# the S-minor pair at 128 — steers the rule, in the test.
+PAIRS = [attention.HD_MINOR, attention.S_MINOR]
+
+
+@pytest.fixture(params=PAIRS)
+def pair(request, monkeypatch):
+    monkeypatch.setattr(attention, "kernel_layout", lambda hd: request.param)
+    return request.param
+
+
+def flash(q, k, v, layout="bshd", **kw):
+    """flash_attention on [B, S, H, hd] arrays handed over in ``layout``."""
+    to = tuple("bshd".index(c) for c in layout)
+    back = tuple(layout.index(c) for c in "bshd")
+    o = flash_attention(*(jnp.transpose(x, to) for x in (q, k, v)),
+                        layout=layout, **kw)
+    return jnp.transpose(o, back)
+
+
+@pytest.mark.parametrize("hd", [64, 32])
 @pytest.mark.parametrize("causal", [True, False])
-def test_forward_matches_reference(causal):
-    q, k, v = make_qkv(jax.random.PRNGKey(0))
+def test_forward_matches_reference(causal, hd, pair):
+    q, k, v = make_qkv(jax.random.PRNGKey(0), hd=hd)
     out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
     ref = ref_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(
@@ -47,7 +70,7 @@ def test_forward_matches_reference(causal):
     )
 
 
-def test_forward_nondivisible_block_fallback():
+def test_forward_nondivisible_block_fallback(pair):
     # S=160 not divisible by 64 → _pick_block halves until it divides
     q, k, v = make_qkv(jax.random.PRNGKey(1), S=160)
     out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
@@ -57,9 +80,10 @@ def test_forward_nondivisible_block_fallback():
     )
 
 
+@pytest.mark.parametrize("hd", [64, 32])
 @pytest.mark.parametrize("causal", [True, False])
-def test_gradients_match_reference(causal):
-    q, k, v = make_qkv(jax.random.PRNGKey(2), B=1, S=128, H=2, hd=32)
+def test_gradients_match_reference(causal, hd, pair):
+    q, k, v = make_qkv(jax.random.PRNGKey(2), B=1, S=128, H=2, hd=hd)
 
     def loss_flash(q, k, v):
         o = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
@@ -121,15 +145,18 @@ def test_fully_masked_chunk_is_zero_weight():
     np.testing.assert_array_equal(np.asarray(o), 0.0)
 
 
+@pytest.mark.parametrize("layout", attention.LAYOUTS)
 @pytest.mark.parametrize("B,H", [(2, 4), (1, 5)])
-def test_merged_rows_match_reference(B, H):
+def test_merged_rows_match_reference(B, H, layout, pair):
     """Batch and head are merged into the one dim of rows the grid walks:
-    any head count, several blocks a row, must match the numerics of the
+    any head count, several blocks a row, either of the two leading — every
+    layout a caller may hand over, to either pair (its own order as it is,
+    any other transposed at the edge) — must match the numerics of the
     reference, fwd and grad."""
     q, k, v = make_qkv(jax.random.PRNGKey(7), B=B, H=H)
 
     def loss(q, k, v):
-        o = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+        o = flash(q, k, v, layout, causal=True, block_q=64, block_k=64)
         return jnp.sum(o.astype(jnp.float32) ** 2), o
 
     (l, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
@@ -167,63 +194,107 @@ TILING_SHAPES = {
 }
 
 
+@pytest.mark.parametrize("layout", PAIRS)
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 @pytest.mark.parametrize("case", sorted(TILING_SHAPES))
-def test_choose_tiling_fits_the_shapes_it_is_given(case, kernel):
+def test_choose_tiling_fits_the_shapes_it_is_given(case, kernel, layout):
     Sq, Skv, hd = TILING_SHAPES[case]
-    t = attention.choose_tiling(kernel, Sq, Skv, hd, 2)
+    t = attention.choose_tiling(kernel, Sq, Skv, hd, 2, layout=layout)
     assert Sq % t.block_q == 0 and Skv % t.block_k == 0
     assert 0 < t.vmem_estimate <= attention.VMEM_BUDGET_BYTES
     assert t.vmem_estimate == attention.vmem_estimate(
-        kernel, t.block_q, t.block_k, Sq, Skv, hd, 2)
+        kernel, t.block_q, t.block_k, Sq, Skv, hd, 2, layout)
     # the target tile wherever it divides the sequence and fits; a smaller
     # one only where the estimate says the target does not fit
     want = (attention._pick_block(Sq, 512), attention._pick_block(Skv, 512))
     if (t.block_q, t.block_k) != want:
         assert attention.vmem_estimate(
-            kernel, *want, Sq, Skv, hd, 2) > attention.VMEM_BUDGET_BYTES
+            kernel, *want, Sq, Skv, hd, 2, layout) > attention.VMEM_BUDGET_BYTES
 
     # explicit keywords override the rule, each on its own
-    e = attention.choose_tiling(kernel, Sq, Skv, hd, 2, block_q=32, block_k=16)
+    e = attention.choose_tiling(kernel, Sq, Skv, hd, 2, block_q=32,
+                                block_k=16, layout=layout)
     assert (e.block_q, e.block_k) == (32, 16)
-    e = attention.choose_tiling(kernel, Sq, Skv, hd, 2, block_q=32)
+    e = attention.choose_tiling(kernel, Sq, Skv, hd, 2, block_q=32,
+                                layout=layout)
     assert e.block_q == 32 and Skv % e.block_k == 0
-    e = attention.choose_tiling(kernel, Sq, Skv, hd, 2, block_k=32)
+    e = attention.choose_tiling(kernel, Sq, Skv, hd, 2, block_k=32,
+                                layout=layout)
     assert e.block_k == 32 and Sq % e.block_q == 0
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("case", sorted(TILING_SHAPES))
+def test_s_minor_estimate_counts_the_blocks_it_is_given_unpadded(case, kernel):
+    """An S-minor block is [hd, tile]: the sequence fills the lanes, so at
+    these shapes (hd a multiple of the 16 bf16 sublanes, tiles of whole lane
+    tiles or the whole row) the estimate is the blocks' own bytes, counted
+    here by hand — where the hd-minor estimate pays a whole lane tile for
+    half a head."""
+    Sq, Skv, hd = TILING_SHAPES[case]
+    t = attention.choose_tiling(kernel, Sq, Skv, hd, 2,
+                                layout=attention.S_MINOR)
+    bq, bk = t.block_q, t.block_k
+    lanes = lambda n: -(-n // 128) * 128
+    stat = lambda n: 8 * lanes(n) * 4            # a [1, n] f32 row: 8 sublanes
+    if kernel == "fwd":
+        io = (2 * hd * lanes(bq) + 2 * hd * lanes(Skv)) * 2 + stat(bq)
+        live = -(-bk // 8) * 8 * lanes(bq) * 4 + hd * lanes(bq) * 4
+    else:       # q, o, do, dq rows; k, v, dk, dv blocks; lse
+        io = (4 * hd * lanes(Sq) + 4 * hd * lanes(bk)) * 2 + stat(Sq)
+        live = (hd * lanes(Sq) * 4 + -(-bk // 8) * 8 * lanes(bq) * 4
+                + 2 * hd * lanes(bk) * 4)
+    assert t.vmem_estimate == 2 * io + live
+    if hd < 128 and min(Sq, Skv) >= 128:
+        assert t.vmem_estimate < attention.vmem_estimate(
+            kernel, bq, bk, Sq, Skv, hd, 2, attention.HD_MINOR)
 
 
 def test_choose_tiling_shrinks_the_tile_before_it_gives_up():
     """The backward's whole-row blocks at S = 8,192, hd = 64 leave no room
-    for a 512 × 512 f32 tile: the rule halves the kv tile, then the q tile."""
+    for a 512 × 512 f32 tile: the rule halves the kv tile, then the q tile.
+    That is the hd-minor pair's [8192, 64] rows, each padded to 128 lanes;
+    the S-minor rows are [64, 8192], half of that, and keep the target."""
     t = attention.choose_tiling("bwd", 8192, 8192, 64, 2)
     assert (t.block_q, t.block_k) < (512, 512)
     assert t.block_q >= 128 and t.block_k >= 128
+    t = attention.choose_tiling("bwd", 8192, 8192, 64, 2,
+                                layout=attention.S_MINOR)
+    assert (t.block_q, t.block_k) == (512, 512)
 
 
-@pytest.mark.parametrize("kernel,S,hd", [
-    ("bwd", 8192, 128),       # whole-row q/do/dq blocks alone are over
-    ("bwd", 16384, 64),
-    ("fwd", 65536, 128),      # whole-row k/v
+@pytest.mark.parametrize("kernel,S,hd,layout", [
+    ("bwd", 8192, 128, attention.HD_MINOR),   # whole-row q/do/dq blocks alone
+    ("bwd", 16384, 64, attention.HD_MINOR),   # are over
+    ("fwd", 65536, 128, attention.HD_MINOR),  # whole-row k/v
+    ("bwd", 32768, 64, attention.S_MINOR),    # the same rows, unpadded: twice
+    ("fwd", 131072, 64, attention.S_MINOR),   # the sequence
 ])
-def test_choose_tiling_raises_when_nothing_fits(kernel, S, hd):
+def test_choose_tiling_raises_when_nothing_fits(kernel, S, hd, layout):
     with pytest.raises(ValueError) as err:
-        attention.choose_tiling(kernel, S, S, hd, 2)
+        attention.choose_tiling(kernel, S, S, hd, 2, layout=layout)
     msg = str(err.value)
     assert f"Sq={S}" in msg and f"Skv={S}" in msg and f"hd={hd}" in msg
+    assert layout in msg
     assert "estimated at" in msg and str(attention.VMEM_BUDGET_BYTES) in msg
     # a caller who fixes both tiles is not second-guessed: Mosaic is the judge
     assert attention.choose_tiling(kernel, S, S, hd, 2, block_q=128,
-                                   block_k=128).vmem_estimate \
+                                   block_k=128, layout=layout).vmem_estimate \
         > attention.VMEM_BUDGET_BYTES
 
 
-def test_tiling_decision_is_recorded_once_per_distinct_choice():
+@pytest.mark.parametrize("hd", [64, 128])
+def test_tiling_decision_is_recorded_once_per_distinct_choice(hd):
+    """... and says which pair the kernel belongs to: the S-minor one at 64,
+    the hd-minor one at 128 (kernel_layout, from the width alone)."""
     from ray_tpu.tracing import get_buffer, names
 
     buf = get_buffer()
     buf.drain(10 ** 6)
     attention._decisions.clear()
-    q, k, v = make_qkv(jax.random.PRNGKey(5), B=2, S=256, H=12, hd=64)
+    q, k, v = make_qkv(jax.random.PRNGKey(5), B=2, S=256, H=12, hd=hd)
+    layout = attention.kernel_layout(hd)
+    assert layout == {64: attention.S_MINOR, 128: attention.HD_MINOR}[hd]
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True))
@@ -234,21 +305,22 @@ def test_tiling_decision_is_recorded_once_per_distinct_choice():
     events = [e for e in buf.drain(10 ** 6)[0]
               if e["name"] == name and e["component"] == component]
     assert [e["args"]["kernel"] for e in events] == ["fwd", "bwd"]
-    chosen = attention.choose_tiling("fwd", 256, 256, 64, 4)
+    chosen = attention.choose_tiling("fwd", 256, 256, hd, 4, layout=layout)
     assert events[0]["args"] == {
-        "kernel": "fwd", "rows": 24, "Sq": 256, "Skv": 256, "hd": 64,
-        **chosen._asdict()}
+        "kernel": "fwd", "rows": 24, "Sq": 256, "Skv": 256, "hd": hd,
+        **chosen._asdict(), "layout": layout}
+    assert all(e["args"]["layout"] == layout for e in events)
     assert tuple(events[0]["args"]) == names.FLASH_TILING_ARGS
     assert [e["args"] for e in events] == attention.flash_tiling_decisions()
 
 
 @pytest.mark.parametrize("heads,S,hd", [(25, 128, 32), (12, 128, 32),
-                                        (2, 1024, 64)])
-def test_chosen_tiling_matches_reference(heads, S, hd):
+                                        (2, 1024, 64), (1, 2048, 64)])
+def test_chosen_tiling_matches_reference(heads, S, hd, pair):
     """flash_attention with NO explicit tile — the rule's choice, at either
     head count and at the 512-wide tile the chip runs (masked and unmasked
-    blocks, a skipped one) — against the XLA reference, values and
-    gradients."""
+    blocks, a skipped one; four tiles a side at 2,048) — against the XLA
+    reference, values and gradients, for either pair."""
     B = 2 if S < 1024 else 1
     q, k, v = make_qkv(jax.random.PRNGKey(11), B=B, S=S, H=heads, hd=hd)
 

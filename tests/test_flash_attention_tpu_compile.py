@@ -47,30 +47,47 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("shape", [
-    (8, 12, 1024, 64),      # gpt2-124m, one chip
-    (8, 25, 1024, 64),      # a gpt2-xl shard under fsdp=4: 200 rows
-    (2, 16, 4096, 128),     # llama-like: hd 128, S 4,096
-], ids=["gpt2-124m", "gpt2-xl-shard", "llama-4k"])
-def test_chosen_tiling_compiles_for_the_v5e(one_chip, shape):
+@pytest.mark.parametrize("shape,layout", [
+    ((8, 12, 64, 1024), "bhds"),     # gpt2-124m, one chip: 96 rows
+    ((25, 8, 64, 1024), "hbds"),     # a gpt2-xl shard under fsdp=4: 200 rows,
+                                     # the heads leading as gpt2._block has them
+    ((2, 16, 4096, 128), "bhsd"),    # llama-like: hd 128, S 4,096
+    ((8, 12, 1024, 64), "bhsd"),     # hd 64 handed over hd-minor: the S-minor
+                                     # pair behind a transpose each way
+], ids=["gpt2-124m", "gpt2-xl-shard", "llama-4k", "gpt2-124m-hd-minor"])
+def test_chosen_tiling_compiles_for_the_v5e(one_chip, shape, layout):
+    from ray_tpu.ops.attention import HD_MINOR, S_MINOR
+
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def grads(q, k, v):
         def loss(q, k, v):
-            o = flash_attention(q, k, v, causal=True, layout="bhsd",
+            o = flash_attention(q, k, v, causal=True, layout=layout,
                                 interpret=False)
             return jnp.sum(o.astype(jnp.float32))
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     hlo = jax.jit(grads).lower(x, x, x).compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    S, hd = shape[layout.index("s")], shape[layout.index("d")]
     rows = shape[0] * shape[1]
     mine = {d["kernel"]: d for d in flash_tiling_decisions()
-            if (d["rows"], d["Sq"], d["hd"]) == (rows, shape[2], shape[3])}
+            if (d["rows"], d["Sq"], d["hd"]) == (rows, S, hd)}
     assert set(mine) == {"fwd", "bwd"}
     # the target tile fits at these shapes: Mosaic took what the rule chose
     assert all((d["block_q"], d["block_k"]) == (512, 512)
                for d in mine.values())
+    # the pair is the head width's: [rows, hd, S] operands at 64 whatever
+    # order the caller's arrays came in, [rows, S, hd] at 128
+    pair = S_MINOR if hd == 64 else HD_MINOR
+    assert all(d["layout"] == pair for d in mine.values())
+    operand = [rows, hd, S] if pair == S_MINOR else [rows, S, hd]
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l]
+    assert all("bf16[%d,%d,%d]" % tuple(operand) in l for l in calls)
+    # a pair handed its own order has nothing to re-lay out at its edge
+    if layout in ("bhds", "hbds") or hd == 128:
+        assert not [l for l in hlo.splitlines() if "bf16[" in l
+                    and re.search(r" (copy|transpose)\(", l)]
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
@@ -236,16 +253,13 @@ def _cell_on(topo, name):
     return cell, config, family, mesh
 
 
-def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
+@pytest.fixture(scope="module")
+def gpt2_124m_step(topo):
     """The `gpt2-124m` cells' whole train step — the cell's own config through
     `program_config`, composed as `make_train_step` composes it, the
-    benchmark's optimizer — compiled for one described chip. Its layer scan
-    writes ONE `[12, 8, 1024, 3072]` stack (the MLP's named hidden tensor),
-    where AD left alone made it write six (the gelu's intermediates), and the
-    step needs 5.07 GiB where that one needed 9.25. What a cell's config
-    compiles to is what PR 27 never looked at."""
-    import re
-
+    benchmark's optimizer — compiled for one described chip: (compiled, cfg,
+    rows a step). What a cell's config compiles to is what PR 27 never
+    looked at."""
     from ray_tpu.models import gpt2
     from ray_tpu.train import train_step
 
@@ -269,6 +283,16 @@ def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
                    in_shardings=(state_sh, batch_sh),
                    out_shardings=(state_sh, None), donate_argnums=(0,))
     compiled = step.lower(state, {"tokens": tokens, "targets": tokens}).compile()
+    return compiled, cfg, rows
+
+
+def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(
+        gpt2_124m_step):
+    """Its layer scan writes ONE `[12, 8, 1024, 3072]` stack (the MLP's named
+    hidden tensor), where AD left alone made it write six (the gelu's
+    intermediates), and the step needs 5.07 GiB where that one needed
+    9.25."""
+    compiled, cfg, rows = gpt2_124m_step
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
     wide = re.escape(f"= bf16[{cfg.n_layer},{rows},{cfg.seq_len},{cfg.d_ff}]")
@@ -277,6 +301,66 @@ def test_the_124m_cell_step_stacks_one_mlp_wide_residual_on_the_v5e(topo):
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert need < 5.5 * 2 ** 30, need / 2 ** 30
+
+
+def _producers(hlo):
+    """Every instruction of a compiled program: name → (opcode, operands)."""
+    out = {}
+    for name, op, args in re.findall(
+            r"(%[\w.\-]+) = [^=]*? ([\w\-]+)\(([^)]*)\)", hlo):
+        out.setdefault(name, (op, re.findall(r"%[\w.\-]+", args)))
+    return out
+
+
+def test_the_124m_cell_step_hands_the_flash_kernels_what_xla_stores(
+        gpt2_124m_step):
+    """PR 48, the regression PR 25 could not see. At hd = 64 the block
+    projects its heads [H, B, hd, S] and the kernels read [rows, hd, S]: what
+    XLA writes the projections' outputs and the layer scan's saved stacks as.
+    So nothing re-lays a head tensor out on its way into a kernel: walking
+    back from either Mosaic call through what only renames bytes (bitcasts,
+    tuple elements, the loop's parameters), the forward call meets the
+    projections' own fusions and the backward call the stacks' slices — no
+    `copy` and no `transpose` — but for do, which the out-projection's
+    backward product makes batch-major (one copy a layer, 25 MB; PERF.md
+    §7). And no `copy` at all has a half-filled `[.., 1024, 64]` result: the
+    parent's step held seven a layer."""
+    compiled, cfg, rows = gpt2_124m_step
+    hlo = compiled.as_text()
+    made = _producers(hlo)
+
+    def sources(name, seen):
+        """The instructions that made ``name``'s bytes."""
+        op, args = made.get(name, ("parameter", []))
+        if op in ("bitcast", "get-tuple-element", "reshape") and args:
+            return set().union(*(sources(a, seen) for a in args[:1]))
+        return {(name, op)}
+
+    calls = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = .* custom-call\(([^)]*)\)"
+                     r".*tpu_custom_call", line)
+        if m:
+            kind = "bwd" if "flash_attention_bwd" in line else "fwd"
+            calls[kind] = [a for a in re.findall(r"%[\w.\-]+", m.group(2))]
+    assert set(calls) == {"fwd", "bwd"}
+    relaid = {kind: sorted(n for a in args for n, op in sources(a, set())
+                           if op in ("copy", "transpose"))
+              for kind, args in calls.items()}
+    assert relaid["fwd"] == [], relaid
+    assert len(relaid["bwd"]) <= 1, relaid           # do
+    for name in relaid["bwd"]:
+        assert "/proj/" in re.search(
+            re.escape(name) + r" = .*op_name=\"([^\"]*)\"", hlo).group(1)
+    S, hd = cfg.seq_len, cfg.head_dim
+    padded = [l for l in hlo.splitlines()
+              if re.search(rf"= bf16\[[\d,]*{S},{hd}\]\S* copy\(", l)]
+    assert padded == [], padded
+    # the saved q, k, v, o are four dense [layers, H, B, hd, S] stacks
+    stacks = set(re.findall(
+        rf"bf16\[{cfg.n_layer},{cfg.n_head},{rows},{hd},{S}\]\{{4,3,2,1,0[:}}]",
+        hlo))
+    assert stacks, "no row-major [L, H, B, hd, S] stack in the step"
 
 
 def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(

@@ -228,3 +228,39 @@ def test_chunked_head_multiplies_a_chunks_logits_once(monkeypatch):
     assert parts.head_rows(_B, _S, _V, 1) == 0
     assert "scan[" not in str(jax.make_jaxpr(      # (a new function: no
         lambda x, w: head(x, w))(x, lm_head))      # trace of `head` is reused)
+
+
+@pytest.mark.parametrize("n_kv_head", [4, 2], ids=["mha", "gqa"])
+def test_narrow_heads_go_s_minor_through_rope_and_either_attention(
+        n_kv_head, monkeypatch):
+    """PR 48: a head narrower than a lane tile (llama_tiny's) is projected
+    [H, B, hd, S] (`parts.head_layout`), rotated along dim -2 and attended
+    to in that order — by the S-minor flash kernels (interpreted here) with
+    no transpose at their edge, or by XLA's einsums. Loss and gradients
+    equal the hd-minor block's, which a width of 128 still takes."""
+    cfg = llama.llama_tiny(dtype=jnp.float32, n_kv_head=n_kv_head)
+    assert parts.head_layout(cfg.head_dim) == "hbds"
+    assert parts.head_layout(128) == "bhsd"
+    params = llama.init(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(3)
+    toks = rng.integers(0, cfg.vocab_size, (2, cfg.seq_len)).astype(np.int32)
+    tgt = np.roll(toks, -1, 1)
+
+    def run(impl):
+        c = llama.llama_tiny(dtype=jnp.float32, n_kv_head=n_kv_head,
+                             attention_impl=impl)
+        fn = jax.value_and_grad(lambda p: llama.loss_fn(p, toks, tgt, c))
+        jaxpr = str(jax.make_jaxpr(fn)(params))
+        return fn(params), jaxpr
+
+    (loss_x, grads_x), _ = run("xla")
+    (loss_p, grads_p), jaxpr = run("pallas")
+    rows = 2 * cfg.n_head
+    assert f"f32[{rows},{cfg.head_dim},{cfg.seq_len}]" in jaxpr  # the kernels'
+    monkeypatch.setattr(parts, "head_layout", lambda hd: "bhsd")
+    (loss_h, grads_h), _ = run("xla")
+    for loss, grads in ((loss_p, grads_p), (loss_h, grads_h)):
+        np.testing.assert_allclose(loss, loss_x, rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_x)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-5)

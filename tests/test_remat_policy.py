@@ -396,8 +396,13 @@ def test_named_residuals_are_what_the_checkpoint_saves():
         return sorted((aval.shape, str(aval.dtype)) for aval, why in saved
                       if not why.startswith("from the argument"))
 
-    o = ((BATCH, cfg.n_head, cfg.seq_len, cfg.head_dim), "bfloat16")
-    lse = ((BATCH, cfg.n_head, cfg.seq_len), "float32")
+    # in the order the block projects its heads at this width (S-minor, heads
+    # first, at gpt2_tiny's 32); lse has the rows' two dims and the sequence
+    layout = parts.head_layout(cfg.head_dim)
+    dims = dict(b=BATCH, h=cfg.n_head, s=cfg.seq_len, d=cfg.head_dim)
+    o = (tuple(dims[c] for c in layout), "bfloat16")
+    lse = (tuple(dims[c] for c in layout if c != "d"), "float32")
+    assert layout == "hbds"
     assert kept(0) == []
     assert kept(1) == sorted([o, lse])
     assert kept(4) == sorted([o, o, o, o, lse])      # q, k and v beside them
@@ -462,8 +467,9 @@ def test_the_cell_step_stacks_the_named_residuals_and_nothing_else(
     B = BATCH // 2 if mesh is not None else BATCH
     S, H, hd = cfg.seq_len, cfg.n_head, cfg.head_dim
     x = ((B, S, cfg.d_model), "bfloat16")
-    named = ([((H, S, hd), "bfloat16")] * 4          # q, k, v, the kernel's o
-             + [((B, H, S), "float32"),              # its lse
+    # hd = 64: the heads are projected [H, B, hd, S] (parts.head_layout)
+    named = ([((B, hd, S), "bfloat16")] * 4          # q, k, v, the kernel's o
+             + [((H, B, S), "float32"),              # its lse
                 x,                                   # after the attention add
                 ((B, S, cfg.d_ff), "bfloat16")])     # the MLP's hidden
     assert _stacks(jaxpr, n_layer, S) == sorted([x] + ([] if remat else named))

@@ -297,6 +297,15 @@ def test_flash_tiling_decision_of_the_lowered_step(kernel, monkeypatch):
             == (kernel, 2 * cfg.n_head, cfg.seq_len, cfg.head_dim)]
     assert len(mine) == 1
     assert tuple(mine[0]) == names.FLASH_TILING_ARGS
+    # ... the last of which says which kernel pair the step was traced with:
+    # the S-minor one at gpt2_tiny's head width, as at GPT-2's 64
+    assert names.FLASH_TILING_ARGS[-1] == "layout"
+    assert mine[0]["layout"] == attention.kernel_layout(cfg.head_dim) \
+        == attention.S_MINOR == attention.kernel_layout(64)
+    assert attention.kernel_layout(128) == attention.HD_MINOR
+    # the EVA event keeps the arguments it had (its kernels have one layout)
+    assert names.EVA_TILING_ARGS == names.FLASH_TILING_ARGS[:-1] + (
+        "window", "chunk")
 
 
 # ------------------------------------------------------------- profile_span
