@@ -288,12 +288,14 @@ def _lightning(u, p, cfg: MiniCPMSALAConfig):
     hd = cfg.head_dim
     positions = jnp.arange(u.shape[1])
     with jax.named_scope(scopes.QKV):
-        # named after the norm and the rotation: a kept q or k has both
-        q = checkpoint_name(parts.rope(_head_norm(
-            jnp.einsum("bsd,dhk->bhsk", u, p["wq"]), p["q_norm"], cfg),
+        # named after the norm and the rotation: a kept q or k has both. What
+        # comes back through the norm is made once, in front of the
+        # projection's two backward products (parts.made_once)
+        q = checkpoint_name(parts.rope(_head_norm(parts.cotangent_made_once(
+            jnp.einsum("bsd,dhk->bhsk", u, p["wq"])), p["q_norm"], cfg),
             positions, cfg.rope_theta), scopes.RES_Q)
-        k = checkpoint_name(parts.rope(_head_norm(
-            jnp.einsum("bsd,dhk->bhsk", u, p["wk"]), p["k_norm"], cfg),
+        k = checkpoint_name(parts.rope(_head_norm(parts.cotangent_made_once(
+            jnp.einsum("bsd,dhk->bhsk", u, p["wk"])), p["k_norm"], cfg),
             positions, cfg.rope_theta), scopes.RES_K)
         # v and the gate as the scan has them, [B, S, H, hd]
         v = checkpoint_name(jnp.einsum("bsd,dhk->bshk", u, p["wv"]),
@@ -305,9 +307,14 @@ def _lightning(u, p, cfg: MiniCPMSALAConfig):
     scale = jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
     y = mamba2.ssd_scan(v, jnp.ones(v.shape[:3], jnp.float32),
                         -lightning_slopes(cfg), k, q * scale, cfg.chunk)
-    y = checkpoint_name(y.astype(u.dtype), scopes.RES_LIGHTNING_Y)
+    # the scan's kernels on one side, the output norm and gate on the other:
+    # y and its gradient cross as they are; so does the gated output, into
+    # the out-projection
+    y = checkpoint_name(parts.made_once(y).astype(u.dtype),
+                        scopes.RES_LIGHTNING_Y)
     o = _head_norm(y, p["o_norm"], cfg)                      # [B, S, H, hd]
-    o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+    o = parts.made_once(
+        o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype))
     with jax.named_scope(scopes.PROJ):
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"],
                           preferred_element_type=jnp.float32)

@@ -76,6 +76,31 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
             + rotated.astype(jnp.float32) * sin).astype(x.dtype)
 
 
+def made_once(x):
+    """x, written to HBM once where it stands — and, since AD carries the
+    barrier to the cotangent, its cotangent likewise. For the seam between
+    elementwise work (a norm, a gate) and the products or the kernel that
+    read its result: left alone, XLA's TPU fusion makes such a value INSIDE
+    every product that reads it, as that product's operand, and a
+    [16384, 2048] x [2048, 4096] product with a norm's float32 arithmetic
+    over several inputs in front of it takes 2.3-2.5 ms where the bare one
+    takes 1.45-1.7 (PERF.md section 6, PR 49)."""
+    return lax.optimization_barrier(x)
+
+
+@jax.custom_vjp
+def cotangent_made_once(x):
+    """x untouched, so that what makes it and what reads it fuse as they
+    did; its cotangent ``made_once``. For a projection whose output goes
+    through a norm: the norm's backward is then one pass in front of the
+    projection's two backward products, not a part of each."""
+    return x
+
+
+cotangent_made_once.defvjp(lambda x: (x, None),
+                           lambda _, g: (made_once(g),))
+
+
 def residual_add(x, y):
     """x + y in float32, the stream stored in x's dtype (the released
     EvaByte's ``fp32_skip_add``; y is a matmul's float32 accumulator)."""

@@ -211,17 +211,24 @@ def test_sparse_attention_kernels_compile_for_the_v5e(one_chip, S, top_k):
     assert not re.findall(rf"\[[0-9,]*{S},{S}\]", hlo)
 
 
-def test_the_minicpm_sala_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
+@pytest.fixture(scope="module")
+def minicpm_sala_step(topo):
     """`minicpm-sala-9b-l4.dataset`'s own step compiled for the described
-    chip: it fits (the compiler's peak leaves 1 GB of 15.75 GiB), nothing
+    chip: (compiled, cell, its family)."""
+    cell, config, family, mesh = _cell_on(topo, "minicpm-sala-9b-l4.dataset")
+    fn, args = family.abstract_step(config, cell, mesh)
+    return fn.lower(*args).compile(), cell, family
+
+
+def test_the_minicpm_sala_cell_step_fits_and_clones_nothing_on_the_v5e(
+        minicpm_sala_step):
+    """It fits (the compiler's peak leaves 1 GB of 15.75 GiB), nothing
     is rematerialized by the compiler, and its Mosaic calls are the scan's
     pair in the layer scan's forward, recompute and backward and the sparse
     layer's three kernels (the forward once more: recompute)."""
     from ray_tpu.models import blocks
 
-    cell, config, family, mesh = _cell_on(topo, "minicpm-sala-9b-l4.dataset")
-    fn, args = family.abstract_step(config, cell, mesh)
-    compiled = fn.lower(*args).compile()
+    compiled, cell, family = minicpm_sala_step
     hlo = compiled.as_text()
     assert blocks.compiler_rematerialized(hlo) == []
     assert hlo.count('custom_call_target="tpu_custom_call"') == 6
@@ -231,6 +238,36 @@ def test_the_minicpm_sala_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
                  if (d["n_layer"], d["seq"]) == (4, cell["seq_len"])
                  and d["bytes_limit"] == family.V5E_BYTES_LIMIT]
     assert policy["saved"][0] == "sparse_block_ids"
+
+
+def test_the_minicpm_sala_cell_step_makes_no_norm_inside_a_lightning_product(
+        minicpm_sala_step):
+    """PR 49. A lightning layer's five projections make nineteen products a
+    step (five forward, four in the recompute, ten backward), each a fusion
+    around a `convolution`. Left alone, XLA made the QK-norm's backward inside
+    BOTH backward products of q and of k, and the output norm and gate inside
+    the out-projection's forward and weight-gradient products, as their
+    operand: such a fusion reads TWO token-wide `[16384, 16 x 128]` tensors
+    (what the norm is applied to, and what comes back) where a bare product
+    reads one, and ran at two thirds of a bare one's speed on the chip
+    (PERF.md section 6). With the seams (`parts.made_once`) every one of the
+    nineteen reads at most one."""
+    compiled, cell, _ = minicpm_sala_step
+    hlo = compiled.as_text()
+    wide = cell["seq_len"] * 16 * 128
+    elements = _elements(hlo)
+    bodies = dict(re.findall(r"^(%[\w.\-]+) \([^\n]*\{\n(.*?)^\}", hlo,
+                             re.S | re.M))
+    products = {}
+    for name, args, body, op_name in re.findall(
+            r"(%[\w.\-]+) = [^\n]*? fusion\(([^)]*)\)[^\n]*calls=(%[\w.\-]+)"
+            r"[^\n]*op_name=\"([^\"]*)\"", hlo):
+        if re.search(r"/lightning_attn/(qkv/bsd,dhk->b\w+|proj/bshk,hkd->bsd)/",
+                     op_name) and " convolution(" in bodies.get(body, ""):
+            products[name] = [a for a in re.findall(r"%[\w.\-]+", args)
+                              if elements.get(a) == wide]
+    assert len(products) == 19, sorted(products)
+    assert {k: v for k, v in products.items() if len(v) > 1} == {}
 
 
 def _cell_on(topo, name):

@@ -237,6 +237,49 @@ def test_a_mixer_alone_equals_the_reference(kind, seq):
     assert _worst(got[1], want[1]) < 1e-4
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+def test_a_seam_changes_no_number_and_stands_where_it_says(dtype):
+    """PR 49: ``parts.made_once`` / ``parts.cotangent_made_once`` (what the
+    lightning mixer puts between its norms and the products and kernels that
+    read them) are the identity, value and gradient, bit for bit; the first
+    bars fusion in both directions, the second in the backward alone — its
+    forward traces to nothing, so a projection and the sum of squares behind
+    it still fuse."""
+    from ray_tpu.models import parts
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 24, 3, 16)).astype(dtype)
+    g = jnp.linspace(0.5, 1.5, 16).astype(dtype)
+
+    def normed(seam):
+        return lambda t: jnp.sum(parts.rmsnorm(seam(t), g, 1e-6).astype(
+            jnp.float32) ** 2)
+
+    want, dwant = jax.value_and_grad(normed(lambda t: t))(x)
+    for seam in (parts.made_once, parts.cotangent_made_once):
+        got, dgot = jax.value_and_grad(normed(seam))(x)
+        assert dgot.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(
+            np.asarray(dgot.astype(jnp.float32)),
+            np.asarray(dwant.astype(jnp.float32)))
+    barrier = "optimization_barrier"
+    assert barrier in str(jax.make_jaxpr(parts.made_once)(x))
+    assert barrier not in str(jax.make_jaxpr(parts.cotangent_made_once)(x))
+    for seam in (parts.made_once, parts.cotangent_made_once):
+        assert barrier in str(jax.make_jaxpr(jax.grad(normed(seam)))(x))
+    # the mixer's own: the cotangents of q's and k's projections, and y and
+    # the gated output each way
+    cfg = ms.minicpm_sala_tiny(dtype=dtype)
+    layer = jax.tree.map(lambda t: t[0].astype(dtype),
+                         ms.init(cfg, jax.random.PRNGKey(0))["blocks"][0]["L"])
+    u = jax.random.normal(jax.random.PRNGKey(4), (1, cfg.seq_len, cfg.d_model)
+                          ).astype(dtype)
+    traced = str(jax.make_jaxpr(jax.grad(
+        lambda t: jnp.sum(ms.mixer(t, layer, cfg, "L"))))(u))
+    assert traced.count(barrier) == 2 + 2 * 2
+
+
 def test_the_scan_at_one_head_a_group_is_the_token_by_token_recurrence():
     """ssd_scan(x = v, Δ = 1, A = −slope, B = k, C = q) with G = H and
     P = N against s_t = λ s_{t−1} + k_tᵀ v_t, o_t = q_t s_t, over several

@@ -323,20 +323,40 @@ def _drain(buf, component):
     return [e for e in buf.drain(10 ** 6)[0] if e["component"] == component]
 
 
-def test_ssd_tiling_decisions_of_the_traced_step(buffer):
+@pytest.fixture
+def own_ssd_record(monkeypatch):
+    """The scan's record of tilings and `_chunks_call`'s trace cache are the
+    process's, and this file's tests share a worker with others': a float32
+    step of the tiny hybrid traced there first (tests/test_nemotron_h.py)
+    leaves a second pair of decisions for this step's shape — the operands'
+    width moves `vmem_estimate` and is not among the event's args — and a
+    scan whose shape the cache holds is not traced again, so it records
+    nothing. A test that reads the record gets an empty one and an empty
+    cache, and hands the cache back empty: what it traced is recorded in a
+    dict that goes with it."""
+    from ray_tpu.ops import mamba2
+
+    monkeypatch.setattr(mamba2, "_decisions", {})
+    mamba2._chunks_call.clear_cache()
+    yield
+    mamba2._chunks_call.clear_cache()
+
+
+def test_ssd_tiling_decisions_of_the_traced_step(buffer, own_ssd_record):
     """Tracing the hybrid's step leaves ONE `ops/ssd_tiling` decision a scan
     kernel for its Mamba layers' shape (two `M` layers; forward, recompute
     and backward trace the scan more than once), with the vocabulary's args.
     Each distinct decision is one instant event of component `ops` in the
-    task-event buffer (-> `ray_tpu.timeline()`): a shape no other test
-    traces records its two, and tracing it again records nothing."""
+    task-event buffer (-> `ray_tpu.timeline()`): a shape not traced before
+    records its two, and tracing it again records nothing."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import nemotron_h
     from ray_tpu.ops import mamba2
 
-    _lowering("nemotron")
+    bundle, batch = _step("nemotron")       # anew: `_lowered` may hold it
+    jax.make_jaxpr(bundle.step_fn)(bundle.state, batch)
     cfg = nemotron_h.nemotron_h_tiny()
     step = dict(rows=2, S=cfg.seq_len, Q=cfg.chunk, P=cfg.mamba_head_dim,
                 N=cfg.ssm_state,
