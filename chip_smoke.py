@@ -20,7 +20,11 @@ state-space scan's kernel pair (PR 41), one step of a small MiniCPM-SALA
 (``models/minicpm_sala.py``: three lightning layers on that scan's kernels at
 one head a group and one block-sparse layer past its ``dense_len``, PR 47) for
 its ``LLLS`` pattern, its ``model/sparse_selection`` event and the
-``ops/sparse_tiling`` decisions of its three attention kernels, and — what the expert layer's chosen-set mask
+``ops/sparse_tiling`` decisions of its three attention kernels, one step of a
+small LFM2-MoE (``models/lfm2_moe.py``: a short-convolution + dense layer, an
+attention layer at hd 64 and three short-convolution layers over gated
+experts, PR 50) for its ``DACCC`` pattern, the ``model/remat_policy`` decision
+over its three kinds and its ``model/expert_load`` events, and — what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
 check_training/check_device) and, after ``shutdown()``, prints from the
@@ -310,6 +314,37 @@ def train_loop(config: Dict[str, Any]) -> None:
                 "sparse_selection": minicpm_sala.sparse_selection_decisions(),
                 "sparse_tiling": sparse_tiling_decisions()}
         del variant
+    # One step of a short-convolution / attention hybrid with gated experts
+    # through the same factory: its pattern of pairs, what the rule over its
+    # three kinds keeps, what its first batch sends the held experts.
+    lfm2 = None
+    if config.get("lfm2_model") is not None:
+        from ray_tpu.models import lfm2_moe
+        from ray_tpu.models.blocks import layer_pattern_decisions
+
+        lfm2_cfg = config["lfm2_model"]
+        variant = make_train_step(
+            lfm2_moe, lfm2_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP, total_steps=steps,
+                                        decay_mask=lfm2_moe.decays))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, lfm2_cfg.seq_len), dtype=np.int32)
+        lfm2_batch = jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding)
+        with mesh_lib.use_mesh(mesh):
+            params, load = lfm2_moe.balance_router_bias(
+                variant.state["params"], lfm2_batch["tokens"], lfm2_cfg)
+        _, m = variant.step_fn({**variant.state, "params": params}, lfm2_batch)
+        lfm2 = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+                "seq_len": lfm2_cfg.seq_len,
+                "layer_pattern": [d for d in layer_pattern_decisions()
+                                  if d["pattern"] == lfm2_cfg.pattern],
+                "remat_policy": [d for d in remat_policy_decisions()
+                                 if d["n_layer"] == lfm2_cfg.n_layer
+                                 and d["seq"] == lfm2_cfg.seq_len],
+                "expert_load": load}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -337,12 +372,14 @@ def train_loop(config: Dict[str, Any]) -> None:
         "eva": eva,
         "hybrid": hybrid,
         "sala": sala,
+        "lfm2": lfm2,
     }})
 
 
 def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         use_tpu: bool, seed: int = 0, eva_model=None,
-        hybrid_model=None, sala_model=None) -> List[Dict[str, Any]]:
+        hybrid_model=None, sala_model=None,
+        lfm2_model=None) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -364,7 +401,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "model": model_cfg, "steps": steps,
             "per_chip_batch": per_chip_batch, "seed": seed,
             "eva_model": eva_model, "hybrid_model": hybrid_model,
-            "sala_model": sala_model,
+            "sala_model": sala_model, "lfm2_model": lfm2_model,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -467,6 +504,20 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
             bad.append("the MiniCPM-SALA step recorded ops/sparse_tiling "
                        f"decisions for {sorted(kernels)}, not for its three "
                        "kernels")
+    lfm2 = summary.get("lfm2")
+    if lfm2 is not None:
+        if not (math.isfinite(lfm2["loss"]) and math.isfinite(lfm2["grad_norm"])):
+            bad.append(f"the LFM2-MoE step's loss {lfm2['loss']} or "
+                       f"grad_norm {lfm2['grad_norm']} is not finite")
+        if not lfm2["layer_pattern"] or not lfm2["remat_policy"]:
+            bad.append("the LFM2-MoE step recorded no model/layer_pattern or "
+                       "no model/remat_policy event for its five layers")
+        if not lfm2["expert_load"]:
+            bad.append("the LFM2-MoE step recorded no model/expert_load event")
+        dropped = sum(e["pairs_dropped"] for e in lfm2["expert_load"])
+        if dropped:
+            bad.append(f"the LFM2-MoE step's expert layers dropped {dropped} "
+                       "(token, choice) pairs")
     return bad
 
 
@@ -591,7 +642,7 @@ def main() -> int:
 
     import ray_tpu
     from ray_tpu.core.resources import tpu_device_files
-    from ray_tpu.models import gpt2, llama, minicpm_sala, nemotron_h
+    from ray_tpu.models import gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h
     from ray_tpu.ops.sparse_attention import SparseSizes
 
     model_cfg = gpt2.gpt2_124m()
@@ -612,6 +663,13 @@ def main() -> int:
         vocab_size=4096, seq_len=4096, pattern="LLLS", d_model=1024, d_ff=4096,
         lightning_heads=4, lightning_heads_published=8, n_head=4, n_kv_head=1,
         sparse=SparseSizes(top_k=16, window=512, dense_len=2048), remat=True)
+    # LFM2-MoE's layers 1-5 at half the width: heads of 64 (the S-minor flash
+    # pair under RoPE and QK-norm, 4 query heads a key-value head), 16 of 64
+    # experts held, top-4
+    lfm2_cfg = lfm2_moe.LFM2MoEConfig(
+        vocab_size=4096, seq_len=2048, pattern="DACCC", first_layer=1,
+        d_model=1024, n_head=16, n_kv_head=4, d_ff=2816, held_count=16,
+        d_expert=768, remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -623,7 +681,8 @@ def main() -> int:
             return 2
         rows = run(model_cfg, steps=STEPS, per_chip_batch=PER_CHIP_BATCH,
                    num_devices=chips, use_tpu=True, eva_model=eva_cfg,
-                   hybrid_model=hybrid_cfg, sala_model=sala_cfg)
+                   hybrid_model=hybrid_cfg, sala_model=sala_cfg,
+                   lfm2_model=lfm2_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -712,6 +771,25 @@ def main() -> int:
     print(f"MiniCPM-SALA step ({sala_cfg.pattern} of {sala_cfg.d_model}, "
           f"{summary['device_count']}x{sala['seq_len']} tokens, remat): loss "
           f"{sala['loss']:.4f} grad_norm {sala['grad_norm']:.4f}")
+    lfm2 = summary["lfm2"]
+    for d in lfm2["layer_pattern"]:
+        print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
+              f"{d['groups']}")
+    for d in lfm2["remat_policy"]:
+        print(f"LFM2-MoE remat policy: {d['n_layer']} layers of three kinds, "
+              f"batch={d['batch']} seq={d['seq']}: saved={d['saved']} "
+              f"({d['saved_bytes'] / gib:.2f} GiB of {d['budget_bytes'] / gib:.2f}"
+              f" left by the backward's phase {d['phase']!r})")
+    for e in lfm2["expert_load"]:
+        print(f"LFM2-MoE expert load: published layer {e['layer']}: "
+              f"{e['pairs']} pairs of {e['tokens']} tokens on the held "
+              f"experts (max {e['max_per_expert']}, mean "
+              f"{e['mean_per_expert']:.1f} an expert), {e['buffer_passes']} "
+              f"pass(es) over a buffer of {e['buffer_rows']} rows, dropped "
+              f"{e['pairs_dropped']}")
+    print(f"LFM2-MoE step ({lfm2_cfg.pattern} of {lfm2_cfg.d_model}, "
+          f"{summary['device_count']}x{lfm2['seq_len']} tokens, remat): loss "
+          f"{lfm2['loss']:.4f} grad_norm {lfm2['grad_norm']:.4f}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

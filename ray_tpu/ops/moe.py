@@ -35,6 +35,13 @@ shared expert. No pair is dropped — a batch that lands more pairs here than
 one row buffer holds takes further passes over it, and everything that costs
 a row (its gate included) is done by the pass that holds it (PR 36) — and
 memory is linear in T.
+
+``gated_moe`` (PR 50) is the same dispatch around the other kind of expert:
+three matrices, SiLU-gated, read and written at the model's width, no latent
+and no shared expert beside them. What a held expert IS — ``relu(x·W1)²·W2``
+or ``(silu(x·W1) ⊙ x·W3)·W2`` — is the tuple of weights the passes are given
+(_pass_rows); routing, the pairs, the row buffer, the passes and their
+written-out backward, the load and the bias's balance are one code for both.
 """
 
 from __future__ import annotations
@@ -310,12 +317,13 @@ def _chosen(biased: jax.Array, top_k: int) -> jax.Array:
 
 
 def route(u: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float, held: Held) -> Tuple[jax.Array, jax.Array]:
+          scaling: float, held: Held, eps: float = 0.0
+          ) -> Tuple[jax.Array, jax.Array]:
     """u [T, D] → (``here`` [T, held] bool: the token chose that held expert,
     the held experts' gates [T, held] float32, which mean something only where
     ``here``): sigmoid scores in float32, the k largest of score + bias chosen
-    (the bias chooses only), gates = scaling · score / Σ over ALL the chosen.
-    The sum is a masked row-sum and the held experts' scores a static slice:
+    (the bias chooses only), gates = scaling · score / (Σ over ALL the chosen
+    + ``eps``). The sum is a masked row-sum and the held experts' scores a static slice:
     nothing is gathered by chosen id. The gates are not masked by ``here``:
     only a pair's row reads one, and ``HeldPairs.valid`` says which rows are
     pairs."""
@@ -323,6 +331,8 @@ def route(u: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
     chosen = _chosen(
         lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
     denom = jnp.sum(jnp.where(chosen, scores, 0.0), axis=-1, keepdims=True)
+    if eps:
+        denom = denom + eps
     span = slice(held.first, held.first + held.count)
     return chosen[:, span], scaling * scores[:, span] / denom
 
@@ -401,18 +411,35 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def _pass_rows(x, w1, w2, gate, valid, group_sizes):
+# the two kinds of held expert, each by the names of its weights in a layer's
+# tensors: relu(x·W1)²·W2 (latent_moe's) and (silu(x·W1) ⊙ x·W3)·W2
+# (gated_moe's). _pass_rows tells them apart by how many there are
+RELU2_EXPERT = ("w1", "w2")
+GATED_EXPERT = ("w1", "w3", "w2")
+
+
+def _pass_rows(x, ws, gate, valid, group_sizes):
     """One buffer of pairs through the held experts, row for row: x [rows,
-    latent] (each pair's token's latent), its gate [rows] → gate ·
-    relu(x·W1_e)² · W2_e [rows, latent] float32, 0 in a row without a pair."""
+    width] (each pair's token's input), its gate [rows] → gate · f_e(x)
+    [rows, width] float32, 0 in a row without a pair. The experts' weights
+    ``ws`` say what a held expert is: (W1, W2) — ``relu(x·W1_e)² · W2_e`` —
+    or (W1, W3, W2) — ``(silu(x·W1_e) ⊙ x·W3_e) · W2_e``."""
+    w1, w2 = ws[0], ws[-1]
     with jax.named_scope(scopes.MOE_DISPATCH):
         x = jnp.where(valid[:, None], x, 0)
     # rows past the last group are whatever the kernel left there (NaN as
     # likely as not), in the products and in their cotangents: each is masked
     # before anything multiplies it
     h = lax.ragged_dot(x, w1, group_sizes, preferred_element_type=x.dtype)
-    with jax.named_scope(scopes.MOE_DISPATCH):
-        a = _relu2(jnp.where(valid[:, None], h, 0))
+    if len(ws) == 3:
+        up = lax.ragged_dot(x, ws[1], group_sizes,
+                            preferred_element_type=x.dtype)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            a = (jax.nn.silu(jnp.where(valid[:, None], h, 0))
+                 * jnp.where(valid[:, None], up, 0))
+    else:
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            a = _relu2(jnp.where(valid[:, None], h, 0))
     # out of the kernel in the compute dtype: a float32 output would make
     # the backward's two grouped products take float32 operands
     o = lax.ragged_dot(a, w2, group_sizes, preferred_element_type=x.dtype)
@@ -430,10 +457,11 @@ def _looked_up(ell, gates, key):
 
 
 @jax.custom_vjp
-def _run_passes(ell, w1, w2, gates, key, valid, group_sizes, n):
+def _run_passes(ell, ws, gates, key, valid, group_sizes, n):
     """The first ``n`` passes' sum over ell [T, latent]: each pass looks up
     its own rows' tokens, latents and gates (``key[i]`` into ell and the flat
-    ``gates``), runs them through _pass_rows and adds each row to its token's
+    ``gates``), runs them through _pass_rows (``ws``: the held experts'
+    weights, and with them their form) and adds each row to its token's
     row of ONE [T, latent] float32 sum. ``n`` is a value of the step — the
     passes this batch's pairs fill — so the loop is a ``while``; its backward
     is written out below (one pass's vjp at a time into float32 sums),
@@ -442,16 +470,16 @@ def _run_passes(ell, w1, w2, gates, key, valid, group_sizes, n):
     15.75)."""
     def body(i, r):
         token, x, gate = _looked_up(ell, gates, key[i])
-        o = _pass_rows(x, w1, w2, gate, valid[i], group_sizes[i])
+        o = _pass_rows(x, ws, gate, valid[i], group_sizes[i])
         with jax.named_scope(scopes.MOE_DISPATCH):
             return r.at[token].add(o)
 
     return lax.fori_loop(0, n, body, jnp.zeros(ell.shape, jnp.float32))
 
 
-def _run_passes_fwd(ell, w1, w2, gates, key, valid, group_sizes, n):
-    return (_run_passes(ell, w1, w2, gates, key, valid, group_sizes, n),
-            (ell, w1, w2, gates, key, valid, group_sizes, n))
+def _run_passes_fwd(ell, ws, gates, key, valid, group_sizes, n):
+    return (_run_passes(ell, ws, gates, key, valid, group_sizes, n),
+            (ell, ws, gates, key, valid, group_sizes, n))
 
 
 def _run_passes_bwd(res, d_r):
@@ -459,41 +487,43 @@ def _run_passes_bwd(res, d_r):
     cotangent gathered by token, the latents' scatter-added by token and the
     gates' by key — a pass's ``rows`` scalars into the [held · T] sum, not
     passes · rows of them kept for one scatter at the end."""
-    ell, w1, w2, gates, key, valid, group_sizes, n = res
+    ell, ws, gates, key, valid, group_sizes, n = res
 
     def body(i, sums):
-        d_ell, d_w1, d_w2, d_gates = sums
+        d_ell, d_ws, d_gates = sums
         token, x, gate = _looked_up(ell, gates, key[i])
         _, vjp = jax.vjp(
-            lambda x, a, b, g: _pass_rows(x, a, b, g, valid[i],
-                                          group_sizes[i]), x, w1, w2, gate)
+            lambda x, ws, g: _pass_rows(x, ws, g, valid[i], group_sizes[i]),
+            x, ws, gate)
         with jax.named_scope(scopes.MOE_DISPATCH):
             d_o = d_r[token]
-        d_x, d_a, d_b, d_gate = vjp(d_o)
+        d_x, d_w, d_gate = vjp(d_o)
         with jax.named_scope(scopes.MOE_DISPATCH):
             d_ell = d_ell.at[token].add(d_x.astype(jnp.float32))
             d_gates = d_gates.at[key[i]].add(d_gate)
-        return (d_ell, d_w1 + d_a.astype(jnp.float32),
-                d_w2 + d_b.astype(jnp.float32), d_gates)
+        return (d_ell, tuple(s + d.astype(jnp.float32)
+                             for s, d in zip(d_ws, d_w)), d_gates)
 
     sums = lax.fori_loop(0, n, body, (
-        jnp.zeros(ell.shape, jnp.float32), jnp.zeros(w1.shape, jnp.float32),
-        jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(gates)))
-    return (sums[0].astype(ell.dtype), sums[1].astype(w1.dtype),
-            sums[2].astype(w2.dtype), sums[3], None, None, None, None)
+        jnp.zeros(ell.shape, jnp.float32),
+        tuple(jnp.zeros(w.shape, jnp.float32) for w in ws),
+        jnp.zeros_like(gates)))
+    return (sums[0].astype(ell.dtype),
+            tuple(s.astype(w.dtype) for s, w in zip(sums[1], ws)), sums[2],
+            None, None, None, None)
 
 
 _run_passes.defvjp(_run_passes_fwd, _run_passes_bwd)
 
 
-def _dispatch(u, p, top_k: int, held: Held, scaling: float):
+def _dispatch(u, p, top_k: int, held: Held, scaling: float, eps: float = 0.0):
     """Route u [T, D] and lay the pairs on held experts over the row buffer:
     (the membership [T, held], the HeldPairs, the passes they fill)."""
     T = u.shape[0]
     n_experts = p["router_w"].shape[-1]
     rows = row_buffer(T, n_experts, top_k, held.count)
     here, gates = route(u, p["router_w"], p["router_bias"], top_k, scaling,
-                        held)
+                        held, eps)
     pairs = held_pairs(here, gates, rows,
                        buffer_passes(T, n_experts, top_k, held.count))
     return here, pairs, -(-jnp.sum(pairs.per_expert) // rows)
@@ -501,16 +531,18 @@ def _dispatch(u, p, top_k: int, held: Held, scaling: float):
 
 @jax.named_scope(scopes.MOE_ROUTED)
 def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
-                   top_k: int, held: Held, scaling: float) -> jax.Array:
-    """The held experts' part of the routed result, in the latent: u [T, D]
-    (what the router reads), ell [T, latent] (what the experts read) →
-    r [T, latent] float32 = Σ over a token's chosen AND held experts of
-    gate · relu(ell·W1_e)² · W2_e. One pass over the row buffer where the
-    batch's pairs fit it (row_buffer); a batch with more runs the further
-    passes it fills."""
+                   top_k: int, held: Held, scaling: float, eps: float = 0.0,
+                   form: Tuple[str, ...] = RELU2_EXPERT) -> jax.Array:
+    """The held experts' part of the routed result, in the width the experts
+    read and write: u [T, D] (what the router reads), ell [T, width] (what
+    the experts read: a latent, or u itself) → r [T, width] float32 = Σ over
+    a token's chosen AND held experts of gate · f_e(ell), f_e by ``form``
+    (the names of the experts' weights in ``p``: _pass_rows). One pass over
+    the row buffer where the batch's pairs fit it (row_buffer); a batch with
+    more runs the further passes it fills."""
     with jax.named_scope(scopes.MOE_DISPATCH):
-        _, pairs, filled = _dispatch(u, p, top_k, held, scaling)
-    return _run_passes(ell, p["w1"], p["w2"], pairs.gates, pairs.key,
+        _, pairs, filled = _dispatch(u, p, top_k, held, scaling, eps)
+    return _run_passes(ell, tuple(p[w] for w in form), pairs.gates, pairs.key,
                        pairs.valid, pairs.group_sizes, filled)
 
 
@@ -549,11 +581,64 @@ def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
     return out.reshape(B, S, D) + sh.swapaxes(0, 1).reshape(B, S, D)
 
 
+def gated_moe_init(rng: jax.Array, n_layers: int, d_model: int,
+                   n_experts: int, held: int, d_expert: int, std: float,
+                   out_std: float, param_dtype=jnp.float32) -> Dict[str, Any]:
+    """``n_layers`` stacked layers of gated_moe: the router over all
+    ``n_experts`` and its selection bias (a buffer, as latent_moe_init's) and
+    ``held`` SiLU-gated experts at the model's width."""
+    k = iter(jax.random.split(rng, 5))
+    L = n_layers
+
+    def normal(key, shape, s):
+        return (jax.random.normal(key, shape) * s).astype(param_dtype)
+
+    return {
+        "router_w": normal(next(k), (L, d_model, n_experts), std),
+        "router_bias": normal(next(k), (L, n_experts), ROUTER_BIAS_STD),
+        "w1": normal(next(k), (L, held, d_model, d_expert), std),
+        "w3": normal(next(k), (L, held, d_model, d_expert), std),
+        "w2": normal(next(k), (L, held, d_expert, d_model), out_std),
+    }
+
+
+def gated_moe_logical_axes() -> Dict[str, Any]:
+    return {
+        "router_w": ("layers", "embed", None),
+        "router_bias": ("layers", None),
+        "w1": ("layers", "expert", "embed", "mlp"),
+        "w3": ("layers", "expert", "embed", "mlp"),
+        "w2": ("layers", "expert", "mlp", "embed"),
+    }
+
+
+def gated_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
+              scaling: float, eps: float = 0.0) -> jax.Array:
+    """u [B, S, D] (normed, compute dtype) → the held experts' part of the
+    layer's output [B, S, D] in float32: Σ over a token's chosen AND held
+    experts of gate · (silu(u·W1_e) ⊙ u·W3_e) · W2_e, at the model's width —
+    no latent around the experts, nothing beside them. ``p`` holds one
+    layer's tensors, GATED_EXPERT's in the compute dtype."""
+    B, S, D = u.shape
+    ut = u.reshape(B * S, D)
+    r = routed_experts(ut, ut, p, top_k=top_k, held=held, scaling=scaling,
+                       eps=eps, form=GATED_EXPERT)
+    return r.reshape(B, S, D)
+
+
+def chosen_experts(u: jax.Array, p: Dict[str, Any], top_k: int) -> jax.Array:
+    """u [T, D] → [T, n_experts] bool: the set route chooses for each token,
+    for whoever compares it with another router's (a reference told the
+    program's choice does not read a flipped near-tie as a wrong model)."""
+    return _chosen(_scores(u, p["router_w"])
+                   + p["router_bias"].astype(jnp.float32), top_k)
+
+
 def held_load(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
-              scaling: float) -> Dict[str, jax.Array]:
+              scaling: float, eps: float = 0.0) -> Dict[str, jax.Array]:
     """What a batch sends the held experts of one layer (u [T, D], the
     layer's normed input): the numbers of the ``model/expert_load`` event."""
-    here, pairs, filled = _dispatch(u, p, top_k, held, scaling)
+    here, pairs, filled = _dispatch(u, p, top_k, held, scaling, eps)
     landed = jnp.sum(pairs.per_expert)
     rows = pairs.key.shape[1]
     return {

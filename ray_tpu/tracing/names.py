@@ -52,12 +52,19 @@ MTP = "mtp"
 LIGHTNING_ATTN = "lightning_attn"
 SPARSE_ATTENTION = "sparse_attention"
 SPARSE_SELECT = "sparse_select"
+# models/lfm2_moe.py: the double-gated short convolution, the whole operator
+# (in-projection, gates and conv, out-projection) and, inside it, what lies
+# between the two products (ops/short_conv.py: the two gates and the
+# depthwise causal conv — elementwise, what a kernel would replace)
+SHORT_CONV = "short_conv"
+CONV_GATE = "conv_gate"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
                                           EVA_PREP_KV, MAMBA, SSD_SCAN,
                                           MOE_ROUTED, MOE_DISPATCH, MOE_LATENT,
                                           MOE_SHARED, MTP, LIGHTNING_ATTN,
-                                          SPARSE_ATTENTION, SPARSE_SELECT)
+                                          SPARSE_ATTENTION, SPARSE_SELECT,
+                                          SHORT_CONV, CONV_GATE)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -164,13 +171,22 @@ RES_SALA_GATE = "sala_gate"
 RES_LIGHTNING_Y = "lightning_y"
 RES_SPARSE_IDS = "sparse_block_ids"
 RES_SPARSE_O, RES_SPARSE_LSE = "sparse_o", "sparse_lse"
+# an LFM2 layer's (models/lfm2_moe.py). The short-convolution operator names
+# its in-projection's output, [B, S, 3·D]: the two gates and the conv's input
+# in one tensor, the operator's one product to make again. Its attention
+# names q, k (after the QK-norm and RoPE) and v as the others do, both
+# feed-forward halves RES_MID, the dense one RES_MLP_GATE and RES_MLP_UP, the
+# expert layer what its routing decided (RES_MOE_SCORES … RES_MOE_PAIR_KEY).
+# The held experts' hidden tensors have no name: they are a pass's, inside
+# the dispatch's written-out backward (ops/moe._run_passes)
+RES_CONV_BCX = "conv_bcx"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
              RES_MLP_GATE, RES_MLP_UP, RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT,
              RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN,
              RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY,
              RES_SALA_GATE, RES_LIGHTNING_Y, RES_SPARSE_IDS, RES_SPARSE_O,
-             RES_SPARSE_LSE)
+             RES_SPARSE_LSE, RES_CONV_BCX)
 # which of them models/blocks.py chose to save, the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once),
 # and the phase of the backward whose working set the budget was left by

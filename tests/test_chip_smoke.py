@@ -240,11 +240,28 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
 
     hybrid_cfg = nemotron_h.nemotron_h_tiny(remat=True)
     sala_cfg = minicpm_sala.minicpm_sala_tiny(remat=True)
+    from ray_tpu.models import lfm2_moe
+
+    lfm2_cfg = lfm2_moe.lfm2_moe_tiny(remat=True)
     rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1,
                           num_devices=8, use_tpu=False, eva_model=eva_cfg,
-                          hybrid_model=hybrid_cfg, sala_model=sala_cfg)
+                          hybrid_model=hybrid_cfg, sala_model=sala_cfg,
+                          lfm2_model=lfm2_cfg)
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
+    # the LFM2-MoE step (PR 50): its pattern of pairs, the rule's decision
+    # over its three kinds and its four expert layers' loads came back, no
+    # pair dropped; and the check fails without them
+    lfm2 = summary["lfm2"]
+    assert [d["groups"] for d in lfm2["layer_pattern"]] == [
+        ["D", "A", "3 x scan(C)"]]
+    assert [d["n_layer"] for d in lfm2["remat_policy"]] == [5]
+    assert [e["layer"] for e in lfm2["expert_load"]] == [2, 3, 4, 5]
+    assert all(e["pairs_dropped"] == 0 and e["tokens"] == 8 * lfm2_cfg.seq_len
+               for e in lfm2["expert_load"])
+    none = [rows[-1] | {"summary": summary | {"lfm2": lfm2 | {
+        "layer_pattern": [], "remat_policy": [], "expert_load": []}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + none, cfg, steps)) == 2
     # the MiniCPM-SALA step (PR 47): its pattern, the scan at one head a
     # group, the selection on the sparse branch and the three kernels'
     # tilings came back; and the check fails without them

@@ -549,7 +549,7 @@ def test_passes_share_an_experts_run_of_rows(rows, sizes):
 
     def routed(ell, w1, w2, gates):
         pairs = moe.held_pairs(jnp.ones((T, 2), bool), gates, rows, passes + 1)
-        return moe._run_passes(ell, w1, w2, pairs.gates, pairs.key,
+        return moe._run_passes(ell, (w1, w2), pairs.gates, pairs.key,
                                pairs.valid, pairs.group_sizes, passes)
 
     def dense(ell, w1, w2, gates):
@@ -1104,3 +1104,105 @@ def test_the_selection_bias_is_a_buffer_no_step_moves():
                                           new["E"]["router_bias"])
             assert float(jnp.max(jnp.abs(
                 old["E"]["router_w"] - new["E"]["router_w"]))) > 0
+
+
+# --------------------------------------------------------------------------- #
+# The seam of ops/moe.py (PR 50): the held experts' form and the gates' eps
+# became arguments of the one dispatch. With both at their defaults the layer
+# is the parent's, bit for bit. The parent's passes, as they stood at PR 49
+# (two matrices, relu², positional w1 / w2), are frozen here as the oracle.
+# --------------------------------------------------------------------------- #
+
+def _pr49_pass_rows(x, w1, w2, gate, valid, group_sizes):
+    x = jnp.where(valid[:, None], x, 0)
+    h = jax.lax.ragged_dot(x, w1, group_sizes, preferred_element_type=x.dtype)
+    a = jnp.square(jax.nn.relu(jnp.where(valid[:, None], h, 0)))
+    o = jax.lax.ragged_dot(a, w2, group_sizes, preferred_element_type=x.dtype)
+    return (jnp.where(valid[:, None], o, 0).astype(jnp.float32)
+            * gate[:, None])
+
+
+@jax.custom_vjp
+def _pr49_run_passes(ell, w1, w2, gates, key, valid, group_sizes, n):
+    def body(i, r):
+        token, x, gate = moe._looked_up(ell, gates, key[i])
+        o = _pr49_pass_rows(x, w1, w2, gate, valid[i], group_sizes[i])
+        return r.at[token].add(o)
+
+    return jax.lax.fori_loop(0, n, body, jnp.zeros(ell.shape, jnp.float32))
+
+
+def _pr49_run_passes_fwd(ell, w1, w2, gates, key, valid, group_sizes, n):
+    return (_pr49_run_passes(ell, w1, w2, gates, key, valid, group_sizes, n),
+            (ell, w1, w2, gates, key, valid, group_sizes, n))
+
+
+def _pr49_run_passes_bwd(res, d_r):
+    ell, w1, w2, gates, key, valid, group_sizes, n = res
+
+    def body(i, sums):
+        d_ell, d_w1, d_w2, d_gates = sums
+        token, x, gate = moe._looked_up(ell, gates, key[i])
+        _, vjp = jax.vjp(
+            lambda x, a, b, g: _pr49_pass_rows(x, a, b, g, valid[i],
+                                               group_sizes[i]),
+            x, w1, w2, gate)
+        d_x, d_a, d_b, d_gate = vjp(d_r[token])
+        d_ell = d_ell.at[token].add(d_x.astype(jnp.float32))
+        d_gates = d_gates.at[key[i]].add(d_gate)
+        return (d_ell, d_w1 + d_a.astype(jnp.float32),
+                d_w2 + d_b.astype(jnp.float32), d_gates)
+
+    sums = jax.lax.fori_loop(0, n, body, (
+        jnp.zeros(ell.shape, jnp.float32), jnp.zeros(w1.shape, jnp.float32),
+        jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(gates)))
+    return (sums[0].astype(ell.dtype), sums[1].astype(w1.dtype),
+            sums[2].astype(w2.dtype), sums[3], None, None, None, None)
+
+
+_pr49_run_passes.defvjp(_pr49_run_passes_fwd, _pr49_run_passes_bwd)
+
+
+def _pr49_routed_experts(u, ell, p, *, top_k, held, scaling):
+    _, pairs, filled = moe._dispatch(u, p, top_k, held, scaling)
+    return _pr49_run_passes(ell, p["w1"], p["w2"], pairs.gates, pairs.key,
+                            pairs.valid, pairs.group_sizes, filled)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_latent_moe_is_the_parents_with_the_new_arguments_at_their_defaults(
+        dtype, monkeypatch):
+    """``latent_moe``'s output and every gradient on a seeded batch, against
+    the same layer over the parent's passes: bit-equal. (The benchmark's
+    Nemotron cell holds the same at its own sizes: its lowered step is the
+    parent's, PERF.md §6, PR 50.)"""
+    cfg = nh.nemotron_h_tiny()
+    params = nh.init(cfg, jax.random.PRNGKey(11))
+    p = {k: (v.astype(dtype) if k in moe.LATENT_MOE_MATMUL_WEIGHTS else v)
+         for k, v in _layer_of(params["blocks"][0], "E").items()}
+    u = jax.random.normal(jax.random.PRNGKey(12),
+                          (2, cfg.seq_len, cfg.d_model)).astype(dtype)
+    w = jax.random.normal(jax.random.PRNGKey(13), u.shape)
+    routing = dict(top_k=cfg.top_k, held=cfg.held, scaling=cfg.routed_scaling)
+
+    def layer(u, p):
+        y = moe.latent_moe(u, p, **routing)
+        return jnp.sum(y * w), y
+
+    now = jax.jit(jax.value_and_grad(layer, (0, 1), has_aux=True))(u, p)
+    monkeypatch.setattr(moe, "routed_experts", _pr49_routed_experts)
+    then = jax.jit(jax.value_and_grad(layer, (0, 1), has_aux=True))(u, p)
+    for a, b in zip(jax.tree.leaves(now), jax.tree.leaves(then), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(jnp.abs(now[1][1]["w1"].astype(jnp.float32)).max()) > 0
+    # eps reaches the gates only where a caller gives one
+    ut = u.reshape(-1, cfg.d_model)
+    plain = moe.route(ut, p["router_w"], p["router_bias"], cfg.top_k,
+                      cfg.routed_scaling, cfg.held)
+    zero = moe.route(ut, p["router_w"], p["router_bias"], cfg.top_k,
+                     cfg.routed_scaling, cfg.held, 0.0)
+    some = moe.route(ut, p["router_w"], p["router_bias"], cfg.top_k,
+                     cfg.routed_scaling, cfg.held, 0.5)
+    np.testing.assert_array_equal(np.asarray(plain[1]), np.asarray(zero[1]))
+    assert float(jnp.max(some[1] / plain[1])) < 1.0

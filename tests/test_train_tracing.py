@@ -39,6 +39,9 @@ LOWERINGS = {
     # a pattern of two attention kinds: lightning on the scan's kernels and
     # block-sparse attention past a tiny dense_len (MiniCPM-SALA, PR 47)
     "sala": dict(remat=True),
+    # a pattern of PAIRS: short convolution or attention, then a dense MLP or
+    # gated experts (LFM2-MoE, PR 50)
+    "lfm2": dict(remat=True, attention_impl="pallas"),
 }
 # each model's own mixer: GPT-2 has the flash kernels, EvaByte the EVA ones
 EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
@@ -52,14 +55,27 @@ SPARSE_KERNELS = (names.SPARSE_ATTN_FWD_KERNEL, names.SPARSE_ATTN_BWD_DQ_KERNEL,
                   names.SPARSE_ATTN_BWD_DKV_KERNEL)
 SALA_SCOPES = (names.LIGHTNING_ATTN, names.SPARSE_ATTENTION,
                names.SPARSE_SELECT)
+LFM2_OWN_SCOPES = (names.SHORT_CONV, names.CONV_GATE)
 DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE
-                     and s not in EVA_SCOPES + NEMOTRON_SCOPES + SALA_SCOPES)
+                     and s not in EVA_SCOPES + NEMOTRON_SCOPES + SALA_SCOPES
+                     + LFM2_OWN_SCOPES)
 EVABYTE_SCOPES = tuple(s for s in names.SCOPES if s not in (
-    names.MOE, names.FLASH_ATTENTION) + NEMOTRON_SCOPES + SALA_SCOPES)
+    names.MOE, names.FLASH_ATTENTION) + NEMOTRON_SCOPES + SALA_SCOPES
+    + LFM2_OWN_SCOPES)
 # every layer of the hybrid is a mixer OR a feed-forward part: one norm a
 # layer (ln1), the shared expert under `mlp` inside `moe`
 HYBRID_SCOPES = tuple(s for s in names.SCOPES if s != names.LN2
-                      and s not in EVA_SCOPES + SALA_SCOPES)
+                      and s not in EVA_SCOPES + SALA_SCOPES + LFM2_OWN_SCOPES)
+# every layer of LFM2-MoE is an operator AND a feed-forward half: the block's
+# six scopes (`mlp` in the dense layer, `moe` with the shared dispatch in the
+# expert layers), the flash kernels' and the short convolution's
+LFM2_SCOPES = DENSE_SCOPES + (names.MOE, names.MOE_ROUTED, names.MOE_DISPATCH
+                              ) + LFM2_OWN_SCOPES
+LFM2_RESIDUALS = (names.RES_CONV_BCX, names.RES_Q, names.RES_K, names.RES_V,
+                  names.RES_FLASH_O, names.RES_FLASH_LSE, names.RES_MID,
+                  names.RES_MLP_GATE, names.RES_MLP_UP, names.RES_MOE_SCORES,
+                  names.RES_MOE_KTH, names.RES_MOE_LAST,
+                  names.RES_MOE_PAIR_KEY)
 # every layer of MiniCPM-SALA is a mixer AND a SwiGLU MLP: the block's six
 # scopes, its two mixers' and the scan's; the sparse branch runs no flash
 # kernel
@@ -77,7 +93,7 @@ _lowered = {}
 
 def _step(key):
     """(bundle, batch of 2) of the tiny train step `key` names, built anew."""
-    from ray_tpu.models import gpt2, llama, minicpm_sala, nemotron_h
+    from ray_tpu.models import gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h
     from ray_tpu.train.train_step import (
         make_gpt2_train_step, make_train_step, synthetic_batch)
 
@@ -90,6 +106,9 @@ def _step(key):
     elif key == "sala":
         cfg = minicpm_sala.minicpm_sala_tiny(**LOWERINGS[key])
         bundle = make_train_step(minicpm_sala, cfg)
+    elif key == "lfm2":
+        cfg = lfm2_moe.lfm2_moe_tiny(**LOWERINGS[key])
+        bundle = make_train_step(lfm2_moe, cfg)
     else:
         cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
         bundle = make_gpt2_train_step(cfg)
@@ -162,6 +181,62 @@ def test_scope_in_lowered_minicpm_sala_step(scope):
               names.SPARSE_ATTENTION: names.BLOCK}
     if scope in inside:
         assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+
+
+@pytest.mark.parametrize("scope", LFM2_SCOPES)
+def test_scope_in_lowered_lfm2_step(scope):
+    """All three kinds of layer carry the block's scopes; the gates and the
+    conv stand inside the short-convolution operator, the dispatch inside
+    the routed experts, both inside the block."""
+    op_names, _ = _lowering("lfm2")
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+    inside = {names.CONV_GATE: names.SHORT_CONV,
+              names.SHORT_CONV: names.BLOCK,
+              names.MOE_DISPATCH: names.MOE_ROUTED,
+              names.MOE_ROUTED: names.MOE, names.MOE: names.BLOCK}
+    if scope in inside:
+        assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+
+
+@pytest.mark.parametrize("residual", LFM2_RESIDUALS)
+def test_residual_name_in_lfm2_jaxpr(residual):
+    assert residual in names.RESIDUALS
+    _, jaxpr = _lowering("lfm2")
+    assert f"name={residual}" in jaxpr
+
+
+def test_lfm2_step_records_its_pattern_and_its_expert_load(buffer):
+    """Tracing the step leaves the `model/layer_pattern` decision of its five
+    layers of three kinds and a `model/remat_policy` one over them;
+    `balance_router_bias` on a batch leaves one `model/expert_load` event an
+    expert layer, `layer` its published index, no pair dropped; the grouped
+    products are the primitive the compiler's kernel comes from."""
+    import jax
+
+    from ray_tpu.models import blocks, lfm2_moe
+    from ray_tpu.train.train_step import synthetic_batch
+
+    _, jaxpr = _lowering("lfm2")
+    assert "ragged_dot" in jaxpr
+    for kernel in FLASH_KERNELS:
+        assert f"name={kernel}" in jaxpr
+    cfg = lfm2_moe.lfm2_moe_tiny()
+    by = {d["pattern"]: d for d in blocks.layer_pattern_decisions()}
+    assert tuple(by[cfg.pattern]) == names.LAYER_PATTERN_ARGS
+    assert by[cfg.pattern]["applications"] == {"D": 1, "A": 1, "C": 3}
+    assert by[cfg.pattern]["groups"] == ["D", "A", "3 x scan(C)"]
+    assert any((d["n_layer"], d["batch"], d["seq"]) == (5, 2, cfg.seq_len)
+               for d in blocks.remat_policy_decisions())
+    batch = synthetic_batch(cfg, 2)
+    params = lfm2_moe.init(cfg, jax.random.PRNGKey(0))
+    _, loads = lfm2_moe.balance_router_bias(params, batch["tokens"], cfg)
+    events = [e for e in _drain(buffer, "model")
+              if e["name"] == names.EXPERT_LOAD.split("/")[1]]
+    assert [e["args"] for e in events] == loads
+    assert [load["layer"] for load in loads] == [2, 3, 4, 5]
+    for load in loads:
+        assert tuple(load) == names.EXPERT_LOAD_ARGS
+        assert load["pairs_dropped"] == 0 and load["tokens"] == 2 * cfg.seq_len
 
 
 @pytest.mark.parametrize("residual", NEMOTRON_RESIDUALS)
