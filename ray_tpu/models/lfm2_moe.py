@@ -464,8 +464,8 @@ def kind_shards(cfg: LFM2MoEConfig, global_batch: int, seq: int, mesh
           3 * 2 * tokens * D * cfg.n_experts),
         C((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
           tokens * _sort_ops(cfg.n_experts, operands=2)),
-        C((scopes.RES_MOE_PAIR_KEY,), passes * rows * 4,
-          _sort_ops(tokens * cfg.held_count, operands=1)))
+        C((scopes.RES_MOE_PAIR_KEY, scopes.RES_MOE_PAIR_GATE),
+          passes * rows * 8, _sort_ops(tokens * cfg.held_count, operands=2)))
     expert_weights = 3 * cfg.held_count * D * Fe
     experts_set = (tokens * D * (2 * a + 8) + tokens * cfg.n_experts * 12
                    + a * rows * (2 * D + 6 * Fe)

@@ -382,8 +382,8 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
     # and what the routing decided (ops/moe.py tags them) — the scores at
     # three bf16 passes of the router's float32 product; the `top_k`'s last
     # value and index at a full sort of each row's n_experts with an index
-    # operand (what the TPU lowers it to) and the pairs' sorted keys at theirs
-    # (_sort_ops): a few hundred KB that spare a sort rank first
+    # operand (what the TPU lowers it to) and the pairs' sorted keys with their
+    # gates at theirs (_sort_ops): a MB or two that spare a sort rank first
     rows = moe.row_buffer(tokens, cfg.n_experts, cfg.top_k, cfg.held_count)
     passes = moe.buffer_passes(tokens, cfg.n_experts, cfg.top_k,
                                cfg.held_count)
@@ -394,8 +394,8 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
           3 * 2 * tokens * D * cfg.n_experts),
         C((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
           tokens * _sort_ops(cfg.n_experts, operands=2)),
-        C((scopes.RES_MOE_PAIR_KEY,), passes * rows * 4,
-          _sort_ops(tokens * cfg.held_count, operands=1))]
+        C((scopes.RES_MOE_PAIR_KEY, scopes.RES_MOE_PAIR_GATE),
+          passes * rows * 8, _sort_ops(tokens * cfg.held_count, operands=2))]
     if base.mlp_rows in (0, base.seq):
         experts_kept.append(C((scopes.RES_MOE_SHARED_HIDDEN,),
                               tokens * base.d_ff * a,

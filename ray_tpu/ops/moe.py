@@ -33,8 +33,10 @@ operand), are the rows of the two products, computed as grouped matmuls
 the real group sizes), around them a latent projection and beside them a
 shared expert. No pair is dropped — a batch that lands more pairs here than
 one row buffer holds takes further passes over it, and everything that costs
-a row (its gate included) is done by the pass that holds it (PR 36) — and
-memory is linear in T.
+a row is done by the pass that holds it (PR 36; its gate comes out of the
+pairs' sort beside its key, PR 51) — and memory is linear in T. A pass that
+runs alone costs one pass (PR 51): the first stands outside any loop and
+writes what it makes, and only a batch that fills more enters one.
 
 ``gated_moe`` (PR 50) is the same dispatch around the other kind of expert:
 three matrices, SiLU-gated, read and written at the model's width, no latent
@@ -47,12 +49,13 @@ written-out backward, the load and the bias's balance are one code for both.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ray_tpu.tracing import names as scopes
 
@@ -361,15 +364,18 @@ class HeldPairs(NamedTuple):
     """The (token, held expert) pairs a batch chose, sorted by expert and
     within an expert by token, as ``passes`` buffers of ``rows``: row r of
     pass i is the pair at place ``key[i, r]`` of the membership's transpose —
-    expert · T + token, so token ``key % T`` with gate ``gates[key]`` — while
-    ``valid[i, r]``; ``group_sizes[i, e]`` of the pass's rows belong to held
-    expert e. Every pair on a held expert is in some pass: passes · rows
-    covers the worst case. A row that holds no pair has key 0 — token 0 and
-    whatever gate sits there, NOT zero: ``valid`` alone says which rows
-    count, and ``_pass_rows`` masks by it. What a row costs (its token, its
-    gate, its latent) is looked up by the pass that runs it, not here."""
+    expert · T + token, so token ``key % T`` — with gate ``gate_rows[i, r]``
+    = ``gates[key[i, r]]``, while ``valid[i, r]``; ``group_sizes[i, e]`` of
+    the pass's rows belong to held expert e. Every pair on a held expert is
+    in some pass: passes · rows covers the worst case. A row that holds no
+    pair has key 0 — token 0 — and whatever gate the sort left there, NOT
+    zero: ``valid`` alone says which rows count, and ``_pass_rows`` masks by
+    it. A row's token and its latent are looked up by the pass that runs it,
+    not here; its gate came out of the sort beside its key (PR 51), a
+    constant to AD — ``gates`` is where a gate's cotangent goes back to."""
     key: jax.Array            # [passes, rows] int32
     gates: jax.Array          # [held · T] float32: route's gates, expert-major
+    gate_rows: jax.Array      # [passes, rows] float32: the same, row for row
     valid: jax.Array          # [passes, rows] bool
     group_sizes: jax.Array    # [passes, held] int32
     per_expert: jax.Array     # [held] int32: pairs on each held expert
@@ -381,27 +387,39 @@ def held_pairs(here: jax.Array, gates: jax.Array, rows: int,
     its ``gates``) over ``passes`` buffers of ``rows``, expert by expert and
     token-ascending within one. A pair's sort key is its place in ``here.T``
     — expert · T + token, so it carries both — and every other entry's key
-    sorts last: one sort of T · held keys, no index operand."""
+    sorts last: one sort of T · held keys with the gates that lie there
+    beside them, no index operand."""
     T, held = here.shape
     none = held * T                              # fits int32 with room
     per_expert = jnp.sum(here, axis=0, dtype=jnp.int32)
     place = jnp.arange(none, dtype=jnp.int32).reshape(held, T)
+    gates = gates.T.reshape(none)
     # (the pairs' keys are distinct, so the sort need not be stable: a stable
-    # one takes an index operand on the TPU)
-    key = jnp.sort(jnp.where(here.T, place, none).reshape(none), stable=False)
+    # one takes an index operand on the TPU — as AD's rule for a sort does,
+    # with a gather by it: the gates ride along as numbers, and their way
+    # back is _run_passes_bwd's scatter by key)
+    key, gate = lax.sort(
+        (jnp.where(here.T, place, none).reshape(none),
+         lax.stop_gradient(gates)), num_keys=1, is_stable=False)
     # past the T · held keys there are no pairs
     total = passes * rows
-    # (named: a block that keeps the sorted keys does not sort them again;
-    # valid and group_sizes are a row-sum of `here` away)
-    key = checkpoint_name(jnp.pad(key, (0, max(0, total - none)))[:total],
-                          scopes.RES_MOE_PAIR_KEY)
+
+    def first(x):
+        return jnp.pad(x, (0, max(0, total - none)))[:total]
+
+    # (named: a block that keeps the sorted keys and their gates does not
+    # sort again and looks no gate up; valid and group_sizes are a row-sum
+    # of `here` away)
+    key = checkpoint_name(first(key), scopes.RES_MOE_PAIR_KEY)
+    gate = checkpoint_name(first(gate), scopes.RES_MOE_PAIR_GATE)
     valid = jnp.arange(total) < jnp.sum(per_expert)
     # a pass's share of each expert's run of rows
     lo = (jnp.arange(passes) * rows)[:, None]
     ends = jnp.clip(jnp.cumsum(per_expert)[None, :], lo, lo + rows) - lo
     return HeldPairs(
         key=jnp.where(valid, key, 0).reshape(passes, rows),
-        gates=gates.T.reshape(none),
+        gates=gates,
+        gate_rows=gate.reshape(passes, rows),
         valid=valid.reshape(passes, rows),
         group_sizes=jnp.diff(ends, axis=1, prepend=0).astype(jnp.int32),
         per_expert=per_expert)
@@ -449,68 +467,121 @@ def _pass_rows(x, ws, gate, valid, group_sizes):
 
 
 @jax.named_scope(scopes.MOE_DISPATCH)
-def _looked_up(ell, gates, key):
-    """What one pass looks up for its rows' ``key`` [rows]: each row's token,
-    its latent out of ell [T, latent] and its gate out of the flat ``gates``."""
+def _looked_up(ell, key):
+    """What one pass looks up for its rows' ``key`` [rows]: each row's token
+    and its latent out of ell [T, latent]."""
     token = key % ell.shape[0]
-    return token, ell[token], gates[key]
+    return token, ell[token]
+
+
+@jax.named_scope(scopes.MOE_FURTHER_PASSES)
+def _further_passes(n, body, first):
+    """``body`` over passes 1 … ``n`` − 1, from what the first pass made:
+    a ``while`` that a batch whose pairs fit one pass — every batch of a
+    balanced router — does not enter. A step that runs nothing under this
+    scope took the one-pass path in every expert layer."""
+    return lax.fori_loop(1, n, body, first)
+
+
+class _Float32Sum(NamedTuple):
+    """A float32 sum that starts AT an array of the compute dtype — the first
+    pass's weight gradient, which is the whole sum where no further pass runs
+    — stored as that array and the bits it lacks: a bfloat16 is the high half
+    of its float32's word, so ``low`` holds the other 16 bits, zero to start
+    with (for float32 there are none). Nothing is converted to start the sum
+    and nothing to leave it; ``high`` + ``low`` take the bytes the float32
+    sum took, and a further pass reads and writes what its add did."""
+    high: jax.Array
+    low: Optional[jax.Array]
+
+    @classmethod
+    def starting_at(cls, d):
+        if d.dtype == jnp.float32:
+            return cls(d, None)
+        if d.dtype != jnp.bfloat16:
+            raise TypeError(f"the experts' weights are {d.dtype}: the passes "
+                            "sum their gradients for bfloat16 or float32")
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            return cls(d, jnp.zeros(d.shape, jnp.uint16))
+
+    def plus(self, d, last):
+        """The sum with ``d`` added in float32; after the ``last`` pass
+        ``high`` is the sum cast to the compute dtype, as a cast of the
+        float32 sum gives it."""
+        if self.low is None:
+            return _Float32Sum(self.high + d, None)
+        word = (lax.bitcast_convert_type(self.high, jnp.uint16)
+                .astype(jnp.uint32) << 16 | self.low.astype(jnp.uint32))
+        s = lax.bitcast_convert_type(word, jnp.float32) + d.astype(jnp.float32)
+        word = lax.bitcast_convert_type(s, jnp.uint32)
+        high = lax.bitcast_convert_type((word >> 16).astype(jnp.uint16),
+                                        jnp.bfloat16)
+        return _Float32Sum(jnp.where(last, s.astype(jnp.bfloat16), high),
+                           word.astype(jnp.uint16))
 
 
 @jax.custom_vjp
-def _run_passes(ell, ws, gates, key, valid, group_sizes, n):
+def _run_passes(ell, ws, gates, gate_rows, key, valid, group_sizes, n):
     """The first ``n`` passes' sum over ell [T, latent]: each pass looks up
-    its own rows' tokens, latents and gates (``key[i]`` into ell and the flat
-    ``gates``), runs them through _pass_rows (``ws``: the held experts'
-    weights, and with them their form) and adds each row to its token's
-    row of ONE [T, latent] float32 sum. ``n`` is a value of the step — the
-    passes this batch's pairs fill — so the loop is a ``while``; its backward
-    is written out below (one pass's vjp at a time into float32 sums),
-    because AD through a loop of conditional passes keeps every pass's
-    operands at once (the 8 x 4,096-token step then needs 18.7 GB of a v5e's
-    15.75)."""
-    def body(i, r):
-        token, x, gate = _looked_up(ell, gates, key[i])
-        o = _pass_rows(x, ws, gate, valid[i], group_sizes[i])
+    its own rows' tokens and latents (``key[i]`` into ell; its gates are
+    ``gate_rows[i]`` — ``gates[key[i]]``, which is not looked up: the flat
+    ``gates`` is here for its cotangent), runs them through _pass_rows
+    (``ws``: the held experts' weights, and with them their form) and adds
+    each row to its token's row of ONE [T, latent] float32 sum. ``n`` is a
+    value of the step — the passes this batch's pairs fill. A pass that runs
+    alone costs one pass: the first runs as it stands and writes what it
+    makes — one without a pair is all zeros, so ``n`` = 0 needs no guard —
+    and only a batch that fills more enters the ``while`` (_further_passes).
+    The backward is written out below (one pass's vjp at a time), because AD
+    through a loop of conditional passes keeps every pass's operands at once
+    (the 8 x 4,096-token step then needs 18.7 GB of a v5e's 15.75)."""
+    def add_pass(i, r):
+        token, x = _looked_up(ell, key[i])
+        o = _pass_rows(x, ws, gate_rows[i], valid[i], group_sizes[i])
         with jax.named_scope(scopes.MOE_DISPATCH):
             return r.at[token].add(o)
 
-    return lax.fori_loop(0, n, body, jnp.zeros(ell.shape, jnp.float32))
+    return _further_passes(n, add_pass,
+                           add_pass(0, jnp.zeros(ell.shape, jnp.float32)))
 
 
-def _run_passes_fwd(ell, ws, gates, key, valid, group_sizes, n):
-    return (_run_passes(ell, ws, gates, key, valid, group_sizes, n),
-            (ell, ws, gates, key, valid, group_sizes, n))
+def _run_passes_fwd(ell, ws, gates, gate_rows, key, valid, group_sizes, n):
+    return (_run_passes(ell, ws, gates, gate_rows, key, valid, group_sizes, n),
+            (ell, ws, gates, gate_rows, key, valid, group_sizes, n))
 
 
 def _run_passes_bwd(res, d_r):
-    """The transposes of a pass's three lookups written out: the sum's
-    cotangent gathered by token, the latents' scatter-added by token and the
-    gates' by key — a pass's ``rows`` scalars into the [held · T] sum, not
-    passes · rows of them kept for one scatter at the end."""
-    ell, ws, gates, key, valid, group_sizes, n = res
+    """The transposes of a pass's lookups written out: the sum's cotangent
+    gathered by token, the latents' scatter-added by token and the gates' by
+    key — a pass's ``rows`` scalars into the [held · T] sum, not passes · rows
+    of them kept for one scatter at the end. The first pass's weight
+    gradients are the kernel's own outputs; further passes add theirs to them
+    in float32 (_Float32Sum)."""
+    ell, ws, gates, gate_rows, key, valid, group_sizes, n = res
 
-    def body(i, sums):
-        d_ell, d_ws, d_gates = sums
-        token, x, gate = _looked_up(ell, gates, key[i])
+    def pass_vjp(i, d_ell, d_gates):
+        token, x = _looked_up(ell, key[i])
         _, vjp = jax.vjp(
             lambda x, ws, g: _pass_rows(x, ws, g, valid[i], group_sizes[i]),
-            x, ws, gate)
+            x, ws, gate_rows[i])
         with jax.named_scope(scopes.MOE_DISPATCH):
             d_o = d_r[token]
         d_x, d_w, d_gate = vjp(d_o)
         with jax.named_scope(scopes.MOE_DISPATCH):
-            d_ell = d_ell.at[token].add(d_x.astype(jnp.float32))
-            d_gates = d_gates.at[key[i]].add(d_gate)
-        return (d_ell, tuple(s + d.astype(jnp.float32)
-                             for s, d in zip(d_ws, d_w)), d_gates)
+            return (d_ell.at[token].add(d_x.astype(jnp.float32)), d_w,
+                    d_gates.at[key[i]].add(d_gate))
 
-    sums = lax.fori_loop(0, n, body, (
-        jnp.zeros(ell.shape, jnp.float32),
-        tuple(jnp.zeros(w.shape, jnp.float32) for w in ws),
-        jnp.zeros_like(gates)))
-    return (sums[0].astype(ell.dtype),
-            tuple(s.astype(w.dtype) for s, w in zip(sums[1], ws)), sums[2],
-            None, None, None, None)
+    def body(i, sums):
+        d_ell, d_w, d_gates = pass_vjp(i, sums[0], sums[2])
+        return (d_ell, tuple(s.plus(d, i == n - 1)
+                             for s, d in zip(sums[1], d_w)), d_gates)
+
+    d_ell, d_w, d_gates = pass_vjp(0, jnp.zeros(ell.shape, jnp.float32),
+                                   jnp.zeros_like(gates))
+    d_ell, d_ws, d_gates = _further_passes(n, body, (
+        d_ell, tuple(_Float32Sum.starting_at(d) for d in d_w), d_gates))
+    return (d_ell.astype(ell.dtype), tuple(s.high for s in d_ws), d_gates,
+            None, None, None, None, None)
 
 
 _run_passes.defvjp(_run_passes_fwd, _run_passes_bwd)
@@ -542,8 +613,16 @@ def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
     more runs the further passes it fills."""
     with jax.named_scope(scopes.MOE_DISPATCH):
         _, pairs, filled = _dispatch(u, p, top_k, held, scaling, eps)
-    return _run_passes(ell, tuple(p[w] for w in form), pairs.gates, pairs.key,
-                       pairs.valid, pairs.group_sizes, filled)
+    # (each weight row-major as it enters the passes: the backward's grouped
+    # products read two of them transposed, and the first pass stands
+    # outside any loop now, so without this the compiler lays the float32
+    # parameters themselves — and their moments — out transposed, by copies
+    # a step, to spare the transposing copy of a cast)
+    ws = tuple(with_layout_constraint(
+        p[w], Layout(major_to_minor=tuple(range(p[w].ndim)))) for w in form)
+    return _run_passes(ell, ws, pairs.gates,
+                       pairs.gate_rows, pairs.key, pairs.valid,
+                       pairs.group_sizes, filled)
 
 
 def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,

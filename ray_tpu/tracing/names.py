@@ -41,6 +41,13 @@ MOE_ROUTED = "moe_routed"
 MOE_DISPATCH = "moe_dispatch"
 MOE_LATENT = "moe_latent"
 MOE_SHARED = "moe_shared"
+# ops/moe._further_passes, inside MOE_ROUTED: the passes over the row buffer
+# after the first — the `while` a batch enters only where its pairs fill more
+# than one buffer — forward and backward: their lookups and masks, the loop's
+# carried state, the float32 sums of the experts' weight gradients. The first
+# pass stands outside it, so a step that runs nothing under this scope took
+# the one-pass path in every expert layer (PR 51)
+MOE_FURTHER_PASSES = "moe_further_passes"
 # models/nemotron_h.py: the multi-token-prediction module, its layers and loss
 MTP = "mtp"
 # models/minicpm_sala.py: the lightning (decayed linear-attention) mixer —
@@ -64,7 +71,8 @@ SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           MOE_ROUTED, MOE_DISPATCH, MOE_LATENT,
                                           MOE_SHARED, MTP, LIGHTNING_ATTN,
                                           SPARSE_ATTENTION, SPARSE_SELECT,
-                                          SHORT_CONV, CONV_GATE)
+                                          SHORT_CONV, CONV_GATE,
+                                          MOE_FURTHER_PASSES)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -152,13 +160,15 @@ RES_MLP_GATE, RES_MLP_UP = "mlp_gate", "mlp_up"
 # hidden pre-activation; and what its routing decided (PR 42) — the router's
 # sigmoid scores, the `top_k`'s last value and index a token (the chosen set
 # is one elementwise pass from them, ops/moe._chosen), the sorted keys of the
-# pairs on held experts (ops/moe.held_pairs).
+# pairs on held experts and, since PR 51, the gates the same sort laid beside
+# them (ops/moe.held_pairs).
 RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT = "mamba_z", "mamba_xbc", "mamba_dt"
 RES_SSD_STATES, RES_SSD_Y = "ssd_states", "ssd_y"
 RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN = "moe_latent_in", "moe_shared_hidden"
 RES_MOE_SCORES = "moe_scores"
 RES_MOE_KTH, RES_MOE_LAST = "moe_kth", "moe_kth_index"
 RES_MOE_PAIR_KEY = "moe_pair_key"
+RES_MOE_PAIR_GATE = "moe_pair_gate"
 # a MiniCPM-SALA layer's (models/minicpm_sala.py). Both mixers name q, k, v
 # (RES_Q, RES_K, RES_V: after the QK-norm and, in the lightning one, RoPE) and
 # their output gate's pre-activation; the lightning one the scan's states and
@@ -186,7 +196,7 @@ RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN,
              RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY,
              RES_SALA_GATE, RES_LIGHTNING_Y, RES_SPARSE_IDS, RES_SPARSE_O,
-             RES_SPARSE_LSE, RES_CONV_BCX)
+             RES_SPARSE_LSE, RES_CONV_BCX, RES_MOE_PAIR_GATE)
 # which of them models/blocks.py chose to save, the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once),
 # and the phase of the backward whose working set the budget was left by

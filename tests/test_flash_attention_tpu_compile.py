@@ -503,7 +503,7 @@ def test_the_nemotron_cell_step_keeps_the_routing_and_clones_nothing(
     layers sets it — and stands a little above what the compiler holds, not
     2.5 GiB above, so there is a GiB to spend: on Δ's projection, on the
     routing's outcome (the `top_k`'s last value and index, the scores, the
-    pairs' sorted keys), on the gate projection of the Mamba layers and the
+    pairs' sorted keys and, since PR 51, their gates), on the gate projection of the Mamba layers and the
     attention layers' q, k, v, o and lse. The step needs 14.13 GiB of 15.75
     (13.36 with nothing kept), XLA's own rematerialization pass — which is
     recompute too — cloned nothing, and the blocks' second forward holds no
@@ -521,7 +521,7 @@ def test_the_nemotron_cell_step_keeps_the_routing_and_clones_nothing(
         names.RES_MAMBA_DT, names.RES_MOE_KTH, names.RES_MOE_LAST,
         names.RES_MOE_SCORES, names.RES_MAMBA_Z, names.RES_Q, names.RES_K,
         names.RES_V, names.RES_FLASH_O, names.RES_FLASH_LSE,
-        names.RES_MOE_PAIR_KEY]
+        names.RES_MOE_PAIR_KEY, names.RES_MOE_PAIR_GATE]
     assert 0 < d["saved_bytes"] <= d["budget_bytes"] <= 1.25 * 2 ** 30
     assert d["phase"] == "4 x scan(ME)"
     again = [line for line in hlo.splitlines()
@@ -592,12 +592,13 @@ def routed_experts_hlo(one_chip):
 
 def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(
         routed_experts_hlo):
-    """Every one of the six grouped products (two forward, two a backward
-    operand side) is the TPU compiler's own grouped kernel (`lax.ragged_dot`
-    → a `ragged-dot` custom call whose work follows the real group sizes) and
-    none is expanded into one dense product an expert; the row buffer is
-    1.25× the mean in whole tiles (28 of 512 rows for 11,264 pairs), not the
-    worst case."""
+    """Every one of the six grouped products a pass makes (two forward, two a
+    backward operand side) — twice in the program since PR 51: the first pass
+    stands outside the loop, further passes inside it — is the TPU
+    compiler's own grouped kernel (`lax.ragged_dot` → a `ragged-dot` custom
+    call whose work follows the real group sizes) and none is expanded into
+    one dense product an expert; the row buffer is 1.25× the mean in whole
+    tiles (28 of 512 rows for 11,264 pairs), not the worst case."""
     from ray_tpu.ops import moe
 
     hlo = routed_experts_hlo
@@ -605,7 +606,7 @@ def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(
     assert rows == 14336 < _MOE_T * _MOE_HELD
     grouped = [line for line in hlo.splitlines()
                if re.match(r"\s*(ROOT )?%?ragged-dot-none\S* = ", line)]
-    assert len(grouped) == 6, len(grouped)
+    assert len(grouped) == 12, len(grouped)
     assert all('custom_call_target="tpu_custom_call"' in g for g in grouped)
     # no product of the whole buffer with one expert's matrix
     assert f"bf16[{rows},{_MOE_F}]" in hlo and not re.search(
@@ -649,8 +650,9 @@ def test_routing_gathers_no_score_and_sorts_the_membership_on_the_v5e(
     scores, so no `gather` (and no `scatter`, its transpose) touches a
     [32,768 x 512] array; the pairs are sorted from the [tokens x 8]
     membership, so beside `top_k`'s own sort no `sort` has more than 262,144
-    keys, and the one that has them takes one operand (the key carries its
-    token: no index rides along)."""
+    keys, and the one that has them takes two operands — the key, which
+    carries its token, and since PR 51 the gate that lies at its place: no
+    index rides along, and no gate is looked up afterwards (below)."""
     hlo = routed_experts_hlo
 
     size = _elements(hlo)
@@ -672,4 +674,65 @@ def test_routing_gathers_no_score_and_sorts_the_membership_on_the_v5e(
         keys = size[op.split(" = ")[0]]
         operands[keys] = max(operands.get(keys, 0), len(shapes))
     assert max(operands) == _MOE_T * _MOE_HELD, operands
-    assert operands[_MOE_T * _MOE_HELD] == 1, operands
+    assert operands[_MOE_T * _MOE_HELD] == 2, operands
+    # no gather reads the flat [held · T] table of gates: the only scalars
+    # that move by key are the gates' cotangents, scatter-added a pass
+    assert _MOE_T * _MOE_HELD not in read, read
+    assert _MOE_T * _MOE_HELD in written, written
+
+
+def test_the_lfm2_tiny_step_runs_one_pass_outside_the_loop_on_the_v5e(
+        topo, monkeypatch):
+    """PR 51: the LFM2 cell's step at every published width and a tiny batch
+    (2 rows of 512 tokens: three passes in the worst case; through
+    `families/lfm2_moe.abstract_step`) lowered and compiled for one described
+    chip, ~25 s. The first
+    pass of an expert layer stands outside any loop; what only a batch with
+    more passes needs is carried by a `while` under `moe_further_passes` —
+    every loop of the routed experts, and with it every float32 [held, width,
+    d_expert] tensor; no pass looks a gate up
+    out of the flat [held · T] table (the sort laid them in pair order); and
+    the compiler rematerialized nothing."""
+    from conftest import time_limit
+
+    from ray_tpu.models import blocks
+    from ray_tpu.tracing import names
+
+    monkeypatch.setattr(blocks, "_decisions", {})
+    cell, config, family, mesh = _cell_on(topo, "lfm2-24b-a2b-l5.dataset")
+    cell.update(seq_len=512, per_chip_batch=2)
+    cfg = family.program_config(config, cell)
+    with time_limit(240, "the LFM2 step's compile for a described v5e"):
+        step, args = family.abstract_step(config, cell, mesh)
+        lowered = step.lower(*args)
+        hlo = lowered.compile().as_text()
+    assert blocks.compiler_rematerialized(hlo) == []
+
+    ops = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"^\s*(?:ROOT )?%\S+ = (.*?) \w[\w\-]*\(.*?op_name=\"([^\"]*)\"", hlo,
+        re.M)]
+    routed = [(shape, op) for shape, op in ops if f"/{names.MOE_ROUTED}/" in op]
+    further = [(shape, op) for shape, op in routed
+               if f"/{names.MOE_FURTHER_PASSES}/" in op]
+    assert further and len(further) < len(routed)
+    # every loop of the routed experts is the further passes'
+    assert all(f"/{names.MOE_FURTHER_PASSES}/while" in op
+               for _, op in routed if "/while" in op.split(names.MOE_ROUTED)[1])
+    # forward and backward (the backward's second forward of them is dead)
+    assert any("transpose(" in op for _, op in further)
+    assert any("transpose(" not in op for _, op in further)
+    held, width, d_expert = cfg.held_count, cfg.d_model, cfg.d_expert
+    sums = re.compile(rf"f32\[{held},({width},{d_expert}|{d_expert},{width})\]")
+    assert [op for shape, op in routed
+            if sums.search(shape) and (shape, op) not in further] == []
+    assert [op for shape, op in further if sums.search(shape)]
+    # (the sums' low halves start as zeros a layer, outside the loop: the one
+    # thing the one-pass path pays for the passes it does not run)
+    low = [op for shape, op in routed if re.search(rf"u16\[{held},", shape)
+           and (shape, op) not in further]
+    assert low and all(f"/{names.MOE_DISPATCH}/" in op for op in low), low
+    # no scalar gather out of the [held · T] table, in the program as traced
+    T = cell["per_chip_batch"] * cfg.seq_len
+    text = lowered.as_text()
+    gathers = re.findall(r'"?stablehlo\.gather"?\(.*?:\s*\((tensor<[^>]*>)', text)
+    assert gathers and f"tensor<{held * T}xf32>" not in gathers, set(gathers)

@@ -37,6 +37,9 @@ CELL = "lfm2-24b-a2b-l5.dataset"
 NEW_READERS = ("lfm2_mfu_device", "short_conv_ms_per_step",
                "conv_gate_ms_per_step", "lfm2_experts_roofline",
                "lfm2_flash_attn_roofline")
+# PR 51's reader of the passes a batch runs beyond the one-pass path, which
+# this cell and the Nemotron cell list
+FURTHER_PASSES = "moe_further_passes_ms_per_step"
 # accepted readers of a scope or a kernel this family's step has too
 SHARED_READERS = ("moe_routed_ms_per_step", "moe_dispatch_ms_per_step",
                   "flash_fwd_ms_per_step", "flash_bwd_ms_per_step")
@@ -297,6 +300,56 @@ def test_no_pair_is_dropped_when_every_token_chooses_held_experts():
                                atol=2e-6 * float(jnp.abs(ref).max()))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("router", ["as_initialised", "all_on_held_experts"])
+def test_gated_moe_is_the_parents_whatever_the_passes_it_fills(
+        router, dtype, monkeypatch):
+    """PR 51: ``gated_moe``'s output and every gradient — the router's
+    included, so the gates' cotangent goes all the way back through
+    ``route`` — against the same layer over the pairs and passes frozen at
+    PR 50 (tests/moe_pr50_passes.py): bit-equal where the batch fills one
+    pass, and where a router sends every token's four choices to the four
+    held experts, which fills three."""
+    import moe_pr50_passes as pr50
+
+    cfg = lm.lfm2_moe_tiny(dtype=dtype, held_first=0, held_count=4, seq_len=512)
+    p = {k: (v.astype(dtype) if k in moe.GATED_EXPERT else v)
+         for k, v in _layer_of(_params(cfg, seed=6), cfg, "C").items()}
+    if router == "all_on_held_experts":
+        p["router_bias"] = jnp.where(jnp.arange(cfg.n_experts) < 4, 1.0, 0.0)
+    u = jax.random.normal(jax.random.PRNGKey(8),
+                          (2, cfg.seq_len, cfg.d_model)).astype(dtype)
+    w = jax.random.normal(jax.random.PRNGKey(9), u.shape)
+    load = moe.held_load(u.reshape(-1, cfg.d_model), p, top_k=cfg.top_k,
+                         held=cfg.held, scaling=cfg.routed_scaling,
+                         eps=cfg.route_eps)
+    assert int(load["buffer_passes"]) == (
+        3 if router == "all_on_held_experts" else 1)
+
+    def layer(u, p):
+        y = moe.gated_moe(u, p, top_k=cfg.top_k, held=cfg.held,
+                          scaling=cfg.routed_scaling, eps=cfg.route_eps)
+        return jnp.sum(y * w), y
+
+    def graded():
+        # (operation by operation, each rounding as it does alone: what is
+        # compared is the arithmetic the two programs state, not how a
+        # compiler fuses a pass outside a loop and the same pass inside one)
+        with jax.disable_jit():
+            return jax.value_and_grad(layer, (0, 1), has_aux=True)(u, p)
+
+    now = graded()
+    monkeypatch.setattr(moe, "routed_experts", pr50.routed_experts)
+    then = graded()
+    for a, b in zip(jax.tree.leaves(now), jax.tree.leaves(then), strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    assert float(jnp.abs(now[1][1]["router_w"]).max()) > 0
+    assert float(jnp.abs(now[1][1]["w3"].astype(jnp.float32)).max()) > 0
+
+
 def test_bf16_program_is_near_the_reference_and_a_coarser_one_is_not():
     """The family's comparison at tiny sizes: the bf16 program passes its
     limits' order of magnitude, the reference with float8 operands does not,
@@ -533,8 +586,9 @@ def test_the_benchmark_gains_one_configuration_and_one_one_chip_cell():
     assert b["workloads"][-1] == {
         **b["workloads"][-1], "name": CELL, "config": "lfm2-24b-a2b-l5",
         "traffic": "dataset", "chips": 1}
-    assert [m["name"] for m in b["per_layer"]][-len(NEW_READERS):] == list(
-        NEW_READERS)
+    # (PR 51 appended one metric of the dispatch after them)
+    assert [m["name"] for m in b["per_layer"]][-len(NEW_READERS) - 1:] == list(
+        NEW_READERS) + [FURTHER_PASSES]
     for name in SHARED_READERS:
         entry = next(m for m in b["per_layer"] if m["name"] == name)
         assert entry["workloads"][-1] == CELL
@@ -619,3 +673,76 @@ def test_a_reader_of_this_cell_reads_its_recorded_trace(name, value):
     got = reader.read(_recorded_facts("lfm2_moe"))
     assert got == pytest.approx(value, rel=1e-3)
     assert 0 < got <= 100 or entry["unit"] != "%"
+
+
+# --------------------------------------------------------------------------- #
+# PR 51: `moe_further_passes_ms_per_step`, the one metric it adds
+# --------------------------------------------------------------------------- #
+
+def _further_reader():
+    return importlib.import_module(f"benchmarks.layer_metrics.{FURTHER_PASSES}")
+
+
+def test_the_further_passes_reader_is_one_appended_entry_of_two_cells():
+    b = _benchmark()
+    entry = b["per_layer"][-1]
+    assert entry == {
+        "name": FURTHER_PASSES, "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "Kernels",
+        "moves": "tokens_per_s_per_chip",
+        "workloads": ["nemotron-3-super-120b-l11.dataset", CELL]}
+    reader = _further_reader()
+    assert (reader.UNIT, reader.MOVES, reader.SOURCE, reader.LAYER) == (
+        entry["unit"], entry["moves"], entry["source"], entry["layer"])
+    assert reader.SCOPE == names.MOE_FURTHER_PASSES in names.SCOPES
+
+
+@pytest.mark.parametrize("family_name", ["lfm2_moe", "nemotron_h", "gpt2"])
+def test_the_further_passes_reader_reads_nothing_under_a_parents_vocabulary(
+        family_name, monkeypatch):
+    """What the driver's control runs: this PR's `benchmarks/` over the
+    parent's `ray_tpu/`, whose `names.SCOPES` has no such scope — the reader
+    returns None from the traces recorded at PR 50 and raises nothing."""
+    from benchmarks.harness import program_trace
+
+    reader = _further_reader()
+    facts = _recorded_facts(family_name)
+    monkeypatch.setattr(program_trace, "SCOPES", tuple(
+        s for s in names.SCOPES if s != names.MOE_FURTHER_PASSES))
+    assert reader.read(facts) is None
+
+
+@pytest.mark.parametrize("family_name,value", [
+    # recorded before the scope existed: every op of the dispatch lies outside
+    ("lfm2_moe", 0.0), ("nemotron_h", 0.0),
+    # a step that routes nothing has nothing to say
+    ("gpt2", None)])
+def test_the_further_passes_reader_on_the_recorded_cells(family_name, value):
+    reader = _further_reader()
+    assert reader.read(_recorded_facts(family_name)) == value
+
+
+def test_the_further_passes_reader_reads_a_recorded_second_pass():
+    """A small expert layer whose router sends every token's choices to the
+    held experts (two passes), one traced step recorded on the chip
+    (`benchmarks/testdata/moe-further-passes.1step.scoped.program.json.gz`):
+    the scope holds the loop's dispatch work forward and backward, inside
+    `moe_routed` and beside none of the one-pass path's."""
+    from benchmarks.harness import program_trace
+
+    tables = program_trace.read_tables(os.path.join(
+        ROOT, "benchmarks", "testdata",
+        "moe-further-passes.1step.scoped.program.json.gz"))
+    got = program_trace.reduce_tables(tables)
+    assert got["instrumented"]
+    reader = _further_reader()
+    facts = {"program_trace": got, "notes": []}
+    value = reader.read(facts)
+    assert value == pytest.approx(
+        got["scope_ms_per_step"][names.MOE_FURTHER_PASSES]) and value > 0
+    assert value < got["scope_ms_per_step"][names.MOE_ROUTED]
+    # every op under the scope is the routed experts'
+    for tf_op, hlo_name, _, kind, _, _ in tables["ops"]:
+        c = program_trace.classify(tf_op, hlo_name, kind)
+        if names.MOE_FURTHER_PASSES in c["scopes"]:
+            assert names.MOE_ROUTED in c["scopes"], tf_op

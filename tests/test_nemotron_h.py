@@ -21,6 +21,8 @@ from ray_tpu.ops import mamba2, moe
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tracing import names
 
+import moe_pr50_passes as pr50
+
 
 def _batch(cfg, rows=2, seed=0):
     rng = np.random.default_rng(seed)
@@ -542,15 +544,16 @@ def test_passes_share_an_experts_run_of_rows(rows, sizes):
     assert pairs.valid.sum(axis=1).tolist() == [sum(g) for g in sizes] + [0]
     # expert 0's tokens first, each once and in order; then expert 1's
     assert (pairs.key.reshape(-1)[:128] % T).tolist() == 2 * list(range(64))
-    np.testing.assert_array_equal(pairs.gates[pairs.key.reshape(-1)[:128]],
+    np.testing.assert_array_equal(pairs.gate_rows.reshape(-1)[:128],
                                   gates.T.reshape(-1))
     ell = jax.random.normal(k[1], (T, 4))
     w1, w2 = jax.random.normal(k[2], (2, 4, 6)), jax.random.normal(k[3], (2, 6, 4))
 
     def routed(ell, w1, w2, gates):
         pairs = moe.held_pairs(jnp.ones((T, 2), bool), gates, rows, passes + 1)
-        return moe._run_passes(ell, (w1, w2), pairs.gates, pairs.key,
-                               pairs.valid, pairs.group_sizes, passes)
+        return moe._run_passes(ell, (w1, w2), pairs.gates, pairs.gate_rows,
+                               pairs.key, pairs.valid, pairs.group_sizes,
+                               passes)
 
     def dense(ell, w1, w2, gates):
         return sum(gates[:, e, None]
@@ -584,6 +587,8 @@ class _Rows(NamedTuple):
 
 
 def _looked_up(pairs: moe.HeldPairs, T: int) -> _Rows:
+    # (looked up here, so that AD sees them: the rows' own copy out of the
+    # sort, `gate_rows`, is a constant to it)
     return _Rows(pairs.key % T, pairs.gates[pairs.key], pairs.valid,
                  pairs.group_sizes, pairs.per_expert)
 
@@ -667,6 +672,11 @@ def test_the_membership_dispatch_is_the_chosen_lists(case):
                                   held, rows, passes)
 
     (here, got), (idx, want) = new(logits), old(logits)
+    # the gates the sort laid beside the keys are the table's, row for row
+    pairs = moe.held_pairs(*moe.route(logits, jnp.eye(E), bias, top_k, scaling,
+                                      held), rows, passes)
+    np.testing.assert_array_equal(np.where(pairs.valid, pairs.gate_rows, 0),
+                                  np.where(got.valid, got.gate, 0))
     # the mask is the list: top_k a row, ties to the lower ids
     everyone, _ = moe.route(logits, jnp.eye(E), bias, top_k, scaling,
                             moe.Held(0, E))
@@ -830,7 +840,8 @@ def test_the_cells_kinds_and_the_familys_arithmetic():
     assert base.head_rows == 128 and base.mlp_rows < cfg.seq_len
 
 
-ROUTING = (names.RES_MOE_KTH, names.RES_MOE_LAST, names.RES_MOE_PAIR_KEY)
+ROUTING = (names.RES_MOE_KTH, names.RES_MOE_LAST, names.RES_MOE_PAIR_KEY,
+           names.RES_MOE_PAIR_GATE)
 
 
 def test_the_cells_decision_from_its_shapes_keeps_the_routing_first():
@@ -891,7 +902,8 @@ def test_the_cells_decision_from_its_shapes_keeps_the_routing_first():
     T = cell["per_chip_batch"] * cfg.seq_len
     assert by_name[(names.RES_MOE_KTH, names.RES_MOE_LAST)].nbytes == 8 * T
     assert by_name[(names.RES_MOE_SCORES,)].nbytes == 4 * T * 512
-    assert by_name[(names.RES_MOE_PAIR_KEY,)].nbytes < 2 ** 21
+    assert by_name[(names.RES_MOE_PAIR_KEY,
+                    names.RES_MOE_PAIR_GATE)].nbytes < 2 ** 22
 
 
 def _count(jaxpr, pred):
@@ -1125,7 +1137,7 @@ def _pr49_pass_rows(x, w1, w2, gate, valid, group_sizes):
 @jax.custom_vjp
 def _pr49_run_passes(ell, w1, w2, gates, key, valid, group_sizes, n):
     def body(i, r):
-        token, x, gate = moe._looked_up(ell, gates, key[i])
+        token, x, gate = pr50.looked_up(ell, gates, key[i])
         o = _pr49_pass_rows(x, w1, w2, gate, valid[i], group_sizes[i])
         return r.at[token].add(o)
 
@@ -1142,7 +1154,7 @@ def _pr49_run_passes_bwd(res, d_r):
 
     def body(i, sums):
         d_ell, d_w1, d_w2, d_gates = sums
-        token, x, gate = moe._looked_up(ell, gates, key[i])
+        token, x, gate = pr50.looked_up(ell, gates, key[i])
         _, vjp = jax.vjp(
             lambda x, a, b, g: _pr49_pass_rows(x, a, b, g, valid[i],
                                                group_sizes[i]),
@@ -1164,7 +1176,7 @@ _pr49_run_passes.defvjp(_pr49_run_passes_fwd, _pr49_run_passes_bwd)
 
 
 def _pr49_routed_experts(u, ell, p, *, top_k, held, scaling):
-    _, pairs, filled = moe._dispatch(u, p, top_k, held, scaling)
+    _, pairs, filled = pr50.dispatch(u, p, top_k, held, scaling)
     return _pr49_run_passes(ell, p["w1"], p["w2"], pairs.gates, pairs.key,
                             pairs.valid, pairs.group_sizes, filled)
 
@@ -1206,3 +1218,112 @@ def test_latent_moe_is_the_parents_with_the_new_arguments_at_their_defaults(
                      cfg.routed_scaling, cfg.held, 0.5)
     np.testing.assert_array_equal(np.asarray(plain[1]), np.asarray(zero[1]))
     assert float(jnp.max(some[1] / plain[1])) < 1.0
+
+
+# --------------------------------------------------------------------------- #
+# The pass that runs alone (PR 51): the first pass stands outside the loop and
+# writes what it makes, further passes add to it in float32 inside a loop a
+# one-pass batch never enters, and a row's gate comes out of the pairs' sort
+# beside its key.
+# The pairs and the passes as they stood at PR 50 are frozen in
+# tests/moe_pr50_passes.py; whatever the passes a batch fills, the new ones
+# are theirs bit for bit.
+# --------------------------------------------------------------------------- #
+
+_FORMS = {"relu2": moe.RELU2_EXPERT, "gated": moe.GATED_EXPERT}
+# the share of the [T, held] membership that is true, by the passes it fills
+_DENSITY = {0: 0.0, 1: 0.12, 2: 0.3, 3: 0.42}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("n", list(_DENSITY))
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_the_pairs_and_the_passes_are_the_parents_bit_for_bit(form, n, dtype):
+    """Value, ``d_ell``, every ``d_ws`` and ``d_gates`` back at ``route``'s
+    [T, held], for both kinds of expert and a batch that fills no pass, one
+    (the peeled pass alone: its weight gradients the kernel's own outputs),
+    two and three (the float32 sums started from the first pass's)."""
+    T, held, rows, width, d_expert = 96, 4, 64, 8, 12
+    passes = T * held // rows
+    rng = np.random.default_rng(51 + n)
+    here = jnp.asarray(rng.random((T, held)) < _DENSITY[n])
+    assert -(-int(here.sum()) // rows) == n
+    k = iter(jax.random.split(jax.random.PRNGKey(51), 6))
+    gates = jax.random.uniform(next(k), (T, held), minval=0.2)
+    ell = jax.random.normal(next(k), (T, width)).astype(dtype)
+    shapes = {"w1": (held, width, d_expert), "w3": (held, width, d_expert),
+              "w2": (held, d_expert, width)}
+    ws = tuple(jax.random.normal(next(k), shapes[w]).astype(dtype)
+               for w in _FORMS[form])
+    w = jax.random.normal(next(k), (T, width))
+
+    def graded(routed):
+        def loss(ell, ws, gates):
+            r = routed(ell, ws, gates)
+            return jnp.sum(jnp.sin(r) * w), r
+        # (operation by operation, each rounding as it does alone: what is
+        # compared is the arithmetic the two programs state, not how a
+        # compiler fuses a pass outside a loop and the same pass inside one —
+        # the CPU's skips a bfloat16 rounding between two float32 operations
+        # and orders a row's sum by the fusion it sits in)
+        with jax.disable_jit():
+            return jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
+                ell, ws, gates)
+
+    def now(ell, ws, gates):
+        pairs = moe.held_pairs(here, gates, rows, passes)
+        return moe._run_passes(ell, ws, pairs.gates, pairs.gate_rows,
+                               pairs.key, pairs.valid, pairs.group_sizes,
+                               -(-jnp.sum(pairs.per_expert) // rows))
+
+    def then(ell, ws, gates):
+        pairs = pr50.held_pairs(here, gates, rows, passes)
+        return pr50.run_passes(ell, ws, pairs.gates, pairs.key, pairs.valid,
+                               pairs.group_sizes,
+                               -(-jnp.sum(pairs.per_expert) // rows))
+
+    got, want = graded(now), graded(then)
+    assert got[0][1].dtype == jnp.float32
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    _, d_ws, d_gates = want[1]
+    assert (float(jnp.abs(d_gates).max()) > 0) == (n > 0)
+    assert (float(jnp.abs(d_ws[0].astype(jnp.float32)).max()) > 0) == (n > 0)
+    # a gate's cotangent went back to its own place and nowhere else
+    np.testing.assert_array_equal(np.asarray(got[1][2] != 0) & ~np.asarray(here),
+                                  False)
+
+
+def test_the_gates_ride_the_sort_and_no_pass_looks_one_up():
+    """The passes and their backward as they are traced: ONE sort, of two
+    operands by one key — the gates beside the keys, as numbers: AD's rule
+    for a sort, which adds an iota operand and gathers by it, is not used —
+    and no gather out of the flat [held · T] table of gates, forward or
+    backward; the gates' cotangent goes back by the scatter-add a pass."""
+    T, held, rows, width, d_expert = 96, 4, 64, 8, 12
+    passes = T * held // rows
+    here = jnp.asarray(np.random.default_rng(0).random((T, held)) < 0.3)
+    ell = jnp.ones((T, width))
+    ws = (jnp.ones((held, width, d_expert)), jnp.ones((held, d_expert, width)))
+
+    def f(ell, ws, gates):
+        pairs = moe.held_pairs(here, gates, rows, passes)
+        return jnp.sum(moe._run_passes(
+            ell, ws, pairs.gates, pairs.gate_rows, pairs.key, pairs.valid,
+            pairs.group_sizes, -(-jnp.sum(pairs.per_expert) // rows)))
+
+    jaxpr = jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(
+        ell, ws, jnp.ones((T, held))).jaxpr
+    sorts, gathers = [], []
+    _count(jaxpr, lambda e: e.primitive.name == "sort" and sorts.append(e))
+    _count(jaxpr, lambda e: e.primitive.name == "gather" and gathers.append(e))
+    assert [(len(e.invars), e.params["num_keys"]) for e in sorts] == [(2, 1)]
+    assert gathers and all(e.invars[0].aval.shape[-1] == width
+                           for e in gathers), gathers
+    scalars = lambda e: (e.primitive.name == "scatter-add"
+                         and e.invars[0].aval.shape == (T * held,))
+    # one in the pass that runs alone, one in the loop's body
+    assert _count(jaxpr, scalars) == 2

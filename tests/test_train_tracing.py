@@ -50,7 +50,7 @@ FLASH_KERNELS = (names.FLASH_FWD_KERNEL, names.FLASH_BWD_KERNEL)
 SSD_KERNELS = (names.SSD_CHUNK_FWD_KERNEL, names.SSD_CHUNK_BWD_KERNEL)
 NEMOTRON_SCOPES = (names.MAMBA, names.SSD_SCAN, names.MOE_ROUTED,
                    names.MOE_DISPATCH, names.MOE_LATENT, names.MOE_SHARED,
-                   names.MTP)
+                   names.MTP, names.MOE_FURTHER_PASSES)
 SPARSE_KERNELS = (names.SPARSE_ATTN_FWD_KERNEL, names.SPARSE_ATTN_BWD_DQ_KERNEL,
                   names.SPARSE_ATTN_BWD_DKV_KERNEL)
 SALA_SCOPES = (names.LIGHTNING_ATTN, names.SPARSE_ATTENTION,
@@ -69,13 +69,13 @@ HYBRID_SCOPES = tuple(s for s in names.SCOPES if s != names.LN2
 # every layer of LFM2-MoE is an operator AND a feed-forward half: the block's
 # six scopes (`mlp` in the dense layer, `moe` with the shared dispatch in the
 # expert layers), the flash kernels' and the short convolution's
-LFM2_SCOPES = DENSE_SCOPES + (names.MOE, names.MOE_ROUTED, names.MOE_DISPATCH
-                              ) + LFM2_OWN_SCOPES
+LFM2_SCOPES = DENSE_SCOPES + (names.MOE, names.MOE_ROUTED, names.MOE_DISPATCH,
+                              names.MOE_FURTHER_PASSES) + LFM2_OWN_SCOPES
 LFM2_RESIDUALS = (names.RES_CONV_BCX, names.RES_Q, names.RES_K, names.RES_V,
                   names.RES_FLASH_O, names.RES_FLASH_LSE, names.RES_MID,
                   names.RES_MLP_GATE, names.RES_MLP_UP, names.RES_MOE_SCORES,
                   names.RES_MOE_KTH, names.RES_MOE_LAST,
-                  names.RES_MOE_PAIR_KEY)
+                  names.RES_MOE_PAIR_KEY, names.RES_MOE_PAIR_GATE)
 # every layer of MiniCPM-SALA is a mixer AND a SwiGLU MLP: the block's six
 # scopes, its two mixers' and the scan's; the sparse branch runs no flash
 # kernel
@@ -157,6 +157,7 @@ def test_scope_in_lowered_nemotron_step(scope):
     op_names, _ = _lowering("nemotron")
     assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
     inside = {names.SSD_SCAN: names.MAMBA, names.MOE_DISPATCH: names.MOE_ROUTED,
+              names.MOE_FURTHER_PASSES: names.MOE_ROUTED,
               names.MOE_ROUTED: names.MOE, names.MOE_LATENT: names.MOE,
               names.MOE_SHARED: f"{names.MOE}/{names.MLP}",
               names.MAMBA: names.BLOCK, names.MOE: names.BLOCK}
@@ -193,6 +194,7 @@ def test_scope_in_lowered_lfm2_step(scope):
     inside = {names.CONV_GATE: names.SHORT_CONV,
               names.SHORT_CONV: names.BLOCK,
               names.MOE_DISPATCH: names.MOE_ROUTED,
+              names.MOE_FURTHER_PASSES: names.MOE_ROUTED,
               names.MOE_ROUTED: names.MOE, names.MOE: names.BLOCK}
     if scope in inside:
         assert _has_scope(op_names, f"{inside[scope]}/{scope}")
