@@ -31,7 +31,10 @@ check_training/check_device) and, after ``shutdown()``, prints from the
 session's record (``ray_tpu.timeline()``, PR 35) the phases of the ``fit()``
 trace, the worker processes started and reaped and the ``train/compile``
 events of the run — failing if ``train/fit`` or ``train/loop_entered`` is not
-in it (``session_story``).
+in it (``session_story``) — and, beside each toy expert run's set-up
+``model/expert_load`` lines, what its step said of itself at run time: the
+``train/step_counters`` event the record holds for it (PR 52; fails where a
+step with an expert layer left none: ``step_load_line``).
 
 This process never initialises a JAX backend: a chip belongs to one process
 and that process is the train worker, so every device fact below travelled
@@ -284,6 +287,7 @@ def train_loop(config: Dict[str, Any]) -> None:
                   "layer_pattern": layer_pattern_decisions(),
                   "ssd_tiling": ssd_tiling_decisions(),
                   "expert_load": load,
+                  "step_load": np.asarray(m["counters"]).tolist(),
                   "chosen_rows_off": chosen_rows_off(config["seed"])}
         del variant
     # One step of a linear / block-sparse attention hybrid through the same
@@ -343,7 +347,8 @@ def train_loop(config: Dict[str, Any]) -> None:
                 "remat_policy": [d for d in remat_policy_decisions()
                                  if d["n_layer"] == lfm2_cfg.n_layer
                                  and d["seq"] == lfm2_cfg.seq_len],
-                "expert_load": load}
+                "expert_load": load,
+                "step_load": np.asarray(m["counters"]).tolist()}
         del variant
     jax.monitoring.unregister_event_listener(on_event)
 
@@ -620,6 +625,33 @@ def session_story(trace: List[Dict[str, Any]]) -> Tuple[List[str], List[str]]:
     return lines, failures
 
 
+def step_load_line(trace: List[Dict[str, Any]], step: Dict[str, Any],
+                   what: str) -> Tuple[List[str], List[str]]:
+    """(lines, failures): the ``train/step_counters`` event in the session's
+    record that holds what ``step`` — a toy run with expert layers — said of
+    itself (its ``metrics["counters"]``, rows of names.STEP_EXPERT_LOAD_ARGS):
+    the step's own load, to read beside set-up's ``model/expert_load``."""
+    from ray_tpu.tracing import names
+
+    for e in trace:
+        args = e.get("args") or {}
+        if (f"{e.get('cat')}/{e.get('name')}" == names.TRAIN_STEP_COUNTERS
+                and [list(row) for row in zip(*(
+                    args[f] for f in names.STEP_EXPERT_LOAD_ARGS))]
+                == step["step_load"]):
+            return [f"{what} step {args['step']} said of itself "
+                    f"({args['kind']}, recorded "
+                    f"{e['ts'] / 1e6 - args['t_dispatch']:.2f} s after its "
+                    f"dispatch): layers {args['layers']} ran "
+                    f"{args['passes']} pass(es) over a buffer of "
+                    f"{args['buffer_rows']} rows for {args['pairs']} pairs "
+                    f"on the {args['held']} held experts (fullest "
+                    f"{args['max_per_expert']})"], []
+    return [], [f"the {what} step ran {len(step['step_load'])} expert layers "
+                "and the session's record holds no train/step_counters "
+                "event with its load"]
+
+
 def print_pattern_and_scan(step: Dict[str, Any]) -> None:
     """A pattern step's `model/layer_pattern` and `ops/ssd_tiling` events."""
     for d in step["layer_pattern"]:
@@ -694,8 +726,13 @@ def main() -> int:
         failures.append("the driver process initialised a JAX backend")
     # the record the session left behind: asked after shutdown(), so it
     # starts nothing and holds the teardown too
-    story, missing = session_story(ray_tpu.timeline())
+    record = ray_tpu.timeline()
+    story, missing = session_story(record)
     failures += missing
+    step_loads = {}
+    for what, key in (("hybrid", "hybrid"), ("LFM2-MoE", "lfm2")):
+        step_loads[key], missing = step_load_line(record, summary[key], what)
+        failures += missing
 
     print(f"device: platform={','.join(summary['platforms'])} "
           f"device_kind={summary['device_kind']!r} "
@@ -747,6 +784,7 @@ def main() -> int:
               f"{e['buffer_passes']} pass(es) over a buffer of "
               f"{e['buffer_rows']} rows ({e['buffer_fill']:.3f} full), "
               f"dropped {e['pairs_dropped']}")
+    print("\n".join(step_loads["hybrid"]))
     print(f"chosen set as a mask against lax.top_k's list, "
           f"{ROUTER_SHAPE[0]}x{ROUTER_SHAPE[1]} scores with ties, top "
           f"{ROUTER_TOP_K}: {hybrid['chosen_rows_off']} rows differ")
@@ -787,6 +825,7 @@ def main() -> int:
               f"{e['mean_per_expert']:.1f} an expert), {e['buffer_passes']} "
               f"pass(es) over a buffer of {e['buffer_rows']} rows, dropped "
               f"{e['pairs_dropped']}")
+    print("\n".join(step_loads["lfm2"]))
     print(f"LFM2-MoE step ({lfm2_cfg.pattern} of {lfm2_cfg.d_model}, "
           f"{summary['device_count']}x{lfm2['seq_len']} tokens, remat): loss "
           f"{lfm2['loss']:.4f} grad_norm {lfm2['grad_norm']:.4f}")

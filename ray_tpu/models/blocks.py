@@ -20,6 +20,7 @@ from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
                     Tuple)
 
 import jax
+import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.tracing import get_buffer, names as scopes
@@ -248,7 +249,11 @@ def run_pattern(block_fns: Dict[str, Callable], pattern: str, x,
 
     ``with_aux``: every block function returns ``(x, aux)`` and the result is
     ``(x, auxes)``, ``auxes[g][i]`` the aux of the i-th layer of run g's
-    sub-pattern (stacked over the repeats where the run is a scan)."""
+    sub-pattern (stacked over the repeats where the run is a scan). The seam
+    serves the training forward too: a checkpoint_kinds block may return an
+    aux, and one of integers (a layer's counters) costs the backward nothing
+    — a ``checkpoint``'s integer outputs and a scan's integer ``ys`` need no
+    residual and have no cotangent."""
     auxes = []
     for (sub, reps), group in zip(pattern_groups(pattern), stacks, strict=True):
         per_rep = {kind: sub.count(kind) for kind in dict.fromkeys(sub)}
@@ -276,6 +281,35 @@ def run_pattern(block_fns: Dict[str, Callable], pattern: str, x,
             x, aux = body(x, jax.tree.map(lambda a: a[0], xs))
         auxes.append(aux)
     return (x, auxes) if with_aux else x
+
+
+class StepCounters(NamedTuple):
+    """What a model's compiled step says of itself every step, as its module's
+    ``step_counters(cfg)`` states it (None, or no such function: nothing):
+    the step's ``metrics["counters"]`` is ONE int32 array [layers, fields] —
+    packed_aux of the aux its loss hands out beside the loss — and the
+    ``train/step_counters`` event a step (tracing/step_counters.py) is this,
+    decoded."""
+    kind: str                       # what a row is (names.EXPERT_LOAD_KIND)
+    fields: Tuple[str, ...]         # the columns, in order
+    layers: Tuple[int, ...]         # the rows: published ids of the layers
+    static: Callable[[int], Dict[str, int]]   # a batch's tokens → what every
+                                    # step's event says besides (sizes that
+                                    # the numbers are read against)
+
+
+def packed_aux(auxes: Sequence[Sequence[Any]], fields: Sequence[str]):
+    """run_pattern's ``auxes`` (several patterns' joined, in the order they
+    ran) as one int32 array [layers with an aux, len(fields)], the layers in
+    the order they are applied: a layer's aux is a dict of int32 scalars
+    (stacked over a scan's repeats) or None."""
+    rows = []
+    for aux in auxes:
+        have = [jnp.stack([a[f] for f in fields], axis=-1)
+                for a in aux if a is not None]
+        if have:        # [repeats, layers of the sub-pattern, fields], or one
+            rows.append(jnp.stack(have, axis=-2).reshape(-1, len(fields)))
+    return jnp.concatenate(rows).astype(jnp.int32)
 
 
 def record_layer_pattern(pattern: str) -> None:

@@ -382,18 +382,21 @@ def _experts(x, p, cfg: LFM2MoEConfig, aux: Optional[str]):
     elif aux == "chosen":
         out = moe.chosen_experts(ht, p, cfg.top_k)
     with jax.named_scope(scopes.MOE):
-        f = moe.gated_moe(h, p, **_routing(cfg))
-    return parts.residual_add(x, f), out
+        f, load = moe.gated_moe(h, p, **_routing(cfg))
+    return parts.residual_add(x, f), load if aux == "load" else out
 
 
 @jax.named_scope(scopes.BLOCK)
 def _layer(x, p, cfg: LFM2MoEConfig, kind: str, aux: Optional[str] = None):
     """One layer of ``kind``, x [B, S, D]: the operator's residual, then the
-    feed-forward half's. With ``aux`` (a forward of its own, no backward) the
-    result is (x, aux's value): ``"balance"`` — an expert layer first
-    balances its selection bias on this input; the bias and what the input
-    then sends the held experts (moe.held_load) —, ``"chosen"`` — the set
-    each token chose, [T, n_experts] bool —; None for a dense layer."""
+    feed-forward half's. With ``aux`` the result is (x, aux's value), None
+    for a dense layer: ``"load"`` — what the batch sends the held experts,
+    as the dispatch that runs the passes has it (moe.routed_experts; the
+    training forward's) —; in a forward of its own, no backward,
+    ``"balance"`` — an expert layer first balances its selection bias on
+    this input; the bias and what the input then sends the held experts
+    (moe.held_load) —, ``"chosen"`` — the set each token chose, [T,
+    n_experts] bool."""
     p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype, _matmul_weights(kind))}
     with jax.named_scope(scopes.LN1):
         u = parts.rmsnorm(x, p["op_norm"], cfg.rms_eps)
@@ -527,13 +530,14 @@ def _layer_bytes(cfg: LFM2MoEConfig, kind: str) -> int:
                for p in jax.tree.leaves(layer))
 
 
-def _block_fns(cfg: LFM2MoEConfig, batch: int, seq: int):
+def _block_fns(cfg: LFM2MoEConfig, batch: int, seq: int,
+               aux: Optional[str] = None):
     from ray_tpu.parallel import mesh as mesh_lib
 
     base, kinds = kind_shards(cfg, batch, seq, mesh_lib.current_mesh())
     blocks.record_layer_pattern(cfg.pattern)
     return blocks.checkpoint_kinds(
-        {kind: partial(_layer, cfg=cfg, kind=kind) for kind in kinds},
+        {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux) for kind in kinds},
         cfg.remat, base, kinds, blocks.pattern_groups(cfg.pattern))
 
 
@@ -543,11 +547,11 @@ def _trunk(params, tokens, cfg: LFM2MoEConfig, aux: Optional[str] = None):
     B, S = tokens.shape
     with jax.named_scope(scopes.EMBED):
         x = params["wte"].astype(cfg.dtype)[tokens]
-    if aux:             # a forward of its own: no backward, no checkpoint
+    if aux in (None, "load"):       # the training forward, checkpointed
+        fns = _block_fns(cfg, B, S, aux)
+    else:               # a forward of its own: no backward, no checkpoint
         fns = {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux)
                for kind in KINDS}
-    else:
-        fns = _block_fns(cfg, B, S)
     out = blocks.run_pattern(fns, cfg.pattern, x, params["blocks"],
                              with_aux=bool(aux))
     x, auxes = out if aux else (out, None)
@@ -562,10 +566,34 @@ def forward(params, tokens, cfg: LFM2MoEConfig) -> jax.Array:
     return jnp.einsum("bsd,vd->bsv", x, params["wte"].astype(cfg.dtype))
 
 
-def loss_fn(params, tokens, targets, cfg: LFM2MoEConfig) -> jax.Array:
-    """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token)."""
-    x = _trunk(params, tokens, cfg)
-    return parts.lm_head_loss(x, targets, params["wte"].T, cfg.dtype)
+def loss_fn(params, tokens, targets, cfg: LFM2MoEConfig,
+            counters: bool = False):
+    """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token).
+    With ``counters`` (what step_counters offers a step factory: the aux of
+    its ``value_and_grad``) the result is (the loss, what the batch sent each
+    expert layer's held experts: int32 [expert layers, fields], in the
+    layers' order)."""
+    x = _trunk(params, tokens, cfg, "load" if counters else None)
+    if counters:
+        x, auxes = x
+    loss = parts.lm_head_loss(x, targets, params["wte"].T, cfg.dtype)
+    if not counters:
+        return loss
+    return loss, blocks.packed_aux(auxes, scopes.STEP_EXPERT_LOAD_ARGS)
+
+
+def step_counters(cfg: LFM2MoEConfig) -> Optional[blocks.StepCounters]:
+    """What ``loss_fn(..., counters=True)`` hands out of a step, or None for
+    a pattern without an expert layer. A layer's id is ``model/expert_load``'s
+    ``layer``: the published index."""
+    layers = tuple(cfg.first_layer + index
+                   for index, _, _ in _expert_layers(cfg.pattern))
+    if not layers:
+        return None
+    return blocks.StepCounters(
+        scopes.EXPERT_LOAD_KIND, scopes.STEP_EXPERT_LOAD_ARGS, layers,
+        partial(moe.step_load_static, n_experts=cfg.n_experts,
+                top_k=cfg.top_k, held=cfg.held))
 
 
 def flops_per_token(cfg: LFM2MoEConfig) -> float:

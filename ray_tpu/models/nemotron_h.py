@@ -36,7 +36,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -286,13 +286,15 @@ def _shared_rows(cfg: NemotronHConfig, batch: int, seq: int) -> int:
 
 
 @jax.named_scope(scopes.BLOCK)
-def _layer(x, p, cfg: NemotronHConfig, kind: str, balance: bool = False):
+def _layer(x, p, cfg: NemotronHConfig, kind: str, aux: Optional[str] = None):
     """One layer of ``kind``: x + f_kind(RMSNorm(x)), x [B, S, D]. With
-    ``balance`` (set-up's forward, balance_router_bias) an expert layer
-    first balances its selection bias on this input, and the result is (x,
-    aux): the bias and what the input then sends the held experts
-    (moe.held_load) for an expert layer, None for the others."""
-    aux = None
+    ``aux`` the result is (x, aux's value), None for a layer that is no
+    expert layer: ``"load"`` — what the batch sends the held experts, as the
+    dispatch that runs the passes has it (moe.routed_experts; the training
+    forward's) —, ``"balance"`` (set-up's forward, balance_router_bias) — an
+    expert layer first balances its selection bias on this input; the bias
+    and what the input then sends the held experts (moe.held_load)."""
+    out = None
     weights = {"M": mamba2.MATMUL_WEIGHTS, "E": moe.LATENT_MOE_MATMUL_WEIGHTS,
                "*": _ATTN_WEIGHTS}[kind]
     p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype, weights)}
@@ -306,16 +308,18 @@ def _layer(x, p, cfg: NemotronHConfig, kind: str, balance: bool = False):
     elif kind == "E":
         routing = dict(top_k=cfg.top_k, held=cfg.held,
                        scaling=cfg.routed_scaling)
-        if balance:
+        if aux == "balance":
             ut = u.reshape(-1, u.shape[-1])
             bias = moe.balance_bias(ut, p["router_w"], p["router_bias"],
                                     cfg.top_k)
             p = {**p, "router_bias": bias}
-            aux = {"router_bias": bias, **moe.held_load(ut, p, **routing)}
+            out = {"router_bias": bias, **moe.held_load(ut, p, **routing)}
         with jax.named_scope(scopes.MOE):
-            y = moe.latent_moe(
+            y, load = moe.latent_moe(
                 u, p, **routing,
                 shared_rows=_shared_rows(cfg, x.shape[0], x.shape[1]))
+        if aux == "load":
+            out = load
     else:
         with jax.named_scope(scopes.QKV):
             q = checkpoint_name(
@@ -330,7 +334,7 @@ def _layer(x, p, cfg: NemotronHConfig, kind: str, balance: bool = False):
             y = jnp.einsum("bhsk,hkd->bsd", o, p["wo"],
                            preferred_element_type=jnp.float32)
     x = parts.residual_add(x, y)
-    return (x, aux) if balance else x
+    return (x, out) if aux else x
 
 
 def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
@@ -443,38 +447,40 @@ def _layer_bytes(cfg: NemotronHConfig, kind: str) -> int:
                for p in jax.tree.leaves(layer))
 
 
-def _block_fns(cfg: NemotronHConfig, batch: int, seq: int):
+def _block_fns(cfg: NemotronHConfig, batch: int, seq: int,
+               aux: Optional[str] = None):
     from ray_tpu.parallel import mesh as mesh_lib
 
     base, kinds = kind_shards(cfg, batch, seq, mesh_lib.current_mesh())
     for pattern in filter(None, (cfg.pattern, cfg.mtp_pattern)):
         blocks.record_layer_pattern(pattern)
     return blocks.checkpoint_kinds(
-        {kind: partial(_layer, cfg=cfg, kind=kind) for kind in kinds},
+        {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux) for kind in kinds},
         cfg.remat, base, kinds,
         blocks.pattern_groups(cfg.pattern)
         + blocks.pattern_groups(cfg.mtp_pattern))
 
 
 def _hidden(params, tokens, targets, cfg: NemotronHConfig,
-            balance: bool = False):
+            aux: Optional[str] = None):
     """tokens [B, S] → (the trunk's stream before the final norm, the MTP
-    module's — None without one —, the MTP targets, and with ``balance`` the
-    layers' aux (_layer): the trunk's, then the MTP module's)."""
+    module's — None without one —, the MTP targets, and with ``aux`` the
+    layers' (_layer says what; blocks.run_pattern's auxes): the trunk's,
+    then the MTP module's)."""
     B, S = tokens.shape
     wte = params["wte"].astype(cfg.dtype)
     with jax.named_scope(scopes.EMBED):
         x = wte[tokens]
-    if balance:      # a forward for set-up: no backward, nothing to checkpoint
-        block_fns = {kind: partial(_layer, cfg=cfg, kind=kind, balance=True)
+    if aux == "balance":     # set-up's forward: no backward, no checkpoint
+        block_fns = {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux)
                      for kind in KINDS}
     else:
-        block_fns = _block_fns(cfg, B, S)
+        block_fns = _block_fns(cfg, B, S, aux)
 
     def run(pattern, x, stacks):
         out = blocks.run_pattern(block_fns, pattern, x, stacks,
-                                 with_aux=balance)
-        return out if balance else (out, None)
+                                 with_aux=bool(aux))
+        return out if aux else (out, None)
 
     x, aux = run(cfg.pattern, x, params["blocks"])
     if not cfg.mtp_pattern:
@@ -514,24 +520,54 @@ def forward(params, tokens, cfg: NemotronHConfig) -> jax.Array:
                       params["lm_head"].astype(cfg.dtype))
 
 
-def losses(params, tokens, targets, cfg: NemotronHConfig):
-    """(the trunk's mean cross-entropy, the MTP module's or 0.0)."""
-    x, h, mtp_targets, _ = _hidden(params, tokens, targets, cfg)
+def _losses(params, tokens, targets, cfg: NemotronHConfig,
+            aux: Optional[str] = None):
+    """losses, and _hidden's auxes of ``aux``."""
+    x, h, mtp_targets, auxes = _hidden(params, tokens, targets, cfg, aux)
     trunk = parts.lm_head_loss(_final_norm(x, params, cfg), targets,
                                params["lm_head"], cfg.dtype)
     if h is None:
-        return trunk, jnp.zeros((), jnp.float32)
+        return trunk, jnp.zeros((), jnp.float32), auxes
     with jax.named_scope(scopes.MTP):
         return trunk, parts.lm_head_loss(_final_norm(h, params, cfg),
                                          mtp_targets, params["lm_head"],
-                                         cfg.dtype)
+                                         cfg.dtype), auxes
 
 
-def loss_fn(params, tokens, targets, cfg: NemotronHConfig) -> jax.Array:
+def losses(params, tokens, targets, cfg: NemotronHConfig):
+    """(the trunk's mean cross-entropy, the MTP module's or 0.0)."""
+    return _losses(params, tokens, targets, cfg)[:2]
+
+
+def loss_fn(params, tokens, targets, cfg: NemotronHConfig,
+            counters: bool = False):
     """CE_trunk + mtp_loss_weight · CE_mtp over targets >= 0 ([B, S] int32,
-    the next token)."""
-    trunk, mtp = losses(params, tokens, targets, cfg)
-    return trunk + cfg.mtp_loss_weight * mtp
+    the next token). With ``counters`` (what step_counters offers a step
+    factory: the aux of its ``value_and_grad``) the result is (the loss, what
+    the batch sent each expert layer's held experts: int32 [expert layers,
+    fields], the trunk's layers and then the MTP module's)."""
+    trunk, mtp, (aux, mtp_aux) = _losses(
+        params, tokens, targets, cfg, "load" if counters else None)
+    loss = trunk + cfg.mtp_loss_weight * mtp
+    if not counters:
+        return loss
+    return loss, blocks.packed_aux(aux + (mtp_aux or []),
+                                   scopes.STEP_EXPERT_LOAD_ARGS)
+
+
+def step_counters(cfg: NemotronHConfig) -> Optional[blocks.StepCounters]:
+    """What ``loss_fn(..., counters=True)`` hands out of a step, or None for
+    a pattern without an expert layer. A layer's id is ``model/expert_load``'s
+    ``layer``: its place among the expert layers, the MTP module's after the
+    trunk's."""
+    layers = (cfg.pattern + cfg.mtp_pattern).count("E")
+    if not layers:
+        return None
+    return blocks.StepCounters(
+        scopes.EXPERT_LOAD_KIND, scopes.STEP_EXPERT_LOAD_ARGS,
+        tuple(range(layers)),
+        partial(moe.step_load_static, n_experts=cfg.n_experts,
+                top_k=cfg.top_k, held=cfg.held))
 
 
 def flops_per_token(cfg: NemotronHConfig) -> float:
@@ -598,7 +634,7 @@ def balance_router_bias(params, tokens, targets, cfg: NemotronHConfig):
     (tracing/names.EXPERT_LOAD_ARGS), recorded here, the trunk's expert
     layers first, then the MTP module's. For set-up, on the first batch."""
     trunk, mtp = jax.device_get(jax.jit(
-        lambda p, tok, tgt: _hidden(p, tok, tgt, cfg, balance=True)[3])(
+        lambda p, tok, tgt: _hidden(p, tok, tgt, cfg, "balance")[3])(
         params, tokens, targets))
     stacks, loads = _balanced(cfg.pattern, params["blocks"], trunk)
     params = {**params, "blocks": stacks}

@@ -589,7 +589,9 @@ _run_passes.defvjp(_run_passes_fwd, _run_passes_bwd)
 
 def _dispatch(u, p, top_k: int, held: Held, scaling: float, eps: float = 0.0):
     """Route u [T, D] and lay the pairs on held experts over the row buffer:
-    (the membership [T, held], the HeldPairs, the passes they fill)."""
+    (the membership [T, held], the HeldPairs, the layer's load — int32
+    scalars under tracing/names.STEP_EXPERT_LOAD_ARGS: the passes the pairs
+    fill, the pairs landed here, the fullest held expert's)."""
     T = u.shape[0]
     n_experts = p["router_w"].shape[-1]
     rows = row_buffer(T, n_experts, top_k, held.count)
@@ -597,22 +599,28 @@ def _dispatch(u, p, top_k: int, held: Held, scaling: float, eps: float = 0.0):
                         held, eps)
     pairs = held_pairs(here, gates, rows,
                        buffer_passes(T, n_experts, top_k, held.count))
-    return here, pairs, -(-jnp.sum(pairs.per_expert) // rows)
+    landed = jnp.sum(pairs.per_expert)
+    return here, pairs, dict(zip(scopes.STEP_EXPERT_LOAD_ARGS, (
+        -(-landed // rows), landed, jnp.max(pairs.per_expert))))
 
 
 @jax.named_scope(scopes.MOE_ROUTED)
 def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
                    top_k: int, held: Held, scaling: float, eps: float = 0.0,
-                   form: Tuple[str, ...] = RELU2_EXPERT) -> jax.Array:
+                   form: Tuple[str, ...] = RELU2_EXPERT
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The held experts' part of the routed result, in the width the experts
     read and write: u [T, D] (what the router reads), ell [T, width] (what
-    the experts read: a latent, or u itself) → r [T, width] float32 = Σ over
+    the experts read: a latent, or u itself) → (r [T, width] float32 = Σ over
     a token's chosen AND held experts of gate · f_e(ell), f_e by ``form``
-    (the names of the experts' weights in ``p``: _pass_rows). One pass over
-    the row buffer where the batch's pairs fit it (row_buffer); a batch with
-    more runs the further passes it fills."""
+    (the names of the experts' weights in ``p``: _pass_rows), and what the
+    batch sent the held experts: _dispatch's load, three int32 scalars that
+    were there to run the passes — for whoever hands them out of the step; a
+    caller that drops them has paid nothing). One pass over the row buffer
+    where the batch's pairs fit it (row_buffer); a batch with more runs the
+    further passes it fills."""
     with jax.named_scope(scopes.MOE_DISPATCH):
-        _, pairs, filled = _dispatch(u, p, top_k, held, scaling, eps)
+        _, pairs, load = _dispatch(u, p, top_k, held, scaling, eps)
     # (each weight row-major as it enters the passes: the backward's grouped
     # products read two of them transposed, and the first pass stands
     # outside any loop now, so without this the compiler lays the float32
@@ -622,24 +630,26 @@ def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
         p[w], Layout(major_to_minor=tuple(range(p[w].ndim)))) for w in form)
     return _run_passes(ell, ws, pairs.gates,
                        pairs.gate_rows, pairs.key, pairs.valid,
-                       pairs.group_sizes, filled)
+                       pairs.group_sizes, load["passes"]), load
 
 
 def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
-               scaling: float, shared_rows: int = 0) -> jax.Array:
-    """u [B, S, D] (normed, compute dtype) → the layer's output [B, S, D] in
+               scaling: float, shared_rows: int = 0
+               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """u [B, S, D] (normed, compute dtype) → (the layer's output [B, S, D] in
     float32: ``r·W_up`` for the held routed experts' r over ``u·W_down``,
-    plus the shared expert ``relu(u·S1)²·S2``. ``p`` holds one layer's
-    tensors, LATENT_MOE_MATMUL_WEIGHTS in the compute dtype. With
-    ``shared_rows`` < S the shared expert takes the sequence in chunks of
-    that many rows, each its own ``checkpoint`` (as models/llama.py's MLP,
-    and for its reason)."""
+    plus the shared expert ``relu(u·S1)²·S2``; routed_experts' load). ``p``
+    holds one layer's tensors, LATENT_MOE_MATMUL_WEIGHTS in the compute
+    dtype. With ``shared_rows`` < S the shared expert takes the sequence in
+    chunks of that many rows, each its own ``checkpoint`` (as
+    models/llama.py's MLP, and for its reason)."""
     B, S, D = u.shape
     ut = u.reshape(B * S, D)
     with jax.named_scope(scopes.MOE_LATENT):
         ell = checkpoint_name(jnp.einsum("td,dl->tl", ut, p["w_down"]),
                               scopes.RES_MOE_LATENT)
-    r = routed_experts(ut, ell, p, top_k=top_k, held=held, scaling=scaling)
+    r, load = routed_experts(ut, ell, p, top_k=top_k, held=held,
+                             scaling=scaling)
     with jax.named_scope(scopes.MOE_LATENT):
         out = jnp.einsum("tl,ld->td", r.astype(u.dtype), p["w_up"],
                          preferred_element_type=jnp.float32)
@@ -654,10 +664,10 @@ def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
                               preferred_element_type=jnp.float32)
 
     if shared_rows in (0, S):
-        return out.reshape(B, S, D) + shared(u)
+        return out.reshape(B, S, D) + shared(u), load
     chunks = u.reshape(B, S // shared_rows, shared_rows, D).swapaxes(0, 1)
     sh = lax.map(jax.checkpoint(shared), chunks)
-    return out.reshape(B, S, D) + sh.swapaxes(0, 1).reshape(B, S, D)
+    return out.reshape(B, S, D) + sh.swapaxes(0, 1).reshape(B, S, D), load
 
 
 def gated_moe_init(rng: jax.Array, n_layers: int, d_model: int,
@@ -692,17 +702,18 @@ def gated_moe_logical_axes() -> Dict[str, Any]:
 
 
 def gated_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
-              scaling: float, eps: float = 0.0) -> jax.Array:
-    """u [B, S, D] (normed, compute dtype) → the held experts' part of the
+              scaling: float, eps: float = 0.0
+              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """u [B, S, D] (normed, compute dtype) → (the held experts' part of the
     layer's output [B, S, D] in float32: Σ over a token's chosen AND held
     experts of gate · (silu(u·W1_e) ⊙ u·W3_e) · W2_e, at the model's width —
-    no latent around the experts, nothing beside them. ``p`` holds one
-    layer's tensors, GATED_EXPERT's in the compute dtype."""
+    no latent around the experts, nothing beside them; routed_experts' load).
+    ``p`` holds one layer's tensors, GATED_EXPERT's in the compute dtype."""
     B, S, D = u.shape
     ut = u.reshape(B * S, D)
-    r = routed_experts(ut, ut, p, top_k=top_k, held=held, scaling=scaling,
-                       eps=eps, form=GATED_EXPERT)
-    return r.reshape(B, S, D)
+    r, load = routed_experts(ut, ut, p, top_k=top_k, held=held,
+                             scaling=scaling, eps=eps, form=GATED_EXPERT)
+    return r.reshape(B, S, D), load
 
 
 def chosen_experts(u: jax.Array, p: Dict[str, Any], top_k: int) -> jax.Array:
@@ -713,17 +724,27 @@ def chosen_experts(u: jax.Array, p: Dict[str, Any], top_k: int) -> jax.Array:
                    + p["router_bias"].astype(jnp.float32), top_k)
 
 
+def step_load_static(tokens: int, n_experts: int, top_k: int,
+                     held: Held) -> Dict[str, int]:
+    """What every step's load of a layer is read against, for a batch of
+    ``tokens`` (tracing/names.EXPERT_LOAD_STATIC_ARGS): the rows one pass
+    over the buffer takes, the experts held."""
+    return dict(zip(scopes.EXPERT_LOAD_STATIC_ARGS, (
+        row_buffer(tokens, n_experts, top_k, held.count), held.count)))
+
+
 def held_load(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
               scaling: float, eps: float = 0.0) -> Dict[str, jax.Array]:
     """What a batch sends the held experts of one layer (u [T, D], the
     layer's normed input): the numbers of the ``model/expert_load`` event."""
-    here, pairs, filled = _dispatch(u, p, top_k, held, scaling, eps)
-    landed = jnp.sum(pairs.per_expert)
+    here, pairs, load = _dispatch(u, p, top_k, held, scaling, eps)
+    # (what a step hands out of itself, and from the same code)
+    filled, landed, fullest = (load[k] for k in scopes.STEP_EXPERT_LOAD_ARGS)
     rows = pairs.key.shape[1]
     return {
         "tokens": jnp.asarray(u.shape[0], jnp.int32),
         "pairs": landed,
-        "max_per_expert": jnp.max(pairs.per_expert),
+        "max_per_expert": fullest,
         "mean_per_expert": jnp.mean(pairs.per_expert.astype(jnp.float32)),
         "tokens_without_held_expert": jnp.sum(~jnp.any(here, axis=-1)),
         "buffer_rows": jnp.asarray(rows, jnp.int32),

@@ -27,6 +27,16 @@ Model
   and the core worker's periodic loops record their own spans through it;
   ``tracing/names.py`` is the vocabulary of those spans and of the scopes and
   kernel names the model puts on the device.
+- A step says what it did (PR 52). The step a factory returns
+  (``train/train_step.py``) opens ``ray_tpu:train/step`` (args ``step``)
+  around its jitted call, through ``profile_span`` like ``train/report``;
+  and a model that offers counters — the expert families': passes over the
+  row buffer, pairs landed on the held experts, the fullest expert, a layer
+  — returns them from the compiled step as one small array, which
+  ``tracing/step_counters.py`` records as ONE ``train/step_counters`` event a
+  step, with the same ``step``, once the device has made it: fetched by a
+  later step's call after its dispatch, or by the worker's loop thread
+  before ``train/loop_done``, never waited for on the loop's path.
 - Set-up and teardown are spans too, once an attempt, a split, a process or
   a session and never a step (``names.SETUP_SPANS``). Each attempt of
   ``DataParallelTrainer.fit()`` opens a trace of its own: ``train/fit`` and

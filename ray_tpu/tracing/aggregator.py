@@ -21,7 +21,10 @@ from the oldest:
 - **PROFILE events of one task** (``max_events_per_task``): counted a span
   name; beyond it that name's newest are dropped (``truncated_events``). A
   loop's ``train/compile`` and ``train/loop_done`` are not crowded out by
-  its ``data/get_block``. Lifecycle events are never truncated.
+  its ``data/get_block`` — nor by ``train/step_counters``, the one name that
+  arrives every step (PR 52): a long run keeps that name's first
+  ``max_events_per_task`` steps and counts the rest as truncated. Lifecycle
+  events are never truncated.
 - **Spans with no task** (the driver's, the raylet's): those named in
   ``tracing/names.SETUP_SPANS`` — once an attempt, a split, a process or a
   session — have a queue of their own (``max_setup_events``); every other

@@ -233,6 +233,13 @@ EXPERT_LOAD_ARGS = ("layer", "tokens", "pairs", "max_per_expert",
                     "mean_per_expert", "tokens_without_held_expert",
                     "buffer_rows", "buffer_passes", "buffer_fill",
                     "pairs_dropped")
+# ... and what EVERY step's batch sends them, out of the compiled step itself
+# (ops/moe.routed_experts returns it beside its result; PR 52): the passes
+# over the row buffer the step's pairs filled (EXPERT_LOAD's `buffer_passes`,
+# a value of the step: the trip count of ops/moe._run_passes), the pairs
+# landed and the fullest held expert's — what the dispatch has in hand, no
+# reduction over the tokens of its own
+STEP_EXPERT_LOAD_ARGS = ("passes", "pairs", "max_per_expert")
 
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer
@@ -243,7 +250,27 @@ DATA_DEVICE_PUT = "data/device_put"
 TRAIN_REPORT = "train/report"
 GC = "gc"
 BG = "bg"                        # `bg/<loop>`: one span a tick of a periodic loop
-SPANS = (DATA_GET_BLOCK, DATA_ASSEMBLE, DATA_DEVICE_PUT, TRAIN_REPORT, GC)
+# the call of the compiled step (train/train_step.make_train_step's
+# callable, around the jitted call: the enqueue, or the wait on a full
+# queue), args `step` = the callable's own count of calls
+TRAIN_STEP = "train/step"
+SPANS = (DATA_GET_BLOCK, DATA_ASSEMBLE, DATA_DEVICE_PUT, TRAIN_REPORT, GC,
+         TRAIN_STEP)
+# the ONE event a step: what the compiled step said it did (PR 52). A model
+# that offers counters (`step_counters(cfg)`: the expert families') returns
+# them from its step as one int32 array, `metrics["counters"]`; the step's
+# callable hands the array to tracing/step_counters.py, which records it —
+# when the device has made it, never waiting on the loop's path — as an
+# instant in the task-event buffer: `step` the TRAIN_STEP span's, `kind` what
+# the rows are ("expert_load": STEP_EXPERT_LOAD_ARGS), `t_dispatch` the
+# `time.time()` of the step's call (the event's own `ts` is when it was
+# recorded), `layers` the published ids of the layers that report, then one
+# list a field with an entry a layer, then the kind's static args (the row
+# buffer one pass takes, the experts held)
+TRAIN_STEP_COUNTERS = "train/step_counters"
+TRAIN_STEP_COUNTERS_ARGS = ("step", "kind", "t_dispatch", "layers")
+EXPERT_LOAD_KIND = "expert_load"
+EXPERT_LOAD_STATIC_ARGS = ("buffer_rows", "held")
 
 # ---- set-up and teardown (PR 35): spans recorded once an attempt, a split,
 # a process or a session — never a step. All on `time.time()`, in the

@@ -10,7 +10,9 @@ over a named mesh, with buffers donated so params update in place in HBM.
 from __future__ import annotations
 
 import math
+import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -21,7 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.models import gpt2
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel import sharding as sharding_lib
-from ray_tpu.tracing import names as scopes
+from ray_tpu.tracing import (PROFILE_MIN_DUR_S, names as scopes, profile_span,
+                             step_counters)
 
 
 @dataclass
@@ -133,12 +136,12 @@ def make_train_step(
         "opt_state": opt_state,
         "step": _step_counter(mesh),
     }
-    step_fn = jax.jit(
+    step_fn = _Step(jax.jit(
         step_given(_chip_memory(mesh, state)),
         in_shardings=(state_shardings, batch_shardings),
         out_shardings=(state_shardings, None),
         donate_argnums=(0,),
-    )
+    ), _offered_counters(model, cfg))
     return TrainStepBundle(
         state=state, step_fn=step_fn, mesh=mesh,
         data_sharding=batch_shardings["tokens"], cfg=cfg,
@@ -154,6 +157,65 @@ def make_gpt2_train_step(
 ) -> TrainStepBundle:
     """make_train_step for GPT-2 (models/gpt2.py)."""
     return make_train_step(gpt2, cfg, mesh, optimizer, rng, rules)
+
+
+def _offered_counters(model, cfg):
+    """What ``model``'s step says of itself every step (a
+    ``models.blocks.StepCounters``), or None: the module states it once, as
+    ``step_counters(cfg)``; most have nothing to say and no such function."""
+    offer = getattr(model, "step_counters", None)
+    return offer(cfg) if offer is not None else None
+
+
+class _Step:
+    """The step a factory returns: the jitted ``step(state, batch)`` under
+    the program's own span, its counters on their way to the record.
+
+    A call (a) opens ``ray_tpu:train/step`` (args ``step`` = this object's
+    count of calls) around the jitted call — the enqueue, or the wait on a
+    full queue: on the profiler's clock under a profiler session, in the
+    task-event buffer when it lasted ``PROFILE_MIN_DUR_S`` —; (b) where the
+    model offers counters, hands ``metrics["counters"]``, still being made,
+    to ``tracing.step_counters`` with the same ``step`` and the wall time of
+    the call, and lets it record what earlier steps' arrays are ready by now:
+    one small fetch, never a wait. Everything else is the jitted object's:
+    ``.lower``, ``.trace``, ``.eval_shape``, ``_cache_size`` pass through."""
+
+    def __init__(self, jitted, counters=None):
+        self._jitted = jitted
+        self._counters = counters
+        self._calls = 0
+        self._decoders: Dict[int, Callable] = {}     # by the batch's tokens
+
+    def __call__(self, state, batch):
+        self._calls = n = self._calls + 1
+        t_dispatch = time.time()
+        with profile_span("step", {"step": n}, component="train",
+                          min_dur_s=PROFILE_MIN_DUR_S):
+            out = self._jitted(state, batch)
+        if self._counters is not None:
+            tokens = batch["tokens"].size
+            decode = (self._decoders.get(tokens)
+                      or self._decoders.setdefault(tokens, self._decode(tokens)))
+            step_counters.watch(n, t_dispatch, out[1]["counters"], decode)
+            step_counters.drain()
+        return out
+
+    def _decode(self, tokens: int):
+        """Host array [layers, fields] → the event's args, for a batch of
+        ``tokens`` tokens."""
+        spec = self._counters
+        static = spec.static(tokens)
+
+        def decode(rows):
+            return {"kind": spec.kind, "layers": list(spec.layers),
+                    **{f: rows[:, i].tolist()
+                       for i, f in enumerate(spec.fields)}, **static}
+
+        return decode
+
+    def __getattr__(self, name):
+        return getattr(object.__getattribute__(self, "_jitted"), name)
 
 
 def _compose_step(model, cfg, mesh: Mesh, optimizer, rules: Optional[Dict]):
@@ -173,6 +235,12 @@ def _compose_step(model, cfg, mesh: Mesh, optimizer, rules: Optional[Dict]):
         "step": mesh_lib.replicated(mesh),
     }
     data_sh = mesh_lib.data_sharding(mesh, extra_dims=1)
+    # a model that offers counters hands them out beside its loss, and they
+    # leave the step as ONE output, `metrics["counters"]`; a model that
+    # offers none is asked exactly what it was always asked
+    with_counters = _offered_counters(model, cfg) is not None
+    loss_fn = (partial(model.loss_fn, counters=True) if with_counters
+               else model.loss_fn)
 
     def step_given(memory: Tuple[Optional[int], int]):
         def step(state, batch):
@@ -181,7 +249,8 @@ def _compose_step(model, cfg, mesh: Mesh, optimizer, rules: Optional[Dict]):
             # the mesh (ring attention wraps a shard_map over it), chip_memory
             # so its remat rule knows what the chip has free.
             with mesh_lib.use_mesh(mesh), mesh_lib.chip_memory(*memory):
-                loss, grads = jax.value_and_grad(model.loss_fn)(
+                out, grads = jax.value_and_grad(
+                    loss_fn, has_aux=with_counters)(
                     state["params"], tokens, targets, cfg
                 )
             new_params, new_opt, gnorm = _apply_optimizer(
@@ -191,7 +260,11 @@ def _compose_step(model, cfg, mesh: Mesh, optimizer, rules: Optional[Dict]):
                 "opt_state": new_opt,
                 "step": state["step"] + 1,
             }
-            return new_state, {"loss": loss, "grad_norm": gnorm}
+            if not with_counters:
+                return new_state, {"loss": out, "grad_norm": gnorm}
+            loss, counters = out
+            return new_state, {"loss": loss, "grad_norm": gnorm,
+                               "counters": counters}
 
         return step
 

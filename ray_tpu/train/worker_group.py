@@ -19,7 +19,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
 from ray_tpu import tracing
-from ray_tpu.tracing import names
+from ray_tpu.tracing import names, step_counters
 from ray_tpu.train import session as session_mod
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.session import TrainContext, _Session, _set_session
@@ -114,6 +114,9 @@ class TrainWorker:
                     except BaseException as e:  # noqa: BLE001
                         traceback.print_exc()
                         error = e
+                    # the last steps' counters: the loop is over, so this
+                    # wait is on nobody's path
+                    step_counters.drain(wait=True)
                     tracing.record_named(names.TRAIN_LOOP_DONE, {
                         "rank": self.rank,
                         "error": repr(error) if error else None})

@@ -13,6 +13,7 @@ import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -259,6 +260,12 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     assert [e["layer"] for e in lfm2["expert_load"]] == [2, 3, 4, 5]
     assert all(e["pairs_dropped"] == 0 and e["tokens"] == 8 * lfm2_cfg.seq_len
                for e in lfm2["expert_load"])
+    # ... and what the step itself said of them at run time (PR 52): a row
+    # a layer of (passes, pairs, fullest held expert), the set-up loads' kin
+    for step in (lfm2, summary["hybrid"]):
+        load = np.asarray(step["step_load"])
+        assert load.shape == (4, 3) and (load[:, 0] >= 1).all()
+        assert (load[:, 2] <= load[:, 1]).all() and (load[:, 1] <= 4 * 8 * 64).all()
     none = [rows[-1] | {"summary": summary | {"lfm2": lfm2 | {
         "layer_pattern": [], "remat_policy": [], "expert_load": []}}}]
     assert len(chip_smoke.check_training(rows[:-1] + none, cfg, steps)) == 2
@@ -325,3 +332,25 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     )
     # and what the chip check would say about this run
     assert chip_smoke.check_device(summary, cfg, 1, advertised_tpus=8) != []
+
+
+def test_step_load_line_finds_the_steps_own_event_or_fails():
+    """chip_smoke reads a toy expert step's run-time load from the session's
+    record — the `train/step_counters` event whose rows are the step's own
+    `metrics["counters"]` — and fails where the record holds none."""
+    import chip_smoke
+
+    step = {"step_load": [[1, 130, 20], [2, 300, 90]]}
+    event = {"cat": "train", "name": "step_counters", "ph": "i", "ts": 12.5e6,
+             "args": {"step": 1, "kind": "expert_load", "t_dispatch": 12.0,
+                      "layers": [2, 3], "passes": [1, 2], "pairs": [130, 300],
+                      "max_per_expert": [20, 90], "buffer_rows": 256,
+                      "held": 8}}
+    other = {**event, "args": {**event["args"], "pairs": [131, 300]}}
+    lines, bad = chip_smoke.step_load_line([other, event], step, "toy")
+    assert not bad and len(lines) == 1
+    assert "layers [2, 3] ran [1, 2] pass(es)" in lines[0]
+    assert "0.50 s after its dispatch" in lines[0]
+    lines, bad = chip_smoke.step_load_line([other], step, "toy")
+    assert not lines and len(bad) == 1 and "train/step_counters" in bad[0]
+

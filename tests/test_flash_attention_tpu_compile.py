@@ -583,7 +583,7 @@ def routed_experts_hlo(one_chip):
 
     def loss(u, ell, p):
         return jnp.sum(moe.routed_experts(u, ell, p, top_k=22, held=held,
-                                          scaling=5.0))
+                                          scaling=5.0)[0])
 
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         abstract((_MOE_T, D)), abstract((_MOE_T, latent)), p

@@ -40,6 +40,10 @@ NEW_READERS = ("lfm2_mfu_device", "short_conv_ms_per_step",
 # PR 51's reader of the passes a batch runs beyond the one-pass path, which
 # this cell and the Nemotron cell list
 FURTHER_PASSES = "moe_further_passes_ms_per_step"
+# PR 52's: the program's span around the step's call and the three readers of
+# what each step said it did (tests/test_train_tracing.py holds their cases)
+STEP_READERS = ("step_dispatch_ms_per_step", "moe_passes_per_step",
+                "moe_multi_pass_steps", "moe_load_imbalance")
 # accepted readers of a scope or a kernel this family's step has too
 SHARED_READERS = ("moe_routed_ms_per_step", "moe_dispatch_ms_per_step",
                   "flash_fwd_ms_per_step", "flash_bwd_ms_per_step")
@@ -155,7 +159,7 @@ def test_a_half_alone_equals_the_reference(half):
             return jnp.einsum("bsf,fd->bsd", jax.nn.silu(
                 u @ p["w_gate"]) * (u @ p["w_up"]), p["w_down"])
         return moe.gated_moe(u, p, top_k=cfg.top_k, held=cfg.held,
-                             scaling=cfg.routed_scaling, eps=cfg.route_eps)
+                             scaling=cfg.routed_scaling, eps=cfg.route_eps)[0]
 
     def plain(u, p):
         f = {"conv": reference.conv_operator,
@@ -267,7 +271,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(dtype):
             parts.append(moe.gated_moe(
                 u.astype(dtype), share, top_k=cfg.top_k,
                 held=moe.Held(first, 4), scaling=cfg.routed_scaling,
-                eps=cfg.route_eps))
+                eps=cfg.route_eps)[0])
     assert all(part.dtype == jnp.float32 for part in parts)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(sum(parts), whole, rtol=tol,
@@ -292,8 +296,8 @@ def test_no_pair_is_dropped_when_every_token_chooses_held_experts():
     assert int(load["pairs"]) == 4 * 2 * cfg.seq_len
     assert int(load["buffer_passes"]) > 1 and int(load["pairs_dropped"]) == 0
     with jax.default_matmul_precision("highest"):
-        out = moe.gated_moe(u, p, top_k=cfg.top_k, held=cfg.held,
-                            scaling=cfg.routed_scaling, eps=cfg.route_eps)
+        out, _ = moe.gated_moe(u, p, top_k=cfg.top_k, held=cfg.held,
+                               scaling=cfg.routed_scaling, eps=cfg.route_eps)
         ref = jnp.stack([reference.experts(row, p, _sizes(cfg))[0]
                          for row in u])
     np.testing.assert_allclose(out, ref, rtol=2e-5,
@@ -328,8 +332,8 @@ def test_gated_moe_is_the_parents_whatever_the_passes_it_fills(
         3 if router == "all_on_held_experts" else 1)
 
     def layer(u, p):
-        y = moe.gated_moe(u, p, top_k=cfg.top_k, held=cfg.held,
-                          scaling=cfg.routed_scaling, eps=cfg.route_eps)
+        y, _ = moe.gated_moe(u, p, top_k=cfg.top_k, held=cfg.held,
+                             scaling=cfg.routed_scaling, eps=cfg.route_eps)
         return jnp.sum(y * w), y
 
     def graded():
@@ -340,7 +344,9 @@ def test_gated_moe_is_the_parents_whatever_the_passes_it_fills(
             return jax.value_and_grad(layer, (0, 1), has_aux=True)(u, p)
 
     now = graded()
-    monkeypatch.setattr(moe, "routed_experts", pr50.routed_experts)
+    # (the frozen passes hand out no load beside their result: PR 52)
+    monkeypatch.setattr(moe, "routed_experts", lambda *a, **k: (
+        pr50.routed_experts(*a, **k), None))
     then = graded()
     for a, b in zip(jax.tree.leaves(now), jax.tree.leaves(then), strict=True):
         assert a.dtype == b.dtype
@@ -586,9 +592,11 @@ def test_the_benchmark_gains_one_configuration_and_one_one_chip_cell():
     assert b["workloads"][-1] == {
         **b["workloads"][-1], "name": CELL, "config": "lfm2-24b-a2b-l5",
         "traffic": "dataset", "chips": 1}
-    # (PR 51 appended one metric of the dispatch after them)
-    assert [m["name"] for m in b["per_layer"]][-len(NEW_READERS) - 1:] == list(
-        NEW_READERS) + [FURTHER_PASSES]
+    # (PR 51 appended one metric of the dispatch after them, PR 52 the
+    # program's span of the step and three readers of its per-step counters)
+    assert [m["name"] for m in b["per_layer"]][
+        -len(NEW_READERS) - 1 - len(STEP_READERS):] == list(
+        NEW_READERS) + [FURTHER_PASSES] + list(STEP_READERS)
     for name in SHARED_READERS:
         entry = next(m for m in b["per_layer"] if m["name"] == name)
         assert entry["workloads"][-1] == CELL
@@ -685,7 +693,7 @@ def _further_reader():
 
 def test_the_further_passes_reader_is_one_appended_entry_of_two_cells():
     b = _benchmark()
-    entry = b["per_layer"][-1]
+    entry = b["per_layer"][-1 - len(STEP_READERS)]
     assert entry == {
         "name": FURTHER_PASSES, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "Kernels",

@@ -630,3 +630,488 @@ def test_program_trace_check_passes_on_the_recorded_trace(capsys):
     from benchmarks.harness import program_trace
 
     assert program_trace.check() == 0, capsys.readouterr().out
+
+
+# ----------------------------------------- the step says what it did (PR 52)
+STEP_COUNTERS = names.TRAIN_STEP_COUNTERS.split("/")[1]
+EXPERT_FAMILIES = {"nemotron": "nemotron_h", "lfm2": "lfm2_moe"}
+
+
+def _expert_step(key, **extra):
+    """(model module, tiny config, bundle with a plain-SGD optimizer — the
+    default schedule's first step has rate 0 and would hide the gradients —,
+    batch of 2 on the device)."""
+    import importlib
+
+    import jax
+    import optax
+
+    from ray_tpu.train.train_step import make_train_step, synthetic_batch
+
+    model = importlib.import_module(f"ray_tpu.models.{EXPERT_FAMILIES[key]}")
+    cfg = getattr(model, EXPERT_FAMILIES[key] + "_tiny")(**extra)
+    bundle = make_train_step(model, cfg, optimizer=optax.sgd(0.1))
+    batch = jax.device_put(synthetic_batch(cfg, 2), bundle.data_sharding)
+    return model, cfg, bundle, batch
+
+
+def _expert_biases(model, cfg):
+    """Where each expert layer's selection bias stands in the parameters, in
+    the layers' published order: (path to the stack, row)."""
+    from ray_tpu.models import blocks
+
+    is_expert = (model.EXPERTS.__getitem__ if hasattr(model, "EXPERTS")
+                 else "E".__eq__)
+    out = []
+    for top, pattern in ((("blocks",), cfg.pattern),
+                         (("mtp", "blocks"), getattr(cfg, "mtp_pattern", ""))):
+        for g, (sub, reps) in enumerate(blocks.pattern_groups(pattern)):
+            seen = {}
+            for kind in sub * reps:
+                if is_expert(kind):
+                    out.append((top + (g, kind, "router_bias"),
+                                seen.get(kind, 0)))
+                seen[kind] = seen.get(kind, 0) + 1
+    return out
+
+
+def _pushed(params, where, cfg):
+    """``params`` with ONE expert layer's router sent onto the first
+    ``top_k`` experts held here, every token's every choice."""
+    import jax.numpy as jnp
+
+    path, row = where
+    ids = jnp.arange(cfg.n_experts)
+    onto = (ids >= cfg.held.first) & (ids < cfg.held.first + cfg.top_k)
+
+    def put(tree, path):
+        if not path:
+            return tree.at[row].set(jnp.where(onto, 1.0, 0.0))
+        if isinstance(tree, dict):
+            return {**tree, path[0]: put(tree[path[0]], path[1:])}
+        return [put(t, path[1:]) if i == path[0] else t
+                for i, t in enumerate(tree)]
+
+    return put(params, path)
+
+
+def _own(state):
+    """A copy of ``state`` a step may take (every step donates its state)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(jnp.copy, state)
+
+
+@pytest.mark.parametrize("key", EXPERT_FAMILIES)
+def test_step_counters_are_the_layers_held_load(key, monkeypatch):
+    """`metrics["counters"]` of a toy step, layer by layer in published
+    order: what `moe.held_load` says (`buffer_passes`, `pairs`,
+    `max_per_expert`) of the SAME inputs — the same layer's normed stream
+    inside the same step —, on the batch as the routers stand and with each
+    expert layer's router in turn pushed onto held experts, so that this
+    layer and no other runs three passes over a (shrunk) row buffer; loss, gradient norm and every
+    stepped parameter bit-equal to the same step without the aux."""
+    import types
+
+    import jax
+    import optax
+
+    from ray_tpu.ops import moe
+    from ray_tpu.train.train_step import make_train_step
+
+    # a row buffer the pushed layer's pairs (tokens · top_k) fill three
+    # times and a layer as initialised once or twice
+    model, cfg, bundle, batch = _expert_step(key)
+    tokens = batch["tokens"].size
+    rows = -(-2 * tokens * cfg.top_k // 5)
+    monkeypatch.setattr(moe, "row_buffer", lambda *shape: rows)
+    model, cfg, bundle, batch = _expert_step(key)
+    spec = model.step_counters(cfg)
+    assert spec.fields == names.STEP_EXPERT_LOAD_ARGS
+    assert spec.static(tokens) == {"buffer_rows": rows,
+                                   "held": cfg.held_count}
+    where = _expert_biases(model, cfg)
+    assert len(where) == len(spec.layers)
+
+    # the same step with no aux: a module that offers no counters
+    plain = types.SimpleNamespace(
+        init=model.init, logical_axes=model.logical_axes,
+        loss_fn=model.loss_fn, mesh_rules=model.mesh_rules)
+    bare = make_train_step(plain, cfg, optimizer=optax.sgd(0.1))
+
+    # ... and the same step whose layers hand out held_load's own numbers
+    real = moe.routed_experts
+
+    def by_held_load(u, ell, p, *, form=moe.RELU2_EXPERT, **routing):
+        load = moe.held_load(u, p, **routing)
+        return real(u, ell, p, form=form, **routing)[0], dict(zip(
+            names.STEP_EXPERT_LOAD_ARGS,
+            (load["buffer_passes"], load["pairs"], load["max_per_expert"])))
+
+    monkeypatch.setattr(moe, "routed_experts", by_held_load)
+    witness = make_train_step(model, cfg, optimizer=optax.sgd(0.1))
+    monkeypatch.setattr(moe, "routed_experts", real)
+
+    for layer in [None] + list(range(len(where))):
+        params = bundle.state["params"]
+        if layer is not None:
+            params = _pushed(params, where[layer], cfg)
+        state = {**bundle.state, "params": params}
+        new, metrics = bundle.step_fn(_own(state), batch)
+        counters = np.asarray(metrics["counters"])
+        assert counters.shape == (len(where), 3) and counters.dtype == np.int32
+        _, said = witness.step_fn(_own(state), batch)
+        np.testing.assert_array_equal(counters, np.asarray(said["counters"]))
+        passes, pairs, fullest = counters.T
+        if layer is None:       # (half the experts are held in the LFM2 toy)
+            assert (passes <= 2).all() and (pairs < tokens * cfg.top_k).all()
+        else:
+            assert pairs[layer] == tokens * cfg.top_k and passes[layer] == 3
+            assert fullest[layer] == tokens
+            assert (np.delete(passes, layer) <= 2).all()
+        assert (passes == -(-pairs // rows)).all() and (fullest <= pairs).all()
+        new_bare, bare_metrics = bare.step_fn(_own(state), batch)
+        assert set(bare_metrics) == {"loss", "grad_norm"}
+        for name in bare_metrics:
+            np.testing.assert_array_equal(np.asarray(metrics[name]),
+                                          np.asarray(bare_metrics[name]))
+        for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(new_bare),
+                        strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert float(metrics["grad_norm"]) > 0
+
+
+def test_model_without_step_counters_lowers_to_the_parents_step():
+    """A model that offers no counters is asked what it was always asked:
+    no `counters` among its metrics, and its lowered step is, to the letter,
+    the one the step had before any model could offer them."""
+    import jax
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel import mesh as mesh_lib
+    from ray_tpu.train import train_step as ts
+
+    bundle, batch = _step("no_remat")
+    assert not hasattr(gpt2, "step_counters")
+    assert ts._offered_counters(gpt2, bundle.cfg) is None
+    _, metrics = bundle.step_fn.eval_shape(bundle.state, batch)
+    assert set(metrics) == {"loss", "grad_norm"}
+
+    optimizer = ts.default_optimizer()
+    _, state_sh, batch_sh = ts._compose_step(
+        gpt2, bundle.cfg, bundle.mesh, optimizer, None)
+    memory = ts._chip_memory(bundle.mesh, bundle.state)
+
+    def step(state, batch):                 # the step as PR 51 composed it
+        tokens, targets = batch["tokens"], batch["targets"]
+        with mesh_lib.use_mesh(bundle.mesh), mesh_lib.chip_memory(*memory):
+            loss, grads = jax.value_and_grad(gpt2.loss_fn)(
+                state["params"], tokens, targets, bundle.cfg)
+        new_params, new_opt, gnorm = ts._apply_optimizer(
+            optimizer, grads, state)
+        new_state = {"params": new_params, "opt_state": new_opt,
+                     "step": state["step"] + 1}
+        return new_state, {"loss": loss, "grad_norm": gnorm}
+
+    parents = jax.jit(step, in_shardings=(state_sh, batch_sh),
+                      out_shardings=(state_sh, None), donate_argnums=(0,))
+    assert (bundle.step_fn.lower(bundle.state, batch).as_text()
+            == parents.lower(bundle.state, batch).as_text())
+
+
+def test_steps_callable_is_the_jitted_step_to_its_callers(buffer, monkeypatch):
+    """What `harness/loop.py` and the compile-count tests ask of the step
+    object: `.lower(...).compile()` and `_cache_size()` as the bare jit gave
+    them — one compile however many calls —, the call under a
+    `ray_tpu:train/step` span numbered by the call."""
+    import jax
+
+    from ray_tpu.train import train_step as ts
+
+    monkeypatch.setattr(ts, "PROFILE_MIN_DUR_S", 0.0)
+    bundle, batch = _step("no_remat")
+    batch = jax.device_put(batch, bundle.data_sharding)
+    assert names.TRAIN_STEP in names.SPANS
+    assert bundle.step_fn._cache_size() == 0
+    state = bundle.state
+    for _ in range(3):
+        state, metrics = bundle.step_fn(state, batch)
+    assert bundle.step_fn._cache_size() == 1
+    mem = bundle.step_fn.lower(state, batch).compile().memory_analysis()
+    assert mem.temp_size_in_bytes > 0
+    assert bundle.step_fn._cache_size() == 1
+    assert bundle.step_fn.eval_shape(state, batch)[1]["loss"].shape == ()
+    spans = [e for e in _drain(buffer, "train") if e["name"] == "step"]
+    assert [e["args"]["step"] for e in spans] == [1, 2, 3]
+    assert all(e["dur"] > 0 for e in spans)
+    assert not [e for e in spans if e["name"] == STEP_COUNTERS]
+
+
+class _Made:
+    """Stands where a step's counters array stands: ready when told."""
+
+    def __init__(self, rows, ready):
+        self.rows, self.ready = np.asarray(rows, np.int32), ready
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        return self.rows
+
+
+def test_drain_records_what_is_ready_in_order_and_waits_for_nothing(buffer):
+    """An entry whose array the device has not made stays pending — and so
+    does every later one, ready or not: the events go in the steps' order —
+    and is recorded by a later call; `wait=True` takes them all."""
+    from ray_tpu.tracing import step_counters
+
+    def decode(rows):
+        return {"kind": "expert_load", "layers": [0],
+                "passes": rows[:, 0].tolist()}
+
+    step_counters.drain(wait=True)
+    buffer.drain(10 ** 6)
+    made = [_Made([[n, 0, 0]], ready=n != 2) for n in (1, 2, 3)]
+    for n, array in enumerate(made, start=1):
+        step_counters.watch(n, 100.0 + n, array, decode)
+
+    def recorded():
+        return [e["args"] for e in _drain(buffer, "train")
+                if e["name"] == STEP_COUNTERS]
+
+    assert step_counters.drain() == 1
+    (first,) = recorded()
+    assert first == {"step": 1, "kind": "expert_load", "t_dispatch": 101.0,
+                     "layers": [0], "passes": [1]}
+    assert tuple(first)[:4] == names.TRAIN_STEP_COUNTERS_ARGS
+    assert step_counters.drain() == 0 and not recorded()   # 2 is not made
+    made[1].ready = True
+    assert step_counters.drain() == 2
+    assert [a["step"] for a in recorded()] == [2, 3]
+    # with `wait` nothing is asked whether it is ready
+    step_counters.watch(4, 104.0, _Made([[4, 0, 0]], ready=False), decode)
+    assert step_counters.drain() == 0
+    assert step_counters.drain(wait=True) == 1
+    assert [a["passes"] for a in recorded()] == [[4]]
+    assert step_counters.drain(wait=True) == 0
+    # `train.report` is not a drain: a loop fetches its loss and reports with
+    # an idle device behind it, and a fetch there is on the step's path
+    from ray_tpu.train import session as session_mod
+
+    step_counters.watch(5, 105.0, _Made([[5, 0, 0]], ready=True), decode)
+    session = session_mod._Session(session_mod.TrainContext())
+    session.report({"loss": 1.0})
+    session.finish()
+    assert not recorded() and len(step_counters._pending) == 1
+    assert step_counters.drain() == 1
+
+
+def _fit_hybrid(steps):
+    """A local `fit()` of the toy hybrid for ``steps`` steps with the loop a
+    user writes (the loss fetched a step, reported every other step); the
+    session's record, and how often the recorder fetched."""
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu.tracing import step_counters
+
+    fetched = []
+    real = jax.device_get
+
+    def loop(config):
+        model, cfg, bundle, batch = _expert_step("nemotron")
+        state = bundle.state
+        for n in range(config["steps"]):
+            state, metrics = bundle.step_fn(state, batch)
+            assert metrics["counters"].shape == (4, 3)
+            if n % 2:
+                train.report({"loss": float(metrics["loss"])})
+        train.report({"steps": config["steps"]})
+
+    def counting(x):
+        fetched.append(x)
+        return real(x)
+
+    ray_tpu.shutdown()
+    ray_tpu.init(local_mode=True)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "device_get", counting)
+            result = train.JaxTrainer(
+                loop, train_loop_config={"steps": steps},
+                scaling_config=train.ScalingConfig(num_workers=1)).fit()
+    finally:
+        ray_tpu.shutdown()
+    assert result.error is None and result.metrics["steps"] == steps
+    return ray_tpu.timeline(), fetched
+
+
+def _named(trace, full_name):
+    cat, name = full_name.split("/")
+    return [e for e in trace if e.get("cat") == cat and e["name"] == name]
+
+
+def test_fit_leaves_one_counters_event_a_step_the_last_included(monkeypatch):
+    """N steps of a local `fit()` leave exactly N `train/step_counters`
+    events in the session's record, steps 1 … N in order — the last one's,
+    which no later step drained, by the loop thread's wait before
+    `train/loop_done` —, each one small fetch; all before `loop_done`."""
+    monkeypatch.setattr(_config, "task_events_enabled", True)
+    monkeypatch.setattr(_config, "task_events_sample_rate", 1.0)
+    steps = 5
+    trace, fetched = _fit_hybrid(steps)
+    events = _named(trace, names.TRAIN_STEP_COUNTERS)
+    assert [e["args"]["step"] for e in events] == list(range(1, steps + 1))
+    assert len(fetched) == steps
+    (done,) = _named(trace, names.TRAIN_LOOP_DONE)
+    assert all(e["ts"] <= done["ts"] for e in events)
+    for e in events:
+        args = e["args"]
+        assert tuple(k for k in args if k in names.TRAIN_STEP_COUNTERS_ARGS) \
+            == names.TRAIN_STEP_COUNTERS_ARGS
+        assert args["kind"] == names.EXPERT_LOAD_KIND
+        assert args["layers"] == [0, 1, 2, 3] and args["held"] == 8
+        for field in names.STEP_EXPERT_LOAD_ARGS:
+            assert len(args[field]) == 4
+        assert args["passes"] == [1, 1, 1, 1]
+        assert args["t_dispatch"] <= e["ts"] / 1e6
+        assert args["trace_id"] == done["args"]["trace_id"]
+    stamps = [e["args"]["t_dispatch"] for e in events]
+    assert stamps == sorted(stamps)
+
+
+def test_fit_with_the_event_plane_off_watches_and_fetches_nothing(monkeypatch):
+    monkeypatch.setattr(_config, "task_events_enabled", False)
+    from ray_tpu.tracing import step_counters
+
+    trace, fetched = _fit_hybrid(3)
+    assert not fetched and not step_counters._pending
+    assert not _named(trace or [], names.TRAIN_STEP_COUNTERS)
+
+
+# --------------------------------------- the benchmark's readers of both
+COUNTER_READERS = ("moe_passes_per_step", "moe_multi_pass_steps",
+                   "moe_load_imbalance")
+STEP_RECORD = os.path.join(ROOT, "benchmarks", "testdata",
+                           "step-counters.rehearsal")
+
+
+def _reader(metric):
+    """(the reader's module, its BENCHMARK.json entry)."""
+    import importlib
+    import json
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == metric)
+    return importlib.import_module(f"benchmarks.layer_metrics.{metric}"), entry
+
+
+def _record_facts(events, summary):
+    from benchmarks.harness import session_timeline
+
+    return {"summary": summary, "notes": [],
+            "session_timeline": session_timeline.parse(events)}
+
+
+def _counters_event(step, t_dispatch, passes, pairs, fullest, held=8):
+    return {"name": STEP_COUNTERS, "cat": "train", "ph": "i", "s": "t",
+            "ts": (t_dispatch + 0.5) * 1e6, "pid": 1, "tid": 1,
+            "args": {"step": step, "kind": names.EXPERT_LOAD_KIND,
+                     "t_dispatch": t_dispatch, "layers": [0, 1],
+                     "passes": passes, "pairs": pairs,
+                     "max_per_expert": fullest, "buffer_rows": 100,
+                     "held": held}}
+
+
+@pytest.mark.parametrize("metric", COUNTER_READERS)
+def test_counter_reader_against_the_recorded_session(metric):
+    """Each reader on the `train/*` events of a recorded rehearsal of the
+    Nemotron cell: the number `benchmarks/testdata/` holds for the window
+    its summary's wall clocks cut; the entry names the two expert cells."""
+    import gzip
+    import json
+
+    with gzip.open(STEP_RECORD + ".json.gz", "rt") as f:
+        events = json.load(f)
+    with open(STEP_RECORD + ".expected.json") as f:
+        expected = json.load(f)
+    reader, entry = _reader(metric)
+    assert (reader.UNIT, reader.LAYER, reader.MOVES, reader.SOURCE) == (
+        entry["unit"], entry["layer"], entry["moves"], entry["source"])
+    assert entry["workloads"] == ["nemotron-3-super-120b-l11.dataset",
+                                  "lfm2-24b-a2b-l5.dataset"]
+    facts = _record_facts(events, expected["summary"])
+    assert reader.read(facts) == pytest.approx(expected["metrics"][metric],
+                                               rel=1e-12)
+    first, last = expected["window_steps"]
+    assert [s["step"] for s in facts["step_counters_window"]] == list(
+        range(first, last + 1))
+    assert expected["steps_recorded"] > last
+
+
+def test_counter_readers_on_steps_that_ran_further_passes():
+    """The readers' arithmetic where it matters: of the window's three steps
+    (a fourth was dispatched before it, a fifth after) one ran a second pass
+    in one layer."""
+    events = [
+        _counters_event(1, 9.0, [3, 3], [250, 250], [250, 250]),
+        _counters_event(2, 10.5, [1, 1], [80, 64], [10, 16]),
+        _counters_event(3, 11.5, [1, 2], [96, 160], [12, 60]),
+        _counters_event(4, 12.5, [1, 1], [80, 80], [10, 10]),
+        _counters_event(5, 14.0, [3, 3], [250, 250], [250, 250]),
+    ]
+    summary = {"t_window_wall": 10.0, "t_end_wall": 13.0}
+    want = {"moe_passes_per_step": (2 + 3 + 2) / 3,
+            "moe_multi_pass_steps": 100 / 3,
+            # the worst layer a step: 16·8/64 − 1, 60·8/160 − 1, 10·8/80 − 1
+            "moe_load_imbalance": 100 * (1.0 + 2.0 + 0.0) / 3}
+    for metric in COUNTER_READERS:
+        reader, _ = _reader(metric)
+        assert reader.read(_record_facts(events, summary)) == pytest.approx(
+            want[metric], rel=1e-12), metric
+
+
+@pytest.mark.parametrize("metric", COUNTER_READERS
+                         + ("step_dispatch_ms_per_step",))
+def test_new_reader_finds_nothing_where_the_program_says_nothing(metric):
+    """No record, a record without the event (the parent's program under this
+    PR's `benchmarks/`), events outside the window, no trace: `None`, so the
+    line leaves the metric out."""
+    reader, _ = _reader(metric)
+    summary = {"t_window_wall": 10.0, "t_end_wall": 13.0}
+    other = {"name": "report", "cat": "train", "ph": "X", "ts": 11e6,
+             "dur": 5.0, "pid": 1, "tid": 1, "args": {}}
+    outside = _counters_event(1, 9.0, [1, 1], [80, 80], [10, 10])
+    for facts in ({"summary": summary, "notes": [], "session_timeline": None},
+                  _record_facts([other], summary),
+                  _record_facts([other, outside], summary)):
+        facts.update(trace=None, program_trace=None)
+        assert reader.read(facts) is None
+
+
+def test_step_dispatch_reader_reads_the_programs_span(monkeypatch):
+    """`step_dispatch_ms_per_step` is the traced window's time under
+    `ray_tpu:train/step` a step, as `report_enqueue_ms_per_step` is under
+    `train/report`; from a program whose vocabulary lacks the span, nothing
+    — not the 0 a layer with other spans would read."""
+    from benchmarks.harness import program_trace
+
+    reader, entry = _reader("step_dispatch_ms_per_step")
+    assert (reader.UNIT, reader.LAYER, reader.MOVES, reader.SOURCE) == (
+        entry["unit"], entry["layer"], entry["moves"], entry["source"])
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        import json
+        assert entry["workloads"] == [
+            w["name"] for w in json.load(f)["workloads"]]
+    facts = {"program_trace": {
+        "host_span_ms": {"train/step": 3.0, "train/report": 1.0},
+        "host_steps": 2}}
+    assert reader.read(facts) == 1.5
+    enqueue, _ = _reader("report_enqueue_ms_per_step")
+    assert enqueue.read(facts) == 0.5
+    monkeypatch.setattr(program_trace.names, "SPANS", tuple(
+        s for s in names.SPANS if s != names.TRAIN_STEP))
+    assert reader.read(facts) is None and enqueue.read(facts) == 0.5
