@@ -62,7 +62,7 @@ SPARSE_SELECT = "sparse_select"
 # models/lfm2_moe.py: the double-gated short convolution, the whole operator
 # (in-projection, gates and conv, out-projection) and, inside it, what lies
 # between the two products (ops/short_conv.py: the two gates and the
-# depthwise causal conv — elementwise, what a kernel would replace)
+# depthwise causal conv — elementwise, the CONV_GATE_* kernels' work)
 SHORT_CONV = "short_conv"
 CONV_GATE = "conv_gate"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
@@ -99,10 +99,16 @@ SSD_CHUNK_BWD_KERNEL = "ssd_chunk_bwd"
 SPARSE_ATTN_FWD_KERNEL = "sparse_attn_fwd"
 SPARSE_ATTN_BWD_DQ_KERNEL = "sparse_attn_bwd_dq"
 SPARSE_ATTN_BWD_DKV_KERNEL = "sparse_attn_bwd_dkv"
+# the gates and the short conv between the LFM2 conv operator's two products
+# (ops/short_conv.py): a run of a row's tokens at the whole width — BCx in
+# and y out; BCx and d y in, d BCx and d w's partial sums out
+CONV_GATE_FWD_KERNEL = "conv_gate_fwd"
+CONV_GATE_BWD_KERNEL = "conv_gate_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
            SSD_CHUNK_BWD_KERNEL, SPARSE_ATTN_FWD_KERNEL,
-           SPARSE_ATTN_BWD_DQ_KERNEL, SPARSE_ATTN_BWD_DKV_KERNEL)
+           SPARSE_ATTN_BWD_DQ_KERNEL, SPARSE_ATTN_BWD_DKV_KERNEL,
+           CONV_GATE_FWD_KERNEL, CONV_GATE_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"

@@ -681,36 +681,53 @@ def test_routing_gathers_no_score_and_sorts_the_membership_on_the_v5e(
     assert _MOE_T * _MOE_HELD in written, written
 
 
-def test_the_lfm2_tiny_step_runs_one_pass_outside_the_loop_on_the_v5e(
-        topo, monkeypatch):
-    """PR 51: the LFM2 cell's step at every published width and a tiny batch
-    (2 rows of 512 tokens: three passes in the worst case; through
+@pytest.fixture(scope="module")
+def lfm2_tiny_step(topo):
+    """The LFM2 cell's step at every published width and a tiny batch (2 rows
+    of 512 tokens: three passes in the worst case; through
     `families/lfm2_moe.abstract_step`) lowered and compiled for one described
-    chip, ~25 s. The first
+    chip, ~25 s: (the compiled step's text, the lowered step's, the program's
+    config, the cell as cut)."""
+    from conftest import time_limit
+
+    from ray_tpu.models import blocks
+
+    cell, config, family, mesh = _cell_on(topo, "lfm2-24b-a2b-l5.dataset")
+    cell.update(seq_len=512, per_chip_batch=2)
+    cfg = family.program_config(config, cell)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(blocks, "_decisions", {})
+        with time_limit(240, "the LFM2 step's compile for a described v5e"):
+            step, args = family.abstract_step(config, cell, mesh)
+            lowered = step.lower(*args)
+            hlo = lowered.compile().as_text()
+    return hlo, lowered.as_text(), cfg, cell
+
+
+def _instructions(hlo):
+    """(result shape, opcode, op_name) of every instruction that has one."""
+    return [m.groups() for m in re.finditer(
+        r"^\s*(?:ROOT )?%\S+ = (.*?) (\w[\w\-]*)\(.*?op_name=\"([^\"]*)\"",
+        hlo, re.M)]
+
+
+def test_the_lfm2_tiny_step_runs_one_pass_outside_the_loop_on_the_v5e(
+        lfm2_tiny_step):
+    """PR 51: in the LFM2 cell's step at a tiny batch (`lfm2_tiny_step`) the
+    first
     pass of an expert layer stands outside any loop; what only a batch with
     more passes needs is carried by a `while` under `moe_further_passes` —
     every loop of the routed experts, and with it every float32 [held, width,
     d_expert] tensor; no pass looks a gate up
     out of the flat [held · T] table (the sort laid them in pair order); and
     the compiler rematerialized nothing."""
-    from conftest import time_limit
-
     from ray_tpu.models import blocks
     from ray_tpu.tracing import names
 
-    monkeypatch.setattr(blocks, "_decisions", {})
-    cell, config, family, mesh = _cell_on(topo, "lfm2-24b-a2b-l5.dataset")
-    cell.update(seq_len=512, per_chip_batch=2)
-    cfg = family.program_config(config, cell)
-    with time_limit(240, "the LFM2 step's compile for a described v5e"):
-        step, args = family.abstract_step(config, cell, mesh)
-        lowered = step.lower(*args)
-        hlo = lowered.compile().as_text()
+    hlo, text, cfg, cell = lfm2_tiny_step
     assert blocks.compiler_rematerialized(hlo) == []
 
-    ops = [(m.group(1), m.group(2)) for m in re.finditer(
-        r"^\s*(?:ROOT )?%\S+ = (.*?) \w[\w\-]*\(.*?op_name=\"([^\"]*)\"", hlo,
-        re.M)]
+    ops = [(shape, op) for shape, _, op in _instructions(hlo)]
     routed = [(shape, op) for shape, op in ops if f"/{names.MOE_ROUTED}/" in op]
     further = [(shape, op) for shape, op in routed
                if f"/{names.MOE_FURTHER_PASSES}/" in op]
@@ -733,6 +750,70 @@ def test_the_lfm2_tiny_step_runs_one_pass_outside_the_loop_on_the_v5e(
     assert low and all(f"/{names.MOE_DISPATCH}/" in op for op in low), low
     # no scalar gather out of the [held · T] table, in the program as traced
     T = cell["per_chip_batch"] * cfg.seq_len
-    text = lowered.as_text()
     gathers = re.findall(r'"?stablehlo\.gather"?\(.*?:\s*\((tensor<[^>]*>)', text)
     assert gathers and f"tensor<{held * T}xf32>" not in gathers, set(gathers)
+
+
+def test_the_conv_gate_kernels_compile_for_the_v5e_at_the_cells_shape(one_chip):
+    """PR 53: ops/short_conv's kernel pair at the tile the rule chooses, at
+    the LFM2 cell's shape ([8, 4,096, 6,144] bf16, w [3, 2,048]), forward and
+    backward: what interpret mode cannot show — the sublane rotations, the
+    halo blocks, the lane cuts of the one BCx block, more VMEM than the call
+    asked for — and that outside the two calls the compiled op holds no
+    [B, S, D] float32 tensor and makes d BCx in no second piece."""
+    from ray_tpu.ops import short_conv
+    from ray_tpu.ops.attention import VMEM_BUDGET_BYTES, VMEM_CEILING_BYTES
+
+    B, S, D = 8, 4096, 2048
+    bcx = jax.ShapeDtypeStruct((B, S, 3 * D), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, D), jnp.float32, sharding=one_chip)
+    fwd, bwd = (short_conv.choose_conv_tiling(k, S, D, 2) for k in ("fwd", "bwd"))
+    assert fwd[:2] == bwd[:2] == (256, 512)
+    assert fwd.vmem_estimate <= VMEM_BUDGET_BYTES < bwd.vmem_estimate
+    assert bwd.vmem_estimate <= VMEM_CEILING_BYTES // 2
+
+    dy = jax.ShapeDtypeStruct((B, S, D), jnp.bfloat16, sharding=one_chip)
+
+    def grads(bcx, w, dy):
+        y, vjp = jax.vjp(lambda *a: short_conv._conv_gate(*a, False), bcx, w)
+        return (y,) + vjp(dy)
+
+    hlo = jax.jit(grads).lower(bcx, w, dy).compile().as_text()
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l and " = " in l]
+    assert sum("conv_gate_fwd" in l for l in calls) == 1
+    assert sum("conv_gate_bwd" in l for l in calls) == 1 and len(calls) == 2
+    assert not re.findall(rf"f32\[{B},{S},({D}|{3 * D})\]", hlo)
+    assert not re.findall(
+        rf"= bf16\[{B},{S},{3 * D}\]\S* (concatenate|pad)\(", hlo)
+
+
+def test_the_lfm2_tiny_step_gates_and_convolves_in_two_kernels_under_the_scope(
+        lfm2_tiny_step):
+    """PR 53: the same compiled step holds the conv-gate kernel pair — the
+    forward, the recompute's forward and the backward of its conv layers (one
+    alone and a loop of three: six calls) — each Mosaic call under the
+    `conv_gate` scope, where `conv_gate_ms_per_step` finds it; under that
+    scope no [B, S, D] float32 tensor is made outside a Mosaic call (`z` and
+    the three shifted gradients were); and d BCx leaves the backward whole:
+    no `concatenate` or `pad` makes a [B, S, 3·D] tensor."""
+    from ray_tpu.tracing import names
+
+    hlo, _, cfg, cell = lfm2_tiny_step
+    B, S, D = cell["per_chip_batch"], cfg.seq_len, cfg.d_model
+    ops = _instructions(hlo)
+    calls = [op for _, code, op in ops if code == "custom-call"
+             and "conv_gate_" in op]
+    assert len(calls) == 6, calls
+    assert all(f"/{names.CONV_GATE}/" in op and f"/{names.SHORT_CONV}/" in op
+               for op in calls), calls
+    fwd, bwd = names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL
+    assert sum(f"/{bwd}/" in op and "transpose(" in op for op in calls) == 2
+    assert sum(f"/{fwd}/" in op and "rematted_computation" in op
+               for op in calls) == 2
+    assert sum(f"/{fwd}/" in op and "transpose(" not in op for op in calls) == 2
+    wide = re.compile(rf"f32\[{B},{S},{D}\]")
+    assert [(shape, op) for shape, code, op in ops
+            if f"/{names.CONV_GATE}/" in op and code != "custom-call"
+            and wide.search(shape)] == []
+    assert not re.findall(
+        rf"= \w+\[{B},{S},{3 * D}\]\S* (concatenate|pad)\(", hlo)

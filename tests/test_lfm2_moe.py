@@ -200,6 +200,113 @@ def test_the_conv_at_a_rows_first_positions_and_its_causality():
         np.asarray(y[0, :4]))
 
 
+def _xla_gated_short_conv(bcx, w):
+    """``ops/short_conv.gated_short_conv`` as XLA's shifted multiply-adds,
+    differentiated by AD — the op's body until PR 53, kept as the kernel
+    pair's oracle."""
+    K, D = w.shape
+    S = bcx.shape[1]
+    f = jnp.float32
+    b, c, x = (bcx[..., i * D:(i + 1) * D].astype(f) for i in range(3))
+    z = jnp.pad(b * x, ((0, 0), (K - 1, 0), (0, 0)))
+    wf = w.astype(f)
+    conv = sum(z[:, tap:tap + S] * wf[tap] for tap in range(K))
+    return (c * conv).astype(bcx.dtype)
+
+
+def _conv_case(B, S, D, dtype, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(keys[0], (B, S, 3 * D)).astype(dtype),
+            jax.random.normal(keys[1], (3, D)) * 0.5,
+            jax.random.normal(keys[2], (B, S, D)).astype(dtype))
+
+
+def _conv_value_and_grads(fn, bcx, w, dy):
+    y, vjp = jax.vjp(fn, bcx, w)
+    return (y,) + vjp(dy)
+
+
+# (rows, tokens, width) → (token tiles, channel tiles) the rule cuts it into
+CONV_SHAPES = {
+    "three-token-tiles": ((2, 768, 128), (3, 1)),
+    "two-channel-tiles": ((1, 256, 1024), (1, 2)),
+    "tiles-both-ways": ((2, 512, 1024), (2, 2)),
+    "tiny-config-whole-axes": ((3, 64, 64), (1, 1)),
+    "tokens-and-lanes-padded": ((2, 6, 8), (1, 1)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", CONV_SHAPES)
+def test_the_conv_gate_kernels_equal_the_xla_form(shape, dtype):
+    """PR 53: ``conv_gate_fwd`` / ``conv_gate_bwd`` (interpreted here) against
+    the XLA form — y, d BCx and d w — in float32 to 1e-6 and in bf16 to
+    bf16's rounding, where a row takes several token tiles (the halo before
+    and after a tile), where the body takes the width in several channel
+    tiles, at the tiny config's whole-axis blocks, and with more rows than
+    one (row b's first outputs and last d z see zeros, not row b ± 1: the
+    oracle pads every row with them)."""
+    (B, S, D), tiles = CONV_SHAPES[shape]
+    Sp, Dp = S + -S % 16, D + -D % 128
+    tiling = short_conv.choose_conv_tiling("bwd", Sp, Dp, dtype.dtype.itemsize)
+    assert (Sp // tiling.token_tile, Dp // tiling.channel_tile) == tiles
+    assert tiling.token_tile % 16 == 0 and tiling.channel_tile % 128 == 0
+    bcx, w, dy = _conv_case(B, S, D, dtype)
+    got = _conv_value_and_grads(short_conv.gated_short_conv, bcx, w, dy)
+    want = _conv_value_and_grads(_xla_gated_short_conv, bcx, w, dy)
+    # a bf16 output is the same float32 value rounded: one ulp where the
+    # float32 sums' last bits differ
+    rtol = 1e-6 if dtype == jnp.float32 else 2.0 ** -7
+    for name, a, b in zip(("y", "d bcx", "d w"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        tol = 1e-5 if name == "d w" else rtol
+        np.testing.assert_allclose(a, b, rtol=tol,
+                                   atol=tol * float(np.abs(b).max()) * 0.1,
+                                   err_msg=name)
+
+
+def test_the_conv_gate_kernels_halo_stops_at_a_rows_ends():
+    """Two rows of three token tiles each, through the kernels together and
+    each alone: bit-equal — nothing of row 0's last tokens reaches row 1's
+    first outputs, nothing of row 1's first ``d c`` row 0's last ``d z``;
+    and a row's first K − 1 outputs are the taps on its own first tokens."""
+    bcx, w, dy = _conv_case(2, 768, 128, jnp.float32, seed=3)
+    both = _conv_value_and_grads(short_conv.gated_short_conv, bcx, w, dy)
+    alone = [_conv_value_and_grads(short_conv.gated_short_conv,
+                                   bcx[r:r + 1], w, dy[r:r + 1])
+             for r in range(2)]
+    for i in range(2):                                   # y, d bcx
+        np.testing.assert_array_equal(
+            np.asarray(both[i]),
+            np.concatenate([np.asarray(a[i]) for a in alone]))
+    np.testing.assert_allclose(both[2], alone[0][2] + alone[1][2], rtol=1e-5,
+                               atol=1e-5)
+    D = 128
+    z = bcx[1, :, :D] * bcx[1, :, 2 * D:]
+    np.testing.assert_allclose(both[0][1, 0], bcx[1, 0, D:2 * D] * (w[2] * z[0]),
+                               rtol=1e-6, atol=1e-7)
+
+
+def test_the_conv_gate_kernels_take_their_own_rows_under_a_mesh():
+    """Under a mesh (dp 2 × fsdp 2 of the host's CPU devices) the kernels
+    run inside a shard_map over the batch axes, a device its own rows at the
+    whole width: y and d BCx are the unsharded call's, d w the devices' sum."""
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(dp=2, fsdp=2),
+                              jax.devices()[:4])
+    bcx, w, dy = _conv_case(4, 64, 128, jnp.float32, seed=5)
+    fn = lambda *a: _conv_value_and_grads(short_conv.gated_short_conv, *a)
+    plain = fn(bcx, w, dy)
+    with mesh_lib.use_mesh(mesh):
+        sharded = jax.jit(fn)(bcx, w, dy)
+    for a, b in zip(sharded[:2], plain[:2]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(sharded[2], plain[2], rtol=1e-5, atol=1e-5)
+
+
 def test_qk_norm_comes_before_rope():
     """The reference with the two the other way round is another model: the
     program follows the published order (q_layernorm, then RoPE)."""

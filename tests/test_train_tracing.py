@@ -56,6 +56,7 @@ SPARSE_KERNELS = (names.SPARSE_ATTN_FWD_KERNEL, names.SPARSE_ATTN_BWD_DQ_KERNEL,
 SALA_SCOPES = (names.LIGHTNING_ATTN, names.SPARSE_ATTENTION,
                names.SPARSE_SELECT)
 LFM2_OWN_SCOPES = (names.SHORT_CONV, names.CONV_GATE)
+CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE
                      and s not in EVA_SCOPES + NEMOTRON_SCOPES + SALA_SCOPES
                      + LFM2_OWN_SCOPES)
@@ -314,7 +315,7 @@ def test_remat_recompute_keeps_the_block_scopes(blocks):
 
 def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
     assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS + SSD_KERNELS
-                                     + SPARSE_KERNELS
+                                     + SPARSE_KERNELS + CONV_KERNELS
                                      + (names.RAGGED_DOT_KERNEL,))
 
 
@@ -327,7 +328,8 @@ def test_kernel_name_in_jaxpr(kernel):
         return
     _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else
                          "nemotron" if kernel in SSD_KERNELS else
-                         "sala" if kernel in SPARSE_KERNELS else "remat")
+                         "sala" if kernel in SPARSE_KERNELS else
+                         "lfm2" if kernel in CONV_KERNELS else "remat")
     assert f"name={kernel}" in jaxpr
 
 
