@@ -24,7 +24,12 @@ its ``LLLS`` pattern, its ``model/sparse_selection`` event and the
 small LFM2-MoE (``models/lfm2_moe.py``: a short-convolution + dense layer, an
 attention layer at hd 64 and three short-convolution layers over gated
 experts, PR 50) for its ``DACCC`` pattern, the ``model/remat_policy`` decision
-over its three kinds and its ``model/expert_load`` events, and — what the expert layer's chosen-set mask
+over its three kinds and its ``model/expert_load`` events, one step of a small
+DeepSeek-V2 (``models/deepseek_v2.py``: latent attention at q·k 192 / v 128
+over a dense layer and two layers of shared + routed experts under a balance
+loss, PR 55) for its ``DEE`` pattern, the flash kernels' ``ops/flash_tiling``
+at the two widths and the balance loss its step says beside its load, and —
+what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
 check_training/check_device) and, after ``shutdown()``, prints from the
@@ -350,6 +355,46 @@ def train_loop(config: Dict[str, Any]) -> None:
                 "expert_load": load,
                 "step_load": np.asarray(m["counters"]).tolist()}
         del variant
+    # One step of a latent-attention model with shared + routed experts
+    # under a balance loss, through the same factory: the flash kernels at
+    # unequal q·k and v widths, the loss term out of the layer scan.
+    dsv2 = None
+    if config.get("dsv2_model") is not None:
+        from ray_tpu.models import deepseek_v2
+        from ray_tpu.models.blocks import layer_pattern_decisions
+
+        dsv2_cfg = config["dsv2_model"]
+        variant = make_train_step(
+            deepseek_v2, dsv2_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP,
+                                        total_steps=steps))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, dsv2_cfg.seq_len), dtype=np.int32)
+        dsv2_batch = jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding)
+        with mesh_lib.use_mesh(mesh):
+            params, load = deepseek_v2.balance_routers(
+                variant.state["params"], dsv2_batch["tokens"], dsv2_cfg)
+        _, m = variant.step_fn({**variant.state, "params": params}, dsv2_batch)
+        counters = np.asarray(m["counters"])
+        n_load = len(deepseek_v2.STEP_FIELDS) - 1
+        dsv2 = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+                "seq_len": dsv2_cfg.seq_len,
+                "layer_pattern": [d for d in layer_pattern_decisions()
+                                  if d["pattern"] == dsv2_cfg.pattern],
+                "remat_policy": [d for d in remat_policy_decisions()
+                                 if d["n_layer"] == dsv2_cfg.n_layer
+                                 and d["seq"] == dsv2_cfg.seq_len],
+                "flash_tiling": [d for d in flash_tiling_decisions()
+                                 if (d["hd"], d["hd_v"]) == (
+                                     dsv2_cfg.qk_dim, dsv2_cfg.v_head_dim)],
+                "expert_load": load,
+                "step_load": counters[:, :n_load].tolist(),
+                # the last column holds the float32's bits
+                "balance_loss": np.ascontiguousarray(
+                    counters[:, n_load]).view(np.float32).tolist()}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -378,13 +423,14 @@ def train_loop(config: Dict[str, Any]) -> None:
         "hybrid": hybrid,
         "sala": sala,
         "lfm2": lfm2,
+        "dsv2": dsv2,
     }})
 
 
 def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         use_tpu: bool, seed: int = 0, eva_model=None,
         hybrid_model=None, sala_model=None,
-        lfm2_model=None) -> List[Dict[str, Any]]:
+        lfm2_model=None, dsv2_model=None) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -407,6 +453,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "per_chip_batch": per_chip_batch, "seed": seed,
             "eva_model": eva_model, "hybrid_model": hybrid_model,
             "sala_model": sala_model, "lfm2_model": lfm2_model,
+            "dsv2_model": dsv2_model,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -523,6 +570,24 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if dropped:
             bad.append(f"the LFM2-MoE step's expert layers dropped {dropped} "
                        "(token, choice) pairs")
+    dsv2 = summary.get("dsv2")
+    if dsv2 is not None:
+        if not (math.isfinite(dsv2["loss"]) and math.isfinite(dsv2["grad_norm"])):
+            bad.append(f"the DeepSeek-V2 step's loss {dsv2['loss']} or "
+                       f"grad_norm {dsv2['grad_norm']} is not finite")
+        if not dsv2["layer_pattern"] or not dsv2["remat_policy"]:
+            bad.append("the DeepSeek-V2 step recorded no model/layer_pattern "
+                       "or no model/remat_policy event for its layers")
+        if not dsv2["expert_load"]:
+            bad.append("the DeepSeek-V2 step recorded no model/expert_load "
+                       "event")
+        if {d["kernel"] for d in dsv2["flash_tiling"]} != {"fwd", "bwd"}:
+            bad.append("the DeepSeek-V2 step recorded no ops/flash_tiling "
+                       "decision of both kernels at its two widths")
+        # ~1 a layer where the router is near balance, never 0 or below
+        if not all(0.5 < b < 8.0 for b in dsv2["balance_loss"]):
+            bad.append("the DeepSeek-V2 step said balance losses "
+                       f"{dsv2['balance_loss']} of its expert layers")
     return bad
 
 
@@ -559,10 +624,10 @@ def check_device(summary: Dict[str, Any], model_cfg, per_chip_batch: int,
     # ... each for the kernel pair its head width takes (the worker's record
     # holds the other steps' attention layers too)
     for d in summary["flash_tiling"]:
-        if d["layout"] != kernel_layout(d["hd"]):
+        if d["layout"] != kernel_layout(d["hd"], d["hd_v"]):
             bad.append(f"flash {d['kernel']} kernel traced for {d['layout']} "
-                       f"operands at hd={d['hd']}, where the rule says "
-                       f"{kernel_layout(d['hd'])}")
+                       f"operands at hd={d['hd']} / {d['hd_v']}, where the "
+                       f"rule says {kernel_layout(d['hd'], d['hd_v'])}")
     return bad
 
 
@@ -674,7 +739,8 @@ def main() -> int:
 
     import ray_tpu
     from ray_tpu.core.resources import tpu_device_files
-    from ray_tpu.models import gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h
+    from ray_tpu.models import (deepseek_v2, gpt2, lfm2_moe, llama,
+                                minicpm_sala, nemotron_h)
     from ray_tpu.ops.sparse_attention import SparseSizes
 
     model_cfg = gpt2.gpt2_124m()
@@ -702,6 +768,12 @@ def main() -> int:
         vocab_size=4096, seq_len=2048, pattern="DACCC", first_layer=1,
         d_model=1024, n_head=16, n_kv_head=4, d_ff=2816, held_count=16,
         d_expert=768, remat=True)
+    # DeepSeek-V2-Lite's layers 0-2 at half the width: latent attention at
+    # the published head widths (q·k 192, v 128: the S-minor flash pair at
+    # unequal widths) under YaRN, 16 of 64 experts held, top-6, two shared
+    dsv2_cfg = deepseek_v2.DeepseekV2Config(
+        vocab_size=4096, seq_len=2048, n_layer=3, d_model=1024, n_head=8,
+        d_ff=2816, held_count=16, d_expert=704, remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -714,7 +786,7 @@ def main() -> int:
         rows = run(model_cfg, steps=STEPS, per_chip_batch=PER_CHIP_BATCH,
                    num_devices=chips, use_tpu=True, eva_model=eva_cfg,
                    hybrid_model=hybrid_cfg, sala_model=sala_cfg,
-                   lfm2_model=lfm2_cfg)
+                   lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -730,7 +802,8 @@ def main() -> int:
     story, missing = session_story(record)
     failures += missing
     step_loads = {}
-    for what, key in (("hybrid", "hybrid"), ("LFM2-MoE", "lfm2")):
+    for what, key in (("hybrid", "hybrid"), ("LFM2-MoE", "lfm2"),
+                      ("DeepSeek-V2", "dsv2")):
         step_loads[key], missing = step_load_line(record, summary[key], what)
         failures += missing
 
@@ -829,6 +902,32 @@ def main() -> int:
     print(f"LFM2-MoE step ({lfm2_cfg.pattern} of {lfm2_cfg.d_model}, "
           f"{summary['device_count']}x{lfm2['seq_len']} tokens, remat): loss "
           f"{lfm2['loss']:.4f} grad_norm {lfm2['grad_norm']:.4f}")
+    dsv2 = summary["dsv2"]
+    for d in dsv2["layer_pattern"]:
+        print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
+              f"{d['groups']}")
+    for d in dsv2["flash_tiling"]:
+        print(f"flash tiling: {d['kernel']} rows={d['rows']} Sq={d['Sq']} "
+              f"Skv={d['Skv']} hd={d['hd']} hd_v={d['hd_v']} -> block_q="
+              f"{d['block_q']} block_k={d['block_k']} vmem_estimate="
+              f"{d['vmem_estimate']} layout={d['layout']}")
+    for d in dsv2["remat_policy"]:
+        print(f"DeepSeek-V2 remat policy: {d['n_layer']} layers of two kinds, "
+              f"batch={d['batch']} seq={d['seq']}: saved={d['saved']} "
+              f"({d['saved_bytes'] / gib:.2f} GiB of {d['budget_bytes'] / gib:.2f}"
+              f" left by the backward's phase {d['phase']!r})")
+    for e in dsv2["expert_load"]:
+        print(f"DeepSeek-V2 expert load: published layer {e['layer']}: "
+              f"{e['pairs']} pairs of {e['tokens']} tokens on the held "
+              f"experts (max {e['max_per_expert']}, mean "
+              f"{e['mean_per_expert']:.1f} an expert), {e['buffer_passes']} "
+              f"pass(es) over a buffer of {e['buffer_rows']} rows, dropped "
+              f"{e['pairs_dropped']}")
+    print("\n".join(step_loads["dsv2"]))
+    print(f"DeepSeek-V2 step ({dsv2_cfg.pattern} of {dsv2_cfg.d_model}, "
+          f"{summary['device_count']}x{dsv2['seq_len']} tokens, remat): loss "
+          f"{dsv2['loss']:.4f} grad_norm {dsv2['grad_norm']:.4f}, balance "
+          f"loss a layer {[round(b, 4) for b in dsv2['balance_loss']]}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

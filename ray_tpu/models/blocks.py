@@ -253,7 +253,8 @@ def run_pattern(block_fns: Dict[str, Callable], pattern: str, x,
     serves the training forward too: a checkpoint_kinds block may return an
     aux, and one of integers (a layer's counters) costs the backward nothing
     — a ``checkpoint``'s integer outputs and a scan's integer ``ys`` need no
-    residual and have no cotangent."""
+    residual and have no cotangent. A float32 scalar a layer (aux_column: a
+    term of the loss) rides the same seam and does have one."""
     auxes = []
     for (sub, reps), group in zip(pattern_groups(pattern), stacks, strict=True):
         per_rep = {kind: sub.count(kind) for kind in dict.fromkeys(sub)}
@@ -296,20 +297,45 @@ class StepCounters(NamedTuple):
     static: Callable[[int], Dict[str, int]]   # a batch's tokens → what every
                                     # step's event says besides (sizes that
                                     # the numbers are read against)
+    # the columns that are a float32's BITS (a loss term a layer: the array
+    # stays one int32 output; whoever decodes it views these as float32)
+    float_fields: Tuple[str, ...] = ()
 
 
-def packed_aux(auxes: Sequence[Sequence[Any]], fields: Sequence[str]):
+def packed_aux(auxes: Sequence[Sequence[Any]], fields: Sequence[str],
+               float_fields: Sequence[str] = ()):
     """run_pattern's ``auxes`` (several patterns' joined, in the order they
     ran) as one int32 array [layers with an aux, len(fields)], the layers in
     the order they are applied: a layer's aux is a dict of int32 scalars
-    (stacked over a scan's repeats) or None."""
+    (stacked over a scan's repeats) or None. A field of ``float_fields`` is
+    a float32 a layer and goes in as its bits, a constant to AD."""
+    def column(a, f):
+        if f not in float_fields:
+            return a[f]
+        return lax.bitcast_convert_type(
+            lax.stop_gradient(a[f]).astype(jnp.float32), jnp.int32)
+
     rows = []
     for aux in auxes:
-        have = [jnp.stack([a[f] for f in fields], axis=-1)
+        have = [jnp.stack([column(a, f) for f in fields], axis=-1)
                 for a in aux if a is not None]
         if have:        # [repeats, layers of the sub-pattern, fields], or one
             rows.append(jnp.stack(have, axis=-2).reshape(-1, len(fields)))
     return jnp.concatenate(rows).astype(jnp.int32)
+
+
+def aux_column(auxes: Sequence[Sequence[Any]], field: str):
+    """One field of run_pattern's ``auxes`` as an array [layers with an aux],
+    in the order the layers are applied, in its own dtype and differentiable:
+    how a float32 loss term a layer (a balance loss) leaves the layer loop —
+    a scan's float ``ys`` — to be added to the loss inside what the step
+    differentiates."""
+    rows = []
+    for aux in auxes:
+        have = [a[field] for a in aux if a is not None]
+        if have:        # [repeats, layers of the sub-pattern], or one a layer
+            rows.append(jnp.stack(have, axis=-1).reshape(-1))
+    return jnp.concatenate(rows)
 
 
 def record_layer_pattern(pattern: str) -> None:

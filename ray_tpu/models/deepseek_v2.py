@@ -1,0 +1,742 @@
+"""DeepSeek-V2-family decoder in pure JAX: latent attention over a dense or a
+shared + routed-experts feed-forward half.
+
+Sixth model family beside GPT-2, LLaMA, Nemotron-H, MiniCPM-SALA and
+LFM2-MoE. Every layer is a pre-normed pair, ``h = x + MLA(RMSNorm(x))``,
+``x' = h + FF(RMSNorm(h))``, of one of two kinds (one character a layer of
+``cfg.pattern``, from the published ``first_k_dense_replace``):
+
+- ``D`` — latent attention + a dense SwiGLU MLP (the leading layers);
+- ``E`` — latent attention + a mixture of experts.
+
+**Multi-head latent attention** (arXiv:2405.04434 §2.1; no query
+compression, the Lite model's ``q_lora_rank`` null): ``q = u·W_q`` →
+``[q_nope | q_pe]`` a head; ``[c | k_pe] = u·W_kva``, ``c ← RMSNorm(c)``;
+``[k_nope | v] = c·W_kvb`` a head; ``k_pe`` is ONE head for all of them.
+RoPE turns ``q_pe`` and ``k_pe`` only — the last ``qk_rope_dim`` channels of
+the q·k width — with YaRN's frequencies (parts.yarn_inv_freq);
+``k = [k_nope | k_pe]``; causal softmax of ``q·kᵀ · s``, ``s = (nope +
+rope)^-½ · m²`` with YaRN's ``m = 0.1 · mscale_all_dim · ln(factor) + 1``;
+``· v`` at ``v_head_dim``; ``· W_o``. q and k are ``nope + rope`` wide (192)
+and v and o ``v_head_dim`` (128): the flash kernels read each at its own
+width (ops/attention.py, the S-minor pair — kernel_layout says why), in
+parts.head_layout's order with no transpose at their edge. The rotary
+channels of ``W_q`` and ``W_kva`` are stored de-interleaved (pairs (i, i +
+rope/2)): the published checkpoint's (2i, 2i + 1) order is a permutation of
+those columns, the same for q and k, which q·k does not see.
+
+The feed-forward halves: the dense ``(silu(u·W₁) ⊙ u·W₃)·W₂``, and the
+expert layer (ops/moe.gated_moe): ``p = softmax(u·W_g)`` in float32 over all
+``n_experts``, the ``top_k`` largest chosen, **gates = the chosen p as they
+are** (``norm_topk_prob`` false) · ``routed_scaling``, experts of the dense
+MLP's form at ``d_expert``, beside ONE shared expert of the same form at
+``n_shared · d_expert`` that every token takes. Training adds, an expert
+layer, ``aux_loss_alpha`` × the sequence-wise balance loss
+(ops/moe.balance_loss): it leaves the layer loop as a float32 a layer beside
+the layer's counters and is added to the loss inside ``loss_fn``, so the
+step's ``jax.grad`` sees it. There is no selection bias: a benchmark's run
+on freshly drawn weights has its routers balanced once at set-up by that
+same loss (balance_routers — set-up's alone: no training path calls it).
+The head is untied, after an RMSNorm.
+
+It runs on the shared machinery: ``blocks.run_pattern`` /
+``blocks.checkpoint_kinds`` (ONE remat rule over the two kinds), parts'
+RMSNorm, RoPE, residual add, weight cast inside the loop, causal attention,
+the rows an MLP and a head take at a time and the chunked head + loss;
+ops/moe.py's dispatch, shared with the Nemotron-H and LFM2 families' expert
+layers; tracing/names.py's scopes and residuals.
+
+The config states the chip's SHARE of a deployment beside the published
+sizes, as LFM2MoEConfig does: which routed experts and how many vocabulary
+rows are held here, and which published layer the pattern starts at.
+Routing is over all ``n_experts`` at the published top-k; what absent
+experts would have added is left out: the shares' routed parts and
+everything a chip computes whole, counted once, add up to the uncut layer
+(tests/test_deepseek_v2.py).
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass, replace
+from functools import partial
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.models import blocks, parts
+from ray_tpu.ops import moe
+from ray_tpu.tracing import get_buffer, names as scopes
+
+KINDS = "DE"
+EXPERTS = {"D": False, "E": True}
+# a softmax over all the experts whose chosen probabilities gate as they are
+RULE = moe.Rule(scoring="softmax", normalise=False)
+# what loss_fn hands out of a step a layer: the dispatch's counters and the
+# balance loss's value (float32 bits in the int32 array)
+STEP_FIELDS = scopes.STEP_EXPERT_LOAD_ARGS + (scopes.STEP_BALANCE_LOSS,)
+
+
+@dataclass(frozen=True)
+class DeepseekV2Config:
+    vocab_size: int = 102400          # rows of the embedding / head held here
+    seq_len: int = 4096
+    n_layer: int = 27                 # layers run here
+    first_layer: int = 0              # the published index of the first
+    first_k_dense: int = 1            # published layers below this are dense
+    d_model: int = 2048
+    n_head: int = 16
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10_000.0
+    # YaRN (rope_scaling): factor 1 is plain RoPE
+    rope_factor: float = 40.0
+    rope_original_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.707
+    rope_mscale_all_dim: float = 0.707
+    d_ff: int = 10944                 # the dense layers' SwiGLU hidden
+    # the expert layers: the router is n_experts wide; ids held_first … +
+    # held_count − 1 are computed here
+    n_experts: int = 64
+    top_k: int = 6
+    held_first: int = 0
+    held_count: int = 64
+    d_expert: int = 1408
+    n_shared: int = 2                 # one shared SwiGLU of n_shared · d_expert
+    routed_scaling: float = 1.0
+    aux_loss_alpha: float = 0.001
+    init_std: float = 0.02            # initializer_range, every matrix
+    rms_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    remat: bool = False
+    attention_impl: str = "auto"
+
+    def __post_init__(self):
+        if not isinstance(self.remat, bool):
+            raise ValueError(f"remat must be True or False; got {self.remat!r}")
+        if self.n_layer < 1:
+            raise ValueError("n_layer must be at least 1")
+        if not 0 <= self.held_first <= self.n_experts - self.held_count:
+            raise ValueError(
+                f"held experts {self.held_first}…+{self.held_count} are not "
+                f"among {self.n_experts}")
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError("top_k must be in [1, n_experts]")
+        if self.qk_rope_dim % 2:
+            raise ValueError("qk_rope_dim must be even")
+        if self.vocab_size % 128:
+            raise ValueError("vocab_size (the rows held here) must be a "
+                             "multiple of 128")
+        if self.rope_mscale != self.rope_mscale_all_dim:
+            raise ValueError(
+                "mscale != mscale_all_dim scales cos and sin by their ratio, "
+                "which no published config of the family does and "
+                "parts.rope does not")
+
+    @property
+    def pattern(self) -> str:
+        return "".join("D" if self.first_layer + i < self.first_k_dense
+                       else "E" for i in range(self.n_layer))
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def held(self) -> moe.Held:
+        return moe.Held(self.held_first, self.held_count)
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_dim^-½ · m²``: YaRN's attention factor on q AND k."""
+        m = (0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
+             if self.rope_factor > 1 else 1.0)
+        return m * m / math.sqrt(self.qk_dim)
+
+
+def deepseek_v2_tiny(**overrides) -> DeepseekV2Config:
+    """Test-size config: the leading dense layer and a run of expert layers,
+    q·k and v of unequal widths."""
+    return replace(DeepseekV2Config(
+        vocab_size=256, seq_len=64, n_layer=4, d_model=64, n_head=4,
+        kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+        rope_original_len=16, d_ff=160, n_experts=16, top_k=4, held_first=4,
+        held_count=8, d_expert=48, n_shared=2), **overrides)
+
+
+# --------------------------------------------------------------------------- #
+# Parameters
+# --------------------------------------------------------------------------- #
+
+_ATTN_WEIGHTS = ("wq", "wkv_a", "wkv_b", "wo")
+_DENSE_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _matmul_weights(kind: str) -> Tuple[str, ...]:
+    """What a layer of ``kind`` takes in the compute dtype (the router and
+    the norms' gains stay as they are stored)."""
+    return _ATTN_WEIGHTS + (moe.GATED_EXPERT + moe.GATED_SHARED_EXPERT
+                            if EXPERTS[kind] else _DENSE_WEIGHTS)
+
+
+def _group_counts(pattern: str):
+    """[{kind: layers of it}] a run of blocks.pattern_groups(pattern)."""
+    return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
+            for sub, reps in blocks.pattern_groups(pattern)]
+
+
+def _layer_init(rng, n: int, kind: str, cfg: DeepseekV2Config):
+    """``n`` stacked layers of ``kind``: the two pre-norms, latent
+    attention's tensors and the feed-forward half's."""
+    D, H, pd, std = cfg.d_model, cfg.n_head, cfg.param_dtype, cfg.init_std
+    k_ff, *k = jax.random.split(rng, 8)
+    k = iter(k)
+
+    def normal(shape):
+        return (jax.random.normal(next(k), shape) * std).astype(pd)
+
+    p = {"attn_norm": jnp.ones((n, D), pd), "ffn_norm": jnp.ones((n, D), pd),
+         "wq": normal((n, D, H, cfg.qk_dim)),
+         # the published kv_a_proj_with_mqa: the latent, then the one k_pe
+         "wkv_a": normal((n, D, cfg.kv_lora_rank + cfg.qk_rope_dim)),
+         "kv_norm": jnp.ones((n, cfg.kv_lora_rank), pd),
+         # the published kv_b_proj: a head's k_nope, then its v
+         "wkv_b": normal((n, cfg.kv_lora_rank, H,
+                          cfg.qk_nope_dim + cfg.v_head_dim)),
+         "wo": normal((n, H, cfg.v_head_dim, D))}
+    if EXPERTS[kind]:
+        p.update(moe.gated_moe_init(
+            k_ff, n, D, cfg.n_experts, cfg.held_count, cfg.d_expert, std, std,
+            pd, selection_bias=False, d_shared=cfg.n_shared * cfg.d_expert))
+    else:
+        p.update(w_gate=normal((n, D, cfg.d_ff)), w_up=normal((n, D, cfg.d_ff)),
+                 w_down=normal((n, cfg.d_ff, D)))
+    return p
+
+
+def _stack_init(rng, pattern: str, cfg: DeepseekV2Config):
+    """The layers of ``pattern`` as blocks.run_pattern takes them: one entry a
+    run of the pattern, a kind's layers of the run stacked in their order."""
+    groups = _group_counts(pattern)
+    out = []
+    for counts, group_key in zip(groups, jax.random.split(rng, len(groups))):
+        keys = dict(zip(KINDS, jax.random.split(group_key, len(KINDS))))
+        out.append({kind: _layer_init(keys[kind], n, kind, cfg)
+                    for kind, n in counts.items()})
+    return out
+
+
+_LAYER_AXES = {
+    "attn_norm": ("layers", "embed"), "ffn_norm": ("layers", "embed"),
+    "wq": ("layers", "embed", "heads", "kv"),
+    "wkv_a": ("layers", "embed", None), "kv_norm": ("layers", None),
+    "wkv_b": ("layers", None, "heads", "kv"),
+    "wo": ("layers", "heads", "kv", "embed"),
+    "w_gate": ("layers", "embed", "mlp"), "w_up": ("layers", "embed", "mlp"),
+    "w_down": ("layers", "mlp", "embed"),
+    **moe.gated_moe_logical_axes(),
+}
+
+
+def logical_axes(cfg: DeepseekV2Config) -> Dict[str, Any]:
+    layers = jax.eval_shape(
+        lambda: _stack_init(jax.random.PRNGKey(0), cfg.pattern, cfg))
+    return {"wte": ("vocab", "embed"),
+            "blocks": [{kind: {name: _LAYER_AXES[name] for name in stack}
+                        for kind, stack in group.items()} for group in layers],
+            "final_norm": ("embed",),
+            "lm_head": ("embed", "vocab")}
+
+
+def mesh_rules(cfg: DeepseekV2Config, mesh) -> Dict[str, str]:
+    """What this config needs of this mesh: no rule beyond the defaults, and
+    the refusal of the axes no code here runs over."""
+    for axis, why in (
+            ("ep", "the expert layer computes the experts the config says it "
+                   "holds and no all-to-all exchanges tokens"),
+            ("tp", "the latent, the one shared k_pe and the held experts' "
+                   "hidden width are not divided here"),
+            ("pp", "a pattern of kinds under a stage schedule"),
+            ("cp", "the flash kernels hold a row's keys whole")):
+        if mesh.shape.get(axis, 1) > 1:
+            raise NotImplementedError(
+                f"{axis} > 1 is not implemented for the DeepSeek-V2 family "
+                f"({why}); use a {axis}=1 mesh")
+    return {}
+
+
+def init(cfg: DeepseekV2Config, rng: jax.Array) -> Dict[str, Any]:
+    k = jax.random.split(rng, 3)
+
+    def normal(key, shape):
+        return (jax.random.normal(key, shape) * cfg.init_std
+                ).astype(cfg.param_dtype)
+
+    return {"wte": normal(k[0], (cfg.vocab_size, cfg.d_model)),
+            "blocks": _stack_init(k[1], cfg.pattern, cfg),
+            "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
+            "lm_head": normal(k[2], (cfg.d_model, cfg.vocab_size))}
+
+
+def param_count(cfg: DeepseekV2Config) -> int:
+    """Every leaf is a parameter a step moves (no buffer: this router has no
+    selection bias)."""
+    shapes = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(shapes))
+
+
+# --------------------------------------------------------------------------- #
+# Forward
+# --------------------------------------------------------------------------- #
+
+def rope_inv_freq(cfg: DeepseekV2Config) -> np.ndarray:
+    """The rotary channels' ``qk_rope_dim`` / 2 frequencies: YaRN's blend, or
+    plain ``theta^(-2i/dim)`` at factor 1."""
+    if cfg.rope_factor > 1:
+        return parts.yarn_inv_freq(
+            cfg.qk_rope_dim, cfg.rope_theta, cfg.rope_factor,
+            cfg.rope_original_len, cfg.rope_beta_fast, cfg.rope_beta_slow)
+    half = cfg.qk_rope_dim // 2
+    return (cfg.rope_theta ** (-np.arange(half) / half)).astype(np.float32)
+
+
+def mla_operator(u, p, cfg: DeepseekV2Config):
+    """u [B, S, D] (normed) → latent attention's output [B, S, D] float32."""
+    nope, rope_dim, hv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    rank = cfg.kv_lora_rank
+    layout = parts.head_layout(cfg.qk_dim, hv)
+    heads = layout.replace("d", "k")                    # the einsums' names
+    s_minor, width = heads[-1] == "s", heads.index("k")
+    one_head = "bks" if s_minor else "bsk"              # k_pe: no head dim
+    rotate = partial(parts.rope, positions=jnp.arange(u.shape[1]),
+                     theta=cfg.rope_theta, s_minor=s_minor,
+                     inv_freq=rope_inv_freq(cfg))
+
+    with jax.named_scope(scopes.MLA_LATENT):
+        # the two halves of each joint projection are slices of the WEIGHT:
+        # no activation is cut in two
+        q = checkpoint_name(
+            rotate(jnp.einsum(f"bsd,dhk->{heads}", u, p["wq"]),
+                   span=(nope, nope + rope_dim)), scopes.RES_Q)
+        c = checkpoint_name(parts.rmsnorm(
+            jnp.einsum("bsd,dc->bsc", u, p["wkv_a"][:, :rank]),
+            p["kv_norm"], cfg.rms_eps), scopes.RES_MLA_C)
+        k_pe = checkpoint_name(
+            rotate(jnp.einsum(f"bsd,dk->{one_head}", u, p["wkv_a"][:, rank:])),
+            scopes.RES_MLA_KPE)
+        k_nope = jnp.einsum(f"bsc,chk->{heads}", c, p["wkv_b"][..., :nope])
+        v = checkpoint_name(
+            jnp.einsum(f"bsc,chk->{heads}", c, p["wkv_b"][..., nope:]),
+            scopes.RES_V)
+        # the one k_pe serves every head: beside each head's k_nope
+        shared = jnp.expand_dims(k_pe, heads.index("h"))
+        k = checkpoint_name(jnp.concatenate(
+            [k_nope, jnp.broadcast_to(
+                shared, k_nope.shape[:width] + (rope_dim,)
+                + k_nope.shape[width + 1:])], axis=width), scopes.RES_K)
+    with jax.named_scope(scopes.ATTN):
+        o = parts.causal_attention(q, k, v, cfg.attention_impl, layout=layout,
+                                   scale=cfg.softmax_scale)
+    with jax.named_scope(scopes.PROJ):
+        return jnp.einsum(f"{heads},hkd->bsd", o, p["wo"],
+                          preferred_element_type=jnp.float32)
+
+
+def _swiglu(x, p, cfg: DeepseekV2Config):
+    """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]."""
+    with jax.named_scope(scopes.LN2):
+        h = parts.rmsnorm(x, p["ffn_norm"], cfg.rms_eps)
+    with jax.named_scope(scopes.MLP):
+        gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
+                               scopes.RES_MLP_GATE)
+        up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
+                             scopes.RES_MLP_UP)
+        return parts.residual_add(x, jnp.einsum(
+            "bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
+            preferred_element_type=jnp.float32))
+
+
+def _dense(x, p, cfg: DeepseekV2Config):
+    """The dense feed-forward half, norm and all; where one hidden tensor of
+    the whole sequence would pass parts.MLP_CHUNK_BYTES the sequence goes in
+    chunks (parts.mlp_rows), each its own ``checkpoint`` — as the llama
+    block's does, and why (models/llama.py)."""
+    B, S, D = x.shape
+    rows = parts.mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
+    if rows == S:
+        return _swiglu(x, p, cfg)
+    chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
+    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg)), chunks)
+    return out.swapaxes(0, 1).reshape(B, S, D)
+
+
+def _routing(cfg: DeepseekV2Config) -> Dict[str, Any]:
+    return dict(top_k=cfg.top_k, held=cfg.held, scaling=cfg.routed_scaling,
+                rule=RULE)
+
+
+def _experts(x, p, cfg: DeepseekV2Config, aux: Optional[str], rate=None):
+    """The expert feed-forward half → (x, what ``aux`` asks of it)."""
+    B, S, D = x.shape
+    with jax.named_scope(scopes.LN2):
+        h = parts.rmsnorm(x, p["ffn_norm"], cfg.rms_eps)
+    ht, out = h.reshape(-1, D), None
+    if aux == "balance":
+        router_w = moe.balance_router(ht, p["router_w"], cfg.top_k, S, rate,
+                                      RULE)
+        p = {**p, "router_w": router_w}
+        out = {"router_w": router_w, **moe.held_load(ht, p, **_routing(cfg))}
+    elif aux == "chosen":
+        out = moe.chosen_experts(ht, p, cfg.top_k, RULE)
+    with jax.named_scope(scopes.MOE):
+        f, load = moe.gated_moe(
+            h, p, **_routing(cfg), balance=aux == "load",
+            shared_rows=parts.mlp_rows(B, S, D, cfg.n_shared * cfg.d_expert,
+                                       x.dtype.itemsize))
+    return parts.residual_add(x, f), load if aux == "load" else out
+
+
+@jax.named_scope(scopes.BLOCK)
+def _layer(x, p, cfg: DeepseekV2Config, kind: str, aux: Optional[str] = None,
+           rate=None):
+    """One layer of ``kind``, x [B, S, D]: latent attention's residual, then
+    the feed-forward half's. With ``aux`` the result is (x, aux's value),
+    None for a dense layer: ``"load"`` — the training forward's: what the
+    batch sends the held experts, as the dispatch that runs the passes has
+    it, and the layer's balance loss (moe.routed_experts) —; in a forward of
+    its own, no backward, ``"balance"`` — an expert layer first takes one
+    round of balancing its router on this input, at ``rate``
+    (moe.balance_router); the router and what the input then sends the held
+    experts (moe.held_load) —, ``"chosen"`` — the set each token chose,
+    [T, n_experts] bool."""
+    p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype, _matmul_weights(kind))}
+    with jax.named_scope(scopes.LN1):
+        u = parts.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
+    x = checkpoint_name(parts.residual_add(x, mla_operator(u, p, cfg)),
+                        scopes.RES_MID)
+    if EXPERTS[kind]:
+        x, out = _experts(x, p, cfg, aux, rate)
+    else:
+        x, out = _dense(x, p, cfg), None
+    return (x, out) if aux else x
+
+
+def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
+                ) -> Tuple[parts.BlockShard, Dict[str, blocks.KindShard]]:
+    """This config's layers on one chip of ``mesh``, for the remat rule: the
+    model's shard (stream, head, rows at a time) and, a kind, how often it is
+    applied, what a layer of it may keep and what its backward holds at once
+    — latent attention's residual set, which waits while the feed-forward
+    half's backward runs, beside that half's own — and its weight
+    gradients."""
+    a = jnp.dtype(cfg.dtype).itemsize
+    D, F, Fe, H = cfg.d_model, cfg.d_ff, cfg.d_expert, cfg.n_head
+    Fs = cfg.n_shared * cfg.d_expert
+    hd, hv, rank = cfg.qk_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    flash = parts.is_flash(cfg.attention_impl, mesh)
+    base = parts.shard_block(parts.BlockShard(
+        batch=global_batch, seq=seq, d_model=D, heads=H, head_dim=hd, d_ff=F,
+        vocab=cfg.vocab_size, dtype_bytes=a, flash=flash, dense_mlp=False,
+        mlp_hidden=(scopes.RES_MLP_GATE, scopes.RES_MLP_UP),
+        head_rows=parts.head_rows(global_batch, seq, cfg.vocab_size, 1),
+        mlp_rows=parts.mlp_rows(global_batch, seq, D, F, a),
+        cast_in_loop=True), mesh)
+    tokens = base.batch * base.seq
+    C = blocks.RematCandidate
+
+    # latent attention. The latent c and the one k_pe are 576 numbers a
+    # token that stand for H · (hd + hv) of k and v: kept, k and v are one
+    # up-projection from c away (priced so); q costs its projection; the
+    # kernel's o and lse its two products over the causal half at their own
+    # widths; the stream after the residual the out-projection
+    latent = rank + cfg.qk_rope_dim
+    attn_kept = [
+        C((scopes.RES_MLA_C, scopes.RES_MLA_KPE), tokens * latent * a,
+          2 * tokens * D * latent),
+        C((scopes.RES_Q,), tokens * H * hd * a, 2 * tokens * D * H * hd),
+        C((scopes.RES_K,), tokens * H * hd * a,
+          2 * tokens * rank * H * cfg.qk_nope_dim),
+        C((scopes.RES_V,), tokens * H * hv * a, 2 * tokens * rank * H * hv),
+        C((scopes.RES_MID,), tokens * D * a, 2 * tokens * H * hv * D)]
+    if flash:
+        attn_kept.append(C(
+            (scopes.RES_FLASH_O, scopes.RES_FLASH_LSE),
+            tokens * H * (hv * a + 4),
+            base.batch * H * base.seq * base.seq
+            * (max(hd, parts.MXU) + max(hv, parts.MXU))))
+    attn_params = D * H * hd + D * latent + rank * H * (cfg.qk_nope_dim + hv) \
+        + H * hv * D
+    # its backward holds four tensors of the stream's width, q, k and their
+    # gradients, v, o and theirs, and the weights cast twice
+    attn_set = a * (tokens * (4 * D + 4 * H * hd + 4 * H * hv)
+                    + 2 * 2 * attn_params)
+
+    # the feed-forward halves, as the LFM2 family prices them: the dense
+    # one's hidden tensors where it is not chunked; what the routing decided
+    # and the shared expert's two hidden tensors
+    dense_kept = tuple(
+        C((name,), tokens * F * a, 2 * tokens * D * F)
+        for name in base.mlp_hidden) if base.mlp_rows in (0, base.seq) else ()
+    dense_set = a * (base.batch * (base.mlp_rows or base.seq) * 5 * F
+                     + 2 * 3 * D * F)
+    rows = moe.row_buffer(tokens, cfg.n_experts, cfg.top_k, cfg.held_count)
+    passes = moe.buffer_passes(tokens, cfg.n_experts, cfg.top_k,
+                               cfg.held_count)
+    shared_rows = parts.mlp_rows(base.batch, base.seq, D, Fs, a)
+    experts_kept = (
+        C((scopes.RES_MOE_SCORES,), tokens * cfg.n_experts * 4,
+          3 * 2 * tokens * D * cfg.n_experts),
+        C((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
+          tokens * _sort_ops(cfg.n_experts, operands=2)),
+        C((scopes.RES_MOE_PAIR_KEY, scopes.RES_MOE_PAIR_GATE),
+          passes * rows * 8, _sort_ops(tokens * cfg.held_count, operands=2)),
+    ) + (tuple(C((name,), tokens * Fs * a, 2 * tokens * D * Fs)
+               for name in (scopes.RES_MOE_SHARED_GATE,
+                            scopes.RES_MOE_SHARED_UP))
+         if shared_rows in (0, base.seq) else ())
+    expert_weights = 3 * cfg.held_count * D * Fe
+    experts_set = (tokens * D * (2 * a + 8) + tokens * cfg.n_experts * 12
+                   + a * rows * (2 * D + 6 * Fe)
+                   + (a + 4) * expert_weights
+                   + a * (base.batch * (shared_rows or base.seq) * 5 * Fs
+                          + 2 * 3 * D * Fs))
+
+    kinds = {}
+    for kind in dict.fromkeys(cfg.pattern):
+        ff_kept, ff_set = ((experts_kept, experts_set) if EXPERTS[kind]
+                           else (dense_kept, dense_set))
+        kinds[kind] = blocks.KindShard(
+            cfg.pattern.count(kind), tuple(attn_kept) + ff_kept,
+            attn_set + ff_set)
+    chips = mesh.devices.size if mesh is not None else 1
+    return base, {k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
+                  for k, v in _one_candidate_a_name(kinds).items()}
+
+
+def _one_candidate_a_name(kinds: Dict[str, blocks.KindShard]
+                          ) -> Dict[str, blocks.KindShard]:
+    """``kinds`` with every set of names a candidate of ONE kind (as
+    models/lfm2_moe.py's, and for its reason: a checkpoint policy keeps a
+    NAME in every layer that has it, and latent attention's names are in
+    both kinds): each shared set goes to the kind applied most, at the bytes
+    and operations of all the layers that have it, spread over that kind's
+    applications."""
+    layers: Dict[Tuple[str, ...], Dict[str, blocks.RematCandidate]] = {}
+    for kind, shard in kinds.items():
+        for c in shard.candidates:
+            layers.setdefault(c.names, {})[kind] = c
+    kept: Dict[str, list] = {kind: [] for kind in kinds}
+    for names, by_kind in layers.items():
+        carrier = max(by_kind, key=lambda kind: kinds[kind].applications)
+        spread = [-(-sum(kinds[k].applications * c[field]
+                         for k, c in by_kind.items())
+                    // kinds[carrier].applications) for field in (1, 2, 3)]
+        kept[carrier].append(blocks.RematCandidate(names, *spread))
+    return {kind: shard._replace(candidates=tuple(kept[kind]))
+            for kind, shard in kinds.items()}
+
+
+def _sort_ops(n: int, operands: int) -> int:
+    """Operations of a sorting network over ``n`` keys (bitonic), each a
+    comparison and two selects an operand that moves."""
+    stages = math.log2(n) * (math.log2(n) + 1) / 2
+    return int(n / 2 * stages * (1 + 2 * operands))
+
+
+def _layer_bytes(cfg: DeepseekV2Config, kind: str) -> int:
+    """Bytes of one layer of ``kind``'s parameters, which its weight
+    gradients take again."""
+    layer = jax.eval_shape(
+        lambda: _layer_init(jax.random.PRNGKey(0), 1, kind, cfg))
+    return sum(math.prod(p.shape) * p.dtype.itemsize
+               for p in jax.tree.leaves(layer))
+
+
+def _block_fns(cfg: DeepseekV2Config, batch: int, seq: int,
+               aux: Optional[str] = None):
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    base, kinds = kind_shards(cfg, batch, seq, mesh_lib.current_mesh())
+    blocks.record_layer_pattern(cfg.pattern)
+    return blocks.checkpoint_kinds(
+        {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux) for kind in kinds},
+        cfg.remat, base, kinds, blocks.pattern_groups(cfg.pattern))
+
+
+def _trunk(params, tokens, cfg: DeepseekV2Config, aux: Optional[str] = None,
+           rate=None):
+    """tokens [B, S] int32 → the head's input [B, S, D] (and, with ``aux``,
+    blocks.run_pattern's: each layer's, _layer says what)."""
+    B, S = tokens.shape
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]
+    if aux in (None, "load"):       # checkpointed: a backward may follow
+        fns = _block_fns(cfg, B, S, aux)
+    else:               # a forward of its own: no backward, no checkpoint
+        fns = {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux, rate=rate)
+               for kind in KINDS}
+    out = blocks.run_pattern(fns, cfg.pattern, x, params["blocks"],
+                             with_aux=bool(aux))
+    x, auxes = out if aux else (out, None)
+    with jax.named_scope(scopes.LN_F):
+        x = parts.rmsnorm(x, params["final_norm"], cfg.rms_eps)
+    return (x, auxes) if aux else x
+
+
+def forward(params, tokens, cfg: DeepseekV2Config) -> jax.Array:
+    """tokens [B, S] int32 → logits [B, S, vocab_size]."""
+    x = _trunk(params, tokens, cfg)
+    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype))
+
+
+def loss_fn(params, tokens, targets, cfg: DeepseekV2Config,
+            counters: bool = False):
+    """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token)
+    plus ``aux_loss_alpha`` × the sum over the expert layers of the balance
+    loss: the objective, whole, inside what the step differentiates. With
+    ``counters`` (what step_counters offers a step factory: the aux of its
+    ``value_and_grad``) the result is (the loss, what each expert layer said
+    of the batch: int32 [expert layers, STEP_FIELDS], in the layers'
+    order)."""
+    x, auxes = _trunk(params, tokens, cfg, "load")
+    loss = parts.lm_head_loss(x, targets, params["lm_head"], cfg.dtype)
+    if any(EXPERTS[k] for k in cfg.pattern):
+        with jax.named_scope(scopes.MOE_AUX):
+            loss = loss + cfg.aux_loss_alpha * jnp.sum(
+                blocks.aux_column(auxes, scopes.STEP_BALANCE_LOSS))
+    if not counters:
+        return loss
+    return loss, blocks.packed_aux(auxes, STEP_FIELDS,
+                                   (scopes.STEP_BALANCE_LOSS,))
+
+
+def step_counters(cfg: DeepseekV2Config) -> Optional[blocks.StepCounters]:
+    """What ``loss_fn(..., counters=True)`` hands out of a step, or None for
+    a pattern without an expert layer. A layer's id is ``model/expert_load``'s
+    ``layer``: the published index."""
+    layers = tuple(cfg.first_layer + i for i in _expert_layers(cfg.pattern))
+    if not layers:
+        return None
+    return blocks.StepCounters(
+        scopes.EXPERT_LOAD_KIND, STEP_FIELDS, layers,
+        partial(moe.step_load_static, n_experts=cfg.n_experts,
+                top_k=cfg.top_k, held=cfg.held),
+        float_fields=(scopes.STEP_BALANCE_LOSS,))
+
+
+def flops_per_token(cfg: DeepseekV2Config) -> float:
+    """Forward + backward operations one trained token REQUIRES here: 6 per
+    matmul parameter the token meets (latent attention's four projections,
+    the router, the shared expert, the routed experts by the pairs a token
+    is expected to land on held ones, top_k · held / n_experts a layer; the
+    embedding is a gather, the head a matmul) and by shape three times the
+    forward's attention (q·k at qk_dim and p·v at v_head_dim over the causal
+    half)."""
+    D, S, H = cfg.d_model, cfg.seq_len, cfg.n_head
+    attn = (D * H * cfg.qk_dim + D * (cfg.kv_lora_rank + cfg.qk_rope_dim)
+            + cfg.kv_lora_rank * H * (cfg.qk_nope_dim + cfg.v_head_dim)
+            + H * cfg.v_head_dim * D)
+    ff = {True: D * cfg.n_experts + 3 * D * cfg.d_expert * (
+              cfg.n_shared + cfg.top_k * cfg.held_count / cfg.n_experts),
+          False: 3 * D * cfg.d_ff}
+    matmul = (sum(attn + ff[EXPERTS[k]] for k in cfg.pattern)
+              + D * cfg.vocab_size)
+    shaped = cfg.n_layer * H * (cfg.qk_dim + cfg.v_head_dim) * (S + 1) / 2
+    return 6.0 * (matmul + shaped)
+
+
+# --------------------------------------------------------------------------- #
+# What each token chose; what a batch sends the held experts
+# --------------------------------------------------------------------------- #
+
+def _expert_layers(pattern: str) -> List[int]:
+    """The expert layers' indices in the pattern, in order."""
+    return [i for i, kind in enumerate(pattern) if EXPERTS[kind]]
+
+
+def _expert_aux(pattern: str, auxes) -> list:
+    """blocks.run_pattern's auxes as one entry an expert layer, in order."""
+    out = []
+    for (sub, reps), aux in zip(blocks.pattern_groups(pattern), auxes,
+                                strict=True):
+        for r in range(reps):
+            out += [jax.tree.map(lambda t: t[r], a) if reps > 1 else a
+                    for a in aux if a is not None]
+    return out
+
+
+def chosen_experts(params, tokens, cfg: DeepseekV2Config) -> List[jax.Array]:
+    """The set each token of ``tokens`` [B, S] chose in each expert layer, in
+    the layers' order: [B·S, n_experts] bool a layer. What a reference is
+    told, so that a near-tie rounding flipped is not read as a wrong model."""
+    return _expert_aux(cfg.pattern, _trunk(params, tokens, cfg, "chosen")[1])
+
+
+def _expert_runs(pattern: str) -> List[int]:
+    """The run of blocks.pattern_groups each expert layer is in, in order (a
+    run's stack of ``E`` holds its layers in that order)."""
+    return [g for g, (sub, reps) in enumerate(blocks.pattern_groups(pattern))
+            for _ in range((sub * reps).count("E"))]
+
+
+def balance_routers(params, batches, cfg: DeepseekV2Config):
+    """(``params`` with every expert layer's router balanced on ``batches``
+    — N token arrays [B, S], or one —, what the last round's batch then
+    sends the experts held here). This family's routers have no selection bias:
+    what balances them is the balance loss, over a run's many steps, so a
+    run on freshly drawn weights starts from routers that something
+    balanced: moe.BALANCE_ROUNDS forwards of their own, round r on batch
+    r mod N, in which every expert layer's router takes one round of
+    moe.balance_router on what the layers before it — as balanced so far —
+    hand it, the rate falling from moe.BALANCE_ROUTER_RATE to 0, every other
+    weight held. The loads are the ``model/expert_load`` events
+    (tracing/names.EXPERT_LOAD_ARGS; ``layer`` is the published index),
+    recorded here. For set-up, as nemotron_h.balance_router_bias and
+    lfm2_moe.balance_router_bias are: NO TRAINING PATH CALLS IT (nor
+    ``_layer``'s ``"balance"``) — a benchmark's build and chip_smoke.py do,
+    once before the first step; a run that starts from a checkpoint, or
+    trains for long, needs neither."""
+    batches = [batches] if hasattr(batches, "ndim") else batches
+    runs = _expert_runs(cfg.pattern)
+
+    def with_routers(routers):
+        stacks = [dict(group) for group in params["blocks"]]
+        for g, w in routers.items():
+            old = stacks[g]["E"]["router_w"]
+            stacks[g]["E"] = {**stacks[g]["E"], "router_w": w.astype(old.dtype)}
+        return {**params, "blocks": stacks}
+
+    @jax.jit
+    def one_round(p, tokens, rate):
+        auxes = _expert_aux(cfg.pattern,
+                            _trunk(p, tokens, cfg, "balance", rate)[1])
+        routers: Dict[int, list] = {}
+        for g, aux in zip(runs, auxes, strict=True):
+            routers.setdefault(g, []).append(aux.pop("router_w"))
+        return {g: jnp.stack(rows) for g, rows in routers.items()}, auxes
+
+    balanced, rounds = params, moe.BALANCE_ROUNDS
+    for r in range(rounds):
+        routers, loads = one_round(
+            balanced, batches[r % len(batches)],
+            moe.BALANCE_ROUTER_RATE * (1.0 - r / rounds))
+        balanced = with_routers(routers)
+    component, name = scopes.EXPERT_LOAD.split("/")
+    events = []
+    for index, load in zip(_expert_layers(cfg.pattern),
+                           jax.device_get(loads), strict=True):
+        # (numpy scalars off the host: a count an int, a mean or share a float)
+        args = {"layer": cfg.first_layer + index, **{
+            k: load[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
+        get_buffer().record_profile(name, component=component, args=args)
+        events.append(args)
+    return balanced, events

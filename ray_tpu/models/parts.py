@@ -16,6 +16,7 @@ from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ray_tpu.models.blocks import (KindShard, RematCandidate, RematPolicy,
@@ -47,16 +48,53 @@ def rmsnorm(x, g, eps: float, unit_offset: bool = False):
     return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's ``dim`` / 2 rotary frequencies (arXiv:2309.00071, as
+    modeling_deepseek.py's DeepseekV2YarnRotaryEmbedding makes them): channel
+    pair i keeps ``theta^(-2i/dim)`` where it turns more than ``beta_fast``
+    times over the ``original_len`` positions the model was trained at, takes
+    it divided by ``factor`` where it turns fewer than ``beta_slow`` times,
+    and a linear blend of the two between (the correction range, floored and
+    ceiled: 10 and 23 of 32 at the published 64 / 10,000 / 40 / 4,096 / 32 /
+    1). Host arithmetic on sizes: a constant of the step."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    f = theta ** (-2.0 * i / dim)
+
+    def turns_at(beta):     # the (fractional) pair that turns `beta` times
+        return (dim * math.log(original_len / (2 * math.pi * beta))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim // 2 - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (f / factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float,
-         s_minor: bool = False) -> jax.Array:
+         s_minor: bool = False, *, inv_freq=None,
+         span: Optional[Tuple[int, int]] = None) -> jax.Array:
     """Rotary embedding, HF-llama convention: x [..., S, hd] with the head
     dim split as [first half, second half] (rotate_half), NOT interleaved.
     With ``s_minor`` x is [..., hd, S] (head_layout's order for a narrow
-    head) and so is the result."""
+    head) and so is the result.
+
+    What a caller may state beside ``theta``: ``inv_freq``, the frequencies
+    themselves, one a channel pair (YaRN's blend: yarn_inv_freq; ``theta`` is
+    then not read); ``span`` = (first, past-the-last) channel of the head that
+    rotates, the others passing as they are (latent attention rotates 64 of
+    its 192: cos is 1, sin 0 and the permutation empty outside the span, so
+    no tensor is sliced or put together)."""
     hd = x.shape[-2] if s_minor else x.shape[-1]
-    half = hd // 2
-    freqs = 1.0 / theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+    lo, hi = span or (0, hd)
+    half = (hi - lo) // 2
+    if inv_freq is None:
+        freqs = 1.0 / theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
+    if span is not None:
+        return _rope_span(x, angles, s_minor, lo, hi)
     # x·cos + rotate_half(x)·sin, rotate_half(x) = [-x2, x1] = x @ R with R a
     # signed permutation (exact in any dtype): a [hd, hd] matmul a head, 0.5 %
     # of a block's operations, where slicing the head dim in two makes
@@ -69,6 +107,30 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
     rot = jnp.block([[zero, eye], [-eye, zero]])                      # x @ rot
     if s_minor:
         cos, sin = cos.T, sin.T                                       # [hd, S]
+        rotated = jnp.einsum("...ds,de->...es", x, rot)
+    else:
+        rotated = jnp.einsum("...d,de->...e", x, rot)
+    return (x.astype(jnp.float32) * cos
+            + rotated.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+def _rope_span(x, angles, s_minor: bool, lo: int, hi: int):
+    """rope's x·cos + (x @ R)·sin over channels [lo, hi) of the head:
+    outside the span cos is 1, sin 0 and R empty."""
+    hd = x.shape[-2] if s_minor else x.shape[-1]
+    half = (hi - lo) // 2
+    rot = np.zeros((hd, hd), np.float32)              # x @ rot
+    i = np.arange(half)
+    first, second = lo + i, lo + half + i
+    rot[first, second], rot[second, first] = 1.0, -1.0
+    cos, sin = (jnp.concatenate([fn(angles)] * 2, axis=-1)
+                for fn in (jnp.cos, jnp.sin))
+    edges = ((0, 0), (lo, hd - hi))
+    cos = jnp.pad(cos, edges, constant_values=1.0)    # [S, hd]
+    sin = jnp.pad(sin, edges)
+    rot = jnp.asarray(rot, x.dtype)
+    if s_minor:
+        cos, sin = cos.T, sin.T                       # [hd, S]
         rotated = jnp.einsum("...ds,de->...es", x, rot)
     else:
         rotated = jnp.einsum("...d,de->...e", x, rot)
@@ -121,27 +183,31 @@ def attention_on_mesh(attention_impl: str):
     return impl, interpret, mesh
 
 
-def head_layout(head_dim: int) -> str:
+def head_layout(head_dim: int, v_dim: Optional[int] = None) -> str:
     """The axis order a block projects its heads in (a flash_attention
-    layout over b, h, s, d), from the head width alone, whatever attention
-    then runs: the order the flash kernels take at that width with no
-    transpose at their edge. A head narrower than a lane tile (GPT-2's 64)
-    goes S-minor with the heads leading, "hbds" = [H, B, hd, S] — how XLA
-    stores such a projection's output and the layer scan's saved stack of
-    it whatever the einsum says; a wider one "bhsd"."""
+    layout over b, h, s, d), from the head's widths alone (q's and k's, and
+    v's where it is another), whatever attention then runs: the order the
+    flash kernels take at those widths with no transpose at their edge
+    (ops/attention.kernel_layout: the one rule). A head narrower than a lane
+    tile (GPT-2's 64) goes S-minor with the heads leading, "hbds" = [H, B,
+    hd, S] — how XLA stores such a projection's output and the layer scan's
+    saved stack of it whatever the einsum says; one of whole tiles "bhsd"."""
     from ray_tpu.ops.attention import S_MINOR, kernel_layout
 
-    return "hbds" if kernel_layout(head_dim) == S_MINOR else "bhsd"
+    return "hbds" if kernel_layout(head_dim, v_dim) == S_MINOR else "bhsd"
 
 
-def causal_attention(q, k, v, attention_impl: str, *, layout: str = "bhsd"):
+def causal_attention(q, k, v, attention_impl: str, *, layout: str = "bhsd",
+                     scale: Optional[float] = None):
     """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd], causal (head-major layout —
     the hd-minor flash kernels' own, so the hot path has no boundary
     transposes); KH heads of k and v serve H / KH heads of q each. Another
     head-major ``layout`` (head_layout's) says where the four dims of the
-    three and of the result are. Which kernel pair runs is the head width's
+    three and of the result are. Which kernel pair runs is the head widths'
     either way; a block that hands the other pair's order pays the
-    transposes at the kernel's edge."""
+    transposes at the kernel's edge. v, and with it the result, may be of
+    another width than q and k; ``scale`` multiplies the logits (1/√hd of
+    q's width where none is given)."""
     from ray_tpu.ops.attention import flash_attention_sharded
 
     impl, interpret, mesh = attention_on_mesh(attention_impl)
@@ -152,10 +218,12 @@ def causal_attention(q, k, v, attention_impl: str, *, layout: str = "bhsd"):
         v = jnp.repeat(v, groups, axis=heads)
     if impl == "pallas":
         return flash_attention_sharded(
-            q, k, v, mesh, layout=layout, causal=True, interpret=interpret)
+            q, k, v, mesh, layout=layout, causal=True, interpret=interpret,
+            scale=scale)
     # XLA path: einsum + mask; XLA fuses the softmax chain.
     S = q.shape[layout.index("s")]
-    scale = 1.0 / math.sqrt(q.shape[layout.index("d")])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[layout.index("d")])
     at_q, at_k = layout.replace("s", "q"), layout.replace("s", "k")
     rows = layout[:2]                       # the logits keep the rows' order
     logits = jnp.einsum(f"{at_q},{at_k}->{rows}qk", q, k) * scale

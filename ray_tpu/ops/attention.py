@@ -8,14 +8,20 @@ backward pass recomputes logits blockwise from (q, k, lse) the flash-attention
 way.
 
 Design notes (TPU-first):
-- TWO kernel pairs, chosen by the head width alone (kernel_layout): where hd
-  fills the 128 lanes the operands are hd-minor, [B·H, S, hd]; where it is
-  narrower (GPT-2's 64) they are S-minor, [B·H, hd, S] — dense at any hd and
-  the way XLA stores such a head anyway, so the projections' outputs and the
-  layer scan's saved stacks go in and the gradients come out with no
-  transposing copy. The pairs share the tile rule, the VMEM arithmetic, the
-  transposed logits tile and the f32 statistics; their tiles' axes differ,
-  so each has its own BlockSpecs and loop body.
+- TWO kernel pairs, chosen by the head's widths alone (kernel_layout): where
+  they fill whole 128-lane tiles the operands are hd-minor, [B·H, S, hd];
+  where one does not (GPT-2's 64; latent attention's 192 beside 128) they
+  are S-minor, [B·H, hd, S] — dense at any width and the way XLA stores such
+  a head anyway, so the projections' outputs and the layer scan's saved
+  stacks go in and the gradients come out with no transposing copy. The
+  pairs share the tile rule, the VMEM arithmetic, the transposed logits tile
+  and the f32 statistics; their tiles' axes differ, so each has its own
+  BlockSpecs and loop body.
+- q and k have one width, ``hd``, and v and o another, ``hd_v`` (equal
+  anywhere but in latent attention: 192 and 128): every operand is read and
+  written at its own width — no v padded to q's, no q·k padded to whole
+  tiles in HBM — and the softmax scale is the caller's where it gives one
+  (PR 55).
 - The public API takes [B, S, H, hd] and transposes at the boundary (XLA
   fuses the transpose into the surrounding projection matmuls) or, with a
   head-major ``layout``, takes a pair's own order as it is. Inside, batch
@@ -25,10 +31,16 @@ Design notes (TPU-first):
 - The q/kv tile is choose_tiling's decision, from the shapes and an estimate
   of the VMEM the blocks need; callers pass no tile.
 - K/V live whole per row in VMEM (S·hd·2B ≈ 128 KiB at S=1024), so the kv
-  loop is VMEM-resident with no DMA choreography. The index maps put the
-  row first and the tile's position last: making kv (forward) or q
+  loop is VMEM-resident with no DMA choreography. Where the whole rows pass
+  Mosaic's default scoped-VMEM limit (the backward from 8,192 tokens at
+  hd 128 or 192 / 128) the call asks for its own estimate and half again,
+  up to VMEM_CEILING_BYTES of the core's 128 MiB (choose_tiling,
+  _compiler_params; PR 55): what bounds the sequence length is that ceiling
+  — a 32,768-token backward at those widths is refused. The index maps put
+  the row first and the tile's position last: making kv (forward) or q
   (backward) a third grid axis with scratch accumulators is a change of
-  those maps, not of the layout (ROADMAP D16).
+  those maps, not of the layout (ROADMAP D16: the route not taken, for rows
+  past the ceiling).
 - The logits tile is computed transposed, s^T = k·q^T: softmax statistics are
   lane-dense [1, block_q] rows and their reductions run down the sublanes.
   Logits/softmax accumulate in f32 (MXU native via preferred_element_type);
@@ -108,16 +120,23 @@ HD_MINOR = "hd_minor"     # [rows, S, hd]: dense where hd fills the 128 lanes
 S_MINOR = "s_minor"       # [rows, hd, S]: dense at any hd
 
 
-def kernel_layout(hd: int) -> str:
-    """THE rule for which kernel pair a head width takes, from the width
-    alone. A head narrower than a lane tile (GPT-2's 64) stored hd-minor
-    fills half of every tile, in HBM and in VMEM, and XLA does not store it
-    so: it writes the projections' outputs and the layer scan's saved stacks
-    S-minor, and a kernel that reads hd-minor costs a transposing copy a
-    tensor each way (PERF.md §6, PR 48). Such a head takes the S-minor pair;
-    a whole tile's worth of head (hd % 128 == 0) is dense hd-minor, which is
-    what XLA picks there, and keeps the hd-minor pair."""
-    return HD_MINOR if hd % 128 == 0 else S_MINOR
+def kernel_layout(hd: int, hd_v: Optional[int] = None) -> str:
+    """THE rule for which kernel pair a head takes, from its two widths
+    alone: ``hd`` of q and k, ``hd_v`` of v and o (``hd`` where the caller
+    gives none). A width that is not whole lane tiles (GPT-2's 64) stored
+    hd-minor fills part of every tile, in HBM and in VMEM, and XLA does not
+    store it so: it writes the projections' outputs and the layer scan's
+    saved stacks S-minor, and a kernel that reads hd-minor costs a
+    transposing copy a tensor each way (PERF.md §6, PR 48). Such a head takes
+    the S-minor pair; widths of whole tiles (% 128 == 0) are dense hd-minor,
+    which is what XLA picks there, and keep the hd-minor pair. One pair
+    serves all four operands, so ONE width that is not whole tiles decides:
+    latent attention's q and k at 192 would stand hd-minor as 256 lanes, a
+    third of them padding in HBM and in VMEM, beside a v that fills its 128 —
+    S-minor both are dense as they are (192 and 128 rows of sublanes), so
+    192 / 128 takes the S-minor pair."""
+    widths = (hd, hd if hd_v is None else hd_v)
+    return HD_MINOR if all(w % 128 == 0 for w in widths) else S_MINOR
 
 
 class Tiling(NamedTuple):
@@ -154,33 +173,37 @@ def vmem_block_bytes(shape, itemsize: int) -> int:
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int,
                   Sq: int, Skv: int, hd: int, dtype_bytes: int,
-                  layout: str = HD_MINOR) -> int:
+                  layout: str = HD_MINOR, hd_v: Optional[int] = None) -> int:
     """VMEM bytes one grid step needs, as the rule counts them: every in/out
     block twice (Pallas double-buffers them), the backward's f32 dq
     accumulator once, and one [block_k, block_q] f32 logits tile plus the
     loop's f32 accumulators. An upper bound, not Mosaic's own figure.
-    ``layout`` says which way a block lies: S-minor blocks are [hd, tile]
-    (no lane is padded at any hd), hd-minor blocks [tile, hd]."""
+    ``layout`` says which way a block lies: S-minor blocks are [width, tile]
+    (no lane is padded at any width), hd-minor blocks [tile, width]. The
+    width is ``hd`` for q, k and their gradients and ``hd_v`` (``hd`` where
+    none is given) for v, o and theirs."""
     blk = vmem_block_bytes
+    hd_v = hd if hd_v is None else hd_v
 
-    def op(rows, itemsize=dtype_bytes):         # an operand block of `rows`
-        return blk((hd, rows) if layout == S_MINOR else (rows, hd), itemsize)
+    def op(rows, width, itemsize=dtype_bytes):  # an operand block of `rows`
+        return blk((width, rows) if layout == S_MINOR else (rows, width),
+                   itemsize)
 
     tile = blk((block_k, block_q), 4)
     if kernel == "fwd":
-        io = (2 * op(block_q)                             # q, o
-              + 2 * op(Skv)                               # k, v: whole rows
+        io = (op(block_q, hd) + op(block_q, hd_v)         # q, o
+              + op(Skv, hd) + op(Skv, hd_v)               # k, v: whole rows
               + blk((1, block_q), 4))                     # lse
-        live = tile + blk((hd, block_q), 4)               # s^T; acc^T
+        live = tile + blk((hd_v, block_q), 4)             # s^T; acc^T
     else:
-        # q, do, dq: whole rows, and lse, delta; the S-minor kernel makes
+        # q, dq and do: whole rows, and lse, delta; the S-minor kernel makes
         # delta itself, from o's row
-        rows, stats = (4, 1) if layout == S_MINOR else (3, 2)
-        io = (rows * op(Sq)
-              + 4 * op(block_k)                           # k, v, dk, dv
+        o_rows, stats = (2, 1) if layout == S_MINOR else (1, 2)
+        io = (2 * op(Sq, hd) + o_rows * op(Sq, hd_v)
+              + 2 * op(block_k, hd) + 2 * op(block_k, hd_v)   # k, dk; v, dv
               + stats * blk((1, Sq), 4))
         live = (blk((hd, Sq), 4)                          # dq^T accumulator
-                + tile + 2 * op(block_k, 4))              # s^T; dk, dv
+                + tile + op(block_k, hd, 4) + op(block_k, hd_v, 4))  # s^T; dk, dv
     return 2 * io + live
 
 
@@ -207,26 +230,40 @@ def record_decision(decisions: Dict[tuple, Dict[str, Any]], event: str,
 
 
 def _record(kernel: str, rows: int, Sq: int, Skv: int, hd: int,
-            tiling: Tiling, layout: str) -> None:
+            tiling: Tiling, layout: str, hd_v: int) -> None:
     """The tiling a flash kernel is traced with, with the shapes it was
-    given and the pair it belongs to (``ops/flash_tiling``)."""
+    given (``hd`` of q and k, ``hd_v`` of v and o) and the pair it belongs
+    to (``ops/flash_tiling``)."""
     record_decision(_decisions, names.FLASH_TILING, dict(zip(
         names.FLASH_TILING_ARGS,
-        (kernel, rows, Sq, Skv, hd) + tuple(tiling) + (layout,))))
+        (kernel, rows, Sq, Skv, hd) + tuple(tiling) + (layout, hd_v))))
+
+
+def _compiler_params(tiling: Tiling):
+    """What a flash call tells Mosaic beside its grid: nothing where the
+    tiling's estimate is inside the default scoped-VMEM limit — the call is
+    then what it always was —, else that estimate and half again as the
+    call's own limit (choose_tiling lets none through that would pass
+    VMEM_CEILING_BYTES), as the EVA, scan and sparse kernels ask."""
+    if tiling.vmem_estimate <= VMEM_BUDGET_BYTES:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(
+        VMEM_CEILING_BYTES, tiling.vmem_estimate + tiling.vmem_estimate // 2))
 
 
 def choose_tiling(
     kernel: str, Sq: int, Skv: int, hd: int, dtype_bytes: int, *,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
-    layout: str = HD_MINOR,
+    layout: str = HD_MINOR, hd_v: Optional[int] = None,
 ) -> Tiling:
     """THE rule for how a flash kernel tiles its work. ``kernel`` is ``"fwd"``
     or ``"bwd"``. The keywords are a caller's explicit choices: each is kept
     (clamped to a divisor of its sequence) and the rule fills in the other.
-    Raises ``ValueError`` when no tiling of its own fits the budget.
+    Raises ``ValueError`` when no tiling of its own fits the ceiling.
     ``layout`` is the pair's (kernel_layout): the tile is along S either way,
     the sublanes of an hd-minor block and the lanes of an S-minor one, and
-    only the estimate differs.
+    only the estimate differs. ``hd`` is q's and k's width, ``hd_v`` v's and
+    o's (``hd`` where none is given).
 
     The constants, fitted on a v5e inside the `gpt2-124m` and `gpt2-xl` train
     steps and standalone at ``[8,16,2048,128]`` (PERF.md §6, PR 25):
@@ -242,36 +279,59 @@ def choose_tiling(
       forward's loop body were measured: nothing on `gpt2-124m` (step 73.99
       ms against 74.00), 0.28 % of the `gpt2-xl` step (914.8 against 917.4),
       nothing in the backward — not worth a second loop in each kernel.
-    - When a tiling does not fit VMEM_BUDGET_BYTES the rule halves the kv
-      tile, then the q tile, in turn, down to 128. What does not shrink that
-      way are the whole-row blocks (k/v in the forward; q, do, dq and the f32
-      dq accumulator in the backward): they bound the sequence length.
+    - When a tiling does not fit VMEM_BUDGET_BYTES (Mosaic's default limit)
+      the rule halves the kv tile, then the q tile, in turn, down to 128.
+      What does not shrink that way are the whole-row blocks (k/v in the
+      forward; q, do, dq — S-minor o too — and the f32 dq accumulator in the
+      backward).
+    - Where the whole rows alone pass that budget (PR 55: the backward of an
+      8,192-token row at 192 / 128 is 26 MiB of them) the tiles go back to
+      their target and the CALL asks Mosaic for its estimate and half again
+      (_compiler_params), as the EVA, scan and sparse kernels do — a v5e
+      core has 128 MiB of VMEM, and the default limit is a default. What
+      bounds the sequence length is then VMEM_CEILING_BYTES: an estimate
+      whose half again passes it is refused (the backward of a 32,768-token
+      row at those widths, 107 MiB, or at hd 128, 68 MiB). A shape that fits
+      the default budget gets the tiling it always got and no limit of its
+      own.
     """
     if kernel not in ("fwd", "bwd"):
         raise ValueError(f"unknown flash kernel {kernel!r}")
-    q_ = _pick_block(Sq, block_q or _TARGET_TILE)
-    k_ = _pick_block(Skv, block_k or _TARGET_TILE)
     # a caller who fixed both gets them: Mosaic is the judge
     explicit = block_q is not None and block_k is not None
-    while True:
-        t = Tiling(q_, k_, vmem_estimate(kernel, q_, k_, Sq, Skv, hd,
-                                         dtype_bytes, layout))
-        if explicit or t.vmem_estimate <= VMEM_BUDGET_BYTES:
-            return t
-        can_k = block_k is None and k_ > _MIN_TILE
-        can_q = block_q is None and q_ > _MIN_TILE
-        if can_k and (k_ >= q_ or not can_q):
-            k_ = _pick_block(Skv, k_ // 2)
-        elif can_q:
-            q_ = _pick_block(Sq, q_ // 2)
-        else:
-            break
+
+    def walk(limit: int) -> Tuple[Optional[Tiling], Tiling]:
+        """(the first tiling of the halving walk estimated within ``limit``,
+        the last one tried)."""
+        q_ = _pick_block(Sq, block_q or _TARGET_TILE)
+        k_ = _pick_block(Skv, block_k or _TARGET_TILE)
+        while True:
+            t = Tiling(q_, k_, vmem_estimate(kernel, q_, k_, Sq, Skv, hd,
+                                             dtype_bytes, layout, hd_v))
+            if explicit or t.vmem_estimate <= limit:
+                return t, t
+            can_k = block_k is None and k_ > _MIN_TILE
+            can_q = block_q is None and q_ > _MIN_TILE
+            if can_k and (k_ >= q_ or not can_q):
+                k_ = _pick_block(Skv, k_ // 2)
+            elif can_q:
+                q_ = _pick_block(Sq, q_ // 2)
+            else:
+                return None, t
+
+    # (the call's own limit is its estimate and half again: the largest
+    # estimate whose half again is still under the ceiling)
+    for limit in (VMEM_BUDGET_BYTES, VMEM_CEILING_BYTES * 2 // 3):
+        fits, t = walk(limit)
+        if fits is not None:
+            return fits
     raise ValueError(
-        f"flash attention {kernel}: no tiling fits the VMEM budget of "
-        f"{VMEM_BUDGET_BYTES} bytes for Sq={Sq} Skv={Skv} hd={hd} "
+        f"flash attention {kernel}: no tiling fits the VMEM ceiling of "
+        f"{VMEM_CEILING_BYTES} bytes for Sq={Sq} Skv={Skv} hd={hd} "
+        f"hd_v={hd if hd_v is None else hd_v} "
         f"({dtype_bytes}-byte operands, {layout}): the smallest tried, block_q="
         f"{t.block_q} block_k={t.block_k}, is estimated at "
-        f"{t.vmem_estimate} bytes"
+        f"{t.vmem_estimate} bytes, and a call asks for half again"
     )
 
 
@@ -310,8 +370,8 @@ def _q_block_range(kv_global, q_off_ref, block_q: int, block_k: int,
 
 def _fwd_kernel(
     q_off_ref, kv_off_ref,            # scalar prefetch: global offsets [1]
-    q_ref, k_ref, v_ref,              # [bq, hd], [Skv, hd], [Skv, hd]
-    o_ref, lse_ref,                   # [bq, hd], [1, bq]
+    q_ref, k_ref, v_ref,              # [bq, hd], [Skv, hd], [Skv, hd_v]
+    o_ref, lse_ref,                   # [bq, hd_v], [1, bq]
     *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
 ):
     qi = pl.program_id(1)
@@ -330,11 +390,11 @@ def _fwd_kernel(
     # fold the softmax scale into q once — a per-block [bk, bq] f32
     # multiply otherwise rides every inner iteration
     qs = q_ref[...] * jnp.asarray(scale, q_ref.dtype)
-    hd = qs.shape[-1]
+    hd_v = v_ref.shape[-1]
 
     def make_body(masked):
         def body(ki, carry):
-            m, l, acc = carry               # [1, bq], [1, bq], [hd, bq]
+            m, l, acc = carry               # [1, bq], [1, bq], [hd_v, bq]
             kv = pl.ds(ki * block_k, block_k)
             s = lax.dot_general(
                 k_ref[kv, :], qs, (((1,), (1,)), ((), ())),
@@ -355,13 +415,13 @@ def _fwd_kernel(
             acc = acc * alpha + lax.dot_general(
                 v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )                               # v^T·p^T = (p·v)^T, [hd, bq]
+            )                               # v^T·p^T = (p·v)^T, [hd_v, bq]
             return m_new, l, acc
         return body
 
     carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
-             jnp.zeros((hd, block_q), jnp.float32))
+             jnp.zeros((hd_v, block_q), jnp.float32))
     carry = lax.fori_loop(0, num_full, make_body(False), carry)
     m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal), carry)
     # rows with no valid kv (ring attention future chunks): l == 0 →
@@ -376,17 +436,20 @@ def _mha_forward_bhsd(
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: [B, H, S, hd] → (o [B,H,S,hd], lse [B,H,S]). Batch and head are
-    merged (a free reshape) into the one dim of independent rows the grid
-    walks. Tiles the caller leaves None are choose_tiling's."""
+    """q,k: [B, H, S, hd], v: [B, H, S, hd_v] → (o [B,H,S,hd_v], lse
+    [B,H,S]). Batch and head are merged (a free reshape) into the one dim of
+    independent rows the grid walks. Tiles the caller leaves None are
+    choose_tiling's."""
     B, H, Sq, hd = q.shape
-    Skv = k.shape[2]
+    Skv, hd_v = k.shape[2], v.shape[3]
     R = B * H
     t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize,
-                      block_q=block_q, block_k=block_k)
-    _record("fwd", R, Sq, Skv, hd, t, HD_MINOR)
+                      block_q=block_q, block_k=block_k, hd_v=hd_v)
+    _record("fwd", R, Sq, Skv, hd, t, HD_MINOR, hd_v)
     bq, bk = t.block_q, t.block_k
-    kv_row = pl.BlockSpec((None, Skv, hd), lambda g, i, *_: (g, 0, 0))
+
+    def kv_row(width):
+        return pl.BlockSpec((None, Skv, width), lambda g, i, *_: (g, 0, 0))
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
@@ -399,34 +462,36 @@ def _mha_forward_bhsd(
             grid=(R, Sq // bq),
             in_specs=[
                 pl.BlockSpec((None, bq, hd), lambda g, i, *_: (g, i, 0)),
-                kv_row, kv_row,
+                kv_row(hd), kv_row(hd_v),
             ],
             out_specs=[
-                pl.BlockSpec((None, bq, hd), lambda g, i, *_: (g, i, 0)),
+                pl.BlockSpec((None, bq, hd_v), lambda g, i, *_: (g, i, 0)),
                 pl.BlockSpec((None, 1, bq), lambda g, i, *_: (g, 0, i)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((R, Sq, hd), q.dtype),
+            jax.ShapeDtypeStruct((R, Sq, hd_v), q.dtype),
             jax.ShapeDtypeStruct((R, 1, Sq), jnp.float32),
         ],
+        compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_FWD_KERNEL,
     )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(R, Skv, hd),
-      v.reshape(R, Skv, hd))
-    return o.reshape(B, H, Sq, hd), lse.reshape(B, H, Sq)
+      v.reshape(R, Skv, hd_v))
+    return o.reshape(B, H, Sq, hd_v), lse.reshape(B, H, Sq)
 
 
 def _fwd_kernel_s_minor(
     q_off_ref, kv_off_ref,            # scalar prefetch: global offsets [1]
-    q_ref, k_ref, v_ref,              # [hd, bq], [hd, Skv], [hd, Skv]
-    o_ref, lse_ref,                   # [hd, bq], [1, bq]
+    q_ref, k_ref, v_ref,              # [hd, bq], [hd, Skv], [hd_v, Skv]
+    o_ref, lse_ref,                   # [hd_v, bq], [1, bq]
     *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
 ):
-    """_fwd_kernel on S-minor tiles: every operand tile is [hd, tile], dense
-    at any hd (the sequence fills the lanes). The logits tile and the
+    """_fwd_kernel on S-minor tiles: every operand tile is [width, tile],
+    dense at any width (the sequence fills the lanes) — hd for q and k, hd_v
+    for v and o, equal or not. The logits tile and the
     statistics are the same s^T [block_k, block_q] and [1, block_q] rows; the
-    accumulator is o^T [hd, block_q] and is stored as it is, and
+    accumulator is o^T [hd_v, block_q] and is stored as it is, and
     v · p^T is a plain product. What the layout costs is k's tile transposed
     for s^T = k^T · q (a [hd, block_k] tile a pair)."""
     qi = pl.program_id(1)
@@ -436,11 +501,11 @@ def _fwd_kernel_s_minor(
         q_global, kv_off_ref, block_q, block_k, kv_len // block_k, causal)
 
     qs = q_ref[...] * jnp.asarray(scale, q_ref.dtype)        # [hd, bq]
-    hd = qs.shape[0]
+    hd_v = v_ref.shape[0]
 
     def make_body(masked):
         def body(ki, carry):
-            m, l, acc = carry               # [1, bq], [1, bq], [hd, bq]
+            m, l, acc = carry               # [1, bq], [1, bq], [hd_v, bq]
             kv = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
             s = lax.dot_general(
                 k_ref[:, kv], qs, (((0,), (0,)), ((), ())),
@@ -460,13 +525,13 @@ def _fwd_kernel_s_minor(
             v = v_ref[:, kv]
             acc = acc * alpha + jnp.dot(
                 v, p.astype(v.dtype), preferred_element_type=jnp.float32,
-            )                               # v·p^T = (p·v)^T, [hd, bq]
+            )                               # v·p^T = (p·v)^T, [hd_v, bq]
             return m_new, l, acc
         return body
 
     carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
-             jnp.zeros((hd, block_q), jnp.float32))
+             jnp.zeros((hd_v, block_q), jnp.float32))
     carry = lax.fori_loop(0, num_full, make_body(False), carry)
     m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal), carry)
     l_safe = jnp.where(l > 0, l, 1.0)
@@ -479,19 +544,23 @@ def _mha_forward_s_minor(
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: [B, H, hd, S] → (o [B,H,hd,S], lse [B,H,S]): _mha_forward_bhsd
-    with the sequence minor ([H, B, ..] as well: the two leading dims are the
-    rows). Rows, grid and tile rule are the same; a block is [hd, tile] and
-    its index moves along the last dim."""
+    """q,k: [B, H, hd, S], v: [B, H, hd_v, S] → (o [B,H,hd_v,S], lse
+    [B,H,S]): _mha_forward_bhsd with the sequence minor ([H, B, ..] as well:
+    the two leading dims are the rows). Rows, grid and tile rule are the
+    same; a block is [width, tile] and its index moves along the last dim."""
     B, H, hd, Sq = q.shape
-    Skv = k.shape[3]
+    Skv, hd_v = k.shape[3], v.shape[2]
     R = B * H
-    t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize,
-                      block_q=block_q, block_k=block_k, layout=S_MINOR)
-    _record("fwd", R, Sq, Skv, hd, t, S_MINOR)
+    t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize, block_q=block_q,
+                      block_k=block_k, layout=S_MINOR, hd_v=hd_v)
+    _record("fwd", R, Sq, Skv, hd, t, S_MINOR, hd_v)
     bq, bk = t.block_q, t.block_k
-    q_tile = pl.BlockSpec((None, hd, bq), lambda g, i, *_: (g, 0, i))
-    kv_row = pl.BlockSpec((None, hd, Skv), lambda g, i, *_: (g, 0, 0))
+
+    def q_tile(width):
+        return pl.BlockSpec((None, width, bq), lambda g, i, *_: (g, 0, i))
+
+    def kv_row(width):
+        return pl.BlockSpec((None, width, Skv), lambda g, i, *_: (g, 0, 0))
 
     kernel = functools.partial(
         _fwd_kernel_s_minor, scale=scale, causal=causal,
@@ -502,21 +571,22 @@ def _mha_forward_s_minor(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, Sq // bq),
-            in_specs=[q_tile, kv_row, kv_row],
+            in_specs=[q_tile(hd), kv_row(hd), kv_row(hd_v)],
             out_specs=[
-                q_tile,
+                q_tile(hd_v),
                 pl.BlockSpec((None, 1, bq), lambda g, i, *_: (g, 0, i)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((R, hd, Sq), q.dtype),
+            jax.ShapeDtypeStruct((R, hd_v, Sq), q.dtype),
             jax.ShapeDtypeStruct((R, 1, Sq), jnp.float32),
         ],
+        compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_FWD_KERNEL,
     )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(R, hd, Skv),
-      v.reshape(R, hd, Skv))
-    return o.reshape(B, H, hd, Sq), lse.reshape(B, H, Sq)
+      v.reshape(R, hd_v, Skv))
+    return o.reshape(B, H, hd_v, Sq), lse.reshape(B, H, Sq)
 
 
 # --------------------------------------------------------------------------- #
@@ -556,7 +626,7 @@ def _fused_bwd_kernel(
     # [Sq, hd] block.
     k = k_ref[...]
     v = v_ref[...]
-    hd = k.shape[-1]
+    hd, hd_v = k.shape[-1], v.shape[-1]
     # dq contribution is ds @ (k*scale): folding the softmax scale into
     # k here is one [bk, hd] multiply per grid step instead of per-pair
     k_scaled = k * scale_c
@@ -602,7 +672,7 @@ def _fused_bwd_kernel(
         return body
 
     carry = (jnp.zeros((block_k, hd), jnp.float32),
-             jnp.zeros((block_k, hd), jnp.float32))
+             jnp.zeros((block_k, hd_v), jnp.float32))
     carry = lax.fori_loop(first, first_full, make_body(causal), carry)
     dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
     dk_ref[...] = dk.astype(dk_ref.dtype)
@@ -618,22 +688,28 @@ def _mha_backward_bhsd(
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
 ):
-    """All tensors [B, H, S, hd]; lse [B, H, S]. Returns dq, dk, dv. Rows and
-    tiles as in _mha_forward_bhsd, chosen for this kernel separately."""
+    """q, k [B, H, S, hd]; v, o, do [B, H, S, hd_v]; lse [B, H, S]. Returns
+    dq, dk, dv. Rows and tiles as in _mha_forward_bhsd, chosen for this
+    kernel separately."""
     B, H, Sq, hd = q.shape
-    Skv = k.shape[2]
+    Skv, hd_v = k.shape[2], v.shape[3]
     R = B * H
     t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize,
-                      block_q=block_q, block_k=block_k)
-    _record("bwd", R, Sq, Skv, hd, t, HD_MINOR)
+                      block_q=block_q, block_k=block_k, hd_v=hd_v)
+    _record("bwd", R, Sq, Skv, hd, t, HD_MINOR, hd_v)
     bq, bk = t.block_q, t.block_k
 
     # delta_i = rowsum(dO_i * O_i): cheap elementwise+reduce, XLA fuses it.
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     ).reshape(R, 1, Sq)
-    row = pl.BlockSpec((None, Sq, hd), lambda g, i, *_: (g, 0, 0))
-    kv_block = pl.BlockSpec((None, bk, hd), lambda g, i, *_: (g, i, 0))
+
+    def row(width):
+        return pl.BlockSpec((None, Sq, width), lambda g, i, *_: (g, 0, 0))
+
+    def kv_block(width):
+        return pl.BlockSpec((None, bk, width), lambda g, i, *_: (g, i, 0))
+
     stat = pl.BlockSpec((None, 1, Sq), lambda g, i, *_: (g, 0, 0))
 
     fused_kernel = functools.partial(
@@ -645,26 +721,28 @@ def _mha_backward_bhsd(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, Skv // bk),
-            in_specs=[row, kv_block, kv_block, row, stat, stat],
-            out_specs=[row, kv_block, kv_block],
+            in_specs=[row(hd), kv_block(hd), kv_block(hd_v), row(hd_v), stat,
+                      stat],
+            out_specs=[row(hd), kv_block(hd), kv_block(hd_v)],
             scratch_shapes=[pltpu.VMEM((hd, Sq), jnp.float32)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((R, Sq, hd), q.dtype),
             jax.ShapeDtypeStruct((R, Skv, hd), k.dtype),
-            jax.ShapeDtypeStruct((R, Skv, hd), v.dtype),
+            jax.ShapeDtypeStruct((R, Skv, hd_v), v.dtype),
         ],
+        compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_BWD_KERNEL,
     )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(R, Skv, hd),
-      v.reshape(R, Skv, hd), do.reshape(R, Sq, hd), lse.reshape(R, 1, Sq),
+      v.reshape(R, Skv, hd_v), do.reshape(R, Sq, hd_v), lse.reshape(R, 1, Sq),
       delta)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _fused_bwd_kernel_s_minor(
     q_off_ref, kv_off_ref,
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,       # [hd, Sq] / [hd, bk]
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,   # [hd | hd_v, Sq | bk]
     dq_ref, dk_ref, dv_ref, dq_acc,
     *, scale: float, causal: bool, block_q: int, block_k: int, q_len: int,
 ):
@@ -690,13 +768,13 @@ def _fused_bwd_kernel_s_minor(
 
     scale_c = jnp.asarray(scale, q_ref.dtype)
     k = k_ref[...]                                       # [hd, bk]
-    v = v_ref[...]
-    hd = k.shape[0]
+    v = v_ref[...]                                       # [hd_v, bk]
+    hd, hd_v = k.shape[0], v.shape[0]
     k_scaled = k * scale_c
 
     def make_body(masked):
         def body(qi, carry):
-            dk, dv = carry                               # [hd, bk] f32
+            dk, dv = carry                       # [hd, bk], [hd_v, bk] f32
             sl = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
             qs = q_ref[:, sl] * scale_c                  # [hd, bq]
             do = do_ref[:, sl]
@@ -719,7 +797,7 @@ def _fused_bwd_kernel_s_minor(
             dv = dv + lax.dot_general(
                 do, p.astype(do.dtype), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )                                            # do·p, [hd, bk]
+            )                                            # do·p, [hd_v, bk]
             dp = lax.dot_general(
                 v, do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -735,7 +813,7 @@ def _fused_bwd_kernel_s_minor(
         return body
 
     carry = (jnp.zeros((hd, block_k), jnp.float32),
-             jnp.zeros((hd, block_k), jnp.float32))
+             jnp.zeros((hd_v, block_k), jnp.float32))
     carry = lax.fori_loop(first, first_full, make_body(causal), carry)
     dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
     dk_ref[...] = dk.astype(dk_ref.dtype)
@@ -751,17 +829,22 @@ def _mha_backward_s_minor(
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
 ):
-    """All tensors [B, H, hd, S]; lse [B, H, S]. Returns dq, dk, dv:
-    _mha_backward_bhsd with the sequence minor."""
+    """q, k [B, H, hd, S]; v, o, do [B, H, hd_v, S]; lse [B, H, S]. Returns
+    dq, dk, dv: _mha_backward_bhsd with the sequence minor."""
     B, H, hd, Sq = q.shape
-    Skv = k.shape[3]
+    Skv, hd_v = k.shape[3], v.shape[2]
     R = B * H
-    t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize,
-                      block_q=block_q, block_k=block_k, layout=S_MINOR)
-    _record("bwd", R, Sq, Skv, hd, t, S_MINOR)
+    t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize, block_q=block_q,
+                      block_k=block_k, layout=S_MINOR, hd_v=hd_v)
+    _record("bwd", R, Sq, Skv, hd, t, S_MINOR, hd_v)
     bq, bk = t.block_q, t.block_k
-    row = pl.BlockSpec((None, hd, Sq), lambda g, i, *_: (g, 0, 0))
-    kv_block = pl.BlockSpec((None, hd, bk), lambda g, i, *_: (g, 0, i))
+
+    def row(width):
+        return pl.BlockSpec((None, width, Sq), lambda g, i, *_: (g, 0, 0))
+
+    def kv_block(width):
+        return pl.BlockSpec((None, width, bk), lambda g, i, *_: (g, 0, i))
+
     stat = pl.BlockSpec((None, 1, Sq), lambda g, i, *_: (g, 0, 0))
 
     fused_kernel = functools.partial(
@@ -773,20 +856,22 @@ def _mha_backward_s_minor(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, Skv // bk),
-            in_specs=[row, kv_block, kv_block, row, row, stat],
-            out_specs=[row, kv_block, kv_block],
+            in_specs=[row(hd), kv_block(hd), kv_block(hd_v), row(hd_v),
+                      row(hd_v), stat],
+            out_specs=[row(hd), kv_block(hd), kv_block(hd_v)],
             scratch_shapes=[pltpu.VMEM((hd, Sq), jnp.float32)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((R, hd, Sq), q.dtype),
             jax.ShapeDtypeStruct((R, hd, Skv), k.dtype),
-            jax.ShapeDtypeStruct((R, hd, Skv), v.dtype),
+            jax.ShapeDtypeStruct((R, hd_v, Skv), v.dtype),
         ],
+        compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_BWD_KERNEL,
     )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(R, hd, Skv),
-      v.reshape(R, hd, Skv), o.reshape(R, hd, Sq), do.reshape(R, hd, Sq),
-      lse.reshape(R, 1, Sq))
+      v.reshape(R, hd_v, Skv), o.reshape(R, hd_v, Sq),
+      do.reshape(R, hd_v, Sq), lse.reshape(R, 1, Sq))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -813,10 +898,10 @@ HEAD_MAJOR_LAYOUTS = _KERNEL_AXES[HD_MINOR] + _KERNEL_AXES[S_MINOR]
 LAYOUTS = ("bshd",) + HEAD_MAJOR_LAYOUTS
 
 
-def _kernel_axes(layout: str, hd: int) -> Tuple[str, str]:
+def _kernel_axes(layout: str, hd: int, hd_v: int) -> Tuple[str, str]:
     """(pair, the axis order its kernels are handed) for a caller's layout:
     the caller's own where the pair takes it, else the pair's first."""
-    pair = kernel_layout(hd)
+    pair = kernel_layout(hd, hd_v)
     own = _KERNEL_AXES[pair]
     return pair, layout if layout in own else own[0]
 
@@ -835,7 +920,8 @@ def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
                bwd_block_k, interpret, layout):
-    pair, axes = _kernel_axes(layout, q.shape[layout.index("d")])
+    d = layout.index("d")
+    pair, axes = _kernel_axes(layout, q.shape[d], v.shape[d])
     qt, kt, vt = (_relayout(x, layout, axes) for x in (q, k, v))
     forward = _mha_forward_s_minor if pair == S_MINOR else _mha_forward_bhsd
     o, lse = forward(
@@ -853,7 +939,10 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
                interpret, layout, res, do):
     qt, kt, vt, o, lse = res
-    pair, axes = _kernel_axes(layout, do.shape[layout.index("d")])
+    # (do has v's width; q's is what q's size leaves beside the rows and
+    # the sequence, which q and do share)
+    hd_v = do.shape[layout.index("d")]
+    pair, axes = _kernel_axes(layout, qt.size // (do.size // hd_v), hd_v)
     backward = _mha_backward_s_minor if pair == S_MINOR else _mha_backward_bhsd
     grads = backward(
         qt, kt, vt, o, lse, _relayout(do, layout, axes),
@@ -885,10 +974,13 @@ def flash_attention(
     """Multi-head flash attention. q,k,v in and o out are in the axis order
     ``layout`` spells (one of LAYOUTS): [B, S, H, hd] ("bshd", the default),
     head-major hd-minor ("bhsd") or head-major S-minor ("bhds", or "hbds"
-    with the heads leading: [.., hd, S]).
+    with the heads leading: [.., hd, S]). v — and with it o — may be of
+    another width than q and k (latent attention's 128 beside 192): each is
+    read at its own width, nothing is padded. ``scale`` is 1/√hd of q's
+    width unless given.
 
-    Which kernel pair runs is kernel_layout's answer for hd, not the
-    caller's: "bhsd" is the hd-minor pair's own order and "bhds" / "hbds"
+    Which kernel pair runs is kernel_layout's answer for the two widths, not
+    the caller's: "bhsd" is the hd-minor pair's own order and "bhds" / "hbds"
     the S-minor pair's (batch and head are merged into rows, so either may
     lead), and a caller that hands a pair its own order (models/gpt2.py
     does) has no transpose at the boundary; any other is transposed here.

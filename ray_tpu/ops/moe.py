@@ -294,9 +294,35 @@ def _sigmoid_bwd(s, g):
 _sigmoid.defvjp(_sigmoid_fwd, _sigmoid_bwd)
 
 
-def _scores(u: jax.Array, router_w: jax.Array) -> jax.Array:
-    """u [T, D] → every expert's sigmoid score [T, n_experts], float32."""
-    return _sigmoid(jnp.einsum(
+@jax.custom_vjp
+def _softmax(x: jax.Array) -> jax.Array:
+    """``jax.nn.softmax`` over the last axis whose backward reads the NAMED
+    probabilities, as _sigmoid's reads its scores and for its reason: same
+    values forward, and backward ``s · (g − Σ g · s)``."""
+    return jax.nn.softmax(x, axis=-1)
+
+
+def _softmax_fwd(x):
+    s = checkpoint_name(jax.nn.softmax(x, axis=-1), scopes.RES_MOE_SCORES)
+    return s, s
+
+
+def _softmax_bwd(s, g):
+    return (s * (g - jnp.sum(g * s, axis=-1, keepdims=True)),)
+
+
+_softmax.defvjp(_softmax_fwd, _softmax_bwd)
+
+# how a router turns its logits into scores: each expert's own sigmoid, or
+# one softmax over all the experts
+SCORING = {"sigmoid": _sigmoid, "softmax": _softmax}
+
+
+def _scores(u: jax.Array, router_w: jax.Array,
+            scoring: str = "sigmoid") -> jax.Array:
+    """u [T, D] → every expert's score [T, n_experts], float32: its sigmoid,
+    or with ``scoring`` "softmax" its probability among all the experts."""
+    return SCORING[scoring](jnp.einsum(
         "td,de->te", u.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGH))
 
@@ -319,25 +345,73 @@ def _chosen(biased: jax.Array, top_k: int) -> jax.Array:
     return (biased > kth) | ((biased == kth) & (ids <= last))
 
 
-def route(u: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float, held: Held, eps: float = 0.0
-          ) -> Tuple[jax.Array, jax.Array]:
+class Rule(NamedTuple):
+    """What a router is told beside its weights: how logits become scores
+    (SCORING) and whether the chosen scores are divided by their sum. The
+    default is the Nemotron and LFM2 routers' (sigmoid, normalised);
+    DeepSeek-V2's is a softmax whose chosen probabilities gate AS THEY ARE
+    (``norm_topk_prob`` false)."""
+    scoring: str = "sigmoid"
+    normalise: bool = True
+
+
+def scored_choice(u: jax.Array, router_w: jax.Array,
+                  bias: Optional[jax.Array], top_k: int,
+                  scoring: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
+    """u [T, D] → (every expert's score [T, n_experts] float32, the set each
+    token chose [T, n_experts] bool): the k largest of score + ``bias`` (the
+    bias — None: a router without one — chooses only). What route gates
+    from and what a balance loss reads (balance_loss), made once."""
+    scores = _scores(u, router_w, scoring)
+    biased = scores if bias is None else scores + bias.astype(jnp.float32)
+    return scores, _chosen(lax.stop_gradient(biased), top_k)
+
+
+def route(u: jax.Array, router_w: jax.Array, bias: Optional[jax.Array],
+          top_k: int, scaling: float, held: Held, eps: float = 0.0,
+          rule: Rule = Rule(), with_choice: bool = False):
     """u [T, D] → (``here`` [T, held] bool: the token chose that held expert,
     the held experts' gates [T, held] float32, which mean something only where
-    ``here``): sigmoid scores in float32, the k largest of score + bias chosen
-    (the bias chooses only), gates = scaling · score / (Σ over ALL the chosen
-    + ``eps``). The sum is a masked row-sum and the held experts' scores a static slice:
+    ``here``): scores in float32 (``rule.scoring``), the k largest of score +
+    bias chosen (the bias chooses only), gates = scaling · score / (Σ over
+    ALL the chosen + ``eps``), or scaling · score where the rule does not
+    normalise. The sum is a masked row-sum and the held experts' scores a
+    static slice:
     nothing is gathered by chosen id. The gates are not masked by ``here``:
     only a pair's row reads one, and ``HeldPairs.valid`` says which rows are
-    pairs."""
-    scores = _scores(u, router_w)
-    chosen = _chosen(
-        lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
-    denom = jnp.sum(jnp.where(chosen, scores, 0.0), axis=-1, keepdims=True)
-    if eps:
-        denom = denom + eps
+    pairs. ``with_choice`` adds scored_choice's pair as a third result."""
+    scores, chosen = scored_choice(u, router_w, bias, top_k, rule.scoring)
+    if rule.normalise:
+        denom = jnp.sum(jnp.where(chosen, scores, 0.0), axis=-1, keepdims=True)
+        if eps:
+            denom = denom + eps
     span = slice(held.first, held.first + held.count)
-    return chosen[:, span], scaling * scores[:, span] / denom
+    here, gates = chosen[:, span], scaling * scores[:, span]
+    if rule.normalise:
+        gates = gates / denom
+    return (here, gates, (scores, chosen)) if with_choice else (here, gates)
+
+
+@jax.named_scope(scopes.MOE_AUX)
+def balance_loss(scores: jax.Array, chosen: jax.Array, top_k: int,
+                 rows: int) -> jax.Array:
+    """The sequence-wise balance loss of one expert layer, before its
+    coefficient (DeepSeek-V2, arXiv:2405.04434 eq. 23–26; ``seq_aux``):
+    scores / chosen [T, n_experts] (scored_choice's) of a batch whose rows
+    are ``rows`` tokens long → the mean over the batch's rows of ``Σ_e f_e ·
+    P_e``, with, within ONE row, ``f_e = count_e · n_experts / (top_k ·
+    rows)`` (how many of the row's tokens chose e: a constant to AD) and
+    ``P_e`` the row's mean score of e. 1.0 where every expert is chosen
+    equally often and scored 1 / n_experts. Its gradient reaches the router
+    through P alone — from every token, held choice or not: the router is
+    whole on every chip of an EP group, and this term is not cut by the
+    share."""
+    n_experts = scores.shape[-1]
+    count = jnp.sum(chosen.reshape(-1, rows, n_experts), axis=1,
+                    dtype=jnp.float32)
+    f = lax.stop_gradient(count) * (n_experts / (top_k * rows))
+    mean_score = jnp.mean(scores.reshape(-1, rows, n_experts), axis=1)
+    return jnp.mean(jnp.sum(f * mean_score, axis=-1))
 
 
 def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
@@ -358,6 +432,46 @@ def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
         return b + rate * jnp.sign(mean - load)
 
     return lax.fori_loop(0, BALANCE_ROUNDS, body, bias.astype(jnp.float32))
+
+
+# balance_router: a run's first rate, in units of a LOGIT (a round's step is
+# the gradient's direction at the length that moves the batch's logits by
+# its rate, root mean square; the caller lets it fall linearly to 0 over
+# BALANCE_ROUNDS as balance_bias's does). A step measured on the weights
+# instead diverges where the layer's input has a component every token
+# shares — after a dense MLP it has —: along it a weight's change moves every
+# token's logit at once (all tokens on six experts within sixteen rounds,
+# PERF.md §6, PR 55)
+BALANCE_ROUTER_RATE = 0.3
+
+
+def balance_router(u: jax.Array, router_w: jax.Array, top_k: int, rows: int,
+                   rate, rule: Rule = Rule()) -> jax.Array:
+    """The router of a layer WITHOUT a selection bias after ONE round of
+    gradient descent on its own balance_loss on one batch (u [T, D], the
+    layer's normed input, in rows of ``rows`` tokens), everything else held:
+    ``W ← W − rate · g / rms(u·g)`` — the round moves the batch's logits by
+    ``rate``. BALANCE_ROUNDS of them, a fresh batch each and the rate falling
+    from BALANCE_ROUTER_RATE to 0, are what a run's many steps under the
+    balance loss do to a router, done at once, as balance_bias does for a
+    router that is balanced by a bias. A fresh batch each because rounds on
+    ONE batch fit that batch: a router's choices on tokens drawn from few
+    symbols are a few dozen patterns, which a batch's own near-ties split —
+    its held experts then get 25 % of the pairs on that batch and a seed's own
+    24–26 % on every other (PERF.md §6, PR 55). NO TRAINING PATH CALLS IT —
+    no step, no trainer, no model's loss_fn: models/deepseek_v2.balance_routers
+    does, which a benchmark's build and chip_smoke.py call once before the
+    first step, so that a run on freshly drawn weights starts balanced (the
+    cell's configuration states the departure, ``assumed`` (i))."""
+    def loss(w):
+        return balance_loss(*scored_choice(u, w, None, top_k, rule.scoring),
+                            top_k, rows)
+
+    w = router_w.astype(jnp.float32)
+    g = jax.grad(loss)(w)
+    moved = jnp.einsum("td,de->te", u.astype(jnp.float32), g,
+                       precision=lax.Precision.HIGH)
+    return w - rate * g / (jnp.sqrt(jnp.mean(jnp.square(moved))) + 1e-30)
 
 
 class HeldPairs(NamedTuple):
@@ -587,27 +701,31 @@ def _run_passes_bwd(res, d_r):
 _run_passes.defvjp(_run_passes_fwd, _run_passes_bwd)
 
 
-def _dispatch(u, p, top_k: int, held: Held, scaling: float, eps: float = 0.0):
+def _dispatch(u, p, top_k: int, held: Held, scaling: float, eps: float = 0.0,
+              rule: Rule = Rule()):
     """Route u [T, D] and lay the pairs on held experts over the row buffer:
     (the membership [T, held], the HeldPairs, the layer's load — int32
     scalars under tracing/names.STEP_EXPERT_LOAD_ARGS: the passes the pairs
-    fill, the pairs landed here, the fullest held expert's)."""
+    fill, the pairs landed here, the fullest held expert's —, scored_choice's
+    scores and choice of every token). A layer without a selection bias has
+    no ``router_bias`` among its tensors."""
     T = u.shape[0]
     n_experts = p["router_w"].shape[-1]
     rows = row_buffer(T, n_experts, top_k, held.count)
-    here, gates = route(u, p["router_w"], p["router_bias"], top_k, scaling,
-                        held, eps)
+    here, gates, choice = route(u, p["router_w"], p.get("router_bias"), top_k,
+                                scaling, held, eps, rule, with_choice=True)
     pairs = held_pairs(here, gates, rows,
                        buffer_passes(T, n_experts, top_k, held.count))
     landed = jnp.sum(pairs.per_expert)
     return here, pairs, dict(zip(scopes.STEP_EXPERT_LOAD_ARGS, (
-        -(-landed // rows), landed, jnp.max(pairs.per_expert))))
+        -(-landed // rows), landed, jnp.max(pairs.per_expert)))), choice
 
 
 @jax.named_scope(scopes.MOE_ROUTED)
 def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
                    top_k: int, held: Held, scaling: float, eps: float = 0.0,
-                   form: Tuple[str, ...] = RELU2_EXPERT
+                   form: Tuple[str, ...] = RELU2_EXPERT, rule: Rule = Rule(),
+                   balance_rows: int = 0
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The held experts' part of the routed result, in the width the experts
     read and write: u [T, D] (what the router reads), ell [T, width] (what
@@ -618,9 +736,17 @@ def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
     were there to run the passes — for whoever hands them out of the step; a
     caller that drops them has paid nothing). One pass over the row buffer
     where the batch's pairs fit it (row_buffer); a batch with more runs the
-    further passes it fills."""
+    further passes it fills. ``rule`` says what kind of router this is
+    (Rule); with ``balance_rows`` — the length of the batch's rows — the load
+    holds the layer's balance_loss too (a float32 under
+    names.STEP_BALANCE_LOSS), from the scores and the choice the dispatch
+    made anyway: no second router product."""
     with jax.named_scope(scopes.MOE_DISPATCH):
-        _, pairs, load = _dispatch(u, p, top_k, held, scaling, eps)
+        _, pairs, load, choice = _dispatch(u, p, top_k, held, scaling, eps,
+                                           rule)
+    if balance_rows:
+        load[scopes.STEP_BALANCE_LOSS] = balance_loss(*choice, top_k,
+                                                      balance_rows)
     # (each weight row-major as it enters the passes: the backward's grouped
     # products read two of them transposed, and the first pass stands
     # outside any loop now, so without this the compiler lays the float32
@@ -672,23 +798,36 @@ def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
 
 def gated_moe_init(rng: jax.Array, n_layers: int, d_model: int,
                    n_experts: int, held: int, d_expert: int, std: float,
-                   out_std: float, param_dtype=jnp.float32) -> Dict[str, Any]:
+                   out_std: float, param_dtype=jnp.float32, *,
+                   selection_bias: bool = True, d_shared: int = 0
+                   ) -> Dict[str, Any]:
     """``n_layers`` stacked layers of gated_moe: the router over all
-    ``n_experts`` and its selection bias (a buffer, as latent_moe_init's) and
-    ``held`` SiLU-gated experts at the model's width."""
+    ``n_experts`` and its selection bias (a buffer, as latent_moe_init's;
+    none without ``selection_bias``: a router balanced by a loss has none),
+    ``held`` SiLU-gated experts at the model's width and, with ``d_shared``,
+    one shared expert of the same form at that hidden width beside them."""
     k = iter(jax.random.split(rng, 5))
     L = n_layers
 
     def normal(key, shape, s):
         return (jax.random.normal(key, shape) * s).astype(param_dtype)
 
-    return {
+    p = {
         "router_w": normal(next(k), (L, d_model, n_experts), std),
         "router_bias": normal(next(k), (L, n_experts), ROUTER_BIAS_STD),
         "w1": normal(next(k), (L, held, d_model, d_expert), std),
         "w3": normal(next(k), (L, held, d_model, d_expert), std),
         "w2": normal(next(k), (L, held, d_expert, d_model), out_std),
     }
+    if not selection_bias:
+        del p["router_bias"]
+    if d_shared:
+        # (keys of their own: the tensors above are drawn as they always were)
+        ks = jax.random.split(jax.random.fold_in(rng, 1), 3)
+        p.update(shared_w1=normal(ks[0], (L, d_model, d_shared), std),
+                 shared_w3=normal(ks[1], (L, d_model, d_shared), std),
+                 shared_w2=normal(ks[2], (L, d_shared, d_model), out_std))
+    return p
 
 
 def gated_moe_logical_axes() -> Dict[str, Any]:
@@ -698,30 +837,70 @@ def gated_moe_logical_axes() -> Dict[str, Any]:
         "w1": ("layers", "expert", "embed", "mlp"),
         "w3": ("layers", "expert", "embed", "mlp"),
         "w2": ("layers", "expert", "mlp", "embed"),
+        "shared_w1": ("layers", "embed", "mlp"),
+        "shared_w3": ("layers", "embed", "mlp"),
+        "shared_w2": ("layers", "mlp", "embed"),
     }
 
 
+# the shared expert beside gated_moe's routed ones, by its weights' names
+GATED_SHARED_EXPERT = ("shared_w1", "shared_w3", "shared_w2")
+
+
 def gated_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
-              scaling: float, eps: float = 0.0
+              scaling: float, eps: float = 0.0, rule: Rule = Rule(),
+              balance: bool = False, shared_rows: int = 0
               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """u [B, S, D] (normed, compute dtype) → (the held experts' part of the
     layer's output [B, S, D] in float32: Σ over a token's chosen AND held
     experts of gate · (silu(u·W1_e) ⊙ u·W3_e) · W2_e, at the model's width —
-    no latent around the experts, nothing beside them; routed_experts' load).
-    ``p`` holds one layer's tensors, GATED_EXPERT's in the compute dtype."""
+    no latent around the experts; routed_experts' load). ``p`` holds one
+    layer's tensors, GATED_EXPERT's in the compute dtype. A layer whose
+    tensors hold a shared expert (GATED_SHARED_EXPERT, compute dtype) adds
+    ``(silu(u·S1) ⊙ u·S3)·S2`` of every token, whole on every chip — with
+    ``shared_rows`` < S in chunks of that many rows, each its own
+    ``checkpoint``, as latent_moe's. ``balance``: the load holds the layer's
+    balance_loss over rows of S tokens."""
     B, S, D = u.shape
     ut = u.reshape(B * S, D)
+    # (a caller with the default router and no balance loss says what it
+    # always said)
+    more = {**({"rule": rule} if rule != Rule() else {}),
+            **({"balance_rows": S} if balance else {})}
     r, load = routed_experts(ut, ut, p, top_k=top_k, held=held,
-                             scaling=scaling, eps=eps, form=GATED_EXPERT)
-    return r.reshape(B, S, D), load
+                             scaling=scaling, eps=eps, form=GATED_EXPERT,
+                             **more)
+    out = r.reshape(B, S, D)
+    if "shared_w1" not in p:
+        return out, load
+
+    def shared(u_rows):
+        # a dense MLP: under the block's `mlp` scope as any other
+        with jax.named_scope(scopes.MLP), jax.named_scope(scopes.MOE_SHARED):
+            gate = checkpoint_name(
+                jnp.einsum("bsd,df->bsf", u_rows, p["shared_w1"]),
+                scopes.RES_MOE_SHARED_GATE)
+            up = checkpoint_name(
+                jnp.einsum("bsd,df->bsf", u_rows, p["shared_w3"]),
+                scopes.RES_MOE_SHARED_UP)
+            return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                              p["shared_w2"],
+                              preferred_element_type=jnp.float32)
+
+    if shared_rows in (0, S):
+        return out + shared(u), load
+    chunks = u.reshape(B, S // shared_rows, shared_rows, D).swapaxes(0, 1)
+    sh = lax.map(jax.checkpoint(shared), chunks)
+    return out + sh.swapaxes(0, 1).reshape(B, S, D), load
 
 
-def chosen_experts(u: jax.Array, p: Dict[str, Any], top_k: int) -> jax.Array:
+def chosen_experts(u: jax.Array, p: Dict[str, Any], top_k: int,
+                   rule: Rule = Rule()) -> jax.Array:
     """u [T, D] → [T, n_experts] bool: the set route chooses for each token,
     for whoever compares it with another router's (a reference told the
     program's choice does not read a flipped near-tie as a wrong model)."""
-    return _chosen(_scores(u, p["router_w"])
-                   + p["router_bias"].astype(jnp.float32), top_k)
+    return scored_choice(u, p["router_w"], p.get("router_bias"), top_k,
+                         rule.scoring)[1]
 
 
 def step_load_static(tokens: int, n_experts: int, top_k: int,
@@ -734,10 +913,11 @@ def step_load_static(tokens: int, n_experts: int, top_k: int,
 
 
 def held_load(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
-              scaling: float, eps: float = 0.0) -> Dict[str, jax.Array]:
+              scaling: float, eps: float = 0.0, rule: Rule = Rule()
+              ) -> Dict[str, jax.Array]:
     """What a batch sends the held experts of one layer (u [T, D], the
     layer's normed input): the numbers of the ``model/expert_load`` event."""
-    here, pairs, load = _dispatch(u, p, top_k, held, scaling, eps)
+    here, pairs, load, _ = _dispatch(u, p, top_k, held, scaling, eps, rule)
     # (what a step hands out of itself, and from the same code)
     filled, landed, fullest = (load[k] for k in scopes.STEP_EXPERT_LOAD_ARGS)
     rows = pairs.key.shape[1]

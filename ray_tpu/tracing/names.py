@@ -65,6 +65,15 @@ SPARSE_SELECT = "sparse_select"
 # depthwise causal conv — elementwise, the CONV_GATE_* kernels' work)
 SHORT_CONV = "short_conv"
 CONV_GATE = "conv_gate"
+# models/deepseek_v2.py: multi-head latent attention's work between the norm
+# and the flash kernel that is not the kernel — the q projection, the joint
+# projection to the latent c and the one shared k_pe, the latent's norm, the
+# up-projection to k_nope and v, RoPE on q_pe and k_pe, k put together from
+# k_nope and the broadcast k_pe (after the kernel the out-projection is PROJ
+# as anywhere); and an expert layer's balance loss (ops/moe.balance_loss:
+# the per-row counts and mean probabilities, their product)
+MLA_LATENT = "mla_latent"
+MOE_AUX = "moe_aux"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
                                           EVA_PREP_KV, MAMBA, SSD_SCAN,
@@ -72,7 +81,8 @@ SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           MOE_SHARED, MTP, LIGHTNING_ATTN,
                                           SPARSE_ATTENTION, SPARSE_SELECT,
                                           SHORT_CONV, CONV_GATE,
-                                          MOE_FURTHER_PASSES)
+                                          MOE_FURTHER_PASSES, MLA_LATENT,
+                                          MOE_AUX)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -115,8 +125,10 @@ FLASH_TILING = "ops/flash_tiling"
 _TILE_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
               "vmem_estimate")
 # ... and which kernel pair it was traced for: "s_minor" ([rows, hd, S]
-# operands, where hd is narrower than a lane tile) or "hd_minor"
-FLASH_TILING_ARGS = _TILE_ARGS + ("layout",)
+# operands, where a width is not whole lane tiles) or "hd_minor"; `hd` is q's
+# and k's width and, since PR 55, `hd_v` v's and o's (latent attention's 192
+# and 128; equal anywhere else)
+FLASH_TILING_ARGS = _TILE_ARGS + ("layout", "hd_v")
 # the same for an EVA kernel (ops/eva_attention.py): Sq = Skv = the sequence
 EVA_TILING = "ops/eva_tiling"
 EVA_TILING_ARGS = _TILE_ARGS + ("window", "chunk")
@@ -196,13 +208,24 @@ RES_SPARSE_O, RES_SPARSE_LSE = "sparse_o", "sparse_lse"
 # The held experts' hidden tensors have no name: they are a pass's, inside
 # the dispatch's written-out backward (ops/moe._run_passes)
 RES_CONV_BCX = "conv_bcx"
+# a DeepSeek-V2 layer's (models/deepseek_v2.py). Latent attention names its
+# normed latent c and the rotated shared k_pe, [B, S, 512] and [B, 64, S]: 576
+# numbers a token that stand for the 16 × (192 + 128) of k and v (one
+# up-projection and a concatenation away), beside q, k and v themselves
+# (RES_Q, RES_K, RES_V: k after the concatenation) and the flash kernel's two;
+# both feed-forward halves RES_MID, the dense one RES_MLP_GATE / RES_MLP_UP,
+# the expert layer the routing's names (RES_MOE_SCORES holds the softmax's
+# probabilities there) and its shared expert's two hidden tensors
+RES_MLA_C, RES_MLA_KPE = "mla_latent_c", "mla_k_pe"
+RES_MOE_SHARED_GATE, RES_MOE_SHARED_UP = "moe_shared_gate", "moe_shared_up"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
              RES_MLP_GATE, RES_MLP_UP, RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT,
              RES_SSD_STATES, RES_SSD_Y, RES_MOE_LATENT, RES_MOE_SHARED_HIDDEN,
              RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY,
              RES_SALA_GATE, RES_LIGHTNING_Y, RES_SPARSE_IDS, RES_SPARSE_O,
-             RES_SPARSE_LSE, RES_CONV_BCX, RES_MOE_PAIR_GATE)
+             RES_SPARSE_LSE, RES_CONV_BCX, RES_MOE_PAIR_GATE, RES_MLA_C,
+             RES_MLA_KPE, RES_MOE_SHARED_GATE, RES_MOE_SHARED_UP)
 # which of them models/blocks.py chose to save, the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once),
 # and the phase of the backward whose working set the budget was left by
@@ -246,6 +269,11 @@ EXPERT_LOAD_ARGS = ("layer", "tokens", "pairs", "max_per_expert",
 # landed and the fullest held expert's — what the dispatch has in hand, no
 # reduction over the tokens of its own
 STEP_EXPERT_LOAD_ARGS = ("passes", "pairs", "max_per_expert")
+# ... and, of a layer whose router is balanced by an auxiliary loss (PR 55:
+# ops/moe.balance_loss, the DeepSeek-V2 family's), that loss's value in the
+# step, BEFORE its coefficient: a float32 whose bits ride in the int32
+# counters (models/blocks.StepCounters.float_fields)
+STEP_BALANCE_LOSS = "balance_loss"
 
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer

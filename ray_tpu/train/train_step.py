@@ -17,6 +17,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -175,7 +176,9 @@ class _Step:
     count of calls) around the jitted call — the enqueue, or the wait on a
     full queue: on the profiler's clock under a profiler session, in the
     task-event buffer when it lasted ``PROFILE_MIN_DUR_S`` —; (b) where the
-    model offers counters, hands ``metrics["counters"]``, still being made,
+    model offers counters (a layer's loss term among them, as float32 bits:
+    ``StepCounters.float_fields``), hands ``metrics["counters"]``, still
+    being made,
     to ``tracing.step_counters`` with the same ``step`` and the wall time of
     the call, and lets it record what earlier steps' arrays are ready by now:
     one small fetch, never a wait. Everything else is the jitted object's:
@@ -208,8 +211,12 @@ class _Step:
         static = spec.static(tokens)
 
         def decode(rows):
+            def column(i, f):       # a float field's int32s are its bits
+                c = np.ascontiguousarray(rows[:, i])
+                return c.view(np.float32) if f in spec.float_fields else c
+
             return {"kind": spec.kind, "layers": list(spec.layers),
-                    **{f: rows[:, i].tolist()
+                    **{f: column(i, f).tolist()
                        for i, f in enumerate(spec.fields)}, **static}
 
         return decode
@@ -325,8 +332,6 @@ def _opt_state_shardings(optimizer, params, param_shardings, mesh):
 
 def synthetic_batch(cfg: gpt2.GPT2Config, global_batch: int, seed: int = 0):
     """Deterministic fake LM batch (benchmarks + tests)."""
-    import numpy as np
-
     rng = np.random.default_rng(seed)
     tokens = rng.integers(
         0, cfg.vocab_size, size=(global_batch, cfg.seq_len), dtype=np.int32

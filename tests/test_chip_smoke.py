@@ -244,12 +244,32 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     from ray_tpu.models import lfm2_moe
 
     lfm2_cfg = lfm2_moe.lfm2_moe_tiny(remat=True)
+    from ray_tpu.models import deepseek_v2
+
+    dsv2_cfg = deepseek_v2.deepseek_v2_tiny(remat=True,
+                                            attention_impl="pallas")
     rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1,
                           num_devices=8, use_tpu=False, eva_model=eva_cfg,
                           hybrid_model=hybrid_cfg, sala_model=sala_cfg,
-                          lfm2_model=lfm2_cfg)
+                          lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg)
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
+    # the DeepSeek-V2 step (PR 55): its pattern, the flash kernels' tilings
+    # at the two widths, its three expert layers' loads and the balance loss
+    # each said beside its load came back; and the check fails without them
+    dsv2 = summary["dsv2"]
+    assert [d["groups"] for d in dsv2["layer_pattern"]] == [
+        ["D", "3 x scan(E)"]]
+    assert [e["layer"] for e in dsv2["expert_load"]] == [1, 2, 3]
+    assert {(d["kernel"], d["hd"], d["hd_v"], d["layout"])
+            for d in dsv2["flash_tiling"]} == {
+        ("fwd", 24, 16, "s_minor"), ("bwd", 24, 16, "s_minor")}
+    assert np.asarray(dsv2["step_load"]).shape == (3, 3)
+    assert all(0.8 < b < 2.0 for b in dsv2["balance_loss"])
+    bare = [rows[-1] | {"summary": summary | {"dsv2": dsv2 | {
+        "layer_pattern": [], "remat_policy": [], "expert_load": [],
+        "flash_tiling": [], "balance_loss": [0.0]}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + bare, cfg, steps)) == 4
     # the LFM2-MoE step (PR 50): its pattern of pairs, the rule's decision
     # over its three kinds and its four expert layers' loads came back, no
     # pair dropped; and the check fails without them

@@ -46,7 +46,8 @@ PAIRS = [attention.HD_MINOR, attention.S_MINOR]
 
 @pytest.fixture(params=PAIRS)
 def pair(request, monkeypatch):
-    monkeypatch.setattr(attention, "kernel_layout", lambda hd: request.param)
+    monkeypatch.setattr(attention, "kernel_layout",
+                        lambda hd, hd_v=None: request.param)
     return request.param
 
 
@@ -263,24 +264,81 @@ def test_choose_tiling_shrinks_the_tile_before_it_gives_up():
     assert (t.block_q, t.block_k) == (512, 512)
 
 
-@pytest.mark.parametrize("kernel,S,hd,layout", [
-    ("bwd", 8192, 128, attention.HD_MINOR),   # whole-row q/do/dq blocks alone
-    ("bwd", 16384, 64, attention.HD_MINOR),   # are over
-    ("fwd", 65536, 128, attention.HD_MINOR),  # whole-row k/v
-    ("bwd", 32768, 64, attention.S_MINOR),    # the same rows, unpadded: twice
-    ("fwd", 131072, 64, attention.S_MINOR),   # the sequence
-])
-def test_choose_tiling_raises_when_nothing_fits(kernel, S, hd, layout):
+# (kernel, Sq = Skv, hd, hd_v, layout) whose whole-row blocks alone pass the
+# default budget — refused until PR 55 (ROADMAP D16) —: the tiles are back at
+# their target and the CALL asks Mosaic for its estimate and half again
+PAST_THE_DEFAULT_BUDGET = [
+    ("bwd", 8192, 128, 128, attention.HD_MINOR),   # the Nemotron cell's width
+    ("bwd", 16384, 64, 64, attention.HD_MINOR),
+    ("fwd", 32768, 128, 128, attention.HD_MINOR),
+    ("bwd", 32768, 64, 64, attention.S_MINOR),
+    ("bwd", 8192, 192, 128, attention.S_MINOR),    # latent attention, the
+    ("bwd", 16384, 192, 128, attention.S_MINOR),   # DeepSeek-V2 cell's rows
+]
+# ... and what D16 still refuses: an estimate whose half again passes
+# VMEM_CEILING_BYTES
+PAST_THE_CEILING = [
+    ("bwd", 32768, 128, 128, attention.HD_MINOR),
+    ("bwd", 32768, 192, 128, attention.S_MINOR),
+    ("bwd", 65536, 64, 64, attention.S_MINOR),
+    ("fwd", 131072, 128, 128, attention.HD_MINOR),
+    ("fwd", 131072, 192, 128, attention.S_MINOR),
+]
+
+
+@pytest.mark.parametrize("kernel,S,hd,hd_v,layout", PAST_THE_DEFAULT_BUDGET)
+def test_choose_tiling_asks_for_its_estimate_past_the_default_budget(
+        kernel, S, hd, hd_v, layout):
+    t = attention.choose_tiling(kernel, S, S, hd, 2, layout=layout, hd_v=hd_v)
+    assert (t.block_q, t.block_k) == (512, 512)
+    assert attention.VMEM_BUDGET_BYTES < t.vmem_estimate
+    asked = attention._compiler_params(t).vmem_limit_bytes
+    assert asked == t.vmem_estimate + t.vmem_estimate // 2 \
+        <= attention.VMEM_CEILING_BYTES
+    # no tiling of the halving walk fits the default budget: what was refused
+    smallest = attention.vmem_estimate(kernel, 128, 128, S, S, hd, 2, layout,
+                                       hd_v)
+    assert smallest > attention.VMEM_BUDGET_BYTES
+    # a shape inside the default budget asks for nothing
+    inside = attention.choose_tiling(kernel, 1024, 1024, hd, 2, layout=layout,
+                                     hd_v=hd_v)
+    assert attention._compiler_params(inside) is None
+
+
+def test_the_cells_rows_at_192_and_128():
+    """The DeepSeek-V2 cell's kernels (S-minor, q·k 192, v 128): the forward
+    of an 8,192-token row fits the default budget at the target tiles, its
+    backward asks for 29.4 MiB and half again; rows of 4,096 fit the default
+    budget, the backward with a halved kv tile."""
+    got = {(k, S): attention.choose_tiling(k, S, S, 192, 2,
+                                           layout=attention.S_MINOR, hd_v=128)
+           for k in ("fwd", "bwd") for S in (4096, 8192)}
+    assert [(t.block_q, t.block_k) for t in got.values()] == [
+        (512, 512), (512, 512), (512, 256), (512, 512)]
+    MiB = 2 ** 20
+    assert got["fwd", 8192].vmem_estimate == 12484608 < 16 * MiB
+    assert got["bwd", 8192].vmem_estimate == 30801920
+    assert got["bwd", 4096].vmem_estimate <= 16 * MiB
+    # equal widths: what the rule always said
+    assert attention.vmem_estimate("bwd", 512, 512, 4096, 4096, 64, 2,
+                                   attention.S_MINOR, 64) \
+        == attention.vmem_estimate("bwd", 512, 512, 4096, 4096, 64, 2,
+                                   attention.S_MINOR)
+
+
+@pytest.mark.parametrize("kernel,S,hd,hd_v,layout", PAST_THE_CEILING)
+def test_choose_tiling_raises_when_nothing_fits(kernel, S, hd, hd_v, layout):
     with pytest.raises(ValueError) as err:
-        attention.choose_tiling(kernel, S, S, hd, 2, layout=layout)
+        attention.choose_tiling(kernel, S, S, hd, 2, layout=layout, hd_v=hd_v)
     msg = str(err.value)
     assert f"Sq={S}" in msg and f"Skv={S}" in msg and f"hd={hd}" in msg
-    assert layout in msg
-    assert "estimated at" in msg and str(attention.VMEM_BUDGET_BYTES) in msg
+    assert f"hd_v={hd_v}" in msg and layout in msg
+    assert "estimated at" in msg and str(attention.VMEM_CEILING_BYTES) in msg
     # a caller who fixes both tiles is not second-guessed: Mosaic is the judge
     assert attention.choose_tiling(kernel, S, S, hd, 2, block_q=128,
-                                   block_k=128, layout=layout).vmem_estimate \
-        > attention.VMEM_BUDGET_BYTES
+                                   block_k=128, layout=layout, hd_v=hd_v
+                                   ).vmem_estimate \
+        > attention.VMEM_CEILING_BYTES * 2 // 3
 
 
 @pytest.mark.parametrize("hd", [64, 128])
@@ -308,7 +366,7 @@ def test_tiling_decision_is_recorded_once_per_distinct_choice(hd):
     chosen = attention.choose_tiling("fwd", 256, 256, hd, 4, layout=layout)
     assert events[0]["args"] == {
         "kernel": "fwd", "rows": 24, "Sq": 256, "Skv": 256, "hd": hd,
-        **chosen._asdict(), "layout": layout}
+        **chosen._asdict(), "layout": layout, "hd_v": hd}
     assert all(e["args"]["layout"] == layout for e in events)
     assert tuple(events[0]["args"]) == names.FLASH_TILING_ARGS
     assert [e["args"] for e in events] == attention.flash_tiling_decisions()

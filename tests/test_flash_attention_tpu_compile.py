@@ -90,6 +90,46 @@ def test_chosen_tiling_compiles_for_the_v5e(one_chip, shape, layout):
                     and re.search(r" (copy|transpose)\(", l)]
 
 
+@pytest.mark.parametrize("S", [4096, 8192])
+def test_latent_attentions_widths_compile_for_the_v5e(one_chip, S):
+    """The flash pair at the `deepseek-v2-lite-l5.dataset` shard — 16 heads,
+    q·k at 192 and v at 128, heads leading, S-minor, rows of 8,192 (and of
+    4,096) — forward and backward: Mosaic takes [64, 192, S] and [64, 128, S]
+    operands as they are (nothing is padded to 256 or to 192), the target
+    tile where the rule gives it, and, for the 8,192-token backward, the
+    VMEM limit the call asks for (its whole rows pass the 16 MiB default:
+    D16's refusal until PR 55)."""
+    from ray_tpu.ops.attention import S_MINOR, VMEM_BUDGET_BYTES
+
+    H, B = 16, 4
+    qk = jax.ShapeDtypeStruct((H, B, 192, S), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((H, B, 128, S), jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, layout="hbds",
+                                scale=0.1147, interpret=False)
+            assert o.shape == v.shape
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = jax.jit(grads).lower(qk, qk, v).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    mine = {d["kernel"]: d for d in flash_tiling_decisions()
+            if (d["rows"], d["Sq"], d["hd"], d["hd_v"]) == (64, S, 192, 128)}
+    assert set(mine) == {"fwd", "bwd"}
+    assert all(d["layout"] == S_MINOR for d in mine.values())
+    assert (mine["fwd"]["block_q"], mine["fwd"]["block_k"]) == (512, 512)
+    assert (mine["bwd"]["block_q"], mine["bwd"]["block_k"]) == (
+        (512, 512) if S == 8192 else (512, 256))
+    assert (mine["bwd"]["vmem_estimate"] > VMEM_BUDGET_BYTES) == (S == 8192)
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l]
+    assert all(f"bf16[64,192,{S}]" in l and f"bf16[64,128,{S}]" in l
+               and "bf16[64,256," not in l for l in calls)
+    assert not [l for l in hlo.splitlines() if "bf16[" in l
+                and re.search(r" (copy|transpose)\(", l)]
+
+
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 def test_ring_chunk_kernels_compile_for_the_v5e(one_chip, kernel):
     """Ring attention's building blocks (a q chunk against an earlier kv
@@ -817,3 +857,35 @@ def test_the_lfm2_tiny_step_gates_and_convolves_in_two_kernels_under_the_scope(
             and wide.search(shape)] == []
     assert not re.findall(
         rf"= \w+\[{B},{S},{3 * D}\]\S* (concatenate|pad)\(", hlo)
+
+
+def test_the_deepseek_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
+    """`deepseek-v2-lite-l5.dataset`'s own step — 4 rows of 8,192 tokens, 16
+    of 64 experts held — compiled for the described chip: it fits (the
+    compiler's peak leaves over 1.5 GB of 15.75 GiB), nothing is
+    rematerialized by the compiler, and its Mosaic calls are the flash pair
+    at 192 / 128 (a forward, its recompute and a backward, in the dense
+    layer and in the scan of four expert layers: 6) and the held experts'
+    grouped products with their metadata kernels (30, as the LFM2 cell's)."""
+    from ray_tpu.models import blocks
+    from ray_tpu.ops.attention import S_MINOR
+
+    cell, config, family, mesh = _cell_on(topo, "deepseek-v2-lite-l5.dataset")
+    fn, args = family.abstract_step(config, cell, mesh)
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert blocks.compiler_rematerialized(hlo) == []
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 36
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= family.V5E_BYTES_LIMIT - 1.5e9, peak / 2 ** 30
+    assert peak >= 0.8 * family.V5E_BYTES_LIMIT         # a deployment's size
+    mine = {d["kernel"]: d for d in flash_tiling_decisions()
+            if (d["rows"], d["Sq"], d["hd"], d["hd_v"]) == (64, 8192, 192, 128)}
+    assert set(mine) == {"fwd", "bwd"}
+    assert all(d["layout"] == S_MINOR and d["block_q"] == d["block_k"] == 512
+               for d in mine.values())
+    (policy,) = [d for d in blocks.remat_policy_decisions()
+                 if (d["n_layer"], d["seq"]) == (5, cell["seq_len"])
+                 and d["bytes_limit"] == family.V5E_BYTES_LIMIT]
+    assert policy["phase"] == "4 x scan(E)"
+

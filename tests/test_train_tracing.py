@@ -56,17 +56,20 @@ SPARSE_KERNELS = (names.SPARSE_ATTN_FWD_KERNEL, names.SPARSE_ATTN_BWD_DQ_KERNEL,
 SALA_SCOPES = (names.LIGHTNING_ATTN, names.SPARSE_ATTENTION,
                names.SPARSE_SELECT)
 LFM2_OWN_SCOPES = (names.SHORT_CONV, names.CONV_GATE)
+# latent attention's projections and the balance loss (DeepSeek-V2, PR 55)
+DSV2_OWN_SCOPES = (names.MLA_LATENT, names.MOE_AUX)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE
                      and s not in EVA_SCOPES + NEMOTRON_SCOPES + SALA_SCOPES
-                     + LFM2_OWN_SCOPES)
+                     + LFM2_OWN_SCOPES + DSV2_OWN_SCOPES)
 EVABYTE_SCOPES = tuple(s for s in names.SCOPES if s not in (
     names.MOE, names.FLASH_ATTENTION) + NEMOTRON_SCOPES + SALA_SCOPES
-    + LFM2_OWN_SCOPES)
+    + LFM2_OWN_SCOPES + DSV2_OWN_SCOPES)
 # every layer of the hybrid is a mixer OR a feed-forward part: one norm a
 # layer (ln1), the shared expert under `mlp` inside `moe`
 HYBRID_SCOPES = tuple(s for s in names.SCOPES if s != names.LN2
-                      and s not in EVA_SCOPES + SALA_SCOPES + LFM2_OWN_SCOPES)
+                      and s not in EVA_SCOPES + SALA_SCOPES + LFM2_OWN_SCOPES
+                      + DSV2_OWN_SCOPES)
 # every layer of LFM2-MoE is an operator AND a feed-forward half: the block's
 # six scopes (`mlp` in the dense layer, `moe` with the shared dispatch in the
 # expert layers), the flash kernels' and the short convolution's
@@ -378,12 +381,17 @@ def test_flash_tiling_decision_of_the_lowered_step(kernel, monkeypatch):
     assert tuple(mine[0]) == names.FLASH_TILING_ARGS
     # ... the last of which says which kernel pair the step was traced with:
     # the S-minor one at gpt2_tiny's head width, as at GPT-2's 64
-    assert names.FLASH_TILING_ARGS[-1] == "layout"
+    # ... and, last, v's and o's width beside q's and k's `hd` (PR 55: equal
+    # anywhere but in latent attention)
+    assert names.FLASH_TILING_ARGS[-2:] == ("layout", "hd_v")
+    assert mine[0]["hd_v"] == mine[0]["hd"] == cfg.head_dim
+    assert attention.kernel_layout(192, 128) == attention.S_MINOR
+    assert attention.kernel_layout(256, 128) == attention.HD_MINOR
     assert mine[0]["layout"] == attention.kernel_layout(cfg.head_dim) \
         == attention.S_MINOR == attention.kernel_layout(64)
     assert attention.kernel_layout(128) == attention.HD_MINOR
     # the EVA event keeps the arguments it had (its kernels have one layout)
-    assert names.EVA_TILING_ARGS == names.FLASH_TILING_ARGS[:-1] + (
+    assert names.EVA_TILING_ARGS == names.FLASH_TILING_ARGS[:-2] + (
         "window", "chunk")
 
 
@@ -1032,7 +1040,7 @@ def _counters_event(step, t_dispatch, passes, pairs, fullest, held=8):
 def test_counter_reader_against_the_recorded_session(metric):
     """Each reader on the `train/*` events of a recorded rehearsal of the
     Nemotron cell: the number `benchmarks/testdata/` holds for the window
-    its summary's wall clocks cut; the entry names the two expert cells."""
+    its summary's wall clocks cut; the entry names the three expert cells."""
     import gzip
     import json
 
@@ -1044,7 +1052,8 @@ def test_counter_reader_against_the_recorded_session(metric):
     assert (reader.UNIT, reader.LAYER, reader.MOVES, reader.SOURCE) == (
         entry["unit"], entry["layer"], entry["moves"], entry["source"])
     assert entry["workloads"] == ["nemotron-3-super-120b-l11.dataset",
-                                  "lfm2-24b-a2b-l5.dataset"]
+                                  "lfm2-24b-a2b-l5.dataset",
+                                  "deepseek-v2-lite-l5.dataset"]
     facts = _record_facts(events, expected["summary"])
     assert reader.read(facts) == pytest.approx(expected["metrics"][metric],
                                                rel=1e-12)
