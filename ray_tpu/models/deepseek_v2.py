@@ -434,10 +434,33 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
                 ) -> Tuple[parts.BlockShard, Dict[str, blocks.KindShard]]:
     """This config's layers on one chip of ``mesh``, for the remat rule: the
     model's shard (stream, head, rows at a time) and, a kind, how often it is
-    applied, what a layer of it may keep and what its backward holds at once
-    — latent attention's residual set, which waits while the feed-forward
-    half's backward runs, beside that half's own — and its weight
-    gradients."""
+    applied, what a layer of it may keep, its weight gradients and what its
+    backward holds at once: the LARGEST of the backward's moments, each
+    summed from what is live in it — never their sum, since no two of them
+    overlap. Through every moment waits the cotangent of the block's output.
+
+    - The feed-forward half's backward. Of latent attention there waits
+      what its own backward will read — the block's input, ``u``, q, k, v,
+      o (+ lse), the weights' cast — and not yet a gradient of those. The
+      expert half holds its own stream (its input, the normed input, the
+      float32 sum and its cotangent) and the routing's three [tokens,
+      n_experts] tensors beside ONE of two: the routed passes' rows with
+      the held experts' weights cast and their float32 gradients, or the
+      shared expert's hidden tensors and weights. The dense half holds a
+      chunk's hidden tensors and its weights.
+    - Latent attention's own backward: its whole set, gradients and all,
+      and nothing of the feed-forward half, which is done.
+
+    At the DeepSeek-V2-Lite cell's shapes (4 x 8,192 tokens, 16 held experts
+    1,408 wide, a 61,440-row buffer) the routed passes' moment is the largest
+    by far, 4.30 GB: a row buffer of 1.25 x the pairs at 2 · 2,048 + 6 ·
+    1,408 numbers a row (1.54 GB) and 4 + 2 bytes a held weight (0.83)
+    outweigh the shared expert's un-chunked five [32,768, 2,816] tensors
+    (0.99), and attention's waiting operands (0.97) with the half's stream
+    (0.83) stand beside either; attention's own backward holds 2.12. Summed,
+    the three stood at 6.18 GB, 1.9 GB over what the compiled step takes, and
+    the rule kept nothing beside a chip with 2 GiB free (PERF.md §6, PR 56).
+    """
     a = jnp.dtype(cfg.dtype).itemsize
     D, F, Fe, H = cfg.d_model, cfg.d_ff, cfg.d_expert, cfg.n_head
     Fs = cfg.n_shared * cfg.d_expert
@@ -476,9 +499,14 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
     attn_params = D * H * hd + D * latent + rank * H * (cfg.qk_nope_dim + hv) \
         + H * hv * D
     # its backward holds four tensors of the stream's width, q, k and their
-    # gradients, v, o and theirs, and the weights cast twice
+    # gradients, v, o and theirs, and the weights cast twice; until then what
+    # that backward will read waits: the block's input and u, q, k, v, o (and
+    # the kernel's lse), the weights cast once
     attn_set = a * (tokens * (4 * D + 4 * H * hd + 4 * H * hv)
                     + 2 * 2 * attn_params)
+    attn_waits = (a * (tokens * (2 * D + 2 * H * hd + 2 * H * hv)
+                       + attn_params) + (tokens * H * 4 if flash else 0))
+    carried = tokens * D * a    # the cotangent of the block's output
 
     # the feed-forward halves, as the LFM2 family prices them: the dense
     # one's hidden tensors where it is not chunked; what the routing decided
@@ -504,11 +532,11 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
                             scopes.RES_MOE_SHARED_UP))
          if shared_rows in (0, base.seq) else ())
     expert_weights = 3 * cfg.held_count * D * Fe
+    routed_set = a * rows * (2 * D + 6 * Fe) + (a + 4) * expert_weights
+    shared_set = a * (base.batch * (shared_rows or base.seq) * 5 * Fs
+                      + 2 * 3 * D * Fs)
     experts_set = (tokens * D * (2 * a + 8) + tokens * cfg.n_experts * 12
-                   + a * rows * (2 * D + 6 * Fe)
-                   + (a + 4) * expert_weights
-                   + a * (base.batch * (shared_rows or base.seq) * 5 * Fs
-                          + 2 * 3 * D * Fs))
+                   + max(routed_set, shared_set))
 
     kinds = {}
     for kind in dict.fromkeys(cfg.pattern):
@@ -516,7 +544,7 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
                            else (dense_kept, dense_set))
         kinds[kind] = blocks.KindShard(
             cfg.pattern.count(kind), tuple(attn_kept) + ff_kept,
-            attn_set + ff_set)
+            carried + max(attn_waits + ff_set, attn_set))
     chips = mesh.devices.size if mesh is not None else 1
     return base, {k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
                   for k, v in _one_candidate_a_name(kinds).items()}

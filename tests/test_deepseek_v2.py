@@ -15,11 +15,13 @@ adds names the new cell alone, imports nothing of ``ray_tpu`` at module level
 and reads nothing, without raising, from another cell's recorded trace."""
 
 import ast
+import dataclasses
 import importlib
 import json
 import math
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -180,6 +182,12 @@ def test_a_step_says_its_balance_loss_among_its_counters():
     assert ds.step_counters(ds.deepseek_v2_tiny(n_layer=1)) is None
 
 
+def _assert_the_same_bits(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32))
+
+
 def test_remat_changes_no_number():
     cfg = ds.deepseek_v2_tiny()
     tokens, targets = _batch(cfg)
@@ -187,9 +195,46 @@ def test_remat_changes_no_number():
     a = jax.value_and_grad(ds.loss_fn)(params, tokens, targets, cfg)
     b = jax.value_and_grad(ds.loss_fn)(
         params, tokens, targets, ds.deepseek_v2_tiny(remat=True))
-    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
-        np.testing.assert_array_equal(np.asarray(x, np.float32),
-                                      np.asarray(y, np.float32))
+    _assert_the_same_bits(a, b)
+
+
+def test_kept_residuals_change_no_number_and_spare_the_second_flash_forward():
+    """PR 56: with a limit stated that gives the rule room for the flash
+    kernel's o and lse and for the latent pair, the loss and every gradient
+    are the fully rematted step's bit for bit — a kept residual is what the
+    second forward would have made — and the backward of a checkpointed
+    layer calls the forward kernel no second time: one call a run of the
+    layers (the dense layer, the scan's body) where nothing kept makes two."""
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    cfg = ds.deepseek_v2_tiny(remat=True, attention_impl="pallas")
+    tokens, targets = _batch(cfg)
+    params = _params(cfg)
+    base, kinds = ds.kind_shards(cfg, 2, cfg.seq_len, None)
+    phase = _phase(cfg, base, kinds)
+    ranked = sorted(((c, k.applications) for k in kinds.values()
+                     for c in k.candidates),
+                    key=lambda cn: -cn[0].flops / cn[0].nbytes)
+    wanted = {names.RES_FLASH_O, names.RES_FLASH_LSE, names.RES_MLA_C,
+              names.RES_MLA_KPE}
+    last = max(i for i, (c, _) in enumerate(ranked) if wanted & set(c.names))
+    limit = blocks.REMAT_RESERVE_BYTES + phase.nbytes + 12345 + sum(
+        n * c.nbytes for c, n in ranked[:last + 1])
+
+    def loss(p, limit):
+        with mesh_lib.chip_memory(limit, 12345):
+            return ds.loss_fn(p, tokens, targets, cfg)
+
+    kept = jax.value_and_grad(partial(loss, limit=limit))
+    nothing = jax.value_and_grad(partial(loss, limit=None))
+    _assert_the_same_bits(kept(params), nothing(params))
+    (d,) = [d for d in blocks.remat_policy_decisions()
+            if d["bytes_limit"] == limit and d["seq"] == cfg.seq_len]
+    assert wanted <= set(d["saved"]) and len(d["saved"]) < len(names.RESIDUALS)
+    forward = f"name={names.FLASH_FWD_KERNEL}"
+    runs = len(blocks.pattern_groups(cfg.pattern))
+    assert str(jax.make_jaxpr(kept)(params)).count(forward) == runs
+    assert str(jax.make_jaxpr(nothing)(params)).count(forward) == 2 * runs
 
 
 # ------------------------------------------------------------------ the shares
@@ -688,6 +733,87 @@ def test_the_pattern_its_groups_and_what_the_rule_may_keep():
         lambda p: ds.loss_fn(p, tokens_, targets, cfg)))(_params(cfg)))
     for name in flat:
         assert f"name={name}" in jaxpr, name
+
+
+def _cell_shards(rows=None, **overrides):
+    """(the cell's program config on the flash pair, kind_shards' two
+    results) at the cell's own shapes, or with ``rows`` rows and
+    ``overrides`` of the config: arithmetic, nothing is traced."""
+    cell, config = _cell()
+    cfg = dataclasses.replace(family.program_config(config, cell),
+                              attention_impl="pallas", **overrides)
+    return (cfg,) + ds.kind_shards(cfg, rows or cell["per_chip_batch"],
+                                   cfg.seq_len, None)
+
+
+def _phase(cfg, base, kinds):
+    return max(blocks.backward_phases(base, kinds,
+                                      blocks.pattern_groups(cfg.pattern)),
+               key=lambda p: p.nbytes)
+
+
+def test_the_cells_decision_from_its_shapes_keeps_the_flash_outputs_first():
+    """PR 56: `deepseek-v2-lite-l5.dataset`'s decision, from its shapes and a
+    v5e's bytes_limit alone. An expert layer's block is the LARGEST moment of
+    its backward — the routed passes', with what latent attention's backward
+    waits to read — where the sum of all three stood at 6.18 GB: the phase
+    `4 x scan(E)` is 5.0–5.4 GB (the compiled step takes 5.0 beside its
+    resident bytes), so the rule has about a GB and spends it on the flash
+    kernel's o and lse first, the router's scores, the latent and its k_pe —
+    and on nothing of a GB a name."""
+    cfg, base, kinds = _cell_shards()
+    assert (base.batch, base.seq, base.head_rows, base.mlp_rows, base.flash
+            ) == (4, 8192, 256, 512, True)
+    phase = _phase(cfg, base, kinds)
+    assert phase.name == "4 x scan(E)" and 5.0e9 <= phase.nbytes <= 5.4e9
+    # attention's waiting operands, the half's stream and routing, the
+    # passes' rows and weights, the carried cotangent: written out
+    T, D, rows = 4 * 8192, 2048, 61440
+    assert kinds["E"].block_bytes == (
+        T * D * 2
+        + 2 * (T * (2 * D + 2 * 16 * 192 + 2 * 16 * 128) + 13_762_560)
+        + T * 16 * 4
+        + T * D * 12 + T * 64 * 12
+        + 2 * rows * (2 * D + 6 * 1408) + 6 * 3 * 16 * D * 1408)
+    assert kinds["D"].block_bytes < kinds["E"].block_bytes
+    params = jax.eval_shape(lambda: ds.init(cfg, jax.random.PRNGKey(0)))
+    # (float32 parameters and gradients, bfloat16 moments: 12 B a parameter)
+    resident = 3 * sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(params))
+    policy = blocks.choose_remat_policy_kinds(
+        tuple(kinds.values()), phase.nbytes, family.V5E_BYTES_LIMIT, resident)
+    assert policy.saved[:2] == (names.RES_FLASH_O, names.RES_FLASH_LSE)
+    assert {names.RES_MOE_SCORES, names.RES_MLA_C, names.RES_MLA_KPE} < set(
+        policy.saved)
+    assert not {names.RES_Q, names.RES_K, names.RES_V, names.RES_MID,
+                names.RES_MOE_SHARED_GATE, names.RES_MOE_SHARED_UP} & set(
+        policy.saved)
+    assert 0.85e9 < policy.saved_bytes <= policy.budget_bytes < 1.2e9
+
+
+@pytest.mark.parametrize("grown", [
+    dict(rows=8), dict(seq_len=16384), dict(held_count=32),
+    dict(d_expert=2816), dict(n_head=32), dict(held_count=1, shared="whole"),
+], ids=lambda g: "-".join(g))
+def test_a_blocks_estimate_grows_with_every_shape_it_is_made_from(
+        grown, monkeypatch):
+    """The estimate is arithmetic from the shapes, not a number fitted to the
+    cell: more rows, a longer sequence, more held experts, wider experts or
+    more heads each make the expert layer's block larger — and where the
+    shared expert's moment is the larger of the half's two (one held expert),
+    a shared expert taken whole makes it larger than one taken in chunks."""
+    grown = dict(grown)
+    whole = grown.pop("shared", None)
+    with monkeypatch.context() as m:
+        if whole:
+            # one hidden tensor of the cell's shared expert is 184.5 MB:
+            # under this bound the baseline takes it in chunks
+            m.setattr(parts, "MLP_CHUNK_BYTES", 2 ** 26)
+            assert parts.mlp_rows(4, 8192, 2048, 2 * 1408, 2) < 8192
+        before = _cell_shards(**(grown if whole else {}))[2]["E"].block_bytes
+    cfg, base, kinds = _cell_shards(**grown)
+    assert kinds["E"].block_bytes > before
+    assert _phase(cfg, base, kinds).name == "4 x scan(E)"
 
 
 # --------------------------------------------------------- the benchmark's files

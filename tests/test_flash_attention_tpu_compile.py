@@ -861,23 +861,34 @@ def test_the_lfm2_tiny_step_gates_and_convolves_in_two_kernels_under_the_scope(
 
 def test_the_deepseek_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
     """`deepseek-v2-lite-l5.dataset`'s own step — 4 rows of 8,192 tokens, 16
-    of 64 experts held — compiled for the described chip: it fits (the
-    compiler's peak leaves over 1.5 GB of 15.75 GiB), nothing is
-    rematerialized by the compiler, and its Mosaic calls are the flash pair
-    at 192 / 128 (a forward, its recompute and a backward, in the dense
-    layer and in the scan of four expert layers: 6) and the held experts'
-    grouped products with their metadata kernels (30, as the LFM2 cell's)."""
+    of 64 experts held — compiled for the described chip: it fits what the
+    remat rule works to (the compiler's peak is under the chip's bytes_limit
+    less the rule's reserve), nothing is rematerialized by the compiler, and
+    its Mosaic calls are the flash pair at 192 / 128 — a forward and a
+    backward in the dense layer and in the scan of four expert layers: 4,
+    and NO recomputed forward, since the rule keeps the kernel's o and lse
+    (PR 56: an expert layer's block is the largest moment of its backward,
+    not the moments' sum) — and the held experts' grouped products with
+    their metadata kernels (30, as the LFM2 cell's)."""
     from ray_tpu.models import blocks
     from ray_tpu.ops.attention import S_MINOR
+    from ray_tpu.tracing import names
+    from ray_tpu.train.train_step import _resident_bytes
 
     cell, config, family, mesh = _cell_on(topo, "deepseek-v2-lite-l5.dataset")
     fn, args = family.abstract_step(config, cell, mesh)
     compiled = fn.lower(*args).compile()
     hlo = compiled.as_text()
     assert blocks.compiler_rematerialized(hlo) == []
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 36
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 34
+    flash = [op for _, code, op in _instructions(hlo)
+             if code == "custom-call" and "flash_attention_" in op]
+    assert sum(f"/{names.FLASH_FWD_KERNEL}" in op for op in flash) == 2
+    assert sum(f"/{names.FLASH_BWD_KERNEL}" in op for op in flash) == 2
+    assert not [op for op in flash if "rematted_computation" in op], flash
     peak = compiled.memory_analysis().peak_memory_in_bytes
-    assert peak <= family.V5E_BYTES_LIMIT - 1.5e9, peak / 2 ** 30
+    assert peak <= family.V5E_BYTES_LIMIT - blocks.REMAT_RESERVE_BYTES, (
+        peak / 2 ** 30)
     assert peak >= 0.8 * family.V5E_BYTES_LIMIT         # a deployment's size
     mine = {d["kernel"]: d for d in flash_tiling_decisions()
             if (d["rows"], d["Sq"], d["hd"], d["hd_v"]) == (64, 8192, 192, 128)}
@@ -888,4 +899,12 @@ def test_the_deepseek_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
                  if (d["n_layer"], d["seq"]) == (5, cell["seq_len"])
                  and d["bytes_limit"] == family.V5E_BYTES_LIMIT]
     assert policy["phase"] == "4 x scan(E)"
-
+    assert 5.0e9 <= policy["phase_bytes"] <= 5.4e9
+    assert policy["saved"][:2] == [names.RES_FLASH_O, names.RES_FLASH_LSE]
+    assert {names.RES_MLA_C, names.RES_MLA_KPE, names.RES_MOE_SCORES} < set(
+        policy["saved"])
+    assert not {names.RES_Q, names.RES_MID, names.RES_MOE_SHARED_GATE,
+                names.RES_MOE_SHARED_UP} & set(policy["saved"])
+    # the estimate stays over what the compiler needs with these kept
+    assert peak <= (_resident_bytes(args[0]) + policy["phase_bytes"]
+                    + policy["saved_bytes"])
