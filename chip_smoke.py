@@ -28,7 +28,12 @@ over its three kinds and its ``model/expert_load`` events, one step of a small
 DeepSeek-V2 (``models/deepseek_v2.py``: latent attention at q·k 192 / v 128
 over a dense layer and two layers of shared + routed experts under a balance
 loss, PR 55) for its ``DEE`` pattern, the flash kernels' ``ops/flash_tiling``
-at the two widths and the balance loss its step says beside its load, and —
+at the two widths and the balance loss its step says beside its load, one
+step of a small Xing4.0 (the same ``models/deepseek_v2.py`` with query
+compression, the biased-sigmoid router, an MTP module and four
+manifold-constrained hyper-connection streams around every sublayer, PR 57)
+for its ``model/hyper_connection`` event and its expert layers' loads, the
+MTP module's among them, and —
 what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
@@ -378,7 +383,7 @@ def train_loop(config: Dict[str, Any]) -> None:
                 variant.state["params"], dsv2_batch["tokens"], dsv2_cfg)
         _, m = variant.step_fn({**variant.state, "params": params}, dsv2_batch)
         counters = np.asarray(m["counters"])
-        n_load = len(deepseek_v2.STEP_FIELDS) - 1
+        n_load = len(deepseek_v2.step_fields(dsv2_cfg)) - 1
         dsv2 = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
                 "seq_len": dsv2_cfg.seq_len,
                 "layer_pattern": [d for d in layer_pattern_decisions()
@@ -394,6 +399,41 @@ def train_loop(config: Dict[str, Any]) -> None:
                 # the last column holds the float32's bits
                 "balance_loss": np.ascontiguousarray(
                     counters[:, n_load]).view(np.float32).tolist()}
+        del variant
+    # One step of the same family as Xing4.0 sets it: query compression, the
+    # biased-sigmoid router (its biases balanced at set-up), an MTP module
+    # and a four-stream hyper-connection around every sublayer.
+    xing4 = None
+    if config.get("xing4_model") is not None:
+        from ray_tpu.models import deepseek_v2, hyper_connections
+        from ray_tpu.models.blocks import layer_pattern_decisions
+
+        xing4_cfg = config["xing4_model"]
+        variant = make_train_step(
+            deepseek_v2, xing4_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP,
+                                        total_steps=steps,
+                                        decay_mask=deepseek_v2.decays))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, xing4_cfg.seq_len), dtype=np.int32)
+        xing4_batch = jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding)
+        with mesh_lib.use_mesh(mesh):
+            params, load = deepseek_v2.balance_router_bias(
+                variant.state["params"], xing4_batch, xing4_cfg)
+        _, m = variant.step_fn({**variant.state, "params": params},
+                               xing4_batch)
+        xing4 = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+                 "seq_len": xing4_cfg.seq_len,
+                 "layer_pattern": [d for d in layer_pattern_decisions()
+                                   if d["pattern"] in (xing4_cfg.pattern,
+                                                       xing4_cfg.mtp_pattern)],
+                 "hyper_connection": [
+                     d for d in hyper_connections.decisions()
+                     if d["streams"] == xing4_cfg.hc_mult],
+                 "expert_load": load,
+                 "step_load": np.asarray(m["counters"]).tolist()}
         del variant
     jax.monitoring.unregister_event_listener(on_event)
 
@@ -424,13 +464,15 @@ def train_loop(config: Dict[str, Any]) -> None:
         "sala": sala,
         "lfm2": lfm2,
         "dsv2": dsv2,
+        "xing4": xing4,
     }})
 
 
 def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         use_tpu: bool, seed: int = 0, eva_model=None,
         hybrid_model=None, sala_model=None,
-        lfm2_model=None, dsv2_model=None) -> List[Dict[str, Any]]:
+        lfm2_model=None, dsv2_model=None,
+        xing4_model=None) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -453,7 +495,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "per_chip_batch": per_chip_batch, "seed": seed,
             "eva_model": eva_model, "hybrid_model": hybrid_model,
             "sala_model": sala_model, "lfm2_model": lfm2_model,
-            "dsv2_model": dsv2_model,
+            "dsv2_model": dsv2_model, "xing4_model": xing4_model,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -588,6 +630,23 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if not all(0.5 < b < 8.0 for b in dsv2["balance_loss"]):
             bad.append("the DeepSeek-V2 step said balance losses "
                        f"{dsv2['balance_loss']} of its expert layers")
+    xing4 = summary.get("xing4")
+    if xing4 is not None:
+        if not (math.isfinite(xing4["loss"])
+                and math.isfinite(xing4["grad_norm"])):
+            bad.append(f"the Xing4.0 step's loss {xing4['loss']} or "
+                       f"grad_norm {xing4['grad_norm']} is not finite")
+        if not xing4["layer_pattern"] or not xing4["hyper_connection"]:
+            bad.append("the Xing4.0 step recorded no model/layer_pattern or "
+                       "no model/hyper_connection event")
+        if len(xing4["expert_load"]) != len(xing4["step_load"]):
+            bad.append("the Xing4.0 step recorded no model/expert_load event "
+                       "for each expert layer its step reports, the MTP "
+                       "module's among them")
+        dropped = sum(e["pairs_dropped"] for e in xing4["expert_load"])
+        if dropped:
+            bad.append(f"the Xing4.0 step's expert layers dropped {dropped} "
+                       "(token, choice) pairs")
     return bad
 
 
@@ -774,6 +833,18 @@ def main() -> int:
     dsv2_cfg = deepseek_v2.DeepseekV2Config(
         vocab_size=4096, seq_len=2048, n_layer=3, d_model=1024, n_head=8,
         d_ff=2816, held_count=16, d_expert=704, remat=True)
+    # Xing4.0's layers 1-2 and its MTP module at two sevenths of the width:
+    # the same latent attention with query compression under YaRN x 64, four
+    # hyper-connection streams of 1,024, 8 of 64 experts held, top-4 by a
+    # biased sigmoid beside one shared expert, no balance loss
+    xing4_cfg = deepseek_v2.DeepseekV2Config(
+        vocab_size=4096, seq_len=2048, n_layer=2, first_layer=1,
+        first_k_dense=2, n_layer_published=40, d_model=1024, n_head=8,
+        q_lora_rank=256, rope_factor=64.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0, d_ff=2816, top_k=4, held_count=8,
+        d_expert=512, n_shared=1, routed_scaling=2.0, scoring="sigmoid",
+        norm_topk_prob=True, selection_bias=True, aux_loss_alpha=0.0,
+        hc_mult=4, mtp_layers=1, remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -786,7 +857,8 @@ def main() -> int:
         rows = run(model_cfg, steps=STEPS, per_chip_batch=PER_CHIP_BATCH,
                    num_devices=chips, use_tpu=True, eva_model=eva_cfg,
                    hybrid_model=hybrid_cfg, sala_model=sala_cfg,
-                   lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg)
+                   lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg,
+                   xing4_model=xing4_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -803,7 +875,7 @@ def main() -> int:
     failures += missing
     step_loads = {}
     for what, key in (("hybrid", "hybrid"), ("LFM2-MoE", "lfm2"),
-                      ("DeepSeek-V2", "dsv2")):
+                      ("DeepSeek-V2", "dsv2"), ("Xing4.0", "xing4")):
         step_loads[key], missing = step_load_line(record, summary[key], what)
         failures += missing
 
@@ -928,6 +1000,26 @@ def main() -> int:
           f"{summary['device_count']}x{dsv2['seq_len']} tokens, remat): loss "
           f"{dsv2['loss']:.4f} grad_norm {dsv2['grad_norm']:.4f}, balance "
           f"loss a layer {[round(b, 4) for b in dsv2['balance_loss']]}")
+    xing4 = summary["xing4"]
+    for d in xing4["layer_pattern"]:
+        print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
+              f"{d['groups']}")
+    for d in xing4["hyper_connection"]:
+        print(f"hyper-connection: {d['streams']} streams, {d['rounds']} "
+              f"Sinkhorn rounds, the stream in {d['stream_dtype']}, "
+              f"{d['carry_bytes_per_token']} B a token")
+    for e in xing4["expert_load"]:
+        print(f"Xing4.0 expert load: published layer {e['layer']}: "
+              f"{e['pairs']} pairs of {e['tokens']} tokens on the held "
+              f"experts (max {e['max_per_expert']}, mean "
+              f"{e['mean_per_expert']:.1f} an expert), {e['buffer_passes']} "
+              f"pass(es) over a buffer of {e['buffer_rows']} rows, dropped "
+              f"{e['pairs_dropped']}")
+    print("\n".join(step_loads["xing4"]))
+    print(f"Xing4.0 step ({xing4_cfg.pattern} + MTP {xing4_cfg.mtp_pattern} "
+          f"of {xing4_cfg.hc_mult} x {xing4_cfg.d_model}, "
+          f"{summary['device_count']}x{xing4['seq_len']} tokens, remat): loss "
+          f"{xing4['loss']:.4f} grad_norm {xing4['grad_norm']:.4f}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

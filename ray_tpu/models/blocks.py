@@ -8,9 +8,12 @@ ONE choice of what ``remat=True`` keeps over all the kinds' applications, its
 A model states its layers as KindShards (how often a kind is applied, what a
 layer of it may keep, what its backward holds) and its own share of the step
 as a shard: any tuple with ``batch``, ``seq``, ``d_model``, ``dtype_bytes``,
-``vocab``, ``head_rows`` and ``mlp_rows`` (parts.BlockShard is one). Nothing
-here reads more of it: no mixer, no MLP is named in this file, and it imports
-no other module of ``ray_tpu.models``.
+``vocab``, ``head_rows`` and ``mlp_rows`` (parts.BlockShard is one) and,
+where the layers' carry is wider than ``d_model`` — a hyper-connected model's
+n streams —, ``carry_width``: the stack of block inputs is priced at the
+carry's width, the head and the embedding at ``d_model``. Nothing here reads
+more of it: no mixer, no MLP is named in this file, and it imports no other
+module of ``ray_tpu.models``.
 """
 
 from __future__ import annotations
@@ -87,7 +90,10 @@ def model_working_set(s: Shard, n_layer: int) -> int:
 
 
 def _block_input(s: Shard) -> int:
-    return s.batch * s.seq * s.d_model * s.dtype_bytes
+    """A layer's input as run_pattern carries it: ``d_model`` wide unless the
+    shard states a wider carry."""
+    width = getattr(s, "carry_width", 0) or s.d_model
+    return s.batch * s.seq * width * s.dtype_bytes
 
 
 def _head_terms(s: Shard) -> int:

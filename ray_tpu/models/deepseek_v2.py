@@ -1,50 +1,77 @@
 """DeepSeek-V2-family decoder in pure JAX: latent attention over a dense or a
-shared + routed-experts feed-forward half.
+shared + routed-experts feed-forward half, on one residual stream or on a
+hyper-connection's n.
 
 Sixth model family beside GPT-2, LLaMA, Nemotron-H, MiniCPM-SALA and
-LFM2-MoE. Every layer is a pre-normed pair, ``h = x + MLA(RMSNorm(x))``,
-``x' = h + FF(RMSNorm(h))``, of one of two kinds (one character a layer of
+LFM2-MoE, with two callers: DeepSeek-V2-Lite (PR 55) and Xing4.0 (PR 57),
+which differ in what the CONFIG states, never in a second copy of the layer.
+Every layer is a pre-normed pair of sublayers, latent attention and then a
+feed-forward half, of one of two kinds (one character a layer of
 ``cfg.pattern``, from the published ``first_k_dense_replace``):
 
 - ``D`` — latent attention + a dense SwiGLU MLP (the leading layers);
 - ``E`` — latent attention + a mixture of experts.
 
-**Multi-head latent attention** (arXiv:2405.04434 §2.1; no query
-compression, the Lite model's ``q_lora_rank`` null): ``q = u·W_q`` →
-``[q_nope | q_pe]`` a head; ``[c | k_pe] = u·W_kva``, ``c ← RMSNorm(c)``;
-``[k_nope | v] = c·W_kvb`` a head; ``k_pe`` is ONE head for all of them.
-RoPE turns ``q_pe`` and ``k_pe`` only — the last ``qk_rope_dim`` channels of
-the q·k width — with YaRN's frequencies (parts.yarn_inv_freq);
-``k = [k_nope | k_pe]``; causal softmax of ``q·kᵀ · s``, ``s = (nope +
-rope)^-½ · m²`` with YaRN's ``m = 0.1 · mscale_all_dim · ln(factor) + 1``;
-``· v`` at ``v_head_dim``; ``· W_o``. q and k are ``nope + rope`` wide (192)
-and v and o ``v_head_dim`` (128): the flash kernels read each at its own
-width (ops/attention.py, the S-minor pair — kernel_layout says why), in
-parts.head_layout's order with no transpose at their edge. The rotary
-channels of ``W_q`` and ``W_kva`` are stored de-interleaved (pairs (i, i +
-rope/2)): the published checkpoint's (2i, 2i + 1) order is a permutation of
-those columns, the same for q and k, which q·k does not see.
+**The residual path** (``hc_mult``). 1: ``h = x + MLA(RMSNorm(x))``, ``x' = h
++ FF(RMSNorm(h))`` on a ``[B, S, d_model]`` carry. n > 1: a
+manifold-constrained hyper-connection of n streams around every sublayer
+(models/hyper_connections.py: the carry is ``[B, S, n · d_model]``; a
+sublayer reads ``RMSNorm(Σ H_pre[i] · x[i])`` and its float32 output y goes
+back as ``x'[i] = Σ H_res[i, j] · x[j] + H_post[i] · y``, the three maps made
+from the token's own streams, H_res by ``hc_sinkhorn_iters`` Sinkhorn rounds);
+the streams start as n copies of the embedding and end summed. With 1 there
+is no map, no parameter and the lowered step is what it was before the path
+existed (tests/test_xing4.py holds it to the recorded text).
+
+**Multi-head latent attention** (arXiv:2405.04434 §2.1): ``q = u·W_q`` →
+``[q_nope | q_pe]`` a head — or, with ``q_lora_rank``, the compressed query
+``q = RMSNorm(u·W_qa)·W_qb`` (null in the Lite model) —; ``[c | k_pe] =
+u·W_kva``, ``c ← RMSNorm(c)``; ``[k_nope | v] = c·W_kvb`` a head; ``k_pe`` is
+ONE head for all of them. RoPE turns ``q_pe`` and ``k_pe`` only — the last
+``qk_rope_dim`` channels of the q·k width — with YaRN's frequencies
+(parts.yarn_inv_freq); ``k = [k_nope | k_pe]``; causal softmax of ``q·kᵀ ·
+s``, ``s = (nope + rope)^-½ · m²`` with YaRN's ``m = 0.1 · mscale_all_dim ·
+ln(factor) + 1``; ``· v`` at ``v_head_dim``; ``· W_o``. q and k are ``nope +
+rope`` wide (192) and v and o ``v_head_dim`` (128): the flash kernels read
+each at its own width (ops/attention.py, the S-minor pair — kernel_layout
+says why), in parts.head_layout's order with no transpose at their edge. The
+rotary channels of ``W_q`` (``W_qb``) and ``W_kva`` are stored de-interleaved
+(pairs (i, i + rope/2)): the published checkpoint's (2i, 2i + 1) order is a
+permutation of those columns, the same for q and k, which q·k does not see.
 
 The feed-forward halves: the dense ``(silu(u·W₁) ⊙ u·W₃)·W₂``, and the
-expert layer (ops/moe.gated_moe): ``p = softmax(u·W_g)`` in float32 over all
-``n_experts``, the ``top_k`` largest chosen, **gates = the chosen p as they
-are** (``norm_topk_prob`` false) · ``routed_scaling``, experts of the dense
-MLP's form at ``d_expert``, beside ONE shared expert of the same form at
-``n_shared · d_expert`` that every token takes. Training adds, an expert
-layer, ``aux_loss_alpha`` × the sequence-wise balance loss
-(ops/moe.balance_loss): it leaves the layer loop as a float32 a layer beside
-the layer's counters and is added to the loss inside ``loss_fn``, so the
-step's ``jax.grad`` sees it. There is no selection bias: a benchmark's run
-on freshly drawn weights has its routers balanced once at set-up by that
-same loss (balance_routers — set-up's alone: no training path calls it).
-The head is untied, after an RMSNorm.
+expert layer (ops/moe.gated_moe) under the router's RULE, which is the
+configuration's (``cfg.rule``, ``selection_bias``, ``aux_loss_alpha``), not
+a constant of this module: scores over all ``n_experts`` in float32 — one
+``softmax`` (DeepSeek-V2) or each expert's ``sigmoid`` (Xing4.0's
+``noaux_tc``) —, the ``top_k`` largest of score (+ a selection bias, a
+buffer no gradient or decay reaches, where the config has one) chosen, gates
+the chosen scores as they are or divided by their sum (``norm_topk_prob``),
+times ``routed_scaling``; experts of the dense MLP's form at ``d_expert``,
+beside ONE shared expert of the same form at ``n_shared · d_expert`` that
+every token takes. With ``aux_loss_alpha`` > 0 training adds, an expert
+layer, that × the sequence-wise balance loss (ops/moe.balance_loss): it
+leaves the layer loop as a float32 a layer beside the layer's counters and
+is added to the loss inside ``loss_fn``, so the step's ``jax.grad`` sees it.
+A benchmark's run on freshly drawn weights has its routers balanced once at
+set-up — by that loss where there is no bias (balance_routers), by the
+bias's own rule where there is one (balance_router_bias) — set-up's alone:
+no training path calls either. The head is untied, after an RMSNorm.
+
+**Multi-token prediction** (``mtp_layers``; DeepSeek-V3, arXiv:2412.19437
+§2.2): after the trunk, a module of that many expert layers on
+``parts.mtp_join`` of the trunk's stream (before the final norm) and the
+next token's embedding, with its own hyper-connections and final norm and
+the SHARED embedding and head, targets shifted one further; ``loss =
+CE_trunk + mtp_loss_weight · CE_mtp``.
 
 It runs on the shared machinery: ``blocks.run_pattern`` /
-``blocks.checkpoint_kinds`` (ONE remat rule over the two kinds), parts'
-RMSNorm, RoPE, residual add, weight cast inside the loop, causal attention,
-the rows an MLP and a head take at a time and the chunked head + loss;
-ops/moe.py's dispatch, shared with the Nemotron-H and LFM2 families' expert
-layers; tracing/names.py's scopes and residuals.
+``blocks.checkpoint_kinds`` (ONE remat rule over the two kinds, the trunk's
+runs and the MTP module's), parts' RMSNorm, RoPE, residual add, weight cast
+inside the loop, causal attention, the MTP join, the rows an MLP and a head
+take at a time and the chunked head + loss; ops/moe.py's dispatch, shared
+with the Nemotron-H and LFM2 families' expert layers; tracing/names.py's
+scopes and residuals.
 
 The config states the chip's SHARE of a deployment beside the published
 sizes, as LFM2MoEConfig does: which routed experts and how many vocabulary
@@ -52,7 +79,7 @@ rows are held here, and which published layer the pattern starts at.
 Routing is over all ``n_experts`` at the published top-k; what absent
 experts would have added is left out: the shares' routed parts and
 everything a chip computes whole, counted once, add up to the uncut layer
-(tests/test_deepseek_v2.py).
+(tests/test_deepseek_v2.py, tests/test_xing4.py).
 """
 
 from __future__ import annotations
@@ -68,17 +95,14 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.models import blocks, parts
+from ray_tpu.models import blocks, hyper_connections as hyper, parts
 from ray_tpu.ops import moe
 from ray_tpu.tracing import get_buffer, names as scopes
 
 KINDS = "DE"
 EXPERTS = {"D": False, "E": True}
-# a softmax over all the experts whose chosen probabilities gate as they are
-RULE = moe.Rule(scoring="softmax", normalise=False)
-# what loss_fn hands out of a step a layer: the dispatch's counters and the
-# balance loss's value (float32 bits in the int32 array)
-STEP_FIELDS = scopes.STEP_EXPERT_LOAD_ARGS + (scopes.STEP_BALANCE_LOSS,)
+# the prefixes of a hyper-connected layer's two sublayers' maps
+HC_ATTN, HC_FFN = "hc_attn_", "hc_ffn_"
 
 
 @dataclass(frozen=True)
@@ -90,6 +114,9 @@ class DeepseekV2Config:
     first_k_dense: int = 1            # published layers below this are dense
     d_model: int = 2048
     n_head: int = 16
+    # query compression: q = RMSNorm(u·W_qa)·W_qb through this rank; None
+    # (the Lite model's null): q = u·W_q
+    q_lora_rank: Optional[int] = None
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -112,7 +139,26 @@ class DeepseekV2Config:
     d_expert: int = 1408
     n_shared: int = 2                 # one shared SwiGLU of n_shared · d_expert
     routed_scaling: float = 1.0
+    # the router's rule: how logits become scores, whether the chosen scores
+    # are divided by their sum, whether a selection bias (a buffer) chooses
+    # beside the scores; and the balance loss's coefficient (0: no such loss)
+    scoring: str = "softmax"
+    norm_topk_prob: bool = False
+    selection_bias: bool = False
     aux_loss_alpha: float = 0.001
+    # the residual path: 1 is the plain ``x + F(norm(x))``; n > 1 a
+    # manifold-constrained hyper-connection of n streams around every
+    # sublayer (models/hyper_connections.py)
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # a multi-token-prediction module of this many expert layers after the
+    # trunk (0: none), its loss's weight, and the published depth (the
+    # module's layers are published layers n_layer_published + k)
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.1
+    n_layer_published: Optional[int] = None
     init_std: float = 0.02            # initializer_range, every matrix
     rms_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
@@ -133,6 +179,11 @@ class DeepseekV2Config:
             raise ValueError("top_k must be in [1, n_experts]")
         if self.qk_rope_dim % 2:
             raise ValueError("qk_rope_dim must be even")
+        if self.scoring not in moe.SCORING:
+            raise ValueError(f"scoring must be one of {sorted(moe.SCORING)}")
+        if self.hc_mult < 1 or self.mtp_layers < 0:
+            raise ValueError("hc_mult must be at least 1, mtp_layers at "
+                             "least 0")
         if self.vocab_size % 128:
             raise ValueError("vocab_size (the rows held here) must be a "
                              "multiple of 128")
@@ -146,6 +197,28 @@ class DeepseekV2Config:
     def pattern(self) -> str:
         return "".join("D" if self.first_layer + i < self.first_k_dense
                        else "E" for i in range(self.n_layer))
+
+    @property
+    def mtp_pattern(self) -> str:
+        return "E" * self.mtp_layers
+
+    @property
+    def rule(self) -> moe.Rule:
+        return moe.Rule(scoring=self.scoring, normalise=self.norm_topk_prob)
+
+    @property
+    def hc(self) -> Optional[hyper.HyperConnection]:
+        """The residual path's hyper-connection, or None for the plain one."""
+        if self.hc_mult == 1:
+            return None
+        return hyper.HyperConnection(self.hc_mult, self.hc_sinkhorn_iters,
+                                     self.hc_eps, self.hc_res_clamp,
+                                     self.rms_eps)
+
+    @property
+    def carry_width(self) -> int:
+        """Channels of the layers' carry: hc_mult streams of d_model."""
+        return self.hc_mult * self.d_model
 
     @property
     def qk_dim(self) -> int:
@@ -163,6 +236,22 @@ class DeepseekV2Config:
         return m * m / math.sqrt(self.qk_dim)
 
 
+def xing4_tiny(**overrides) -> DeepseekV2Config:
+    """Test-size config of the Xing4.0 kind: the second of two leading dense
+    layers and expert layers, query compression, four streams, a biased
+    sigmoid router whose chosen scores are normalised and scaled, one shared
+    expert, no balance loss, an MTP module."""
+    return replace(DeepseekV2Config(
+        vocab_size=256, seq_len=64, n_layer=3, first_layer=1, first_k_dense=2,
+        d_model=128, n_head=4, q_lora_rank=24, kv_lora_rank=32,
+        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope_factor=64.0,
+        rope_original_len=16, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        d_ff=160, n_experts=16, top_k=4, held_first=4, held_count=8,
+        d_expert=48, n_shared=1, routed_scaling=2.0, scoring="sigmoid",
+        norm_topk_prob=True, selection_bias=True, aux_loss_alpha=0.0,
+        hc_mult=4, mtp_layers=1, n_layer_published=6), **overrides)
+
+
 def deepseek_v2_tiny(**overrides) -> DeepseekV2Config:
     """Test-size config: the leading dense layer and a run of expert layers,
     q·k and v of unequal widths."""
@@ -178,14 +267,26 @@ def deepseek_v2_tiny(**overrides) -> DeepseekV2Config:
 # --------------------------------------------------------------------------- #
 
 _ATTN_WEIGHTS = ("wq", "wkv_a", "wkv_b", "wo")
+_COMPRESSED_Q = ("wq_a", "wq_b")         # in wq's place under q_lora_rank
 _DENSE_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-def _matmul_weights(kind: str) -> Tuple[str, ...]:
-    """What a layer of ``kind`` takes in the compute dtype (the router and
-    the norms' gains stay as they are stored)."""
-    return _ATTN_WEIGHTS + (moe.GATED_EXPERT + moe.GATED_SHARED_EXPERT
-                            if EXPERTS[kind] else _DENSE_WEIGHTS)
+def _matmul_weights(kind: str, cfg: DeepseekV2Config) -> Tuple[str, ...]:
+    """What a layer of ``kind`` takes in the compute dtype (the router, the
+    norms' gains and the hyper-connection's α and biases stay as they are
+    stored)."""
+    attn = _ATTN_WEIGHTS if cfg.q_lora_rank is None else (
+        _COMPRESSED_Q + _ATTN_WEIGHTS[1:])
+    maps = (HC_ATTN + hyper.PHI, HC_FFN + hyper.PHI) if cfg.hc else ()
+    return attn + maps + (moe.GATED_EXPERT + moe.GATED_SHARED_EXPERT
+                          if EXPERTS[kind] else _DENSE_WEIGHTS)
+
+
+def _runs(cfg: DeepseekV2Config) -> List[Tuple[str, int]]:
+    """Every run of layers a step applies, in the forward's order: the
+    trunk's, then the MTP module's."""
+    return (blocks.pattern_groups(cfg.pattern)
+            + blocks.pattern_groups(cfg.mtp_pattern))
 
 
 def _group_counts(pattern: str):
@@ -213,10 +314,24 @@ def _layer_init(rng, n: int, kind: str, cfg: DeepseekV2Config):
          "wkv_b": normal((n, cfg.kv_lora_rank, H,
                           cfg.qk_nope_dim + cfg.v_head_dim)),
          "wo": normal((n, H, cfg.v_head_dim, D))}
+    # (keys of their own: the tensors above are drawn as they always were)
+    k_q, k_norm, k_hc_a, k_hc_f = jax.random.split(jax.random.fold_in(rng, 1), 4)
+    if cfg.q_lora_rank is not None:
+        r = cfg.q_lora_rank
+        del p["wq"]
+        p.update(
+            wq_a=(jax.random.normal(k_q, (n, D, r)) * std).astype(pd),
+            q_norm=jnp.ones((n, r), pd),
+            wq_b=(jax.random.normal(k_norm, (n, r, H, cfg.qk_dim)) * std
+                  ).astype(pd))
+    if cfg.hc:
+        p.update(hyper.init(k_hc_a, n, cfg.hc, D, std, pd, HC_ATTN))
+        p.update(hyper.init(k_hc_f, n, cfg.hc, D, std, pd, HC_FFN))
     if EXPERTS[kind]:
         p.update(moe.gated_moe_init(
             k_ff, n, D, cfg.n_experts, cfg.held_count, cfg.d_expert, std, std,
-            pd, selection_bias=False, d_shared=cfg.n_shared * cfg.d_expert))
+            pd, selection_bias=cfg.selection_bias,
+            d_shared=cfg.n_shared * cfg.d_expert))
     else:
         p.update(w_gate=normal((n, D, cfg.d_ff)), w_up=normal((n, D, cfg.d_ff)),
                  w_down=normal((n, cfg.d_ff, D)))
@@ -238,6 +353,9 @@ def _stack_init(rng, pattern: str, cfg: DeepseekV2Config):
 _LAYER_AXES = {
     "attn_norm": ("layers", "embed"), "ffn_norm": ("layers", "embed"),
     "wq": ("layers", "embed", "heads", "kv"),
+    "wq_a": ("layers", "embed", None), "q_norm": ("layers", None),
+    "wq_b": ("layers", None, "heads", "kv"),
+    **hyper.logical_axes(HC_ATTN), **hyper.logical_axes(HC_FFN),
     "wkv_a": ("layers", "embed", None), "kv_norm": ("layers", None),
     "wkv_b": ("layers", None, "heads", "kv"),
     "wo": ("layers", "heads", "kv", "embed"),
@@ -247,14 +365,23 @@ _LAYER_AXES = {
 }
 
 
-def logical_axes(cfg: DeepseekV2Config) -> Dict[str, Any]:
+def _stack_axes(pattern: str, cfg: DeepseekV2Config):
     layers = jax.eval_shape(
-        lambda: _stack_init(jax.random.PRNGKey(0), cfg.pattern, cfg))
-    return {"wte": ("vocab", "embed"),
-            "blocks": [{kind: {name: _LAYER_AXES[name] for name in stack}
-                        for kind, stack in group.items()} for group in layers],
-            "final_norm": ("embed",),
-            "lm_head": ("embed", "vocab")}
+        lambda: _stack_init(jax.random.PRNGKey(0), pattern, cfg))
+    return [{kind: {name: _LAYER_AXES[name] for name in stack}
+             for kind, stack in group.items()} for group in layers]
+
+
+def logical_axes(cfg: DeepseekV2Config) -> Dict[str, Any]:
+    out = {"wte": ("vocab", "embed"),
+           "blocks": _stack_axes(cfg.pattern, cfg),
+           "final_norm": ("embed",),
+           "lm_head": ("embed", "vocab")}
+    if cfg.mtp_layers:
+        out["mtp"] = {"blocks": _stack_axes(cfg.mtp_pattern, cfg),
+                      "enorm": ("embed",), "hnorm": ("embed",),
+                      "eh_proj": (None, "embed"), "final_norm": ("embed",)}
+    return out
 
 
 def mesh_rules(cfg: DeepseekV2Config, mesh) -> Dict[str, str]:
@@ -276,22 +403,44 @@ def mesh_rules(cfg: DeepseekV2Config, mesh) -> Dict[str, str]:
 
 def init(cfg: DeepseekV2Config, rng: jax.Array) -> Dict[str, Any]:
     k = jax.random.split(rng, 3)
+    D, pd = cfg.d_model, cfg.param_dtype
 
     def normal(key, shape):
-        return (jax.random.normal(key, shape) * cfg.init_std
-                ).astype(cfg.param_dtype)
+        return (jax.random.normal(key, shape) * cfg.init_std).astype(pd)
 
-    return {"wte": normal(k[0], (cfg.vocab_size, cfg.d_model)),
-            "blocks": _stack_init(k[1], cfg.pattern, cfg),
-            "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
-            "lm_head": normal(k[2], (cfg.d_model, cfg.vocab_size))}
+    out = {"wte": normal(k[0], (cfg.vocab_size, D)),
+           "blocks": _stack_init(k[1], cfg.pattern, cfg),
+           "final_norm": jnp.ones((D,), pd),
+           "lm_head": normal(k[2], (D, cfg.vocab_size))}
+    if cfg.mtp_layers:
+        # (keys of their own: the tensors above are drawn as they always were)
+        k_stack, k_proj = jax.random.split(jax.random.fold_in(rng, 1))
+        out["mtp"] = {"blocks": _stack_init(k_stack, cfg.mtp_pattern, cfg),
+                      "enorm": jnp.ones((D,), pd), "hnorm": jnp.ones((D,), pd),
+                      "eh_proj": normal(k_proj, (2 * D, D)),
+                      "final_norm": jnp.ones((D,), pd)}
+    return out
+
+
+def _is_buffer(path) -> bool:
+    return getattr(path[-1], "key", None) == "router_bias"
 
 
 def param_count(cfg: DeepseekV2Config) -> int:
-    """Every leaf is a parameter a step moves (no buffer: this router has no
-    selection bias)."""
+    """The parameters a step moves: every leaf but the expert layers'
+    selection biases, which are buffers (a router without one has none)."""
     shapes = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
-    return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(shapes))
+    return sum(int(np.prod(p.shape)) for path, p in
+               jax.tree_util.tree_leaves_with_path(shapes)
+               if not _is_buffer(path))
+
+
+def decays(params):
+    """Which leaves an optimizer's weight decay may touch (optax's ``mask``):
+    all but the selection biases — no gradient reaches them, and a decay must
+    not."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: not _is_buffer(path), params)
 
 
 # --------------------------------------------------------------------------- #
@@ -324,8 +473,14 @@ def mla_operator(u, p, cfg: DeepseekV2Config):
     with jax.named_scope(scopes.MLA_LATENT):
         # the two halves of each joint projection are slices of the WEIGHT:
         # no activation is cut in two
+        if cfg.q_lora_rank is None:
+            q_in, wq, q_spec = u, p["wq"], f"bsd,dhk->{heads}"
+        else:       # the compressed query: a latent, its norm, then the heads
+            q_in, wq, q_spec = parts.rmsnorm(
+                jnp.einsum("bsd,dr->bsr", u, p["wq_a"]), p["q_norm"],
+                cfg.rms_eps), p["wq_b"], f"bsr,rhk->{heads}"
         q = checkpoint_name(
-            rotate(jnp.einsum(f"bsd,dhk->{heads}", u, p["wq"]),
+            rotate(jnp.einsum(q_spec, q_in, wq),
                    span=(nope, nope + rope_dim)), scopes.RES_Q)
         c = checkpoint_name(parts.rmsnorm(
             jnp.einsum("bsd,dc->bsc", u, p["wkv_a"][:, :rank]),
@@ -351,8 +506,10 @@ def mla_operator(u, p, cfg: DeepseekV2Config):
                           preferred_element_type=jnp.float32)
 
 
-def _swiglu(x, p, cfg: DeepseekV2Config):
-    """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]."""
+def _swiglu(x, p, cfg: DeepseekV2Config, add: bool = True):
+    """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]; without
+    ``add`` the float32 product alone (a hyper-connected layer writes it back
+    itself)."""
     with jax.named_scope(scopes.LN2):
         h = parts.rmsnorm(x, p["ffn_norm"], cfg.rms_eps)
     with jax.named_scope(scopes.MLP):
@@ -360,12 +517,12 @@ def _swiglu(x, p, cfg: DeepseekV2Config):
                                scopes.RES_MLP_GATE)
         up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
                              scopes.RES_MLP_UP)
-        return parts.residual_add(x, jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
-            preferred_element_type=jnp.float32))
+        y = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
+                       preferred_element_type=jnp.float32)
+        return parts.residual_add(x, y) if add else y
 
 
-def _dense(x, p, cfg: DeepseekV2Config):
+def _dense(x, p, cfg: DeepseekV2Config, add: bool = True):
     """The dense feed-forward half, norm and all; where one hidden tensor of
     the whole sequence would pass parts.MLP_CHUNK_BYTES the sequence goes in
     chunks (parts.mlp_rows), each its own ``checkpoint`` — as the llama
@@ -373,43 +530,77 @@ def _dense(x, p, cfg: DeepseekV2Config):
     B, S, D = x.shape
     rows = parts.mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
     if rows == S:
-        return _swiglu(x, p, cfg)
+        return _swiglu(x, p, cfg, add)
     chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
-    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg)), chunks)
+    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg, add=add)),
+                  chunks)
     return out.swapaxes(0, 1).reshape(B, S, D)
 
 
 def _routing(cfg: DeepseekV2Config) -> Dict[str, Any]:
+    # (the published code's 1e-20 under the chosen scores' sum, where the
+    # rule divides by it)
     return dict(top_k=cfg.top_k, held=cfg.held, scaling=cfg.routed_scaling,
-                rule=RULE)
+                rule=cfg.rule, **({"eps": 1e-20} if cfg.norm_topk_prob else {}))
 
 
-def _experts(x, p, cfg: DeepseekV2Config, aux: Optional[str], rate=None):
-    """The expert feed-forward half → (x, what ``aux`` asks of it)."""
+def _experts(x, p, cfg: DeepseekV2Config, aux: Optional[str], rate=None,
+             add: bool = True):
+    """The expert feed-forward half → (x — without ``add`` the half's float32
+    output alone —, what ``aux`` asks of it)."""
     B, S, D = x.shape
     with jax.named_scope(scopes.LN2):
         h = parts.rmsnorm(x, p["ffn_norm"], cfg.rms_eps)
     ht, out = h.reshape(-1, D), None
-    if aux == "balance":
+    if aux == "balance" and cfg.selection_bias:
+        bias = moe.balance_bias_round(ht, p["router_w"], p["router_bias"],
+                                      cfg.top_k, rate)
+        p = {**p, "router_bias": bias}
+        out = {"router_bias": bias, **moe.held_load(ht, p, **_routing(cfg))}
+    elif aux == "balance":
         router_w = moe.balance_router(ht, p["router_w"], cfg.top_k, S, rate,
-                                      RULE)
+                                      cfg.rule)
         p = {**p, "router_w": router_w}
         out = {"router_w": router_w, **moe.held_load(ht, p, **_routing(cfg))}
     elif aux == "chosen":
-        out = moe.chosen_experts(ht, p, cfg.top_k, RULE)
+        out = moe.chosen_experts(ht, p, cfg.top_k, cfg.rule)
     with jax.named_scope(scopes.MOE):
         f, load = moe.gated_moe(
-            h, p, **_routing(cfg), balance=aux == "load",
+            h, p, **_routing(cfg),
+            balance=aux == "load" and cfg.aux_loss_alpha > 0,
             shared_rows=parts.mlp_rows(B, S, D, cfg.n_shared * cfg.d_expert,
                                        x.dtype.itemsize))
-    return parts.residual_add(x, f), load if aux == "load" else out
+    return (parts.residual_add(x, f) if add else f,
+            load if aux == "load" else out)
+
+
+def _mixed(x, p, prefix: str, cfg: DeepseekV2Config):
+    """What a sublayer reads of the carry x, and the maps it will write back
+    by: x itself and None on the plain residual path; under a
+    hyper-connection the pre-mix of the streams and the token's maps."""
+    if cfg.hc is None:
+        return x, None
+    with jax.named_scope(scopes.MHC):
+        h = hyper.maps(x, p, prefix, cfg.hc)
+        return hyper.pre_mix(x, h), h
+
+
+def _joined(x, y, h):
+    """The carry after a sublayer's float32 output y: ``x + y``, or the
+    hyper-connection's write-back by the maps ``h``."""
+    if h is None:
+        return parts.residual_add(x, y)
+    with jax.named_scope(scopes.MHC):
+        return hyper.write_back(x, y, h)
 
 
 @jax.named_scope(scopes.BLOCK)
 def _layer(x, p, cfg: DeepseekV2Config, kind: str, aux: Optional[str] = None,
            rate=None):
-    """One layer of ``kind``, x [B, S, D]: latent attention's residual, then
-    the feed-forward half's. With ``aux`` the result is (x, aux's value),
+    """One layer of ``kind``, x [B, S, carry_width]: latent attention's
+    residual, then the feed-forward half's — each ``x + F(norm(x))``, or under
+    a hyper-connection F(norm(the streams' pre-mix)) written back to every
+    stream (_mixed, _joined). With ``aux`` the result is (x, aux's value),
     None for a dense layer: ``"load"`` — the training forward's: what the
     batch sends the held experts, as the dispatch that runs the passes has
     it, and the layer's balance loss (moe.routed_experts) —; in a forward of
@@ -418,15 +609,22 @@ def _layer(x, p, cfg: DeepseekV2Config, kind: str, aux: Optional[str] = None,
     (moe.balance_router); the router and what the input then sends the held
     experts (moe.held_load) —, ``"chosen"`` — the set each token chose,
     [T, n_experts] bool."""
-    p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype, _matmul_weights(kind))}
+    p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype,
+                                       _matmul_weights(kind, cfg))}
+    plain = cfg.hc is None
+    mixed, h = _mixed(x, p, HC_ATTN, cfg)
     with jax.named_scope(scopes.LN1):
-        u = parts.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
-    x = checkpoint_name(parts.residual_add(x, mla_operator(u, p, cfg)),
+        u = parts.rmsnorm(mixed, p["attn_norm"], cfg.rms_eps)
+    x = checkpoint_name(_joined(x, mla_operator(u, p, cfg), h),
                         scopes.RES_MID)
+    # (the plain path's halves add their own residual, where they always did:
+    # a chunked half inside each chunk)
+    mixed, h = _mixed(x, p, HC_FFN, cfg)
     if EXPERTS[kind]:
-        x, out = _experts(x, p, cfg, aux, rate)
+        y, out = _experts(mixed, p, cfg, aux, rate, add=plain)
     else:
-        x, out = _dense(x, p, cfg), None
+        y, out = _dense(mixed, p, cfg, add=plain), None
+    x = y if plain else _joined(x, y, h)
     return (x, out) if aux else x
 
 
@@ -460,9 +658,23 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
     (0.83) stand beside either; attention's own backward holds 2.12. Summed,
     the three stood at 6.18 GB, 1.9 GB over what the compiled step takes, and
     the rule kept nothing beside a chip with 2 GiB free (PERF.md §6, PR 56).
+
+    Under a hyper-connection (``hc_mult`` n > 1) the carry is n · d_model
+    wide and the shard says so (``carry_width``: the stack of block inputs
+    and the carried cotangent are priced at it). A sublayer still reads and
+    writes ``d_model`` — the pre-mix goes in, the float32 y comes out — so
+    each moment holds what it held, with the n-stream tensors that wait in
+    it added: through the feed-forward half's backward the block's input and
+    the carry after attention's write-back, that carry's float32 cotangent
+    being summed (the write-back's ``H_resᵀ`` part and the pre-mix's), and a
+    sublayer's maps (the Φ product's 24 float32 planes and two copies a
+    Sinkhorn round of its n² planes, 3 KB a token beside the carry's 28);
+    attention's own backward holds the block's input and its float32
+    cotangent in the carry after attention's place.
     """
     a = jnp.dtype(cfg.dtype).itemsize
     D, F, Fe, H = cfg.d_model, cfg.d_ff, cfg.d_expert, cfg.n_head
+    W = cfg.carry_width
     Fs = cfg.n_shared * cfg.d_expert
     hd, hv, rank = cfg.qk_dim, cfg.v_head_dim, cfg.kv_lora_rank
     flash = parts.is_flash(cfg.attention_impl, mesh)
@@ -472,9 +684,18 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
         mlp_hidden=(scopes.RES_MLP_GATE, scopes.RES_MLP_UP),
         head_rows=parts.head_rows(global_batch, seq, cfg.vocab_size, 1),
         mlp_rows=parts.mlp_rows(global_batch, seq, D, F, a),
-        cast_in_loop=True), mesh)
+        cast_in_loop=True, carry_width=0 if cfg.hc is None else W), mesh)
     tokens = base.batch * base.seq
     C = blocks.RematCandidate
+    if cfg.hc is None:
+        waiting_streams = own_streams = 0
+    else:
+        n = cfg.hc_mult
+        maps = tokens * 4 * (cfg.hc.outputs + 2 * cfg.hc.rounds * n * n)
+        # beside the d_model-wide tensors the plain arithmetic counts: the
+        # carry after attention and its float32 cotangent, the maps
+        waiting_streams = tokens * (W - D) * a + tokens * W * (a + 4) + maps
+        own_streams = tokens * (W - D) * a + tokens * W * 4 + maps
 
     # latent attention. The latent c and the one k_pe are 576 numbers a
     # token that stand for H · (hd + hv) of k and v: kept, k and v are one
@@ -482,21 +703,23 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
     # kernel's o and lse its two products over the causal half at their own
     # widths; the stream after the residual the out-projection
     latent = rank + cfg.qk_rope_dim
+    q_params = (D * H * hd if cfg.q_lora_rank is None
+                else cfg.q_lora_rank * (D + H * hd))
     attn_kept = [
         C((scopes.RES_MLA_C, scopes.RES_MLA_KPE), tokens * latent * a,
           2 * tokens * D * latent),
-        C((scopes.RES_Q,), tokens * H * hd * a, 2 * tokens * D * H * hd),
+        C((scopes.RES_Q,), tokens * H * hd * a, 2 * tokens * q_params),
         C((scopes.RES_K,), tokens * H * hd * a,
           2 * tokens * rank * H * cfg.qk_nope_dim),
         C((scopes.RES_V,), tokens * H * hv * a, 2 * tokens * rank * H * hv),
-        C((scopes.RES_MID,), tokens * D * a, 2 * tokens * H * hv * D)]
+        C((scopes.RES_MID,), tokens * W * a, 2 * tokens * H * hv * D)]
     if flash:
         attn_kept.append(C(
             (scopes.RES_FLASH_O, scopes.RES_FLASH_LSE),
             tokens * H * (hv * a + 4),
             base.batch * H * base.seq * base.seq
             * (max(hd, parts.MXU) + max(hv, parts.MXU))))
-    attn_params = D * H * hd + D * latent + rank * H * (cfg.qk_nope_dim + hv) \
+    attn_params = q_params + D * latent + rank * H * (cfg.qk_nope_dim + hv) \
         + H * hv * D
     # its backward holds four tensors of the stream's width, q, k and their
     # gradients, v, o and theirs, and the weights cast twice; until then what
@@ -506,7 +729,7 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
                     + 2 * 2 * attn_params)
     attn_waits = (a * (tokens * (2 * D + 2 * H * hd + 2 * H * hv)
                        + attn_params) + (tokens * H * 4 if flash else 0))
-    carried = tokens * D * a    # the cotangent of the block's output
+    carried = tokens * W * a    # the cotangent of the block's output
 
     # the feed-forward halves, as the LFM2 family prices them: the dense
     # one's hidden tensors where it is not chunked; what the routing decided
@@ -539,12 +762,14 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
                    + max(routed_set, shared_set))
 
     kinds = {}
-    for kind in dict.fromkeys(cfg.pattern):
+    layers = cfg.pattern + cfg.mtp_pattern
+    for kind in dict.fromkeys(layers):
         ff_kept, ff_set = ((experts_kept, experts_set) if EXPERTS[kind]
                            else (dense_kept, dense_set))
         kinds[kind] = blocks.KindShard(
-            cfg.pattern.count(kind), tuple(attn_kept) + ff_kept,
-            carried + max(attn_waits + ff_set, attn_set))
+            layers.count(kind), tuple(attn_kept) + ff_kept,
+            carried + max(attn_waits + ff_set + waiting_streams,
+                          attn_set + own_streams))
     chips = mesh.devices.size if mesh is not None else 1
     return base, {k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
                   for k, v in _one_candidate_a_name(kinds).items()}
@@ -594,91 +819,176 @@ def _block_fns(cfg: DeepseekV2Config, batch: int, seq: int,
     from ray_tpu.parallel import mesh as mesh_lib
 
     base, kinds = kind_shards(cfg, batch, seq, mesh_lib.current_mesh())
-    blocks.record_layer_pattern(cfg.pattern)
+    for pattern in filter(None, (cfg.pattern, cfg.mtp_pattern)):
+        blocks.record_layer_pattern(pattern)
+    if cfg.hc:
+        hyper.record_decision(cfg.hc, cfg.d_model, cfg.dtype)
     return blocks.checkpoint_kinds(
         {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux) for kind in kinds},
-        cfg.remat, base, kinds, blocks.pattern_groups(cfg.pattern))
+        cfg.remat, base, kinds, _runs(cfg))
 
 
-def _trunk(params, tokens, cfg: DeepseekV2Config, aux: Optional[str] = None,
-           rate=None):
-    """tokens [B, S] int32 → the head's input [B, S, D] (and, with ``aux``,
-    blocks.run_pattern's: each layer's, _layer says what)."""
+def _hidden(params, tokens, targets, cfg: DeepseekV2Config,
+            aux: Optional[str] = None, rate=None):
+    """tokens [B, S] int32 → (the trunk's stream [B, S, D] before the final
+    norm — a hyper-connection's streams summed —, the MTP module's or None,
+    the MTP targets, and with ``aux`` blocks.run_pattern's auxes — each
+    layer's, _layer says what —: the trunk's runs, then the MTP module's).
+    ``targets`` (the next token) is read by an MTP module only."""
     B, S = tokens.shape
     with jax.named_scope(scopes.EMBED):
-        x = params["wte"].astype(cfg.dtype)[tokens]
+        wte = params["wte"].astype(cfg.dtype)
+        x = wte[tokens]
     if aux in (None, "load"):       # checkpointed: a backward may follow
         fns = _block_fns(cfg, B, S, aux)
     else:               # a forward of its own: no backward, no checkpoint
         fns = {kind: partial(_layer, cfg=cfg, kind=kind, aux=aux, rate=rate)
                for kind in KINDS}
-    out = blocks.run_pattern(fns, cfg.pattern, x, params["blocks"],
-                             with_aux=bool(aux))
-    x, auxes = out if aux else (out, None)
+
+    def run(pattern, x, stacks):
+        """x [B, S, D] through ``pattern``'s layers → [B, S, D]: under a
+        hyper-connection the streams start as copies of x and end summed."""
+        if cfg.hc:
+            with jax.named_scope(scopes.MHC):
+                x = hyper.expand(x, cfg.hc_mult)
+        out = blocks.run_pattern(fns, pattern, x, stacks, with_aux=bool(aux))
+        x, auxes = out if aux else (out, [])
+        if cfg.hc:
+            with jax.named_scope(scopes.MHC):
+                x = hyper.collapse(x, cfg.hc_mult)
+        return x, auxes
+
+    x, auxes = run(cfg.pattern, x, params["blocks"])
+    if not cfg.mtp_layers:
+        return x, None, None, auxes
+    mtp = params["mtp"]
+    with jax.named_scope(scopes.MTP):
+        h, mtp_targets = parts.mtp_join(x, targets, wte, mtp["enorm"],
+                                        mtp["hnorm"], mtp["eh_proj"],
+                                        cfg.rms_eps)
+        h, mtp_auxes = run(cfg.mtp_pattern, h, mtp["blocks"])
+    return x, h, mtp_targets, auxes + mtp_auxes
+
+
+def _final_norm(x, g, cfg: DeepseekV2Config):
     with jax.named_scope(scopes.LN_F):
-        x = parts.rmsnorm(x, params["final_norm"], cfg.rms_eps)
-    return (x, auxes) if aux else x
+        return parts.rmsnorm(x, g, cfg.rms_eps)
 
 
 def forward(params, tokens, cfg: DeepseekV2Config) -> jax.Array:
-    """tokens [B, S] int32 → logits [B, S, vocab_size]."""
-    x = _trunk(params, tokens, cfg)
-    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype))
+    """tokens [B, S] int32 → the trunk's logits [B, S, vocab_size]."""
+    x, *_ = _hidden(params, tokens, None, replace(cfg, mtp_layers=0))
+    return jnp.einsum("bsd,dv->bsv", _final_norm(x, params["final_norm"], cfg),
+                      params["lm_head"].astype(cfg.dtype))
+
+
+def _losses(params, tokens, targets, cfg: DeepseekV2Config,
+            aux: Optional[str] = None):
+    """(the trunk's mean cross-entropy, the MTP module's or None, _hidden's
+    auxes of ``aux``). The MTP module has a final norm of its own and shares
+    the embedding and the head."""
+    x, h, mtp_targets, auxes = _hidden(params, tokens, targets, cfg, aux)
+    trunk = parts.lm_head_loss(_final_norm(x, params["final_norm"], cfg),
+                               targets, params["lm_head"], cfg.dtype)
+    if h is None:
+        return trunk, None, auxes
+    with jax.named_scope(scopes.MTP):
+        return trunk, parts.lm_head_loss(
+            _final_norm(h, params["mtp"]["final_norm"], cfg), mtp_targets,
+            params["lm_head"], cfg.dtype), auxes
+
+
+def losses(params, tokens, targets, cfg: DeepseekV2Config):
+    """(the trunk's mean cross-entropy, the MTP module's or 0.0)."""
+    trunk, mtp, _ = _losses(params, tokens, targets, cfg)
+    return trunk, jnp.zeros((), jnp.float32) if mtp is None else mtp
+
+
+def step_fields(cfg: DeepseekV2Config) -> Tuple[str, ...]:
+    """What loss_fn hands out of a step a layer: the dispatch's counters and,
+    under a balance loss, its value (float32 bits in the int32 array)."""
+    return scopes.STEP_EXPERT_LOAD_ARGS + (
+        (scopes.STEP_BALANCE_LOSS,) if cfg.aux_loss_alpha > 0 else ())
 
 
 def loss_fn(params, tokens, targets, cfg: DeepseekV2Config,
             counters: bool = False):
-    """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token)
-    plus ``aux_loss_alpha`` × the sum over the expert layers of the balance
-    loss: the objective, whole, inside what the step differentiates. With
-    ``counters`` (what step_counters offers a step factory: the aux of its
-    ``value_and_grad``) the result is (the loss, what each expert layer said
-    of the batch: int32 [expert layers, STEP_FIELDS], in the layers'
-    order)."""
-    x, auxes = _trunk(params, tokens, cfg, "load")
-    loss = parts.lm_head_loss(x, targets, params["lm_head"], cfg.dtype)
-    if any(EXPERTS[k] for k in cfg.pattern):
+    """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token),
+    plus ``mtp_loss_weight`` × an MTP module's, plus ``aux_loss_alpha`` × the
+    sum over the expert layers of the balance loss: the objective, whole,
+    inside what the step differentiates. With ``counters`` (what
+    step_counters offers a step factory: the aux of its ``value_and_grad``)
+    the result is (the loss, what each expert layer said of the batch: int32
+    [expert layers, step_fields], the trunk's layers and then the MTP
+    module's)."""
+    loss, mtp, auxes = _losses(params, tokens, targets, cfg, "load")
+    if mtp is not None:
+        loss = loss + cfg.mtp_loss_weight * mtp
+    balanced = cfg.aux_loss_alpha > 0
+    if balanced and any(EXPERTS[k] for k in cfg.pattern + cfg.mtp_pattern):
         with jax.named_scope(scopes.MOE_AUX):
             loss = loss + cfg.aux_loss_alpha * jnp.sum(
                 blocks.aux_column(auxes, scopes.STEP_BALANCE_LOSS))
     if not counters:
         return loss
-    return loss, blocks.packed_aux(auxes, STEP_FIELDS,
-                                   (scopes.STEP_BALANCE_LOSS,))
+    return loss, blocks.packed_aux(
+        auxes, step_fields(cfg),
+        (scopes.STEP_BALANCE_LOSS,) if balanced else ())
+
+
+def _expert_layer_ids(cfg: DeepseekV2Config) -> Tuple[int, ...]:
+    """The published index of every expert layer, the trunk's and then the
+    MTP module's (published layers ``n_layer_published`` + k, DeepSeek-V3's
+    numbering; past the layers run here where the config does not say)."""
+    past = (cfg.n_layer_published if cfg.n_layer_published is not None
+            else cfg.first_layer + cfg.n_layer)
+    return (tuple(cfg.first_layer + i for i in _expert_layers(cfg.pattern))
+            + tuple(past + i for i in _expert_layers(cfg.mtp_pattern)))
 
 
 def step_counters(cfg: DeepseekV2Config) -> Optional[blocks.StepCounters]:
     """What ``loss_fn(..., counters=True)`` hands out of a step, or None for
     a pattern without an expert layer. A layer's id is ``model/expert_load``'s
     ``layer``: the published index."""
-    layers = tuple(cfg.first_layer + i for i in _expert_layers(cfg.pattern))
+    layers = _expert_layer_ids(cfg)
     if not layers:
         return None
     return blocks.StepCounters(
-        scopes.EXPERT_LOAD_KIND, STEP_FIELDS, layers,
+        scopes.EXPERT_LOAD_KIND, step_fields(cfg), layers,
         partial(moe.step_load_static, n_experts=cfg.n_experts,
                 top_k=cfg.top_k, held=cfg.held),
-        float_fields=(scopes.STEP_BALANCE_LOSS,))
+        float_fields=((scopes.STEP_BALANCE_LOSS,)
+                      if cfg.aux_loss_alpha > 0 else ()))
 
 
 def flops_per_token(cfg: DeepseekV2Config) -> float:
     """Forward + backward operations one trained token REQUIRES here: 6 per
-    matmul parameter the token meets (latent attention's four projections,
-    the router, the shared expert, the routed experts by the pairs a token
-    is expected to land on held ones, top_k · held / n_experts a layer; the
+    matmul parameter the token meets (latent attention's projections — the
+    query's two under compression —, the router, the shared expert, the
+    routed experts by the pairs a token is expected to land on held ones,
+    top_k · held / n_experts a layer, a hyper-connection's two Φ a layer; an
+    MTP module's layers, its join and its pass through the head; the
     embedding is a gather, the head a matmul) and by shape three times the
     forward's attention (q·k at qk_dim and p·v at v_head_dim over the causal
-    half)."""
+    half). A hyper-connection's mixes and rounds are elementwise: not
+    counted."""
     D, S, H = cfg.d_model, cfg.seq_len, cfg.n_head
-    attn = (D * H * cfg.qk_dim + D * (cfg.kv_lora_rank + cfg.qk_rope_dim)
+    q = (D * H * cfg.qk_dim if cfg.q_lora_rank is None
+         else cfg.q_lora_rank * (D + H * cfg.qk_dim))
+    attn = (q + D * (cfg.kv_lora_rank + cfg.qk_rope_dim)
             + cfg.kv_lora_rank * H * (cfg.qk_nope_dim + cfg.v_head_dim)
             + H * cfg.v_head_dim * D)
+    if cfg.hc:
+        attn += 2 * cfg.carry_width * cfg.hc.outputs
     ff = {True: D * cfg.n_experts + 3 * D * cfg.d_expert * (
               cfg.n_shared + cfg.top_k * cfg.held_count / cfg.n_experts),
           False: 3 * D * cfg.d_ff}
-    matmul = (sum(attn + ff[EXPERTS[k]] for k in cfg.pattern)
+    layers = cfg.pattern + cfg.mtp_pattern
+    matmul = (sum(attn + ff[EXPERTS[k]] for k in layers)
               + D * cfg.vocab_size)
-    shaped = cfg.n_layer * H * (cfg.qk_dim + cfg.v_head_dim) * (S + 1) / 2
+    if cfg.mtp_layers:
+        matmul += 2 * D * D + D * cfg.vocab_size
+    shaped = len(layers) * H * (cfg.qk_dim + cfg.v_head_dim) * (S + 1) / 2
     return 6.0 * (matmul + shaped)
 
 
@@ -691,36 +1001,120 @@ def _expert_layers(pattern: str) -> List[int]:
     return [i for i, kind in enumerate(pattern) if EXPERTS[kind]]
 
 
-def _expert_aux(pattern: str, auxes) -> list:
-    """blocks.run_pattern's auxes as one entry an expert layer, in order."""
+def _expert_aux(cfg: DeepseekV2Config, auxes) -> list:
+    """_hidden's auxes as one entry an expert layer, in order."""
     out = []
-    for (sub, reps), aux in zip(blocks.pattern_groups(pattern), auxes,
-                                strict=True):
+    for (sub, reps), aux in zip(_runs(cfg), auxes, strict=True):
         for r in range(reps):
             out += [jax.tree.map(lambda t: t[r], a) if reps > 1 else a
                     for a in aux if a is not None]
     return out
 
 
-def chosen_experts(params, tokens, cfg: DeepseekV2Config) -> List[jax.Array]:
+def chosen_experts(params, tokens, cfg: DeepseekV2Config, targets=None
+                   ) -> List[jax.Array]:
     """The set each token of ``tokens`` [B, S] chose in each expert layer, in
-    the layers' order: [B·S, n_experts] bool a layer. What a reference is
-    told, so that a near-tie rounding flipped is not read as a wrong model."""
-    return _expert_aux(cfg.pattern, _trunk(params, tokens, cfg, "chosen")[1])
+    the layers' order (the MTP module's last: it reads ``targets``): [B·S,
+    n_experts] bool a layer. What a reference is told, so that a near-tie
+    rounding flipped is not read as a wrong model."""
+    return _expert_aux(cfg, _hidden(params, tokens, targets, cfg,
+                                    "chosen")[3])
 
 
-def _expert_runs(pattern: str) -> List[int]:
-    """The run of blocks.pattern_groups each expert layer is in, in order (a
-    run's stack of ``E`` holds its layers in that order)."""
-    return [g for g, (sub, reps) in enumerate(blocks.pattern_groups(pattern))
-            for _ in range((sub * reps).count("E"))]
+def _with_expert_leaf(params, cfg: DeepseekV2Config, name: str, rows):
+    """``params`` with leaf ``name`` of every expert layer replaced by its
+    entry of ``rows`` (one a layer, in order: the trunk's runs, then the MTP
+    module's; a run's stack of ``E`` holds its layers in that order), in the
+    old leaf's dtype."""
+    rows = iter(rows)
+
+    def replaced(pattern, stacks):
+        out = []
+        for (sub, reps), group in zip(blocks.pattern_groups(pattern), stacks,
+                                      strict=True):
+            layers = (sub * reps).count("E")
+            if layers:
+                old = group["E"][name]
+                group = {**group, "E": {**group["E"], name: jnp.stack(
+                    [next(rows) for _ in range(layers)]).astype(old.dtype)}}
+            out.append(group)
+        return out
+
+    out = {**params, "blocks": replaced(cfg.pattern, params["blocks"])}
+    if cfg.mtp_layers:
+        out["mtp"] = {**params["mtp"], "blocks": replaced(
+            cfg.mtp_pattern, params["mtp"]["blocks"])}
+    return out
+
+
+def _record_loads(cfg: DeepseekV2Config, loads) -> List[Dict[str, Any]]:
+    """The ``model/expert_load`` events (tracing/names.EXPERT_LOAD_ARGS;
+    ``layer`` is the published index) of ``loads``, one an expert layer in
+    order, recorded; and returned."""
+    component, name = scopes.EXPERT_LOAD.split("/")
+    events = []
+    for layer, load in zip(_expert_layer_ids(cfg), jax.device_get(loads),
+                           strict=True):
+        # (numpy scalars off the host: a count an int, a mean or share a float)
+        args = {"layer": layer, **{
+            k: load[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
+        get_buffer().record_profile(name, component=component, args=args)
+        events.append(args)
+    return events
+
+
+def _balanced(params, batches, cfg: DeepseekV2Config, leaf: str, rates):
+    """(``params`` after ``len(rates)`` forwards of their own, round r on
+    batch r mod N of ``batches`` — dicts of ``tokens`` and ``targets``
+    [B, S], or one —, in which every expert layer's ``leaf`` takes one round
+    of its balancing rule (_experts' ``"balance"``) at ``rates[r]`` on what
+    the layers before it — as balanced so far — hand it, every other tensor
+    held; what the last round's batch then sends the experts held here: the
+    ``model/expert_load`` events, recorded here)."""
+    batches = [batches] if isinstance(batches, dict) else list(batches)
+
+    @jax.jit
+    def one_round(p, batch, rate):
+        auxes = _expert_aux(cfg, _hidden(p, batch["tokens"], batch["targets"],
+                                         cfg, "balance", rate)[3])
+        return [aux.pop(leaf) for aux in auxes], auxes
+
+    balanced = params
+    for r, rate in enumerate(rates):
+        rows, loads = one_round(balanced, batches[r % len(batches)], rate)
+        balanced = _with_expert_leaf(balanced, cfg, leaf, rows)
+    return balanced, _record_loads(cfg, loads)
+
+
+def _falling(first_rate: float) -> List[float]:
+    """moe.BALANCE_ROUNDS rates falling linearly from ``first_rate`` to 0."""
+    rounds = moe.BALANCE_ROUNDS
+    return [first_rate * (1.0 - r / rounds) for r in range(rounds)]
+
+
+def balance_router_bias(params, batches, cfg: DeepseekV2Config):
+    """(``params`` with every expert layer's selection bias balanced on
+    ``batches`` — N dicts of ``tokens`` and ``targets`` [B, S], or one —,
+    what the last round's batch then sends the experts held here). For a
+    config whose router has a selection bias: the bias's between-step update
+    is not part of the step, so a run starts from a bias that something
+    balanced: moe.BALANCE_ROUNDS rounds of the auxiliary-loss-free rule
+    (moe.balance_bias_round), round r on batch r mod N, the rate falling from
+    moe.BALANCE_RATE to 0, layer by layer (a layer's input is what the layers
+    before it, as balanced so far, give), the MTP module's layers after the
+    trunk's, the weights held. Give it as many batches as rounds: rounds on
+    one batch fit that batch's near-ties (moe.balance_bias_round says what
+    that cost). For set-up, as nemotron_h.balance_router_bias and
+    lfm2_moe.balance_router_bias are: no training path calls it."""
+    return _balanced(params, batches, cfg, "router_bias",
+                     _falling(moe.BALANCE_RATE))
 
 
 def balance_routers(params, batches, cfg: DeepseekV2Config):
     """(``params`` with every expert layer's router balanced on ``batches``
     — N token arrays [B, S], or one —, what the last round's batch then
-    sends the experts held here). This family's routers have no selection bias:
-    what balances them is the balance loss, over a run's many steps, so a
+    sends the experts held here). For a config whose routers have no selection
+    bias: what balances them is the balance loss, over a run's many steps, so a
     run on freshly drawn weights starts from routers that something
     balanced: moe.BALANCE_ROUNDS forwards of their own, round r on batch
     r mod N, in which every expert layer's router takes one round of
@@ -733,38 +1127,10 @@ def balance_routers(params, batches, cfg: DeepseekV2Config):
     ``_layer``'s ``"balance"``) — a benchmark's build and chip_smoke.py do,
     once before the first step; a run that starts from a checkpoint, or
     trains for long, needs neither."""
+    if cfg.mtp_layers or cfg.selection_bias:
+        raise ValueError("balance_routers is for routers balanced by a loss "
+                         "(no selection bias, no MTP module's targets): "
+                         "balance_router_bias balances a bias")
     batches = [batches] if hasattr(batches, "ndim") else batches
-    runs = _expert_runs(cfg.pattern)
-
-    def with_routers(routers):
-        stacks = [dict(group) for group in params["blocks"]]
-        for g, w in routers.items():
-            old = stacks[g]["E"]["router_w"]
-            stacks[g]["E"] = {**stacks[g]["E"], "router_w": w.astype(old.dtype)}
-        return {**params, "blocks": stacks}
-
-    @jax.jit
-    def one_round(p, tokens, rate):
-        auxes = _expert_aux(cfg.pattern,
-                            _trunk(p, tokens, cfg, "balance", rate)[1])
-        routers: Dict[int, list] = {}
-        for g, aux in zip(runs, auxes, strict=True):
-            routers.setdefault(g, []).append(aux.pop("router_w"))
-        return {g: jnp.stack(rows) for g, rows in routers.items()}, auxes
-
-    balanced, rounds = params, moe.BALANCE_ROUNDS
-    for r in range(rounds):
-        routers, loads = one_round(
-            balanced, batches[r % len(batches)],
-            moe.BALANCE_ROUTER_RATE * (1.0 - r / rounds))
-        balanced = with_routers(routers)
-    component, name = scopes.EXPERT_LOAD.split("/")
-    events = []
-    for index, load in zip(_expert_layers(cfg.pattern),
-                           jax.device_get(loads), strict=True):
-        # (numpy scalars off the host: a count an int, a mean or share a float)
-        args = {"layer": cfg.first_layer + index, **{
-            k: load[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
-        get_buffer().record_profile(name, component=component, args=args)
-        events.append(args)
-    return balanced, events
+    return _balanced(params, [{"tokens": b, "targets": None} for b in batches],
+                     cfg, "router_w", _falling(moe.BALANCE_ROUTER_RATE))

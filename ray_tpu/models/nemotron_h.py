@@ -492,20 +492,10 @@ def _hidden(params, tokens, targets, cfg: NemotronHConfig,
 
 
 def _mtp_input(params, x, targets, wte, cfg: NemotronHConfig):
-    """The MTP module's input and targets: position t joins the trunk's x_t
-    (before the final norm) with the embedding of token t+1 (= targets[t])
-    and predicts token t+2 (= targets[t+1]); no target where either is past
-    the row's end."""
+    """The MTP module's input and targets (parts.mtp_join)."""
     mtp = params["mtp"]
-    has_next = targets >= 0
-    later = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)), constant_values=-1)
-    with jax.named_scope(scopes.EMBED):
-        e = wte[jnp.where(has_next, targets, 0)]
-    both = jnp.concatenate([parts.rmsnorm(e, mtp["enorm"], cfg.rms_eps),
-                            parts.rmsnorm(x, mtp["hnorm"], cfg.rms_eps)],
-                           axis=-1)
-    h = jnp.einsum("bse,ed->bsd", both, mtp["eh_proj"].astype(cfg.dtype))
-    return h, jnp.where(has_next, later, -1)
+    return parts.mtp_join(x, targets, wte, mtp["enorm"], mtp["hnorm"],
+                          mtp["eh_proj"], cfg.rms_eps)
 
 
 def _final_norm(x, params, cfg):

@@ -318,6 +318,23 @@ def lm_head_loss(x, targets, lm_head, dtype, n_pred_heads: int = 1):
         x, head_targets(targets, n_pred_heads), lm_head, rows)
 
 
+def mtp_join(x, targets, wte, enorm, hnorm, eh_proj, eps: float):
+    """A multi-token-prediction module's input and targets (DeepSeek-V3,
+    arXiv:2412.19437 §2.2): position t joins the trunk's x_t [B, S, D]
+    (before the final norm) with the embedding of token t+1 (= targets[t];
+    ``wte`` in the compute dtype) — each under a norm of its own, the
+    embedding's half first, through ``eh_proj`` [2·D, D] — and predicts token
+    t+2 (= targets[t+1]); no target where either is past the row's end."""
+    has_next = targets >= 0
+    later = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)), constant_values=-1)
+    with jax.named_scope(scopes.EMBED):
+        e = wte[jnp.where(has_next, targets, 0)]
+    both = jnp.concatenate([rmsnorm(e, enorm, eps), rmsnorm(x, hnorm, eps)],
+                           axis=-1)
+    h = jnp.einsum("bse,ed->bsd", both, eh_proj.astype(x.dtype))
+    return h, jnp.where(has_next, later, -1)
+
+
 class BlockShard(NamedTuple):
     """One chip's share of a step, in elements: global shapes ÷ the mesh axes
     that split them. Everything the remat rule computes, it computes from
@@ -347,6 +364,9 @@ class BlockShard(NamedTuple):
     # the block casts its layer's matmul weights inside the layer loop
     # (cast_in_the_loop): one layer's stand in the block's backward
     cast_in_loop: bool = False
+    # channels of the layers' carry where it is wider than d_model (a
+    # hyper-connected model's n streams: models/hyper_connections.py); 0: d_model
+    carry_width: int = 0
 
 
 def shard_block(whole: BlockShard, mesh) -> BlockShard:

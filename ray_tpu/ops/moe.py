@@ -434,6 +434,25 @@ def balance_bias(u: jax.Array, router_w: jax.Array, bias: jax.Array,
     return lax.fori_loop(0, BALANCE_ROUNDS, body, bias.astype(jnp.float32))
 
 
+def balance_bias_round(u: jax.Array, router_w: jax.Array, bias: jax.Array,
+                       top_k: int, rate) -> jax.Array:
+    """ONE round of balance_bias's rule on one batch at ``rate``: ``b_e ← b_e
+    + rate · sign(mean load − load_e)``. For a caller that gives every round
+    a batch of its own (models/deepseek_v2.balance_router_bias): rounds on
+    ONE batch fit that batch — where a layer's routing is a few dozen
+    patterns (tokens drawn from few symbols) an expert's load moves by a
+    pattern's whole block of tokens, the rule settles where a block's
+    near-ties split evenly, and that split is the batch's own: on every other
+    batch the held experts' share stood up to a quarter over the mean
+    (PERF.md §6, PR 57). Set-up's, as balance_bias: no step calls it."""
+    scores = _scores(u, router_w)
+    mean = scores.shape[0] * top_k / scores.shape[1]
+    biased = scores + bias.astype(jnp.float32)
+    kth = lax.top_k(biased, top_k)[0][:, -1:]
+    load = jnp.sum(biased >= kth, axis=0, dtype=jnp.float32)
+    return bias.astype(jnp.float32) + rate * jnp.sign(mean - load)
+
+
 # balance_router: a run's first rate, in units of a LOGIT (a round's step is
 # the gradient's direction at the length that moves the batch's logits by
 # its rate, root mean square; the caller lets it fall linearly to 0 over

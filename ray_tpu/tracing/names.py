@@ -74,6 +74,12 @@ CONV_GATE = "conv_gate"
 # the per-row counts and mean probabilities, their product)
 MLA_LATENT = "mla_latent"
 MOE_AUX = "moe_aux"
+# models/hyper_connections.py: all of a sublayer's hyper-connection work —
+# the maps, the pre-mix Σ H_pre[i]·x[i] and the write-back H_res·x + H_post ⊗ y
+# over the n-stream carry — and, inside it, the maps alone (the flattened
+# stream's RMS, the Φ product, the sigmoids, the Sinkhorn rounds)
+MHC = "mhc"
+MHC_MAPS = "mhc_maps"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
                                           EVA_PREP_KV, MAMBA, SSD_SCAN,
@@ -82,7 +88,7 @@ SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           SPARSE_ATTENTION, SPARSE_SELECT,
                                           SHORT_CONV, CONV_GATE,
                                           MOE_FURTHER_PASSES, MLA_LATENT,
-                                          MOE_AUX)
+                                          MOE_AUX, MHC, MHC_MAPS)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -250,6 +256,13 @@ HEAD_LOSS_ARGS = ("batch", "rows", "chunks", "columns", "heads",
 # one instant event per distinct pattern, at trace time
 LAYER_PATTERN = "model/layer_pattern"
 LAYER_PATTERN_ARGS = ("pattern", "applications", "groups")
+# a model whose residual path is a hyper-connection (models/
+# hyper_connections.py): the streams, the Sinkhorn rounds, the stream's dtype
+# and the bytes a token's carry takes; one instant event per distinct
+# decision, at trace time
+HYPER_CONNECTION = "model/hyper_connection"
+HYPER_CONNECTION_ARGS = ("streams", "rounds", "stream_dtype",
+                         "carry_bytes_per_token")
 # what the held experts of each expert layer are sent by one batch, its
 # selection bias balanced on it (nemotron_h.balance_router_bias, from the
 # first batch at set-up): pairs landed here,

@@ -251,9 +251,28 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1,
                           num_devices=8, use_tpu=False, eva_model=eva_cfg,
                           hybrid_model=hybrid_cfg, sala_model=sala_cfg,
-                          lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg)
+                          lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg,
+                          xing4_model=deepseek_v2.xing4_tiny(
+                              remat=True, hc_sinkhorn_iters=2, n_layer=1,
+                              first_layer=2))
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
+    # the Xing4.0 step (PR 57): the same family with four hyper-connection
+    # streams, a biased router and an MTP module — its decision event, its
+    # three expert layers' loads (the MTP module's last) and what the step
+    # said of them came back; and the check fails without them
+    xing4 = summary["xing4"]
+    assert [d["groups"] for d in xing4["layer_pattern"]] == [
+        ["E"]]
+    assert xing4["hyper_connection"] == [{
+        "streams": 4, "rounds": 2, "stream_dtype": "bfloat16",
+        "carry_bytes_per_token": 4 * 128 * 2}]
+    assert [e["layer"] for e in xing4["expert_load"]] == [2, 6]
+    assert np.asarray(xing4["step_load"]).shape == (2, 3)
+    stripped = [rows[-1] | {"summary": summary | {"xing4": xing4 | {
+        "layer_pattern": [], "hyper_connection": [], "expert_load": []}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + stripped, cfg, steps)
+               ) == 2
     # the DeepSeek-V2 step (PR 55): its pattern, the flash kernels' tilings
     # at the two widths, its three expert layers' loads and the balance loss
     # each said beside its load came back; and the check fails without them

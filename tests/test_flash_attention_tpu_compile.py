@@ -908,3 +908,46 @@ def test_the_deepseek_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
     # the estimate stays over what the compiler needs with these kept
     assert peak <= (_resident_bytes(args[0]) + policy["phase_bytes"]
                     + policy["saved_bytes"])
+
+
+def test_the_xing4_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
+    """`xing4.0-29b-a4b-l5.dataset`'s own step — 1 row of 8,192 tokens, four
+    hyper-connection streams, 8 of 64 experts held, the MTP module — compiled
+    for the described chip: it fits what the remat rule works to (the
+    compiler's peak is under the chip's bytes_limit less the rule's reserve)
+    with the named residuals the rule gave room, nothing is rematerialized by
+    the compiler, and its Mosaic calls are the flash pair at 192 / 128 over
+    32 heads — a forward and a backward in each of its three runs of layers
+    (the dense layer, the scan of four expert layers, the MTP module's) and NO
+    recomputed forward, since the rule keeps the kernel's o and lse — and the
+    held experts' grouped products with their metadata kernels."""
+    from ray_tpu.models import blocks, hyper_connections
+    from ray_tpu.tracing import names
+    from ray_tpu.train.train_step import _resident_bytes
+
+    cell, config, family, mesh = _cell_on(topo, "xing4.0-29b-a4b-l5.dataset")
+    fn, args = family.abstract_step(config, cell, mesh)
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert blocks.compiler_rematerialized(hlo) == []
+    flash = [op for _, code, op in _instructions(hlo)
+             if code == "custom-call" and "flash_attention_" in op]
+    assert sum(f"/{names.FLASH_FWD_KERNEL}" in op for op in flash) == 3
+    assert sum(f"/{names.FLASH_BWD_KERNEL}" in op for op in flash) == 3
+    assert not [op for op in flash if "rematted_computation" in op], flash
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= family.V5E_BYTES_LIMIT - blocks.REMAT_RESERVE_BYTES, (
+        peak / 2 ** 30)
+    assert peak >= 0.8 * family.V5E_BYTES_LIMIT         # a deployment's size
+    (policy,) = [d for d in blocks.remat_policy_decisions()
+                 if (d["n_layer"], d["seq"], d["batch"]) == (
+                     6, cell["seq_len"], 1)
+                 and d["bytes_limit"] == family.V5E_BYTES_LIMIT]
+    assert policy["phase"] == "4 x scan(E)"
+    assert policy["saved"][:2] == [names.RES_FLASH_O, names.RES_FLASH_LSE]
+    assert names.RES_MID not in policy["saved"]         # the 4-stream carry
+    # the estimate stays over what the compiler needs with these kept
+    assert peak <= (_resident_bytes(args[0]) + policy["phase_bytes"]
+                    + policy["saved_bytes"])
+    assert {"streams": 4, "rounds": 20, "stream_dtype": "bfloat16",
+            "carry_bytes_per_token": 28672} in hyper_connections.decisions()

@@ -58,6 +58,9 @@ SALA_SCOPES = (names.LIGHTNING_ATTN, names.SPARSE_ATTENTION,
 LFM2_OWN_SCOPES = (names.SHORT_CONV, names.CONV_GATE)
 # latent attention's projections and the balance loss (DeepSeek-V2, PR 55)
 DSV2_OWN_SCOPES = (names.MLA_LATENT, names.MOE_AUX)
+# the hyper-connected residual path (Xing4.0, PR 57: tests/test_xing4.py holds
+# its lowered step)
+DSV2_OWN_SCOPES += (names.MHC, names.MHC_MAPS)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE
                      and s not in EVA_SCOPES + NEMOTRON_SCOPES + SALA_SCOPES
@@ -1053,7 +1056,8 @@ def test_counter_reader_against_the_recorded_session(metric):
         entry["unit"], entry["layer"], entry["moves"], entry["source"])
     assert entry["workloads"] == ["nemotron-3-super-120b-l11.dataset",
                                   "lfm2-24b-a2b-l5.dataset",
-                                  "deepseek-v2-lite-l5.dataset"]
+                                  "deepseek-v2-lite-l5.dataset",
+                                  "xing4.0-29b-a4b-l5.dataset"]
     facts = _record_facts(events, expected["summary"])
     assert reader.read(facts) == pytest.approx(expected["metrics"][metric],
                                                rel=1e-12)
