@@ -32,8 +32,9 @@ at the two widths and the balance loss its step says beside its load, one
 step of a small Xing4.0 (the same ``models/deepseek_v2.py`` with query
 compression, the biased-sigmoid router, an MTP module and four
 manifold-constrained hyper-connection streams around every sublayer, PR 57)
-for its ``model/hyper_connection`` event and its expert layers' loads, the
-MTP module's among them, and —
+for its ``model/hyper_connection`` event, the ``ops/mhc_tiling`` decisions
+of its hyper-connection kernels (PR 58: one of each of the four, or it
+fails) and its expert layers' loads, the MTP module's among them, and —
 what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
@@ -407,6 +408,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     if config.get("xing4_model") is not None:
         from ray_tpu.models import deepseek_v2, hyper_connections
         from ray_tpu.models.blocks import layer_pattern_decisions
+        from ray_tpu.ops.hyper_connections import mhc_tiling_decisions
 
         xing4_cfg = config["xing4_model"]
         variant = make_train_step(
@@ -432,6 +434,8 @@ def train_loop(config: Dict[str, Any]) -> None:
                  "hyper_connection": [
                      d for d in hyper_connections.decisions()
                      if d["streams"] == xing4_cfg.hc_mult],
+                 "mhc_tiling": [d for d in mhc_tiling_decisions()
+                                if d["C"] == xing4_cfg.d_model],
                  "expert_load": load,
                  "step_load": np.asarray(m["counters"]).tolist()}
         del variant
@@ -639,6 +643,10 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if not xing4["layer_pattern"] or not xing4["hyper_connection"]:
             bad.append("the Xing4.0 step recorded no model/layer_pattern or "
                        "no model/hyper_connection event")
+        took = sorted(d["kernel"] for d in xing4["mhc_tiling"])
+        if took != ["mix_bwd", "mix_fwd", "write_bwd", "write_fwd"]:
+            bad.append("the Xing4.0 step recorded no ops/mhc_tiling decision "
+                       f"for each of its four hyper-connection kernels: {took}")
         if len(xing4["expert_load"]) != len(xing4["step_load"]):
             bad.append("the Xing4.0 step recorded no model/expert_load event "
                        "for each expert layer its step reports, the MTP "
@@ -1008,6 +1016,10 @@ def main() -> int:
         print(f"hyper-connection: {d['streams']} streams, {d['rounds']} "
               f"Sinkhorn rounds, the stream in {d['stream_dtype']}, "
               f"{d['carry_bytes_per_token']} B a token")
+    for d in xing4["mhc_tiling"]:
+        print(f"mhc tiling: {d['kernel']}: "
+              f"{d['token_tile']} of {d['tokens']} tokens a tile at "
+              f"{d['n']} x {d['C']}, VMEM estimate {d['vmem_estimate']} B")
     for e in xing4["expert_load"]:
         print(f"Xing4.0 expert load: published layer {e['layer']}: "
               f"{e['pairs']} pairs of {e['tokens']} tokens on the held "

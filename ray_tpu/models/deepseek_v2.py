@@ -184,6 +184,10 @@ class DeepseekV2Config:
         if self.hc_mult < 1 or self.mtp_layers < 0:
             raise ValueError("hc_mult must be at least 1, mtp_layers at "
                              "least 0")
+        if self.hc_mult > 1 and self.d_model % 128:
+            raise ValueError(
+                "under a hyper-connection (hc_mult > 1) a stream is whole "
+                f"lane tiles: d_model {self.d_model} is not a multiple of 128")
         if self.vocab_size % 128:
             raise ValueError("vocab_size (the rows held here) must be a "
                              "multiple of 128")
@@ -575,14 +579,14 @@ def _experts(x, p, cfg: DeepseekV2Config, aux: Optional[str], rate=None,
 
 
 def _mixed(x, p, prefix: str, cfg: DeepseekV2Config):
-    """What a sublayer reads of the carry x, and the maps it will write back
-    by: x itself and None on the plain residual path; under a
-    hyper-connection the pre-mix of the streams and the token's maps."""
+    """(The carry for ``_joined`` to read, what a sublayer reads of it, the
+    maps it will write back by): x, x itself and None on the plain residual
+    path; under a hyper-connection the carry handed through the mix, the
+    pre-mix of the streams and the token's maps (hyper.mixed)."""
     if cfg.hc is None:
-        return x, None
+        return x, x, None
     with jax.named_scope(scopes.MHC):
-        h = hyper.maps(x, p, prefix, cfg.hc)
-        return hyper.pre_mix(x, h), h
+        return hyper.mixed(x, p, prefix, cfg.hc)
 
 
 def _joined(x, y, h):
@@ -591,7 +595,7 @@ def _joined(x, y, h):
     if h is None:
         return parts.residual_add(x, y)
     with jax.named_scope(scopes.MHC):
-        return hyper.write_back(x, y, h)
+        return hyper.joined(x, y, h)
 
 
 @jax.named_scope(scopes.BLOCK)
@@ -612,14 +616,14 @@ def _layer(x, p, cfg: DeepseekV2Config, kind: str, aux: Optional[str] = None,
     p = {**p, **parts.cast_in_the_loop(p, x, cfg.dtype,
                                        _matmul_weights(kind, cfg))}
     plain = cfg.hc is None
-    mixed, h = _mixed(x, p, HC_ATTN, cfg)
+    x, mixed, h = _mixed(x, p, HC_ATTN, cfg)
     with jax.named_scope(scopes.LN1):
         u = parts.rmsnorm(mixed, p["attn_norm"], cfg.rms_eps)
     x = checkpoint_name(_joined(x, mla_operator(u, p, cfg), h),
                         scopes.RES_MID)
     # (the plain path's halves add their own residual, where they always did:
     # a chunked half inside each chunk)
-    mixed, h = _mixed(x, p, HC_FFN, cfg)
+    x, mixed, h = _mixed(x, p, HC_FFN, cfg)
     if EXPERTS[kind]:
         y, out = _experts(mixed, p, cfg, aux, rate, add=plain)
     else:
@@ -665,12 +669,16 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
     writes ``d_model`` — the pre-mix goes in, the float32 y comes out — so
     each moment holds what it held, with the n-stream tensors that wait in
     it added: through the feed-forward half's backward the block's input and
-    the carry after attention's write-back, that carry's float32 cotangent
-    being summed (the write-back's ``H_resᵀ`` part and the pre-mix's), and a
-    sublayer's maps (the Φ product's 24 float32 planes and two copies a
-    Sinkhorn round of its n² planes, 3 KB a token beside the carry's 28);
-    attention's own backward holds the block's input and its float32
-    cotangent in the carry after attention's place.
+    the carry after attention's write-back, that carry's cotangent (the
+    write-back's ``H_resᵀ`` part, which the mix's backward takes in), and a
+    sublayer's maps (the mix's 24 float32 planes and 1 / rms, and two copies
+    a Sinkhorn round of its n² planes, 3 KB a token beside the carry's 28);
+    attention's own backward holds the block's input and its cotangent in
+    the carry after attention's place. The cotangent is priced at float32,
+    as XLA summed it before PR 58; the kernels (ops/hyper_connections.py) sum
+    it in VMEM and it crosses HBM in the stream's dtype, n · d · (4 − a)
+    bytes a token less, 235 MB at the Xing4.0 cell's shapes: the estimate is
+    an upper bound by that much, and the compiled step says so (PERF.md §7).
     """
     a = jnp.dtype(cfg.dtype).itemsize
     D, F, Fe, H = cfg.d_model, cfg.d_ff, cfg.d_expert, cfg.n_head

@@ -28,6 +28,12 @@ four numbers on a tile's 128. Φ enters the product in the compute dtype
 the norm's statistic, the sigmoids, the rounds and both mixes' sums are
 float32, the mixes' results rounded to the stream's dtype once.
 
+``mixed`` and ``joined`` are what a layer calls around a sublayer: the kernel
+pairs of ``ops/hyper_connections.py``, which make the values of the three
+plain functions above in one pass over x each way (PR 58). ``maps``,
+``pre_mix`` and ``write_back`` stay as the definition the tests hold the
+kernels to; no layer calls them.
+
 ``expand`` starts a stream (every stream a copy of the embedding) and
 ``collapse`` ends one (the sum over the streams, float32). A model whose
 ``n`` is 1 calls none of this: its layers are the plain residual's, with no
@@ -42,6 +48,7 @@ from typing import Any, Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import hyper_connections as kernels
 from ray_tpu.tracing import get_buffer, names as scopes
 
 # a sublayer's tensors, each under its sublayer's prefix; PHI alone is a
@@ -112,17 +119,10 @@ def sinkhorn(logits: jax.Array, rounds: int, eps: float) -> jax.Array:
     return m
 
 
-@jax.named_scope(scopes.MHC_MAPS)
-def maps(x: jax.Array, p: Dict[str, jax.Array], prefix: str,
-         hc: HyperConnection) -> Maps:
-    """x [B, S, n·C] → the token's three maps (module docstring). ``p``
-    holds the sublayer's tensors under ``prefix``, Φ in the compute dtype."""
+def _maps_of(m: jax.Array, p: Dict[str, jax.Array], prefix: str,
+             hc: HyperConnection) -> Maps:
+    """The three maps from the normalised logits m [n² + 2n, B, S] float32."""
     n = hc.n
-    xf = x.astype(jnp.float32)
-    inv_rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1) + hc.norm_eps)  # [B, S]
-    m = jnp.moveaxis(jnp.einsum("bsk,km->bsm", x, p[prefix + PHI],
-                                preferred_element_type=jnp.float32), -1, 0
-                     ) * inv_rms                                 # [n² + 2n, B, S]
     alpha = p[prefix + ALPHA].astype(jnp.float32)
     bias = p[prefix + BIAS].astype(jnp.float32)[:, None, None]
     pre = jax.nn.sigmoid(alpha[0] * m[:n] + bias[:n])
@@ -130,6 +130,19 @@ def maps(x: jax.Array, p: Dict[str, jax.Array], prefix: str,
     res = (alpha[2] * m[2 * n:] + bias[2 * n:]).reshape((n, n) + m.shape[1:])
     return Maps(pre, post, sinkhorn(jnp.clip(res, -hc.clamp, hc.clamp),
                                     hc.rounds, hc.eps))
+
+
+@jax.named_scope(scopes.MHC_MAPS)
+def maps(x: jax.Array, p: Dict[str, jax.Array], prefix: str,
+         hc: HyperConnection) -> Maps:
+    """x [B, S, n·C] → the token's three maps (module docstring). ``p``
+    holds the sublayer's tensors under ``prefix``, Φ in the compute dtype."""
+    xf = x.astype(jnp.float32)
+    inv_rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1) + hc.norm_eps)  # [B, S]
+    m = jnp.moveaxis(jnp.einsum("bsk,km->bsm", x, p[prefix + PHI],
+                                preferred_element_type=jnp.float32), -1, 0
+                     ) * inv_rms                                 # [n² + 2n, B, S]
+    return _maps_of(m, p, prefix, hc)
 
 
 def _streams(x: jax.Array, n: int):
@@ -155,6 +168,24 @@ def write_back(x: jax.Array, y: jax.Array, h: Maps) -> jax.Array:
         (sum(h.res[i, j][..., None] * xs[j] for j in range(n))
          + h.post[i][..., None] * yf).astype(x.dtype)
         for i in range(n)], axis=-1)
+
+
+def mixed(x: jax.Array, p: Dict[str, jax.Array], prefix: str,
+          hc: HyperConnection) -> Tuple[jax.Array, jax.Array, Maps]:
+    """What a sublayer takes of the carry x [B, S, n·C]: (x for ``joined`` to
+    read, the pre-mix u [B, S, C], the token's maps) — ``maps`` and
+    ``pre_mix`` in one pass over x (ops/hyper_connections.mix: the logits,
+    the RMS and u from one read, x handed through so that its cotangent comes
+    back once), the other maps XLA's on the logits' planes."""
+    with jax.named_scope(scopes.MHC_MAPS):
+        x, u, m = kernels.mix(x, p[prefix + PHI], p[prefix + ALPHA][0],
+                              p[prefix + BIAS][:hc.n], hc.norm_eps)
+        return x, u, _maps_of(m, p, prefix, hc)
+
+
+def joined(x: jax.Array, y: jax.Array, h: Maps) -> jax.Array:
+    """``write_back`` as one kernel: each stream of x' written in place."""
+    return kernels.write_back(x, y, h.post, h.res)
 
 
 def expand(x: jax.Array, n: int) -> jax.Array:

@@ -76,8 +76,11 @@ MLA_LATENT = "mla_latent"
 MOE_AUX = "moe_aux"
 # models/hyper_connections.py: all of a sublayer's hyper-connection work —
 # the maps, the pre-mix Σ H_pre[i]·x[i] and the write-back H_res·x + H_post ⊗ y
-# over the n-stream carry — and, inside it, the maps alone (the flattened
-# stream's RMS, the Φ product, the sigmoids, the Sinkhorn rounds)
+# over the n-stream carry — and, inside it, the pass over x that makes the
+# maps: since PR 58 the mix pair (ops/hyper_connections.py: the flattened
+# stream's RMS and the Φ product from one read of x, with the pre-mix riding
+# on the same read; backward the one kernel that writes d x) and, XLA's, the
+# sigmoids and the Sinkhorn rounds on the logits' planes
 MHC = "mhc"
 MHC_MAPS = "mhc_maps"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
@@ -120,11 +123,22 @@ SPARSE_ATTN_BWD_DKV_KERNEL = "sparse_attn_bwd_dkv"
 # and y out; BCx and d y in, d BCx and d w's partial sums out
 CONV_GATE_FWD_KERNEL = "conv_gate_fwd"
 CONV_GATE_BWD_KERNEL = "conv_gate_bwd"
+# the passes over a hyper-connection's n-stream carry (ops/
+# hyper_connections.py), a tile of tokens at the carry's whole width: the mix
+# (x in once: the maps' normalised logits and the pre-mix u out; backward the
+# ONE kernel that sums and writes d x, with d Φ's sum) and the write-back
+# (x, y and the maps in, x' out; backward d y, the maps' cotangents and the
+# carry's part H_resᵀ · d x')
+MHC_MIX_FWD_KERNEL = "mhc_mix_fwd"
+MHC_MIX_BWD_KERNEL = "mhc_mix_bwd"
+MHC_WRITE_FWD_KERNEL = "mhc_write_fwd"
+MHC_WRITE_BWD_KERNEL = "mhc_write_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
            SSD_CHUNK_BWD_KERNEL, SPARSE_ATTN_FWD_KERNEL,
            SPARSE_ATTN_BWD_DQ_KERNEL, SPARSE_ATTN_BWD_DKV_KERNEL,
-           CONV_GATE_FWD_KERNEL, CONV_GATE_BWD_KERNEL)
+           CONV_GATE_FWD_KERNEL, CONV_GATE_BWD_KERNEL, MHC_MIX_FWD_KERNEL,
+           MHC_MIX_BWD_KERNEL, MHC_WRITE_FWD_KERNEL, MHC_WRITE_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
@@ -153,6 +167,13 @@ SPARSE_TILING = "ops/sparse_tiling"
 SPARSE_TILING_ARGS = ("kernel", "rows", "S", "group_heads", "hd", "block",
                       "blocks_per_query", "block_q", "block_k",
                       "vmem_estimate")
+# the same for a hyper-connection kernel ("mix_fwd", "mix_bwd", "write_fwd",
+# "write_bwd"; ops/hyper_connections.py): the tokens a device holds, the
+# streams and a stream's width, the tokens a grid step takes at the carry's
+# whole width
+MHC_TILING = "ops/mhc_tiling"
+MHC_TILING_ARGS = ("kernel", "tokens", "n", "C", "token_tile",
+                   "vmem_estimate")
 # what a block-sparse mixer's selection is, once a distinct shape (models/
 # minicpm_sala.py): rows and sequence, the key blocks there are, how many a
 # query is given of them, how many of those are forced (the window's and the

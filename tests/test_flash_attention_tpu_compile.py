@@ -920,7 +920,15 @@ def test_the_xing4_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
     32 heads — a forward and a backward in each of its three runs of layers
     (the dense layer, the scan of four expert layers, the MTP module's) and NO
     recomputed forward, since the rule keeps the kernel's o and lse — and the
-    held experts' grouped products with their metadata kernels."""
+    held experts' grouped products with their metadata kernels. Since PR 58
+    the hyper-connection's passes over the carry are the four `mhc_*` kernels
+    (ops/hyper_connections.py), in each of the three runs of layers: both
+    sublayers' mix and write-back forward, the recompute's mix, write-back,
+    mix (the carry after attention is made again; the last write-back's
+    result is nobody's residual), both sublayers' two backward kernels — every
+    one under `/mhc/`, the mix pair under `/mhc/mhc_maps/` too, backward
+    instances included; no sublayer's write-back puts the carry together by
+    a `concatenate` (the stream's start, outside any block, is `expand`'s)."""
     from ray_tpu.models import blocks, hyper_connections
     from ray_tpu.tracing import names
     from ray_tpu.train.train_step import _resident_bytes
@@ -935,6 +943,32 @@ def test_the_xing4_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
     assert sum(f"/{names.FLASH_FWD_KERNEL}" in op for op in flash) == 3
     assert sum(f"/{names.FLASH_BWD_KERNEL}" in op for op in flash) == 3
     assert not [op for op in flash if "rematted_computation" in op], flash
+    mhc = [op for _, code, op in _instructions(hlo)
+           if code == "custom-call" and "/mhc_" in op and "pallas_call" in op]
+    assert all(f"/{names.MHC}/" in op for op in mhc), mhc
+
+    def count(kernel, *marks):
+        return sum(f"/{kernel}/" in op and all(
+            (mark[1:] not in op) if mark[0] == "-" else (mark in op)
+            for mark in marks) for op in mhc)
+
+    fwd, bwd, again = "-transpose(", "transpose(", "rematted_computation"
+    mix = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL)
+    assert all(f"/{names.MHC}/{names.MHC_MAPS}/" in op for op in mhc
+               if any(f"/{k}/" in op for k in mix)), mhc
+    assert not [op for op in mhc if f"/{names.MHC_MAPS}/" in op
+                and not any(f"/{k}/" in op for k in mix)], mhc
+    assert count(names.MHC_MIX_FWD_KERNEL, fwd) == 3 * 2
+    assert count(names.MHC_MIX_FWD_KERNEL, bwd, again) == 3 * 2
+    assert count(names.MHC_WRITE_FWD_KERNEL, fwd) == 3 * 2
+    assert count(names.MHC_WRITE_FWD_KERNEL, bwd, again) == 3 * 1
+    assert count(names.MHC_MIX_BWD_KERNEL, bwd, "-" + again) == 3 * 2
+    assert count(names.MHC_WRITE_BWD_KERNEL, bwd, "-" + again) == 3 * 2
+    assert len(mhc) == 3 * (4 + 3 + 4)
+    carry = f"{cell['seq_len']},{4 * config['hidden_size']}]"
+    assert [(shape, op) for shape, code, op in _instructions(hlo)
+            if carry in shape and (code == "concatenate" or (
+                "concatenate" in op and f"/{names.BLOCK}/" in op))] == []
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert peak <= family.V5E_BYTES_LIMIT - blocks.REMAT_RESERVE_BYTES, (
         peak / 2 ** 30)

@@ -42,6 +42,9 @@ LOWERINGS = {
     # a pattern of PAIRS: short convolution or attention, then a dense MLP or
     # gated experts (LFM2-MoE, PR 50)
     "lfm2": dict(remat=True, attention_impl="pallas"),
+    # the DeepSeek-V2 family's layer inside four hyper-connection streams a
+    # lane tile wide: the kernel pairs of ops/hyper_connections.py (PR 58)
+    "xing4": dict(remat=True, hc_sinkhorn_iters=3),
 }
 # each model's own mixer: GPT-2 has the flash kernels, EvaByte the EVA ones
 EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
@@ -62,6 +65,8 @@ DSV2_OWN_SCOPES = (names.MLA_LATENT, names.MOE_AUX)
 # its lowered step)
 DSV2_OWN_SCOPES += (names.MHC, names.MHC_MAPS)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
+MHC_KERNELS = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL,
+               names.MHC_WRITE_FWD_KERNEL, names.MHC_WRITE_BWD_KERNEL)
 DENSE_SCOPES = tuple(s for s in names.SCOPES if s != names.MOE
                      and s not in EVA_SCOPES + NEMOTRON_SCOPES + SALA_SCOPES
                      + LFM2_OWN_SCOPES + DSV2_OWN_SCOPES)
@@ -100,7 +105,8 @@ _lowered = {}
 
 def _step(key):
     """(bundle, batch of 2) of the tiny train step `key` names, built anew."""
-    from ray_tpu.models import gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h
+    from ray_tpu.models import (
+        deepseek_v2, gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h)
     from ray_tpu.train.train_step import (
         make_gpt2_train_step, make_train_step, synthetic_batch)
 
@@ -116,6 +122,9 @@ def _step(key):
     elif key == "lfm2":
         cfg = lfm2_moe.lfm2_moe_tiny(**LOWERINGS[key])
         bundle = make_train_step(lfm2_moe, cfg)
+    elif key == "xing4":
+        cfg = deepseek_v2.xing4_tiny(**LOWERINGS[key])
+        bundle = make_train_step(deepseek_v2, cfg)
     else:
         cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
         bundle = make_gpt2_train_step(cfg)
@@ -322,6 +331,7 @@ def test_remat_recompute_keeps_the_block_scopes(blocks):
 def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
     assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS + SSD_KERNELS
                                      + SPARSE_KERNELS + CONV_KERNELS
+                                     + MHC_KERNELS
                                      + (names.RAGGED_DOT_KERNEL,))
 
 
@@ -335,7 +345,8 @@ def test_kernel_name_in_jaxpr(kernel):
     _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else
                          "nemotron" if kernel in SSD_KERNELS else
                          "sala" if kernel in SPARSE_KERNELS else
-                         "lfm2" if kernel in CONV_KERNELS else "remat")
+                         "lfm2" if kernel in CONV_KERNELS else
+                         "xing4" if kernel in MHC_KERNELS else "remat")
     assert f"name={kernel}" in jaxpr
 
 

@@ -261,6 +261,127 @@ def test_maps_set_to_the_plain_residuals_give_the_plain_layer(kind):
                                atol=1e-5)
 
 
+def _sublayer(n, width, rows, seq, seed=0):
+    """A hyper-connection's tensors with lively maps, a carry, a sublayer's
+    output and two cotangents, all float32."""
+    hc = hyper.HyperConnection(n, 5, 1e-6, 30.0, 1e-6)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    p = {k: v[0] for k, v in hyper.init(keys[0], 1, hc, width, 0.05,
+                                        jnp.float32, "s_").items()}
+    p["s_" + hyper.ALPHA] = jnp.array([0.7, 0.5, 0.9])
+    p["s_" + hyper.BIAS] += 0.3 * jax.random.normal(
+        keys[1], p["s_" + hyper.BIAS].shape)
+    x = jax.random.normal(keys[2], (rows, seq, n * width))
+    y = jax.random.normal(keys[3], (rows, seq, width))
+    return hc, p, x, y, (jax.random.normal(keys[4], x.shape),
+                         jax.random.normal(keys[5], y.shape))
+
+
+def _around_a_sublayer(path, hc, p, x, y, weights, dtype):
+    """((u, x'), every gradient) of Σ x' · w + Σ u · v through the
+    hyper-connection around a sublayer whose output is the given y: by the
+    plain functions, or by what a layer calls (the kernels)."""
+    def loss(x, y, p):
+        x = x.astype(dtype)
+        p = {**p, "s_" + hyper.PHI: p["s_" + hyper.PHI].astype(dtype)}
+        if path == "plain":
+            h = hyper.maps(x, p, "s_", hc)
+            u, out = hyper.pre_mix(x, h), hyper.write_back(x, y, h)
+        else:
+            x, u, h = hyper.mixed(x, p, "s_", hc)
+            out = hyper.joined(x, y, h)
+        f = jnp.float32
+        return (jnp.sum(out.astype(f) * weights[0])
+                + jnp.sum(u.astype(f) * weights[1])), (u, out)
+
+    (_, made), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(x, y, p)
+    return made, grads
+
+
+@pytest.mark.parametrize("seq", [128, 100], ids=["whole-tiles", "ragged"])
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_kernel_pairs_equal_the_plain_functions(dtype, n, seq):
+    """`ops/hyper_connections.py` (interpreted here): u, x' and EVERY
+    gradient — d x, d y, d Φ, d α, d b — of the kernel path equal the plain
+    `maps` / `pre_mix` / `write_back`: in float32 to 1e-5 of a tensor's
+    largest; in bf16 no further from the float32 truth, by a tensor's root
+    mean square, than the plain path is. (To a quarter: here d Φ reads 1.09
+    to 1.17 of the plain path's, whose product on this backend takes the
+    logits' float32 cotangent as it is where the kernel rounds it to the
+    stream's dtype, as the chip's matrix unit does on both paths — there the
+    two read equal to four digits, PERF.md §6, PR 58; d x reads 0.82 to
+    0.86.) 2 × 128 tokens are one tile, 2 × 100 are padded to it."""
+    from ray_tpu.ops import hyper_connections as kernels
+
+    hc, p, x, y, weights = _sublayer(n, 128, 2, seq)
+    truth = _around_a_sublayer("plain", hc, p, x, y, weights, jnp.float32)
+    plain = _around_a_sublayer("plain", hc, p, x, y, weights, dtype)
+    got = _around_a_sublayer("kernel", hc, p, x, y, weights, dtype)
+    assert {d["kernel"] for d in kernels.mhc_tiling_decisions()
+            if (d["n"], d["C"]) == (n, 128)} == set(kernels.KERNELS)
+
+    def errors(tree, norm):
+        return [float(norm(a.astype(jnp.float32) - b) / norm(b))
+                for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(truth),
+                                strict=True)]
+
+    largest, rms = (lambda t: jnp.abs(t).max(),
+                    lambda t: jnp.sqrt(jnp.mean(t * t)))
+    mine, theirs = errors(got, rms), errors(plain, rms)
+    assert len(mine) == 2 + 2 + 3                # u, x'; d x, d y; d Φ, α, b
+    if dtype == jnp.float32:
+        assert max(errors(got, largest)) < 1e-5
+    else:
+        assert all(m <= 1.25 * t for m, t in zip(mine, theirs, strict=True))
+
+
+def test_the_kernel_pairs_take_their_own_rows_under_a_mesh():
+    """Under a mesh (dp 2 × fsdp 2 of the host's CPU devices) the kernels run
+    inside a shard_map over the batch axes, a device its own rows at the
+    whole width: u, x', d x and d y are the unsharded call's, the maps'
+    tensors' gradients the devices' sums."""
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(dp=2, fsdp=2),
+                              jax.devices()[:4])
+    hc, p, x, y, weights = _sublayer(4, 128, 4, 32)
+    alone = _around_a_sublayer("kernel", hc, p, x, y, weights, jnp.float32)
+    with mesh_lib.use_mesh(mesh):
+        sharded = _around_a_sublayer("kernel", hc, p, x, y, weights,
+                                     jnp.float32)
+    for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(alone),
+                    strict=True):
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-5 * float(jnp.abs(b).max()))
+
+
+def test_a_stream_is_whole_lane_tiles_or_the_config_is_refused():
+    """The kernels are the one path, so what they need is the config's to
+    hold: a hyper-connected model 96 wide is refused where it is stated (the
+    plain residual at that width is not), and so is a call of the kernels at
+    such a width, or with more streams than put the maps' planes in one lane
+    tile. The `ops/mhc_tiling` event carries what a kernel's tiling is."""
+    from ray_tpu.ops import hyper_connections as kernels
+
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        ds.xing4_tiny(d_model=96)
+    assert ds.xing4_tiny(d_model=96, hc_mult=1).hc is None
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        kernels.choose_mhc_tiling("mix_fwd", 256, 4, 96, 2)
+    with pytest.raises(ValueError, match="planes fit one"):
+        kernels.choose_mhc_tiling("mix_fwd", 256, 11, 128, 2)
+    tiling = kernels.choose_mhc_tiling("write_bwd", 512, 4, 128, 2)
+    assert tiling.token_tile == 256
+    assert dict(zip(names.MHC_TILING_ARGS, ("write_bwd", 512, 4, 128)
+                    + tuple(tiling))) in kernels.mhc_tiling_decisions()
+    assert names.MHC_TILING == "ops/mhc_tiling"
+    assert set(kernels.KERNELS) == {
+        k[len("mhc_"):] for k in names.KERNELS if k.startswith("mhc_")}
+
+
 # sha256 of the StableHLO text of deepseek_v2_tiny's loss, counters and
 # gradient as the PARENT of PR 57 lowers it (JAX 0.9.0; no remat, remat). To
 # make one again: jax.jit(jax.value_and_grad(lambda p, t, g: ds.loss_fn(p, t,
