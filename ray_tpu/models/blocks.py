@@ -18,6 +18,7 @@ module of ``ray_tpu.models``.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
                     Tuple)
@@ -166,6 +167,33 @@ def choose_remat_policy_kinds(kinds: Sequence[KindShard], working_set: int,
     return RematPolicy(tuple(saved), used, max(0, budget), bytes_limit)
 
 
+def one_candidate_a_name(kinds: Dict[str, KindShard]) -> Dict[str, KindShard]:
+    """``kinds`` with every set of names a candidate of ONE kind. Kinds that
+    share halves share names, and a checkpoint policy keeps a NAME — in every
+    layer that has it, whichever kind's candidate the rule took: an
+    operator's ``block_mid`` may be in every kind, the routing's names in
+    those with experts. Each shared set goes to the kind applied most, at the
+    bytes and operations of all the layers that have it, spread over that
+    kind's applications — so the rule takes or leaves it once, for what it
+    really costs. For a model to call on its KindShards where its kinds
+    share names; not part of choose_remat_policy_kinds (ROADMAP D25 (b))."""
+    layers: Dict[Tuple[str, ...], Dict[str, RematCandidate]] = {}
+    for kind, shard in kinds.items():
+        for c in shard.candidates:
+            layers.setdefault(c.names, {})[kind] = c
+    kept: Dict[str, list] = {kind: [] for kind in kinds}
+    for names, by_kind in layers.items():
+        carrier = max(by_kind, key=lambda kind: kinds[kind].applications)
+        # (bytes, operations, bytes freed) over all the layers that have the
+        # names, an application of the carrier
+        spread = [-(-sum(kinds[k].applications * c[field]
+                         for k, c in by_kind.items())
+                    // kinds[carrier].applications) for field in (1, 2, 3)]
+        kept[carrier].append(RematCandidate(names, *spread))
+    return {kind: shard._replace(candidates=tuple(kept[kind]))
+            for kind, shard in kinds.items()}
+
+
 def remat_policy_decisions() -> List[Dict[str, Any]]:
     """Every distinct remat decision this process has traced a model with, as
     the ``model/remat_policy`` events carry them."""
@@ -240,6 +268,47 @@ def pattern_groups(pattern: str) -> List[Tuple[str, int]]:
         groups.append(best)
         i += best[1] * len(best[0])
     return groups
+
+
+def group_counts(pattern: str) -> List[Dict[str, int]]:
+    """[{kind: layers of it}] a run of pattern_groups(pattern)."""
+    return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
+            for sub, reps in pattern_groups(pattern)]
+
+
+def init_pattern(rng, pattern: str, kinds: str, layer_init: Callable):
+    """The layers of ``pattern`` as run_pattern takes them: one entry a run of
+    the pattern, a kind's layers of the run stacked in the order they come,
+    each stack the model's ``layer_init(key, n, kind)``: ``n`` stacked layers
+    of ``kind``. A run has a key of its own and in it every kind of ``kinds``
+    (the model's kinds, in its own order) one: what a kind draws does not
+    depend on which other kinds the run holds."""
+    groups = group_counts(pattern)
+    out = []
+    for counts, group_key in zip(groups, jax.random.split(rng, len(groups))):
+        keys = dict(zip(kinds, jax.random.split(group_key, len(kinds))))
+        out.append({kind: layer_init(keys[kind], n, kind)
+                    for kind, n in counts.items()})
+    return out
+
+
+def with_grad_bytes(kinds: Dict[str, KindShard], layer_init: Callable,
+                    mesh) -> Dict[str, KindShard]:
+    """``kinds`` with each kind's ``grad_bytes``: the bytes of one layer of
+    its parameters (init_pattern's ``layer_init``, nothing made), which its
+    weight gradients take again, on one chip of ``mesh``. A chip holds no
+    less than its even share: backward_phases takes gradients that do not
+    exist yet OFF what is resident, so the least is the safe figure."""
+    chips = mesh.devices.size if mesh is not None else 1
+
+    def layer_bytes(kind):
+        layer = jax.eval_shape(
+            lambda: layer_init(jax.random.PRNGKey(0), 1, kind))
+        return sum(math.prod(p.shape) * p.dtype.itemsize
+                   for p in jax.tree.leaves(layer))
+
+    return {kind: shard._replace(grad_bytes=layer_bytes(kind) // chips)
+            for kind, shard in kinds.items()}
 
 
 def run_pattern(block_fns: Dict[str, Callable], pattern: str, x,
@@ -342,6 +411,38 @@ def aux_column(auxes: Sequence[Sequence[Any]], field: str):
         if have:        # [repeats, layers of the sub-pattern], or one a layer
             rows.append(jnp.stack(have, axis=-1).reshape(-1))
     return jnp.concatenate(rows)
+
+
+def aux_by_layer(runs: Sequence[Run], auxes: Sequence[Sequence[Any]]) -> list:
+    """run_pattern's ``auxes`` over ``runs`` (several patterns' joined, in the
+    order they ran) as one entry a layer that has an aux, in the order the
+    layers are applied: a scan's stacked aux taken apart by its repeats."""
+    out = []
+    for (sub, reps), aux in zip(runs, auxes, strict=True):
+        for r in range(reps):
+            out += [jax.tree.map(lambda t: t[r], a) if reps > 1 else a
+                    for a in aux if a is not None]
+    return out
+
+
+def with_leaf(pattern: str, stacks: Sequence[Dict[str, Any]], name: str,
+              rows) -> List[Dict[str, Any]]:
+    """aux_by_layer's way back: ``stacks`` (run_pattern's, of ``pattern``)
+    with the leaf ``name`` of every layer whose kind has one replaced by the
+    next entry of the iterator ``rows`` — one a layer, in the order the
+    layers are applied; several patterns draw from one iterator in turn —,
+    in the old leaf's dtype and sharding."""
+    out = []
+    for (sub, reps), group in zip(pattern_groups(pattern), stacks, strict=True):
+        new = {kind: [] for kind, stack in group.items() if name in stack}
+        for kind in sub * reps:
+            if kind in new:
+                new[kind].append(next(rows))
+        out.append({kind: stack if kind not in new else {
+            **stack, name: jax.device_put(
+                jnp.stack(new[kind]).astype(stack[name].dtype),
+                stack[name].sharding)} for kind, stack in group.items()})
+    return out
 
 
 def record_layer_pattern(pattern: str) -> None:

@@ -92,12 +92,11 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import blocks, hyper_connections as hyper, parts
 from ray_tpu.ops import moe
-from ray_tpu.tracing import get_buffer, names as scopes
+from ray_tpu.tracing import names as scopes
 
 KINDS = "DE"
 EXPERTS = {"D": False, "E": True}
@@ -293,12 +292,6 @@ def _runs(cfg: DeepseekV2Config) -> List[Tuple[str, int]]:
             + blocks.pattern_groups(cfg.mtp_pattern))
 
 
-def _group_counts(pattern: str):
-    """[{kind: layers of it}] a run of blocks.pattern_groups(pattern)."""
-    return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
-            for sub, reps in blocks.pattern_groups(pattern)]
-
-
 def _layer_init(rng, n: int, kind: str, cfg: DeepseekV2Config):
     """``n`` stacked layers of ``kind``: the two pre-norms, latent
     attention's tensors and the feed-forward half's."""
@@ -343,15 +336,8 @@ def _layer_init(rng, n: int, kind: str, cfg: DeepseekV2Config):
 
 
 def _stack_init(rng, pattern: str, cfg: DeepseekV2Config):
-    """The layers of ``pattern`` as blocks.run_pattern takes them: one entry a
-    run of the pattern, a kind's layers of the run stacked in their order."""
-    groups = _group_counts(pattern)
-    out = []
-    for counts, group_key in zip(groups, jax.random.split(rng, len(groups))):
-        keys = dict(zip(KINDS, jax.random.split(group_key, len(KINDS))))
-        out.append({kind: _layer_init(keys[kind], n, kind, cfg)
-                    for kind, n in counts.items()})
-    return out
+    return blocks.init_pattern(rng, pattern, KINDS,
+                               partial(_layer_init, cfg=cfg))
 
 
 _LAYER_AXES = {
@@ -426,25 +412,18 @@ def init(cfg: DeepseekV2Config, rng: jax.Array) -> Dict[str, Any]:
     return out
 
 
-def _is_buffer(path) -> bool:
-    return getattr(path[-1], "key", None) == "router_bias"
-
-
 def param_count(cfg: DeepseekV2Config) -> int:
     """The parameters a step moves: every leaf but the expert layers'
     selection biases, which are buffers (a router without one has none)."""
-    shapes = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
-    return sum(int(np.prod(p.shape)) for path, p in
-               jax.tree_util.tree_leaves_with_path(shapes)
-               if not _is_buffer(path))
+    return parts.param_count(lambda: init(cfg, jax.random.PRNGKey(0)),
+                             "router_bias")
 
 
 def decays(params):
     """Which leaves an optimizer's weight decay may touch (optax's ``mask``):
     all but the selection biases — no gradient reaches them, and a decay must
     not."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: not _is_buffer(path), params)
+    return parts.all_but(params, "router_bias")
 
 
 # --------------------------------------------------------------------------- #
@@ -516,29 +495,20 @@ def _swiglu(x, p, cfg: DeepseekV2Config, add: bool = True):
     itself)."""
     with jax.named_scope(scopes.LN2):
         h = parts.rmsnorm(x, p["ffn_norm"], cfg.rms_eps)
+    y = parts.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+    if not add:
+        return y
     with jax.named_scope(scopes.MLP):
-        gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
-                               scopes.RES_MLP_GATE)
-        up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
-                             scopes.RES_MLP_UP)
-        y = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
-                       preferred_element_type=jnp.float32)
-        return parts.residual_add(x, y) if add else y
+        return parts.residual_add(x, y)
 
 
 def _dense(x, p, cfg: DeepseekV2Config, add: bool = True):
-    """The dense feed-forward half, norm and all; where one hidden tensor of
-    the whole sequence would pass parts.MLP_CHUNK_BYTES the sequence goes in
-    chunks (parts.mlp_rows), each its own ``checkpoint`` — as the llama
-    block's does, and why (models/llama.py)."""
-    B, S, D = x.shape
-    rows = parts.mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
-    if rows == S:
-        return _swiglu(x, p, cfg, add)
-    chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
-    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg, add=add)),
-                  chunks)
-    return out.swapaxes(0, 1).reshape(B, S, D)
+    """The dense feed-forward half, norm and all, in chunks of the sequence
+    where parts.mlp_rows says so — as the llama block's, and why
+    (models/llama.py)."""
+    return parts.in_row_chunks(
+        partial(_swiglu, p=p, cfg=cfg, add=add), x,
+        parts.mlp_rows(*x.shape, cfg.d_ff, x.dtype.itemsize))
 
 
 def _routing(cfg: DeepseekV2Config) -> Dict[str, Any]:
@@ -739,35 +709,20 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
                        + attn_params) + (tokens * H * 4 if flash else 0))
     carried = tokens * W * a    # the cotangent of the block's output
 
-    # the feed-forward halves, as the LFM2 family prices them: the dense
-    # one's hidden tensors where it is not chunked; what the routing decided
-    # and the shared expert's two hidden tensors
-    dense_kept = tuple(
-        C((name,), tokens * F * a, 2 * tokens * D * F)
-        for name in base.mlp_hidden) if base.mlp_rows in (0, base.seq) else ()
-    dense_set = a * (base.batch * (base.mlp_rows or base.seq) * 5 * F
-                     + 2 * 3 * D * F)
-    rows = moe.row_buffer(tokens, cfg.n_experts, cfg.top_k, cfg.held_count)
-    passes = moe.buffer_passes(tokens, cfg.n_experts, cfg.top_k,
-                               cfg.held_count)
-    shared_rows = parts.mlp_rows(base.batch, base.seq, D, Fs, a)
-    experts_kept = (
-        C((scopes.RES_MOE_SCORES,), tokens * cfg.n_experts * 4,
-          3 * 2 * tokens * D * cfg.n_experts),
-        C((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
-          tokens * _sort_ops(cfg.n_experts, operands=2)),
-        C((scopes.RES_MOE_PAIR_KEY, scopes.RES_MOE_PAIR_GATE),
-          passes * rows * 8, _sort_ops(tokens * cfg.held_count, operands=2)),
-    ) + (tuple(C((name,), tokens * Fs * a, 2 * tokens * D * Fs)
-               for name in (scopes.RES_MOE_SHARED_GATE,
-                            scopes.RES_MOE_SHARED_UP))
-         if shared_rows in (0, base.seq) else ())
-    expert_weights = 3 * cfg.held_count * D * Fe
-    routed_set = a * rows * (2 * D + 6 * Fe) + (a + 4) * expert_weights
-    shared_set = a * (base.batch * (shared_rows or base.seq) * 5 * Fs
-                      + 2 * 3 * D * Fs)
-    experts_set = (tokens * D * (2 * a + 8) + tokens * cfg.n_experts * 12
-                   + max(routed_set, shared_set))
+    # the feed-forward halves: the dense one and the shared expert are
+    # SwiGLUs (parts.swiglu_price); what the routing decided
+    # (parts.routing_candidates); in the expert half's backward its stream
+    # beside the LARGER of the routed passes' set and the shared expert's
+    dense_kept, dense_set = parts.swiglu_price(
+        base.batch, base.seq, base.mlp_rows, D, F, a, base.mlp_hidden)
+    shared_kept, shared_set = parts.swiglu_price(
+        base.batch, base.seq, parts.mlp_rows(base.batch, base.seq, D, Fs, a),
+        D, Fs, a, (scopes.RES_MOE_SHARED_GATE, scopes.RES_MOE_SHARED_UP))
+    experts_kept = parts.routing_candidates(
+        tokens, D, cfg.n_experts, cfg.top_k, cfg.held_count) + shared_kept
+    stream, routed_set = parts.gated_experts_working_set(
+        tokens, D, cfg.n_experts, cfg.top_k, cfg.held_count, Fe, a)
+    experts_set = stream + max(routed_set, shared_set)
 
     kinds = {}
     layers = cfg.pattern + cfg.mtp_pattern
@@ -778,48 +733,8 @@ def kind_shards(cfg: DeepseekV2Config, global_batch: int, seq: int, mesh
             layers.count(kind), tuple(attn_kept) + ff_kept,
             carried + max(attn_waits + ff_set + waiting_streams,
                           attn_set + own_streams))
-    chips = mesh.devices.size if mesh is not None else 1
-    return base, {k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
-                  for k, v in _one_candidate_a_name(kinds).items()}
-
-
-def _one_candidate_a_name(kinds: Dict[str, blocks.KindShard]
-                          ) -> Dict[str, blocks.KindShard]:
-    """``kinds`` with every set of names a candidate of ONE kind (as
-    models/lfm2_moe.py's, and for its reason: a checkpoint policy keeps a
-    NAME in every layer that has it, and latent attention's names are in
-    both kinds): each shared set goes to the kind applied most, at the bytes
-    and operations of all the layers that have it, spread over that kind's
-    applications."""
-    layers: Dict[Tuple[str, ...], Dict[str, blocks.RematCandidate]] = {}
-    for kind, shard in kinds.items():
-        for c in shard.candidates:
-            layers.setdefault(c.names, {})[kind] = c
-    kept: Dict[str, list] = {kind: [] for kind in kinds}
-    for names, by_kind in layers.items():
-        carrier = max(by_kind, key=lambda kind: kinds[kind].applications)
-        spread = [-(-sum(kinds[k].applications * c[field]
-                         for k, c in by_kind.items())
-                    // kinds[carrier].applications) for field in (1, 2, 3)]
-        kept[carrier].append(blocks.RematCandidate(names, *spread))
-    return {kind: shard._replace(candidates=tuple(kept[kind]))
-            for kind, shard in kinds.items()}
-
-
-def _sort_ops(n: int, operands: int) -> int:
-    """Operations of a sorting network over ``n`` keys (bitonic), each a
-    comparison and two selects an operand that moves."""
-    stages = math.log2(n) * (math.log2(n) + 1) / 2
-    return int(n / 2 * stages * (1 + 2 * operands))
-
-
-def _layer_bytes(cfg: DeepseekV2Config, kind: str) -> int:
-    """Bytes of one layer of ``kind``'s parameters, which its weight
-    gradients take again."""
-    layer = jax.eval_shape(
-        lambda: _layer_init(jax.random.PRNGKey(0), 1, kind, cfg))
-    return sum(math.prod(p.shape) * p.dtype.itemsize
-               for p in jax.tree.leaves(layer))
+    return base, blocks.with_grad_bytes(
+        blocks.one_candidate_a_name(kinds), partial(_layer_init, cfg=cfg), mesh)
 
 
 def _block_fns(cfg: DeepseekV2Config, batch: int, seq: int,
@@ -958,15 +873,10 @@ def step_counters(cfg: DeepseekV2Config) -> Optional[blocks.StepCounters]:
     """What ``loss_fn(..., counters=True)`` hands out of a step, or None for
     a pattern without an expert layer. A layer's id is ``model/expert_load``'s
     ``layer``: the published index."""
-    layers = _expert_layer_ids(cfg)
-    if not layers:
-        return None
-    return blocks.StepCounters(
-        scopes.EXPERT_LOAD_KIND, step_fields(cfg), layers,
-        partial(moe.step_load_static, n_experts=cfg.n_experts,
-                top_k=cfg.top_k, held=cfg.held),
-        float_fields=((scopes.STEP_BALANCE_LOSS,)
-                      if cfg.aux_loss_alpha > 0 else ()))
+    return parts.expert_step_counters(
+        _expert_layer_ids(cfg), cfg.n_experts, cfg.top_k, cfg.held,
+        step_fields(cfg),
+        (scopes.STEP_BALANCE_LOSS,) if cfg.aux_loss_alpha > 0 else ())
 
 
 def flops_per_token(cfg: DeepseekV2Config) -> float:
@@ -1009,24 +919,14 @@ def _expert_layers(pattern: str) -> List[int]:
     return [i for i, kind in enumerate(pattern) if EXPERTS[kind]]
 
 
-def _expert_aux(cfg: DeepseekV2Config, auxes) -> list:
-    """_hidden's auxes as one entry an expert layer, in order."""
-    out = []
-    for (sub, reps), aux in zip(_runs(cfg), auxes, strict=True):
-        for r in range(reps):
-            out += [jax.tree.map(lambda t: t[r], a) if reps > 1 else a
-                    for a in aux if a is not None]
-    return out
-
-
 def chosen_experts(params, tokens, cfg: DeepseekV2Config, targets=None
                    ) -> List[jax.Array]:
     """The set each token of ``tokens`` [B, S] chose in each expert layer, in
     the layers' order (the MTP module's last: it reads ``targets``): [B·S,
     n_experts] bool a layer. What a reference is told, so that a near-tie
     rounding flipped is not read as a wrong model."""
-    return _expert_aux(cfg, _hidden(params, tokens, targets, cfg,
-                                    "chosen")[3])
+    return blocks.aux_by_layer(
+        _runs(cfg), _hidden(params, tokens, targets, cfg, "chosen")[3])
 
 
 def _with_expert_leaf(params, cfg: DeepseekV2Config, name: str, rows):
@@ -1035,40 +935,12 @@ def _with_expert_leaf(params, cfg: DeepseekV2Config, name: str, rows):
     module's; a run's stack of ``E`` holds its layers in that order), in the
     old leaf's dtype."""
     rows = iter(rows)
-
-    def replaced(pattern, stacks):
-        out = []
-        for (sub, reps), group in zip(blocks.pattern_groups(pattern), stacks,
-                                      strict=True):
-            layers = (sub * reps).count("E")
-            if layers:
-                old = group["E"][name]
-                group = {**group, "E": {**group["E"], name: jnp.stack(
-                    [next(rows) for _ in range(layers)]).astype(old.dtype)}}
-            out.append(group)
-        return out
-
-    out = {**params, "blocks": replaced(cfg.pattern, params["blocks"])}
+    out = {**params, "blocks": blocks.with_leaf(
+        cfg.pattern, params["blocks"], name, rows)}
     if cfg.mtp_layers:
-        out["mtp"] = {**params["mtp"], "blocks": replaced(
-            cfg.mtp_pattern, params["mtp"]["blocks"])}
+        out["mtp"] = {**params["mtp"], "blocks": blocks.with_leaf(
+            cfg.mtp_pattern, params["mtp"]["blocks"], name, rows)}
     return out
-
-
-def _record_loads(cfg: DeepseekV2Config, loads) -> List[Dict[str, Any]]:
-    """The ``model/expert_load`` events (tracing/names.EXPERT_LOAD_ARGS;
-    ``layer`` is the published index) of ``loads``, one an expert layer in
-    order, recorded; and returned."""
-    component, name = scopes.EXPERT_LOAD.split("/")
-    events = []
-    for layer, load in zip(_expert_layer_ids(cfg), jax.device_get(loads),
-                           strict=True):
-        # (numpy scalars off the host: a count an int, a mean or share a float)
-        args = {"layer": layer, **{
-            k: load[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
-        get_buffer().record_profile(name, component=component, args=args)
-        events.append(args)
-    return events
 
 
 def _balanced(params, batches, cfg: DeepseekV2Config, leaf: str, rates):
@@ -1083,15 +955,16 @@ def _balanced(params, batches, cfg: DeepseekV2Config, leaf: str, rates):
 
     @jax.jit
     def one_round(p, batch, rate):
-        auxes = _expert_aux(cfg, _hidden(p, batch["tokens"], batch["targets"],
-                                         cfg, "balance", rate)[3])
+        auxes = blocks.aux_by_layer(_runs(cfg), _hidden(
+            p, batch["tokens"], batch["targets"], cfg, "balance", rate)[3])
         return [aux.pop(leaf) for aux in auxes], auxes
 
     balanced = params
     for r, rate in enumerate(rates):
         rows, loads = one_round(balanced, batches[r % len(batches)], rate)
         balanced = _with_expert_leaf(balanced, cfg, leaf, rows)
-    return balanced, _record_loads(cfg, loads)
+    return balanced, moe.record_expert_loads(_expert_layer_ids(cfg),
+                                             jax.device_get(loads))
 
 
 def _falling(first_rate: float) -> List[float]:

@@ -232,8 +232,7 @@ def init(cfg: GPT2Config, rng: jax.Array) -> Dict[str, Any]:
 
 
 def param_count(cfg: GPT2Config) -> int:
-    return sum(math.prod(p.shape) for p in jax.tree.leaves(
-        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))))
+    return parts.param_count(lambda: init(cfg, jax.random.PRNGKey(0)))
 
 
 # --------------------------------------------------------------------------- #

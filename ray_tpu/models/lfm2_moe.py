@@ -51,13 +51,12 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import blocks, parts
 from ray_tpu.ops import moe, short_conv
-from ray_tpu.tracing import get_buffer, names as scopes
+from ray_tpu.tracing import names as scopes
 
 KINDS = "DAC"
 # a kind's operator and feed-forward half
@@ -165,12 +164,6 @@ def _matmul_weights(kind: str) -> Tuple[str, ...]:
             + (moe.GATED_EXPERT if EXPERTS[kind] else _DENSE_WEIGHTS))
 
 
-def _group_counts(pattern: str):
-    """[{kind: layers of it}] a run of blocks.pattern_groups(pattern)."""
-    return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
-            for sub, reps in blocks.pattern_groups(pattern)]
-
-
 def _layer_init(rng, n: int, kind: str, cfg: LFM2MoEConfig):
     """``n`` stacked layers of ``kind``: the two pre-norms, the operator's
     tensors and the feed-forward half's."""
@@ -205,15 +198,8 @@ def _layer_init(rng, n: int, kind: str, cfg: LFM2MoEConfig):
 
 
 def _stack_init(rng, pattern: str, cfg: LFM2MoEConfig):
-    """The layers of ``pattern`` as blocks.run_pattern takes them: one entry a
-    run of the pattern, a kind's layers of the run stacked in their order."""
-    groups = _group_counts(pattern)
-    out = []
-    for counts, group_key in zip(groups, jax.random.split(rng, len(groups))):
-        keys = dict(zip(KINDS, jax.random.split(group_key, len(KINDS))))
-        out.append({kind: _layer_init(keys[kind], n, kind, cfg)
-                    for kind, n in counts.items()})
-    return out
+    return blocks.init_pattern(rng, pattern, KINDS,
+                               partial(_layer_init, cfg=cfg))
 
 
 _HEAD_AXES = ("layers", "embed", "heads", "kv")
@@ -265,25 +251,18 @@ def init(cfg: LFM2MoEConfig, rng: jax.Array) -> Dict[str, Any]:
             "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype)}
 
 
-def _is_buffer(path) -> bool:
-    return getattr(path[-1], "key", None) == "router_bias"
-
-
 def param_count(cfg: LFM2MoEConfig) -> int:
     """The parameters a step moves: every leaf but the expert layers'
     selection biases, which are buffers (the tied embedding once)."""
-    shapes = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
-    return sum(int(np.prod(p.shape)) for path, p in
-               jax.tree_util.tree_leaves_with_path(shapes)
-               if not _is_buffer(path))
+    return parts.param_count(lambda: init(cfg, jax.random.PRNGKey(0)),
+                             "router_bias")
 
 
 def decays(params):
     """Which leaves an optimizer's weight decay may touch (optax's ``mask``):
     all but the selection biases — no gradient reaches them, and a decay must
     not."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: not _is_buffer(path), params)
+    return parts.all_but(params, "router_bias")
 
 
 # --------------------------------------------------------------------------- #
@@ -341,28 +320,18 @@ def _swiglu(x, p, cfg: LFM2MoEConfig):
     """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]."""
     with jax.named_scope(scopes.LN2):
         h = parts.rmsnorm(x, p["ffn_norm"], cfg.rms_eps)
+    y = parts.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
     with jax.named_scope(scopes.MLP):
-        gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
-                               scopes.RES_MLP_GATE)
-        up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
-                             scopes.RES_MLP_UP)
-        return parts.residual_add(x, jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
-            preferred_element_type=jnp.float32))
+        return parts.residual_add(x, y)
 
 
 def _dense(x, p, cfg: LFM2MoEConfig):
-    """The dense feed-forward half, norm and all; where one hidden tensor of
-    the whole sequence would pass parts.MLP_CHUNK_BYTES the sequence goes in
-    chunks (parts.mlp_rows), each its own ``checkpoint`` — as the llama
-    block's does, and why (models/llama.py)."""
-    B, S, D = x.shape
-    rows = parts.mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
-    if rows == S:
-        return _swiglu(x, p, cfg)
-    chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
-    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg)), chunks)
-    return out.swapaxes(0, 1).reshape(B, S, D)
+    """The dense feed-forward half, norm and all, in chunks of the sequence
+    where parts.mlp_rows says so — as the llama block's, and why
+    (models/llama.py)."""
+    return parts.in_row_chunks(
+        partial(_swiglu, p=p, cfg=cfg), x,
+        parts.mlp_rows(*x.shape, cfg.d_ff, x.dtype.itemsize))
 
 
 def _routing(cfg: LFM2MoEConfig) -> Dict[str, Any]:
@@ -447,32 +416,16 @@ def kind_shards(cfg: LFM2MoEConfig, global_batch: int, seq: int, mesh
     attn_set = a * (tokens * (4 * D + 4 * width)
                     + 2 * 2 * D * (width + kv_width))
 
-    # the feed-forward halves. Dense: its hidden tensors where it is not
-    # chunked; a chunk's five of them, the weights cast twice. Experts: what
-    # the routing decided (ops/moe.py tags them, priced as the Nemotron
-    # family prices them); its backward holds the experts' input and float32
-    # sum, three [tokens, n_experts] tensors of the routing, one pass's rows
-    # (input, two hidden tensors, their product, the gradients of each), the
-    # weights cast and the float32 sums their gradients are made in
-    dense_kept = tuple(
-        C((name,), tokens * F * a, 2 * tokens * D * F)
-        for name in base.mlp_hidden) if base.mlp_rows in (0, base.seq) else ()
-    dense_set = a * (base.batch * (base.mlp_rows or base.seq) * 5 * F
-                     + 2 * 3 * D * F)
-    rows = moe.row_buffer(tokens, cfg.n_experts, cfg.top_k, cfg.held_count)
-    passes = moe.buffer_passes(tokens, cfg.n_experts, cfg.top_k,
-                               cfg.held_count)
-    experts_kept = (
-        C((scopes.RES_MOE_SCORES,), tokens * cfg.n_experts * 4,
-          3 * 2 * tokens * D * cfg.n_experts),
-        C((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
-          tokens * _sort_ops(cfg.n_experts, operands=2)),
-        C((scopes.RES_MOE_PAIR_KEY, scopes.RES_MOE_PAIR_GATE),
-          passes * rows * 8, _sort_ops(tokens * cfg.held_count, operands=2)))
-    expert_weights = 3 * cfg.held_count * D * Fe
-    experts_set = (tokens * D * (2 * a + 8) + tokens * cfg.n_experts * 12
-                   + a * rows * (2 * D + 6 * Fe)
-                   + (a + 4) * expert_weights)
+    # the feed-forward halves. Dense: parts.swiglu_price. Experts: what the
+    # routing decided (parts.routing_candidates), and in their backward the
+    # half's stream and the routed passes' set (parts.gated_experts_working_set)
+    # together
+    dense_kept, dense_set = parts.swiglu_price(
+        base.batch, base.seq, base.mlp_rows, D, F, a, base.mlp_hidden)
+    experts_kept = parts.routing_candidates(
+        tokens, D, cfg.n_experts, cfg.top_k, cfg.held_count)
+    experts_set = sum(parts.gated_experts_working_set(
+        tokens, D, cfg.n_experts, cfg.top_k, cfg.held_count, Fe, a))
 
     kinds = {}
     for kind in dict.fromkeys(cfg.pattern):
@@ -482,52 +435,8 @@ def kind_shards(cfg: LFM2MoEConfig, global_batch: int, seq: int, mesh
                            else (dense_kept, dense_set))
         kinds[kind] = blocks.KindShard(
             cfg.pattern.count(kind), op_kept + ff_kept, op_set + ff_set)
-    chips = mesh.devices.size if mesh is not None else 1
-    return base, {k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
-                  for k, v in _one_candidate_a_name(kinds).items()}
-
-
-def _one_candidate_a_name(kinds: Dict[str, blocks.KindShard]
-                          ) -> Dict[str, blocks.KindShard]:
-    """``kinds`` with every set of names a candidate of ONE kind. The kinds
-    here share halves, and a checkpoint policy keeps a NAME — in every layer
-    that has it, whichever kind's candidate the rule took: the operator's
-    ``block_mid`` is in all three kinds, the routing's names in two. Each
-    shared set goes to the kind applied most, at the bytes and operations of
-    all the layers that have it, spread over that kind's applications — so
-    the rule takes or leaves it once, for what it really costs."""
-    layers: Dict[Tuple[str, ...], Dict[str, blocks.RematCandidate]] = {}
-    for kind, shard in kinds.items():
-        for c in shard.candidates:
-            layers.setdefault(c.names, {})[kind] = c
-    kept: Dict[str, list] = {kind: [] for kind in kinds}
-    for names, by_kind in layers.items():
-        carrier = max(by_kind, key=lambda kind: kinds[kind].applications)
-        # (bytes, operations, bytes freed) over all the layers that have the
-        # names, an application of the carrier
-        spread = [-(-sum(kinds[k].applications * c[field]
-                         for k, c in by_kind.items())
-                    // kinds[carrier].applications) for field in (1, 2, 3)]
-        kept[carrier].append(blocks.RematCandidate(names, *spread))
-    return {kind: shard._replace(candidates=tuple(kept[kind]))
-            for kind, shard in kinds.items()}
-
-
-def _sort_ops(n: int, operands: int) -> int:
-    """Operations of a sorting network over ``n`` keys (bitonic: log2(n) ·
-    (log2(n) + 1) / 2 stages of n / 2 compare-exchanges), each a comparison
-    and two selects an operand that moves."""
-    stages = math.log2(n) * (math.log2(n) + 1) / 2
-    return int(n / 2 * stages * (1 + 2 * operands))
-
-
-def _layer_bytes(cfg: LFM2MoEConfig, kind: str) -> int:
-    """Bytes of one layer of ``kind``'s parameters, which its weight
-    gradients take again."""
-    layer = jax.eval_shape(
-        lambda: _layer_init(jax.random.PRNGKey(0), 1, kind, cfg))
-    return sum(math.prod(p.shape) * p.dtype.itemsize
-               for p in jax.tree.leaves(layer))
+    return base, blocks.with_grad_bytes(
+        blocks.one_candidate_a_name(kinds), partial(_layer_init, cfg=cfg), mesh)
 
 
 def _block_fns(cfg: LFM2MoEConfig, batch: int, seq: int,
@@ -586,14 +495,8 @@ def step_counters(cfg: LFM2MoEConfig) -> Optional[blocks.StepCounters]:
     """What ``loss_fn(..., counters=True)`` hands out of a step, or None for
     a pattern without an expert layer. A layer's id is ``model/expert_load``'s
     ``layer``: the published index."""
-    layers = tuple(cfg.first_layer + index
-                   for index, _, _ in _expert_layers(cfg.pattern))
-    if not layers:
-        return None
-    return blocks.StepCounters(
-        scopes.EXPERT_LOAD_KIND, scopes.STEP_EXPERT_LOAD_ARGS, layers,
-        partial(moe.step_load_static, n_experts=cfg.n_experts,
-                top_k=cfg.top_k, held=cfg.held))
+    return parts.expert_step_counters(_expert_layer_ids(cfg), cfg.n_experts,
+                                      cfg.top_k, cfg.held)
 
 
 def flops_per_token(cfg: LFM2MoEConfig) -> float:
@@ -622,35 +525,18 @@ def flops_per_token(cfg: LFM2MoEConfig) -> float:
 # The selection bias, balanced at set-up; what each token chose
 # --------------------------------------------------------------------------- #
 
-def _expert_layers(pattern: str) -> List[Tuple[int, str, int]]:
-    """The expert layers in the order they come: (index in the pattern, run
-    of blocks.pattern_groups, kind). A run's stack of a kind holds its
-    layers in this order."""
-    out, index = [], 0
-    for g, (sub, reps) in enumerate(blocks.pattern_groups(pattern)):
-        for kind in sub * reps:
-            if EXPERTS[kind]:
-                out.append((index, g, kind))
-            index += 1
-    return out
-
-
-def _expert_aux(pattern: str, auxes) -> list:
-    """blocks.run_pattern's auxes as one entry an expert layer, in order."""
-    out = []
-    for (sub, reps), aux in zip(blocks.pattern_groups(pattern), auxes,
-                                strict=True):
-        for r in range(reps):
-            out += [jax.tree.map(lambda t: t[r], a) if reps > 1 else a
-                    for a in aux if a is not None]
-    return out
+def _expert_layer_ids(cfg: LFM2MoEConfig) -> Tuple[int, ...]:
+    """The published index of every expert layer, in the order they come."""
+    return tuple(cfg.first_layer + i for i, kind in enumerate(cfg.pattern)
+                 if EXPERTS[kind])
 
 
 def chosen_experts(params, tokens, cfg: LFM2MoEConfig) -> List[jax.Array]:
     """The set each token of ``tokens`` [B, S] chose in each expert layer, in
     the layers' order: [B·S, n_experts] bool a layer. What a reference is
     told, so that a near-tie rounding flipped is not read as a wrong model."""
-    return _expert_aux(cfg.pattern, _trunk(params, tokens, cfg, "chosen")[1])
+    return blocks.aux_by_layer(blocks.pattern_groups(cfg.pattern),
+                               _trunk(params, tokens, cfg, "chosen")[1])
 
 
 def balance_router_bias(params, tokens, cfg: LFM2MoEConfig):
@@ -664,21 +550,8 @@ def balance_router_bias(params, tokens, cfg: LFM2MoEConfig):
     recorded here. For set-up, on the first batch."""
     auxes = jax.device_get(jax.jit(
         lambda p, tok: _trunk(p, tok, cfg, "balance")[1])(params, tokens))
-    stacks = [dict(group) for group in params["blocks"]]
-    biases: Dict[Tuple[int, str], list] = {}
-    component, name = scopes.EXPERT_LOAD.split("/")
-    events = []
-    for (index, g, kind), aux in zip(_expert_layers(cfg.pattern),
-                                     _expert_aux(cfg.pattern, auxes),
-                                     strict=True):
-        biases.setdefault((g, kind), []).append(aux.pop("router_bias"))
-        # (numpy scalars off the host: a count an int, a mean or share a float)
-        args = {"layer": cfg.first_layer + index, **{
-            k: aux[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
-        get_buffer().record_profile(name, component=component, args=args)
-        events.append(args)
-    for (g, kind), rows in biases.items():
-        old = stacks[g][kind]["router_bias"]
-        stacks[g][kind] = {**stacks[g][kind], "router_bias": jax.device_put(
-            np.stack(rows), old.sharding)}
-    return {**params, "blocks": stacks}, events
+    loads = blocks.aux_by_layer(blocks.pattern_groups(cfg.pattern), auxes)
+    biases = iter([load.pop("router_bias") for load in loads])
+    return ({**params, "blocks": blocks.with_leaf(
+        cfg.pattern, params["blocks"], "router_bias", biases)},
+        moe.record_expert_loads(_expert_layer_ids(cfg), loads))

@@ -32,7 +32,6 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import parts
@@ -221,8 +220,7 @@ def init(cfg: LlamaConfig, rng: jax.Array) -> Dict[str, Any]:
 
 
 def param_count(cfg: LlamaConfig) -> int:
-    return sum(math.prod(p.shape) for p in jax.tree.leaves(
-        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))))
+    return parts.param_count(lambda: init(cfg, jax.random.PRNGKey(0)))
 
 
 # --------------------------------------------------------------------------- #
@@ -291,35 +289,27 @@ def _swiglu(x, p, cfg: LlamaConfig):
     """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]."""
     with jax.named_scope(scopes.LN2):
         h = _norm(x, p["mlp_norm"], cfg)
+    y = parts.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
     with jax.named_scope(scopes.MLP):
-        gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
-                               scopes.RES_MLP_GATE)
-        up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
-                             scopes.RES_MLP_UP)
-        return parts.residual_add(x, jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
-            preferred_element_type=jnp.float32))
+        return parts.residual_add(x, y)
 
 
 def _mlp(x, p, cfg: LlamaConfig):
     """The block's second half, norm and all. Where one hidden tensor of the
     whole sequence would pass parts.MLP_CHUNK_BYTES the sequence goes in
-    chunks (parts.mlp_rows), each its own ``checkpoint``: a chunk's hidden
-    tensors are made again in its backward, never exist for the whole sequence
-    (nor can a remat policy keep them: llama.block_shard tells the rule so).
+    chunks (parts.mlp_rows, parts.in_row_chunks), each its own ``checkpoint``:
+    a chunk's hidden tensors are made again in its backward, never exist for
+    the whole sequence (nor can a remat policy keep them: llama.block_shard
+    tells the rule so).
     The norm is the chunk's too — a row's norm needs the row alone — so the
     loop's one input is the stream itself: the normed stream and its gradient
     never stand whole beside it. That is 0.5 GB of the 32,768-byte EvaByte
     step's peak, which falls in this loop's backward; with it, and k kept
     where q was, the step fits without the compiler making k and v a second
     time in every layer (PERF.md §6, PR 32)."""
-    B, S, D = x.shape
-    rows = parts.mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
-    if rows == S:
-        return _swiglu(x, p, cfg)
-    chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
-    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg)), chunks)
-    return out.swapaxes(0, 1).reshape(B, S, D)
+    return parts.in_row_chunks(
+        partial(_swiglu, p=p, cfg=cfg), x,
+        parts.mlp_rows(*x.shape, cfg.d_ff, x.dtype.itemsize))
 
 
 def block_shard(cfg: LlamaConfig, global_batch: int, seq: int,

@@ -48,8 +48,6 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import blocks, parts
@@ -144,12 +142,6 @@ def minicpm_sala_tiny(**overrides) -> MiniCPMSALAConfig:
 _MATMUL_WEIGHTS = ("wq", "wk", "wv", "wg", "wo", "w_gate", "w_up", "w_down")
 
 
-def _group_counts(pattern: str):
-    """[{kind: layers of it}] a run of blocks.pattern_groups(pattern)."""
-    return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
-            for sub, reps in blocks.pattern_groups(pattern)]
-
-
 def _heads(cfg: MiniCPMSALAConfig, kind: str) -> Tuple[int, int]:
     """(query heads, key-value heads) a layer of ``kind`` holds here."""
     if kind == "L":
@@ -180,18 +172,6 @@ def _layer_init(rng, n: int, kind: str, cfg: MiniCPMSALAConfig):
     return p
 
 
-def _stack_init(rng, pattern: str, cfg: MiniCPMSALAConfig):
-    """The layers of ``pattern`` as blocks.run_pattern takes them: one entry a
-    run of the pattern, a kind's layers of the run stacked in their order."""
-    groups = _group_counts(pattern)
-    out = []
-    for counts, group_key in zip(groups, jax.random.split(rng, len(groups))):
-        keys = dict(zip(KINDS, jax.random.split(group_key, len(KINDS))))
-        out.append({kind: _layer_init(keys[kind], n, kind, cfg)
-                    for kind, n in counts.items()})
-    return out
-
-
 _HEAD_AXES = ("layers", "embed", "heads", "kv")
 _LAYER_AXES = {
     "norm": ("layers", "embed"), "wq": _HEAD_AXES, "wk": _HEAD_AXES,
@@ -211,7 +191,7 @@ def logical_axes(cfg: MiniCPMSALAConfig) -> Dict[str, Any]:
 
     return {"wte": ("vocab", "embed"),
             "blocks": [{kind: axes(kind) for kind in counts}
-                       for counts in _group_counts(cfg.pattern)],
+                       for counts in blocks.group_counts(cfg.pattern)],
             "final_norm": ("embed",), "lm_head": ("embed", "vocab")}
 
 
@@ -237,14 +217,14 @@ def init(cfg: MiniCPMSALAConfig, rng: jax.Array) -> Dict[str, Any]:
         return (jax.random.normal(key, shape) * INIT_STD).astype(pd)
 
     return {"wte": normal(k[0], (V, D)),
-            "blocks": _stack_init(k[1], cfg.pattern, cfg),
+            "blocks": blocks.init_pattern(k[1], cfg.pattern, KINDS,
+                                          partial(_layer_init, cfg=cfg)),
             "final_norm": jnp.ones((D,), pd),
             "lm_head": normal(k[2], (D, V))}
 
 
 def param_count(cfg: MiniCPMSALAConfig) -> int:
-    return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(
-        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))))
+    return parts.param_count(lambda: init(cfg, jax.random.PRNGKey(0)))
 
 
 # --------------------------------------------------------------------------- #
@@ -370,28 +350,18 @@ def _swiglu(x, p, cfg: MiniCPMSALAConfig):
     [B, rows, D]."""
     with jax.named_scope(scopes.LN2):
         h = parts.rmsnorm(x, p["mlp_norm"], cfg.rms_eps)
+    y = parts.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
     with jax.named_scope(scopes.MLP):
-        gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
-                               scopes.RES_MLP_GATE)
-        up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, p["w_up"]),
-                             scopes.RES_MLP_UP)
-        return _residual(x, jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
-            preferred_element_type=jnp.float32), cfg)
+        return _residual(x, y, cfg)
 
 
 def _mlp(x, p, cfg: MiniCPMSALAConfig):
-    """The layer's second half, norm and all; where one hidden tensor of the
-    whole sequence would pass parts.MLP_CHUNK_BYTES the sequence goes in
-    chunks (parts.mlp_rows), each its own ``checkpoint`` — as the llama
-    block's does, and why (models/llama.py)."""
-    B, S, D = x.shape
-    rows = parts.mlp_rows(B, S, D, cfg.d_ff, x.dtype.itemsize)
-    if rows == S:
-        return _swiglu(x, p, cfg)
-    chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
-    out = lax.map(jax.checkpoint(partial(_swiglu, p=p, cfg=cfg)), chunks)
-    return out.swapaxes(0, 1).reshape(B, S, D)
+    """The layer's second half, norm and all, in chunks of the sequence
+    where parts.mlp_rows says so — as the llama block's, and why
+    (models/llama.py)."""
+    return parts.in_row_chunks(
+        partial(_swiglu, p=p, cfg=cfg), x,
+        parts.mlp_rows(*x.shape, cfg.d_ff, x.dtype.itemsize))
 
 
 @jax.named_scope(scopes.BLOCK)
@@ -473,19 +443,8 @@ def kind_shards(cfg: MiniCPMSALAConfig, global_batch: int, seq: int, mesh
         kinds[kind] = blocks.KindShard(
             cfg.pattern.count(kind), tuple(kept),
             parts.block_working_set(s) + extra)
-    chips = mesh.devices.size if mesh is not None else 1
-    return shard(cfg.pattern[0]), {
-        k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
-        for k, v in kinds.items()}
-
-
-def _layer_bytes(cfg: MiniCPMSALAConfig, kind: str) -> int:
-    """Bytes of one layer of ``kind``'s parameters, which its weight
-    gradients take again."""
-    layer = jax.eval_shape(
-        lambda: _layer_init(jax.random.PRNGKey(0), 1, kind, cfg))
-    return sum(math.prod(p.shape) * p.dtype.itemsize
-               for p in jax.tree.leaves(layer))
+    return shard(cfg.pattern[0]), blocks.with_grad_bytes(
+        kinds, partial(_layer_init, cfg=cfg), mesh)
 
 
 def _block_fns(cfg: MiniCPMSALAConfig, batch: int, seq: int):
@@ -536,13 +495,8 @@ def chosen_blocks(params, tokens, cfg: MiniCPMSALAConfig) -> List[jax.Array]:
     layer, in the layers' order: int32 [B, n_kv_head, S, top] a layer (none
     where the rows are short enough for plain attention). What a reference is
     told, so that a tie rounding flipped is not read as a wrong model."""
-    _, aux = _trunk(params, tokens, cfg, with_ids=True)
-    out = []
-    for (sub, reps), layers in zip(blocks.pattern_groups(cfg.pattern), aux):
-        for r in range(reps):
-            out += [ids[r] if reps > 1 else ids for ids in layers
-                    if ids is not None]
-    return out
+    return blocks.aux_by_layer(blocks.pattern_groups(cfg.pattern),
+                               _trunk(params, tokens, cfg, with_ids=True)[1])
 
 
 def flops_per_token(cfg: MiniCPMSALAConfig) -> float:

@@ -40,12 +40,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import blocks, parts
 from ray_tpu.ops import mamba2, moe
-from ray_tpu.tracing import get_buffer, names as scopes
+from ray_tpu.tracing import names as scopes
 
 KINDS = "ME*"        # Mamba-2 mixer, LatentMoE layer, attention
 INIT_STD = 0.02      # every matrix; the three out-projections rescaled (init)
@@ -146,12 +145,6 @@ def nemotron_h_tiny(**overrides) -> NemotronHConfig:
 # Parameters
 # --------------------------------------------------------------------------- #
 
-def _group_counts(pattern: str):
-    """[{kind: layers of it}] a run of blocks.pattern_groups(pattern)."""
-    return [{kind: reps * sub.count(kind) for kind in dict.fromkeys(sub)}
-            for sub, reps in blocks.pattern_groups(pattern)]
-
-
 def _attn_init(rng, n: int, cfg: NemotronHConfig, out_std: float):
     D, H, KH, hd = cfg.d_model, cfg.n_head, cfg.n_kv_head, cfg.head_dim
     k = jax.random.split(rng, 4)
@@ -172,41 +165,35 @@ _ATTN_AXES = {"wq": ("layers", "embed", "heads", "kv"),
 _ATTN_WEIGHTS = ("wq", "wk", "wv", "wo")
 
 
-def _stack_init(rng, pattern: str, cfg: NemotronHConfig):
-    """The layers of ``pattern`` as blocks.run_pattern takes them: one entry a
-    run of the pattern, a kind's layers of the run stacked in the order they
-    come; every kind's layer has its pre-norm ``norm``."""
+def _layer_init(rng, n: int, kind: str, cfg: NemotronHConfig):
+    """``n`` stacked layers of ``kind``: the kind's own tensors and its
+    pre-norm ``norm``."""
     # rescale_prenorm_residual: the three out-projections by 1/sqrt(2·layers)
     out_std = INIT_STD / math.sqrt(2 * cfg.n_layer_published)
-    groups = _group_counts(pattern)
-    out = []
-    for counts, group_key in zip(groups, jax.random.split(rng, len(groups))):
-        keys = dict(zip(KINDS, jax.random.split(group_key, len(KINDS))))
-        group = {}
-        for kind, n in counts.items():
-            if kind == "M":
-                p = mamba2.mamba2_init(
-                    keys[kind], n, cfg.d_model, cfg.mamba_heads,
-                    cfg.mamba_head_dim, cfg.mamba_groups, cfg.ssm_state,
-                    cfg.conv_kernel, INIT_STD, out_std, cfg.param_dtype)
-            elif kind == "E":
-                p = moe.latent_moe_init(
-                    keys[kind], n, cfg.d_model, cfg.n_experts, cfg.held_count,
-                    cfg.latent, cfg.d_expert, cfg.d_shared, INIT_STD, out_std,
-                    cfg.param_dtype)
-            else:
-                p = _attn_init(keys[kind], n, cfg, out_std)
-            group[kind] = {**p,
-                           "norm": jnp.ones((n, cfg.d_model), cfg.param_dtype)}
-        out.append(group)
-    return out
+    if kind == "M":
+        p = mamba2.mamba2_init(
+            rng, n, cfg.d_model, cfg.mamba_heads, cfg.mamba_head_dim,
+            cfg.mamba_groups, cfg.ssm_state, cfg.conv_kernel, INIT_STD,
+            out_std, cfg.param_dtype)
+    elif kind == "E":
+        p = moe.latent_moe_init(
+            rng, n, cfg.d_model, cfg.n_experts, cfg.held_count, cfg.latent,
+            cfg.d_expert, cfg.d_shared, INIT_STD, out_std, cfg.param_dtype)
+    else:
+        p = _attn_init(rng, n, cfg, out_std)
+    return {**p, "norm": jnp.ones((n, cfg.d_model), cfg.param_dtype)}
+
+
+def _stack_init(rng, pattern: str, cfg: NemotronHConfig):
+    return blocks.init_pattern(rng, pattern, KINDS,
+                               partial(_layer_init, cfg=cfg))
 
 
 def _stack_axes(pattern: str):
     axes = {"M": mamba2.mamba2_logical_axes(),
             "E": moe.latent_moe_logical_axes(), "*": _ATTN_AXES}
     return [{kind: {**axes[kind], "norm": ("layers", "embed")}
-             for kind in counts} for counts in _group_counts(pattern)]
+             for kind in counts} for counts in blocks.group_counts(pattern)]
 
 
 def logical_axes(cfg: NemotronHConfig) -> Dict[str, Any]:
@@ -258,17 +245,14 @@ def init(cfg: NemotronHConfig, rng: jax.Array) -> Dict[str, Any]:
 
 
 def param_count(cfg: NemotronHConfig) -> int:
-    return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(
-        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))))
+    return parts.param_count(lambda: init(cfg, jax.random.PRNGKey(0)))
 
 
 def decays(params):
     """Which leaves an optimizer's weight decay may touch (optax's ``mask``):
     all but the expert layers' selection biases, which are buffers — no
     gradient reaches them, and a decay must not."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: getattr(path[-1], "key", None) != "router_bias",
-        params)
+    return parts.all_but(params, "router_bias")
 
 
 # --------------------------------------------------------------------------- #
@@ -276,13 +260,10 @@ def decays(params):
 # --------------------------------------------------------------------------- #
 
 def _shared_rows(cfg: NemotronHConfig, batch: int, seq: int) -> int:
-    """Rows of the sequence the shared expert takes at a time (parts.mlp_rows
-    for an MLP with one hidden tensor of d_shared)."""
-    a = jnp.dtype(cfg.dtype).itemsize
-    if batch * seq * cfg.d_shared * a <= parts.MLP_CHUNK_BYTES:
-        return seq
-    return parts.rows_under(seq, 3 * batch * cfg.d_shared * a,
-                            2 * batch * seq * cfg.d_model * a)
+    """Rows of the sequence the shared expert takes at a time: an MLP with
+    one hidden tensor of d_shared."""
+    return parts.mlp_rows(batch, seq, cfg.d_model, cfg.d_shared,
+                          jnp.dtype(cfg.dtype).itemsize, hidden_tensors=3)
 
 
 @jax.named_scope(scopes.BLOCK)
@@ -382,24 +363,14 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
         + chunks * H * P * N * 4 + 2 * tokens * inner * 4
         + 2 * a * D * (2 * inner + conv_dim))
 
-    # E: the latent input; the shared expert's hidden where it is not chunked;
-    # and what the routing decided (ops/moe.py tags them) — the scores at
-    # three bf16 passes of the router's float32 product; the `top_k`'s last
-    # value and index at a full sort of each row's n_experts with an index
-    # operand (what the TPU lowers it to) and the pairs' sorted keys with their
-    # gates at theirs (_sort_ops): a MB or two that spare a sort rank first
+    # E: the latent input; what the routing decided (parts.routing_candidates);
+    # the shared expert's hidden where it is not chunked
     rows = moe.row_buffer(tokens, cfg.n_experts, cfg.top_k, cfg.held_count)
-    passes = moe.buffer_passes(tokens, cfg.n_experts, cfg.top_k,
-                               cfg.held_count)
     experts_kept = [
         C((scopes.RES_MOE_LATENT,), tokens * cfg.latent * a,
           2 * tokens * D * cfg.latent),
-        C((scopes.RES_MOE_SCORES,), tokens * cfg.n_experts * 4,
-          3 * 2 * tokens * D * cfg.n_experts),
-        C((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
-          tokens * _sort_ops(cfg.n_experts, operands=2)),
-        C((scopes.RES_MOE_PAIR_KEY, scopes.RES_MOE_PAIR_GATE),
-          passes * rows * 8, _sort_ops(tokens * cfg.held_count, operands=2))]
+        *parts.routing_candidates(tokens, D, cfg.n_experts, cfg.top_k,
+                                  cfg.held_count)]
     if base.mlp_rows in (0, base.seq):
         experts_kept.append(C((scopes.RES_MOE_SHARED_HIDDEN,),
                               tokens * base.d_ff * a,
@@ -423,28 +394,9 @@ def kind_shards(cfg: NemotronHConfig, global_batch: int, seq: int, mesh
     ), a * tokens * (4 * D + 4 * base.heads * base.head_dim)
         + 2 * a * D * 2 * (base.heads + base.kv_heads) * base.head_dim)
     kinds = {"M": mamba, "E": experts, "*": attn}
-    chips = mesh.devices.size if mesh is not None else 1
-    return base, {k: v._replace(grad_bytes=_layer_bytes(cfg, k) // chips)
-                  for k, v in kinds.items() if v.applications}
-
-
-def _sort_ops(n: int, operands: int) -> int:
-    """Operations of a sorting network over ``n`` keys (bitonic: log2(n) ·
-    (log2(n) + 1) / 2 stages of n / 2 compare-exchanges), each a comparison
-    and two selects an operand that moves."""
-    stages = math.log2(n) * (math.log2(n) + 1) / 2
-    return int(n / 2 * stages * (1 + 2 * operands))
-
-
-def _layer_bytes(cfg: NemotronHConfig, kind: str) -> int:
-    """Bytes of one layer of ``kind``'s parameters, which its weight
-    gradients take again. A chip of a mesh holds no less than its even share
-    (kind_shards divides by the chips: the rule takes gradients that do not
-    exist yet OFF what is resident, so the least is the safe figure)."""
-    layer = jax.eval_shape(
-        lambda: _stack_init(jax.random.PRNGKey(0), kind, cfg))
-    return sum(math.prod(p.shape) * p.dtype.itemsize
-               for p in jax.tree.leaves(layer))
+    return base, blocks.with_grad_bytes(
+        {k: v for k, v in kinds.items() if v.applications},
+        partial(_layer_init, cfg=cfg), mesh)
 
 
 def _block_fns(cfg: NemotronHConfig, batch: int, seq: int,
@@ -486,16 +438,12 @@ def _hidden(params, tokens, targets, cfg: NemotronHConfig,
     if not cfg.mtp_pattern:
         return x, None, None, (aux, None)
     with jax.named_scope(scopes.MTP):
-        h, mtp_targets = _mtp_input(params, x, targets, wte, cfg)
-        h, mtp_aux = run(cfg.mtp_pattern, h, params["mtp"]["blocks"])
+        mtp = params["mtp"]
+        h, mtp_targets = parts.mtp_join(x, targets, wte, mtp["enorm"],
+                                        mtp["hnorm"], mtp["eh_proj"],
+                                        cfg.rms_eps)
+        h, mtp_aux = run(cfg.mtp_pattern, h, mtp["blocks"])
     return x, h, mtp_targets, (aux, mtp_aux)
-
-
-def _mtp_input(params, x, targets, wte, cfg: NemotronHConfig):
-    """The MTP module's input and targets (parts.mtp_join)."""
-    mtp = params["mtp"]
-    return parts.mtp_join(x, targets, wte, mtp["enorm"], mtp["hnorm"],
-                          mtp["eh_proj"], cfg.rms_eps)
 
 
 def _final_norm(x, params, cfg):
@@ -550,14 +498,9 @@ def step_counters(cfg: NemotronHConfig) -> Optional[blocks.StepCounters]:
     a pattern without an expert layer. A layer's id is ``model/expert_load``'s
     ``layer``: its place among the expert layers, the MTP module's after the
     trunk's."""
-    layers = (cfg.pattern + cfg.mtp_pattern).count("E")
-    if not layers:
-        return None
-    return blocks.StepCounters(
-        scopes.EXPERT_LOAD_KIND, scopes.STEP_EXPERT_LOAD_ARGS,
-        tuple(range(layers)),
-        partial(moe.step_load_static, n_experts=cfg.n_experts,
-                top_k=cfg.top_k, held=cfg.held))
+    return parts.expert_step_counters(
+        range((cfg.pattern + cfg.mtp_pattern).count("E")), cfg.n_experts,
+        cfg.top_k, cfg.held)
 
 
 def flops_per_token(cfg: NemotronHConfig) -> float:
@@ -593,27 +536,6 @@ def flops_per_token(cfg: NemotronHConfig) -> float:
 # The selection bias, balanced at set-up; what a batch sends the held experts
 # --------------------------------------------------------------------------- #
 
-def _balanced(pattern: str, stacks, auxes):
-    """``stacks`` with the balanced biases of ``auxes`` (run_pattern's, on the
-    host) in place, and the expert layers' loads in the order they come."""
-    out, loads = [], []
-    for (sub, reps), group, aux in zip(blocks.pattern_groups(pattern), stacks,
-                                       auxes, strict=True):
-        layers = [a for a in aux if a is not None]   # the sub-pattern's E's
-        if layers:
-            # a leaf [reps, …] (a scan's) or […] a layer of the sub-pattern →
-            # [reps · layers, …], the order the run's stack has
-            n = reps * len(layers)
-            flat = {k: np.stack([np.reshape(a[k], (reps, -1)) for a in layers],
-                                axis=1).reshape(n, -1) for k in layers[0]}
-            old = group["E"]["router_bias"]
-            group = {**group, "E": {**group["E"], "router_bias": jax.device_put(
-                flat.pop("router_bias"), old.sharding)}}
-            loads += [{k: v[i, 0] for k, v in flat.items()} for i in range(n)]
-        out.append(group)
-    return out, loads
-
-
 def balance_router_bias(params, tokens, targets, cfg: NemotronHConfig):
     """(``params`` with every expert layer's selection bias balanced on this
     batch, what the batch then sends the experts held here). The bias's
@@ -626,18 +548,13 @@ def balance_router_bias(params, tokens, targets, cfg: NemotronHConfig):
     trunk, mtp = jax.device_get(jax.jit(
         lambda p, tok, tgt: _hidden(p, tok, tgt, cfg, "balance")[3])(
         params, tokens, targets))
-    stacks, loads = _balanced(cfg.pattern, params["blocks"], trunk)
-    params = {**params, "blocks": stacks}
+    loads = blocks.aux_by_layer(
+        blocks.pattern_groups(cfg.pattern)
+        + blocks.pattern_groups(cfg.mtp_pattern), trunk + (mtp or []))
+    biases = iter([load.pop("router_bias") for load in loads])
+    params = {**params, "blocks": blocks.with_leaf(
+        cfg.pattern, params["blocks"], "router_bias", biases)}
     if cfg.mtp_pattern:
-        stacks, more = _balanced(cfg.mtp_pattern, params["mtp"]["blocks"], mtp)
-        params = {**params, "mtp": {**params["mtp"], "blocks": stacks}}
-        loads += more
-    component, name = scopes.EXPERT_LOAD.split("/")
-    events = []
-    for layer, load in enumerate(loads):
-        # (numpy scalars off the host: a count an int, a mean or share a float)
-        args = {"layer": layer, **{
-            k: load[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
-        get_buffer().record_profile(name, component=component, args=args)
-        events.append(args)
-    return params, events
+        params["mtp"] = {**params["mtp"], "blocks": blocks.with_leaf(
+            cfg.mtp_pattern, params["mtp"]["blocks"], "router_bias", biases)}
+    return params, moe.record_expert_loads(range(len(loads)), loads)

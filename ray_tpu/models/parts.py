@@ -12,16 +12,19 @@ file imports both and no other model.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.blocks import (KindShard, RematCandidate, RematPolicy,
-                                   checkpoint_kinds, choose_remat_policy_kinds,
-                                   model_working_set)
+                                   StepCounters, checkpoint_kinds,
+                                   choose_remat_policy_kinds, model_working_set)
+from ray_tpu.ops import moe
 from ray_tpu.tracing import names as scopes
 
 
@@ -254,6 +257,44 @@ def cast_in_the_loop(p, x, dt, keys):
     return {k: (p[k] * one).astype(dt) for k in keys}
 
 
+def all_but(tree, *names: str):
+    """``tree``'s structure with True at every leaf but those whose key is
+    one of ``names``. An optimizer's weight-decay ``mask`` (optax's) for a
+    model whose expert layers hold selection biases: buffers — no gradient
+    reaches them, and a decay must not."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) not in names, tree)
+
+
+def param_count(init, *buffers: str) -> int:
+    """The elements of the tree ``init()`` makes (its shapes alone: nothing
+    is made), all but the leaves named ``buffers``: the parameters a step
+    moves."""
+    shapes = jax.eval_shape(init)
+    return sum(math.prod(p.shape) for p, counted in zip(
+        jax.tree.leaves(shapes), jax.tree.leaves(all_but(shapes, *buffers)))
+        if counted)
+
+
+def expert_step_counters(layers, n_experts: int, top_k: int, held: moe.Held,
+                         fields: Tuple[str, ...] = scopes.STEP_EXPERT_LOAD_ARGS,
+                         float_fields: Tuple[str, ...] = ()
+                         ) -> Optional[StepCounters]:
+    """What a model with expert layers (ops/moe.routed_experts' load a layer,
+    handed out of its loss as blocks.packed_aux packs it) offers a step
+    factory as its ``step_counters(cfg)``: a row an expert layer under the
+    ids ``layers`` — ``model/expert_load``'s ``layer`` —, read against the
+    row buffer a batch's tokens give (moe.step_load_static); None for a
+    pattern without an expert layer."""
+    layers = tuple(layers)
+    if not layers:
+        return None
+    return StepCounters(
+        scopes.EXPERT_LOAD_KIND, fields, layers,
+        partial(moe.step_load_static, n_experts=n_experts, top_k=top_k,
+                held=held), float_fields)
+
+
 def rows_under(seq: int, bytes_a_row: int, limit: int) -> int:
     """The largest power-of-two fraction of ``seq`` whose rows stay under
     ``limit`` bytes (``seq`` itself where they do)."""
@@ -264,10 +305,11 @@ def rows_under(seq: int, bytes_a_row: int, limit: int) -> int:
 
 
 def mlp_rows(batch: int, seq: int, d_model: int, d_ff: int,
-             itemsize: int) -> int:
+             itemsize: int, hidden_tensors: int = 5) -> int:
     """Rows of the sequence the MLP takes at a time: all of them where a
     hidden tensor of the whole sequence stays under MLP_CHUNK_BYTES. A longer
-    sequence goes in chunks whose five hidden tensors together take what two
+    sequence goes in chunks whose hidden tensors (a SwiGLU's backward holds
+    five, an MLP with one hidden tensor three) together take what two
     of the block's [B, S, D] activations do — a fifth more beside the eight
     of that size that wait in the chunk's backward for the attention's
     (rematted_working_set). On the chip the 32,768-byte EvaByte step's
@@ -276,8 +318,37 @@ def mlp_rows(batch: int, seq: int, d_model: int, d_ff: int,
     (PERF.md §6, PR 32)."""
     if batch * seq * d_ff * itemsize <= MLP_CHUNK_BYTES:
         return seq
-    return rows_under(seq, 5 * batch * d_ff * itemsize,
+    return rows_under(seq, hidden_tensors * batch * d_ff * itemsize,
                       2 * batch * seq * d_model * itemsize)
+
+
+def swiglu(h, w_gate, w_up, w_down):
+    """down(silu(gate(h)) · up(h)) of a normed h [B, rows, D], float32 (the
+    down-projection's accumulator): the gated MLP's three products under
+    scope ``mlp``, its two hidden tensors named. The norm before it and the
+    residual after it are the caller's."""
+    with jax.named_scope(scopes.MLP):
+        gate = checkpoint_name(jnp.einsum("bsd,df->bsf", h, w_gate),
+                               scopes.RES_MLP_GATE)
+        up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, w_up),
+                             scopes.RES_MLP_UP)
+        return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up, w_down,
+                          preferred_element_type=jnp.float32)
+
+
+def in_row_chunks(fn, x, rows: int):
+    """``fn(x)`` for a ``fn`` that works each row of x [B, S, D] alone (a
+    feed-forward half, norm and residual and all), the sequence taken
+    ``rows`` at a time (mlp_rows) where that is fewer than S, each chunk its
+    own ``checkpoint``: a chunk's hidden tensors are made again in its
+    backward and never exist for the whole sequence (models/llama.py's _mlp
+    says what that is worth)."""
+    B, S, D = x.shape
+    if rows == S:
+        return fn(x)
+    chunks = x.reshape(B, S // rows, rows, D).swapaxes(0, 1)
+    out = lax.map(jax.checkpoint(fn), chunks)
+    return out.swapaxes(0, 1).reshape(B, S, D)
 
 
 def head_rows(batch: int, seq: int, columns: int, heads: int) -> int:
@@ -500,6 +571,60 @@ def block_working_set(s: BlockShard) -> int:
         2 * attn_width + 2 * kv_width + (len(s.mlp_hidden) + 1) * s.d_ff
     ) if s.cast_in_loop else 0
     return block + _eva_k_f32(s) + weights
+
+
+def swiglu_price(batch: int, seq: int, rows: int, d_model: int, d_ff: int,
+                 itemsize: int, names: Tuple[str, str]
+                 ) -> Tuple[Tuple[RematCandidate, ...], int]:
+    """A SwiGLU half of hidden width ``d_ff`` that takes ``rows`` of the
+    sequence at a time (0: all), for the remat rule: (what it may keep — its
+    two hidden tensors, ``names``, where it is not chunked: a chunk makes
+    them again —, the bytes its backward holds: a chunk's five hidden
+    tensors, the weights cast twice)."""
+    tokens = batch * seq
+    kept = tuple(
+        RematCandidate((name,), tokens * d_ff * itemsize,
+                       2 * tokens * d_model * d_ff)
+        for name in names) if rows in (0, seq) else ()
+    return kept, itemsize * (batch * (rows or seq) * 5 * d_ff
+                             + 2 * 3 * d_model * d_ff)
+
+
+def routing_candidates(tokens: int, d_model: int, n_experts: int, top_k: int,
+                       held_count: int) -> Tuple[RematCandidate, ...]:
+    """What an expert layer's routing decided (ops/moe.py tags it), for the
+    remat rule: the scores at three bf16 passes of the router's float32
+    product; the ``top_k``'s last value and index at a full sort of each
+    row's n_experts with an index operand (what the TPU lowers it to) and
+    the pairs' sorted keys with their gates at theirs (moe.sort_ops): a MB or
+    two that spare a sort rank first."""
+    rows = moe.row_buffer(tokens, n_experts, top_k, held_count)
+    passes = moe.buffer_passes(tokens, n_experts, top_k, held_count)
+    return (
+        RematCandidate((scopes.RES_MOE_SCORES,), tokens * n_experts * 4,
+                       3 * 2 * tokens * d_model * n_experts),
+        RematCandidate((scopes.RES_MOE_KTH, scopes.RES_MOE_LAST), tokens * 8,
+                       tokens * moe.sort_ops(n_experts, operands=2)),
+        RematCandidate((scopes.RES_MOE_PAIR_KEY, scopes.RES_MOE_PAIR_GATE),
+                       passes * rows * 8,
+                       moe.sort_ops(tokens * held_count, operands=2)))
+
+
+def gated_experts_working_set(tokens: int, d_model: int, n_experts: int,
+                              top_k: int, held_count: int, d_expert: int,
+                              itemsize: int) -> Tuple[int, int]:
+    """What the backward of moe.gated_moe's routed part holds, as two terms a
+    family combines by what it measured: (the half's stream — the experts'
+    input and float32 sum with their cotangents — and the routing's three
+    [tokens, n_experts] tensors; the routed passes' — one pass's rows (input,
+    two hidden tensors, their product, the gradients of each), the held
+    experts' weights cast and the float32 sums their gradients are made
+    in)."""
+    rows = moe.row_buffer(tokens, n_experts, top_k, held_count)
+    weights = 3 * held_count * d_model * d_expert
+    return (tokens * d_model * (2 * itemsize + 8) + tokens * n_experts * 12,
+            itemsize * rows * (2 * d_model + 6 * d_expert)
+            + (itemsize + 4) * weights)
 
 
 def choose_remat_policy(shard: BlockShard, n_layer: int,

@@ -57,7 +57,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from ray_tpu.tracing import names as scopes
+from ray_tpu.tracing import get_buffer, names as scopes
 
 
 def moe_capacity(num_tokens: int, num_experts: int, top_k: int,
@@ -270,6 +270,17 @@ def buffer_passes(tokens: int, n_experts: int, top_k: int, held: int) -> int:
     runs those its pairs fill."""
     return -(-tokens * min(top_k, held)
              // row_buffer(tokens, n_experts, top_k, held))
+
+
+def sort_ops(n: int, operands: int) -> int:
+    """Operations of a sorting network over ``n`` keys (bitonic: log2(n) ·
+    (log2(n) + 1) / 2 stages of n / 2 compare-exchanges), each a comparison
+    and two selects an operand that moves: what the TPU lowers _chosen's
+    ``top_k`` (a row's n_experts with an index operand) and held_pairs' sort
+    (tokens · held keys with their gates) to, for whoever prices keeping
+    their outcome against making it again."""
+    stages = math.log2(n) * (math.log2(n) + 1) / 2
+    return int(n / 2 * stages * (1 + 2 * operands))
 
 
 @jax.custom_vjp
@@ -778,6 +789,22 @@ def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
                        pairs.group_sizes, load["passes"]), load
 
 
+def _beside_shared(routed: jax.Array, shared, u: jax.Array,
+                   rows: int) -> jax.Array:
+    """An expert layer's output [B, S, D] float32: ``routed`` (the held
+    experts' part, of that size in any shape) + ``shared(u)``, the shared
+    expert of every token of u [B, S, D]. ``shared`` works each row alone, so
+    with ``rows`` < S (0: all of them) it takes the sequence that many rows
+    at a time, each chunk its own ``checkpoint`` (as models/parts.py's
+    in_row_chunks, which this module may not import, and for its reason)."""
+    B, S, D = u.shape
+    if rows in (0, S):
+        return routed.reshape(B, S, D) + shared(u)
+    chunks = u.reshape(B, S // rows, rows, D).swapaxes(0, 1)
+    sh = lax.map(jax.checkpoint(shared), chunks)
+    return routed.reshape(B, S, D) + sh.swapaxes(0, 1).reshape(B, S, D)
+
+
 def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
                scaling: float, shared_rows: int = 0
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -808,11 +835,7 @@ def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
             return jnp.einsum("bsf,fd->bsd", _relu2(h), p["shared_w2"],
                               preferred_element_type=jnp.float32)
 
-    if shared_rows in (0, S):
-        return out.reshape(B, S, D) + shared(u), load
-    chunks = u.reshape(B, S // shared_rows, shared_rows, D).swapaxes(0, 1)
-    sh = lax.map(jax.checkpoint(shared), chunks)
-    return out.reshape(B, S, D) + sh.swapaxes(0, 1).reshape(B, S, D), load
+    return _beside_shared(out, shared, u, shared_rows), load
 
 
 def gated_moe_init(rng: jax.Array, n_layers: int, d_model: int,
@@ -906,11 +929,7 @@ def gated_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
                               p["shared_w2"],
                               preferred_element_type=jnp.float32)
 
-    if shared_rows in (0, S):
-        return out + shared(u), load
-    chunks = u.reshape(B, S // shared_rows, shared_rows, D).swapaxes(0, 1)
-    sh = lax.map(jax.checkpoint(shared), chunks)
-    return out + sh.swapaxes(0, 1).reshape(B, S, D), load
+    return _beside_shared(out, shared, u, shared_rows), load
 
 
 def chosen_experts(u: jax.Array, p: Dict[str, Any], top_k: int,
@@ -952,3 +971,18 @@ def held_load(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
         "buffer_fill": landed / jnp.maximum(filled * rows, 1),
         "pairs_dropped": landed - jnp.sum(pairs.valid),
     }
+
+
+def record_expert_loads(layers, loads) -> list:
+    """The ``model/expert_load`` events (tracing/names.EXPERT_LOAD_ARGS) of
+    ``loads`` — held_load's numbers, one an expert layer, on the host — under
+    the ids ``layers`` gives them in that order: recorded, and returned."""
+    component, name = scopes.EXPERT_LOAD.split("/")
+    events = []
+    for layer, load in zip(layers, loads, strict=True):
+        # (numpy scalars off the host: a count an int, a mean or share a float)
+        args = {"layer": layer, **{
+            k: load[k].item() for k in scopes.EXPERT_LOAD_ARGS[1:]}}
+        get_buffer().record_profile(name, component=component, args=args)
+        events.append(args)
+    return events

@@ -264,3 +264,48 @@ def test_narrow_heads_go_s_minor_through_rope_and_either_attention(
         for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_x)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-3, atol=2e-5)
+
+
+def _toy_swiglu(seed=0, B=2, S=16, D=8, F=24):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    x = jax.random.normal(k[0], (B, S, D), jnp.float32)
+    ws = tuple(jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[0])
+               for key, shape in zip(k[1:], ((D, F), (D, F), (F, D))))
+    return x, ws
+
+
+def test_the_shared_swiglu_is_its_three_products_written_out():
+    h, (w_gate, w_up, w_down) = _toy_swiglu()
+    want = (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+    got = parts.swiglu(h, w_gate, w_up, w_down)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # the accumulator is float32 whatever the operands are
+    half = parts.swiglu(*(a.astype(jnp.bfloat16)
+                          for a in (h, w_gate, w_up, w_down)))
+    assert half.dtype == jnp.float32
+    np.testing.assert_allclose(half, want, rtol=0.1, atol=0.1)
+
+
+@pytest.mark.parametrize("rows", [16, 8, 4, 1])
+def test_a_half_in_row_chunks_is_the_half_in_value_and_gradient(rows):
+    x, ws = _toy_swiglu(seed=1)
+
+    def half(x, ws):        # works each row alone: a norm, the MLP, a residual
+        return parts.residual_add(
+            x, parts.swiglu(parts.rmsnorm(x, jnp.ones(x.shape[-1]), 1e-6), *ws))
+
+    def chunked(x, ws):
+        return parts.in_row_chunks(lambda c: half(c, ws), x, rows)
+
+    np.testing.assert_allclose(chunked(x, ws), half(x, ws), rtol=1e-6,
+                               atol=1e-6)
+    want = jax.grad(lambda x, ws: jnp.sum(jnp.sin(half(x, ws))),
+                    argnums=(0, 1))(x, ws)
+    got = jax.grad(lambda x, ws: jnp.sum(jnp.sin(chunked(x, ws))),
+                   argnums=(0, 1))(x, ws)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    # all the rows at once is the function itself: no loop, no checkpoint
+    loops = str(jax.make_jaxpr(chunked)(x, ws)).count("scan")
+    assert loops == (0 if rows == 16 else 1)

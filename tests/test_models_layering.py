@@ -11,9 +11,20 @@ model file imports only those three —
 never another model — and no module reads a name with a leading underscore off
 another one of them. A model is added beside the others, not inside one
 (ROADMAP D21: PRs 31, 33 and 42 edited `gpt2.py`, the control cell's file, to
-add a model, and PR 37 was refused on that cell)."""
+add a model, and PR 37 was refused on that cell).
+
+A family that may not import its neighbour does not copy from it either (PR
+59; ROADMAP D25): no top-level function of a model file has the body of one
+of another module of the layer. What two families share has ONE home behind
+the seam — `blocks.py` what counts a PATTERN (`group_counts`, `init_pattern`,
+`with_grad_bytes`, `aux_by_layer`, `one_candidate_a_name`), `parts.py` what
+is, or prices, a HALF of a layer (`swiglu`, `in_row_chunks`, `swiglu_price`,
+`routing_candidates`, `gated_experts_working_set`, `all_but`,
+`param_count`), `ops/moe.py` what prices or reports its own kernels
+(`sort_ops`, `record_expert_loads`) — and a family calls it."""
 
 import ast
+import functools
 import os
 
 import pytest
@@ -117,3 +128,70 @@ def test_the_hybrid_config_carries_nothing_for_another_models_functions():
 
     for name in ("mixer", "norm_unit_offset", "n_pred_heads"):
         assert not hasattr(nemotron_h.NemotronHConfig, name), name
+
+
+# --------------------------------------------------------------------------- #
+# No copies across the seam (PR 59)
+# --------------------------------------------------------------------------- #
+
+# the modules a model file's one-line body may hand its work to
+HOMES = {"blocks", "parts", "hyper", "moe"}
+# pairs that stay, by name, and why
+KEPT_ALIKE = {
+    # a family's public forward over an untied head: its own trunk, then one
+    # product — two lines that name the family's own functions
+    frozenset({("llama", "forward"), ("minicpm_sala", "forward")}),
+    # each module's own registry of recorded decisions, listed: one line
+    frozenset({("blocks", "remat_policy_decisions"),
+               ("hyper_connections", "decisions")}),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _bodies(name):
+    """{function: its body as `ast.dump` gives it, the docstring and every
+    annotation dropped} for the top-level functions of models/``name``.py —
+    but for a body that is one ``return`` of a call into a shared home: that
+    is what USING the home looks like, and two families that use it alike
+    are not copies of each other."""
+    out = {}
+    for node in _tree(os.path.join(MODELS, name + ".py")).body:
+        if not isinstance(node, ast.FunctionDef):
+            continue
+        body = node.body
+        if (isinstance(body[0], ast.Expr)
+                and isinstance(body[0].value, ast.Constant)
+                and isinstance(body[0].value.value, str)):
+            body = body[1:]
+        if len(body) == 1 and isinstance(body[0], ast.Return):
+            call = body[0].value
+            if (isinstance(call, ast.Call)
+                    and isinstance(call.func, ast.Attribute)
+                    and isinstance(call.func.value, ast.Name)
+                    and call.func.value.id in HOMES):
+                continue
+        for inner in ast.walk(ast.Module(body=body, type_ignores=[])):
+            if isinstance(inner, ast.arg):
+                inner.annotation = None
+            elif isinstance(inner, ast.FunctionDef):
+                inner.returns = None
+        out[node.name] = ast.dump(ast.Module(body=body, type_ignores=[]))
+    return out
+
+
+@pytest.mark.parametrize("name", MODEL_FILES)
+def test_no_function_of_a_model_file_has_the_body_of_another_modules(name):
+    """It failed on the tree before PR 59: `_group_counts` x 4, `_stack_init`
+    x 3, `_sort_ops` x 3, `_layer_bytes` x 3, `_one_candidate_a_name` x 2,
+    `_dense` / `_mlp` x 3, `decays` / `_is_buffer` / `param_count` x 2."""
+    mine = _bodies(name)
+    found = []
+    for other in MODEL_FILES:
+        if other == name:
+            continue
+        for theirs, body in _bodies(other).items():
+            found += [
+                f"models/{name}.py:{fn} = models/{other}.py:{theirs}"
+                for fn, my_body in mine.items() if my_body == body
+                and frozenset({(name, fn), (other, theirs)}) not in KEPT_ALIKE]
+    assert not found, "\n".join(found)

@@ -106,3 +106,18 @@ def test_moe_expert_parallel_over_ep_mesh():
     batch = synthetic_batch(cfg, global_batch=4, seed=1)
     state, metrics = bundle.step_fn(bundle.state, batch)
     assert np.isfinite(float(metrics["loss"]))
+
+
+@pytest.mark.parametrize("n, operands, ops", [
+    (2, 0, 1),              # one compare-exchange
+    (64, 2, 3360),          # a row's 64 scores with an index: 21 stages of 32
+    (512, 2, 57600),
+    (8 * 4096 * 16, 2, 249036800),      # the pairs' keys with their gates
+])
+def test_sort_ops_counts_a_bitonic_networks_compare_exchanges(n, operands,
+                                                              ops):
+    from ray_tpu.ops import moe
+
+    assert moe.sort_ops(n, operands) == ops
+    stages = int(np.log2(n)) * (int(np.log2(n)) + 1) // 2
+    assert ops == n // 2 * stages * (1 + 2 * operands)

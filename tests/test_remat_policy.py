@@ -581,3 +581,87 @@ def test_no_remat_asks_no_rule_and_records_nothing(buffer):
 def test_remat_is_a_plain_bool(value):
     with pytest.raises(ValueError, match="remat must be True or False"):
         gpt2.gpt2_tiny(remat=value)
+
+
+# --------------------------------------------------------------------------- #
+# What a pattern family is written from (PR 59): the pattern's bookkeeping
+# --------------------------------------------------------------------------- #
+
+HYBRID = "MEMEMEMEM*E"
+
+
+def test_a_patterns_runs_and_how_many_layers_of_a_kind_each_holds():
+    assert blocks.pattern_groups(HYBRID) == [
+        ("ME", 4), ("M", 1), ("*", 1), ("E", 1)]
+    assert blocks.group_counts(HYBRID) == [
+        {"M": 4, "E": 4}, {"M": 1}, {"*": 1}, {"E": 1}]
+
+
+def test_init_pattern_gives_a_run_a_key_and_in_it_a_kind_its_own():
+    """One entry a run, a kind's layers stacked; the keys are split a run and,
+    in a run's key, a kind of ``kinds`` in ITS order — whatever kinds the run
+    holds: state from a seed is what it was when each family had the loop."""
+    rng = jax.random.PRNGKey(3)
+    drawn = []
+
+    def layer_init(key, n, kind):
+        drawn.append((kind, n, key))
+        return {"w": jax.random.normal(key, (n, 2))}
+
+    stacks = blocks.init_pattern(rng, HYBRID, "ME*", layer_init)
+    assert [(kind, n) for kind, n, _ in drawn] == [
+        ("M", 4), ("E", 4), ("M", 1), ("*", 1), ("E", 1)]
+    assert [sorted(group) for group in stacks] == [
+        ["E", "M"], ["M"], ["*"], ["E"]]
+    assert stacks[0]["M"]["w"].shape == (4, 2)
+    run_keys = jax.random.split(rng, 4)
+    want = {(g, kind): jax.random.split(run_keys[g], 3)["ME*".index(kind)]
+            for g, kind in ((0, "M"), (0, "E"), (1, "M"), (2, "*"), (3, "E"))}
+    for (g, kind), (_, _, key) in zip(want, drawn):
+        np.testing.assert_array_equal(key, want[g, kind])
+    # two kinds of one run, and one kind in two runs, draw different numbers
+    assert not np.array_equal(stacks[0]["M"]["w"], stacks[0]["E"]["w"])
+    assert not np.array_equal(stacks[0]["M"]["w"][:1], stacks[1]["M"]["w"])
+
+
+def test_a_name_two_kinds_share_is_one_candidate_of_the_kind_applied_most():
+    C = blocks.RematCandidate
+    kinds = {
+        "A": blocks.KindShard(1, (C(("mid",), 100, 1000, 8),
+                                  C(("q",), 50, 700)), 5000, 11),
+        "C": blocks.KindShard(3, (C(("bcx",), 30, 300),
+                                  C(("mid",), 100, 1000, 8)), 4000, 7),
+    }
+    got = blocks.one_candidate_a_name(kinds)
+    # the carrier is C (3 applications): the 4 layers' bytes, operations and
+    # freed bytes over its 3, rounded up; A keeps what is its own alone
+    assert got["A"].candidates == (C(("q",), 50, 700),)
+    assert got["C"].candidates == (C(("mid",), 134, 1334, 11),
+                                   C(("bcx",), 30, 300))
+    # nothing else of a kind is touched
+    assert [(k.applications, k.block_bytes, k.grad_bytes)
+            for k in got.values()] == [(1, 5000, 11), (3, 4000, 7)]
+    # the rule then prices the shared name once: 3 x 134 >= 4 x 100 bytes
+    assert 3 * 134 >= 4 * 100 > 3 * 133
+    alone = {"A": kinds["A"]._replace(candidates=(C(("q",), 50, 700),)),
+             "C": kinds["C"]._replace(candidates=(C(("bcx",), 30, 300),))}
+    assert blocks.one_candidate_a_name(alone) == alone
+
+
+def test_aux_by_layer_takes_a_scans_stacked_aux_apart_in_the_layers_order():
+    runs = [("ME", 2), ("E", 1)]
+    auxes = [[None, {"n": jnp.asarray([10, 11])}], [{"n": jnp.asarray(12)}]]
+    assert [int(a["n"]) for a in blocks.aux_by_layer(runs, auxes)] == [
+        10, 11, 12]
+
+
+def test_with_grad_bytes_is_a_layers_parameters_over_the_chips(cpu_mesh8):
+    def layer_init(key, n, kind):
+        return {"w": jnp.zeros((n, 16, 4 if kind == "a" else 8), jnp.float32)}
+
+    kinds = {"a": blocks.KindShard(2, (), 1), "b": blocks.KindShard(1, (), 1)}
+    assert {k: v.grad_bytes for k, v in blocks.with_grad_bytes(
+        kinds, layer_init, None).items()} == {"a": 256, "b": 512}
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec(fsdp=8), cpu_mesh8)
+    assert blocks.with_grad_bytes(kinds, layer_init, mesh)[
+        "b"].grad_bytes == 64
