@@ -120,6 +120,62 @@ def chosen_rows_off(seed: int) -> int:
     return int(jnp.sum(jnp.any(moe._chosen(tied, top_k) != listed, axis=-1)))
 
 
+# the held experts' grouped products of the DeepSeek-V2-Lite and Xing4.0
+# cells, as ops/grouped_matmul.grouped_dot is given them: (cell, rows of the
+# buffer, held experts, pairs a balanced batch lands, K, N of W1 / W3; W2's is
+# [N, K])
+GROUPED_SHAPES = (("deepseek-v2-lite", 61440, 16, 49152, 2048, 1408),
+                  ("xing4.0", 5120, 8, 4096, 3584, 1024))
+# two roundings of one float32 sum to bfloat16 differ by one unit in the last
+# place of the larger (2 ** -8); the sums differ by their order
+GROUPED_OFF_LIMIT = 2 ** -6
+
+
+def grouped_products_off(seed: int, shapes) -> Dict[str, Any]:
+    """``ops/grouped_matmul.grouped_dot`` against ``lax.ragged_dot`` on this
+    backend at ``shapes`` (as GROUPED_SHAPES; tests/test_chip_smoke.py gives
+    a toy's), both of an expert's matrix shapes: the largest
+    difference of the product and of its gradients to the input and to the
+    weights, each as a share of the compiler's largest value (bf16 products
+    of float32 sums: the two differ by the order of a sum, a few 1e-3), over
+    the rows that are pairs; and the rule's decisions for those shapes
+    (``ops/grouped_tiling``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import grouped_matmul
+
+    worst = {}
+    for cell, rows, held, pairs, K, N in shapes:
+        rng = np.random.default_rng(seed)
+        sizes = rng.multinomial(pairs, np.full(held, 1.0 / held))
+        valid = (jnp.arange(rows) < pairs)[:, None]
+        for k, n in ((K, N), (N, K)):
+            kx, kw, kd = jax.random.split(jax.random.PRNGKey(seed % 2 ** 31), 3)
+            x = jnp.where(valid, jax.random.normal(kx, (rows, k), jnp.bfloat16), 0)
+            d = jnp.where(valid, jax.random.normal(kd, (rows, n), jnp.bfloat16), 0)
+            w = 0.03 * jax.random.normal(kw, (held, k, n), jnp.bfloat16)
+            group_sizes = jnp.asarray(sizes, jnp.int32)
+
+            def results(product):
+                o, vjp = jax.vjp(lambda x, w: product(x, w, group_sizes), x, w)
+                d_x, d_w = vjp(d)
+                return [jnp.where(valid, o, 0), jnp.where(valid, d_x, 0), d_w]
+
+            ours = jax.jit(lambda: results(grouped_matmul.grouped_dot))()
+            theirs = jax.jit(lambda: results(
+                lambda x, w, s: jax.lax.ragged_dot(
+                    x, w, s, preferred_element_type=x.dtype)))()
+            for what, a, b in zip(("product", "d_input", "d_weights"),
+                                  ours, theirs):
+                a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+                worst[f"{cell} [{k}->{n}] {what}"] = float(
+                    jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+    return {"off": worst, "shapes": [(s[1], s[4], s[5]) for s in shapes],
+            "tiling": grouped_matmul.grouped_tiling_decisions()}
+
+
 def attention_call_shapes(hlo_text: str, head_dim: int) -> Tuple[int, List[List[int]]]:
     """(number of Mosaic custom calls, the distinct [B·H, S, hd] — or, from
     the S-minor kernel pair, [B·H, hd, S] — shapes on their lines) in a
@@ -299,7 +355,9 @@ def train_loop(config: Dict[str, Any]) -> None:
                   "ssd_tiling": ssd_tiling_decisions(),
                   "expert_load": load,
                   "step_load": np.asarray(m["counters"]).tolist(),
-                  "chosen_rows_off": chosen_rows_off(config["seed"])}
+                  "chosen_rows_off": chosen_rows_off(config["seed"]),
+                  "grouped": grouped_products_off(
+                      config["seed"], config["grouped_shapes"])}
         del variant
     # One step of a linear / block-sparse attention hybrid through the same
     # factory: its pattern, the scan's tiling at one head a group, what its
@@ -476,7 +534,8 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         use_tpu: bool, seed: int = 0, eva_model=None,
         hybrid_model=None, sala_model=None,
         lfm2_model=None, dsv2_model=None,
-        xing4_model=None) -> List[Dict[str, Any]]:
+        xing4_model=None, grouped_shapes=GROUPED_SHAPES
+        ) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -500,6 +559,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "eva_model": eva_model, "hybrid_model": hybrid_model,
             "sala_model": sala_model, "lfm2_model": lfm2_model,
             "dsv2_model": dsv2_model, "xing4_model": xing4_model,
+            "grouped_shapes": grouped_shapes,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -583,6 +643,20 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
                        f"{hybrid['chosen_rows_off']} of {ROUTER_SHAPE[0]} "
                        "rows with ties: this backend's top_k does not list "
                        "equal elements in index order")
+        grouped = hybrid["grouped"]
+        for what, off in grouped["off"].items():
+            if not off < GROUPED_OFF_LIMIT:
+                bad.append(f"ops/grouped_matmul.grouped_dot differs from "
+                           f"lax.ragged_dot at {what} by {off} of the largest "
+                           f"value (limit {GROUPED_OFF_LIMIT})")
+        took = {(d["rows"], d["K"], d["N"], d["form"])
+                for d in grouped["tiling"]}
+        for rows, K, N in grouped["shapes"]:
+            for k, n in ((K, N), (N, K)):
+                for form in ("gmm", "gmm_t", "tgmm"):
+                    if (rows, k, n, form) not in took:
+                        bad.append("no ops/grouped_tiling decision for "
+                                   f"{form} at rows={rows} K={k} N={n}")
     sala = summary.get("sala")
     if sala is not None:
         if not (math.isfinite(sala["loss"]) and math.isfinite(sala["grad_norm"])):
@@ -938,6 +1012,14 @@ def main() -> int:
               f"{e['buffer_rows']} rows ({e['buffer_fill']:.3f} full), "
               f"dropped {e['pairs_dropped']}")
     print("\n".join(step_loads["hybrid"]))
+    for d in hybrid["grouped"]["tiling"]:
+        print(f"grouped tiling: {d['form']} rows={d['rows']} "
+              f"held={d['held']} K={d['K']} N={d['N']} "
+              f"({d['dtype_bytes']}-byte) -> {d['impl']}, row tile "
+              f"{d['row_tile']}, vmem estimate {d['vmem_estimate']}")
+    for what, off in hybrid["grouped"]["off"].items():
+        print(f"grouped products against lax.ragged_dot: {what}: off by "
+              f"{off:.2e} of the largest value")
     print(f"chosen set as a mask against lax.top_k's list, "
           f"{ROUTER_SHAPE[0]}x{ROUTER_SHAPE[1]} scores with ties, top "
           f"{ROUTER_TOP_K}: {hybrid['chosen_rows_off']} rows differ")

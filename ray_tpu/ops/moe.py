@@ -29,8 +29,10 @@ set as a mask over the scores, so the normalising sum is a masked row-sum and
 no score is gathered by chosen id. The membership's true entries, sorted by
 expert (T · held keys that carry their own token, not T · top_k with an index
 operand), are the rows of the two products, computed as grouped matmuls
-(``lax.ragged_dot``: the TPU compiler's own grouped kernel, whose work follows
-the real group sizes), around them a latent projection and beside them a
+(``ops/grouped_matmul.grouped_dot``: since PR 60 the program's own Pallas
+kernels at a cell's shapes, ``lax.ragged_dot`` — the TPU compiler's grouped
+kernel — at a toy's; the work of either follows the real group sizes),
+around them a latent projection and beside them a
 shared expert. No pair is dropped — a batch that lands more pairs here than
 one row buffer holds takes further passes over it, and everything that costs
 a row is done by the pass that holds it (PR 36; its gate comes out of the
@@ -48,6 +50,7 @@ written-out backward, the load and the bias's balance are one code for both.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -57,6 +60,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from ray_tpu.ops.grouped_matmul import grouped_dot
 from ray_tpu.tracing import get_buffer, names as scopes
 
 
@@ -192,7 +196,7 @@ def moe_mlp(x: jax.Array, params: Dict[str, Any], *, top_k: int,
 # of balance costs more passes (a second one ~10 ms a layer and step there),
 # never a wrong result.
 ROW_BUFFER_MULTIPLE = 1.25
-_ROW_TILE = 512              # the compiler's grouped kernel tiles rows by 512
+_ROW_TILE = 512              # whole row tiles of either grouped kernel
 # the selection bias at initialisation: noise small beside the scores' spread
 # (a sigmoid of logits of std ~1.3), large enough to decide near-ties
 ROUTER_BIAS_STD = 0.01
@@ -580,6 +584,16 @@ RELU2_EXPERT = ("w1", "w2")
 GATED_EXPERT = ("w1", "w3", "w2")
 
 
+@contextlib.contextmanager
+def _dispatch_scope():
+    """``moe_routed`` > ``moe_dispatch``: a pass's own work around the grouped
+    products. routed_experts is no one scope: what it runs enters
+    ``moe_routed`` part by part, and the products' kernels do not."""
+    with jax.named_scope(scopes.MOE_ROUTED), \
+            jax.named_scope(scopes.MOE_DISPATCH):
+        yield
+
+
 def _pass_rows(x, ws, gate, valid, group_sizes):
     """One buffer of pairs through the held experts, row for row: x [rows,
     width] (each pair's token's input), its gate [rows] → gate · f_e(x)
@@ -587,30 +601,31 @@ def _pass_rows(x, ws, gate, valid, group_sizes):
     ``ws`` say what a held expert is: (W1, W2) — ``relu(x·W1_e)² · W2_e`` —
     or (W1, W3, W2) — ``(silu(x·W1_e) ⊙ x·W3_e) · W2_e``."""
     w1, w2 = ws[0], ws[-1]
-    with jax.named_scope(scopes.MOE_DISPATCH):
+    with _dispatch_scope():
         x = jnp.where(valid[:, None], x, 0)
     # rows past the last group are whatever the kernel left there (NaN as
     # likely as not), in the products and in their cotangents: each is masked
-    # before anything multiplies it
-    h = lax.ragged_dot(x, w1, group_sizes, preferred_element_type=x.dtype)
+    # before anything multiplies it. The products themselves stand under
+    # neither scope: a trace's reader finds them by the kernel's name
+    # (ops/grouped_matmul.py) and adds them to the `moe_routed` scope's time
+    h = grouped_dot(x, w1, group_sizes)
     if len(ws) == 3:
-        up = lax.ragged_dot(x, ws[1], group_sizes,
-                            preferred_element_type=x.dtype)
-        with jax.named_scope(scopes.MOE_DISPATCH):
+        up = grouped_dot(x, ws[1], group_sizes)
+        with _dispatch_scope():
             a = (jax.nn.silu(jnp.where(valid[:, None], h, 0))
                  * jnp.where(valid[:, None], up, 0))
     else:
-        with jax.named_scope(scopes.MOE_DISPATCH):
+        with _dispatch_scope():
             a = _relu2(jnp.where(valid[:, None], h, 0))
     # out of the kernel in the compute dtype: a float32 output would make
     # the backward's two grouped products take float32 operands
-    o = lax.ragged_dot(a, w2, group_sizes, preferred_element_type=x.dtype)
-    with jax.named_scope(scopes.MOE_DISPATCH):
+    o = grouped_dot(a, w2, group_sizes)
+    with _dispatch_scope():
         return (jnp.where(valid[:, None], o, 0).astype(jnp.float32)
                 * gate[:, None])
 
 
-@jax.named_scope(scopes.MOE_DISPATCH)
+@_dispatch_scope()
 def _looked_up(ell, key):
     """What one pass looks up for its rows' ``key`` [rows]: each row's token
     and its latent out of ell [T, latent]."""
@@ -645,7 +660,7 @@ class _Float32Sum(NamedTuple):
         if d.dtype != jnp.bfloat16:
             raise TypeError(f"the experts' weights are {d.dtype}: the passes "
                             "sum their gradients for bfloat16 or float32")
-        with jax.named_scope(scopes.MOE_DISPATCH):
+        with _dispatch_scope():
             return cls(d, jnp.zeros(d.shape, jnp.uint16))
 
     def plus(self, d, last):
@@ -682,11 +697,12 @@ def _run_passes(ell, ws, gates, gate_rows, key, valid, group_sizes, n):
     def add_pass(i, r):
         token, x = _looked_up(ell, key[i])
         o = _pass_rows(x, ws, gate_rows[i], valid[i], group_sizes[i])
-        with jax.named_scope(scopes.MOE_DISPATCH):
+        with _dispatch_scope():
             return r.at[token].add(o)
 
-    return _further_passes(n, add_pass,
-                           add_pass(0, jnp.zeros(ell.shape, jnp.float32)))
+    with jax.named_scope(scopes.MOE_ROUTED):
+        zeros = jnp.zeros(ell.shape, jnp.float32)
+    return _further_passes(n, add_pass, add_pass(0, zeros))
 
 
 def _run_passes_fwd(ell, ws, gates, gate_rows, key, valid, group_sizes, n):
@@ -708,24 +724,27 @@ def _run_passes_bwd(res, d_r):
         _, vjp = jax.vjp(
             lambda x, ws, g: _pass_rows(x, ws, g, valid[i], group_sizes[i]),
             x, ws, gate_rows[i])
-        with jax.named_scope(scopes.MOE_DISPATCH):
+        with _dispatch_scope():
             d_o = d_r[token]
         d_x, d_w, d_gate = vjp(d_o)
-        with jax.named_scope(scopes.MOE_DISPATCH):
+        with _dispatch_scope():
             return (d_ell.at[token].add(d_x.astype(jnp.float32)), d_w,
                     d_gates.at[key[i]].add(d_gate))
 
     def body(i, sums):
         d_ell, d_w, d_gates = pass_vjp(i, sums[0], sums[2])
-        return (d_ell, tuple(s.plus(d, i == n - 1)
-                             for s, d in zip(sums[1], d_w)), d_gates)
+        with jax.named_scope(scopes.MOE_ROUTED):
+            return (d_ell, tuple(s.plus(d, i == n - 1)
+                                 for s, d in zip(sums[1], d_w)), d_gates)
 
-    d_ell, d_w, d_gates = pass_vjp(0, jnp.zeros(ell.shape, jnp.float32),
-                                   jnp.zeros_like(gates))
+    with jax.named_scope(scopes.MOE_ROUTED):
+        zeros = jnp.zeros(ell.shape, jnp.float32), jnp.zeros_like(gates)
+    d_ell, d_w, d_gates = pass_vjp(0, *zeros)
     d_ell, d_ws, d_gates = _further_passes(n, body, (
         d_ell, tuple(_Float32Sum.starting_at(d) for d in d_w), d_gates))
-    return (d_ell.astype(ell.dtype), tuple(s.high for s in d_ws), d_gates,
-            None, None, None, None, None)
+    with jax.named_scope(scopes.MOE_ROUTED):
+        return (d_ell.astype(ell.dtype), tuple(s.high for s in d_ws), d_gates,
+                None, None, None, None, None)
 
 
 _run_passes.defvjp(_run_passes_fwd, _run_passes_bwd)
@@ -751,7 +770,6 @@ def _dispatch(u, p, top_k: int, held: Held, scaling: float, eps: float = 0.0,
         -(-landed // rows), landed, jnp.max(pairs.per_expert)))), choice
 
 
-@jax.named_scope(scopes.MOE_ROUTED)
 def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
                    top_k: int, held: Held, scaling: float, eps: float = 0.0,
                    form: Tuple[str, ...] = RELU2_EXPERT, rule: Rule = Rule(),
@@ -771,19 +789,25 @@ def routed_experts(u: jax.Array, ell: jax.Array, p: Dict[str, Any], *,
     holds the layer's balance_loss too (a float32 under
     names.STEP_BALANCE_LOSS), from the scores and the choice the dispatch
     made anyway: no second router product."""
-    with jax.named_scope(scopes.MOE_DISPATCH):
+    with _dispatch_scope():
         _, pairs, load, choice = _dispatch(u, p, top_k, held, scaling, eps,
                                            rule)
     if balance_rows:
-        load[scopes.STEP_BALANCE_LOSS] = balance_loss(*choice, top_k,
-                                                      balance_rows)
+        with jax.named_scope(scopes.MOE_ROUTED):
+            load[scopes.STEP_BALANCE_LOSS] = balance_loss(*choice, top_k,
+                                                          balance_rows)
     # (each weight row-major as it enters the passes: the backward's grouped
     # products read two of them transposed, and the first pass stands
     # outside any loop now, so without this the compiler lays the float32
     # parameters themselves — and their moments — out transposed, by copies
     # a step, to spare the transposing copy of a cast)
-    ws = tuple(with_layout_constraint(
-        p[w], Layout(major_to_minor=tuple(range(p[w].ndim)))) for w in form)
+    with jax.named_scope(scopes.MOE_ROUTED):
+        ws = tuple(with_layout_constraint(
+            p[w], Layout(major_to_minor=tuple(range(p[w].ndim))))
+            for w in form)
+    # (no scope around the passes: each part of one enters `moe_routed`
+    # itself, and the grouped products' kernels stand outside it — a trace's
+    # reader adds the kernel's time to the scope's, once)
     return _run_passes(ell, ws, pairs.gates,
                        pairs.gate_rows, pairs.key, pairs.valid,
                        pairs.group_sizes, load["passes"]), load

@@ -100,10 +100,15 @@ FLASH_BWD_KERNEL = "flash_attention_bwd"
 # of every earlier window), forward and backward; the summary pass is XLA
 EVA_AGG_FWD_KERNEL = "eva_agg_fwd"
 EVA_AGG_BWD_KERNEL = "eva_agg_bwd"
-# what `lax.ragged_dot` (ops/moe.latent_moe's grouped expert products) is on
-# the chip: the TPU compiler's own grouped-matmul kernel, whose instructions
-# are named `ragged-dot-…` and carry NO op_name — the scopes they were written
-# under are gone, so a trace's reader knows them by this name alone
+# the held experts' grouped products (ops/moe._pass_rows), whoever makes
+# them. Since PR 60 the program's own kernels (ops/grouped_matmul.py:
+# `grouped_gmm`, `grouped_gmm_t`, `grouped_tgmm`) carry this name as a scope
+# around each call — and NOT `moe_routed`: the benchmark's reader adds this
+# kernel's time to that scope's. Where the rule leaves a call to
+# `lax.ragged_dot` it is the TPU compiler's own grouped-matmul kernel, whose
+# instructions are named `ragged-dot-…` and carry NO op_name — the scopes
+# they were written under are gone, so a trace's reader knows them by this
+# name alone. (The name is the compiler's; the readers were written to it.)
 RAGGED_DOT_KERNEL = "ragged-dot"
 # the state-space scan (ops/mamba2.ssd_scan): a chunk's quadratic form, its
 # state update and the carried state's part of y, a (row, head tile) at a time
@@ -174,6 +179,15 @@ SPARSE_TILING_ARGS = ("kernel", "rows", "S", "group_heads", "hd", "block",
 MHC_TILING = "ops/mhc_tiling"
 MHC_TILING_ARGS = ("kernel", "tokens", "n", "C", "token_tile",
                    "vmem_estimate")
+# the same for a held experts' grouped product (ops/grouped_matmul.py; `form`
+# is "gmm" x·W, "gmm_t" d·Wᵀ or "tgmm" xᵀ·d): the rows of the buffer, the
+# held experts, an expert's matrix [K, N], its bytes an element and the
+# devices of the step's mesh; which implementation took the call ("pallas":
+# the program's kernel, "compiler": `lax.ragged_dot`'s) and the rows of one
+# visit
+GROUPED_TILING = "ops/grouped_tiling"
+GROUPED_TILING_ARGS = ("form", "rows", "held", "K", "N", "dtype_bytes",
+                       "devices", "impl", "row_tile", "vmem_estimate")
 # what a block-sparse mixer's selection is, once a distinct shape (models/
 # minicpm_sala.py): rows and sequence, the key blocks there are, how many a
 # query is given of them, how many of those are forced (the window's and the

@@ -254,9 +254,23 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
                           lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg,
                           xing4_model=deepseek_v2.xing4_tiny(
                               remat=True, hc_sinkhorn_iters=2, n_layer=1,
-                              first_layer=2))
+                              first_layer=2),
+                          grouped_shapes=(("toy", 512, 4, 400, 256, 128),))
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
+    # the grouped products (PR 60): the program's kernels (interpreted here)
+    # against lax.ragged_dot at the toy's two matrix shapes, value and both
+    # gradients, and the rule's decision for each form came back; and the
+    # check fails on a difference or a missing decision
+    grouped = summary["hybrid"]["grouped"]
+    assert len(grouped["off"]) == 6 and max(grouped["off"].values()) < 2 ** -6
+    assert {(d["K"], d["N"], d["form"], d["impl"]) for d in grouped["tiling"]
+            if d["rows"] == 512} == {
+        (k, n, form, "pallas") for k, n in ((256, 128), (128, 256))
+        for form in ("gmm", "gmm_t", "tgmm")}
+    wrong = [rows[-1] | {"summary": summary | {"hybrid": summary["hybrid"] | {
+        "grouped": grouped | {"off": {"toy": 0.5}, "tiling": []}}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + wrong, cfg, steps)) == 7
     # the Xing4.0 step (PR 57): the same family with four hyper-connection
     # streams, a biased router and an MTP module — its decision event, its
     # three expert layers' loads (the MTP module's last) and what the step

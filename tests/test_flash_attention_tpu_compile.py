@@ -10,6 +10,7 @@ Kept in ONE file and behind fixtures: only the xdist worker that is given this
 file loads the TPU's library (on-chip-measurement guide, section 2).
 """
 
+import functools
 import math
 import re
 
@@ -310,16 +311,28 @@ def test_the_minicpm_sala_cell_step_makes_no_norm_inside_a_lightning_product(
     assert {k: v for k, v in products.items() if len(v) > 1} == {}
 
 
-def _cell_on(topo, name):
-    """(cell, config, its family, its mesh over the described chips) of a
-    BENCHMARK.json cell."""
-    import importlib
+def _benchmarks_importable():
     import os
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
+
+
+def _program_trace():
+    """The benchmark's reader of a device trace (`benchmarks/harness`)."""
+    _benchmarks_importable()
+    from benchmarks.harness import program_trace
+    return program_trace
+
+
+def _cell_on(topo, name):
+    """(cell, config, its family, its mesh over the described chips) of a
+    BENCHMARK.json cell."""
+    import importlib
+
+    _benchmarks_importable()
     from benchmarks.harness import spec
     from ray_tpu.parallel import mesh as mesh_lib
 
@@ -570,7 +583,9 @@ def test_the_nemotron_cell_step_keeps_the_routing_and_clones_nothing(
     keys = {math.prod(int(n) for n in re.search(
         r"= \(?\w+\[([\d,]*)\]", l).group(1).split(","))
         for l in again if re.search(r" sort\(", l)}
-    assert keys == {14336}, keys
+    # (the 64: the grouped kernels' visits — 56 row tiles + 8 groups —, PR
+    # 60, which a recomputed pass orders again)
+    assert keys == {14336, 14336 // 256 + 8}, keys
     assert not [l for l in again if re.search(r" (dot|convolution)\(", l)
                 and "f32[32768,512]" in l.split(" = ")[1][:40]]
     # and the forward still makes each once an expert layer's program (the
@@ -605,11 +620,12 @@ _MOE_T, _MOE_E, _MOE_HELD, _MOE_F = 32768, 512, 8, 2688
 
 
 @pytest.fixture(scope="module")
-def routed_experts_hlo(one_chip):
+def routed_experts_hlo(topo, one_chip):
     """`ops/moe.routed_experts` at the Nemotron cell's shapes (32,768 tokens,
     experts 24–31 of 512 held, top-22, latent 1,024 → 2,688), forward and
     backward, compiled for one described chip: the program text."""
     from ray_tpu.ops import moe
+    from ray_tpu.parallel import mesh as mesh_lib
 
     D, latent, held = 4096, 1024, moe.Held(24, _MOE_HELD)
 
@@ -625,32 +641,120 @@ def routed_experts_hlo(one_chip):
         return jnp.sum(moe.routed_experts(u, ell, p, top_k=22, held=held,
                                           scaling=5.0)[0])
 
-    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        abstract((_MOE_T, D)), abstract((_MOE_T, latent)), p
-    ).compile().as_text()
+    # (the step factory's mesh of the one described chip, as a cell's step
+    # traces under: the kernels compile for it and are not interpreted)
+    with mesh_lib.use_mesh(mesh_lib.make_mesh(mesh_lib.MeshSpec(),
+                                              [topo.devices[0]])):
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            abstract((_MOE_T, D)), abstract((_MOE_T, latent)), p
+        ).compile().as_text()
 
 
 def test_the_held_experts_products_are_grouped_kernels_on_the_v5e(
         routed_experts_hlo):
     """Every one of the six grouped products a pass makes (two forward, two a
     backward operand side) — twice in the program since PR 51: the first pass
-    stands outside the loop, further passes inside it — is the TPU
-    compiler's own grouped kernel (`lax.ragged_dot` → a `ragged-dot` custom
-    call whose work follows the real group sizes) and none is expanded into
-    one dense product an expert; the row buffer is 1.25× the mean in whole
-    tiles (28 of 512 rows for 11,264 pairs), not the worst case."""
-    from ray_tpu.ops import moe
+    stands outside the loop, further passes inside it — is a grouped kernel
+    whose work follows the real group sizes — since PR 60 the program's own
+    (`ops/grouped_matmul.py`, which `grouped_tiling` chose for all three
+    forms at these shapes: a Mosaic call under the name a trace's reader
+    finds the grouped products by, `ragged-dot`, and under NO `moe_routed`
+    scope, whose time the reader adds the kernel's to), none the compiler's
+    (`ragged-dot-none…`) — and none is expanded into one dense product an
+    expert; the row buffer is 1.25× the mean in whole tiles (28 of 512 rows
+    for 11,264 pairs), not the worst case."""
+    from ray_tpu.ops import grouped_matmul, moe
+    from ray_tpu.tracing import names
 
     hlo = routed_experts_hlo
     rows = moe.row_buffer(_MOE_T, _MOE_E, 22, _MOE_HELD)
     assert rows == 14336 < _MOE_T * _MOE_HELD
-    grouped = [line for line in hlo.splitlines()
-               if re.match(r"\s*(ROOT )?%?ragged-dot-none\S* = ", line)]
-    assert len(grouped) == 12, len(grouped)
-    assert all('custom_call_target="tpu_custom_call"' in g for g in grouped)
+    assert {grouped_matmul.grouped_tiling(form, rows, _MOE_HELD, k, n, 2).impl
+            for form in grouped_matmul.FORMS
+            for k, n in ((1024, _MOE_F), (_MOE_F, 1024))} == {
+        grouped_matmul.PALLAS}
+    assert not re.search(r"%?ragged-dot-none\S* = ", hlo)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 12
+    # as the benchmark's reader files a device instruction (by its HLO name
+    # and its op_name's elements): every Mosaic call is the grouped products'
+    # kernel, and none counts under `moe_routed` a second time
+    classify = _program_trace().classify
+    calls = re.findall(r"^\s*(?:ROOT )?%(\S+) = [^\n]*?custom-call\([^\n]*?"
+                       r"tpu_custom_call[^\n]*?op_name=\"([^\"]*)\"", hlo, re.M)
+    filed = [classify(op, name, "mosaic") for name, op in calls]
+    assert len(filed) == 12
+    assert {c["kernel"] for c in filed} == {names.RAGGED_DOT_KERNEL}
+    assert not [c for c in filed if names.MOE_ROUTED in c["scopes"]], filed
+    by_form = {form: sum(f"/grouped_{form}/" in op for _, op in calls)
+               for form in grouped_matmul.FORMS}
+    assert by_form == {"gmm": 4, "gmm_t": 4, "tgmm": 4}, by_form
+    # they say which way they run (the compiler's instructions carried no
+    # op_name: direction "other"); a gradient's program holds the backward's
+    # twelve — each pass's products made again, then their transposes
+    assert {c["direction"] for c in filed} == {"bwd"}
     # no product of the whole buffer with one expert's matrix
     assert f"bf16[{rows},{_MOE_F}]" in hlo and not re.search(
         rf"= \S+\[{_MOE_HELD},{rows},", hlo)
+
+
+# the four expert cells' grouped products, as `ops/moe._pass_rows` gives them
+# to `grouped_dot`: (rows of the buffer, held experts, K, N of W1 / W3); W2's
+# is [N, K]
+_CELL_PRODUCTS = {
+    "deepseek-v2-lite-l5": (61440, 16, 2048, 1408),
+    "xing4.0-29b-a4b-l5": (5120, 8, 3584, 1024),
+    "lfm2-24b-a2b-l5": (40960, 16, 2048, 1536),
+    "nemotron-3-super-120b-l11": (14336, 8, 1024, 2688),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_PRODUCTS))
+def test_the_grouped_kernels_compile_within_their_stated_vmem_on_the_v5e(
+        cell, one_chip):
+    """The kernel family of `ops/grouped_matmul.py` at one expert cell's
+    shapes, both of an expert's matrix shapes, bf16, compiled for the
+    described chip: what interpret mode cannot show — a product contracted
+    over the rows' (sublane) dimension, a [K, N] block read transposed, a
+    width of 11 or 21 lane tiles as ONE block — each call under the scoped
+    VMEM its tiling states (Mosaic refuses one that needs more), which is
+    past the default 16 MiB and under the ceiling."""
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops.attention import VMEM_BUDGET_BYTES, VMEM_CEILING_BYTES
+    from ray_tpu.tracing import names
+
+    rows, held, K, N = _CELL_PRODUCTS[cell]
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for k, n in ((K, N), (N, K)):
+        operands = {"gmm": ((rows, k), (held, k, n)),
+                    "gmm_t": ((rows, n), (held, k, n)),
+                    "tgmm": ((rows, k), (rows, n))}
+        for form in gm.FORMS:
+            tiling = gm.grouped_tiling(form, rows, held, k, n, 2)
+            assert tiling.impl == gm.PALLAS and rows % tiling.row_tile == 0
+            assert (VMEM_BUDGET_BYTES < tiling.vmem_estimate
+                    <= VMEM_CEILING_BYTES * 2 // 3)
+            hlo = jax.jit(functools.partial(
+                gm._pallas_product, form, tiling=tiling, interpret=False)
+            ).lower(*(abstract(s) for s in operands[form]),
+                    abstract((held,), jnp.int32)).compile().as_text()
+            (call,) = [op for _, code, op in _instructions(hlo)
+                       if code == "custom-call" and "pallas_call" in op]
+            assert _program_trace().classify(call, f"grouped_{form}.1", "mosaic")[
+                "kernel"] == names.RAGGED_DOT_KERNEL
+            # what the call states, and what Mosaic took of it: the
+            # estimate is an upper bound that holds
+            (line,) = [line for line in hlo.splitlines()
+                       if 'custom_call_target="tpu_custom_call"' in line]
+            (stated,) = re.findall(
+                r'(?<!used_)scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)
+            (used,) = re.findall(
+                r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)
+            assert int(stated) == (tiling.vmem_estimate
+                                   + tiling.vmem_estimate // 2), (form, stated)
+            assert int(used) <= tiling.vmem_estimate, (form, used, tiling)
 
 
 def _elements(hlo):
@@ -868,8 +972,10 @@ def test_the_deepseek_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
     backward in the dense layer and in the scan of four expert layers: 4,
     and NO recomputed forward, since the rule keeps the kernel's o and lse
     (PR 56: an expert layer's block is the largest moment of its backward,
-    not the moments' sum) — and the held experts' grouped products with
-    their metadata kernels (30, as the LFM2 cell's)."""
+    not the moments' sum) — and the held experts' grouped products: 24,
+    since PR 60 the program's own kernels (`ops/grouped_matmul.py`), whose
+    visits are made by XLA — the compiler's kernel brought 6 metadata
+    kernels of its own beside its 24 calls."""
     from ray_tpu.models import blocks
     from ray_tpu.ops.attention import S_MINOR
     from ray_tpu.tracing import names
@@ -880,7 +986,7 @@ def test_the_deepseek_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
     compiled = fn.lower(*args).compile()
     hlo = compiled.as_text()
     assert blocks.compiler_rematerialized(hlo) == []
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 34
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 4 + 24
     flash = [op for _, code, op in _instructions(hlo)
              if code == "custom-call" and "flash_attention_" in op]
     assert sum(f"/{names.FLASH_FWD_KERNEL}" in op for op in flash) == 2

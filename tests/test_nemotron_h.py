@@ -479,10 +479,12 @@ def test_row_buffer_is_the_worst_case_where_that_is_small_and_passes_take_the_re
 @pytest.mark.parametrize("passes", [1, 2, 3])
 def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(
         passes, monkeypatch):
-    """The TPU's grouped kernel leaves the rows past the last group as it
-    found them (the chip run that lacked a mask: NaN gates' gradients, then a
-    NaN router, then every pair on the first experts and a DMA past the
-    buffer). Here `lax.ragged_dot` is made to leave NaN there, in its output
+    """The TPU's grouped kernels — the compiler's, and since PR 60 the
+    program's own (`ops/grouped_matmul.py`) — leave the rows past the last
+    group as they found them (the chip run that lacked a mask: NaN gates'
+    gradients, then a NaN router, then every pair on the first experts and a
+    DMA past the buffer). Here the passes' seam, `moe.grouped_dot`, is made
+    to leave NaN there, in its output
     and in its operand's cotangent: the layer and its gradients are what they
     were and what the float32 reference's are, the router's included — with
     one pass partly filled, and with two and three of which the last is."""
@@ -492,7 +494,8 @@ def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(
 
     @jax.custom_vjp
     def dirty(lhs, rhs, sizes):
-        return _nan_past(real(lhs, rhs, sizes), sizes)
+        return _nan_past(real(lhs, rhs, sizes, preferred_element_type=lhs.dtype),
+                         sizes)
 
     def _nan_past(x, sizes):
         return jnp.where(jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes),
@@ -503,7 +506,8 @@ def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(
 
     def bwd(res, g):
         lhs, rhs, sizes = res
-        _, vjp = jax.vjp(lambda l, r: real(l, r, sizes), lhs, rhs)
+        _, vjp = jax.vjp(lambda l, r: real(
+            l, r, sizes, preferred_element_type=l.dtype), lhs, rhs)
         d_lhs, d_rhs = vjp(jnp.where(jnp.isnan(g), 0, g))
         return _nan_past(d_lhs, sizes), d_rhs, None
 
@@ -515,7 +519,7 @@ def test_rows_past_the_last_group_never_reach_a_result_or_a_gradient(
     _fill_passes(monkeypatch, _held_load(cfg, p, x), passes)
     program, plain = _expert_layer_and_reference(cfg, params["blocks"])
     want = program(x)
-    monkeypatch.setattr(moe.lax, "ragged_dot", lambda l, r, s, **kw: dirty(l, r, s))
+    monkeypatch.setattr(moe, "grouped_dot", dirty)
     got = program(x)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert bool(jnp.all(jnp.isfinite(a)))

@@ -165,6 +165,19 @@ def test_scope_in_lowered_evabyte_step(scope):
         assert _has_scope(op_names, f"{names.EVA_ATTENTION}/{scope}")
 
 
+def _a_further_pass_enters_moe_routed_itself(op_names, scope):
+    """Since PR 60 `routed_experts` is no one scope: each part of a pass
+    enters `moe_routed` itself and the grouped products stand outside it (a
+    trace's reader adds their kernel's time to the scope's, once) — so the
+    further passes' loop is `moe_routed`'s neighbour, with the scope inside
+    its body, and no grouped product under it."""
+    if scope != names.MOE_FURTHER_PASSES:
+        return
+    assert _has_scope(op_names, f"{scope}/while/body/{names.MOE_ROUTED}")
+    products = [n for n in op_names if "ragged_dot" in n]
+    assert products and not [n for n in products if names.MOE_ROUTED in n]
+
+
 @pytest.mark.parametrize("scope", HYBRID_SCOPES)
 def test_scope_in_lowered_nemotron_step(scope):
     """Every kind of layer carries the block's scopes and its own, nested as
@@ -173,12 +186,13 @@ def test_scope_in_lowered_nemotron_step(scope):
     op_names, _ = _lowering("nemotron")
     assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
     inside = {names.SSD_SCAN: names.MAMBA, names.MOE_DISPATCH: names.MOE_ROUTED,
-              names.MOE_FURTHER_PASSES: names.MOE_ROUTED,
+              names.MOE_FURTHER_PASSES: names.MOE,
               names.MOE_ROUTED: names.MOE, names.MOE_LATENT: names.MOE,
               names.MOE_SHARED: f"{names.MOE}/{names.MLP}",
               names.MAMBA: names.BLOCK, names.MOE: names.BLOCK}
     if scope in inside:
         assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+    _a_further_pass_enters_moe_routed_itself(op_names, scope)
     if scope == names.MTP:
         for inner in (names.BLOCK, names.LM_HEAD_LOSS, names.LN_F):
             assert any(re.search(rf"{names.MTP}\)*/(.*/)?{inner}", n)
@@ -210,10 +224,11 @@ def test_scope_in_lowered_lfm2_step(scope):
     inside = {names.CONV_GATE: names.SHORT_CONV,
               names.SHORT_CONV: names.BLOCK,
               names.MOE_DISPATCH: names.MOE_ROUTED,
-              names.MOE_FURTHER_PASSES: names.MOE_ROUTED,
+              names.MOE_FURTHER_PASSES: names.MOE,
               names.MOE_ROUTED: names.MOE, names.MOE: names.BLOCK}
     if scope in inside:
         assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+    _a_further_pass_enters_moe_routed_itself(op_names, scope)
 
 
 @pytest.mark.parametrize("residual", LFM2_RESIDUALS)
@@ -335,12 +350,40 @@ def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
                                      + (names.RAGGED_DOT_KERNEL,))
 
 
+def _routed_experts_gradient_jaxpr():
+    """`ops/moe.routed_experts` and its gradient at the smallest shapes the
+    program's grouped kernels take (128-wide experts, a 512-row buffer)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    T, D, held = 256, 128, moe.Held(0, 4)
+    p = {"router_w": jnp.zeros((D, 8)), "router_bias": jnp.zeros((8,)),
+         "w1": jnp.zeros((held.count, D, D)), "w2": jnp.zeros((held.count, D, D))}
+    assert moe.row_buffer(T, 8, 2, held.count) == 512
+
+    def loss(u, p):
+        return jnp.sum(moe.routed_experts(u, u, p, top_k=2, held=held,
+                                          scaling=1.0)[0])
+
+    return str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+        jnp.zeros((T, D)), p))
+
+
 @pytest.mark.parametrize("kernel", names.KERNELS)
 def test_kernel_name_in_jaxpr(kernel):
     if kernel == names.RAGGED_DOT_KERNEL:
-        # the compiler's kernel: the program writes the primitive it becomes
+        # the grouped products: at a toy's widths the compiler's kernel —
+        # the program writes the primitive it becomes —, at whole lane tiles
+        # the program's own three (ops/grouped_matmul.py, PR 60), which a
+        # trace's reader finds under this name as a scope (`classify` below)
         _, jaxpr = _lowering("nemotron")
         assert "ragged_dot" in jaxpr
+        jaxpr = _routed_experts_gradient_jaxpr()
+        assert "ragged_dot" not in jaxpr
+        for form in ("gmm", "gmm_t", "tgmm"):
+            assert f"name=grouped_{form}" in jaxpr
         return
     _, jaxpr = _lowering("eva" if kernel in EVA_KERNELS else
                          "nemotron" if kernel in SSD_KERNELS else
@@ -622,7 +665,8 @@ def test_iterator_spans_on_the_profiler_clock(tmp_path, monkeypatch, buffer):
 
 
 # ------------------------------------------------------- the trace's reader
-@pytest.mark.parametrize("tf_op, want", [
+@pytest.mark.parametrize("tf_op, hlo_name, want", [
+    (case[0], (case + ("fusion.1",))[2], case[1]) for case in [
     ("jit(step)/jvp()/while/body/closed_call/block/mlp/tanh:",
      dict(direction="fwd", scopes=["block", "mlp"], remat=False, stack=False)),
     ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
@@ -640,13 +684,30 @@ def test_iterator_spans_on_the_profiler_clock(tmp_path, monkeypatch, buffer):
     ("", dict(direction="other", scopes=[], remat=False, stack=False)),
     # the compiler's grouped kernel: no op_name of the program's, its own name
     ("ragged-dot-none:", dict(direction="other", scopes=[],
-                              kernel="ragged-dot", stack=False)),
-])
-def test_classify_by_the_programs_names(tf_op, want):
+                              kernel="ragged-dot", stack=False),
+     "ragged-dot-none.3"),
+    # the program's grouped kernels (PR 60; op_names of the described-v5e
+    # compiles): the instruction is named after the form, the reader's name
+    # is a scope around the call, which AD wraps — found as the SAME kernel,
+    # under `block` and `moe` but under no `moe_routed` (whose time the
+    # reader adds this kernel's to), with a direction and the recompute's mark
+    ("jit(step)/jvp(block)/moe/jvp(ragged-dot)/grouped_gmm/pallas_call:",
+     dict(direction="fwd", scopes=["block", "moe"], kernel="ragged-dot",
+          remat=False, stack=False), "grouped_gmm.4"),
+    ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/block/moe/transpose(jvp(ragged-dot))/"
+     "grouped_tgmm/pallas_call:",
+     dict(direction="bwd", scopes=["block", "moe"], kernel="ragged-dot",
+          remat=True, stack=False), "grouped_tgmm.7"),
+    ("jit(loss)/transpose(jvp(moe_further_passes))/while/body/"
+     "transpose(jvp(ragged-dot))/grouped_gmm_t/pallas_call:",
+     dict(direction="bwd", scopes=["moe_further_passes"],
+          kernel="ragged-dot", stack=False), "grouped_gmm_t.24"),
+]])
+def test_classify_by_the_programs_names(tf_op, hlo_name, want):
     from benchmarks.harness import program_trace
 
-    got = program_trace.classify(
-        tf_op, "ragged-dot-none.3" if "ragged" in tf_op else "fusion.1", "op")
+    got = program_trace.classify(tf_op, hlo_name, "op")
     assert {k: got[k] for k in want} == want
 
 
