@@ -34,7 +34,12 @@ compression, the biased-sigmoid router, an MTP module and four
 manifold-constrained hyper-connection streams around every sublayer, PR 57)
 for its ``model/hyper_connection`` event, the ``ops/mhc_tiling`` decisions
 of its hyper-connection kernels (PR 58: one of each of the four, or it
-fails) and its expert layers' loads, the MTP module's among them, and —
+fails) and its expert layers' loads, the MTP module's among them, one step of
+a small Qwen3-Next (``models/qwen3_next.py``: three Gated DeltaNet layers on
+the delta rule's kernel pair, ``ops/gated_delta.py``, and one output-gated
+attention layer at hd 256, each over gated experts beside a gated shared one,
+PR 61) for its ``LLLF`` pattern, its ``model/remat_policy`` decision and the
+``ops/delta_tiling`` decisions of both kernels (or it fails), and —
 what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
@@ -497,6 +502,49 @@ def train_loop(config: Dict[str, Any]) -> None:
                  "expert_load": load,
                  "step_load": np.asarray(m["counters"]).tolist()}
         del variant
+    # One step of a Gated DeltaNet / gated attention hybrid over experts
+    # beside a gated shared one: the delta rule's kernel pair at two value
+    # heads a key head, the flash pair at hd 256.
+    qwen3 = None
+    if config.get("qwen3_model") is not None:
+        from ray_tpu.models import qwen3_next
+        from ray_tpu.models.blocks import layer_pattern_decisions
+        from ray_tpu.ops.gated_delta import delta_tiling_decisions
+
+        qwen3_cfg = config["qwen3_model"]
+        variant = make_train_step(
+            qwen3_next, qwen3_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP,
+                                        total_steps=steps,
+                                        decay_mask=qwen3_next.decays))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, qwen3_cfg.seq_len), dtype=np.int32)
+        qwen3_batch = jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding)
+        with mesh_lib.use_mesh(mesh):
+            params, load = qwen3_next.balance_routers(
+                variant.state["params"], qwen3_batch["tokens"], qwen3_cfg)
+        _, m = variant.step_fn({**variant.state, "params": params},
+                               qwen3_batch)
+        counters = np.asarray(m["counters"])
+        n_load = len(qwen3_next.step_fields(qwen3_cfg)) - 1
+        qwen3 = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+                 "seq_len": qwen3_cfg.seq_len,
+                 "layer_pattern": [d for d in layer_pattern_decisions()
+                                   if d["pattern"] == qwen3_cfg.pattern],
+                 "remat_policy": [d for d in remat_policy_decisions()
+                                  if d["n_layer"] == qwen3_cfg.n_layer
+                                  and d["seq"] == qwen3_cfg.seq_len],
+                 "delta_tiling": [d for d in delta_tiling_decisions()
+                                  if d["S"] == qwen3_cfg.seq_len],
+                 "flash_tiling": [d for d in flash_tiling_decisions()
+                                  if d["hd"] == qwen3_cfg.head_dim],
+                 "expert_load": load,
+                 "step_load": counters[:, :n_load].tolist(),
+                 "balance_loss": np.ascontiguousarray(
+                     counters[:, n_load]).view(np.float32).tolist()}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -527,6 +575,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         "lfm2": lfm2,
         "dsv2": dsv2,
         "xing4": xing4,
+        "qwen3": qwen3,
     }})
 
 
@@ -534,7 +583,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         use_tpu: bool, seed: int = 0, eva_model=None,
         hybrid_model=None, sala_model=None,
         lfm2_model=None, dsv2_model=None,
-        xing4_model=None, grouped_shapes=GROUPED_SHAPES
+        xing4_model=None, qwen3_model=None, grouped_shapes=GROUPED_SHAPES
         ) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
@@ -559,7 +608,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "eva_model": eva_model, "hybrid_model": hybrid_model,
             "sala_model": sala_model, "lfm2_model": lfm2_model,
             "dsv2_model": dsv2_model, "xing4_model": xing4_model,
-            "grouped_shapes": grouped_shapes,
+            "qwen3_model": qwen3_model, "grouped_shapes": grouped_shapes,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -708,6 +757,29 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if not all(0.5 < b < 8.0 for b in dsv2["balance_loss"]):
             bad.append("the DeepSeek-V2 step said balance losses "
                        f"{dsv2['balance_loss']} of its expert layers")
+    qwen3 = summary.get("qwen3")
+    if qwen3 is not None:
+        if not (math.isfinite(qwen3["loss"])
+                and math.isfinite(qwen3["grad_norm"])):
+            bad.append(f"the Qwen3-Next step's loss {qwen3['loss']} or "
+                       f"grad_norm {qwen3['grad_norm']} is not finite")
+        if not qwen3["layer_pattern"] or not qwen3["remat_policy"]:
+            bad.append("the Qwen3-Next step recorded no model/layer_pattern "
+                       "or no model/remat_policy event for its layers")
+        if {d["kernel"] for d in qwen3["delta_tiling"]} != {"fwd", "bwd"}:
+            bad.append("the Qwen3-Next step recorded no ops/delta_tiling "
+                       "decision of both kernels: the delta rule ran on no "
+                       "kernel of the program's")
+        if {d["kernel"] for d in qwen3["flash_tiling"]} != {"fwd", "bwd"}:
+            bad.append("the Qwen3-Next step recorded no ops/flash_tiling "
+                       "decision of both kernels at its head width")
+        dropped = sum(e["pairs_dropped"] for e in qwen3["expert_load"])
+        if not qwen3["expert_load"] or dropped:
+            bad.append(f"the Qwen3-Next step's expert halves recorded no "
+                       f"model/expert_load event or dropped {dropped} pairs")
+        if not all(0.5 < b < 8.0 for b in qwen3["balance_loss"]):
+            bad.append("the Qwen3-Next step said balance losses "
+                       f"{qwen3['balance_loss']} of its layers")
     xing4 = summary.get("xing4")
     if xing4 is not None:
         if not (math.isfinite(xing4["loss"])
@@ -880,7 +952,7 @@ def main() -> int:
 
     import ray_tpu
     from ray_tpu.core.resources import tpu_device_files
-    from ray_tpu.models import (deepseek_v2, gpt2, lfm2_moe, llama,
+    from ray_tpu.models import (deepseek_v2, gpt2, lfm2_moe, llama, qwen3_next,
                                 minicpm_sala, nemotron_h)
     from ray_tpu.ops.sparse_attention import SparseSizes
 
@@ -927,6 +999,15 @@ def main() -> int:
         d_expert=512, n_shared=1, routed_scaling=2.0, scoring="sigmoid",
         norm_topk_prob=True, selection_bias=True, aux_loss_alpha=0.0,
         hc_mult=4, mtp_layers=1, remat=True)
+    # Qwen3-Next's layers 0-3 at half the width: the delta rule at the
+    # published head widths (8 key heads serving 16 value heads at 128, chunk
+    # 64: two value heads a grid step), gated attention at hd 256 (8 query
+    # heads on 2 key-value heads, rotary on 64), 16 of 128 experts held,
+    # top-10 by a normalised softmax beside a gated shared expert
+    qwen3_cfg = qwen3_next.Qwen3NextConfig(
+        vocab_size=4096, seq_len=2048, n_layer=4, d_model=1024, n_head=8,
+        linear_key_heads=8, linear_value_heads=16, n_experts=128,
+        held_count=16, d_expert=512, d_shared=512, remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -940,7 +1021,7 @@ def main() -> int:
                    num_devices=chips, use_tpu=True, eva_model=eva_cfg,
                    hybrid_model=hybrid_cfg, sala_model=sala_cfg,
                    lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg,
-                   xing4_model=xing4_cfg)
+                   xing4_model=xing4_cfg, qwen3_model=qwen3_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -957,7 +1038,8 @@ def main() -> int:
     failures += missing
     step_loads = {}
     for what, key in (("hybrid", "hybrid"), ("LFM2-MoE", "lfm2"),
-                      ("DeepSeek-V2", "dsv2"), ("Xing4.0", "xing4")):
+                      ("DeepSeek-V2", "dsv2"), ("Xing4.0", "xing4"),
+                      ("Qwen3-Next", "qwen3")):
         step_loads[key], missing = step_load_line(record, summary[key], what)
         failures += missing
 
@@ -1114,6 +1196,37 @@ def main() -> int:
           f"of {xing4_cfg.hc_mult} x {xing4_cfg.d_model}, "
           f"{summary['device_count']}x{xing4['seq_len']} tokens, remat): loss "
           f"{xing4['loss']:.4f} grad_norm {xing4['grad_norm']:.4f}")
+    qwen3 = summary["qwen3"]
+    for d in qwen3["layer_pattern"]:
+        print(f"layer pattern: {d['pattern']} -> {d['applications']} as "
+              f"{d['groups']}")
+    for d in qwen3["delta_tiling"]:
+        print(f"delta tiling: {d['kernel']} rows={d['rows']} S={d['S']} "
+              f"chunk={d['C']}, {d['key_heads']} key heads x "
+              f"{d['value_heads_per_key']} value heads at {d['dk']} / "
+              f"{d['dv']} -> {d['head_tile']} value head(s) a grid step, VMEM "
+              f"estimate {d['vmem_estimate'] / 2 ** 20:.2f} MiB")
+    for d in qwen3["flash_tiling"]:
+        print(f"flash tiling: {d['kernel']} rows={d['rows']} Sq={d['Sq']} "
+              f"hd={d['hd']} -> block_q={d['block_q']} block_k={d['block_k']} "
+              f"layout={d['layout']}")
+    for d in qwen3["remat_policy"]:
+        print(f"Qwen3-Next remat policy: {d['n_layer']} layers of two kinds, "
+              f"batch={d['batch']} seq={d['seq']}: saved={d['saved']} "
+              f"({d['saved_bytes'] / gib:.2f} GiB of {d['budget_bytes'] / gib:.2f}"
+              f" left by the backward's phase {d['phase']!r})")
+    for e in qwen3["expert_load"]:
+        print(f"Qwen3-Next expert load: published layer {e['layer']}: "
+              f"{e['pairs']} pairs of {e['tokens']} tokens on the held "
+              f"experts (max {e['max_per_expert']}, mean "
+              f"{e['mean_per_expert']:.1f} an expert), {e['buffer_passes']} "
+              f"pass(es) over a buffer of {e['buffer_rows']} rows, dropped "
+              f"{e['pairs_dropped']}")
+    print("\n".join(step_loads["qwen3"]))
+    print(f"Qwen3-Next step ({qwen3_cfg.pattern} of {qwen3_cfg.d_model}, "
+          f"{summary['device_count']}x{qwen3['seq_len']} tokens, remat): loss "
+          f"{qwen3['loss']:.4f} grad_norm {qwen3['grad_norm']:.4f}, balance "
+          f"loss a layer {[round(b, 4) for b in qwen3['balance_loss']]}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

@@ -865,13 +865,14 @@ def latent_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
 def gated_moe_init(rng: jax.Array, n_layers: int, d_model: int,
                    n_experts: int, held: int, d_expert: int, std: float,
                    out_std: float, param_dtype=jnp.float32, *,
-                   selection_bias: bool = True, d_shared: int = 0
-                   ) -> Dict[str, Any]:
+                   selection_bias: bool = True, d_shared: int = 0,
+                   shared_gate: bool = False) -> Dict[str, Any]:
     """``n_layers`` stacked layers of gated_moe: the router over all
     ``n_experts`` and its selection bias (a buffer, as latent_moe_init's;
     none without ``selection_bias``: a router balanced by a loss has none),
     ``held`` SiLU-gated experts at the model's width and, with ``d_shared``,
-    one shared expert of the same form at that hidden width beside them."""
+    one shared expert of the same form at that hidden width beside them —
+    with ``shared_gate`` under a per-token gate, one column ``w_g``."""
     k = iter(jax.random.split(rng, 5))
     L = n_layers
 
@@ -893,6 +894,9 @@ def gated_moe_init(rng: jax.Array, n_layers: int, d_model: int,
         p.update(shared_w1=normal(ks[0], (L, d_model, d_shared), std),
                  shared_w3=normal(ks[1], (L, d_model, d_shared), std),
                  shared_w2=normal(ks[2], (L, d_shared, d_model), out_std))
+    if shared_gate:
+        p["shared_gate"] = normal(jax.random.fold_in(rng, 2),
+                                  (L, d_model, 1), std)
     return p
 
 
@@ -906,6 +910,7 @@ def gated_moe_logical_axes() -> Dict[str, Any]:
         "shared_w1": ("layers", "embed", "mlp"),
         "shared_w3": ("layers", "embed", "mlp"),
         "shared_w2": ("layers", "mlp", "embed"),
+        "shared_gate": ("layers", "embed", None),
     }
 
 
@@ -925,8 +930,10 @@ def gated_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
     tensors hold a shared expert (GATED_SHARED_EXPERT, compute dtype) adds
     ``(silu(u·S1) ⊙ u·S3)·S2`` of every token, whole on every chip — with
     ``shared_rows`` < S in chunks of that many rows, each its own
-    ``checkpoint``, as latent_moe's. ``balance``: the load holds the layer's
-    balance_loss over rows of S tokens."""
+    ``checkpoint``, as latent_moe's —, times the token's ``sigmoid(u · w_g)``
+    where the tensors hold ``shared_gate`` (w_g [D, 1], compute dtype; the
+    gate in float32). ``balance``: the load holds the layer's balance_loss
+    over rows of S tokens."""
     B, S, D = u.shape
     ut = u.reshape(B * S, D)
     # (a caller with the default router and no balance loss says what it
@@ -949,9 +956,13 @@ def gated_moe(u: jax.Array, p: Dict[str, Any], *, top_k: int, held: Held,
             up = checkpoint_name(
                 jnp.einsum("bsd,df->bsf", u_rows, p["shared_w3"]),
                 scopes.RES_MOE_SHARED_UP)
-            return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                              p["shared_w2"],
-                              preferred_element_type=jnp.float32)
+            y = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                           p["shared_w2"], preferred_element_type=jnp.float32)
+            if "shared_gate" not in p:
+                return y
+            return y * jax.nn.sigmoid(jnp.einsum(
+                "bsd,do->bso", u_rows, p["shared_gate"],
+                preferred_element_type=jnp.float32))
 
     return _beside_shared(out, shared, u, shared_rows), load
 

@@ -83,6 +83,16 @@ MOE_AUX = "moe_aux"
 # sigmoids and the Sinkhorn rounds on the logits' planes
 MHC = "mhc"
 MHC_MAPS = "mhc_maps"
+# models/qwen3_next.py: the Gated DeltaNet mixer — the fused q, k, v, z
+# projection and the b, a one, the causal conv with its SiLU, the L2 norms,
+# the gates, the scan, the gated per-head norm and the out-projection — and,
+# inside it, the scan alone (ops/gated_delta.gated_delta_scan: the kernel
+# pair and what XLA prepares for it — the cumulative gates, padding, the
+# rows' layout); and, inside the gated attention layer's `attn`, the sigmoid
+# output gate's product with the kernel's output
+DELTA_MIXER = "delta_mixer"
+GATED_DELTA = "gated_delta"
+GATED_ATTN_GATE = "gated_attn_gate"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
                                           EVA_PREP_KV, MAMBA, SSD_SCAN,
@@ -91,7 +101,9 @@ SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           SPARSE_ATTENTION, SPARSE_SELECT,
                                           SHORT_CONV, CONV_GATE,
                                           MOE_FURTHER_PASSES, MLA_LATENT,
-                                          MOE_AUX, MHC, MHC_MAPS)
+                                          MOE_AUX, MHC, MHC_MAPS,
+                                          DELTA_MIXER, GATED_DELTA,
+                                          GATED_ATTN_GATE)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -138,12 +150,19 @@ MHC_MIX_FWD_KERNEL = "mhc_mix_fwd"
 MHC_MIX_BWD_KERNEL = "mhc_mix_bwd"
 MHC_WRITE_FWD_KERNEL = "mhc_write_fwd"
 MHC_WRITE_BWD_KERNEL = "mhc_write_bwd"
+# the gated delta rule (ops/gated_delta.py): a chunk's masked products after
+# its unit-lower-triangular solve, the state's correction and the carried
+# state's part of o, a (row, tile of one key head's value heads) at a time
+# along the row's chunks — and the same tiles' gradients, the chunks reversed
+GATED_DELTA_FWD_KERNEL = "gated_delta_fwd"
+GATED_DELTA_BWD_KERNEL = "gated_delta_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
            SSD_CHUNK_BWD_KERNEL, SPARSE_ATTN_FWD_KERNEL,
            SPARSE_ATTN_BWD_DQ_KERNEL, SPARSE_ATTN_BWD_DKV_KERNEL,
            CONV_GATE_FWD_KERNEL, CONV_GATE_BWD_KERNEL, MHC_MIX_FWD_KERNEL,
-           MHC_MIX_BWD_KERNEL, MHC_WRITE_FWD_KERNEL, MHC_WRITE_BWD_KERNEL)
+           MHC_MIX_BWD_KERNEL, MHC_WRITE_FWD_KERNEL, MHC_WRITE_BWD_KERNEL,
+           GATED_DELTA_FWD_KERNEL, GATED_DELTA_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
@@ -163,6 +182,14 @@ EVA_TILING_ARGS = _TILE_ARGS + ("window", "chunk")
 SSD_TILING = "ops/ssd_tiling"
 SSD_TILING_ARGS = ("kernel", "rows", "S", "Q", "group_heads", "P", "N",
                    "head_tile", "vmem_estimate")
+# the same for a delta-rule kernel (ops/gated_delta.py): the batch rows, the
+# (padded) sequence, the chunk, the key heads and the value heads each serves,
+# the two head widths, the value heads of one key head a grid step stacks and
+# the key heads (each such a stack) it takes
+DELTA_TILING = "ops/delta_tiling"
+DELTA_TILING_ARGS = ("kernel", "rows", "S", "C", "key_heads",
+                     "value_heads_per_key", "dk", "dv", "head_tile",
+                     "key_tile", "vmem_estimate")
 # the same for a block-sparse attention kernel ("fwd", "bwd_dq", "bwd_dkv"):
 # the (batch x key-value head) rows, the sequence, the query heads that share
 # a key-value head (the rows of a tile's products, with `block_q` tokens),
@@ -259,6 +286,17 @@ RES_CONV_BCX = "conv_bcx"
 # probabilities there) and its shared expert's two hidden tensors
 RES_MLA_C, RES_MLA_KPE = "mla_latent_c", "mla_k_pe"
 RES_MOE_SHARED_GATE, RES_MOE_SHARED_UP = "moe_shared_gate", "moe_shared_up"
+# a Qwen3-Next layer's (models/qwen3_next.py). The Gated DeltaNet mixer names
+# its fused projection's four parts (q, k, v before the conv, and z: the
+# mixer's one large weight, a product a part) and the b, a one's, the state
+# each chunk of the scan starts from and the scan's output; the gated attention layer q, k (after the QK-norm
+# and the partial rotation) and v as the others do, the flash kernel's two,
+# and its output gate's pre-activation; both halves RES_MID and the expert
+# half the routing's names and the shared expert's two hidden tensors
+RES_DELTA_PARTS = ("delta_q", "delta_k", "delta_v", "delta_z")
+RES_DELTA_BA = "delta_ba"
+RES_DELTA_STATES, RES_DELTA_O = "delta_states", "delta_o"
+RES_ATTN_GATE = "attn_gate"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
              RES_MLP_GATE, RES_MLP_UP, RES_MAMBA_Z, RES_MAMBA_XBC, RES_MAMBA_DT,
@@ -266,7 +304,9 @@ RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MOE_SCORES, RES_MOE_KTH, RES_MOE_LAST, RES_MOE_PAIR_KEY,
              RES_SALA_GATE, RES_LIGHTNING_Y, RES_SPARSE_IDS, RES_SPARSE_O,
              RES_SPARSE_LSE, RES_CONV_BCX, RES_MOE_PAIR_GATE, RES_MLA_C,
-             RES_MLA_KPE, RES_MOE_SHARED_GATE, RES_MOE_SHARED_UP)
+             RES_MLA_KPE, RES_MOE_SHARED_GATE, RES_MOE_SHARED_UP,
+             *RES_DELTA_PARTS, RES_DELTA_BA, RES_DELTA_STATES, RES_DELTA_O,
+             RES_ATTN_GATE)
 # which of them models/blocks.py chose to save, the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once),
 # and the phase of the backward whose working set the budget was left by

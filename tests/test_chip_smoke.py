@@ -387,6 +387,39 @@ def test_gpt2_through_trainer_and_iterator_at_toy_size(fake_tpu_node):
     assert chip_smoke.check_device(summary, cfg, 1, advertised_tpus=8) != []
 
 
+def test_qwen3_next_through_trainer_at_toy_size(fake_tpu_node):
+    """chip_smoke's loop with the small Qwen3-Next step alone beside GPT-2's
+    (PR 61; a test of its own: with it the one above passed its time limit
+    under the suite's six workers)."""
+    import chip_smoke
+    from ray_tpu.models import gpt2, qwen3_next
+
+    cfg, steps = gpt2.gpt2_tiny(), 16
+    rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1, num_devices=8,
+                          use_tpu=False,
+                          qwen3_model=qwen3_next.qwen3_next_tiny(
+                              remat=True, attention_impl="pallas"),
+                          grouped_shapes=())
+    assert chip_smoke.check_training(rows, cfg, steps) == []
+    summary = rows[-1]["summary"]
+    # its pattern, the delta rule's two kernels'
+    # tilings (both value heads of a key head a grid step), the flash pair at
+    # its head width, its four layers' loads and balance losses came back;
+    # and the check fails without them
+    qwen3 = summary["qwen3"]
+    assert [d["groups"] for d in qwen3["layer_pattern"]] == [
+        ["3 x scan(L)", "F"]]
+    assert {(d["kernel"], d["C"], d["head_tile"])
+            for d in qwen3["delta_tiling"]} == {("fwd", 16, 2), ("bwd", 16, 2)}
+    assert [e["layer"] for e in qwen3["expert_load"]] == [0, 1, 2, 3]
+    assert np.asarray(qwen3["step_load"]).shape == (4, 3)
+    assert all(0.8 < b < 2.0 for b in qwen3["balance_loss"])
+    no_kernel = [rows[-1] | {"summary": summary | {"qwen3": qwen3 | {
+        "delta_tiling": [], "layer_pattern": [], "expert_load": []}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + no_kernel, cfg, steps)
+               ) == 3
+
+
 def test_step_load_line_finds_the_steps_own_event_or_fails():
     """chip_smoke reads a toy expert step's run-time load from the session's
     record — the `train/step_counters` event whose rows are the step's own

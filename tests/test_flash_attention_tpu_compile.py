@@ -219,6 +219,44 @@ def test_scan_kernels_compile_for_the_v5e(one_chip, shape):
     assert all(n <= B * (S // Q) * G * Q * Q for n in sizes), set(per_head)
 
 
+@pytest.mark.parametrize("shape", [
+    (4, 8192, 16, 32, 128, 128, 64),    # the Qwen3-Next cell: two value heads
+                                        # a key head, stacked at chunk 64
+    (1, 2000, 4, 4, 128, 256, 128),     # one value head a key head at chunk
+                                        # 128, unequal widths, a padded row
+], ids=["qwen3-next-cell", "one-head-c128"])
+def test_delta_rule_kernels_compile_for_the_v5e(one_chip, shape):
+    """ops/gated_delta's kernel pair (PR 61) at the tile the rule chooses,
+    forward and backward, bf16: what interpret mode cannot show — the stacked
+    heads' sublane slices, the row / column spreads of the gates, the masks'
+    bit arithmetic, the state scratch — and that k and q reach the kernels at
+    the KEY heads' width: no value head's copy of either exists."""
+    import functools
+
+    from ray_tpu.ops import gated_delta
+
+    B, S, Hk, Hv, dk, dv, C = shape
+    sd = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    key, gate = sd((B, S, Hk, dk), jnp.bfloat16), sd((B, S, Hv), jnp.float32)
+    args = (key, key, sd((B, S, Hv, dv), jnp.bfloat16), gate, gate)
+    scan = functools.partial(gated_delta._kernel_scan, chunk=C,
+                             interpret=False)
+    hlo = jax.jit(jax.grad(
+        lambda *a: jnp.sum(jnp.sin(scan(*a).astype(jnp.float32))),
+        argnums=(0, 1, 2, 3, 4))).lower(*args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    padded = -(-S // C) * C
+    mine = {d["kernel"]: d for d in gated_delta.delta_tiling_decisions()
+            if (d["rows"], d["S"], d["C"], d["key_heads"]) == (B, padded, C, Hk)}
+    assert set(mine) == {"fwd", "bwd"}
+    assert all(d["head_tile"] == min(Hv // Hk, 128 // C)
+               for d in mine.values())
+    # q and k are the calls' operands at the key heads' own width
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert all(f"bf16[{B},{padded},{Hk * dk}]" in line for line in calls)
+
+
 @pytest.mark.parametrize("S,top_k", [(16384, 64), (2048, 8)],
                          ids=["minicpm-sala-cell", "short-row"])
 def test_sparse_attention_kernels_compile_for_the_v5e(one_chip, S, top_k):

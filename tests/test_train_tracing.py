@@ -45,6 +45,9 @@ LOWERINGS = {
     # the DeepSeek-V2 family's layer inside four hyper-connection streams a
     # lane tile wide: the kernel pairs of ops/hyper_connections.py (PR 58)
     "xing4": dict(remat=True, hc_sinkhorn_iters=3),
+    # a pattern of Gated DeltaNet and gated attention layers over experts
+    # beside a gated shared one: the delta rule's kernel pair (PR 61)
+    "qwen3": dict(remat=True, attention_impl="pallas"),
 }
 # each model's own mixer: GPT-2 has the flash kernels, EvaByte the EVA ones
 EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
@@ -64,6 +67,12 @@ DSV2_OWN_SCOPES = (names.MLA_LATENT, names.MOE_AUX)
 # the hyper-connected residual path (Xing4.0, PR 57: tests/test_xing4.py holds
 # its lowered step)
 DSV2_OWN_SCOPES += (names.MHC, names.MHC_MAPS)
+# the Gated DeltaNet mixer, its scan and attention's output gate (Qwen3-Next,
+# PR 61)
+QWEN3_OWN_SCOPES = (names.DELTA_MIXER, names.GATED_DELTA,
+                    names.GATED_ATTN_GATE)
+DSV2_OWN_SCOPES += QWEN3_OWN_SCOPES     # (no other family's step has them)
+DELTA_KERNELS = (names.GATED_DELTA_FWD_KERNEL, names.GATED_DELTA_BWD_KERNEL)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 MHC_KERNELS = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL,
                names.MHC_WRITE_FWD_KERNEL, names.MHC_WRITE_BWD_KERNEL)
@@ -106,7 +115,8 @@ _lowered = {}
 def _step(key):
     """(bundle, batch of 2) of the tiny train step `key` names, built anew."""
     from ray_tpu.models import (
-        deepseek_v2, gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h)
+        deepseek_v2, gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h,
+        qwen3_next)
     from ray_tpu.train.train_step import (
         make_gpt2_train_step, make_train_step, synthetic_batch)
 
@@ -125,6 +135,9 @@ def _step(key):
     elif key == "xing4":
         cfg = deepseek_v2.xing4_tiny(**LOWERINGS[key])
         bundle = make_train_step(deepseek_v2, cfg)
+    elif key == "qwen3":
+        cfg = qwen3_next.qwen3_next_tiny(**LOWERINGS[key])
+        bundle = make_train_step(qwen3_next, cfg)
     else:
         cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
         bundle = make_gpt2_train_step(cfg)
@@ -229,6 +242,25 @@ def test_scope_in_lowered_lfm2_step(scope):
     if scope in inside:
         assert _has_scope(op_names, f"{inside[scope]}/{scope}")
     _a_further_pass_enters_moe_routed_itself(op_names, scope)
+
+
+@pytest.mark.parametrize("scope", QWEN3_OWN_SCOPES + (
+    names.MOE, names.MOE_ROUTED, names.MOE_DISPATCH, names.MOE_SHARED,
+    names.MOE_AUX, names.FLASH_ATTENTION, names.QKV, names.ATTN, names.PROJ))
+def test_scope_in_lowered_qwen3_next_step(scope):
+    """Both kinds of layer carry the block's scopes and their own: the scan
+    inside the DeltaNet mixer, the output gate inside `attn`, the shared
+    expert under `mlp` inside `moe`."""
+    op_names, _ = _lowering("qwen3")
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+    inside = {names.GATED_DELTA: names.DELTA_MIXER,
+              names.DELTA_MIXER: names.BLOCK,
+              names.GATED_ATTN_GATE: names.ATTN,
+              names.MOE_DISPATCH: names.MOE_ROUTED,
+              names.MOE_SHARED: f"{names.MOE}/{names.MLP}",
+              names.MOE: names.BLOCK}
+    if scope in inside:
+        assert _has_scope(op_names, f"{inside[scope]}/{scope}")
 
 
 @pytest.mark.parametrize("residual", LFM2_RESIDUALS)
@@ -346,7 +378,7 @@ def test_remat_recompute_keeps_the_block_scopes(blocks):
 def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
     assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS + SSD_KERNELS
                                      + SPARSE_KERNELS + CONV_KERNELS
-                                     + MHC_KERNELS
+                                     + MHC_KERNELS + DELTA_KERNELS
                                      + (names.RAGGED_DOT_KERNEL,))
 
 
@@ -389,7 +421,8 @@ def test_kernel_name_in_jaxpr(kernel):
                          "nemotron" if kernel in SSD_KERNELS else
                          "sala" if kernel in SPARSE_KERNELS else
                          "lfm2" if kernel in CONV_KERNELS else
-                         "xing4" if kernel in MHC_KERNELS else "remat")
+                         "xing4" if kernel in MHC_KERNELS else
+                         "qwen3" if kernel in DELTA_KERNELS else "remat")
     assert f"name={kernel}" in jaxpr
 
 
@@ -1115,7 +1148,7 @@ def _counters_event(step, t_dispatch, passes, pairs, fullest, held=8):
 def test_counter_reader_against_the_recorded_session(metric):
     """Each reader on the `train/*` events of a recorded rehearsal of the
     Nemotron cell: the number `benchmarks/testdata/` holds for the window
-    its summary's wall clocks cut; the entry names the three expert cells."""
+    its summary's wall clocks cut; the entry names the expert cells."""
     import gzip
     import json
 
@@ -1129,7 +1162,8 @@ def test_counter_reader_against_the_recorded_session(metric):
     assert entry["workloads"] == ["nemotron-3-super-120b-l11.dataset",
                                   "lfm2-24b-a2b-l5.dataset",
                                   "deepseek-v2-lite-l5.dataset",
-                                  "xing4.0-29b-a4b-l5.dataset"]
+                                  "xing4.0-29b-a4b-l5.dataset",
+                                  "qwen3-next-80b-a3b-l4.dataset"]
     facts = _record_facts(events, expected["summary"])
     assert reader.read(facts) == pytest.approx(expected["metrics"][metric],
                                                rel=1e-12)
