@@ -36,10 +36,11 @@ for its ``model/hyper_connection`` event, the ``ops/mhc_tiling`` decisions
 of its hyper-connection kernels (PR 58: one of each of the four, or it
 fails) and its expert layers' loads, the MTP module's among them, one step of
 a small Qwen3-Next (``models/qwen3_next.py``: three Gated DeltaNet layers on
-the delta rule's kernel pair, ``ops/gated_delta.py``, and one output-gated
+the delta rule's three kernels, ``ops/gated_delta.py``, and one output-gated
 attention layer at hd 256, each over gated experts beside a gated shared one,
 PR 61) for its ``LLLF`` pattern, its ``model/remat_policy`` decision and the
-``ops/delta_tiling`` decisions of both kernels (or it fails), and —
+``ops/delta_tiling`` decisions of the solve, the forward and the backward
+kernel (or it fails), and —
 what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
@@ -503,8 +504,8 @@ def train_loop(config: Dict[str, Any]) -> None:
                  "step_load": np.asarray(m["counters"]).tolist()}
         del variant
     # One step of a Gated DeltaNet / gated attention hybrid over experts
-    # beside a gated shared one: the delta rule's kernel pair at two value
-    # heads a key head, the flash pair at hd 256.
+    # beside a gated shared one: the delta rule's solve, forward and backward
+    # kernels at two value heads a key head, the flash pair at hd 256.
     qwen3 = None
     if config.get("qwen3_model") is not None:
         from ray_tpu.models import qwen3_next
@@ -766,10 +767,12 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if not qwen3["layer_pattern"] or not qwen3["remat_policy"]:
             bad.append("the Qwen3-Next step recorded no model/layer_pattern "
                        "or no model/remat_policy event for its layers")
-        if {d["kernel"] for d in qwen3["delta_tiling"]} != {"fwd", "bwd"}:
+        if {d["kernel"] for d in qwen3["delta_tiling"]} != {"solve", "fwd",
+                                                            "bwd"}:
             bad.append("the Qwen3-Next step recorded no ops/delta_tiling "
-                       "decision of both kernels: the delta rule ran on no "
-                       "kernel of the program's")
+                       "decision of each of the solve, the forward and the "
+                       "backward kernel: the delta rule ran on no kernel of "
+                       "the program's, or solved inside the other two")
         if {d["kernel"] for d in qwen3["flash_tiling"]} != {"fwd", "bwd"}:
             bad.append("the Qwen3-Next step recorded no ops/flash_tiling "
                        "decision of both kernels at its head width")
@@ -1204,7 +1207,8 @@ def main() -> int:
         print(f"delta tiling: {d['kernel']} rows={d['rows']} S={d['S']} "
               f"chunk={d['C']}, {d['key_heads']} key heads x "
               f"{d['value_heads_per_key']} value heads at {d['dk']} / "
-              f"{d['dv']} -> {d['head_tile']} value head(s) a grid step, VMEM "
+              f"{d['dv']} -> {d['head_tile']} value head(s) of "
+              f"{d['key_tile']} key head(s) a grid step, VMEM "
               f"estimate {d['vmem_estimate'] / 2 ** 20:.2f} MiB")
     for d in qwen3["flash_tiling"]:
         print(f"flash tiling: {d['kernel']} rows={d['rows']} Sq={d['Sq']} "

@@ -484,12 +484,13 @@ def kind_shards(cfg: Qwen3NextConfig, global_batch: int, seq: int, mesh
     - The expert half's backward. The whole block's forward has been made
       again by then, so the mixer's residual set waits: its input and ``u``,
       the delta mixer's fused projection, the conv's output, q, k, v as the
-      scan reads them, the states a chunk starts from (float32 [d_k, d_v] a
-      value head and chunk: 8 × v's bytes at the published sizes), o and the
-      gated y — or attention's q (with its gate), k, v (each once more by
-      the group, as the kernel is handed them), o and lse. The expert half
-      holds its stream and the routing's tensors beside the LARGER of the
-      routed passes' set and the shared expert's.
+      scan reads them, the solve's X (a value head's [C, C] a chunk: a
+      tenth of the states), the states a chunk starts from (float32 [d_k,
+      d_v] a value head and chunk: 8 × v's bytes at the published sizes), o
+      and the gated y — or attention's q (with its gate), k, v (each once
+      more by the group, as the kernel is handed them), o and lse. The
+      expert half holds its stream and the routing's tensors beside the
+      LARGER of the routed passes' set and the shared expert's.
     - The mixer's own backward: its set and each tensor's gradient."""
     a = jnp.dtype(cfg.dtype).itemsize
     D, H, hd = cfg.d_model, cfg.n_head, cfg.head_dim
@@ -507,20 +508,27 @@ def kind_shards(cfg: Qwen3NextConfig, global_batch: int, seq: int, mesh
     carried = tokens * D * a
     mid = C((scopes.RES_MID,), tokens * D * a, 2 * tokens * vw * D)
 
-    # Gated DeltaNet: the projections' outputs; the scan's states and o go
-    # together (either alone spares no kernel call)
+    # Gated DeltaNet: the projections' outputs; the solve's X, at what its
+    # kernel spends (the one residual of the scan that spares a call alone:
+    # the recompute's solve); the scan's states and o together (either alone
+    # spares no call), at what the forward call is left with
     fused = 2 * kw + 2 * vw
-    chunks = -(-base.seq // cfg.delta_chunk) * base.batch
+    chunk, r = cfg.delta_chunk, Hv // cfg.linear_key_heads
+    chunks = -(-base.seq // chunk) * base.batch
     states = chunks * Hv * dk * dv * 4
+    solved = chunks * Hv * chunk * chunk * a
     delta_kept = (
         C(scopes.RES_DELTA_PARTS, tokens * fused * a, 2 * tokens * D * fused),
         C((scopes.RES_DELTA_BA,), tokens * 2 * Hv * 4, 2 * tokens * D * 2 * Hv),
+        C((scopes.RES_DELTA_X,), solved, chunks * cfg.linear_key_heads
+          * gated_delta.solve_flops(chunk, r, dk)),
         C((scopes.RES_DELTA_STATES, scopes.RES_DELTA_O),
-          states + tokens * vw * a, int(2 * tokens * delta_scan_macs(cfg))),
+          states + tokens * vw * a,
+          int(2 * tokens * (delta_scan_macs(cfg) - kw * chunk / 2))),
         mid)
     delta_params = D * fused + D * 2 * Hv + vw * D
     delta_waits = (a * (tokens * (2 * D + fused + 2 * (2 * kw + vw) + 2 * vw)
-                        + delta_params) + states)
+                        + delta_params) + states + solved)
     delta_set = delta_waits + a * (tokens * (2 * D + fused + (2 * kw + vw)
                                              + 2 * vw) + delta_params)
 

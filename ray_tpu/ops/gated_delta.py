@@ -20,19 +20,36 @@ K, V, Q the chunk's rows and S the state at its start:
 ``ops/mamba2.ssd_scan`` cannot express it: its chunk is a masked product,
 this one a masked product AFTER a unit-lower-triangular solve inside every
 chunk, and the backward differentiates through that solve (``d A = −Xᵀ·d[W|U]
-· [W|U]ᵀ``). Both directions are one Pallas kernel each (``gated_delta_fwd``,
-``gated_delta_bwd``, behind ``jax.custom_vjp``) on ssd_scan's grid: a grid
-step is one chunk of one row for a TILE of the value heads of one key head,
-the row's chunks the last, sequential axis along which the [d_k, d_v] states
-(backward: their gradients) ride in VMEM. The tile's heads are stacked along
-the ROWS of every [C, C] matrix — ``ht`` heads are one [ht·C, ht·C] problem
-whose off-diagonal blocks the mask empties, so at the published C = 64 the
-two value heads of a key head fill the MXU's 128 rows and columns, and the
-key head's K and Q are read once for both: no value head's copy of k or q
-exists in HBM. A grid step takes several such key heads (``key_tile``), each
-its own stack: the solve is a chain of dependent products, a chain a key
-head, and independent chains in one basic block are what lets the scheduler
-fill one's latency with another's passes.
+· [W|U]ᵀ``). Three Pallas kernels behind ``jax.custom_vjp``:
+
+- ``gated_delta_fwd_solve`` writes X of every chunk. X depends on k, c and β
+  alone, not on the carried state, so its grid has NO sequential axis: every
+  chunk of every key head is its own problem. Stored are the value heads'
+  [C, C] diagonal blocks alone, in the compute dtype — the one form anything
+  reads X in — a tile's heads side by side along the lanes: ``[B, H_v / ht,
+  chunks, C, ht·C]``, a tenth of the float32 states' bytes.
+- ``gated_delta_fwd`` and ``gated_delta_bwd`` read X's block and make the
+  rest of the chunk form on ssd_scan's grid: a grid step is one chunk of one
+  row for a TILE of the value heads of one key head, the row's chunks the
+  last, sequential axis along which the [d_k, d_v] states (backward: their
+  gradients) ride in VMEM. X is a residual, not a differentiated value:
+  ``d A`` leaves the backward kernel, which needs K Kᵀ ⊙ Γ for it still.
+
+The residuals are (q, k, v, c, β, the state each chunk starts from, X), the
+last two by name (``delta_states``, ``delta_x``): with no ``remat`` the solve
+runs once a step by construction; under a checkpoint policy that keeps
+``delta_x`` the recompute's dead-code elimination drops the solve's call and
+runs the solve-free forward alone — the step solves once a layer, where the
+two kernels that each solved inside themselves did it three times.
+
+The tile's heads are stacked along the ROWS of every [C, C] matrix — ``ht``
+heads are one [ht·C, ht·C] problem whose off-diagonal blocks the mask
+empties, so at the published C = 64 the two value heads of a key head fill
+the MXU's 128 rows and columns, and the key head's K and Q are read once for
+both: no value head's copy of k or q exists in HBM. A grid step takes several
+such key heads (``key_tile``), each its own stack: the solve is a chain of
+dependent products, a chain a key head, and independent chains in one basic
+block are what lets the scheduler fill one's latency with another's passes.
 
 The solve is blocked forward substitution by doubling: with the inverse of the
 b-blocks on the diagonal in hand, ``X ← X − X · L · X`` (L the blocks left of
@@ -165,8 +182,11 @@ class DeltaTiling(NamedTuple):
     vmem_estimate: int        # bytes, _vmem_estimate() of this choice
 
 
+_KERNELS = ("solve", "fwd", "bwd")
 # key heads a grid step takes at most: each is an unrolled copy of the body
 # (program size), and past a few chains the MXU has no latency left to fill
+# (the solve kernel alone at 4 × 8,192 tokens, 16 key heads: 16.9 / 16.1 /
+# 15.7 / 15.5 ms at 2 / 4 / 8 / 16; PERF.md §6, PR 62)
 _MAX_KEY_TILE = 4
 
 _decisions: Dict[tuple, Dict[str, Any]] = {}
@@ -178,19 +198,33 @@ def delta_tiling_decisions() -> List[Dict[str, Any]]:
     return list(_decisions.values())
 
 
+def _stackable(r: int, C: int) -> List[int]:
+    """The divisors of r whose stack of heads is at most one MXU pass tall
+    (a taller one multiplies the emptied off-diagonal blocks for nothing),
+    the largest first; one head always."""
+    return [t for t in range(r, 0, -1)
+            if r % t == 0 and (t == 1 or t * C <= _MXU)]
+
+
 def _vmem_estimate(kernel: str, C: int, ht: int, kt: int, dk: int, dv: int,
                    dtype_bytes: int) -> int:
     """VMEM bytes one grid step needs: every in/out block twice (Pallas
     double-buffers them), the carried states once, and the float32 values
     the body holds at once — a key head's stacked [N, N] matrices (N = ht·C)
-    and [N, d] operands and products. An upper bound, not Mosaic's own
-    figure."""
+    and [N, d] operands and products. The solve kernel has k, the rows and
+    X's blocks, no state and no [N, d_v] value: its live set is the levels'
+    squares beside the stacked K. The other two read X's block and hold
+    none of the solve's. An upper bound, not Mosaic's own figure."""
     blk, a, N = vmem_block_bytes, dtype_bytes, ht * C
     square, wide = blk((N, N), 4), blk((N, max(dk, dv)), 4)
+    x_block = kt * blk((C, N), a)
+    if kernel == "solve":
+        io = blk((C, kt * dk), a) + kt * blk((2, N), 4) + x_block
+        return 2 * io + kt * (10 * square + blk((N, dk), 4))
     state = kt * ht * blk((dk, dv), 4)
     io = (2 * blk((C, kt * dk), a) + 2 * blk((C, kt * ht * dv), a)  # q k v o
-          + kt * blk((2, N), 4) + state)                        # rows, states
-    live = kt * (10 * square + 10 * wide)
+          + kt * blk((2, N), 4) + x_block + state)              # rows, X, states
+    live = kt * (6 * square + 10 * wide)
     if kernel == "bwd":
         io += (2 * blk((C, kt * ht * dv), a)                    # d o, d v
                + 2 * blk((C, kt * dk), 4) + kt * blk((2, N), 4))
@@ -201,40 +235,53 @@ def _vmem_estimate(kernel: str, C: int, ht: int, kt: int, dk: int, dv: int,
 def choose_delta_tiling(kernel: str, rows: int, S: int, C: int, Hk: int,
                         r: int, dk: int, dv: int, dtype_bytes: int
                         ) -> DeltaTiling:
-    """THE rule for how a delta-rule kernel (``"fwd"`` / ``"bwd"``) tiles its
-    work: a grid step is one chunk of one row for ``key_tile`` key heads,
-    each with ``head_tile`` of its r value heads stacked along the rows of
-    the chunk's matrices. ``head_tile`` is the largest divisor of r whose
-    stack is at most one MXU pass tall (``head_tile · C ≤ 128``: a taller
-    stack multiplies the emptied off-diagonal blocks for nothing);
-    ``key_tile`` — where a step holds ALL of a key head's value heads — the
-    largest divisor of H_k up to _MAX_KEY_TILE; both as far as the estimate
-    fits half of what a kernel may be given (VMEM_CEILING_BYTES; past
-    Mosaic's default the call raises its limit, as the scan kernels do). A
-    shape of which not even one head fits is refused. Recorded once a
-    distinct decision (``ops/delta_tiling``)."""
-    if kernel not in ("fwd", "bwd"):
+    """THE rule for how a delta-rule kernel (``"solve"`` / ``"fwd"`` /
+    ``"bwd"``) tiles its work: a grid step is one chunk of one row for
+    ``key_tile`` key heads, each with ``head_tile`` of its r value heads
+    stacked along the rows of the chunk's matrices. ``head_tile`` is the
+    largest divisor of r whose stack is at most one MXU pass tall
+    (``head_tile · C ≤ 128``, _stackable) — ONE for the three kernels of a
+    scan, whose X is laid out by it: the largest that the backward, the
+    widest of them, has room for —; ``key_tile`` — where a step holds ALL of
+    a key head's value heads — the largest divisor of H_k up to _MAX_KEY_TILE
+    that this kernel has room for. Room is half of what a
+    kernel may be given (VMEM_CEILING_BYTES; past Mosaic's default the call
+    raises its limit, as the scan kernels do). A shape of which not even one
+    head fits is refused. Recorded once a distinct decision
+    (``ops/delta_tiling``)."""
+    if kernel not in _KERNELS:
         raise ValueError(f"unknown delta-rule kernel {kernel!r}")
-    estimate = functools.partial(_vmem_estimate, kernel, C, dk=dk, dv=dv,
+    estimate = functools.partial(_vmem_estimate, C=C, dk=dk, dv=dv,
                                  dtype_bytes=dtype_bytes)
-    fits = lambda ht, kt: estimate(ht=ht, kt=kt) <= VMEM_CEILING_BYTES // 2
-    tiles = [t for t in range(r, 0, -1)
-             if r % t == 0 and (t == 1 or t * C <= _MXU) and fits(t, 1)]
+    room = VMEM_CEILING_BYTES // 2
+    tiles = [t for t in _stackable(r, C)
+             if estimate("bwd", ht=t, kt=1) <= room]
     if not tiles:
         raise ValueError(
             f"gated_delta_scan {kernel}: one head of a chunk does not fit "
             f"VMEM for chunk C={C}, widths d_k={dk} d_v={dv} ({dtype_bytes}-"
-            f"byte operands): estimated at {estimate(ht=1, kt=1)} bytes of "
-            f"{VMEM_CEILING_BYTES // 2}; use a smaller chunk")
+            f"byte operands): the backward's estimated at "
+            f"{estimate('bwd', ht=1, kt=1)} bytes of {room}; use a smaller "
+            f"chunk")
     ht = tiles[0]
     kt = 1 if ht < r else next(
         t for t in range(min(Hk, _MAX_KEY_TILE), 0, -1)
-        if Hk % t == 0 and fits(ht, t))
-    tiling = DeltaTiling(ht, kt, estimate(ht=ht, kt=kt))
+        if Hk % t == 0 and estimate(kernel, ht=ht, kt=t) <= room)
+    tiling = DeltaTiling(ht, kt, estimate(kernel, ht=ht, kt=kt))
     record_decision(_decisions, scopes.DELTA_TILING, dict(zip(
         scopes.DELTA_TILING_ARGS,
         (kernel, rows, S, C, Hk, r, dk, dv) + tuple(tiling))))
     return tiling
+
+
+def solve_flops(C: int, r: int, dk: int) -> int:
+    """FLOPs the solve kernel SPENDS on one chunk of one key head: a stack's
+    K Kᵀ and, a doubling level, two products of three bf16 passes at the
+    stack's N = head_tile · C — what making X again costs the step (the
+    remat rule's price), not what the recurrence requires."""
+    ht = _stackable(r, C)[0]
+    N, levels = ht * C, max(0, C.bit_length() - 2)
+    return (r // ht) * 2 * (N * N * dk + levels * 2 * 3 * N ** 3)
 
 
 # --------------------------------------------------------------------------- #
@@ -285,9 +332,23 @@ def _solve(A, i, j, C: int):
     return X
 
 
+class _Gates(NamedTuple):
+    """A stack's masks and decays (``_gates``), N = ht·C rows."""
+    c_row: Any      # [1, N] c along the lanes
+    c: Any          # [N, 1] c, β down the sublanes
+    b: Any
+    G: Any          # [N, N] Γ, the diagonal's 1 included, 0 outside the mask
+    same: Any       # the masks: same head; and i ≥ j; and i > j; i = j
+    incl: Any
+    strict: Any
+    eye: Any
+    i: Any          # the row and column iotas
+    j: Any
+
+
 class _Chunk(NamedTuple):
-    """What both kernels make of a grid step's blocks (``_chunk``): the ht
-    heads stacked along the N = ht·C rows."""
+    """What the forward and the backward kernel make of a grid step's blocks
+    (``_chunk``): the ht heads stacked along the N = ht·C rows."""
     K: Any          # [N, dk] the key head's rows, once a stacked head
     Q: Any
     V: Any          # [N, dv]
@@ -295,8 +356,7 @@ class _Chunk(NamedTuple):
     gam: Any        # [N, 1] γ = e^c
     gam_end: Any    # [N, 1] γ at its head's last token
     e: Any          # [N, 1] γ_C / γ
-    G: Any          # [N, N] Γ, the diagonal's 1 included, 0 outside the mask
-    Ms: Any         # [N, N] strict_lower(K Kᵀ ⊙ Γ)
+    G: Any          # [N, N] Γ
     Xb: Any         # [N, N] (I + A)⁻¹ in the compute dtype
     Kg: Any         # [N, dk] float32 γ ⊙ K
     Qg: Any
@@ -328,14 +388,9 @@ def _to_row(col, eye):
     return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
 
 
-def _chunk(K, Q, V, rows, states, ht: int, C: int) -> _Chunk:
-    """The module docstring's chunk form of one key head's stack, up to D
-    and P: K, Q [C, dk] (the key head's), V [N, dv] (its ht value heads'
-    rows stacked), rows [2, N] (c, β), states ht × [dk, dv]."""
-    f32 = jnp.float32
+def _gates(rows, ht: int, C: int) -> _Gates:
+    """rows [2, N] (c, β) → the stack's masks, c and β as columns, and Γ."""
     N = ht * C
-    dt = V.dtype
-    K, Q = _stack([K] * ht), _stack([Q] * ht)
     c_row, b_row = rows[0:1, :], rows[1:2, :]
     i = lax.broadcasted_iota(jnp.int32, (N, N), 0)
     j = lax.broadcasted_iota(jnp.int32, (N, N), 1)
@@ -343,40 +398,69 @@ def _chunk(K, Q, V, rows, states, ht: int, C: int) -> _Chunk:
     same = (i & -C) == (j & -C)
     incl, strict = same & (i >= j), same & (i > j)
     c, b = _to_col(c_row, eye), _to_col(b_row, eye)
-    c_end = jnp.sum(jnp.where(same & ((j & (C - 1)) == C - 1), c_row, 0.0),
-                    axis=1, keepdims=True)
     G = jnp.where(incl, jnp.exp(jnp.where(incl, c - c_row, 0.0)), 0.0)
-    gam, gam_end, e = jnp.exp(c), jnp.exp(c_end), jnp.exp(c_end - c)
-    Ms = jnp.where(strict, _nt(K, K) * G, 0.0)
-    Xb = _solve(b * Ms, i, j, C).astype(dt)
+    return _Gates(c_row, c, b, G, same, incl, strict, eye, i, j)
+
+
+def _chunk(K, Q, V, rows, X, states, ht: int, C: int) -> _Chunk:
+    """The module docstring's chunk form of one key head's stack, up to D
+    and P, from the solve kernel's X: K, Q [C, dk] (the key head's), V [N,
+    dv] (its ht value heads' rows stacked), rows [2, N] (c, β), X [C, N]
+    (the heads' blocks side by side), states ht × [dk, dv]."""
+    f32 = jnp.float32
+    dt = V.dtype
+    K, Q = _stack([K] * ht), _stack([Q] * ht)
+    t = _gates(rows, ht, C)
+    c_end = jnp.sum(jnp.where(t.same & ((t.j & (C - 1)) == C - 1), t.c_row,
+                              0.0), axis=1, keepdims=True)
+    gam, gam_end, e = jnp.exp(t.c), jnp.exp(c_end), jnp.exp(c_end - t.c)
+    # every head's rows get the tile's blocks; the mask leaves each its own
+    Xb = X if ht == 1 else jnp.where(t.same, _stack([X] * ht),
+                                     jnp.zeros((), X.dtype))
     Kf = K.astype(f32)
     Kg, Qg, Kd = gam * Kf, gam * Q.astype(f32), e * Kf
-    W = _nn(Xb, (b * Kg).astype(dt))
-    U = _nn(Xb, (b * V.astype(f32)).astype(dt))
+    W = _nn(Xb, (t.b * Kg).astype(dt))
+    U = _nn(Xb, (t.b * V.astype(f32)).astype(dt))
     D = U - _stack([_nn(w.astype(dt), s.astype(dt))
                     for w, s in zip(_heads(W, ht, C), states)])
-    P = jnp.where(incl, _nt(Q, K) * G, 0.0)
-    return _Chunk(K, Q, V, b, gam, gam_end, e, G, Ms, Xb, Kg, Qg, Kd,
-                  W, U, D, P, incl, strict, eye)
+    P = jnp.where(t.incl, _nt(Q, K) * t.G, 0.0)
+    return _Chunk(K, Q, V, t.b, gam, gam_end, e, t.G, Xb, Kg, Qg, Kd,
+                  W, U, D, P, t.incl, t.strict, t.eye)
 
 
-def _key_heads(q_ref, k_ref, v_ref, rows_ref, kt: int, ht: int):
-    """A grid step's blocks as its kt key heads' (K, Q, V stacked, rows):
-    q, k [C, kt·dk]; v [C, kt·ht·dv]; rows [kt, 2, N]."""
+def _solve_kernel(k_ref, rows_ref, x_ref, *, kt: int, ht: int, C: int):
+    """(I + A)⁻¹ of one chunk of one row for kt key heads and the ht value
+    heads of each: k [C, kt·dk]; rows [kt, 2, N] float32 (c, β) → x [kt, C,
+    N], head h's [C, C] block at lanes h·C: (the stacked X is empty outside
+    its diagonal blocks: its heads' rows summed lay them side by side).
+    Every grid step is its own problem: no state, no sequential axis."""
+    dk = k_ref.shape[-1] // kt
+    for a in range(kt):
+        K = _stack([k_ref[:, a * dk:(a + 1) * dk]] * ht)
+        t = _gates(rows_ref[a], ht, C)
+        Ms = jnp.where(t.strict, _nt(K, K) * t.G, 0.0)
+        X = _solve(t.b * Ms, t.i, t.j, C)
+        x_ref[a] = sum(_heads(X, ht, C)).astype(x_ref.dtype)
+
+
+def _key_heads(q_ref, k_ref, v_ref, rows_ref, x_ref, kt: int, ht: int):
+    """A grid step's blocks as its kt key heads' (K, Q, V stacked, rows, X):
+    q, k [C, kt·dk]; v [C, kt·ht·dv]; rows [kt, 2, N]; x [kt, C, N]."""
     dk, dv = k_ref.shape[-1] // kt, v_ref.shape[-1] // (kt * ht)
     for a in range(kt):
         lanes = slice(a * dk, (a + 1) * dk)
         V = _stack([v_ref[:, (a * ht + h) * dv:(a * ht + h + 1) * dv]
                     for h in range(ht)])
-        yield k_ref[:, lanes], q_ref[:, lanes], V, rows_ref[a]
+        yield k_ref[:, lanes], q_ref[:, lanes], V, rows_ref[a], x_ref[a]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, kt: int, ht: int,
-                C: int, with_states: bool):
+def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, x_ref, o_ref, *rest, kt: int,
+                ht: int, C: int, with_states: bool):
     """One chunk of one row for kt key heads and the ht value heads of each.
     Blocks: q, k [C, kt·dk]; v, o [C, kt·ht·dv]; rows [kt, 2, N] float32 (c,
-    β); with_states the state each head's chunk STARTS from, [kt·ht, dk, dv]
-    float32. The states ride in ``s_ref`` along the chunks."""
+    β); x [kt, C, N] (_solve_kernel's); with_states the state each head's
+    chunk STARTS from, [kt·ht, dk, dv] float32. The states ride in ``s_ref``
+    along the chunks."""
     states_ref, s_ref = rest if with_states else (None,) + rest
     dt = v_ref.dtype
     dv = v_ref.shape[-1] // (kt * ht)
@@ -387,10 +471,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, kt: int, ht: int,
 
     if with_states:
         states_ref[...] = s_ref[...]
-    for a, (K, Q, V, rows) in enumerate(
-            _key_heads(q_ref, k_ref, v_ref, rows_ref, kt, ht)):
+    for a, (K, Q, V, rows, X) in enumerate(
+            _key_heads(q_ref, k_ref, v_ref, rows_ref, x_ref, kt, ht)):
         states = [s_ref[a * ht + h] for h in range(ht)]
-        m = _chunk(K, Q, V, rows, states, ht, C)
+        m = _chunk(K, Q, V, rows, X, states, ht, C)
         Db = m.D.astype(dt)
         inner = _nn(m.P.astype(dt), Db)
         for h, (qg, kd, d, s0, o) in enumerate(zip(
@@ -402,13 +486,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, kt: int, ht: int,
             s_ref[at] = m.gam_end[h * C:h * C + 1] * s0 + _tn(kd.astype(dt), d)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, states_ref, do_ref,
+def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, x_ref, states_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, drows_ref, ds_ref, *, kt: int, ht: int,
                 C: int):
     """The same tile's gradients, the chunks reversed: d q, d k [C, kt·dk]
     float32 (a key head's stacked heads summed), d v [C, kt·ht·dv], d rows
-    [kt, 2, N] (d c, d β). ``ds_ref`` carries the gradient of the state a
-    chunk ENDS with."""
+    [kt, 2, N] (d c, d β). X is read, not differentiated: d A leaves here.
+    ``ds_ref`` carries the gradient of the state a chunk ENDS with."""
     f32 = jnp.float32
     dt = v_ref.dtype
     dk, dv = k_ref.shape[-1] // kt, v_ref.shape[-1] // (kt * ht)
@@ -419,10 +503,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, states_ref, do_ref,
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    for a, (K, Q, V, rows) in enumerate(
-            _key_heads(q_ref, k_ref, v_ref, rows_ref, kt, ht)):
+    for a, (K, Q, V, rows, X) in enumerate(
+            _key_heads(q_ref, k_ref, v_ref, rows_ref, x_ref, kt, ht)):
         states = [states_ref[a * ht + h] for h in range(ht)]
-        m = _chunk(K, Q, V, rows, states, ht, C)
+        m = _chunk(K, Q, V, rows, X, states, ht, C)
+        Ms = jnp.where(m.strict, _nt(m.K, m.K) * m.G, 0.0)
         dO = _stack([do_ref[:, (a * ht + h) * dv:(a * ht + h + 1) * dv]
                      for h in range(ht)])
         dS1 = [ds_ref[a * ht + h] for h in range(ht)]
@@ -446,12 +531,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, states_ref, do_ref,
         dMs = m.b * dA
         # Ms = K Kᵀ ⊙ Γ, P = Q Kᵀ ⊙ Γ (the masks are in d Ms, d P and Γ)
         GK, GQ = (dMs * m.G).astype(dt), (dP * m.G).astype(dt)
-        E = dMs * m.Ms + dP * m.P                   # Γ ⊙ d Γ
+        E = dMs * Ms + dP * m.P                     # Γ ⊙ d Γ
         dKg = m.b * dRk
         kd_dot = jnp.sum(dKd * m.Kd, axis=1, keepdims=True)
         dbeta = (jnp.sum(dRk * m.Kg, axis=1, keepdims=True)
                  + jnp.sum(dRv * m.V.astype(f32), axis=1, keepdims=True)
-                 + jnp.sum(dA * m.Ms, axis=1, keepdims=True))
+                 + jnp.sum(dA * Ms, axis=1, keepdims=True))
         dc = (jnp.sum(dKg * m.Kg + dQg * m.Qg, axis=1, keepdims=True) - kd_dot
               + jnp.sum(E, axis=1, keepdims=True))
         dK = (_nn(GK, m.K) + _tn(GK, m.K) + _tn(GQ, m.Q)
@@ -497,17 +582,19 @@ def _unrows(t, S: int):
 @functools.partial(jax.jit, static_argnames=("kernel", "Hk", "C", "interpret",
                                              "with_states"))
 def _chunks_call(kernel: str, q, k, v, cum, beta, Hk: int, C: int,
-                 interpret: bool, states=None, do=None,
+                 interpret: bool, X=None, states=None, do=None,
                  with_states: bool = True):
-    """The pallas_call of either kernel over grid (rows, head tiles, chunks).
+    """The pallas_call of one kernel over grid (rows, head tiles, chunks).
     q, k [B, S, Hk·dk]; v [B, S, Hv·dv]; cum, beta [B, S, Hv] float32; S whole
-    chunks. Forward → (o [B, S, Hv·dv] in v's dtype, states | None); backward
-    (``states``, ``do`` given) → the five gradients, shaped as the inputs. A
-    jit of its own, as mamba2._chunks_call and for its reason; heads and
-    channels cross it merged."""
+    chunks. ``"solve"`` (q unread) → X [B, T, nc, C, ht·C] in v's dtype, a
+    tile of ht value heads' blocks side by side; ``"fwd"`` (X given) → (o [B,
+    S, Hv·dv] in v's dtype, states | None); ``"bwd"`` (X, ``states``, ``do``
+    given) → the five gradients, shaped as the inputs. A jit of its own, as
+    mamba2._chunks_call and for its reason; heads and channels cross it
+    merged."""
     B, S, Hv = cum.shape
     r = Hv // Hk
-    dk, dv = q.shape[2] // Hk, v.shape[2] // Hv
+    dk, dv = k.shape[2] // Hk, v.shape[2] // Hv
     nc = S // C
     if not interpret and (dk % 128 or dv % 128 or C % 16):
         raise NotImplementedError(
@@ -518,8 +605,7 @@ def _chunks_call(kernel: str, q, k, v, cum, beta, Hk: int, C: int,
     # T tiles of ht value heads; a key head's `per` tiles; kt key heads (each
     # ONE tile: the rule takes several only where per == 1) a grid step
     T, per, N = Hv // ht, r // ht, ht * C
-    fwd = kernel == "fwd"
-    chunk_of = (lambda c: c) if fwd else (lambda c: nc - 1 - c)
+    chunk_of = (lambda c: nc - 1 - c) if kernel == "bwd" else (lambda c: c)
 
     def by_chunk(*block):
         """[B, T, chunks, *block] arrays: kt tiles' blocks a grid step."""
@@ -533,16 +619,23 @@ def _chunks_call(kernel: str, q, k, v, cum, beta, Hk: int, C: int,
     state_shape = jax.ShapeDtypeStruct((B, T * ht, nc, dk, dv), jnp.float32)
     state_spec = pl.BlockSpec((None, kt * ht, None, dk, dv),
                               lambda b, i, c: (b, i, chunk_of(c), 0, 0))
-    args = [q, k, v, _rows(cum, beta, C, ht)]
-    specs = [key_spec, key_spec, val_spec, by_chunk(2, N)]
-    if fwd:
-        body = functools.partial(_fwd_kernel, kt=kt, ht=ht, C=C,
-                                 with_states=with_states)
+    rows = _rows(cum, beta, C, ht)
+    rows_spec, x_spec = by_chunk(2, N), by_chunk(C, N)
+    args = [q, k, v, rows, X]
+    specs = [key_spec, key_spec, val_spec, rows_spec, x_spec]
+    if kernel == "solve":
+        body, name = _solve_kernel, scopes.GATED_DELTA_SOLVE_KERNEL
+        args, specs = [k, rows], [key_spec, rows_spec]
+        out_shape = jax.ShapeDtypeStruct((B, T, nc, C, N), v.dtype)
+        out_specs = x_spec
+    elif kernel == "fwd":
+        body = functools.partial(_fwd_kernel, with_states=with_states)
+        name = scopes.GATED_DELTA_FWD_KERNEL
         out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)] \
             + [state_shape] * with_states
         out_specs = [val_spec] + [state_spec] * with_states
     else:
-        body = functools.partial(_bwd_kernel, kt=kt, ht=ht, C=C)
+        body, name = _bwd_kernel, scopes.GATED_DELTA_BWD_KERNEL
         args += [states, do]
         specs += [state_spec, val_spec]
         # d q, d k: a key head's tiles each write their own, summed below
@@ -551,20 +644,23 @@ def _chunks_call(kernel: str, q, k, v, cum, beta, Hk: int, C: int,
         key_grad = jax.ShapeDtypeStruct((B, S, T * dk), jnp.float32)
         out_shape = [key_grad, key_grad, jax.ShapeDtypeStruct(v.shape, v.dtype),
                      jax.ShapeDtypeStruct((B, T, nc, 2, N), jnp.float32)]
-        out_specs = [tile_spec, tile_spec, val_spec, by_chunk(2, N)]
+        out_specs = [tile_spec, tile_spec, val_spec, rows_spec]
     out = pl.pallas_call(
-        body, grid=(B, T // kt, nc), in_specs=specs, out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((kt * ht, dk, dv), jnp.float32)],
+        functools.partial(body, kt=kt, ht=ht, C=C), grid=(B, T // kt, nc),
+        in_specs=specs, out_specs=out_specs, out_shape=out_shape,
+        # the states ride along the chunks; the solve carries nothing
+        scratch_shapes=[] if kernel == "solve" else [
+            pltpu.VMEM((kt * ht, dk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "parallel"
+                                 if kernel == "solve" else "arbitrary"),
             vmem_limit_bytes=None if estimate <= VMEM_BUDGET_BYTES else min(
                 VMEM_CEILING_BYTES, estimate + estimate // 2)),
-        interpret=interpret,
-        name=(scopes.GATED_DELTA_FWD_KERNEL if fwd
-              else scopes.GATED_DELTA_BWD_KERNEL),
+        interpret=interpret, name=name,
     )(*args)
-    if fwd:
+    if kernel == "solve":
+        return out
+    if kernel == "fwd":
         return out[0], (out[1] if with_states else None)
     dq, dk_, dv_, drows = out
 
@@ -581,25 +677,36 @@ def _merged(*ts):
     return tuple(t.reshape(t.shape[:2] + (-1,)) for t in ts)
 
 
+def _solved(q, k, v, cum, beta, C, interpret):
+    """X of every chunk, by the name a checkpoint policy keeps it under: the
+    recompute of a layer that kept it runs no solve, and the backward reads
+    it whether the layer did or not."""
+    return checkpoint_name(
+        _chunks_call("solve", *_merged(q, k, v), cum, beta, q.shape[2], C,
+                     interpret), scopes.RES_DELTA_X)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _delta_chunks(q, k, v, cum, beta, C, interpret):
+    X = _solved(q, k, v, cum, beta, C, interpret)
     return _chunks_call("fwd", *_merged(q, k, v), cum, beta, q.shape[2], C,
-                        interpret, with_states=False)[0].reshape(v.shape)
+                        interpret, X=X, with_states=False)[0].reshape(v.shape)
 
 
 def _delta_chunks_fwd(q, k, v, cum, beta, C, interpret):
+    X = _solved(q, k, v, cum, beta, C, interpret)
     o, states = _chunks_call("fwd", *_merged(q, k, v), cum, beta, q.shape[2],
-                             C, interpret)
-    # by name, so that a checkpoint policy that keeps it spares the backward
-    # a second forward call
+                             C, interpret, X=X)
+    # by name, so that a checkpoint policy that keeps it (and o) spares the
+    # backward a second forward call
     states = checkpoint_name(states, scopes.RES_DELTA_STATES)
-    return o.reshape(v.shape), (q, k, v, cum, beta, states)
+    return o.reshape(v.shape), (q, k, v, cum, beta, states, X)
 
 
 def _delta_chunks_bwd(C, interpret, res, do):
-    q, k, v, cum, beta, states = res
+    q, k, v, cum, beta, states, X = res
     dq, dk, dv, dcum, dbeta = _chunks_call(
-        "bwd", *_merged(q, k, v), cum, beta, q.shape[2], C, interpret,
+        "bwd", *_merged(q, k, v), cum, beta, q.shape[2], C, interpret, X=X,
         states=states, do=do.reshape(do.shape[:2] + (-1,)))
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dcum, dbeta)
@@ -629,7 +736,7 @@ def gated_delta_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     document: no state is reset inside it. A row that is not whole chunks is
     padded with steps that change nothing and cut again. Which
     implementation runs is attention.resolve_attention's rule on ``impl``:
-    the kernel pair on a TPU (and interpreted where a caller says "pallas"
+    the kernels on a TPU (and interpreted where a caller says "pallas"
     elsewhere), ``gated_delta_chunked`` off it. Under a mesh each device
     scans its own rows."""
     mesh = mesh_lib.current_mesh()

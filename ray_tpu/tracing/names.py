@@ -153,8 +153,14 @@ MHC_WRITE_BWD_KERNEL = "mhc_write_bwd"
 # the gated delta rule (ops/gated_delta.py): a chunk's masked products after
 # its unit-lower-triangular solve, the state's correction and the carried
 # state's part of o, a (row, tile of one key head's value heads) at a time
-# along the row's chunks — and the same tiles' gradients, the chunks reversed
+# along the row's chunks — and the same tiles' gradients, the chunks reversed.
+# Both read the solve's result, (I + A)⁻¹ of every chunk, which a third
+# kernel makes once with no sequential axis. ITS NAME BEGINS WITH THE
+# FORWARD'S and stands after it in KERNELS: a reader that finds a kernel by
+# the first of KERNELS inside an instruction's name counts the solve's time
+# as the forward's, where it was before it had a kernel of its own
 GATED_DELTA_FWD_KERNEL = "gated_delta_fwd"
+GATED_DELTA_SOLVE_KERNEL = "gated_delta_fwd_solve"
 GATED_DELTA_BWD_KERNEL = "gated_delta_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
@@ -162,7 +168,8 @@ KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            SPARSE_ATTN_BWD_DQ_KERNEL, SPARSE_ATTN_BWD_DKV_KERNEL,
            CONV_GATE_FWD_KERNEL, CONV_GATE_BWD_KERNEL, MHC_MIX_FWD_KERNEL,
            MHC_MIX_BWD_KERNEL, MHC_WRITE_FWD_KERNEL, MHC_WRITE_BWD_KERNEL,
-           GATED_DELTA_FWD_KERNEL, GATED_DELTA_BWD_KERNEL)
+           GATED_DELTA_FWD_KERNEL, GATED_DELTA_SOLVE_KERNEL,
+           GATED_DELTA_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
@@ -288,14 +295,18 @@ RES_MLA_C, RES_MLA_KPE = "mla_latent_c", "mla_k_pe"
 RES_MOE_SHARED_GATE, RES_MOE_SHARED_UP = "moe_shared_gate", "moe_shared_up"
 # a Qwen3-Next layer's (models/qwen3_next.py). The Gated DeltaNet mixer names
 # its fused projection's four parts (q, k, v before the conv, and z: the
-# mixer's one large weight, a product a part) and the b, a one's, the state
-# each chunk of the scan starts from and the scan's output; the gated attention layer q, k (after the QK-norm
+# mixer's one large weight, a product a part) and the b, a one's, the solve's
+# (I + A)⁻¹ of every chunk (the value heads' [C, C] blocks in the compute
+# dtype: the one residual of the scan that spares a kernel call alone), the
+# state each chunk of the scan starts from and the scan's output (together:
+# the forward call); the gated attention layer q, k (after the QK-norm
 # and the partial rotation) and v as the others do, the flash kernel's two,
 # and its output gate's pre-activation; both halves RES_MID and the expert
 # half the routing's names and the shared expert's two hidden tensors
 RES_DELTA_PARTS = ("delta_q", "delta_k", "delta_v", "delta_z")
 RES_DELTA_BA = "delta_ba"
 RES_DELTA_STATES, RES_DELTA_O = "delta_states", "delta_o"
+RES_DELTA_X = "delta_x"
 RES_ATTN_GATE = "attn_gate"
 RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_MLP_HIDDEN, RES_EVA_O, RES_EVA_LSE, RES_EVA_KT, RES_EVA_VT,
@@ -306,7 +317,7 @@ RESIDUALS = (RES_Q, RES_K, RES_V, RES_FLASH_O, RES_FLASH_LSE, RES_MID,
              RES_SPARSE_LSE, RES_CONV_BCX, RES_MOE_PAIR_GATE, RES_MLA_C,
              RES_MLA_KPE, RES_MOE_SHARED_GATE, RES_MOE_SHARED_UP,
              *RES_DELTA_PARTS, RES_DELTA_BA, RES_DELTA_STATES, RES_DELTA_O,
-             RES_ATTN_GATE)
+             RES_ATTN_GATE, RES_DELTA_X)
 # which of them models/blocks.py chose to save, the rows of the sequence
 # the block's MLP and the LM head take at a time (the sequence: all at once),
 # and the phase of the backward whose working set the budget was left by

@@ -402,15 +402,17 @@ def test_qwen3_next_through_trainer_at_toy_size(fake_tpu_node):
                           grouped_shapes=())
     assert chip_smoke.check_training(rows, cfg, steps) == []
     summary = rows[-1]["summary"]
-    # its pattern, the delta rule's two kernels'
-    # tilings (both value heads of a key head a grid step), the flash pair at
-    # its head width, its four layers' loads and balance losses came back;
-    # and the check fails without them
+    # its pattern, the delta rule's three kernels' tilings (the solve's, the
+    # forward's, the backward's: both value heads of a key head a grid step),
+    # the flash pair at its head width, its four layers' loads and balance
+    # losses came back; and the check fails without them — or with the pair's
+    # decisions alone: a step that solved inside the other two
     qwen3 = summary["qwen3"]
     assert [d["groups"] for d in qwen3["layer_pattern"]] == [
         ["3 x scan(L)", "F"]]
     assert {(d["kernel"], d["C"], d["head_tile"])
-            for d in qwen3["delta_tiling"]} == {("fwd", 16, 2), ("bwd", 16, 2)}
+            for d in qwen3["delta_tiling"]} == {
+        ("solve", 16, 2), ("fwd", 16, 2), ("bwd", 16, 2)}
     assert [e["layer"] for e in qwen3["expert_load"]] == [0, 1, 2, 3]
     assert np.asarray(qwen3["step_load"]).shape == (4, 3)
     assert all(0.8 < b < 2.0 for b in qwen3["balance_loss"])
@@ -418,6 +420,11 @@ def test_qwen3_next_through_trainer_at_toy_size(fake_tpu_node):
         "delta_tiling": [], "layer_pattern": [], "expert_load": []}}}]
     assert len(chip_smoke.check_training(rows[:-1] + no_kernel, cfg, steps)
                ) == 3
+    no_solve = [rows[-1] | {"summary": summary | {"qwen3": qwen3 | {
+        "delta_tiling": [d for d in qwen3["delta_tiling"]
+                         if d["kernel"] != "solve"]}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + no_solve, cfg, steps)
+               ) == 1
 
 
 def test_step_load_line_finds_the_steps_own_event_or_fails():

@@ -226,11 +226,12 @@ def test_scan_kernels_compile_for_the_v5e(one_chip, shape):
                                         # 128, unequal widths, a padded row
 ], ids=["qwen3-next-cell", "one-head-c128"])
 def test_delta_rule_kernels_compile_for_the_v5e(one_chip, shape):
-    """ops/gated_delta's kernel pair (PR 61) at the tile the rule chooses,
-    forward and backward, bf16: what interpret mode cannot show — the stacked
-    heads' sublane slices, the row / column spreads of the gates, the masks'
-    bit arithmetic, the state scratch — and that k and q reach the kernels at
-    the KEY heads' width: no value head's copy of either exists."""
+    """ops/gated_delta's kernels (PR 61; the solve's own since PR 62) at the
+    tile the rule chooses, solve, forward and backward, bf16: what interpret
+    mode cannot show — the stacked heads' sublane slices, the row / column
+    spreads of the gates, the masks' bit arithmetic, the state scratch, X's
+    blocks side by side along the lanes — and that k and q reach the kernels
+    at the KEY heads' width: no value head's copy of either exists."""
     import functools
 
     from ray_tpu.ops import gated_delta
@@ -244,17 +245,21 @@ def test_delta_rule_kernels_compile_for_the_v5e(one_chip, shape):
     hlo = jax.jit(jax.grad(
         lambda *a: jnp.sum(jnp.sin(scan(*a).astype(jnp.float32))),
         argnums=(0, 1, 2, 3, 4))).lower(*args).compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
     padded = -(-S // C) * C
     mine = {d["kernel"]: d for d in gated_delta.delta_tiling_decisions()
             if (d["rows"], d["S"], d["C"], d["key_heads"]) == (B, padded, C, Hk)}
-    assert set(mine) == {"fwd", "bwd"}
+    assert set(mine) == {"solve", "fwd", "bwd"}
     assert all(d["head_tile"] == min(Hv // Hk, 128 // C)
                for d in mine.values())
     # q and k are the calls' operands at the key heads' own width
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert all(f"bf16[{B},{padded},{Hk * dk}]" in line for line in calls)
+    # X leaves the solve and enters the other two as the diagonal blocks alone
+    ht = min(Hv // Hk, 128 // C)
+    blocks = f"bf16[{B},{Hv // ht},{padded // C},{C},{ht * C}]"
+    assert all(blocks in line for line in calls)
 
 
 @pytest.mark.parametrize("S,top_k", [(16384, 64), (2048, 8)],

@@ -15,6 +15,7 @@ the new cell alone, imports nothing of ``ray_tpu`` at module level and reads
 nothing, without raising, from another cell's recorded trace."""
 
 import ast
+import collections
 import dataclasses
 import importlib
 import json
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -219,8 +221,8 @@ def test_the_kernels_the_chunk_form_and_the_recurrence_agree(dtype, tol):
             assert _rel(a, b) < tol, (name, what, _rel(a, b))
     tiled = {d["kernel"]: d for d in gated_delta.delta_tiling_decisions()
              if (d["S"], d["C"], d["dk"]) == (64, 16, 16)}
-    # both value heads of a key head in one grid step
-    assert set(tiled) == {"fwd", "bwd"}
+    # both value heads of a key head in one grid step, of all three kernels
+    assert set(tiled) == {"solve", "fwd", "bwd"}
     assert all(d["head_tile"] == 2 and d["value_heads_per_key"] == 2
                for d in tiled.values())
 
@@ -266,17 +268,149 @@ def test_the_scan_repeats_no_key_head_and_names_its_kernels():
             names.GATED_ATTN_GATE} <= set(names.SCOPES)
 
 
+def _kernel_calls(jaxpr):
+    """The pallas_call equations of a jaxpr, nested ones included, by the
+    kernel's name."""
+    found = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_calls(inner)
+    return found
+
+
+@pytest.mark.parametrize("kept,solves,forwards", [
+    ("delta_x", 1, 2), ("nothing", 2, 2), ("no_checkpoint", 1, 1),
+    ("delta_x_states_o", 1, 1)])
+def test_a_step_solves_once_where_the_policy_keeps_x(kept, solves, forwards):
+    """The gradient's kernel calls: X is a value of the step with a name, so
+    a checkpoint policy that holds it drops the recompute's solve (the
+    forward kernel still runs twice: the backward needs the states); one
+    that holds nothing solves twice; without a checkpoint, or with the
+    states and o kept besides, every kernel runs once."""
+    args = _scan_inputs(1, 32, 2, 4, 16, 16, jnp.float32)
+
+    def loss(*a):
+        o = gated_delta.gated_delta_scan(*a, chunk=16, impl="pallas")
+        return jnp.sum(checkpoint_name(o, names.RES_DELTA_O))
+
+    policies = jax.checkpoint_policies
+    policy = {
+        "delta_x": policies.save_only_these_names(names.RES_DELTA_X),
+        "nothing": policies.nothing_saveable,
+        "delta_x_states_o": policies.save_only_these_names(
+            names.RES_DELTA_X, names.RES_DELTA_STATES, names.RES_DELTA_O),
+    }.get(kept)
+    fn = jax.checkpoint(loss, policy=policy) if policy else loss
+    calls = _kernel_calls(jax.make_jaxpr(
+        jax.grad(fn, argnums=(0, 1, 2, 3, 4)))(*args).jaxpr)
+    assert calls == {names.GATED_DELTA_SOLVE_KERNEL: solves,
+                     names.GATED_DELTA_FWD_KERNEL: forwards,
+                     names.GATED_DELTA_BWD_KERNEL: 1}
+
+
+@pytest.mark.parametrize("r,S,head_tile", [(1, 64, 1), (2, 64, 2), (4, 64, 4),
+                                           (2, 40, 2)],
+                         ids=["ht1", "ht2", "ht4", "padded-row"])
+def test_the_solve_kernel_writes_the_chunk_forms_inverse(r, S, head_tile):
+    """The solve kernel's X against ``_solve`` on the XLA chunk form's A —
+    float32 K Kᵀ ⊙ Γ by β, a chunk and value head at a time — in bf16, the
+    dtype the other two kernels read it in: equal to the rounding of the
+    cast, at one, two and four value heads stacked a grid step and at a row
+    padded to whole chunks; outside a head's own block nothing is stored."""
+    C, Hk, dk = 16, 2, 16
+    q, k, v, g, beta = _scan_inputs(2, S, Hk, r * Hk, dk, 16, jnp.bfloat16,
+                                    seed=5)
+    q, k, v, g, beta = gated_delta._padded(q, k, v, g, beta, C)
+    cum = gated_delta._cumulative(g, C)
+    B, Sp, Hv = cum.shape
+    nc = Sp // C
+    X = gated_delta._chunks_call(
+        "solve", *gated_delta._merged(q, k, v), cum, beta, Hk, C, True)
+    assert X.dtype == jnp.bfloat16
+    assert X.shape == (B, Hv // head_tile, nc, C, head_tile * C)
+    assert {d["head_tile"] for d in gated_delta.delta_tiling_decisions()
+            if (d["kernel"], d["S"], d["C"], d["value_heads_per_key"]) == (
+                "solve", Sp, C, r)} == {head_tile}
+    # [B, T, nc, C, ht, C] → a value head's [C, C] a chunk
+    got = X.reshape(B, Hv // head_tile, nc, C, head_tile, C).transpose(
+        0, 2, 1, 4, 3, 5).reshape(B, nc, Hv, C, C).astype(jnp.float32)
+    i = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    j = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    K = jnp.repeat(k.astype(jnp.float32), r, axis=2).reshape(
+        B, nc, C, Hv, dk)
+    c = cum.reshape(B, nc, C, Hv)
+    b = beta.reshape(B, nc, C, Hv)
+
+    def one(K, c, b):       # [C, dk], [C], [C] → [C, C]
+        G = jnp.exp(jnp.where(i >= j, c[:, None] - c[None, :], 0.0))
+        A = jnp.where(i > j, b[:, None] * (jnp.dot(
+            K, K.T, precision=lax.Precision.HIGHEST) * G), 0.0)
+        return gated_delta._solve(A, i, j, C)
+
+    want = jax.vmap(jax.vmap(jax.vmap(one, in_axes=(1, 1, 1))))(K, c, b)
+    assert float(jnp.max(jnp.abs(want))) > 1.0 - 1e-6
+    # bf16 keeps 8 bits: half a unit in the last place of the largest entry
+    assert float(jnp.max(jnp.abs(got - want))) <= 2.0 ** -8 * float(
+        jnp.max(jnp.abs(want)))
+    assert _rel(got, want) < 3e-3
+
+
+def test_the_solves_time_stays_in_the_rooflines_denominator():
+    """``gated_delta_roofline`` divides by ``kernel_ms_per_step`` of
+    ``gated_delta_fwd`` + ``gated_delta_bwd``, and the trace's reader names
+    an instruction's kernel by the FIRST of ``names.KERNELS`` inside its HLO
+    name: the solve's name begins with the forward's and stands after it,
+    so its time is the forward's there — a roofline that rose because the
+    solve's time fell out of it would be a false reading."""
+    from benchmarks.harness import program_trace
+
+    assert names.GATED_DELTA_SOLVE_KERNEL.startswith(
+        names.GATED_DELTA_FWD_KERNEL)
+    order = names.KERNELS.index
+    assert order(names.GATED_DELTA_FWD_KERNEL) < order(
+        names.GATED_DELTA_SOLVE_KERNEL)
+    path = ("jit(step)/jit(main)/while/body/checkpoint/block/delta_mixer/"
+            "gated_delta/jit(_chunks_call)/pallas_call")
+    for hlo, kernel in ((f"{names.GATED_DELTA_SOLVE_KERNEL}.14",
+                         names.GATED_DELTA_FWD_KERNEL),
+                        (f"{names.GATED_DELTA_FWD_KERNEL}.27",
+                         names.GATED_DELTA_FWD_KERNEL),
+                        (f"{names.GATED_DELTA_BWD_KERNEL}.12",
+                         names.GATED_DELTA_BWD_KERNEL)):
+        where = program_trace.classify(path, hlo, "mosaic")
+        assert where["kernel"] == kernel
+        assert names.GATED_DELTA in where["scopes"]
+
+
 def test_the_tiling_rule_reads_the_shapes():
     cell = gated_delta.choose_delta_tiling("fwd", 4, 8192, 64, 16, 2, 128,
                                            128, 2)
     assert cell.head_tile == 2          # 2 x 64 rows: one MXU pass tall
-    assert gated_delta.choose_delta_tiling(
-        "bwd", 4, 8192, 128, 16, 2, 128, 128, 2).head_tile == 1
+    # the solve's grid step has no state and no [N, d_v] value: the same
+    # stack and key heads in under half the forward's estimate
+    solve = gated_delta.choose_delta_tiling("solve", 4, 8192, 64, 16, 2, 128,
+                                            128, 2)
+    assert (solve.head_tile, solve.key_tile) == (2, cell.key_tile) == (2, 4)
+    assert solve.vmem_estimate < cell.vmem_estimate // 2
+    assert {"fwd", "solve"} <= {
+        d["kernel"] for d in gated_delta.delta_tiling_decisions()
+        if (d["rows"], d["S"], d["C"], d["key_heads"]) == (4, 8192, 64, 16)}
+    # one head_tile a scan (X is laid out by it): the backward's room decides
+    assert {gated_delta.choose_delta_tiling(
+        kernel, 4, 8192, 128, 16, 2, 128, 128, 2).head_tile
+        for kernel in ("solve", "fwd", "bwd")} == {1}
+    # what making X again costs: K Kᵀ and five levels of two three-pass
+    # products at N = 128 — ~130 MFLOP, 7.9 kFLOP a byte of X
+    assert gated_delta.solve_flops(64, 2, 128) == 2 * 31 * 128 ** 3
+    assert gated_delta.solve_flops(64, 4, 128) == 2 * gated_delta.solve_flops(
+        64, 2, 128)
     assert gated_delta.choose_delta_tiling(
         "fwd", 1, 64, 16, 2, 4, 16, 16, 4).head_tile == 4
     with pytest.raises(ValueError, match="does not fit VMEM"):
         gated_delta.choose_delta_tiling("bwd", 1, 8192, 2048, 1, 1, 128, 128, 4)
-    with pytest.raises(ValueError, match="unknown"):
+    with pytest.raises(ValueError, match="unknown delta-rule kernel 'both'"):
         gated_delta.choose_delta_tiling("both", 1, 64, 16, 2, 2, 16, 16, 4)
     event = gated_delta.delta_tiling_decisions()[-1]
     assert tuple(event) == names.DELTA_TILING_ARGS
@@ -440,7 +574,8 @@ def test_the_pattern_its_groups_and_what_the_rule_may_keep():
     flat = [n for k in kinds.values() for c in k.candidates for n in c.names]
     assert len(flat) == len(set(flat)) and set(flat) <= set(names.RESIDUALS)
     assert {*names.RES_DELTA_PARTS, names.RES_DELTA_BA, names.RES_DELTA_STATES,
-            names.RES_DELTA_O, names.RES_ATTN_GATE, names.RES_Q,
+            names.RES_DELTA_O, names.RES_DELTA_X, names.RES_ATTN_GATE,
+            names.RES_Q,
             names.RES_FLASH_O, names.RES_MID, names.RES_MOE_SCORES,
             names.RES_MOE_SHARED_GATE} <= set(flat)
     assert all(k.grad_bytes > 0 and k.block_bytes > 0 for k in kinds.values())
@@ -450,6 +585,42 @@ def test_the_pattern_its_groups_and_what_the_rule_may_keep():
         lambda p: qn.loss_fn(p, tokens, targets, cfg)))(params))
     for name in flat:
         assert f"name={name}" in text, name
+
+
+def test_at_the_cells_shapes_the_rule_keeps_the_solves_result():
+    """The cell's layers on a chip that states a v5e's 15.75 GiB: the rule
+    takes ``delta_x`` (7.9 kFLOP a byte of it made again, where the
+    projections' outputs stand at 2,048) before the projections', at 134 MB
+    a DeltaNet layer, and ``block_q`` and the routing's scores make room."""
+    cell, config = _cell()
+    cfg = dataclasses.replace(family.program_config(config, cell),
+                              attention_impl="pallas")
+    base, kinds = qn.kind_shards(cfg, cell["per_chip_batch"], cell["seq_len"],
+                                 None)
+    x = next(c for c in kinds["L"].candidates
+             if c.names == (names.RES_DELTA_X,))
+    assert x.nbytes == 4 * 128 * 32 * 64 * 64 * 2 == 134_217_728
+    assert x.flops == 4 * 128 * 16 * gated_delta.solve_flops(64, 2, 128)
+    assert 7_900 < x.flops / x.nbytes < 8_000
+    ranked = sorted((c for k in kinds.values() for c in k.candidates),
+                    key=lambda c: -c.flops / c.nbytes)
+    assert ranked.index(x) < ranked.index(next(
+        c for c in ranked if c.names == names.RES_DELTA_PARTS))
+    phase = max(blocks.backward_phases(
+        base, kinds, blocks.pattern_groups(cfg.pattern)),
+        key=lambda p: p.nbytes)
+    assert phase.name == "3 x scan(L)"
+    # parameters, two moments and the gradients: 7.51 GB (the config's file)
+    resident = 12 * qn.param_count(cfg)
+    assert "7.51 GB" in config["deployment"] and round(resident / 1e7) == 751
+    policy = blocks.choose_remat_policy_kinds(
+        tuple(kinds.values()), phase.nbytes, family.V5E_BYTES_LIMIT, resident)
+    assert names.RES_DELTA_X in policy.saved
+    assert policy.saved_bytes <= policy.budget_bytes
+    assert {names.RES_FLASH_O, names.RES_MID, names.RES_DELTA_BA} <= set(
+        policy.saved)
+    assert not {names.RES_DELTA_STATES, names.RES_DELTA_O,
+                *names.RES_DELTA_PARTS} & set(policy.saved)
 
 
 @pytest.mark.parametrize("axis", ["ep", "tp", "pp", "cp"])

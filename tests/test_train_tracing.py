@@ -72,7 +72,8 @@ DSV2_OWN_SCOPES += (names.MHC, names.MHC_MAPS)
 QWEN3_OWN_SCOPES = (names.DELTA_MIXER, names.GATED_DELTA,
                     names.GATED_ATTN_GATE)
 DSV2_OWN_SCOPES += QWEN3_OWN_SCOPES     # (no other family's step has them)
-DELTA_KERNELS = (names.GATED_DELTA_FWD_KERNEL, names.GATED_DELTA_BWD_KERNEL)
+DELTA_KERNELS = (names.GATED_DELTA_FWD_KERNEL, names.GATED_DELTA_SOLVE_KERNEL,
+                 names.GATED_DELTA_BWD_KERNEL)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 MHC_KERNELS = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL,
                names.MHC_WRITE_FWD_KERNEL, names.MHC_WRITE_BWD_KERNEL)
