@@ -40,7 +40,8 @@ the delta rule's three kernels, ``ops/gated_delta.py``, and one output-gated
 attention layer at hd 256, each over gated experts beside a gated shared one,
 PR 61) for its ``LLLF`` pattern, its ``model/remat_policy`` decision and the
 ``ops/delta_tiling`` decisions of the solve, the forward and the backward
-kernel (or it fails), and —
+kernel and of the four kernels of the mixer's elementwise work around them
+(``ops/delta_pointwise.py``, PR 63; or it fails), and —
 what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
@@ -510,6 +511,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     if config.get("qwen3_model") is not None:
         from ray_tpu.models import qwen3_next
         from ray_tpu.models.blocks import layer_pattern_decisions
+        from ray_tpu.ops.delta_pointwise import pointwise_tiling_decisions
         from ray_tpu.ops.gated_delta import delta_tiling_decisions
 
         qwen3_cfg = config["qwen3_model"]
@@ -539,6 +541,8 @@ def train_loop(config: Dict[str, Any]) -> None:
                                   and d["seq"] == qwen3_cfg.seq_len],
                  "delta_tiling": [d for d in delta_tiling_decisions()
                                   if d["S"] == qwen3_cfg.seq_len],
+                 "pointwise_tiling": [d for d in pointwise_tiling_decisions()
+                                      if d["S"] == qwen3_cfg.seq_len],
                  "flash_tiling": [d for d in flash_tiling_decisions()
                                   if d["hd"] == qwen3_cfg.head_dim],
                  "expert_load": load,
@@ -773,6 +777,13 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
                        "decision of each of the solve, the forward and the "
                        "backward kernel: the delta rule ran on no kernel of "
                        "the program's, or solved inside the other two")
+        if {d["kernel"] for d in qwen3["pointwise_tiling"]} != {
+                "conv_norm_fwd", "conv_norm_bwd", "gate_norm_fwd",
+                "gate_norm_bwd"}:
+            bad.append("the Qwen3-Next step recorded no ops/delta_tiling "
+                       "decision of each of the four kernels around the "
+                       "scan: the mixer's conv, norms and gate ran as XLA's "
+                       "elementwise code")
         if {d["kernel"] for d in qwen3["flash_tiling"]} != {"fwd", "bwd"}:
             bad.append("the Qwen3-Next step recorded no ops/flash_tiling "
                        "decision of both kernels at its head width")
@@ -1210,6 +1221,12 @@ def main() -> int:
               f"{d['dv']} -> {d['head_tile']} value head(s) of "
               f"{d['key_tile']} key head(s) a grid step, VMEM "
               f"estimate {d['vmem_estimate'] / 2 ** 20:.2f} MiB")
+    for d in qwen3["pointwise_tiling"]:
+        print(f"delta tiling: {d['kernel']} rows={d['rows']} S={d['S']} "
+              f"channels={d['channels']}, the norm over {d['heads']} head(s) "
+              f"-> {d['token_tile']} tokens a grid step, "
+              f"{d['channel_tile']} lanes at a time, VMEM estimate "
+              f"{d['vmem_estimate'] / 2 ** 20:.2f} MiB")
     for d in qwen3["flash_tiling"]:
         print(f"flash tiling: {d['kernel']} rows={d['rows']} Sq={d['Sq']} "
               f"hd={d['hd']} -> block_q={d['block_q']} block_k={d['block_k']} "

@@ -63,7 +63,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import blocks, parts
-from ray_tpu.ops import gated_delta, mamba2, moe
+from ray_tpu.ops import delta_pointwise, gated_delta, mamba2, moe
 from ray_tpu.tracing import names as scopes
 
 KINDS = "LF"        # Gated DeltaNet, full (gated) attention
@@ -339,13 +339,30 @@ def _conv_silu(x, taps, dtype):
         x, taps, jnp.zeros((x.shape[-1],), taps.dtype))).astype(dtype)
 
 
+def _gated_rmsnorm(o, z, gain, eps: float):
+    """o / rms(o) over each head's channels of o [B, S, heads · d] · gain [d]
+    · silu(z), the product in float32, in o's dtype; written once where it
+    stands: elementwise work on one side, the out-projection on the other
+    (parts.made_once)."""
+    heads = o.shape[-1] // gain.shape[0]
+    of = o.astype(jnp.float32)
+    sums, spread = _head_sums(of, heads)
+    return parts.made_once(
+        (of * spread(lax.rsqrt(sums / gain.shape[0] + eps))
+         * jnp.tile(gain.astype(jnp.float32), heads)
+         * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype))
+
+
 @jax.named_scope(scopes.DELTA_MIXER)
 def delta_mixer(u, p, cfg: Qwen3NextConfig):
     """u [B, S, D] (normed) → the mixer's output [B, S, D] float32. The
     fused projection is ONE tensor, as published; its four parts (q, k, v,
     z) are four products on slices of the WEIGHT, so that no [tokens, 12,288]
     activation is made to be cut apart again, and the conv — depthwise —
-    takes each part with its own taps."""
+    takes each part with its own taps. The elementwise work on either side
+    of the scan is ops/delta_pointwise.py's two kernel pairs where the scan
+    is its kernels (attention.resolve_attention's rule on
+    ``attention_impl``), and the plain forms above anywhere else."""
     B, S, _ = u.shape
     Hk, Hv = cfg.linear_key_heads, cfg.linear_value_heads
     dk, dv, kw, vw = (cfg.linear_key_dim, cfg.linear_value_dim, cfg.key_width,
@@ -358,10 +375,17 @@ def delta_mixer(u, p, cfg: Qwen3NextConfig):
     ba = checkpoint_name(jnp.einsum("bsd,de->bse", u, p["w_ba"],
                                     preferred_element_type=jnp.float32),
                          scopes.RES_DELTA_BA)
-    q, k, v = (_conv_silu(x, taps[:, lo:hi], u.dtype)
-               for x, lo, hi in zip((q, k, v), edges, edges[1:]))
-    q = (_l2norm(q, Hk) * dk ** -0.5).astype(u.dtype)
-    k = _l2norm(k, Hk).astype(u.dtype)
+    impl, interpret, _ = parts.attention_on_mesh(cfg.attention_impl)
+    if impl == "pallas":
+        q, k, v = (delta_pointwise.conv_silu_norm(
+            x, taps[:, lo:hi], heads, scale, interpret=interpret)
+            for x, lo, hi, heads, scale in zip(
+                (q, k, v), edges, edges[1:], (Hk, Hk, 0), (dk ** -0.5, 1, 1)))
+    else:
+        q, k, v = (_conv_silu(x, taps[:, lo:hi], u.dtype)
+                   for x, lo, hi in zip((q, k, v), edges, edges[1:]))
+        q = (_l2norm(q, Hk) * dk ** -0.5).astype(u.dtype)
+        k = _l2norm(k, Hk).astype(u.dtype)
     beta = jax.nn.sigmoid(ba[..., :Hv])
     g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
         ba[..., Hv:] + p["dt_bias"].astype(jnp.float32))
@@ -369,14 +393,11 @@ def delta_mixer(u, p, cfg: Qwen3NextConfig):
         q.reshape(B, S, Hk, dk), k.reshape(B, S, Hk, dk),
         v.reshape(B, S, Hv, dv), g, beta, cfg.delta_chunk, cfg.attention_impl)
     o = checkpoint_name(o.reshape(B, S, vw), scopes.RES_DELTA_O)
-    # the per-head norm and the gate, the product in float32; elementwise
-    # work on one side, the out-projection on the other (parts.made_once)
-    of = o.astype(jnp.float32)
-    sums, spread = _head_sums(of, Hv)
-    gain = jnp.tile(p["delta_norm"].astype(jnp.float32), Hv)
-    y = parts.made_once(
-        (of * spread(lax.rsqrt(sums / dv + cfg.rms_eps)) * gain
-         * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype))
+    if impl == "pallas":
+        y = delta_pointwise.gated_rmsnorm(o, z, p["delta_norm"], cfg.rms_eps,
+                                          interpret=interpret)
+    else:
+        y = _gated_rmsnorm(o, z, p["delta_norm"], cfg.rms_eps)
     return jnp.einsum("bse,ed->bsd", y, p["w_out"],
                       preferred_element_type=jnp.float32)
 

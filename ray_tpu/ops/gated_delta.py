@@ -638,10 +638,14 @@ def _chunks_call(kernel: str, q, k, v, cum, beta, Hk: int, C: int,
         body, name = _bwd_kernel, scopes.GATED_DELTA_BWD_KERNEL
         args += [states, do]
         specs += [state_spec, val_spec]
-        # d q, d k: a key head's tiles each write their own, summed below
+        # d q, d k: a key head's tiles each write their own, summed below —
+        # float32 partial sums; one tile a key head writes the operands'
+        # dtype at once (a cast outside the call is a pass over HBM of its
+        # own wherever a kernel reads the gradient: PERF.md §6, PR 63)
         tile_spec = pl.BlockSpec((None, C, kt * dk),
                                  lambda b, i, c: (b, chunk_of(c), i))
-        key_grad = jax.ShapeDtypeStruct((B, S, T * dk), jnp.float32)
+        key_grad = jax.ShapeDtypeStruct(
+            (B, S, T * dk), jnp.float32 if per > 1 else q.dtype)
         out_shape = [key_grad, key_grad, jax.ShapeDtypeStruct(v.shape, v.dtype),
                      jax.ShapeDtypeStruct((B, T, nc, 2, N), jnp.float32)]
         out_specs = [tile_spec, tile_spec, val_spec, rows_spec]

@@ -162,6 +162,17 @@ MHC_WRITE_BWD_KERNEL = "mhc_write_bwd"
 GATED_DELTA_FWD_KERNEL = "gated_delta_fwd"
 GATED_DELTA_SOLVE_KERNEL = "gated_delta_fwd_solve"
 GATED_DELTA_BWD_KERNEL = "gated_delta_bwd"
+# the Gated DeltaNet mixer's elementwise work on either side of that rule
+# (ops/delta_pointwise.py), a run of a row's tokens at a tensor's whole width,
+# a head's channels whole lane tiles: conv + SiLU + per-head L2 norm of one
+# projection's output (backward: d x and d w's partial sums), and the
+# per-head RMS norm of o times gain times silu(z) (backward: d o, d z and
+# d gain's partial sums). They run under DELTA_MIXER and outside GATED_DELTA;
+# no name holds an older kernel's, and none of those holds one of these
+DELTA_CONV_NORM_FWD_KERNEL = "delta_conv_norm_fwd"
+DELTA_CONV_NORM_BWD_KERNEL = "delta_conv_norm_bwd"
+DELTA_GATE_NORM_FWD_KERNEL = "delta_gate_norm_fwd"
+DELTA_GATE_NORM_BWD_KERNEL = "delta_gate_norm_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
            SSD_CHUNK_BWD_KERNEL, SPARSE_ATTN_FWD_KERNEL,
@@ -169,7 +180,9 @@ KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            CONV_GATE_FWD_KERNEL, CONV_GATE_BWD_KERNEL, MHC_MIX_FWD_KERNEL,
            MHC_MIX_BWD_KERNEL, MHC_WRITE_FWD_KERNEL, MHC_WRITE_BWD_KERNEL,
            GATED_DELTA_FWD_KERNEL, GATED_DELTA_SOLVE_KERNEL,
-           GATED_DELTA_BWD_KERNEL)
+           GATED_DELTA_BWD_KERNEL, DELTA_CONV_NORM_FWD_KERNEL,
+           DELTA_CONV_NORM_BWD_KERNEL, DELTA_GATE_NORM_FWD_KERNEL,
+           DELTA_GATE_NORM_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"
@@ -197,6 +210,13 @@ DELTA_TILING = "ops/delta_tiling"
 DELTA_TILING_ARGS = ("kernel", "rows", "S", "C", "key_heads",
                      "value_heads_per_key", "dk", "dv", "head_tile",
                      "key_tile", "vmem_estimate")
+# ... and, under the same event, for a kernel of the mixer's elementwise work
+# around the rule (ops/delta_pointwise.py: "conv_norm_fwd" / "_bwd",
+# "gate_norm_fwd" / "_bwd"): the batch rows, the (padded) sequence and width,
+# the heads a norm sums over apart (0: no norm), the tokens a grid step takes
+# and the lanes its body works on at a time
+DELTA_POINTWISE_TILING_ARGS = ("kernel", "rows", "S", "channels", "heads",
+                               "token_tile", "channel_tile", "vmem_estimate")
 # the same for a block-sparse attention kernel ("fwd", "bwd_dq", "bwd_dkv"):
 # the (batch x key-value head) rows, the sequence, the query heads that share
 # a key-value head (the rows of a tile's products, with `block_q` tokens),

@@ -413,6 +413,11 @@ def test_qwen3_next_through_trainer_at_toy_size(fake_tpu_node):
     assert {(d["kernel"], d["C"], d["head_tile"])
             for d in qwen3["delta_tiling"]} == {
         ("solve", 16, 2), ("fwd", 16, 2), ("bwd", 16, 2)}
+    # ... and (PR 63) the four kernels around the scan: q and k under the
+    # norm over 2 heads, v without, o and z over 4
+    assert {(d["kernel"], d["heads"]) for d in qwen3["pointwise_tiling"]} == {
+        ("conv_norm_fwd", 2), ("conv_norm_bwd", 2), ("conv_norm_fwd", 0),
+        ("conv_norm_bwd", 0), ("gate_norm_fwd", 4), ("gate_norm_bwd", 4)}
     assert [e["layer"] for e in qwen3["expert_load"]] == [0, 1, 2, 3]
     assert np.asarray(qwen3["step_load"]).shape == (4, 3)
     assert all(0.8 < b < 2.0 for b in qwen3["balance_loss"])
@@ -420,6 +425,11 @@ def test_qwen3_next_through_trainer_at_toy_size(fake_tpu_node):
         "delta_tiling": [], "layer_pattern": [], "expert_load": []}}}]
     assert len(chip_smoke.check_training(rows[:-1] + no_kernel, cfg, steps)
                ) == 3
+    no_gate = [rows[-1] | {"summary": summary | {"qwen3": qwen3 | {
+        "pointwise_tiling": [d for d in qwen3["pointwise_tiling"]
+                             if d["kernel"] != "gate_norm_bwd"]}}}]
+    assert len(chip_smoke.check_training(rows[:-1] + no_gate, cfg, steps)
+               ) == 1
     no_solve = [rows[-1] | {"summary": summary | {"qwen3": qwen3 | {
         "delta_tiling": [d for d in qwen3["delta_tiling"]
                          if d["kernel"] != "solve"]}}}]

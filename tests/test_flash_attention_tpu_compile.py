@@ -1134,3 +1134,64 @@ def test_the_xing4_cell_step_fits_and_clones_nothing_on_the_v5e(topo):
                     + policy["saved_bytes"])
     assert {"streams": 4, "rounds": 20, "stream_dtype": "bfloat16",
             "carry_bytes_per_token": 28672} in hyper_connections.decisions()
+
+
+@pytest.mark.parametrize("C,heads", [(2048, 16), (4096, 0)],
+                         ids=["q-k-with-the-norm", "v-without"])
+def test_the_conv_norm_kernels_compile_for_the_v5e_at_the_cells_shape(
+        one_chip, C, heads):
+    """PR 63: ops/delta_pointwise's first pair at the tile the rule chooses,
+    at the Qwen3-Next cell's shapes (q and k [4, 8,192, 2,048] bf16 with the
+    per-head norm, v [4, 8,192, 4,096] without, w [4, C]), forward and
+    backward: what interpret mode cannot show — the sublane rotations, the
+    halo blocks on both sides, a head's lane reduction, more VMEM than
+    Mosaic's default — and that outside the two calls the compiled op holds
+    no [B, S, C] float32 tensor."""
+    from ray_tpu.ops import delta_pointwise as dp
+    from ray_tpu.ops.attention import VMEM_BUDGET_BYTES
+
+    B, S = 4, 8192
+    x = jax.ShapeDtypeStruct((B, S, C), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, C), jnp.float32, sharding=one_chip)
+
+    def grads(x, w, dy):
+        y, vjp = jax.vjp(lambda *a: dp._conv_norm(
+            *a, heads, 128 ** -0.5 if heads else 1.0, False), x, w)
+        return (y,) + vjp(dy)
+
+    hlo = jax.jit(grads).lower(x, w, x).compile().as_text()
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l and " = " in l]
+    assert sum("delta_conv_norm_fwd" in l for l in calls) == 1
+    assert sum("delta_conv_norm_bwd" in l for l in calls) == 1 and len(calls) == 2
+    assert not re.findall(rf"f32\[{B},{S},{C}\]", hlo)
+    mine = {d["kernel"]: d for d in dp.pointwise_tiling_decisions()
+            if (d["rows"], d["S"], d["channels"], d["heads"]) == (B, S, C, heads)}
+    assert set(mine) == {dp.CONV_NORM_FWD, dp.CONV_NORM_BWD}
+    assert all(d["token_tile"] == 256 and d["vmem_estimate"] <= VMEM_BUDGET_BYTES
+               for d in mine.values())
+
+
+def test_the_gate_norm_kernels_compile_for_the_v5e_at_the_cells_shape(one_chip):
+    """PR 63: the second pair at the cell's shape (o, z [4, 8,192, 4,096]
+    bf16: 32 heads of 128, one gain vector), forward and backward."""
+    from ray_tpu.ops import delta_pointwise as dp
+    from ray_tpu.ops.attention import VMEM_BUDGET_BYTES
+
+    B, S, C, heads = 4, 8192, 4096, 32
+    o = jax.ShapeDtypeStruct((B, S, C), jnp.bfloat16, sharding=one_chip)
+    gain = jax.ShapeDtypeStruct((C // heads,), jnp.float32, sharding=one_chip)
+
+    def grads(o, z, gain, dy):
+        y, vjp = jax.vjp(lambda *a: dp._gate_norm(*a, heads, 1e-6, False),
+                         o, z, gain)
+        return (y,) + vjp(dy)
+
+    hlo = jax.jit(grads).lower(o, o, gain, o).compile().as_text()
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l and " = " in l]
+    assert sum("delta_gate_norm_fwd" in l for l in calls) == 1
+    assert sum("delta_gate_norm_bwd" in l for l in calls) == 1 and len(calls) == 2
+    assert not re.findall(rf"f32\[{B},{S},{C}\]", hlo)
+    mine = {d["kernel"]: d for d in dp.pointwise_tiling_decisions()
+            if (d["rows"], d["S"], d["channels"], d["heads"]) == (B, S, C, heads)}
+    assert set(mine) == {dp.GATE_NORM_FWD, dp.GATE_NORM_BWD}
+    assert all(d["vmem_estimate"] <= VMEM_BUDGET_BYTES for d in mine.values())

@@ -37,7 +37,7 @@ if ROOT not in sys.path:
 from benchmarks.families import qwen3_next as family  # noqa: E402
 from benchmarks.families import qwen3_next_reference as reference  # noqa: E402
 from ray_tpu.models import blocks, qwen3_next as qn  # noqa: E402
-from ray_tpu.ops import gated_delta, moe  # noqa: E402
+from ray_tpu.ops import delta_pointwise, gated_delta, moe  # noqa: E402
 from ray_tpu.tracing import names  # noqa: E402
 
 CELL = "qwen3-next-80b-a3b-l4.dataset"
@@ -428,6 +428,212 @@ def test_a_rows_first_outputs_do_not_depend_on_what_follows():
         a, b = forward(tokens), forward(other)
     assert float(jnp.max(jnp.abs(a[:, :29] - b[:, :29]))) < 1e-5
     assert float(jnp.max(jnp.abs(a[:, 29:] - b[:, 29:]))) > 1e-3
+
+
+# ------------------------------- the elementwise work around the scan (PR 63)
+def _plain_conv_norm(x, w, heads, scale):
+    """The first pair's definition: models/qwen3_next.py's plain lines."""
+    a = qn._conv_silu(x, w, x.dtype)
+    return (qn._l2norm(a, heads) * scale).astype(x.dtype) if heads else a
+
+
+def _close(got, want, dtype):
+    """≤ 1e-6 of the tensor's largest value in float32 (the arithmetic's own
+    error, where terms cancel); in bf16 one ulp OF EACH VALUE's binade (2⁻⁷
+    of it) besides."""
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    tol = 1e-6 * max(1.0, float(np.max(np.abs(want))))
+    if dtype == jnp.bfloat16:
+        tol = tol + 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30)))
+                            - 7)
+    return bool(np.all(np.abs(got - want) <= tol))
+
+
+def _with_grads(fn, *args, dy):
+    y, vjp = jax.vjp(fn, *args)
+    return (y,) + vjp(dy)
+
+
+# S = 300 is 19 tiles of 16 tokens (the rule takes a divisor of the padded
+# 304): every tile but the first reads the one before it, every one but the
+# last the one after; S = 40 is one tile of 48 with 8 tokens of padding
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("B,S,heads,d", [(2, 300, 2, 128), (1, 40, 4, 16),
+                                         (2, 50, 0, 192)],
+                         ids=["2-heads-19-tiles", "4-narrow-heads", "no-norm"])
+def test_the_conv_norm_pair_is_the_plain_conv_silu_and_l2norm(B, S, heads, d,
+                                                              dtype):
+    """``delta_conv_norm_fwd`` / ``_bwd`` (interpreted here) against
+    ``_conv_silu`` + ``_l2norm`` and ``jax.grad`` of them: the output, the
+    input's gradient and the taps'. K = 4."""
+    C = max(heads, 1) * d
+    k = jax.random.split(jax.random.PRNGKey(S), 3)
+    x = jax.random.normal(k[0], (B, S, C), jnp.float32).astype(dtype)
+    dy = jax.random.normal(k[1], (B, S, C), jnp.float32).astype(dtype)
+    w = jax.random.normal(k[2], (4, C), jnp.float32) * 0.5
+    scale = d ** -0.5 if heads else 1.0
+    got = _with_grads(lambda x, w: delta_pointwise.conv_silu_norm(
+        x, w, heads, scale, interpret=True), x, w, dy=dy)
+    want = _with_grads(lambda x, w: _plain_conv_norm(x, w, heads, scale),
+                       x, w, dy=dy)
+    assert [t.dtype for t in got] == [dtype, dtype, jnp.float32]
+    assert _close(got[0], want[0], dtype)
+    # (the plain form's d x is a sum of K bf16-rounded terms: two ulps)
+    assert _close(got[1], want[1], dtype) or dtype == jnp.bfloat16 and float(
+        np.max(np.abs(np.asarray(got[1] - want[1], np.float32)))) <= 2.0 ** -6 * float(
+            jnp.max(jnp.abs(want[1])))
+    assert _rel(got[2], want[2]) < (1e-6 if dtype == jnp.float32 else 2e-3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("B,S,heads,d", [(2, 300, 2, 128), (1, 40, 4, 16)],
+                         ids=["2-heads-19-tiles", "4-narrow-heads"])
+def test_the_gate_norm_pair_is_the_plain_gated_rmsnorm(B, S, heads, d, dtype):
+    """``delta_gate_norm_fwd`` / ``_bwd`` against the mixer's plain lines
+    after the scan and ``jax.grad`` of them: y, d o, d z and d gain."""
+    k = jax.random.split(jax.random.PRNGKey(S + 1), 4)
+    o, z, dy = (jax.random.normal(key, (B, S, heads * d), jnp.float32
+                                  ).astype(dtype) for key in k[:3])
+    gain = 1 + 0.1 * jax.random.normal(k[3], (d,), jnp.float32)
+    got = _with_grads(lambda *a: delta_pointwise.gated_rmsnorm(
+        *a, 1e-6, interpret=True), o, z, gain, dy=dy)
+    want = _with_grads(lambda *a: qn._gated_rmsnorm(*a, 1e-6),
+                       o, z, gain, dy=dy)
+    assert [t.dtype for t in got] == [dtype] * 3 + [jnp.float32]
+    assert all(_close(g, w, dtype) for g, w in zip(got[:3], want[:3]))
+    assert _rel(got[3], want[3]) < (1e-6 if dtype == jnp.float32 else 2e-3)
+
+
+def test_a_rows_first_tokens_see_zeros_and_a_tile_sees_its_neighbours(
+        monkeypatch):
+    """The conv by its definition, token by token in numpy: a row's first
+    K − 1 tokens read zeros before the row — not the row before it in the
+    batch —, a tile's first tokens the tile before (the halo), and the
+    gradient of a tile's last tokens the tile after."""
+    B, S, C, K = 2, 72, 128, 4
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    x, dy = (np.asarray(jax.random.normal(key, (B, S, C))) for key in k[:2])
+    w = np.asarray(jax.random.normal(k[2], (K, C))) * 0.5
+    padded = np.concatenate([np.zeros((B, K - 1, C), np.float32), x], 1)
+    c = sum(padded[:, j:j + S] * w[j] for j in range(K))
+    sig = 1 / (1 + np.exp(-c))
+    dc = dy * sig * (1 + c * (1 - sig))
+    after = np.concatenate([dc, np.zeros((B, K - 1, C), np.float32)], 1)
+    dx = sum(after[:, K - 1 - j:K - 1 - j + S] * w[j] for j in range(K))
+    dw = np.stack([(dc * padded[:, j:j + S]).sum((0, 1)) for j in range(K)])
+    # 72 tokens are 80 with the padding: five tiles of 16 under this target
+    # (the calls are jitted by shape: none traced under another target stays)
+    monkeypatch.setattr(delta_pointwise, "_TARGET_TOKENS", 16)
+    monkeypatch.setattr(delta_pointwise, "_decisions", {})
+    delta_pointwise._conv_norm_call.clear_cache()
+    got = _with_grads(lambda x, w: delta_pointwise.conv_silu_norm(
+        x, w, interpret=True), jnp.asarray(x), jnp.asarray(w),
+        dy=jnp.asarray(dy))
+    delta_pointwise._conv_norm_call.clear_cache()
+    assert [(d["kernel"], d["S"], d["token_tile"])
+            for d in delta_pointwise.pointwise_tiling_decisions()] == [
+        ("conv_norm_fwd", 80, 16), ("conv_norm_bwd", 80, 16)]
+    for mine, theirs in zip(got, (c * sig, dx, dw)):
+        assert _rel(mine, theirs) < 1e-6
+    assert np.allclose(got[0][:, 0], (x[:, 0] * w[K - 1]) * sig[:, 0],
+                       rtol=1e-5, atol=1e-7)
+
+
+def test_the_pointwise_tiling_rule_reads_the_shapes():
+    """At the cell's shapes: 256 tokens of q, k and v a grid step, 128 of o
+    and z (the gate pair's backward holds five blocks of 4,096 channels),
+    a head's lanes at a time, every estimate inside Mosaic's default limit."""
+    from ray_tpu.ops.attention import VMEM_BUDGET_BYTES
+
+    rule = delta_pointwise.choose_pointwise_tiling
+    cell = {(kernel, C, heads): rule(kernel, 4, 8192, C, heads, 2)
+            for kernel, C, heads in (
+                ("conv_norm_fwd", 2048, 16), ("conv_norm_bwd", 2048, 16),
+                ("conv_norm_fwd", 4096, 0), ("conv_norm_bwd", 4096, 0),
+                ("gate_norm_fwd", 4096, 32), ("gate_norm_bwd", 4096, 32))}
+    assert {key: t[:2] for key, t in cell.items()} == {
+        ("conv_norm_fwd", 2048, 16): (256, 128),
+        ("conv_norm_bwd", 2048, 16): (256, 128),
+        ("conv_norm_fwd", 4096, 0): (256, 128),
+        ("conv_norm_bwd", 4096, 0): (256, 128),
+        ("gate_norm_fwd", 4096, 32): (128, 128),
+        ("gate_norm_bwd", 4096, 32): (128, 128)}
+    assert all(t.vmem_estimate <= VMEM_BUDGET_BYTES for t in cell.values())
+    # a pair has one tile, the backward's; a forward's estimate is its own
+    assert cell["conv_norm_fwd", 4096, 0].vmem_estimate < cell[
+        "conv_norm_bwd", 4096, 0].vmem_estimate
+    # with no norm a narrow tensor takes more lanes at a time
+    assert rule("conv_norm_fwd", 4, 8192, 1024, 0, 2)[:2] == (256, 512)
+    event = delta_pointwise.pointwise_tiling_decisions()[-1]
+    assert tuple(event) == names.DELTA_POINTWISE_TILING_ARGS
+    assert names.DELTA_TILING_ARGS != names.DELTA_POINTWISE_TILING_ARGS
+    with pytest.raises(ValueError, match="do not fit VMEM"):
+        rule("gate_norm_bwd", 1, 8192, 2 ** 17, 1024, 4)
+    with pytest.raises(ValueError, match="unknown delta-mixer kernel 'fwd'"):
+        rule("fwd", 1, 64, 128, 1, 4)
+
+
+def test_the_mixers_gradient_runs_the_four_kernels_and_names_them():
+    """The delta mixer's gradient under a checkpoint that keeps nothing:
+    q, k and v each through the first pair's forward twice (the recompute)
+    and its backward once, the scan's result and z through the second's. No
+    new name holds an older kernel's, and no older one a new one's — a
+    trace's reader finds a kernel by the first of ``names.KERNELS`` inside
+    an instruction's name."""
+    cfg = qn.qwen3_next_tiny(dtype=jnp.float32, attention_impl="pallas")
+    p = {**_first_layer(_params(cfg)), }
+    u = jax.random.normal(jax.random.PRNGKey(0), (1, cfg.seq_len, cfg.d_model))
+    loss = jax.checkpoint(lambda u, p: jnp.sum(qn.delta_mixer(u, p, cfg)),
+                          policy=jax.checkpoint_policies.nothing_saveable)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(u, p)
+    calls = _kernel_calls(jaxpr.jaxpr)
+    new = (names.DELTA_CONV_NORM_FWD_KERNEL, names.DELTA_CONV_NORM_BWD_KERNEL,
+           names.DELTA_GATE_NORM_FWD_KERNEL, names.DELTA_GATE_NORM_BWD_KERNEL)
+    assert [calls[name] for name in new] == [6, 3, 2, 1]
+    text = str(jaxpr)
+    assert "bsc,ch->bsh" not in text and "bsh,ch->bsc" not in text
+    old = tuple(k for k in names.KERNELS if k not in new)
+    assert len(old) + 4 == len(names.KERNELS) and names.KERNELS[-4:] == new
+    assert not [(a, b) for a in new for b in old if a in b or b in a]
+    assert not [(a, b) for a in new for b in new if a != b and a in b]
+    # and off a TPU, left to the rule, the plain forms run
+    plain = qn.qwen3_next_tiny(dtype=jnp.float32)
+    assert not _kernel_calls(jax.make_jaxpr(
+        lambda u, p: qn.delta_mixer(u, p, plain))(u, p).jaxpr)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_tiny_model_with_the_kernels_is_the_tiny_model_without(
+        dtype, tol, monkeypatch):
+    """Loss and every gradient of the tiny model on the four kernels
+    against the same model — the same scan and flash kernels — with the plain
+    forms in their place."""
+    cfg = qn.qwen3_next_tiny(dtype=dtype, attention_impl="pallas", remat=True)
+    params, (tokens, targets) = _params(cfg), _batch(cfg)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(
+                lambda p: qn.loss_fn(p, tokens, targets, cfg)))(params)
+
+    loss, grads = run()
+    monkeypatch.setattr(
+        delta_pointwise, "conv_silu_norm",
+        lambda x, w, heads=0, scale=1.0, **_: _plain_conv_norm(
+            x, w, heads, scale))
+    monkeypatch.setattr(
+        delta_pointwise, "gated_rmsnorm",
+        lambda o, z, gain, eps, **_: qn._gated_rmsnorm(o, z, gain, eps))
+    plain_loss, plain_grads = run()
+    assert float(loss) == pytest.approx(float(plain_loss), rel=tol / 10)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(plain_grads)):
+        loose = getattr(path[-1], "key", "") in ("A_log", "dt_bias")
+        assert _rel(g, r) < tol * (100 if loose else 1), (path, _rel(g, r))
 
 
 # --------------------------------------------------------- the expert half

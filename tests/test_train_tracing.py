@@ -74,6 +74,11 @@ QWEN3_OWN_SCOPES = (names.DELTA_MIXER, names.GATED_DELTA,
 DSV2_OWN_SCOPES += QWEN3_OWN_SCOPES     # (no other family's step has them)
 DELTA_KERNELS = (names.GATED_DELTA_FWD_KERNEL, names.GATED_DELTA_SOLVE_KERNEL,
                  names.GATED_DELTA_BWD_KERNEL)
+# ... and the mixer's elementwise work on either side of the scan (PR 63)
+DELTA_KERNELS += (names.DELTA_CONV_NORM_FWD_KERNEL,
+                  names.DELTA_CONV_NORM_BWD_KERNEL,
+                  names.DELTA_GATE_NORM_FWD_KERNEL,
+                  names.DELTA_GATE_NORM_BWD_KERNEL)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 MHC_KERNELS = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL,
                names.MHC_WRITE_FWD_KERNEL, names.MHC_WRITE_BWD_KERNEL)
