@@ -41,7 +41,11 @@ attention layer at hd 256, each over gated experts beside a gated shared one,
 PR 61) for its ``LLLF`` pattern, its ``model/remat_policy`` decision and the
 ``ops/delta_tiling`` decisions of the solve, the forward and the backward
 kernel and of the four kernels of the mixer's elementwise work around them
-(``ops/delta_pointwise.py``, PR 63; or it fails), and —
+(``ops/delta_pointwise.py``, PR 63; or it fails), one step of a small Ouro
+(``models/llama.py`` with ``ut_steps`` 2 over two layers, sandwich norms and
+the exit gate, PR 64) for its ``model/loop`` event (or it fails), the remat
+rule's passes and applications and the mean exit distribution the step says
+of itself, and —
 what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
@@ -550,6 +554,34 @@ def train_loop(config: Dict[str, Any]) -> None:
                  "balance_loss": np.ascontiguousarray(
                      counters[:, n_load]).view(np.float32).tolist()}
         del variant
+    # One step of a looped stack: two layers run twice on one set of weights,
+    # a head and an exit gate after each pass (models/llama.py, PR 64).
+    ouro = None
+    if config.get("ouro_model") is not None:
+        from ray_tpu.models import llama
+        from ray_tpu.models.blocks import loop_decisions
+
+        ouro_cfg = config["ouro_model"]
+        variant = make_train_step(
+            llama, ouro_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP,
+                                        total_steps=steps))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, ouro_cfg.seq_len), dtype=np.int32)
+        _, m = variant.step_fn(variant.state, jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding))
+        ouro = {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+                "seq_len": ouro_cfg.seq_len,
+                "loop": [d for d in loop_decisions()
+                         if d["passes"] == ouro_cfg.ut_steps
+                         and d["layers"] == ouro_cfg.n_layer],
+                "remat_policy": [d for d in remat_policy_decisions()
+                                 if d.get("passes") == ouro_cfg.ut_steps
+                                 and d["seq"] == ouro_cfg.seq_len],
+                "exit_distribution": np.asarray(m["counters"]).view(
+                    np.float32)[0].tolist()}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -581,6 +613,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         "dsv2": dsv2,
         "xing4": xing4,
         "qwen3": qwen3,
+        "ouro": ouro,
     }})
 
 
@@ -588,8 +621,8 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         use_tpu: bool, seed: int = 0, eva_model=None,
         hybrid_model=None, sala_model=None,
         lfm2_model=None, dsv2_model=None,
-        xing4_model=None, qwen3_model=None, grouped_shapes=GROUPED_SHAPES
-        ) -> List[Dict[str, Any]]:
+        xing4_model=None, qwen3_model=None, ouro_model=None,
+        grouped_shapes=GROUPED_SHAPES) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -613,7 +646,8 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "eva_model": eva_model, "hybrid_model": hybrid_model,
             "sala_model": sala_model, "lfm2_model": lfm2_model,
             "dsv2_model": dsv2_model, "xing4_model": xing4_model,
-            "qwen3_model": qwen3_model, "grouped_shapes": grouped_shapes,
+            "qwen3_model": qwen3_model, "ouro_model": ouro_model,
+            "grouped_shapes": grouped_shapes,
         },
         scaling_config=train.ScalingConfig(
             num_workers=1, use_tpu=use_tpu,
@@ -794,6 +828,20 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
         if not all(0.5 < b < 8.0 for b in qwen3["balance_loss"]):
             bad.append("the Qwen3-Next step said balance losses "
                        f"{qwen3['balance_loss']} of its layers")
+    ouro = summary.get("ouro")
+    if ouro is not None:
+        if not (math.isfinite(ouro["loss"])
+                and math.isfinite(ouro["grad_norm"])):
+            bad.append(f"the Ouro step's loss {ouro['loss']} or grad_norm "
+                       f"{ouro['grad_norm']} is not finite")
+        if not ouro["loop"]:
+            bad.append("the Ouro step recorded no model/loop event: its "
+                       "layers did not go through blocks.run_repeated")
+        *p, entropy = ouro["exit_distribution"]
+        if not (abs(sum(p) - 1.0) < 1e-3 and all(0.0 < q < 1.0 for q in p)
+                and 0.0 < entropy < math.log(len(p)) + 1e-3):
+            bad.append("the Ouro step said a mean exit distribution "
+                       f"{p} of entropy {entropy}: not one over its passes")
     xing4 = summary.get("xing4")
     if xing4 is not None:
         if not (math.isfinite(xing4["loss"])
@@ -1022,6 +1070,11 @@ def main() -> int:
         vocab_size=4096, seq_len=2048, n_layer=4, d_model=1024, n_head=8,
         linear_key_heads=8, linear_value_heads=16, n_experts=128,
         held_count=16, d_expert=512, d_shared=512, remat=True)
+    # Ouro's block at half the width: two layers run twice on one set of
+    # weights, sandwich norms, a head and an exit gate after each pass
+    ouro_cfg = llama.ouro_2p6b(
+        n_layer=2, ut_steps=2, d_model=1024, n_head=8, n_kv_head=8, d_ff=2816,
+        vocab_size=8192, seq_len=2048, remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -1035,7 +1088,8 @@ def main() -> int:
                    num_devices=chips, use_tpu=True, eva_model=eva_cfg,
                    hybrid_model=hybrid_cfg, sala_model=sala_cfg,
                    lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg,
-                   xing4_model=xing4_cfg, qwen3_model=qwen3_cfg)
+                   xing4_model=xing4_cfg, qwen3_model=qwen3_cfg,
+                   ouro_model=ouro_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -1248,6 +1302,24 @@ def main() -> int:
           f"{summary['device_count']}x{qwen3['seq_len']} tokens, remat): loss "
           f"{qwen3['loss']:.4f} grad_norm {qwen3['grad_norm']:.4f}, balance "
           f"loss a layer {[round(b, 4) for b in qwen3['balance_loss']]}")
+    ouro = summary["ouro"]
+    for d in ouro["loop"]:
+        print(f"loop: {d['layers']} layers x {d['passes']} passes = "
+              f"{d['applications']} applications on one set of weights; a "
+              f"stack of their float32 gradients "
+              f"{d['grad_stack_bytes'] / 2 ** 20:.1f} MiB; heads: {d['heads']}")
+    for d in ouro["remat_policy"]:
+        print(f"Ouro remat policy: {d['n_layer']} layers, {d['passes']} "
+              f"passes, {d['applications']} applications, batch={d['batch']} "
+              f"seq={d['seq']}: saved={d['saved']} "
+              f"({d['saved_bytes'] / gib:.2f} GiB of {d['budget_bytes'] / gib:.2f}"
+              f" left by the backward's phase {d['phase']!r})")
+    print(f"Ouro step ({ouro_cfg.n_layer} layers x {ouro_cfg.ut_steps} passes "
+          f"of {ouro_cfg.d_model}, {summary['device_count']}x"
+          f"{ouro['seq_len']} tokens, remat): loss {ouro['loss']:.4f} "
+          f"grad_norm {ouro['grad_norm']:.4f}; it said of itself a mean exit "
+          f"distribution {[round(q, 4) for q in ouro['exit_distribution'][:-1]]}"
+          f", entropy {ouro['exit_distribution'][-1]:.4f}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

@@ -62,7 +62,10 @@ class KindShard(NamedTuple):
     or the kind's own arithmetic), what its whole residual set takes while its
     backward runs (parts.block_working_set), and the bytes of one application's
     weight gradients (backward_phases takes those not made yet off a run's
-    phase; a model of one run of layers has none to take off and states 0)."""
+    phase; a model of one run of layers has none to take off and states 0).
+    Under run_repeated ``applications`` counts every PASS's — a kept residual
+    is copied once an application — while ``grad_bytes`` stays ONE layer's:
+    the passes share the weights, and backward_phases is told the passes."""
     applications: int
     candidates: Tuple[RematCandidate, ...]
     block_bytes: int
@@ -86,7 +89,8 @@ def run_name(run: Run) -> str:
 
 def model_working_set(s: Shard, n_layer: int) -> int:
     """parts.rematted_working_set's part that no block decides: the stack of
-    ``n_layer`` block inputs, the LM head's logits, the gathered embedding."""
+    ``n_layer`` block inputs (applications, under run_repeated), the LM
+    head's logits, the gathered embedding."""
     return n_layer * _block_input(s) + _head_terms(s) + _gathered(s)
 
 
@@ -97,15 +101,40 @@ def _block_input(s: Shard) -> int:
     return s.batch * s.seq * width * s.dtype_bytes
 
 
+def _passes(s: Shard) -> int:
+    """How often the shard's model runs its layers on one set of weights
+    (run_repeated): 1 unless the shard states ``passes``."""
+    return getattr(s, "passes", 1) or 1
+
+
 def _head_terms(s: Shard) -> int:
     a = s.dtype_bytes
-    head = s.batch * (s.head_rows or s.seq) * s.vocab * (2 * a + 4)
+    # (a looped model's ONE head call takes every pass's rows: its batch)
+    head = (_passes(s) * s.batch * (s.head_rows or s.seq) * s.vocab
+            * (2 * a + 4))
     if s.head_rows:
         # a head in chunks makes its gradient in the forward and keeps it
         # (ops/cross_entropy.chunked_head_xent): d x stands where the chunked
         # x stood, the running float32 d lm_head is new
         head += s.d_model * s.vocab * 4
     return head
+
+
+def _loop_terms(s: Shard, kinds: Dict[str, "KindShard"],
+                runs: Sequence["Run"]) -> int:
+    """What run_repeated's outer loop itself holds while its backward runs,
+    0 for a model of one pass: the passes' loop-end states beside their
+    cotangents (the head hands back all of them at once) and what the
+    function between the passes keeps of its input (the un-normed x of a
+    loop-end norm), and ONE pass's stacks of weight gradients — a pass's
+    backward writes its own before they are added to the running sum, which
+    the resident bytes count."""
+    passes = _passes(s)
+    if passes == 1:
+        return 0
+    states = 3 * passes * s.batch * s.seq * s.d_model * s.dtype_bytes
+    return states + sum(reps * sum(kinds[k].grad_bytes for k in sub)
+                        for sub, reps in runs)
 
 
 def _gathered(s: Shard) -> int:
@@ -128,14 +157,29 @@ def backward_phases(s: Shard, kinds: Dict[str, KindShard],
     that less the block). The MEMEMEMEM*E + *E hybrid's head, MTP module
     and last five layers are dead by the time the scan of eight writes 1.7 GiB
     of gradients: summed, the estimate stood 2.5 GiB over the compiled step
-    (PERF.md §6, PR 42)."""
+    (PERF.md §6, PR 42).
+
+    A model that runs ``runs`` several times on one set of weights
+    (run_repeated; the shard states ``passes``) meets them LAYERS x PASSES
+    times: the backward starts in the LAST pass, where every earlier pass's
+    block inputs still wait beside its own, and the loop holds what
+    _loop_terms says. Its weight gradients are the layers', not the
+    applications': the running sum is resident from the start (nothing is
+    taken off), one more pass's stacks stand beside it."""
     layers = [reps * len(sub) for sub, reps in runs]
     grads = [reps * sum(kinds[k].grad_bytes for k in sub) for sub, reps in runs]
-    phases = [Phase("head", model_working_set(s, sum(layers)) - sum(grads))]
+    passes = _passes(s)
+    earlier = loop = 0
+    if passes > 1:
+        earlier = (passes - 1) * sum(layers) * _block_input(s)
+        loop = _loop_terms(s, kinds, runs)
+        grads = [0] * len(runs)
+    phases = [Phase("head", model_working_set(s, passes * sum(layers))
+                    - sum(grads))]
     for i in reversed(range(len(runs))):
         live = (sum(layers[:i + 1]) * _block_input(s) + _gathered(s)
                 + max(kinds[k].block_bytes for k in runs[i][0])
-                - sum(grads[:i]))
+                - sum(grads[:i]) + earlier + loop)
         if i == len(runs) - 1:
             live += _head_terms(s)
         phases.append(Phase(run_name(runs[i]), live))
@@ -218,7 +262,9 @@ def _remat_policy(shard: Shard, kinds: Dict[str, KindShard],
     head and the rows the head and the MLP take at a time."""
     from ray_tpu.parallel import mesh as mesh_lib
 
-    n_layer = sum(k.applications for k in kinds.values())
+    applications = sum(k.applications for k in kinds.values())
+    passes = _passes(shard)
+    n_layer = applications // passes
     phase = max(backward_phases(shard, kinds, runs), key=lambda p: p.nbytes)
     policy = choose_remat_policy_kinds(
         tuple(kinds.values()), phase.nbytes, *mesh_lib.current_chip_memory())
@@ -226,6 +272,8 @@ def _remat_policy(shard: Shard, kinds: Dict[str, KindShard],
                     (n_layer, shard.batch, shard.seq, list(policy.saved))
                     + policy[1:] + (shard.mlp_rows or shard.seq,
                                     shard.head_rows or shard.seq) + phase))
+    if passes > 1:
+        args.update(zip(scopes.REMAT_POLICY_LOOP_ARGS, (passes, applications)))
     key = (shard, tuple(kinds.items()), tuple(runs)) + policy
     if key not in _decisions:
         _decisions[key] = args
@@ -359,6 +407,62 @@ def run_pattern(block_fns: Dict[str, Callable], pattern: str, x,
     return (x, auxes) if with_aux else x
 
 
+_loops: Dict[tuple, Dict[str, Any]] = {}
+
+
+def loop_decisions() -> List[Dict[str, Any]]:
+    """Every distinct looped stack this process has traced a model with, as
+    the ``model/loop`` events carry them."""
+    return list(_loops.values())
+
+
+def _record_loop(pattern: str, stacks, repeats: int, heads: str) -> None:
+    """The ``model/loop`` event of run_repeated: once per distinct decision,
+    at trace time. ``grad_stack_bytes`` is one float32 copy of the stacks'
+    gradients on one chip of the mesh in use (its even share)."""
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.current_mesh()
+    chips = mesh.devices.size if mesh is not None else 1
+    nbytes = sum(math.prod(a.shape) * 4 for a in jax.tree.leaves(stacks))
+    args = dict(zip(scopes.LOOP_ARGS, (
+        repeats, len(pattern), repeats * len(pattern), nbytes // chips, heads)))
+    key = tuple(args.values())
+    if key not in _loops:
+        _loops[key] = args
+        component, name = scopes.LOOP.split("/")
+        get_buffer().record_profile(name, component=component, args=args)
+
+
+def run_repeated(block_fns: Dict[str, Callable], pattern: str, x,
+                 stacks: Sequence[Dict[str, Any]], repeats: int,
+                 between: Callable, heads: str = ""):
+    """x through ``pattern``'s layers ``repeats`` times over the SAME
+    ``stacks`` (run_pattern's), ``between(x)`` after the last layer of every
+    pass: what it returns is what that pass hands out AND what the next pass
+    starts from. The result stacks the passes' states, ``[repeats, ...]``.
+
+    ONE ``lax.scan`` over the passes around run_pattern's own: compile time
+    and program size follow the distinct runs of ``pattern``, not repeats x
+    depth. The stacks are the outer scan's constants, so AD's transpose
+    carries ONE running sum of their gradients through the passes and adds
+    each pass's stacks to it as the pass's backward ends — never ``repeats``
+    stacks side by side. The block inputs a pass's scans keep are stacked
+    over the passes in turn: repeats x layers of them wait (``KindShard.
+    applications``; backward_phases is told the shard's ``passes``).
+    ``heads`` is the model's word in the ``model/loop`` event for how its
+    heads are called on the passes' states."""
+    _record_loop(pattern, stacks, repeats, heads)
+    record_layer_pattern(pattern, repeats)
+
+    def one_pass(x, _):
+        h = between(run_pattern(block_fns, pattern, x, stacks))
+        return h, h
+
+    _, states = lax.scan(one_pass, x, None, length=repeats)
+    return states
+
+
 class StepCounters(NamedTuple):
     """What a model's compiled step says of itself every step, as its module's
     ``step_counters(cfg)`` states it (None, or no such function: nothing):
@@ -445,19 +549,25 @@ def with_leaf(pattern: str, stacks: Sequence[Dict[str, Any]], name: str,
     return out
 
 
-def record_layer_pattern(pattern: str) -> None:
+def record_layer_pattern(pattern: str, passes: int = 1) -> None:
     """The ``model/layer_pattern`` event of a model whose layers are of more
-    than one kind: the pattern, how often each kind is applied and which
-    runs are one scan; once per distinct pattern, at trace time."""
-    if pattern in _patterns:
+    than one kind, or run more than once (run_repeated: ``passes``, and the
+    event then says them): the pattern of one pass, how often each kind is
+    applied — over all the passes — and which runs are one scan; once per
+    distinct pattern, at trace time."""
+    key = pattern if passes == 1 else f"{passes} x ({pattern})"
+    if key in _patterns:
         return
     groups = pattern_groups(pattern)
-    _patterns[pattern] = dict(zip(scopes.LAYER_PATTERN_ARGS, (
-        pattern, {kind: pattern.count(kind) for kind in dict.fromkeys(pattern)},
+    _patterns[key] = dict(zip(scopes.LAYER_PATTERN_ARGS, (
+        pattern, {kind: passes * pattern.count(kind)
+                  for kind in dict.fromkeys(pattern)},
         [run_name(run) for run in groups])))
+    if passes > 1:
+        _patterns[key]["passes"] = passes
     component, name = scopes.LAYER_PATTERN.split("/")
     get_buffer().record_profile(name, component=component,
-                                args=_patterns[pattern])
+                                args=_patterns[key])
 
 
 def layer_pattern_decisions() -> List[Dict[str, Any]]:

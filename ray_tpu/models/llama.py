@@ -14,7 +14,13 @@ The config selects the mixer — ``causal`` (flash attention) or ``eva``
 (ops/eva_attention.py: exact softmax inside a window, one learned summary a
 chunk of every earlier window) — the number of prediction heads (head p
 predicts token t + 1 + p) and the norm's unit offset: with ``eva``, eight
-heads and the offset this is EvaByte (``evabyte_6p5b``).
+heads and the offset this is EvaByte (``evabyte_6p5b``). It also says how
+often the stack of layers runs (``ut_steps`` passes over ONE set of weights,
+the final norm inside the loop: ``blocks.run_repeated``), whether each
+sublayer's output goes through a second norm before its residual add
+(``sandwich_norm``), and whether a gate after every pass weighs that pass's
+loss (``exit_gate``: the exit distribution over the passes, its entropy in
+the objective): with four passes and both this is Ouro (``ouro_2p6b``).
 
 Numerics anchor: tests/test_llama_model.py checks logits against
 HuggingFace transformers' LlamaForCausalLM on a tiny config — RoPE layout,
@@ -35,7 +41,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import parts
-from ray_tpu.models.blocks import run_blocks
+from ray_tpu.models.blocks import StepCounters, run_blocks, run_repeated
 from ray_tpu.tracing import names as scopes
 
 
@@ -61,8 +67,17 @@ class LlamaConfig:
     n_pred_heads: int = 1         # head p predicts token t + 1 + p
     norm_unit_offset: bool = False   # norm scales by (1 + g), g born 0
     init_std: float = 0.02
+    ut_steps: int = 1             # passes of the layers over one set of weights
+    sandwich_norm: bool = False   # a norm on each sublayer's output too
+    exit_gate: bool = False       # a gate a pass weighs the passes' losses
+    exit_beta: float = 0.0        # the exit distribution's entropy, in the loss
 
     def __post_init__(self):
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps must be >= 1; got {self.ut_steps}")
+        if self.exit_gate and self.n_pred_heads != 1:
+            raise ValueError("the exit gate weighs ONE head's loss a pass: "
+                             "n_pred_heads must be 1")
         if self.mixer not in ("causal", "eva"):
             raise ValueError(f"unknown mixer {self.mixer!r}")
         if self.mixer == "eva":
@@ -140,6 +155,32 @@ def evabyte_tiny(**overrides) -> LlamaConfig:
     )
 
 
+def ouro_2p6b(**overrides) -> LlamaConfig:
+    """Ouro-2.6B as published (huggingface.co/ByteDance/Ouro-2.6B; "Scaling
+    Latent Reasoning via Looped Language Models", arXiv:2510.25741): 48
+    layers run four times on one set of weights, sandwich norms, the final
+    norm inside the loop, a head and an exit gate after every pass; the
+    Stage-I objective at beta 0.05."""
+    return replace(
+        LlamaConfig(vocab_size=49152, seq_len=65536, n_layer=48, n_head=16,
+                    n_kv_head=16, d_model=2048, d_ff=5632,
+                    rope_theta=1000000.0, rms_eps=1e-6, ut_steps=4,
+                    sandwich_norm=True, exit_gate=True, exit_beta=0.05),
+        **overrides,
+    )
+
+
+def ouro_tiny(**overrides) -> LlamaConfig:
+    """Test-size Ouro: two layers run three times."""
+    return replace(
+        LlamaConfig(vocab_size=256, seq_len=64, n_layer=2, n_head=4,
+                    n_kv_head=4, d_model=64, d_ff=176, rope_theta=1000000.0,
+                    rms_eps=1e-6, ut_steps=3, sandwich_norm=True,
+                    exit_gate=True, exit_beta=0.05),
+        **overrides,
+    )
+
+
 # --------------------------------------------------------------------------- #
 # Parameters
 # --------------------------------------------------------------------------- #
@@ -158,12 +199,17 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     }
     if cfg.mixer == "eva":
         blocks["eva_phi"] = blocks["eva_mu"] = ("layers", "heads", "kv")
-    return {
+    if cfg.sandwich_norm:
+        blocks["attn_out_norm"] = blocks["mlp_out_norm"] = ("layers", "embed")
+    axes = {
         "wte": ("vocab", "embed"),
         "blocks": blocks,
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
+    if cfg.exit_gate:
+        axes["exit_w"], axes["exit_b"] = ("embed",), (None,)
+    return axes
 
 
 def mesh_rules(cfg: LlamaConfig, mesh) -> Dict[str, str]:
@@ -211,12 +257,22 @@ def init(cfg: LlamaConfig, rng: jax.Array) -> Dict[str, Any]:
     if cfg.mixer == "eva":
         blocks["eva_phi"] = eva_vector(next(keys))
         blocks["eva_mu"] = eva_vector(next(keys))
-    return {
+    if cfg.sandwich_norm:
+        blocks["attn_out_norm"] = norm_init((L, D), pd)
+        blocks["mlp_out_norm"] = norm_init((L, D), pd)
+    params = {
         "wte": wte,
         "blocks": blocks,
         "final_norm": norm_init((D,), pd),
         "lm_head": lm_head,
     }
+    if cfg.exit_gate:
+        # drawn like any other weight (a key of its own: the eleven above
+        # draw what they always drew), the bias 0: at birth the gate is
+        # near 1/2 and differs by token
+        params["exit_w"] = normal(jax.random.fold_in(rng, 11), (D,))
+        params["exit_b"] = jnp.zeros((1,), pd)
+    return params
 
 
 def param_count(cfg: LlamaConfig) -> int:
@@ -279,17 +335,25 @@ def _block(x, p, cfg: LlamaConfig):
     with jax.named_scope(scopes.ATTN):
         attn = _attention(q, k, v, p, cfg)
     with jax.named_scope(scopes.PROJ):
-        x = checkpoint_name(parts.residual_add(x, jnp.einsum(
-            f"{heads},hkd->bsd", attn, p["wo"],
-            preferred_element_type=jnp.float32)), scopes.RES_MID)
+        y = jnp.einsum(f"{heads},hkd->bsd", attn, p["wo"],
+                       preferred_element_type=jnp.float32)
+        if cfg.sandwich_norm:
+            with jax.named_scope(scopes.LN1_POST):
+                y = _norm(y, p["attn_out_norm"], cfg)
+        x = checkpoint_name(parts.residual_add(x, y), scopes.RES_MID)
     return _mlp(x, p, cfg)
 
 
 def _swiglu(x, p, cfg: LlamaConfig):
-    """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]."""
+    """x + down(silu(gate(h)) · up(h)), h = norm(x), on [B, rows, D]; with
+    ``sandwich_norm`` the MLP's output under a norm of its own, the rows'
+    (float32 in, float32 out: no normed copy of the sequence stands whole)."""
     with jax.named_scope(scopes.LN2):
         h = _norm(x, p["mlp_norm"], cfg)
     y = parts.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+    if cfg.sandwich_norm:
+        with jax.named_scope(scopes.LN2_POST):
+            y = _norm(y, p["mlp_out_norm"], cfg)
     with jax.named_scope(scopes.MLP):
         return parts.residual_add(x, y)
 
@@ -326,27 +390,60 @@ def block_shard(cfg: LlamaConfig, global_batch: int, seq: int,
         dense_mlp=True, kv_heads=cfg.n_kv_head,
         mlp_hidden=(scopes.RES_MLP_GATE, scopes.RES_MLP_UP),
         window=cfg.window if cfg.mixer == "eva" else 0, chunk=cfg.chunk,
-        head_rows=parts.head_rows(global_batch, seq, columns,
-                                  cfg.n_pred_heads),
+        head_rows=_head_rows(cfg, global_batch, seq),
         mlp_rows=parts.mlp_rows(global_batch, seq, cfg.d_model, cfg.d_ff,
                                 jnp.dtype(cfg.dtype).itemsize),
-        cast_in_loop=True,
+        cast_in_loop=True, passes=cfg.ut_steps, out_norms=cfg.sandwich_norm,
     ), mesh)
 
 
-def _trunk(params, tokens, cfg: LlamaConfig):
-    """tokens [B, S] int32 → final hidden states [B, S, D]."""
+def _head_rows(cfg: LlamaConfig, batch: int, seq: int) -> int:
+    """Rows of the sequence the head takes at a time (parts.head_rows). A
+    weighted head (``exit_gate``) is ONE chunked call over every pass's rows:
+    its batch is the passes' together, and it is never taken whole."""
+    columns = cfg.n_pred_heads * cfg.head_vocab
+    if not cfg.exit_gate:
+        return parts.head_rows(batch, seq, columns, cfg.n_pred_heads)
+    return parts.rows_under(seq, cfg.ut_steps * batch * columns * 4,
+                            parts.HEAD_CHUNK_BYTES)
+
+
+def _trunk(params, tokens, cfg: LlamaConfig, every_pass: bool = False):
+    """tokens [B, S] int32 → final hidden states [B, S, D]. Where the layers
+    run ``ut_steps`` times over their one set of weights
+    (blocks.run_repeated) the final norm stands INSIDE the loop: the normed
+    state is what a pass hands out and what the next starts from, and the
+    result is the last pass's — or, with ``every_pass``, all of them,
+    [ut_steps, B, S, D]."""
     from ray_tpu.parallel import mesh as mesh_lib
 
     B, S = tokens.shape
     with jax.named_scope(scopes.EMBED):
         x = params["wte"].astype(cfg.dtype)[tokens]
+    mesh = mesh_lib.current_mesh()
+
+    def loop_end(x):
+        with jax.named_scope(scopes.LN_F):
+            return _norm(x, params["final_norm"], cfg)
+
+    if cfg.ut_steps == 1:
+        block_fn = parts.checkpoint_block(
+            partial(_block, cfg=cfg), cfg.remat,
+            block_shard(cfg, B, S, mesh), cfg.n_layer)
+        x = loop_end(run_blocks(block_fn, x, params["blocks"]))
+        return x[None] if every_pass else x
+    chips = mesh.devices.size if mesh is not None else 1
+    layer_bytes = sum(math.prod(a.shape[1:]) * a.dtype.itemsize
+                      for a in jax.tree.leaves(params["blocks"]))
     block_fn = parts.checkpoint_block(
-        partial(_block, cfg=cfg), cfg.remat,
-        block_shard(cfg, B, S, mesh_lib.current_mesh()), cfg.n_layer)
-    x = run_blocks(block_fn, x, params["blocks"])
-    with jax.named_scope(scopes.LN_F):
-        return _norm(x, params["final_norm"], cfg)
+        partial(_block, cfg=cfg), cfg.remat, block_shard(cfg, B, S, mesh),
+        cfg.n_layer, grad_bytes=layer_bytes // chips)
+    states = run_repeated(
+        {"B": block_fn}, "B" * cfg.n_layer, x, [{"B": params["blocks"]}],
+        cfg.ut_steps, loop_end,
+        heads=(f"one chunked call over {cfg.ut_steps} x {B} rows"
+               if cfg.exit_gate else "the last pass's"))
+    return states if every_pass else states[-1]
 
 
 def forward(params, tokens, cfg: LlamaConfig) -> jax.Array:
@@ -356,12 +453,80 @@ def forward(params, tokens, cfg: LlamaConfig) -> jax.Array:
     return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype))
 
 
-def loss_fn(params, tokens, targets, cfg: LlamaConfig) -> jax.Array:
+def loss_fn(params, tokens, targets, cfg: LlamaConfig,
+            counters: bool = False):
     """Mean cross-entropy over targets >= 0 ([B, S] int32, the next token):
-    with n_pred_heads > 1 head p is scored on targets[t + p]."""
-    x = _trunk(params, tokens, cfg)
-    return parts.lm_head_loss(x, targets, params["lm_head"], cfg.dtype,
-                              cfg.n_pred_heads)
+    with n_pred_heads > 1 head p is scored on targets[t + p]. With
+    ``exit_gate`` the exit-weighted objective (_exit_loss), and with
+    ``counters`` (what step_counters offers) ``(loss, counters)``: the
+    step's mean exit distribution as blocks.StepCounters packs it."""
+    if not cfg.exit_gate:
+        x = _trunk(params, tokens, cfg)
+        return parts.lm_head_loss(x, targets, params["lm_head"], cfg.dtype,
+                                  cfg.n_pred_heads)
+    loss, said = _exit_loss(
+        params, _trunk(params, tokens, cfg, every_pass=True), targets, cfg)
+    return (loss, said) if counters else loss
+
+
+def _exit_loss(params, states, targets, cfg: LlamaConfig):
+    """The exit-weighted objective over the passes' states [T, B, S, D]
+    (Ouro's Stage I, a uniform prior over the exit step): a gate after every
+    pass, λ_t = sigmoid(h_t · w + b) a token; the exit distribution p_t =
+    λ_t · Π_{j<t} (1 − λ_j), the last pass taking what is left; and
+
+        loss = 1/N · Σ_i [ Σ_t p_t(i) · nll_t(i) − β · H(p(i)) ]
+
+    over the N valid targets, nll_t the ONE head's cross-entropy on pass t's
+    state. The head is one chunked call over the passes' rows [B · T, S]
+    with p as its weights (parts.lm_head_loss), so p's cotangent is the
+    per-token nll and the float32 d lm_head is summed once a step. Gate,
+    distribution and entropy are float32; log p comes from log λ and
+    log(1 − λ) (``log_sigmoid`` of ± the logit), never the log of a product.
+    Returns (loss, the step's counters: the mean of each p_t and of H over
+    the valid tokens, float32 bits in ONE int32 row, constants to AD)."""
+    T, B, S, D = states.shape
+    valid = targets >= 0
+    n_valid = jnp.maximum(jnp.sum(valid), 1).astype(jnp.float32)
+    with jax.named_scope(scopes.EXIT_GATE):
+        logit = (jnp.sum(states.astype(jnp.float32)
+                         * params["exit_w"].astype(jnp.float32), axis=-1)
+                 + params["exit_b"].astype(jnp.float32))        # [T, B, S]
+        log_stay = jax.nn.log_sigmoid(-logit)                  # log(1 − λ)
+        stayed = jnp.cumsum(log_stay, axis=0) - log_stay       # Σ_{j<t}
+        last = (jnp.arange(T) == T - 1)[:, None, None]
+        log_p = stayed + jnp.where(last, 0.0, jax.nn.log_sigmoid(logit))
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)                   # [B, S]
+        mean_entropy = jnp.sum(jnp.where(valid, entropy, 0.0)) / n_valid
+    # rows [B · T, S], a batch row's passes together: the batch's sharding
+    # stays what it is
+    weighted = parts.lm_head_loss(
+        states.swapaxes(0, 1).reshape(B * T, S, D),
+        jnp.repeat(targets, T, axis=0), params["lm_head"], cfg.dtype,
+        weights=p.swapaxes(0, 1).reshape(B * T, S))
+    # (the head divides by ITS valid targets, each of the N counted T times)
+    loss = T * weighted - cfg.exit_beta * mean_entropy
+    with jax.named_scope(scopes.EXIT_GATE):
+        mean_p = jnp.sum(jnp.where(valid, p, 0.0), axis=(1, 2)) / n_valid
+        said = jax.lax.bitcast_convert_type(jax.lax.stop_gradient(
+            jnp.concatenate([mean_p, mean_entropy[None]])), jnp.int32)[None]
+    return loss, said
+
+
+def step_counters(cfg: LlamaConfig):
+    """What a step of this config says of itself (train_step asks every
+    model): with the exit gate ONE row, the step's mean exit distribution
+    over the passes and its mean entropy (names.EXIT_DISTRIBUTION_KIND);
+    nothing without."""
+    if not cfg.exit_gate:
+        return None
+    fields = tuple(f"{scopes.STEP_EXIT_PASS}{t + 1}"
+                   for t in range(cfg.ut_steps)) + (scopes.STEP_EXIT_ENTROPY,)
+    return StepCounters(
+        scopes.EXIT_DISTRIBUTION_KIND, fields, (cfg.n_layer - 1,),
+        lambda tokens: dict(zip(scopes.EXIT_DISTRIBUTION_STATIC_ARGS,
+                                (cfg.ut_steps,))), fields)
 
 
 # --------------------------------------------------------------------------- #

@@ -368,17 +368,25 @@ def head_targets(targets: jax.Array, n_heads: int) -> jax.Array:
 
 
 @jax.named_scope(scopes.LM_HEAD_LOSS)
-def lm_head_loss(x, targets, lm_head, dtype, n_pred_heads: int = 1):
+def lm_head_loss(x, targets, lm_head, dtype, n_pred_heads: int = 1,
+                 weights=None):
     """Untied head(s) + cross-entropy over final hidden states [B, S, D]: the
     mean over the heads of each head's mean over its valid targets. Where the
     head goes in chunks (head_rows) the logits and their gradient
     are never one tensor, and a chunk's logits are multiplied out once a
     step: the chunk that makes its loss makes its gradient
-    (ops/cross_entropy.chunked_head_xent)."""
+    (ops/cross_entropy.chunked_head_xent). With ``weights`` [B, S] float32
+    (one head): Σ weight · nll over the valid targets ÷ their number, always
+    through the chunked head, whose gradient reaches the weights too."""
     from ray_tpu.ops import cross_entropy
 
     B, S = targets.shape
     lm_head = lm_head.astype(dtype)
+    if weights is not None:
+        return cross_entropy.chunked_head_xent(
+            x, head_targets(targets, 1), lm_head,
+            rows_under(S, B * lm_head.shape[1] * 4, HEAD_CHUNK_BYTES),
+            weights[..., None])
     rows = head_rows(B, S, lm_head.shape[1], n_pred_heads)
     if not rows:
         # fused CE (ops/cross_entropy.py): no [B, S, V] float32 residual
@@ -438,6 +446,14 @@ class BlockShard(NamedTuple):
     # channels of the layers' carry where it is wider than d_model (a
     # hyper-connected model's n streams: models/hyper_connections.py); 0: d_model
     carry_width: int = 0
+    # how often the model runs its layers on one set of weights
+    # (blocks.run_repeated): the block inputs that wait, and the head's rows,
+    # are this many times a pass's
+    passes: int = 1
+    # each sublayer's output goes through a norm of its own before the
+    # residual add (a sandwich norm): the float32 output and the normed one
+    # wait in the block's backward, and so do their cotangents
+    out_norms: bool = False
 
 
 def shard_block(whole: BlockShard, mesh) -> BlockShard:
@@ -570,7 +586,8 @@ def block_working_set(s: BlockShard) -> int:
     weights = 2 * a * s.d_model * (
         2 * attn_width + 2 * kv_width + (len(s.mlp_hidden) + 1) * s.d_ff
     ) if s.cast_in_loop else 0
-    return block + _eva_k_f32(s) + weights
+    out_norms = 4 * tokens * s.d_model * 4 if s.out_norms else 0
+    return block + _eva_k_f32(s) + weights + out_norms
 
 
 def swiglu_price(batch: int, seq: int, rows: int, d_model: int, d_ff: int,
@@ -638,7 +655,7 @@ def choose_remat_policy(shard: BlockShard, n_layer: int,
 
 
 def checkpoint_block(block_fn, remat: bool, shard: BlockShard,
-                     n_layer: int):
+                     n_layer: int, grad_bytes: int = 0):
     """``block_fn(x, layer_params)`` as the layer scan calls it, for any model
     whose block carries the names of tracing/names.RESIDUALS; n_layer is how
     many of them one chip runs (a pipeline stage's share under pp): a
@@ -652,10 +669,13 @@ def checkpoint_block(block_fn, remat: bool, shard: BlockShard,
     where the names cover every output that is dear to make again — a Pallas
     attention kernel's and the dense MLP's; XLA and ring attention and the
     experts tag none of theirs, so those blocks stay as AD leaves them. The
-    one-kind case of checkpoint_kinds."""
+    one-kind case of checkpoint_kinds. Where the shard states ``passes``
+    (blocks.run_repeated) the block is applied n_layer x passes times and
+    ``grad_bytes``, one LAYER's weight gradients on a chip, says what one
+    pass's stack of them takes beside the running sum."""
     if not remat and not (shard.flash and shard.dense_mlp):
         return block_fn
-    kind = KindShard(n_layer, tuple(remat_candidates(shard)),
-                     block_working_set(shard))
+    kind = KindShard(n_layer * shard.passes, tuple(remat_candidates(shard)),
+                     block_working_set(shard), grad_bytes)
     return checkpoint_kinds({"block": block_fn}, remat, shard,
                             {"block": kind}, [(("block",), n_layer)])["block"]

@@ -137,35 +137,51 @@ def _mean_over_heads(total, count):
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def chunked_head_xent(x: jax.Array, targets: jax.Array, lm_head: jax.Array,
-                      rows: int) -> jax.Array:
+                      rows: int, weights=None) -> jax.Array:
     """Head(s) + cross-entropy over hidden states ``x`` [B, S, D], ``rows``
     positions of the sequence at a time: ``lm_head`` [D, heads · columns] in
     x's dtype, head p in columns p·columns … (p+1)·columns, ``targets``
     [B, S, heads] int32 (< 0 = ignore) → the mean over the heads of each
     head's mean negative log-likelihood over its valid targets, float32.
     ``rows`` divides S; a chunk's logits [B, rows, heads · columns] are
-    float32 and the largest tensor there is."""
+    float32 and the largest tensor there is.
+
+    ``weights`` [B, S, heads] float32 (None: every target weighs 1, and the
+    op lowers as it did before it took any): Σ weight · nll over a head's
+    valid targets ÷ their NUMBER — the weights are differentiated, their
+    cotangent a target's own nll ÷ that number (and ÷ heads)."""
     _record(x, targets, lm_head, rows, grad_in_forward=False)
     heads = targets.shape[-1]
 
     def chunk(total, xs):
-        x_c, t_c = xs
+        x_c, t_c, *w_c = xs
         nll, _ = _nll_and_lse(_chunk_logits(x_c, lm_head, heads), t_c)
+        for w in w_c:
+            nll = nll * w
         return total + jnp.sum(nll, axis=(0, 1)), None
 
     total, _ = lax.scan(chunk, jnp.zeros((heads,), jnp.float32),
-                        (_chunks(x, rows), _chunks(targets, rows)))
+                        _scanned(x, targets, weights, rows))
     return _mean_over_heads(total, jnp.sum(targets >= 0, axis=(0, 1)))
 
 
-def _chunked_fwd(x, targets, lm_head, rows):
+def _scanned(x, targets, weights, rows: int):
+    """What the chunk scan walks: x, the targets and, where there are any,
+    the weights."""
+    given = (x, targets) if weights is None else (x, targets, weights)
+    return tuple(_chunks(a, rows) for a in given)
+
+
+def _chunked_fwd(x, targets, lm_head, rows, weights=None):
     """The loss, and for a unit cotangent ``d x`` (a chunk: stacked by the
     scan, in x's dtype) and ``d lm_head`` (the scan's carry, float32): each
     chunk forms (softmax − onehot) · weight from the logits it has and
     multiplies it into both. The weight of a valid target of head p is
-    1 / (heads · count_p), from the targets alone. The two products take what
-    AD's transposes of the logits' einsum took: float32 ``d logits``, the
-    other operand in the compute dtype, float32 out."""
+    1 / (heads · count_p), from the targets alone — times the target's own of
+    ``weights`` where those are given, whose cotangent, nll / (heads ·
+    count_p), the chunk then hands out too (stacked by the scan). The two
+    products take what AD's transposes of the logits' einsum took: float32
+    ``d logits``, the other operand in the compute dtype, float32 out."""
     _record(x, targets, lm_head, rows, grad_in_forward=True)
     heads = targets.shape[-1]
     count = jnp.sum(targets >= 0, axis=(0, 1))
@@ -173,31 +189,48 @@ def _chunked_fwd(x, targets, lm_head, rows):
 
     def chunk(carry, xs):
         total, d_head = carry
-        x_c, t_c = xs
+        x_c, t_c, *w_c = xs
         logits = _chunk_logits(x_c, lm_head, heads)
         nll, lse = _nll_and_lse(logits, t_c)
-        d_logits, _ = _xent_bwd((logits, lse, t_c),
-                                jnp.broadcast_to(weight, t_c.shape))
+        of_target = jnp.broadcast_to(weight, t_c.shape)
+        for w in w_c:
+            of_target = of_target * w
+        d_logits, _ = _xent_bwd((logits, lse, t_c), of_target)
         d_logits = d_logits.reshape(x_c.shape[:2] + (-1,))
         d_x = lax.dot_general(d_logits, lm_head, (((2,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
         d_head = d_head + lax.dot_general(
             x_c, d_logits, (((0, 1), (0, 1)), ((), ())),
             preferred_element_type=jnp.float32)
-        return (total + jnp.sum(nll, axis=(0, 1)), d_head), d_x.astype(x.dtype)
+        if not w_c:
+            return ((total + jnp.sum(nll, axis=(0, 1)), d_head),
+                    d_x.astype(x.dtype))
+        return ((total + jnp.sum(nll * w_c[0], axis=(0, 1)), d_head),
+                (d_x.astype(x.dtype), nll * weight))
 
     (total, d_head), d_x = lax.scan(
         chunk, (jnp.zeros((heads,), jnp.float32),
                 jnp.zeros(lm_head.shape, jnp.float32)),
-        (_chunks(x, rows), _chunks(targets, rows)))
-    return _mean_over_heads(total, count), (d_x, d_head)
+        _scanned(x, targets, weights, rows))
+    d_w = None
+    if weights is not None:
+        d_x, d_w = d_x
+    return _mean_over_heads(total, count), (d_x, d_head, d_w)
+
+
+def _unchunked(a, g):
+    """The scan's stack [S / rows, B, rows, ...] times the cotangent ``g``,
+    as [B, S, ...] in the stack's dtype."""
+    n, B, c = a.shape[:3]
+    return (g * a).astype(a.dtype).swapaxes(0, 1).reshape(
+        (B, n * c) + a.shape[3:])
 
 
 def _chunked_bwd(rows, res, g):
-    d_x, d_head = res                       # [S / rows, B, rows, D], [D, V]
-    n, B, c, D = d_x.shape
-    d_x = (g * d_x).astype(d_x.dtype).swapaxes(0, 1).reshape(B, n * c, D)
-    return d_x, None, (g * d_head).astype(d_x.dtype)
+    d_x, d_head, d_w = res                  # [S / rows, B, rows, D], [D, V]
+    d_x = _unchunked(d_x, g)
+    return (d_x, None, (g * d_head).astype(d_x.dtype),
+            None if d_w is None else _unchunked(d_w, g))
 
 
 chunked_head_xent.defvjp(_chunked_fwd, _chunked_bwd)

@@ -93,6 +93,14 @@ MHC_MAPS = "mhc_maps"
 DELTA_MIXER = "delta_mixer"
 GATED_DELTA = "gated_delta"
 GATED_ATTN_GATE = "gated_attn_gate"
+# models/llama.py's looped (Ouro) configs: the second norm on each sublayer's
+# OUTPUT, before its residual add (the attention's inside `proj`, the MLP's
+# inside its row chunk beside `mlp`); and everything of the objective that is
+# not the head — the gate's product with each pass's state, the exit
+# distribution over the passes, its entropy, the step's mean of each. The
+# loop-end norm is LN_F: it stands after the last layer of EVERY pass
+LN1_POST, LN2_POST = "ln1_post", "ln2_post"
+EXIT_GATE = "exit_gate"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
                                           EVA_PREP_KV, MAMBA, SSD_SCAN,
@@ -103,7 +111,8 @@ SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           MOE_FURTHER_PASSES, MLA_LATENT,
                                           MOE_AUX, MHC, MHC_MAPS,
                                           DELTA_MIXER, GATED_DELTA,
-                                          GATED_ATTN_GATE)
+                                          GATED_ATTN_GATE, LN1_POST, LN2_POST,
+                                          EXIT_GATE)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -348,6 +357,11 @@ REMAT_POLICY = "model/remat_policy"
 REMAT_POLICY_ARGS = ("n_layer", "batch", "seq", "saved", "saved_bytes",
                      "budget_bytes", "bytes_limit", "mlp_rows", "head_rows",
                      "phase", "phase_bytes")
+# ... and, of a model that runs its layers several times on one set of
+# weights (blocks.run_repeated), the passes and the block applications a kept
+# residual is copied for (n_layer stays the LAYERS: the weight gradients'
+# slices); a model of one pass says what it always said
+REMAT_POLICY_LOOP_ARGS = ("passes", "applications")
 # a head that takes the sequence in chunks (ops/cross_entropy.
 # chunked_head_xent): the batch rows and the positions a chunk holds, the
 # chunks, the head's columns (all heads') and heads, whether this trace makes
@@ -362,6 +376,16 @@ HEAD_LOSS_ARGS = ("batch", "rows", "chunks", "columns", "heads",
 # one instant event per distinct pattern, at trace time
 LAYER_PATTERN = "model/layer_pattern"
 LAYER_PATTERN_ARGS = ("pattern", "applications", "groups")
+# a model whose layers run several times on ONE set of weights
+# (blocks.run_repeated): the passes, the layers of one pass, the block
+# applications a step makes (passes x layers), the float32 bytes of one
+# stack of the layers' weight gradients on a chip (the backward holds the
+# running sum and at most one pass's stack beside it) and how the heads are
+# called ("one call over passes x batch rows": the model says); one instant
+# event per distinct decision, at trace time. A model/layer_pattern event of
+# the same trace says the pattern of ONE pass and these `passes`
+LOOP = "model/loop"
+LOOP_ARGS = ("passes", "layers", "applications", "grad_stack_bytes", "heads")
 # a model whose residual path is a hyper-connection (models/
 # hyper_connections.py): the streams, the Sinkhorn rounds, the stream's dtype
 # and the bytes a token's carry takes; one instant event per distinct
@@ -393,6 +417,14 @@ STEP_EXPERT_LOAD_ARGS = ("passes", "pairs", "max_per_expert")
 # step, BEFORE its coefficient: a float32 whose bits ride in the int32
 # counters (models/blocks.StepCounters.float_fields)
 STEP_BALANCE_LOSS = "balance_loss"
+# what a looped model with an exit gate (models/llama.py, `exit_gate`) says of
+# every step: ONE row, the step's mean over its valid tokens of the exit
+# distribution's mass on each pass (`exit_p1` … `exit_p<T>`, summing to 1) and
+# of its entropy — all float32 bits in the int32 counters, constants to AD
+EXIT_DISTRIBUTION_KIND = "exit_distribution"
+STEP_EXIT_PASS = "exit_p"            # + the pass, from 1
+STEP_EXIT_ENTROPY = "exit_entropy"
+EXIT_DISTRIBUTION_STATIC_ARGS = ("passes",)
 
 # host spans: `ray_tpu:<component>/<name>` on the profiler's clock,
 # `<component>/<name>` with that component in the task-event buffer

@@ -9,6 +9,7 @@ iterator; at GPT-2-124M it runs on the chip (python chip_smoke.py).
 """
 
 import json
+import math
 import os
 import subprocess
 import sys
@@ -435,6 +436,36 @@ def test_qwen3_next_through_trainer_at_toy_size(fake_tpu_node):
                          if d["kernel"] != "solve"]}}}]
     assert len(chip_smoke.check_training(rows[:-1] + no_solve, cfg, steps)
                ) == 1
+
+
+def test_ouro_through_trainer_at_toy_size(fake_tpu_node):
+    """chip_smoke's loop with a small Ouro step (two layers run twice, a head
+    and an exit gate after each pass) alone beside GPT-2's (PR 64; a test of
+    its own with its own time limit, as PR 61's lesson has it)."""
+    import chip_smoke
+    from ray_tpu.models import gpt2, llama
+
+    cfg, steps = gpt2.gpt2_tiny(), 16
+    rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1, num_devices=8,
+                          use_tpu=False,
+                          ouro_model=llama.ouro_tiny(ut_steps=2, remat=True),
+                          grouped_shapes=())
+    assert chip_smoke.check_training(rows, cfg, steps) == []
+    summary = rows[-1]["summary"]
+    ouro = summary["ouro"]
+    assert [(d["passes"], d["layers"], d["applications"])
+            for d in ouro["loop"]] == [(2, 2, 4)]
+    assert "2 x 8 rows" in ouro["loop"][0]["heads"]
+    assert [(d["n_layer"], d["passes"], d["applications"])
+            for d in ouro["remat_policy"]] == [(2, 2, 4)]
+    p1, p2, entropy = ouro["exit_distribution"]
+    assert p1 + p2 == pytest.approx(1.0, abs=1e-5) and 0.4 < p1 < 0.6
+    assert 0.6 < entropy < math.log(2) + 1e-5
+    # the check fails unless the model/loop event was recorded
+    no_loop = [rows[-1] | {"summary": summary | {"ouro": ouro | {
+        "loop": []}}}]
+    bad = chip_smoke.check_training(rows[:-1] + no_loop, cfg, steps)
+    assert len(bad) == 1 and "model/loop" in bad[0]
 
 
 def test_step_load_line_finds_the_steps_own_event_or_fails():

@@ -1000,17 +1000,19 @@ def test_a_new_reader_names_the_new_cell_alone_and_imports_no_program(name):
 
 def test_the_benchmark_gains_one_configuration_and_one_one_chip_cell():
     b = _benchmark()
-    assert b["configs"][-1]["name"] == CONFIG
-    assert b["workloads"][-1] == {
-        **b["workloads"][-1], "name": CELL, "config": CONFIG,
+    # (PR 64's configuration and cell came after this one's)
+    assert b["configs"][8]["name"] == CONFIG
+    assert b["workloads"][9] == {
+        **b["workloads"][9], "name": CELL, "config": CONFIG,
         "traffic": "dataset", "chips": 1}
-    assert (len(b["configs"]), len(b["workloads"])) == (9, 10)
+    assert len(b["configs"]) >= 9 and len(b["workloads"]) >= 10
     assert sum(w["chips"] == 4 for w in b["workloads"]) == 1
-    assert [m["name"] for m in b["per_layer"]][-len(NEW_READERS):] == list(
-        NEW_READERS)
+    readers = [m["name"] for m in b["per_layer"]]
+    first = readers.index(NEW_READERS[0])
+    assert readers[first:first + len(NEW_READERS)] == list(NEW_READERS)
     for name in SHARED_READERS:
         entry = next(m for m in b["per_layer"] if m["name"] == name)
-        assert entry["workloads"][-1] == CELL
+        assert CELL in entry["workloads"]
     # the rate and the set-up time, not the p90; the readers written for
     # another family's arithmetic are not this cell's
     p90 = next(m for m in b["end_to_end"] if m["name"] == "step_ms_p90")

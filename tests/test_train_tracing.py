@@ -48,6 +48,9 @@ LOWERINGS = {
     # a pattern of Gated DeltaNet and gated attention layers over experts
     # beside a gated shared one: the delta rule's kernel pair (PR 61)
     "qwen3": dict(remat=True, attention_impl="pallas"),
+    # the llama-family block run several times on one set of weights, with
+    # sandwich norms and an exit gate after every pass (Ouro, PR 64)
+    "ouro": dict(remat=True, attention_impl="pallas"),
 }
 # each model's own mixer: GPT-2 has the flash kernels, EvaByte the EVA ones
 EVA_SCOPES = (names.EVA_ATTENTION, names.EVA_PREP_KV)
@@ -79,6 +82,9 @@ DELTA_KERNELS += (names.DELTA_CONV_NORM_FWD_KERNEL,
                   names.DELTA_CONV_NORM_BWD_KERNEL,
                   names.DELTA_GATE_NORM_FWD_KERNEL,
                   names.DELTA_GATE_NORM_BWD_KERNEL)
+# the sandwich norms and the exit gate's objective (Ouro, PR 64)
+OURO_OWN_SCOPES = (names.LN1_POST, names.LN2_POST, names.EXIT_GATE)
+DSV2_OWN_SCOPES += OURO_OWN_SCOPES      # (no other family's step has them)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 MHC_KERNELS = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL,
                names.MHC_WRITE_FWD_KERNEL, names.MHC_WRITE_BWD_KERNEL)
@@ -144,6 +150,9 @@ def _step(key):
     elif key == "qwen3":
         cfg = qwen3_next.qwen3_next_tiny(**LOWERINGS[key])
         bundle = make_train_step(qwen3_next, cfg)
+    elif key == "ouro":
+        cfg = llama.ouro_tiny(**LOWERINGS[key])
+        bundle = make_train_step(llama, cfg)
     else:
         cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
         bundle = make_gpt2_train_step(cfg)
@@ -267,6 +276,25 @@ def test_scope_in_lowered_qwen3_next_step(scope):
               names.MOE: names.BLOCK}
     if scope in inside:
         assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+
+
+@pytest.mark.parametrize("scope", OURO_OWN_SCOPES + (
+    names.EMBED, names.BLOCK, names.LN1, names.QKV, names.ATTN, names.PROJ,
+    names.LN2, names.MLP, names.LN_F, names.LM_HEAD_LOSS, names.OPTIMIZER,
+    names.FLASH_ATTENTION))
+def test_scope_in_lowered_ouro_step(scope):
+    """The looped step carries the llama block's scopes, each output norm
+    under a scope of its own (the attention's inside `proj`, the MLP's beside
+    `mlp` in the block), the loop-end norm as `ln_f` and the gate's objective
+    under `exit_gate` (tests/test_ouro.py holds the two loops themselves)."""
+    op_names, _ = _lowering("ouro")
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+    inside = {names.LN1_POST: f"{names.BLOCK}/{names.PROJ}",
+              names.LN2_POST: names.BLOCK, names.LN1: names.BLOCK}
+    if scope in inside:
+        assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+    if scope == names.EXIT_GATE:     # the objective's, not a block's
+        assert not any(names.BLOCK in n and scope in n for n in op_names)
 
 
 @pytest.mark.parametrize("residual", LFM2_RESIDUALS)
@@ -721,6 +749,28 @@ def test_iterator_spans_on_the_profiler_clock(tmp_path, monkeypatch, buffer):
      dict(direction="fwd", scopes=["block", "attn", "flash_attention"],
           kernel="flash_attention_fwd", stack=False)),
     ("", dict(direction="other", scopes=[], remat=False, stack=False)),
+    # a looped stack (PR 64): the block inside the passes' loop AND the
+    # layers', an output norm under its own scope inside `proj`; the loop-end
+    # norm in the outer body alone; the gate's objective outside both; the
+    # INNER loop's slices and stacks are layer-stack traffic as ever, and so
+    # are the outer loop's own (its last three elements read the same)
+    ("jit(step)/jvp()/while/body/while/body/closed_call/checkpoint/block/"
+     "proj/ln1_post/mul:",
+     dict(direction="fwd", scopes=["block", "proj", "ln1_post"], remat=False,
+          stack=False)),
+    ("jit(step)/transpose(jvp())/while/body/while/body/closed_call/"
+     "checkpoint/rematted_computation/block/ln2_post/rsqrt:",
+     dict(direction="bwd", scopes=["block", "ln2_post"], remat=True,
+          stack=False)),
+    ("jit(step)/jvp()/while/body/ln_f/mul:",
+     dict(direction="fwd", scopes=["ln_f"], remat=False, stack=False)),
+    ("jit(step)/transpose(jvp(exit_gate))/log_sigmoid/logistic:",
+     dict(direction="bwd", scopes=["exit_gate"], remat=False, stack=False)),
+    ("jit(step)/transpose(jvp())/while/body/while/body/"
+     "dynamic_update_slice:",
+     dict(direction="bwd", scopes=[], remat=False, stack=True)),
+    ("jit(step)/transpose(jvp())/while/body/add_any:",
+     dict(direction="bwd", scopes=[], remat=False, stack=False)),
     # the compiler's grouped kernel: no op_name of the program's, its own name
     ("ragged-dot-none:", dict(direction="other", scopes=[],
                               kernel="ragged-dot", stack=False),
@@ -983,6 +1033,36 @@ class _Made:
 
     def __array__(self, dtype=None, copy=None):
         return self.rows
+
+
+def test_a_looped_steps_exit_distribution_is_the_event_of_its_own_kind(buffer):
+    """The Ouro step's counters are not expert loads: ONE row of float32
+    bits, decoded by the step's callable into a `train/step_counters` event
+    of kind `exit_distribution` — a list a field, the passes beside them."""
+    from ray_tpu.tracing import step_counters
+
+    bundle, batch = _step("ouro")
+    step_counters.drain(wait=True)
+    buffer.drain(10 ** 6)
+    _, metrics = bundle.step_fn(bundle.state, batch)
+    assert step_counters.drain(wait=True) == 1
+    (args,) = [e["args"] for e in _drain(buffer, "train")
+               if e["name"] == STEP_COUNTERS]
+    passes = bundle.cfg.ut_steps
+    fields = [f"{names.STEP_EXIT_PASS}{t + 1}" for t in range(passes)]
+    assert list(args) == list(names.TRAIN_STEP_COUNTERS_ARGS) + fields + [
+        names.STEP_EXIT_ENTROPY, *names.EXIT_DISTRIBUTION_STATIC_ARGS]
+    assert args["kind"] == names.EXIT_DISTRIBUTION_KIND
+    assert (args["layers"], args["passes"]) == ([bundle.cfg.n_layer - 1],
+                                                passes)
+    said = np.asarray(metrics["counters"]).view(np.float32)[0]
+    assert [args[f][0] for f in fields + [names.STEP_EXIT_ENTROPY]] == (
+        said.tolist())
+    assert sum(args[f][0] for f in fields) == pytest.approx(1.0, abs=1e-5)
+    # and the reader of the expert cells' loads takes it for none of its own
+    from benchmarks.harness import step_counters as reader
+
+    assert reader.KIND != args["kind"]
 
 
 def test_drain_records_what_is_ready_in_order_and_waits_for_nothing(buffer):
