@@ -1145,7 +1145,9 @@ def main() -> int:
               f"{d['rows']} positions x {d['columns']} columns "
               f"({d['heads']} heads), gradient in the forward: "
               f"{d['grad_in_forward']}, kept for the backward "
-              f"{d['residual_bytes'] / 2 ** 20:.1f} MiB")
+              f"{d['residual_bytes'] / 2 ** 20:.1f} MiB, the float32 "
+              f"d lm_head carry moved {d['carry_bytes_a_step'] / 2 ** 20:.1f}"
+              " MiB a step")
     print(f"eva step ({eva_cfg.n_layer} layers of {eva_cfg.d_model}, "
           f"{summary['device_count']}x{eva['seq_len']} bytes, remat): "
           f"attention {eva['attention']}, loss {eva['loss']:.4f} "

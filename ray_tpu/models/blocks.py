@@ -109,7 +109,12 @@ def _passes(s: Shard) -> int:
 
 def _head_terms(s: Shard) -> int:
     a = s.dtype_bytes
-    # (a looped model's ONE head call takes every pass's rows: its batch)
+    # (a looped model's ONE head call takes every pass's rows: its batch).
+    # A chunked head's logits are dead once its forward scan ends — no
+    # compiled peak of six cells moved when their chunks grew two- to
+    # eightfold (PERF.md §6, PR 65) — so in the last run's phase these
+    # bytes stand ABOVE what the compiler holds; no kept list turns on
+    # them yet
     head = (_passes(s) * s.batch * (s.head_rows or s.seq) * s.vocab
             * (2 * a + 4))
     if s.head_rows:

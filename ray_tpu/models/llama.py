@@ -404,8 +404,7 @@ def _head_rows(cfg: LlamaConfig, batch: int, seq: int) -> int:
     columns = cfg.n_pred_heads * cfg.head_vocab
     if not cfg.exit_gate:
         return parts.head_rows(batch, seq, columns, cfg.n_pred_heads)
-    return parts.rows_under(seq, cfg.ut_steps * batch * columns * 4,
-                            parts.HEAD_CHUNK_BYTES)
+    return parts.head_chunk_rows(cfg.ut_steps * batch, seq, columns)
 
 
 def _trunk(params, tokens, cfg: LlamaConfig, every_pass: bool = False):

@@ -28,9 +28,20 @@ from ray_tpu.ops import moe
 from ray_tpu.tracing import names as scopes
 
 
-# the head's float32 logits of one sequence chunk stay under this
-# (head_rows): [B, S, V] whole is 4.2 GB at llama_7b's 8 x 4,096 x 32,000
+# one head whose float32 logits of the whole sequence stay under this takes
+# them whole (head_rows): [B, S, V] is 4.2 GB at llama_7b's 8 x 4,096 x 32,000.
+# Past it the head goes in chunks whose logits stay under it wherever that
+# leaves a chunk tokens enough (head_chunk_rows): 64 MiB is the largest
+# buffer the TPU compiler gives the chip's fast memory — S(1) on the chunk's
+# logits in the compiled step — and every pass over a chunk's logits, the two
+# gradient products' operands among them, then reads them from there
 HEAD_CHUNK_BYTES = 2 ** 26
+# the least tokens a chunk of the chunked head holds, whatever its logits then
+# take: the chunk's d lm_head product is 2 · tokens · D · V operations over a
+# carry of 8 · D · V bytes, tokens / 4 operations a byte whatever D and V, and
+# a v5e does 240 in the time it moves one (197 TFLOP/s, 819 GB/s): under ~960
+# tokens the product waits for the carry (PERF.md §6, PR 65)
+HEAD_CHUNK_TOKENS = 1024
 # an MLP whose hidden tensor of the whole sequence passes this takes the
 # sequence in chunks (mlp_rows): a SwiGLU's backward holds five of them —
 # 3.6 GB at 32,768 x 11,008, which one chip does not have beside EvaByte's
@@ -351,12 +362,26 @@ def in_row_chunks(fn, x, rows: int):
     return out.swapaxes(0, 1).reshape(B, S, D)
 
 
+def head_chunk_rows(batch: int, seq: int, columns: int) -> int:
+    """Rows of the sequence a chunk of the chunked head holds
+    (ops/cross_entropy.chunked_head_xent): as many as keep its float32
+    logits [batch, rows, columns] under HEAD_CHUNK_BYTES, but never fewer
+    than HEAD_CHUNK_TOKENS tokens a chunk — the float32 ``d lm_head``, the
+    chunk scan's carry, is read and written whole by EVERY chunk, and a
+    shorter chunk's ``d lm_head`` product waits for it."""
+    return rows_under(seq, batch * columns * 4,
+                      max(HEAD_CHUNK_BYTES, HEAD_CHUNK_TOKENS * columns * 4))
+
+
 def head_rows(batch: int, seq: int, columns: int, heads: int) -> int:
     """Rows of the sequence the head takes at a time where it goes in chunks
-    — their float32 logits stay under HEAD_CHUNK_BYTES; more heads than one
-    always do — and 0 where one head takes the sequence whole (softmax_xent)."""
-    rows = rows_under(seq, batch * columns * 4, HEAD_CHUNK_BYTES)
-    return rows if heads > 1 or rows < seq else 0
+    (head_chunk_rows) — more heads than one always do — and 0 where one head
+    takes the sequence whole (softmax_xent): where its float32 logits stay
+    under HEAD_CHUNK_BYTES."""
+    if heads == 1 and rows_under(seq, batch * columns * 4,
+                                 HEAD_CHUNK_BYTES) == seq:
+        return 0
+    return head_chunk_rows(batch, seq, columns)
 
 
 def head_targets(targets: jax.Array, n_heads: int) -> jax.Array:
@@ -385,8 +410,7 @@ def lm_head_loss(x, targets, lm_head, dtype, n_pred_heads: int = 1,
     if weights is not None:
         return cross_entropy.chunked_head_xent(
             x, head_targets(targets, 1), lm_head,
-            rows_under(S, B * lm_head.shape[1] * 4, HEAD_CHUNK_BYTES),
-            weights[..., None])
+            head_chunk_rows(B, S, lm_head.shape[1]), weights[..., None])
     rows = head_rows(B, S, lm_head.shape[1], n_pred_heads)
     if not rows:
         # fused CE (ops/cross_entropy.py): no [B, S, V] float32 residual

@@ -28,6 +28,18 @@ are multiplied out once a step, and nothing of their size is kept or made
 again (a ``checkpoint`` a chunk made them twice: PERF.md §6, PR 39). Called
 without differentiation the op does no gradient work.
 
+What a chunk costs beside its three products is the running ``d lm_head``:
+the scan's carry, float32 [D, heads · columns], which EVERY chunk reads and
+writes whole — chunks x 2 x D x columns x 4 bytes a step
+(``model/head_loss``'s ``carry_bytes_a_step``). The chunk's ``d lm_head``
+product does tokens / 4 operations a byte of it, so a chunk of few tokens
+waits for the carry, and one of ~1,000 or more hides it under the product
+(on a v5e). Against that stands where a chunk's logits live: up to 64 MiB
+the TPU compiler keeps them in the chip's fast memory, past it every pass
+over them goes to HBM. The caller weighs the two (parts.head_chunk_rows);
+the op itself takes any ``rows`` that divide the sequence: the sums are the
+same sums, only the addends a partial one holds change.
+
 Numerics of both are the reference formulation's (f32 max-subtracted softmax;
 tests assert equality vs jax.nn.log_softmax). Ignore index: any target < 0
 contributes 0 loss and 0 gradient.
@@ -104,17 +116,22 @@ def head_loss_decisions() -> List[Dict[str, Any]]:
 
 def _record(x, targets, lm_head, rows: int, grad_in_forward: bool) -> None:
     """What a chunked head was traced as (``model/head_loss``): the chunks,
-    whether this trace makes the gradient beside the loss, and what it then
+    whether this trace makes the gradient beside the loss, what it then
     keeps for the backward — ``d x`` where the chunked ``x`` stood, and the
-    running ``d lm_head`` in float32."""
+    running ``d lm_head`` in float32 — and the bytes of that running sum the
+    step moves: every chunk reads and writes it whole."""
     from ray_tpu.ops.attention import record_decision
 
     B, S = x.shape[:2]
-    kept = (x.size * x.dtype.itemsize + lm_head.size * 4) if grad_in_forward else 0
+    chunks = S // rows
+    kept = carry = 0
+    if grad_in_forward:
+        carry = lm_head.size * 4
+        kept = x.size * x.dtype.itemsize + carry
     record_decision(_decisions, names.HEAD_LOSS, dict(zip(
         names.HEAD_LOSS_ARGS,
-        (B, rows, S // rows, lm_head.shape[1], targets.shape[-1],
-         grad_in_forward, kept))))
+        (B, rows, chunks, lm_head.shape[1], targets.shape[-1],
+         grad_in_forward, kept, chunks * 2 * carry))))
 
 
 def _chunks(a, rows: int):
@@ -144,7 +161,9 @@ def chunked_head_xent(x: jax.Array, targets: jax.Array, lm_head: jax.Array,
     [B, S, heads] int32 (< 0 = ignore) → the mean over the heads of each
     head's mean negative log-likelihood over its valid targets, float32.
     ``rows`` divides S; a chunk's logits [B, rows, heads · columns] are
-    float32 and the largest tensor there is.
+    float32 and the largest tensor there is — beside the float32 ``d
+    lm_head`` that every chunk of the differentiated op reads and writes
+    (the module's docstring: why a chunk should not be too short).
 
     ``weights`` [B, S, heads] float32 (None: every target weighs 1, and the
     op lowers as it did before it took any): Σ weight · nll over a head's

@@ -366,11 +366,15 @@ REMAT_POLICY_LOOP_ARGS = ("passes", "applications")
 # chunked_head_xent): the batch rows and the positions a chunk holds, the
 # chunks, the head's columns (all heads') and heads, whether this trace makes
 # each chunk's gradient beside its loss (under differentiation) or only the
-# loss, and the bytes it then keeps for the backward (d x, the float32
-# d lm_head); one instant event per distinct decision, at trace time
+# loss, the bytes it then keeps for the backward (d x, the float32
+# d lm_head), and the bytes of that float32 d lm_head the step moves — the
+# chunk scan's carry, read and written whole by every chunk: chunks x 2 x
+# d_model x columns x 4, what parts.head_chunk_rows gives a chunk tokens
+# enough to hide (0 where the trace makes no gradient); one instant event per
+# distinct decision, at trace time
 HEAD_LOSS = "model/head_loss"
 HEAD_LOSS_ARGS = ("batch", "rows", "chunks", "columns", "heads",
-                  "grad_in_forward", "residual_bytes")
+                  "grad_in_forward", "residual_bytes", "carry_bytes_a_step")
 # a model whose layers are of more than one kind (blocks.run_pattern): the
 # pattern, how often each kind is applied and which runs of it are one scan;
 # one instant event per distinct pattern, at trace time

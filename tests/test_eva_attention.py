@@ -240,6 +240,7 @@ def test_loss_chunks_the_head_and_the_mlp_to_the_same_numbers(monkeypatch):
     rows = cfg.seq_len // 4
     monkeypatch.setattr(parts, "HEAD_CHUNK_BYTES",
                         2 * rows * cfg.n_pred_heads * cfg.head_vocab * 4)
+    monkeypatch.setattr(parts, "HEAD_CHUNK_TOKENS", 2 * rows)
     monkeypatch.setattr(parts, "MLP_CHUNK_BYTES", 2 * rows * cfg.d_ff * 4)
     shard = llama.block_shard(cfg, 2, cfg.seq_len, None)
     # an MLP past its limit goes in chunks whose five hidden tensors take

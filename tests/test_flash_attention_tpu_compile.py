@@ -535,7 +535,8 @@ def test_the_evabyte_cell_step_holds_nothing_the_compiler_rematerialized(
     (h,) = cross_entropy.head_loss_decisions()
     assert h == dict(batch=1, rows=4096, chunks=8, columns=8 * 320, heads=8,
                      grad_in_forward=True,
-                     residual_bytes=32768 * 4096 * 2 + 4096 * 2560 * 4)
+                     residual_bytes=32768 * 4096 * 2 + 4096 * 2560 * 4,
+                     carry_bytes_a_step=8 * 2 * 4096 * 2560 * 4)
     assert d["saved_bytes"] <= d["budget_bytes"] == 1_347_631_092 - 4096 * 2560 * 4
     assert not [line for line in hlo.splitlines()
                 if "lm_head_loss" in line and "rematted_computation" in line]
@@ -587,9 +588,16 @@ def test_the_nemotron_cell_step_multiplies_a_chunks_logits_once(nemotron_step):
     # the d lm_head of a call is the loop's carry, in float32
     assert len(re.findall(r"= f32\[4096,16384,1\]\S* convolution\(", hlo)) == 2
     (h,) = heads
+    # (PR 65: a chunk of 8 x 128 = 1,024 tokens hides the carry and its
+    # 64 MiB of logits live in the chip's fast memory: it stays)
     assert h == dict(batch=8, rows=128, chunks=32, columns=16384, heads=1,
                      grad_in_forward=True,
-                     residual_bytes=32768 * 4096 * 2 + 4096 * 16384 * 4)
+                     residual_bytes=32768 * 4096 * 2 + 4096 * 16384 * 4,
+                     carry_bytes_a_step=32 * 2 * 4096 * 16384 * 4)
+    # what parts.HEAD_CHUNK_BYTES rests on: the compiler puts a chunk's 64 MiB
+    # of logits in the chip's fast memory (memory space 1), in both calls
+    assert len(re.findall(r"= f32\[8,128,16384\]\{[^}]*S\(1\)\} convolution\(",
+                          hlo)) == 2
 
 
 def test_the_nemotron_cell_step_keeps_the_routing_and_clones_nothing(

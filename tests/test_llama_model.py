@@ -5,6 +5,8 @@ repetition, RMSNorm, and SwiGLU must reproduce transformers'
 LlamaForCausalLM logits on identical weights.
 """
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -200,10 +202,16 @@ def test_chunked_head_multiplies_a_chunks_logits_once(monkeypatch):
     from ray_tpu.tracing import names
 
     monkeypatch.setattr(cross_entropy, "_decisions", {})
+    # the most a chunk's logits take — while that leaves it tokens enough
     monkeypatch.setattr(parts, "HEAD_CHUNK_BYTES", _B * _ROWS * _V * 4)
+    monkeypatch.setattr(parts, "HEAD_CHUNK_TOKENS", _B * _ROWS)
     cfg = llama.llama_tiny(dtype=jnp.float32, vocab_size=_V)
     x, targets, lm_head = _head_case(1, ())
     assert parts.head_rows(_B, _S, _V, 1) == _ROWS
+    # a wider head's chunk keeps its tokens and takes more bytes; a narrower
+    # one's grows to the bytes
+    assert parts.head_rows(_B, _S, 4 * _V, 1) == _ROWS
+    assert parts.head_rows(_B, _S, _V // 2, 2) == 2 * _ROWS
 
     def head(x, w):
         return parts.lm_head_loss(x, targets[..., 0], w, cfg.dtype,
@@ -219,15 +227,149 @@ def test_chunked_head_multiplies_a_chunks_logits_once(monkeypatch):
     assert set(by_grad) == {False, True}
     assert tuple(by_grad[True]) == names.HEAD_LOSS_ARGS
     assert by_grad[False]["residual_bytes"] == 0
+    assert by_grad[False]["carry_bytes_a_step"] == 0
+    # every chunk reads and writes the float32 d lm_head whole
     assert by_grad[True] == dict(
         batch=_B, rows=_ROWS, chunks=_S // _ROWS, columns=_V, heads=1,
         grad_in_forward=True,
-        residual_bytes=x.size * 4 + lm_head.size * 4)
-    # one head whose whole-sequence logits fit takes them whole
+        residual_bytes=x.size * 4 + lm_head.size * 4,
+        carry_bytes_a_step=(_S // _ROWS) * 2 * _D * _V * 4)
+    # one head whose whole-sequence logits fit takes them whole, however
+    # small the carry; more heads than one still go in chunks (of the
+    # whole sequence, here)
     monkeypatch.setattr(parts, "HEAD_CHUNK_BYTES", _B * _S * _V * 4)
     assert parts.head_rows(_B, _S, _V, 1) == 0
+    assert parts.head_rows(_B, _S, _V // 2, 2) == _S
     assert "scan[" not in str(jax.make_jaxpr(      # (a new function: no
         lambda x, w: head(x, w))(x, lm_head))      # trace of `head` is reused)
+
+
+# the cells that take parts.lm_head_loss, as benchmarks/configs and
+# benchmarks/cells state them: rows of the batch (a looped model's head call
+# takes every pass's), sequence, the heads' columns together, heads, passes;
+# then the tokens a chunk holds and the chunks under the flat 64 MiB alone
+# (PR 64) and with the least tokens a chunk (PR 65)
+_CELL_HEADS = {
+    "ouro-2.6b-l8": (1, 8192, 49152, 1, 4, (256, 128), (1024, 32)),
+    "qwen3-next-80b-a3b-l4": (4, 8192, 18992, 1, 1, (512, 64), (1024, 32)),
+    "nemotron-3-super-120b-l11": (8, 4096, 16384, 1, 1, (1024, 32), (1024, 32)),
+    "xing4.0-29b-a4b-l5": (1, 8192, 16384, 1, 1, (1024, 8), (1024, 8)),
+    "deepseek-v2-lite-l5": (4, 8192, 12800, 1, 1, (1024, 32), (1024, 32)),
+    "minicpm-sala-9b-l4": (1, 16384, 9216, 1, 1, (1024, 16), (1024, 16)),
+    "lfm2-24b-a2b-l5": (8, 4096, 8192, 1, 1, (2048, 16), (2048, 16)),
+    "evabyte-6.5b-l4": (1, 32768, 8 * 320, 8, 1, (4096, 8), (4096, 8)),
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_HEADS))
+def test_a_chunk_of_the_head_holds_tokens_enough_to_hide_its_carry(
+        cell, monkeypatch):
+    """PR 65: a chunk's float32 logits stay under HEAD_CHUNK_BYTES (the
+    chip's fast memory holds them) unless that leaves it fewer than
+    HEAD_CHUNK_TOKENS tokens (its d lm_head product then waits for the
+    float32 carry every chunk reads and writes): one function of the
+    shapes, the same answer from parts.head_rows, from lm_head_loss's
+    weighted branch and from llama's block shard (whose looped model's batch
+    is the passes' together)."""
+    from ray_tpu.ops import cross_entropy
+
+    B, S, V, heads, passes, before, after = _CELL_HEADS[cell]
+    flat = parts.rows_under(S, passes * B * V * 4, parts.HEAD_CHUNK_BYTES)
+    assert (passes * B * flat, S // flat) == before
+    rows = parts.head_chunk_rows(passes * B, S, V)
+    assert (passes * B * rows, S // rows) == after
+    assert rows >= flat and passes * B * rows >= parts.HEAD_CHUNK_TOKENS
+    # a cell whose 64 MiB held tokens enough keeps its chunk
+    if before[0] >= parts.HEAD_CHUNK_TOKENS:
+        assert after == before
+    # no cell takes its sequence whole, before or after
+    assert flat < S or heads > 1
+    if passes == 1:
+        assert parts.head_rows(B, S, V, heads) == rows
+    # what the step itself traces: lm_head_loss hands the op these rows
+    seen = []
+    monkeypatch.setattr(cross_entropy, "chunked_head_xent",
+                        lambda x, t, w, rows, *a: seen.append(rows))
+    D = 256
+    x = jax.ShapeDtypeStruct((passes * B, S, D), jnp.bfloat16)
+    targets = jax.ShapeDtypeStruct((passes * B, S), jnp.int32)
+    head = jax.ShapeDtypeStruct((D, V), jnp.float32)
+    if passes > 1:
+        weights = jax.ShapeDtypeStruct((passes * B, S), jnp.float32)
+        jax.eval_shape(lambda x, t, w, p: parts.lm_head_loss(
+            x, t, w, jnp.bfloat16, weights=p), x, targets, head, weights)
+    else:
+        jax.eval_shape(lambda x, t, w: parts.lm_head_loss(
+            x, t, w, jnp.bfloat16, heads), x, targets, head)
+    assert seen == [rows]
+    # and what the remat rule prices: the llama family's shard
+    if cell.startswith(("ouro", "evabyte")):
+        cfg = llama.LlamaConfig(
+            vocab_size=V // heads, seq_len=S, n_layer=2, n_head=2,
+            n_kv_head=2, d_model=D, d_ff=2 * D, n_pred_heads=heads,
+            ut_steps=passes, exit_gate=passes > 1)
+        assert llama._head_rows(cfg, B, S) == rows
+
+
+@pytest.mark.parametrize("heads", [1, 8])
+def test_the_rule_moves_no_threshold_and_shortens_no_chunk(heads):
+    """Whether ONE head takes its sequence whole is decided against
+    HEAD_CHUNK_BYTES as it was; where the head goes in chunks, a chunk is
+    never shorter than the flat limit gave, at any shape, and longer only
+    where that held fewer than HEAD_CHUNK_TOKENS tokens."""
+    least = parts.HEAD_CHUNK_TOKENS
+    for B, S, V in itertools.product(
+            (1, 4, 8, 32), (1024, 4096, 32768, 3 * 1024),
+            (320, 8192, 16384, 50304, 151936)):
+        flat = parts.rows_under(S, B * V * 4, parts.HEAD_CHUNK_BYTES)
+        rows = parts.head_rows(B, S, V, heads)
+        if heads == 1 and flat == S:
+            assert rows == 0, (B, S, V)
+            continue
+        assert rows >= flat and S % rows == 0, (B, S, V)
+        assert rows == parts.head_chunk_rows(B, S, V)
+        if B * flat >= least or flat == S:
+            assert rows == flat, (B, S, V)
+        else:
+            # the largest power-of-two fraction with no more tokens than
+            # the least (the whole sequence where even that has fewer)
+            assert B * rows <= least or rows % 2, (B, S, V)
+            assert rows == S or 2 * B * rows > least, (B, S, V)
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unweighted", "weighted"])
+@pytest.mark.parametrize("longer", [4, 16])
+def test_a_longer_chunk_is_the_same_sums(longer, weighted):
+    """PR 65 changes how many addends a chunk's partial sums hold and
+    nothing else: the loss, d x, d lm_head and the weights' cotangent of a
+    chunk 4 and 16 times as long are the short chunk's to float32
+    rounding."""
+    from ray_tpu.ops.cross_entropy import chunked_head_xent
+
+    short = _S // 16
+    x, targets, lm_head = _head_case(1, ((0, slice(40, None)),))
+    weights = None
+    if weighted:
+        weights = jax.random.uniform(jax.random.PRNGKey(5), targets.shape,
+                                     jnp.float32)
+
+    def run(rows):
+        if not weighted:
+            return jax.value_and_grad(
+                lambda x, w: chunked_head_xent(x, targets, w, rows),
+                argnums=(0, 1))(x, lm_head)
+        return jax.value_and_grad(
+            lambda x, w, p: chunked_head_xent(x, targets, w, rows, p),
+            argnums=(0, 1, 2))(x, lm_head, weights)
+
+    want, want_g = run(short)
+    got, got_g = run(longer * short)
+    assert len(got_g) == (3 if weighted else 2)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for g, w in zip(got_g, want_g):
+        assert g.dtype == w.dtype == jnp.float32
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
 
 
 @pytest.mark.parametrize("n_kv_head", [4, 2], ids=["mha", "gqa"])
