@@ -45,7 +45,11 @@ kernel and of the four kernels of the mixer's elementwise work around them
 (``models/llama.py`` with ``ut_steps`` 2 over two layers, sandwich norms and
 the exit gate, PR 64) for its ``model/loop`` event (or it fails), the remat
 rule's passes and applications and the mean exit distribution the step says
-of itself, and —
+of itself, one step of a small Trinity-class model (``models/afmoe.py``:
+window and full gated attention layers in one pattern, experts beside a
+shared one, PR 66) for its ``DWFWW`` pattern and the ``ops/flash_tiling``
+decisions of its windowed calls beside its full ones — ``window`` and how
+many of the triangle's tile pairs each visits (or it fails) —, and —
 what the expert layer's chosen-set mask
 rests on — that this backend's ``lax.top_k`` lists equal elements in index
 order (``chosen_rows_off``). It then checks what came back (see
@@ -582,6 +586,42 @@ def train_loop(config: Dict[str, Any]) -> None:
                 "exit_distribution": np.asarray(m["counters"]).view(
                     np.float32)[0].tolist()}
         del variant
+    # One step of a pattern whose kinds differ in attention: window layers
+    # under RoPE whose flash calls walk the band alone, a full layer with no
+    # position, gated experts beside a shared one (models/afmoe.py, PR 66).
+    afmoe_step = None
+    if config.get("afmoe_model") is not None:
+        from ray_tpu.models import afmoe
+        from ray_tpu.models.blocks import layer_pattern_decisions
+
+        afmoe_cfg = config["afmoe_model"]
+        variant = make_train_step(
+            afmoe, afmoe_cfg, mesh=mesh,
+            rng=jax.random.PRNGKey(config["seed"]),
+            optimizer=default_optimizer(lr=LR, warmup=WARMUP,
+                                        total_steps=steps,
+                                        decay_mask=afmoe.decays))
+        tokens = np.random.default_rng(config["seed"]).integers(
+            0, ALPHABET, size=(n_dev, afmoe_cfg.seq_len), dtype=np.int32)
+        afmoe_batch = jax.device_put(
+            with_targets({"tokens": tokens}), data_sharding)
+        with mesh_lib.use_mesh(mesh):
+            params, load = afmoe.balance_router_bias(
+                variant.state["params"], [afmoe_batch["tokens"]], afmoe_cfg)
+        _, m = variant.step_fn({**variant.state, "params": params},
+                               afmoe_batch)
+        afmoe_step = {
+            "loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
+            "seq_len": afmoe_cfg.seq_len, "expert_load": load,
+            "layer_pattern": [d for d in layer_pattern_decisions()
+                              if d["pattern"] == afmoe_cfg.pattern],
+            "remat_policy": [d for d in remat_policy_decisions()
+                             if (d["n_layer"], d["seq"]) == (
+                                 afmoe_cfg.n_layer, afmoe_cfg.seq_len)],
+            "flash_tiling": [d for d in flash_tiling_decisions()
+                             if (d["Sq"], d["hd"]) == (
+                                 afmoe_cfg.seq_len, afmoe_cfg.head_dim)]}
+        del variant
     jax.monitoring.unregister_event_listener(on_event)
 
     tpu_calls, attn_shapes = attention_call_shapes(hlo, cfg.head_dim)
@@ -614,6 +654,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         "xing4": xing4,
         "qwen3": qwen3,
         "ouro": ouro,
+        "afmoe": afmoe_step,
     }})
 
 
@@ -622,7 +663,8 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
         hybrid_model=None, sala_model=None,
         lfm2_model=None, dsv2_model=None,
         xing4_model=None, qwen3_model=None, ouro_model=None,
-        grouped_shapes=GROUPED_SHAPES) -> List[Dict[str, Any]]:
+        afmoe_model=None, grouped_shapes=GROUPED_SHAPES
+        ) -> List[Dict[str, Any]]:
     """Driver side: a small token dataset through Data, then
     JaxTrainer(train_loop) with one worker driving `num_devices` devices.
     Returns the reported rows (steps, then the summary); raises the worker's
@@ -647,6 +689,7 @@ def run(model_cfg, *, steps: int, per_chip_batch: int, num_devices: int,
             "sala_model": sala_model, "lfm2_model": lfm2_model,
             "dsv2_model": dsv2_model, "xing4_model": xing4_model,
             "qwen3_model": qwen3_model, "ouro_model": ouro_model,
+            "afmoe_model": afmoe_model,
             "grouped_shapes": grouped_shapes,
         },
         scaling_config=train.ScalingConfig(
@@ -842,6 +885,30 @@ def check_training(rows: List[Dict[str, Any]], model_cfg, steps: int) -> List[st
                 and 0.0 < entropy < math.log(len(p)) + 1e-3):
             bad.append("the Ouro step said a mean exit distribution "
                        f"{p} of entropy {entropy}: not one over its passes")
+    afmoe_step = summary.get("afmoe")
+    if afmoe_step is not None:
+        if not (math.isfinite(afmoe_step["loss"])
+                and math.isfinite(afmoe_step["grad_norm"])):
+            bad.append(f"the AFMoE step's loss {afmoe_step['loss']} or "
+                       f"grad_norm {afmoe_step['grad_norm']} is not finite")
+        if not afmoe_step["layer_pattern"]:
+            bad.append("the AFMoE step recorded no model/layer_pattern event")
+        calls = {(d["kernel"], bool(d["window"]))
+                 for d in afmoe_step["flash_tiling"]}
+        if calls != {(k, w) for k in ("fwd", "bwd") for w in (False, True)}:
+            bad.append("the AFMoE step recorded ops/flash_tiling decisions "
+                       f"{sorted(calls)}: not a windowed and a full call of "
+                       "each kernel")
+        for d in afmoe_step["flash_tiling"]:
+            skipped = d["tiles_visited"] < d["tiles_causal"]
+            if d["window"] and d["window"] + d["block_k"] < d["Skv"] \
+                    and not skipped:
+                bad.append(f"the AFMoE step's {d['kernel']} call under "
+                           f"window={d['window']} visits every tile pair of "
+                           "the triangle: the band is not walked alone")
+        if any(load["pairs_dropped"] for load in afmoe_step["expert_load"]):
+            bad.append("the AFMoE step's set-up dropped pairs: "
+                       f"{afmoe_step['expert_load']}")
     xing4 = summary.get("xing4")
     if xing4 is not None:
         if not (math.isfinite(xing4["loss"])
@@ -1014,8 +1081,8 @@ def main() -> int:
 
     import ray_tpu
     from ray_tpu.core.resources import tpu_device_files
-    from ray_tpu.models import (deepseek_v2, gpt2, lfm2_moe, llama, qwen3_next,
-                                minicpm_sala, nemotron_h)
+    from ray_tpu.models import (afmoe, deepseek_v2, gpt2, lfm2_moe, llama,
+                                qwen3_next, minicpm_sala, nemotron_h)
     from ray_tpu.ops.sparse_attention import SparseSizes
 
     model_cfg = gpt2.gpt2_124m()
@@ -1075,6 +1142,12 @@ def main() -> int:
     ouro_cfg = llama.ouro_2p6b(
         n_layer=2, ut_steps=2, d_model=1024, n_head=8, n_kv_head=8, d_ff=2816,
         vocab_size=8192, seq_len=2048, remat=True)
+    # Trinity-Mini's layers at half the width: a dense window layer, a window
+    # and a full expert layer and two more window layers, rows of four windows
+    afmoe_cfg = afmoe.trinity_mini(
+        pattern="DWFWW", first_layer=1, d_model=1024, n_head=8, n_kv_head=2,
+        sliding_window=1024, d_ff=2816, n_experts=32, held_count=8,
+        d_expert=512, vocab_size=8192, seq_len=4096, remat=True)
     ray_tpu.init()
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -1089,7 +1162,7 @@ def main() -> int:
                    hybrid_model=hybrid_cfg, sala_model=sala_cfg,
                    lfm2_model=lfm2_cfg, dsv2_model=dsv2_cfg,
                    xing4_model=xing4_cfg, qwen3_model=qwen3_cfg,
-                   ouro_model=ouro_cfg)
+                   ouro_model=ouro_cfg, afmoe_model=afmoe_cfg)
     finally:
         ray_tpu.shutdown()
 
@@ -1322,6 +1395,25 @@ def main() -> int:
           f"grad_norm {ouro['grad_norm']:.4f}; it said of itself a mean exit "
           f"distribution {[round(q, 4) for q in ouro['exit_distribution'][:-1]]}"
           f", entropy {ouro['exit_distribution'][-1]:.4f}")
+    afmoe_step = summary["afmoe"]
+    for d in afmoe_step["layer_pattern"]:
+        print(f"AFMoE layer pattern {d['pattern']}: applications "
+              f"{d['applications']}, runs {d['groups']}")
+    for d in afmoe_step["flash_tiling"]:
+        print(f"AFMoE flash {d['kernel']}: rows={d['rows']} S={d['Sq']} "
+              f"hd={d['hd']} window={d['window'] or 'none'} -> block_q="
+              f"{d['block_q']} block_k={d['block_k']}, visits "
+              f"{d['tiles_visited']} of the triangle's {d['tiles_causal']} "
+              "tile pairs")
+    for d in afmoe_step["remat_policy"]:
+        print(f"AFMoE remat policy: {d['n_layer']} layers batch={d['batch']} "
+              f"seq={d['seq']}: saved={d['saved']} "
+              f"({d['saved_bytes'] / gib:.2f} GiB of {d['budget_bytes'] / gib:.2f}"
+              f" left by the backward's phase {d['phase']!r})")
+    print(f"AFMoE step ({afmoe_cfg.pattern} of {afmoe_cfg.d_model}, window "
+          f"{afmoe_cfg.sliding_window}, {summary['device_count']}x"
+          f"{afmoe_step['seq_len']} tokens, remat): loss "
+          f"{afmoe_step['loss']:.4f} grad_norm {afmoe_step['grad_norm']:.4f}")
     print(f"set-up seconds (not speed): backend {summary['backend_seconds']:.1f}"
           f", step compile {summary['step_compile_seconds']:.1f}, start to "
           f"end of first step {summary['setup_seconds']:.1f}")

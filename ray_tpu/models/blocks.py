@@ -345,6 +345,19 @@ def init_pattern(rng, pattern: str, kinds: str, layer_init: Callable):
     return out
 
 
+def pattern_logical_axes(stack_init: Callable, layer_axes: Dict[str, Any]
+                         ) -> Dict[str, Any]:
+    """The logical axes of a pattern family's parameter tree with an untied
+    head — an embedding, init_pattern's stacks (``stack_init(rng)``, nothing
+    made), a final gain, a head —: each stacked tensor's by its name in
+    ``layer_axes``."""
+    layers = jax.eval_shape(lambda: stack_init(jax.random.PRNGKey(0)))
+    return {"wte": ("vocab", "embed"),
+            "blocks": [{kind: {name: layer_axes[name] for name in stack}
+                        for kind, stack in group.items()} for group in layers],
+            "final_norm": ("embed",), "lm_head": ("embed", "vocab")}
+
+
 def with_grad_bytes(kinds: Dict[str, KindShard], layer_init: Callable,
                     mesh) -> Dict[str, KindShard]:
     """``kinds`` with each kind's ``grad_bytes``: the bytes of one layer of

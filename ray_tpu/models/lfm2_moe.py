@@ -51,7 +51,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import blocks, parts
@@ -269,16 +268,6 @@ def decays(params):
 # Forward
 # --------------------------------------------------------------------------- #
 
-def _head_norm(x, g, eps: float, axis: int):
-    """RMSNorm over ``axis`` (the head's width, wherever the layout has it),
-    float32 statistics, one gain vector for every head."""
-    xf = x.astype(jnp.float32)
-    rms = lax.rsqrt(jnp.mean(xf * xf, axis=axis, keepdims=True) + eps)
-    shape = [1] * x.ndim
-    shape[axis] = x.shape[axis]
-    return (xf * rms).astype(x.dtype) * g.astype(x.dtype).reshape(shape)
-
-
 @jax.named_scope(scopes.SHORT_CONV)
 def conv_operator(u, p):
     """u [B, S, D] (normed) → the operator's output [B, S, D] float32."""
@@ -299,7 +288,7 @@ def attention_operator(u, p, cfg: LFM2MoEConfig):
     positions = jnp.arange(u.shape[1])
 
     def normed_rotated(w, g):
-        x = _head_norm(jnp.einsum(f"bsd,dhk->{heads}", u, w), g, cfg.rms_eps,
+        x = parts.head_rmsnorm(jnp.einsum(f"bsd,dhk->{heads}", u, w), g, cfg.rms_eps,
                        width)
         return parts.rope(x, positions, cfg.rope_theta, s_minor)
 
@@ -526,9 +515,7 @@ def flops_per_token(cfg: LFM2MoEConfig) -> float:
 # --------------------------------------------------------------------------- #
 
 def _expert_layer_ids(cfg: LFM2MoEConfig) -> Tuple[int, ...]:
-    """The published index of every expert layer, in the order they come."""
-    return tuple(cfg.first_layer + i for i, kind in enumerate(cfg.pattern)
-                 if EXPERTS[kind])
+    return parts.expert_layer_ids(cfg.pattern, cfg.first_layer, EXPERTS)
 
 
 def chosen_experts(params, tokens, cfg: LFM2MoEConfig) -> List[jax.Array]:

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,16 @@ def rmsnorm(x, g, eps: float, unit_offset: bool = False):
     rms = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     scale = 1.0 + g.astype(jnp.float32) if unit_offset else g
     return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def head_rmsnorm(x, g, eps: float, axis: int):
+    """RMSNorm over ``axis`` (the head's width, wherever the layout has it),
+    float32 statistics, one gain vector for every head: QK-norm."""
+    xf = x.astype(jnp.float32)
+    rms = lax.rsqrt(jnp.mean(xf * xf, axis=axis, keepdims=True) + eps)
+    shape = [1] * x.ndim
+    shape[axis] = x.shape[axis]
+    return (xf * rms).astype(x.dtype) * g.astype(x.dtype).reshape(shape)
 
 
 def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
@@ -212,7 +222,9 @@ def head_layout(head_dim: int, v_dim: Optional[int] = None) -> str:
 
 
 def causal_attention(q, k, v, attention_impl: str, *, layout: str = "bhsd",
-                     scale: Optional[float] = None):
+                     scale: Optional[float] = None,
+                     window: Optional[int] = None,
+                     grouped_kv: bool = False):
     """q [B,H,S,hd], k/v [B,KH,S,hd] → [B,H,S,hd], causal (head-major layout —
     the hd-minor flash kernels' own, so the hot path has no boundary
     transposes); KH heads of k and v serve H / KH heads of q each. Another
@@ -221,19 +233,25 @@ def causal_attention(q, k, v, attention_impl: str, *, layout: str = "bhsd",
     either way; a block that hands the other pair's order pays the
     transposes at the kernel's edge. v, and with it the result, may be of
     another width than q and k; ``scale`` multiplies the logits (1/√hd of
-    q's width where none is given)."""
+    q's width where none is given). Under ``window`` query i sees the keys
+    j <= i with i − j < window: the flash pair walks that band alone
+    (ops/attention.flash_attention), the XLA path masks it. With
+    ``grouped_kv`` the flash pair is handed k and v at their own KH heads and
+    reads each where it stands (S8: nothing repeated in HBM; PERF.md §6, PR 66
+    says what the repeat cost); without it — every caller before PR 66, whose
+    lowered steps stay as they were — they are repeated to q's heads first."""
     from ray_tpu.ops.attention import flash_attention_sharded
 
     impl, interpret, mesh = attention_on_mesh(attention_impl)
     heads = layout.index("h")
     groups = q.shape[heads] // k.shape[heads]
-    if groups > 1:
+    if groups > 1 and not (grouped_kv and impl == "pallas"):
         k = jnp.repeat(k, groups, axis=heads)
         v = jnp.repeat(v, groups, axis=heads)
     if impl == "pallas":
         return flash_attention_sharded(
             q, k, v, mesh, layout=layout, causal=True, interpret=interpret,
-            scale=scale)
+            scale=scale, **({} if window is None else {"window": window}))
     # XLA path: einsum + mask; XLA fuses the softmax chain.
     S = q.shape[layout.index("s")]
     if scale is None:
@@ -242,6 +260,8 @@ def causal_attention(q, k, v, attention_impl: str, *, layout: str = "bhsd",
     rows = layout[:2]                       # the logits keep the rows' order
     logits = jnp.einsum(f"{at_q},{at_k}->{rows}qk", q, k) * scale
     mask = jnp.tril(jnp.ones((S, S), dtype=bool))
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((S, S), dtype=bool), -window)
     logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum(f"{rows}qk,{at_k}->{at_q}", probs, v)
@@ -304,6 +324,21 @@ def expert_step_counters(layers, n_experts: int, top_k: int, held: moe.Held,
         scopes.EXPERT_LOAD_KIND, fields, layers,
         partial(moe.step_load_static, n_experts=n_experts, top_k=top_k,
                 held=held), float_fields)
+
+
+def expert_layer_ids(pattern: str, first_layer: int,
+                     experts: Dict[str, bool]) -> Tuple[int, ...]:
+    """The published index of every expert layer of ``pattern`` (one
+    character a layer, ``experts[kind]`` says which kinds hold experts), in
+    the order they come: ``model/expert_load``'s ``layer``."""
+    return tuple(first_layer + i for i, kind in enumerate(pattern)
+                 if experts[kind])
+
+
+def untied_logits(x, lm_head, dtype):
+    """The head's input x [B, S, D] → logits [B, S, vocab] through an untied
+    head [D, vocab] in the compute dtype: a family's public ``forward``."""
+    return jnp.einsum("bsd,dv->bsv", x, lm_head.astype(dtype))
 
 
 def rows_under(seq: int, bytes_a_row: int, limit: int) -> int:
@@ -478,6 +513,11 @@ class BlockShard(NamedTuple):
     # residual add (a sandwich norm): the float32 output and the normed one
     # wait in the block's backward, and so do their cotangents
     out_norms: bool = False
+    # > 0: the flash pair is given this causal window (a query sees that many
+    # keys at most; ops/attention.py walks the band alone), so its o and lse
+    # cost the band's pairs to make again, not the triangle's. NOT ``window``
+    # above, which selects the EVA mixer's arithmetic
+    flash_window: int = 0
 
 
 def shard_block(whole: BlockShard, mesh) -> BlockShard:
@@ -553,7 +593,8 @@ def remat_candidates(s: BlockShard) -> List[RematCandidate]:
         out.append(RematCandidate(
             (scopes.RES_FLASH_O, scopes.RES_FLASH_LSE),
             tokens * s.heads * (s.head_dim * a + 4),
-            2 * s.batch * s.heads * s.seq * s.seq * max(s.head_dim, MXU),
+            2 * s.batch * s.heads * twice_causal_pairs(s.seq, s.flash_window)
+            * max(s.head_dim, MXU),
         ))
     out.append(RematCandidate((scopes.RES_MID,), tokens * s.d_model * a,
                               2 * tokens * attn_width * s.d_model))
@@ -562,6 +603,16 @@ def remat_candidates(s: BlockShard) -> List[RematCandidate]:
                                2 * tokens * s.d_model * s.d_ff)
                 for name in s.mlp_hidden]
     return sorted(out, key=lambda c: (-c.flops / c.nbytes, -c.frees))
+
+
+def twice_causal_pairs(seq: int, window: int = 0) -> int:
+    """Twice the (query, key) pairs of a causal row of ``seq`` tokens under a
+    window of ``window`` keys: S · S as the rule has always priced the
+    triangle where there is none (0) or it hides nothing, else the band's
+    w(w+1) + 2(S − w)·w."""
+    if not 0 < window < seq:
+        return seq * seq
+    return window * (window + 1) + 2 * (seq - window) * window
 
 
 def _eva_k_f32(s: BlockShard) -> int:

@@ -230,13 +230,18 @@ def record_decision(decisions: Dict[tuple, Dict[str, Any]], event: str,
 
 
 def _record(kernel: str, rows: int, Sq: int, Skv: int, hd: int,
-            tiling: Tiling, layout: str, hd_v: int) -> None:
+            tiling: Tiling, layout: str, hd_v: int,
+            window: Optional[int] = None) -> None:
     """The tiling a flash kernel is traced with, with the shapes it was
-    given (``hd`` of q and k, ``hd_v`` of v and o) and the pair it belongs
-    to (``ops/flash_tiling``)."""
+    given (``hd`` of q and k, ``hd_v`` of v and o), the pair it belongs to
+    and, under a causal window (0: none), how many (q, kv) tile pairs the
+    call visits beside how many the causal walk alone would
+    (``ops/flash_tiling``; visited_tiles)."""
     record_decision(_decisions, names.FLASH_TILING, dict(zip(
         names.FLASH_TILING_ARGS,
-        (kernel, rows, Sq, Skv, hd) + tuple(tiling) + (layout, hd_v))))
+        (kernel, rows, Sq, Skv, hd) + tuple(tiling) + (layout, hd_v)
+        + (window or 0,) + visited_tiles(kernel, Sq, Skv, tiling.block_q,
+                                         tiling.block_k, window))))
 
 
 def _compiler_params(tiling: Tiling):
@@ -368,11 +373,119 @@ def _q_block_range(kv_global, q_off_ref, block_q: int, block_k: int,
     return first, first_full
 
 
+def _kv_band(q_global, kv_off_ref, block_q: int, block_k: int,
+             causal_range, window: int):
+    """_kv_block_range under a causal WINDOW (query i sees keys j <= i with
+    i - j < ``window``): the four edges ``(start, lo, hi, end)`` of a q
+    tile's walk over the kv blocks, from the causal walk's ``causal_range``
+    (_kv_block_range's pair). It starts at the first block that
+    holds a key inside the window of the tile's FIRST row and ends where the
+    causal walk ends; blocks ``[lo, hi)`` lie wholly inside the band — their
+    first key inside the window of the tile's LAST row, their last key at or
+    before its first — and take no mask, ``[start, lo)`` straddle the
+    window's edge and ``[hi, end)`` the diagonal and take one (a tile whose
+    window is narrower than its blocks has lo == hi: one masked walk)."""
+    off = kv_off_ref[0]
+    last_q = q_global + block_q - 1
+    end, causal_full = causal_range
+    start = jnp.clip((q_global - window + 1 - off) // block_k, 0, end)
+    lo = jnp.clip(-((off - (last_q - window + 1)) // block_k), start, end)
+    hi = jnp.clip(causal_full, lo, end)
+    return start, lo, hi, end
+
+
+def _q_band(kv_global, q_off_ref, block_q: int, block_k: int, nq: int,
+            causal_range, window: int):
+    """_q_block_range under a causal window: the four edges ``(first, lo,
+    hi, end)`` of a kv block's walk over the ``nq`` q tiles, from the causal
+    walk's ``causal_range`` (_q_block_range's pair). It starts where
+    the causal walk starts and ends after the last tile that holds a row
+    whose window still reaches the block's LAST key; tiles ``[lo, hi)`` see
+    the whole block — their first row at or past its last key, their last
+    row's window reaching its first — and take no mask, ``[first, lo)``
+    straddle the diagonal and ``[hi, end)`` the window's edge."""
+    off = q_off_ref[0]
+    first, causal_full = causal_range
+    last_k = kv_global + block_k - 1
+    end = jnp.clip((last_k + window - 1 - off) // block_q + 1, first, nq)
+    lo = jnp.clip(causal_full, first, end)
+    hi = jnp.clip((kv_global + window - off) // block_q, lo, end)
+    return first, lo, hi, end
+
+
+def _kv_row_of(groups: int, batch: int, heads_lead: bool):
+    """The index map's row of k and v for a q row ``g`` of the grid where
+    ``groups`` query heads read one key-value head (1: g itself — the map a
+    call without grouped heads always had). Rows are batch · heads merged:
+    with the batch leading a kv head's row is g // groups; with the heads
+    leading ([H, B, ..]) it is (g // (B · groups)) · B + g % B."""
+    if groups == 1:
+        return lambda g: g
+    if heads_lead:
+        return lambda g: (g // (batch * groups)) * batch + g % batch
+    return lambda g: g // groups
+
+
+def _group_sum(d, q_shape, groups: int, heads_lead: bool):
+    """dk or dv as the backward kernel writes it, one a QUERY head ([rows of
+    q, ...]), summed over each key-value head's ``groups`` query heads →
+    [leading two dims of k, ...]."""
+    a, b = q_shape[:2]
+    if groups == 1:
+        return d.reshape((a, b) + d.shape[1:])
+    if heads_lead:      # [H, B, ..] → [KH, G, B, ..]
+        return d.reshape((a // groups, groups, b) + d.shape[1:]).sum(
+            axis=1, dtype=jnp.float32).astype(d.dtype)
+    return d.reshape((a, b // groups, groups) + d.shape[1:]).sum(
+        axis=2, dtype=jnp.float32).astype(d.dtype)
+
+
+def _in_window(keep, q_pos, k_pos, window: Optional[int]):
+    """A masked tile's ``keep`` (the causal half, from the tile's global
+    positions) with the window's other edge: one ``where`` takes both."""
+    if window is None:
+        return keep
+    return keep & (q_pos - k_pos < window)
+
+
+def _band_loops(edges, make_body, carry):
+    """The three walks of a windowed call over ``edges`` = (start, lo, hi,
+    end): masked, unmasked, masked."""
+    start, lo, hi, end = edges
+    carry = lax.fori_loop(start, lo, make_body(True), carry)
+    carry = lax.fori_loop(lo, hi, make_body(False), carry)
+    return lax.fori_loop(hi, end, make_body(True), carry)
+
+
+def visited_tiles(kernel: str, Sq: int, Skv: int, block_q: int, block_k: int,
+                  window: Optional[int]) -> Tuple[int, int]:
+    """(tiles a causal call of ``kernel`` visits at offsets 0 under
+    ``window``, tiles the causal walk alone would): _kv_band / _q_band's
+    walks counted in plain integers — what an ``ops/flash_tiling`` event
+    says of how much of the triangle a window skipped."""
+    nq, nk = Sq // block_q, Skv // block_k
+    visited = causal = 0
+    if kernel == "fwd":
+        for i in range(nq):
+            end = min(max((i * block_q + block_q - 1) // block_k + 1, 0), nk)
+            start = 0 if window is None else min(
+                max((i * block_q - window + 1) // block_k, 0), end)
+            visited, causal = visited + end - start, causal + end
+    else:
+        for j in range(nk):
+            first = min(max(j * block_k // block_q, 0), nq)
+            end = nq if window is None else min(max(
+                (j * block_k + block_k + window - 2) // block_q + 1, first), nq)
+            visited, causal = visited + end - first, causal + nq - first
+    return visited, causal
+
+
 def _fwd_kernel(
     q_off_ref, kv_off_ref,            # scalar prefetch: global offsets [1]
     q_ref, k_ref, v_ref,              # [bq, hd], [Skv, hd], [Skv, hd_v]
     o_ref, lse_ref,                   # [bq, hd_v], [1, bq]
     *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
+    window: Optional[int] = None,
 ):
     qi = pl.program_id(1)
     q_global = q_off_ref[0] + qi * block_q
@@ -401,11 +514,11 @@ def _fwd_kernel(
                 preferred_element_type=jnp.float32,
             )                               # [bk, bq]
             if masked:
-                keep = (
-                    q_global + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 1)
-                    >= kv_off_ref[0] + ki * block_k + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 0))
+                q_pos = q_global + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                k_pos = kv_off_ref[0] + ki * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                keep = _in_window(q_pos >= k_pos, q_pos, k_pos, window)
                 s = jnp.where(keep, s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -422,8 +535,14 @@ def _fwd_kernel(
     carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
              jnp.zeros((hd_v, block_q), jnp.float32))
-    carry = lax.fori_loop(0, num_full, make_body(False), carry)
-    m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal), carry)
+    if window is None:
+        carry = lax.fori_loop(0, num_full, make_body(False), carry)
+        m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal),
+                                  carry)
+    else:
+        m, l, acc = _band_loops(
+            _kv_band(q_global, kv_off_ref, block_q, block_k,
+                     (num_blocks, num_full), window), make_body, carry)
     # rows with no valid kv (ring attention future chunks): l == 0 →
     # output 0, lse = -inf-ish so the ring merge gives them zero weight.
     l_safe = jnp.where(l > 0, l, 1.0)
@@ -435,6 +554,7 @@ def _mha_forward_bhsd(
     q, k, v, q_offset, kv_offset, *,
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
+    window: Optional[int] = None, heads_lead: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """q,k: [B, H, S, hd], v: [B, H, S, hd_v] → (o [B,H,S,hd_v], lse
     [B,H,S]). Batch and head are merged (a free reshape) into the one dim of
@@ -442,18 +562,20 @@ def _mha_forward_bhsd(
     choose_tiling's."""
     B, H, Sq, hd = q.shape
     Skv, hd_v = k.shape[2], v.shape[3]
-    R = B * H
+    R, KR = B * H, k.shape[0] * k.shape[1]
+    kv_of = _kv_row_of(R // KR, H if heads_lead else B, heads_lead)
     t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize,
                       block_q=block_q, block_k=block_k, hd_v=hd_v)
-    _record("fwd", R, Sq, Skv, hd, t, HD_MINOR, hd_v)
+    _record("fwd", R, Sq, Skv, hd, t, HD_MINOR, hd_v, window)
     bq, bk = t.block_q, t.block_k
 
     def kv_row(width):
-        return pl.BlockSpec((None, Skv, width), lambda g, i, *_: (g, 0, 0))
+        return pl.BlockSpec((None, Skv, width),
+                            lambda g, i, *_: (kv_of(g), 0, 0))
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, kv_len=Skv,
+        block_q=bq, block_k=bk, kv_len=Skv, window=window,
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -476,8 +598,8 @@ def _mha_forward_bhsd(
         compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_FWD_KERNEL,
-    )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(R, Skv, hd),
-      v.reshape(R, Skv, hd_v))
+    )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(KR, Skv, hd),
+      v.reshape(KR, Skv, hd_v))
     return o.reshape(B, H, Sq, hd_v), lse.reshape(B, H, Sq)
 
 
@@ -486,6 +608,7 @@ def _fwd_kernel_s_minor(
     q_ref, k_ref, v_ref,              # [hd, bq], [hd, Skv], [hd_v, Skv]
     o_ref, lse_ref,                   # [hd_v, bq], [1, bq]
     *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
+    window: Optional[int] = None,
 ):
     """_fwd_kernel on S-minor tiles: every operand tile is [width, tile],
     dense at any width (the sequence fills the lanes) — hd for q and k, hd_v
@@ -512,11 +635,11 @@ def _fwd_kernel_s_minor(
                 preferred_element_type=jnp.float32,
             )                               # k^T·q, [bk, bq]
             if masked:
-                keep = (
-                    q_global + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 1)
-                    >= kv_off_ref[0] + ki * block_k + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 0))
+                q_pos = q_global + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                k_pos = kv_off_ref[0] + ki * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                keep = _in_window(q_pos >= k_pos, q_pos, k_pos, window)
                 s = jnp.where(keep, s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -532,8 +655,14 @@ def _fwd_kernel_s_minor(
     carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
              jnp.zeros((hd_v, block_q), jnp.float32))
-    carry = lax.fori_loop(0, num_full, make_body(False), carry)
-    m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal), carry)
+    if window is None:
+        carry = lax.fori_loop(0, num_full, make_body(False), carry)
+        m, l, acc = lax.fori_loop(num_full, num_blocks, make_body(causal),
+                                  carry)
+    else:
+        m, l, acc = _band_loops(
+            _kv_band(q_global, kv_off_ref, block_q, block_k,
+                     (num_blocks, num_full), window), make_body, carry)
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[...] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[...] = jnp.where(l > 0, m + jnp.log(l_safe), _NEG_INF)
@@ -543,6 +672,7 @@ def _mha_forward_s_minor(
     q, k, v, q_offset, kv_offset, *,
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
+    window: Optional[int] = None, heads_lead: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """q,k: [B, H, hd, S], v: [B, H, hd_v, S] → (o [B,H,hd_v,S], lse
     [B,H,S]): _mha_forward_bhsd with the sequence minor ([H, B, ..] as well:
@@ -550,21 +680,23 @@ def _mha_forward_s_minor(
     same; a block is [width, tile] and its index moves along the last dim."""
     B, H, hd, Sq = q.shape
     Skv, hd_v = k.shape[3], v.shape[2]
-    R = B * H
+    R, KR = B * H, k.shape[0] * k.shape[1]
+    kv_of = _kv_row_of(R // KR, H if heads_lead else B, heads_lead)
     t = choose_tiling("fwd", Sq, Skv, hd, q.dtype.itemsize, block_q=block_q,
                       block_k=block_k, layout=S_MINOR, hd_v=hd_v)
-    _record("fwd", R, Sq, Skv, hd, t, S_MINOR, hd_v)
+    _record("fwd", R, Sq, Skv, hd, t, S_MINOR, hd_v, window)
     bq, bk = t.block_q, t.block_k
 
     def q_tile(width):
         return pl.BlockSpec((None, width, bq), lambda g, i, *_: (g, 0, i))
 
     def kv_row(width):
-        return pl.BlockSpec((None, width, Skv), lambda g, i, *_: (g, 0, 0))
+        return pl.BlockSpec((None, width, Skv),
+                            lambda g, i, *_: (kv_of(g), 0, 0))
 
     kernel = functools.partial(
         _fwd_kernel_s_minor, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, kv_len=Skv,
+        block_q=bq, block_k=bk, kv_len=Skv, window=window,
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -584,8 +716,8 @@ def _mha_forward_s_minor(
         compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_FWD_KERNEL,
-    )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(R, hd, Skv),
-      v.reshape(R, hd_v, Skv))
+    )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(KR, hd, Skv),
+      v.reshape(KR, hd_v, Skv))
     return o.reshape(B, H, hd_v, Sq), lse.reshape(B, H, Sq)
 
 
@@ -598,6 +730,7 @@ def _fused_bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dq_acc,
     *, scale: float, causal: bool, block_q: int, block_k: int, q_len: int,
+    window: Optional[int] = None,
 ):
     """Single-pass backward: grid over kv blocks; dk/dv written per block,
     dq accumulated over the kv grid dim in a whole-row f32 VMEM scratch
@@ -644,11 +777,11 @@ def _fused_bwd_kernel(
                 preferred_element_type=jnp.float32,
             )                                            # [bk, bq]
             if masked:
-                keep = (
-                    q_off_ref[0] + qi * block_q + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 1)
-                    >= kv_global + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 0))
+                q_pos = q_off_ref[0] + qi * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                k_pos = kv_global + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                keep = _in_window(q_pos >= k_pos, q_pos, k_pos, window)
                 s = jnp.where(keep, s, _NEG_INF)
             p = jnp.exp(s - lse)
             dv = dv + lax.dot_general(
@@ -673,8 +806,13 @@ def _fused_bwd_kernel(
 
     carry = (jnp.zeros((block_k, hd), jnp.float32),
              jnp.zeros((block_k, hd_v), jnp.float32))
-    carry = lax.fori_loop(first, first_full, make_body(causal), carry)
-    dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
+    if window is None:
+        carry = lax.fori_loop(first, first_full, make_body(causal), carry)
+        dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
+    else:
+        dk, dv = _band_loops(
+            _q_band(kv_global, q_off_ref, block_q, block_k, nq,
+                    (first, first_full), window), make_body, carry)
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
@@ -687,16 +825,19 @@ def _mha_backward_bhsd(
     q, k, v, o, lse, do, q_offset, kv_offset, *,
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
+    window: Optional[int] = None, heads_lead: bool = False,
 ):
     """q, k [B, H, S, hd]; v, o, do [B, H, S, hd_v]; lse [B, H, S]. Returns
     dq, dk, dv. Rows and tiles as in _mha_forward_bhsd, chosen for this
     kernel separately."""
     B, H, Sq, hd = q.shape
     Skv, hd_v = k.shape[2], v.shape[3]
-    R = B * H
+    R, KR = B * H, k.shape[0] * k.shape[1]
+    groups = R // KR
+    kv_of = _kv_row_of(groups, H if heads_lead else B, heads_lead)
     t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize,
                       block_q=block_q, block_k=block_k, hd_v=hd_v)
-    _record("bwd", R, Sq, Skv, hd, t, HD_MINOR, hd_v)
+    _record("bwd", R, Sq, Skv, hd, t, HD_MINOR, hd_v, window)
     bq, bk = t.block_q, t.block_k
 
     # delta_i = rowsum(dO_i * O_i): cheap elementwise+reduce, XLA fuses it.
@@ -707,22 +848,23 @@ def _mha_backward_bhsd(
     def row(width):
         return pl.BlockSpec((None, Sq, width), lambda g, i, *_: (g, 0, 0))
 
-    def kv_block(width):
-        return pl.BlockSpec((None, bk, width), lambda g, i, *_: (g, i, 0))
+    def kv_block(width, of=lambda g: g):
+        return pl.BlockSpec((None, bk, width),
+                            lambda g, i, *_: (of(g), i, 0))
 
     stat = pl.BlockSpec((None, 1, Sq), lambda g, i, *_: (g, 0, 0))
 
     fused_kernel = functools.partial(
         _fused_bwd_kernel, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, q_len=Sq,
+        block_q=bq, block_k=bk, q_len=Sq, window=window,
     )
     dq, dk, dv = pl.pallas_call(
         fused_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, Skv // bk),
-            in_specs=[row(hd), kv_block(hd), kv_block(hd_v), row(hd_v), stat,
-                      stat],
+            in_specs=[row(hd), kv_block(hd, kv_of), kv_block(hd_v, kv_of),
+                      row(hd_v), stat, stat],
             out_specs=[row(hd), kv_block(hd), kv_block(hd_v)],
             scratch_shapes=[pltpu.VMEM((hd, Sq), jnp.float32)],
         ),
@@ -734,10 +876,14 @@ def _mha_backward_bhsd(
         compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_BWD_KERNEL,
-    )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(R, Skv, hd),
-      v.reshape(R, Skv, hd_v), do.reshape(R, Sq, hd_v), lse.reshape(R, 1, Sq),
+    )(q_offset, kv_offset, q.reshape(R, Sq, hd), k.reshape(KR, Skv, hd),
+      v.reshape(KR, Skv, hd_v), do.reshape(R, Sq, hd_v), lse.reshape(R, 1, Sq),
       delta)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    # dk and dv leave the kernel one a QUERY head; a key-value head's is the
+    # sum over its group (all of them where none is grouped)
+    return (dq.reshape(q.shape),
+            _group_sum(dk, q.shape, groups, heads_lead),
+            _group_sum(dv, q.shape, groups, heads_lead))
 
 
 def _fused_bwd_kernel_s_minor(
@@ -745,6 +891,7 @@ def _fused_bwd_kernel_s_minor(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,   # [hd | hd_v, Sq | bk]
     dq_ref, dk_ref, dv_ref, dq_acc,
     *, scale: float, causal: bool, block_q: int, block_k: int, q_len: int,
+    window: Optional[int] = None,
 ):
     """_fused_bwd_kernel on S-minor tiles. s^T = k^T·q and dp^T = v^T·do
     contract hd, dimension 0 of both tiles (Mosaic transposes the [hd, bk]
@@ -787,11 +934,11 @@ def _fused_bwd_kernel_s_minor(
                 preferred_element_type=jnp.float32,
             )                                            # k^T·q, [bk, bq]
             if masked:
-                keep = (
-                    q_off_ref[0] + qi * block_q + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 1)
-                    >= kv_global + lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 0))
+                q_pos = q_off_ref[0] + qi * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                k_pos = kv_global + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                keep = _in_window(q_pos >= k_pos, q_pos, k_pos, window)
                 s = jnp.where(keep, s, _NEG_INF)
             p = jnp.exp(s - lse)                         # [bk, bq]
             dv = dv + lax.dot_general(
@@ -814,8 +961,13 @@ def _fused_bwd_kernel_s_minor(
 
     carry = (jnp.zeros((hd, block_k), jnp.float32),
              jnp.zeros((hd_v, block_k), jnp.float32))
-    carry = lax.fori_loop(first, first_full, make_body(causal), carry)
-    dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
+    if window is None:
+        carry = lax.fori_loop(first, first_full, make_body(causal), carry)
+        dk, dv = lax.fori_loop(first_full, nq, make_body(False), carry)
+    else:
+        dk, dv = _band_loops(
+            _q_band(kv_global, q_off_ref, block_q, block_k, nq,
+                    (first, first_full), window), make_body, carry)
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
@@ -828,36 +980,40 @@ def _mha_backward_s_minor(
     q, k, v, o, lse, do, q_offset, kv_offset, *,
     causal: bool, scale: float, interpret: bool,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
+    window: Optional[int] = None, heads_lead: bool = False,
 ):
     """q, k [B, H, hd, S]; v, o, do [B, H, hd_v, S]; lse [B, H, S]. Returns
     dq, dk, dv: _mha_backward_bhsd with the sequence minor."""
     B, H, hd, Sq = q.shape
     Skv, hd_v = k.shape[3], v.shape[2]
-    R = B * H
+    R, KR = B * H, k.shape[0] * k.shape[1]
+    groups = R // KR
+    kv_of = _kv_row_of(groups, H if heads_lead else B, heads_lead)
     t = choose_tiling("bwd", Sq, Skv, hd, q.dtype.itemsize, block_q=block_q,
                       block_k=block_k, layout=S_MINOR, hd_v=hd_v)
-    _record("bwd", R, Sq, Skv, hd, t, S_MINOR, hd_v)
+    _record("bwd", R, Sq, Skv, hd, t, S_MINOR, hd_v, window)
     bq, bk = t.block_q, t.block_k
 
     def row(width):
         return pl.BlockSpec((None, width, Sq), lambda g, i, *_: (g, 0, 0))
 
-    def kv_block(width):
-        return pl.BlockSpec((None, width, bk), lambda g, i, *_: (g, 0, i))
+    def kv_block(width, of=lambda g: g):
+        return pl.BlockSpec((None, width, bk),
+                            lambda g, i, *_: (of(g), 0, i))
 
     stat = pl.BlockSpec((None, 1, Sq), lambda g, i, *_: (g, 0, 0))
 
     fused_kernel = functools.partial(
         _fused_bwd_kernel_s_minor, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, q_len=Sq,
+        block_q=bq, block_k=bk, q_len=Sq, window=window,
     )
     dq, dk, dv = pl.pallas_call(
         fused_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, Skv // bk),
-            in_specs=[row(hd), kv_block(hd), kv_block(hd_v), row(hd_v),
-                      row(hd_v), stat],
+            in_specs=[row(hd), kv_block(hd, kv_of), kv_block(hd_v, kv_of),
+                      row(hd_v), row(hd_v), stat],
             out_specs=[row(hd), kv_block(hd), kv_block(hd_v)],
             scratch_shapes=[pltpu.VMEM((hd, Sq), jnp.float32)],
         ),
@@ -869,10 +1025,12 @@ def _mha_backward_s_minor(
         compiler_params=_compiler_params(t),
         interpret=interpret,
         name=names.FLASH_BWD_KERNEL,
-    )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(R, hd, Skv),
-      v.reshape(R, hd_v, Skv), o.reshape(R, hd_v, Sq),
+    )(q_offset, kv_offset, q.reshape(R, hd, Sq), k.reshape(KR, hd, Skv),
+      v.reshape(KR, hd_v, Skv), o.reshape(R, hd_v, Sq),
       do.reshape(R, hd_v, Sq), lse.reshape(R, 1, Sq))
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    return (dq.reshape(q.shape),
+            _group_sum(dk, q.shape, groups, heads_lead),
+            _group_sum(dv, q.shape, groups, heads_lead))
 
 
 # --------------------------------------------------------------------------- #
@@ -910,16 +1068,30 @@ def _zero_off():
     return jnp.zeros((1,), jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _checked_window(window: Optional[int], causal: bool) -> Optional[int]:
+    """A caller's ``window`` as the kernels take it: None, or a whole number
+    of keys >= 1 under the causal mask."""
+    if window is None:
+        return None
+    if not causal:
+        raise ValueError("a window is the causal mask's other edge: "
+                         f"window={window} needs causal=True")
+    if int(window) != window or window < 1:
+        raise ValueError(f"window must be a whole number >= 1; got {window!r}")
+    return int(window)
+
+
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret, layout):
+           bwd_block_k, interpret, layout, window):
     o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-                      bwd_block_k, interpret, layout)
+                      bwd_block_k, interpret, layout, window)
     return o
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-               bwd_block_k, interpret, layout):
+               bwd_block_k, interpret, layout, window):
     d = layout.index("d")
     pair, axes = _kernel_axes(layout, q.shape[d], v.shape[d])
     qt, kt, vt = (_relayout(x, layout, axes) for x in (q, k, v))
@@ -927,7 +1099,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
     o, lse = forward(
         qt, kt, vt, _zero_off(), _zero_off(),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=window, heads_lead=axes[0] == "h",
     )
     # the kernel's two residuals by name: a checkpoint policy that keeps both
     # does not run the forward kernel a second time in the backward
@@ -937,7 +1109,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
 
 
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-               interpret, layout, res, do):
+               interpret, layout, window, res, do):
     qt, kt, vt, o, lse = res
     # (do has v's width; q's is what q's size leaves beside the rows and
     # the sequence, which q and do share)
@@ -948,7 +1120,7 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
         qt, kt, vt, o, lse, _relayout(do, layout, axes),
         _zero_off(), _zero_off(),
         causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k,
-        interpret=interpret,
+        interpret=interpret, window=window, heads_lead=axes[0] == "h",
     )
     return tuple(_relayout(g, axes, layout) for g in grads)
 
@@ -970,6 +1142,7 @@ def flash_attention(
     bwd_block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     layout: str = "bshd",
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head flash attention. q,k,v in and o out are in the axis order
     ``layout`` spells (one of LAYOUTS): [B, S, H, hd] ("bshd", the default),
@@ -989,6 +1162,20 @@ def flash_attention(
     the shapes. The block_* keywords are explicit overrides of it: block_q /
     block_k the forward's tiles; a bwd_* left None follows its forward twin.
 
+    k and v may hold FEWER heads than q (grouped-query attention: H / KH
+    query heads read one key-value head, head n the key-value head n // (H /
+    KH)): the kernels then read each key-value head's rows where they stand
+    — the index map of k's and v's blocks divides the row —, nothing is
+    repeated in HBM, and dk and dv are summed over each group after the
+    backward kernel, which writes them a query head.
+
+    ``window`` (static; causal calls only): query i sees the keys j <= i with
+    i - j < window. Both kernels then walk the BAND alone — a q tile's kv
+    blocks from where its first row's window starts, a kv block's q tiles up
+    to where the last row that still reaches it lies (_kv_band, _q_band) —,
+    unmasked inside it and masked on its two edges. A window that hides
+    nothing (>= the keys' length) and None are the same program.
+
     Differentiable (custom VJP, flash backward). On non-TPU backends the
     kernels run in Pallas interpreter mode so tests validate the same code.
     """
@@ -998,10 +1185,13 @@ def flash_attention(
         _, interpret = resolve_attention()
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[layout.index("d")])
+    window = _checked_window(window, causal)
+    if window is not None and window >= k.shape[layout.index("s")]:
+        window = None
     return _flash(
         q, k, v, causal, scale, block_q, block_k,
         bwd_block_q or block_q, bwd_block_k or block_k,
-        interpret, layout,
+        interpret, layout, window,
     )
 
 
@@ -1037,15 +1227,27 @@ def flash_attention_sharded(q, k, v, mesh, *, layout: str = "bhsd",
     shard_map"). The shard_map hands each device its own shard, the batch
     over dp·fsdp and the heads over tp, wherever ``layout`` has them. The
     sequence stays whole per device (a cp axis belongs to
-    ring_attention_sharded)."""
+    ring_attention_sharded), so a ``window`` is refused on a mesh with one."""
     if layout not in HEAD_MAJOR_LAYOUTS:
         raise ValueError(f"unknown head-major layout {layout!r}")
+    if (kwargs.get("window") is not None and mesh is not None
+            and mesh.shape.get("cp", 1) > 1):
+        raise NotImplementedError(
+            f"window={kwargs['window']} under cp > 1: a device holds a chunk "
+            "of the sequence and the ring (ops/ring_attention.py) carries "
+            "whole rows of kv; use a cp=1 mesh for windowed attention")
     if mesh is None:
         return flash_attention(q, k, v, layout=layout, **kwargs)
     if kwargs.get("interpret") is None:
         _, kwargs["interpret"] = resolve_attention(mesh=mesh)
     b, h = layout.index("b"), layout.index("h")
     batch_axes, head_ax = batch_head_axes(mesh, q.shape[b], q.shape[h])
+    if (k.shape[h] != q.shape[h] and head_ax is not None
+            and mesh.shape.get(head_ax, 1) > 1):
+        raise NotImplementedError(
+            f"grouped heads ({q.shape[h]} of q on {k.shape[h]} of k and v) "
+            "under tp > 1: the kernels' rows are not cut by the group here; "
+            "repeat k and v to q's heads, or use a tp=1 mesh")
     spec = [None] * 4
     spec[b], spec[h] = batch_axes, head_ax
     spec = P(*spec)
@@ -1067,10 +1269,13 @@ def flash_attention_with_lse(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forward-only flash attention returning (out [B,S,H,hd], lse [B,H,S])
     with GLOBAL position offsets — the building block for ring attention's
     per-step chunk computation (ops/ring_attention.py merges partials by lse).
+    ``window`` as flash_attention's, over the global positions (the ring
+    itself passes none: ops/ring_attention.py refuses a window).
     """
     if interpret is None:
         _, interpret = resolve_attention()
@@ -1081,7 +1286,7 @@ def flash_attention_with_lse(
     o, lse = _mha_forward_bhsd(
         _to_bhsd(q), _to_bhsd(k), _to_bhsd(v), q_off, kv_off,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=_checked_window(window, causal),
     )
     return _to_bhsd(o), lse
 
@@ -1094,10 +1299,12 @@ def mha_backward_chunk(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ):
     """Backward for one (q-chunk, kv-chunk) pair with global offsets; returns
     (dq, dk, dv) contributions (all [B,S,H,hd]). `lse` is the GLOBAL logsumexp
-    over all chunks. Used by ring attention's backward ring pass."""
+    over all chunks. Used by ring attention's backward ring pass. ``window``
+    as flash_attention_with_lse's."""
     if interpret is None:
         _, interpret = resolve_attention()
     if scale is None:
@@ -1108,6 +1315,6 @@ def mha_backward_chunk(
         _to_bhsd(q), _to_bhsd(k), _to_bhsd(v), _to_bhsd(o), lse,
         _to_bhsd(do), q_off, kv_off,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=_checked_window(window, causal),
     )
     return _to_bhsd(dq), _to_bhsd(dk), _to_bhsd(dv)

@@ -140,6 +140,17 @@ def _ring_bwd(axis_name, causal, scale, block_q, block_k, interpret, res, do):
 _ring.defvjp(_ring_fwd, _ring_bwd)
 
 
+def _refuse_window(window: Optional[int]) -> None:
+    """The ring carries WHOLE rows of kv round every device, a step a chunk:
+    under a window most of those steps would carry keys no query of the
+    device sees, and skipping them is a schedule this ring does not have."""
+    if window is not None:
+        raise NotImplementedError(
+            f"window={window}: ring attention (a cp axis) has no windowed "
+            "schedule — the ring carries whole rows of kv; use a cp=1 mesh "
+            "(ops/attention.flash_attention takes the window)")
+
+
 def ring_attention(
     q: jax.Array,
     k: jax.Array,
@@ -151,10 +162,13 @@ def ring_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Ring attention over `axis_name`. Must run where the axis is bound
     (inside shard_map/pmap); q, k, v are the LOCAL sequence shards
-    [B, S_local, H, hd]. Differentiable (custom VJP, ring backward)."""
+    [B, S_local, H, hd]. Differentiable (custom VJP, ring backward). A
+    ``window`` is refused (_refuse_window)."""
+    _refuse_window(window)
     if interpret is None:
         _, interpret = resolve_attention()
     if scale is None:
@@ -174,11 +188,13 @@ def ring_attention_sharded(
     block_q: int = 512,
     block_k: int = 512,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Ring attention for callers under jit/GSPMD (the GPT-2 forward): wraps
     the ring in a shard_map over `mesh` with batch on (dp, fsdp), sequence on
     `axis_name`, heads on tp (ops/attention.batch_head_axes). GLOBAL-length
-    q/k/v in, global out."""
+    q/k/v in, global out. A ``window`` is refused (_refuse_window)."""
+    _refuse_window(window)
     if interpret is None:
         _, interpret = resolve_attention(mesh=mesh)
     cp = mesh.shape.get(axis_name, 1)

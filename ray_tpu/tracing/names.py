@@ -101,6 +101,11 @@ GATED_ATTN_GATE = "gated_attn_gate"
 # loop-end norm is LN_F: it stands after the last layer of EVERY pass
 LN1_POST, LN2_POST = "ln1_post", "ln2_post"
 EXIT_GATE = "exit_gate"
+# models/afmoe.py: inside `attn`, the attention operator of a WINDOW layer
+# (the flash pair under a causal window, its output gate) and of a FULL one —
+# both kinds run kernels of one name, and a trace tells their time apart by
+# these
+ATTN_WINDOW, ATTN_FULL = "attn_window", "attn_full"
 SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           FLASH_ATTENTION, EVA_ATTENTION,
                                           EVA_PREP_KV, MAMBA, SSD_SCAN,
@@ -112,7 +117,7 @@ SCOPES = (EMBED, BLOCK) + BLOCK_SCOPES + (MOE, LN_F, LM_HEAD_LOSS, OPTIMIZER,
                                           MOE_AUX, MHC, MHC_MAPS,
                                           DELTA_MIXER, GATED_DELTA,
                                           GATED_ATTN_GATE, LN1_POST, LN2_POST,
-                                          EXIT_GATE)
+                                          EXIT_GATE, ATTN_WINDOW, ATTN_FULL)
 
 # the two Mosaic kernels (`name=` of their pallas_call)
 FLASH_FWD_KERNEL = "flash_attention_fwd"
@@ -200,8 +205,12 @@ _TILE_ARGS = ("kernel", "rows", "Sq", "Skv", "hd", "block_q", "block_k",
 # ... and which kernel pair it was traced for: "s_minor" ([rows, hd, S]
 # operands, where a width is not whole lane tiles) or "hd_minor"; `hd` is q's
 # and k's width and, since PR 55, `hd_v` v's and o's (latent attention's 192
-# and 128; equal anywhere else)
-FLASH_TILING_ARGS = _TILE_ARGS + ("layout", "hd_v")
+# and 128; equal anywhere else); and, since PR 66, the causal `window` the
+# call was given (0: none) with the (q, kv) tile pairs it visits beside those
+# the causal walk alone would (equal without a window): how much of the
+# triangle the band skipped
+FLASH_TILING_ARGS = _TILE_ARGS + ("layout", "hd_v", "window", "tiles_visited",
+                                  "tiles_causal")
 # the same for an EVA kernel (ops/eva_attention.py): Sq = Skv = the sequence
 EVA_TILING = "ops/eva_tiling"
 EVA_TILING_ARGS = _TILE_ARGS + ("window", "chunk")

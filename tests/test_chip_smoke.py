@@ -468,6 +468,42 @@ def test_ouro_through_trainer_at_toy_size(fake_tpu_node):
     assert len(bad) == 1 and "model/loop" in bad[0]
 
 
+def test_afmoe_through_trainer_at_toy_size(fake_tpu_node):
+    """chip_smoke's loop with a small AFMoE step (window and full layers in
+    one pattern, experts beside a shared one) alone beside GPT-2's (PR 66; a
+    test of its own with its own time limit, as PR 61's lesson has it): the
+    interpreted flash pair records a windowed and a full call of each
+    kernel, and the check fails without the windowed ones."""
+    import chip_smoke
+    from ray_tpu.models import afmoe, gpt2
+
+    cfg, steps = gpt2.gpt2_tiny(), 16
+    toy = afmoe.afmoe_tiny(remat=True, attention_impl="pallas", seq_len=128,
+                           head_dim=32)
+    rows = chip_smoke.run(cfg, steps=steps, per_chip_batch=1, num_devices=8,
+                          use_tpu=False, afmoe_model=toy, grouped_shapes=())
+    assert chip_smoke.check_training(rows, cfg, steps) == []
+    summary = rows[-1]["summary"]
+    step = summary["afmoe"]
+    assert [d["groups"] for d in step["layer_pattern"]] == [
+        ["D", "W", "F", "2 x scan(W)"]]
+    assert {(d["kernel"], d["window"]) for d in step["flash_tiling"]} == {
+        (k, w) for k in ("fwd", "bwd") for w in (0, toy.sliding_window)}
+    assert [load["layer"] for load in step["expert_load"]] == [2, 3, 4, 5]
+    full_only = [rows[-1] | {"summary": summary | {"afmoe": step | {
+        "flash_tiling": [d for d in step["flash_tiling"]
+                         if not d["window"]]}}}]
+    bad = chip_smoke.check_training(rows[:-1] + full_only, cfg, steps)
+    assert len(bad) == 1 and "ops/flash_tiling" in bad[0]
+    # a windowed call that visits the whole triangle is refused
+    whole = [rows[-1] | {"summary": summary | {"afmoe": step | {
+        "flash_tiling": [d | {"Skv": 4096, "tiles_visited": 36,
+                              "tiles_causal": 36} if d["window"] else d
+                         for d in step["flash_tiling"]]}}}]
+    bad = chip_smoke.check_training(rows[:-1] + whole, cfg, steps)
+    assert len(bad) == 2 and all("band" in b for b in bad)
+
+
 def test_step_load_line_finds_the_steps_own_event_or_fails():
     """chip_smoke reads a toy expert step's run-time load from the session's
     record — the `train/step_counters` event whose rows are the step's own

@@ -366,7 +366,9 @@ def test_tiling_decision_is_recorded_once_per_distinct_choice(hd):
     chosen = attention.choose_tiling("fwd", 256, 256, hd, 4, layout=layout)
     assert events[0]["args"] == {
         "kernel": "fwd", "rows": 24, "Sq": 256, "Skv": 256, "hd": hd,
-        **chosen._asdict(), "layout": layout, "hd_v": hd}
+        **chosen._asdict(), "layout": layout, "hd_v": hd,
+        # (no window: one 256-token tile, the triangle's one pair; PR 66)
+        "window": 0, "tiles_visited": 1, "tiles_causal": 1}
     assert all(e["args"]["layout"] == layout for e in events)
     assert tuple(events[0]["args"]) == names.FLASH_TILING_ARGS
     assert [e["args"] for e in events] == attention.flash_tiling_decisions()

@@ -91,6 +91,42 @@ def test_chosen_tiling_compiles_for_the_v5e(one_chip, shape, layout):
                     and re.search(r" (copy|transpose)\(", l)]
 
 
+@pytest.mark.parametrize("shape,layout,window", [
+    ((2, 32, 16384, 128), "bhsd", 2048),   # the Trinity-Mini cell's shard
+    ((8, 64, 4096), "hds", 1000),          # the S-minor pair, a window off
+                                           # the tile's multiple
+], ids=["trinity-16k-hd128", "s-minor-hd64"])
+def test_the_windowed_pair_compiles_for_the_v5e(one_chip, shape, layout,
+                                                window):
+    """The flash pair under a causal window (PR 66), forward and fused
+    backward: Mosaic takes the band's dynamic loop bounds on both sides of
+    the walk and the straddling tiles' mask of two conditions, at the target
+    tile; the event says how much of the triangle the call skips."""
+    if layout == "hds":
+        shape, layout = (1,) + shape, "bhds"
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, layout=layout,
+                                window=window, interpret=False)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = jax.jit(grads).lower(x, x, x).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    S, hd = shape[layout.index("s")], shape[layout.index("d")]
+    mine = {d["kernel"]: d for d in flash_tiling_decisions()
+            if (d["Sq"], d["hd"], d["window"]) == (S, hd, window)}
+    assert set(mine) == {"fwd", "bwd"}
+    for d in mine.values():
+        assert (d["block_q"], d["block_k"]) == (512, 512)
+        assert d["tiles_visited"] < d["tiles_causal"]
+    if S == 16384:      # five tiles a q tile from the fifth on: 150 of 528
+        assert {(d["tiles_visited"], d["tiles_causal"])
+                for d in mine.values()} == {(150, 528)}
+
+
 @pytest.mark.parametrize("S", [4096, 8192])
 def test_latent_attentions_widths_compile_for_the_v5e(one_chip, S):
     """The flash pair at the `deepseek-v2-lite-l5.dataset` shard — 16 heads,

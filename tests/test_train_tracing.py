@@ -45,6 +45,9 @@ LOWERINGS = {
     # the DeepSeek-V2 family's layer inside four hyper-connection streams a
     # lane tile wide: the kernel pairs of ops/hyper_connections.py (PR 58)
     "xing4": dict(remat=True, hc_sinkhorn_iters=3),
+    # a pattern whose kinds differ in attention: a causal window under RoPE,
+    # the whole triangle with no position (AFMoE / Trinity, PR 66)
+    "afmoe": dict(remat=True, attention_impl="pallas"),
     # a pattern of Gated DeltaNet and gated attention layers over experts
     # beside a gated shared one: the delta rule's kernel pair (PR 61)
     "qwen3": dict(remat=True, attention_impl="pallas"),
@@ -85,6 +88,9 @@ DELTA_KERNELS += (names.DELTA_CONV_NORM_FWD_KERNEL,
 # the sandwich norms and the exit gate's objective (Ouro, PR 64)
 OURO_OWN_SCOPES = (names.LN1_POST, names.LN2_POST, names.EXIT_GATE)
 DSV2_OWN_SCOPES += OURO_OWN_SCOPES      # (no other family's step has them)
+# attention by the layer's kind: a window, or every key (AFMoE, PR 66)
+AFMOE_OWN_SCOPES = (names.ATTN_WINDOW, names.ATTN_FULL)
+DSV2_OWN_SCOPES += AFMOE_OWN_SCOPES
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 MHC_KERNELS = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL,
                names.MHC_WRITE_FWD_KERNEL, names.MHC_WRITE_BWD_KERNEL)
@@ -127,7 +133,7 @@ _lowered = {}
 def _step(key):
     """(bundle, batch of 2) of the tiny train step `key` names, built anew."""
     from ray_tpu.models import (
-        deepseek_v2, gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h,
+        afmoe, deepseek_v2, gpt2, lfm2_moe, llama, minicpm_sala, nemotron_h,
         qwen3_next)
     from ray_tpu.train.train_step import (
         make_gpt2_train_step, make_train_step, synthetic_batch)
@@ -153,6 +159,9 @@ def _step(key):
     elif key == "ouro":
         cfg = llama.ouro_tiny(**LOWERINGS[key])
         bundle = make_train_step(llama, cfg)
+    elif key == "afmoe":
+        cfg = afmoe.afmoe_tiny(**LOWERINGS[key])
+        bundle = make_train_step(afmoe, cfg)
     else:
         cfg = gpt2.gpt2_tiny(**LOWERINGS[key])
         bundle = make_gpt2_train_step(cfg)
@@ -295,6 +304,60 @@ def test_scope_in_lowered_ouro_step(scope):
         assert _has_scope(op_names, f"{inside[scope]}/{scope}")
     if scope == names.EXIT_GATE:     # the objective's, not a block's
         assert not any(names.BLOCK in n and scope in n for n in op_names)
+
+
+@pytest.mark.parametrize("scope", AFMOE_OWN_SCOPES + (
+    names.GATED_ATTN_GATE, names.LN1_POST, names.LN2_POST, names.EMBED,
+    names.BLOCK, names.LN1, names.QKV, names.ATTN, names.PROJ, names.LN2,
+    names.MLP, names.MOE, names.MOE_ROUTED, names.MOE_DISPATCH,
+    names.MOE_SHARED, names.LN_F, names.LM_HEAD_LOSS, names.OPTIMIZER,
+    names.FLASH_ATTENTION))
+def test_scope_in_lowered_afmoe_step(scope):
+    """All three kinds of layer carry the block's scopes; attention stands
+    under its KIND's scope inside `attn` — the flash kernels and the output
+    gate inside that —, each output norm under a scope of its own (the
+    attention's inside `proj`), the shared expert under `mlp` inside `moe`."""
+    op_names, _ = _lowering("afmoe")
+    assert _has_scope(op_names, scope), f"no op_name carries {scope!r}"
+    inside = {names.ATTN_WINDOW: f"{names.BLOCK}/{names.ATTN}",
+              names.ATTN_FULL: f"{names.BLOCK}/{names.ATTN}",
+              names.LN1_POST: f"{names.BLOCK}/{names.PROJ}",
+              names.LN2_POST: names.BLOCK,
+              names.MOE_SHARED: f"{names.MOE}/{names.MLP}",
+              names.MOE: names.BLOCK}
+    if scope in inside:
+        assert _has_scope(op_names, f"{inside[scope]}/{scope}")
+    if scope in AFMOE_OWN_SCOPES:       # the kernels and the gate, by kind
+        for inner in (names.FLASH_ATTENTION, names.GATED_ATTN_GATE):
+            assert _has_scope(op_names, f"{names.ATTN}/{scope}/{inner}")
+
+
+def test_afmoe_step_records_its_pattern_and_its_windowed_calls():
+    """Tracing the step leaves the `model/layer_pattern` decision of `DWFWW`
+    (three kinds, the last two window layers one scan), a `model/remat_policy`
+    one over them, and `ops/flash_tiling` decisions that tell the window
+    layers' calls from the full layer's: `window` and how many tile pairs of
+    the triangle the call visits."""
+    from ray_tpu.models import afmoe, blocks
+    from ray_tpu.ops import attention
+
+    _lowering("afmoe")
+    cfg = afmoe.afmoe_tiny()
+    by = {d["pattern"]: d for d in blocks.layer_pattern_decisions()}
+    assert tuple(by["DWFWW"]) == names.LAYER_PATTERN_ARGS
+    assert by["DWFWW"]["applications"] == {"D": 1, "W": 3, "F": 1}
+    assert by["DWFWW"]["groups"] == ["D", "W", "F", "2 x scan(W)"]
+    assert any((d["n_layer"], d["batch"], d["seq"]) == (5, 2, cfg.seq_len)
+               for d in blocks.remat_policy_decisions())
+    mine = [d for d in attention.flash_tiling_decisions()
+            if (d["rows"], d["Sq"], d["hd"])
+            == (2 * cfg.n_head, cfg.seq_len, cfg.head_dim)]
+    assert {(d["kernel"], d["window"]) for d in mine} == {
+        (k, w) for k in ("fwd", "bwd") for w in (0, cfg.sliding_window)}
+    for d in mine:
+        assert tuple(d) == names.FLASH_TILING_ARGS
+        # one tile a row of 64: the band IS the triangle's one pair
+        assert d["tiles_visited"] == d["tiles_causal"] == 1
 
 
 @pytest.mark.parametrize("residual", LFM2_RESIDUALS)
@@ -507,7 +570,7 @@ def test_flash_tiling_decision_of_the_lowered_step(kernel, monkeypatch):
     # the S-minor one at gpt2_tiny's head width, as at GPT-2's 64
     # ... and, last, v's and o's width beside q's and k's `hd` (PR 55: equal
     # anywhere but in latent attention)
-    assert names.FLASH_TILING_ARGS[-2:] == ("layout", "hd_v")
+    assert names.FLASH_TILING_ARGS[8:10] == ("layout", "hd_v")
     assert mine[0]["hd_v"] == mine[0]["hd"] == cfg.head_dim
     assert attention.kernel_layout(192, 128) == attention.S_MINOR
     assert attention.kernel_layout(256, 128) == attention.HD_MINOR
@@ -515,8 +578,14 @@ def test_flash_tiling_decision_of_the_lowered_step(kernel, monkeypatch):
         == attention.S_MINOR == attention.kernel_layout(64)
     assert attention.kernel_layout(128) == attention.HD_MINOR
     # the EVA event keeps the arguments it had (its kernels have one layout)
-    assert names.EVA_TILING_ARGS == names.FLASH_TILING_ARGS[:-2] + (
+    assert names.EVA_TILING_ARGS == names.FLASH_TILING_ARGS[:8] + (
         "window", "chunk")
+    # ... and, since PR 66, the causal window (0: none: GPT-2's) and how much
+    # of the triangle's tile pairs the call visits (all of them without one)
+    assert names.FLASH_TILING_ARGS[10:] == ("window", "tiles_visited",
+                                            "tiles_causal")
+    assert mine[0]["window"] == 0
+    assert mine[0]["tiles_visited"] == mine[0]["tiles_causal"] > 0
 
 
 # ------------------------------------------------------------- profile_span
@@ -1249,7 +1318,8 @@ def test_counter_reader_against_the_recorded_session(metric):
                                   "lfm2-24b-a2b-l5.dataset",
                                   "deepseek-v2-lite-l5.dataset",
                                   "xing4.0-29b-a4b-l5.dataset",
-                                  "qwen3-next-80b-a3b-l4.dataset"]
+                                  "qwen3-next-80b-a3b-l4.dataset",
+                                  "trinity-mini-l5.dataset"]
     facts = _record_facts(events, expected["summary"])
     assert reader.read(facts) == pytest.approx(expected["metrics"][metric],
                                                rel=1e-12)
