@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import blocks, parts
-from ray_tpu.ops import moe
+from ray_tpu.ops import attention_pointwise, moe
 from ray_tpu.tracing import names as scopes
 
 KINDS = "DWF"
@@ -293,39 +293,74 @@ def decays(params):
 def attention_operator(u, p, cfg: AfmoeConfig, kind: str):
     """u [B, S, D] (normed) → the operator's output [B, S, D] float32, under
     its output norm: QK-norm, then RoPE on a window layer alone, the flash
-    pair over the kind's window, the sigmoid gate, the out-projection."""
+    pair over the kind's window, the sigmoid gate, the out-projection.
+
+    Every elementwise value between a projection and the kernel or product
+    that reads it is made once, in one pass, and is no operand fusion of a
+    product (PERF.md §6, PR 67). The five weights as cast are pinned
+    (parts.made_once: in a layer outside a scan the cast and a copy of the
+    float32 weight stood inside each first-forward product). Where the flash
+    pair runs at a head of whole lane tiles, QK-norm + RoPE and the gate are
+    the kernel pairs of ops/attention_pointwise.py, which read and write
+    ``[B, S, H · hd]`` where a plain product writes and reads it and
+    ``[B, H, S, hd]`` where the flash pair does: no tensor is re-ordered in
+    HBM on the way. Anywhere else (a narrower head, the XLA path) the plain
+    forms run as they did (barriers on u, on q's and k's cotangents and on
+    the gate's product were measured at this cell's shapes and gave nothing
+    or cost: a barrier pins a value, not the order XLA stores it in)."""
     layout = parts.head_layout(cfg.head_dim)
     heads = layout.replace("d", "k")                    # the einsums' names
     s_minor, width = heads[-1] == "s", heads.index("k")
     window = cfg.window(kind)
-    positions = jnp.arange(u.shape[1])
+    theta = None if window is None else cfg.rope_theta  # a full layer: NoPE
+    impl, interpret, _ = parts.attention_on_mesh(cfg.attention_impl)
+    kernels = impl == "pallas" and layout == "bhsd"
 
-    def projected(w):
-        return jnp.einsum(f"bsd,dhk->{heads}", u, w)
+    def by_head(name):     # (w: the five weights as cast, pinned below)
+        return jnp.einsum(f"bsd,dhk->{heads}", u, w[name])
 
-    def normed(w, g):
-        x = parts.head_rmsnorm(projected(w), g, cfg.rms_eps, width)
-        if window is None:          # a full layer: no positional signal
+    def flat(name):     # [B, S, H · hd]: where a plain product writes it
+        return jnp.einsum("bsd,dn->bsn", u, w[name].reshape(u.shape[2], -1))
+
+    def normed(name, gain):
+        if kernels:
+            return attention_pointwise.head_norm_rope(
+                flat(name), gain, w[name].shape[1], cfg.rms_eps, theta,
+                interpret=interpret)
+        x = parts.head_rmsnorm(by_head(name), gain, cfg.rms_eps, width)
+        if theta is None:
             return x
-        return parts.rope(x, positions, cfg.rope_theta, s_minor)
+        return parts.rope(x, jnp.arange(u.shape[1]), theta, s_minor)
 
     with jax.named_scope(scopes.QKV):
+        w = dict(zip(_ATTN_WEIGHTS, parts.made_once(
+            tuple(p[name] for name in _ATTN_WEIGHTS))))
         # named after the norm and the rotation: a kept q or k has both
-        q = checkpoint_name(normed(p["wq"], p["q_norm"]), scopes.RES_Q)
-        k = checkpoint_name(normed(p["wk"], p["k_norm"]), scopes.RES_K)
-        v = checkpoint_name(projected(p["wv"]), scopes.RES_V)
-        gate = checkpoint_name(projected(p["wg"]), scopes.RES_ATTN_GATE)
+        q = checkpoint_name(normed("wq", p["q_norm"]), scopes.RES_Q)
+        k = checkpoint_name(normed("wk", p["k_norm"]), scopes.RES_K)
+        v = checkpoint_name(by_head("wv"), scopes.RES_V)
+        gate = checkpoint_name(flat("wg") if kernels else by_head("wg"),
+                               scopes.RES_ATTN_GATE)
     with jax.named_scope(scopes.ATTN), jax.named_scope(
             scopes.ATTN_FULL if window is None else scopes.ATTN_WINDOW):
         o = parts.causal_attention(q, k, v, cfg.attention_impl, layout=layout,
                                    window=window, grouped_kv=True)
         with jax.named_scope(scopes.GATED_ATTN_GATE):
-            o = parts.made_once(
-                (o.astype(jnp.float32)
-                 * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(u.dtype))
+            if kernels:
+                o = attention_pointwise.sigmoid_gated(o, gate,
+                                                      interpret=interpret)
+            else:
+                o = parts.made_once(
+                    (o.astype(jnp.float32)
+                     * jax.nn.sigmoid(gate.astype(jnp.float32))
+                     ).astype(u.dtype))
     with jax.named_scope(scopes.PROJ):
-        y = jnp.einsum(f"{heads},hkd->bsd", o, p["wo"],
-                       preferred_element_type=jnp.float32)
+        if kernels:     # o [B, S, H · hd]: where a plain product reads it
+            y = jnp.einsum("bsn,nd->bsd", o, w["wo"].reshape(o.shape[2], -1),
+                           preferred_element_type=jnp.float32)
+        else:
+            y = jnp.einsum(f"{heads},hkd->bsd", o, w["wo"],
+                           preferred_element_type=jnp.float32)
         with jax.named_scope(scopes.LN1_POST):
             return parts.rmsnorm(y, p["attn_post_norm"], cfg.rms_eps)
 

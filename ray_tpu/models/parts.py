@@ -170,7 +170,33 @@ def made_once(x):
     every product that reads it, as that product's operand, and a
     [16384, 2048] x [2048, 4096] product with a norm's float32 arithmetic
     over several inputs in front of it takes 2.3-2.5 ms where the bare one
-    takes 1.45-1.7 (PERF.md section 6, PR 49)."""
+    takes 1.45-1.7 (PERF.md section 6, PR 49).
+
+    The rule a new model file follows, with its two measurements (PR 49 in
+    the MiniCPM-SALA cell, PR 67 in the Trinity cell; PERF.md section 6):
+
+    - a projection whose output goes through a norm or a rotation takes
+      ``cotangent_made_once``: the norm's backward is one pass in front of
+      the projection's two backward products (PR 49: d W 2.34 -> 1.71 ms)
+      — where XLA stores the product's output in the order the norm reads
+      it; where it re-orders on the way (PR 67, hd 128 in front of the
+      hd-minor flash pair) the seam gave nothing (+1.8 ms of 926);
+    - the weights ``cast_in_the_loop`` hands a layer are ``made_once`` before
+      the products that read them: in a layer that stands outside a scan the
+      cast, and a transposing copy of the float32 weight with it, is else an
+      operand fusion of the first forward's product (PR 67: a [32768, 2048]
+      x [2048, 4096] product 7.7 ms with it, 2.9 bare, 2.8 its arithmetic;
+      the step 953.3 -> 925.8 ms by that seam alone);
+    - a norm read by SEVERAL products is ``made_once`` where the trace says
+      its passes cost less than they take out of the products — they did in
+      PR 49's seams (one reader each), and did NOT for a [32768, 2048] ln1
+      in front of four readers (PR 67: the norm's own passes +5.7 ms a step
+      for 2.7 off the products): measure, do not assume;
+    - a value that XLA makes as a product's OUTPUT fusion in an order its
+      next reader does not take (a gate on the output of a kernel) gains
+      nothing from a barrier — the re-ordering copies stay, and cost more
+      alone (PR 67: 13.1 -> 29.4 ms): that pass is a kernel's
+      (ops/attention_pointwise.py)."""
     return lax.optimization_barrier(x)
 
 

@@ -187,6 +187,18 @@ DELTA_CONV_NORM_FWD_KERNEL = "delta_conv_norm_fwd"
 DELTA_CONV_NORM_BWD_KERNEL = "delta_conv_norm_bwd"
 DELTA_GATE_NORM_FWD_KERNEL = "delta_gate_norm_fwd"
 DELTA_GATE_NORM_BWD_KERNEL = "delta_gate_norm_bwd"
+# the gated attention operator's elementwise work on either side of its flash
+# pair (ops/attention_pointwise.py), a run of a row's tokens of a few heads:
+# QK-norm (+ RoPE on a window layer) of a q or k projection's output, read
+# [B, S, H · hd] where the product writes it and written [B, H, S, hd] for
+# the flash pair (backward: d x and d gain's partial sums), and o · sigmoid(
+# gate) the other way round (backward: d o and d gate). The first pair runs
+# under QKV, the second under GATED_ATTN_GATE; no name holds an older
+# kernel's, and none of those holds one of these
+HEAD_NORM_ROPE_FWD_KERNEL = "head_norm_rope_fwd"
+HEAD_NORM_ROPE_BWD_KERNEL = "head_norm_rope_bwd"
+ATTN_GATE_FWD_KERNEL = "attn_gate_fwd"
+ATTN_GATE_BWD_KERNEL = "attn_gate_bwd"
 KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            EVA_AGG_BWD_KERNEL, RAGGED_DOT_KERNEL, SSD_CHUNK_FWD_KERNEL,
            SSD_CHUNK_BWD_KERNEL, SPARSE_ATTN_FWD_KERNEL,
@@ -196,7 +208,9 @@ KERNELS = (FLASH_FWD_KERNEL, FLASH_BWD_KERNEL, EVA_AGG_FWD_KERNEL,
            GATED_DELTA_FWD_KERNEL, GATED_DELTA_SOLVE_KERNEL,
            GATED_DELTA_BWD_KERNEL, DELTA_CONV_NORM_FWD_KERNEL,
            DELTA_CONV_NORM_BWD_KERNEL, DELTA_GATE_NORM_FWD_KERNEL,
-           DELTA_GATE_NORM_BWD_KERNEL)
+           DELTA_GATE_NORM_BWD_KERNEL, HEAD_NORM_ROPE_FWD_KERNEL,
+           HEAD_NORM_ROPE_BWD_KERNEL, ATTN_GATE_FWD_KERNEL,
+           ATTN_GATE_BWD_KERNEL)
 # the tiling ops/attention.py chose for a kernel: one instant event per
 # distinct decision, at trace time, in the task-event buffer
 FLASH_TILING = "ops/flash_tiling"

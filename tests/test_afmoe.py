@@ -12,11 +12,13 @@ that with no window the GPT-2 and LFM2 tiny steps lower to the parent's
 text."""
 
 import ast
+import collections
 import hashlib
 import importlib
 import json
 import math
 import os
+import re
 import sys
 
 import jax
@@ -31,7 +33,8 @@ if ROOT not in sys.path:
 from benchmarks.families import afmoe as family  # noqa: E402
 from benchmarks.families import afmoe_reference as reference  # noqa: E402
 from ray_tpu.models import afmoe, blocks, gpt2, lfm2_moe, parts  # noqa: E402
-from ray_tpu.ops import attention, moe, ring_attention  # noqa: E402
+from ray_tpu.ops import (  # noqa: E402
+    attention, attention_pointwise, moe, ring_attention)
 from ray_tpu.tracing import names  # noqa: E402
 
 CELL = "trinity-mini-l5.dataset"
@@ -750,3 +753,283 @@ def test_the_tile_share_reader_reads_the_windowed_calls_alone(monkeypatch):
     assert reader.read({}) is None
     monkeypatch.setattr(session_timeline, "load_record", lambda: None)
     assert reader.read({}) is None
+
+
+# --------------------------------------------------------------------------- #
+# The attention operator's seams and its elementwise kernel pairs (PR 67)
+# --------------------------------------------------------------------------- #
+
+def _plain_operator(u, p, cfg, kind):
+    """``afmoe.attention_operator`` as a plain composition: parts'
+    QK-norm and RoPE, the XLA attention path over repeated heads, a plain
+    gate — no barrier, no kernel —, the heads where ``parts.head_layout``
+    has them (a sum's order follows the layout)."""
+    layout = parts.head_layout(cfg.head_dim)
+    heads = layout.replace("d", "k")
+    window = cfg.window(kind)
+    positions = jnp.arange(u.shape[1])
+
+    def projected(w):
+        return jnp.einsum(f"bsd,dhk->{heads}", u, w)
+
+    def normed(w, g):
+        x = parts.head_rmsnorm(projected(w), g, cfg.rms_eps,
+                               heads.index("k"))
+        return x if window is None else parts.rope(
+            x, positions, cfg.rope_theta, heads[-1] == "s")
+
+    q, k = normed(p["wq"], p["q_norm"]), normed(p["wk"], p["k_norm"])
+    o = parts.causal_attention(q, k, projected(p["wv"]), "xla", layout=layout,
+                               window=window)
+    o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+        projected(p["wg"]).astype(jnp.float32))).astype(u.dtype)
+    y = jnp.einsum(f"{heads},hkd->bsd", o, p["wo"],
+                   preferred_element_type=jnp.float32)
+    return parts.rmsnorm(y, p["attn_post_norm"], cfg.rms_eps)
+
+
+def _operator_inputs(cfg, kind):
+    """u and the layer's attention weights in the compute dtype (as
+    ``afmoe._layer`` hands them over), and a cotangent."""
+    p = _layer_of(_params(cfg), cfg, kind)
+    p = {**p, **{n: p[n].astype(cfg.dtype) for n in afmoe._ATTN_WEIGHTS}}
+    k = jax.random.split(jax.random.PRNGKey(11), 2)
+    u = jax.random.normal(k[0], (2, cfg.seq_len, cfg.d_model)).astype(cfg.dtype)
+    dy = jax.random.normal(k[1], (2, cfg.seq_len, cfg.d_model))
+    return u, p, dy
+
+
+def _value_and_vjp(fn, *args):
+    """(fn's value, its vjp of the LAST argument) at the arguments before."""
+    *primals, dy = args
+    y, vjp = jax.vjp(fn, *primals)
+    return (y,) + vjp(dy)
+
+
+def _value_and_grads(fn, u, p, cfg, kind, dy):
+    return _value_and_vjp(lambda u, p: fn(u, p, cfg, kind), u, p, dy)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["W", "F"], ids=["window", "full"])
+def test_the_seams_change_no_number(kind, dtype):
+    """The operator with its barriers (``parts.made_once`` on the weights as
+    cast and on the gated o; the path of a head narrower than a lane tile
+    and of any run off a TPU) against the plain composition: its value and
+    the gradients of a scalar of it with respect to u and every weight — in
+    float32 to the bit, in bf16 to one rounding of each value (XLA may keep
+    excess precision inside a fusion that a barrier cuts)."""
+    cfg = afmoe.afmoe_tiny(dtype=dtype, attention_impl="xla")
+    u, p, dy = _operator_inputs(cfg, kind)
+    got = jax.tree.leaves(jax.jit(lambda u, p: _value_and_grads(
+        afmoe.attention_operator, u, p, cfg, kind, dy))(u, p))
+    want = jax.tree.leaves(jax.jit(lambda u, p: _value_and_grads(
+        _plain_operator, u, p, cfg, kind, dy))(u, p))
+    assert len(got) == len(want) > 8
+    for a, b in zip(got, want):
+        a, b = (np.asarray(t, np.float32) for t in (a, b))
+        if dtype == jnp.float32:
+            assert np.array_equal(a, b)
+        else:
+            ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(b), 1e-30))) - 7)
+            assert np.all(np.abs(a - b) <= ulp + 1e-6 * np.abs(b).max())
+
+
+def _close(got, want, dtype):
+    """≤ 1e-6 of the tensor's largest value in float32; in bf16 one ulp of
+    EACH VALUE's binade besides (tests/test_qwen3_next.py's, for the
+    ``delta_pointwise`` pairs)."""
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    tol = 1e-6 * max(1.0, float(np.max(np.abs(want))))
+    if dtype == jnp.bfloat16:
+        tol = tol + 2.0 ** (np.floor(np.log2(np.maximum(
+            np.abs(want), 1e-30))) - 7)
+    return bool(np.all(np.abs(got - want) <= tol))
+
+
+def _rel(a, b):
+    a, b = (np.asarray(t, np.float32) for t in (a, b))
+    return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+
+def _plain_norm_rope(x, gain, heads, eps, theta):
+    B, S, C = x.shape
+    x = x.reshape(B, S, heads, C // heads).transpose(0, 2, 1, 3)
+    y = parts.head_rmsnorm(x, gain, eps, 3)
+    return y if theta is None else parts.rope(y, jnp.arange(S), theta)
+
+
+def _heads_first(t, heads):
+    B, S, C = t.shape
+    return t.reshape(B, S, heads, C // heads).transpose(0, 2, 1, 3)
+
+
+# S = 300 is 304 with the padding: one tile, or 19 of 16 tokens under a
+# target of 64; S = 512 is four runs of 128 tokens in ONE tile; two heads a
+# step at 256 tokens, one at 512 (a block stays under a MiB in float32)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("theta", [10_000.0, None], ids=["rope", "nope"])
+@pytest.mark.parametrize("S,target", [(300, 512), (300, 64), (512, 512)],
+                         ids=["one-padded-tile", "19-tiles", "4-runs"])
+def test_the_norm_rope_pair_is_the_plain_qk_norm_and_rope(S, target, theta,
+                                                          dtype, monkeypatch):
+    """``head_norm_rope_fwd`` / ``_bwd`` (interpreted here) at hd 128 against
+    ``parts.head_rmsnorm`` + ``parts.rope`` and ``jax.vjp`` of them: y — to
+    the bit of the dtype without the rotation, one rounding with it (a
+    multiply-add is fused or not) —, d x and d gain."""
+    monkeypatch.setattr(attention_pointwise, "_TARGET_TOKENS", target)
+    attention_pointwise._norm_rope_call.clear_cache()
+    B, H, hd = 2, 4, 128
+    k = jax.random.split(jax.random.PRNGKey(S), 3)
+    x = jax.random.normal(k[0], (B, S, H * hd), jnp.float32).astype(dtype)
+    dy = jax.random.normal(k[1], (B, H, S, hd), jnp.float32).astype(dtype)
+    gain = 1 + 0.2 * jax.random.normal(k[2], (hd,), jnp.float32)
+    y, vjp = jax.vjp(lambda x, g: attention_pointwise.head_norm_rope(
+        x, g, H, 1e-5, theta, interpret=True), x, gain)
+    dx, dg = vjp(dy)
+    attention_pointwise._norm_rope_call.clear_cache()
+    want_y, plain_vjp = jax.vjp(
+        lambda x, g: _plain_norm_rope(x, g, H, 1e-5, theta), x, gain)
+    want_dx, _ = plain_vjp(dy)
+    # the gradients against the float32 arithmetic on the same values too:
+    # the plain form in bf16 rounds d x three times on its way back, each to
+    # 8 bits of a value that the norm's backward then cancels, and the sum
+    # over every token that is d gain once
+    # (at the gain as the plain form multiplies by it: rounded to the dtype)
+    _, exact_vjp = jax.vjp(
+        lambda x, g: _plain_norm_rope(x, g, H, 1e-5, theta),
+        x.astype(jnp.float32), gain.astype(dtype).astype(jnp.float32))
+    exact_dx, want_dg = exact_vjp(dy.astype(jnp.float32))
+    assert (y.dtype, dx.dtype, dg.dtype) == (dtype, dtype, jnp.float32)
+    assert y.shape == (B, H, S, hd) and dx.shape == x.shape
+    if theta is None and dtype == jnp.bfloat16:
+        assert np.array_equal(np.asarray(y, np.float32),
+                              np.asarray(want_y, np.float32))
+    assert _close(y, want_y, dtype)
+    assert _close(dx, exact_dx, dtype)
+    assert _rel(dx, want_dx) < (1e-6 if dtype == jnp.float32 else 1e-2)
+    assert _rel(dg, want_dg) < (1e-6 if dtype == jnp.float32 else 2e-3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("S,target", [(300, 512), (300, 64), (512, 512)],
+                         ids=["one-padded-tile", "19-tiles", "4-runs"])
+def test_the_gate_pair_is_the_plain_sigmoid_gate(S, target, dtype,
+                                                 monkeypatch):
+    """``attn_gate_fwd`` / ``_bwd`` against o · sigmoid(z) in float32 rounded
+    once, and ``jax.vjp`` of it: y, d o and d z, each to the bit."""
+    monkeypatch.setattr(attention_pointwise, "_TARGET_TOKENS", target)
+    attention_pointwise._gate_call.clear_cache()
+    B, H, hd = 2, 4, 128
+    k = jax.random.split(jax.random.PRNGKey(S + 1), 3)
+    o = jax.random.normal(k[0], (B, H, S, hd), jnp.float32).astype(dtype)
+    z, dy = (jax.random.normal(key, (B, S, H * hd), jnp.float32).astype(dtype)
+             for key in k[1:])
+
+    def plain(o, z):
+        y = (o.astype(jnp.float32) * jax.nn.sigmoid(
+            _heads_first(z, H).astype(jnp.float32))).astype(o.dtype)
+        return y.transpose(0, 2, 1, 3).reshape(z.shape)
+
+    got = _value_and_vjp(lambda o, z: attention_pointwise.sigmoid_gated(
+        o, z, interpret=True), o, z, dy)
+    attention_pointwise._gate_call.clear_cache()
+    want = _value_and_vjp(plain, o, z, dy)
+    assert [t.dtype for t in got] == [dtype] * 3
+    assert [t.shape for t in got] == [z.shape, o.shape, z.shape]
+    assert all(_close(a, b, dtype) for a, b in zip(got, want))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["W", "F"], ids=["window", "full"])
+def test_the_operator_on_its_kernels_is_the_plain_composition(kind, dtype,
+                                                              tol):
+    """At a head of a whole lane tile on the Pallas path (interpreted here)
+    the operator runs the two kernel pairs beside the flash pair: value and
+    every gradient against the plain composition."""
+    cfg = afmoe.afmoe_tiny(dtype=dtype, attention_impl="pallas", head_dim=128)
+    u, p, dy = _operator_inputs(cfg, kind)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda u, p: _value_and_grads(
+            afmoe.attention_operator, u, p, cfg, kind, dy))(u, p)
+        want = jax.jit(lambda u, p: _value_and_grads(
+            _plain_operator, u, p, cfg, kind, dy))(u, p)
+    calls = _kernel_calls(jax.make_jaxpr(lambda u, p: _value_and_grads(
+        afmoe.attention_operator, u, p, cfg, kind, dy))(u, p).jaxpr)
+    assert [calls[n] for n in (
+        names.HEAD_NORM_ROPE_FWD_KERNEL, names.HEAD_NORM_ROPE_BWD_KERNEL,
+        names.ATTN_GATE_FWD_KERNEL, names.ATTN_GATE_BWD_KERNEL)] == [2, 2, 1, 1]
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        # (the plain form in bf16 rounds a gain's gradient, a sum over every
+        # token and head, to 8 bits; the kernel's partial sums are float32)
+        loose = getattr(path[-1], "key", "") in ("q_norm", "k_norm")
+        assert _rel(a, b) < tol * (4 if loose else 1), (path, _rel(a, b))
+
+
+def _kernel_calls(jaxpr, found=None):
+    """How often each Pallas kernel name stands in a jaxpr, sub-jaxprs
+    included."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, found)
+    return found
+
+
+def _barriers_by_scope(jaxpr, found=None):
+    """The ``optimization_barrier`` equations of a jaxpr, sub-jaxprs
+    included, counted by (the innermost of the program's scopes in their
+    name stack, how many values they hold)."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "optimization_barrier":
+            stack = re.split(r"[/()]", str(eqn.source_info.name_stack))
+            found[next((s for s in reversed(stack) if s in names.SCOPES), ""),
+                  len(eqn.invars)] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _barriers_by_scope(sub, found)
+    return found
+
+
+def test_a_dropped_seam_fails_a_test_and_not_a_cell():
+    """``afmoe_tiny``'s loss holds, a layer body, under ``qkv`` ONE barrier
+    on the five weights as cast; its gradient holds that one again (the
+    cotangents', and the recompute's). On the plain path the gated o keeps
+    its barrier under ``gated_attn_gate``; at a head of a whole lane tile on
+    the Pallas path QK-norm + RoPE and the gate are the kernel pairs — a
+    custom call fuses with nothing — and no other barrier stands. (None on
+    u, on q's and k's cotangents or on the gate's product: measured, they
+    gave nothing or cost; PERF.md §6, PR 67.)"""
+    def barriers(cfg):
+        params, (tokens, targets) = _params(cfg), _batch(cfg)
+        loss = lambda p: afmoe.loss_fn(p, tokens, targets, cfg)
+        return (_barriers_by_scope(jax.make_jaxpr(loss)(params).jaxpr),
+                _barriers_by_scope(jax.make_jaxpr(jax.grad(loss))(
+                    params).jaxpr))
+
+    # the pattern DWFWW is three layers unrolled and one scanned run of two
+    bodies, weights = 4, len(afmoe._ATTN_WEIGHTS)
+    fwd, both = barriers(afmoe.afmoe_tiny(attention_impl="xla"))
+    assert fwd[names.QKV, weights] == bodies
+    assert both[names.QKV, weights] >= 2 * bodies
+    assert fwd[names.GATED_ATTN_GATE, 1] == bodies
+    assert both[names.GATED_ATTN_GATE, 1] >= 2 * bodies
+    wide = afmoe.afmoe_tiny(attention_impl="pallas", head_dim=128)
+    fwd, both = barriers(wide)
+    assert fwd[names.QKV, weights] == bodies
+    assert both[names.QKV, weights] >= 2 * bodies
+    assert set(both) == {(names.QKV, weights)}
+    params, (tokens, targets) = _params(wide), _batch(wide)
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(
+        lambda p: afmoe.loss_fn(p, tokens, targets, wide)))(params).jaxpr)
+    assert all(calls[n] >= bodies for n in (
+        names.HEAD_NORM_ROPE_FWD_KERNEL, names.HEAD_NORM_ROPE_BWD_KERNEL,
+        names.ATTN_GATE_FWD_KERNEL, names.ATTN_GATE_BWD_KERNEL))

@@ -1239,3 +1239,61 @@ def test_the_gate_norm_kernels_compile_for_the_v5e_at_the_cells_shape(one_chip):
             if (d["rows"], d["S"], d["channels"], d["heads"]) == (B, S, C, heads)}
     assert set(mine) == {dp.GATE_NORM_FWD, dp.GATE_NORM_BWD}
     assert all(d["vmem_estimate"] <= VMEM_BUDGET_BYTES for d in mine.values())
+
+
+@pytest.mark.parametrize("heads,theta", [(32, 10_000.0), (4, 10_000.0),
+                                         (32, None)],
+                         ids=["q-window", "k-window", "q-full"])
+def test_the_norm_rope_kernels_compile_for_the_v5e_at_the_cells_shape(
+        one_chip, heads, theta):
+    """PR 67: ops/attention_pointwise's first pair at the Trinity cell's
+    shapes (q [2, 16,384, 32 · 128], k at 4 heads, bf16; with the rotation
+    and without), forward and backward: what interpret mode cannot show —
+    the lane roll, a head's dynamic lane window of a flat block, blocks whose
+    two sides lie in two orders — and that outside the two calls the
+    compiled op holds no float32 tensor of the whole shape and no copy of
+    one (the index maps re-order; nothing else does)."""
+    from ray_tpu.ops import attention_pointwise as ap
+
+    B, S, hd = 2, 16384, 128
+    x = jax.ShapeDtypeStruct((B, S, heads * hd), jnp.bfloat16, sharding=one_chip)
+    dy = jax.ShapeDtypeStruct((B, heads, S, hd), jnp.bfloat16, sharding=one_chip)
+    gain = jax.ShapeDtypeStruct((hd,), jnp.float32, sharding=one_chip)
+
+    def grads(x, gain, dy):
+        y, vjp = jax.vjp(lambda *a: ap._norm_rope(*a, heads, 1e-5, theta,
+                                                  False), x, gain)
+        return (y,) + vjp(dy)
+
+    hlo = jax.jit(grads).lower(x, gain, dy).compile().as_text()
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l and " = " in l]
+    assert sum("head_norm_rope_fwd" in l for l in calls) == 1
+    assert sum("head_norm_rope_bwd" in l for l in calls) == 1 and len(calls) == 2
+    whole = rf"\[{B},({S},{heads * hd}|{heads},{S},{hd})\]"
+    assert not re.findall("f32" + whole, hlo)
+    assert not [l for l in hlo.splitlines()
+                if re.search(r" = bf16" + whole + r"\S* copy\(", l)]
+
+
+def test_the_attention_gate_kernels_compile_for_the_v5e_at_the_cells_shape(
+        one_chip):
+    """PR 67: the second pair at the cell's shape (o [2, 32, 16,384, 128], the
+    gate's logits [2, 16,384, 4,096], bf16), forward and backward."""
+    from ray_tpu.ops import attention_pointwise as ap
+
+    B, H, S, hd = 2, 32, 16384, 128
+    o = jax.ShapeDtypeStruct((B, H, S, hd), jnp.bfloat16, sharding=one_chip)
+    z = jax.ShapeDtypeStruct((B, S, H * hd), jnp.bfloat16, sharding=one_chip)
+
+    def grads(o, z, dy):
+        y, vjp = jax.vjp(lambda *a: ap._gate(*a, False), o, z)
+        return (y,) + vjp(dy)
+
+    hlo = jax.jit(grads).lower(o, z, z).compile().as_text()
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l and " = " in l]
+    assert sum("attn_gate_fwd" in l for l in calls) == 1
+    assert sum("attn_gate_bwd" in l for l in calls) == 1 and len(calls) == 2
+    whole = rf"\[{B},({S},{H * hd}|{H},{S},{hd})\]"
+    assert not re.findall("f32" + whole, hlo)
+    assert not [l for l in hlo.splitlines()
+                if re.search(r" = bf16" + whole + r"\S* copy\(", l)]

@@ -48,6 +48,9 @@ LOWERINGS = {
     # a pattern whose kinds differ in attention: a causal window under RoPE,
     # the whole triangle with no position (AFMoE / Trinity, PR 66)
     "afmoe": dict(remat=True, attention_impl="pallas"),
+    # ... at a head of a whole lane tile: the operator's elementwise work is
+    # the kernel pairs of ops/attention_pointwise.py (PR 67)
+    "afmoe_wide_head": dict(remat=True, attention_impl="pallas", head_dim=128),
     # a pattern of Gated DeltaNet and gated attention layers over experts
     # beside a gated shared one: the delta rule's kernel pair (PR 61)
     "qwen3": dict(remat=True, attention_impl="pallas"),
@@ -91,6 +94,10 @@ DSV2_OWN_SCOPES += OURO_OWN_SCOPES      # (no other family's step has them)
 # attention by the layer's kind: a window, or every key (AFMoE, PR 66)
 AFMOE_OWN_SCOPES = (names.ATTN_WINDOW, names.ATTN_FULL)
 DSV2_OWN_SCOPES += AFMOE_OWN_SCOPES
+# ... and QK-norm + RoPE and the output gate round its flash pair (PR 67)
+AFMOE_KERNELS = (names.HEAD_NORM_ROPE_FWD_KERNEL,
+                 names.HEAD_NORM_ROPE_BWD_KERNEL, names.ATTN_GATE_FWD_KERNEL,
+                 names.ATTN_GATE_BWD_KERNEL)
 CONV_KERNELS = (names.CONV_GATE_FWD_KERNEL, names.CONV_GATE_BWD_KERNEL)
 MHC_KERNELS = (names.MHC_MIX_FWD_KERNEL, names.MHC_MIX_BWD_KERNEL,
                names.MHC_WRITE_FWD_KERNEL, names.MHC_WRITE_BWD_KERNEL)
@@ -159,7 +166,7 @@ def _step(key):
     elif key == "ouro":
         cfg = llama.ouro_tiny(**LOWERINGS[key])
         bundle = make_train_step(llama, cfg)
-    elif key == "afmoe":
+    elif key in ("afmoe", "afmoe_wide_head"):
         cfg = afmoe.afmoe_tiny(**LOWERINGS[key])
         bundle = make_train_step(afmoe, cfg)
     else:
@@ -476,6 +483,7 @@ def test_every_kernel_of_the_vocabulary_belongs_to_a_model():
     assert set(names.KERNELS) == set(FLASH_KERNELS + EVA_KERNELS + SSD_KERNELS
                                      + SPARSE_KERNELS + CONV_KERNELS
                                      + MHC_KERNELS + DELTA_KERNELS
+                                     + AFMOE_KERNELS
                                      + (names.RAGGED_DOT_KERNEL,))
 
 
@@ -519,7 +527,9 @@ def test_kernel_name_in_jaxpr(kernel):
                          "sala" if kernel in SPARSE_KERNELS else
                          "lfm2" if kernel in CONV_KERNELS else
                          "xing4" if kernel in MHC_KERNELS else
-                         "qwen3" if kernel in DELTA_KERNELS else "remat")
+                         "qwen3" if kernel in DELTA_KERNELS else
+                         "afmoe_wide_head" if kernel in AFMOE_KERNELS else
+                         "remat")
     assert f"name={kernel}" in jaxpr
 
 
