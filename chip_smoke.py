@@ -978,12 +978,16 @@ def session_story(trace: List[Dict[str, Any]]) -> Tuple[List[str], List[str]]:
     ``ray_tpu.timeline()`` returns after ``shutdown()``: the phases of the
     ``fit()`` attempt's trace in time order, the worker processes the raylet
     started and reaped, the driver's init and shutdown, and the train
-    worker's compiles of half a second or more (PR 35). Seconds on the
-    host's clock: set-up and teardown, not speed."""
+    worker's compiles of half a second or more (PR 35); the actor class's
+    load and the backend's bring-up among the phases, what each kill did, and
+    the record's account of itself, which must be there and read nothing
+    lost (PR 68). Seconds on the host's clock: set-up and teardown, not
+    speed."""
     from ray_tpu.tracing import names
 
     spans = [e for e in trace
-             if e.get("cat") in ("train", "data", "raylet", "driver")
+             if e.get("cat") in ("train", "data", "raylet", "driver",
+                                 "worker", "gcs")
              and e.get("ph") in ("X", "i")]
     by_name: Dict[str, List[Dict[str, Any]]] = {}
     for e in spans:
@@ -998,15 +1002,41 @@ def session_story(trace: List[Dict[str, Any]]) -> Tuple[List[str], List[str]]:
     lines = [f"fit() trace {trace_id}: attempt {fit['args'].get('attempt')}, "
              f"{fit.get('dur', 0.0) / 1e6:.2f} s; phases (start after fit() "
              "was called, seconds):"]
-    compiles = []
+    compiles, task_loads = [], []
     for e in spans:
         name = f"{e['cat']}/{e['name']}"
         if name == "train/compile":
             compiles.append(e)
+        elif name == "worker/load_class" and e["args"].get("kind") == "task":
+            task_loads.append(e.get("dur", 0.0) / 1e6)
         elif (name in names.SETUP_SPANS and e is not fit
               and e["args"].get("trace_id") == trace_id):
+            said = ""
+            if name == "worker/load_class":
+                said = (f"  {e['args']['name']}: {e['args']['modules_imported']}"
+                        " modules imported")
+            elif name == "train/backend_init":
+                said = (f"  rank {e['args']['rank']}: {e['args']['devices']} x "
+                        f"{e['args']['platform']}")
+            elif name == "train/group_shutdown":
+                said = (f"  gone at return {e['args']['gone_at_return']} / "
+                        f"{e['args']['killed']} killed"
+                        + (f", errors {e['args']['kill_errors']}"
+                           if e["args"]["kill_errors"] else ""))
             lines.append(f"  {name:28s} +{(e['ts'] - t0) / 1e6:7.2f}  "
-                         f"{e.get('dur', 0.0) / 1e6:7.2f}")
+                         f"{e.get('dur', 0.0) / 1e6:7.2f}{said}")
+    if task_loads:
+        lines.append(f"  {'worker/load_class':28s} {len(task_loads)} x task: "
+                     f"{sum(task_loads):.2f} s in all")
+    for e in by_name.get("gcs/kill_actor", ()):
+        a = e["args"]
+        lines.append(f"  {'gcs/kill_actor':28s} +{(e['ts'] - t0) / 1e6:7.2f}  "
+                     f"{e.get('dur', 0.0) / 1e6:7.2f}  {a['class_name']}: "
+                     f"{a['outcome']}"
+                     + ("" if a["forwarded"] else
+                        f" (actor {a['state']}, node alive "
+                        f"{a['node_alive']}, address {a['had_address']})")
+                     + (f" {a['error']}" if a["error"] else ""))
     for name in ("raylet/worker_start", "raylet/worker_reap"):
         groups: Dict[str, List[float]] = {}
         for e in by_name.get(name, ()):
@@ -1021,6 +1051,29 @@ def session_story(trace: List[Dict[str, Any]]) -> Tuple[List[str], List[str]]:
     for name in ("driver/init", "driver/shutdown"):
         for e in by_name.get(name, ()):
             lines.append(f"  {name:28s} {e.get('dur', 0.0) / 1e6:.2f} s")
+    summaries = by_name.get("driver/record_summary", ())
+    if not summaries:
+        failures.append("the session's record holds no driver/record_summary: "
+                        "it cannot say what it lost")
+    for e in summaries:
+        a = e["args"]
+        lost = sum(r["lost"] for r in a["sources"]) + a["setup_evicted"]
+        lines.append(
+            f"  {'driver/record_summary':28s} lost {lost}; in flight at "
+            f"shutdown() {a['in_flight']}, unflushed set-up spans "
+            f"{a['unflushed_setup']}, last flush "
+            f"{a['flush_age_s'] or 0.0:.2f} s before, loop gone "
+            f"{a['window_s'] or 0.0:.2f} s after; evicted tasks "
+            f"{a['evicted_tasks']}, truncated {a['truncated_events']}, "
+            f"set-up spans evicted {a['setup_evicted']}")
+        for r in a["sources"]:
+            lines.append(f"    {r['source']:26s} recorded {r['recorded']}, "
+                         f"delivered {r['delivered']}, recovered "
+                         f"{r['recovered']}, dropped {r['dropped']}, lost "
+                         f"{r['lost']}")
+        if lost:
+            failures.append(f"the session's record lost {lost} events "
+                            "(driver/record_summary)")
     total = sum(e["args"]["seconds"] for e in compiles)
     slow = [e for e in compiles if e["args"]["seconds"] >= 0.5]
     lines.append(f"train/compile: {len(compiles)} backend compiles or cache "

@@ -27,7 +27,7 @@ from ray_tpu.core.core_worker import CoreWorker
 from ray_tpu.core.ids import ActorID
 from ray_tpu.core.options import RemoteOptions
 from ray_tpu.core.refs import ObjectRef
-from ray_tpu.tracing import names
+from ray_tpu.tracing import aggregator, names
 
 
 def _session_tmp_dir(session: str) -> str:
@@ -166,6 +166,29 @@ def _event_key(e: dict) -> tuple:
             e.get("worker"))
 
 
+def record_summary(rows: Dict[str, dict], account: dict,
+                   closing: dict) -> dict:
+    """``driver/record_summary``'s args (``tracing/names.py``) from the
+    sources' rows (source -> ``recorded`` / ``delivered`` / ``recovered`` /
+    ``dropped``; ``lost`` is made here), the aggregator's ``accounting()``
+    and what the driver noted of its own buffer as it closed the record."""
+    sources = []
+    for source, row in sorted(rows.items()):
+        row = {**row, "source": source, "lost": max(
+            row["dropped"],
+            row["recorded"] - row["delivered"] - row["recovered"])}
+        sources.append({k: row[k] for k in names.RECORD_SOURCE_ARGS})
+    return {
+        "sources": sources,
+        **{k: account.get(k, 0) for k in (
+            "evicted_tasks", "truncated_events", "setup_evicted")},
+        "in_flight": closing.get("in_flight", 0),
+        "unflushed_setup": closing.get("unflushed_setup", []),
+        "flush_age_s": closing.get("flush_age_s"),
+        "window_s": closing.get("window_s"),
+    }
+
+
 def _free_port() -> int:
     import socket
 
@@ -193,6 +216,9 @@ class ClusterBackend(Backend):
             self.core = core_worker
             return
         self._t_init = time.time()
+        # this process's event counts as the session begins: the record's
+        # own row is what happened since (`driver/record_summary`)
+        self._events_at_init = tracing.get_buffer().counts()
         session = f"s{uuid.uuid4().hex[:10]}"
         with tracing.named_span(names.DRIVER_INIT, {
                 "session": session, "started_cluster": address is None}):
@@ -329,7 +355,7 @@ class ClusterBackend(Backend):
         return out
 
     def kill_actor(self, actor_id, no_restart):
-        self.core.kill_actor(actor_id, no_restart)
+        return self.core.kill_actor(actor_id, no_restart)
 
     # ------------------------------------------------- fault-tolerance plane
     def actor_state(self, actor_id) -> str:
@@ -520,24 +546,40 @@ class ClusterBackend(Backend):
             # session and its record are not this process's to close
             self.core.shutdown()
             return
-        # the session's record outlives the session: what the aggregator
-        # holds is fetched before anything stops, what is recorded from here
-        # on (this driver's shutdown spans, the raylet's and the workers'
-        # last events in the WAL directory) is appended once every process
-        # is gone
+        # the session's record outlives the session. This driver's flush
+        # loop is stopped first — from here on nothing leaves its buffer but
+        # through _write_session_record, so no batch can be popped, sent or
+        # acknowledged after the fetch and be in neither copy —, then what
+        # the aggregator holds is fetched, before anything stops; what is
+        # recorded from here on (this driver's shutdown spans, the raylet's
+        # and the workers' last events in the WAL directory) is appended
+        # once every process is gone
         from ray_tpu.core.config import _config
 
         keep = _config.task_events_enabled
-        events = self._fetch_session_events() if keep else []
+        fetched, closing = {}, {}
+        if keep:
+            buf = tracing.get_buffer()
+            try:
+                self.core.io.run(self.core.stop_event_flush(), timeout=10)
+            except Exception:  # noqa: BLE001 - a loop already gone
+                pass
+            at_stop = buf.counts()
+            t_stop = time.time()
+            closing = {"in_flight": at_stop["in_flight"],
+                       "flush_age_s": at_stop["flush_age_s"], "t_stop": t_stop}
+            fetched = self._fetch_session_record()
         try:
             with tracing.named_span(names.DRIVER_SHUTDOWN,
                                     {"session": self.core.session}):
                 try:
                     self.core.shutdown()
+                    if keep:
+                        closing["window_s"] = time.time() - t_stop
                 finally:
                     self._procs.shutdown()
             if keep:
-                self._write_session_record(events)
+                self._write_session_record(fetched, closing)
         finally:
             # reclaim tmpfs (real RAM): this driver owns the session
             try:
@@ -547,31 +589,62 @@ class ClusterBackend(Backend):
             except Exception:  # noqa: BLE001
                 pass
 
-    def _fetch_session_events(self) -> List[dict]:
+    def _fetch_session_record(self) -> dict:
+        """The aggregator's events and its account of what it lacks."""
         try:
             return self.core.io.run(self.core.gcs.call(
-                "timeline_events", limit=10 ** 9, timeout=30))
+                "close_session_record", timeout=30))
         except Exception:  # noqa: BLE001 - a dead GCS: the files still tell
-            return []
+            return {}
 
-    def _write_session_record(self, events: List[dict]) -> None:
+    def _write_session_record(self, fetched: dict, closing: dict) -> None:
         """``<session_dir>/timeline.json``: the Chrome trace of the whole
-        session — the aggregator's events, this process's still unflushed
-        ones and the files under the session's ``task_wal/`` — kept in
+        session — the aggregator's events, every event of this process no
+        aggregator acknowledged (a batch in flight when the flush loop was
+        stopped, and what is still in the buffer) and the files under the
+        session's ``task_wal/`` —, closed by ``driver/record_summary``, the
+        record's account of itself (``tracing/names.py``); kept in
         ``session_timeline`` for ``ray_tpu.timeline()`` after shutdown."""
         import glob
         import json
 
         from ray_tpu.core.object_store.shm_store import session_dir
 
-        late = [e for e in tracing.get_buffer().drain(10 ** 6)[0]
-                if e["ts"] >= self._t_init]
-        for path in sorted(glob.glob(os.path.join(
-                session_dir(self.core.session), "task_wal", "*.jsonl"))):
-            late.extend(tracing.read_wal(path))
+        events = fetched.get("events") or []
+        account = fetched.get("accounting") or {}
+        rows = {s: dict(r) for s, r in (account.get("sources") or {}).items()}
         # a worker's last flush may have delivered what its file still holds
         seen = {_event_key(e) for e in events}
-        events = events + [e for e in late if _event_key(e) not in seen]
+
+        def merge(late: List[dict]) -> int:
+            new = [e for e in late if _event_key(e) not in seen]
+            seen.update(_event_key(e) for e in new)
+            events.extend(new)
+            return len(new)
+
+        buf = tracing.get_buffer()
+        own, _ = buf.take_unacked()
+        t_stop = closing.get("t_stop", 0.0)
+        unflushed_setup = sorted({
+            f"{e.get('component')}/{e.get('name')}" for e in own
+            if e["ts"] < t_stop} & set(names.SETUP_SPANS))
+        merge([e for e in own if e["ts"] >= self._t_init])
+        mine = buf.counts(since=self._events_at_init)
+        rows[self.core.event_source] = {
+            "worker": buf.worker, "recorded": mine["recorded"],
+            "delivered": mine["delivered"], "dropped": mine["dropped"],
+            "recovered": len(own)}
+        for path in sorted(glob.glob(os.path.join(
+                session_dir(self.core.session), "task_wal", "*.jsonl"))):
+            late = tracing.read_wal(path)
+            if late:
+                aggregator.credit_recovered(
+                    rows, os.path.splitext(os.path.basename(path))[0],
+                    late[0].get("worker"), merge(late))
+        closing["unflushed_setup"] = unflushed_setup
+        tracing.record_named(names.DRIVER_RECORD_SUMMARY,
+                             record_summary(rows, account, closing))
+        events.extend(buf.take_unacked()[0])
         self.session_timeline = tracing.build_chrome_trace(events)
         try:
             with open(os.path.join(self._procs.session_dir, "timeline.json"),

@@ -346,8 +346,10 @@ class CoreWorker:
                 self._job_num = reply["job_id"]  # for idempotent re-register
                 self.job_id = f"{reply['job_id']:04x}"
             await self._subscribe_logs()
+        self._event_flush = self._hold_bg(
+            asyncio.ensure_future(self._flush_task_events_loop()))
         for loop_coro in (
-            self._flush_task_events_loop(), self._metrics_flush_loop(),
+            self._metrics_flush_loop(),
             self._gcs_watchdog(), self._lease_reaper_loop(),
             self._pin_renew_loop(),
         ):
@@ -1222,7 +1224,11 @@ class CoreWorker:
             self._packed_envs[key] = wire
         return wire
 
-    async def load_function(self, fn_id: bytes):
+    async def load_function(self, fn_id: bytes,
+                            info: Optional[dict] = None):
+        """The function or class registered under ``fn_id``, fetched from
+        the GCS and unpickled on first use; ``info`` (a dict) is then given
+        the blob's ``bytes``."""
         fn = self._fn_cache.get(fn_id)
         if fn is None:
             blob = None
@@ -1236,6 +1242,8 @@ class CoreWorker:
                     await asyncio.sleep(0.5)
             if blob is None:
                 raise exc.RayTpuError(f"function {fn_id.hex()} not in registry")
+            if info is not None:
+                info["bytes"] = len(blob)
             fn = cloudpickle.loads(blob)
             self._fn_cache[fn_id] = fn
         return fn
@@ -2104,11 +2112,25 @@ class CoreWorker:
             args=args,
         )
 
+    @property
+    def event_source(self) -> str:
+        """This process's name at the aggregator (its flush loop's)."""
+        return f"{self.mode}-{self.worker_id.hex()[:12]}"
+
     async def _flush_task_events_loop(self):
         await tracing.events.flush_task_events_loop(
-            self.events, lambda: self.gcs,
-            source=f"{self.mode}-{self.worker_id.hex()[:12]}",
+            self.events, lambda: self.gcs, source=self.event_source,
         )
+
+    async def stop_event_flush(self) -> None:
+        """Stop this process's flush loop for good: whoever closes the
+        session's record (the driver's ``shutdown()``) does it BEFORE it
+        fetches the aggregator's events, so that no batch is popped — or
+        acknowledged — after the fetch and before this process's loop is
+        gone. A batch in flight at the cancel stays the buffer's in-flight
+        batch (``TaskEventBuffer.take_unacked``)."""
+        self._event_flush.cancel()
+        await asyncio.gather(self._event_flush, return_exceptions=True)
 
     # ----------------------------------------------- distributed refcounting
     # Owner-based (reference_count.h:61): the submitting/putting process owns
@@ -2702,8 +2724,9 @@ class CoreWorker:
         coro = self.gcs.call(
             "kill_actor", actor_id=actor_id.binary(), no_restart=no_restart
         )
+        outcome = None
         if wait:
-            self.io.run(coro)
+            outcome = self.io.run(coro)    # names.GCS_KILL_ACTOR's outcome
         else:
             async def fire(c=coro):
                 try:
@@ -2713,6 +2736,7 @@ class CoreWorker:
 
             self.io.spawn(fire())
         self._actor_addr_cache.pop(actor_id.binary(), None)
+        return outcome
 
     def get_named_actor(self, name: str, namespace: Optional[str]) -> ActorID:
         info = self.io.run(

@@ -28,10 +28,12 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from ray_tpu import tracing
 from ray_tpu.core import rpc
 from ray_tpu.core.config import _config
 from ray_tpu.core.resources import ResourceSet
 from ray_tpu.core.scheduling_policy import NodeView, hybrid_policy, pack_bundles
+from ray_tpu.tracing import names
 
 logger = logging.getLogger(__name__)
 
@@ -105,6 +107,16 @@ class ActorInfo:
         }
 
 
+def _class_name(info: Optional[ActorInfo]) -> Optional[str]:
+    """The actor's class, from its creation spec's name (``<Class>.__init__``)."""
+    if info is None:
+        return None
+    try:
+        return pickle.loads(info.spec_blob).name.rpartition(".")[0]
+    except Exception:  # noqa: BLE001 - a spec this process cannot unpickle
+        return None
+
+
 @dataclass
 class PlacementGroupInfo:
     pg_id: bytes
@@ -144,6 +156,7 @@ class GcsServer:
         from ray_tpu.tracing import TaskEventAggregator
 
         self.task_events = TaskEventAggregator()
+        self._record_closed = False   # handle_close_session_record
         self.metrics: Dict[str, int] = {}
         # metrics plane: {source: (ts, [series snapshots])} flushed by every
         # process's registry (util/metrics.py); dashboard /metrics renders
@@ -1291,12 +1304,21 @@ class GcsServer:
 
     # ------------------------------------------------------- observability
     def handle_report_task_events(self, conn, events: List[dict],
-                                  dropped: int = 0, source: str = None):
+                                  dropped: int = 0, source: str = None,
+                                  recorded: int = 0, delivered: int = 0,
+                                  worker: str = None):
         """Workers/drivers/raylets flush buffered task state transitions
         here (task_event_buffer.h:193 → GcsTaskManager). ``dropped`` is the
         source's CUMULATIVE drop counter (bounded-buffer overflow + flush
-        failures), surfaced through metrics and get_task."""
-        self.task_events.ingest(events, dropped=dropped, source=source)
+        failures), surfaced through metrics and get_task; ``recorded`` and
+        ``delivered`` its cumulative counts beside it and ``worker`` the
+        address its events carry — the source's row of the session's
+        record (``TaskEventAggregator.accounting``)."""
+        if self._record_closed:
+            raise RuntimeError("the session's record is closed")
+        self.task_events.ingest(events, dropped=dropped, source=source,
+                                recorded=recorded, delivered=delivered,
+                                worker=worker)
         for e in events:
             state = e.get("state", "UNKNOWN")
             if state == "PROFILE":
@@ -1360,6 +1382,18 @@ class GcsServer:
     def handle_timeline_events(self, conn, limit=50_000):
         """Flat event list backing ray_tpu.timeline()'s Chrome-trace export."""
         return self.task_events.timeline_events(limit)
+
+    def handle_close_session_record(self, conn):
+        """What the driver that closes the session keeps of it: every event
+        and the aggregator's account of what it lacks, in one reply. From
+        here on ``report_task_events`` is refused: a flush that would land
+        after this reply and be acknowledged would be in no copy — the
+        fetched one is made, its source's WAL shrinks on the ack — so it
+        fails instead, the source counts its batch as dropped and its WAL
+        keeps it for whoever closes the record to read."""
+        self._record_closed = True
+        return {"events": self.task_events.timeline_events(10 ** 9),
+                "accounting": self.task_events.accounting()}
 
     def handle_list_placement_groups(self, conn):
         return [
@@ -1462,24 +1496,60 @@ class GcsServer:
         return [a.public() for a in self.actors.values()]
 
     async def handle_kill_actor(self, conn, actor_id, no_restart=True):
+        """Kill an actor's process through its raylet. What this call knew
+        and what came of it is one ``gcs/kill_actor`` span (``tracing/
+        names.py``); the reply is that span's ``outcome`` — "reaped" where
+        the raylet confirmed the process gone —, False for an unknown actor."""
+        t0 = time.monotonic()
         info = self.actors.get(actor_id)
-        if info is None:
-            return False
-        if no_restart:
-            info.restarts_left = 0
-        node = self.nodes.get(info.node_id) if info.node_id else None
-        if node and node.alive and info.address:
-            try:
-                # the raylet replies once the worker process is gone
-                from ray_tpu.core.raylet.worker_pool import REAP_TIMEOUT_S
+        node = self.nodes.get(info.node_id) if info and info.node_id else None
+        span = {
+            "actor_id": actor_id.hex(), "class_name": _class_name(info),
+            "no_restart": no_restart,
+            "state": info.state if info else None,
+            "node_alive": bool(node and node.alive),
+            "had_address": bool(info and info.address),
+            "forwarded": False, "outcome": "unknown_actor", "error": None,
+        }
+        try:
+            if info is None:
+                return False
+            if no_restart:
+                info.restarts_left = 0
+            span["outcome"] = "not_forwarded"
+            if node and node.alive and info.address:
+                span["forwarded"] = True
+                try:
+                    # the raylet replies once the worker process is gone
+                    from ray_tpu.core.raylet.worker_pool import REAP_TIMEOUT_S
 
-                await node.conn.call("kill_actor_worker", actor_id=actor_id,
-                                     timeout=REAP_TIMEOUT_S + 5)
-            except (rpc.RpcError, rpc.ConnectionLost):
-                pass
-        if no_restart:
-            await self._mark_actor_dead(info, "killed via ray_tpu.kill")
-        return True
+                    found = await node.conn.call(
+                        "kill_actor_worker", actor_id=actor_id,
+                        timeout=REAP_TIMEOUT_S + 5)
+                    span["outcome"] = (names.KILL_REAPED if found
+                                       else "not_found")
+                except (rpc.RpcError, rpc.ConnectionLost) as e:
+                    # swallowed as ever: the actor is marked dead below
+                    span["outcome"] = ("connection_lost" if isinstance(
+                        e, rpc.ConnectionLost) else "rpc_error")
+                    span["error"] = tracing.events.error_text(e)
+            if no_restart:
+                await self._mark_actor_dead(info, "killed via ray_tpu.kill")
+            return span["outcome"]
+        finally:
+            seconds = time.monotonic() - t0
+            span["seconds"] = seconds
+            self._record_own(
+                names.GCS_KILL_ACTOR,
+                {k: span[k] for k in names.GCS_KILL_ACTOR_ARGS}, dur=seconds)
+
+    def _record_own(self, full_name: str, args: dict, dur: float) -> None:
+        """A span of this process: its events reach the aggregator it hosts
+        directly (this process runs no flush loop)."""
+        tracing.record_named(full_name, args, dur=dur)
+        events, _ = tracing.get_buffer().drain()
+        self.task_events.ingest(events, source="gcs")
+        tracing.get_buffer().wal_flushed()
 
     # --------------------------------------------------- placement groups
     async def handle_create_placement_group(

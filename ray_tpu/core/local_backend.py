@@ -334,6 +334,7 @@ class LocalBackend(Backend):
             events, dropped=max(0, dropped - self._drop_baseline),
             source="local",
         )
+        self._events.wal_flushed()     # the aggregator has the batch
         return self._aggregator
 
     # ------------------------------------------------------------------ utils

@@ -1344,7 +1344,7 @@ class Raylet:
     async def handle_kill_actor_worker(self, conn, actor_id):
         handle = self.pool.get_actor_worker(actor_id)
         if handle:
-            self.pool.kill_worker(handle)
+            self.pool.kill_worker(handle, cause=tracing.names.REAP_KILL_ACTOR)
             # kill_worker marks the handle DEAD, so poll_deaths never routes
             # this through _on_worker_death — release the actor's resources
             # here or the node permanently leaks them. Taken out before the
@@ -1698,7 +1698,8 @@ def main():
             if raylet.transfer:
                 raylet.transfer.stop()
             raylet.pool.shutdown()
-            # this raylet's last events (each worker's reap among them) by
+            # this raylet's last events (each worker's reap among them, and
+            # a batch its flush loop had popped and not yet sent) by
             # the file route of the workers' WALs: the GCS is going down
             # beside us, and the driver's shutdown() reads the directory
             from ray_tpu.core.object_store.shm_store import session_dir
@@ -1706,7 +1707,7 @@ def main():
             tracing.events.write_wal(
                 os.path.join(session_dir(raylet.session), "task_wal",
                              f"raylet-{raylet.node_id}.jsonl"),
-                tracing.get_buffer().drain(10 ** 6)[0])
+                tracing.get_buffer().take_unacked()[0])
             signal.signal(signal.SIGTERM, signal.SIG_DFL)
             os.kill(os.getpid(), signal.SIGTERM)
 

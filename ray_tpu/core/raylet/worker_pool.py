@@ -39,6 +39,7 @@ class WorkerHandle:
     started_at: float = field(default_factory=time.monotonic)
     platform: str = "cpu"            # worker_platform() of its lease
     killed_at: Optional[float] = None  # time.monotonic() of our signal
+    kill_cause: str = names.REAP_EXIT  # who sent it (kill_worker's cause)
 
 
 def worker_platform(demand: Optional[Dict[str, float]]) -> str:
@@ -149,8 +150,10 @@ class WorkerPool:
                     if asyncio.iscoroutine(res):
                         await res
 
-    def kill_worker(self, handle: WorkerHandle, force: bool = True):
+    def kill_worker(self, handle: WorkerHandle, force: bool = True,
+                    cause: str = names.REAP_EXIT):
         handle.killed_at = time.monotonic()
+        handle.kill_cause = cause      # `raylet/worker_reap`'s, names.py
         try:
             handle.proc.kill() if force else handle.proc.terminate()
         except ProcessLookupError:
@@ -193,13 +196,14 @@ class WorkerPool:
         seconds = time.monotonic() - t0
         tracing.record_named(names.RAYLET_WORKER_REAP, {
             "pid": handle.proc.pid, "platform": handle.platform,
-            "seconds": seconds, "timed_out": timed_out}, dur=seconds)
+            "seconds": seconds, "timed_out": timed_out,
+            "cause": handle.kill_cause}, dur=seconds)
 
     def shutdown(self):
         alive = [w for w in self.workers.values() if w.proc.poll() is None]
         for w in alive:
             if w.killed_at is None:
-                self.kill_worker(w)
+                self.kill_worker(w, cause=names.REAP_SIGTERM)
         # chip-less processes first: they are gone in milliseconds, and a
         # reap's seconds run until the process is SEEN gone — behind a
         # chip-holding one (seconds to die) they would read its time

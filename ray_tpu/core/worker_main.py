@@ -14,6 +14,7 @@ import concurrent.futures
 import contextlib
 import logging
 import os
+import sys
 import threading
 import time
 import traceback
@@ -278,6 +279,28 @@ class WorkerAgent(CoreWorker):
             deadline=getattr(spec, "deadline", None),
         )
 
+    def _load(self, spec: ts.TaskSpec, kind: str):
+        """This worker's first load of ``spec.fn_id`` — the GCS's blob
+        fetched and unpickled, with every import that pulls in — as the span
+        ``worker/load_class`` (``tracing/names.py``) of the calling task: an
+        actor's class always, a plain task's function where it took
+        ``PROFILE_MIN_DUR_S``."""
+        info: dict = {}
+        modules = len(sys.modules)
+        with tracing.named_span(
+                tracing.names.WORKER_LOAD_CLASS,
+                min_dur_s=0.0 if kind == "actor"
+                else tracing.PROFILE_MIN_DUR_S) as span:
+            t0 = time.perf_counter()
+            fn = self.io.run(self.load_function(spec.fn_id, info))
+            span.args = {
+                "fn_id": spec.fn_id.hex(),
+                "name": getattr(fn, "__qualname__", None) or spec.name,
+                "kind": kind, "bytes": info.get("bytes"),
+                "modules_imported": len(sys.modules) - modules,
+                "seconds": time.perf_counter() - t0}
+        return fn
+
     def _shed_if_expired(self, spec: ts.TaskSpec):
         """Pre-execution admission (overload protection): a spec whose
         request deadline already passed is failed typed WITHOUT running
@@ -319,7 +342,7 @@ class WorkerAgent(CoreWorker):
                 # thread hops, which dominate a short task's wall time
                 fn = self._fn_cache.get(spec.fn_id)
                 if fn is None:
-                    fn = self.io.run(self.load_function(spec.fn_id))
+                    fn = self._load(spec, "task")
                 args, kwargs = ts.decode_args(
                     spec.args, spec.kwargs,
                     lambda refs: self._get_args(spec, refs),
@@ -488,7 +511,7 @@ class WorkerAgent(CoreWorker):
             with self._task_ctx(spec):
                 fn = self._fn_cache.get(spec.fn_id)
                 if fn is None:
-                    fn = self.io.run(self.load_function(spec.fn_id))
+                    fn = self._load(spec, "task")
                 args, kwargs = ts.decode_args(
                     spec.args, spec.kwargs,
                     lambda refs: self._get_args(spec, refs),
@@ -727,7 +750,9 @@ class WorkerAgent(CoreWorker):
             if spec.runtime_env:
                 # actor workers are dedicated: the env applies for life
                 self._env_applier().apply(spec.runtime_env)
-            cls = self.io.run(self.load_function(spec.fn_id))
+            # under the creating call's task and trace, like the constructor
+            with self._task_ctx(spec):
+                cls = self._load(spec, "actor")
             args, kwargs = ts.decode_args(
                 spec.args, spec.kwargs, lambda refs: self.get(refs, None)
             )

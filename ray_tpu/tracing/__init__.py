@@ -46,20 +46,48 @@ Model
   and one ``train/compile`` a backend compile of the worker
   (``tracing/compiles.py``) share its ``trace_id``. ``Dataset.split`` records
   ``data/split`` and its three phases, the raylet ``raylet/worker_start`` and
-  ``raylet/worker_reap`` a worker process, the driver ``driver/init``,
-  ``driver/shutdown`` and one ``driver/wait_process`` a daemon.
-- The session's record outlives the session. ``shutdown()`` of the driver
-  that started the cluster fetches the aggregator's events before it stops
-  anything, appends what is recorded afterwards — its own shutdown spans,
-  and what the raylet and the workers left under the session's
+  ``raylet/worker_reap`` (with its ``cause``) a worker process, the driver
+  ``driver/init``, ``driver/shutdown`` and one ``driver/wait_process`` a
+  daemon. Two more components since PR 68: ``worker/load_class`` — a
+  worker's first load of a function id, the actor's class with every import
+  it pulls in, under the creating call's task and trace
+  (``core/worker_main.py``) — and ``gcs/kill_actor``, one span a
+  ``handle_kill_actor`` call with what the GCS knew and what came of it,
+  which the GCS hands straight to the aggregator it hosts. A train worker's
+  first JAX backend coming up is ``train/backend_init``, observed from
+  JAX's own two log lines and never called (``tracing/backend_init.py``).
+- No event is in neither place. The batch ``drain()`` pops stays with the
+  buffer as its IN-FLIGHT batch until the aggregator acknowledged it
+  (``wal_flushed()``) or the flush failed and it is counted
+  (``note_dropped()``: "never retried", as before). Whoever closes a
+  process's record takes the in-flight batch with the unflushed events
+  (``take_unacked()``): the driver's ``shutdown()``, the raylet's SIGTERM
+  handler into its file; a worker's WAL holds both already. The buffer
+  counts what became of every event — ``recorded`` = ``delivered`` +
+  ``dropped`` + ``taken`` + what it still holds — and its flush loop
+  reports ``recorded`` / ``delivered`` / ``dropped`` with each batch.
+- The session's record outlives the session and accounts for itself.
+  ``shutdown()`` of the driver that started the cluster STOPS ITS OWN FLUSH
+  LOOP, then fetches the aggregator's events and its ``accounting()`` before
+  it stops anything else — so no batch is popped, sent or acknowledged
+  between the fetch and this process's loop going away —, appends what no
+  aggregator acknowledged (a batch in flight at the stop, its own shutdown
+  spans) and what the raylet and the workers left under the session's
   ``task_wal/`` (the workers' WALs; the raylet writes its last events there
-  on SIGTERM) — and writes one Chrome trace,
+  on SIGTERM), closes with ONE ``driver/record_summary`` event — a row a
+  source (``recorded``, ``delivered``, ``recovered``, ``dropped``, ``lost``)
+  and the aggregator's ``evicted_tasks`` / ``truncated_events`` /
+  ``setup_evicted``: a reader can tell a record that lost events from a
+  program that recorded none — and writes one Chrome trace,
   ``/tmp/ray_tpu/<session>/timeline.json``. After ``shutdown()``,
   ``ray_tpu.timeline()`` returns that record instead of starting a cluster
-  to ask it (the local backend keeps its last record, and writes no file).
+  to ask it (the local backend keeps its last record, and writes no file
+  and no summary).
 - Retention (``tracing/aggregator.py``) evicts what is most numerous, not
   what is oldest: a job's set-up spans and the lifecycle of its set-up tasks
-  are still in the record after any number of later ``poll`` tasks.
+  are still in the record after any number of later ``poll`` tasks; a
+  task's events of ONE span name are a ring that keeps the newest
+  (``train/step_counters`` of a long run: its last 256 steps).
 
 Cheap by default: recording is a couple of dict writes behind one lock;
 ``task_events_enabled=False`` reduces it to a single attribute check, and

@@ -18,17 +18,29 @@ from the oldest:
   ``start_training`` and sixteen ``count_rows`` outlive its polls.
 - **Task records, all jobs** (``task_events_max_tasks``): oldest first — a
   job's own cap is lower, so one job never gets here alone.
-- **PROFILE events of one task** (``max_events_per_task``): counted a span
-  name; beyond it that name's newest are dropped (``truncated_events``). A
-  loop's ``train/compile`` and ``train/loop_done`` are not crowded out by
-  its ``data/get_block`` — nor by ``train/step_counters``, the one name that
-  arrives every step (PR 52): a long run keeps that name's first
-  ``max_events_per_task`` steps and counts the rest as truncated. Lifecycle
-  events are never truncated.
-- **Spans with no task** (the driver's, the raylet's): those named in
-  ``tracing/names.SETUP_SPANS`` — once an attempt, a split, a process or a
-  session — have a queue of their own (``max_setup_events``); every other
+- **PROFILE events of one task** (``max_events_per_task``): a ring a span
+  name — beyond the cap each arrival drops that name's OLDEST event of the
+  task (``truncated_events`` counts them). A loop's ``train/compile`` and
+  ``train/loop_done`` are not crowded out by its ``data/get_block`` — nor by
+  ``train/step_counters``, the one name that arrives every step (PR 52): a
+  run of 100,000 steps keeps that name's NEWEST ``max_events_per_task``
+  steps, the hour the question is about, and counts the rest as truncated.
+  Lifecycle events are no span names and are never truncated.
+- **Spans with no task** (the driver's, the raylet's, the GCS's): those named in
+  ``tracing/names.SETUP_SPANS`` — once an attempt, a split, a process, a kill
+  or a session — have a queue of their own (``max_setup_events``; what it
+  pushes out is counted, ``setup_evicted``); every other
   span shares ``max_profile_events``, oldest first.
+
+What the record lacks
+---------------------
+``accounting()`` is the aggregator's half of ``driver/record_summary``
+(``tracing/names.py``): a row a source with the cumulative ``recorded`` /
+``delivered`` / ``dropped`` its flush loop last reported and ``recovered`` —
+the events a WAL replay (a ``wal-`` source: the raylet's recovery of a dead
+worker's file) brought for it, matched by the events' ``worker`` —, and the
+three retention counters. A source whose last counts never came (a process
+killed with -9) stands there with what was last heard.
 
 ``timeline_events(limit)`` returns the newest ``limit`` events; the session
 record written at ``shutdown()`` asks for all of them.
@@ -106,6 +118,30 @@ def _observe_task_duration(rec: dict, e: dict) -> None:
             e2e_hist.observe(max(0.0, e["ts"] - sub) * 1000, tags)
 
 
+def source_row(rows: Dict[str, Dict[str, Any]], source: str) -> Dict[str, Any]:
+    """``source``'s row of the record's account, made where it is new."""
+    return rows.setdefault(source, {
+        "recorded": 0, "delivered": 0, "dropped": 0, "recovered": 0,
+        "worker": None})
+
+
+def credit_recovered(rows: Dict[str, Dict[str, Any]], source: str,
+                     worker: Optional[str], n: int) -> None:
+    """``n`` events came by a file (``source``: its name), not by a flush:
+    they are recovered for the row of that name (the raylet's file is named
+    as the raylet reports), else for the source whose events carry this
+    ``worker`` (a worker's WAL), else for a row of the file's own — a
+    process that never reported. The aggregator's rule for a WAL replay and
+    the closing driver's for the files it reads."""
+    row = rows.get(source) or next(
+        (r for r in rows.values()
+         if worker is not None and r.get("worker") == worker), None)
+    if row is None:
+        row = source_row(rows, source)
+        row["worker"] = worker
+    row["recovered"] += n
+
+
 class TaskEventAggregator:
     """Bounded store of per-task event timelines + free-floating spans."""
 
@@ -133,20 +169,21 @@ class TaskEventAggregator:
         # spans, ad-hoc profile spans, a process's bg/gc/core spans)
         self._setup: deque = deque(maxlen=max_setup_events)
         self._profile: deque = deque(maxlen=max_profile_events)
-        # drop accounting, surfaced as metrics
-        self._dropped_at_source: Dict[str, int] = {}  # source -> cumulative
+        # drop accounting, surfaced as metrics and in the session's record:
+        # source -> its cumulative recorded / delivered / dropped as last
+        # reported, the `worker` its events carry, and what WAL replays
+        # recovered for that worker (module docstring)
+        self._sources: Dict[str, Dict[str, Any]] = {}
         self.evicted_tasks = 0
         self.evicted_per_job: Dict[str, int] = {}
         self.truncated_events = 0
+        self.setup_evicted = 0
 
     # ------------------------------------------------------------- ingestion
     def ingest(self, events: List[dict], dropped: int = 0,
-               source: Optional[str] = None) -> None:
+               source: Optional[str] = None, recorded: int = 0,
+               delivered: int = 0, worker: Optional[str] = None) -> None:
         with self._lock:
-            if source is not None and dropped:
-                # sources report a cumulative counter; max() is idempotent
-                prev = self._dropped_at_source.get(source, 0)
-                self._dropped_at_source[source] = max(prev, int(dropped))
             # WAL recovery replays a dead worker's file; truncation races the
             # kill (flush delivered, worker died before wal_flushed), so a
             # replayed event may already be here. Per-process timestamps are
@@ -154,12 +191,26 @@ class TaskEventAggregator:
             # identity within one task — recovery is idempotent, duration
             # histograms never double-observe.
             dedup = source is not None and source.startswith("wal-")
+            replayed = 0
+            if source is not None and not dedup and (
+                    dropped or recorded or delivered):
+                # sources report cumulative counters; max() is idempotent
+                row = source_row(self._sources, source)
+                for k, v in (("dropped", dropped), ("recorded", recorded),
+                             ("delivered", delivered)):
+                    row[k] = max(row[k], int(v))
+                row["worker"] = worker or row["worker"]
             for e in events:
                 tid = e.get("task_id")
                 if tid is None:
+                    replayed += 1
                     once = f"{e.get('component')}/{e.get('name')}"
-                    (self._setup if once in names.SETUP_SPANS
-                     else self._profile).append(e)
+                    if once not in names.SETUP_SPANS:
+                        self._profile.append(e)
+                        continue
+                    if len(self._setup) == self._setup.maxlen:
+                        self.setup_evicted += 1
+                    self._setup.append(e)
                     continue
                 rec = self._tasks.get(tid)
                 if dedup and rec is not None:
@@ -170,6 +221,7 @@ class TaskEventAggregator:
                         for x in rec["events"]
                     ):
                         continue
+                replayed += 1
                 if rec is None:
                     rec = self._tasks[tid] = {
                         "task_id": tid,
@@ -203,9 +255,15 @@ class TaskEventAggregator:
                     counts = rec["profile_counts"]
                     span = e.get("name") or ""
                     if counts.get(span, 0) >= self._max_events_per_task:
+                        # the name's ring is full: its oldest makes room
                         self.truncated_events += 1
-                        continue
-                    counts[span] = counts.get(span, 0) + 1
+                        kept = rec["events"]
+                        del kept[next(
+                            i for i, x in enumerate(kept)
+                            if x.get("state") == ev.PROFILE
+                            and (x.get("name") or "") == span)]
+                    else:
+                        counts[span] = counts.get(span, 0) + 1
                 rec["events"].append(e)
                 # WAL replays never drive the duration histograms: the
                 # record-level dedup above can't see tasks already evicted
@@ -214,6 +272,20 @@ class TaskEventAggregator:
                 if not dedup and e.get("state") in (
                         ev.EXECUTED, ev.FINISHED, ev.FAILED):
                     _observe_task_duration(rec, e)
+            if dedup and replayed:
+                credit_recovered(self._sources, source,
+                                 events[0].get("worker"), replayed)
+
+    def accounting(self) -> dict:
+        """What the record lacks, as far as this aggregator knows (module
+        docstring): the rows of ``driver/record_summary`` and its counters."""
+        with self._lock:
+            return {
+                "sources": {s: dict(r) for s, r in self._sources.items()},
+                "evicted_tasks": self.evicted_tasks,
+                "truncated_events": self.truncated_events,
+                "setup_evicted": self.setup_evicted,
+            }
 
     def _index_job_locked(self, tid: str, rec: dict) -> None:
         """Record tid under its job and name and enforce the per-job cap:
@@ -264,10 +336,11 @@ class TaskEventAggregator:
                 ],
                 "profile": list(self._profile),
                 "setup": list(self._setup),
-                "dropped_at_source": dict(self._dropped_at_source),
+                "sources": {s: dict(r) for s, r in self._sources.items()},
                 "evicted_tasks": self.evicted_tasks,
                 "evicted_per_job": dict(self.evicted_per_job),
                 "truncated_events": self.truncated_events,
+                "setup_evicted": self.setup_evicted,
             }
 
     def restore(self, state: Optional[dict]) -> None:
@@ -287,12 +360,12 @@ class TaskEventAggregator:
             self._profile.extend(state.get("profile", ()))
             self._setup.clear()
             self._setup.extend(state.get("setup", ()))
-            self._dropped_at_source = dict(
-                state.get("dropped_at_source", {})
-            )
+            self._sources = {s: dict(r) for s, r in
+                             state.get("sources", {}).items()}
             self.evicted_tasks = state.get("evicted_tasks", 0)
             self.evicted_per_job = dict(state.get("evicted_per_job", {}))
             self.truncated_events = state.get("truncated_events", 0)
+            self.setup_evicted = state.get("setup_evicted", 0)
 
     # --------------------------------------------------------------- queries
     @staticmethod
@@ -324,7 +397,7 @@ class TaskEventAggregator:
             out["events"] = sorted(
                 rec["events"], key=lambda e: e.get("ts", 0)
             )
-            out["dropped_at_source"] = sum(self._dropped_at_source.values())
+            out["dropped_at_source"] = self._dropped_locked()
             return out
 
     def list_tasks(self, limit: int = 1000) -> List[dict]:
@@ -343,11 +416,14 @@ class TaskEventAggregator:
             return {
                 "tasks": by_name,
                 "total_tasks": len(self._tasks),
-                "dropped_at_source": sum(self._dropped_at_source.values()),
+                "dropped_at_source": self._dropped_locked(),
                 "evicted_tasks": self.evicted_tasks,
                 "evicted_per_job": dict(self.evicted_per_job),
                 "truncated_events": self.truncated_events,
             }
+
+    def _dropped_locked(self) -> int:
+        return sum(r["dropped"] for r in self._sources.values())
 
     def timeline_events(self, limit: int = 50_000) -> List[dict]:
         """Flat, time-sorted event list for Chrome-trace export."""
@@ -364,9 +440,7 @@ class TaskEventAggregator:
         with self._lock:
             return {
                 "task_events_tasks": len(self._tasks),
-                "task_events_dropped_at_source": sum(
-                    self._dropped_at_source.values()
-                ),
+                "task_events_dropped_at_source": self._dropped_locked(),
                 "task_events_evicted_tasks": self.evicted_tasks,
                 "task_events_truncated": self.truncated_events,
             }

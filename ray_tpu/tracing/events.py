@@ -199,7 +199,17 @@ class TaskEventBuffer:
         self._lock = _san.make_rlock("tracing.buffer")
         self._capacity = capacity or max(100, _config.task_events_buffer_size)
         self._events: deque = deque()
-        self._dropped = 0          # cumulative, this process
+        # what became of every event that passed the sampler, cumulative,
+        # this process: recorded = delivered + dropped + taken + what is
+        # still in _events and _in_flight — the buffer never loses count
+        self._recorded = 0         # offered (an overflow's drop included)
+        self._dropped = 0          # overflowed, or in a flush that failed
+        self._delivered = 0        # acknowledged by whoever drained them
+        self._taken = 0            # handed to whoever closed the record
+        # the batch drain() popped last, until wal_flushed() or note_dropped():
+        # in the buffer no longer and at the aggregator not yet
+        self._in_flight: List[dict] = []
+        self._last_drain = time.time()
         self._last_ts = 0.0
         # process identity defaults: events recorded without an explicit
         # node/worker (profile_span, serve/cgraph spans) are attributed to
@@ -251,14 +261,18 @@ class TaskEventBuffer:
             self._wal_fd = None
 
     def wal_flushed(self) -> None:
-        """The flush loop delivered a drain to the aggregator: shrink the
-        WAL to exactly the still-unflushed events. Empty buffer (the common
+        """The flush loop delivered a drain to the aggregator (its
+        acknowledgement is here): the in-flight batch is the aggregator's
+        now, and the WAL shrinks to exactly the still-unflushed events.
+        Empty buffer (the common
         case — a flush usually drains everything) truncates in place; a
         non-empty buffer REWRITES the file from the in-memory events (an
         atomic tmp+rename, re-opened for appends), so a busy worker's WAL
         never grows past one buffer and crash recovery never replays events
         the aggregator already has."""
         with self._lock:
+            self._delivered += len(self._in_flight)
+            self._in_flight = []
             if self._wal_fd is None:
                 return
             try:
@@ -324,6 +338,7 @@ class TaskEventBuffer:
         if job_id is None:
             job_id = current_job_id()
         with self._lock:
+            self._recorded += 1
             if len(self._events) >= self._capacity:
                 self._dropped += 1
                 return False
@@ -360,27 +375,75 @@ class TaskEventBuffer:
         )
 
     def note_dropped(self, n: int) -> None:
-        """Count events lost outside the buffer (e.g. a flush whose GCS call
-        failed after the drain)."""
+        """Count events lost outside the buffer: the in-flight batch of a
+        flush whose GCS call failed after the drain (never retried)."""
         with self._lock:
             self._dropped += n
+            self._in_flight = []
 
     # --------------------------------------------------------------- draining
     def drain(self, max_batch: int = 5000) -> Tuple[List[dict], int]:
         """Pop up to ``max_batch`` events plus the cumulative drop count.
         The drop count is CUMULATIVE (not a delta) so the aggregator can
-        take a max per source — idempotent under re-reports."""
+        take a max per source — idempotent under re-reports. The popped
+        batch stays with the buffer as its in-flight batch until
+        ``wal_flushed()`` (the aggregator has it) or ``note_dropped()`` (it
+        is lost, and counted): a batch still in flight when the record is
+        closed is ``take_unacked()``'s, with the events not yet popped."""
         out: List[dict] = []
         with self._lock:
+            # a batch nobody acknowledged (a drain outside a flush loop: a
+            # test's, a reader's) went where its caller took it
+            self._taken += len(self._in_flight)
             while self._events and len(out) < max_batch:
                 out.append(self._events.popleft())
+            self._in_flight = out
+            self._last_drain = time.time()
             dropped = self._dropped
         return out, dropped
+
+    def take_unacked(self) -> Tuple[List[dict], int]:
+        """Close the record: every event no aggregator has acknowledged —
+        the in-flight batch (it may have arrived all the same: whoever merges
+        drops duplicates), then the buffer — and how many of them were in
+        flight. They are counted as taken, not as delivered or dropped."""
+        with self._lock:
+            in_flight = len(self._in_flight)
+            out = self._in_flight + list(self._events)
+            self._in_flight = []
+            self._events.clear()
+            self._taken += len(out)
+        return out, in_flight
+
+    @property
+    def worker(self) -> Optional[str]:
+        """The ``worker`` this process's events carry (``set_identity``)."""
+        return self._worker
 
     @property
     def dropped(self) -> int:
         with self._lock:
             return self._dropped
+
+    def counts(self, since: Optional[dict] = None) -> Dict[str, Any]:
+        """This process's cumulative ``recorded`` / ``delivered`` /
+        ``dropped`` / ``taken``, with ``pending`` = what the buffer and its
+        in-flight batch hold now and ``flush_age_s`` since the last drain.
+        With ``since`` (an earlier ``counts()``: a flush loop's start, a
+        driver's ``init()``) the four are what happened after it — the buffer
+        is process-global and outlives clusters —, what was pending then
+        counted as recorded since."""
+        with self._lock:
+            out = {"recorded": self._recorded, "delivered": self._delivered,
+                   "dropped": self._dropped, "taken": self._taken,
+                   "pending": len(self._events) + len(self._in_flight),
+                   "in_flight": len(self._in_flight),
+                   "flush_age_s": time.time() - self._last_drain}
+        if since is not None:
+            for k in ("recorded", "delivered", "dropped", "taken"):
+                out[k] -= since[k]
+            out["recorded"] += since["pending"]
+        return out
 
     def __len__(self) -> int:
         with self._lock:
@@ -417,20 +480,28 @@ async def flush_task_events_loop(buf: TaskEventBuffer, get_conn,
     and a fresh GCS must not be told about overflow that happened before it
     existed — ``dropped_at_source`` means "dropped during this cluster's
     lifetime". The reported value stays cumulative and monotonic, so the
-    aggregator's per-source max() idempotence is unchanged."""
+    aggregator's per-source max() idempotence is unchanged. Beside it go
+    the source's ``recorded`` and ``delivered`` (this batch counted: the
+    aggregator that reads the numbers has it), from the same start: what
+    the session's record says of a source it last heard from here.
+
+    The drained batch is the buffer's in-flight batch from the drain to the
+    acknowledgement: a loop cancelled in between (``shutdown()`` of the
+    driver that closes the record) leaves it there for ``take_unacked()``."""
     import asyncio
 
     from ray_tpu.core import rpc
 
     period = max(_config.task_events_flush_interval_ms, 100) / 1000
-    baseline = buf.dropped
+    start = buf.counts()
     last_dropped = 0
     while True:
         await asyncio.sleep(period)
         with bg_span("task_event_flush") as tick:
-            events, raw_dropped = buf.drain()
+            events, _ = buf.drain()
             tick.args = {"events": len(events)}
-        dropped = max(0, raw_dropped - baseline)
+        now = buf.counts(since=start)
+        dropped = now["dropped"]
         if not events and dropped == last_dropped:
             continue
         conn = get_conn()
@@ -441,7 +512,9 @@ async def flush_task_events_loop(buf: TaskEventBuffer, get_conn,
         try:
             send = conn.notify if use_notify else conn.call
             await send("report_task_events", events=events, dropped=dropped,
-                       source=source)
+                       source=source, recorded=now["recorded"],
+                       delivered=now["delivered"] + len(events),
+                       worker=buf.worker)
             last_dropped = dropped
             # flushed events are aggregated: the crash-forensics WAL only
             # needs to keep the unflushed tail
@@ -567,12 +640,18 @@ class profile_span:
         return False
 
 
-def named_span(full_name: str, args: Optional[dict] = None) -> profile_span:
+def named_span(full_name: str, args: Optional[dict] = None,
+               min_dur_s: float = 0.0) -> profile_span:
     """A span under one of ``tracing/names.py``'s ``<component>/<name>``
     constants, always recorded (set-up and teardown: once an attempt, a
-    split or a session, never a step)."""
+    split or a session, never a step) unless ``min_dur_s`` says from when."""
     component, _, name = full_name.partition("/")
-    return profile_span(name, args, component=component)
+    return profile_span(name, args, component=component, min_dur_s=min_dur_s)
+
+
+def error_text(e: BaseException) -> str:
+    """An exception a span says it swallowed: type and message, cut short."""
+    return f"{type(e).__name__}: {e}"[:200]
 
 
 def record_named(full_name: str, args: Optional[dict] = None,

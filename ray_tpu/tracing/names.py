@@ -510,8 +510,14 @@ TRAIN_START_TRAINING = "train/start_training"
 TRAIN_START_TRAINING_ARGS = ("num_workers",)
 TRAIN_DRIVE = "train/drive"
 TRAIN_DRIVE_ARGS = ("reports", "error")
+# `WorkerGroup.shutdown`: the `ray_tpu.kill` calls it made, what they raised
+# (swallowed as before; "<Type>: <message>" cut to 200 characters, a list) and
+# the workers whose process the raylet had confirmed gone when the span
+# closed — the kills whose GCS_KILL_ACTOR outcome was "reaped". Fewer gone
+# than killed: `fit()` returned while a process still held its chips
 TRAIN_GROUP_SHUTDOWN = "train/group_shutdown"
-TRAIN_GROUP_SHUTDOWN_ARGS = ("num_workers",)
+TRAIN_GROUP_SHUTDOWN_ARGS = ("num_workers", "killed", "kill_errors",
+                             "gone_at_return")
 # instants on the loop's own thread: immediately before the user's function
 # is called, and when it has returned or raised
 TRAIN_LOOP_ENTERED = "train/loop_entered"
@@ -524,6 +530,12 @@ TRAIN_LOOP_DONE_ARGS = ("rank", "error")
 # events said so, else None. Also on the profiler's clock.
 TRAIN_COMPILE = "train/compile"
 TRAIN_COMPILE_ARGS = ("fun_name", "seconds", "cache")
+# the first initialisation of a JAX backend in a train worker's process, on
+# the thread that asked for it (the loop's, at the user's own first
+# `jax.devices()`): JAX's `xla_bridge._init_backend` from its first DEBUG
+# line to its last, observed (`tracing/backend_init.py`), never called
+TRAIN_BACKEND_INIT = "train/backend_init"
+TRAIN_BACKEND_INIT_ARGS = ("rank", "platform", "devices", "seconds")
 
 # `Dataset.split`: DATA_SPLIT holds the three others
 DATA_SPLIT = "data/split"
@@ -548,7 +560,36 @@ TASK_PENDING_ARGS_AVAIL = "PENDING_ARGS_AVAIL"
 RAYLET_WORKER_START = "raylet/worker_start"
 RAYLET_WORKER_START_ARGS = ("pid", "startup_token", "platform", "kind")
 RAYLET_WORKER_REAP = "raylet/worker_reap"
-RAYLET_WORKER_REAP_ARGS = ("pid", "platform", "seconds", "timed_out")
+RAYLET_WORKER_REAP_ARGS = ("pid", "platform", "seconds", "timed_out", "cause")
+# ... `cause`: who killed the process that is waited for — a `kill_actor`
+# (`handle_kill_actor_worker`), the raylet's own SIGTERM (`WorkerPool.
+# shutdown`: the driver's `shutdown()`), or "exit": it had been killed
+# before — its owner's exit (a pooled worker still leased to a driver that
+# disconnected), a chaos plan — and is only collected here
+REAP_KILL_ACTOR, REAP_SIGTERM, REAP_EXIT = "kill_actor", "sigterm", "exit"
+
+# a worker's first load of a function id — the GCS's blob fetched and
+# unpickled, with every import that pulls in (`TrainWorker`: `ray_tpu.train`
+# and JAX): `WorkerAgent._init_actor`'s, always (`kind` "actor"), and a plain
+# task's where it lasted PROFILE_MIN_DUR_S (`kind` "task"); under the
+# creating call's task and trace. `name` is the class's or function's
+# qualified name, `bytes` the blob's, `modules_imported` how many entries
+# `sys.modules` grew by
+WORKER_LOAD_CLASS = "worker/load_class"
+WORKER_LOAD_CLASS_ARGS = ("fn_id", "name", "kind", "bytes", "modules_imported",
+                          "seconds")
+
+# one `GcsServer.handle_kill_actor` call: what the GCS knew (the actor's
+# `state` in its table, `node_alive`, `had_address`), whether it asked the actor's raylet (`forwarded`) and what
+# came of it — "reaped": the raylet replied, the process is gone;
+# "not_found": it replied that it holds no such worker; "rpc_error" /
+# "connection_lost": the call raised (`error`: type and message, cut to 200
+# characters; swallowed as before); "not_forwarded"; "unknown_actor"
+GCS_KILL_ACTOR = "gcs/kill_actor"
+GCS_KILL_ACTOR_ARGS = ("actor_id", "class_name", "no_restart", "state",
+                       "node_alive", "had_address", "forwarded", "outcome",
+                       "seconds", "error")
+KILL_REAPED = "reaped"
 
 # the driver's `init()` and `shutdown()` (ClusterBackend), and inside the
 # latter one span a daemon process waited on
@@ -558,6 +599,26 @@ DRIVER_SHUTDOWN = "driver/shutdown"
 DRIVER_SHUTDOWN_ARGS = ("session",)
 DRIVER_WAIT_PROCESS = "driver/wait_process"
 DRIVER_WAIT_PROCESS_ARGS = ("name", "pid", "seconds", "killed")
+# the record's account of itself, the LAST event `shutdown()` appends: a row a
+# source (a process's buffer: `source`, its cumulative `recorded`, `delivered`
+# — acknowledged by the GCS — and `dropped` since its flush loop started, as
+# the GCS last heard them, the driver's own as they stand; `recovered` = what
+# whoever closed the record took from an in-flight batch, an unflushed buffer
+# or a WAL file; `lost` = max(dropped, recorded - delivered - recovered)),
+# the aggregator's `evicted_tasks`, `truncated_events` and `setup_evicted`,
+# and of the driver's own buffer when its flush loop was stopped: the events
+# `in_flight` (popped, not yet acknowledged), the set-up spans still
+# `unflushed_setup` (names), the seconds since its last drain (`flush_age_s`)
+# and from the stop to the core worker's loop gone (`window_s`) — a flush
+# would have fired in that window, and its batch been in neither place,
+# where flush_age_s + window_s reaches the flush period. A record without
+# this event lost its tail or comes from a tree that wrote none
+DRIVER_RECORD_SUMMARY = "driver/record_summary"
+DRIVER_RECORD_SUMMARY_ARGS = ("sources", "evicted_tasks", "truncated_events",
+                              "setup_evicted", "in_flight", "unflushed_setup",
+                              "flush_age_s", "window_s")
+RECORD_SOURCE_ARGS = ("source", "recorded", "delivered", "recovered",
+                      "dropped", "lost")
 
 SETUP_SPANS = {
     TRAIN_FIT: TRAIN_FIT_ARGS,
@@ -571,6 +632,7 @@ SETUP_SPANS = {
     TRAIN_LOOP_ENTERED: TRAIN_LOOP_ENTERED_ARGS,
     TRAIN_LOOP_DONE: TRAIN_LOOP_DONE_ARGS,
     TRAIN_COMPILE: TRAIN_COMPILE_ARGS,
+    TRAIN_BACKEND_INIT: TRAIN_BACKEND_INIT_ARGS,
     DATA_SPLIT: DATA_SPLIT_ARGS,
     DATA_MATERIALIZE: DATA_MATERIALIZE_ARGS,
     DATA_COUNT_ROWS: DATA_COUNT_ROWS_ARGS,
@@ -580,4 +642,7 @@ SETUP_SPANS = {
     DRIVER_INIT: DRIVER_INIT_ARGS,
     DRIVER_SHUTDOWN: DRIVER_SHUTDOWN_ARGS,
     DRIVER_WAIT_PROCESS: DRIVER_WAIT_PROCESS_ARGS,
+    DRIVER_RECORD_SUMMARY: DRIVER_RECORD_SUMMARY_ARGS,
+    WORKER_LOAD_CLASS: WORKER_LOAD_CLASS_ARGS,
+    GCS_KILL_ACTOR: GCS_KILL_ACTOR_ARGS,
 }

@@ -174,8 +174,8 @@ class DataParallelTrainer(BaseTrainer):
                 error = e
             return error, self._latest_group_checkpoint(group)
         finally:
-            with tracing.named_span(names.TRAIN_GROUP_SHUTDOWN, workers):
-                group.shutdown()
+            with tracing.named_span(names.TRAIN_GROUP_SHUTDOWN) as span:
+                span.args = group.shutdown()
 
     def _shard_datasets(self, num_workers: int) -> Dict[str, List[Any]]:
         """Row-balanced per-rank shards of every dataset passed to the

@@ -91,11 +91,13 @@ class TrainWorker:
             experiment_name=self.experiment_name,
         )
         self.session = _Session(ctx, latest_checkpoint, dataset_shards)
+        from ray_tpu.tracing.backend_init import record_backend_init
         from ray_tpu.tracing.compiles import record_compiles
         from ray_tpu.util.compile_cache import enable_compile_cache
 
         enable_compile_cache()
         record_compiles()
+        record_backend_init(self.rank)
 
         # the loop's own thread inherits this actor task's ids, so that the
         # spans of Data and Train under it attach to the task and its trace
@@ -256,12 +258,25 @@ class WorkerGroup:
             f"rendezvous failed after {attempts} port attempts"
         ) from last_err
 
-    def shutdown(self):
+    def shutdown(self) -> Dict[str, Any]:
+        """Kill every worker and free the placement group. Returns when it
+        always did; what the kills did is what it hands back —
+        ``train/group_shutdown``'s args (``tracing/names.py``): the calls
+        made, what they raised (swallowed as before) and the workers whose
+        process the raylet had confirmed gone when the call returned."""
+        from ray_tpu.api import _global_worker
+
+        backend = _global_worker().backend
+        outcomes, errors = [], []
         for w in self.workers:
             try:
-                ray_tpu.kill(w)
-            except Exception:  # noqa: BLE001
-                pass
+                # ray_tpu.kill(w), for its reply: gcs/kill_actor's outcome
+                outcomes.append(backend.kill_actor(w._actor_id, True))
+            except Exception as e:  # noqa: BLE001
+                errors.append(tracing.events.error_text(e))
+        told = {"num_workers": self.num_workers,
+                "killed": len(outcomes) + len(errors), "kill_errors": errors,
+                "gone_at_return": outcomes.count(names.KILL_REAPED)}
         if self.placement_group is not None:
             from ray_tpu.util.placement_group import remove_placement_group
 
@@ -269,3 +284,4 @@ class WorkerGroup:
                 remove_placement_group(self.placement_group)
             except Exception:  # noqa: BLE001
                 pass
+        return told

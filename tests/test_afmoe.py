@@ -693,7 +693,9 @@ def test_the_benchmark_gains_one_configuration_and_one_one_chip_cell():
                                   "config": "trinity-mini-l5",
                                   "traffic": "dataset", "chips": 1}
     assert len(b["workloads"]) == 12 and len(b["configs"]) == 11
-    assert [m["name"] for m in b["per_layer"]][-5:] == list(NEW_READERS)
+    per_layer = [m["name"] for m in b["per_layer"]]
+    first = per_layer.index(NEW_READERS[0])
+    assert per_layer[first:first + 5] == list(NEW_READERS)
     for name in SHARED_READERS:
         entry = next(m for m in b["per_layer"] if m["name"] == name)
         assert entry["workloads"][-1] == CELL, name

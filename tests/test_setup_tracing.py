@@ -102,14 +102,16 @@ ONCE = (names.TRAIN_FIT, names.TRAIN_WORKER_GROUP_START,
         names.TRAIN_DRIVE, names.TRAIN_GROUP_SHUTDOWN,
         names.TRAIN_LOOP_ENTERED, names.TRAIN_LOOP_DONE, names.DATA_SPLIT,
         names.DATA_MATERIALIZE, names.DATA_COUNT_ROWS, names.DATA_SLICE,
-        names.DRIVER_INIT, names.DRIVER_SHUTDOWN)
+        names.DRIVER_INIT, names.DRIVER_SHUTDOWN, names.TRAIN_BACKEND_INIT,
+        names.DRIVER_RECORD_SUMMARY)
 
 
 @pytest.mark.parametrize("name", ONCE)
 def test_each_phase_is_one_span_with_its_args(session, name):
     (span,) = _spans(session["trace"], name)
     assert _own_args(span, name) == set(names.SETUP_SPANS[name])
-    instant = name in (names.TRAIN_LOOP_ENTERED, names.TRAIN_LOOP_DONE)
+    instant = name in (names.TRAIN_LOOP_ENTERED, names.TRAIN_LOOP_DONE,
+                       names.DRIVER_RECORD_SUMMARY)
     assert span["ph"] == ("i" if instant else "X")
 
 
@@ -128,6 +130,10 @@ def test_one_trace_per_fit_from_the_call_to_the_loop(session):
         if name.startswith(("train/", "data/")):
             (span,) = _spans(trace, name)
             assert span["args"]["trace_id"] == trace_id, name
+    # ... and the load of the actor's class, before its constructor
+    (load,) = [e for e in _spans(trace, names.WORKER_LOAD_CLASS)
+               if e["args"]["kind"] == "actor"]
+    assert load["args"]["trace_id"] == trace_id
     # the tasks submitted under it carry the id: the actor's constructor,
     # the Dataset's tasks, start_training, every poll
     ran = {}
@@ -233,6 +239,346 @@ def test_poll_span_is_gone_and_the_poll_task_is_a_slice(session):
     assert not hasattr(names, "TRAIN_POLL")
 
 
+# ------------------------------ what set-up and teardown used to hide (PR 68)
+def test_actor_class_load_is_a_span_between_start_and_constructor(session):
+    trace = session["trace"]
+    (load,) = [e for e in _spans(trace, names.WORKER_LOAD_CLASS)
+               if e["args"]["kind"] == "actor"]
+    assert _own_args(load, names.WORKER_LOAD_CLASS) == set(
+        names.WORKER_LOAD_CLASS_ARGS)
+    args = load["args"]
+    assert args["name"] == "TrainWorker" and args["bytes"] > 0
+    # unpickling the class imports ray_tpu.train in the new process
+    assert args["modules_imported"] > 0
+    assert load["dur"] / 1e6 == pytest.approx(args["seconds"], abs=0.01)
+    # the creating call's task: the constructor's slice starts where it ends
+    (init,) = [e for e in trace if e["ph"] == "X"
+               and e["name"] == "TrainWorker.__init__"]
+    assert args["task_id"] == init["args"]["task_id"]
+    assert load["ts"] + load["dur"] <= init["ts"] + 1000.0
+    # on the worker's own row, after the raylet saw that process register
+    (entered,) = _spans(trace, names.TRAIN_LOOP_ENTERED)
+    assert (load["pid"], load["tid"]) == (entered["pid"], entered["tid"])
+    (start,) = [e for e in _spans(trace, names.RAYLET_WORKER_START)
+                if e["args"]["pid"] == entered["args"]["pid"]]
+    assert start["ts"] + start["dur"] <= load["ts"] + 1000.0
+    (group,) = _spans(trace, names.TRAIN_START_TRAINING)
+    assert load["ts"] + load["dur"] <= group["ts"] + group["dur"]
+    # a plain task's first load of a function is the same span, kind "task"
+    tasks = [e for e in _spans(trace, names.WORKER_LOAD_CLASS)
+             if e["args"]["kind"] == "task"]
+    assert tasks and all(e["dur"] >= tracing.PROFILE_MIN_DUR_S * 1e6
+                         for e in tasks)
+    assert {e["args"]["name"] for e in tasks} <= {
+        "_run_read_task", "_run_map_task", "block_num_rows", "_slice_block"}
+
+
+def test_backend_init_is_observed_on_the_loops_thread(session):
+    trace = session["trace"]
+    (init,) = _spans(trace, names.TRAIN_BACKEND_INIT)
+    (entered,) = _spans(trace, names.TRAIN_LOOP_ENTERED)
+    (done,) = _spans(trace, names.TRAIN_LOOP_DONE)
+    assert init["args"]["platform"] == "cpu" and init["args"]["rank"] == 0
+    assert init["args"]["devices"] >= 1
+    assert init["dur"] / 1e6 == pytest.approx(init["args"]["seconds"], abs=0.01)
+    # the loop's own first use of JAX: on its task, inside the loop
+    assert init["args"]["task_id"] == entered["args"]["task_id"]
+    assert entered["ts"] <= init["ts"] and init["ts"] + init["dur"] <= done["ts"]
+
+
+def test_a_kill_says_what_it_did(session):
+    trace = session["trace"]
+    kills = [e for e in _spans(trace, names.GCS_KILL_ACTOR)
+             if e["args"]["class_name"] == "TrainWorker"]
+    for e in kills:
+        assert _own_args(e, names.GCS_KILL_ACTOR) == set(
+            names.GCS_KILL_ACTOR_ARGS)
+    (group,) = _spans(trace, names.TRAIN_GROUP_SHUTDOWN)
+    first = kills[0]
+    assert first["args"]["outcome"] == names.KILL_REAPED
+    assert (first["args"]["forwarded"], first["args"]["node_alive"],
+            first["args"]["had_address"]) == (True, True, True)
+    assert group["ts"] <= first["ts"] and (
+        first["ts"] + first["dur"] <= group["ts"] + group["dur"] + 1000.0)
+    assert group["args"]["killed"] == group["args"]["gone_at_return"] == 1
+    assert group["args"]["kill_errors"] == []
+    # a later kill of the same, dead actor (its handle's release) finds no
+    # address to forward to, and says so
+    for e in kills[1:]:
+        assert e["args"]["outcome"] == "not_forwarded"
+        assert e["args"]["had_address"] is False
+    # and the reaps say who killed: the group's kill, then the raylet's own
+    # at its SIGTERM — or, of a pooled worker still leased to the driver
+    # when it disconnected, that owner's exit
+    (entered,) = _spans(trace, names.TRAIN_LOOP_ENTERED)
+    causes = {e["args"]["pid"]: e["args"]["cause"]
+              for e in _spans(trace, names.RAYLET_WORKER_REAP)}
+    assert causes.pop(entered["args"]["pid"]) == names.REAP_KILL_ACTOR
+    assert causes and set(causes.values()) <= {names.REAP_SIGTERM,
+                                               names.REAP_EXIT}
+
+
+def test_the_record_accounts_for_itself(session):
+    trace = session["trace"]
+    (summary,) = _spans(trace, names.DRIVER_RECORD_SUMMARY)
+    assert summary is max(trace, key=lambda e: e["ts"])     # the last event
+    args = summary["args"]
+    for row in args["sources"]:
+        assert tuple(row) == names.RECORD_SOURCE_ARGS
+        assert row["lost"] == 0 == row["dropped"], row
+    by_kind = {}
+    for row in args["sources"]:
+        by_kind.setdefault(row["source"].split("-")[0], []).append(row)
+    (driver,) = by_kind["driver"]
+    # the driver's shutdown spans at least never went through a flush
+    assert driver["recovered"] >= 3
+    assert driver["recorded"] == driver["delivered"] + driver["recovered"]
+    (raylet,) = by_kind["raylet"]
+    assert raylet["recovered"] > 0          # its file: the pooled reaps
+    assert by_kind["worker"]
+    assert (args["evicted_tasks"], args["truncated_events"],
+            args["setup_evicted"]) == (0, 0, 0)
+    assert names.TRAIN_FIT in args["unflushed_setup"] or args["in_flight"] == 0
+    assert args["flush_age_s"] >= 0 and args["window_s"] >= 0
+
+
+KILLS = {
+    # case: (node alive, the actor's address, what the raylet's call does)
+    "reaped": (True, "127.0.0.1:9", True),
+    "not_found": (True, "127.0.0.1:9", False),
+    "dead_node": (False, "127.0.0.1:9", None),
+    "no_address": (True, None, None),
+    "connection_lost": (True, "127.0.0.1:9", "ConnectionLost"),
+    "rpc_error": (True, "127.0.0.1:9", "RpcError"),
+    "unknown_actor": (False, None, None),      # no actor: no node known
+}
+
+
+@pytest.mark.parametrize("case", sorted(KILLS))
+def test_handle_kill_actor_records_its_branch(case):
+    """Driven directly: every branch of GcsServer.handle_kill_actor leaves
+    one gcs/kill_actor span in the aggregator the GCS hosts, with what it
+    knew and what came of it; what it swallows is in `error`; the reply is
+    the outcome (False for an actor it never heard of), and the actor is
+    marked dead whatever the raylet's call did, as ever."""
+    import asyncio
+    import pickle
+
+    from ray_tpu.core import rpc, task_spec as ts
+    from ray_tpu.core.gcs import server as gcs
+    from ray_tpu.core.ids import TaskID
+
+    alive, address, call = KILLS[case]
+    calls = []
+
+    class Conn:
+        async def call(self, method, **kw):
+            calls.append((method, kw["actor_id"]))
+            if isinstance(call, str):
+                raise getattr(rpc, call)("the raylet said " + "no " * 200)
+            return call
+
+    srv = gcs.GcsServer()
+    aid = b"\x07" * 16
+    spec = ts.TaskSpec(task_id=TaskID.from_random(), name="Victim.__init__",
+                       fn_id=b"f" * 16, args=[], kwargs={}, num_returns=0,
+                       resources={}, owner_addr="127.0.0.1:1")
+    if case != "unknown_actor":
+        srv.actors[aid] = gcs.ActorInfo(
+            actor_id=aid, spec_blob=pickle.dumps(spec), state=gcs.ALIVE,
+            address=address, node_id="n0")
+    srv.nodes["n0"] = gcs.NodeInfo("n0", "127.0.0.1:8", "s", conn=Conn(),
+                                   alive=alive)
+    reply = asyncio.run(srv.handle_kill_actor(None, aid))
+    (event,) = [e for e in srv.task_events.timeline_events()
+                if (e["component"], e["name"]) == ("gcs", "kill_actor")]
+    args = event["args"]
+    assert tuple(args) == names.GCS_KILL_ACTOR_ARGS
+    forwarded = case in ("reaped", "not_found", "connection_lost", "rpc_error")
+    outcome = case if forwarded or case == "unknown_actor" else "not_forwarded"
+    assert args["outcome"] == outcome and args["forwarded"] is forwarded
+    assert calls == ([("kill_actor_worker", aid)] if forwarded else [])
+    assert (args["node_alive"], args["had_address"]) == (alive, bool(address))
+    assert args["actor_id"] == aid.hex() and args["no_restart"] is True
+    assert args["state"] == (None if case == "unknown_actor" else gcs.ALIVE)
+    assert event["dur"] == args["seconds"] >= 0
+    if isinstance(call, str):
+        assert args["error"].startswith(f"{call}: the raylet said no")
+        assert len(args["error"]) == 200
+    else:
+        assert args["error"] is None
+    if case == "unknown_actor":
+        assert reply is False and args["class_name"] is None
+    else:
+        assert reply == outcome and args["class_name"] == "Victim"
+        assert srv.actors[aid].state == gcs.DEAD
+    # the GCS's events reach the aggregator it hosts directly
+    assert len(tracing.get_buffer()) == 0 or all(
+        e["component"] != "gcs" for e in tracing.get_buffer()._events)
+
+
+def test_gcs_refuses_events_once_the_record_is_closed():
+    """A flush that landed after the closing fetch and was acknowledged
+    would be in no copy (the fetched one is made, the source's WAL shrinks
+    on the ack): the GCS refuses it, so the source's WAL keeps the batch."""
+    from ray_tpu.core.gcs import server as gcs
+
+    srv = gcs.GcsServer()
+    event = {"task_id": "t", "name": "loop_done", "component": "train",
+             "state": "PROFILE", "ts": 1.0, "worker": "w:1"}
+    assert srv.handle_report_task_events(
+        None, [event], source="worker-a", recorded=1, delivered=1,
+        worker="w:1") is True
+    reply = srv.handle_close_session_record(None)
+    assert reply["events"] == [event]
+    assert reply["accounting"]["sources"]["worker-a"]["delivered"] == 1
+    with pytest.raises(RuntimeError, match="record is closed"):
+        srv.handle_report_task_events(None, [dict(event, ts=2.0)],
+                                      source="worker-a", recorded=2,
+                                      delivered=2, worker="w:1")
+    assert srv.task_events.timeline_events() == [event]
+    # the source's side of it: a refused flush counts its batch as dropped
+    # and does not shrink the WAL
+    buf = tracing.TaskEventBuffer(capacity=100)
+    buf.record(task_id="t", name="x", state="SUBMITTED")
+    batch, _ = buf.drain()
+    buf.note_dropped(len(batch))
+    assert buf.counts()["dropped"] == 1 and buf.counts()["in_flight"] == 0
+
+
+@pytest.mark.parametrize("raises", [False, True])
+def test_group_shutdown_says_what_its_kills_did(monkeypatch, raises):
+    """WorkerGroup.shutdown swallows what a kill raises, as before, and
+    hands back what it swallowed beside what the kills did."""
+    from ray_tpu import api
+    from ray_tpu.train.worker_group import WorkerGroup
+
+    class Handle:
+        def __init__(self, i):
+            self._actor_id = i
+
+    class Backend:
+        def kill_actor(self, actor_id, no_restart):
+            assert no_restart is True
+            if raises and actor_id == 1:
+                raise RuntimeError("gcs gone " + "x" * 300)
+            return (names.KILL_REAPED, "not_forwarded", None)[actor_id]
+
+    class Worker:
+        backend = Backend()
+
+    monkeypatch.setattr(api, "_global_worker", lambda: Worker)
+    group = WorkerGroup.__new__(WorkerGroup)
+    group.num_workers, group.placement_group = 3, None
+    group.workers = [Handle(0), Handle(1), Handle(2)]
+    told = group.shutdown()
+    assert tuple(told) == names.TRAIN_GROUP_SHUTDOWN_ARGS
+    assert (told["num_workers"], told["killed"], told["gone_at_return"]) == (
+        3, 3, 1)
+    if raises:
+        (error,) = told["kill_errors"]
+        assert error.startswith("RuntimeError: gcs gone x") and len(error) == 200
+    else:
+        assert told["kill_errors"] == []
+
+
+def test_a_flush_held_between_drain_and_ack_loses_nothing(monkeypatch):
+    """The parent's fault: flush_loop popped a batch, shutdown() fetched the
+    aggregator's events and later drained the buffer, and a batch between
+    the pop and the GCS's ingest was in neither — `train/fit`, closed a
+    moment before shutdown(), most often. Here the driver's flush is HELD
+    after its drain: the batch never reaches the GCS, and the record still
+    has it, says where it came from and counts nothing lost."""
+    import asyncio
+    import time
+
+    monkeypatch.setattr(_config, "task_events_flush_interval_ms", 100)
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=1, num_tpus=0)
+    try:
+        core = ray_tpu.api._global_worker().backend.core
+        buf = tracing.get_buffer()
+        real_call, held = core.gcs.call, []
+
+        async def call(method, *a, **kw):
+            if method != "report_task_events":
+                return await real_call(method, *a, **kw)
+            held.append(len(kw["events"]))
+            await asyncio.Event().wait()         # the ack never comes
+
+        # hold the flush loop at its sleep while the span is recorded, so
+        # that the very next drain pops it
+        deadline = time.monotonic() + 20
+        while len(buf) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        monkeypatch.setattr(core.gcs, "call", call)
+        with tracing.trace_context("trace-pr68"), tracing.named_span(
+                names.TRAIN_FIT, {"name": "held", "attempt": 0,
+                                  "num_workers": 0, "tpus_per_worker": 0}):
+            pass
+        while (len(buf) or not held) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert held and len(buf) == 0      # popped, sent, never acknowledged
+    finally:
+        ray_tpu.shutdown()
+    trace = ray_tpu.timeline()
+    (fit,) = [e for e in _spans(trace, names.TRAIN_FIT)
+              if e["args"]["trace_id"] == "trace-pr68"]
+    assert fit["args"]["name"] == "held"
+    (summary,) = _spans(trace, names.DRIVER_RECORD_SUMMARY)
+    args = summary["args"]
+    assert args["in_flight"] == held[0] >= 1
+    (driver,) = [r for r in args["sources"] if r["source"].startswith("driver-")]
+    assert driver["recovered"] > args["in_flight"] - 1 and driver["lost"] == 0
+    assert driver["recorded"] == driver["delivered"] + driver["recovered"]
+    assert sum(r["lost"] for r in args["sources"]) + args["setup_evicted"] == 0
+    from benchmarks.layer_metrics import session_record_lost_events
+
+    assert session_record_lost_events.read(_record_facts(trace)) == 0
+
+
+def _record_facts(trace):
+    from benchmarks.harness import session_record, session_timeline
+
+    return {"summary": {}, "notes": [],
+            "session_timeline": session_timeline.parse(trace),
+            "session_record": session_record.parse(trace) if trace else None}
+
+
+NEW_READERS = ("actor_class_load_s", "program_backend_init_s",
+               "session_record_lost_events")
+
+
+@pytest.mark.parametrize("metric", NEW_READERS)
+def test_new_reader_on_the_session_and_on_a_record_without_its_span(
+        session, metric):
+    import importlib
+
+    reader = importlib.import_module(f"benchmarks.layer_metrics.{metric}")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == metric)
+    assert (reader.UNIT, reader.LAYER, reader.MOVES, reader.SOURCE) == (
+        entry["unit"], entry["layer"], entry["moves"], entry["source"])
+    assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
+    trace = session["trace"]
+    got = reader.read(_record_facts(trace))
+    if metric == "session_record_lost_events":
+        assert got == 0
+    else:
+        name = {"actor_class_load_s": names.WORKER_LOAD_CLASS,
+                "program_backend_init_s": names.TRAIN_BACKEND_INIT}[metric]
+        spans = [e for e in _spans(trace, name)
+                 if e["args"].get("kind", "actor") == "actor"]
+        assert got == pytest.approx(spans[-1]["dur"] / 1e6, abs=1e-6) and got > 0
+    # the parent's record: every span of this PR taken out again
+    new = {names.WORKER_LOAD_CLASS, names.TRAIN_BACKEND_INIT,
+           names.GCS_KILL_ACTOR, names.DRIVER_RECORD_SUMMARY}
+    parent = [e for e in trace if f"{e.get('cat')}/{e['name']}" not in new]
+    assert reader.read(_record_facts(parent)) is None
+    assert reader.read(_record_facts([])) is None
+
+
 def test_chip_smoke_tells_the_sessions_story(session):
     import chip_smoke
 
@@ -245,8 +591,23 @@ def test_chip_smoke_tells_the_sessions_story(session):
                  names.RAYLET_WORKER_START, names.RAYLET_WORKER_REAP,
                  names.DRIVER_WAIT_PROCESS, names.DRIVER_SHUTDOWN):
         assert name in text, name
+    for name in (names.WORKER_LOAD_CLASS, names.TRAIN_BACKEND_INIT,
+                 names.GCS_KILL_ACTOR, names.DRIVER_RECORD_SUMMARY):
+        assert name in text, name
+    assert "TrainWorker: reaped" in text and "gone at return 1 / 1 killed" in text
+    assert "lost 0; in flight at shutdown()" in text
     assert lines[0].startswith("fit() trace ") and "attempt 0" in lines[0]
     assert lines[-1].startswith("train/compile: ")
+    # a record that lost events, or cannot say, is a failed smoke
+    lossy = json.loads(json.dumps(session["trace"], default=str))
+    (summary,) = _spans(lossy, names.DRIVER_RECORD_SUMMARY)
+    summary["args"]["sources"][0]["lost"] = 7
+    assert chip_smoke.session_story(lossy)[1] == [
+        "the session's record lost 7 events (driver/record_summary)"]
+    lossy.remove(summary)
+    assert chip_smoke.session_story(lossy)[1] == [
+        "the session's record holds no driver/record_summary: it cannot say "
+        "what it lost"]
     # a session that recorded nothing is a failed smoke
     assert chip_smoke.session_story([]) == ([], [
         "the session's record holds no train/fit",

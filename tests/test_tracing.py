@@ -31,6 +31,169 @@ def test_buffer_bounded_and_drop_counting():
     assert ts == sorted(ts) and len(set(ts)) == len(ts)
 
 
+# ------------------------------- the buffer never loses count (PR 68)
+@pytest.mark.parametrize("path", ["acked", "dropped", "closed_in_flight",
+                                  "overflowed"])
+def test_buffer_accounts_for_every_event(path):
+    """recorded = delivered + dropped + taken + pending, whichever way a
+    batch leaves: acknowledged, lost with its flush, or still in flight —
+    popped, not yet acknowledged — when the record is closed."""
+    from ray_tpu.tracing import TaskEventBuffer
+
+    buf = TaskEventBuffer(capacity=100)
+    n = 150 if path == "overflowed" else 40
+    for i in range(n):
+        buf.record(task_id=f"{i:032x}", name="t", state="SUBMITTED")
+    start = buf.counts()
+    assert (start["recorded"], start["pending"], start["in_flight"]) == (
+        n, min(n, 100), 0)
+    batch, _ = buf.drain(max_batch=30)
+    assert buf.counts()["in_flight"] == 30 and len(buf) == min(n, 100) - 30
+    if path == "acked":
+        buf.wal_flushed()
+    elif path == "dropped":
+        buf.note_dropped(len(batch))
+    # whoever closes the record takes the in-flight batch FIRST, then the
+    # rest: nothing popped is in neither place
+    taken, in_flight = buf.take_unacked()
+    c = buf.counts()
+    assert c["pending"] == 0 and c["in_flight"] == 0
+    if path in ("closed_in_flight", "overflowed"):
+        assert in_flight == 30 and taken[:30] == batch
+        assert len(taken) == min(n, 100)
+    else:
+        assert in_flight == 0 and len(taken) == 10
+    assert c["delivered"] == (30 if path == "acked" else 0)
+    assert c["dropped"] == {"dropped": 30, "overflowed": 50}.get(path, 0)
+    assert c["recorded"] == c["delivered"] + c["dropped"] + c["taken"] == n
+    # since an earlier counts(): what was pending then counts as recorded
+    for i in range(5):
+        buf.record(task_id=f"{i:032x}", name="u", state="SUBMITTED")
+    mid = buf.counts()
+    buf.record(task_id="f" * 32, name="u", state="SUBMITTED")
+    since = buf.counts(since=mid)
+    assert (since["recorded"], since["delivered"], since["dropped"],
+            since["taken"], since["pending"]) == (6, 0, 0, 0, 6)
+
+
+def test_an_overflowed_buffer_shows_in_the_summary_and_the_reader():
+    """dropped > 0 at a source is `lost` in driver/record_summary and reads
+    > 0 through session_record_lost_events; a sound row reads 0."""
+    import os
+    import sys
+
+    from ray_tpu.core.cluster_backend import record_summary
+    from ray_tpu.tracing import TaskEventBuffer, build_chrome_trace, names
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.layer_metrics import session_record_lost_events as reader
+    from benchmarks.harness import session_record
+
+    def lost(rows, account=None):
+        summary = record_summary(rows, account or {}, {})
+        assert tuple(summary) == names.DRIVER_RECORD_SUMMARY_ARGS
+        trace = build_chrome_trace([{
+            "name": "record_summary", "component": "driver", "ts": 5.0,
+            "state": "PROFILE", "args": summary}])
+        return summary, reader.read({
+            "session_record": session_record.parse(trace), "notes": []})
+
+    buf = TaskEventBuffer(capacity=100)
+    at_init = buf.counts()
+    for i in range(150):
+        buf.record(task_id=f"{i:032x}", name="t", state="SUBMITTED")
+    taken, _ = buf.take_unacked()
+    row = {"recovered": len(taken), **buf.counts(since=at_init)}
+    summary, read = lost({"driver-x": row})
+    assert summary["sources"][0]["dropped"] == 50 == summary["sources"][0]["lost"]
+    assert read == 50
+    sound = {"recorded": 9, "delivered": 7, "recovered": 2, "dropped": 0}
+    assert lost({"worker-y": sound})[1] == 0
+    # a source last heard with events pending and no file to recover them
+    # from (killed with -9), and set-up spans the aggregator pushed out
+    killed = {"recorded": 9, "delivered": 5, "recovered": 0, "dropped": 1}
+    summary, read = lost({"worker-y": sound, "worker-z": killed},
+                         {"setup_evicted": 3, "truncated_events": 11})
+    assert [r["lost"] for r in summary["sources"]] == [0, 4]
+    assert read == 7 and summary["truncated_events"] == 11
+
+
+@pytest.mark.parametrize("cap,n", [(256, 600), (5, 20), (8, 8)])
+def test_a_tasks_span_name_keeps_its_newest_events(cap, n):
+    """Past max_events_per_task a span name of one task is a ring: the
+    OLDEST goes (600 step_counters leave steps 345..600), lifecycle events
+    and the task's other span names stay, truncated_events counts."""
+    from ray_tpu.tracing import TaskEventAggregator
+
+    agg = TaskEventAggregator(max_tasks=10, max_events_per_task=cap)
+    agg.ingest([{"task_id": "t", "name": "start_training", "state": "RUNNING",
+                 "ts": 1.0},
+                {"task_id": "t", "name": "loop_entered", "state": "PROFILE",
+                 "ts": 1.5}])
+    for step in range(1, n + 1):       # one flush a few steps, as it arrives
+        agg.ingest([{"task_id": "t", "name": "step_counters",
+                     "state": "PROFILE", "ts": 2.0 + step * 1e-3,
+                     "args": {"step": step}}], source="worker-a")
+    agg.ingest([{"task_id": "t", "name": "loop_done", "state": "PROFILE",
+                 "ts": 9.0},
+                {"task_id": "t", "name": "start_training", "state": "EXECUTED",
+                 "ts": 9.5}])
+    events = agg.get_task("t")["events"]
+    steps = [e["args"]["step"] for e in events if e["name"] == "step_counters"]
+    assert steps == list(range(max(1, n - cap + 1), n + 1))
+    assert agg.truncated_events == max(0, n - cap)
+    assert [e["name"] for e in events if e["name"] != "step_counters"] == [
+        "start_training", "loop_entered", "loop_done", "start_training"]
+    assert agg.accounting()["truncated_events"] == max(0, n - cap)
+    # a restarted head keeps the ring where it was
+    again = TaskEventAggregator(max_tasks=10, max_events_per_task=cap)
+    again.restore(agg.dump())
+    again.ingest([{"task_id": "t", "name": "step_counters", "state": "PROFILE",
+                   "ts": 8.0, "args": {"step": n + 1}}])
+    steps = [e["args"]["step"] for e in again.get_task("t")["events"]
+             if e["name"] == "step_counters"]
+    assert steps[-1] == n + 1 and len(steps) == min(cap, n + 1)
+
+
+def test_aggregator_keeps_each_sources_last_counts_and_replays():
+    """accounting(): a row a source with what it last reported (max-merged:
+    a re-report changes nothing), a WAL replay counted as `recovered` for
+    the source whose events carry that worker, set-up evictions counted."""
+    from ray_tpu.tracing import TaskEventAggregator
+
+    agg = TaskEventAggregator(max_setup_events=2)
+
+    def ev(i, worker):
+        return {"task_id": "t", "name": "x", "state": "PROFILE",
+                "ts": 1.0 + i, "worker": worker}
+
+    agg.ingest([ev(0, "w:1"), ev(1, "w:1")], source="worker-a", recorded=5,
+               delivered=2, worker="w:1")
+    agg.ingest([ev(2, "w:1")], source="worker-a", recorded=6, delivered=3,
+               dropped=1, worker="w:1")
+    agg.ingest([], source="worker-a", recorded=5, delivered=2, worker="w:1")
+    # the raylet recovers the dead worker's file: one event is here already
+    agg.ingest([ev(2, "w:1"), ev(3, "w:1"), ev(4, "w:1")],
+               source="wal-n0-7")
+    # a file of a worker that never reported has a row of its own
+    agg.ingest([ev(9, "w:2")], source="wal-n0-8")
+    rows = agg.accounting()["sources"]
+    assert rows["worker-a"] == {"recorded": 6, "delivered": 3, "dropped": 1,
+                                "recovered": 2, "worker": "w:1"}
+    assert rows["wal-n0-8"]["recovered"] == 1 and "wal-n0-7" not in rows
+    assert agg.summarize()["dropped_at_source"] == 1
+    assert agg.stats()["task_events_dropped_at_source"] == 1
+    # the set-up queue counts what it pushes out
+    agg.ingest([{"name": "fit", "component": "train", "state": "PROFILE",
+                 "ts": 1.0 + i} for i in range(5)], source="driver-d")
+    assert agg.accounting()["setup_evicted"] == 3
+    again = TaskEventAggregator(max_setup_events=2)
+    again.restore(agg.dump())
+    assert again.accounting() == agg.accounting()
+
+
 def test_sampling_is_deterministic_per_trace():
     from ray_tpu.core.config import _config
     from ray_tpu.tracing import TaskEventBuffer
