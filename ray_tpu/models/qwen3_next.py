@@ -498,21 +498,51 @@ def kind_shards(cfg: Qwen3NextConfig, global_batch: int, seq: int, mesh
     """This config's layers on one chip of ``mesh``, for the remat rule: the
     model's shard (stream, head, rows at a time) and, a kind, how often it is
     applied, what a layer of it may keep, its weight gradients and what its
-    backward holds at once: the LARGER of two moments, as the DeepSeek
-    family's (no two overlap). Through both waits the cotangent of the
-    block's output.
+    backward holds at once — ``carried + max(waits + experts_set, own) −
+    absent``: the LARGER of two moments (no two overlap), beside the block's
+    carried cotangent (``carried``), less what the step's own terms count
+    there and the compiled step does not hold. Every term is a buffer of the
+    cell's step compiled for a v5e, read off the buffer assignment's live
+    ranges (``chiprun_out/pr69/``: ``parent.live.txt`` is XLA's own list at its
+    peak — moment 1 —, ``parent.moments.txt`` the bytes in use by program
+    point and the lists at both moments; PERF.md §6, PR 69).
 
-    - The expert half's backward. The whole block's forward has been made
-      again by then, so the mixer's residual set waits: its input and ``u``,
-      the delta mixer's fused projection, the conv's output, q, k, v as the
-      scan reads them, the solve's X (a value head's [C, C] a chunk: a
-      tenth of the states), the states a chunk starts from (float32 [d_k,
-      d_v] a value head and chunk: 8 × v's bytes at the published sizes), o
-      and the gated y — or attention's q (with its gate), k, v (each once
-      more by the group, as the kernel is handed them), o and lse. The
-      expert half holds its stream and the routing's tensors beside the
-      LARGER of the routed passes' set and the shared expert's.
-    - The mixer's own backward: its set and each tensor's gradient."""
+    1. The expert half's backward. Of the DeltaNet mixer's second forward the
+       in-projections and the conv kernels have run by then and their outputs
+       wait (``delta_waits``): the block's input, q‖k‖v‖z as projected, q, k,
+       v out of ``conv_silu_norm`` (ONE tensor each: the kernel convolves,
+       gates and normalises in one pass), the solve's X, the float32 [b, a]
+       and the weights' cast. The scan has NOT run: no state, no o, no y
+       waits. Of gated attention what its backward will read waits
+       (``attn_waits``): the block's input and u, q with its gate, k, v (each
+       once more by the group, as the kernel is handed them), o and lse, the
+       weights' cast. The expert half holds its stream and the routing's
+       tensors beside the LARGER of the routed passes' set and the shared
+       expert's (``experts_set``: parts.gated_experts_working_set,
+       parts.swiglu_price — the DeepSeek family's terms, 0.3 GiB over this
+       step's 1.54).
+    2. The mixer's own backward. The DeltaNet mixer's is largest while the
+       gate/norm kernel's backward runs, the compiled step's peak
+       (``delta_own``): the whole second forward stands — moment 1's set, the
+       states a chunk starts from (float32 [d_k, d_v] a value head and chunk:
+       8 × v's bytes at the published sizes), o and the gated y — beside the
+       three cotangents born so far, d y, d o, d z. A tensor's cotangent is
+       born when its consumer's backward runs and the residual dies there:
+       never a gradient for every tensor at once. Attention's
+       (``attn_own``): its set and the gradients of the stream, of q with its
+       gate, of k, v at the query heads and of o.
+
+    ``absent``: through a layer run's backward the step's terms
+    (blocks.backward_phases, the resident bytes) count, and the compiled
+    step does not hold, the embedding gathered beside an unreduced float32
+    gradient (on one chip nothing is gathered: _trunk indexes the cast
+    table), the embedding's gradient (made when the layers are done: 4 bytes
+    a number), and in float32 the gradients made BEFORE the run's that wait
+    for the optimizer in the compute dtype, 4 − a bytes a number over: the
+    head's and those of the layers that stand alone after the kind's runs
+    (a scan stacks its layers' in float32: nothing off for those). 0.68 GiB
+    for the cell's ``L``; what else those terms hold too long — a later
+    run's kept residuals, dead by then — is left on (PERF.md §7)."""
     a = jnp.dtype(cfg.dtype).itemsize
     D, H, hd = cfg.d_model, cfg.n_head, cfg.head_dim
     Hv, dk, dv = cfg.linear_value_heads, cfg.linear_key_dim, cfg.linear_value_dim
@@ -538,9 +568,10 @@ def kind_shards(cfg: Qwen3NextConfig, global_batch: int, seq: int, mesh
     chunks = -(-base.seq // chunk) * base.batch
     states = chunks * Hv * dk * dv * 4
     solved = chunks * Hv * chunk * chunk * a
+    ba = tokens * 2 * Hv * 4
     delta_kept = (
         C(scopes.RES_DELTA_PARTS, tokens * fused * a, 2 * tokens * D * fused),
-        C((scopes.RES_DELTA_BA,), tokens * 2 * Hv * 4, 2 * tokens * D * 2 * Hv),
+        C((scopes.RES_DELTA_BA,), ba, 2 * tokens * D * 2 * Hv),
         C((scopes.RES_DELTA_X,), solved, chunks * cfg.linear_key_heads
           * gated_delta.solve_flops(chunk, r, dk)),
         C((scopes.RES_DELTA_STATES, scopes.RES_DELTA_O),
@@ -548,10 +579,9 @@ def kind_shards(cfg: Qwen3NextConfig, global_batch: int, seq: int, mesh
           int(2 * tokens * (delta_scan_macs(cfg) - kw * chunk / 2))),
         mid)
     delta_params = D * fused + D * 2 * Hv + vw * D
-    delta_waits = (a * (tokens * (2 * D + fused + 2 * (2 * kw + vw) + 2 * vw)
-                        + delta_params) + states + solved)
-    delta_set = delta_waits + a * (tokens * (2 * D + fused + (2 * kw + vw)
-                                             + 2 * vw) + delta_params)
+    delta_waits = (a * (tokens * (D + fused + (2 * kw + vw)) + delta_params)
+                   + solved + ba)
+    delta_own = delta_waits + states + a * tokens * 5 * vw
 
     # gated attention: q, k, v, the gate and the kernel's two
     # (parts.remat_candidates prices q, k, v and o + lse)
@@ -563,7 +593,7 @@ def kind_shards(cfg: Qwen3NextConfig, global_batch: int, seq: int, mesh
     attn_params = D * 2 * width + 2 * D * kv_width + width * D
     attn_waits = (a * (tokens * (2 * D + 3 * width + 2 * kv_width + 2 * width)
                        + attn_params) + (tokens * H * 4 if flash else 0))
-    attn_set = attn_waits + a * (tokens * (2 * D + 5 * width) + attn_params)
+    attn_own = attn_waits + a * (tokens * (2 * D + 5 * width) + attn_params)
 
     # the expert half, as the DeepSeek family prices it
     shared_kept, shared_set = parts.swiglu_price(
@@ -578,13 +608,35 @@ def kind_shards(cfg: Qwen3NextConfig, global_batch: int, seq: int, mesh
 
     kinds = {}
     for kind in dict.fromkeys(cfg.pattern):
-        kept, waits, own = ((delta_kept, delta_waits, delta_set) if kind == "L"
-                            else (attn_kept, attn_waits, attn_set))
+        kept, waits, own = ((delta_kept, delta_waits, delta_own) if kind == "L"
+                            else (attn_kept, attn_waits, attn_own))
         kinds[kind] = blocks.KindShard(
             cfg.pattern.count(kind), kept + experts_kept,
             carried + max(waits + experts_set, own))
-    return base, blocks.with_grad_bytes(
+    kinds = blocks.with_grad_bytes(
         blocks.one_candidate_a_name(kinds), partial(_layer_init, cfg=cfg), mesh)
+    one_chip = mesh is None or mesh.devices.size == 1
+    table = base.vocab * D
+    # d lm_head in the compute dtype, no d wte yet, nothing gathered (what
+    # backward_phases adds: the table in the compute dtype and in float32)
+    absent = table * ((4 - a) + 4 + (a + 4 if one_chip else 0))
+    for kind, lone in _lone_after(cfg.pattern, kinds).items():
+        kinds[kind] = kinds[kind]._replace(block_bytes=max(
+            0, kinds[kind].block_bytes - absent - lone * (4 - a) // 4))
+    return base, kinds
+
+
+def _lone_after(pattern: str, kinds: Dict[str, blocks.KindShard]
+                ) -> Dict[str, int]:
+    """A kind of ``pattern`` → the float32 bytes of the weight gradients of
+    the layers that stand alone (in no scan) after the kind's runs — after
+    EVERY run that holds it: the least over them, so that what kind_shards
+    takes off is off in each."""
+    runs = blocks.pattern_groups(pattern)
+    after = [sum(kinds[k].grad_bytes for sub, reps in runs[i + 1:] if reps == 1
+                 for k in sub) for i in range(len(runs))]
+    return {kind: min(after[i] for i, (sub, _) in enumerate(runs)
+                      if kind in sub) for kind in kinds}
 
 
 def _trunk(params, tokens, cfg: Qwen3NextConfig, aux: Optional[str] = None,

@@ -36,7 +36,7 @@ if ROOT not in sys.path:
 
 from benchmarks.families import qwen3_next as family  # noqa: E402
 from benchmarks.families import qwen3_next_reference as reference  # noqa: E402
-from ray_tpu.models import blocks, qwen3_next as qn  # noqa: E402
+from ray_tpu.models import blocks, parts, qwen3_next as qn  # noqa: E402
 from ray_tpu.ops import delta_pointwise, gated_delta, moe  # noqa: E402
 from ray_tpu.tracing import names  # noqa: E402
 
@@ -794,16 +794,35 @@ def test_the_pattern_its_groups_and_what_the_rule_may_keep():
         assert f"name={name}" in text, name
 
 
-def test_at_the_cells_shapes_the_rule_keeps_the_solves_result():
-    """The cell's layers on a chip that states a v5e's 15.75 GiB: the rule
-    takes ``delta_x`` (7.9 kFLOP a byte of it made again, where the
-    projections' outputs stand at 2,048) before the projections', at 134 MB
-    a DeltaNet layer, and ``block_q`` and the routing's scores make room."""
+def _cells_rule():
+    """The cell's layers on a chip that states a v5e's 15.75 GiB → (the
+    config, its shard, its kinds, the phase that sets the working set, the
+    resident bytes, the rule's choice)."""
     cell, config = _cell()
     cfg = dataclasses.replace(family.program_config(config, cell),
                               attention_impl="pallas")
     base, kinds = qn.kind_shards(cfg, cell["per_chip_batch"], cell["seq_len"],
                                  None)
+    phase = max(blocks.backward_phases(
+        base, kinds, blocks.pattern_groups(cfg.pattern)),
+        key=lambda p: p.nbytes)
+    # parameters, two moments and the gradients: 7.51 GB (the config's file)
+    resident = 12 * qn.param_count(cfg)
+    assert "7.51 GB" in config["deployment"] and round(resident / 1e7) == 751
+    policy = blocks.choose_remat_policy_kinds(
+        tuple(kinds.values()), phase.nbytes, family.V5E_BYTES_LIMIT, resident)
+    return cfg, base, kinds, phase, resident, policy
+
+
+def test_at_the_cells_shapes_the_rule_keeps_the_solves_result():
+    """The cell's layers on a chip that states a v5e's 15.75 GiB: the rule
+    takes ``delta_x`` (7.9 kFLOP a byte of it made again, where the
+    projections' outputs stand at 2,048) before the projections', at 134 MB
+    a DeltaNet layer — and, since the DeltaNet kind is charged what the
+    compiled backward holds (PR 69), the four projections' outputs of all
+    three layers after it, 2.25 GiB, with the routing's scores and
+    attention's q: never the scan's states and o, 3.75 GiB."""
+    _, _, kinds, phase, _, policy = _cells_rule()
     x = next(c for c in kinds["L"].candidates
              if c.names == (names.RES_DELTA_X,))
     assert x.nbytes == 4 * 128 * 32 * 64 * 64 * 2 == 134_217_728
@@ -811,23 +830,119 @@ def test_at_the_cells_shapes_the_rule_keeps_the_solves_result():
     assert 7_900 < x.flops / x.nbytes < 8_000
     ranked = sorted((c for k in kinds.values() for c in k.candidates),
                     key=lambda c: -c.flops / c.nbytes)
-    assert ranked.index(x) < ranked.index(next(
-        c for c in ranked if c.names == names.RES_DELTA_PARTS))
-    phase = max(blocks.backward_phases(
-        base, kinds, blocks.pattern_groups(cfg.pattern)),
-        key=lambda p: p.nbytes)
+    parts_ = next(c for c in ranked if c.names == names.RES_DELTA_PARTS)
+    assert ranked.index(x) < ranked.index(parts_)
+    assert kinds["L"].applications * parts_.nbytes == 2_415_919_104
     assert phase.name == "3 x scan(L)"
-    # parameters, two moments and the gradients: 7.51 GB (the config's file)
-    resident = 12 * qn.param_count(cfg)
-    assert "7.51 GB" in config["deployment"] and round(resident / 1e7) == 751
-    policy = blocks.choose_remat_policy_kinds(
-        tuple(kinds.values()), phase.nbytes, family.V5E_BYTES_LIMIT, resident)
-    assert names.RES_DELTA_X in policy.saved
     assert policy.saved_bytes <= policy.budget_bytes
-    assert {names.RES_FLASH_O, names.RES_MID, names.RES_DELTA_BA} <= set(
+    assert {names.RES_DELTA_X, names.RES_FLASH_O, names.RES_MID,
+            names.RES_MOE_SCORES, names.RES_Q, *names.RES_DELTA_PARTS} <= set(
         policy.saved)
-    assert not {names.RES_DELTA_STATES, names.RES_DELTA_O,
-                *names.RES_DELTA_PARTS} & set(policy.saved)
+    assert not {names.RES_DELTA_STATES, names.RES_DELTA_O} & set(policy.saved)
+
+
+@pytest.mark.parametrize("kind", ["L", "F"])
+def test_a_kinds_backward_is_priced_by_the_larger_of_its_two_moments(kind):
+    """Each term of ``kind_shards``' ``block_bytes`` at the cell's shapes, as
+    its docstring states them: 32,768 tokens in bf16, a stream of 2,048, the
+    fused projection 12,288 wide (q, k 2,048; v, z 4,096), 128 chunks a row."""
+    cfg, base, kinds, _, _, _ = _cells_rule()
+    MiB, T, a, D = 2 ** 20, 4 * 8192, 2, 2048
+    carried = T * D * a
+    stream, routed = parts.gated_experts_working_set(T, D, 512, 10, 32, 512, a)
+    _, shared = parts.swiglu_price(4, 8192, 8192, D, 512, a, ("g", "u"))
+    experts_set = stream + max(routed, shared)
+    assert (stream, routed, shared) == (960 * MiB, 926 * MiB, 172 * MiB)
+    table = 18_992 * D
+    lone = {"L": kinds["F"].grad_bytes, "F": 0}[kind]
+    absent = lone * (4 - a) // 4 + table * ((4 - a) + 4 + (a + 4))
+    if kind == "L":
+        solved, ba = 4 * 128 * 32 * 64 * 64 * a, T * 64 * 4
+        states = 4 * 128 * 32 * 128 * 128 * 4
+        weights = a * (D * 12_288 + D * 64 + 4_096 * D)
+        # x, q‖k‖v‖z as projected, q, k, v out of the conv kernel
+        waits = a * T * (D + 12_288 + 8_192) + weights + solved + ba
+        # … the states, o, y and the cotangents d y, d o, d z
+        own = waits + states + a * T * 5 * 4_096
+        assert (waits, states, own - waits - states) == (
+            1_686_372_352, 1_024 * MiB, 1_280 * MiB)     # 1.57, 1, 1.25 GiB
+        assert absent == 731_001_856            # 0.68 GiB
+        assert own > waits + experts_set        # the compiled step's peak
+    else:
+        width, kv = 16 * 256, 2 * 256
+        weights = a * (D * 2 * width + 2 * D * kv + width * D)
+        waits = (a * T * (2 * D + 3 * width + 2 * kv + 2 * width) + weights
+                 + T * 16 * 4)
+        own = waits + a * T * (2 * D + 5 * width) + weights
+        assert absent == 466_747_392
+    assert kinds[kind].block_bytes == carried + max(waits + experts_set,
+                                                    own) - absent
+    assert kinds[kind].grad_bytes == 4 * parts.param_count(
+        lambda: qn._layer_init(jax.random.PRNGKey(0), 1, kind, cfg))
+
+
+# ``peak_memory_in_bytes`` of the cell's step compiled for a v5e (the described
+# chip; _exp_pr69_compile.py, the buffer lists under chiprun_out/pr69/): with
+# the list the parent kept — commit f5d8d11, the same bytes from this tree with
+# that list forced — and with the list this rule keeps — PR 69's tree
+PEAK_AT_THE_PARENTS_LIST = 12_494_747_648
+PEAK_AT_THE_NEW_LIST = 15_103_342_592
+PARENTS_LIST = (
+    names.RES_FLASH_O, names.RES_FLASH_LSE, names.RES_DELTA_X,
+    names.RES_MOE_KTH, names.RES_MOE_LAST, names.RES_MID, names.RES_K,
+    names.RES_V, names.RES_DELTA_BA, names.RES_MOE_PAIR_KEY,
+    names.RES_MOE_PAIR_GATE)
+
+
+@pytest.mark.parametrize("kept,compiled", [
+    ("parent", PEAK_AT_THE_PARENTS_LIST), ("new", PEAK_AT_THE_NEW_LIST)])
+def test_the_rules_sum_stands_just_over_the_compiled_peak(kept, compiled):
+    """Resident + working set + kept against what the compiler holds: at or
+    above it and no more than 0.75 GiB above, with the parent's kept list
+    and with this rule's — and the compiled step under the limit less the
+    reserve, which is all the rule can promise."""
+    _, _, kinds, phase, resident, policy = _cells_rule()
+    saved = PARENTS_LIST if kept == "parent" else policy.saved
+    kept_bytes = sum(k.applications * c.nbytes for k in kinds.values()
+                     for c in k.candidates if set(c.names) <= set(saved))
+    assert kept == "parent" or kept_bytes == policy.saved_bytes
+    estimate = resident + phase.nbytes + kept_bytes
+    assert 0 <= estimate - compiled <= 0.75 * 2 ** 30
+    assert max(estimate, compiled) <= (family.V5E_BYTES_LIMIT
+                                       - blocks.REMAT_RESERVE_BYTES)
+
+
+def test_kept_projections_are_not_made_again():
+    """The ``RES_DELTA_PARTS`` names sit on the in-projections' own outputs:
+    with them in the policy a DeltaNet layer's backward holds no second
+    ``bsd,de->bse`` product but ``[b, a]``'s, and none with ``delta_ba`` too
+    — keeping them spares the four products, not only holds their bytes."""
+    cfg = qn.qwen3_next_tiny()
+    p = jax.tree.map(lambda t: t[0],
+                     qn._layer_init(jax.random.PRNGKey(0), 1, "L", cfg))
+    x = jnp.ones((2, cfg.seq_len, cfg.d_model), cfg.dtype)
+
+    def products(jaxpr, above=""):
+        for eqn in jaxpr.eqns:
+            stack = f"{above}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "dot_general":
+                yield stack
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from products(sub, stack)
+
+    def made_again(saved):
+        layer = jax.checkpoint(
+            partial(qn._layer, cfg=cfg, kind="L"),
+            policy=jax.checkpoint_policies.save_only_these_names(*saved))
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda x, p: layer(x, p).astype(jnp.float32).sum(),
+            argnums=(0, 1)))(x, p)
+        return sum("rematted_computation" in s and "bsd,de->bse" in s
+                   for s in products(jaxpr.jaxpr))
+
+    assert made_again(()) == 5
+    assert made_again(names.RES_DELTA_PARTS) == 1
+    assert made_again(names.RES_DELTA_PARTS + (names.RES_DELTA_BA,)) == 0
 
 
 @pytest.mark.parametrize("axis", ["ep", "tp", "pp", "cp"])
